@@ -59,13 +59,15 @@ PRECISION_BUDGET = 3
 #: The adapterless variant ("" sig) rides outside this allowance.
 LORA_BUDGET = 4
 
-#: bf16 peak FLOPs/s per chip by device_kind substring (public specs);
-#: bench.py's MFU estimate shares this table via :func:`peak_flops_for`.
+#: bf16 peak FLOPs/s per chip, keyed by the exact ``device_kind`` string
+#: JAX reports on that chip (``jax.devices()[0].device_kind``). Source:
+#: Google Cloud documentation, "TPU v5e" system architecture — 197 TFLOP/s
+#: bf16 and 393 TOP/s int8 per chip. A kind that is not a key here has no
+#: peak: add a row with its source once a run on that chip has printed its
+#: kind. bench.py's MFU estimate shares this table via
+#: :func:`peak_flops_for`.
 PEAK_FLOPS_BF16: Dict[str, float] = {
-    "v6e": 918e12, "v6": 918e12,
-    "v5p": 459e12,
-    "v5e": 197e12, "v5litepod": 197e12, "v5": 197e12,
-    "v4": 275e12,
+    "TPU v5 lite": 197e12,
 }
 #: int8 MXU peak relative to bf16 (BENCH_int8.json's mxu_peak_ratio).
 INT8_PEAK_RATIO = 2.0
@@ -81,20 +83,13 @@ def enabled() -> bool:
 def peak_flops_for(device_kind: str, precision: str = "bf16"
                    ) -> Optional[float]:
     """Peak FLOPs/s for a device kind at a serving precision, or ``None``
-    when the hardware is unknown (CPU dev boxes: MFU stays null rather
-    than inventing a denominator). ``SDTPU_PERF_PEAK_FLOPS`` overrides
-    the table outright — deterministic MFU in tests, and a forward knob
-    for chips the table hasn't met."""
-    override = env_float("SDTPU_PERF_PEAK_FLOPS", 0.0)
-    if override > 0:
-        return override
-    dk = str(device_kind or "").lower().replace(" ", "")
-    for key, val in PEAK_FLOPS_BF16.items():
-        if key in dk:
-            if str(precision or "").startswith("int8"):
-                return val * INT8_PEAK_RATIO
-            return val
-    return None
+    when the kind is not in :data:`PEAK_FLOPS_BF16` (CPU dev boxes, chips
+    the table has not met: MFU stays null rather than inventing a
+    denominator)."""
+    peak = PEAK_FLOPS_BF16.get(str(device_kind or ""))
+    if peak is not None and str(precision or "").startswith("int8"):
+        return peak * INT8_PEAK_RATIO
+    return peak
 
 
 def _device_kind() -> str:

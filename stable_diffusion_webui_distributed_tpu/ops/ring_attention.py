@@ -49,14 +49,9 @@ def _ring_body(q, k, v, axis_name: str, scale: float, vary_axes=None):
 
     # fresh accumulators must be marked device-varying over every mesh axis
     # the inputs vary over (the ring axis, plus dp on combined dp+sp
-    # meshes) or the fori_loop carry types disagree under shard_map; older
-    # jax has no varying-mesh-axes type system, so pcast degrades to identity
-    _pcast = getattr(lax, "pcast", None)
-
+    # meshes) or the fori_loop carry types disagree under shard_map
     def varying(x):
-        if _pcast is None:
-            return x
-        return _pcast(x, vary_axes or axis_name, to="varying")
+        return lax.pcast(x, vary_axes or axis_name, to="varying")
 
     m0 = varying(jnp.full((b, h, t_loc, 1), -jnp.inf, jnp.float32))
     l0 = varying(jnp.zeros((b, h, t_loc, 1), jnp.float32))
@@ -129,13 +124,6 @@ def ring_attention(
     """
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map as _shard_map  # jax >= 0.6 name
-
-        shard_map = _shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
-
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     # carry the dp axis on the batch dim when the mesh has one — otherwise
@@ -148,6 +136,6 @@ def ring_attention(
     def body(q_l, k_l, v_l):
         return _ring_body(q_l, k_l, v_l, axis_name, scale, vary_axes)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
     )(q, k, v)

@@ -26,32 +26,6 @@ _ROW_ENDINGS = ("out_proj", "fc2", "ff_out", "time_fc2", "add_fc2",
                 "proj_out")
 
 
-def keystr_path(keypath, separator: str = "/") -> str:
-    """Version-compat ``jax.tree_util.keystr`` in "simple" form.
-
-    ``keystr(..., simple=True, separator=...)`` only exists from jax 0.4.35
-    behind a changing signature (0.4.37 still raises TypeError on the
-    kwargs). Every keystr call site in the repo goes through this shim:
-    try the modern call, fall back to joining the key entries by hand —
-    DictKey('a')/GetAttrKey('a') -> "a", SequenceKey(0) -> "0" — which is
-    exactly what ``simple=True`` produces."""
-    try:
-        return jax.tree_util.keystr(keypath, simple=True,
-                                    separator=separator)
-    except TypeError:
-        parts = []
-        for k in keypath:
-            if hasattr(k, "key"):       # DictKey / FlattenedIndexKey
-                parts.append(str(k.key))
-            elif hasattr(k, "name"):    # GetAttrKey
-                parts.append(str(k.name))
-            elif hasattr(k, "idx"):     # SequenceKey
-                parts.append(str(k.idx))
-            else:
-                parts.append(str(k))
-        return separator.join(parts)
-
-
 def tp_spec_for(path: str, ndim: int):
     """PartitionSpec for one param, from its tree path (joined with '/')."""
     from jax.sharding import PartitionSpec as P
@@ -89,7 +63,8 @@ def shard_params(params, mesh, use_tp: bool = True):
     placed = []
     for keypath, leaf in leaves:
         if tp > 1 and use_tp and hasattr(leaf, "ndim"):
-            path = keystr_path(keypath, separator="/")
+            path = jax.tree_util.keystr(keypath, simple=True,
+                                          separator="/")
             spec = tp_spec_for(path, leaf.ndim)
             # only shard dims that divide evenly; else replicate
             ok = True

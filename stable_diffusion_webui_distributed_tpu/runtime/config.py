@@ -175,10 +175,6 @@ def _logger():
 #   distinct (bucket, cadence, precision) rows and distinct (tenant,
 #   class) SLO rows each; least-recently-touched rows are evicted (and
 #   counted) so adversarial tenant names cannot grow the ledger.
-# - ``SDTPU_PERF_PEAK_FLOPS`` (float FLOP/s, default 0 = auto): MFU
-#   denominator override. 0 resolves the chip's bf16 peak from the
-#   built-in table (int8 counts double); unknown hardware (CPU dev
-#   boxes) reports MFU null rather than inventing a denominator.
 # - ``SDTPU_PERF_SLO_TARGET`` (float, default 0.95): SLO attainment
 #   target behind the burn-rate gauge — burn 1.0 means consuming the
 #   (1 - target) error budget exactly.

@@ -15,6 +15,7 @@ registered at runtime/flags.py:33-38).
 
 from __future__ import annotations
 
+import os
 from typing import Dict, Optional, Sequence
 
 import numpy as np
@@ -22,32 +23,37 @@ import numpy as np
 AXIS_ORDER = ("dp", "tp", "sp")
 
 
-def enable_compilation_cache(
-        cache_dir: Optional[str] = None) -> Optional[str]:
+#: where ``JAX_COMPILATION_CACHE_DIR`` places nothing: one fixed directory
+#: inside the checkout (git-ignored). The path is part of XLA's cache key,
+#: so it never carries a home directory, temp name, pid or time.
+DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def enable_compilation_cache() -> str:
     """Persist XLA executables across process restarts (first SDXL compile
     costs ~minutes on TPU; a restarted node re-serves in seconds). The
     reference's workers pay webui's model-load on every restart with no
     equivalent escape hatch.
 
-    Returns the active cache directory (None when enabling failed) so the
-    serving warmup (serving/warmup.py) can report where its pre-built
-    executables landed — warmup + this cache is what turns a restarted
-    server's first request from compile cost into dispatch cost."""
-    import os
-
+    Placement comes from outside: when ``JAX_COMPILATION_CACHE_DIR`` is set
+    JAX already reads it and no directory is set in code; otherwise
+    :data:`DEFAULT_COMPILE_CACHE` is used. Returns the active directory so
+    callers (serving/warmup.py, chip_smoke.py) can report where executables
+    land. A directory that cannot be created raises: a cache that silently
+    stays off turns every restart back into a full compile."""
     import jax
 
     from stable_diffusion_webui_distributed_tpu.runtime.config import env_str
 
-    cache_dir = cache_dir or env_str(
-        "SDTPU_XLA_CACHE", os.path.expanduser("~/.cache/sdtpu-xla"))
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        return cache_dir
-    except Exception:  # noqa: BLE001 — cache is an optimization only
-        return None
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    placed = env_str("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    os.makedirs(DEFAULT_COMPILE_CACHE, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE)
+    return DEFAULT_COMPILE_CACHE
 
 
 def init_multihost(coordinator: Optional[str] = None,

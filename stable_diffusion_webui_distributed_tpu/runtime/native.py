@@ -4,8 +4,9 @@ The reference keeps all of its own code in Python and leans on each
 worker's CUDA substrate for performance (SURVEY.md §2: zero native code in
 the repo). Here the serving path has real host-side work — PNG encoding of
 finished images — done natively (native/png_encoder.cpp, zlib) with a
-silent PIL fallback when no toolchain is available. The library is
-compiled once per machine into ``native/build/`` and memoized.
+PIL fallback when no toolchain is available; the fallback is logged once
+and :func:`active_encoder` names which one serves. The library is compiled
+once per machine into ``native/build/`` (git-ignored) and memoized.
 """
 
 from __future__ import annotations
@@ -28,9 +29,19 @@ def _native_dir() -> str:
         os.path.dirname(os.path.abspath(__file__)))), "native")
 
 
+def _warn_fallback(why: str) -> None:
+    from stable_diffusion_webui_distributed_tpu.runtime.logging import (
+        get_logger,
+    )
+
+    get_logger().warning(
+        "native png encoder unavailable (%s); PNGs are encoded by PIL", why)
+
+
 def _build_library() -> Optional[str]:
     src = os.path.join(_native_dir(), "png_encoder.cpp")
     if not os.path.exists(src):
+        _warn_fallback(f"{src} missing")
         return None
     build_dir = os.path.join(_native_dir(), "build")
     out = os.path.join(build_dir, "libsdtpu_png.so")
@@ -40,15 +51,12 @@ def _build_library() -> Optional[str]:
     cmd = ["g++", "-O3", "-shared", "-fPIC", src, "-lz", "-o", out]
     try:
         proc = subprocess.run(cmd, capture_output=True, timeout=120)
-    except (OSError, subprocess.TimeoutExpired):
+    except (OSError, subprocess.TimeoutExpired) as e:
+        _warn_fallback(f"g++ did not run: {e}")
         return None
     if proc.returncode != 0:
-        from stable_diffusion_webui_distributed_tpu.runtime.logging import (
-            get_logger,
-        )
-
-        get_logger().debug("native png encoder build failed: %s",
-                           proc.stderr.decode(errors="replace")[:400])
+        _warn_fallback("build failed: "
+                       + proc.stderr.decode(errors="replace")[:400])
         return None
     return out
 
@@ -70,9 +78,16 @@ def _get_lib() -> Optional[ctypes.CDLL]:
                 ctypes.c_int, ctypes.c_char_p, ctypes.c_long,
             ]
             _lib = lib
-        except OSError:
+        except OSError as e:
+            _warn_fallback(f"{path} did not load: {e}")
             _lib_failed = True
         return _lib
+
+
+def active_encoder() -> str:
+    """``"native"`` when the C++ encoder built and loaded, else ``"pil"`` —
+    the encoder :func:`pipeline.payload.array_to_b64png` serves with."""
+    return "native" if _get_lib() is not None else "pil"
 
 
 def warm_up(background: bool = True) -> None:

@@ -7,7 +7,7 @@ persistent XLA cache (``runtime/mesh.py``) softens this but still re-runs
 tracing, lowering and cache probing per stage. This module closes the
 loop the way ahead-of-time compilation systems do: each compiled stage is
 serialized once (``jax.experimental.serialize_executable``) and persisted
-under ``SDTPU_AOT_DIR`` beside the XLA cache, keyed by the EXISTING
+under ``SDTPU_AOT_DIR``, keyed by the EXISTING
 ``Engine._cached`` compile key plus the *call signature* (abstract shapes
 / dtypes / static values of one concrete call — one compile key can host
 several executables, e.g. the encode stage retraces per chunk count) plus
@@ -66,8 +66,7 @@ def enabled() -> bool:
 
 
 def default_dir() -> str:
-    """Artifact root: ``SDTPU_AOT_DIR``, defaulting beside the XLA cache
-    (``~/.cache/sdtpu-aot`` next to ``~/.cache/sdtpu-xla``)."""
+    """Artifact root: ``SDTPU_AOT_DIR``, default ``~/.cache/sdtpu-aot``."""
     return env_str("SDTPU_AOT_DIR",
                    os.path.expanduser("~/.cache/sdtpu-aot"))
 
@@ -369,14 +368,23 @@ def _serialize_compiled(compiled) -> bytes:
     from jax.experimental import serialize_executable as se
 
     payload_bytes, in_tree, out_tree = se.serialize(compiled)
-    return pickle.dumps((payload_bytes, in_tree, out_tree))
+    # the ids of the devices the program runs on ride along: loading
+    # defaults to EVERY device of the backend, which an executable compiled
+    # for fewer (one chip of a host, a mesh slice) then refuses to run on
+    device_ids = [d.id for d in
+                  compiled.runtime_executable().local_devices()]
+    return pickle.dumps((payload_bytes, in_tree, out_tree, device_ids))
 
 
 def _deserialize_compiled(blob: bytes):
+    import jax
     from jax.experimental import serialize_executable as se
 
-    payload_bytes, in_tree, out_tree = pickle.loads(blob)
-    return se.deserialize_and_load(payload_bytes, in_tree, out_tree)
+    payload_bytes, in_tree, out_tree, device_ids = pickle.loads(blob)
+    by_id = {d.id: d for d in jax.devices()}
+    return se.deserialize_and_load(
+        payload_bytes, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])
 
 
 class AotFunction:

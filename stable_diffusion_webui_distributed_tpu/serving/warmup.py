@@ -101,8 +101,7 @@ def _warmup_lora_cells() -> List[Optional[tuple]]:
 
 def warmup_engine(engine, bucketer: Optional[ShapeBucketer] = None,
                   steps: Optional[int] = None,
-                  sampler: Optional[str] = None,
-                  cache_dir: Optional[str] = None) -> Dict:
+                  sampler: Optional[str] = None) -> Dict:
     """Pre-lower every (shape, batch[, precision]) bucket's pipeline;
     returns a report of how many stage builds the sweep triggered and its
     wall time."""
@@ -116,7 +115,7 @@ def warmup_engine(engine, bucketer: Optional[ShapeBucketer] = None,
     if env_str("SDTPU_WARMUP") == "0":
         return {"skipped": True, "reason": "SDTPU_WARMUP=0"}
 
-    active_cache = enable_compilation_cache(cache_dir)
+    active_cache = enable_compilation_cache()
     bucketer = bucketer or ShapeBucketer()
     steps = steps if steps is not None else env_int("SDTPU_WARMUP_STEPS", 20)
     sampler = sampler or env_str("SDTPU_WARMUP_SAMPLER", "Euler a")

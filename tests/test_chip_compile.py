@@ -1,0 +1,89 @@
+"""Both Pallas attention kernels, compiled by the TPU's own compiler.
+
+Interpret mode (tests/test_ops.py, tests/test_ragged.py) checks the math;
+it cannot see what Mosaic refuses: a slice off the tiling, too much VMEM.
+The TPU compiler is installed here and compiles for a chip that is
+described and not attached, so the kernels of the main path are lowered
+with ``interpret=False`` at the real self-attention shapes of SD1.5 512²
+and SDXL 1024² for one chip of a ``v5e:2x2`` host. Nothing runs: a pass
+says the chip's compiler accepts the kernel, not that its result is right
+(chip_smoke.py compares results on the chip).
+"""
+
+import os
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import topologies
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from stable_diffusion_webui_distributed_tpu.ops.flash_attention import (
+    _flash_bhtd,
+)
+from stable_diffusion_webui_distributed_tpu.ops.ragged_attention import (
+    _ragged_bhtd,
+)
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from chip_smoke import KERNEL_CASES  # noqa: E402  (repo root on path)
+
+#: (batch*heads, tokens, head_dim) of every UNet self-attention at CFG
+#: batch 2 — the cases chip_smoke.py runs on the chip: (16, 4096, 40),
+#: (16, 1024, 80), (16, 256, 160), (16, 64, 160) for SD1.5 at 512²;
+#: (20, 4096, 64), (40, 1024, 64) for SDXL at 1024²
+SHAPES = [(b * h, t, d) for b, h, t, d in KERNEL_CASES]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip (the next run warns and
+    compiles again): keep the cache off around these."""
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("bh,t,d", SHAPES)
+def test_flash_kernel_compiles_for_v5e(one_chip, bh, t, d):
+    qkv = jax.ShapeDtypeStruct((bh, t, d), jnp.bfloat16, sharding=one_chip)
+    block = min(128, t)
+    text = _compiled_text(
+        lambda q, k, v: _flash_bhtd(q, k, v, d ** -0.5, block, block, False),
+        qkv, qkv, qkv)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("bh,t,d", SHAPES)
+def test_ragged_kernel_compiles_for_v5e(one_chip, bh, t, d):
+    qkv = jax.ShapeDtypeStruct((bh, t, d), jnp.bfloat16, sharding=one_chip)
+    tl = jax.ShapeDtypeStruct((bh,), jnp.int32, sharding=one_chip)
+    block = min(128, t)
+    text = _compiled_text(
+        lambda n, q, k, v: _ragged_bhtd(q, k, v, n, d ** -0.5, block, block,
+                                        False),
+        tl, qkv, qkv, qkv)
+    assert "tpu_custom_call" in text

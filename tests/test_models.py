@@ -246,12 +246,9 @@ def make_ldm_vae(cfg, prefix="first_stage_model"):
 # --------------------------------------------------------------------------
 
 def tree_shapes(tree):
-    from stable_diffusion_webui_distributed_tpu.parallel.sharding import (
-        keystr_path,
-    )
-
     flat = jax.tree_util.tree_flatten_with_path(tree)[0]
-    return {keystr_path(k): np.shape(v) for k, v in flat}
+    return {jax.tree_util.keystr(k, simple=True, separator="/"): np.shape(v)
+            for k, v in flat}
 
 
 def assert_same_structure(converted, initialized, scope):

@@ -87,13 +87,13 @@ def _record_one(led, **kw):
 # -- ledger math -------------------------------------------------------------
 
 class TestLedgerMath:
-    def test_mfu_against_forced_peak(self, monkeypatch):
-        # 1e12 FLOPs over 2 s against a forced 1e12 FLOP/s peak: MFU 0.5
-        # exactly, deterministic on any host
+    def test_mfu_against_table_peak(self, monkeypatch):
+        # 197e12 FLOPs over 2 s on a device whose kind the table knows
+        # (197e12 FLOP/s): MFU 0.5 exactly, deterministic on any host
         monkeypatch.setenv("SDTPU_PERF", "1")
-        monkeypatch.setenv("SDTPU_PERF_PEAK_FLOPS", "1e12")
+        monkeypatch.setattr(perf, "_device_kind", lambda: "TPU v5 lite")
         led = perf.PerfLedger(max_groups=8)
-        _record_one(led)
+        _record_one(led, flops=197e12)
         (g,) = led.summary()["groups"]
         assert g["bucket"] == "64x64"
         assert g["mfu"] == pytest.approx(0.5)
@@ -101,9 +101,8 @@ class TestLedgerMath:
         assert g["padding_waste"] == pytest.approx(0.25)
         assert g["dispatches"] == 1 and g["requests"] == 2
 
-    def test_cpu_without_override_never_fabricates_mfu(self, monkeypatch):
+    def test_cpu_never_fabricates_mfu(self, monkeypatch):
         monkeypatch.setenv("SDTPU_PERF", "1")
-        monkeypatch.delenv("SDTPU_PERF_PEAK_FLOPS", raising=False)
         led = perf.PerfLedger(max_groups=8)
         _record_one(led)
         (g,) = led.summary()["groups"]
@@ -161,27 +160,22 @@ class TestLedgerMath:
 
 
 class TestPeakFlops:
-    @pytest.fixture(autouse=True)
-    def _no_override(self, monkeypatch):
-        monkeypatch.delenv("SDTPU_PERF_PEAK_FLOPS", raising=False)
-
-    def test_known_chips(self):
-        assert perf.peak_flops_for("TPU v5p") == pytest.approx(459e12)
-        assert perf.peak_flops_for("TPU v5e") == pytest.approx(197e12)
-        assert perf.peak_flops_for("TPU v4") == pytest.approx(275e12)
+    def test_known_chip_is_keyed_by_reported_device_kind(self):
+        assert perf.peak_flops_for("TPU v5 lite") == pytest.approx(197e12)
 
     def test_int8_doubles_the_mxu_peak(self):
-        assert perf.peak_flops_for("TPU v5p", "int8") \
-            == pytest.approx(2 * 459e12)
+        assert perf.peak_flops_for("TPU v5 lite", "int8") \
+            == pytest.approx(2 * 197e12)
 
-    def test_unknown_hardware_is_none(self):
-        assert perf.peak_flops_for("cpu") is None
-        assert perf.peak_flops_for("") is None
-
-    def test_env_override_wins_outright(self, monkeypatch):
+    @pytest.mark.parametrize("kind", [
+        "cpu", "", "TPU v5e", "TPU v5", "tpu v5 lite", "TPU v5 lite pod",
+    ])
+    def test_kind_outside_the_table_is_none(self, kind, monkeypatch):
+        # no substring matching and no env override: a kind the table has
+        # not met gets no peak, at any precision
         monkeypatch.setenv("SDTPU_PERF_PEAK_FLOPS", "123e9")
-        assert perf.peak_flops_for("cpu") == pytest.approx(123e9)
-        assert perf.peak_flops_for("TPU v4") == pytest.approx(123e9)
+        assert perf.peak_flops_for(kind) is None
+        assert perf.peak_flops_for(kind, "int8") is None
 
 
 # -- executable census -------------------------------------------------------

@@ -264,3 +264,48 @@ class TestBatchKeys:
 
     def test_full_uint32_seed_range(self):
         rng.batch_keys(2 ** 32 - 1, 0, 2)  # must not overflow
+
+
+class TestCompileCachePlacement:
+    """runtime/mesh.py: the compile cache is placed from outside
+    (JAX_COMPILATION_CACHE_DIR) or at one fixed path inside the checkout."""
+
+    @pytest.fixture(autouse=True)
+    def _restore_jax_config(self):
+        from jax.experimental.compilation_cache import compilation_cache
+
+        keys = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_compile_time_secs")
+        was = {k: getattr(jax.config, k) for k in keys}
+        yield
+        for k, v in was.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+
+    def test_env_placement_sets_no_directory_in_code(self, monkeypatch,
+                                                     tmp_path):
+        from stable_diffusion_webui_distributed_tpu.runtime import mesh
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", "sentinel-untouched")
+        assert mesh.enable_compilation_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == "sentinel-untouched"
+
+    def test_default_is_the_fixed_path_inside_the_checkout(self,
+                                                           monkeypatch):
+        from stable_diffusion_webui_distributed_tpu.runtime import mesh
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(repo, ".jax_cache")
+        assert mesh.enable_compilation_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert os.path.isdir(want)
+
+    def test_two_calls_give_the_same_path(self, monkeypatch):
+        from stable_diffusion_webui_distributed_tpu.runtime import mesh
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert mesh.enable_compilation_cache() \
+            == mesh.enable_compilation_cache() \
+            == mesh.DEFAULT_COMPILE_CACHE
