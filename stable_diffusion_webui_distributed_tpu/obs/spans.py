@@ -5,8 +5,7 @@ in-flight and recently-finished request trace. A request context is minted
 at API ingress (or lazily by the serving dispatcher for direct callers) via
 :func:`request`; any code on that thread — or on a thread entered through
 :func:`bind_current` — can then open child spans with :func:`span`, and
-``runtime/trace.py`` feeds every ``StageStats.timer`` block in as a leaf
-span automatically (:func:`stage_event`).
+``runtime/trace.py`` runs every ``StageStats.timer`` block as one.
 
 Coalesced dispatches link leader and followers: the leader's device span is
 mirrored into each follower's trace with ``leader_request_id`` /
@@ -14,7 +13,13 @@ mirrored into each follower's trace with ``leader_request_id`` /
 shows where its wall-clock went even though another request drove the TPU.
 
 Timing is host-side ``time.perf_counter()`` only — recording a span never
-syncs the device. The store is bounded (``SDTPU_OBS_MAX_REQUESTS`` finished
+syncs the device. While a ``jax.profiler`` capture runs (whoever started
+it), every span opened through :func:`request` or :func:`span` is also a
+``TraceAnnotation`` named ``sdtpu:<span name>`` carrying ``request_id`` and
+``span_id``, so the capture holds the span tree on its host planes, on the
+profiler's clock; with no capture running that costs one flag check.
+Intervals recorded after the fact (:func:`add_span`) exist only here. The
+store is bounded (``SDTPU_OBS_MAX_REQUESTS`` finished
 traces) and lock-disciplined: one lock, nothing external called while
 holding it. Export is Chrome trace-event JSON ("X" complete events with
 ph/ts/dur/pid/tid), loadable in Perfetto / ``chrome://tracing``.
@@ -53,11 +58,35 @@ _PID = os.getpid()
 #: under the GIL, so ids are unique without touching the tracer lock.
 _IDS = itertools.count(1)
 
+#: ``jax.profiler.TraceAnnotation``, imported by the first span: jax is
+#: heavy and this module is imported by code that never traces.
+_ANNOTATION = None
+
+
+def _annotate(name: str, **meta: Any):
+    """An entered ``sdtpu:<name>`` annotation while a profiler capture is
+    running, else None (a flag check)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    if not _ANNOTATION.is_enabled():
+        return None
+    ann = _ANNOTATION("sdtpu:" + name, **meta)
+    ann.__enter__()
+    return ann
+
+
 #: (RequestTrace, parent span id) for the code currently executing, or None
 #: outside any request. Thread- and contextvars-scoped: HTTP handler
 #: threads each see only their own request.
 _CURRENT: "contextvars.ContextVar[Optional[Tuple[RequestTrace, int]]]" = \
     contextvars.ContextVar("sdtpu_obs_request", default=None)  # sdtpu-lint: metric
+
+#: The HTTP exchange this thread is serving (:class:`Exchange`), or None.
+_EXCHANGE: "contextvars.ContextVar[Optional[Exchange]]" = \
+    contextvars.ContextVar("sdtpu_obs_exchange", default=None)  # sdtpu-lint: metric
 
 
 class Span:
@@ -129,7 +158,7 @@ class SpanTracer:
                                    DEFAULT_MAX_REQUESTS)
         if slow_s is None:
             slow_s = env_float("SDTPU_OBS_SLOW_S", DEFAULT_SLOW_S)
-        #: set once at construction; tests flip it to measure overhead
+        #: set once at construction; tests flip it
         self.enabled = bool(enabled)
         self.slow_s = max(0.0, float(slow_s or 0.0))
         self._lock = threading.Lock()
@@ -213,7 +242,11 @@ def request(request_id: Optional[str] = None, name: str = "request",
     rid = str(request_id or uuid.uuid4().hex)
     req = RequestTrace(rid, name, dict(attrs))
     tr.open(req)
+    exchange = _EXCHANGE.get()
+    if exchange is not None:
+        exchange.adopt(req)
     token = _CURRENT.set((req, req.root_id))
+    ann = _annotate(name, request_id=rid, span_id=req.root_id)
     error: Optional[str] = None
     try:
         yield req
@@ -221,6 +254,8 @@ def request(request_id: Optional[str] = None, name: str = "request",
         error = f"{type(e).__name__}: {e}"
         raise
     finally:
+        if ann is not None:
+            ann.__exit__(None, None, None)
         _CURRENT.reset(token)
         _finish(tr, req, error)
 
@@ -259,9 +294,13 @@ def span(name: str, **attrs: Any) -> Iterator[Optional[Span]]:
     sp = Span(next(_IDS), parent, name, time.perf_counter(), 0.0,
               threading.get_ident(), dict(attrs))
     token = _CURRENT.set((req, sp.span_id))
+    ann = _annotate(name, request_id=req.request_id,
+                    span_id=sp.span_id)
     try:
         yield sp
     finally:
+        if ann is not None:
+            ann.__exit__(None, None, None)
         _CURRENT.reset(token)
         sp.dur = time.perf_counter() - sp.t0
         tr.record(req, sp)
@@ -279,6 +318,84 @@ def maybe_request(request_id: Optional[str] = None, name: str = "request",
         return
     with request(request_id, name, **attrs) as req:
         yield req
+
+
+# -- the HTTP exchange around a request ---------------------------------------
+
+class Exchange:
+    """The two intervals of one HTTP exchange that lie outside the root
+    span: ``http.read_parse`` ends where the root starts (the trace, and
+    the request id, exist only from there), ``http.respond`` starts after
+    it has closed. Both are recorded into the request's trace with no
+    parent, beside the root; the finished trace stays in the store, so
+    the export holds them."""
+
+    __slots__ = ("t0", "req", "attrs", "_ann")
+
+    def __init__(self) -> None:
+        self.t0 = time.perf_counter()
+        self.req: Optional[RequestTrace] = None
+        #: of ``http.read_parse`` (the handler notes the body's ``bytes``)
+        self.attrs: Dict[str, Any] = {}
+        self._ann = _annotate("http.read_parse")
+
+    def adopt(self, req: RequestTrace) -> None:
+        """Called by :func:`request` when the handler mints the root: the
+        read ends here."""
+        if self.req is not None:
+            return      # a second root on this thread is not the exchange's
+        self.req = req
+        sp = Span(next(_IDS), None, "http.read_parse", self.t0,
+                  req.t0 - self.t0, threading.get_ident(), self.attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(request_id=req.request_id,
+                                   span_id=sp.span_id)
+        self.close_read()
+        TRACER.record(req, sp)
+
+    def close_read(self) -> None:
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+
+
+@contextlib.contextmanager
+def http_exchange() -> Iterator[Optional[Exchange]]:
+    """Around one HTTP handler call (server/api.py ``_dispatch``), from
+    before the body is read until the response is written. Records nothing
+    unless the handler mints a request."""
+    if not TRACER.enabled:
+        yield None
+        return
+    exchange = Exchange()
+    token = _EXCHANGE.set(exchange)
+    try:
+        yield exchange
+    finally:
+        _EXCHANGE.reset(token)
+        exchange.close_read()
+
+
+@contextlib.contextmanager
+def http_respond() -> Iterator[Optional[Span]]:
+    """``http.respond`` of the exchange's request: serialising and writing
+    the response. A no-op when this exchange minted no request."""
+    exchange = _EXCHANGE.get()
+    req = None if exchange is None else exchange.req
+    if req is None:
+        yield None
+        return
+    sp = Span(next(_IDS), None, "http.respond", time.perf_counter(), 0.0,
+              threading.get_ident(), {})
+    ann = _annotate("http.respond", request_id=req.request_id,
+                    span_id=sp.span_id)
+    try:
+        yield sp
+    finally:
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        sp.dur = time.perf_counter() - sp.t0
+        TRACER.record(req, sp)
 
 
 # -- cross-thread / cross-request recording ----------------------------------
@@ -332,6 +449,18 @@ def add_span(req: Optional[RequestTrace], name: str, t0: float, dur: float,
     return sp
 
 
+def add_child(name: str, seconds: float, **attrs: Any) -> Optional[Span]:
+    """Record an interval that ended just now as a child of the span open
+    on this thread (serving/metrics.py: the compile listener hears of an
+    executable only when it is made). A no-op outside a request."""
+    ctx = _CURRENT.get()
+    if ctx is None:
+        return None
+    req, parent = ctx
+    return add_span(req, name, time.perf_counter() - seconds, seconds,
+                    attrs, parent_id=parent)
+
+
 def mirror_span(req: Optional[RequestTrace], name: str, src: Optional[Span],
                 **attrs: Any) -> Optional[Span]:
     """Copy ``src``'s interval into another request's trace — the
@@ -349,22 +478,6 @@ def mark(req: Optional[RequestTrace], status: str, detail: str = "") -> None:
     req.status = status
     if detail:
         req.detail = detail
-
-
-def stage_event(stage: str, seconds: float,
-                t0: Optional[float] = None) -> None:
-    """Leaf span + stage histogram for one ``StageStats.timer`` block
-    (called by runtime/trace.py on every timed stage)."""
-    prometheus.observe_stage(stage, seconds)
-    tr = TRACER
-    ctx = _CURRENT.get()
-    if ctx is None or not tr.enabled:
-        return
-    req, parent = ctx
-    if t0 is None:
-        t0 = time.perf_counter() - seconds
-    tr.record(req, Span(next(_IDS), parent, stage, t0, seconds,
-                        threading.get_ident(), {}))
 
 
 def bind_current(fn):

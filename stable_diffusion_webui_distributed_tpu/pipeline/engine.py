@@ -58,16 +58,15 @@ from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
     build_infotext,
     fix_seed,
 )
-from stable_diffusion_webui_distributed_tpu.obs import (
-    perf as obs_perf,
-    spans as obs_spans,
-)
+from stable_diffusion_webui_distributed_tpu.obs import spans as obs_spans
 from stable_diffusion_webui_distributed_tpu.runtime import dtypes, rng, trace
 from stable_diffusion_webui_distributed_tpu.runtime import interrupt as interrupt_mod
 from stable_diffusion_webui_distributed_tpu.samplers import kdiffusion as kd
 from stable_diffusion_webui_distributed_tpu.samplers import schedules as sched
 from stable_diffusion_webui_distributed_tpu.serving import aot as aot_mod
-from stable_diffusion_webui_distributed_tpu.serving.metrics import METRICS
+from stable_diffusion_webui_distributed_tpu.serving.metrics import (
+    METRICS, install_xla_listener,
+)
 
 
 class Engine:
@@ -90,6 +89,7 @@ class Engine:
         upscaler_provider: Optional[Callable[[str], Optional[Callable]]] = None,
         embedding_store=None,
     ):
+        install_xla_listener()  # /internal/status serving.xla; idempotent
         self.family = family
         self.policy = policy
         self.model_name = model_name or family.name
@@ -276,17 +276,11 @@ class Engine:
                 # each build is a fresh jitted executable for this exact
                 # shape key — i.e. one XLA compile at first dispatch; the
                 # serving layer asserts on this counter (compile count,
-                # bucket hit rate) instead of wall-clock
+                # bucket hit rate) instead of wall-clock. build() only
+                # makes the jit wrapper: the compile's seconds are the
+                # xla.compile span and serving.xla (serving/metrics.py)
                 METRICS.record_compile(key[0])
-                t0 = time.perf_counter()
-                with obs_spans.span("compile", kind=str(key[0]),
-                                    key=str(key)):
-                    fn = build()
-                # perf ledger: compile count + latency histogram per kind
-                # (no-op unless SDTPU_PERF; perf_counter is passive)
-                obs_perf.LEDGER.record_compile(
-                    str(key[0]), time.perf_counter() - t0)
-                self._cache[key] = fn
+                fn = self._cache[key] = build()
             else:
                 METRICS.record_cache_hit(key[0])
         return fn
@@ -923,7 +917,7 @@ class Engine:
 
         def attempt_fn(xx, x_prev, s, h, rtol, atol):
             with trace.STATS.timer("denoise_chunk"), \
-                    trace.annotate("dpm-adaptive-attempt"):
+                    obs_spans.span("chunk.enqueue", adaptive=True):
                 return fn(self.params["unet"], xx, x_prev, s, h, rtol, atol,
                           ctx_u, ctx_c, cfg, au, ac, controls_at(float(s)),
                           inp_arg)
@@ -1320,9 +1314,11 @@ class Engine:
         counts = self._embedding_counts()
         prompt_list = [payload.prompt] if prompts is None else list(prompts)
         cleaned = [extract_lora_tags(p)[0] for p in prompt_list]
-        toks = [tokenize_with_embeddings(tok, c, counts) for c in cleaned]
-        ids_u, w_u, inj_u = tokenize_with_embeddings(
-            tok, payload.negative_prompt, counts)
+        with obs_spans.span("tokenize", prompts=len(cleaned) + 1):
+            toks = [tokenize_with_embeddings(tok, c, counts)
+                    for c in cleaned]
+            ids_u, w_u, inj_u = tokenize_with_embeddings(
+                tok, payload.negative_prompt, counts)
         # cond and uncond must agree on context length (webui pads both);
         # payload.context_chunks floors it at the REQUEST-wide max so an
         # image's conditioning doesn't depend on its dispatch group /
@@ -1570,10 +1566,6 @@ class Engine:
             payload.context_chunks = self.request_context_chunks(payload)
         self._apply_prompt_loras(payload)
         count = payload.total_images if count is None else count
-        from stable_diffusion_webui_distributed_tpu.obs import (
-            spans as obs_spans,
-        )
-
         with obs_spans.span("generate_range", job=job,
                             start=int(start_index), count=int(count),
                             size=f"{payload.width}x{payload.height}"):
@@ -1699,11 +1691,8 @@ class Engine:
                        lora=None):
         """Obs-span wrapper around the chunk loop: one ``denoise_range``
         span (host-side perf_counter, no extra device sync) grouping the
-        per-chunk ``denoise_chunk`` leaf spans StageStats feeds in."""
-        from stable_diffusion_webui_distributed_tpu.obs import (
-            spans as obs_spans,
-        )
-
+        per-chunk ``denoise_chunk`` spans StageStats feeds in, each the
+        parent of its ``chunk.enqueue`` and ``chunk.fence_wait``."""
         with obs_spans.span("denoise_range", sampler=payload.sampler_name,
                             steps=int(steps), start_step=int(start_step),
                             batch=int(x.shape[0]), size=f"{width}x{height}"):
@@ -1879,7 +1868,9 @@ class Engine:
                 # valid, pos) lives in this frame — resumption is
                 # byte-identical and reuses the same executables.
                 if pending is not None:
-                    pending[0].block_until_ready()
+                    with obs_spans.span("chunk.fence_wait",
+                                        steps=pending[1]):
+                        pending[0].block_until_ready()
                     done += pending[1]
                     self.state.step(done)
                     pending = None
@@ -1916,32 +1907,36 @@ class Engine:
                                 step_cache=cached_chunk,
                                 precision=prec.name,
                                 lora_sig=lora_sig)
-            with trace.STATS.timer("denoise_chunk"), \
-                    trace.annotate(f"denoise[{pos}:{pos + length}]"):
-                if ragged is not None:
-                    true_rows, ctx_true_u, ctx_true_c = ragged
-                    carry, fence = fn(
-                        self.params["unet"], carry, jnp.int32(pos), ctx_u,
-                        ctx_c, cfg, image_keys, au, ac, true_rows,
-                        ctx_true_u, ctx_true_c, **lora_kw)
-                elif cached_chunk:
-                    carry, cache, valid, fence = fn(
-                        self.params["unet"], carry, cache, valid,
-                        jnp.int32(pos), ctx_u, ctx_c, cfg, image_keys,
-                        au, ac, mask_arg, init_arg, inp_arg,
-                        jnp.int32(sc.cadence), jnp.int32(cfg_stop),
-                        **lora_kw)
-                else:
-                    carry, fence = fn(
-                        self.params["unet"], carry, jnp.int32(pos), ctx_u,
-                        ctx_c, cfg, image_keys, au, ac, mask_arg, init_arg,
-                        active, inp_arg, **lora_kw)
-                    if valid is not None:
-                        # a plain (CN-active) chunk advanced the latent
-                        # outside the cache's view — refresh on re-entry
-                        valid = jnp.asarray(False)
+            # denoise_chunk is the enqueue of chunk i plus the wait on chunk
+            # i-1's fence: its two children say which
+            with trace.STATS.timer("denoise_chunk"):
+                with obs_spans.span("chunk.enqueue", pos=pos, steps=length):
+                    if ragged is not None:
+                        true_rows, ctx_true_u, ctx_true_c = ragged
+                        carry, fence = fn(
+                            self.params["unet"], carry, jnp.int32(pos),
+                            ctx_u, ctx_c, cfg, image_keys, au, ac,
+                            true_rows, ctx_true_u, ctx_true_c, **lora_kw)
+                    elif cached_chunk:
+                        carry, cache, valid, fence = fn(
+                            self.params["unet"], carry, cache, valid,
+                            jnp.int32(pos), ctx_u, ctx_c, cfg, image_keys,
+                            au, ac, mask_arg, init_arg, inp_arg,
+                            jnp.int32(sc.cadence), jnp.int32(cfg_stop),
+                            **lora_kw)
+                    else:
+                        carry, fence = fn(
+                            self.params["unet"], carry, jnp.int32(pos),
+                            ctx_u, ctx_c, cfg, image_keys, au, ac,
+                            mask_arg, init_arg, active, inp_arg, **lora_kw)
+                        if valid is not None:
+                            # a plain (CN-active) chunk advanced the latent
+                            # outside the cache's view — refresh on re-entry
+                            valid = jnp.asarray(False)
                 if sync and pending is not None:
-                    pending[0].block_until_ready()
+                    with obs_spans.span("chunk.fence_wait",
+                                        steps=pending[1]):
+                        pending[0].block_until_ready()
                     done += pending[1]
                     self.state.step(done)
             dispatched.append((pos, length, cached_chunk))
@@ -1955,7 +1950,8 @@ class Engine:
                 # gated-on path only.
                 cache_prefix.maybe_capture(prefix_plan, pos, tuple(carry))
         if sync and pending is not None:
-            pending[0].block_until_ready()
+            with obs_spans.span("chunk.fence_wait", steps=pending[1]):
+                pending[0].block_until_ready()
             done += pending[1]
             self.state.step(done)
         self.state.finish()
@@ -2075,12 +2071,14 @@ class Engine:
         if not payload.all_prompts:
             # conditioning resolved ONCE per request, not per batch group;
             # per-image prompts resolve per group in the loop instead
-            if ragged_wh is not None:
-                conds, pooleds, ctx_true = self.encode_prompts(
-                    payload, ragged=True)
-            else:
-                conds, pooleds = self.encode_prompts(payload)
-            ref_cond = refiner.encode_prompts(payload) if refiner else None
+            with obs_spans.span("prepare"):
+                if ragged_wh is not None:
+                    conds, pooleds, ctx_true = self.encode_prompts(
+                        payload, ragged=True)
+                else:
+                    conds, pooleds = self.encode_prompts(payload)
+                ref_cond = refiner.encode_prompts(payload) \
+                    if refiner else None
         out = GenerationResult(parameters=payload.model_dump())
 
         # Generate in groups of batch_size so the compiled batch dim is
@@ -2101,36 +2099,35 @@ class Engine:
                 # SURVEY.md §7 layer 5; extra images cost FLOPs once, a new
                 # compile costs minutes)
                 gen_n = group
-            ragged = None
-            if ragged_wh is not None:
-                # true latent rows (ceil: a partial row still needs its
-                # pixels); noise drawn at the TRUE height and zero-padded
-                # so the masked tail starts exactly 0 and row content is
-                # independent of the bucket height the request landed in
-                f = self.family.vae_scale_factor
-                tr = min(h, -(-ragged_wh[1] // f))
-                noise = rng.batch_noise(
-                    payload.seed, payload.subseed, payload.subseed_strength,
-                    pos, gen_n, (tr, w, C),
-                    seed_resize=self._seed_resize_latent(payload),
-                    pin_index=payload.same_seed)
-                noise = jnp.pad(noise, ((0, 0), (0, h - tr), (0, 0), (0, 0)))
-                ragged = (jnp.full((gen_n,), tr, jnp.int32),
-                          jnp.full((gen_n,), ctx_true[0], jnp.int32),
-                          jnp.full((gen_n,), ctx_true[1], jnp.int32))
-            else:
-                noise = rng.batch_noise(
-                    payload.seed, payload.subseed, payload.subseed_strength,
-                    pos, gen_n, (h, w, C),
-                    seed_resize=self._seed_resize_latent(payload),
-                    pin_index=payload.same_seed)
-            x = self._place_batch(noise.astype(jnp.float32) * sigmas[0])
-            keys = self._image_keys(payload, pos, gen_n)
-            if payload.all_prompts:
-                conds, pooleds, ref_cond = self._group_conds(
-                    payload, pos, gen_n, refiner)
-            inp = (self._blank_inpaint_cond(gen_n, width, height)
-                   if self.family.inpaint else None)
+            with obs_spans.span("prepare", group=pos, images=gen_n):
+                # ragged: true latent rows (ceil: a partial row still needs
+                # its pixels); noise drawn at the TRUE height and
+                # zero-padded so the masked tail starts exactly 0 and row
+                # content is independent of the bucket height the request
+                # landed in
+                tr = h if ragged_wh is None else min(
+                    h, -(-ragged_wh[1] // self.family.vae_scale_factor))
+                with obs_spans.span("noise"):
+                    noise = rng.batch_noise(
+                        payload.seed, payload.subseed,
+                        payload.subseed_strength, pos, gen_n, (tr, w, C),
+                        seed_resize=self._seed_resize_latent(payload),
+                        pin_index=payload.same_seed)
+                ragged = None
+                if ragged_wh is not None:
+                    noise = jnp.pad(
+                        noise, ((0, 0), (0, h - tr), (0, 0), (0, 0)))
+                    ragged = (jnp.full((gen_n,), tr, jnp.int32),
+                              jnp.full((gen_n,), ctx_true[0], jnp.int32),
+                              jnp.full((gen_n,), ctx_true[1], jnp.int32))
+                x = self._place_batch(
+                    noise.astype(jnp.float32) * sigmas[0])
+                keys = self._image_keys(payload, pos, gen_n)
+                if payload.all_prompts:
+                    conds, pooleds, ref_cond = self._group_conds(
+                        payload, pos, gen_n, refiner)
+                inp = (self._blank_inpaint_cond(gen_n, width, height)
+                       if self.family.inpaint else None)
             latents = self._split_denoise(
                 payload, x, keys, conds, pooleds, width, height, job,
                 controls, refiner, ref_cond, payload.steps, 0,
@@ -2367,7 +2364,7 @@ class Engine:
             if self.state.flag.interrupted:
                 break
             with trace.STATS.timer("denoise_chunk"), \
-                    trace.annotate(f"denoise[{i}:{i + 1}]"):
+                    obs_spans.span("chunk.enqueue", pos=i, steps=1):
                 carry, fence = stepfn(
                     self.params["unet"], carry, jnp.int32(i), ctx_u,
                     ctx_c, cfg, image_keys, au, ac, res)
@@ -2380,7 +2377,8 @@ class Engine:
                 # enqueues on the slice — the towers overlap on silicon
                 res = residuals_for(carry.x, i)
             while len(fences) > 2:
-                fences.pop(0).block_until_ready()
+                with obs_spans.span("chunk.fence_wait", steps=1):
+                    fences.pop(0).block_until_ready()
                 done += 1
                 self.state.step(done)
         # NO final drain: like _denoise_range(sync=False), the tail
@@ -2655,17 +2653,31 @@ class Engine:
         t_enc = int(min(payload.denoising_strength, 0.999) * payload.steps)
         start_step = payload.steps - t_enc
 
-        init = b64png_to_array(payload.init_images[0]).astype(np.float32) / 255.0
-        init = _resize_image(init, width, height)
-        controls = self._prepare_controls(payload, width, height)
-        # inpainting never uses the refiner (mask pinning is tied to the
-        # base chunk loop) — don't load a refiner checkpoint for it
-        refiner = None if payload.mask is not None \
-            else self._refiner_engine(payload)
-        conds = pooleds = ref_cond = None
-        if not payload.all_prompts:
-            conds, pooleds = self.encode_prompts(payload)
-            ref_cond = refiner.encode_prompts(payload) if refiner else None
+        with obs_spans.span("prepare"):
+            with obs_spans.span("init_image",
+                                bytes=len(payload.init_images[0])):
+                with obs_spans.span("png_decode"):
+                    init = b64png_to_array(payload.init_images[0])
+                init = _resize_image(init.astype(np.float32) / 255.0,
+                                     width, height)
+                with obs_spans.span("upload"):
+                    init_dev = jnp.asarray(init)[None]
+            controls = self._prepare_controls(payload, width, height)
+            # inpainting never uses the refiner (mask pinning is tied to
+            # the base chunk loop) — don't load a refiner checkpoint for it
+            refiner = None if payload.mask is not None \
+                else self._refiner_engine(payload)
+            conds = pooleds = ref_cond = None
+            if not payload.all_prompts:
+                conds, pooleds = self.encode_prompts(payload)
+                ref_cond = refiner.encode_prompts(payload) \
+                    if refiner else None
+            # the init image is one frame shared by every row: encode it
+            # ONCE at batch 1 (flat VAE scratch at SDXL sizes) and repeat
+            # per group
+            with obs_spans.span("vae_encode"):
+                init_lat1 = self._encode_image_fn(width, height, 1)(
+                    self.params["vae"], init_dev)
 
         mask_lat = None
         mask_pixels = None
@@ -2687,32 +2699,32 @@ class Engine:
         group = max(1, payload.group_size or payload.batch_size)
         pos, remaining = start, count
         pending = []
-        # the init image is one frame shared by every row: encode it ONCE
-        # at batch 1 (flat VAE scratch at SDXL sizes) and repeat per group
-        init_lat1 = self._encode_image_fn(width, height, 1)(
-            self.params["vae"], jnp.asarray(init)[None])
         while remaining > 0 and not self.state.flag.interrupted:
             n = min(group, remaining)
-            init_lat = jnp.repeat(init_lat1, n, axis=0)
-            keys = self._image_keys(payload, pos, n)
-            init_lat = self._apply_inpaint_fill(
-                payload, init_lat, mask_lat, keys)
-            if payload.all_prompts:
-                conds, pooleds, ref_cond = self._group_conds(
-                    payload, pos, n, refiner)
-            inp = None
-            if self.family.inpaint:
-                inp = (self._masked_inpaint_cond(n, width, height, init,
-                                                 mask_pixels)
-                       if mask_pixels is not None
-                       else self._blank_inpaint_cond(n, width, height))
-            noise = rng.batch_noise(
-                payload.seed, payload.subseed, payload.subseed_strength,
-                pos, n, init_lat.shape[1:],
-                seed_resize=self._seed_resize_latent(payload),
-                pin_index=payload.same_seed)
-            x = self._place_batch(
-                init_lat + noise.astype(jnp.float32) * sigmas[start_step])
+            with obs_spans.span("prepare", group=pos, images=n):
+                init_lat = jnp.repeat(init_lat1, n, axis=0)
+                keys = self._image_keys(payload, pos, n)
+                init_lat = self._apply_inpaint_fill(
+                    payload, init_lat, mask_lat, keys)
+                if payload.all_prompts:
+                    conds, pooleds, ref_cond = self._group_conds(
+                        payload, pos, n, refiner)
+                inp = None
+                if self.family.inpaint:
+                    inp = (self._masked_inpaint_cond(n, width, height, init,
+                                                     mask_pixels)
+                           if mask_pixels is not None
+                           else self._blank_inpaint_cond(n, width, height))
+                with obs_spans.span("noise"):
+                    noise = rng.batch_noise(
+                        payload.seed, payload.subseed,
+                        payload.subseed_strength, pos, n,
+                        init_lat.shape[1:],
+                        seed_resize=self._seed_resize_latent(payload),
+                        pin_index=payload.same_seed)
+                x = self._place_batch(
+                    init_lat
+                    + noise.astype(jnp.float32) * sigmas[start_step])
             if mask_lat is None:
                 # plain img2img honors the refiner switch too (webui does);
                 # inpainting stays base-only — the per-step mask pinning is
@@ -2823,7 +2835,11 @@ class Engine:
             prompt_i = payload.prompt
             if payload.all_prompts and i < len(payload.all_prompts):
                 prompt_i = payload.all_prompts[i]
-            out.images.append(array_to_b64png(imgs[j]))
+            with obs_spans.span("png_encode") as sp:
+                png = array_to_b64png(imgs[j])
+                if sp is not None:
+                    sp.attrs["bytes"] = len(png) * 3 // 4  # base64 -> PNG
+            out.images.append(png)
             out.seeds.append(int(seed_i))
             out.subseeds.append(int(sub_i))
             out.prompts.append(prompt_i)

@@ -46,7 +46,13 @@ def enable_compilation_cache() -> str:
     import jax
 
     from stable_diffusion_webui_distributed_tpu.runtime.config import env_str
+    from stable_diffusion_webui_distributed_tpu.serving.metrics import (
+        install_xla_listener,
+    )
 
+    # whoever places the cache is about to compile: count from here on,
+    # hits and misses included (/internal/status serving.xla)
+    install_xla_listener()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     placed = env_str("JAX_COMPILATION_CACHE_DIR")
     if placed:

@@ -32,13 +32,24 @@ class StageStats:
 
     @contextlib.contextmanager
     def timer(self, stage: str) -> Iterator[None]:
+        """Time the block as ``stage``: a rolling sample here, the stage's
+        latency histogram, and a span of the active request (obs/spans.py;
+        while a profiler capture runs also an ``sdtpu:<stage>``
+        annotation), so spans opened inside are its children."""
+        # lazy: trace is imported everywhere, obs pulls serving/metrics
+        from stable_diffusion_webui_distributed_tpu.obs import (
+            prometheus as obs_prom,
+            spans as obs_spans,
+        )
+
         t0 = time.perf_counter()
         try:
-            yield
+            with obs_spans.span(stage):
+                yield
         finally:
             dur = time.perf_counter() - t0
             self.record(stage, dur)
-            _obs_stage(stage, dur, t0)
+            obs_prom.observe_stage(stage, dur)
 
     def summary(self) -> Dict[str, Dict[str, float]]:
         """{stage: {count, mean, p50, last}} over the rolling window."""
@@ -59,21 +70,6 @@ class StageStats:
     def clear(self) -> None:
         with self._lock:
             self._samples.clear()
-
-
-def _obs_stage(stage: str, seconds: float, t0: float) -> None:
-    """Mirror one timed stage into the obs layer: a leaf span on the active
-    request trace plus the matching latency histogram. Lazy import (trace
-    is imported everywhere; obs pulls serving/metrics) and exception-proof:
-    observability must never take a generation down."""
-    try:
-        from stable_diffusion_webui_distributed_tpu.obs import (
-            spans as obs_spans,
-        )
-
-        obs_spans.stage_event(stage, seconds, t0)
-    except Exception:  # noqa: BLE001 — pragma: no cover
-        pass
 
 
 #: Process-wide stats the engine and server share.
@@ -121,12 +117,3 @@ def capture(log_dir: str) -> Iterator[None]:
     finally:
         if started:
             stop_trace()
-
-
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named region visible in the profiler timeline (TraceAnnotation)."""
-    import jax
-
-    with jax.profiler.TraceAnnotation(name):
-        yield

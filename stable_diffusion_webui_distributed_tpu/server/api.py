@@ -971,6 +971,10 @@ class ApiServer:
         }
 
     def make_handler(self):
+        from stable_diffusion_webui_distributed_tpu.obs import (
+            spans as obs_spans,
+        )
+
         server = self
         routes = self.routes()
         log = get_logger()
@@ -993,6 +997,12 @@ class ApiServer:
                 return False
 
             def _dispatch(self, method: str):
+                # http.read_parse / http.respond of a request minted in
+                # here: the two ends of the exchange outside its root span
+                with obs_spans.http_exchange() as exchange:
+                    self._route(method, exchange)
+
+            def _route(self, method: str, exchange):
                 if not self._check_auth():
                     return
                 key = (method, self.path.split("?")[0].rstrip("/"))
@@ -1004,6 +1014,8 @@ class ApiServer:
                     if method == "POST":
                         length = int(self.headers.get("Content-Length", 0))
                         raw = self.rfile.read(length) if length else b"{}"
+                        if exchange is not None:
+                            exchange.attrs["bytes"] = length
                         body = json.loads(raw or b"{}")
                         if key[1] in ("/sdapi/v1/txt2img",
                                       "/sdapi/v1/img2img") \
@@ -1043,14 +1055,17 @@ class ApiServer:
 
             def _send(self, status: int, obj: Any,
                       headers: Optional[Dict[str, str]] = None):
-                data = json.dumps(obj).encode()
-                self.send_response(status)
-                self.send_header("Content-Type", "application/json")
-                self.send_header("Content-Length", str(len(data)))
-                for k, v in (headers or {}).items():
-                    self.send_header(k, v)
-                self.end_headers()
-                self.wfile.write(data)
+                with obs_spans.http_respond() as sp:
+                    data = json.dumps(obj).encode()
+                    if sp is not None:
+                        sp.attrs.update(bytes=len(data), status=status)
+                    self.send_response(status)
+                    self.send_header("Content-Type", "application/json")
+                    self.send_header("Content-Length", str(len(data)))
+                    for k, v in (headers or {}).items():
+                        self.send_header(k, v)
+                    self.end_headers()
+                    self.wfile.write(data)
 
             def _send_html(self, status: int, text: str):
                 data = text.encode()

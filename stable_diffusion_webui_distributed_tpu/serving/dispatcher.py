@@ -64,6 +64,7 @@ from stable_diffusion_webui_distributed_tpu.obs import (
     watchdog as obs_watchdog,
 )
 from stable_diffusion_webui_distributed_tpu.obs import spans as obs_spans
+from stable_diffusion_webui_distributed_tpu.runtime import trace
 from stable_diffusion_webui_distributed_tpu.serving.bucketer import (
     ShapeBucketer, ragged_enabled,
 )
@@ -945,7 +946,8 @@ class ServingDispatcher:
         """Serial group execution: the four stages back-to-back on the
         calling thread, byte-identical to the pre-stage-graph code (the
         stages are the same statements, split at data-dependency seams)."""
-        built = self._group_build_inputs(g)
+        with obs_spans.span("prepare", requests=len(g.tickets)):
+            built = self._group_build_inputs(g)
         if built is None:
             return
         latents = self._group_denoise(g, built)
@@ -1110,13 +1112,16 @@ class ServingDispatcher:
                         f"resolvable at dispatch")
                 engine._traced_lora = ts
                 row_sets += [ts] * n_p
+            tr = h      # ragged: the TRUE latent rows, zero-padded to h
             if ragged_mode:
                 tw, th = engine._ragged_plan(p) or (width, height)
                 tr = min(h, -(-th // f))
+            with obs_spans.span("noise"):
                 part = rng.batch_noise(
                     p.seed, p.subseed, p.subseed_strength, 0, n_p,
                     (tr, w, C), seed_resize=engine._seed_resize_latent(p),
                     pin_index=p.same_seed)
+            if ragged_mode:
                 noise_parts.append(jnp.pad(
                     part, ((0, 0), (0, h - tr), (0, 0), (0, 0))))
                 (cu, cc), (pu, pc), (ct_u, ct_c) = engine.encode_prompts(
@@ -1125,10 +1130,7 @@ class ServingDispatcher:
                 ctx_true_u_l += [ct_u] * n_p
                 ctx_true_c_l += [ct_c] * n_p
             else:
-                noise_parts.append(rng.batch_noise(
-                    p.seed, p.subseed, p.subseed_strength, 0, n_p,
-                    (h, w, C), seed_resize=engine._seed_resize_latent(p),
-                    pin_index=p.same_seed))
+                noise_parts.append(part)
                 (cu, cc), (pu, pc) = engine.encode_prompts(p)
             if perf_on:
                 try:
@@ -1274,8 +1276,9 @@ class ServingDispatcher:
         live, counts = built["live"], built["counts"]
         b_raw, b_run = built["b_raw"], built["b_run"]
         ragged_mode = built["ragged_mode"]
-        imgs = np.concatenate(
-            [np.asarray(e[0])[:e[2]] for e in entries], axis=0)
+        with trace.STATS.timer("vae_decode_fetch"):
+            imgs = np.concatenate(
+                [np.asarray(e[0])[:e[2]] for e in entries], axis=0)
         jr_on = obs_journal.enabled()
         if jr_on:
             obs_journal.emit("decoded", live[0].request_id,
@@ -1335,8 +1338,9 @@ class ServingDispatcher:
             arr = b64png_to_array(b64)
             if arr.shape[:2] != (bh, bw):
                 continue  # hires/second-pass output: not bucket-sized
-            result.images[i] = array_to_b64png(
-                crop(arr, orig.width, orig.height))
+            with obs_spans.span("png_encode", recrop=True):
+                result.images[i] = array_to_b64png(
+                    crop(arr, orig.width, orig.height))
             suffix = ""
             if i < len(result.infotexts) and \
                     result.infotexts[i].endswith(", DPM adaptive: incomplete"):

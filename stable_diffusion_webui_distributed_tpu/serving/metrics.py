@@ -11,13 +11,19 @@ counting — safe to assert in CPU tests, unlike wall-clock.
 ``Engine._cached`` reports every stage build/hit; the serving dispatcher
 reports requests, dispatches and queue waits; ``handle_internal_status``
 exposes :meth:`DispatchMetrics.summary` under ``"serving"``.
+
+What XLA really did is counted at the source: :data:`XLA` listens to
+``jax.monitoring`` (:func:`install_xla_listener`) and sums, per jitted
+function, the seconds of tracing, lowering and backend compile (a compile
+on a persistent-cache miss, a load on a hit), with the cache's own hits,
+misses and retrieval seconds. ``summary()["xla"]`` carries it.
 """
 
 from __future__ import annotations
 
 import threading
 from collections import defaultdict
-from typing import Dict
+from typing import Any, Dict, List
 
 
 class DispatchMetrics:
@@ -169,7 +175,7 @@ class DispatchMetrics:
     def summary(self) -> Dict:
         with self._lock:
             total_buckets = self.bucket_hits + self.bucket_misses
-            return {
+            out = {
                 "compiles": dict(self.compiles),
                 "cache_hits": dict(self.cache_hits),
                 "aot_loads": dict(self.aot_loads),
@@ -205,6 +211,168 @@ class DispatchMetrics:
                                        | set(self.precision_requests))
                 },
             }
+        out["xla"] = XLA.summary()    # its own lock, never under this one
+        return out
+
+
+#: jax.monitoring duration events of one executable's making -> the key
+#: its seconds are summed under. jax names the function ``fun_name`` on all
+#: three: ``run_chunk`` when tracing, ``jit(run_chunk)`` or
+#: ``jit_run_chunk`` from lowering on (:func:`_fun_name` folds them).
+_XLA_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_XLA_BACKEND = "/jax/core/compile/backend_compile_duration"
+_XLA_STAGES = {
+    _XLA_TRACE: "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    _XLA_BACKEND: "backend_s",
+}
+_XLA_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+_XLA_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: rows of ``summary()["top"]``
+_XLA_TOP = 10
+
+
+def _fun_name(name: Any) -> str:
+    name = str(name)
+    if name.startswith("jit(") and name.endswith(")"):
+        return name[4:-1]
+    return name.removeprefix("jit_")
+
+
+class XlaCompileStats:
+    """Process-wide compile accounting from ``jax.monitoring``.
+
+    ``backend_s`` is the time inside ``compile_or_get_cached``: an XLA
+    compile when the persistent cache misses (or is off, or the compile is
+    under its floor), a deserialise-and-load when it hits; ``executables``
+    counts those calls. A function jitted inside another's trace makes no
+    executable and its tracing lies inside the outer one's: it counts in
+    ``traces``, its seconds only in the outermost function's ``trace_s``,
+    so the seconds add up to time that passed. The persistent cache's
+    hits and misses (a miss is counted when the new executable is written:
+    compiles under the cache's floor are neither) happen inside a backend
+    compile, so they are also counted in that function's row: a function
+    that misses in every process is one whose cache key does not hold."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        #: per thread: ``depth`` of open jaxpr traces, ``compiling`` the
+        #: function whose backend compile is open
+        self._thread = threading.local()
+        self.clear()
+
+    @staticmethod
+    def _new_row() -> Dict[str, float]:
+        return {"executables": 0, "traces": 0, "trace_s": 0.0,
+                "lower_s": 0.0, "backend_s": 0.0, "cache_hits": 0,
+                "cache_misses": 0}
+
+    def clear(self) -> None:
+        with self._lock:
+            #: fun_name -> :meth:`_new_row`
+            self.functions: Dict[str, Dict[str, float]] = {}  # guarded-by: _lock
+            self.cache: Dict[str, float] = {  # guarded-by: _lock
+                "cache_hits": 0, "cache_misses": 0, "cache_retrieval_s": 0.0}
+
+    def on_event(self, event: str, **_kw: Any) -> None:
+        key = _XLA_CACHE_EVENTS.get(event)
+        if key is None:
+            return
+        fun = getattr(self._thread, "compiling", None)
+        with self._lock:
+            self.cache[key] += 1
+            if fun is not None:
+                self.functions.setdefault(fun, self._new_row())[key] += 1
+
+    def on_scalar(self, event: str, _value: float, **kw: Any) -> None:
+        """jax reports a timed block's start as a scalar of its event."""
+        if event == _XLA_TRACE:
+            self._thread.depth = getattr(self._thread, "depth", 0) + 1
+        elif event == _XLA_BACKEND:
+            self._thread.compiling = _fun_name(kw.get("fun_name", ""))
+
+    def on_duration(self, event: str, seconds: float, **kw: Any) -> None:
+        key = _XLA_STAGES.get(event)
+        if key is None:
+            if event == _XLA_CACHE_RETRIEVAL:
+                with self._lock:
+                    self.cache["cache_retrieval_s"] += float(seconds)
+            return
+        if key == "trace_s":
+            depth = self._thread.depth = max(
+                0, getattr(self._thread, "depth", 1) - 1)
+            if depth:
+                seconds = 0.0   # inside the outer function's seconds
+        fun = _fun_name(kw.get("fun_name", ""))
+        with self._lock:
+            row = self.functions.setdefault(fun, self._new_row())
+            row[key] += float(seconds)
+            if key == "trace_s":
+                row["traces"] += 1
+            elif key == "backend_s":
+                row["executables"] += 1
+        if key == "backend_s":
+            self._thread.compiling = None
+            # lazy: obs/spans.py pulls this module in through prometheus
+            from stable_diffusion_webui_distributed_tpu.obs import (
+                spans as obs_spans,
+            )
+
+            obs_spans.add_child("xla.compile", float(seconds), fun_name=fun,
+                                stage="backend_compile")
+
+    def executables(self, fun_name: str) -> int:
+        with self._lock:
+            return int(self.functions.get(fun_name, {}).get("executables", 0))
+
+    def summary(self) -> Dict[str, Any]:
+        """Totals, the functions the persistent cache missed (``missed``),
+        then the :data:`_XLA_TOP` functions with most seconds."""
+        with self._lock:
+            rows: List[Dict[str, Any]] = [
+                dict(row, fun_name=fun,
+                     seconds=row["trace_s"] + row["lower_s"]
+                     + row["backend_s"])
+                for fun, row in self.functions.items()]
+            out: Dict[str, Any] = dict(self.cache)
+        for key in ("executables", "traces", "trace_s", "lower_s",
+                    "backend_s"):
+            out[key] = sum(row[key] for row in rows)
+        out["functions"] = len(rows)
+        out["missed"] = sorted(row["fun_name"] for row in rows
+                               if row["cache_misses"])
+        rows.sort(key=lambda row: row["seconds"], reverse=True)
+        out["top"] = rows[:_XLA_TOP]
+        return out
+
+
+#: Process-wide compile accounting (fed once :func:`install_xla_listener`
+#: has run).
+XLA = XlaCompileStats()
+
+_xla_install_lock = threading.Lock()
+_xla_installed = False  # guarded-by: _xla_install_lock
+
+
+def install_xla_listener() -> None:
+    """Register :data:`XLA` with ``jax.monitoring``, once per process
+    however often it is called. ``runtime/mesh.enable_compilation_cache``
+    and ``Engine`` call it, so executables made before an engine exists
+    (weights, warm-up) are counted from the first one on."""
+    global _xla_installed
+    import jax.monitoring
+
+    with _xla_install_lock:
+        if _xla_installed:
+            return
+        jax.monitoring.register_event_listener(XLA.on_event)
+        jax.monitoring.register_scalar_listener(XLA.on_scalar)
+        jax.monitoring.register_event_duration_secs_listener(
+            XLA.on_duration)
+        _xla_installed = True
 
 
 #: Process-wide metrics instance (mirrors ``trace.STATS``).

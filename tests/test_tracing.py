@@ -1,0 +1,331 @@
+"""The request's host timeline and the set-up's compile accounting, inside
+the program (ISSUE 24): spans where the host's work is, the same tree on
+the profiler's clock, and ``serving.xla`` from ``jax.monitoring``.
+
+CPU-only, tiny model: counts and shapes of the tree, never a time.
+"""
+
+import glob
+import json
+import urllib.request
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from stable_diffusion_webui_distributed_tpu.models.configs import TINY
+from stable_diffusion_webui_distributed_tpu.obs import spans as obs_spans
+from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
+from stable_diffusion_webui_distributed_tpu.runtime.interrupt import (
+    GenerationState,
+)
+from stable_diffusion_webui_distributed_tpu.serving import metrics
+from test_pipeline import init_params
+
+#: table B of ISSUE 24: span -> the span it sits under (None: beside the
+#: root). ``text_encode`` and the others that existed keep their names.
+TXT2IMG_SPANS = {
+    "http.read_parse": None, "http.respond": None,
+    "queue_wait": "txt2img", "dispatch.device": "txt2img",
+    "prepare": "dispatch.device", "tokenize": "prepare",
+    "text_encode": "prepare", "noise": "prepare",
+    "denoise_range": "dispatch.device", "denoise_chunk": "denoise_range",
+    "chunk.enqueue": "denoise_chunk", "chunk.fence_wait": None,
+    "vae_decode_dispatch": "dispatch.device",
+    "vae_decode_fetch": "dispatch.device", "png_encode": None,
+    "xla.compile": None,
+}
+IMG2IMG_SPANS = {
+    "http.read_parse": None, "http.respond": None,
+    "generate_range": "dispatch.device", "prepare": "generate_range",
+    "init_image": "prepare", "png_decode": "init_image",
+    "upload": "init_image", "vae_encode": "prepare", "noise": "prepare",
+    "chunk.enqueue": "denoise_chunk", "png_encode": "generate_range",
+}
+
+
+def post(server, route, body):
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{server.port}{route}",
+        data=json.dumps(body).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=600) as r:
+        return json.loads(r.read())
+
+
+def get(server, route):
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{server.port}{route}", timeout=60) as r:
+        return json.loads(r.read())
+
+
+def events_of(server, rid):
+    doc = get(server, "/internal/trace.json")
+    return [e for e in doc["traceEvents"] if e["args"]["request_id"] == rid]
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """A fresh engine (so its executables are made inside the requests)
+    behind ApiServer; one txt2img, one img2img, then a txt2img under a
+    profiler capture."""
+    from stable_diffusion_webui_distributed_tpu.server.api import ApiServer
+
+    mp = pytest.MonkeyPatch()
+    mp.setenv("SDTPU_BUCKET_LADDER", "32x32")
+    mp.setenv("SDTPU_BATCH_LADDER", "1")
+    engine = Engine(TINY, init_params(TINY), chunk_size=2,
+                    state=GenerationState())
+    server = ApiServer(engine, state=engine.state, host="127.0.0.1",
+                       port=0).start()
+    body = {"prompt": "a traced cow", "steps": 4, "width": 32, "height": 32,
+            "seed": 11, "sampler_name": "Euler a"}
+    try:
+        first = post(server, "/sdapi/v1/txt2img",
+                     dict(body, request_id="trace-t2i"))
+        post(server, "/sdapi/v1/img2img",
+             dict(body, request_id="trace-i2i", init_images=first["images"],
+                  denoising_strength=0.5))
+        tdir = str(tmp_path_factory.mktemp("xplane"))
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(tdir, profiler_options=options)
+        try:
+            post(server, "/sdapi/v1/txt2img",
+                 dict(body, request_id="trace-prof", seed=12))
+        finally:
+            jax.profiler.stop_trace()
+        xplane = glob.glob(tdir + "/**/*.xplane.pb", recursive=True)[0]
+        yield {"server": server,
+               "txt2img": events_of(server, "trace-t2i"),
+               "img2img": events_of(server, "trace-i2i"),
+               "profiled": events_of(server, "trace-prof"),
+               "xplane": xplane}
+    finally:
+        server.stop()
+        mp.undo()
+
+
+def by_id(events):
+    return {e["args"]["span_id"]: e for e in events}
+
+
+class TestSpanTree:
+    @pytest.mark.parametrize("name", sorted(TXT2IMG_SPANS))
+    def test_txt2img_has_span(self, served, name):
+        events = served["txt2img"]
+        found = [e for e in events if e["name"] == name]
+        assert found, sorted({e["name"] for e in events})
+        want = TXT2IMG_SPANS[name]
+        if want is not None:
+            ids = by_id(events)
+            assert {ids[e["args"]["parent_id"]]["name"] for e in found} \
+                == {want}
+
+    @pytest.mark.parametrize("name", sorted(IMG2IMG_SPANS))
+    def test_img2img_has_span(self, served, name):
+        events = served["img2img"]
+        found = [e for e in events if e["name"] == name]
+        assert found, sorted({e["name"] for e in events})
+        want = IMG2IMG_SPANS[name]
+        if want is not None:
+            ids = by_id(events)
+            assert want in {ids[e["args"]["parent_id"]]["name"]
+                            for e in found}
+
+    @pytest.mark.parametrize("which", ["txt2img", "img2img", "profiled"])
+    def test_parents_resolve_and_hold_their_children(self, served, which):
+        events = served[which]
+        ids = by_id(events)
+        assert len(ids) == len(events)          # span ids are unique
+        tops = [e for e in events if "parent_id" not in e["args"]]
+        # the root, and the two ends of the HTTP exchange beside it
+        assert sorted(e["name"] for e in tops) == sorted(
+            ["http.read_parse", "http.respond", which.replace(
+                "profiled", "txt2img")])
+        slack = 50.0        # us: a parent's clock is read outside its child's
+        for e in events:
+            parent = e["args"].get("parent_id")
+            if parent is None:
+                continue
+            assert parent in ids, (e["name"], parent)
+            p = ids[parent]
+            assert e["ts"] >= p["ts"] - slack, (e["name"], p["name"])
+            assert e["ts"] + e["dur"] <= p["ts"] + p["dur"] + slack, \
+                (e["name"], p["name"])
+
+    def test_exchange_ends_meet_the_root(self, served):
+        events = {e["name"]: e for e in served["txt2img"]
+                  if "parent_id" not in e["args"]}
+        root = events["txt2img"]
+        read, respond = events["http.read_parse"], events["http.respond"]
+        assert read["ts"] + read["dur"] == pytest.approx(root["ts"], abs=5)
+        assert respond["ts"] >= root["ts"] + root["dur"] - 5
+        assert read["args"]["bytes"] > 0
+        assert respond["args"]["bytes"] > 0 \
+            and respond["args"]["status"] == 200
+
+    def test_counts_ride_in_attrs(self, served):
+        named = {}
+        for e in served["txt2img"]:
+            named.setdefault(e["name"], []).append(e)
+        assert named["png_encode"][0]["args"]["bytes"] > 0
+        assert sum(e["args"]["steps"] for e in named["chunk.enqueue"]) == 4
+        assert sum(e["args"]["steps"]
+                   for e in named["chunk.fence_wait"]) == 4
+        assert {e["args"]["fun_name"] for e in named["xla.compile"]} \
+            >= {"run_chunk", "decode_u8"}
+
+    def test_warm_request_compiles_nothing(self, served):
+        assert not [e for e in served["profiled"]
+                    if e["name"] == "xla.compile"]
+
+
+class TestProfilerClock:
+    @pytest.fixture(scope="class")
+    def host_events(self, served):
+        data = jax.profiler.ProfileData.from_file(served["xplane"])
+        out = []
+        for plane in data.planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for event in line.events:
+                    if event.name.startswith("sdtpu:"):
+                        out.append((event.name[len("sdtpu:"):],
+                                    dict(event.stats), event.start_ns,
+                                    event.duration_ns))
+        return out
+
+    @pytest.mark.parametrize("name", ["dispatch.device", "png_encode"])
+    def test_capture_holds_the_span(self, host_events, name):
+        mine = [e for e in host_events if e[0] == name]
+        assert mine, sorted({e[0] for e in host_events})
+        assert {e[1]["request_id"] for e in mine} == {"trace-prof"}
+
+    def test_every_live_span_is_annotated(self, served, host_events):
+        """All but the after-the-fact intervals (add_span: queue_wait,
+        xla.compile) are on the profiler's host plane, with the store's
+        own span ids."""
+        annotated = {e[1]["span_id"]: e[0] for e in host_events
+                     if e[1].get("request_id") == "trace-prof"}
+        for e in served["profiled"]:
+            if e["name"] in ("queue_wait", "xla.compile"):
+                assert e["args"]["span_id"] not in annotated
+            else:
+                assert annotated.get(e["args"]["span_id"]) == e["name"]
+
+    def test_durations_agree_across_the_two_clocks(self, served,
+                                                   host_events):
+        spans = {e["args"]["span_id"]: e for e in served["profiled"]}
+        for name, stats, _start, dur_ns in host_events:
+            if name == "dispatch.device" \
+                    and stats["request_id"] == "trace-prof":
+                assert dur_ns / 1e3 == pytest.approx(
+                    spans[stats["span_id"]]["dur"], rel=0.05, abs=500)
+
+    def test_no_capture_no_annotation(self):
+        assert obs_spans._annotate("idle", request_id="x") is None
+
+
+class TestXlaAccounting:
+    def test_fresh_jit_in_a_request_is_one_executable_and_one_span(self):
+        metrics.install_xla_listener()
+
+        def fresh_for_test_tracing(x):
+            return x * 3 + 1
+
+        fn = jax.jit(fresh_for_test_tracing)
+        name = "fresh_for_test_tracing"
+        before = metrics.XLA.executables(name)
+        total = metrics.METRICS.summary()["xla"]["executables"]
+        with obs_spans.request("rid-xla", name="unit") as req:
+            with obs_spans.span("outer") as outer:
+                fn(jnp.ones(3)).block_until_ready()
+            made = [s for s in req.spans if s.name == "xla.compile"
+                    and s.attrs["fun_name"] == name]
+            assert len(made) == 1
+            assert made[0].parent_id == outer.span_id
+            assert made[0].attrs["stage"] == "backend_compile"
+            assert metrics.XLA.executables(name) == before + 1
+            fn(jnp.ones(3)).block_until_ready()     # warm: neither moves
+            assert metrics.XLA.executables(name) == before + 1
+            assert len([s for s in req.spans
+                        if s.name == "xla.compile"]) == 1
+        block = metrics.METRICS.summary()["xla"]
+        assert block["executables"] == total + 1
+        row = metrics.XLA.functions[name]
+        assert row["traces"] == 1 and row["trace_s"] > 0
+        assert row["lower_s"] > 0 and row["backend_s"] > 0
+
+    def test_registering_twice_counts_once(self):
+        metrics.install_xla_listener()
+        metrics.install_xla_listener()
+
+        def twice_for_test_tracing(x):
+            return x - 2
+
+        jax.jit(twice_for_test_tracing)(jnp.ones(2)).block_until_ready()
+        assert metrics.XLA.executables("twice_for_test_tracing") == 1
+
+    def test_outside_a_request_counts_but_leaves_no_span(self):
+        metrics.install_xla_listener()
+
+        def outside_for_test_tracing(x):
+            return x + 5
+
+        before = len(obs_spans.TRACER.finished())
+        jax.jit(outside_for_test_tracing)(jnp.ones(2)).block_until_ready()
+        assert metrics.XLA.executables("outside_for_test_tracing") == 1
+        assert len(obs_spans.TRACER.finished()) == before
+
+    def test_nested_traces_do_not_add_seconds_twice(self):
+        stats = metrics.XlaCompileStats()
+        trace = metrics._XLA_TRACE
+        stats.on_scalar(trace, 0.0, fun_name="outer")
+        stats.on_scalar(trace, 0.1, fun_name="inner")
+        stats.on_duration(trace, 0.5, fun_name="inner")
+        stats.on_duration(trace, 2.0, fun_name="outer")
+        out = stats.summary()
+        assert out["traces"] == 2 and out["trace_s"] == 2.0
+        assert {r["fun_name"]: r["trace_s"] for r in out["top"]} \
+            == {"outer": 2.0, "inner": 0.0}
+
+    def test_cache_events_and_names_fold(self):
+        stats = metrics.XlaCompileStats()
+        stats.on_event("/jax/compilation_cache/cache_hits")
+        stats.on_event("/jax/compilation_cache/cache_misses")
+        stats.on_event("/jax/compilation_cache/cache_misses")
+        stats.on_duration(metrics._XLA_CACHE_RETRIEVAL, 0.25)
+        for name in ("jit(run_chunk)", "jit_run_chunk"):
+            stats.on_duration(
+                "/jax/core/compile/jaxpr_to_mlir_module_duration", 1.0,
+                fun_name=name)
+        out = stats.summary()
+        assert (out["cache_hits"], out["cache_misses"],
+                out["cache_retrieval_s"]) == (1, 2, 0.25)
+        assert [(r["fun_name"], r["lower_s"]) for r in out["top"]] \
+            == [("run_chunk", 2.0)]
+
+    def test_cache_misses_name_their_function(self):
+        stats = metrics.XlaCompileStats()
+        backend = metrics._XLA_BACKEND
+        for name, event in (("jit(fill)", "cache_misses"),
+                            ("jit(run_chunk)", "cache_hits")):
+            stats.on_scalar(backend, 0.0, fun_name=name)
+            stats.on_event("/jax/compilation_cache/" + event)
+            stats.on_duration(backend, 1.5, fun_name=name)
+        stats.on_event("/jax/compilation_cache/cache_misses")  # unowned
+        out = stats.summary()
+        assert out["missed"] == ["fill"] and out["cache_misses"] == 2
+        rows = {r["fun_name"]: r for r in out["top"]}
+        assert rows["fill"]["cache_misses"] == 1
+        assert rows["run_chunk"]["cache_hits"] == 1
+
+    def test_status_carries_the_block(self, served):
+        xla = get(served["server"], "/internal/status")["serving"]["xla"]
+        assert xla["executables"] >= 3 and len(xla["top"]) <= 10
+        assert {"trace_s", "lower_s", "backend_s", "cache_hits",
+                "cache_misses", "cache_retrieval_s", "missed"} <= set(xla)
+        assert "run_chunk" in {r["fun_name"] for r in xla["top"]}
