@@ -7,7 +7,10 @@ One process, one chip, no arguments (``python chip_smoke.py``):
 2. both Pallas attention kernels are compiled through their public entry
    points at every UNet self-attention shape of SD1.5 512² and SDXL 1024²,
    the compiled text is searched for the Mosaic call, and each runs once on
-   seeded q/k/v against its reference on the same device;
+   seeded q/k/v against its reference on the same device; the tiled kernel
+   and XLA's attention are then timed alone at each shape and both times
+   printed beside what the default path takes there, so the crossover of
+   ops/attention.py can be read again on any chip;
 3. SD1.5 is built at its published width with seeded random weights in the
    serving policy's dtype, wrapped in ``ApiServer(engine, port=0)`` and asked
    over real HTTP for the reference's calibration image, the same image
@@ -138,14 +141,24 @@ def phase_cache(report: Report) -> str:
 
 # -- phase: the Pallas kernels against their references ----------------------
 
+#: attention calls in one device-side loop, and loops timed (the median is
+#: reported) after one that warms up
+TIMING_CALLS = 20
+TIMING_LOOPS = 5
+
+
 def phase_kernels(report: Report, cases, seed: int) -> None:
     """flash_attention / ragged_attention through their public entry points:
     is the Mosaic kernel in the compiled text, and does one run on seeded
-    bf16 q/k/v agree with the reference on the same device."""
+    bf16 q/k/v agree with the reference on the same device. Then the tiled
+    kernel and XLA's attention alone, in milliseconds a call."""
+    import statistics
+
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    from stable_diffusion_webui_distributed_tpu.ops.attention import choose
     from stable_diffusion_webui_distributed_tpu.ops.flash_attention import (
         flash_attention,
     )
@@ -162,6 +175,18 @@ def phase_kernels(report: Report, cases, seed: int) -> None:
         report.check(f"{name} agrees with its reference", ok,
                      f"max abs err {err:.3e}, tol {KERNEL_TOL}")
 
+    def alone_ms(attention, q, k, v) -> float:
+        """One call's milliseconds on the device: the output feeds the next
+        call's q inside one executable, so no dispatch is timed."""
+        loop = jax.jit(lambda q, k, v: jax.lax.fori_loop(
+            0, TIMING_CALLS, lambda _, x: attention(x, k, v), q))
+        seconds = []
+        for _ in range(TIMING_LOOPS + 1):
+            t0 = time.perf_counter()
+            loop(q, k, v).block_until_ready()
+            seconds.append(time.perf_counter() - t0)
+        return statistics.median(seconds[1:]) / TIMING_CALLS * 1e3
+
     for b, h, t, d in cases:
         shape = f"B{b} H{h} T{t} D{d}"
         kq, kk, kv = jax.random.split(jax.random.key(seed), 3)
@@ -177,6 +202,12 @@ def phase_kernels(report: Report, cases, seed: int) -> None:
                      "tpu_custom_call" in flash.as_text())
         close(f"flash [{shape}]", flash(q, k, v),
               jax.jit(jax.nn.dot_product_attention)(q, k, v))
+        report.fact(
+            f"attention alone [{shape}]",
+            f"tiled {alone_ms(flash_attention, q, k, v):.4f} ms, "
+            f"XLA {alone_ms(jax.nn.dot_product_attention, q, k, v):.4f} ms "
+            f"a call; the default path takes "
+            f"{choose(jax.default_backend(), t, t, q.dtype, self_attention=True)}")
 
         ragged = jax.jit(ragged_attention).lower(q, k, v, true_len).compile()
         report.check(f"ragged kernel in compiled text [{shape}]",

@@ -72,7 +72,7 @@ class ControlNet(nn.Module):
     # or it all-gathers and materializes the dense score matrix the ring
     # exists to avoid
     use_remat: bool = False
-    attention_impl: str = "xla"
+    attention_impl: str = "auto"
     mesh: object = None
 
     def heads_for(self, channels: int) -> int:

@@ -36,6 +36,7 @@ from stable_diffusion_webui_distributed_tpu.ops.quant import (
     conv as _conv,
     linear as _linear,
 )
+from stable_diffusion_webui_distributed_tpu.serving.metrics import ATTENTION
 
 
 def timestep_embedding(t: jax.Array, dim: int, max_period: float = 10000.0) -> jax.Array:
@@ -88,12 +89,15 @@ class ResBlock(nn.Module):
 class Attention(nn.Module):
     """Self- or cross-attention over flattened spatial tokens.
 
-    ``impl``: "xla" (compiler-fused), "flash" (Pallas online-softmax kernel
-    for the latent self-attention hot spot), "ring" (sequence-parallel
-    over the mesh's ``sp`` axis for token counts beyond one chip — requires
-    ``mesh``), or "ragged" (per-row true-length masked kernel,
-    ops/ragged_attention.py). Cross-attention's 77-token context always
-    takes the XLA path, as does any shape the chosen impl can't tile.
+    ``impl``: "auto" (ops/attention.py chooses per site, from platform,
+    shape and dtype, between the tiled Pallas kernel and XLA), "xla"
+    (compiler-fused, always), "flash" (the tiled kernel wherever the
+    sequence tiles), "ring" (sequence-parallel over the mesh's ``sp`` axis
+    for token counts beyond one chip — requires ``mesh``), or "ragged"
+    (per-row true-length masked kernel, ops/ragged_attention.py).
+    Cross-attention's 77-token context always takes the XLA path, as does
+    any shape the chosen impl can't tile. Every site is counted at trace
+    time by the path it took (serving/metrics.py ``ATTENTION``).
 
     ``true_len`` (traced (B,) int32, optional) forces the ragged path
     regardless of ``impl``: for self-attention the row's valid spatial
@@ -104,7 +108,7 @@ class Attention(nn.Module):
 
     num_heads: int
     dtype: jnp.dtype = jnp.float32
-    impl: str = "xla"
+    impl: str = "auto"
     mesh: Optional[object] = None
     quant_linears: bool = False
 
@@ -146,6 +150,7 @@ class Attention(nn.Module):
             tl = (true_len if true_len is not None
                   else jnp.full((B,), T, jnp.int32))
             out = ragged_attention(q, k, v, tl, scale=1.0 / head_dim**0.5)
+            path = "ragged"
         elif context is not None and true_len is not None:
             # ragged cross-attention: mask padded context rows; the 77·n
             # token context is small, so the dense masked form suffices
@@ -155,6 +160,7 @@ class Attention(nn.Module):
 
             out = ragged_attention_reference(q, k, v, true_len,
                                              scale=1.0 / head_dim**0.5)
+            path = "ragged"
         elif self.impl == "ring" and context is None and sp > 1 \
                 and T % sp == 0 and dp_ok:
             from stable_diffusion_webui_distributed_tpu.ops.ring_attention import (
@@ -163,15 +169,16 @@ class Attention(nn.Module):
 
             out = ring_attention(q, k, v, self.mesh,
                                  scale=1.0 / head_dim**0.5)
-        elif self.impl == "flash" and context is None:
-            from stable_diffusion_webui_distributed_tpu.ops.flash_attention import (
-                flash_attention,
+            path = "ring"
+        else:
+            from stable_diffusion_webui_distributed_tpu.ops.attention import (
+                attend,
             )
 
-            out = flash_attention(q, k, v, scale=1.0 / head_dim**0.5)
-        else:
-            out = jax.nn.dot_product_attention(
-                q, k, v, scale=1.0 / head_dim**0.5)
+            out, path = attend(q, k, v, scale=1.0 / head_dim**0.5,
+                               impl=self.impl,
+                               self_attention=context is None)
+        ATTENTION.record(path, T, ctx_len, head_dim)
         out = out.reshape(B, T, C)
         y = _linear(self.quant_linears, C, dtype=self.dtype,
                     name="out_proj")(out)
@@ -197,7 +204,7 @@ class TransformerBlock(nn.Module):
 
     num_heads: int
     dtype: jnp.dtype = jnp.float32
-    attention_impl: str = "xla"
+    attention_impl: str = "auto"
     mesh: Optional[object] = None
     quant_linears: bool = False
 
@@ -238,7 +245,7 @@ class SpatialTransformer(nn.Module):
     num_heads: int
     use_remat: bool = False
     dtype: jnp.dtype = jnp.float32
-    attention_impl: str = "xla"
+    attention_impl: str = "auto"
     mesh: Optional[object] = None
     quant_linears: bool = False
 
@@ -365,7 +372,7 @@ class UNet(nn.Module):
     cfg: UNetConfig
     dtype: jnp.dtype = jnp.float32
     use_remat: bool = False
-    attention_impl: str = "xla"
+    attention_impl: str = "auto"
     mesh: Optional[object] = None
     # experimental dynamic W8A8 for transformer linears (ops/quant.py;
     # SDTPU_UNET_INT8=1) — the int8-MXU lever from PERF.md's roofline

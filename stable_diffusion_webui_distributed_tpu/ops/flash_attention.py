@@ -1,20 +1,27 @@
-"""Pallas flash attention for the UNet's latent-token self-attention.
+"""Tiled online-softmax attention (Pallas) for the UNet's latent self-attention.
 
-Online-softmax blockwise attention in the canonical TPU form: the grid is
-``(batch*heads, T/block_q, S/block_k)`` with the key dimension innermost,
-K/V arrive as ``block_k`` tiles through the pallas pipeline (double-buffered
-DMA, never whole-sequence resident in VMEM), and the running softmax state
-(m, l, acc) lives in VMEM scratch that persists across the sequential grid
-steps of one query tile. The (T x S) score matrix never materializes in
-HBM — the standard memory-bound win at SDXL resolutions (T = 4096 latent
-tokens at 1024²) and the only viable form at the hires second pass
-(T = 65536 at 2048², where even one (T x S) bf16 score matrix would be
-8 GB). Whole-K-in-VMEM variants stop fitting around S≈16k at f32; tile
-streaming has no such ceiling.
+``jax.nn.dot_product_attention`` writes the whole (T x S) score matrix to
+HBM and reads it back: 1.34 GB a layer at SDXL 1024² (T = 4096, 20 batch x
+heads, f32), which pins the layer to the HBM roofline at 12 % of the MXU's
+(PERF.md section 6, PR 24). Here the scores never leave VMEM. The grid is
+``(batch, head group, T/block_q, S/block_k)``; up to S = 4096 one K/V block
+holds the whole sequence (512 KB a head at head_dim 64) and a grid step is
+one plain softmax over a q tile, beyond that (the hires second pass, up to
+65 536 tokens) the running state (m, l, acc) lives in VMEM scratch across
+the k steps of a q tile. q, k, v go into the MXU in their own dtype (bf16
+under the serving policy) with float32 accumulation; the softmax statistics
+and the accumulator are float32, and the probabilities are cast to v's
+dtype for P·V exactly as XLA's path does.
 
-Falls back to ``jax.nn.dot_product_attention`` when shapes don't tile
-(cross-attention's 77-token context) or when running on CPU test platforms
-without ``interpret=True``.
+Heads are indexed through the ``BlockSpec`` where the head size allows:
+q, k, v stay ``(B, T, H*D)`` as the qkv projection leaves them, and one block
+is the 128 lanes of ``128 // D`` neighbouring heads, so nothing is transposed
+in HBM (one SDXL self-attention sublayer on a v5e: 1.27 ms against 1.47 ms
+through ``(B*H, T, D)`` copies; PERF.md section 6, PR 25). Other head sizes
+(SD1.5's 40, 80, 160) go through the ``(B*H, T, D)`` layout.
+
+Falls back to ``jax.nn.dot_product_attention`` when the sequence does not
+tile (cross-attention's 77-token context).
 """
 
 from __future__ import annotations
@@ -26,61 +33,144 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+_NT = (((1,), (1,)), ((), ()))   # q @ k.T without a transpose in the kernel
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                 scale: float):
-    """One (batch*head, q-tile, k-tile) step: fold one K/V tile into the
-    running online-softmax state; finalize on the last k-tile."""
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0].astype(jnp.float32) * scale            # (block_q, D)
-    k_blk = k_ref[0].astype(jnp.float32)                # (block_k, D)
-    v_blk = v_ref[0].astype(jnp.float32)
-
-    s = q @ k_blk.T                                     # (block_q, block_k)
-    m_prev = m_ref[:]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    m_ref[:] = m_new
-    l_ref[:] = l_ref[:] * alpha + p.sum(axis=-1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * alpha + p @ v_blk
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finalize():
-        o_ref[0] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
+#: score-tile budget, in elements: block_q * block_k float32 scores are
+#: 4 MiB of VMEM, their exponentials as much again. On a v5e q tiles of 256,
+#: 512 and 1024 against K/V of 4096 all ran the SDXL layer in 1.12-1.13 ms,
+#: and Mosaic's compile time grows with the tile (1.7, 3.3, 6.3 s).
+_SCORE_TILE = 1 << 20
+#: longest K/V block; longer sequences take the online-softmax steps
+_MAX_BLOCK_K = 4096
+#: scoped VMEM the kernel may use: about three times what the largest tile
+#: needs (double-buffered K and V blocks, scores, exponentials), and under
+#: half of a v5e core's 128 MiB. The compiler's default of 16 MiB is short.
+_VMEM_LIMIT = 48 * 2 ** 20
 
 
-def _flash_bhtd(q, k, v, scale, block_q, block_k, interpret):
-    """(BH, T, D) x (BH, S, D) -> (BH, T, D)."""
+def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *scratch, heads: int,
+                 head_dim: int, k_steps: int, scale: float):
+    """One (batch, head group, q-tile, k-block) step.
+
+    Refs are ``(1, block, heads*head_dim)``: ``heads`` heads side by side
+    in the lanes. With one K/V block for the whole sequence there is no
+    running state and no scratch."""
+    j = pl.program_id(3)
+    if k_steps > 1:
+        m_ref, l_ref, acc_ref = scratch
+
+        @pl.when(j == 0)
+        def _init():
+            m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # bf16 products are exact in the float32 accumulator, and Mosaic refuses
+    # bf16 operands at a higher precision: a caller's
+    # jax.default_matmul_precision("highest") must not reach these dots
+    precision = (jax.lax.Precision.DEFAULT
+                 if q_ref.dtype == jnp.bfloat16 else None)
+    for g in range(heads):
+        lanes = slice(g * head_dim, (g + 1) * head_dim)
+        q = q_ref[0, :, lanes] * scale                       # (block_q, D)
+        k = k_ref[0, :, lanes]                               # (block_k, D)
+        v = v_ref[0, :, lanes]
+        s = jax.lax.dot_general(q, k, _NT, precision=precision,
+                                preferred_element_type=jnp.float32)
+        if k_steps == 1:
+            p = jnp.exp(s - s.max(axis=-1, keepdims=True))
+            l = p.sum(axis=-1, keepdims=True)
+            o = jnp.dot(p.astype(v.dtype), v, precision=precision,
+                        preferred_element_type=jnp.float32)
+            o_ref[0, :, lanes] = (o / l).astype(o_ref.dtype)
+            continue
+        m_prev = m_ref[g]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        m_ref[g] = m_new
+        l_ref[g] = l_ref[g] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_ref[g] = acc_ref[g] * alpha + jnp.dot(
+            p.astype(v.dtype), v, precision=precision,
+            preferred_element_type=jnp.float32)
+
+    if k_steps > 1:
+        @pl.when(j == k_steps - 1)
+        def _finalize():
+            for g in range(heads):
+                lanes = slice(g * head_dim, (g + 1) * head_dim)
+                o_ref[0, :, lanes] = (acc_ref[g] / l_ref[g]).astype(
+                    o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "head_dim", "block_q", "block_k", "scale", "interpret"))
+def _tiled(q, k, v, *, heads: int, head_dim: int, block_q: int,
+           block_k: int, scale: float, interpret: bool):
+    """``(N, T, W) x (N, S, W) -> (N, T, W)`` with ``W = groups * heads *
+    head_dim``: ``heads`` heads to a block, side by side in the lanes.
+
+    Jitted on its own so that the 70 attention sites of one UNet trace and
+    lower the kernel once per shape, not once per site."""
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, t, d = q.shape
+    n, t, w = q.shape
     s_len = k.shape[1]
-    kernel = functools.partial(_attn_kernel, scale=scale)
+    width = heads * head_dim
+    k_steps = s_len // block_k
+    kernel = functools.partial(_attn_kernel, heads=heads, head_dim=head_dim,
+                               k_steps=k_steps, scale=scale)
+    all_heads = n * (w // head_dim)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        grid=(bh, t // block_q, s_len // block_k),
+        grid=(n, w // width, t // block_q, k_steps),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_q, width), lambda b, h, i, j: (b, i, h)),
+            pl.BlockSpec((1, block_k, width), lambda b, h, i, j: (b, j, h)),
+            pl.BlockSpec((1, block_k, width), lambda b, h, i, j: (b, j, h)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running max m
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running denom l
-            pltpu.VMEM((block_q, d), jnp.float32),   # unnormalized acc
+        out_specs=pl.BlockSpec((1, block_q, width),
+                               lambda b, h, i, j: (b, i, h)),
+        scratch_shapes=[] if k_steps == 1 else [
+            pltpu.VMEM((heads, block_q, 1), jnp.float32),          # max m
+            pltpu.VMEM((heads, block_q, 1), jnp.float32),          # denom l
+            pltpu.VMEM((heads, block_q, head_dim), jnp.float32),   # acc
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        # XLA's cost analysis sees a custom call as free: say what it does
+        cost_estimate=pl.CostEstimate(
+            flops=4 * all_heads * t * s_len * head_dim,
+            transcendentals=all_heads * t * s_len,
+            bytes_accessed=q.dtype.itemsize * (2 * q.size + k.size + v.size)),
         interpret=interpret,
     )(q, k, v)
+
+
+def _block(n: int, cap: int) -> int | None:
+    """The whole of ``n`` when it fits under ``cap``, else its largest
+    divisor up to ``cap`` that is a multiple of 128; None when there is
+    none."""
+    if n <= cap:
+        return n
+    return next((b for b in range(cap - cap % 128, 0, -128) if n % b == 0),
+                None)
+
+
+def blocks(t: int, s: int) -> tuple[int, int] | None:
+    """(block_q, block_k) from the shape, or None when ``(t, s)`` does not
+    tile: a sequence under 8 tokens or off the sublane tiling (77), or a
+    long one with no divisor that is a multiple of 128."""
+    if t % 8 or s % 8:
+        return None
+    block_k = _block(s, _MAX_BLOCK_K)
+    if block_k is None:
+        return None
+    block_q = _block(t, max(128, min(1024, _SCORE_TILE // block_k)))
+    return None if block_q is None else (block_q, block_k)
 
 
 def flash_attention(
@@ -88,30 +178,43 @@ def flash_attention(
     k: jax.Array,      # (B, S, H, D)
     v: jax.Array,      # (B, S, H, D)
     scale: float | None = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: int | None = None,
+    block_k: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Drop-in for ``jax.nn.dot_product_attention`` (no mask/bias path).
 
-    Tiles shrink to fit short sequences; if the sequence still doesn't tile
-    evenly, falls back to the XLA path (correctness first — the reference's
-    degraded-capability spirit, worker.py:457-467).
-    """
+    Tile sizes come from the shape (:func:`blocks`); ``block_q`` and
+    ``block_k`` override them for tests. A sequence that does not tile
+    takes the XLA path."""
     b, t, h, d = q.shape
     s = k.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    block_q = min(block_q, t)
-    block_k = min(block_k, s)
-    if t % block_q or s % block_k:
+    if block_q is None and block_k is None:
+        chosen = blocks(t, s)
+    else:
+        block_q = min(block_q or t, t)
+        block_k = min(block_k or s, s)
+        chosen = None if t % block_q or s % block_k else (block_q, block_k)
+    if chosen is None:
         return jax.nn.dot_product_attention(q, k, v, scale=scale)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    call = functools.partial(_tiled, head_dim=d, block_q=chosen[0],
+                             block_k=chosen[1], scale=float(scale),
+                             interpret=interpret)
+
+    per_block = 128 // d if 128 % d == 0 else 0
+    if per_block and h % per_block == 0:
+        def flat(x):
+            return x.reshape(b, x.shape[1], h * d)
+
+        return call(flat(q), flat(k), flat(v),
+                    heads=per_block).reshape(b, t, h, d)
 
     def to_bhtd(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
 
-    out = _flash_bhtd(to_bhtd(q), to_bhtd(k), to_bhtd(v), scale,
-                      block_q, block_k, interpret)
+    out = call(to_bhtd(q), to_bhtd(k), to_bhtd(v), heads=1)
     return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)

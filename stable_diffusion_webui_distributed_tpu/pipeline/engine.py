@@ -164,6 +164,12 @@ class Engine:
             # for meshes without an sp axis
             attn_impl = "ring"
             attn_mesh = mesh
+        elif mesh is not None and mesh.size > 1 and attn_impl == "auto":
+            # pjit does not partition a pallas_call: under a dp or tp mesh
+            # the tiled kernel would be run whole on every chip, so the
+            # partitioned program keeps XLA's attention (PERF.md section 6,
+            # PR 25)
+            attn_impl = "xla"
         self.unet = UNet(family.unet, dtype=cd,
                          attention_impl=attn_impl,
                          use_remat=policy.use_remat,

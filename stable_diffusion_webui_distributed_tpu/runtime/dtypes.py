@@ -18,9 +18,10 @@ class Policy:
     param_dtype: jnp.dtype = jnp.dtype(jnp.float32)   # storage dtype of weights
     compute_dtype: jnp.dtype = jnp.dtype(jnp.bfloat16)  # matmul/conv dtype
     sampler_dtype: jnp.dtype = jnp.dtype(jnp.float32)   # latent/sigma math
-    # "xla" | "flash" (Pallas online-softmax kernel for latent self-attn).
-    # SDTPU_ATTENTION=flash flips the default TPU policy.
-    attention_impl: str = "xla"
+    # "auto": ops/attention.py chooses per attention site, from platform,
+    # shape and dtype, between the tiled Pallas kernel and XLA. "xla" and
+    # "flash" force one side (tests, chip_smoke.py).
+    attention_impl: str = "auto"
     # rematerialize transformer blocks: trades UNet FLOPs for HBM at large
     # batch/resolution (SDTPU_REMAT=1 flips the default TPU policy).
     use_remat: bool = False
@@ -62,10 +63,6 @@ def _env_choice(name: str, default: str, choices) -> str:
     return env_parsed(name, parse, default, "choice")
 
 
-def _default_attention() -> str:
-    return _env_choice("SDTPU_ATTENTION", "xla", ("xla", "flash"))
-
-
 def _env_flag(name: str) -> bool:
     from stable_diffusion_webui_distributed_tpu.runtime.config import env_flag
 
@@ -99,7 +96,6 @@ def _default_decode_bf16() -> bool:
 
 #: Default policy for real TPU runs.
 TPU = Policy(param_dtype=_default_param_dtype(),
-             attention_impl=_default_attention(),
              use_remat=_env_flag("SDTPU_REMAT"),
              decode_in_bf16=_default_decode_bf16(),
              unet_int8=_env_flag("SDTPU_UNET_INT8"),

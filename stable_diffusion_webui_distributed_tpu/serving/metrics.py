@@ -17,6 +17,10 @@ What XLA really did is counted at the source: :data:`XLA` listens to
 function, the seconds of tracing, lowering and backend compile (a compile
 on a persistent-cache miss, a load on a hit), with the cache's own hits,
 misses and retrieval seconds. ``summary()["xla"]`` carries it.
+
+Which attention the UNet's sites took is counted where they are traced:
+:data:`ATTENTION` (``summary()["attention"]``), fed by
+``models/unet.py:Attention``.
 """
 
 from __future__ import annotations
@@ -212,6 +216,7 @@ class DispatchMetrics:
                 },
             }
         out["xla"] = XLA.summary()    # its own lock, never under this one
+        out["attention"] = ATTENTION.summary()
         return out
 
 
@@ -374,6 +379,45 @@ def install_xla_listener() -> None:
             XLA.on_duration)
         _xla_installed = True
 
+
+class AttentionSites:
+    """Attention sites by the path they took, counted when a UNet (or
+    ControlNet) is traced: ``tiled`` (ops/flash_attention.py), ``xla``
+    (``jax.nn.dot_product_attention``), and ``ragged`` or ``ring`` where a
+    request or a mesh asked for those. A site is one ``Attention`` call in
+    one trace, so a model traced twice (the chunk executable, then the
+    FLOPs pricing of pipeline/stepcache.py) counts twice; nothing is
+    counted when an executable runs."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self.clear()
+
+    def clear(self) -> None:
+        with self._lock:
+            #: (path, tokens, context tokens, head_dim) -> sites
+            self.sites: Dict[tuple, int] = defaultdict(int)  # guarded-by: _lock
+
+    def record(self, path: str, t: int, s: int, head_dim: int) -> None:
+        with self._lock:
+            self.sites[(path, int(t), int(s), int(head_dim))] += 1
+
+    def summary(self) -> Dict[str, Any]:
+        """``{"tiled": n, "xla": m, "by_shape": {"T4096 S4096 D64":
+        {"tiled": n}, ...}}``; other paths appear once they are taken."""
+        with self._lock:
+            sites = dict(self.sites)
+        out: Dict[str, Any] = {"tiled": 0, "xla": 0}
+        by_shape: Dict[str, Dict[str, int]] = {}
+        for (path, t, s, d), n in sorted(sites.items()):
+            out[path] = out.get(path, 0) + n
+            by_shape.setdefault(f"T{t} S{s} D{d}", {})[path] = n
+        out["by_shape"] = by_shape
+        return out
+
+
+#: Process-wide count of attention sites by path (fed at trace time).
+ATTENTION = AttentionSites()
 
 #: Process-wide metrics instance (mirrors ``trace.STATS``).
 METRICS = DispatchMetrics()

@@ -23,7 +23,7 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from stable_diffusion_webui_distributed_tpu.ops.flash_attention import (
-    _flash_bhtd,
+    flash_attention,
 )
 from stable_diffusion_webui_distributed_tpu.ops.ragged_attention import (
     _ragged_bhtd,
@@ -67,12 +67,26 @@ def _compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("bh,t,d", SHAPES)
-def test_flash_kernel_compiles_for_v5e(one_chip, bh, t, d):
-    qkv = jax.ShapeDtypeStruct((bh, t, d), jnp.bfloat16, sharding=one_chip)
-    block = min(128, t)
+@pytest.mark.parametrize("b,h,t,d", KERNEL_CASES)
+def test_flash_kernel_compiles_for_v5e(one_chip, b, h, t, d):
+    """Through the public entry point, with the tiles it takes from the
+    shape: head_dim 64 rides the lanes two heads to a block, 40, 80 and
+    160 go through the (B*H, T, D) layout."""
+    qkv = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=one_chip)
     text = _compiled_text(
-        lambda q, k, v: _flash_bhtd(q, k, v, d ** -0.5, block, block, False),
+        lambda q, k, v: flash_attention(q, k, v, interpret=False),
+        qkv, qkv, qkv)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("tokens", [16384, 65536])
+def test_flash_kernel_compiles_at_hires_lengths(one_chip, tokens):
+    """The hires second pass: K/V blocks of 4096 and the running softmax
+    state in VMEM scratch across the k steps."""
+    qkv = jax.ShapeDtypeStruct((1, tokens, 10, 64), jnp.bfloat16,
+                               sharding=one_chip)
+    text = _compiled_text(
+        lambda q, k, v: flash_attention(q, k, v, interpret=False),
         qkv, qkv, qkv)
     assert "tpu_custom_call" in text
 
@@ -86,4 +100,18 @@ def test_ragged_kernel_compiles_for_v5e(one_chip, bh, t, d):
         lambda n, q, k, v: _ragged_bhtd(q, k, v, n, d ** -0.5, block, block,
                                         False),
         tl, qkv, qkv, qkv)
+    assert "tpu_custom_call" in text
+
+
+def test_flash_kernel_compiles_under_highest_matmul_precision(one_chip):
+    """benchmarks/verify_reference.py runs the program under
+    ``jax.default_matmul_precision("highest")``; Mosaic refuses bf16
+    operands at that precision ("Bad lhs type"), so the kernel's dots pin
+    their own."""
+    qkv = jax.ShapeDtypeStruct((1, 1024, 10, 64), jnp.bfloat16,
+                               sharding=one_chip)
+    with jax.default_matmul_precision("highest"):
+        text = _compiled_text(
+            lambda q, k, v: flash_attention(q, k, v, interpret=False),
+            qkv, qkv, qkv)
     assert "tpu_custom_call" in text

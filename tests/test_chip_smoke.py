@@ -50,6 +50,10 @@ def test_kernel_phase_on_cpu_agrees_but_has_no_kernel():
         "flash kernel in compiled text [B2 H2 T128 D32]",
         "ragged kernel in compiled text [B2 H2 T128 D32]",
     ]
+    # both times are printed beside what the default path takes there
+    timed = report.facts["attention alone [B2 H2 T128 D32]"]
+    assert timed.startswith("tiled ") and " ms, XLA " in timed
+    assert timed.endswith("the default path takes xla")
 
 
 @pytest.mark.slow

@@ -1,6 +1,7 @@
-"""Kernel tests: Pallas flash attention (interpret mode on CPU) and ring
+"""Kernel tests: the tiled Pallas attention (interpret mode on CPU) and ring
 attention over the 8-device virtual mesh, both checked against the XLA
-reference attention."""
+reference attention; the chooser between the tiled kernel and XLA
+(ops/attention.py), and the count of attention sites by path."""
 
 import numpy as np
 import pytest
@@ -8,7 +9,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from stable_diffusion_webui_distributed_tpu.ops import attention as attention_ops
 from stable_diffusion_webui_distributed_tpu.ops.flash_attention import (
+    _tiled,
+    blocks,
     flash_attention,
 )
 from stable_diffusion_webui_distributed_tpu.ops.ring_attention import (
@@ -134,6 +138,192 @@ class TestFlashAttentionStreaming:
         np.testing.assert_allclose(np.asarray(out),
                                    np.asarray(reference(q, k, v)),
                                    rtol=2e-5, atol=2e-5)
+
+
+class TestTiledKernel:
+    """The kernel as the default path calls it: tiles from the shape, both
+    layouts (heads in the lanes where 128 % head_dim == 0, else
+    ``(B*H, T, D)``), one plain softmax or the running state."""
+
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                           (jnp.bfloat16, 3e-2)])
+    @pytest.mark.parametrize("d", [40, 64, 80, 160])
+    def test_matches_xla_at_unet_head_dims(self, d, dtype, tol):
+        q, k, v = qkv(2, 128, 2, d)
+        got = flash_attention(*(x.astype(dtype) for x in (q, k, v)),
+                              interpret=True)
+        assert got.dtype == dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(reference(q, k, v)),
+                                   rtol=tol, atol=tol)
+
+    @pytest.mark.parametrize("h,d", [(2, 64), (4, 32), (3, 64), (2, 40)])
+    def test_running_state_in_both_layouts(self, h, d):
+        """Four k steps: (2, 64) and (4, 32) ride the lanes, three heads of
+        64 and head_dim 40 go through (B*H, T, D)."""
+        q, k, v = qkv(1, 256, h, d)
+        got = flash_attention(q, k, v, block_q=128, block_k=64,
+                              interpret=True)
+        np.testing.assert_allclose(np.asarray(got),
+                                   np.asarray(reference(q, k, v)),
+                                   rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("block_q,block_k", [(48, 128), (128, 48)])
+    def test_blocks_that_do_not_divide_fall_back(self, block_q, block_k):
+        q, k, v = qkv(1, 128, 2, 64)
+        got = flash_attention(q, k, v, block_q=block_q, block_k=block_k,
+                              interpret=True)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(reference(q, k, v)))
+
+    @pytest.mark.parametrize("t,s,want", [
+        (4096, 4096, (256, 4096)),     # SDXL 64x64: 4 MiB of scores a tile
+        (1024, 1024, (1024, 1024)),    # SDXL 32x32: the whole layer of a head
+        (256, 256, (256, 256)),
+        (64, 64, (64, 64)),
+        (65536, 65536, (256, 4096)),   # hires: 16 k steps of 4096
+        (9216, 9216, (256, 3072)),     # 96x96 latent: divisors of 128
+        (64, 77, None),                # cross-attention's context
+        (4104, 4104, None),            # over a block and no divisor of 128
+    ])
+    def test_blocks_come_from_the_shape(self, t, s, want):
+        assert blocks(t, s) == want
+
+    def test_inner_jit_traces_the_kernel_once_per_shape(self):
+        """Three sites, two shapes: the sites of one shape share one traced
+        kernel (the same jaxpr object), so a UNet's 70 sites trace and
+        lower it two or three times."""
+        a, b = qkv(1, 128, 2, 64), qkv(1, 64, 2, 64)
+
+        def three_sites(a, b):
+            once = flash_attention(*a, interpret=True)
+            again = flash_attention(once, a[1], a[2], interpret=True)
+            return again, flash_attention(*b, interpret=True)
+
+        inner = [e.params["jaxpr"]
+                 for e in jax.make_jaxpr(three_sites)(a, b).eqns
+                 if e.primitive.name in ("pjit", "jit")
+                 and e.params["name"] == _tiled.__name__]
+        assert len(inner) == 3
+        assert inner[0] is inner[1] and inner[0] is not inner[2]
+        again, other = jax.jit(three_sites)(a, b)
+        np.testing.assert_allclose(
+            np.asarray(again),
+            np.asarray(reference(reference(*a), a[1], a[2])),
+            rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(other),
+                                   np.asarray(reference(*b)),
+                                   rtol=2e-5, atol=2e-5)
+
+    def test_cost_estimate_counts_both_matmuls(self):
+        """XLA's cost analysis prices a custom call at nothing: the kernel
+        says what it does (FlopsAccountant, obs/perf.py read it)."""
+        q, k, v = (x.astype(jnp.bfloat16) for x in qkv(2, 128, 2, 64))
+        jaxpr = jax.make_jaxpr(
+            lambda q, k, v: flash_attention(q, k, v, interpret=True))(q, k, v)
+        (inner,) = [e for e in jaxpr.eqns if e.primitive.name in ("pjit",
+                                                                  "jit")]
+        (call,) = [e for e in inner.params["jaxpr"].eqns
+                   if e.primitive.name == "pallas_call"]
+        cost = call.params["cost_estimate"]
+        assert cost.flops == 4 * 2 * 2 * 128 * 128 * 64
+        assert cost.transcendentals == 2 * 2 * 128 * 128
+        assert cost.bytes_accessed == 2 * 4 * (2 * 128 * 2 * 64)
+
+
+class TestChooser:
+    """ops/attention.py: the tiled kernel on a TPU, for bf16 self-attention
+    at or over the crossover; XLA everywhere else. No chip needed."""
+
+    @pytest.mark.parametrize("t", [1024, 4096, 16384])
+    def test_tpu_self_attention_over_the_crossover_is_tiled(self, t):
+        assert attention_ops.choose("tpu", t, t, jnp.bfloat16,
+                                    self_attention=True) == "tiled"
+
+    @pytest.mark.parametrize("platform,t,s,dtype,self_attention", [
+        ("tpu", 4096, 77, jnp.bfloat16, False),    # cross-attention
+        ("tpu", 256, 256, jnp.bfloat16, True),     # under the crossover
+        ("tpu", 64, 64, jnp.bfloat16, True),
+        ("tpu", 4104, 4104, jnp.bfloat16, True),   # does not tile
+        ("tpu", 1023, 1023, jnp.bfloat16, True),   # odd
+        ("tpu", 4096, 4096, jnp.float32, True),    # a dtype never timed
+        ("cpu", 4096, 4096, jnp.bfloat16, True),
+        ("gpu", 4096, 4096, jnp.bfloat16, True),
+    ])
+    def test_everything_else_is_xla(self, platform, t, s, dtype,
+                                    self_attention):
+        assert attention_ops.choose(platform, t, s, dtype,
+                                    self_attention=self_attention) == "xla"
+
+    def test_auto_on_this_cpu_is_xla_bit_for_bit(self):
+        q, k, v = (x.astype(jnp.bfloat16) for x in qkv(1, 1024, 2, 64))
+        out, path = attention_ops.attend(q, k, v, scale=0.125,
+                                         self_attention=True)
+        assert path == "xla"
+        np.testing.assert_array_equal(
+            np.asarray(out, np.float32),
+            np.asarray(jax.nn.dot_product_attention(q, k, v, scale=0.125),
+                       np.float32))
+
+    @pytest.mark.parametrize("impl,self_attention,s,want", [
+        ("flash", True, 128, "tiled"),
+        ("flash", False, 77, "xla"),     # cross-attention is never tiled
+        ("flash", True, 76, "xla"),      # does not tile
+        ("xla", True, 128, "xla"),
+        ("ring", True, 128, "xla"),      # a ring site that fell through
+    ])
+    def test_explicit_impl_forces_a_side(self, impl, self_attention, s, want):
+        q, k, v = qkv(1, s if self_attention else 64, 2, 64, s=s)
+        out, path = attention_ops.attend(q, k, v, scale=0.125, impl=impl,
+                                         self_attention=self_attention)
+        assert path == want
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(reference(q, k, v)),
+                                   rtol=2e-5, atol=2e-5)
+
+
+class TestAttentionSites:
+    """serving.attention of /internal/status: one UNet trace's sites by the
+    path they took and by (T, S, head_dim)."""
+
+    def _trace(self, impl):
+        from stable_diffusion_webui_distributed_tpu.models.configs import (
+            TINY_XL,
+        )
+        from stable_diffusion_webui_distributed_tpu.models.unet import UNet
+        from stable_diffusion_webui_distributed_tpu.serving.metrics import (
+            ATTENTION, METRICS,
+        )
+
+        cfg = TINY_XL.unet
+        unet = UNet(cfg, attention_impl=impl)
+        x = jnp.zeros((2, 16, 16, cfg.in_channels))
+        args = (x, jnp.zeros((2,)), jnp.zeros((2, 77, cfg.cross_attention_dim)),
+                jnp.zeros((2, cfg.projection_input_dim)))
+        ATTENTION.clear()
+        jax.eval_shape(
+            lambda *a: unet.init_with_output(jax.random.key(0), *a)[0],
+            *args)
+        return METRICS.summary()["attention"]
+
+    def test_default_counts_every_site_on_xla_off_the_tpu(self):
+        got = self._trace("auto")
+        # TINY_XL: depth 2 at the 8x8 level down and up (1 + 2 blocks of
+        # layers_per_block + 1) and a mid block of depth 2, one self- and
+        # one cross-attention each
+        assert got["tiled"] == 0 and got["xla"] > 0
+        assert got["xla"] % 2 == 0
+        assert set(got["by_shape"]) == {"T64 S64 D16", "T64 S77 D16"}
+        assert (got["by_shape"]["T64 S64 D16"]
+                == got["by_shape"]["T64 S77 D16"]
+                == {"xla": got["xla"] // 2})
+
+    def test_forced_kernel_takes_the_self_attention_sites_only(self):
+        auto = self._trace("auto")
+        got = self._trace("flash")
+        assert got["tiled"] == got["xla"] == auto["xla"] // 2
+        assert got["by_shape"]["T64 S64 D16"] == {"tiled": got["tiled"]}
+        assert got["by_shape"]["T64 S77 D16"] == {"xla": got["xla"]}
 
 
 @pytest.mark.slow

@@ -472,6 +472,22 @@ class TestMeshEngine:
         # order differences across device boundaries
         assert np.abs(ia - ib).max() <= 1
 
+    @pytest.mark.parametrize("spec,want", [
+        (None, "auto"), ("dp=1", "auto"), ("dp=4", "xla"),
+        ("dp=2,tp=2", "xla"), ("sp=4", "ring")])
+    def test_attention_impl_follows_the_mesh(self, spec, want):
+        """pjit does not partition a pallas_call: an engine under a dp or
+        tp mesh keeps XLA's attention, one chip lets ops/attention.py
+        choose per site, an sp axis rides the ring."""
+        from stable_diffusion_webui_distributed_tpu.runtime.mesh import (
+            build_mesh,
+        )
+
+        eng = Engine(TINY, init_params(TINY), state=GenerationState(),
+                     mesh=None if spec is None else build_mesh(spec))
+        assert eng.unet.attention_impl == want
+        assert eng.controlnet_module.attention_impl == want
+
     def test_sp_mesh_ring_attention_matches(self, engine):
         """Engine on an sp=4 mesh routes latent self-attention through the
         ring — output must match the meshless run (sequence parallelism is
