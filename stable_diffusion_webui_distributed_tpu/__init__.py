@@ -16,6 +16,13 @@ Import convention::
 
 __version__ = "0.1.0"
 
+# before anything allocates much (runtime/malloc.py says why)
+from stable_diffusion_webui_distributed_tpu.runtime.malloc import (
+    retain_freed_memory,
+)
+
+retain_freed_memory()
+
 # Short, stable aliases for the most-used entry points. Heavy submodules
 # (models, pipeline) are imported lazily by callers to keep CLI startup fast.
 from stable_diffusion_webui_distributed_tpu.runtime.logging import get_logger  # noqa: F401

@@ -73,6 +73,73 @@ class VAEConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeConfig:
+    """One rotary parameterisation. ``factor`` 0 is plain RoPE; over 0 the
+    frequencies are YaRN's (interpolated below ``beta_slow`` rotations of
+    the original context, kept above ``beta_fast``) and cos/sin are
+    multiplied by ``attention_factor``."""
+
+    theta: float = 1e4
+    partial_rotary_factor: float = 1.0   # share of head_dim that is rotated
+    factor: float = 0.0
+    original_max_position: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class LMConfig:
+    """A decoder-only language model read from lists: the kind of every
+    layer's attention (``"full"`` or ``"sliding"``), its query heads, which
+    layers have a dense MLP and which a router over experts. ``num_experts``
+    is the router's width (all of the layer's experts); ``experts_held`` and
+    ``vocab_held`` are the contiguous ranges ``(first, count)`` of experts
+    and of vocabulary ids whose weights THIS chip holds (``None``: all)."""
+
+    vocab_size: int = 100352
+    hidden_size: int = 3072
+    layer_types: Tuple[str, ...] = ("full",)
+    num_heads_per_layer: Tuple[int, ...] = (48,)
+    num_kv_heads: int = 8
+    head_dim: int = 128
+    sliding_window: int = 512
+    rope_full: RopeConfig = dataclasses.field(default_factory=RopeConfig)
+    rope_sliding: RopeConfig = dataclasses.field(default_factory=RopeConfig)
+    dense_layers: Tuple[int, ...] = (0,)
+    intermediate_size: int = 12288
+    num_experts: int = 256
+    num_experts_per_tok: int = 10
+    moe_intermediate_size: int = 1024
+    shared_expert_intermediate_size: int = 1024
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    experts_held: Optional[Tuple[int, int]] = None
+    vocab_held: Optional[Tuple[int, int]] = None
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def experts(self) -> Tuple[int, int]:
+        return self.experts_held or (0, self.num_experts)
+
+    @property
+    def vocab(self) -> Tuple[int, int]:
+        return self.vocab_held or (0, self.vocab_size)
+
+    def layers_of(self, kind: str) -> Tuple[int, ...]:
+        return tuple(i for i, t in enumerate(self.layer_types) if t == kind)
+
+    @property
+    def expert_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i in range(self.num_layers)
+                     if i not in self.dense_layers)
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelFamily:
     """A complete diffusion model family: text encoder(s) + UNet + VAE."""
 
@@ -84,6 +151,10 @@ class ModelFamily:
     vae: VAEConfig = dataclasses.field(default_factory=VAEConfig)
     # v-prediction (SD2.x-style) vs epsilon-prediction.
     prediction_type: str = "epsilon"
+    # resident prompt expander (models/lm.py): the always-on "prompt
+    # expansion" script's language model; None = the family has none and
+    # the script is ignored
+    expander: Optional[LMConfig] = None
 
     @property
     def vae_scale_factor(self) -> int:
@@ -254,6 +325,66 @@ SDXL_INPAINT = dataclasses.replace(
 TINY_INPAINT = dataclasses.replace(
     TINY, name="tiny-inpaint",
     unet=dataclasses.replace(TINY.unet, in_channels=9))
+
+# Laguna-S-2.1 (poolside; huggingface.co/poolside/Laguna-S-2.1 config.json)
+# at its published widths: 48 layers in the pattern full, sliding, sliding,
+# sliding with 48 and 72 query heads, a dense first layer, 256 experts of
+# which 10 a token plus one shared.
+_LAGUNA_ROPE_FULL = RopeConfig(
+    theta=5e5, partial_rotary_factor=0.5, factor=128.0,
+    original_max_position=8192, beta_fast=32.0, beta_slow=1.0,
+    attention_factor=1.4852030263919618)
+LAGUNA_S_2_1 = LMConfig(
+    layer_types=("full", "sliding", "sliding", "sliding") * 12,
+    num_heads_per_layer=(48, 72, 72, 72) * 12,
+    rope_full=_LAGUNA_ROPE_FULL, rope_sliding=RopeConfig(theta=1e4))
+
+
+def lm_share(cfg: LMConfig, layers: int, chips: int, rank: int) -> LMConfig:
+    """The share of ``cfg`` one chip of ``chips`` holds when they share each
+    layer: the first ``layers`` layers (the others lie on further chips as
+    pipeline stages), every attention head, the shared expert, and the
+    ``rank``-th contiguous part of the experts and of the vocabulary."""
+    experts = cfg.num_experts // chips
+    vocab = cfg.vocab_size // chips
+    return dataclasses.replace(
+        cfg, layer_types=cfg.layer_types[:layers],
+        num_heads_per_layer=cfg.num_heads_per_layer[:layers],
+        experts_held=(rank * experts, experts),
+        vocab_held=(rank * vocab, vocab))
+
+
+def sd15_laguna_expander() -> ModelFamily:
+    """SD1.5 with Laguna-S-2.1 as its resident prompt expander, cut to one
+    chip of a pair: layers 0-4 (the dense layer and one whole period),
+    experts 0-127 of every expert layer, vocabulary ids 0-50175."""
+    return dataclasses.replace(
+        SD15, name="sd15-laguna-expand",
+        expander=lm_share(LAGUNA_S_2_1, layers=5, chips=2, rank=0))
+
+
+# Tiny expander that keeps every kind: two head counts, a window (8) shorter
+# than any test context so the ring wraps, a dense first layer, 16 experts
+# top-4 with a shared one, partial YaRN and full plain rotary.
+TINY_LM = LMConfig(
+    vocab_size=512, hidden_size=32,
+    layer_types=("full", "sliding", "sliding", "full"),
+    num_heads_per_layer=(4, 6, 6, 4), num_kv_heads=2, head_dim=16,
+    sliding_window=8,
+    rope_full=RopeConfig(theta=5e5, partial_rotary_factor=0.5, factor=4.0,
+                         original_max_position=16, attention_factor=1.1386),
+    rope_sliding=RopeConfig(theta=1e4),
+    dense_layers=(0,), intermediate_size=64, num_experts=16,
+    num_experts_per_tok=4, moe_intermediate_size=16,
+    shared_expert_intermediate_size=16)
+TINY_EXPAND = dataclasses.replace(
+    TINY, name="tiny-expand", expander=lm_share(TINY_LM, 4, chips=2, rank=0))
+
+
+def tiny_expander() -> ModelFamily:
+    """Factory form of :data:`TINY_EXPAND` (benchmark rehearsals)."""
+    return TINY_EXPAND
+
 
 FAMILIES = {f.name: f for f in (SD15, SD21, SD21_BASE, SDXL_BASE,
                                 SDXL_REFINER, SD15_INPAINT, SD2_INPAINT,

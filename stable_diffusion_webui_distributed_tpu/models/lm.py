@@ -1,0 +1,392 @@
+"""A decoder-only language model read from its config's lists: the family's
+resident prompt expander (``ModelFamily.expander``, pipeline/engine.py's
+``expand`` stage).
+
+Nothing here names an architecture. ``LMConfig`` says, layer by layer, which
+attention a layer has (``"full"``: every earlier position; ``"sliding"``:
+the last ``sliding_window``), how many query heads (they may differ by
+layer; the KV heads are shared by groups of them), which rotary
+parameterisation goes with which kind, and whether the MLP is dense or a
+router over experts with one shared expert (ops/moe.py). Every attention
+output passes a per-head gate, ``sigmoid(W_g n)``, before ``o_proj``.
+
+One call, :meth:`DecoderLM.__call__`, runs a chunk of ``T`` tokens that
+starts at position ``start`` against the cache and returns the cache with
+the chunk written: a prefill is a long chunk, a decode step a chunk of one.
+The cache (cache/kv.py) is a key and a value buffer a layer, of two kinds:
+a full layer's holds every position up to its capacity, a sliding layer's
+is a ring of ``sliding_window`` slots, slot ``p % window`` holding position
+``p``.
+A chunk may be padded: only its first ``length`` tokens are real, the rest
+are never written to a ring and never seen by a real query.
+
+Batch 1: a prompt is one sequence.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Dict, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from stable_diffusion_webui_distributed_tpu.models.configs import (
+    LMConfig, RopeConfig,
+)
+from stable_diffusion_webui_distributed_tpu.ops import moe
+from stable_diffusion_webui_distributed_tpu.ops.attention import (
+    attend_positions,
+)
+from stable_diffusion_webui_distributed_tpu.ops.quant import int8_dot
+from stable_diffusion_webui_distributed_tpu.serving.metrics import ATTENTION
+
+FULL, SLIDING = "full", "sliding"
+
+
+# -- rotary embeddings -------------------------------------------------------
+
+def rope_frequencies(rope: RopeConfig, head_dim: int) -> np.ndarray:
+    """Inverse frequencies of the rotated pairs, ``(rotary_dim / 2,)``.
+    With ``factor`` over 0 they are YaRN's: a pair that turns fewer than
+    ``beta_slow`` times over the original context is interpolated by
+    ``factor``, one that turns more than ``beta_fast`` times is kept, and
+    those between blend linearly."""
+    dim = int(head_dim * rope.partial_rotary_factor)
+    exponent = np.arange(0, dim, 2, dtype=np.float64) / dim
+    plain = 1.0 / rope.theta ** exponent
+    if not rope.factor:
+        return plain
+
+    def pair_of(rotations: float) -> float:
+        return (dim * math.log(rope.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(rope.theta)))
+
+    low = max(math.floor(pair_of(rope.beta_fast)), 0)
+    high = min(math.ceil(pair_of(rope.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return plain / rope.factor * ramp + plain * (1 - ramp)
+
+
+def rope_tables(rope: RopeConfig, head_dim: int, positions: jax.Array):
+    """(cos, sin), each ``(T, rotary_dim / 2)`` float32."""
+    angles = (positions.astype(jnp.float32)[:, None]
+              * jnp.asarray(rope_frequencies(rope, head_dim), jnp.float32))
+    return (jnp.cos(angles) * rope.attention_factor,
+            jnp.sin(angles) * rope.attention_factor)
+
+
+def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """Rotates the first ``2 * cos.shape[-1]`` dims of ``(T, H, D)`` ``x``
+    (pairs are (i, i + half), the ``rotate_half`` convention); the rest
+    pass. Float32 result."""
+    half = cos.shape[-1]
+    x = x.astype(jnp.float32)
+    a, b, rest = x[..., :half], x[..., half:2 * half], x[..., 2 * half:]
+    c, s = cos[:, None, :], sin[:, None, :]
+    return jnp.concatenate([a * c - b * s, b * c + a * s, rest], axis=-1)
+
+
+# -- modules -----------------------------------------------------------------
+
+class Linear(nn.Module):
+    """``x @ kernel`` without bias: operands in ``dtype``, float32
+    accumulation and result. ``quant`` takes ops/quant.py's dynamic int8
+    product instead (the lower-precision control)."""
+
+    features: int
+    dtype: jnp.dtype = jnp.float32
+    quant: bool = False
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (x.shape[-1], self.features))
+        if self.quant:
+            return int8_dot(x, kernel)
+        return jnp.dot(x.astype(self.dtype), kernel.astype(self.dtype),
+                       preferred_element_type=jnp.float32)
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x = x.astype(jnp.float32)
+        mean = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt(mean + self.eps) * scale.astype(jnp.float32)
+
+
+class SwiGLU(nn.Module):
+    width: int
+    dtype: jnp.dtype = jnp.float32
+    quant: bool = False
+
+    @nn.compact
+    def __call__(self, n: jax.Array) -> jax.Array:
+        def lin(features, name):
+            return Linear(features, self.dtype, self.quant, name=name)
+
+        hidden = jax.nn.silu(lin(self.width, "gate_proj")(n)) \
+            * lin(self.width, "up_proj")(n)
+        return lin(n.shape[-1], "down_proj")(hidden)
+
+
+class Experts(nn.Module):
+    """The stacked kernels of the experts held here."""
+
+    held: int
+    width: int
+
+    @nn.compact
+    def __call__(self, hidden_size: int):
+        init = nn.initializers.variance_scaling(
+            1.0, "fan_in", "truncated_normal", in_axis=1, out_axis=2,
+            batch_axis=0)
+        return (self.param("w_gate", init,
+                           (self.held, hidden_size, self.width)),
+                self.param("w_up", init,
+                           (self.held, hidden_size, self.width)),
+                self.param("w_down", init,
+                           (self.held, self.width, hidden_size)))
+
+
+class MoE(nn.Module):
+    config: LMConfig
+    dtype: jnp.dtype = jnp.float32
+    quant: bool = False
+
+    @nn.compact
+    def __call__(self, n: jax.Array, valid: jax.Array):
+        cfg = self.config
+        first, held = cfg.experts
+        router = self.param("router", nn.initializers.lecun_normal(),
+                            (n.shape[-1], cfg.num_experts))
+        # the router sees the normed input in float32: a near-tie decided
+        # by rounding the input would send a token to other experts
+        logits = jnp.dot(n, router.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        routing = moe.route(logits, cfg.num_experts_per_tok,
+                            renormalise=cfg.norm_topk_prob,
+                            scale=cfg.routed_scaling_factor)
+        kernels = Experts(held, cfg.moe_intermediate_size,
+                          name="experts")(n.shape[-1])
+        compute = [w.astype(self.dtype) for w in kernels]
+        routed = moe.routed_experts(n.astype(self.dtype), routing, *compute,
+                                    first=first,
+                                    num_experts=cfg.num_experts)
+        shared = SwiGLU(cfg.shared_expert_intermediate_size, self.dtype,
+                        self.quant, name="shared_expert")(n)
+        load, none_held = moe.load_counts(routing, first, held, valid)
+        return routed + shared, (routing.experts, load, none_held)
+
+
+class Attention(nn.Module):
+    config: LMConfig
+    layer: int
+    dtype: jnp.dtype = jnp.float32
+    quant: bool = False
+
+    @nn.compact
+    def __call__(self, n, q_pos, start, end, k_cache, v_cache):
+        cfg = self.config
+        kind = cfg.layer_types[self.layer]
+        heads = cfg.num_heads_per_layer[self.layer]
+        kv, dim = cfg.num_kv_heads, cfg.head_dim
+        tokens = n.shape[0]
+
+        def lin(features, name):
+            return Linear(features, self.dtype, self.quant, name=name)
+
+        cos, sin = rope_tables(
+            cfg.rope_full if kind == FULL else cfg.rope_sliding, dim, q_pos)
+        store = k_cache.dtype
+        q = apply_rope(lin(heads * dim, "q_proj")(n).reshape(
+            tokens, heads, dim), cos, sin).astype(self.dtype)
+        k = apply_rope(lin(kv * dim, "k_proj")(n).reshape(
+            tokens, kv, dim), cos, sin).astype(store)
+        v = lin(kv * dim, "v_proj")(n).reshape(tokens, kv, dim).astype(store)
+        real = q_pos < end
+        if kind == FULL:
+            # written first: a padded row lands beyond ``end``, where no
+            # query looks until a real token has overwritten it
+            k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k, start, 0)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v, start, 0)
+            slots = jnp.arange(k_cache.shape[0])
+            keys, values = k_cache, v_cache
+            k_pos = jnp.where(slots < end, slots, -1)
+            window = 0
+        else:
+            # the chunk's keys would overwrite slots its own first queries
+            # still need, so it attends over ring + chunk and writes after
+            window = k_cache.shape[0]
+            slots = jnp.arange(window)
+            ring_pos = start - 1 - ((start - 1 - slots) % window)
+            keys = jnp.concatenate([k_cache, k])
+            values = jnp.concatenate([v_cache, v])
+            k_pos = jnp.concatenate([ring_pos, jnp.where(real, q_pos, -1)])
+            into = jnp.where(real & (q_pos >= end - window),
+                             q_pos % window, window)
+            k_cache = k_cache.at[into].set(k, mode="drop")
+            v_cache = v_cache.at[into].set(v, mode="drop")
+        out, path = attend_positions(q, keys, values, q_pos, k_pos,
+                                     scale=dim ** -0.5, window=window)
+        ATTENTION.record(path, tokens, keys.shape[0], dim)
+        gate = jax.nn.sigmoid(lin(heads, "g_proj")(n))
+        out = out.astype(jnp.float32) * gate[:, :, None]
+        return (lin(n.shape[-1], "o_proj")(out.reshape(tokens, heads * dim)),
+                k_cache, v_cache)
+
+
+class DecoderLayer(nn.Module):
+    config: LMConfig
+    layer: int
+    dtype: jnp.dtype = jnp.float32
+    quant: bool = False
+
+    @nn.compact
+    def __call__(self, x, q_pos, start, end, k_cache, v_cache):
+        cfg = self.config
+        n = RMSNorm(cfg.rms_norm_eps, name="input_norm")(x)
+        attn, k_cache, v_cache = Attention(
+            cfg, self.layer, self.dtype, self.quant, name="attn")(
+                n, q_pos, start, end, k_cache, v_cache)
+        h = x + attn
+        n = RMSNorm(cfg.rms_norm_eps, name="post_attention_norm")(h)
+        if self.layer in cfg.dense_layers:
+            out, routed = SwiGLU(cfg.intermediate_size, self.dtype,
+                                 self.quant, name="mlp")(n), None
+        else:
+            out, routed = MoE(cfg, self.dtype, self.quant, name="mlp")(
+                n, q_pos < end)
+        return h + out, k_cache, v_cache, routed
+
+
+class DecoderLM(nn.Module):
+    """``(logits, cache, routed)`` for one chunk. ``tokens`` ``(T,)`` are
+    vocabulary ids; ``start`` is the first one's position and ``length``
+    how many are real. ``logits`` are float32 over
+    the held slice, for every row of the chunk or (``all_logits`` False)
+    for the last real one alone. ``routed`` has, stacked over the expert
+    layers, the experts every token chose ``(layers, T, k)``, the tokens
+    sent to each held expert ``(layers, held)`` and the tokens none of
+    whose experts is held ``(layers,)``."""
+
+    config: LMConfig
+    dtype: jnp.dtype = jnp.float32
+    quant_linears: bool = False
+
+    @nn.compact
+    def __call__(self, tokens, start, length, cache: Dict[str, jax.Array],
+                 all_logits: bool = True):
+        cfg = self.config
+        q_pos = start + jnp.arange(tokens.shape[0], dtype=jnp.int32)
+        end = start + length
+        # the table is sharded over the vocabulary: an id another chip
+        # holds gets nothing here (their parts are summed in a deployment)
+        first, count = cfg.vocab
+        local = tokens - first
+        here = (local >= 0) & (local < count)
+        x = nn.Embed(count, cfg.hidden_size, name="embed_tokens")(
+            jnp.clip(local, 0, count - 1)).astype(jnp.float32)
+        x = x * here[:, None]
+        keys, values, routed = [], [], []
+        for layer in range(cfg.num_layers):
+            x, k, v, r = DecoderLayer(
+                cfg, layer, self.dtype, self.quant_linears,
+                name=f"layers_{layer}")(
+                    x, q_pos, start, end, cache["k"][layer],
+                    cache["v"][layer])
+            keys.append(k)
+            values.append(v)
+            if r is not None:
+                routed.append(r)
+        cache = {"k": keys, "v": values}
+        if not all_logits:
+            x = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, 0)
+        n = RMSNorm(cfg.rms_norm_eps, name="norm")(x)
+        logits = Linear(cfg.vocab[1], self.dtype, self.quant_linears,
+                        name="lm_head")(n)
+        if not routed:     # no expert layer: the three parts, empty
+            none = jnp.zeros((0,), jnp.int32)
+            return logits, cache, (none[:, None, None], none[:, None], none)
+        return logits, cache, tuple(jnp.stack(part) for part in zip(*routed))
+
+
+# -- the cache's shapes, and the executables the engine builds ----------------
+
+def cache_shapes(cfg: LMConfig, capacity: int) -> Dict[str, list]:
+    """Per layer the shape of its key (and value) buffer: a full layer
+    holds ``capacity`` positions, a sliding layer a ring of its window."""
+    rows = [(capacity if kind == FULL else cfg.sliding_window,
+             cfg.num_kv_heads, cfg.head_dim) for kind in cfg.layer_types]
+    return {"k": rows, "v": list(rows)}
+
+
+def empty_cache(cfg: LMConfig, capacity: int, dtype) -> Dict[str, list]:
+    return {name: [jnp.zeros(shape, dtype) for shape in rows]
+            for name, rows in cache_shapes(cfg, capacity).items()}
+
+
+def sample(logits: jax.Array, key: jax.Array, position, temperature,
+           first: int = 0):
+    """One vocabulary id from ``logits`` ``(V,)`` over the held slice that
+    starts at id ``first``: the draw is keyed by the position of the token
+    being made, so a sequence does not depend on how its decoding was cut
+    into chunks. Temperature 0 is the arg-max."""
+    key = jax.random.fold_in(key, position)
+    drawn = jax.random.categorical(
+        key, logits / jnp.maximum(temperature, 1e-6))
+    return first + jnp.where(temperature > 0, drawn,
+                             jnp.argmax(logits)).astype(jnp.int32)
+
+
+def prefill_fn(module: DecoderLM):
+    """``expand_prefill(params, cache, tokens, start, length, key,
+    temperature) -> (cache, next token, routed load, none held)``: one
+    chunk, and the token that follows its last real one."""
+
+    def expand_prefill(params, cache, tokens, start, length, key,
+                       temperature):
+        logits, cache, routed = module.apply(
+            {"params": params}, tokens, start, length, cache,
+            all_logits=False)
+        token = sample(logits[0], key, start + length, temperature,
+                       module.config.vocab[0])
+        return cache, token, routed[1], routed[2]
+
+    return expand_prefill
+
+
+def decode_chunk_fn(module: DecoderLM, steps: int):
+    """``expand_decode_chunk(params, cache, token, position, key,
+    temperature) -> (cache, token, position, the steps' tokens, routed
+    load, none held)``: ``steps`` tokens, each fed back as the next input;
+    ``token`` sits at ``position`` and is not yet in the cache."""
+
+    def expand_decode_chunk(params, cache, token, position, key,
+                            temperature):
+        def step(carry, _):
+            cache, token, position, load, none_held = carry
+            logits, cache, routed = module.apply(
+                {"params": params}, token[None], position, 1, cache,
+                all_logits=False)
+            token = sample(logits[0], key, position + 1, temperature,
+                           module.config.vocab[0])
+            return (cache, token, position + 1, load + routed[1],
+                    none_held + routed[2]), token
+
+        cfg = module.config
+        layers = len(cfg.expert_layers)
+        zero = (jnp.zeros((layers, cfg.experts[1]), jnp.int32),
+                jnp.zeros((layers,), jnp.int32))
+        (cache, token, position, load, none_held), made = jax.lax.scan(
+            step, (cache, token, position) + zero, None, length=steps)
+        return cache, token, position, made, load, none_held
+
+    return expand_decode_chunk

@@ -199,3 +199,55 @@ def load_tokenizer(model_dir: Optional[str], vocab_size: int = 49408):
         f" in {model_dir}" if model_dir else "",
     )
     return FallbackTokenizer(vocab_size)
+
+
+# -- the resident language model's tokenizer (models/lm.py) -------------------
+
+class FallbackLMTokenizer:
+    """Deterministic hash tokenizer of the prompt expander, for seeded
+    weights and tests: each whitespace word maps to a stable id INSIDE the
+    slice of the vocabulary this chip holds (``first``, ``count``), so
+    every id has an embedding row here; an id decodes to ``w<id>``, a word
+    the CLIP tokenizers take whole."""
+
+    def __init__(self, first: int, count: int) -> None:
+        self.first = int(first)
+        self._words = FallbackTokenizer(int(count))   # ids 0 .. count - 1
+        self.bos = self.first + self._words.bos
+        self.eos = self.first + self._words.eos
+
+    def encode(self, text: str) -> List[int]:
+        return [self.first + i for i in self._words.encode(text)]
+
+    def decode(self, ids: Sequence[int]) -> str:
+        return " ".join(f"w{int(i)}" for i in ids
+                        if int(i) not in (self.bos, self.eos))
+
+
+class LMTokenizer:
+    """Byte-level BPE from ``vocab.json`` + ``merges.txt`` (the files
+    :class:`CLIPTokenizer` reads), with the inverse map to decode."""
+
+    def __init__(self, bpe: CLIPTokenizer) -> None:
+        self._bpe = bpe
+        self.bos, self.eos = bpe.bos, bpe.eos
+        self._words = {i: w for w, i in bpe.vocab.items()}
+
+    def encode(self, text: str) -> List[int]:
+        return self._bpe.encode(text)
+
+    def decode(self, ids: Sequence[int]) -> str:
+        text = "".join(self._words.get(int(i), "") for i in ids
+                       if int(i) not in (self.bos, self.eos))
+        return text.replace("</w>", " ").strip()
+
+
+def load_lm_tokenizer(model_dir: Optional[str], first: int, count: int):
+    """The expander's tokenizer: vocabulary files when ``model_dir`` has
+    them, else the hash fallback into the held slice."""
+    if model_dir:
+        try:
+            return LMTokenizer(CLIPTokenizer.load(model_dir))
+        except (FileNotFoundError, OSError):
+            pass
+    return FallbackLMTokenizer(first, count)

@@ -1,9 +1,12 @@
-"""Which attention a UNet site takes: the tiled Pallas kernel or XLA.
+"""Which attention a site takes: the tiled Pallas kernel or XLA.
 
 One place decides, from what the call itself shows: the platform, whether
-it is self-attention, the shapes and the dtype. No environment variable and
-no option: a shape where the kernel has not measured faster stays on
-``jax.nn.dot_product_attention``.
+it is self-attention, the shapes and the dtype, whether it is masked and
+whether query heads share KV heads. No environment variable and no option:
+a shape where the kernel has not measured faster stays on XLA
+(``jax.nn.dot_product_attention`` for a UNet's sites,
+:func:`attend_positions` for a decoder LM's causal, windowed, grouped-query
+sites over a cache, which the kernel cannot take at all).
 
 The readings that set :data:`TILED_MIN_TOKENS` (one v5e, bf16, CFG batch 2,
 each alone in a device-side loop of 20 calls, median of 5; my chip runs,
@@ -43,12 +46,18 @@ XLA = "xla"
 
 
 def choose(platform: str, t: int, s: int, dtype, *,
-           self_attention: bool) -> str:
+           self_attention: bool, masked: bool = False,
+           kv_groups: int = 1) -> str:
     """``"tiled"`` or ``"xla"`` for one site, from what the site shows.
 
     Tiled wants a TPU, self-attention (cross-attention's 77-token context
     is small and does not tile), the serving policy's bf16 (the only dtype
-    timed) and a sequence at or over the crossover that tiles evenly."""
+    timed) and a sequence at or over the crossover that tiles evenly. The
+    kernel takes no mask and one KV head a query head, so a causal or
+    windowed site (``masked``) and a grouped-query one (``kv_groups`` query
+    heads a KV head) stay on XLA; neither has been timed on the kernel."""
+    if masked or kv_groups != 1:
+        return XLA
     if (platform == "tpu" and self_attention
             and jnp.dtype(dtype) == jnp.bfloat16
             and t >= TILED_MIN_TOKENS and blocks(t, s) is not None):
@@ -74,3 +83,38 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, *, scale: float,
     if path == TILED:
         return flash_attention(q, k, v, scale=scale), path
     return jax.nn.dot_product_attention(q, k, v, scale=scale), path
+
+
+def attend_positions(q: jax.Array, k: jax.Array, v: jax.Array,
+                     q_pos: jax.Array, k_pos: jax.Array, *, scale: float,
+                     window: int = 0):
+    """(output, path taken) for causal, optionally windowed, grouped-query
+    attention over keys that carry their own positions: a decoder LM's site
+    (models/lm.py), where the keys are a cache.
+
+    ``q`` is ``(T, H, D)``, ``k`` and ``v`` ``(S, KV, D)`` with ``H`` a
+    multiple of ``KV``: query head ``j`` attends KV head ``j // (H / KV)``.
+    ``q_pos`` ``(T,)`` and ``k_pos`` ``(S,)`` are token positions; a key
+    with a negative position is an empty slot. Query ``i`` sees key ``j``
+    when ``0 <= i - j`` and, with ``window`` over 0, ``i - j < window``.
+    Scores and softmax are float32; the output has ``q``'s dtype. A query
+    that sees no key (a padded row) gets zeros."""
+    t, heads, dim = q.shape
+    s, kv, _ = k.shape
+    groups = heads // kv
+    path = choose(jax.default_backend(), t, s, q.dtype, self_attention=True,
+                  masked=True, kv_groups=groups)
+    delta = q_pos[:, None] - k_pos[None, :]
+    seen = (delta >= 0) & (k_pos[None, :] >= 0)
+    if window:
+        seen &= delta < window
+    scores = jnp.einsum("tkgd,skd->kgts", q.reshape(t, kv, groups, dim), k,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(seen[None, None], scores, -jnp.inf)
+    top = jnp.max(scores, axis=-1, keepdims=True)
+    weights = jnp.exp(scores - jnp.where(jnp.isfinite(top), top, 0.0))
+    total = jnp.sum(weights, axis=-1, keepdims=True)
+    weights = weights / jnp.where(total > 0, total, 1.0)
+    out = jnp.einsum("kgts,skd->tkgd", weights.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(t, heads, dim).astype(q.dtype), path

@@ -1,0 +1,152 @@
+"""Mixture-of-experts feed-forward over the experts THIS chip holds.
+
+A deployment shards an expert layer over chips: every chip routes each
+token over all of the layer's experts (the router's weight is whole
+everywhere), computes the part of the sum that its own experts give, and an
+all-reduce over the expert axis adds the parts. This module is one chip's
+part: it is told which contiguous range of experts its stacked kernels are
+(``first``, and their count from the kernels' leading dimension), computes
+those, and adds nothing for the absent ones. No code stands in for the
+other chips.
+
+Two products, chosen by what the call shows (the number of tokens, which is
+static):
+
+- ``T > 1`` (prefill): tokens are sorted by the expert they chose, each
+  expert's rows padded up to a row tile, and one loop walks the tiles that
+  hold rows, each a ``(tile, d) @ (d, f)`` product against the one expert
+  the tile belongs to. An expert nobody chose is not read.
+- ``T == 1`` (a decode step): a loop over the chosen experts held here (5 of
+  10 on average when half are held), each reading that expert's three
+  kernels once. The experts that were held but not chosen are not read:
+  that is what bounds a decoded token's bytes.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+
+class Routing(NamedTuple):
+    experts: jax.Array   # (T, k) int32, ids over ALL of the layer's experts
+    weights: jax.Array   # (T, k) float32, renormalised and scaled
+
+
+def route(logits: jax.Array, k: int, *, renormalise: bool,
+          scale: float) -> Routing:
+    """Scores are a float32 softmax over every expert; the ``k`` largest are
+    taken, their scores optionally renormalised to sum to one, then scaled."""
+    scores = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    top, experts = jax.lax.top_k(scores, k)
+    if renormalise:
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+    return Routing(experts.astype(jnp.int32), top * scale)
+
+
+def row_tile(tokens: int, k: int, num_experts: int) -> int:
+    """Rows of one tile of the grouped product: the power of two at or over
+    the rows an expert gets on average, within [8, 256]."""
+    mean = max(1, -(-tokens * k // num_experts))
+    return int(min(256, max(8, 1 << (mean - 1).bit_length())))
+
+
+def _swiglu(x, w_gate, w_up, w_down):
+    """``W_d(silu(W_g x) * W_u x)`` for one expert; float32 accumulation,
+    operands in ``x``'s dtype, float32 result."""
+    f32 = jnp.float32
+    gate = jnp.dot(x, w_gate, preferred_element_type=f32)
+    up = jnp.dot(x, w_up, preferred_element_type=f32)
+    hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+    return jnp.dot(hidden, w_down, preferred_element_type=f32)
+
+
+def held_mask(experts: jax.Array, first: int, count: int):
+    """(local ids, which of the chosen experts are held here)."""
+    local = experts - first
+    return local, (local >= 0) & (local < count)
+
+
+def _grouped(x, routing: Routing, w_gate, w_up, w_down, first: int,
+             num_experts: int):
+    tokens, k = routing.experts.shape
+    count = w_gate.shape[0]
+    tile = row_tile(tokens, k, num_experts)
+    local, held = held_mask(routing.experts, first, count)
+    chosen = jnp.where(held, local, count).reshape(-1)   # absent sort last
+    order = jnp.argsort(chosen, stable=True)
+    sorted_e = chosen[order]
+    token_of = order // k
+    sizes = jnp.bincount(chosen, length=count + 1)[:count]
+    tiles_of = (sizes + tile - 1) // tile
+    tile_end = jnp.cumsum(tiles_of)
+    row_start = jnp.cumsum(sizes) - sizes
+    # every expert wastes less than one tile, so this many always suffice
+    max_tiles = tokens * k // tile + count
+    rows = max_tiles * tile
+    e = jnp.minimum(sorted_e, count - 1)
+    dest = ((tile_end[e] - tiles_of[e]) * tile
+            + jnp.arange(tokens * k) - row_start[e])
+    dest = jnp.where(sorted_e < count, dest, rows)       # absent: dropped
+    xs = jnp.zeros((rows, x.shape[-1]), x.dtype).at[dest].set(
+        x[token_of], mode="drop")
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(max_tiles), side="right"),
+        count - 1)
+
+    def one_tile(t, ys):
+        expert = tile_expert[t]
+        y = _swiglu(jax.lax.dynamic_slice_in_dim(xs, t * tile, tile),
+                    w_gate[expert], w_up[expert], w_down[expert])
+        return jax.lax.dynamic_update_slice_in_dim(ys, y, t * tile, 0)
+
+    ys = jax.lax.fori_loop(0, tile_end[-1], one_tile,
+                           jnp.zeros((rows, x.shape[-1]), jnp.float32))
+    weight = jnp.where(sorted_e < count,
+                       routing.weights.reshape(-1)[order], 0.0)
+    part = ys[jnp.minimum(dest, rows - 1)] * weight[:, None]
+    return jnp.zeros((tokens, x.shape[-1]), jnp.float32).at[token_of].add(
+        part)
+
+
+def _chosen(x, routing: Routing, w_gate, w_up, w_down, first: int):
+    count = w_gate.shape[0]
+    local, held = held_mask(routing.experts[0], first, count)
+    order = jnp.argsort(~held, stable=True)              # held ones first
+    experts = jnp.where(held, local, 0)[order]
+    weights = jnp.where(held, routing.weights[0], 0.0)[order]
+
+    def one_expert(j, acc):
+        expert = experts[j]
+        return acc + weights[j] * _swiglu(x, w_gate[expert], w_up[expert],
+                                          w_down[expert])
+
+    return jax.lax.fori_loop(0, jnp.sum(held), one_expert,
+                             jnp.zeros(x.shape, jnp.float32))
+
+
+def routed_experts(x: jax.Array, routing: Routing, w_gate: jax.Array,
+                   w_up: jax.Array, w_down: jax.Array, *, first: int,
+                   num_experts: int) -> jax.Array:
+    """This chip's part of ``sum_e w_e E_e(x)``: ``x`` is ``(T, d)``, the
+    kernels are stacked ``(held, d, f)``, ``(held, d, f)``, ``(held, f,
+    d)`` and are experts ``first .. first + held - 1`` of ``num_experts``.
+    Float32 ``(T, d)``."""
+    if x.shape[0] == 1:
+        return _chosen(x, routing, w_gate, w_up, w_down, first)
+    return _grouped(x, routing, w_gate, w_up, w_down, first, num_experts)
+
+
+def load_counts(routing: Routing, first: int, count: int, valid=None):
+    """(tokens routed to each held expert ``(count,)``, tokens none of whose
+    chosen experts is held here), both int32. ``valid`` masks padded rows."""
+    local, held = held_mask(routing.experts, first, count)
+    if valid is not None:
+        held = held & valid[:, None]
+    per_expert = jnp.bincount(jnp.where(held, local, count).reshape(-1),
+                              length=count + 1)[:count]
+    rows = jnp.ones(held.shape[0], bool) if valid is None else valid
+    none_held = jnp.sum(rows & ~jnp.any(held, axis=-1))
+    return per_expert.astype(jnp.int32), none_held.astype(jnp.int32)

@@ -12,6 +12,14 @@ TP rules (applied by param-path pattern, the Megatron split):
   (row parallel; XLA inserts the psum);
 - convs: split output channels (last dim of HWIO) over ``tp``;
 - norms, biases of row-parallel layers, embeddings: replicated.
+
+The resident language model (models/lm.py) adds two axes a mesh may carry:
+``ep`` splits an expert layer's stacked kernels by expert (every chip routes
+over all experts and computes its own, ops/moe.py), ``vp`` splits the token
+table's rows and the head's columns by vocabulary id. A mesh without these
+axes leaves the leaves whole. One chip's share (``LMConfig.experts_held``,
+``vocab_held``) is what one position of those axes holds; the exchange that
+adds the parts exists only on a mesh that has the axis.
 """
 
 from __future__ import annotations
@@ -26,6 +34,9 @@ _ROW_ENDINGS = ("out_proj", "fc2", "ff_out", "time_fc2", "add_fc2",
                 "proj_out")
 
 
+_EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
 def tp_spec_for(path: str, ndim: int):
     """PartitionSpec for one param, from its tree path (joined with '/')."""
     from jax.sharding import PartitionSpec as P
@@ -34,6 +45,12 @@ def tp_spec_for(path: str, ndim: int):
     leaf = parts[-1]              # kernel | bias | scale | embedding
     module = parts[-2] if len(parts) > 1 else ""
 
+    if leaf in _EXPERT_LEAVES and module == "experts":
+        return P("ep", None, None)
+    if leaf == "embedding" and module == "embed_tokens":
+        return P("vp", None)
+    if leaf == "kernel" and module == "lm_head":
+        return P(None, "vp")
     if leaf == "kernel":
         if module in _ROW_ENDINGS:
             # row-parallel: contract dim sharded
@@ -55,21 +72,25 @@ def shard_params(params, mesh, use_tp: bool = True):
     otherwise fully replicated."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     if mesh is None:
         return params
+    model_axes = {a: mesh.shape.get(a, 1) for a in ("tp", "ep", "vp")}
     flat = jax.tree_util.tree_flatten_with_path(params)
     leaves, treedef = flat
     placed = []
     for keypath, leaf in leaves:
-        if tp > 1 and use_tp and hasattr(leaf, "ndim"):
+        if max(model_axes.values()) > 1 and use_tp \
+                and hasattr(leaf, "ndim"):
             path = jax.tree_util.keystr(keypath, simple=True,
                                           separator="/")
-            spec = tp_spec_for(path, leaf.ndim)
+            # an axis the mesh lacks (or has at size 1) splits nothing
+            axes = [a if model_axes.get(a, 1) > 1 else None
+                    for a in tp_spec_for(path, leaf.ndim)]
+            spec = P(*axes) if any(axes) else P()
             # only shard dims that divide evenly; else replicate
             ok = True
             for dim, axis in enumerate(spec):
-                if axis == "tp" and leaf.shape[dim] % tp != 0:
+                if axis and leaf.shape[dim] % model_axes[axis] != 0:
                     ok = False
             sharding = NamedSharding(mesh, spec if ok else P())
         else:

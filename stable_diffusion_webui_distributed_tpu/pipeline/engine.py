@@ -57,6 +57,7 @@ from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
     b64png_to_array,
     build_infotext,
     fix_seed,
+    prompt_expansion_args,
 )
 from stable_diffusion_webui_distributed_tpu.obs import spans as obs_spans
 from stable_diffusion_webui_distributed_tpu.runtime import dtypes, rng, trace
@@ -213,6 +214,16 @@ class Engine:
 
         self._cache: Dict[Tuple, Callable] = {}  # guarded-by: _cache_lock
         self._cache_lock = threading.Lock()
+        # resident prompt expander (pipeline/expand.py): only a family that
+        # has one, loaded with its weights, gets the ``expand`` stage; every
+        # other engine ignores the always-on script
+        self.expander = None
+        if family.expander is not None \
+                and self.params.get("expander") is not None:
+            from stable_diffusion_webui_distributed_tpu.pipeline.expand \
+                import PromptExpander
+
+            self.expander = PromptExpander(self)
         # XLA cost_analysis pricer for the per-request UNet-FLOPs metric
         # (pipeline/stepcache.py); lowers abstractly, so it is cheap to
         # hold per engine and its cache keys on eval shapes only
@@ -1565,6 +1576,11 @@ class Engine:
         # _denoise_adaptive; snapshot-and-cleared PER GROUP by
         # _queue_decoded so complete batches are never mislabeled)
         self._adaptive_incomplete = False
+        expansion = prompt_expansion_args(payload) \
+            if self.expander is not None else None
+        if expansion is not None:
+            count = payload.total_images if count is None else count
+            self._expand_prompts(payload, expansion, start_index, count)
         if payload.all_prompts and payload.context_chunks is None:
             # full-request entry (a sub-range over HTTP arrives with the
             # master's value): pin the request-wide context length so
@@ -1578,6 +1594,25 @@ class Engine:
             if payload.init_images:
                 return self._run_img2img(payload, start_index, count, job)
             return self._run_txt2img(payload, start_index, count, job)
+
+    def _expand_prompts(self, payload, expansion, start: int,
+                        count: int) -> None:
+        """The ``expand`` stage: images [start, start+count) get their
+        prompts continued by the resident language model, each keyed by
+        its own seed, so a sub-range expands exactly its share. Mutates
+        ``payload`` (generate_range's copy)."""
+        total = payload.total_images
+        prompts = list(payload.all_prompts or [payload.prompt] * total)
+        for i in range(start, min(start + count, len(prompts))):
+            prompts[i] = self.expander.expand(
+                prompts[i], expansion, payload.seed,
+                0 if payload.same_seed else i)
+        if total == 1 and not payload.all_prompts:
+            payload.prompt = prompts[0]
+        else:
+            payload.all_prompts = prompts
+        if expansion.context_chunks:
+            payload.context_chunks = int(expansion.context_chunks)
 
     def txt2img(self, payload: GenerationPayload) -> GenerationResult:
         # top-level request: reset the interrupt latch and expand native

@@ -291,6 +291,46 @@ def apply_scripts(payload: "GenerationPayload") -> "GenerationPayload":
     return payload
 
 
+#: title of the always-on script that asks for prompt expansion
+PROMPT_EXPANSION = "prompt expansion"
+
+
+class PromptExpansion(BaseModel):
+    """Arguments of the always-on ``prompt expansion`` script
+    (``alwayson_scripts: {"prompt expansion": {"args": [{...}]}}``): the
+    worker's resident language model (``ModelFamily.expander``) continues
+    ``instruction`` + the user's prompt, and the continuation is appended
+    to the prompt before CLIP sees it (upstream: Dynamic Prompts' Magic
+    Prompt, the promptgen extension). Every setting is an argument."""
+
+    #: the operator's text before every prompt; its cache is kept
+    instruction: str = ""
+    max_new_tokens: int = 64
+    #: 0 = greedy; draws are keyed by the image's seed
+    temperature: float = 1.0
+    #: keep decoding past an end-of-sequence token
+    ignore_eos: bool = False
+    #: pin the conditioning to this many 77-token chunks: the LAST
+    #: 75 * context_chunks CLIP tokens of prompt + expansion go on, so one
+    #: UNet shape serves every request (None: as long as it comes out)
+    context_chunks: Optional[int] = None
+
+
+def prompt_expansion_args(payload: "GenerationPayload"
+                          ) -> Optional[PromptExpansion]:
+    """The request's prompt-expansion arguments, or None when it has no
+    such script (or the script asks for no token)."""
+    for title, script in (payload.alwayson_scripts or {}).items():
+        if title.strip().lower() != PROMPT_EXPANSION:
+            continue
+        args = (script or {}).get("args") if isinstance(script, dict) \
+            else None
+        first = args[0] if args else {}
+        parsed = PromptExpansion(**(first if isinstance(first, dict) else {}))
+        return parsed if parsed.max_new_tokens > 0 else None
+    return None
+
+
 def fix_seed(seed: Optional[int]) -> int:
     """-1 -> fresh random seed (webui fix_seed semantics; the reference
     records the fixed value before fan-out so every worker agrees on the

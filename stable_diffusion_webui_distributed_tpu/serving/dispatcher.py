@@ -64,6 +64,9 @@ from stable_diffusion_webui_distributed_tpu.obs import (
     watchdog as obs_watchdog,
 )
 from stable_diffusion_webui_distributed_tpu.obs import spans as obs_spans
+from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
+    prompt_expansion_args,
+)
 from stable_diffusion_webui_distributed_tpu.runtime import trace
 from stable_diffusion_webui_distributed_tpu.serving.bucketer import (
     ShapeBucketer, ragged_enabled,
@@ -565,6 +568,12 @@ class ServingDispatcher:
         if self.engine._parse_controlnet_units(p):
             return False
         if self.engine.family.inpaint:
+            return False
+        if getattr(self.engine, "expander", None) is not None \
+                and prompt_expansion_args(p) is not None:
+            # an expanded request runs its own token loop before the
+            # denoise and ends with its own prompt: it never shares a
+            # dispatch, with a plain request or with another expanded one
             return False
         return p.total_images <= self.max_batch
 

@@ -20,7 +20,10 @@ misses and retrieval seconds. ``summary()["xla"]`` carries it.
 
 Which attention the UNet's sites took is counted where they are traced:
 :data:`ATTENTION` (``summary()["attention"]``), fed by
-``models/unet.py:Attention``.
+``models/unet.py:Attention`` (and by ``models/lm.py:Attention`` for the
+resident language model's sites). What that model's ``expand`` stage did
+with tokens, experts and its cache is :data:`EXPANDER`
+(``summary()["expander"]``).
 """
 
 from __future__ import annotations
@@ -217,6 +220,7 @@ class DispatchMetrics:
             }
         out["xla"] = XLA.summary()    # its own lock, never under this one
         out["attention"] = ATTENTION.summary()
+        out["expander"] = EXPANDER.summary()
         return out
 
 
@@ -415,6 +419,72 @@ class AttentionSites:
         out["by_shape"] = by_shape
         return out
 
+
+class ExpanderStats:
+    """What the resident prompt expander (models/lm.py, the engine's
+    ``expand`` stage) did: tokens prefilled, tokens whose cache came from
+    the kept instruction prefix, tokens decoded, how many tokens the router
+    sent to each expert held here (load and its imbalance), tokens none of
+    whose chosen experts is held here, and the cache positions the last
+    sequence occupied by layer kind."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self.clear()
+
+    def clear(self) -> None:
+        with self._lock:
+            self.requests = 0          # guarded-by: _lock
+            self.prefilled = 0         # guarded-by: _lock
+            self.from_prefix = 0       # guarded-by: _lock
+            self.decoded = 0           # guarded-by: _lock
+            self.decode_steps = 0      # guarded-by: _lock
+            self.none_held = 0         # guarded-by: _lock
+            #: per expert layer, tokens sent to each held expert
+            self.load: List[List[int]] = []  # guarded-by: _lock
+            self.positions: Dict[str, int] = {}  # guarded-by: _lock
+
+    def record(self, *, prefilled: int, from_prefix: int, decoded: int,
+               decode_steps: int, load, none_held: int,
+               positions: Dict[str, int]) -> None:
+        """``load`` is (expert layers, held experts) counts of one
+        request; ``decode_steps`` the steps its decode executables ran
+        (whole chunks, so at least ``decoded - 1``)."""
+        rows = [[int(n) for n in row] for row in load]
+        with self._lock:
+            self.requests += 1
+            self.prefilled += int(prefilled)
+            self.from_prefix += int(from_prefix)
+            self.decoded += int(decoded)
+            self.decode_steps += int(decode_steps)
+            self.none_held += int(none_held)
+            if len(self.load) != len(rows):
+                self.load = rows
+            else:
+                self.load = [[a + b for a, b in zip(old, new)]
+                             for old, new in zip(self.load, rows)]
+            self.positions = dict(positions)
+
+    def summary(self) -> Dict[str, Any]:
+        with self._lock:
+            flat = [n for row in self.load for n in row]
+            mean = sum(flat) / len(flat) if flat else 0.0
+            return {
+                "requests": self.requests,
+                "tokens_prefilled": self.prefilled,
+                "tokens_from_prefix_cache": self.from_prefix,
+                "tokens_decoded": self.decoded,
+                "decode_steps": self.decode_steps,
+                "tokens_no_held_expert": self.none_held,
+                "expert_tokens": [list(row) for row in self.load],
+                "expert_load_max_over_mean":
+                    (max(flat) / mean if mean else 0.0),
+                "cache_positions": dict(self.positions),
+            }
+
+
+#: Process-wide prompt-expander counters (``summary()["expander"]``).
+EXPANDER = ExpanderStats()
 
 #: Process-wide count of attention sites by path (fed at trace time).
 ATTENTION = AttentionSites()

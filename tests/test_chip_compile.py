@@ -115,3 +115,47 @@ def test_flash_kernel_compiles_under_highest_matmul_precision(one_chip):
             lambda q, k, v: flash_attention(q, k, v, interpret=False),
             qkv, qkv, qkv)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
+        one_chip, which):
+    """The Laguna share's decode chunk and its 64-token prefill at the
+    published widths (5.57 B parameters as bfloat16 shapes, a 1 024-slot
+    cache): the chip's compiler accepts them, the weights are arguments and
+    not copies (an expert's kernels are sliced in the loop, never gathered
+    whole), and everything fits beside SD1.5."""
+    from stable_diffusion_webui_distributed_tpu.models import configs, lm
+
+    cfg = configs.sd15_laguna_expander().expander
+    module = lm.DecoderLM(cfg, dtype=jnp.bfloat16)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cache = {name: [on_chip(shape, jnp.bfloat16) for shape in rows]
+             for name, rows in lm.cache_shapes(cfg, 1024).items()}
+    small = {name: [jax.ShapeDtypeStruct(shape, jnp.float32)
+                    for shape in rows]
+             for name, rows in lm.cache_shapes(cfg, 8).items()}
+    scalar = on_chip((), jnp.int32)
+    shapes = jax.eval_shape(
+        lambda *a: module.init(jax.random.key(0), *a),
+        jax.ShapeDtypeStruct((4,), jnp.int32), scalar, scalar,
+        small)["params"]
+    params = jax.tree_util.tree_map(
+        lambda x: on_chip(x.shape, jnp.bfloat16), shapes)
+    key = on_chip((), jax.random.key(0).dtype)
+    heat = on_chip((), jnp.float32)
+    if which == "decode":
+        lowered = jax.jit(lm.decode_chunk_fn(module, 32),
+                          donate_argnums=(1,)).lower(
+            params, cache, scalar, scalar, key, heat)
+    else:
+        lowered = jax.jit(lm.prefill_fn(module), donate_argnums=(1,)).lower(
+            params, cache, on_chip((64,), jnp.int32), scalar, scalar, key,
+            heat)
+    memory = lowered.compile().memory_analysis()
+    assert 11.1e9 < memory.argument_size_in_bytes < 11.2e9
+    assert memory.temp_size_in_bytes < 64e6
+    assert memory.alias_size_in_bytes > 14e6      # the cache is donated

@@ -1,0 +1,562 @@
+"""The resident prompt expander: a config-driven decoder LM with window and
+full attention mixed, head counts that differ by layer, a dense layer and
+expert layers in one stack, on the txt2img path.
+
+Everything runs the tiny preset that keeps every kind (models/configs.py
+``TINY_LM``): two head counts, a window of 8 under every context here so the
+ring wraps, a dense first layer, 16 experts top-4 with a shared one, partial
+YaRN and full plain rotary. The plain reference is the benchmark's own
+(benchmarks/reference/laguna_ref.py: float32, no cache, no chunks).
+"""
+
+import dataclasses
+import importlib.util
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from stable_diffusion_webui_distributed_tpu.cache import kv
+from stable_diffusion_webui_distributed_tpu.models import configs, lm
+from stable_diffusion_webui_distributed_tpu.models.tokenizer import (
+    FallbackLMTokenizer, load_lm_tokenizer,
+)
+from stable_diffusion_webui_distributed_tpu.ops import attention, moe
+from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
+from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
+    GenerationPayload, prompt_expansion_args,
+)
+from stable_diffusion_webui_distributed_tpu.runtime import dtypes
+from stable_diffusion_webui_distributed_tpu.runtime.interrupt import (
+    GenerationState,
+)
+from stable_diffusion_webui_distributed_tpu.serving.metrics import (
+    EXPANDER, METRICS,
+)
+from tests.test_pipeline import init_params
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+REF = _load(os.path.join(ROOT, "benchmarks", "reference", "laguna_ref.py"),
+            "laguna_ref_for_tests")
+FAMILY = configs.TINY_EXPAND
+CFG = FAMILY.expander
+
+
+def lm_params(cfg, seed=0):
+    module = lm.DecoderLM(cfg)
+    cache = lm.empty_cache(cfg, 8, jnp.float32)
+    return module.init(jax.random.key(seed), jnp.zeros((4,), jnp.int32),
+                       jnp.int32(0), jnp.int32(4), cache)["params"]
+
+
+@pytest.fixture(scope="module")
+def params():
+    return lm_params(CFG)
+
+
+def rel_rms(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.sqrt(np.mean((got - want) ** 2))
+                 / np.sqrt(np.mean(want ** 2)))
+
+
+class TestAgainstTheReference:
+    @pytest.mark.parametrize("size", [40, 60])
+    def test_prefill_then_cached_decode_matches_the_full_forward(
+            self, params, size):
+        """Prefix prefill, user-chunk prefill against it, then one token a
+        step through both cache kinds; the ring (8 slots) wraps several
+        times. Logits at every position against one plain forward."""
+        (ids,) = REF.inputs(FAMILY, 3, size)
+        got, chose = jax.jit(REF.program(FAMILY, dtypes.F32,
+                                         with_routing=True))(params, ids)
+        want, own = jax.jit(lambda p, i: REF.forward(
+            FAMILY, p, i, with_routing=True))(params, ids)
+        assert got.shape == want.shape == (size, CFG.vocab[1])
+        assert rel_rms(got, want) < 1e-4
+        assert np.array_equal(np.sort(chose, -1), np.sort(own, -1))
+
+    def test_the_int8_control_is_further_from_the_reference(self, params):
+        (ids,) = REF.inputs(FAMILY, 3, 40)
+        want = REF.forward(FAMILY, params, ids)
+        control = jax.jit(REF.program(FAMILY, dtypes.F32, control=True))(
+            params, ids)
+        assert rel_rms(control, want) > 1e-3
+
+    def test_a_padded_chunk_gives_what_the_exact_chunk_gives(self, params):
+        module = lm.DecoderLM(CFG)
+        (ids,) = REF.inputs(FAMILY, 5, 24)
+        run = lambda t, n: module.apply(    # noqa: E731
+            {"params": params}, t, jnp.int32(0), jnp.int32(n),
+            lm.empty_cache(CFG, 32, jnp.float32), all_logits=False)
+        exact, cache_a, _ = run(ids[:19], 19)
+        padded, cache_b, _ = run(ids, 19)
+        np.testing.assert_allclose(exact, padded, rtol=1e-5, atol=1e-5)
+        # decoding on from both caches agrees: the pad rows left no trace
+        nxt = lambda c: module.apply(       # noqa: E731
+            {"params": params}, ids[19:20], jnp.int32(19), jnp.int32(1), c,
+            all_logits=False)[0]
+        np.testing.assert_allclose(nxt(cache_a), nxt(cache_b), rtol=1e-5,
+                                   atol=1e-5)
+
+    def test_reference_held_to_other_routing_differs(self, params):
+        (ids,) = REF.inputs(FAMILY, 3, 16)
+        want, own = REF.forward(FAMILY, params, ids, with_routing=True)
+        same = REF.forward(FAMILY, params, ids, forced=own)
+        np.testing.assert_allclose(same, want, rtol=1e-5, atol=1e-5)
+        other = REF.forward(FAMILY, params, ids,
+                            forced=(own + 1) % CFG.num_experts)
+        assert rel_rms(other, want) > 1e-3
+
+
+class TestRope:
+    def test_program_and_reference_frequencies_agree(self):
+        for rope in (CFG.rope_full, CFG.rope_sliding,
+                     configs.LAGUNA_S_2_1.rope_full,
+                     configs.LAGUNA_S_2_1.rope_sliding):
+            dim = 128 if rope.theta == 5e5 and rope.factor == 128 else 16
+            np.testing.assert_allclose(lm.rope_frequencies(rope, dim),
+                                       REF._inv_freq(rope, dim), rtol=1e-12)
+
+    def test_yarn_interpolates_slow_pairs_and_keeps_fast_ones(self):
+        rope = configs.LAGUNA_S_2_1.rope_full
+        freq = lm.rope_frequencies(rope, 128)
+        plain = 1.0 / rope.theta ** (np.arange(0, 64, 2) / 64)
+        assert freq.shape == (32,)       # half of 128 dims are rotated
+        np.testing.assert_allclose(freq[0], plain[0])
+        np.testing.assert_allclose(freq[-1], plain[-1] / rope.factor)
+
+    def test_partial_rotary_leaves_the_rest_alone(self):
+        x = jax.random.normal(jax.random.key(0), (5, 3, 16))
+        cos, sin = lm.rope_tables(CFG.rope_full, 16, jnp.arange(5))
+        out = lm.apply_rope(x, cos, sin)
+        assert cos.shape == (5, 4)
+        np.testing.assert_array_equal(out[..., 8:], x[..., 8:])
+        assert not np.allclose(out[1:, :, :8], x[1:, :, :8])
+
+
+class TestMasks:
+    def test_causal_and_window(self):
+        t, kv_heads, heads, dim = 12, 2, 6, 8
+        key = jax.random.key(1)
+        q = jax.random.normal(key, (t, heads, dim))
+        k = jax.random.normal(jax.random.fold_in(key, 1), (t, kv_heads, dim))
+        v = jax.random.normal(jax.random.fold_in(key, 2), (t, kv_heads, dim))
+        pos = jnp.arange(t)
+        for window in (0, 5):
+            out, path = attention.attend_positions(
+                q, k, v, pos, pos, scale=dim ** -0.5, window=window)
+            assert path == attention.XLA
+            of = np.arange(heads) * kv_heads // heads
+            scores = np.einsum("ihd,jhd->hij", q, np.asarray(k)[:, of]) \
+                * dim ** -0.5
+            i, j = np.arange(t)[:, None], np.arange(t)[None, :]
+            seen = (j <= i) & ((i - j < window) if window else True)
+            scores = np.where(seen[None], scores, -np.inf)
+            probs = np.exp(scores - scores.max(-1, keepdims=True))
+            probs /= probs.sum(-1, keepdims=True)
+            want = np.einsum("hij,jhd->ihd", probs, np.asarray(v)[:, of])
+            np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-5)
+
+    def test_empty_slots_and_unseeing_rows(self):
+        q = jnp.ones((2, 2, 4))
+        k = v = jnp.ones((3, 1, 4))
+        out, _ = attention.attend_positions(
+            q, k, v, jnp.array([0, 5]), jnp.array([-1, 3, 9]), scale=1.0)
+        np.testing.assert_array_equal(out[0], 0.0)    # sees nothing
+        np.testing.assert_allclose(out[1], 1.0)       # sees position 3 only
+
+    @pytest.mark.parametrize("site", [
+        ("tpu", 4096, 4096, jnp.bfloat16, True),
+        ("tpu", 1024, 1024, jnp.bfloat16, True),
+        ("tpu", 256, 256, jnp.bfloat16, True),
+        ("tpu", 4096, 77, jnp.bfloat16, False),
+        ("tpu", 4096, 4096, jnp.float32, True),
+        ("cpu", 4096, 4096, jnp.bfloat16, True),
+    ])
+    def test_the_unet_sites_choose_as_before(self, site):
+        platform, t, s, dtype, self_attention = site
+        want = (attention.TILED if platform == "tpu" and self_attention
+                and dtype == jnp.bfloat16 and t >= 1024 else attention.XLA)
+        assert attention.choose(platform, t, s, dtype,
+                                self_attention=self_attention) == want
+        for extra in ({"masked": True}, {"kv_groups": 6}):
+            assert attention.choose(platform, t, s, dtype,
+                                    self_attention=self_attention,
+                                    **extra) == attention.XLA
+
+
+class TestExperts:
+    def _layer(self, tokens, seed=0):
+        key = jax.random.key(seed)
+        d, f, experts = 32, 16, 16
+        ks = jax.random.split(key, 5)
+        x = jax.random.normal(ks[0], (tokens, d))
+        logits = jax.random.normal(ks[1], (tokens, experts))
+        wg = jax.random.normal(ks[2], (experts, d, f)) / d ** 0.5
+        wu = jax.random.normal(ks[3], (experts, d, f)) / d ** 0.5
+        wd = jax.random.normal(ks[4], (experts, f, d)) / f ** 0.5
+        return x, moe.route(logits, 4, renormalise=True, scale=2.5), \
+            wg, wu, wd
+
+    def _dense(self, x, routing, wg, wu, wd):
+        out = np.zeros(x.shape, np.float64)
+        for t in range(x.shape[0]):
+            for e, w in zip(np.asarray(routing.experts[t]),
+                            np.asarray(routing.weights[t])):
+                h = jax.nn.silu(x[t] @ wg[e]) * (x[t] @ wu[e])
+                out[t] += w * np.asarray(h @ wd[e])
+        return out
+
+    def test_route_is_topk_of_a_softmax_renormalised_and_scaled(self):
+        _, routing, *_ = self._layer(7)
+        np.testing.assert_allclose(routing.weights.sum(-1), 2.5, rtol=1e-5)
+        assert routing.experts.shape == (7, 4)
+        assert np.all(np.diff(np.asarray(routing.weights), axis=-1) <= 0)
+
+    @pytest.mark.parametrize("tokens", [1, 5, 40])
+    def test_both_products_equal_the_dense_sum(self, tokens):
+        x, routing, wg, wu, wd = self._layer(tokens)
+        got = jax.jit(lambda *a: moe.routed_experts(
+            *a, first=0, num_experts=16))(x, routing, wg, wu, wd)
+        np.testing.assert_allclose(got, self._dense(x, routing, wg, wu, wd),
+                                   rtol=2e-4, atol=2e-5)
+
+    @pytest.mark.parametrize("tokens", [1, 24])
+    def test_the_share(self, tokens):
+        """The two halves' routed parts add up to the uncut layer's: a chip
+        computes its own experts and adds nothing for the absent ones."""
+        x, routing, wg, wu, wd = self._layer(tokens, seed=2)
+        whole = self._dense(x, routing, wg, wu, wd)
+        parts = [moe.routed_experts(x, routing, wg[lo:lo + 8],
+                                    wu[lo:lo + 8], wd[lo:lo + 8], first=lo,
+                                    num_experts=16) for lo in (0, 8)]
+        assert not np.allclose(parts[0], whole, atol=1e-3)
+        np.testing.assert_allclose(parts[0] + parts[1], whole, rtol=2e-4,
+                                   atol=2e-5)
+
+    def test_load_counts(self):
+        _, routing, *_ = self._layer(24, seed=2)
+        valid = jnp.arange(24) < 20
+        load, none = moe.load_counts(routing, 8, 8, valid)
+        chosen = np.asarray(routing.experts)[:20]
+        assert list(load) == [int((chosen == e).sum()) for e in range(8, 16)]
+        assert int(none) == int((~((chosen >= 8).any(-1))).sum())
+
+    def test_row_tile_is_a_power_of_two_near_the_mean_rows(self):
+        assert moe.row_tile(576, 10, 256) == 32
+        assert moe.row_tile(64, 10, 256) == 8
+        assert moe.row_tile(100000, 10, 256) == 256
+
+
+class TestTheShareOfALayer:
+    def test_two_halves_and_the_shared_expert_once_equal_the_uncut_layer(
+            self):
+        """The reference's uncut expert layer against the sum of the two
+        chips' layers: each chip's routed part, the shared expert counted
+        once, the residual once."""
+        whole = dataclasses.replace(CFG, experts_held=None, vocab_held=None)
+        p = lm_params(whole, seed=4)["layers_1"]
+        x = jax.random.normal(jax.random.key(9), (20, whole.hidden_size))
+        want, _ = REF.layer_forward(whole, 1, x, p)
+        h = x + REF._attention(whole, 1, REF._rms(
+            x, p["input_norm"]["scale"], whole.rms_norm_eps), p["attn"])
+        n = REF._rms(h, p["post_attention_norm"]["scale"],
+                     whole.rms_norm_eps)
+        total = h + REF._swiglu(n, p["mlp"]["shared_expert"])
+        for rank in (0, 1):
+            share = configs.lm_share(whole, whole.num_layers, 2, rank)
+            lo, count = share.experts
+            mlp = dict(p["mlp"], experts={
+                k: w[lo:lo + count] for k, w in p["mlp"]["experts"].items()})
+            out, _ = lm.MoE(share, jnp.float32).apply(
+                {"params": mlp}, n, jnp.ones(20, bool))
+            total = total + out - REF._swiglu(n, p["mlp"]["shared_expert"])
+        np.testing.assert_allclose(total, want, rtol=2e-4, atol=2e-5)
+
+    def test_lm_share_cuts_layers_experts_and_vocabulary(self):
+        share = configs.sd15_laguna_expander().expander
+        assert share.num_layers == 5
+        assert share.layer_types == ("full", "sliding", "sliding",
+                                     "sliding", "full")
+        assert share.num_heads_per_layer == (48, 72, 72, 72, 48)
+        assert share.experts == (0, 128) and share.vocab == (0, 50176)
+        assert share.num_experts == 256 and share.num_experts_per_tok == 10
+        other = configs.lm_share(configs.LAGUNA_S_2_1, 5, 2, 1)
+        assert other.experts == (128, 128) and other.vocab == (50176, 50176)
+
+
+class TestSampling:
+    def test_a_draw_is_keyed_by_seed_and_position(self):
+        logits = jax.random.normal(jax.random.key(0), (256,))
+        key = jax.random.key(7)
+        a = lm.sample(logits, key, 5, jnp.float32(1.0), 256)
+        assert int(a) == int(lm.sample(logits, key, 5, jnp.float32(1.0),
+                                       256))
+        draws = {int(lm.sample(logits, key, p, jnp.float32(1.0), 256))
+                 for p in range(40)}
+        assert len(draws) > 10 and min(draws) >= 256
+        assert int(lm.sample(logits, key, 5, jnp.float32(0.0))) \
+            == int(jnp.argmax(logits))
+
+    def test_chunked_decode_equals_one_step_at_a_time(self, params):
+        module = lm.DecoderLM(CFG)
+        key = jax.random.key(11)
+        first = jnp.int32(CFG.vocab[0] + 3)
+
+        def run(steps, calls):
+            fn = jax.jit(lm.decode_chunk_fn(module, steps))
+            cache = lm.empty_cache(CFG, 64, jnp.float32)
+            token, position, made = first, jnp.int32(0), []
+            for _ in range(calls):
+                cache, token, position, out, _, _ = fn(
+                    params, cache, token, position, key, jnp.float32(1.0))
+                made += np.asarray(out).tolist()
+            return made
+
+        assert run(12, 1) == run(4, 3) == run(1, 12)
+
+
+class TestTokenizerAndCache:
+    def test_the_fallback_hashes_into_the_held_slice_and_back(self):
+        tok = load_lm_tokenizer(None, 512, 256)
+        assert isinstance(tok, FallbackLMTokenizer)
+        ids = tok.encode("a herd of cows, grazing")
+        assert ids == tok.encode("a herd of cows, grazing")
+        assert all(514 <= i < 768 for i in ids)
+        assert tok.decode([tok.bos] + ids + [tok.eos]) \
+            == " ".join(f"w{i}" for i in ids)
+
+    def test_vocabulary_files_are_used_when_a_directory_has_them(
+            self, tmp_path):
+        vocab = {}
+        for ch in "abcdefghijklmnopqrstuvwxyz":
+            vocab[ch] = len(vocab)
+            vocab[ch + "</w>"] = len(vocab)
+        for piece in ("co", "cow</w>", "re", "red</w>"):
+            vocab[piece] = len(vocab)
+        vocab["<|startoftext|>"] = len(vocab)
+        vocab["<|endoftext|>"] = len(vocab)
+        merges = [("c", "o"), ("co", "w</w>"), ("r", "e"), ("re", "d</w>")]
+        (tmp_path / "vocab.json").write_text(json.dumps(vocab))
+        (tmp_path / "merges.txt").write_text(
+            "\n".join(f"{a} {b}" for a, b in merges))
+        tok = load_lm_tokenizer(str(tmp_path), 0, len(vocab))
+        ids = tok.encode("red cow")
+        assert ids == [vocab["red</w>"], vocab["cow</w>"]]
+        assert tok.decode([tok.bos] + ids + [tok.eos]) == "red cow"
+        assert isinstance(load_lm_tokenizer(str(tmp_path / "none"), 0, 64),
+                          FallbackLMTokenizer)
+
+    def test_buckets(self):
+        assert [kv.chunk_bucket(n) for n in (1, 16, 64, 65, 512)] \
+            == [64, 64, 64, 128, 512]
+        assert kv.capacity_for(960) == 1024 and kv.capacity_for(256) == 256
+
+    def test_a_kept_prefix_is_handed_out_as_a_copy(self):
+        manager = kv.KVCacheManager(CFG, jnp.float32)
+        cache, held = manager.acquire([1, 2, 3], 256)
+        assert held == 0 and [k.shape for k in cache["k"]] == [
+            (256, 2, 16), (8, 2, 16), (8, 2, 16), (256, 2, 16)]
+        manager.keep_prefix([1, 2, 3], 256,
+                            jax.tree_util.tree_map(lambda x: x + 1, cache))
+        again, held = manager.acquire([1, 2, 3], 256)
+        assert held == 3 and float(again["v"][1][0, 0, 0]) == 1.0
+        assert manager.acquire([1, 2], 256)[1] == 0
+        assert (manager.prefix_hits, manager.prefix_misses) == (1, 2)
+        assert manager.positions_in_use(40) == {"full": 80, "sliding": 16}
+
+
+INSTRUCTION = " ".join(f"rule{i}" for i in range(30))
+
+
+def script(**args):
+    return {"prompt expansion": {"args": [dict(
+        {"instruction": INSTRUCTION, "max_new_tokens": 40,
+         "temperature": 1.0, "ignore_eos": True, "context_chunks": 1},
+        **args)]}}
+
+
+@pytest.fixture(scope="module")
+def engine():
+    params = init_params(configs.TINY)
+    params["expander"] = lm_params(CFG, seed=1)
+    return Engine(FAMILY, params, chunk_size=4, state=GenerationState())
+
+
+def payload(**kw):
+    base = dict(prompt="a cow in a valley", steps=4, width=32, height=32,
+                seed=1234, alwayson_scripts=script())
+    base.update(kw)
+    return GenerationPayload(**base)
+
+
+class TestEnginePath:
+    def test_the_script_is_parsed_from_alwayson_scripts(self):
+        args = prompt_expansion_args(payload())
+        assert args.max_new_tokens == 40 and args.context_chunks == 1
+        assert prompt_expansion_args(payload(alwayson_scripts={})) is None
+        off = payload(alwayson_scripts=script(max_new_tokens=0))
+        assert prompt_expansion_args(off) is None
+        assert prompt_expansion_args(payload(alwayson_scripts={
+            "Prompt Expansion": {"args": []}})).max_new_tokens == 64
+
+    def test_expanded_txt2img_repeats_and_differs_from_plain(self, engine):
+        EXPANDER.clear()
+        a = engine.txt2img(payload())
+        b = engine.txt2img(payload())
+        plain = engine.txt2img(payload(alwayson_scripts={}))
+        assert a.images == b.images and a.prompts == b.prompts
+        assert a.images != plain.images
+        assert plain.prompts == ["a cow in a valley"]
+        words = a.prompts[0].split()
+        assert len(words) == 45 and words[:5] == "a cow in a valley".split()
+        assert all(w.startswith("w") for w in words[5:])
+        assert a.prompts[0] in a.infotexts[0]
+        stats = EXPANDER.summary()
+        # the second request found the instruction's cache
+        assert stats["requests"] == 2
+        assert stats["tokens_prefilled"] == 31 + 5 + 5
+        assert stats["tokens_from_prefix_cache"] == 31
+        assert stats["tokens_decoded"] == 80
+        assert stats["cache_positions"] == {"full": 2 * 76, "sliding": 16}
+        routed = sum(map(sum, stats["expert_tokens"]))
+        assert len(stats["expert_tokens"]) == 3
+        assert 0 < routed <= 3 * 4 * (31 + 5 + 64 + 5 + 64)
+        assert stats["expert_load_max_over_mean"] >= 1.0
+
+    def test_another_seed_gets_another_expansion(self, engine):
+        a = engine.txt2img(payload())
+        b = engine.txt2img(payload(seed=99))
+        assert a.prompts != b.prompts
+
+    def test_a_batch_expands_each_image_by_its_own_seed(self, engine):
+        both = engine.txt2img(payload(batch_size=2))
+        second = engine.generate_range(payload(batch_size=2), 1, 1)
+        assert both.prompts[0] != both.prompts[1]
+        assert second.prompts == both.prompts[1:]
+        assert second.images == both.images[1:]
+        solo = engine.txt2img(payload(seed=1235))
+        assert solo.prompts[0] == both.prompts[1]
+
+    def test_context_chunks_keeps_the_tail(self, engine):
+        long = engine.txt2img(payload(alwayson_scripts=script(
+            max_new_tokens=100)))
+        words = long.prompts[0].split()
+        assert len(words) == 75 and "cow" not in words
+
+    def test_eos_ends_the_expansion(self, engine, monkeypatch):
+        full = engine.txt2img(payload()).prompts[0].split()[5:]
+        eos = int(full[9][1:])
+        monkeypatch.setattr(engine.expander.tokenizer, "eos", eos)
+        cut = engine.txt2img(payload(alwayson_scripts=script(
+            ignore_eos=False))).prompts[0].split()[5:]
+        assert cut == full[:full.index(f"w{eos}")]
+
+    def test_the_stage_builds_its_executables_through_the_engines_cache(
+            self, engine):
+        engine.txt2img(payload())
+        kinds = {k[0] for k in engine.executable_keys()}
+        assert {"expand_prefill", "expand_decode_chunk"} <= kinds
+        before = dict(METRICS.summary()["compiles"])
+        engine.txt2img(payload(prompt="another prompt of five"))
+        assert METRICS.summary()["compiles"] == before
+
+    def test_spans(self, engine):
+        from stable_diffusion_webui_distributed_tpu.obs import spans
+
+        spans.TRACER.clear()
+        with spans.request("rid-expand"):
+            engine.txt2img(payload())
+        events = [e for e in spans.TRACER.export_chrome()["traceEvents"]
+                  if e.get("ph") == "X"]
+        names = [e["name"] for e in events]
+        for name in ("expand", "expand.tokenize", "expand.prefill",
+                     "expand.decode_chunk", "expand.fence_wait",
+                     "expand.detokenize", "prepare"):
+            assert name in names, name
+        by_id = {e["args"]["span_id"]: e for e in events}
+        for e in events:
+            if e["name"].startswith("expand."):
+                assert by_id[e["args"]["parent_id"]]["name"] == "expand"
+        prefill = next(e for e in events if e["name"] == "expand.prefill")
+        assert prefill["args"]["tokens"] == 5
+        assert prefill["args"]["prefix_hit"] is True
+
+    def test_a_family_without_an_expander_is_untouched(self):
+        plain = Engine(configs.TINY, init_params(configs.TINY),
+                       chunk_size=4, state=GenerationState())
+        assert plain.expander is None
+        with_script = plain.txt2img(payload())
+        without = plain.txt2img(payload(alwayson_scripts={}))
+        assert with_script.images == without.images
+        assert with_script.prompts == ["a cow in a valley"]
+        kinds = {k[0] for k in plain.executable_keys()}
+        assert not any(k.startswith("expand") for k in kinds)
+
+    def test_status_block(self, engine):
+        engine.txt2img(payload())
+        block = METRICS.summary()["expander"]
+        assert set(block) == {
+            "requests", "tokens_prefilled", "tokens_from_prefix_cache",
+            "tokens_decoded", "decode_steps", "tokens_no_held_expert",
+            "expert_tokens",
+            "expert_load_max_over_mean", "cache_positions"}
+        json.dumps(block)
+
+
+class TestDispatcher:
+    def test_an_expanded_request_never_shares_a_dispatch(self, engine):
+        from stable_diffusion_webui_distributed_tpu.serving.dispatcher import (
+            ServingDispatcher,
+        )
+
+        class Stub:
+            max_batch = 4
+
+        stub = Stub()
+        stub.engine = engine
+        stub._traced_rowspec = lambda p: (0, 0)
+        assert ServingDispatcher._coalescable(
+            stub, payload(alwayson_scripts={}))
+        assert not ServingDispatcher._coalescable(stub, payload())
+        stub.engine = Engine(configs.TINY, init_params(configs.TINY),
+                             state=GenerationState())
+        assert ServingDispatcher._coalescable(stub, payload())
+
+
+class TestSharding:
+    def test_expert_and_vocabulary_axes(self):
+        from jax.sharding import PartitionSpec as P
+
+        from stable_diffusion_webui_distributed_tpu.parallel.sharding import (
+            shard_params, tp_spec_for,
+        )
+
+        assert tp_spec_for("layers_1/mlp/experts/w_gate", 3) \
+            == P("ep", None, None)
+        assert tp_spec_for("embed_tokens/embedding", 2) == P("vp", None)
+        assert tp_spec_for("lm_head/kernel", 2) == P(None, "vp")
+        devices = np.array(jax.devices()[:4]).reshape(2, 2)
+        mesh = jax.sharding.Mesh(devices, ("ep", "vp"))
+        placed = shard_params(lm_params(CFG), mesh)
+        gate = placed["layers_1"]["mlp"]["experts"]["w_gate"]
+        assert gate.sharding.spec == P("ep", None, None)
+        assert placed["lm_head"]["kernel"].sharding.spec == P(None, "vp")
+        assert placed["embed_tokens"]["embedding"].sharding.spec \
+            == P("vp", None)
+        # no tp axis on this mesh: a Megatron leaf stays whole
+        assert placed["layers_0"]["attn"]["q_proj"]["kernel"].sharding.spec \
+            == P()

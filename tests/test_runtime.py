@@ -309,3 +309,43 @@ class TestCompileCachePlacement:
         assert mesh.enable_compilation_cache() \
             == mesh.enable_compilation_cache() \
             == mesh.DEFAULT_COMPILE_CACHE
+
+
+def test_importing_the_package_keeps_freed_host_memory_mapped():
+    """runtime/malloc.py: after the import a 4 MiB block comes from an
+    arena's heap, not from an mmap of its own (glibc's default would map
+    and unmap it, and a request's buffers would fault in again every
+    time)."""
+    import subprocess
+    import sys
+
+    script = """
+import ctypes, sys
+libc = ctypes.CDLL(None)
+libc.malloc.restype = ctypes.c_void_p
+libc.free.argtypes = [ctypes.c_void_p]
+class MallInfo(ctypes.Structure):
+    _fields_ = [(n, ctypes.c_int) for n in (
+        "arena", "ordblks", "smblks", "hblks", "hblkhd", "usmblks",
+        "fsmblks", "uordblks", "fordblks", "keepcost")]
+libc.mallinfo.restype = MallInfo
+def mapped_blocks_while_holding_4_mib():
+    before = libc.mallinfo().hblks
+    block = libc.malloc(4 * 2 ** 20)
+    held = libc.mallinfo().hblks - before
+    libc.free(block)
+    return held
+if sys.argv[1] == "with":
+    import stable_diffusion_webui_distributed_tpu  # noqa: F401
+    from stable_diffusion_webui_distributed_tpu.runtime.malloc import (
+        retain_freed_memory)
+    assert retain_freed_memory()
+print(mapped_blocks_while_holding_4_mib())
+"""
+    out = {}
+    for which in ("without", "with"):
+        proc = subprocess.run([sys.executable, "-c", script, which],
+                              capture_output=True, text=True, timeout=300)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        out[which] = proc.stdout.strip().splitlines()[-1]
+    assert out == {"without": "1", "with": "0"}
