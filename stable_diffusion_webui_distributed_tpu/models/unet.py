@@ -47,19 +47,74 @@ def timestep_embedding(t: jax.Array, dim: int, max_period: float = 10000.0) -> j
     return jnp.concatenate([jnp.cos(args), jnp.sin(args)], axis=-1)
 
 
+#: GroupNorm32 pins its input (below) from this many spatial positions a row
+#: on: SDXL's 128x128 level. Measured on a v5e (PERF.md section 6, PR 29):
+#: there the pin takes 306 ms off a request; at 64x64 and 32x32 the float32
+#: copies cost little and a pinned input makes XLA fuse the normalise into a
+#: plain convolution that runs at a third of the spatial-major one's speed.
+PIN_MIN_POSITIONS = 128 * 128
+
+
+class _ChannelAffine(nn.Module):
+    """``scale`` and ``bias`` per channel, float32, ones and zeros."""
+
+    @nn.compact
+    def __call__(self, channels: int) -> Tuple[jax.Array, jax.Array]:
+        scale = self.param("scale", nn.initializers.ones, (channels,),
+                           jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros, (channels,),
+                          jnp.float32)
+        return scale, bias
+
+
 class GroupNorm32(nn.Module):
-    """GroupNorm with f32 statistics regardless of activation dtype."""
+    """GroupNorm with f32 statistics regardless of activation dtype.
+
+    Per-channel sums over the activation as it lies (the cast to float32
+    lives inside the reduction), folded into groups on the ``(B, C)``
+    vectors, then one elementwise pass ``x * a + b`` in float32 that writes
+    the storage dtype; the activation is never viewed by groups.
+
+    A narrower-than-float32 activation of ``PIN_MIN_POSITIONS`` or more
+    positions a row is pinned by an optimisation barrier first. Without it
+    the TPU compiler hoists the cast into the producing convolution's
+    epilogue, in that convolution's spatial-major shape with the batch
+    folded into a block index, and then moves float32 copies of the
+    activation, of its square and of the broadcast ``a`` and ``b`` through
+    HBM (PERF.md section 6, PR 29: 2.9 GB of float32 copies an SDXL step).
+    A float32 activation (the VAE decoder) has no cast to pin, and a barrier
+    there forces float32 tensors XLA otherwise keeps in bf16.
+
+    Parameters sit at ``gn/scale`` and ``gn/bias``, where
+    ``flax.linen.GroupNorm`` under that name kept them.
+    """
 
     num_groups: int = 32
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        orig = x.dtype
-        groups = min(self.num_groups, x.shape[-1])
-        y = nn.GroupNorm(num_groups=groups, dtype=jnp.float32, name="gn")(
-            x.astype(jnp.float32)
-        )
-        return y.astype(orig)
+        batch, channels = x.shape[0], x.shape[-1]
+        groups = min(self.num_groups, channels)
+        per_group = channels // groups
+        positions = x.size // (batch * channels)
+        scale, bias = _ChannelAffine(name="gn")(channels)
+        if x.dtype != jnp.float32 and positions >= PIN_MIN_POSITIONS:
+            x = jax.lax.optimization_barrier(x)
+        spatial = tuple(range(1, x.ndim - 1))
+        x32 = x.astype(jnp.float32)
+
+        def group_mean(per_channel_sum: jax.Array) -> jax.Array:
+            grouped = per_channel_sum.reshape(batch, groups, per_group).sum(-1)
+            return jnp.repeat(grouped / (positions * per_group), per_group,
+                              axis=-1)
+
+        mean = group_mean(x32.sum(spatial))
+        var = jnp.maximum(0.0, group_mean(jnp.square(x32).sum(spatial))
+                          - jnp.square(mean))
+        a = scale * jax.lax.rsqrt(var + 1e-6)  # flax GroupNorm's epsilon
+        b = bias - mean * a
+        a, b = (jnp.expand_dims(v, spatial) for v in (a, b))
+        return (x32 * a + b).astype(x.dtype)
 
 
 class ResBlock(nn.Module):

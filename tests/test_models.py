@@ -6,6 +6,9 @@ the converter's key numbering or any transpose is wrong, the converted tree
 will not match the Flax-initialized tree and the forward pass fails.
 """
 
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -17,7 +20,9 @@ from stable_diffusion_webui_distributed_tpu.models.configs import (
 )
 from stable_diffusion_webui_distributed_tpu.models import convert
 from stable_diffusion_webui_distributed_tpu.models.clip import CLIPTextModel
-from stable_diffusion_webui_distributed_tpu.models.unet import UNet, make_added_cond
+from stable_diffusion_webui_distributed_tpu.models.unet import (
+    GroupNorm32, UNet, make_added_cond,
+)
 from stable_diffusion_webui_distributed_tpu.models.vae import VAE
 from stable_diffusion_webui_distributed_tpu.models.tokenizer import (
     CLIPTokenizer, FallbackTokenizer,
@@ -362,6 +367,109 @@ class TestVAEConversion:
                                    method=VAE.encode)
         dec = model.apply({"params": converted}, mean, method=VAE.decode)
         assert dec.shape == (1, 16, 16, 3)
+
+
+class TestGroupNorm32:
+    """models/unet.py:GroupNorm32 never views the activation by groups; it
+    must still be flax's GroupNorm at float32, on the same parameters."""
+
+    @pytest.mark.parametrize("batch", [1, 2])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("channels", [8, 320, 960, 1920])
+    @pytest.mark.parametrize("mean_over_std", [0.0, 10.0])
+    def test_matches_flax_group_norm(self, mean_over_std, channels, dtype,
+                                     batch):
+        """Against ``flax.linen.GroupNorm(dtype=float32)`` on the same
+        parameters, both held to a float64 GroupNorm: flax's float32
+        E[x^2] - E[x]^2 is itself 1.2e-5 from it on centred input and
+        1e-4..2e-3 with the mean at 10 x std, so "agrees with flax" is
+        1e-5 (3e-4 off-centre) plus flax's own distance, one bf16 ulp
+        more in bf16, and this module may not be the farther of the two."""
+        import flax.linen as nn
+
+        rng = np.random.default_rng(channels + batch)
+        x = jnp.asarray(1.3 * (rng.standard_normal((batch, 16, 16, channels))
+                               + mean_over_std), dtype)
+        scale = 1.0 + 0.3 * rng.standard_normal(channels)
+        bias = rng.standard_normal(channels)
+        gn = {"scale": jnp.asarray(scale, jnp.float32),
+              "bias": jnp.asarray(bias, jnp.float32)}
+        groups = min(32, channels)
+        got = GroupNorm32().apply({"params": {"gn": gn}}, x)
+        assert got.dtype == dtype and got.shape == x.shape
+        flax = nn.GroupNorm(num_groups=groups, dtype=jnp.float32).apply(
+            {"params": gn}, x.astype(jnp.float32))
+        x64 = np.asarray(x, np.float64).reshape(batch, -1, groups,
+                                                channels // groups)
+        exact = (x64 - x64.mean((1, 3), keepdims=True)) / np.sqrt(
+            x64.var((1, 3), keepdims=True) + 1e-6)
+        exact = exact.reshape(x.shape) * scale + bias
+        flax_off = np.abs(np.asarray(flax, np.float64) - exact).max()
+        flax = np.asarray(flax.astype(dtype), np.float64)
+        got = np.asarray(got, np.float64)
+        limit = 3e-4 if mean_over_std else 1e-5
+        if dtype == jnp.bfloat16:
+            # one unit in the last of bf16's 8 significant bits
+            ulp = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(got), 1e-30)))
+                          - 7)
+            limit = ulp + limit
+        else:
+            assert np.abs(got - exact).max() <= 1.1 * flax_off + 1e-6
+        assert (np.abs(got - exact) <= limit).all()
+        assert (np.abs(got - flax) <= limit + flax_off).all()
+
+    def test_parameter_trees_are_the_parents(self):
+        """Paths, shapes, dtypes and order of UNet(TINY_XL) and VAE as
+        written out at the commit before GroupNorm32 left flax's module
+        (tests/param_trees.json): convert.py, the sharding rules, LoRA and
+        the benchmark's weight fill read these paths."""
+        from flax.traverse_util import flatten_dict
+
+        from test_pipeline import init_params
+
+        with open(os.path.join(os.path.dirname(__file__),
+                               "param_trees.json")) as fh:
+            want = json.load(fh)
+        params = jax.eval_shape(lambda: init_params(TINY_XL))
+        for name in ("unet", "vae"):
+            got = [["/".join(path), list(leaf.shape), leaf.dtype.name]
+                   for path, leaf in flatten_dict(params[name]).items()]
+            assert got == want[name], name
+
+    def test_no_group_shaped_view_of_the_activation(self):
+        shape = (2, 16, 16, 960)
+        x = jnp.zeros(shape, jnp.bfloat16)
+        module = GroupNorm32()
+        params = module.init(jax.random.key(0), x)
+        closed = jax.make_jaxpr(module.apply)(params, x)
+
+        def outvars(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield from eqn.outvars
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from outvars(sub)
+
+        sized = [v.aval.shape for v in outvars(closed.jaxpr)
+                 if v.aval.size == x.size]
+        assert sized and set(sized) == {shape}, set(sized)
+        (out,) = closed.out_avals
+        assert out.dtype == jnp.bfloat16 and out.shape == shape
+
+    @pytest.mark.parametrize("side,dtype,pinned", [
+        (128, jnp.bfloat16, True), (64, jnp.bfloat16, False),
+        (128, jnp.float32, False)])
+    def test_pins_only_a_narrow_activation_of_the_large_level(
+            self, side, dtype, pinned):
+        """The optimisation barrier goes with shape and dtype alone (no
+        knob): bf16 at 128 x 128 positions and over, where the TPU compiler
+        otherwise copies float32 activations through HBM; never a float32
+        activation (the VAE decoder), never the small levels."""
+        x = jnp.zeros((1, side, side, 8), dtype)
+        module = GroupNorm32()
+        params = jax.eval_shape(module.init, jax.random.key(0), x)
+        closed = jax.make_jaxpr(module.apply)(params, x)
+        names = [eqn.primitive.name for eqn in closed.jaxpr.eqns]
+        assert ("optimization_barrier" in names) == pinned
 
 
 class TestTokenizer:

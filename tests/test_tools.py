@@ -884,3 +884,48 @@ class TestAotReport:
         # the report flags it so an operator sees hydration will miss
         assert report["ok"]
         assert report["cells"][0]["fingerprint_match"] is False
+
+
+class TestChunkHlo:
+    """tools/chunk_hlo.py's reading of optimised HLO text (the compile for a
+    described chip takes minutes and is not run here)."""
+
+    HLO = """HloModule jit_run_chunk
+
+%fused_computation.1 (p: bf16[2,8]) -> bf16[2,8] {
+  ROOT %p = bf16[2,8]{1,0} parameter(0)
+}
+
+%body (arg: (f32[2])) -> (f32[2]) {
+  %x = bf16[128,16,17,960]{3,1,2,0:T(8,128)(2,1)} parameter(0)
+  %copy.1 = f32[128,16,17,960]{0,3,2,1:T(8,128)} copy(%x), metadata={op_name="jit(run_chunk)/while/body/closed_call/UNet/up_0_res_0/norm1/convert_element_type"}
+  %broadcast.2 = f32[128,2,136,960]{0,3,2,1:T(8,128)} broadcast(%x), dimensions={1,3}, metadata={op_name="jit(run_chunk)/while/body/closed_call/UNet/up_0_res_0/norm1/mul"}
+  %bitcast.3 = f32[128,16,17,960]{0,3,2,1:T(8,128)} bitcast(%broadcast.2)
+  %fusion.4 = bf16[128,16,17,320]{3,1,2,0:T(8,128)(2,1)} fusion(%x), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(run_chunk)/while/body/closed_call/UNet/up_0_res_0/conv1/conv_general_dilated"}
+  %fusion.5 = bf16[2,32,32,1280]{3,0,2,1:T(2,128)(2,1)} fusion(%x), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(run_chunk)/while/body/closed_call/UNet/up_2_res_0/conv2/conv_general_dilated"}
+  %small.6 = f32[2,960]{1,0} fusion(%x), kind=kLoop, calls=%fused_computation.1
+  ROOT %t = (f32[2]) tuple(%small.6)
+}
+
+ENTRY %main (a: f32[2]) -> f32[2] {
+  ROOT %a = f32[2]{0} parameter(0)
+}
+"""
+
+    def test_sums_the_body_by_op_and_dtype_and_names_plain_convolutions(self):
+        import chunk_hlo
+
+        out = chunk_hlo.summarise(self.HLO)
+        each = 128 * 16 * 17 * 960 * 4 / 1e6
+        assert out["outputs"][:2] == [
+            ["copy", "f32", 1, round(each, 1)],
+            ["broadcast", "f32", 1, round(each, 1)]]
+        assert out["outputs"][2] == [
+            "fusion:Output", "bf16", 2,
+            round((128 * 16 * 17 * 320 + 2 * 32 * 32 * 1280) * 2 / 1e6, 1)]
+        assert not any(op == "bitcast" for op, *_ in out["outputs"])
+        assert out["plain_convolutions"] == [
+            ["UNet/up_2_res_0/conv2/conv_general_dilated",
+             "bf16[2,32,32,1280]"]]
+        scoped = chunk_hlo.summarise(self.HLO, scope=r"/norm1/")["outputs"]
+        assert [row[0] for row in scoped] == ["copy", "broadcast"]

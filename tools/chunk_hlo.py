@@ -1,0 +1,183 @@
+"""What the TPU's compiler makes of a cell's denoise executable, without the
+chip.
+
+    python3 tools/chunk_hlo.py --workload sdxl_solo [--scope REGEX]
+        [--keep chunk.hlo] [--min-mb 4]
+
+Builds the cell's engine here on the CPU with zeros for weights, sends the
+cell's own request, and where the engine would run its first ``run_chunk``
+compiles it instead for one chip of a described ``v5e:2x2`` (the TPU
+compiler is installed here; ``tests/test_chip_compile.py`` does the same for
+the kernels), with ``jax.default_backend`` answering ``tpu`` while it is
+traced so that attention takes the path it takes on the chip. Then it reads
+the optimised HLO's largest computation (the scan's body) and prints, as one
+JSON object:
+
+- ``temp_mb``: the executable's temporaries;
+- ``outputs``: the ops that write ``--min-mb`` or more, summed by opcode
+  (fusions by kind) and element type: ``[op, dtype, count, MB]``, largest
+  first. float32 ``copy`` and ``broadcast`` rows of activation size are
+  layout copies through HBM (PERF.md section 6, PR 29);
+- ``plain_convolutions``: convolution fusions whose result lies batch-major
+  (``[B, H, W, C]{3,0,2,1}``) and not in the spatial-major shape ``[rows,
+  batch x blocks, columns, C]``: with a fused producer these ran at a third
+  of the other form's speed (same entry);
+- with ``--scope``: the same sums over the ops whose flax scope matches.
+
+About three minutes for SDXL. No times: a time comes from the chip
+(``benchmarks/op_table.py``); this says which ops the chip will run.
+"""
+
+from __future__ import annotations
+
+import argparse
+import collections
+import json
+import os
+import re
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BYTES = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "pred": 1,
+         "s8": 1, "u8": 1}
+NOT_RUN = {"bitcast", "get-tuple-element", "tuple", "parameter", "constant",
+           "copy-start", "copy-done"}
+COMPUTATION = re.compile(
+    r"^(?:ENTRY )?%?[\w.\-]+ \(.*?\) -> .*? \{\n(.*?)^\}", re.S | re.M)
+OP = re.compile(r"\s*(?:ROOT )?(\S+) = (\S+) ([\w-]+)\(")
+#: a convolution result with the batch outermost and channels minor: not the
+#: spatial-major form, whose shape leads with the rows
+BATCH_MAJOR = re.compile(r"\(?\w+\[[\d,]+\]\{3,0,2,1")
+
+
+def _rows(text: str):
+    """(opcode, dtype, bytes written, result shape, flax scope) of every op
+    of the largest computation that runs on its own."""
+    body = max(COMPUTATION.findall(text), key=lambda b: b.count("\n"))
+    for line in body.splitlines():
+        found = OP.match(line)
+        if not found or found.group(3) in NOT_RUN:
+            continue
+        shape, op = found.group(2), found.group(3)
+        written, dtype = 0, "?"
+        for i, (dt, dims) in enumerate(re.findall(r"(\w+)\[([\d,]*)\]",
+                                                  shape)):
+            count = 1
+            for d in filter(None, dims.split(",")):
+                count *= int(d)
+            written += count * BYTES.get(dt, 4)
+            dtype = dt if i == 0 else dtype
+        if op == "fusion":
+            kind = re.search(r"kind=k(\w+)", line)
+            op = "fusion:" + (kind.group(1) if kind else "")
+        scope = re.search(r'op_name="([^"]*)"', line)
+        yield op, dtype, written, shape, scope.group(1) if scope else ""
+
+
+def summarise(text: str, min_mb: float = 4.0, scope: str | None = None):
+    """The sums the module docstring names, out of optimised HLO text."""
+    total: dict = collections.Counter()
+    count: dict = collections.Counter()
+    plain = []
+    wanted = re.compile(scope) if scope else None
+    for op, dtype, written, shape, where in _rows(text):
+        if (op == "fusion:Output" and "conv_general_dilated" in where
+                and BATCH_MAJOR.match(shape)):
+            plain.append([where.split("closed_call/")[-1],
+                          shape.split("{")[0]])
+        if written < min_mb * 1e6 or (wanted and not wanted.search(where)):
+            continue
+        total[op, dtype] += written
+        count[op, dtype] += 1
+    return {"outputs": [[op, dtype, count[op, dtype], round(mb / 1e6, 1)]
+                        for (op, dtype), mb in total.most_common()],
+            "plain_convolutions": plain}
+
+
+def compile_chunk(workload: str) -> tuple[str, float]:
+    """(optimised HLO text, temporaries in MB) of the cell's first chunk
+    executable, compiled for one described v5e chip."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    sys.path.insert(0, ROOT)
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmarks.harness import files, weights
+    from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
+    from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
+        GenerationPayload,
+    )
+
+    # a compile for a described chip cannot be read back from the cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    bench = files.Bench(ROOT)
+    cell = bench.cell(workload)
+    for key, value in cell.get("server_env", {}).items():
+        os.environ[key] = str(value)
+    config = bench.config(cell["config"])
+    family, policy = files.resolve_family(config), files.resolve_policy(config)
+    shapes = jax.eval_shape(lambda: weights.family_params(
+        bench.components(config), family, policy.param_dtype, 1))
+    engine = Engine(family, jax.tree.map(
+        lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), shapes), policy=policy)
+    chip = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+    compiled = []
+
+    class Compiled(Exception):
+        pass
+
+    chunk_fn = engine._chunk_fn
+
+    def compiling_chunk_fn(*args, **kwargs):
+        fn = chunk_fn(*args, **kwargs)
+
+        def compile_instead(*call_args, **call_kwargs):
+            described = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
+                if hasattr(x, "shape") else x, (call_args, call_kwargs))
+            backend, jax.default_backend = jax.default_backend, lambda: "tpu"
+            try:
+                lowered = fn.lower(*described[0], **described[1])
+            finally:
+                jax.default_backend = backend
+            compiled.append(lowered.compile())
+            raise Compiled
+
+        return compile_instead
+
+    engine._chunk_fn = compiling_chunk_fn
+    try:
+        engine.txt2img(GenerationPayload(
+            **dict(bench.traffic(cell["traffic"])["payload"], seed=1)))
+    except Compiled:
+        pass
+    (executable,) = compiled
+    return (executable.as_text(),
+            executable.memory_analysis().temp_size_in_bytes / 1e6)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--scope", help="regular expression on the flax scope")
+    ap.add_argument("--keep", help="write the optimised HLO text here")
+    ap.add_argument("--min-mb", type=float, default=4.0)
+    args = ap.parse_args(argv)
+    text, temp_mb = compile_chunk(args.workload)
+    if args.keep:
+        with open(args.keep, "w") as fh:
+            fh.write(text)
+    out = {"workload": args.workload, "temp_mb": round(temp_mb, 1),
+           **summarise(text, args.min_mb)}
+    if args.scope:
+        out["scope"] = summarise(text, args.min_mb, args.scope)["outputs"]
+    print(json.dumps(out))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
