@@ -496,133 +496,11 @@ def _ssim_b64(imgs_a, imgs_b, window=7):
     return sum(vals) / max(1, len(vals))
 
 
-def _deepcache_quality(cadence):
-    """Tiny-model PSNR vs uncached with seeded RANDOM weights (zero weights
-    give identical images on any compute path) at the same cadence +
-    mid-ladder cutoff the perf cells use."""
-    from stable_diffusion_webui_distributed_tpu.models import configs as C
-    from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
-    from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
-        GenerationPayload,
-    )
-    from stable_diffusion_webui_distributed_tpu.runtime.interrupt import (
-        GenerationState,
-    )
-    from stable_diffusion_webui_distributed_tpu.samplers import (
-        kdiffusion as kd,
-    )
-
-    engine = Engine(C.TINY, family_params(C.TINY, seed=0), chunk_size=4,
-                    state=GenerationState())
-    p = GenerationPayload(prompt="a herd of cows", steps=8, width=32,
-                          height=32, batch_size=2, seed=42)
-    spec = kd.resolve_sampler(p.sampler_name)
-    cutoff = float(kd.build_sigmas(spec, engine.schedule,
-                                   p.steps)[p.steps // 2])
-    base = engine.txt2img(p)
-    fast_p = p.model_copy()
-    fast_p.override_settings = {"deepcache": cadence, "cfg_cutoff": cutoff}
-    fast = engine.txt2img(fast_p)
-    return {
-        "family": C.TINY.name,
-        "steps": p.steps,
-        "cadence": cadence,
-        "cfg_cutoff_sigma": round(cutoff, 4),
-        "psnr_db_vs_uncached": round(_psnr_b64(base.images, fast.images), 2),
-    }
-
-
-def run_deepcache(tiny):
-    """Step-cache cells (ISSUE 3): configs #1/#2 run uncached, then with
-    deepcache cadence 3 + CFG cutoff at the mid-ladder sigma. The headline
-    numbers are platform-independent — UNet FLOPs/image comes from XLA
-    cost_analysis priced over the ACTUALLY dispatched chunk schedule
-    (DispatchMetrics/pipeline/stepcache.py), compile counts are host-side,
-    and PSNR compares tiny-model outputs — so CPU tiny mode produces the
-    same accounting a chip run would. Also writes BENCH_deepcache.json."""
-    import jax
-
-    from stable_diffusion_webui_distributed_tpu.samplers import (
-        kdiffusion as kd,
-    )
-    from stable_diffusion_webui_distributed_tpu.serving.metrics import METRICS
-
-    dev = jax.devices()[0]
-    cadence = 3
-    cells = []
-    for n in (1, 2):
-        metric, engine, payload, _segments, _rel = _build_config(n, tiny)
-        spec = kd.resolve_sampler(payload.sampler_name)
-        sigmas = kd.build_sigmas(spec, engine.schedule, payload.steps)
-        # cutoff at the mid-ladder sigma: the CFG branch stops mattering in
-        # the low-sigma half (arXiv:2304.11267's trick)
-        cutoff = float(sigmas[payload.steps // 2])
-
-        METRICS.clear()
-        base = engine.txt2img(payload)
-        s_base = METRICS.summary()
-
-        fast_p = payload.model_copy()
-        fast_p.override_settings = {**payload.override_settings,
-                                    "deepcache": cadence,
-                                    "cfg_cutoff": cutoff}
-        METRICS.clear()
-        fast = engine.txt2img(fast_p)
-        s_fast = METRICS.summary()
-
-        f_base = s_base["unet_flops_per_image"]
-        f_fast = s_fast["unet_flops_per_image"]
-        cut = (1.0 - f_fast / f_base) if f_base and f_fast else None
-        cells.append({
-            "config": n,
-            "metric": metric,
-            "unet_flops_per_image_base": f_base,
-            "unet_flops_per_image_cached": f_fast,
-            "flops_cut_pct": round(cut * 100.0, 1) if cut is not None
-            else None,
-            "psnr_db_vs_uncached": round(_psnr_b64(base.images,
-                                                   fast.images), 2),
-            "chunk_executables_base": s_base["compiles"].get("chunk", 0),
-            "chunk_executables_cached": s_fast["compiles"].get("chunk", 0),
-            "cadence": cadence,
-            "cfg_cutoff_sigma": round(cutoff, 4),
-            "images": len(fast.images),
-        })
-        print(f"bench: deepcache config {n}: flops/image "
-              f"{f_base:.3e} -> {f_fast:.3e} "
-              f"({cells[-1]['flops_cut_pct']}% cut), "
-              f"psnr {cells[-1]['psnr_db_vs_uncached']} dB", file=sys.stderr)
-
-    out = {
-        "metric": ("tiny_" if tiny or dev.platform == "cpu" else "")
-        + "deepcache_flops_cut",
-        "value": min(c["flops_cut_pct"] for c in cells
-                     if c["flops_cut_pct"] is not None),
-        "unit": "pct_unet_flops_per_image",
-        "vs_baseline": None,
-        # documented floor (PERF.md "FLOP levers"): tiny-model PSNR vs the
-        # uncached output at cadence 3 + mid-ladder cutoff, measured on
-        # the random-weights quality cell below (the zero-init perf cells
-        # report 99 dB by construction)
-        "psnr_floor_db": 20.0,
-        "quality": _deepcache_quality(cadence),
-        "cells": cells,
-        "device": dev.device_kind,
-    }
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "BENCH_deepcache.json")
-    with open(path, "w") as f:
-        json.dump(out, f, indent=2)
-        f.write("\n")
-    return out
-
-
 def run_int8(tiny):
     """Int8 x step-cache grid (ISSUE 7): ONE random-weights tiny engine
     serves every cell through the per-request ``precision`` override
     (pipeline/precision.py) — the same engine/variant-module path
-    production dispatch uses. Each int8 cell reports UNet FLOPs/image
-    (XLA cost analysis over the dispatched schedule), chunk compile
+    production dispatch uses. Each int8 cell reports chunk compile
     counts, and PSNR/SSIM against the bf16 cell at the SAME cadence, so
     quantization error is isolated from step-cache error. Quality is the
     platform-independent part; the 2x MXU rate is stated as peak basis,
@@ -664,7 +542,6 @@ def run_int8(tiny):
             "cell": f"c{cadence}-{precision or 'bf16'}",
             "precision": precision or "bf16",
             "cadence": cadence,
-            "unet_flops_per_image": s["unet_flops_per_image"],
             "chunk_executables": s["compiles"].get("chunk", 0),
         }
 
@@ -681,8 +558,7 @@ def run_int8(tiny):
             c["ssim_vs_bf16"] = round(
                 _ssim_b64(r.images, base_r.images), 4)
             cells.append(c)
-            print(f"bench: int8 {c['cell']}: flops/image "
-                  f"{c['unet_flops_per_image']:.3e}, "
+            print(f"bench: int8 {c['cell']}: "
                   f"psnr {c['psnr_db_vs_bf16']} dB, "
                   f"ssim {c['ssim_vs_bf16']}", file=sys.stderr)
 
@@ -790,7 +666,6 @@ def run_serving(tiny):
         "coalesced_dispatches": s["coalesced_dispatches"],
         "avg_queue_wait_s": round(s["avg_queue_wait_s"] or 0.0, 4),
         "avg_padding_ratio": round(s["avg_padding_ratio"] or 1.0, 4),
-        "unet_flops_per_image": s["unet_flops_per_image"],
         "requests": 8,
         "raw_shapes": len(set(shapes)),
         "bucket_ladder": [f"{w}x{h}" for w, h in bucketer.shapes],
@@ -1223,7 +1098,6 @@ def run_ragged(tiny):
         return {
             "chunk_compiles": s["compiles"].get("chunk", 0),
             "avg_padding_ratio": round(s["avg_padding_ratio"] or 1.0, 4),
-            "unet_flops_per_image": s["unet_flops_per_image"],
             "dispatches": s["dispatches"],
             "token_padding_ratio": round(sum(tok) / len(tok), 4)
             if tok else None,
@@ -1253,7 +1127,6 @@ def run_ragged(tiny):
             coarse_classic["avg_padding_ratio"],
         "token_padding_ratio": ragged["token_padding_ratio"],
         "census_alarm": int(ragged["census_alarm"]),
-        "unet_flops_per_image": ragged["unet_flops_per_image"],
         "phases": {"fine_ladder": fine_classic,
                    "coarse_classic": coarse_classic, "ragged": ragged},
         "requests": 8,
@@ -1277,7 +1150,6 @@ def run_ragged(tiny):
             coarse_classic["avg_padding_ratio"],
         "token_padding_ratio": ragged["token_padding_ratio"],
         "census_alarm": int(ragged["census_alarm"]),
-        "unet_flops_per_image": ragged["unet_flops_per_image"],
     }, dev.device_kind, tiny, time.time())
     with open(os.path.join(base, "BENCH_LEDGER.jsonl"), "a",
               encoding="utf-8") as f:
@@ -1387,7 +1259,6 @@ def run_cache(tiny):
         distinct = [payload(i, 200 + i) for i in range(6)]
         for p in distinct:
             go(p.model_copy(deep=True))
-        flops_full = METRICS.summary()["unet_flops_per_image"]
 
         # phase 2 — byte-exact repeats: served from the result cache at
         # admission; no new dispatch, no encode, no denoise.
@@ -1409,14 +1280,11 @@ def run_cache(tiny):
         # txt2img but splits the result key, so the second request of
         # each pair misses result dedupe and instead resumes mid-denoise
         # from the carry its twin captured at the chunk boundary.
-        resumed_flops = []
         for j in range(3):
             first = payload(f"prefix{j}", 500 + j, denoising_strength=0.4)
             second = payload(f"prefix{j}", 500 + j, denoising_strength=0.7)
             go(first.model_copy(deep=True))
-            METRICS.clear()
             go(second.model_copy(deep=True))
-            resumed_flops.append(METRICS.summary()["unet_flops_per_image"])
 
         summ = cache.summary()
         cache.clear_all()
@@ -1428,11 +1296,6 @@ def run_cache(tiny):
     e_hits = pos["hits"] + neg["hits"]
     e_total = e_hits + pos["misses"] + neg["misses"]
     res = summ["result"]
-    resumed = [f for f in resumed_flops if f]
-    flops_resumed = (sum(resumed) / len(resumed)) if resumed else None
-    reduction = None
-    if flops_full and flops_resumed is not None:
-        reduction = round((1.0 - flops_resumed / flops_full) * 100.0, 2)
     out = {
         "metric": ("tiny_" if tiny or dev.platform == "cpu" else "")
         + "cache_embed_hit_rate",
@@ -1448,9 +1311,6 @@ def run_cache(tiny):
         "single_flight": res["single_flight"],
         "prefix_captured": summ["prefix"]["captured"],
         "prefix_resumed": summ["prefix"]["resumed"],
-        "unet_flops_per_image_full": flops_full,
-        "unet_flops_per_image_resumed": flops_resumed,
-        "prefix_flops_reduction_pct": reduction,
         "e2e_p50_s": round(_percentile(lat, 0.50), 4),
         "e2e_p95_s": round(_percentile(lat, 0.95), 4),
         "requests": len(lat),
@@ -1464,7 +1324,6 @@ def run_cache(tiny):
     row = _ledger_row("cache", {
         "embed_cache_hit_rate": out["embed_cache_hit_rate"],
         "result_dedupe_hit_rate": out["result_dedupe_hit_rate"],
-        "prefix_flops_reduction_pct": out["prefix_flops_reduction_pct"],
         "prefix_resumed": out["prefix_resumed"],
         "single_flight_joined": res["single_flight"].get("joined", 0),
     }, dev.device_kind, tiny, time.time())
@@ -2914,7 +2773,6 @@ def run_ledger(tiny):
             "coalesce_factor": serving.get("value"),
             "bucket_hit_rate": serving.get("bucket_hit_rate"),
             "avg_padding_ratio": serving.get("avg_padding_ratio"),
-            "unet_flops_per_image": serving.get("unet_flops_per_image"),
             "dispatches": serving.get("dispatches"),
             "coalesced_dispatches": serving.get("coalesced_dispatches"),
         }, serving.get("device", ""), tiny, recorded_at),
@@ -2979,10 +2837,6 @@ def main() -> None:
     ap.add_argument("--serving", action="store_true",
                     help="serving-layer microbench: coalesce factor + "
                          "compile counts (CPU-safe)")
-    ap.add_argument("--deepcache", action="store_true",
-                    help="step-cache cells: FLOPs/image cut, compile "
-                         "counts, PSNR vs uncached; writes "
-                         "BENCH_deepcache.json (CPU-safe)")
     ap.add_argument("--fleet", action="store_true",
                     help="fleet-scheduler comparison: mixed-tenant "
                          "open-loop workload, FIFO vs WFQ gate; writes "
@@ -3105,8 +2959,6 @@ def main() -> None:
             print(json.dumps(run_stages(tiny)))
         elif args.aot:
             print(json.dumps(run_aot(tiny)))
-        elif args.deepcache:
-            print(json.dumps(run_deepcache(tiny)))
         elif args.int8:
             print(json.dumps(run_int8(tiny)))
         else:

@@ -11,7 +11,7 @@ same knobs a user could set by hand (pipeline/stepcache.py) — and only
 rejected with 429 when no degrade rung fits either.
 
 Degrade cost model: a cached (reuse) step prices at ~45% of a full UNet
-eval on the XLA cost-analysis grid (tools/flops_report.py), so cadence
+eval by XLA's cost analysis of the two lowered paths, so cadence
 ``c`` scales the compute part of the ETA by ``1/c + (1 - 1/c) * 0.45``.
 Queue wait is latency, not compute — it is never rescaled.
 """

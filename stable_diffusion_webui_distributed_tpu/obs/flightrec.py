@@ -42,7 +42,7 @@ class FlightRecorder:
         """Append one failure entry; returns it (already JSON-plain).
 
         ``perf`` carries the failing request's device-time attribution
-        (MFU / padding / compile totals); left ``None`` the recorder pulls
+        (padding / compile totals); left ``None`` the recorder pulls
         the perf ledger's last-dispatch snapshot itself, so span-layer
         callers need no knowledge of the ledger."""
         from stable_diffusion_webui_distributed_tpu.runtime.logging import (

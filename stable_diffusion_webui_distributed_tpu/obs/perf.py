@@ -2,12 +2,10 @@
 
 PERF.md's roofline was computed by hand from one-shot BENCH files; this
 module makes the same numbers *live*. The serving dispatcher reports every
-device dispatch here — host-observed seconds joined with the FLOPs that
-``FlopsAccountant`` priced for the same denoise range — and the ledger
-folds them into per-(bucket, cadence, precision) groups carrying:
+device dispatch here — host-observed seconds and true-vs-padded shapes —
+and the ledger folds them into per-(bucket, cadence, precision) groups
+carrying:
 
-- **MFU**: dispatched FLOPs / device seconds / chip peak (``None`` on CPU
-  or unknown hardware, so a dev box can never fabricate an MFU claim);
 - **padding waste**: true-requested pixels vs padded-dispatched pixels —
   the per-bucket version of BENCH_serving.json's ``avg_padding_ratio``,
   the gauge the ragged-dispatch work will be judged against (ROADMAP);
@@ -84,8 +82,7 @@ def peak_flops_for(device_kind: str, precision: str = "bf16"
                    ) -> Optional[float]:
     """Peak FLOPs/s for a device kind at a serving precision, or ``None``
     when the kind is not in :data:`PEAK_FLOPS_BF16` (CPU dev boxes, chips
-    the table has not met: MFU stays null rather than inventing a
-    denominator)."""
+    the table has not met: no denominator is invented)."""
     peak = PEAK_FLOPS_BF16.get(str(device_kind or ""))
     if peak is not None and str(precision or "").startswith("int8"):
         return peak * INT8_PEAK_RATIO
@@ -93,9 +90,9 @@ def peak_flops_for(device_kind: str, precision: str = "bf16"
 
 
 def _device_kind() -> str:
-    """Best-effort device kind for the MFU denominator. jax is already
-    imported by the time anything dispatches; failure means "unknown"
-    (MFU null), never an exception on the dispatch path."""
+    """Best-effort device kind for the summary. jax is already imported
+    by the time anything dispatches; failure means "unknown", never an
+    exception on the dispatch path."""
     try:
         import jax
 
@@ -140,14 +137,14 @@ class PerfLedger:
 
     def record_dispatch(self, *, bucket: str, cadence: int, precision: str,
                         lora: str = "",
-                        device_s: float, flops: float, requests: int,
+                        device_s: float, requests: int,
                         batch_raw: int, batch_run: int, true_pixels: int,
                         padded_pixels: int, masked_pixels: int = 0,
                         true_tokens: int = 0, padded_tokens: int = 0,
                         hbm: Optional[Dict[str, int]] = None
                         ) -> None:
-        """One device dispatch: host-observed seconds + the FLOPs priced
-        for the same denoise range + true-vs-padded shape accounting.
+        """One device dispatch: host-observed seconds + true-vs-padded
+        shape accounting.
         No-op (and never raises) when ``SDTPU_PERF`` is off.
 
         ``padded_pixels`` counts everything RESIDENT in the dispatch
@@ -166,7 +163,7 @@ class PerfLedger:
 
         ``lora`` is the traced-adapter cell label (``"r8s1"``-style, "" on
         adapterless and merged-path dispatches) — appended as the LAST
-        group-key axis so adapter-active traffic gets its own MFU rows
+        group-key axis so adapter-active traffic gets its own rows
         without disturbing key[0..2] consumers."""
         if not enabled():
             return
@@ -181,7 +178,7 @@ class PerfLedger:
                         self._groups.popitem(last=False)
                         self._groups_evicted += 1
                     g = {"dispatches": 0, "requests": 0, "device_s": 0.0,
-                         "flops": 0.0, "true_pixels": 0, "padded_pixels": 0,
+                         "true_pixels": 0, "padded_pixels": 0,
                          "batch_raw": 0, "batch_run": 0, "masked_pixels": 0,
                          "true_tokens": 0, "padded_tokens": 0}
                     self._groups[key] = g
@@ -190,7 +187,6 @@ class PerfLedger:
                 g["dispatches"] += 1
                 g["requests"] += int(requests)
                 g["device_s"] += max(0.0, float(device_s))
-                g["flops"] += max(0.0, float(flops))
                 g["true_pixels"] += int(true_pixels)
                 g["padded_pixels"] += int(padded_pixels)
                 g["batch_raw"] += int(batch_raw)
@@ -212,8 +208,7 @@ class PerfLedger:
                 compiles_total = sum(int(c["count"])
                                      for c in self._compiles.values())
                 self._last_dispatch = self._dispatch_entry(
-                    key, g, device_s, flops, self._device_kind,
-                    compiles_total)
+                    key, g, device_s, compiles_total)
         except Exception:  # noqa: BLE001 — telemetry must not fail dispatch
             pass
 
@@ -243,7 +238,7 @@ class PerfLedger:
                         self._groups.popitem(last=False)
                         self._groups_evicted += 1
                     g = {"dispatches": 0, "requests": 0, "device_s": 0.0,
-                         "flops": 0.0, "true_pixels": 0, "padded_pixels": 0,
+                         "true_pixels": 0, "padded_pixels": 0,
                          "batch_raw": 0, "batch_run": 0, "masked_pixels": 0,
                          "true_tokens": 0, "padded_tokens": 0}
                     self._groups[key] = g
@@ -322,36 +317,25 @@ class PerfLedger:
     @staticmethod
     def _dispatch_entry(key: Tuple[str, int, str, str],
                         g: Dict[str, float], device_s: float,
-                        flops: float, device_kind: Optional[str],
                         compiles_total: int) -> Dict[str, Any]:
         # static: the caller (record_dispatch, under _lock) passes the
         # guarded values in, so this stays pure derivation; computes the
         # flight-recorder snapshot for THIS dispatch (instant values, not
         # the group's running sums)
-        peak = peak_flops_for(device_kind or "", key[2])
-        mfu = None
-        if peak and device_s > 0:
-            mfu = float(flops) / float(device_s) / peak
         true_px = g["true_pixels"]
         padded_px = g["padded_pixels"]
         return {
             "bucket": key[0], "cadence": key[1], "precision": key[2],
             "lora": key[3],
             "device_s": round(float(device_s), 6),
-            "flops": float(flops),
-            "mfu": mfu,
             "padding_ratio": (padded_px / true_px) if true_px else None,
             "compiles_total": int(compiles_total),
         }
 
     @staticmethod
-    def _group_row(key: Tuple[str, int, str, str], g: Dict[str, float],
-                   device_kind: Optional[str]) -> Dict[str, Any]:
+    def _group_row(key: Tuple[str, int, str, str],
+                   g: Dict[str, float]) -> Dict[str, Any]:
         # static for the same reason as _dispatch_entry (LK001 discipline)
-        peak = peak_flops_for(device_kind or "", key[2])
-        mfu = None
-        if peak and g["device_s"] > 0:
-            mfu = g["flops"] / g["device_s"] / peak
         true_px, padded_px = g["true_pixels"], g["padded_pixels"]
         ratio = (padded_px / true_px) if true_px else None
         # ragged split (defaulted 0 so pre-ragged rows read identically):
@@ -371,8 +355,6 @@ class PerfLedger:
             "dispatches": int(g["dispatches"]),
             "requests": int(g["requests"]),
             "device_s": g["device_s"],
-            "flops": g["flops"],
-            "mfu": mfu,
             "padding_ratio": ratio,
             "padding_waste": (1.0 - true_px / padded_px) if padded_px
             else None,
@@ -417,7 +399,7 @@ class PerfLedger:
     def summary(self) -> Dict[str, Any]:
         """The ``/internal/perf`` body."""
         with self._lock:
-            groups = [self._group_row(k, g, self._device_kind)
+            groups = [self._group_row(k, g)
                       for k, g in self._groups.items()]
             slo = [self._slo_row(k, r) for k, r in self._slo.items()]
             compiles = {k: dict(c) for k, c in self._compiles.items()}

@@ -725,7 +725,7 @@ def _labeled_family(lines: List[str], name: str, mtype: str,
 
 
 def _render_perf(lines: List[str]) -> None:
-    """The perf-ledger families: per-(bucket, cadence, precision) MFU /
+    """The perf-ledger families: per-(bucket, cadence, precision)
     padding / device-time attribution and per-(tenant, class) SLO gauges.
     All pulled live from obs/perf.py's LEDGER — empty (and absent from
     the exposition) until SDTPU_PERF turns recording on."""
@@ -750,15 +750,6 @@ def _render_perf(lines: List[str]) -> None:
         lines, "sdtpu_perf_device_seconds_total", "counter",
         "Host-observed device-dispatch seconds by serving group.",
         [(body(g), g["device_s"]) for g in groups])
-    _labeled_family(
-        lines, "sdtpu_perf_flops_total", "counter",
-        "Dispatched UNet FLOPs by serving group (cost_analysis priced).",
-        [(body(g), g["flops"]) for g in groups])
-    _labeled_family(
-        lines, "sdtpu_perf_mfu", "gauge",
-        "Live MFU: dispatched FLOPs / device seconds / chip peak "
-        "(NaN when the peak is unknown, e.g. CPU).",
-        [(body(g), g["mfu"]) for g in groups])
     _labeled_family(
         lines, "sdtpu_perf_padding_ratio", "gauge",
         "Padded-dispatched pixels / true-requested pixels by group.",
@@ -831,14 +822,8 @@ def render() -> str:
     _scalar(lines, "sdtpu_serving_avg_padding_ratio", "gauge",
             "Mean bucket-px / requested-px over bucketed requests.",
             s["avg_padding_ratio"])
-    _scalar(lines, "sdtpu_serving_unet_flops_total", "counter",
-            "UNet FLOPs dispatched (XLA cost_analysis pricing).",
-            s["unet_flops_total"])
     _scalar(lines, "sdtpu_serving_unet_images_total", "counter",
             "Images decoded to outputs.", s["unet_images"])
-    _scalar(lines, "sdtpu_serving_unet_flops_per_image", "gauge",
-            "Mean dispatched UNet FLOPs per output image.",
-            s["unet_flops_per_image"])
 
     _labeled_family(
         lines, "sdtpu_stage_compiles_total", "counter",

@@ -224,10 +224,6 @@ class Engine:
                 import PromptExpander
 
             self.expander = PromptExpander(self)
-        # XLA cost_analysis pricer for the per-request UNet-FLOPs metric
-        # (pipeline/stepcache.py); lowers abstractly, so it is cheap to
-        # hold per engine and its cache keys on eval shapes only
-        self._flops = stepcache.FlopsAccountant(self)
         # blank hybrid-conditioning latents per (batch, size); VAE-derived,
         # so set_vae clears it
         self._blank_cond_cache: Dict[Tuple, Any] = {}
@@ -1836,7 +1832,6 @@ class Engine:
                                  x.shape[1], x.shape[2]),
                 self.policy.compute_dtype)
             valid = jnp.asarray(False)
-        dispatched = []  # (start, length, cached) — FLOPs accounting
 
         # Denoise prefix sharing (cache/prefix.py, SDTPU_CACHE): only for
         # ranges where a captured prefix can be BYTE-identical — the plain
@@ -1980,7 +1975,6 @@ class Engine:
                         pending[0].block_until_ready()
                     done += pending[1]
                     self.state.step(done)
-            dispatched.append((pos, length, cached_chunk))
             pending = (fence, length)
             pos += length
             if prefix_plan is not None and not prefix_plan.captured:
@@ -1996,38 +1990,7 @@ class Engine:
             done += pending[1]
             self.state.step(done)
         self.state.finish()
-        self._record_unet_flops(dispatched, sc.cadence if use_cache else 1,
-                                cfg_stop, spec.evals_per_step, steps, batch,
-                                x.shape[1], x.shape[2], ctx_c.shape[1],
-                                precision=prec.name)
         return carry.x
-
-    def _record_unet_flops(self, dispatched, cadence, cfg_stop,
-                           evals_per_step, steps, batch, lat_h, lat_w,
-                           ctx_len, precision: str = "") -> None:
-        """Price a denoise range's dispatched chunk schedule with XLA
-        cost_analysis (stepcache.FlopsAccountant) and fold the total into
-        DispatchMetrics — the numerator of ``unet_flops_per_image`` on
-        ``/internal/status``. Gated by ``SDTPU_FLOPS_METRICS``; pricing
-        failures never break generation."""
-        from stable_diffusion_webui_distributed_tpu.runtime.config import (
-            env_flag,
-        )
-        from stable_diffusion_webui_distributed_tpu.serving.metrics import (
-            METRICS,
-        )
-
-        if not dispatched or not env_flag("SDTPU_FLOPS_METRICS", True):
-            return
-        try:
-            counts = stepcache.plan_schedule(
-                dispatched, cadence, cfg_stop, evals_per_step, steps)
-            total = self._flops.request_flops(
-                counts, batch, lat_h, lat_w, ctx_len, precision=precision)
-            if total is not None:
-                METRICS.record_unet_flops(total)
-        except Exception:
-            pass
 
     def _start_sigma(self, spec, steps):
         sigmas = kd.build_sigmas(spec, self.schedule, steps)
@@ -2396,7 +2359,6 @@ class Engine:
 
         stepfn = self._cn_step_fn(payload.sampler_name, steps, width,
                                   height, batch, prec.name)
-        dispatched = []
         fences = []  # completed-dispatch fences; depth-2 host pacing
         done = 0
         res = residuals_for(carry.x, 0)
@@ -2409,7 +2371,6 @@ class Engine:
                 carry, fence = stepfn(
                     self.params["unet"], carry, jnp.int32(i), ctx_u,
                     ctx_c, cfg, image_keys, au, ac, res)
-            dispatched.append((i, 1, False))
             fences.append(fence)
             i += 1
             if i < steps:
@@ -2428,9 +2389,6 @@ class Engine:
         # the host timeline. The depth-2 pacing above already bounds
         # in-flight buffers; finish() only snapshots progress.
         self.state.finish()
-        self._record_unet_flops(dispatched, 1, 0, spec.evals_per_step,
-                                steps, batch, x.shape[1], x.shape[2],
-                                ctx_c.shape[1], precision=prec.name)
         return carry.x
 
     def _cn_residual_fn(self, sampler_name: str, steps: int, width: int,

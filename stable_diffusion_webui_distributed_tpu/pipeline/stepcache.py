@@ -23,19 +23,12 @@ quantized onto :data:`CADENCE_LADDER` (:func:`bucket_cadence`, the RC001
 bucket-ladder rule) so serving-side coalescing groups on a bounded key
 set; together that mints at most 2 chunk executables per shape bucket
 (plain + step-cache).
-
-The module also mirrors the in-graph refresh/truncation schedule on the
-host (:func:`plan_schedule`) and prices it with XLA ``cost_analysis``
-(:func:`request_flops`) — the per-request "UNet FLOPs per image" number
-DispatchMetrics exposes in ``/internal/status`` and ``bench.py
---deepcache`` records in BENCH_deepcache.json.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import threading
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Sequence
 
 import numpy as np
 
@@ -137,164 +130,3 @@ def prefix_boundary(pos: int, cadence: int, cfg_stop: int,
     if int(cadence) > 1 and pos % int(cadence) != 0:
         return False
     return pos <= int(cfg_stop)
-
-
-# -- host mirror of the in-graph schedule (FLOPs accounting) ---------------
-
-
-def plan_schedule(chunks: Sequence[Tuple[int, int, bool]], cadence: int,
-                  cfg_stop: int, evals_per_step: int,
-                  total_steps: int) -> Dict[str, int]:
-    """Replay the chunk loop's refresh/truncation decisions host-side.
-
-    ``chunks``: (start, length, cached) per dispatched chunk, in order —
-    ``cached=False`` marks a chunk routed to the plain executable (CN
-    active in window / cache unsupported), which also invalidates the
-    carried feature so the next cached chunk refreshes on entry (the same
-    rule the engine applies after an interrupt-resume boundary).
-
-    Returns eval counts keyed by UNet variant:
-      full_evals          plain full cond+uncond evals (2B rows)
-      reuse_full_evals    shallow-path evals with CFG (2B rows)
-      reuse_trunc_evals   shallow-path evals, cond only (B rows)
-      deep_full           deep refreshes with CFG (2B rows)
-      deep_trunc          deep refreshes, cond only (B rows)
-      refreshes           total refresh steps (= deep_full + deep_trunc)
-    Multi-eval samplers skip their second-order eval on the final step
-    (``sigma_next == 0``), mirrored here.
-    """
-    counts = {"full_evals": 0, "reuse_full_evals": 0,
-              "reuse_trunc_evals": 0, "deep_full": 0, "deep_trunc": 0,
-              "refreshes": 0}
-    cadence = max(1, int(cadence))
-    valid = False
-    for start, length, cached in chunks:
-        for i in range(start, start + length):
-            evals = evals_per_step if i < total_steps - 1 else 1
-            if not cached:
-                valid = False
-                counts["full_evals"] += evals
-                continue
-            truncated = i >= cfg_stop
-            if (not valid) or (i % cadence == 0):
-                counts["refreshes"] += 1
-                counts["deep_trunc" if truncated else "deep_full"] += 1
-            valid = True
-            counts["reuse_trunc_evals" if truncated
-                   else "reuse_full_evals"] += evals
-    return counts
-
-
-# -- XLA cost_analysis pricing --------------------------------------------
-
-
-class FlopsAccountant:
-    """Per-engine cache of UNet-eval FLOPs from XLA's cost analysis.
-
-    Prices ONE UNet evaluation per (rows, latent hw, context length,
-    cache mode) by lowering the eval with abstract (ShapeDtypeStruct)
-    arguments — no device compile, no weight materialization — and
-    reading ``Lowered.cost_analysis()['flops']``. Platform-independent:
-    the number is a property of the HLO, not the backend.
-
-    A note on why evals are priced individually instead of reading the
-    chunk executable's own cost analysis: XLA counts a ``while`` body
-    once regardless of trip count and counts BOTH ``lax.cond`` branches,
-    so the scanned chunk's raw number is neither per-step nor
-    schedule-aware. Pricing the branch functions and summing over the
-    steps actually dispatched (:func:`plan_schedule`) measures what ran.
-    """
-
-    def __init__(self, engine) -> None:
-        self._engine = engine
-        self._cache: Dict[Tuple, Optional[float]] = {}  # guarded-by: _lock
-        self._lock = threading.Lock()
-
-    def eval_flops(self, rows: int, lat_h: int, lat_w: int,
-                   ctx_len: int, mode: Optional[str],
-                   precision: str = "") -> Optional[float]:
-        """FLOPs of one UNet apply at the given batch rows / mode
-        (None = full forward, "deep", "reuse") / serving precision name
-        ("" = the engine's policy default, pipeline/precision.py); None
-        when the lowering or cost analysis is unavailable (never
-        raises)."""
-        key = (rows, lat_h, lat_w, ctx_len, mode, precision)
-        with self._lock:
-            if key in self._cache:
-                return self._cache[key]
-        flops = self._measure(rows, lat_h, lat_w, ctx_len, mode, precision)
-        with self._lock:
-            self._cache[key] = flops
-        return flops
-
-    def _measure(self, rows, lat_h, lat_w, ctx_len, mode, precision=""):
-        import jax
-        import jax.numpy as jnp
-
-        from stable_diffusion_webui_distributed_tpu.models import (
-            unet as unet_mod,
-        )
-
-        eng = self._engine
-        ucfg = eng.family.unet
-        if mode is not None and not unet_mod.cache_supported(ucfg):
-            return None
-        # precision variant module (pipeline/precision.py): same param
-        # tree, different traced computation — int8 cells price their own
-        # HLO. "" keeps the policy-default module (legacy callers).
-        unet = (eng._modules_for(precision)[0]
-                if precision and hasattr(eng, "_modules_for") else eng.unet)
-        try:
-            struct = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                eng.params["unet"])
-            cd = eng.policy.compute_dtype
-            x = jax.ShapeDtypeStruct(
-                (rows, lat_h, lat_w, ucfg.in_channels), jnp.float32)
-            tb = jax.ShapeDtypeStruct((rows,), jnp.float32)
-            ctx = jax.ShapeDtypeStruct(
-                (rows, ctx_len, ucfg.cross_attention_dim), jnp.float32)
-            added = (jax.ShapeDtypeStruct(
-                (rows, ucfg.projection_input_dim), jnp.float32)
-                if ucfg.addition_embed_dim else None)
-            cache = (jax.ShapeDtypeStruct(
-                unet_mod.deep_cache_shape(ucfg, rows, lat_h, lat_w), cd)
-                if mode == "reuse" else None)
-
-            def call(p, xx, tt, cc, aa, ca):
-                return unet.apply({"params": p}, xx, tt, cc, aa,
-                                  cache=ca, cache_mode=mode)
-
-            lowered = jax.jit(call).lower(struct, x, tb, ctx, added, cache)
-            cost = lowered.cost_analysis()
-            if isinstance(cost, (list, tuple)):
-                cost = cost[0] if cost else {}
-            flops = float(cost.get("flops", 0.0) or 0.0)
-            return flops if flops > 0 else None
-        except Exception:  # pricing must never break generation
-            return None
-
-    def request_flops(self, counts: Dict[str, int], batch: int,
-                      lat_h: int, lat_w: int, ctx_len: int,
-                      precision: str = "") -> Optional[float]:
-        """Total UNet FLOPs for a denoise range priced from its
-        :func:`plan_schedule` counts; None when any needed eval price is
-        unavailable."""
-        need = (
-            ("full_evals", 2 * batch, None),
-            ("reuse_full_evals", 2 * batch, "reuse"),
-            ("reuse_trunc_evals", batch, "reuse"),
-            ("deep_full", 2 * batch, "deep"),
-            ("deep_trunc", batch, "deep"),
-        )
-        total = 0.0
-        for key, rows, mode in need:
-            n = counts.get(key, 0)
-            if not n:
-                continue
-            price = self.eval_flops(rows, lat_h, lat_w, ctx_len, mode,
-                                    precision)
-            if price is None:
-                return None
-            total += n * price
-        return total

@@ -51,11 +51,6 @@ def _logger():
 #   sigma the CFG uncond half is dropped and the UNet runs cond-only
 #   rows. Mapped host-side onto the built sigma ladder and carried as a
 #   traced step index; per-request: ``override_settings.cfg_cutoff``.
-# - ``SDTPU_FLOPS_METRICS`` (flag, default on): price each dispatched
-#   denoise schedule with XLA cost_analysis and expose UNet
-#   FLOPs-per-image in DispatchMetrics / ``/internal/status``. ``0``
-#   skips the accounting (it costs one abstract lowering per new eval
-#   shape).
 #
 # Defaults keep both levers off: generation stays byte-identical to the
 # plain executable unless a deployment opts into the FLOP/quality trade.
@@ -165,9 +160,8 @@ def _logger():
 #   audit-ring capacity behind ``/internal/autoscale`` — every retained
 #   decision with its wall-clock timestamp (fleet/slices.py).
 # - ``SDTPU_PERF`` (flag, default off): the perf ledger (obs/perf.py).
-#   On, every device dispatch reports host-observed seconds + accounted
-#   FLOPs into per-(bucket, cadence, precision) MFU / padding-waste
-#   groups served at ``/internal/perf`` and as ``sdtpu_perf_*``
+#   On, every device dispatch reports host-observed seconds into
+#   per-(bucket, cadence, precision) padding-waste groups served at ``/internal/perf`` and as ``sdtpu_perf_*``
 #   Prometheus families; compile builds and fleet SLO outcomes feed the
 #   same ledger. Off (the default), every record call is a no-op and
 #   the dispatch path is byte-identical to the uninstrumented build.

@@ -592,7 +592,7 @@ class ApiServer:
 
     def handle_perf(self) -> Dict[str, Any]:
         """Perf-ledger summary (obs/perf.py): per-(bucket, cadence,
-        precision) MFU / padding-waste rows, compile latencies, and
+        precision) padding-waste rows, compile latencies, and
         per-(tenant, class) SLO attainment. Empty until SDTPU_PERF=1."""
         from stable_diffusion_webui_distributed_tpu.obs import perf
 

@@ -879,7 +879,6 @@ class ServingDispatcher:
                 # the grouped path — no-op with the knob off
                 perf_on = obs_perf.enabled()
                 if perf_on:
-                    flops0 = METRICS.unet_flops_snapshot()
                     t0_dev = time.perf_counter()
                 wd = obs_watchdog.arm(
                     ticket.request_id, "dispatch.device",
@@ -928,7 +927,6 @@ class ServingDispatcher:
                         lora=(f"r{rs[0]}s{rs[1]}"
                               if rs and rs != (0, 0) else ""),
                         device_s=time.perf_counter() - t0_dev,
-                        flops=METRICS.unet_flops_snapshot() - flops0,
                         requests=1, batch_raw=n_img, batch_run=n_run,
                         true_pixels=ticket.payload.width
                         * ticket.payload.height * n_img,
@@ -1222,13 +1220,11 @@ class ServingDispatcher:
         pooled_u, pooled_c = built["pooled"]
         b_raw, b_run = built["b_raw"], built["b_run"]
         perf_on = built["perf_on"]
-        # perf ledger (SDTPU_PERF): host-observed denoise seconds joined
-        # with the FLOPs delta the engine prices for this exact range —
+        # perf ledger (SDTPU_PERF): host-observed denoise seconds —
         # passive perf_counter reads, no extra device syncs, and with the
         # knob off record_dispatch is a no-op (dispatch stays byte-
         # identical to the uninstrumented path)
         if perf_on:
-            flops0 = METRICS.unet_flops_snapshot()
             t0_dev = time.perf_counter()
         latents = engine._denoise_range(
             rp, built["x"], built["keys"], (ctx_u, ctx_c),
@@ -1250,7 +1246,6 @@ class ServingDispatcher:
                 lora=(f"r{built['lora_rb']}s{built['lora_sc']}"
                       if built["traced_group"] else ""),
                 device_s=time.perf_counter() - t0_dev,
-                flops=METRICS.unet_flops_snapshot() - flops0,
                 requests=len(live), batch_raw=b_raw, batch_run=b_run,
                 true_pixels=sum(t.payload.width * t.payload.height * n_p
                                 for t, n_p in zip(live, counts)),

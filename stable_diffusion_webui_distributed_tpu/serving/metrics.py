@@ -70,11 +70,7 @@ class DispatchMetrics:
             #: sum of (bucket px / requested px) per bucketed request
             self.padding_ratio_total = 0.0  # guarded-by: _lock
             self.padding_ratio_count = 0  # guarded-by: _lock
-            #: UNet FLOPs actually dispatched (XLA cost_analysis priced
-            #: over each request's chunk schedule, pipeline/stepcache.py)
-            self.unet_flops_total = 0.0  # guarded-by: _lock
-            #: images decoded to outputs (denominator for FLOPs/image —
-            #: hires/refiner FLOPs fold into the one image they produce)
+            #: images decoded to outputs
             self.unet_images = 0  # guarded-by: _lock
             #: resolved precision name -> device dispatches / requests
             #: carried (pipeline/precision.py; "" = caller didn't say)
@@ -126,11 +122,6 @@ class DispatchMetrics:
             self.queue_wait_total += float(seconds)
             self.queue_wait_count += 1
 
-    def record_unet_flops(self, flops: float) -> None:
-        """One denoise range's priced UNet FLOPs (engine-side)."""
-        with self._lock:
-            self.unet_flops_total += float(flops)
-
     def record_unet_images(self, n: int) -> None:
         with self._lock:
             self.unet_images += int(n)
@@ -144,12 +135,6 @@ class DispatchMetrics:
     def aot_load_count(self, kind: str = "chunk") -> int:
         with self._lock:
             return self.aot_loads.get(kind, 0)
-
-    def unet_flops_snapshot(self) -> float:
-        """Current dispatched-FLOPs total; the perf ledger takes a delta
-        around each device dispatch to attribute FLOPs per group."""
-        with self._lock:
-            return self.unet_flops_total
 
     def coalesce_factor(self) -> float:
         """Mean requests per device dispatch (1.0 = no coalescing yet)."""
@@ -170,14 +155,6 @@ class DispatchMetrics:
             if not self.padding_ratio_count:
                 return 1.0
             return self.padding_ratio_total / self.padding_ratio_count
-
-    def unet_flops_per_image(self) -> float:
-        """Mean dispatched UNet FLOPs per output image (0.0 until both
-        a priced denoise range and a decoded image have been recorded)."""
-        with self._lock:
-            if not self.unet_images:
-                return 0.0
-            return self.unet_flops_total / self.unet_images
 
     def summary(self) -> Dict:
         with self._lock:
@@ -202,11 +179,7 @@ class DispatchMetrics:
                 "avg_padding_ratio": (self.padding_ratio_total
                                       / self.padding_ratio_count
                                       if self.padding_ratio_count else None),
-                "unet_flops_total": self.unet_flops_total,
                 "unet_images": self.unet_images,
-                "unet_flops_per_image": (self.unet_flops_total
-                                         / self.unet_images
-                                         if self.unet_images else None),
                 # per-precision dispatch mix (flows into /internal/status
                 # under serving.precision; ISSUE 7 observability)
                 "precision": {

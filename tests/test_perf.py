@@ -2,7 +2,7 @@
 
 Covers the device-time-attribution layer end to end on CPU:
 
-- ledger math (MFU against a forced peak, padding ratio/waste, bounded
+- ledger math (padding ratio/waste, dispatch counts, bounded
   group rings, SLO attainment + burn rate) on fresh ``PerfLedger``s;
 - the executable census against the contracted <=2 step-cache x <=3
   precision budget, driven by REAL mixed cadence+precision traffic
@@ -78,7 +78,7 @@ def clean_ledger():
 
 def _record_one(led, **kw):
     args = dict(bucket="64x64", cadence=1, precision="bf16",
-                device_s=2.0, flops=1e12, requests=2, batch_raw=2,
+                device_s=2.0, requests=2, batch_raw=2,
                 batch_run=4, true_pixels=3000, padded_pixels=4000)
     args.update(kw)
     led.record_dispatch(**args)
@@ -87,26 +87,16 @@ def _record_one(led, **kw):
 # -- ledger math -------------------------------------------------------------
 
 class TestLedgerMath:
-    def test_mfu_against_table_peak(self, monkeypatch):
-        # 197e12 FLOPs over 2 s on a device whose kind the table knows
-        # (197e12 FLOP/s): MFU 0.5 exactly, deterministic on any host
+    def test_padding_and_dispatch_counts(self, monkeypatch):
         monkeypatch.setenv("SDTPU_PERF", "1")
         monkeypatch.setattr(perf, "_device_kind", lambda: "TPU v5 lite")
         led = perf.PerfLedger(max_groups=8)
-        _record_one(led, flops=197e12)
+        _record_one(led)
         (g,) = led.summary()["groups"]
         assert g["bucket"] == "64x64"
-        assert g["mfu"] == pytest.approx(0.5)
         assert g["padding_ratio"] == pytest.approx(4000 / 3000)
         assert g["padding_waste"] == pytest.approx(0.25)
         assert g["dispatches"] == 1 and g["requests"] == 2
-
-    def test_cpu_never_fabricates_mfu(self, monkeypatch):
-        monkeypatch.setenv("SDTPU_PERF", "1")
-        led = perf.PerfLedger(max_groups=8)
-        _record_one(led)
-        (g,) = led.summary()["groups"]
-        assert g["mfu"] is None          # unknown hardware: null, not 0
 
     def test_disabled_is_a_noop(self, monkeypatch):
         monkeypatch.delenv("SDTPU_PERF", raising=False)

@@ -121,39 +121,6 @@ class TestResolve:
         assert sc == stepcache.StepCacheSpec(1, 0.0)
 
 
-class TestPlanSchedule:
-    def test_cadence_one_refreshes_every_step(self):
-        c = stepcache.plan_schedule([(0, 4, True)], cadence=1, cfg_stop=4,
-                                    evals_per_step=1, total_steps=4)
-        assert c["refreshes"] == 4
-        assert c["deep_full"] == 4
-        assert c["reuse_full_evals"] == 4
-        assert c["full_evals"] == c["deep_trunc"] == 0
-
-    def test_second_order_sampler_skips_final_midpoint(self):
-        # Heun: 2 evals per step except the final step (sigma_next == 0)
-        c = stepcache.plan_schedule([(0, 4, True)], cadence=2, cfg_stop=4,
-                                    evals_per_step=2, total_steps=4)
-        assert c["reuse_full_evals"] == 2 + 2 + 2 + 1
-        assert c["refreshes"] == 2  # i = 0, 2
-
-    def test_uncached_chunk_invalidates(self):
-        chunks = [(0, 2, True), (2, 2, False), (4, 2, True)]
-        c = stepcache.plan_schedule(chunks, cadence=4, cfg_stop=6,
-                                    evals_per_step=1, total_steps=6)
-        # step 0 refreshes (fresh range), steps 2-3 run the plain
-        # executable, step 4 refreshes AGAIN on cache re-entry
-        assert c["refreshes"] == 2
-        assert c["full_evals"] == 2
-        assert c["reuse_full_evals"] == 4
-
-    def test_truncation_split(self):
-        c = stepcache.plan_schedule([(0, 4, True)], cadence=1, cfg_stop=2,
-                                    evals_per_step=1, total_steps=4)
-        assert c["deep_full"] == 2 and c["deep_trunc"] == 2
-        assert c["reuse_full_evals"] == 2 and c["reuse_trunc_evals"] == 2
-
-
 class TestServingGroupKey:
     """Coalesced requests share ONE denoise range, so the resolved
     step-cache knobs must be part of the dispatcher's group key."""
@@ -264,21 +231,6 @@ class TestCacheCorrectness:
         armed["on"] = False
         again = eng2.txt2img(_payload(override_settings=ov))
         assert again.images == ref.images
-
-    def test_flops_metrics_recorded_and_cut(self, engine):
-        METRICS.clear()
-        engine.txt2img(_payload())
-        plain = METRICS.unet_flops_per_image()
-        assert plain and plain > 0
-        assert METRICS.unet_images == 2
-
-        METRICS.clear()
-        engine.txt2img(_payload(
-            override_settings={"deepcache": 3, "cfg_cutoff": 2.0}))
-        cached = METRICS.unet_flops_per_image()
-        assert cached and cached < plain
-        s = METRICS.summary()
-        assert s["unet_flops_per_image"] == pytest.approx(cached)
 
 
 @pytest.mark.slow

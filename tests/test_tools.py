@@ -51,71 +51,6 @@ class TestSweepRehearsal:
         assert "wall_s" in row
 
 
-class TestFlopsReport:
-    """tools/flops_report.py: the static step-cache pricing grid. The
-    XLA cost-analysis pricing itself is exercised by the (slow)
-    test_stepcache FLOPs-metrics test; here the accountant is stubbed so
-    the schedule arithmetic and report shape stay tier-1 fast."""
-
-    @pytest.fixture()
-    def report(self, monkeypatch):
-        import types
-
-        import flops_report
-        from stable_diffusion_webui_distributed_tpu.models import (
-            configs as C,
-        )
-        from stable_diffusion_webui_distributed_tpu.pipeline import (
-            stepcache,
-        )
-        from stable_diffusion_webui_distributed_tpu.samplers import (
-            schedules as sched,
-        )
-
-        fake_engine = types.SimpleNamespace(
-            family=C.TINY, schedule=sched.sd_schedule())
-        monkeypatch.setattr(flops_report, "_engine",
-                            lambda family: fake_engine)
-
-        real_request_flops = stepcache.FlopsAccountant.request_flops
-
-        class StubAccountant:
-            # rows-proportional pricing: reuse and deep each cost a
-            # fraction of the full forward (reuse + deep ~= full)
-            def __init__(self, engine):
-                pass
-
-            def eval_flops(self, rows, lat_h, lat_w, ctx_len, mode,
-                           precision=""):
-                scale = {None: 1.0, "reuse": 0.45, "deep": 0.55}[mode]
-                return rows * lat_h * lat_w * scale * 1e6
-
-            request_flops = real_request_flops
-
-        monkeypatch.setattr(flops_report.stepcache, "FlopsAccountant",
-                            StubAccountant)
-        return flops_report.build_report(steps=8, families=(C.TINY,))
-
-    def test_cut_ordering(self, report):
-        cells = report["families"][0]["settings"]
-        assert cells["off"]["cut_pct"] == 0.0
-        cuts = [cells[k]["cut_pct"]
-                for k in ("cadence2", "cadence3", "cadence3+cutoff")]
-        assert all(c > 0 for c in cuts)
-        assert cuts == sorted(cuts)  # each lever cuts strictly deeper
-
-    def test_schedule_counts_cover_all_steps(self, report):
-        for label, cell in report["families"][0]["settings"].items():
-            sched_counts = cell["schedule"]
-            reuse_or_full = (sched_counts["full_evals"]
-                             + sched_counts["reuse_full_evals"]
-                             + sched_counts["reuse_trunc_evals"])
-            assert reuse_or_full == 8, label  # Euler: 1 eval per step
-
-    def test_report_is_json_serializable(self, report):
-        assert json.loads(json.dumps(report)) == report
-
-
 class TestTraceReport:
     """tools/trace_report.py: span-tree rendering and the slowest-span
     roll-up over the /internal/trace.json artifact shape."""
