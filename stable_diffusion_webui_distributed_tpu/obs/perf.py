@@ -459,33 +459,32 @@ def census_from_keys(keys: Iterable[Tuple],
                      lora_budget: int = LORA_BUDGET
                      ) -> Dict[str, Any]:
     """Group compiled-stage cache keys by shape bucket and check the
-    chunk-executable budget. Chunk keys are ``("chunk", sampler, steps,
-    w, h, batch, ..., lora_sig, step_cache, precision)``
-    (pipeline/engine.py) — everything between the kind and the last three
-    axes identifies the bucket; the trailing axes are the budgeted
-    variants. The lora_sig axis ("" adapterless, ``"lora:rXsY"`` per
-    traced ladder cell) is recognized by its string shape, so older key
-    layouts (no lora axis) census exactly as before. The lora allowance
-    is PER CELL, not per adapter — any number of adapter combos share a
-    cell's executables, which is the recompile-free serving contract."""
+    chunk-executable budget. A chunk key is a ``pipeline/denoise.py:Variant``
+    (read by name through its ``parse_key``): the step-cache bit, the
+    precision and the traced-LoRA cell (``lora_sig``: "" adapterless,
+    ``"lora:rXsY"`` per ladder cell) are the budgeted variants, every other
+    field identifies the bucket. The lora allowance is PER CELL, not per
+    adapter — any number of adapter combos share a cell's executables,
+    which is the recompile-free serving contract."""
+    from stable_diffusion_webui_distributed_tpu.pipeline.denoise import (
+        parse_key,
+    )
+
     buckets: "OrderedDict[Tuple, Dict[str, Any]]" = OrderedDict()
     other = 0
     total_chunks = 0
     for k in keys:
-        if not (isinstance(k, tuple) and len(k) >= 8 and k[0] == "chunk"):
+        v = parse_key(k)
+        if v is None:
             other += 1
             continue
         total_chunks += 1
-        lora_v = ""
-        ident = k[1:-2]
-        if isinstance(k[-3], str) and (k[-3] == ""
-                                       or k[-3].startswith("lora:")):
-            lora_v = k[-3]
-            ident = k[1:-3]
+        ident = v._replace(lora_sig="", step_cache=False, precision="")
         b = buckets.get(ident)
         if b is None:
             b = {
-                "bucket": f"{k[1]}/{k[2]}st {k[3]}x{k[4]} b{k[5]}",
+                "bucket": f"{v.sampler}/{v.steps}st {v.width}x{v.height} "
+                          f"b{v.batch}",
                 "executables": 0,
                 "step_cache_variants": set(),
                 "precision_variants": set(),
@@ -493,9 +492,9 @@ def census_from_keys(keys: Iterable[Tuple],
             }
             buckets[ident] = b
         b["executables"] += 1
-        b["step_cache_variants"].add(k[-2])
-        b["precision_variants"].add(str(k[-1]))
-        b["lora_variants"].add(lora_v)
+        b["step_cache_variants"].add(v.step_cache)
+        b["precision_variants"].add(str(v.precision))
+        b["lora_variants"].add(v.lora_sig)
     rows: List[Dict[str, Any]] = []
     over: List[str] = []
     for b in buckets.values():

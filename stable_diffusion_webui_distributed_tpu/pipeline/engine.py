@@ -41,14 +41,13 @@ from stable_diffusion_webui_distributed_tpu.models.unet import (
 )
 from stable_diffusion_webui_distributed_tpu.models.vae import VAE
 from stable_diffusion_webui_distributed_tpu.parallel.sharding import (
-    batch_concat,
     channel_concat,
 )
 from stable_diffusion_webui_distributed_tpu.models.tokenizer import load_tokenizer
 from stable_diffusion_webui_distributed_tpu.pipeline import (
     precision as precision_mod,
 )
-from stable_diffusion_webui_distributed_tpu.pipeline import stepcache
+from stable_diffusion_webui_distributed_tpu.pipeline import denoise, stepcache
 from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
     GenerationPayload,
     GenerationResult,
@@ -308,11 +307,11 @@ class Engine:
                           height: int, batch: int) -> bool:
         """Is a chunk executable for this (payload, batch) bucket already
         compiled? Drives the pad-and-drop remainder policy."""
-        with self._cache_lock:
-            return any(
-                k[0] == "chunk" and k[1] == sampler and k[2] == steps
-                and k[3] == width and k[4] == height and k[5] == batch
-                for k in self._cache)
+        want = (sampler, steps, width, height, batch)
+        return any(
+            v is not None
+            and (v.sampler, v.steps, v.width, v.height, v.batch) == want
+            for v in map(denoise.parse_key, self.executable_keys()))
 
     def _modules_for(self, precision_name: str) -> Tuple[Any, Any]:
         """(UNet, ControlNet) module pair for a resolved precision name.
@@ -411,445 +410,31 @@ class Engine:
         key = ("encode",) if not lora_sig else ("encode", lora_sig)
         return self._cached(key, build, static_argnums=(4,))
 
-    def _make_denoise_fn(self, unet_tree, ctx_u, ctx_c, cfg_scale,
-                         added_u, added_c, controls=(), total_steps=1,
-                         inpaint_cond=None, unet=None, controlnet=None,
-                         ragged=None, lora=None, residuals_in=None):
-        """Closure: x0-prediction denoiser with classifier-free guidance and
-        optional ControlNet residual injection.
-
-        ``controls``: tuple of (cn_params, hint(B,H,W,3), weight, g_start,
-        g_end) — residuals from every unit are summed, each gated by its
-        guidance step-fraction window (webui unit semantics; the reference
-        serializes exactly these fields, control_net.py:20-79).
-
-        ``unet``/``controlnet`` select a precision module variant
-        (:meth:`_modules_for`); None keeps the policy-default modules.
-
-        ``ragged``: ``(true_rows, ctx_true_u, ctx_true_c)`` traced (B,)
-        int32 vectors for ragged dispatch — valid latent rows per batch
-        row plus valid context tokens per CFG half. The CFG batch doubling
-        duplicates ``true_rows`` and interleaves the two context lengths
-        exactly like the contexts themselves.
-
-        ``lora``: per-row [B, slots, ...] traced delta tree for the UNet
-        component (models/lora.py) — doubled along the batch axis here so
-        each image's adapter set rides both of its CFG rows; None (the
-        default trace) leaves the graph byte-identical.
-
-        ``residuals_in``: already-computed ControlNet residual tuple fed
-        in as a stage input (the stage-graph executor evaluates the
-        ControlNet tower one sigma-step ahead on its own mesh slice,
-        _denoise_range_staged_cn) — mutually exclusive with ``controls``;
-        None (the default trace) leaves the graph byte-identical."""
-        unet = unet if unet is not None else self.unet
-        controlnet = (controlnet if controlnet is not None
-                      else self.controlnet_module)
-        unet_params = {"params": unet_tree}
-        lora2 = (None if lora is None else jax.tree_util.tree_map(
-            lambda a: batch_concat([a, a]), lora))
-        v_pred = self.schedule.prediction_type == "v_prediction"
-
-        def denoise(x, sigma, step):
-            B = x.shape[0]
-            c_in = 1.0 / jnp.sqrt(sigma**2 + 1.0)
-            t = self.schedule.sigma_to_t(sigma)
-            xin = (x * c_in).astype(x.dtype)
-            # batch_concat, not jnp.concatenate: x may arrive dp-sharded
-            # and the partitioner mis-lowers a batch-axis concatenate on
-            # multi-axis meshes (parallel/sharding.py:batch_concat)
-            both = batch_concat([xin, xin])
-            tb = jnp.full((2 * B,), t, jnp.float32)
-            ctx = batch_concat([
-                jnp.broadcast_to(ctx_u, (B,) + ctx_u.shape[1:]),
-                jnp.broadcast_to(ctx_c, (B,) + ctx_c.shape[1:]),
-            ])
-            added = None
-            if added_u is not None:
-                added = batch_concat([
-                    jnp.broadcast_to(added_u, (B,) + added_u.shape[1:]),
-                    jnp.broadcast_to(added_c, (B,) + added_c.shape[1:]),
-                ])
-
-            residuals = residuals_in
-            frac = (step.astype(jnp.float32) + 0.5) / total_steps
-            for cn_params, hint, weight, g_start, g_end in controls:
-                gate = jnp.where(
-                    (frac >= g_start) & (frac <= g_end), weight, 0.0
-                ).astype(jnp.float32)
-                hint_b = jnp.broadcast_to(hint, (B,) + hint.shape[1:])
-                hint2 = batch_concat([hint_b, hint_b])
-                rs = controlnet.apply(
-                    {"params": cn_params}, both, tb, ctx, hint2, added)
-                rs = tuple(r.astype(jnp.float32) * gate for r in rs)
-                residuals = rs if residuals is None else tuple(
-                    a + b for a, b in zip(residuals, rs))
-
-            unet_in = both
-            if inpaint_cond is not None:
-                # inpainting-specialized model (ldm hybrid conditioning):
-                # [latent, mask, masked-image latent] per CFG branch.
-                # ControlNet above still sees the bare 4-channel input.
-                cond2 = batch_concat(
-                    [inpaint_cond, inpaint_cond]).astype(both.dtype)
-                unet_in = channel_concat([both, cond2])
-            ragged_kw = {}
-            if ragged is not None:
-                true_rows, ctx_true_u, ctx_true_c = ragged
-                ragged_kw = {
-                    "true_rows": batch_concat([true_rows, true_rows]),
-                    "ctx_true": batch_concat([ctx_true_u, ctx_true_c]),
-                }
-            out = unet.apply(unet_params, unet_in, tb, ctx, added,
-                             control_residuals=residuals, lora=lora2,
-                             **ragged_kw)
-            out_u, out_c = jnp.split(out.astype(jnp.float32), 2, axis=0)
-            guided = out_u + cfg_scale * (out_c - out_u)
-            if v_pred:
-                c_skip = 1.0 / (sigma**2 + 1.0)
-                c_out = sigma / jnp.sqrt(sigma**2 + 1.0)
-                return x * c_skip - guided * c_out
-            return x - sigma * guided
-
-        return denoise
-
-    def _chunk_fn(self, sampler_name: str, steps: int, width: int,
-                  height: int, batch: int, length: int,
-                  masked: bool, n_controls: int = 0,
-                  inpaint: bool = False,
-                  ragged: bool = False,
-                  step_cache: bool = False,
-                  precision: str = "",
-                  lora_sig: str = "") -> Callable:
-        """Compiled scan over ``length`` sampler steps starting at a traced
-        index. Cache key excludes prompt/seed/cfg — those are data.
-
-        ``step_cache`` selects the step-cache variant (deep-feature reuse
-        + CFG truncation, pipeline/stepcache.py): the refresh cadence and
-        the cutoff step index travel as traced data, so the on/off bit is
-        its only static key component. ``precision`` is the resolved
-        serving precision name (pipeline/precision.py) — necessarily
-        static (int8 is different HLO) but bounded to the 3-rung ladder,
-        and the int8 activation scales are traced data inside the
-        executable (dynamic per-tensor, ops/quant.py), so a shape bucket
-        mints at most 2 step-cache × 3 precision chunk executables.
-        ControlNet chunks never take the cached path (the chunk loop
-        routes active-CN windows to the plain executable).
-
-        ``ragged`` selects the ragged-dispatch variant: per-row
-        ``true_rows``/``ctx_true_u``/``ctx_true_c`` length vectors are
-        TRACED trailing arguments (lengths must never enter this key —
-        a static length would re-fragment the executable cache back into
-        the ladder; sdtpu-lint RC001 fixture ``ragged_bad.py``), and the
-        sampler step re-zeroes latent rows past ``true_rows`` so
-        ancestral noise injection cannot leak into the masked tail. The
-        ragged bit sits BEFORE the lora/step_cache/precision axes so the
-        census parser (obs/perf.py census_from_keys) keeps attributing
-        budget per bucket identity.
-
-        ``lora_sig`` (SDTPU_LORA_TRACED): "" or ``lora:r{rb}s{sc}``
-        (models/lora.py TracedSet.sig). Non-empty sigs add a trailing
-        per-row ``[B, slots, ...]`` delta tree as traced data — adapter
-        NAMES, WEIGHTS and exact RANKS never enter this key (sdtpu-lint
-        RC001 fixture ``lora_bad.py``), so one executable per
-        (rank_bucket, slot_count) cell serves every adapter combo and an
-        adapter switch costs zero compiles. Empty sig traces with the
-        unpassed-default ``lora=None``, which folds the delta branches
-        away entirely — the gate-off executable is byte-identical.
-
-        Both variants return ``(carry..., fence)`` where ``fence`` is a
-        tiny data-dependent output: the host paces progress/interrupt on
-        it because the carry's INPUT buffers are donated into the next
-        chunk (dead after each dispatch — donating halves peak latent
-        HBM) and must not be touched once a later chunk is in flight."""
-        spec = kd.resolve_sampler(sampler_name)
+    def _denoise_fn(self, kind: str, sampler_name: str = "", steps: int = 1,
+                    width: int = 0, height: int = 0, batch: int = 0,
+                    length: int = 1, precision: str = "",
+                    **static) -> Callable:
+        """The compiled denoise executable of one static variant
+        (pipeline/denoise.py:Variant, whose tuple is the cache key; prompt,
+        seed and cfg are data). ``precision`` is a resolved or requested
+        serving precision name; the policy default's module pair IS the
+        constructor-built one, so a request that names nothing routes to
+        the unchanged executables."""
         prec = precision_mod.bucket_precision(
             precision, self._default_precision.name)
-        unet, cn_module = self._modules_for(prec)
-        key = ("chunk", sampler_name, steps, width, height, batch, length,
-               masked, n_controls, inpaint, self.family.name, ragged,
-               lora_sig, step_cache, prec)
-        if step_cache:
-            assert not ragged, "ragged chunks disable the step cache"
-            return self._cached(key, lambda: self._build_stepcache_chunk(
-                spec, steps, batch, length, masked, inpaint, unet=unet))
-        if ragged:
-            def build_ragged():
-                sigmas = kd.build_sigmas(spec, self.schedule, steps)
-
-                def run_chunk(unet_params, carry, start, ctx_u, ctx_c, cfg,
-                              image_keys, added_u, added_c, true_rows,
-                              ctx_true_u, ctx_true_c, lora=None):
-                    denoise = self._make_denoise_fn(
-                        unet_params, ctx_u, ctx_c, cfg, added_u, added_c,
-                        total_steps=steps, unet=unet, controlnet=cn_module,
-                        ragged=(true_rows, ctx_true_u, ctx_true_c),
-                        lora=lora)
-                    base_step = kd.make_sampler_step(
-                        spec, denoise, sigmas, image_keys)
-                    lat_h = carry.x.shape[1]
-                    row_mask = (jnp.arange(lat_h, dtype=jnp.int32)[None, :]
-                                < true_rows[:, None])[:, :, None, None]
-
-                    def step(carry, i):
-                        carry2, _ = base_step(carry, i)
-                        # ancestral samplers inject fresh noise everywhere;
-                        # re-zero the masked tail so padded rows stay
-                        # exactly 0 into every conv of the next step —
-                        # the row-independence invariant solo==group
-                        # byte identity rests on
-                        carry2 = carry2._replace(
-                            x=jnp.where(row_mask, carry2.x, 0.0))
-                        return carry2, ()
-
-                    idx = start + jnp.arange(length)
-                    carry, _ = jax.lax.scan(step, carry, idx)
-                    return carry, carry.x.reshape(-1)[:1]
-
-                return jax.jit(run_chunk, donate_argnums=(1,))
-
-            return self._cached(key, build_ragged)
-
-        def build():
-            sigmas = kd.build_sigmas(spec, self.schedule, steps)
-
-            def run_chunk(unet_params, carry, start, ctx_u, ctx_c, cfg,
-                          image_keys, added_u, added_c, mask_lat, init_lat,
-                          controls, inpaint_cond, lora=None):
-                denoise = self._make_denoise_fn(
-                    unet_params, ctx_u, ctx_c, cfg, added_u, added_c,
-                    controls=controls, total_steps=steps,
-                    inpaint_cond=inpaint_cond if inpaint else None,
-                    unet=unet, controlnet=cn_module, lora=lora)
-                base_step = kd.make_sampler_step(
-                    spec, denoise, sigmas, image_keys)
-
-                def step(carry, i):
-                    carry2, _ = base_step(carry, i)
-                    if masked:
-                        # inpaint: keep unmasked regions pinned to the init
-                        # latent re-noised to the *next* sigma level.
-                        def renoise(k):
-                            return jax.random.normal(
-                                jax.random.fold_in(k, 1_000_000 + i),
-                                init_lat.shape[1:], jnp.float32)
-
-                        noise = jax.vmap(renoise)(image_keys)
-                        pinned = init_lat + noise * sigmas[i + 1]
-                        x = mask_lat * carry2.x + (1 - mask_lat) * pinned
-                        carry2 = carry2._replace(x=x)
-                    return carry2, ()
-
-                idx = start + jnp.arange(length)
-                carry, _ = jax.lax.scan(step, carry, idx)
-                return carry, carry.x.reshape(-1)[:1]
-
-            return jax.jit(run_chunk, donate_argnums=(1,))
-
-        return self._cached(key, build)
-
-    def _build_stepcache_chunk(self, spec, steps: int, batch: int,
-                               length: int, masked: bool,
-                               inpaint: bool, unet=None) -> Callable:
-        """Step-cache chunk executable (see _chunk_fn / stepcache.py).
-
-        Scan state is (sampler carry, deep-feature cache, valid bit). The
-        deep feature — everything below models/unet.py:CACHE_SPLIT plus
-        the mid block — is refreshed BEFORE the sampler step whenever the
-        bit is unset or the absolute step index lands on the cadence, so
-        every UNet eval that step makes (Heun's midpoint included) rides
-        the shallow reuse path against a feature computed from the step's
-        own entry latent. The cache always holds [uncond; cond] rows: a
-        CFG-truncated refresh computes the cond half only and mirrors it,
-        so crossing the cutoff never changes buffer shapes. Cadence and
-        cutoff are traced int32 scalars (``lax.cond`` picks the variant
-        per step); carry and cache are donated — dead after each chunk."""
-        unet = unet if unet is not None else self.unet
-        sigmas = kd.build_sigmas(spec, self.schedule, steps)
-        v_pred = self.schedule.prediction_type == "v_prediction"
-        B = batch
-
-        def run_chunk(unet_params, carry, cache, valid, start, ctx_u,
-                      ctx_c, cfg, image_keys, added_u, added_c, mask_lat,
-                      init_lat, inpaint_cond, cadence, cfg_stop,
-                      lora=None):
-            params = {"params": unet_params}
-            # traced adapter deltas (models/lora.py): the [B, ...] per-row
-            # tree serves the CFG-truncated cond-only paths; the full
-            # paths run [uncond; cond] rows, so double it like the latent
-            lora2 = (None if lora is None else jax.tree_util.tree_map(
-                lambda a: batch_concat([a, a]), lora))
-
-            def prep(x, sigma):
-                c_in = 1.0 / jnp.sqrt(sigma**2 + 1.0)
-                return (x * c_in).astype(x.dtype), \
-                    self.schedule.sigma_to_t(sigma)
-
-            def full_inputs(xin, t):
-                # batch_concat: the carry latent is dp-sharded under a
-                # mesh and a batch-axis jnp.concatenate mis-partitions
-                # there (parallel/sharding.py:batch_concat)
-                both = batch_concat([xin, xin])
-                tb = jnp.full((2 * B,), t, jnp.float32)
-                ctx = batch_concat([
-                    jnp.broadcast_to(ctx_u, (B,) + ctx_u.shape[1:]),
-                    jnp.broadcast_to(ctx_c, (B,) + ctx_c.shape[1:]),
-                ])
-                added = None
-                if added_u is not None:
-                    added = batch_concat([
-                        jnp.broadcast_to(added_u, (B,) + added_u.shape[1:]),
-                        jnp.broadcast_to(added_c, (B,) + added_c.shape[1:]),
-                    ])
-                if inpaint:
-                    cond2 = batch_concat(
-                        [inpaint_cond, inpaint_cond]).astype(both.dtype)
-                    both = channel_concat([both, cond2])
-                return both, tb, ctx, added
-
-            def cond_inputs(xin, t):
-                # CFG-truncated half: cond rows only, uncond branch dropped
-                tb = jnp.full((B,), t, jnp.float32)
-                ctx = jnp.broadcast_to(ctx_c, (B,) + ctx_c.shape[1:])
-                added = None
-                if added_u is not None:
-                    added = jnp.broadcast_to(
-                        added_c, (B,) + added_c.shape[1:])
-                xi = xin
-                if inpaint:
-                    xi = channel_concat(
-                        [xin, inpaint_cond.astype(xin.dtype)])
-                return xi, tb, ctx, added
-
-            def step(state, i):
-                carry, cache, valid = state
-                sigma = sigmas[i]
-                xin, t = prep(carry.x, sigma)
-                refresh = jnp.logical_or(
-                    jnp.logical_not(valid), jnp.mod(i, cadence) == 0)
-
-                def do_refresh(_):
-                    def deep_full(_):
-                        xi, tb, ctx, added = full_inputs(xin, t)
-                        return unet.apply(params, xi, tb, ctx, added,
-                                          cache_mode="deep", lora=lora2)
-
-                    def deep_trunc(_):
-                        xi, tb, ctx, added = cond_inputs(xin, t)
-                        d = unet.apply(params, xi, tb, ctx, added,
-                                       cache_mode="deep", lora=lora)
-                        return batch_concat([d, d])
-
-                    return jax.lax.cond(i >= cfg_stop, deep_trunc,
-                                        deep_full, None).astype(cache.dtype)
-
-                new_cache = jax.lax.cond(
-                    refresh, do_refresh, lambda _: cache, None)
-
-                def denoise(x, sigma_e, step_i):
-                    xe, te = prep(x, sigma_e)
-
-                    def eval_full(_):
-                        xi, tb, ctx, added = full_inputs(xe, te)
-                        out = unet.apply(
-                            params, xi, tb, ctx, added,
-                            cache=new_cache, cache_mode="reuse",
-                            lora=lora2)
-                        out_u, out_c = jnp.split(
-                            out.astype(jnp.float32), 2, axis=0)
-                        return out_u + cfg * (out_c - out_u)
-
-                    def eval_trunc(_):
-                        xi, tb, ctx, added = cond_inputs(xe, te)
-                        out = unet.apply(
-                            params, xi, tb, ctx, added,
-                            cache=new_cache[B:], cache_mode="reuse",
-                            lora=lora)
-                        return out.astype(jnp.float32)
-
-                    guided = jax.lax.cond(step_i >= cfg_stop, eval_trunc,
-                                          eval_full, None)
-                    if v_pred:
-                        c_skip = 1.0 / (sigma_e**2 + 1.0)
-                        c_out = sigma_e / jnp.sqrt(sigma_e**2 + 1.0)
-                        return x * c_skip - guided * c_out
-                    return x - sigma_e * guided
-
-                base_step = kd.make_sampler_step(
-                    spec, denoise, sigmas, image_keys)
-                carry2, _ = base_step(carry, i)
-                if masked:
-                    # same unmasked-region pinning (and noise domain) as
-                    # the plain chunk — cadence must not move inpaint RNG
-                    def renoise(k):
-                        return jax.random.normal(
-                            jax.random.fold_in(k, 1_000_000 + i),
-                            init_lat.shape[1:], jnp.float32)
-
-                    noise = jax.vmap(renoise)(image_keys)
-                    pinned = init_lat + noise * sigmas[i + 1]
-                    xp = mask_lat * carry2.x + (1 - mask_lat) * pinned
-                    carry2 = carry2._replace(x=xp)
-                return (carry2, new_cache, jnp.full_like(valid, True)), ()
-
-            idx = start + jnp.arange(length)
-            (carry, cache, valid), _ = jax.lax.scan(
-                step, (carry, cache, valid), idx)
-            return carry, cache, valid, carry.x.reshape(-1)[:1]
-
-        return jax.jit(run_chunk, donate_argnums=(1, 2))
-
-    def _adaptive_attempt_fn(self, width: int, height: int, batch: int,
-                             n_controls: int = 0,
-                             inpaint: bool = False,
-                             precision: str = "") -> Callable:
-        """Compiled DPM-adaptive attempt (kd.make_adaptive_attempt): 3 CFG
-        UNet evals + embedded-pair error norm in ONE dispatch, with the
-        log-sigma position/step (s, h) as traced data — the whole adaptive
-        trajectory reuses a single executable (per resolved precision)."""
-        prec = precision_mod.bucket_precision(
-            precision, self._default_precision.name)
-        unet, cn_module = self._modules_for(prec)
-        key = ("adaptive", width, height, batch, n_controls, inpaint,
-               self.family.name, prec)
-
-        def build():
-            def run(unet_params, x, x_prev, s, h, rtol, atol, ctx_u, ctx_c,
-                    cfg, added_u, added_c, controls, inpaint_cond):
-                denoise = self._make_denoise_fn(
-                    unet_params, ctx_u, ctx_c, cfg, added_u, added_c,
-                    controls=controls, total_steps=1,
-                    inpaint_cond=inpaint_cond if inpaint else None,
-                    unet=unet, controlnet=cn_module)
-                return kd.make_adaptive_attempt(denoise)(
-                    x, x_prev, s, h, rtol, atol)
-
-            return jax.jit(run)
-
-        return self._cached(key, build)
-
-    def _adaptive_pin_fn(self) -> Callable:
-        """Inpaint region pinning after an accepted adaptive step: unmasked
-        area re-noised to the accepted sigma (the adaptive-path analogue of
-        the per-step pinning in _chunk_fn). Noise domain 2_000_000+n keeps
-        it disjoint from the fixed-grid path's 1_000_000+i keys."""
-        key = ("adaptive-pin", self.family.name)
-
-        def build():
-            def pin(x, mask_lat, init_lat, image_keys, sigma, n):
-                def renoise(k):
-                    return jax.random.normal(
-                        jax.random.fold_in(
-                            jax.random.fold_in(k, 2_000_000), n),
-                        init_lat.shape[1:], jnp.float32)
-
-                noise = jax.vmap(renoise)(image_keys)
-                return mask_lat * x + (1 - mask_lat) * (init_lat
-                                                        + noise * sigma)
-
-            return jax.jit(pin)
-
-        return self._cached(key, build)
+        variant = denoise.Variant(
+            kind, sampler_name, steps, width, height, batch, length,
+            family=self.family.name, precision=prec, **static)
+        denoise.check(variant)
+        unet, controlnet = self._modules_for(prec)
+        key, mesh = variant.key(), None
+        if kind == "cnres":
+            # the mesh this stage runs on: its own slice, else the engine's
+            cn_mesh = self._stage_cn_mesh()
+            mesh = cn_mesh or self.mesh
+            key += (0 if cn_mesh is None else cn_mesh.size,)
+        deps = denoise.Deps(unet, controlnet, self.schedule, mesh)
+        return self._cached(key, lambda: denoise.build(variant, deps))
 
     def _denoise_adaptive(self, payload, x, image_keys, conds, pooleds,
                           width, height, start_step, steps, job,
@@ -890,8 +475,10 @@ class Engine:
         batch = x.shape[0]
         cfg = jnp.float32(payload.cfg_scale)
         inpainting = self.family.inpaint and inpaint_cond is not None
-        inp_arg = inpaint_cond if inpainting else jnp.float32(0)
         masked = mask_lat is not None
+        inputs = denoise.Inputs(
+            ctx_u, ctx_c, cfg, added_u=au, added_c=ac,
+            inpaint_cond=inpaint_cond if inpainting else None)
         # Guidance-window gating happens HERE on the host, per attempt: the
         # in-graph gate sees total_steps=1 (frozen fraction 0.5), so each
         # unit's window is widened to (0, 1) in-graph and its WEIGHT is
@@ -923,17 +510,17 @@ class Engine:
                 (p, h, float(w) if gs <= frac <= ge else 0.0, lo, hi)
                 for (p, h, w, lo, hi), (gs, ge) in zip(wide, windows))
 
-        fn = self._adaptive_attempt_fn(
-            width, height, batch, n_controls=len(controls),
-            inpaint=inpainting,
+        fn = self._denoise_fn(
+            "adaptive", width=width, height=height, batch=batch,
+            n_controls=len(controls), inpaint=inpainting,
             precision=precision_mod.resolve(payload, self.policy).name)
+        pin = self._denoise_fn("adaptive-pin") if masked else None
 
         def attempt_fn(xx, x_prev, s, h, rtol, atol):
             with trace.STATS.timer("denoise_chunk"), \
                     obs_spans.span("chunk.enqueue", adaptive=True):
                 return fn(self.params["unet"], xx, x_prev, s, h, rtol, atol,
-                          ctx_u, ctx_c, cfg, au, ac, controls_at(float(s)),
-                          inp_arg)
+                          inputs._replace(controls=controls_at(float(s))))
 
         # progress: accepted steps against the slider value (the controller
         # ignores the slider, so the bar is indicative, like webui's)
@@ -942,9 +529,9 @@ class Engine:
         def on_accept(xx, sigma, n):
             self.state.step(min(n, end - start_step))
             if masked:
-                xx = self._adaptive_pin_fn()(
-                    xx, mask_lat, init_lat, image_keys,
-                    jnp.float32(sigma), jnp.int32(n))
+                # noise domain 2_000_000+n: disjoint from the fixed grid's
+                xx = pin(xx, mask_lat, init_lat, image_keys,
+                         jnp.float32(sigma), jnp.int32(n))
             return xx
 
         x_out, info = kd.sample_dpm_adaptive(
@@ -956,9 +543,8 @@ class Engine:
             # as the CLEAN init latent, exactly like the fixed-grid path's
             # last step (which pins with sigmas[steps] == 0) — without this
             # the whole unmasked area keeps sigma_min-level grain
-            x_out = self._adaptive_pin_fn()(
-                x_out, mask_lat, init_lat, image_keys,
-                jnp.float32(0.0), jnp.int32(0))
+            x_out = pin(x_out, mask_lat, init_lat, image_keys,
+                        jnp.float32(0.0), jnp.int32(0))
         from stable_diffusion_webui_distributed_tpu.runtime.logging import (
             get_logger,
         )
@@ -1787,13 +1373,13 @@ class Engine:
             lora = (ts.sig, ts.content,
                     lora_mod.broadcast_set(ts, batch)["unet"])
         lora_sig, lora_content, lora_rows = lora or ("", "", None)
-        lora_kw = {} if lora_rows is None else {"lora": lora_rows}
-        cfg = jnp.float32(payload.cfg_scale)
         masked = mask_lat is not None
-        mask_arg = mask_lat if masked else jnp.float32(0)
-        init_arg = init_lat if masked else jnp.float32(0)
         inpainting = self.family.inpaint and inpaint_cond is not None
-        inp_arg = inpaint_cond if inpainting else jnp.float32(0)
+        inputs = denoise.Inputs(
+            ctx_u, ctx_c, jnp.float32(payload.cfg_scale), image_keys, au, ac,
+            mask_lat, init_lat if masked else None,
+            inpaint_cond=inpaint_cond if inpainting else None,
+            lora=lora_rows, ragged=ragged)
         carry = kd.init_carry(x)
         end = steps if end_step is None else min(end_step, steps)
 
@@ -1817,9 +1403,6 @@ class Engine:
         cfg_stop = stepcache.cutoff_step(
             np.asarray(kd.build_sigmas(spec, self.schedule, steps)),
             sc.cutoff_sigma)
-        if ragged is not None:
-            assert not masked and not inpainting and not controls, \
-                "ragged dispatch covers the plain txt2img path only"
         use_cache = (sc.active and cache_supported(self.family.unet)
                      and ragged is None)
         cache = valid = None
@@ -1832,6 +1415,8 @@ class Engine:
                                  x.shape[1], x.shape[2]),
                 self.policy.compute_dtype)
             valid = jnp.asarray(False)
+            cached_inputs = inputs._replace(cadence=jnp.int32(sc.cadence),
+                                            cfg_stop=jnp.int32(cfg_stop))
 
         # Denoise prefix sharing (cache/prefix.py, SDTPU_CACHE): only for
         # ranges where a captured prefix can be BYTE-identical — the plain
@@ -1936,35 +1521,25 @@ class Engine:
             # ControlNet windows bypass the step cache: residuals feed the
             # deep blocks, so a stale deep feature would drop them
             cached_chunk = use_cache and not active
-            fn = self._chunk_fn(payload.sampler_name, steps, width, height,
-                                batch, length, masked=masked,
-                                n_controls=len(active), inpaint=inpainting,
-                                ragged=ragged is not None,
-                                step_cache=cached_chunk,
-                                precision=prec.name,
-                                lora_sig=lora_sig)
+            fn = self._denoise_fn(
+                "chunk", payload.sampler_name, steps, width, height, batch,
+                length, prec.name, masked=masked, n_controls=len(active),
+                inpaint=inpainting, ragged=ragged is not None,
+                lora_sig=lora_sig, step_cache=cached_chunk)
+            state, chunk_inputs = carry, inputs._replace(controls=active)
+            if cached_chunk:
+                state = denoise.CachedState(carry, cache, valid)
+                chunk_inputs = cached_inputs
             # denoise_chunk is the enqueue of chunk i plus the wait on chunk
             # i-1's fence: its two children say which
             with trace.STATS.timer("denoise_chunk"):
                 with obs_spans.span("chunk.enqueue", pos=pos, steps=length):
-                    if ragged is not None:
-                        true_rows, ctx_true_u, ctx_true_c = ragged
-                        carry, fence = fn(
-                            self.params["unet"], carry, jnp.int32(pos),
-                            ctx_u, ctx_c, cfg, image_keys, au, ac,
-                            true_rows, ctx_true_u, ctx_true_c, **lora_kw)
-                    elif cached_chunk:
-                        carry, cache, valid, fence = fn(
-                            self.params["unet"], carry, cache, valid,
-                            jnp.int32(pos), ctx_u, ctx_c, cfg, image_keys,
-                            au, ac, mask_arg, init_arg, inp_arg,
-                            jnp.int32(sc.cadence), jnp.int32(cfg_stop),
-                            **lora_kw)
+                    state, fence = fn(self.params["unet"], state,
+                                      jnp.int32(pos), chunk_inputs)
+                    if cached_chunk:
+                        carry, cache, valid = state
                     else:
-                        carry, fence = fn(
-                            self.params["unet"], carry, jnp.int32(pos),
-                            ctx_u, ctx_c, cfg, image_keys, au, ac,
-                            mask_arg, init_arg, active, inp_arg, **lora_kw)
+                        carry = state
                         if valid is not None:
                             # a plain (CN-active) chunk advanced the latent
                             # outside the cache's view — refresh on re-entry
@@ -2283,14 +1858,13 @@ class Engine:
         au, ac = self._added_cond(*pooleds, width, height)
         batch = x.shape[0]
         cfg = jnp.float32(payload.cfg_scale)
-        spec = kd.resolve_sampler(payload.sampler_name)
         prec = precision_mod.resolve(payload, self.policy)
         cn_mesh = self._stage_cn_mesh()
         carry = kd.init_carry(x)
         self.state.begin(job, steps)
 
         # CN-side per-request constants hop to the slice once per range
-        cn_ctx_u, cn_ctx_c, cn_au, cn_ac = ctx_u, ctx_c, au, ac
+        cn_inputs = denoise.Inputs(ctx_u, ctx_c, added_u=au, added_c=ac)
         cn_controls = controls
         if cn_mesh is not None:
             from stable_diffusion_webui_distributed_tpu.parallel import (
@@ -2301,10 +1875,9 @@ class Engine:
             )
 
             cn_controls = jax.device_put(controls, replicated(cn_mesh))
-            cn_ctx_u = stage_graph.to_mesh(ctx_u, cn_mesh, batch=False)
-            cn_ctx_c = stage_graph.to_mesh(ctx_c, cn_mesh, batch=False)
-            cn_au = stage_graph.to_mesh(au, cn_mesh, batch=False)
-            cn_ac = stage_graph.to_mesh(ac, cn_mesh, batch=False)
+            cn_inputs = jax.tree.map(
+                lambda a: stage_graph.to_mesh(a, cn_mesh, batch=False),
+                cn_inputs)
 
         def active_idxs(chunk_pos):
             # the serial loop drops units whose window misses the whole
@@ -2319,9 +1892,9 @@ class Engine:
             idxs = active_idxs((i // self.chunk_size) * self.chunk_size)
             if not idxs:
                 return None
-            resfn = self._cn_residual_fn(
-                payload.sampler_name, steps, width, height, batch,
-                len(idxs), prec.name)
+            resfn = self._denoise_fn(
+                "cnres", payload.sampler_name, steps, width, height, batch,
+                precision=prec.name, n_controls=len(idxs))
             x_cn = x_now
             if cn_mesh is not None:
                 from stable_diffusion_webui_distributed_tpu.parallel import (
@@ -2329,8 +1902,8 @@ class Engine:
                 )
 
                 x_cn = stage_graph.to_mesh(x_now, cn_mesh, batch=True)
-            rs = resfn(x_cn, jnp.int32(i), cn_ctx_u, cn_ctx_c, cn_au,
-                       cn_ac, tuple(cn_controls[k] for k in idxs))
+            rs = resfn(x_cn, jnp.int32(i), cn_inputs._replace(
+                controls=tuple(cn_controls[k] for k in idxs)))
             # Host-side stage-input check: the UNet's traced assert on
             # residual arity only fires inside the step executable, long
             # after the CN-slice dispatch — validate here instead.
@@ -2357,8 +1930,9 @@ class Engine:
                     for r in rs)
             return rs
 
-        stepfn = self._cn_step_fn(payload.sampler_name, steps, width,
-                                  height, batch, prec.name)
+        stepfn = self._denoise_fn("cnstep", payload.sampler_name, steps,
+                                  width, height, batch, precision=prec.name)
+        inputs = denoise.Inputs(ctx_u, ctx_c, cfg, image_keys, au, ac)
         fences = []  # completed-dispatch fences; depth-2 host pacing
         done = 0
         res = residuals_for(carry.x, 0)
@@ -2369,8 +1943,8 @@ class Engine:
             with trace.STATS.timer("denoise_chunk"), \
                     obs_spans.span("chunk.enqueue", pos=i, steps=1):
                 carry, fence = stepfn(
-                    self.params["unet"], carry, jnp.int32(i), ctx_u,
-                    ctx_c, cfg, image_keys, au, ac, res)
+                    self.params["unet"], carry, jnp.int32(i),
+                    inputs._replace(residuals=res))
             fences.append(fence)
             i += 1
             if i < steps:
@@ -2390,112 +1964,6 @@ class Engine:
         # in-flight buffers; finish() only snapshots progress.
         self.state.finish()
         return carry.x
-
-    def _cn_residual_fn(self, sampler_name: str, steps: int, width: int,
-                        height: int, batch: int, n_controls: int,
-                        precision: str) -> Callable:
-        """Compiled ControlNet residual stage: the EXACT CFG input build
-        and control loop from _make_denoise_fn, lifted into its own
-        executable so it can run a step ahead of (and on different
-        devices than) the UNet. Key family ``cnres`` is deliberately not
-        ``chunk``: obs/perf.py census_from_keys counts only chunk keys,
-        so the stage split can never fragment the chunk census
-        (bench_compare gates ``stage_graph_chunk_compiles`` at 0)."""
-        spec = kd.resolve_sampler(sampler_name)
-        prec = precision_mod.bucket_precision(
-            precision, self._default_precision.name)
-        _unet, cn_module = self._modules_for(prec)
-        # the mesh this stage runs on: its own slice, else the engine's
-        cn_mesh = self._stage_cn_mesh()
-        mesh = cn_mesh or self.mesh
-        key = ("cnres", sampler_name, steps, width, height, batch,
-               n_controls, self.family.name, prec,
-               0 if cn_mesh is None else cn_mesh.size)
-
-        def build():
-            sigmas = kd.build_sigmas(spec, self.schedule, steps)
-
-            def rows(a):
-                # pin the CFG-doubled rows to dp: left to propagation, the
-                # partitioner may run this stage replicated while the fused
-                # chunk runs it batch-sharded, and the two then round
-                # differently (seen with jax 0.9.0's Shardy partitioner)
-                if mesh is None or a.shape[0] % mesh.shape["dp"]:
-                    return a
-                return jax.lax.with_sharding_constraint(
-                    a, jax.sharding.NamedSharding(
-                        mesh, jax.sharding.PartitionSpec("dp")))
-
-            def run_res(x, step, ctx_u, ctx_c, added_u, added_c, controls):
-                B = x.shape[0]
-                sigma = sigmas[step]
-                c_in = 1.0 / jnp.sqrt(sigma**2 + 1.0)
-                t = self.schedule.sigma_to_t(sigma)
-                xin = (x * c_in).astype(x.dtype)
-                both = rows(batch_concat([xin, xin]))
-                tb = jnp.full((2 * B,), t, jnp.float32)
-                ctx = batch_concat([
-                    jnp.broadcast_to(ctx_u, (B,) + ctx_u.shape[1:]),
-                    jnp.broadcast_to(ctx_c, (B,) + ctx_c.shape[1:]),
-                ])
-                added = None
-                if added_u is not None:
-                    added = batch_concat([
-                        jnp.broadcast_to(added_u, (B,) + added_u.shape[1:]),
-                        jnp.broadcast_to(added_c, (B,) + added_c.shape[1:]),
-                    ])
-                residuals = None
-                frac = (step.astype(jnp.float32) + 0.5) / steps
-                for cn_params, hint, weight, g_start, g_end in controls:
-                    gate = jnp.where(
-                        (frac >= g_start) & (frac <= g_end), weight, 0.0
-                    ).astype(jnp.float32)
-                    hint_b = jnp.broadcast_to(hint, (B,) + hint.shape[1:])
-                    hint2 = batch_concat([hint_b, hint_b])
-                    rs = cn_module.apply(
-                        {"params": cn_params}, both, tb, ctx, hint2, added)
-                    rs = tuple(r.astype(jnp.float32) * gate for r in rs)
-                    residuals = rs if residuals is None else tuple(
-                        a + b for a, b in zip(residuals, rs))
-                return residuals
-
-            return jax.jit(run_res)
-
-        return self._cached(key, build)
-
-    def _cn_step_fn(self, sampler_name: str, steps: int, width: int,
-                    height: int, batch: int, precision: str) -> Callable:
-        """One-sampler-step executable taking the ControlNet residual
-        tuple as a TRACED stage input (fed to models/unet.py via
-        ``control_residuals``). Same (carry, fence) contract as the chunk
-        executables — the carry is donated, the host paces on the fence.
-        ``cnstep`` is its own key family (never enters the chunk census);
-        the None-residual and tuple-residual pytrees retrace under one
-        cached wrapper, so at most two traces serve a range."""
-        spec = kd.resolve_sampler(sampler_name)
-        prec = precision_mod.bucket_precision(
-            precision, self._default_precision.name)
-        unet, cn_module = self._modules_for(prec)
-        key = ("cnstep", sampler_name, steps, width, height, batch,
-               self.family.name, prec)
-
-        def build():
-            sigmas = kd.build_sigmas(spec, self.schedule, steps)
-
-            def run_step(unet_params, carry, i, ctx_u, ctx_c, cfg,
-                         image_keys, added_u, added_c, residuals):
-                denoise = self._make_denoise_fn(
-                    unet_params, ctx_u, ctx_c, cfg, added_u, added_c,
-                    total_steps=steps, unet=unet, controlnet=cn_module,
-                    residuals_in=residuals)
-                base_step = kd.make_sampler_step(
-                    spec, denoise, sigmas, image_keys)
-                carry, _ = base_step(carry, i)
-                return carry, carry.x.reshape(-1)[:1]
-
-            return jax.jit(run_step, donate_argnums=(1,))
-
-        return self._cached(key, build)
 
     def _stage_cn_mesh(self):
         """Mesh slice for the stage-ahead ControlNet tower

@@ -1,7 +1,8 @@
 """Shape bucketing: bound the engine's compiled-stage cache.
 
 Every novel ``(width, height, batch)`` tuple costs a fresh XLA compile of
-the denoise chunk executable (``Engine._chunk_fn`` keys on exact shapes).
+the denoise chunk executable (``pipeline/denoise.py:Variant`` keys on exact
+shapes).
 Under open traffic that is one compile per unique request shape — the
 dominant serving-latency tax on TPU. The bucketer pads incoming requests
 UP to a small configured ladder of shapes so the cache converges to at

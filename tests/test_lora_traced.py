@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from stable_diffusion_webui_distributed_tpu.models import lora as lora_mod
 from stable_diffusion_webui_distributed_tpu.models.configs import TINY
 from stable_diffusion_webui_distributed_tpu.obs import perf as obs_perf
+from stable_diffusion_webui_distributed_tpu.pipeline import denoise
 from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
 from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
     GenerationPayload,
@@ -378,9 +379,10 @@ class TestCensusLoraBudget:
     def _keys(self, sigs):
         keys = []
         for i, sig in enumerate(sigs):
-            for sc in (1, 2):
-                keys.append(("chunk", "Euler a", 8, 64, 64, 1, sig,
-                             sc, "bf16"))
+            for sc in (False, True):
+                keys.append(denoise.Variant(
+                    "chunk", "Euler a", 8, 64, 64, 1, lora_sig=sig,
+                    step_cache=sc, precision="bf16").key())
         return keys
 
     def test_ladder_cells_within_budget_stay_silent(self):
@@ -396,14 +398,19 @@ class TestCensusLoraBudget:
         census = obs_perf.census_from_keys(self._keys(sigs))
         assert census["alarm"]
 
-    def test_legacy_keys_census_unchanged(self):
-        # pre-lora key layout (no sig axis): nothing looks like a sig,
-        # nothing is attributed to the lora axis
+    def test_adapterless_keys_use_no_lora_allowance(self):
+        census = obs_perf.census_from_keys(self._keys([""]))
+        assert not census["alarm"]
+        assert census["buckets"][0]["lora_variants"] == 0
+
+    def test_a_key_of_another_layout_is_not_a_chunk(self):
+        # one layout, read by name: a tuple that is not a Variant is
+        # counted with the other executables, never guessed at
         keys = [("chunk", "Euler a", 8, 64, 64, 1, sc, "bf16")
                 for sc in (1, 2)]
         census = obs_perf.census_from_keys(keys)
-        assert not census["alarm"]
-        assert census["buckets"][0]["lora_variants"] == 0
+        assert census["chunk_executables"] == 0
+        assert census["other_executables"] == 2
 
 
 class TestWarmupCells:

@@ -32,6 +32,7 @@ from stable_diffusion_webui_distributed_tpu.obs import prometheus as obs_prom
 from stable_diffusion_webui_distributed_tpu.obs.flightrec import (
     FlightRecorder,
 )
+from stable_diffusion_webui_distributed_tpu.pipeline import denoise
 from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
 from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
     GenerationPayload,
@@ -209,8 +210,9 @@ class TestCensus:
 
     def test_synthetic_over_budget_trips_the_alarm(self):
         def key(sc, prec):
-            return ("chunk", "Euler a", 4, 64, 64, 4, 1, False, 0, False,
-                    "sd", sc, prec)
+            return denoise.Variant("chunk", "Euler a", 4, 64, 64, 4, 1,
+                                   family="sd", step_cache=sc,
+                                   precision=prec).key()
 
         keys = [key(False, "bf16"), key(True, "bf16"), key("half", "bf16")]
         census = perf.census_from_keys(keys)

@@ -29,7 +29,7 @@ from stable_diffusion_webui_distributed_tpu.models.configs import TINY
 from stable_diffusion_webui_distributed_tpu.models.unet import (
     deep_cache_shape,
 )
-from stable_diffusion_webui_distributed_tpu.pipeline import stepcache
+from stable_diffusion_webui_distributed_tpu.pipeline import denoise, stepcache
 from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
     GenerationPayload,
 )
@@ -197,11 +197,10 @@ class TestCacheCorrectness:
 
     def test_at_most_two_executables_per_bucket(self, engine):
         buckets = {}
-        with engine._cache_lock:
-            for k in engine._cache:
-                if k[0] != "chunk":
-                    continue
-                buckets.setdefault(k[:-1], set()).add(k[-1])
+        for v in map(denoise.parse_key, engine.executable_keys()):
+            if v is not None:
+                buckets.setdefault(v._replace(step_cache=False),
+                                   set()).add(v.step_cache)
         assert buckets, "no chunk executables compiled?"
         for bucket, variants in buckets.items():
             assert len(variants) <= 2, (bucket, variants)
@@ -248,25 +247,32 @@ class TestDonationDeclared:
         return x, carry, ctx, keys
 
     def test_plain_chunk_aliases_carry(self, engine):
-        fn = engine._chunk_fn("Euler", 4, 32, 32, 1, 2, masked=False)
+        fn = denoise.build(
+            denoise.Variant("chunk", "Euler", 4, 32, 32, 1, 2),
+            denoise.Deps(engine.unet, engine.controlnet_module,
+                         engine.schedule))
         x, carry, ctx, keys = self._chunk_args(engine)
         hlo = fn.lower(
-            engine.params["unet"], carry, jnp.int32(0), ctx, ctx,
-            jnp.float32(7.0), keys, None, None, jnp.float32(0),
-            jnp.float32(0), (), jnp.float32(0)).as_text()
+            engine.params["unet"], carry, jnp.int32(0),
+            denoise.Inputs(ctx, ctx, jnp.float32(7.0), keys)).as_text()
         assert "tf.aliasing_output" in hlo
 
     def test_stepcache_chunk_aliases_carry_and_cache(self, engine):
-        fn = engine._chunk_fn("Euler", 4, 32, 32, 1, 2, masked=False,
-                              step_cache=True)
+        fn = denoise.build(
+            denoise.Variant("chunk", "Euler", 4, 32, 32, 1, 2,
+                            step_cache=True),
+            denoise.Deps(engine.unet, engine.controlnet_module,
+                         engine.schedule))
         x, carry, ctx, keys = self._chunk_args(engine)
         cache = jnp.zeros(deep_cache_shape(engine.family.unet, 2, 4, 4),
                           jnp.float32)
         hlo = fn.lower(
-            engine.params["unet"], carry, cache, jnp.asarray(False),
-            jnp.int32(0), ctx, ctx, jnp.float32(7.0), keys, None, None,
-            jnp.float32(0), jnp.float32(0), jnp.float32(0),
-            jnp.int32(3), jnp.int32(2)).as_text()
+            engine.params["unet"],
+            denoise.CachedState(carry, cache, jnp.asarray(False)),
+            jnp.int32(0),
+            denoise.Inputs(ctx, ctx, jnp.float32(7.0), keys,
+                           cadence=jnp.int32(3),
+                           cfg_stop=jnp.int32(2))).as_text()
         assert hlo.count("tf.aliasing_output") >= 2  # carry.x AND cache
 
     def test_decode_u8_declares_unusable_donation(self, engine):

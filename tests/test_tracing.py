@@ -7,6 +7,7 @@ CPU-only, tiny model: counts and shapes of the tree, never a time.
 
 import glob
 import json
+import time
 import urllib.request
 
 import jax
@@ -94,6 +95,15 @@ def served(tmp_path_factory):
         try:
             post(server, "/sdapi/v1/txt2img",
                  dict(body, request_id="trace-prof", seed=12))
+            # the client has its answer before the server's handler thread
+            # has closed http.respond: stop the capture only once the
+            # exchange has ended on the server too, or a loaded machine
+            # cuts that span's annotation off
+            deadline = time.monotonic() + 60
+            while time.monotonic() < deadline and not any(
+                    e["name"] == "http.respond"
+                    for e in events_of(server, "trace-prof")):
+                time.sleep(0.01)
         finally:
             jax.profiler.stop_trace()
         xplane = glob.glob(tdir + "/**/*.xplane.pb", recursive=True)[0]

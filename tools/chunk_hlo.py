@@ -106,6 +106,7 @@ def compile_chunk(workload: str) -> tuple[str, float]:
     from jax.sharding import SingleDeviceSharding
 
     from benchmarks.harness import files, weights
+    from stable_diffusion_webui_distributed_tpu.pipeline import denoise
     from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
     from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
         GenerationPayload,
@@ -130,10 +131,10 @@ def compile_chunk(workload: str) -> tuple[str, float]:
     class Compiled(Exception):
         pass
 
-    chunk_fn = engine._chunk_fn
+    build = denoise.build
 
-    def compiling_chunk_fn(*args, **kwargs):
-        fn = chunk_fn(*args, **kwargs)
+    def compiling_build(*args, **kwargs):
+        fn = build(*args, **kwargs)
 
         def compile_instead(*call_args, **call_kwargs):
             described = jax.tree.map(
@@ -149,12 +150,14 @@ def compile_chunk(workload: str) -> tuple[str, float]:
 
         return compile_instead
 
-    engine._chunk_fn = compiling_chunk_fn
+    denoise.build = compiling_build
     try:
         engine.txt2img(GenerationPayload(
             **dict(bench.traffic(cell["traffic"])["payload"], seed=1)))
     except Compiled:
         pass
+    finally:
+        denoise.build = build
     (executable,) = compiled
     return (executable.as_text(),
             executable.memory_analysis().temp_size_in_bytes / 1e6)
