@@ -52,9 +52,9 @@ from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
     GenerationPayload,
     GenerationResult,
     apply_scripts,
-    array_to_b64png,
     b64png_to_array,
     build_infotext,
+    encode_b64png,
     fix_seed,
     prompt_expansion_args,
 )
@@ -2303,9 +2303,10 @@ class Engine:
             if payload.all_prompts and i < len(payload.all_prompts):
                 prompt_i = payload.all_prompts[i]
             with obs_spans.span("png_encode") as sp:
-                png = array_to_b64png(imgs[j])
+                png, strips = encode_b64png(imgs[j])
                 if sp is not None:
                     sp.attrs["bytes"] = len(png) * 3 // 4  # base64 -> PNG
+                    sp.attrs["strips"] = strips
             out.images.append(png)
             out.seeds.append(int(seed_i))
             out.subseeds.append(int(sub_i))

@@ -12,7 +12,7 @@ from __future__ import annotations
 import base64
 import io
 import re
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 from pydantic import BaseModel, Field
@@ -361,22 +361,30 @@ def canonical_dump(payload: "GenerationPayload") -> Dict[str, Any]:
 # image <-> base64 PNG (wire format parity with the reference)
 # --------------------------------------------------------------------------
 
-def array_to_b64png(img: np.ndarray) -> str:
-    """(H,W,3) uint8 -> base64 PNG string.
+def encode_b64png(img: np.ndarray) -> Tuple[str, int]:
+    """(H,W,3) uint8 -> (base64 PNG string, strips it was deflated as).
 
     Uses the native C++ encoder (runtime/native.py) when available — PNG
     encoding is the host-side cost of the wire format after the TPU has
-    finished — and falls back to PIL otherwise."""
+    finished, so that encoder spreads an image's scanlines over the host's
+    idle cores as strips and says how many — and falls back to PIL (one
+    strip) otherwise."""
     from stable_diffusion_webui_distributed_tpu.runtime import native
 
-    data = native.encode_png(np.asarray(img))
-    if data is None:
+    encoded = native.encode_png(np.asarray(img))
+    if encoded is None:
         from PIL import Image
 
         buf = io.BytesIO()
         Image.fromarray(img).save(buf, format="PNG")
-        data = buf.getvalue()
-    return base64.b64encode(data).decode("ascii")
+        encoded = buf.getvalue(), 1
+    data, strips = encoded
+    return base64.b64encode(data).decode("ascii"), strips
+
+
+def array_to_b64png(img: np.ndarray) -> str:
+    """(H,W,3) uint8 -> base64 PNG string (:func:`encode_b64png`'s)."""
+    return encode_b64png(img)[0]
 
 
 def b64png_to_array(data: str) -> np.ndarray:

@@ -3,9 +3,13 @@
 The reference keeps all of its own code in Python and leans on each
 worker's CUDA substrate for performance (SURVEY.md §2: zero native code in
 the repo). Here the serving path has real host-side work — PNG encoding of
-finished images — done natively (native/png_encoder.cpp, zlib) with a
-PIL fallback when no toolchain is available; the fallback is logged once
-and :func:`active_encoder` names which one serves. The library is compiled
+finished images, with the device idle and the client waiting — done
+natively (native/png_encoder.cpp: zlib, each image deflated as strips of
+scanlines on the host's idle cores and stitched into one stream; the
+library picks the number of strips from the image's bytes and the cores
+this process may run on, and reports it) with a PIL fallback when no
+toolchain is available; the fallback is logged once and
+:func:`active_encoder` names which one serves. The library is compiled
 once per machine into ``native/build/`` (git-ignored) and memoized.
 """
 
@@ -15,7 +19,7 @@ import ctypes
 import os
 import subprocess
 import threading
-from typing import Optional
+from typing import Optional, Tuple
 
 import numpy as np
 
@@ -48,7 +52,8 @@ def _build_library() -> Optional[str]:
     if os.path.exists(out) and os.path.getmtime(out) >= os.path.getmtime(src):
         return out
     os.makedirs(build_dir, exist_ok=True)
-    cmd = ["g++", "-O3", "-shared", "-fPIC", src, "-lz", "-o", out]
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread", src, "-lz", "-o",
+           out]
     try:
         proc = subprocess.run(cmd, capture_output=True, timeout=120)
     except (OSError, subprocess.TimeoutExpired) as e:
@@ -74,8 +79,10 @@ def _get_lib() -> Optional[ctypes.CDLL]:
             lib = ctypes.CDLL(path)
             lib.sdtpu_encode_png.restype = ctypes.c_long
             lib.sdtpu_encode_png.argtypes = [
-                ctypes.c_char_p, ctypes.c_int, ctypes.c_int, ctypes.c_int,
-                ctypes.c_int, ctypes.c_char_p, ctypes.c_long,
+                ctypes.c_void_p, ctypes.c_int, ctypes.c_int, ctypes.c_int,
+                ctypes.c_ssize_t, ctypes.c_ssize_t, ctypes.c_ssize_t,
+                ctypes.c_int, ctypes.c_void_p, ctypes.c_long,
+                ctypes.POINTER(ctypes.c_int),
             ]
             _lib = lib
         except OSError as e:
@@ -101,27 +108,28 @@ def warm_up(background: bool = True) -> None:
 
 
 def encode_png(img: np.ndarray, compression_level: int = 6
-               ) -> Optional[bytes]:
-    """(H, W, 3|4) uint8 -> PNG bytes via the native encoder, or None when
-    the native path is unavailable (caller falls back to PIL)."""
+               ) -> Optional[Tuple[bytes, int]]:
+    """(H, W, 3|4) uint8 -> (PNG bytes, strips the image was deflated as)
+    via the native encoder, or None when the native path is unavailable
+    (caller falls back to PIL). The array goes to the library as it lies,
+    strides and all: the image a TPU hands back is three planes, and the
+    strips' threads interleave them."""
     lib = _get_lib()
     if lib is None:
         return None
     if img.dtype != np.uint8 or img.ndim != 3 or img.shape[2] not in (3, 4):
         return None
-    img = np.ascontiguousarray(img)
     h, w, c = img.shape
-    cap = w * h * (c + 1) + 4096
-    buf = ctypes.create_string_buffer(cap)
-    n = lib.sdtpu_encode_png(
-        img.ctypes.data_as(ctypes.c_char_p), w, h, c, compression_level,
-        buf, cap)
-    if n < 0:  # undersized buffer: retry at the reported size
-        cap = -n
-        buf = ctypes.create_string_buffer(cap)
+    strips = ctypes.c_int(0)
+    cap = h * (w * c + 1) + (h * w * c >> 10) + 4096
+    for _ in range(2):
+        buf = np.empty(cap, np.uint8)   # the library writes the file here
         n = lib.sdtpu_encode_png(
-            img.ctypes.data_as(ctypes.c_char_p), w, h, c, compression_level,
-            buf, cap)
+            img.ctypes.data, w, h, c, *img.strides, compression_level,
+            buf.ctypes.data, cap, ctypes.byref(strips))
+        if n >= 0:
+            break
+        cap = -n    # undersized buffer: once more at the reported size
     if n <= 0:
         return None
-    return buf.raw[:n]
+    return buf[:n].tobytes(), strips.value

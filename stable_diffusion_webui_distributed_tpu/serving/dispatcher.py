@@ -1330,7 +1330,7 @@ class ServingDispatcher:
         infotext from the ORIGINAL payload so user-visible metadata shows
         the requested dimensions."""
         from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
-            array_to_b64png, b64png_to_array, build_infotext,
+            b64png_to_array, build_infotext, encode_b64png,
         )
 
         orig = ticket.payload
@@ -1342,9 +1342,11 @@ class ServingDispatcher:
             arr = b64png_to_array(b64)
             if arr.shape[:2] != (bh, bw):
                 continue  # hires/second-pass output: not bucket-sized
-            with obs_spans.span("png_encode", recrop=True):
-                result.images[i] = array_to_b64png(
+            with obs_spans.span("png_encode", recrop=True) as sp:
+                result.images[i], strips = encode_b64png(
                     crop(arr, orig.width, orig.height))
+                if sp is not None:
+                    sp.attrs["strips"] = strips
             suffix = ""
             if i < len(result.infotexts) and \
                     result.infotexts[i].endswith(", DPM adaptive: incomplete"):

@@ -169,7 +169,17 @@ class TestCoalescedRunTracing:
     @pytest.fixture(scope="class")
     def run(self, engine, bucketer):
         """4 concurrent requests (2 shapes -> 2 buckets) through a
-        coalescing dispatcher, with per-request wall clocks."""
+        coalescing dispatcher, with per-request wall clocks. The requests
+        compile on the CPU (20-35 s beside five other test workers), so
+        the tracer's 30 s "slow" mark is off while they run: the statuses
+        below are about errors, not about this machine's speed."""
+        slow_s, obs_spans.TRACER.slow_s = obs_spans.TRACER.slow_s, 0.0
+        try:
+            yield self._run(engine, bucketer)
+        finally:
+            obs_spans.TRACER.slow_s = slow_s
+
+    def _run(self, engine, bucketer):
         obs_spans.TRACER.clear()
         flightrec.RECORDER.clear()
         METRICS.clear()

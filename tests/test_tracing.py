@@ -187,6 +187,17 @@ class TestSpanTree:
         assert {e["args"]["fun_name"] for e in named["xla.compile"]} \
             >= {"run_chunk", "decode_u8"}
 
+    @pytest.mark.parametrize("which", ["txt2img", "profiled"])
+    def test_png_encode_says_its_strips(self, served, which):
+        """Every encode names the strips its image was deflated as (the
+        encoder's own count: 1 for a small image or under PIL) beside the
+        file's bytes."""
+        encodes = [e for e in served[which] if e["name"] == "png_encode"]
+        assert encodes
+        for e in encodes:
+            assert e["args"]["strips"] >= 1
+            assert e["args"]["bytes"] > 0
+
     def test_warm_request_compiles_nothing(self, served):
         assert not [e for e in served["profiled"]
                     if e["name"] == "xla.compile"]
