@@ -1,23 +1,28 @@
-"""Key/value cache of the resident language model (models/lm.py), and the
-instruction prefix's cache kept across requests.
+"""Per-layer state of the resident language model (models/lm.py), and the
+instruction prefix's state kept across requests.
 
-One manager holds two kinds of layer. A FULL layer keeps every position, so
-its buffer is as long as the sequence may get; lengths are bucketed
-(:data:`CAPACITY_STEP`) so that one executable serves every request of a
-traffic mix. A SLIDING layer only ever attends the last ``sliding_window``
-positions, so its buffer is a ring of that many slots whatever the length.
+One manager holds three kinds of layer. A FULL layer keeps every position,
+so its key and value buffers are as long as the sequence may get; lengths
+are bucketed (:data:`CAPACITY_STEP`) so that one executable serves every
+request of a traffic mix. A SLIDING layer only ever attends the last
+``sliding_window`` positions, so its buffers are rings of that many slots
+whatever the length. A LINEAR layer has no positions: its recurrent state
+and the convolution's last inputs are of one size at every length.
 
 The expander's requests all begin with the operator's instruction text.
-Its cache (both kinds, as they stand after the prefix's last token) is
-computed once and kept here, next to cache/embed.py (conditioning) and
+What its layers hold after the prefix's LAST token (every kind) is computed
+once and kept here as a snapshot, next to cache/embed.py (conditioning) and
 cache/prefix.py (denoise carries), which do the same for their artifacts:
 a request whose prefix is held starts from a copy and prefills only its own
-prompt. The executables donate the cache they are given, so what is kept is
-never handed out itself.
+prompt. A snapshot serves exactly the prefix it was taken after: a key
+buffer could be read up to a shorter ``end``, a recurrent state cannot be
+cut back, so the key is the whole prefix. The executables donate the cache
+they are given, so what is kept is never handed out itself.
 """
 
 from __future__ import annotations
 
+import math
 import threading
 from collections import OrderedDict
 from typing import Dict, Sequence, Tuple
@@ -31,7 +36,8 @@ from stable_diffusion_webui_distributed_tpu.models import lm
 CAPACITY_STEP = 256
 #: a prefill chunk is padded up to a power of two, at least this
 MIN_CHUNK = 64
-#: instruction prefixes kept (each is one cache: ~15 MB at 1024 positions)
+#: instruction prefixes kept, each a whole cache: :func:`state_bytes` of the
+#: model at the capacity says how large (``serving.expander.state_bytes``)
 MAX_PREFIXES = 4
 
 
@@ -44,15 +50,32 @@ def capacity_for(positions: int) -> int:
     return -(-positions // CAPACITY_STEP) * CAPACITY_STEP
 
 
+def state_bytes(config, capacity: int, dtype) -> Dict[str, int]:
+    """Bytes one sequence's cache takes at ``capacity``, by layer kind,
+    from the shapes: keys and values in ``dtype``, a linear layer's state
+    and kept inputs in float32."""
+    shapes = lm.cache_shapes(config, capacity)
+    attention = [kind for kind in config.layer_types if kind != lm.LINEAR]
+    out = {lm.FULL: 0, lm.SLIDING: 0}
+    for name in lm.ATTENTION_BUFFERS:
+        for kind, shape in zip(attention, shapes[name]):
+            out[kind] += math.prod(shape) * jnp.dtype(dtype).itemsize
+    if lm.LINEAR in config.layer_types:
+        out[lm.LINEAR] = 4 * sum(math.prod(shape)
+                                 for name in lm.LINEAR_BUFFERS
+                                 for shape in shapes[name])
+    return out
+
+
 class KVCacheManager:
     """Hands out caches of one model at bucketed capacities, keeps the
-    caches of instruction prefixes, and counts what is in use."""
+    snapshots of instruction prefixes, and counts what is in use."""
 
     def __init__(self, config, dtype) -> None:
         self.config = config
         self.dtype = dtype
         self._lock = threading.Lock()
-        #: (prefix ids, capacity) -> cache after the prefix's last token
+        #: (prefix ids, capacity) -> snapshot at the prefix's last token
         self._prefixes: "OrderedDict[Tuple, Dict]" = OrderedDict()  # guarded-by: _lock
         self.prefix_hits = 0    # guarded-by: _lock
         self.prefix_misses = 0  # guarded-by: _lock
@@ -81,12 +104,22 @@ class KVCacheManager:
             while len(self._prefixes) > MAX_PREFIXES:
                 self._prefixes.popitem(last=False)
 
+    @property
+    def snapshots(self) -> int:
+        """Instruction prefixes held."""
+        with self._lock:
+            return len(self._prefixes)
+
     def positions_in_use(self, length: int) -> Dict[str, int]:
         """Cache positions a sequence of ``length`` occupies, by layer
-        kind, summed over the layers of the kind."""
+        kind, summed over the layers of the kind; a linear layer uses
+        none at any length."""
         cfg = self.config
-        return {
+        out = {
             lm.FULL: len(cfg.layers_of(lm.FULL)) * length,
             lm.SLIDING: len(cfg.layers_of(lm.SLIDING))
             * min(length, cfg.sliding_window),
         }
+        if lm.LINEAR in cfg.layer_types:
+            out[lm.LINEAR] = 0
+        return out

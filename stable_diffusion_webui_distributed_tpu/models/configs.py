@@ -91,11 +91,24 @@ class RopeConfig:
 @dataclasses.dataclass(frozen=True)
 class LMConfig:
     """A decoder-only language model read from lists: the kind of every
-    layer's attention (``"full"`` or ``"sliding"``), its query heads, which
-    layers have a dense MLP and which a router over experts. ``num_experts``
+    layer's token mixer (``"full"`` or ``"sliding"`` attention over cached
+    keys and values, or ``"linear"``: a gated delta rule over a recurrent
+    state of fixed size behind a short causal convolution), its query
+    heads (an entry of a linear layer is not read), which layers have a
+    dense MLP and which a router over experts. ``num_experts``
     is the router's width (all of the layer's experts); ``experts_held`` and
     ``vocab_held`` are the contiguous ranges ``(first, count)`` of experts
-    and of vocabulary ids whose weights THIS chip holds (``None``: all)."""
+    and of vocabulary ids whose weights THIS chip holds (``None``: all).
+
+    ``attn_gate`` says where an attention layer's output gate comes from:
+    ``"head"`` is one sigmoid a query head from its own ``g_proj``,
+    ``"element"`` one a channel, the second half of every head's
+    ``q_proj`` columns. ``qk_norm`` puts an RMS norm over each head's query
+    and key before the rotation. ``zero_centred_norm`` makes every norm
+    ``x_hat * (1 + weight)`` instead of ``x_hat * scale``.
+    ``shared_expert_gate`` multiplies the shared expert by
+    ``sigmoid(w_s^T n)``. The defaults of these four are the ungated,
+    un-normed forms."""
 
     vocab_size: int = 100352
     hidden_size: int = 3072
@@ -117,6 +130,17 @@ class LMConfig:
     rms_norm_eps: float = 1e-6
     experts_held: Optional[Tuple[int, int]] = None
     vocab_held: Optional[Tuple[int, int]] = None
+    attn_gate: str = "head"
+    qk_norm: bool = False
+    zero_centred_norm: bool = False
+    shared_expert_gate: bool = False
+    # "linear" layers: key heads (each serves value_heads / key_heads value
+    # heads), their widths, and the taps of the causal convolution
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 4
 
     @property
     def num_layers(self) -> int:
@@ -137,6 +161,13 @@ class LMConfig:
     def expert_layers(self) -> Tuple[int, ...]:
         return tuple(i for i in range(self.num_layers)
                      if i not in self.dense_layers)
+
+    @property
+    def linear_conv_channels(self) -> int:
+        """Channels the convolution of a linear layer runs over: its
+        queries, keys and values side by side."""
+        return (2 * self.linear_num_key_heads * self.linear_key_head_dim
+                + self.linear_num_value_heads * self.linear_value_head_dim)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -363,6 +394,38 @@ def sd15_laguna_expander() -> ModelFamily:
         expander=lm_share(LAGUNA_S_2_1, layers=5, chips=2, rank=0))
 
 
+# Qwen3-Next-80B-A3B-Instruct
+# (huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct config.json) at its
+# published widths: 48 layers in the pattern linear, linear, linear, full;
+# a linear layer is a gated delta rule over 16 key and 32 value heads of
+# width 128 behind a 4-tap convolution, a full layer 16 query heads of
+# width 256 over 2 KV heads with q/k norms, a quarter of the dims rotated
+# and an element-wise output gate out of q_proj; every layer a router over
+# 512 experts of width 512, 10 a token, plus one gated shared expert.
+QWEN3_NEXT_80B_A3B = LMConfig(
+    vocab_size=151936, hidden_size=2048,
+    layer_types=("linear", "linear", "linear", "full") * 12,
+    num_heads_per_layer=(16,) * 48, num_kv_heads=2, head_dim=256,
+    rope_full=RopeConfig(theta=1e7, partial_rotary_factor=0.25),
+    dense_layers=(), intermediate_size=5120, num_experts=512,
+    num_experts_per_tok=10, moe_intermediate_size=512,
+    shared_expert_intermediate_size=512, routed_scaling_factor=1.0,
+    norm_topk_prob=True, rms_norm_eps=1e-6, attn_gate="element",
+    qk_norm=True, zero_centred_norm=True, shared_expert_gate=True,
+    linear_num_key_heads=16, linear_num_value_heads=32,
+    linear_key_head_dim=128, linear_value_head_dim=128,
+    linear_conv_kernel=4)
+
+
+def sd15_qwen3next_expander() -> ModelFamily:
+    """SD1.5 with Qwen3-Next-80B-A3B-Instruct as its resident prompt
+    expander, cut to one chip of a four-chip host: layers 0-11 (three whole
+    periods), experts 0-127 of every layer, vocabulary ids 0-37983."""
+    return dataclasses.replace(
+        SD15, name="sd15-qwen3next-expand",
+        expander=lm_share(QWEN3_NEXT_80B_A3B, layers=12, chips=4, rank=0))
+
+
 # Tiny expander that keeps every kind: two head counts, a window (8) shorter
 # than any test context so the ring wraps, a dense first layer, 16 experts
 # top-4 with a shared one, partial YaRN and full plain rotary.
@@ -384,6 +447,34 @@ TINY_EXPAND = dataclasses.replace(
 def tiny_expander() -> ModelFamily:
     """Factory form of :data:`TINY_EXPAND` (benchmark rehearsals)."""
     return TINY_EXPAND
+
+
+# Tiny expander with all three layer kinds: two linear layers (2 key heads
+# serving 4 value heads of width 8, 4 taps) before a sliding and a full
+# one, element-wise gate, q/k norms, zero-centred norms, a gated shared
+# expert and no dense layer.
+TINY_DELTA_LM = LMConfig(
+    vocab_size=512, hidden_size=32,
+    layer_types=("linear", "linear", "sliding", "full"),
+    num_heads_per_layer=(4, 4, 4, 4), num_kv_heads=2, head_dim=16,
+    sliding_window=8,
+    rope_full=RopeConfig(theta=1e7, partial_rotary_factor=0.25),
+    rope_sliding=RopeConfig(theta=1e4),
+    dense_layers=(), intermediate_size=64, num_experts=16,
+    num_experts_per_tok=4, moe_intermediate_size=16,
+    shared_expert_intermediate_size=16, routed_scaling_factor=1.0,
+    attn_gate="element", qk_norm=True, zero_centred_norm=True,
+    shared_expert_gate=True, linear_num_key_heads=2,
+    linear_num_value_heads=4, linear_key_head_dim=8,
+    linear_value_head_dim=8, linear_conv_kernel=4)
+TINY_DELTA_EXPAND = dataclasses.replace(
+    TINY, name="tiny-delta-expand",
+    expander=lm_share(TINY_DELTA_LM, 4, chips=4, rank=0))
+
+
+def tiny_delta_expander() -> ModelFamily:
+    """Factory form of :data:`TINY_DELTA_EXPAND` (benchmark rehearsals)."""
+    return TINY_DELTA_EXPAND
 
 
 FAMILIES = {f.name: f for f in (SD15, SD21, SD21_BASE, SDXL_BASE,

@@ -3,22 +3,34 @@ resident prompt expander (``ModelFamily.expander``, pipeline/engine.py's
 ``expand`` stage).
 
 Nothing here names an architecture. ``LMConfig`` says, layer by layer, which
-attention a layer has (``"full"``: every earlier position; ``"sliding"``:
-the last ``sliding_window``), how many query heads (they may differ by
-layer; the KV heads are shared by groups of them), which rotary
-parameterisation goes with which kind, and whether the MLP is dense or a
-router over experts with one shared expert (ops/moe.py). Every attention
-output passes a per-head gate, ``sigmoid(W_g n)``, before ``o_proj``.
+token mixer a layer has (``"full"``: attention over every earlier
+position; ``"sliding"``: over the last ``sliding_window``; ``"linear"``: a
+gated delta rule over a recurrent state, ops/delta_rule.py, behind a short
+causal convolution), how many query heads an attention layer has (they may
+differ by layer; the KV heads are shared by groups of them), which rotary
+parameterisation goes with which kind, whether queries and keys are normed
+per head, and whether the MLP is dense or a router over experts with one
+shared expert (ops/moe.py), gated or not. An attention output passes a
+sigmoid gate before ``o_proj``: one a head from ``g_proj``, or one a
+channel from the second half of ``q_proj``'s columns
+(``LMConfig.attn_gate``). A norm is ``x_hat * scale`` or, zero-centred,
+``x_hat * (1 + weight)``.
 
 One call, :meth:`DecoderLM.__call__`, runs a chunk of ``T`` tokens that
 starts at position ``start`` against the cache and returns the cache with
 the chunk written: a prefill is a long chunk, a decode step a chunk of one.
-The cache (cache/kv.py) is a key and a value buffer a layer, of two kinds:
-a full layer's holds every position up to its capacity, a sliding layer's
-is a ring of ``sliding_window`` slots, slot ``p % window`` holding position
-``p``.
-A chunk may be padded: only its first ``length`` tokens are real, the rest
-are never written to a ring and never seen by a real query.
+The cache (cache/kv.py) holds, per layer, the buffers of the layer's kind,
+three kinds in all: a full layer's key and value buffers hold every
+position up to their capacity; a sliding layer's are rings of
+``sliding_window`` slots, slot ``p % window`` holding position ``p``; a
+linear layer has no positions at all but the recurrent state ``(value
+heads, key width, value width)`` in float32 and the convolution's last
+``taps - 1`` inputs, neither growing with the sequence.
+A chunk may be padded: only its first ``length`` tokens are real. A padded
+row is never written to a ring and lands beyond ``end`` in a full buffer,
+where no real query sees it; in a linear layer it neither decays the state
+nor writes to it (its decay and write strength are masked), and the
+convolution keeps the last REAL rows.
 
 Batch 1: a prompt is one sequence.
 """
@@ -36,14 +48,21 @@ import numpy as np
 from stable_diffusion_webui_distributed_tpu.models.configs import (
     LMConfig, RopeConfig,
 )
-from stable_diffusion_webui_distributed_tpu.ops import moe
+from stable_diffusion_webui_distributed_tpu.ops import delta_rule, moe
 from stable_diffusion_webui_distributed_tpu.ops.attention import (
     attend_positions,
 )
 from stable_diffusion_webui_distributed_tpu.ops.quant import int8_dot
 from stable_diffusion_webui_distributed_tpu.serving.metrics import ATTENTION
 
-FULL, SLIDING = "full", "sliding"
+FULL, SLIDING, LINEAR = "full", "sliding", "linear"
+#: the cache's buffers of one layer, by the layer's kind
+ATTENTION_BUFFERS = ("k", "v")
+LINEAR_BUFFERS = ("state", "conv")
+
+
+def buffers_of(kind: str) -> Tuple[str, str]:
+    return LINEAR_BUFFERS if kind == LINEAR else ATTENTION_BUFFERS
 
 
 # -- rotary embeddings -------------------------------------------------------
@@ -112,14 +131,24 @@ class Linear(nn.Module):
 
 
 class RMSNorm(nn.Module):
+    """``x_hat * scale`` over the last axis in float32;
+    ``zero_centred``: ``x_hat * (1 + weight)``."""
+
     eps: float = 1e-6
+    zero_centred: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        if self.zero_centred:
+            scale = 1.0 + self.param(
+                "weight", nn.initializers.zeros,
+                (x.shape[-1],)).astype(jnp.float32)
+        else:
+            scale = self.param("scale", nn.initializers.ones,
+                               (x.shape[-1],)).astype(jnp.float32)
         x = x.astype(jnp.float32)
         mean = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-        return x * jax.lax.rsqrt(mean + self.eps) * scale.astype(jnp.float32)
+        return x * jax.lax.rsqrt(mean + self.eps) * scale
 
 
 class SwiGLU(nn.Module):
@@ -182,6 +211,9 @@ class MoE(nn.Module):
                                     num_experts=cfg.num_experts)
         shared = SwiGLU(cfg.shared_expert_intermediate_size, self.dtype,
                         self.quant, name="shared_expert")(n)
+        if cfg.shared_expert_gate:
+            shared = shared * jax.nn.sigmoid(Linear(
+                1, self.dtype, self.quant, name="shared_expert_gate")(n))
         load, none_held = moe.load_counts(routing, first, held, valid)
         return routed + shared, (routing.experts, load, none_held)
 
@@ -206,10 +238,20 @@ class Attention(nn.Module):
         cos, sin = rope_tables(
             cfg.rope_full if kind == FULL else cfg.rope_sliding, dim, q_pos)
         store = k_cache.dtype
-        q = apply_rope(lin(heads * dim, "q_proj")(n).reshape(
-            tokens, heads, dim), cos, sin).astype(self.dtype)
-        k = apply_rope(lin(kv * dim, "k_proj")(n).reshape(
-            tokens, kv, dim), cos, sin).astype(store)
+        if cfg.attn_gate == "element":
+            # every head's columns are its query, then its gate
+            q, gate = jnp.split(lin(2 * heads * dim, "q_proj")(n).reshape(
+                tokens, heads, 2 * dim), 2, axis=-1)
+        else:
+            q = lin(heads * dim, "q_proj")(n).reshape(tokens, heads, dim)
+        k = lin(kv * dim, "k_proj")(n).reshape(tokens, kv, dim)
+        if cfg.qk_norm:
+            q = RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm,
+                        name="q_norm")(q)
+            k = RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm,
+                        name="k_norm")(k)
+        q = apply_rope(q, cos, sin).astype(self.dtype)
+        k = apply_rope(k, cos, sin).astype(store)
         v = lin(kv * dim, "v_proj")(n).reshape(tokens, kv, dim).astype(store)
         real = q_pos < end
         if kind == FULL:
@@ -237,10 +279,83 @@ class Attention(nn.Module):
         out, path = attend_positions(q, keys, values, q_pos, k_pos,
                                      scale=dim ** -0.5, window=window)
         ATTENTION.record(path, tokens, keys.shape[0], dim)
-        gate = jax.nn.sigmoid(lin(heads, "g_proj")(n))
-        out = out.astype(jnp.float32) * gate[:, :, None]
+        if cfg.attn_gate == "element":
+            out = out.astype(jnp.float32) * jax.nn.sigmoid(gate)
+        else:
+            gate = jax.nn.sigmoid(lin(heads, "g_proj")(n))
+            out = out.astype(jnp.float32) * gate[:, :, None]
         return (lin(n.shape[-1], "o_proj")(out.reshape(tokens, heads * dim)),
                 k_cache, v_cache)
+
+
+def _decay_init(key, shape, dtype=jnp.float32):
+    """``A_log``: the log of a decay rate drawn uniformly from (0, 16)."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1e-3, 16.0))
+
+
+class DeltaMixer(nn.Module):
+    """The token mixer of a ``"linear"`` layer: queries, keys and values
+    through a causal depth-wise convolution and SiLU, queries and keys
+    L2-normalised per head, then the gated delta rule over the layer's
+    recurrent state; the read-out is RMS-normed per head, gated by
+    ``silu(z)`` and projected. ``state`` is ``(value heads, key width,
+    value width)`` float32; ``conv`` holds the convolution's last
+    ``taps - 1`` real inputs."""
+
+    config: LMConfig
+    dtype: jnp.dtype = jnp.float32
+    quant: bool = False
+
+    @nn.compact
+    def __call__(self, n, real, length, state, conv):
+        cfg = self.config
+        k_heads, v_heads = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+        k_dim, v_dim = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+        channels, taps = cfg.linear_conv_channels, cfg.linear_conv_kernel
+        tokens = n.shape[0]
+        f32 = jnp.float32
+
+        def lin(features, name):
+            return Linear(features, self.dtype, self.quant, name=name)
+
+        # columns: [q | k | v | z] and [b | a]
+        qkv, z = jnp.split(lin(channels + v_heads * v_dim, "qkvz_proj")(n),
+                           [channels], axis=-1)
+        b, a = jnp.split(lin(2 * v_heads, "ba_proj")(n), 2, axis=-1)
+        kernel = self.param("conv_kernel", nn.initializers.lecun_normal(),
+                            (taps, channels)).astype(f32)
+        a_log = self.param("A_log", _decay_init, (v_heads,)).astype(f32)
+        dt_bias = self.param("dt_bias", nn.initializers.ones,
+                             (v_heads,)).astype(f32)
+        # tap j of row t reads input t - (taps - 1) + j; the inputs before
+        # the chunk are the ones kept from the last real rows
+        inputs = jnp.concatenate([conv.astype(f32), qkv])
+        qkv = jax.nn.silu(sum(kernel[j] * inputs[j:j + tokens]
+                              for j in range(taps)))
+        conv = jax.lax.dynamic_slice_in_dim(
+            inputs, length, taps - 1, 0).astype(conv.dtype)
+        q, k, v = jnp.split(
+            qkv, [k_heads * k_dim, 2 * k_heads * k_dim], axis=-1)
+
+        def unit(x):
+            x = x.reshape(tokens, k_heads, k_dim)
+            x = x * jax.lax.rsqrt(
+                jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+            return jnp.repeat(x, v_heads // k_heads, axis=1)
+
+        # a padded row neither decays the state nor writes to it
+        g = jnp.where(real[:, None],
+                      -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias), 0.0)
+        beta = jnp.where(real[:, None], jax.nn.sigmoid(b), 0.0)
+        # computed in float32 whatever the buffer holds
+        out, after = delta_rule.gated_delta_rule(
+            state.astype(f32), unit(q) * k_dim ** -0.5, unit(k),
+            v.reshape(tokens, v_heads, v_dim), g, beta)
+        state = after.astype(state.dtype)
+        out = RMSNorm(cfg.rms_norm_eps, name="norm")(out) \
+            * jax.nn.silu(z.reshape(tokens, v_heads, v_dim))
+        return (lin(n.shape[-1], "out_proj")(
+            out.reshape(tokens, v_heads * v_dim)), state, conv)
 
 
 class DecoderLayer(nn.Module):
@@ -250,21 +365,33 @@ class DecoderLayer(nn.Module):
     quant: bool = False
 
     @nn.compact
-    def __call__(self, x, q_pos, start, end, k_cache, v_cache):
+    def __call__(self, x, q_pos, start, end, buffers):
+        """``buffers`` are the layer's two of the cache
+        (:func:`buffers_of` its kind), returned as the chunk leaves them."""
         cfg = self.config
-        n = RMSNorm(cfg.rms_norm_eps, name="input_norm")(x)
-        attn, k_cache, v_cache = Attention(
-            cfg, self.layer, self.dtype, self.quant, name="attn")(
-                n, q_pos, start, end, k_cache, v_cache)
-        h = x + attn
-        n = RMSNorm(cfg.rms_norm_eps, name="post_attention_norm")(h)
+
+        def norm(name):
+            return RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm,
+                           name=name)
+
+        n = norm("input_norm")(x)
+        if cfg.layer_types[self.layer] == LINEAR:
+            mixed, *buffers = DeltaMixer(
+                cfg, self.dtype, self.quant, name="delta")(
+                    n, q_pos < end, end - start, *buffers)
+        else:
+            mixed, *buffers = Attention(
+                cfg, self.layer, self.dtype, self.quant, name="attn")(
+                    n, q_pos, start, end, *buffers)
+        h = x + mixed
+        n = norm("post_attention_norm")(h)
         if self.layer in cfg.dense_layers:
             out, routed = SwiGLU(cfg.intermediate_size, self.dtype,
                                  self.quant, name="mlp")(n), None
         else:
             out, routed = MoE(cfg, self.dtype, self.quant, name="mlp")(
                 n, q_pos < end)
-        return h + out, k_cache, v_cache, routed
+        return h + out, tuple(buffers), routed
 
 
 class DecoderLM(nn.Module):
@@ -295,21 +422,25 @@ class DecoderLM(nn.Module):
         x = nn.Embed(count, cfg.hidden_size, name="embed_tokens")(
             jnp.clip(local, 0, count - 1)).astype(jnp.float32)
         x = x * here[:, None]
-        keys, values, routed = [], [], []
-        for layer in range(cfg.num_layers):
-            x, k, v, r = DecoderLayer(
+        # a buffer list has one entry for each layer that has the buffer,
+        # in layer order
+        written = {name: [] for name in cache}
+        routed = []
+        for layer, kind in enumerate(cfg.layer_types):
+            names = buffers_of(kind)
+            x, buffers, r = DecoderLayer(
                 cfg, layer, self.dtype, self.quant_linears,
                 name=f"layers_{layer}")(
-                    x, q_pos, start, end, cache["k"][layer],
-                    cache["v"][layer])
-            keys.append(k)
-            values.append(v)
+                    x, q_pos, start, end,
+                    tuple(cache[name][len(written[name])] for name in names))
+            for name, buffer in zip(names, buffers):
+                written[name].append(buffer)
             if r is not None:
                 routed.append(r)
-        cache = {"k": keys, "v": values}
+        cache = written
         if not all_logits:
             x = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, 0)
-        n = RMSNorm(cfg.rms_norm_eps, name="norm")(x)
+        n = RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm, name="norm")(x)
         logits = Linear(cfg.vocab[1], self.dtype, self.quant_linears,
                         name="lm_head")(n)
         if not routed:     # no expert layer: the three parts, empty
@@ -321,15 +452,30 @@ class DecoderLM(nn.Module):
 # -- the cache's shapes, and the executables the engine builds ----------------
 
 def cache_shapes(cfg: LMConfig, capacity: int) -> Dict[str, list]:
-    """Per layer the shape of its key (and value) buffer: a full layer
-    holds ``capacity`` positions, a sliding layer a ring of its window."""
+    """By buffer name the shapes of the layers that have it, in layer
+    order. An attention layer has ``k`` and ``v``: a full layer holds
+    ``capacity`` positions, a sliding layer a ring of its window. A linear
+    layer has ``state`` and ``conv``, whatever the capacity; a model
+    without such layers has neither name."""
     rows = [(capacity if kind == FULL else cfg.sliding_window,
-             cfg.num_kv_heads, cfg.head_dim) for kind in cfg.layer_types]
-    return {"k": rows, "v": list(rows)}
+             cfg.num_kv_heads, cfg.head_dim)
+            for kind in cfg.layer_types if kind != LINEAR]
+    shapes = {"k": rows, "v": list(rows)}
+    linear = len(cfg.layers_of(LINEAR))
+    if linear:
+        shapes["state"] = [(cfg.linear_num_value_heads,
+                            cfg.linear_key_head_dim,
+                            cfg.linear_value_head_dim)] * linear
+        shapes["conv"] = [(cfg.linear_conv_kernel - 1,
+                           cfg.linear_conv_channels)] * linear
+    return shapes
 
 
 def empty_cache(cfg: LMConfig, capacity: int, dtype) -> Dict[str, list]:
-    return {name: [jnp.zeros(shape, dtype) for shape in rows]
+    """Keys and values in ``dtype``; a linear layer's state and kept
+    convolution inputs in float32 (zero is the state at position 0)."""
+    return {name: [jnp.zeros(shape, dtype if name in ATTENTION_BUFFERS
+                             else jnp.float32) for shape in rows]
             for name, rows in cache_shapes(cfg, capacity).items()}
 
 

@@ -1,0 +1,137 @@
+"""The gated delta rule: the recurrence of a linear-attention layer
+(models/lm.py, layer kind ``"linear"``) over a state of fixed size.
+
+Per head, with the state ``S`` of shape ``(K, V)``, a key ``k`` and a query
+``q`` of width ``K``, a value ``v`` of width ``V``, a log-decay ``g <= 0``
+and a write strength ``beta`` in (0, 1)::
+
+    S <- exp(g) S;   u = beta (v - S^T k);   S <- S + k u^T;   o = S^T q
+
+Two forms of the same mathematics, chosen by what the call shows (the
+number of tokens, which is static), as ops/moe.py chooses its product:
+
+- ``T == 1`` (a decode step): the recurrence as written, element-wise
+  products and sums over the state, so float32 stays float32 on a TPU.
+- ``T > 1`` (prefill): the chunk-wise form. Tokens are cut into chunks of
+  :data:`CHUNK`; inside a chunk the writes depend on each other through a
+  unit lower-triangular system, solved row by row as the published
+  implementation does; between chunks only the state is carried. Its
+  products are float32 at the highest precision: a prefill happens once a
+  prompt, and a state that later steps read for hundreds of tokens should
+  not start from a bfloat16 product.
+
+A masked row (``g = 0`` and ``beta = 0``) neither decays the state nor
+writes to it: that is how a chunk padded to its bucket leaves the state
+where its last real token put it. Everything is float32; the caller scales
+``q`` and normalises ``q`` and ``k``.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+#: tokens of one chunk of the chunk-wise form
+CHUNK = 64
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def recurrent_step(state, q, k, v, g, beta):
+    """One token. ``state`` ``(H, K, V)``; ``q``, ``k`` ``(H, K)``; ``v``
+    ``(H, V)``; ``g``, ``beta`` ``(H,)``. Returns ``(o (H, V), state)``."""
+    state = state * jnp.exp(g)[:, None, None]
+    seen = jnp.sum(state * k[:, :, None], axis=1)             # S^T k
+    u = beta[:, None] * (v - seen)
+    state = state + k[:, :, None] * u[:, None, :]
+    return jnp.sum(state * q[:, :, None], axis=1), state
+
+
+def recurrent(state, q, k, v, g, beta):
+    """The recurrence token by token over ``T`` tokens (leading axis of
+    every operand but ``state``): what the chunk-wise form must equal."""
+
+    def step(state, row):
+        out, state = recurrent_step(state, *row)
+        return state, out
+
+    state, out = jax.lax.scan(step, state, (q, k, v, g, beta))
+    return out, state
+
+
+def _solve_rows(lower):
+    """``(I + L)^-1`` for strictly lower-triangular ``L`` ``(..., C, C)``,
+    by forward substitution over the rows: row ``i`` of ``-L`` plus its
+    product with the rows already solved, then the unit diagonal."""
+    size = lower.shape[-1]
+
+    def one_row(i, solved):
+        row = jax.lax.dynamic_slice_in_dim(solved, i, 1, axis=-2)
+        row = row + jnp.matmul(row, solved, precision=_HIGHEST)
+        return jax.lax.dynamic_update_slice_in_dim(solved, row, i, axis=-2)
+
+    return jax.lax.fori_loop(1, size, one_row, -lower) \
+        + jnp.eye(size, dtype=lower.dtype)
+
+
+def chunked(state, q, k, v, g, beta, chunk: int = CHUNK):
+    """The chunk-wise form over ``T`` tokens: ``q``, ``k`` ``(T, H, K)``,
+    ``v`` ``(T, H, V)``, ``g``, ``beta`` ``(T, H)``, ``state`` ``(H, K,
+    V)``. ``T`` is padded up to whole chunks with masked rows. Returns
+    ``(o (T, H, V), state)``."""
+    tokens = q.shape[0]
+    pad = -tokens % chunk
+
+    def chunks(x):
+        x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+        x = x.reshape((-1, chunk) + x.shape[1:])
+        return jnp.moveaxis(x, 2, 1)                # (N, H, C, ...)
+
+    q, k, v, g, beta = map(chunks, (q, k, v, g, beta))
+    decay = jnp.cumsum(g, axis=-1)                  # (N, H, C), <= 0
+    k_beta = k * beta[..., None]
+    rows = jnp.arange(chunk)
+    below = rows[:, None] > rows[None, :]
+    upto = rows[:, None] >= rows[None, :]
+    # exp(decay_i - decay_j) for j <= i; masked before the exponential, so
+    # the positive differences above the diagonal never overflow
+    between = jnp.exp(jnp.where(
+        upto, decay[..., :, None] - decay[..., None, :], 0.0)) * upto
+
+    def mm(a, b):
+        return jnp.matmul(a, b, precision=_HIGHEST)
+
+    k_t = jnp.swapaxes(k, -1, -2)
+    solve = _solve_rows(mm(k_beta, k_t) * between * below)
+    writes = mm(solve, v * beta[..., None])
+    k_decayed = mm(solve, k_beta * jnp.exp(decay)[..., None])
+    inside = mm(q, k_t) * between                   # j <= i
+    to_end = jnp.exp(decay[..., -1:] - decay)       # (N, H, C)
+
+    def one_chunk(state, parts):
+        q_i, k_i, writes_i, k_decayed_i, inside_i, decay_i, to_end_i = parts
+        new = writes_i - mm(k_decayed_i, state)
+        out = mm(q_i * jnp.exp(decay_i)[..., None], state) + mm(inside_i, new)
+        state = state * jnp.exp(decay_i[:, -1])[:, None, None] \
+            + mm(jnp.swapaxes(k_i * to_end_i[..., None], -1, -2), new)
+        return state, out
+
+    state, out = jax.lax.scan(
+        one_chunk, state, (q, k, writes, k_decayed, inside, decay, to_end))
+    out = jnp.moveaxis(out, 1, 2).reshape((-1,) + out.shape[1:2]
+                                          + out.shape[3:])
+    return out[:tokens], state
+
+
+def gated_delta_rule(state, q, k, v, g, beta):
+    """``(o (T, H, V), state)`` for a chunk of ``T`` tokens: the recurrent
+    step at one token, the chunk-wise form at more."""
+    if q.shape[0] == 1:
+        out, state = recurrent_step(state, q[0], k[0], v[0], g[0], beta[0])
+        return out[None], state
+    return chunked(state, q, k, v, g, beta)
+
+
+def form(tokens: int) -> str:
+    """Which form a chunk of ``tokens`` takes (spans, counters)."""
+    return "recurrent" if tokens == 1 else "chunked"

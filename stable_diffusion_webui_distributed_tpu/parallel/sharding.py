@@ -17,7 +17,11 @@ The resident language model (models/lm.py) adds two axes a mesh may carry:
 ``ep`` splits an expert layer's stacked kernels by expert (every chip routes
 over all experts and computes its own, ops/moe.py), ``vp`` splits the token
 table's rows and the head's columns by vocabulary id. A mesh without these
-axes leaves the leaves whole. One chip's share (``LMConfig.experts_held``,
+axes leaves the leaves whole. A linear-attention mixer (module ``delta``:
+its fused projections, convolution taps, decay rates, gated norm) and the
+shared expert's gate are whole on every chip, as attention and the router
+are in the deployment the shares stand for: the recurrence runs per head
+over a state that is not split. One chip's share (``LMConfig.experts_held``,
 ``vocab_held``) is what one position of those axes holds; the exchange that
 adds the parts exists only on a mesh that has the axis.
 """
@@ -47,6 +51,8 @@ def tp_spec_for(path: str, ndim: int):
 
     if leaf in _EXPERT_LEAVES and module == "experts":
         return P("ep", None, None)
+    if "delta" in parts[:-1] or module == "shared_expert_gate":
+        return P()
     if leaf == "embedding" and module == "embed_tokens":
         return P("vp", None)
     if leaf == "kernel" and module == "lm_head":

@@ -17,8 +17,12 @@ the position of the token being made, so image ``i`` of a request gets the
 same expansion on whichever worker its sub-range lands
 (scheduler/world.py), and however decoding was cut into chunks.
 
-The instruction's cache is kept across requests (cache/kv.py): from the
-second request on only the user's own tokens are prefilled.
+What the layers hold after the instruction's last token (keys and values,
+recurrent state, the convolution's inputs) is kept across requests as a
+snapshot (cache/kv.py): from the second request on only the user's own
+tokens are prefilled, against a copy of it. Every kind of state rides in
+the one cache tree, so the decode scan carries it and the executables
+donate it whole.
 """
 
 from __future__ import annotations
@@ -35,6 +39,7 @@ from stable_diffusion_webui_distributed_tpu.models.tokenizer import (
     load_lm_tokenizer,
 )
 from stable_diffusion_webui_distributed_tpu.obs import spans as obs_spans
+from stable_diffusion_webui_distributed_tpu.ops import delta_rule
 from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
     PromptExpansion,
 )
@@ -100,27 +105,40 @@ class PromptExpander:
         key = jax.random.fold_in(rng.key_for_image(seed, image_index),
                                  _KEY_DOMAIN)
         temperature = jnp.float32(args.temperature)
-        cache, held = self.cache.acquire(prefix, capacity)
+        sizes = kv.state_bytes(self.config, capacity, self.cache.dtype)
+        copied = sum(sizes.values())
+        with obs_spans.span("expand.prefix_copy", bytes=copied) as sp:
+            cache, held = self.cache.acquire(prefix, capacity)
+            if sp is not None:
+                sp.attrs["hit"] = bool(held)
+        recurrent = lm.LINEAR in self.config.layer_types
         routed = []       # per executable call: (load, none held)
+        masked = 0        # padded rows kept out of the recurrence
         token = None
         for ids, start, keep in ((prefix, 0, True),
                                  (user, len(prefix), False)):
             if keep and held:
                 continue
-            with obs_spans.span("expand.prefill", tokens=len(ids),
-                                prefix_hit=bool(held)):
-                padded = np.zeros(kv.chunk_bucket(len(ids)), np.int32)
-                padded[:len(ids)] = ids
+            padded = np.zeros(kv.chunk_bucket(len(ids)), np.int32)
+            padded[:len(ids)] = ids
+            attrs = {"tokens": len(ids), "prefix_hit": bool(held)}
+            if recurrent:     # rows masked out of the recurrence, its form
+                attrs.update(padded=len(padded) - len(ids),
+                             form=delta_rule.form(len(padded)))
+            with obs_spans.span("expand.prefill", **attrs):
                 cache, token, step_load, step_none = self._prefill_fn(
                     len(padded), capacity)(
                         params, cache, padded, jnp.int32(start),
                         jnp.int32(len(ids)), key, temperature)
-                if keep:
-                    self.cache.keep_prefix(prefix, capacity, cache)
                 # fenced: the span is the chunk's device time, not its
                 # enqueue
                 jax.block_until_ready(token)
+            if keep:
+                with obs_spans.span("expand.prefix_copy", hit=False,
+                                    bytes=copied):
+                    self.cache.keep_prefix(prefix, capacity, cache)
             routed.append((step_load, step_none))
+            masked += attrs.get("padded", 0)
         made: List[int] = [int(token)]
         position = jnp.int32(len(prefix) + len(user))
         decode = self._decode_fn(capacity)
@@ -154,7 +172,9 @@ class PromptExpander:
             prefilled=len(user) + (0 if held else len(prefix)),
             from_prefix=held, decoded=len(made), decode_steps=steps,
             load=np.sum(loads, axis=0), none_held=int(np.sum(none_held)),
-            positions=self.cache.positions_in_use(length))
+            positions=self.cache.positions_in_use(length),
+            state_bytes=sizes, prefix_snapshots=self.cache.snapshots,
+            padded_rows_masked=masked)
         return made
 
     def _fit(self, text: str, chunks: Optional[int]) -> str:

@@ -398,8 +398,11 @@ class ExpanderStats:
     ``expand`` stage) did: tokens prefilled, tokens whose cache came from
     the kept instruction prefix, tokens decoded, how many tokens the router
     sent to each expert held here (load and its imbalance), tokens none of
-    whose chosen experts is held here, and the cache positions the last
-    sequence occupied by layer kind."""
+    whose chosen experts is held here, the cache positions the last
+    sequence occupied and the bytes its cache took by layer kind (keys and
+    values of full and sliding layers, a linear layer's recurrent state and
+    kept convolution inputs), the instruction prefixes held as snapshots,
+    and the padded prefill rows that were masked out of a recurrence."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -416,10 +419,14 @@ class ExpanderStats:
             #: per expert layer, tokens sent to each held expert
             self.load: List[List[int]] = []  # guarded-by: _lock
             self.positions: Dict[str, int] = {}  # guarded-by: _lock
+            self.state_bytes: Dict[str, int] = {}  # guarded-by: _lock
+            self.prefix_snapshots = 0  # guarded-by: _lock
+            self.padded_rows_masked = 0  # guarded-by: _lock
 
     def record(self, *, prefilled: int, from_prefix: int, decoded: int,
                decode_steps: int, load, none_held: int,
-               positions: Dict[str, int]) -> None:
+               positions: Dict[str, int], state_bytes: Dict[str, int],
+               prefix_snapshots: int, padded_rows_masked: int) -> None:
         """``load`` is (expert layers, held experts) counts of one
         request; ``decode_steps`` the steps its decode executables ran
         (whole chunks, so at least ``decoded - 1``)."""
@@ -437,6 +444,9 @@ class ExpanderStats:
                 self.load = [[a + b for a, b in zip(old, new)]
                              for old, new in zip(self.load, rows)]
             self.positions = dict(positions)
+            self.state_bytes = dict(state_bytes)
+            self.prefix_snapshots = int(prefix_snapshots)
+            self.padded_rows_masked += int(padded_rows_masked)
 
     def summary(self) -> Dict[str, Any]:
         with self._lock:
@@ -453,6 +463,9 @@ class ExpanderStats:
                 "expert_load_max_over_mean":
                     (max(flat) / mean if mean else 0.0),
                 "cache_positions": dict(self.positions),
+                "state_bytes": dict(self.state_bytes),
+                "prefix_snapshots": self.prefix_snapshots,
+                "padded_rows_masked": self.padded_rows_masked,
             }
 
 

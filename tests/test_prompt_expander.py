@@ -513,7 +513,8 @@ class TestEnginePath:
             "requests", "tokens_prefilled", "tokens_from_prefix_cache",
             "tokens_decoded", "decode_steps", "tokens_no_held_expert",
             "expert_tokens",
-            "expert_load_max_over_mean", "cache_positions"}
+            "expert_load_max_over_mean", "cache_positions", "state_bytes",
+            "prefix_snapshots", "padded_rows_masked"}
         json.dumps(block)
 
 
