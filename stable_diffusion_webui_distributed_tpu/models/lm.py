@@ -53,7 +53,9 @@ from stable_diffusion_webui_distributed_tpu.ops.attention import (
     attend_positions,
 )
 from stable_diffusion_webui_distributed_tpu.ops.quant import int8_dot
-from stable_diffusion_webui_distributed_tpu.serving.metrics import ATTENTION
+from stable_diffusion_webui_distributed_tpu.serving.metrics import (
+    ATTENTION, EXPANDER,
+)
 
 FULL, SLIDING, LINEAR = "full", "sliding", "linear"
 #: the cache's buffers of one layer, by the layer's kind
@@ -189,6 +191,7 @@ class MoE(nn.Module):
     config: LMConfig
     dtype: jnp.dtype = jnp.float32
     quant: bool = False
+    meshed: bool = False
 
     @nn.compact
     def __call__(self, n: jax.Array, valid: jax.Array):
@@ -206,9 +209,10 @@ class MoE(nn.Module):
         kernels = Experts(held, cfg.moe_intermediate_size,
                           name="experts")(n.shape[-1])
         compute = [w.astype(self.dtype) for w in kernels]
-        routed = moe.routed_experts(n.astype(self.dtype), routing, *compute,
-                                    first=first,
-                                    num_experts=cfg.num_experts)
+        routed, path = moe.routed_experts(
+            n.astype(self.dtype), routing, *compute, first=first,
+            num_experts=cfg.num_experts, meshed=self.meshed)
+        EXPANDER.record_product(path)
         shared = SwiGLU(cfg.shared_expert_intermediate_size, self.dtype,
                         self.quant, name="shared_expert")(n)
         if cfg.shared_expert_gate:
@@ -363,6 +367,7 @@ class DecoderLayer(nn.Module):
     layer: int
     dtype: jnp.dtype = jnp.float32
     quant: bool = False
+    meshed: bool = False
 
     @nn.compact
     def __call__(self, x, q_pos, start, end, buffers):
@@ -389,8 +394,8 @@ class DecoderLayer(nn.Module):
             out, routed = SwiGLU(cfg.intermediate_size, self.dtype,
                                  self.quant, name="mlp")(n), None
         else:
-            out, routed = MoE(cfg, self.dtype, self.quant, name="mlp")(
-                n, q_pos < end)
+            out, routed = MoE(cfg, self.dtype, self.quant, self.meshed,
+                              name="mlp")(n, q_pos < end)
         return h + out, tuple(buffers), routed
 
 
@@ -407,6 +412,9 @@ class DecoderLM(nn.Module):
     config: LMConfig
     dtype: jnp.dtype = jnp.float32
     quant_linears: bool = False
+    #: a mesh partitions the program this module is traced into: its expert
+    #: layers keep the products ``pjit`` can split (ops/moe.py:choose)
+    meshed: bool = False
 
     @nn.compact
     def __call__(self, tokens, start, length, cache: Dict[str, jax.Array],
@@ -429,7 +437,7 @@ class DecoderLM(nn.Module):
         for layer, kind in enumerate(cfg.layer_types):
             names = buffers_of(kind)
             x, buffers, r = DecoderLayer(
-                cfg, layer, self.dtype, self.quant_linears,
+                cfg, layer, self.dtype, self.quant_linears, self.meshed,
                 name=f"layers_{layer}")(
                     x, q_pos, start, end,
                     tuple(cache[name][len(written[name])] for name in names))

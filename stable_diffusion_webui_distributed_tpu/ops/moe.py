@@ -9,17 +9,26 @@ part: it is told which contiguous range of experts its stacked kernels are
 those, and adds nothing for the absent ones. No code stands in for the
 other chips.
 
-Two products, chosen by what the call shows (the number of tokens, which is
-static):
+Three products, chosen by what the call shows (:func:`choose`: the number
+of tokens, the platform, the operands' dtype and the kernels' shapes, all
+static, and whether a mesh will partition the program):
 
-- ``T > 1`` (prefill): tokens are sorted by the expert they chose, each
-  expert's rows padded up to a row tile, and one loop walks the tiles that
-  hold rows, each a ``(tile, d) @ (d, f)`` product against the one expert
-  the tile belongs to. An expert nobody chose is not read.
-- ``T == 1`` (a decode step): a loop over the chosen experts held here (5 of
-  10 on average when half are held), each reading that expert's three
-  kernels once. The experts that were held but not chosen are not read:
-  that is what bounds a decoded token's bytes.
+- ``T > 1`` (prefill), ``"grouped"``: tokens are sorted by the expert they
+  chose, each expert's rows padded up to a row tile, and one loop walks the
+  tiles that hold rows, each a ``(tile, d) @ (d, f)`` product against the
+  one expert the tile belongs to. An expert nobody chose is not read.
+- ``T == 1`` (a decode step), ``"loop"``: a loop over the chosen experts
+  held here (5 of 10 on average when half are held), each reading that
+  expert's three kernels once. The experts that were held but not chosen
+  are not read: that is what bounds a decoded token's bytes. Taken on a
+  CPU, for float32 operands, for widths off the lane tiling (the tests'
+  tiny presets) and under a mesh (``pjit`` does not partition a
+  ``pallas_call``).
+- ``T == 1`` on a TPU with bf16 operands and ``d`` and ``f`` multiples of
+  the lane width, no mesh, ``"kernel"``: the same sum as one pipelined
+  Pallas kernel (ops/moe_kernel.py) that reads expert ``j + 1``'s kernels
+  while expert ``j`` multiplies, where the loop's trips run one after the
+  other with nothing in flight between them. The same experts are read.
 """
 
 from __future__ import annotations
@@ -28,6 +37,12 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from stable_diffusion_webui_distributed_tpu.ops import moe_kernel
+
+GROUPED = "grouped"
+LOOP = "loop"
+KERNEL = "kernel"
 
 
 class Routing(NamedTuple):
@@ -111,12 +126,16 @@ def _grouped(x, routing: Routing, w_gate, w_up, w_down, first: int,
         part)
 
 
-def _chosen(x, routing: Routing, w_gate, w_up, w_down, first: int):
+def _chosen(x, routing: Routing, w_gate, w_up, w_down, first: int,
+            kernel: bool = False):
     count = w_gate.shape[0]
     local, held = held_mask(routing.experts[0], first, count)
     order = jnp.argsort(~held, stable=True)              # held ones first
     experts = jnp.where(held, local, 0)[order]
     weights = jnp.where(held, routing.weights[0], 0.0)[order]
+    if kernel:
+        return moe_kernel.chosen_experts(x, experts, weights, jnp.sum(held),
+                                         w_gate, w_up, w_down)
 
     def one_expert(j, acc):
         expert = experts[j]
@@ -127,16 +146,38 @@ def _chosen(x, routing: Routing, w_gate, w_up, w_down, first: int):
                              jnp.zeros(x.shape, jnp.float32))
 
 
+def choose(platform: str, tokens: int, dtype, d: int, f: int, *,
+           meshed: bool = False) -> str:
+    """``"grouped"``, ``"loop"`` or ``"kernel"`` for one expert layer's
+    call, from what the call shows. The kernel wants a decode step on a
+    TPU, the serving policy's bf16 (the only dtype run on the chip), widths
+    that tile (ops/moe_kernel.py:f_tile) and a program no mesh partitions:
+    ``pjit`` would run a ``pallas_call`` whole on every chip, against
+    kernels it has split by expert."""
+    if tokens != 1:
+        return GROUPED
+    dtype = jnp.dtype(dtype)
+    if (platform == "tpu" and not meshed and dtype == jnp.bfloat16
+            and moe_kernel.f_tile(d, f, dtype.itemsize) is not None):
+        return KERNEL
+    return LOOP
+
+
 def routed_experts(x: jax.Array, routing: Routing, w_gate: jax.Array,
                    w_up: jax.Array, w_down: jax.Array, *, first: int,
-                   num_experts: int) -> jax.Array:
-    """This chip's part of ``sum_e w_e E_e(x)``: ``x`` is ``(T, d)``, the
-    kernels are stacked ``(held, d, f)``, ``(held, d, f)``, ``(held, f,
-    d)`` and are experts ``first .. first + held - 1`` of ``num_experts``.
+                   num_experts: int, meshed: bool = False):
+    """(this chip's part of ``sum_e w_e E_e(x)``, the product taken):
+    ``x`` is ``(T, d)``, the kernels are stacked ``(held, d, f)``, ``(held,
+    d, f)``, ``(held, f, d)`` and are experts ``first .. first + held - 1``
+    of ``num_experts``; ``meshed`` says a mesh will partition the program.
     Float32 ``(T, d)``."""
-    if x.shape[0] == 1:
-        return _chosen(x, routing, w_gate, w_up, w_down, first)
-    return _grouped(x, routing, w_gate, w_up, w_down, first, num_experts)
+    path = choose(jax.default_backend(), x.shape[0], x.dtype,
+                  *w_gate.shape[1:], meshed=meshed)
+    if path == GROUPED:
+        return _grouped(x, routing, w_gate, w_up, w_down, first,
+                        num_experts), path
+    return _chosen(x, routing, w_gate, w_up, w_down, first,
+                   kernel=path == KERNEL), path
 
 
 def load_counts(routing: Routing, first: int, count: int, valid=None):

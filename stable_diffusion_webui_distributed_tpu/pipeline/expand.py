@@ -59,8 +59,10 @@ class PromptExpander:
     def __init__(self, engine, tokenizer=None) -> None:
         self.engine = engine
         self.config = engine.family.expander
-        self.module = lm.DecoderLM(self.config,
-                                   dtype=engine.policy.compute_dtype)
+        mesh = engine.mesh
+        self.module = lm.DecoderLM(
+            self.config, dtype=engine.policy.compute_dtype,
+            meshed=mesh is not None and mesh.size > 1)
         self.tokenizer = tokenizer or load_lm_tokenizer(
             None, *self.config.vocab)
         self.cache = kv.KVCacheManager(self.config,

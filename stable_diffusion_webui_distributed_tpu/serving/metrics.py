@@ -402,7 +402,10 @@ class ExpanderStats:
     sequence occupied and the bytes its cache took by layer kind (keys and
     values of full and sliding layers, a linear layer's recurrent state and
     kept convolution inputs), the instruction prefixes held as snapshots,
-    and the padded prefill rows that were masked out of a recurrence."""
+    and the padded prefill rows that were masked out of a recurrence.
+    ``expert_products`` counts expert layers by the product they took
+    (ops/moe.py:choose) when the model was TRACED, as :class:`AttentionSites`
+    counts its sites: nothing is counted when an executable runs."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -422,6 +425,13 @@ class ExpanderStats:
             self.state_bytes: Dict[str, int] = {}  # guarded-by: _lock
             self.prefix_snapshots = 0  # guarded-by: _lock
             self.padded_rows_masked = 0  # guarded-by: _lock
+            self.products = {"kernel": 0, "loop": 0,
+                             "grouped": 0}  # guarded-by: _lock
+
+    def record_product(self, path: str) -> None:
+        """One expert layer in one trace took product ``path``."""
+        with self._lock:
+            self.products[path] += 1
 
     def record(self, *, prefilled: int, from_prefix: int, decoded: int,
                decode_steps: int, load, none_held: int,
@@ -466,6 +476,7 @@ class ExpanderStats:
                 "state_bytes": dict(self.state_bytes),
                 "prefix_snapshots": self.prefix_snapshots,
                 "padded_rows_masked": self.padded_rows_masked,
+                "expert_products": dict(self.products),
             }
 
 

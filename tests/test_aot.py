@@ -14,7 +14,10 @@ to ``executed``/``failed`` in the audit ring.
 
 import os
 
+import jax
 import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
 
 from stable_diffusion_webui_distributed_tpu.fleet import pool as fleet_pool
 from stable_diffusion_webui_distributed_tpu.fleet.slices import (
@@ -35,6 +38,22 @@ from test_goldens import _check
 from test_pipeline import init_params
 
 
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    """A test that ran earlier in this process may have placed JAX's
+    persistent cache (serving/warmup.py through tests/test_serving.py): a
+    CPU executable that the "cold" engine is then handed from that cache
+    serializes to an artifact whose functions are not found at load. The
+    store under test is SDTPU_AOT's: keep the other cache off around
+    these."""
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
 def payload(**kw):
     defaults = dict(prompt="an aot cow", steps=4, width=32, height=32,
                     seed=7, sampler_name="Euler a")
@@ -50,8 +69,6 @@ def fresh_engine():
 # -- unit plumbing over a tiny jit cell --------------------------------------
 
 def _double_build():
-    import jax
-
     return jax.jit(lambda x: x * 2.0)
 
 

@@ -1,4 +1,5 @@
-"""Both Pallas attention kernels, compiled by the TPU's own compiler.
+"""The Pallas kernels (both attention kernels, the chosen experts' sum of a
+decode step), compiled by the TPU's own compiler.
 
 Interpret mode (tests/test_ops.py, tests/test_ragged.py) checks the math;
 it cannot see what Mosaic refuses: a slice off the tiling, too much VMEM.
@@ -22,6 +23,7 @@ from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+from stable_diffusion_webui_distributed_tpu.ops import moe_kernel
 from stable_diffusion_webui_distributed_tpu.ops.flash_attention import (
     flash_attention,
 )
@@ -117,23 +119,53 @@ def test_flash_kernel_compiles_under_highest_matmul_precision(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("which", ["decode", "prefill"])
+@pytest.mark.parametrize("precision", ["default", "highest"])
+@pytest.mark.parametrize("d,f", [(3072, 1024), (2048, 512)])
+def test_chosen_experts_kernel_compiles_for_v5e(one_chip, d, f, precision):
+    """Both published expert shapes (Laguna-S-2.1's and Qwen3-Next's), 128
+    held, 10 chosen, bf16; also under benchmarks/verify_reference.py's
+    ``default_matmul_precision("highest")``, which must not reach the
+    kernel's dots."""
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    wide = on_chip((128, d, f), jnp.bfloat16)
+    with jax.default_matmul_precision(precision):
+        text = _compiled_text(
+            lambda *a: moe_kernel.chosen_experts(*a, interpret=False),
+            on_chip((1, d), jnp.bfloat16), on_chip((10,), jnp.int32),
+            on_chip((10,), jnp.float32), on_chip((), jnp.int32), wide, wide,
+            on_chip((128, f, d), jnp.bfloat16))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("which,expander,argument_gb,kernels", [
+    ("decode", "sd15_laguna_expander", 11.1, 4),
+    ("prefill", "sd15_laguna_expander", 11.1, 0),
+    ("decode", "sd15_qwen3next_expander", 10.8, 12),
+])
 def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
-        one_chip, which):
-    """The Laguna share's decode chunk and its 64-token prefill at the
-    published widths (5.57 B parameters as bfloat16 shapes, a 1 024-slot
-    cache): the chip's compiler accepts them, the weights are arguments and
-    not copies (an expert's kernels are sliced in the loop, never gathered
-    whole), and everything fits beside SD1.5."""
+        one_chip, monkeypatch, which, expander, argument_gb, kernels):
+    """A share's decode chunk (and the Laguna share's 64-token prefill) at
+    the published widths (5.57 B and 5.42 B parameters as bfloat16 shapes,
+    a 1 024-slot cache): the chip's compiler accepts them, every expert
+    layer of a decode step is the pipelined kernel (ops/moe.py:choose is
+    told the platform it is compiled for) and a prefill has none, the
+    weights are arguments and not copies (an expert's kernels are read
+    block by block, never gathered whole), and everything fits beside
+    SD1.5."""
     from stable_diffusion_webui_distributed_tpu.models import configs, lm
 
-    cfg = configs.sd15_laguna_expander().expander
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = getattr(configs, expander)().expander
     module = lm.DecoderLM(cfg, dtype=jnp.bfloat16)
 
     def on_chip(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    cache = {name: [on_chip(shape, jnp.bfloat16) for shape in rows]
+    cache = {name: [on_chip(shape, jnp.bfloat16
+                            if name in lm.ATTENTION_BUFFERS else jnp.float32)
+                    for shape in rows]
              for name, rows in lm.cache_shapes(cfg, 1024).items()}
     small = {name: [jax.ShapeDtypeStruct(shape, jnp.float32)
                     for shape in rows]
@@ -155,7 +187,11 @@ def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
         lowered = jax.jit(lm.prefill_fn(module), donate_argnums=(1,)).lower(
             params, cache, on_chip((64,), jnp.int32), scalar, scalar, key,
             heat)
-    memory = lowered.compile().memory_analysis()
-    assert 11.1e9 < memory.argument_size_in_bytes < 11.2e9
+    compiled = lowered.compile()
+    calls = compiled.as_text().count("tpu_custom_call")
+    assert calls >= kernels and bool(calls) == bool(kernels)
+    memory = compiled.memory_analysis()
+    assert argument_gb * 1e9 < memory.argument_size_in_bytes \
+        < (argument_gb + 0.1) * 1e9
     assert memory.temp_size_in_bytes < 64e6
     assert memory.alias_size_in_bytes > 14e6      # the cache is donated

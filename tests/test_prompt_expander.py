@@ -24,7 +24,9 @@ from stable_diffusion_webui_distributed_tpu.models import configs, lm
 from stable_diffusion_webui_distributed_tpu.models.tokenizer import (
     FallbackLMTokenizer, load_lm_tokenizer,
 )
-from stable_diffusion_webui_distributed_tpu.ops import attention, moe
+from stable_diffusion_webui_distributed_tpu.ops import (
+    attention, moe, moe_kernel,
+)
 from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
 from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
     GenerationPayload, prompt_expansion_args,
@@ -229,8 +231,15 @@ class TestExperts:
     @pytest.mark.parametrize("tokens", [1, 5, 40])
     def test_both_products_equal_the_dense_sum(self, tokens):
         x, routing, wg, wu, wd = self._layer(tokens)
-        got = jax.jit(lambda *a: moe.routed_experts(
-            *a, first=0, num_experts=16))(x, routing, wg, wu, wd)
+        paths = []
+
+        def layer(*a):
+            out, path = moe.routed_experts(*a, first=0, num_experts=16)
+            paths.append(path)
+            return out
+
+        got = jax.jit(layer)(x, routing, wg, wu, wd)
+        assert paths == [moe.LOOP if tokens == 1 else moe.GROUPED]
         np.testing.assert_allclose(got, self._dense(x, routing, wg, wu, wd),
                                    rtol=2e-4, atol=2e-5)
 
@@ -242,7 +251,7 @@ class TestExperts:
         whole = self._dense(x, routing, wg, wu, wd)
         parts = [moe.routed_experts(x, routing, wg[lo:lo + 8],
                                     wu[lo:lo + 8], wd[lo:lo + 8], first=lo,
-                                    num_experts=16) for lo in (0, 8)]
+                                    num_experts=16)[0] for lo in (0, 8)]
         assert not np.allclose(parts[0], whole, atol=1e-3)
         np.testing.assert_allclose(parts[0] + parts[1], whole, rtol=2e-4,
                                    atol=2e-5)
@@ -258,6 +267,136 @@ class TestExperts:
     def test_row_tile_is_a_power_of_two_near_the_mean_rows(self):
         assert moe.row_tile(576, 10, 256) == 32
         assert moe.row_tile(64, 10, 256) == 8
+
+
+class TestTheChosenExpertsKernel:
+    """ops/moe_kernel.py in interpret mode against ``moe._chosen``'s loop,
+    at small widths on the lane tiling. The token chose experts 9, 2, 12
+    and 5; which of them are held is the share's range."""
+
+    D, F, EXPERTS = 128, 256, 16
+
+    def _layer(self, dtype, seed=3):
+        ks = jax.random.split(jax.random.key(seed), 4)
+        d, f, e = self.D, self.F, self.EXPERTS
+        x = jax.random.normal(ks[0], (1, d)).astype(dtype)
+        wg = (jax.random.normal(ks[1], (e, d, f)) / d ** 0.5).astype(dtype)
+        wu = (jax.random.normal(ks[2], (e, d, f)) / d ** 0.5).astype(dtype)
+        wd = (jax.random.normal(ks[3], (e, f, d)) / f ** 0.5).astype(dtype)
+        routing = moe.Routing(jnp.array([[9, 2, 12, 5]], jnp.int32),
+                              jnp.array([[0.9, 0.7, 0.5, 0.4]], jnp.float32))
+        return x, routing, wg, wu, wd
+
+    @pytest.mark.parametrize("tile", [256, 128])
+    @pytest.mark.parametrize("first,count,held", [
+        (13, 3, 0), (8, 2, 1), (4, 8, 2), (2, 11, 4), (0, 16, 4)])
+    def test_the_kernel_equals_the_loop(self, monkeypatch, first, count,
+                                        held, tile):
+        """None, one, some and all of the chosen experts held, shares that
+        start past expert 0, a whole expert a block and two tiles of f."""
+        x, routing, wg, wu, wd = self._layer(jnp.float32)
+        share = [w[first:first + count] for w in (wg, wu, wd)]
+        assert int(moe.held_mask(routing.experts[0], first,
+                                 count)[1].sum()) == held
+        # six blocks of a whole expert are 768 KiB: under that, f is cut
+        monkeypatch.setattr(moe_kernel, "_WEIGHT_VMEM",
+                            (768 if tile == 256 else 400) * 1024)
+        assert moe_kernel.f_tile(self.D, self.F, 4) == tile
+        want = moe._chosen(x, routing, *share, first)
+        got = moe._chosen(x, routing, *share, first, kernel=True)
+        assert got.shape == (1, self.D) and got.dtype == jnp.float32
+        if held == 0:
+            assert not np.any(np.asarray(got))
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("precision", [None, "highest"])
+    def test_bf16_operands_accumulate_in_float32(self, precision):
+        """The serving policy's operands, and a caller's matmul precision
+        that must not reach the kernel's dots."""
+        x, routing, wg, wu, wd = self._layer(jnp.bfloat16)
+        want = moe._chosen(x, routing, wg, wu, wd, 0)
+        with jax.default_matmul_precision(precision or "default"):
+            got = moe._chosen(x, routing, wg, wu, wd, 0, kernel=True)
+        np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+
+    @pytest.mark.parametrize("d,f,itemsize,tile", [
+        (3072, 1024, 2, 512),     # Laguna-S-2.1's experts: two tiles
+        (2048, 512, 2, 512),      # Qwen3-Next's: a whole expert a block
+        (128, 256, 4, 256),
+        (32, 16, 4, None),        # the tiny presets: off the lanes
+        (3072, 1000, 2, None),
+        (65536, 128, 2, None),    # on the lanes, and one tile is too much
+    ])
+    def test_the_tile_comes_from_the_shape(self, d, f, itemsize, tile):
+        assert moe_kernel.f_tile(d, f, itemsize) == tile
+
+    def test_a_width_that_does_not_tile_is_refused(self):
+        x, routing, wg, wu, wd = self._layer(jnp.float32)
+        with pytest.raises(ValueError, match="do not tile"):
+            moe_kernel.chosen_experts(
+                x[:, :96], routing.experts[0], routing.weights[0],
+                jnp.int32(4), wg[:, :96], wu[:, :96], wd[:, :, :96])
+
+    @pytest.mark.parametrize("platform,tokens,dtype,d,f,meshed,path", [
+        ("tpu", 1, jnp.bfloat16, 3072, 1024, False, moe.KERNEL),
+        ("tpu", 1, jnp.bfloat16, 2048, 512, False, moe.KERNEL),
+        ("cpu", 1, jnp.bfloat16, 3072, 1024, False, moe.LOOP),
+        ("gpu", 1, jnp.bfloat16, 3072, 1024, False, moe.LOOP),
+        ("tpu", 1, jnp.float32, 3072, 1024, False, moe.LOOP),
+        ("tpu", 1, jnp.bfloat16, 32, 16, False, moe.LOOP),
+        ("tpu", 1, jnp.bfloat16, 3072, 1000, False, moe.LOOP),
+        ("tpu", 1, jnp.bfloat16, 3000, 1024, False, moe.LOOP),
+        ("tpu", 1, jnp.bfloat16, 3072, 1024, True, moe.LOOP),
+        ("tpu", 64, jnp.bfloat16, 3072, 1024, False, moe.GROUPED),
+        ("cpu", 2, jnp.float32, 32, 16, True, moe.GROUPED),
+    ])
+    def test_the_choice_is_made_from_what_the_call_shows(
+            self, platform, tokens, dtype, d, f, meshed, path):
+        assert moe.choose(platform, tokens, dtype, d, f,
+                          meshed=meshed) == path
+
+    @pytest.mark.parametrize("platform,meshed,tokens,path", [
+        ("cpu", False, 1, "loop"), ("cpu", False, 6, "grouped"),
+        ("tpu", False, 1, "kernel"), ("tpu", True, 1, "loop"),
+        ("tpu", False, 6, "grouped")])
+    def test_an_expert_layer_counts_the_product_it_was_traced_with(
+            self, monkeypatch, platform, meshed, tokens, path):
+        """``serving.expander`` ``expert_products``: one count a layer a
+        trace (nothing compiles here: a described platform only steers the
+        chooser)."""
+        cfg = dataclasses.replace(
+            configs.TINY_LM, hidden_size=self.D,
+            moe_intermediate_size=self.F)
+        n = jnp.zeros((tokens, self.D), jnp.bfloat16)
+        layer = lm.MoE(cfg, jnp.bfloat16, meshed=meshed)
+        valid = jnp.ones(tokens, bool)
+        params = jax.eval_shape(
+            lambda: layer.init(jax.random.key(0), n, valid))["params"]
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        EXPANDER.clear()
+        out, _ = jax.eval_shape(
+            lambda p: layer.apply({"params": p}, n, valid), params)
+        assert out.shape == (tokens, self.D)
+        want = {"kernel": 0, "loop": 0, "grouped": 0, path: 1}
+        assert EXPANDER.summary()["expert_products"] == want
+
+    def test_the_tiny_expanders_decode_chunk_counts_its_layers(self):
+        """Three expert layers traced once inside the scan's body, on the
+        loop: a CPU, float32, widths off the lanes."""
+        module = lm.DecoderLM(CFG)
+        params = jax.eval_shape(lambda: lm_params(CFG))
+        cache = lm.empty_cache(CFG, 16, jnp.float32)
+        EXPANDER.clear()
+        jax.eval_shape(lm.decode_chunk_fn(module, 4), params, cache,
+                       jnp.int32(1), jnp.int32(3), jax.random.key(0),
+                       jnp.float32(0.0))
+        assert EXPANDER.summary()["expert_products"] == {
+            "kernel": 0, "loop": 3, "grouped": 0}
+        jax.eval_shape(lm.prefill_fn(module), params, cache,
+                       jnp.zeros((8,), jnp.int32), jnp.int32(0),
+                       jnp.int32(5), jax.random.key(0), jnp.float32(0.0))
+        assert EXPANDER.summary()["expert_products"] == {
+            "kernel": 0, "loop": 3, "grouped": 3}
         assert moe.row_tile(100000, 10, 256) == 256
 
 
@@ -514,7 +653,8 @@ class TestEnginePath:
             "tokens_decoded", "decode_steps", "tokens_no_held_expert",
             "expert_tokens",
             "expert_load_max_over_mean", "cache_positions", "state_bytes",
-            "prefix_snapshots", "padded_rows_masked"}
+            "prefix_snapshots", "padded_rows_masked", "expert_products"}
+        assert set(block["expert_products"]) == {"kernel", "loop", "grouped"}
         json.dumps(block)
 
 

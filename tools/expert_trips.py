@@ -1,0 +1,126 @@
+"""What one chosen expert costs a decode step: the loop against the
+pipelined kernel (PERF.md section 6, PR 34).
+
+    chiprun -- python3 tools/expert_trips.py [--budgets 3 6 12 24 40]
+
+For each published expert shape (Laguna-S-2.1's 3072 x 1024 and
+Qwen3-Next's 2048 x 512, 128 of ``num_experts`` held, 10 a token, bf16) one
+expert layer's routed sum runs ``--steps`` times in a device-side scan, each
+step on its own seeded draw of 10 of the layer's experts and fed the step
+before's result, once through ``ops/moe.py``'s loop and once through
+``ops/moe_kernel.py`` at each ``--budgets`` MiB of VMEM for the kernels'
+blocks (the ``f`` tile follows from the budget). A row says microseconds a
+step and a chosen-and-held expert, GB/s over the bytes those experts'
+kernels hold, and the kernel's largest difference from the loop over the
+steps' results. Written to ``chiprun_out/expert_trips.json``; a CPU is
+refused: a time comes from the chip.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+OUT = os.path.join(REPO, "chiprun_out", "expert_trips.json")
+#: (name, d, f, experts of the layer, held here, chosen a token)
+SHAPES = (("laguna", 3072, 1024, 256, 128, 10),
+          ("qwen3next", 2048, 512, 512, 128, 10))
+REPEATS = 5
+
+
+def main() -> int:
+    parser = argparse.ArgumentParser()
+    parser.add_argument("--steps", type=int, default=256)
+    parser.add_argument("--budgets", type=int, nargs="*", default=[24])
+    parser.add_argument("--seed", type=int, default=0)
+    args = parser.parse_args()
+
+    sys.path.insert(0, REPO)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from stable_diffusion_webui_distributed_tpu.ops import moe, moe_kernel
+
+    if jax.default_backend() != "tpu":
+        print("expert_trips.py times the chip: no TPU here", file=sys.stderr)
+        return 1
+
+    def layer(name, d, f, experts, held, k):
+        keys = jax.random.split(jax.random.key(args.seed), 5)
+
+        def kernel(key, shape, fan_in):
+            return (jax.random.normal(key, shape, jnp.bfloat16)
+                    * fan_in ** -0.5)
+
+        w = (kernel(keys[0], (held, d, f), d),
+             kernel(keys[1], (held, d, f), d),
+             kernel(keys[2], (held, f, d), f))
+        x0 = jax.random.normal(keys[3], (1, d), jnp.bfloat16)
+        logits = jax.random.normal(keys[4], (args.steps, experts))
+        routing = moe.route(logits, k, renormalise=True, scale=1.0)
+        held_trips = int(np.sum(np.asarray(routing.experts) < held))
+
+        def run(use_kernel):
+            def steps(x, w, routing):
+                def step(x, route):
+                    out = moe._chosen(x, moe.Routing(route[0][None],
+                                                     route[1][None]),
+                                      *w, 0, kernel=use_kernel)
+                    return (0.9 * x + 0.1 * out).astype(x.dtype), out[0]
+
+                return jax.lax.scan(step, x, tuple(routing))[1]
+
+            return jax.jit(steps)
+
+        def time_it(fn):
+            result = jax.block_until_ready(fn(x0, w, routing))
+            seconds = []
+            for _ in range(REPEATS):
+                t0 = time.perf_counter()
+                jax.block_until_ready(fn(x0, w, routing))
+                seconds.append(time.perf_counter() - t0)
+            return result, statistics.median(seconds)
+
+        expert_bytes = 3 * d * f * 2
+        rows = []
+        want, seconds = time_it(run(False))
+
+        def row(path, seconds, **more):
+            return dict(
+                shape=name, path=path, steps=args.steps,
+                held_trips=held_trips,
+                us_a_step=1e6 * seconds / args.steps,
+                us_a_trip=1e6 * seconds / held_trips,
+                gb_s=held_trips * expert_bytes / seconds / 1e9, **more)
+
+        rows.append(row("loop", seconds))
+        for budget in args.budgets:
+            moe_kernel._WEIGHT_VMEM = budget * 2 ** 20
+            tile = moe_kernel.f_tile(d, f, 2)
+            if tile is None:
+                continue
+            got, seconds = time_it(run(True))
+            rows.append(row(
+                "kernel", seconds, budget_mib=budget, f_tile=tile,
+                max_abs_diff=float(jnp.max(jnp.abs(got - want))),
+                max_abs=float(jnp.max(jnp.abs(want)))))
+        return rows
+
+    rows = [r for shape in SHAPES for r in layer(*shape)]
+    os.makedirs(os.path.dirname(OUT), exist_ok=True)
+    with open(OUT, "w") as out:
+        json.dump({"device": jax.devices()[0].device_kind, "rows": rows},
+                  out, indent=1)
+    for r in rows:
+        print(json.dumps(r))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
