@@ -1,13 +1,16 @@
 """Per-layer state of the resident language model (models/lm.py), and the
 instruction prefix's state kept across requests.
 
-One manager holds three kinds of layer. A FULL layer keeps every position,
+One manager holds four kinds of layer. A FULL layer keeps every position,
 so its key and value buffers are as long as the sequence may get; lengths
 are bucketed (:data:`CAPACITY_STEP`) so that one executable serves every
 request of a traffic mix. A SLIDING layer only ever attends the last
 ``sliding_window`` positions, so its buffers are rings of that many slots
 whatever the length. A LINEAR layer has no positions: its recurrent state
-and the convolution's last inputs are of one size at every length.
+and the convolution's last inputs are of one size at every length. A LATENT
+layer keeps every position as a FULL one does, in one buffer whose row is
+the position's latent and its one rotated key, not every head's keys and
+values.
 
 The expander's requests all begin with the operator's instruction text.
 What its layers hold after the prefix's LAST token (every kind) is computed
@@ -52,18 +55,17 @@ def capacity_for(positions: int) -> int:
 
 def state_bytes(config, capacity: int, dtype) -> Dict[str, int]:
     """Bytes one sequence's cache takes at ``capacity``, by layer kind,
-    from the shapes: keys and values in ``dtype``, a linear layer's state
-    and kept inputs in float32."""
-    shapes = lm.cache_shapes(config, capacity)
-    attention = [kind for kind in config.layer_types if kind != lm.LINEAR]
+    from the shapes: keys, values and latents in ``dtype``, a linear
+    layer's state and kept inputs in float32. Full and sliding are always
+    named; linear and latent where the model has such layers."""
+    shapes = {name: iter(rows)
+              for name, rows in lm.cache_shapes(config, capacity).items()}
     out = {lm.FULL: 0, lm.SLIDING: 0}
-    for name in lm.ATTENTION_BUFFERS:
-        for kind, shape in zip(attention, shapes[name]):
-            out[kind] += math.prod(shape) * jnp.dtype(dtype).itemsize
-    if lm.LINEAR in config.layer_types:
-        out[lm.LINEAR] = 4 * sum(math.prod(shape)
-                                 for name in lm.LINEAR_BUFFERS
-                                 for shape in shapes[name])
+    for kind in config.layer_types:
+        out[kind] = out.get(kind, 0) + sum(
+            math.prod(next(shapes[name]))
+            * lm.buffer_dtype(name, dtype).itemsize
+            for name in lm.buffers_of(kind))
     return out
 
 
@@ -113,7 +115,7 @@ class KVCacheManager:
     def positions_in_use(self, length: int) -> Dict[str, int]:
         """Cache positions a sequence of ``length`` occupies, by layer
         kind, summed over the layers of the kind; a linear layer uses
-        none at any length."""
+        none at any length, a latent layer one a position."""
         cfg = self.config
         out = {
             lm.FULL: len(cfg.layers_of(lm.FULL)) * length,
@@ -122,4 +124,6 @@ class KVCacheManager:
         }
         if lm.LINEAR in cfg.layer_types:
             out[lm.LINEAR] = 0
+        if lm.LATENT in cfg.layer_types:
+            out[lm.LATENT] = len(cfg.layers_of(lm.LATENT)) * length
         return out

@@ -9,6 +9,7 @@ path, ~100k params.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Tuple
 
 
@@ -92,8 +93,10 @@ class RopeConfig:
 class LMConfig:
     """A decoder-only language model read from lists: the kind of every
     layer's token mixer (``"full"`` or ``"sliding"`` attention over cached
-    keys and values, or ``"linear"``: a gated delta rule over a recurrent
-    state of fixed size behind a short causal convolution), its query
+    keys and values, ``"linear"``: a gated delta rule over a recurrent
+    state of fixed size behind a short causal convolution, or ``"latent"``:
+    attention over one cached low-rank latent and one rotated key a
+    position, shared by every head), its query
     heads (an entry of a linear layer is not read), which layers have a
     dense MLP and which a router over experts. ``num_experts``
     is the router's width (all of the layer's experts); ``experts_held`` and
@@ -108,7 +111,15 @@ class LMConfig:
     ``x_hat * (1 + weight)`` instead of ``x_hat * scale``.
     ``shared_expert_gate`` multiplies the shared expert by
     ``sigmoid(w_s^T n)``. The defaults of these four are the ungated,
-    un-normed forms."""
+    un-normed forms.
+
+    ``router_scoring`` is ``"softmax"`` or ``"sigmoid"`` over the router's
+    outputs; ``router_bias`` adds a learned per-expert bias to the scores
+    when the experts are CHOSEN and not when they are weighed.
+    ``residual_streams`` over 1 replaces ``x + F(norm(x))`` by that many
+    streams a token, read, written and mixed per token by a mixer around
+    every sublayer (models/lm.py:StreamMixer), whose mixing matrix is made
+    doubly stochastic by ``sinkhorn_iters`` Sinkhorn iterations."""
 
     vocab_size: int = 100352
     hidden_size: int = 3072
@@ -141,6 +152,22 @@ class LMConfig:
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
     linear_conv_kernel: int = 4
+    # "latent" layers: the ranks of the query's and the cache's low-rank
+    # paths, a head's un-rotated and rotated key widths and its value
+    # width; they rotate by ``rope_full`` over all of ``qk_rope_head_dim``.
+    # YaRN's ``mscale_all_dim`` scales their softmax, not the tables.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_mscale_all_dim: float = 0.0
+    router_scoring: str = "softmax"
+    router_bias: bool = False
+    residual_streams: int = 1
+    sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
 
     @property
     def num_layers(self) -> int:
@@ -168,6 +195,22 @@ class LMConfig:
         queries, keys and values side by side."""
         return (2 * self.linear_num_key_heads * self.linear_key_head_dim
                 + self.linear_num_value_heads * self.linear_value_head_dim)
+
+    @property
+    def latent_width(self) -> int:
+        """Values a latent layer caches a position: the normed latent and
+        the one rotated key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_softmax_scale(self) -> float:
+        """``(nope + rope) ** -0.5 * m ** 2``, ``m = 0.1 * mscale_all_dim *
+        ln(factor) + 1`` where the rotary tables are YaRN's, else 1."""
+        m = 1.0
+        if self.rope_full.factor > 1 and self.rope_mscale_all_dim:
+            m += 0.1 * self.rope_mscale_all_dim \
+                * math.log(self.rope_full.factor)
+        return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5 * m * m
 
 
 @dataclasses.dataclass(frozen=True)
@@ -426,6 +469,39 @@ def sd15_qwen3next_expander() -> ModelFamily:
         expander=lm_share(QWEN3_NEXT_80B_A3B, layers=12, chips=4, rank=0))
 
 
+# Xing4.0-29B-A4B (huggingface.co/XingChen-AGI/Xing4.0-29B-A4B config.json)
+# at its published widths: 40 latent-attention layers of 32 heads (a 768-wide
+# query latent, a cached 512-wide latent and one 64-wide rotated key, keys
+# of 128 + 64 and values of 128 a head), YaRN by 64 over 4096 whose mscale
+# goes into the softmax, four residual streams mixed by 20 Sinkhorn
+# iterations around every sublayer, two dense layers of width 9216 then a
+# sigmoid router with a selection bias over 64 experts of width 1024, 4 a
+# token at scale 2, plus one shared.
+XING4_0_29B_A4B = LMConfig(
+    vocab_size=131072, hidden_size=3584, layer_types=("latent",) * 40,
+    num_heads_per_layer=(32,) * 40,
+    rope_full=RopeConfig(theta=1e4, factor=64.0, original_max_position=4096,
+                         beta_fast=32.0, beta_slow=1.0, attention_factor=1.0),
+    dense_layers=(0, 1), intermediate_size=9216, num_experts=64,
+    num_experts_per_tok=4, moe_intermediate_size=1024,
+    shared_expert_intermediate_size=1024, routed_scaling_factor=2.0,
+    norm_topk_prob=True, rms_norm_eps=1e-6, q_lora_rank=768,
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128, rope_mscale_all_dim=1.0, router_scoring="sigmoid",
+    router_bias=True, residual_streams=4, sinkhorn_iters=20, hc_eps=1e-6,
+    hc_res_clamp=(-30.0, 30.0))
+
+
+def sd15_xing4_expander() -> ModelFamily:
+    """SD1.5 with Xing4.0-29B-A4B as its resident prompt expander, cut to
+    one chip of a four-chip host: layers 0-19 (the first pipeline stage:
+    both dense layers and 18 expert layers), experts 0-15 of every expert
+    layer, vocabulary ids 0-32767."""
+    return dataclasses.replace(
+        SD15, name="sd15-xing4-expand",
+        expander=lm_share(XING4_0_29B_A4B, layers=20, chips=4, rank=0))
+
+
 # Tiny expander that keeps every kind: two head counts, a window (8) shorter
 # than any test context so the ring wraps, a dense first layer, 16 experts
 # top-4 with a shared one, partial YaRN and full plain rotary.
@@ -475,6 +551,32 @@ TINY_DELTA_EXPAND = dataclasses.replace(
 def tiny_delta_expander() -> ModelFamily:
     """Factory form of :data:`TINY_DELTA_EXPAND` (benchmark rehearsals)."""
     return TINY_DELTA_EXPAND
+
+
+# Tiny expander of latent-attention layers: a cached latent (16 + a rotated
+# key of 8) narrower than the four heads' keys and values (4 x (8 + 8) and
+# 4 x 8), a 24-wide query latent, YaRN whose mscale scales the softmax, four
+# residual streams, two dense layers then expert layers, 16 experts top-4
+# by sigmoid scores with a selection bias, one shared.
+TINY_LATENT_LM = LMConfig(
+    vocab_size=512, hidden_size=32, layer_types=("latent",) * 4,
+    num_heads_per_layer=(4,) * 4,
+    rope_full=RopeConfig(theta=1e4, factor=4.0, original_max_position=16,
+                         attention_factor=1.0),
+    dense_layers=(0, 1), intermediate_size=64, num_experts=16,
+    num_experts_per_tok=4, moe_intermediate_size=16,
+    shared_expert_intermediate_size=16, routed_scaling_factor=2.0,
+    q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8,
+    qk_rope_head_dim=8, v_head_dim=8, rope_mscale_all_dim=1.0,
+    router_scoring="sigmoid", router_bias=True, residual_streams=4)
+TINY_LATENT_EXPAND = dataclasses.replace(
+    TINY, name="tiny-latent-expand",
+    expander=lm_share(TINY_LATENT_LM, 4, chips=4, rank=0))
+
+
+def tiny_xing4_expander() -> ModelFamily:
+    """Factory form of :data:`TINY_LATENT_EXPAND` (benchmark rehearsals)."""
+    return TINY_LATENT_EXPAND
 
 
 FAMILIES = {f.name: f for f in (SD15, SD21, SD21_BASE, SDXL_BASE,

@@ -6,31 +6,39 @@ Nothing here names an architecture. ``LMConfig`` says, layer by layer, which
 token mixer a layer has (``"full"``: attention over every earlier
 position; ``"sliding"``: over the last ``sliding_window``; ``"linear"``: a
 gated delta rule over a recurrent state, ops/delta_rule.py, behind a short
-causal convolution), how many query heads an attention layer has (they may
-differ by layer; the KV heads are shared by groups of them), which rotary
-parameterisation goes with which kind, whether queries and keys are normed
-per head, and whether the MLP is dense or a router over experts with one
-shared expert (ops/moe.py), gated or not. An attention output passes a
-sigmoid gate before ``o_proj``: one a head from ``g_proj``, or one a
-channel from the second half of ``q_proj``'s columns
+causal convolution; ``"latent"``: attention whose cache holds one low-rank
+latent and one rotated key a position, shared by every head, in two forms
+over that one cache, :class:`LatentAttention`), how many query heads an
+attention layer has (they may differ by layer; the KV heads are shared by
+groups of them), which rotary parameterisation goes with which kind,
+whether queries and keys are normed per head, and whether the MLP is dense
+or a router over experts with one shared expert (ops/moe.py), gated or
+not. An attention output passes a sigmoid gate before ``o_proj``: one a
+head from ``g_proj``, or one a channel from the second half of
+``q_proj``'s columns
 (``LMConfig.attn_gate``). A norm is ``x_hat * scale`` or, zero-centred,
-``x_hat * (1 + weight)``.
+``x_hat * (1 + weight)``. The router's scores are a softmax or sigmoids,
+with or without a bias that chooses (ops/moe.py:route). With
+``residual_streams`` over 1 a token is ``(streams, hidden)`` between
+sublayers and a :class:`StreamMixer` around each sublayer reads, writes
+and mixes the streams; with 1 a layer is ``x + F(norm(x))``.
 
 One call, :meth:`DecoderLM.__call__`, runs a chunk of ``T`` tokens that
 starts at position ``start`` against the cache and returns the cache with
 the chunk written: a prefill is a long chunk, a decode step a chunk of one.
 The cache (cache/kv.py) holds, per layer, the buffers of the layer's kind,
-three kinds in all: a full layer's key and value buffers hold every
+four kinds in all: a full layer's key and value buffers hold every
 position up to their capacity; a sliding layer's are rings of
 ``sliding_window`` slots, slot ``p % window`` holding position ``p``; a
 linear layer has no positions at all but the recurrent state ``(value
 heads, key width, value width)`` in float32 and the convolution's last
-``taps - 1`` inputs, neither growing with the sequence.
+``taps - 1`` inputs, neither growing with the sequence; a latent layer has
+ONE buffer, ``capacity`` rows of the normed latent beside the rotated key.
 A chunk may be padded: only its first ``length`` tokens are real. A padded
-row is never written to a ring and lands beyond ``end`` in a full buffer,
-where no real query sees it; in a linear layer it neither decays the state
-nor writes to it (its decay and write strength are masked), and the
-convolution keeps the last REAL rows.
+row is never written to a ring and lands beyond ``end`` in a full or a
+latent buffer, where no real query sees it; in a linear layer it neither
+decays the state nor writes to it (its decay and write strength are
+masked), and the convolution keeps the last REAL rows.
 
 Batch 1: a prompt is one sequence.
 """
@@ -57,14 +65,25 @@ from stable_diffusion_webui_distributed_tpu.serving.metrics import (
     ATTENTION, EXPANDER,
 )
 
-FULL, SLIDING, LINEAR = "full", "sliding", "linear"
+FULL, SLIDING, LINEAR, LATENT = "full", "sliding", "linear", "latent"
 #: the cache's buffers of one layer, by the layer's kind
 ATTENTION_BUFFERS = ("k", "v")
 LINEAR_BUFFERS = ("state", "conv")
+LATENT_BUFFERS = ("latent",)
+#: ops/attention.py records a latent layer's site under its form
+LATENT_ABSORBED, LATENT_EXPANDED = "latent_absorbed", "latent_expanded"
 
 
-def buffers_of(kind: str) -> Tuple[str, str]:
-    return LINEAR_BUFFERS if kind == LINEAR else ATTENTION_BUFFERS
+def buffers_of(kind: str) -> Tuple[str, ...]:
+    return {LINEAR: LINEAR_BUFFERS, LATENT: LATENT_BUFFERS}.get(
+        kind, ATTENTION_BUFFERS)
+
+
+def latent_form(tokens: int) -> str:
+    """The form a latent layer's chunk of ``tokens`` takes: a decode step
+    attends the cache as it lies, a longer chunk rebuilds keys and values
+    from it (:class:`LatentAttention`)."""
+    return LATENT_ABSORBED if tokens == 1 else LATENT_EXPANDED
 
 
 # -- rotary embeddings -------------------------------------------------------
@@ -203,9 +222,15 @@ class MoE(nn.Module):
         # by rounding the input would send a token to other experts
         logits = jnp.dot(n, router.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
+        bias = None
+        if cfg.router_bias:
+            bias = self.param("e_score_correction_bias",
+                              nn.initializers.zeros,
+                              (cfg.num_experts,)).astype(jnp.float32)
         routing = moe.route(logits, cfg.num_experts_per_tok,
                             renormalise=cfg.norm_topk_prob,
-                            scale=cfg.routed_scaling_factor)
+                            scale=cfg.routed_scaling_factor,
+                            scoring=cfg.router_scoring, bias=bias)
         kernels = Experts(held, cfg.moe_intermediate_size,
                           name="experts")(n.shape[-1])
         compute = [w.astype(self.dtype) for w in kernels]
@@ -292,6 +317,168 @@ class Attention(nn.Module):
                 k_cache, v_cache)
 
 
+class LatentUp(nn.Module):
+    """``kv_b_proj``: the kernel that takes a cached latent to every
+    head's un-rotated key and value, ``(rank, heads, key + value)``. Handed
+    out as :class:`Experts` hands out its kernels, since a decode step
+    never applies it to the cache: it folds the key half into the query
+    and the value half into the output."""
+
+    heads: int
+    width: int
+
+    @nn.compact
+    def __call__(self, rank: int) -> jax.Array:
+        return self.param(
+            "kernel", nn.initializers.lecun_normal(),
+            (rank, self.heads * self.width)).reshape(
+                rank, self.heads, self.width)
+
+
+class LatentAttention(nn.Module):
+    """The token mixer of a ``"latent"`` layer. Queries go through a normed
+    low-rank latent; a position's keys and values all derive from one
+    normed latent ``c`` (``kv_lora_rank``) and one rotated key shared by
+    every head (``qk_rope_head_dim``), and those two are all the cache
+    holds: ``cache`` is ``(capacity, rank + rope)``.
+
+    Two forms over that cache, by the chunk's length
+    (:func:`latent_form`). *Expanded* (a prefill): every cached position's
+    per-head key ``[W_k c | k_rope]`` and value ``W_v c`` are rebuilt
+    through ``kv_b_proj`` and attended as any keys and values. *Absorbed*
+    (one token): ``q W_k^T`` is a query over the latent itself, so the step
+    attends the cache as it lies, one KV head of width ``rank + rope``
+    whose values are its first ``rank`` columns, and ``W_v`` is applied to
+    each head's attended latent instead of to every position. The same
+    scores and the same sum, in another order."""
+
+    config: LMConfig
+    layer: int
+    dtype: jnp.dtype = jnp.float32
+    quant: bool = False
+
+    @nn.compact
+    def __call__(self, n, q_pos, start, end, cache):
+        cfg = self.config
+        heads = cfg.num_heads_per_layer[self.layer]
+        rank, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+        rope, v_dim = cfg.qk_rope_head_dim, cfg.v_head_dim
+        tokens = n.shape[0]
+        f32 = jnp.float32
+
+        def lin(features, name):
+            return Linear(features, self.dtype, self.quant, name=name)
+
+        def norm(name):
+            return RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm,
+                           name=name)
+
+        def dot(spec, a, b):
+            return jnp.einsum(spec, a.astype(self.dtype), b,
+                              preferred_element_type=f32)
+
+        cos, sin = rope_tables(cfg.rope_full, rope, q_pos)
+        q = lin(heads * (nope + rope), "q_b_proj")(
+            norm("q_a_norm")(lin(cfg.q_lora_rank, "q_a_proj")(n))).reshape(
+                tokens, heads, nope + rope)
+        q_nope, q_rope = q[..., :nope], apply_rope(q[..., nope:], cos, sin)
+        c, k_rope = jnp.split(lin(rank + rope, "kv_a_proj_with_mqa")(n),
+                              [rank], axis=-1)
+        row = jnp.concatenate(
+            [norm("kv_a_norm")(c),
+             apply_rope(k_rope[:, None, :], cos, sin)[:, 0]], axis=-1)
+        # written first: a padded row lands beyond ``end``
+        cache = jax.lax.dynamic_update_slice_in_dim(
+            cache, row.astype(cache.dtype), start, 0)
+        slots = jnp.arange(cache.shape[0])
+        k_pos = jnp.where(slots < end, slots, -1)
+        up = LatentUp(heads, nope + v_dim, name="kv_b_proj")(rank).astype(
+            self.dtype)
+        w_k, w_v = up[..., :nope], up[..., nope:]
+        form = latent_form(tokens)
+        if form == LATENT_ABSORBED:
+            q = jnp.concatenate([dot("thd,rhd->thr", q_nope, w_k), q_rope],
+                                axis=-1).astype(self.dtype)
+            keys = cache[:, None, :]
+            values = keys[..., :rank]
+        else:
+            q = jnp.concatenate([q_nope, q_rope], axis=-1).astype(self.dtype)
+            held = dot("sr,rhd->shd", cache[:, :rank], up)
+            keys = jnp.concatenate(
+                [held[..., :nope],
+                 jnp.broadcast_to(cache[:, None, rank:].astype(f32),
+                                  held.shape[:2] + (rope,))],
+                axis=-1).astype(cache.dtype)
+            values = held[..., nope:].astype(cache.dtype)
+        out, _ = attend_positions(q, keys, values, q_pos, k_pos,
+                                  scale=cfg.latent_softmax_scale)
+        ATTENTION.record(form, tokens, cache.shape[0], cache.shape[1])
+        if form == LATENT_ABSORBED:
+            out = dot("thr,rhd->thd", out, w_v)
+        return (lin(n.shape[-1], "o_proj")(
+            out.reshape(tokens, heads * v_dim)), cache)
+
+
+class StreamMixer(nn.Module):
+    """The three maps of one sublayer's hyper-connection, per token, from
+    the token's ``(streams, hidden)`` state ``X``: ``H_pre`` ``(T, n)``
+    reads the sublayer's input ``H_pre X`` out of the streams, ``H_post``
+    ``(T, n)`` writes its output back into each, and ``H_res`` ``(T, n,
+    n)`` mixes the streams among themselves. All three are projections of
+    the RMS-normed flattened state; ``H_res`` is the exponential of its
+    projection made doubly stochastic by Sinkhorn's alternating column and
+    row normalisations, so that mixing neither grows nor shrinks what the
+    streams carry. Float32 throughout, the projection at the highest
+    precision like the router's: ``sinkhorn_dtype`` is the lower-precision
+    control.
+
+    The iterations are a ``fori_loop`` of static trip count, not unrolled.
+    Measured in the decode scan of the 20-layer share on a v5e (PERF.md
+    section 6, PR 35): the loop's mixers take 387 ms a request and the
+    unrolled ones 422 (an iteration is four small fusions either way, 98 a
+    mixer unrolled), and the unrolled graph costs the share's three
+    executables 95 s more to compile, which alone puts a cold run over the
+    benchmark's run limit. The matrix as sixteen arrays of its entries
+    fuses into one launch and is worse on both counts: 2.4x the compile
+    and a decode span of 5.1 s for 1.6."""
+
+    config: LMConfig
+    sinkhorn_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, streams: jax.Array):
+        cfg = self.config
+        n = cfg.residual_streams
+        tokens = streams.shape[0]
+        f32 = jnp.float32
+        # projections of deviation 1/2: twenty iterations then bring every
+        # token's matrix to sums within 1e-3 of one
+        phi = self.param(
+            "phi", nn.initializers.variance_scaling(0.25, "fan_in", "normal"),
+            (n * cfg.hidden_size, n * n + 2 * n)).astype(f32)
+        alpha = self.param("alpha", nn.initializers.ones, (3,)).astype(f32)
+        b_pre = self.param("b_pre", nn.initializers.zeros, (n,)).astype(f32)
+        b_post = self.param("b_post", nn.initializers.zeros, (n,)).astype(f32)
+        b_res = self.param("b_res", nn.initializers.normal(0.5),
+                           (n, n)).astype(f32)
+        flat = RMSNorm(cfg.rms_norm_eps, name="norm")(
+            streams.reshape(tokens, n * cfg.hidden_size))
+        pre, post, res = jnp.split(
+            jnp.dot(flat, phi, precision=jax.lax.Precision.HIGHEST),
+            [n, 2 * n], axis=-1)
+        h_pre = jax.nn.sigmoid(alpha[0] * pre + b_pre)
+        h_post = 2.0 * jax.nn.sigmoid(alpha[1] * post + b_post)
+        m = jnp.exp(jnp.clip(alpha[2] * res.reshape(tokens, n, n) + b_res,
+                             *cfg.hc_res_clamp)).astype(self.sinkhorn_dtype)
+
+        def columns_then_rows(_, m):
+            m = m / (jnp.sum(m, axis=-2, keepdims=True) + cfg.hc_eps)
+            return m / (jnp.sum(m, axis=-1, keepdims=True) + cfg.hc_eps)
+
+        h_res = jax.lax.fori_loop(0, cfg.sinkhorn_iters, columns_then_rows, m)
+        return h_pre, h_post, h_res.astype(f32)
+
+
 def _decay_init(key, shape, dtype=jnp.float32):
     """``A_log``: the log of a decay rate drawn uniformly from (0, 16)."""
     return jnp.log(jax.random.uniform(key, shape, dtype, 1e-3, 16.0))
@@ -368,35 +555,72 @@ class DecoderLayer(nn.Module):
     dtype: jnp.dtype = jnp.float32
     quant: bool = False
     meshed: bool = False
+    #: lower-precision controls of a model with several residual streams
+    stream_dtype: jnp.dtype = jnp.float32
+    sinkhorn_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x, q_pos, start, end, buffers):
-        """``buffers`` are the layer's two of the cache
-        (:func:`buffers_of` its kind), returned as the chunk leaves them."""
+        """``buffers`` are the layer's own of the cache (:func:`buffers_of`
+        its kind), returned as the chunk leaves them. ``x`` is ``(T,
+        hidden)``, or ``(T, streams, hidden)`` with several streams."""
         cfg = self.config
+        kind = cfg.layer_types[self.layer]
 
-        def norm(name):
-            return RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm,
-                           name=name)
+        def token_mixer(n):
+            """(mixed, the layer's buffers as the chunk leaves them)."""
+            if kind == LINEAR:
+                mixed, *after = DeltaMixer(
+                    cfg, self.dtype, self.quant, name="delta")(
+                        n, q_pos < end, end - start, *buffers)
+            elif kind == LATENT:
+                mixed, *after = LatentAttention(
+                    cfg, self.layer, self.dtype, self.quant, name="attn")(
+                        n, q_pos, start, end, *buffers)
+            else:
+                mixed, *after = Attention(
+                    cfg, self.layer, self.dtype, self.quant, name="attn")(
+                        n, q_pos, start, end, *buffers)
+            return mixed, tuple(after)
 
-        n = norm("input_norm")(x)
-        if cfg.layer_types[self.layer] == LINEAR:
-            mixed, *buffers = DeltaMixer(
-                cfg, self.dtype, self.quant, name="delta")(
-                    n, q_pos < end, end - start, *buffers)
-        else:
-            mixed, *buffers = Attention(
-                cfg, self.layer, self.dtype, self.quant, name="attn")(
-                    n, q_pos, start, end, *buffers)
-        h = x + mixed
-        n = norm("post_attention_norm")(h)
-        if self.layer in cfg.dense_layers:
-            out, routed = SwiGLU(cfg.intermediate_size, self.dtype,
-                                 self.quant, name="mlp")(n), None
-        else:
-            out, routed = MoE(cfg, self.dtype, self.quant, self.meshed,
-                              name="mlp")(n, q_pos < end)
-        return h + out, tuple(buffers), routed
+        def mlp(n):
+            """(out, what an expert layer routed; None for a dense one)."""
+            if self.layer in cfg.dense_layers:
+                return SwiGLU(cfg.intermediate_size, self.dtype, self.quant,
+                              name="mlp")(n), None
+            return MoE(cfg, self.dtype, self.quant, self.meshed,
+                       name="mlp")(n, q_pos < end)
+
+        streams = cfg.residual_streams
+        beside = []     # what each sublayer returns beside its output
+        for sublayer, norm_name, hc in (
+                (token_mixer, "input_norm", "attn_hc"),
+                (mlp, "post_attention_norm", "mlp_hc")):
+            norm = RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm,
+                           name=norm_name)
+            if streams == 1:
+                out, more = sublayer(norm(x))
+                x = x + out
+                beside.append(more)
+                continue
+            h_pre, h_post, h_res = StreamMixer(
+                cfg, self.sinkhorn_dtype, name=hc)(x)
+            # element-wise in float32: a product of (n, n) with (n, hidden)
+            # has nothing for the MXU, whose default precision would round
+            # the streams to bfloat16
+            with jax.named_scope(hc):
+                rows = [x[:, j].astype(jnp.float32) for j in range(streams)]
+                read = sum(h_pre[:, j, None] * r for j, r in enumerate(rows))
+            out, more = sublayer(norm(read))
+            beside.append(more)
+            with jax.named_scope(hc):
+                x = jnp.stack(
+                    [sum(h_res[:, i, j, None] * r
+                         for j, r in enumerate(rows))
+                     + h_post[:, i, None] * out for i in range(streams)],
+                    axis=1).astype(self.stream_dtype)
+        buffers, routed = beside
+        return x, buffers, routed
 
 
 class DecoderLM(nn.Module):
@@ -415,6 +639,10 @@ class DecoderLM(nn.Module):
     #: a mesh partitions the program this module is traced into: its expert
     #: layers keep the products ``pjit`` can split (ops/moe.py:choose)
     meshed: bool = False
+    #: what the residual streams are kept in between sublayers, and what
+    #: Sinkhorn's iterations run in: float32; lower is a control
+    stream_dtype: jnp.dtype = jnp.float32
+    sinkhorn_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, tokens, start, length, cache: Dict[str, jax.Array],
@@ -430,6 +658,10 @@ class DecoderLM(nn.Module):
         x = nn.Embed(count, cfg.hidden_size, name="embed_tokens")(
             jnp.clip(local, 0, count - 1)).astype(jnp.float32)
         x = x * here[:, None]
+        if cfg.residual_streams > 1:    # every stream starts as the token
+            x = jnp.broadcast_to(
+                x[:, None, :], (x.shape[0], cfg.residual_streams,
+                                x.shape[1])).astype(self.stream_dtype)
         # a buffer list has one entry for each layer that has the buffer,
         # in layer order
         written = {name: [] for name in cache}
@@ -438,6 +670,7 @@ class DecoderLM(nn.Module):
             names = buffers_of(kind)
             x, buffers, r = DecoderLayer(
                 cfg, layer, self.dtype, self.quant_linears, self.meshed,
+                self.stream_dtype, self.sinkhorn_dtype,
                 name=f"layers_{layer}")(
                     x, q_pos, start, end,
                     tuple(cache[name][len(written[name])] for name in names))
@@ -446,6 +679,8 @@ class DecoderLM(nn.Module):
             if r is not None:
                 routed.append(r)
         cache = written
+        if cfg.residual_streams > 1:    # the streams' sum is the output
+            x = jnp.sum(x.astype(jnp.float32), axis=1)
         if not all_logits:
             x = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, 0)
         n = RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm, name="norm")(x)
@@ -463,12 +698,13 @@ def cache_shapes(cfg: LMConfig, capacity: int) -> Dict[str, list]:
     """By buffer name the shapes of the layers that have it, in layer
     order. An attention layer has ``k`` and ``v``: a full layer holds
     ``capacity`` positions, a sliding layer a ring of its window. A linear
-    layer has ``state`` and ``conv``, whatever the capacity; a model
-    without such layers has neither name."""
+    layer has ``state`` and ``conv``, whatever the capacity. A latent layer
+    has ``latent``: ``capacity`` rows of ``latent_width``. A model without
+    layers of a kind has none of the kind's names."""
     rows = [(capacity if kind == FULL else cfg.sliding_window,
              cfg.num_kv_heads, cfg.head_dim)
-            for kind in cfg.layer_types if kind != LINEAR]
-    shapes = {"k": rows, "v": list(rows)}
+            for kind in cfg.layer_types if kind in (FULL, SLIDING)]
+    shapes = {"k": rows, "v": list(rows)} if rows else {}
     linear = len(cfg.layers_of(LINEAR))
     if linear:
         shapes["state"] = [(cfg.linear_num_value_heads,
@@ -476,14 +712,24 @@ def cache_shapes(cfg: LMConfig, capacity: int) -> Dict[str, list]:
                             cfg.linear_value_head_dim)] * linear
         shapes["conv"] = [(cfg.linear_conv_kernel - 1,
                            cfg.linear_conv_channels)] * linear
+    latent = len(cfg.layers_of(LATENT))
+    if latent:
+        shapes["latent"] = [(capacity, cfg.latent_width)] * latent
     return shapes
 
 
+def buffer_dtype(name: str, dtype):
+    """What a cache buffer holds: keys, values and latents are in the
+    cache's ``dtype``, a linear layer's state and kept convolution inputs
+    in float32."""
+    return jnp.dtype(jnp.float32 if name in LINEAR_BUFFERS else dtype)
+
+
 def empty_cache(cfg: LMConfig, capacity: int, dtype) -> Dict[str, list]:
-    """Keys and values in ``dtype``; a linear layer's state and kept
-    convolution inputs in float32 (zero is the state at position 0)."""
-    return {name: [jnp.zeros(shape, dtype if name in ATTENTION_BUFFERS
-                             else jnp.float32) for shape in rows]
+    """Every buffer zero in its :func:`buffer_dtype` (zero is a linear
+    layer's state at position 0)."""
+    return {name: [jnp.zeros(shape, buffer_dtype(name, dtype))
+                   for shape in rows]
             for name, rows in cache_shapes(cfg, capacity).items()}
 
 

@@ -92,8 +92,10 @@ def attend_positions(q: jax.Array, k: jax.Array, v: jax.Array,
     attention over keys that carry their own positions: a decoder LM's site
     (models/lm.py), where the keys are a cache.
 
-    ``q`` is ``(T, H, D)``, ``k`` and ``v`` ``(S, KV, D)`` with ``H`` a
-    multiple of ``KV``: query head ``j`` attends KV head ``j // (H / KV)``.
+    ``q`` is ``(T, H, D)``, ``k`` ``(S, KV, D)`` and ``v`` ``(S, KV, Dv)``
+    (a value need not be as wide as a key; the output is ``(T, H, Dv)``)
+    with ``H`` a multiple of ``KV``: query head ``j`` attends KV head
+    ``j // (H / KV)``.
     ``q_pos`` ``(T,)`` and ``k_pos`` ``(S,)`` are token positions; a key
     with a negative position is an empty slot. Query ``i`` sees key ``j``
     when ``0 <= i - j`` and, with ``window`` over 0, ``i - j < window``.
@@ -117,4 +119,4 @@ def attend_positions(q: jax.Array, k: jax.Array, v: jax.Array,
     weights = weights / jnp.where(total > 0, total, 1.0)
     out = jnp.einsum("kgts,skd->tkgd", weights.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(t, heads, dim).astype(q.dtype), path
+    return out.reshape(t, heads, v.shape[-1]).astype(q.dtype), path

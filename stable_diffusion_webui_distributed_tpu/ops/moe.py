@@ -50,12 +50,22 @@ class Routing(NamedTuple):
     weights: jax.Array   # (T, k) float32, renormalised and scaled
 
 
-def route(logits: jax.Array, k: int, *, renormalise: bool,
-          scale: float) -> Routing:
-    """Scores are a float32 softmax over every expert; the ``k`` largest are
-    taken, their scores optionally renormalised to sum to one, then scaled."""
-    scores = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    top, experts = jax.lax.top_k(scores, k)
+def route(logits: jax.Array, k: int, *, renormalise: bool, scale: float,
+          scoring: str = "softmax", bias: jax.Array | None = None) -> Routing:
+    """Scores are float32 over every expert: a softmax, or (``scoring``
+    ``"sigmoid"``) each expert's own sigmoid. The ``k`` largest are taken,
+    their scores optionally renormalised to sum to one, then scaled. With a
+    ``bias`` ``(experts,)`` the ``k`` are those with the largest ``score +
+    bias``; the bias chooses and does not weigh: the weights are the
+    chosen experts' scores without it."""
+    logits = logits.astype(jnp.float32)
+    scores = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    if bias is None:
+        top, experts = jax.lax.top_k(scores, k)
+    else:
+        _, experts = jax.lax.top_k(scores + bias, k)
+        top = jnp.take_along_axis(scores, experts, axis=-1)
     if renormalise:
         top = top / jnp.sum(top, axis=-1, keepdims=True)
     return Routing(experts.astype(jnp.int32), top * scale)

@@ -46,8 +46,8 @@ from jax.experimental import pallas as pl
 #: lane width: ``d`` and the ``f`` tile are multiples of it
 LANES = 128
 #: VMEM the three kernels' blocks may take, double-buffered. The ``f`` tile
-#: is the widest that fits: 512 at both published shapes (3072 x 1024 and
-#: 2048 x 512; 18.9 and 12.6 MB).
+#: is the widest that fits: 512 at the three published shapes (3072 x 1024,
+#: 2048 x 512 and 3584 x 1024; 18.9, 12.6 and 22.0 MB).
 _WEIGHT_VMEM = 24 * 2 ** 20
 #: beside the blocks: x, the output, a tile's float32 intermediates (Mosaic
 #: compiles both published shapes with 1 MiB; the decode span does not move

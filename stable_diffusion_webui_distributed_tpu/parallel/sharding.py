@@ -21,7 +21,11 @@ axes leaves the leaves whole. A linear-attention mixer (module ``delta``:
 its fused projections, convolution taps, decay rates, gated norm) and the
 shared expert's gate are whole on every chip, as attention and the router
 are in the deployment the shares stand for: the recurrence runs per head
-over a state that is not split. One chip's share (``LMConfig.experts_held``,
+over a state that is not split. Latent attention's low-rank projections
+(one latent a position has one head: splitting by heads would copy the
+cache to every chip), the residual streams' mixers (``attn_hc``,
+``mlp_hc``) and the router's selection bias are whole on every chip too.
+One chip's share (``LMConfig.experts_held``,
 ``vocab_held``) is what one position of those axes holds; the exchange that
 adds the parts exists only on a mesh that has the axis.
 """
@@ -39,6 +43,9 @@ _ROW_ENDINGS = ("out_proj", "fc2", "ff_out", "time_fc2", "add_fc2",
 
 
 _EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+#: modules of the language model whose leaves every chip holds whole
+_LM_REPLICATED = ("shared_expert_gate", "q_a_proj", "q_b_proj",
+                  "kv_a_proj_with_mqa", "kv_b_proj", "attn_hc", "mlp_hc")
 
 
 def tp_spec_for(path: str, ndim: int):
@@ -51,7 +58,8 @@ def tp_spec_for(path: str, ndim: int):
 
     if leaf in _EXPERT_LEAVES and module == "experts":
         return P("ep", None, None)
-    if "delta" in parts[:-1] or module == "shared_expert_gate":
+    if "delta" in parts[:-1] or module in _LM_REPLICATED \
+            or leaf == "e_score_correction_bias":
         return P()
     if leaf == "embedding" and module == "embed_tokens":
         return P("vp", None)

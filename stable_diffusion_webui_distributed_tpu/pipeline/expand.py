@@ -18,9 +18,9 @@ same expansion on whichever worker its sub-range lands
 (scheduler/world.py), and however decoding was cut into chunks.
 
 What the layers hold after the instruction's last token (keys and values,
-recurrent state, the convolution's inputs) is kept across requests as a
-snapshot (cache/kv.py): from the second request on only the user's own
-tokens are prefilled, against a copy of it. Every kind of state rides in
+latents, recurrent state, the convolution's inputs) is kept across requests
+as a snapshot (cache/kv.py): from the second request on only the user's
+own tokens are prefilled, against a copy of it. Every kind of state rides in
 the one cache tree, so the decode scan carries it and the executables
 donate it whole.
 """
@@ -114,6 +114,7 @@ class PromptExpander:
             if sp is not None:
                 sp.attrs["hit"] = bool(held)
         recurrent = lm.LINEAR in self.config.layer_types
+        latent = lm.LATENT in self.config.layer_types
         routed = []       # per executable call: (load, none held)
         masked = 0        # padded rows kept out of the recurrence
         token = None
@@ -127,6 +128,8 @@ class PromptExpander:
             if recurrent:     # rows masked out of the recurrence, its form
                 attrs.update(padded=len(padded) - len(ids),
                              form=delta_rule.form(len(padded)))
+            if latent:        # the form its attention takes over the cache
+                attrs["latent"] = lm.latent_form(len(padded))
             with obs_spans.span("expand.prefill", **attrs):
                 cache, token, step_load, step_none = self._prefill_fn(
                     len(padded), capacity)(
@@ -176,7 +179,10 @@ class PromptExpander:
             load=np.sum(loads, axis=0), none_held=int(np.sum(none_held)),
             positions=self.cache.positions_in_use(length),
             state_bytes=sizes, prefix_snapshots=self.cache.snapshots,
-            padded_rows_masked=masked)
+            padded_rows_masked=masked,
+            residual_streams=self.config.residual_streams,
+            sinkhorn_iters=(self.config.sinkhorn_iters
+                            if self.config.residual_streams > 1 else 0))
         return made
 
     def _fit(self, text: str, chunks: Optional[int]) -> str:

@@ -401,8 +401,11 @@ class ExpanderStats:
     whose chosen experts is held here, the cache positions the last
     sequence occupied and the bytes its cache took by layer kind (keys and
     values of full and sliding layers, a linear layer's recurrent state and
-    kept convolution inputs), the instruction prefixes held as snapshots,
-    and the padded prefill rows that were masked out of a recurrence.
+    kept convolution inputs, a latent layer's latents), the instruction
+    prefixes held as snapshots, the padded prefill rows that were masked
+    out of a recurrence, and the residual streams a token has between
+    sublayers with the Sinkhorn iterations each of their mixers runs (1
+    and 0: a plain residual).
     ``expert_products`` counts expert layers by the product they took
     (ops/moe.py:choose) when the model was TRACED, as :class:`AttentionSites`
     counts its sites: nothing is counted when an executable runs."""
@@ -425,6 +428,8 @@ class ExpanderStats:
             self.state_bytes: Dict[str, int] = {}  # guarded-by: _lock
             self.prefix_snapshots = 0  # guarded-by: _lock
             self.padded_rows_masked = 0  # guarded-by: _lock
+            self.residual_streams = 1  # guarded-by: _lock
+            self.sinkhorn_iters = 0    # guarded-by: _lock
             self.products = {"kernel": 0, "loop": 0,
                              "grouped": 0}  # guarded-by: _lock
 
@@ -436,7 +441,8 @@ class ExpanderStats:
     def record(self, *, prefilled: int, from_prefix: int, decoded: int,
                decode_steps: int, load, none_held: int,
                positions: Dict[str, int], state_bytes: Dict[str, int],
-               prefix_snapshots: int, padded_rows_masked: int) -> None:
+               prefix_snapshots: int, padded_rows_masked: int,
+               residual_streams: int, sinkhorn_iters: int) -> None:
         """``load`` is (expert layers, held experts) counts of one
         request; ``decode_steps`` the steps its decode executables ran
         (whole chunks, so at least ``decoded - 1``)."""
@@ -457,6 +463,8 @@ class ExpanderStats:
             self.state_bytes = dict(state_bytes)
             self.prefix_snapshots = int(prefix_snapshots)
             self.padded_rows_masked += int(padded_rows_masked)
+            self.residual_streams = int(residual_streams)
+            self.sinkhorn_iters = int(sinkhorn_iters)
 
     def summary(self) -> Dict[str, Any]:
         with self._lock:
@@ -476,6 +484,8 @@ class ExpanderStats:
                 "state_bytes": dict(self.state_bytes),
                 "prefix_snapshots": self.prefix_snapshots,
                 "padded_rows_masked": self.padded_rows_masked,
+                "residual_streams": self.residual_streams,
+                "sinkhorn_iters": self.sinkhorn_iters,
                 "expert_products": dict(self.products),
             }
 

@@ -120,9 +120,10 @@ def test_flash_kernel_compiles_under_highest_matmul_precision(one_chip):
 
 
 @pytest.mark.parametrize("precision", ["default", "highest"])
-@pytest.mark.parametrize("d,f", [(3072, 1024), (2048, 512)])
+@pytest.mark.parametrize("d,f", [(3072, 1024), (2048, 512), (3584, 1024)])
 def test_chosen_experts_kernel_compiles_for_v5e(one_chip, d, f, precision):
-    """Both published expert shapes (Laguna-S-2.1's and Qwen3-Next's), 128
+    """The three published expert shapes (Laguna-S-2.1's, Qwen3-Next's and
+    Xing4.0's, whose blocks are the largest: 22.0 MB double-buffered), 128
     held, 10 chosen, bf16; also under benchmarks/verify_reference.py's
     ``default_matmul_precision("highest")``, which must not reach the
     kernel's dots."""
@@ -139,18 +140,25 @@ def test_chosen_experts_kernel_compiles_for_v5e(one_chip, d, f, precision):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("which,expander,argument_gb,kernels", [
-    ("decode", "sd15_laguna_expander", 11.1, 4),
-    ("prefill", "sd15_laguna_expander", 11.1, 0),
-    ("decode", "sd15_qwen3next_expander", 10.8, 12),
+@pytest.mark.parametrize("which,expander,argument_gb,kernels,temp_mb", [
+    ("decode", "sd15_laguna_expander", 11.1, 4, 64),
+    ("prefill", "sd15_laguna_expander", 11.1, 0, 64),
+    ("decode", "sd15_qwen3next_expander", 10.8, 12, 64),
+    # float32 copies of forty mixers' phi (1.4 MB each) and eighteen
+    # routers, hoisted out of the scan: 110 MB
+    ("decode", "sd15_xing4_expander", 8.75, 18, 128),
+    ("prefill", "sd15_xing4_expander", 8.75, 0, 128),
 ])
 def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
-        one_chip, monkeypatch, which, expander, argument_gb, kernels):
-    """A share's decode chunk (and the Laguna share's 64-token prefill) at
-    the published widths (5.57 B and 5.42 B parameters as bfloat16 shapes,
-    a 1 024-slot cache): the chip's compiler accepts them, every expert
-    layer of a decode step is the pipelined kernel (ops/moe.py:choose is
-    told the platform it is compiled for) and a prefill has none, the
+        one_chip, monkeypatch, which, expander, argument_gb, kernels,
+        temp_mb):
+    """A share's decode chunk (and two shares' 64-token prefill) at the
+    published widths (5.57 B, 5.42 B and 4.39 B parameters as bfloat16
+    shapes, a 1 024-slot cache; the last with twenty layers of latent
+    attention and forty mixers in the scan's body): the chip's
+    compiler accepts them, every expert layer of a decode step is the
+    pipelined kernel (ops/moe.py:choose is told the platform it is
+    compiled for) and a prefill has none, the
     weights are arguments and not copies (an expert's kernels are read
     block by block, never gathered whole), and everything fits beside
     SD1.5."""
@@ -163,8 +171,7 @@ def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
     def on_chip(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    cache = {name: [on_chip(shape, jnp.bfloat16
-                            if name in lm.ATTENTION_BUFFERS else jnp.float32)
+    cache = {name: [on_chip(shape, lm.buffer_dtype(name, jnp.bfloat16))
                     for shape in rows]
              for name, rows in lm.cache_shapes(cfg, 1024).items()}
     small = {name: [jax.ShapeDtypeStruct(shape, jnp.float32)
@@ -193,5 +200,5 @@ def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
     memory = compiled.memory_analysis()
     assert argument_gb * 1e9 < memory.argument_size_in_bytes \
         < (argument_gb + 0.1) * 1e9
-    assert memory.temp_size_in_bytes < 64e6
+    assert memory.temp_size_in_bytes < temp_mb * 1e6
     assert memory.alias_size_in_bytes > 14e6      # the cache is donated

@@ -653,7 +653,8 @@ class TestEnginePath:
             "tokens_decoded", "decode_steps", "tokens_no_held_expert",
             "expert_tokens",
             "expert_load_max_over_mean", "cache_positions", "state_bytes",
-            "prefix_snapshots", "padded_rows_masked", "expert_products"}
+            "prefix_snapshots", "padded_rows_masked", "expert_products",
+            "residual_streams", "sinkhorn_iters"}
         assert set(block["expert_products"]) == {"kernel", "loop", "grouped"}
         json.dumps(block)
 

@@ -4,10 +4,11 @@ pipelined kernel (PERF.md section 6, PR 34).
     chiprun -- python3 tools/expert_trips.py [--budgets 3 6 12 24 40]
 
 For each published expert shape (Laguna-S-2.1's 3072 x 1024 and
-Qwen3-Next's 2048 x 512, 128 of ``num_experts`` held, 10 a token, bf16) one
-expert layer's routed sum runs ``--steps`` times in a device-side scan, each
-step on its own seeded draw of 10 of the layer's experts and fed the step
-before's result, once through ``ops/moe.py``'s loop and once through
+Qwen3-Next's 2048 x 512, 128 of ``num_experts`` held, 10 a token; Xing4.0's
+3584 x 1024, 16 of 64 held, 4 a token; bf16) one expert layer's routed sum
+runs ``--steps`` times in a device-side scan, each step on its own seeded
+draw of the token's experts among the layer's and fed the step before's
+result, once through ``ops/moe.py``'s loop and once through
 ``ops/moe_kernel.py`` at each ``--budgets`` MiB of VMEM for the kernels'
 blocks (the ``f`` tile follows from the budget). A row says microseconds a
 step and a chosen-and-held expert, GB/s over the bytes those experts'
@@ -29,7 +30,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 OUT = os.path.join(REPO, "chiprun_out", "expert_trips.json")
 #: (name, d, f, experts of the layer, held here, chosen a token)
 SHAPES = (("laguna", 3072, 1024, 256, 128, 10),
-          ("qwen3next", 2048, 512, 512, 128, 10))
+          ("qwen3next", 2048, 512, 512, 128, 10),
+          ("xing4", 3584, 1024, 64, 16, 4))
 REPEATS = 5
 
 
