@@ -46,7 +46,7 @@ Batch 1: a prompt is one sequence.
 from __future__ import annotations
 
 import math
-from typing import Dict, Tuple
+from typing import Dict, Mapping, NamedTuple, Tuple
 
 import flax.linen as nn
 import jax
@@ -56,7 +56,9 @@ import numpy as np
 from stable_diffusion_webui_distributed_tpu.models.configs import (
     LMConfig, RopeConfig,
 )
-from stable_diffusion_webui_distributed_tpu.ops import delta_rule, moe
+from stable_diffusion_webui_distributed_tpu.ops import (
+    delta_rule, moe, stream_mixer,
+)
 from stable_diffusion_webui_distributed_tpu.ops.attention import (
     attend_positions,
 )
@@ -419,31 +421,48 @@ class LatentAttention(nn.Module):
             out.reshape(tokens, heads * v_dim)), cache)
 
 
-class StreamMixer(nn.Module):
-    """The three maps of one sublayer's hyper-connection, per token, from
-    the token's ``(streams, hidden)`` state ``X``: ``H_pre`` ``(T, n)``
-    reads the sublayer's input ``H_pre X`` out of the streams, ``H_post``
-    ``(T, n)`` writes its output back into each, and ``H_res`` ``(T, n,
-    n)`` mixes the streams among themselves. All three are projections of
-    the RMS-normed flattened state; ``H_res`` is the exponential of its
-    projection made doubly stochastic by Sinkhorn's alternating column and
-    row normalisations, so that mixing neither grows nor shrinks what the
-    streams carry. Float32 throughout, the projection at the highest
-    precision like the router's: ``sinkhorn_dtype`` is the lower-precision
-    control.
+class Mixed(NamedTuple):
+    """What a :class:`StreamMixer` gives before its sublayer. ``tile`` is
+    the kernel's form of the three maps (ops/stream_mixer.py:mixer), which
+    its write-back takes; None in the XLA form."""
+    read: jax.Array
+    h_pre: jax.Array
+    h_post: jax.Array
+    h_res: jax.Array
+    tile: jax.Array | None = None
 
-    The iterations are a ``fori_loop`` of static trip count, not unrolled.
-    Measured in the decode scan of the 20-layer share on a v5e (PERF.md
-    section 6, PR 35): the loop's mixers take 387 ms a request and the
-    unrolled ones 422 (an iteration is four small fusions either way, 98 a
-    mixer unrolled), and the unrolled graph costs the share's three
-    executables 95 s more to compile, which alone puts a cold run over the
-    benchmark's run limit. The matrix as sixteen arrays of its entries
-    fuses into one launch and is worse on both counts: 2.4x the compile
-    and a decode span of 5.1 s for 1.6."""
+
+class StreamMixer(nn.Module):
+    """One sublayer's hyper-connection, per token, from the token's
+    ``(streams, hidden)`` state ``X``: a :class:`Mixed` of the sublayer's
+    input read out of the streams and the three maps. ``H_pre`` ``(T, n)``
+    reads ``read = H_pre X`` ``(T, hidden)``, ``H_post`` ``(T, n)`` writes
+    the sublayer's output back into each stream (:func:`written`), and
+    ``H_res`` ``(T, n, n)`` mixes the streams among themselves. All three
+    are projections of the RMS-normed flattened state; ``H_res`` is the
+    exponential of its projection made doubly stochastic by Sinkhorn's
+    alternating column and row normalisations, so that mixing neither
+    grows nor shrinks what the streams carry. Float32 throughout, the
+    projection at the highest precision like the router's:
+    ``sinkhorn_dtype`` is the lower-precision control.
+
+    Two forms of the one computation, chosen by what the call shows
+    (ops/stream_mixer.py:choose), as an expert layer's product is. A decode
+    step on a TPU takes two Pallas kernels a mixer, one here and one in
+    :func:`written`: in XLA a mixer at one token is about a hundred
+    launches with nothing streaming, 25.3 us, forty times a token (PERF.md
+    section 6, PR 35 and PR 36), and none of XLA's own forms is both few
+    launches and cheap. Everything else (a prefill's rows, a CPU, a
+    program a mesh partitions, the lower-precision control) keeps the XLA
+    form, whose iterations are a ``fori_loop`` of static trip count:
+    unrolled they tie on the chip (an iteration is four small fusions
+    either way) and cost the share's executables 95 s more to compile, and
+    the matrix as sixteen arrays of its entries fuses into one launch and
+    a decode span of 5.1 s for 1.6 (PR 35)."""
 
     config: LMConfig
     sinkhorn_dtype: jnp.dtype = jnp.float32
+    meshed: bool = False
 
     @nn.compact
     def __call__(self, streams: jax.Array):
@@ -455,16 +474,34 @@ class StreamMixer(nn.Module):
         # token's matrix to sums within 1e-3 of one
         phi = self.param(
             "phi", nn.initializers.variance_scaling(0.25, "fan_in", "normal"),
-            (n * cfg.hidden_size, n * n + 2 * n)).astype(f32)
-        alpha = self.param("alpha", nn.initializers.ones, (3,)).astype(f32)
-        b_pre = self.param("b_pre", nn.initializers.zeros, (n,)).astype(f32)
-        b_post = self.param("b_post", nn.initializers.zeros, (n,)).astype(f32)
-        b_res = self.param("b_res", nn.initializers.normal(0.5),
-                           (n, n)).astype(f32)
-        flat = RMSNorm(cfg.rms_norm_eps, name="norm")(
-            streams.reshape(tokens, n * cfg.hidden_size))
+            (n * cfg.hidden_size, n * n + 2 * n))
+        alpha = self.param("alpha", nn.initializers.ones, (3,))
+        b_pre = self.param("b_pre", nn.initializers.zeros, (n,))
+        b_post = self.param("b_post", nn.initializers.zeros, (n,))
+        b_res = self.param("b_res", nn.initializers.normal(0.5), (n, n))
+        norm = RMSNorm(cfg.rms_norm_eps, name="norm")
+        # an init runs the XLA form: the norm makes its weight when called
+        path = stream_mixer.LOOP if self.is_initializing() else \
+            stream_mixer.choose(
+                jax.default_backend(), tokens, n, cfg.hidden_size,
+                meshed=self.meshed, sinkhorn_dtype=self.sinkhorn_dtype)
+        EXPANDER.record_mixer(path)
+        if path == stream_mixer.KERNEL:
+            # a decode scan hands in what mixer_operands made outside it
+            packed = self.get_variable("mixers", "packed") \
+                if self.has_variable("mixers", "packed") \
+                else stream_mixer.pack(phi, alpha, b_pre, b_post, b_res)
+            read, tile = stream_mixer.mixer(
+                streams, norm.get_variable("params", "scale"), packed,
+                eps=cfg.rms_norm_eps, hc_eps=cfg.hc_eps,
+                clamp=cfg.hc_res_clamp, iters=cfg.sinkhorn_iters)
+            return Mixed(read, *stream_mixer.maps_of(tile, n), tile)
+        alpha, b_pre, b_post, b_res = (
+            p.astype(f32) for p in (alpha, b_pre, b_post, b_res))
+        flat = norm(streams.reshape(tokens, n * cfg.hidden_size))
         pre, post, res = jnp.split(
-            jnp.dot(flat, phi, precision=jax.lax.Precision.HIGHEST),
+            jnp.dot(flat, phi.astype(f32),
+                    precision=jax.lax.Precision.HIGHEST),
             [n, 2 * n], axis=-1)
         h_pre = jax.nn.sigmoid(alpha[0] * pre + b_pre)
         h_post = 2.0 * jax.nn.sigmoid(alpha[1] * post + b_post)
@@ -476,7 +513,42 @@ class StreamMixer(nn.Module):
             return m / (jnp.sum(m, axis=-1, keepdims=True) + cfg.hc_eps)
 
         h_res = jax.lax.fori_loop(0, cfg.sinkhorn_iters, columns_then_rows, m)
-        return h_pre, h_post, h_res.astype(f32)
+        # element-wise in float32: a product of (n,) with (n, hidden) has
+        # nothing for the MXU, whose default precision would round the
+        # streams to bfloat16
+        read = sum(h_pre[:, j, None] * streams[:, j].astype(f32)
+                   for j in range(n))
+        return Mixed(read, h_pre, h_post, h_res.astype(f32))
+
+
+def written(streams: jax.Array, mixed: Mixed, out: jax.Array) -> jax.Array:
+    """``H_res X + H_post (outer) out``: the streams after a sublayer whose
+    output is ``out``, in the dtype they came in; in the form ``mixed``
+    was made in."""
+    if mixed.tile is not None:
+        return stream_mixer.write_back(streams, mixed.tile, out)
+    # element-wise in float32, as the mixer's read is
+    rows = [streams[:, j].astype(jnp.float32)
+            for j in range(streams.shape[1])]
+    return jnp.stack(
+        [sum(mixed.h_res[:, i, j, None] * r for j, r in enumerate(rows))
+         + mixed.h_post[:, i, None] * out for i in range(len(rows))],
+        axis=1).astype(streams.dtype)
+
+
+def mixer_operands(params) -> dict:
+    """The ``mixers`` collection of a model's ``params``: by each
+    :class:`StreamMixer`'s path, its parameters as the kernel reads them
+    (ops/stream_mixer.py:pack). Made outside a decode scan they are made
+    once a chunk: inside its body XLA hoists only the part that does not
+    grow (``phi``'s transpose, not the gates' tiles). Empty for a model
+    with one stream."""
+    if "phi" in params:
+        return {"packed": stream_mixer.pack(*(params[name] for name in (
+            "phi", "alpha", "b_pre", "b_post", "b_res")))}
+    found = {name: mixer_operands(sub) for name, sub in params.items()
+             if isinstance(sub, Mapping)}
+    return {name: sub for name, sub in found.items() if sub}
 
 
 def _decay_init(key, shape, dtype=jnp.float32):
@@ -603,22 +675,12 @@ class DecoderLayer(nn.Module):
                 x = x + out
                 beside.append(more)
                 continue
-            h_pre, h_post, h_res = StreamMixer(
-                cfg, self.sinkhorn_dtype, name=hc)(x)
-            # element-wise in float32: a product of (n, n) with (n, hidden)
-            # has nothing for the MXU, whose default precision would round
-            # the streams to bfloat16
-            with jax.named_scope(hc):
-                rows = [x[:, j].astype(jnp.float32) for j in range(streams)]
-                read = sum(h_pre[:, j, None] * r for j, r in enumerate(rows))
-            out, more = sublayer(norm(read))
+            mixed = StreamMixer(
+                cfg, self.sinkhorn_dtype, self.meshed, name=hc)(x)
+            out, more = sublayer(norm(mixed.read))
             beside.append(more)
             with jax.named_scope(hc):
-                x = jnp.stack(
-                    [sum(h_res[:, i, j, None] * r
-                         for j, r in enumerate(rows))
-                     + h_post[:, i, None] * out for i in range(streams)],
-                    axis=1).astype(self.stream_dtype)
+                x = written(x, mixed, out)
         buffers, routed = beside
         return x, buffers, routed
 
@@ -771,10 +833,12 @@ def decode_chunk_fn(module: DecoderLM, steps: int):
 
     def expand_decode_chunk(params, cache, token, position, key,
                             temperature):
+        variables = {"params": params, "mixers": mixer_operands(params)}
+
         def step(carry, _):
             cache, token, position, load, none_held = carry
             logits, cache, routed = module.apply(
-                {"params": params}, token[None], position, 1, cache,
+                variables, token[None], position, 1, cache,
                 all_logits=False)
             token = sample(logits[0], key, position + 1, temperature,
                            module.config.vocab[0])

@@ -408,7 +408,9 @@ class ExpanderStats:
     and 0: a plain residual).
     ``expert_products`` counts expert layers by the product they took
     (ops/moe.py:choose) when the model was TRACED, as :class:`AttentionSites`
-    counts its sites: nothing is counted when an executable runs."""
+    counts its sites: nothing is counted when an executable runs.
+    ``mixer_products`` counts the residual streams' mixers the same way, by
+    the form ops/stream_mixer.py:choose gave them."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -432,11 +434,17 @@ class ExpanderStats:
             self.sinkhorn_iters = 0    # guarded-by: _lock
             self.products = {"kernel": 0, "loop": 0,
                              "grouped": 0}  # guarded-by: _lock
+            self.mixers = {"kernel": 0, "loop": 0}  # guarded-by: _lock
 
     def record_product(self, path: str) -> None:
         """One expert layer in one trace took product ``path``."""
         with self._lock:
             self.products[path] += 1
+
+    def record_mixer(self, path: str) -> None:
+        """One stream mixer in one trace took form ``path``."""
+        with self._lock:
+            self.mixers[path] += 1
 
     def record(self, *, prefilled: int, from_prefix: int, decoded: int,
                decode_steps: int, load, none_held: int,
@@ -487,6 +495,7 @@ class ExpanderStats:
                 "residual_streams": self.residual_streams,
                 "sinkhorn_iters": self.sinkhorn_iters,
                 "expert_products": dict(self.products),
+                "mixer_products": dict(self.mixers),
             }
 
 

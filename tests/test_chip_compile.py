@@ -1,5 +1,6 @@
 """The Pallas kernels (both attention kernels, the chosen experts' sum of a
-decode step), compiled by the TPU's own compiler.
+decode step, a residual-stream mixer's two), compiled by the TPU's own
+compiler.
 
 Interpret mode (tests/test_ops.py, tests/test_ragged.py) checks the math;
 it cannot see what Mosaic refuses: a slice off the tiling, too much VMEM.
@@ -23,7 +24,7 @@ from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
-from stable_diffusion_webui_distributed_tpu.ops import moe_kernel
+from stable_diffusion_webui_distributed_tpu.ops import moe_kernel, stream_mixer
 from stable_diffusion_webui_distributed_tpu.ops.flash_attention import (
     flash_attention,
 )
@@ -140,13 +141,43 @@ def test_chosen_experts_kernel_compiles_for_v5e(one_chip, d, f, precision):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("stored", [jnp.bfloat16, jnp.float32])
+def test_stream_mixer_kernels_compile_for_v5e(one_chip, stored):
+    """The published shape (four streams of 3 584, ``phi`` 14 336 x 24,
+    twenty iterations): the kernel before the sublayer, with ``phi`` as the
+    policy stores it on the chip and in float32, and the write-back after
+    it."""
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n, hidden = 4, 3584
+
+    def both(streams, scale, phi, alpha, b_pre, b_post, b_res, out):
+        read, tile = stream_mixer.mixer(
+            streams, scale, stream_mixer.pack(phi, alpha, b_pre, b_post,
+                                              b_res),
+            eps=1e-6, hc_eps=1e-6, clamp=(-30.0, 30.0), iters=20,
+            interpret=False)
+        return read, stream_mixer.write_back(streams, tile, out,
+                                             interpret=False)
+
+    text = _compiled_text(
+        both, on_chip((1, n, hidden), jnp.float32),
+        on_chip((n * hidden,), stored),
+        on_chip((n * hidden, n * n + 2 * n), stored), on_chip((3,), stored),
+        on_chip((n,), stored), on_chip((n,), stored), on_chip((n, n), stored),
+        on_chip((1, hidden), jnp.float32))
+    assert text.count("tpu_custom_call") == 2
+
+
 @pytest.mark.parametrize("which,expander,argument_gb,kernels,temp_mb", [
     ("decode", "sd15_laguna_expander", 11.1, 4, 64),
     ("prefill", "sd15_laguna_expander", 11.1, 0, 64),
     ("decode", "sd15_qwen3next_expander", 10.8, 12, 64),
-    # float32 copies of forty mixers' phi (1.4 MB each) and eighteen
-    # routers, hoisted out of the scan: 110 MB
-    ("decode", "sd15_xing4_expander", 8.75, 18, 128),
+    # eighteen expert kernels and forty mixers of two; forty transposed
+    # copies of phi (0.9 MB each in bf16 tiles) made before the scan and
+    # eighteen float32 routers hoisted out of it: 124 MB
+    ("decode", "sd15_xing4_expander", 8.75, 98, 128),
     ("prefill", "sd15_xing4_expander", 8.75, 0, 128),
 ])
 def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
@@ -157,8 +188,9 @@ def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
     shapes, a 1 024-slot cache; the last with twenty layers of latent
     attention and forty mixers in the scan's body): the chip's
     compiler accepts them, every expert layer of a decode step is the
-    pipelined kernel (ops/moe.py:choose is told the platform it is
-    compiled for) and a prefill has none, the
+    pipelined kernel and every mixer its two (ops/moe.py:choose and
+    ops/stream_mixer.py:choose are told the platform it is compiled for)
+    and a prefill has none, the
     weights are arguments and not copies (an expert's kernels are read
     block by block, never gathered whole), and everything fits beside
     SD1.5."""

@@ -25,7 +25,9 @@ import pytest
 
 from stable_diffusion_webui_distributed_tpu.cache import kv
 from stable_diffusion_webui_distributed_tpu.models import configs, lm
-from stable_diffusion_webui_distributed_tpu.ops import moe, moe_kernel
+from stable_diffusion_webui_distributed_tpu.ops import (
+    moe, moe_kernel, stream_mixer,
+)
 from stable_diffusion_webui_distributed_tpu.ops.attention import (
     attend_positions,
 )
@@ -102,8 +104,17 @@ def empty(capacity=64, cfg=CFG):
     return lm.empty_cache(cfg, capacity, jnp.float32)
 
 
+def lm_params_of_a_mixer(mixer, streams, stored, at_the_clamp=False):
+    """One mixer's parameters in the dtype a policy stores them in."""
+    p = mixer.init(jax.random.key(1), streams)["params"]
+    if at_the_clamp:
+        p["alpha"] = jnp.asarray([1.0, 1.0, 500.0])
+    return jax.tree_util.tree_map(lambda leaf: leaf.astype(stored), p)
+
+
 def stream_maps(cfg, p, streams):
-    return lm.StreamMixer(cfg).apply({"params": p}, streams)
+    """(h_pre, h_post, h_res): the mixer's maps without its read."""
+    return lm.StreamMixer(cfg).apply({"params": p}, streams)[1:4]
 
 
 # -- program against reference ------------------------------------------------
@@ -451,6 +462,154 @@ class TestTheStreams:
         assert "f32[4,4,32]" in latent
 
 
+# -- a decode step's mixer as two kernels --------------------------------------
+
+def only_at_one_token(platform, tokens, *args, **kw):
+    """ops/stream_mixer.py:choose for a test that forces the kernels where
+    a decode step would take them on the chip."""
+    return stream_mixer.KERNEL if tokens == 1 else stream_mixer.LOOP
+
+
+class TestTheMixerKernels:
+    """ops/stream_mixer.py in interpret mode against the XLA form of
+    ``models/lm.py:StreamMixer`` and ``written``."""
+
+    @staticmethod
+    def close(got, want, what):
+        """1e-5 of the largest entry: the two forms sum in another order."""
+        want = np.asarray(want)
+        np.testing.assert_allclose(
+            got, want, rtol=1e-5, atol=1e-5 * np.abs(want).max(),
+            err_msg=what)
+
+    @pytest.mark.parametrize("at_the_clamp", [False, True])
+    @pytest.mark.parametrize("stored", [jnp.bfloat16, jnp.float32])
+    @pytest.mark.parametrize("hidden", [256, 3584])
+    @pytest.mark.parametrize("streams", [2, 4])
+    def test_the_kernels_equal_the_xla_form(self, monkeypatch, streams,
+                                            hidden, stored, at_the_clamp):
+        """Both forms on the same stored parameters (bf16 is widened, never
+        narrowed): the read, the three maps and the streams written back.
+        A projection driven to the clamp takes the ``exp`` to its ends."""
+        cfg = dataclasses.replace(CFG, residual_streams=streams,
+                                  hidden_size=hidden,
+                                  hc_res_clamp=(-3.0, 3.0))
+        x = 1.3 * jax.random.normal(jax.random.key(0), (1, streams, hidden))
+        mixer = lm.StreamMixer(cfg)
+        p = lm_params_of_a_mixer(mixer, x, stored, at_the_clamp)
+        want = mixer.apply({"params": p}, x)
+        assert want.tile is None
+        monkeypatch.setattr(stream_mixer, "choose", only_at_one_token)
+        got = mixer.apply({"params": p}, x)
+        assert got.tile.shape == (8, 128) and got.tile.dtype == jnp.float32
+        for name in ("read", "h_pre", "h_post", "h_res"):
+            g, w = getattr(got, name), getattr(want, name)
+            assert g.shape == w.shape and g.dtype == jnp.float32, name
+            self.close(g, w, name)
+        np.testing.assert_allclose(got.h_res.sum(-1), 1.0, atol=1e-3)
+        if not at_the_clamp:
+            np.testing.assert_allclose(got.h_res.sum(-2), 1.0, atol=1e-3)
+        # nothing of the tile but the maps
+        rest = np.asarray(got.tile).copy()
+        rest[:streams, :streams + 2] = 0.0
+        assert not rest.any()
+        out = jax.random.normal(jax.random.key(5), (1, hidden))
+        after = lm.written(x, got, out)
+        assert after.shape == x.shape and after.dtype == x.dtype
+        self.close(after, lm.written(x, want, out), "written")
+
+    @pytest.mark.parametrize(
+        "platform,tokens,streams,hidden,meshed,sinkhorn_dtype,path", [
+            ("tpu", 1, 4, 3584, False, jnp.float32, "kernel"),
+            ("tpu", 1, 2, 256, False, jnp.float32, "kernel"),
+            ("tpu", 1, 8, 128, False, jnp.float32, "kernel"),
+            ("cpu", 1, 4, 3584, False, jnp.float32, "loop"),
+            ("gpu", 1, 4, 3584, False, jnp.float32, "loop"),
+            ("tpu", 64, 4, 3584, False, jnp.float32, "loop"),
+            ("tpu", 2, 4, 3584, False, jnp.float32, "loop"),
+            ("tpu", 1, 1, 3584, False, jnp.float32, "loop"),
+            ("tpu", 1, 9, 3584, False, jnp.float32, "loop"),
+            ("tpu", 1, 4, 32, False, jnp.float32, "loop"),
+            ("tpu", 1, 4, 3500, False, jnp.float32, "loop"),
+            ("tpu", 1, 4, 3584, True, jnp.float32, "loop"),
+            ("tpu", 1, 4, 3584, False, jnp.bfloat16, "loop"),
+        ])
+    def test_the_choice_is_made_from_what_the_call_shows(
+            self, platform, tokens, streams, hidden, meshed, sinkhorn_dtype,
+            path):
+        assert stream_mixer.choose(
+            platform, tokens, streams, hidden, meshed=meshed,
+            sinkhorn_dtype=sinkhorn_dtype) == path
+
+    def test_a_decode_chunk_on_the_kernels_equals_the_xla_form(
+            self, monkeypatch):
+        """The tiny preset with its hidden size on the lanes: one token's
+        logits and cache on both forms, then a chunk of the decode scan
+        drawing at temperature 1.0, whose mixers read what
+        ``mixer_operands`` made outside the scan."""
+        cfg = dataclasses.replace(CFG, hidden_size=128)
+        p = lm_params(cfg)
+        module = lm.DecoderLM(cfg)
+        ids = jax.random.randint(jax.random.key(1), (12,), *cfg.vocab)
+        _, cache, _ = run(p, ids, 0, 12, empty(32, cfg), cfg=cfg)
+        chunk = lm.decode_chunk_fn(module, 6)
+        args = (p, cache, ids[3], jnp.int32(12), jax.random.key(7),
+                jnp.float32(1.0))
+
+        def both():
+            logits, after, _ = run(p, ids[3:4], 12, 1, cache, cfg=cfg)
+            EXPANDER.clear()
+            made = chunk(*args)
+            return logits, after, made, EXPANDER.summary()["mixer_products"]
+
+        want = both()
+        monkeypatch.setattr(stream_mixer, "choose", only_at_one_token)
+        got = both()
+        assert want[3] == {"kernel": 0, "loop": 8}
+        assert got[3] == {"kernel": 8, "loop": 0}
+        np.testing.assert_allclose(got[0], want[0], rtol=1e-4, atol=1e-4)
+        for g, w in zip(jax.tree_util.tree_leaves(got[1]),
+                        jax.tree_util.tree_leaves(want[1])):
+            np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
+        assert np.array_equal(got[2][3], want[2][3])    # the steps' tokens
+        assert len(set(np.asarray(want[2][3]).tolist())) > 2
+        packed = lm.mixer_operands(p)
+        assert set(packed) == {f"layers_{i}" for i in range(4)}
+        phi_t, gates = packed["layers_2"]["mlp_hc"]["packed"]
+        assert phi_t.shape == (24, 4 * 128) and gates.shape == (2, 8, 128)
+        assert phi_t.dtype == p["layers_2"]["mlp_hc"]["phi"].dtype
+        # a model with one stream has nothing to pack
+        assert lm.mixer_operands(
+            {"layers_0": {"attn": {"q_proj": {"kernel": ids}}}}) == {}
+
+    def test_the_layer_metric_reads_the_status_block(self):
+        """benchmarks/layer_metrics/x4_mixer_kernel_sites.json through the
+        harness's own loader and reader, and its entry in BENCHMARK.json."""
+        from benchmarks.harness import files
+
+        bench = files.Bench(ROOT)
+        spec = bench.layer_metric("x4_mixer_kernel_sites")
+        reader = bench.load("readers", spec["reader"])
+        EXPANDER.clear()
+        EXPANDER.record_mixer("loop")
+        status = {"status_before": {"serving": METRICS.summary()}}
+        assert reader.read(status, **spec["args"]) == 0.0
+        for _ in range(40):
+            EXPANDER.record_mixer("kernel")
+        status = {"status_before": {"serving": METRICS.summary()}}
+        assert reader.read(status, **spec["args"]) == 40.0
+        # the parent's block has no such counter: nothing to read
+        del status["status_before"]["serving"]["expander"]["mixer_products"]
+        assert reader.read(status, **spec["args"]) is None
+        EXPANDER.clear()
+        entry = bench.manifest["per_layer"][-1]
+        assert entry == {key: spec[key] for key in (
+            "name", "unit", "better", "source", "layer", "moves")} | {
+                "workloads": ["sd15_xing4_expand_solo"]}
+        assert (entry["layer"], entry["moves"]) == ("kernels",
+                                                    "request_p50_s")
+
+
 # -- the router ---------------------------------------------------------------
 
 class TestTheRouter:
@@ -748,8 +907,13 @@ class TestEnginePath:
         params["expander"] = lm_params(family.expander, seed=1)
         fresh = Engine(family, params, chunk_size=4, state=GenerationState())
         ATTENTION.clear()
+        EXPANDER.clear()
         fresh.txt2img(payload())
         fresh.txt2img(payload())
+        # /internal/status: eight mixers a trace, two traces, none of them
+        # the kernel on a CPU
+        assert METRICS.summary()["expander"]["mixer_products"] == {
+            "kernel": 0, "loop": 16}
         sites = ATTENTION.summary()
         assert sites["latent_absorbed"] == 4
         assert sites["latent_expanded"] == 4
@@ -760,7 +924,9 @@ class TestEnginePath:
         engine.txt2img(payload())
         block = METRICS.summary()["expander"]
         assert {"state_bytes", "cache_positions", "residual_streams",
-                "sinkhorn_iters", "expert_products"} <= set(block)
+                "sinkhorn_iters", "expert_products",
+                "mixer_products"} <= set(block)
+        assert set(block["mixer_products"]) == {"kernel", "loop"}
         assert set(block["state_bytes"]) == {"full", "sliding", "latent"}
         # on a CPU, in float32, at these widths every expert layer loops
         assert block["expert_products"]["kernel"] == 0

@@ -654,8 +654,9 @@ class TestEnginePath:
             "expert_tokens",
             "expert_load_max_over_mean", "cache_positions", "state_bytes",
             "prefix_snapshots", "padded_rows_masked", "expert_products",
-            "residual_streams", "sinkhorn_iters"}
+            "mixer_products", "residual_streams", "sinkhorn_iters"}
         assert set(block["expert_products"]) == {"kernel", "loop", "grouped"}
+        assert set(block["mixer_products"]) == {"kernel", "loop"}
         json.dumps(block)
 
 

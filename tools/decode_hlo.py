@@ -11,10 +11,14 @@ file: every one of them is a launch of its own in every step of the scan,
 0.3 us or more on a v5e however little it computes. Where a step is bound
 by launches and not by bytes (the residual streams' mixers: PERF.md
 section 6, PR 35) the count moves before any chip time is spent. A loop
-nested in the step (Sinkhorn's iterations) is counted once whatever its
-trips: 22 launches a mixer outside its loop and 4 an iteration inside. It
-says nothing about a time. ``--layers`` cuts the depth for a faster answer;
-``--keep`` writes the HLO text there. Run it from a scratch directory.
+nested in the step is counted once whatever its trips. Since PR 36 a mixer
+of a decode step is 2 launches, its two kernels (``hc`` 80 of a step's 927
+at 20 layers); in XLA's form it was 22 outside Sinkhorn's loop and 4 an
+iteration inside, about a hundred. The scope of an op the compiler hoisted
+out of the loop still says ``while/body``: where that matters read the
+``--keep`` text by computation. It says nothing about a time.
+``--layers`` cuts the depth for a faster answer; ``--keep`` writes the HLO
+text there. Run it from a scratch directory.
 """
 
 from __future__ import annotations
