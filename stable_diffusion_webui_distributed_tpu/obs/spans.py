@@ -7,18 +7,21 @@ at API ingress (or lazily by the serving dispatcher for direct callers) via
 :func:`bind_current` — can then open child spans with :func:`span`, and
 ``runtime/trace.py`` runs every ``StageStats.timer`` block as one.
 
-Coalesced dispatches link leader and followers: the leader's device span is
-mirrored into each follower's trace with ``leader_request_id`` /
-``leader_span_id`` attrs (:func:`mirror_span`), so a follower's tree still
-shows where its wall-clock went even though another request drove the TPU.
+Coalesced dispatches link leader and followers: a follower's wait on its
+leader is its own live span (``coalesced.wait``, serving/dispatcher.py)
+carrying ``leader_request_id`` / ``leader_span_id``, so a follower's tree
+still shows where its wall-clock went even though another request drove the
+TPU.
 
 Timing is host-side ``time.perf_counter()`` only — recording a span never
 syncs the device. While a ``jax.profiler`` capture runs (whoever started
 it), every span opened through :func:`request` or :func:`span` is also a
 ``TraceAnnotation`` named ``sdtpu:<span name>`` carrying ``request_id`` and
 ``span_id``, so the capture holds the span tree on its host planes, on the
-profiler's clock; with no capture running that costs one flag check.
-Intervals recorded after the fact (:func:`add_span`) exist only here. The
+profiler's clock; with no capture running that costs one flag check. A wait
+that ends inside another context manager's body is opened and closed by hand
+(:func:`open_span` / :func:`close_span`). Intervals recorded after the fact
+(:func:`add_span`) exist only here. The
 store is bounded (``SDTPU_OBS_MAX_REQUESTS`` finished
 traces) and lock-disciplined: one lock, nothing external called while
 holding it. Export is Chrome trace-event JSON ("X" complete events with
@@ -32,6 +35,7 @@ import contextvars
 import hashlib
 import itertools
 import os
+import statistics
 import threading
 import time
 import uuid
@@ -46,8 +50,17 @@ from stable_diffusion_webui_distributed_tpu.runtime.config import (
 #: Finished request traces retained for /internal/trace.json.
 DEFAULT_MAX_REQUESTS = 256
 #: e2e latency (seconds) above which a request is flight-recorded as a
-#: slow outlier; 0 disables slow capture.
+#: slow outlier; 0 disables slow capture (the rule below too).
 DEFAULT_SLOW_S = 30.0
+#: A request is also slow when its root exceeds SLOW_RATIO x the median of
+#: the last SLOW_WINDOW ``ok`` requests of its class (root name, ``width``,
+#: ``height``, ``steps`` of the root's attrs); never before SLOW_MIN_SAMPLES
+#: of them, never for a root without the three attrs.
+SLOW_RATIO = 1.5
+SLOW_WINDOW = 32
+SLOW_MIN_SAMPLES = 8
+#: classes whose durations are kept (the oldest goes first)
+SLOW_MAX_CLASSES = 64
 
 #: perf_counter base for trace-event timestamps (µs since process start of
 #: tracing, not wall clock — Perfetto only needs a shared monotonic base).
@@ -80,7 +93,8 @@ def _annotate(name: str, **meta: Any):
 
 #: (RequestTrace, parent span id) for the code currently executing, or None
 #: outside any request. Thread- and contextvars-scoped: HTTP handler
-#: threads each see only their own request.
+#: threads each see only their own request. (The id is None only while
+#: ``http.respond`` opens beside the root.)
 _CURRENT: "contextvars.ContextVar[Optional[Tuple[RequestTrace, int]]]" = \
     contextvars.ContextVar("sdtpu_obs_request", default=None)  # sdtpu-lint: metric
 
@@ -165,6 +179,8 @@ class SpanTracer:
         self._active: Dict[str, RequestTrace] = {}  # guarded-by: _lock
         self._done: Deque[RequestTrace] = deque(
             maxlen=max(1, int(max_requests or DEFAULT_MAX_REQUESTS)))  # guarded-by: _lock
+        #: class -> durations of its last ``ok`` requests
+        self._ok_durs: Dict[tuple, Deque[float]] = {}  # guarded-by: _lock
 
     # -- store ------------------------------------------------------------
 
@@ -188,6 +204,34 @@ class SpanTracer:
         with self._lock:
             self._active.clear()
             self._done.clear()
+            self._ok_durs.clear()
+
+    def slow_detail(self, req: RequestTrace) -> Optional[str]:
+        """Why a finished request counts as slow, or None; the duration of
+        one that does not joins its class's window."""
+        if self.slow_s <= 0:
+            return None
+        if req.dur >= self.slow_s:
+            return f"e2e {req.dur:.3f}s >= {self.slow_s:.3f}s threshold"
+        shape = tuple(req.attrs.get(k) for k in ("width", "height", "steps"))
+        if None in shape:
+            return None
+        key = (req.name,) + shape
+        with self._lock:
+            recent = self._ok_durs.get(key)
+            if recent is None:
+                if len(self._ok_durs) >= SLOW_MAX_CLASSES:
+                    self._ok_durs.pop(next(iter(self._ok_durs)))
+                recent = self._ok_durs[key] = deque(maxlen=SLOW_WINDOW)
+            if len(recent) >= SLOW_MIN_SAMPLES:
+                median = statistics.median(recent)
+                if req.dur > SLOW_RATIO * median:
+                    return (f"e2e {req.dur:.3f}s > {SLOW_RATIO} x median "
+                            f"{median:.3f}s of the last {len(recent)} ok "
+                            f"{req.name} {shape[0]}x{shape[1]} "
+                            f"{shape[2]} steps")
+            recent.append(req.dur)
+        return None
 
     # -- export -----------------------------------------------------------
 
@@ -266,11 +310,12 @@ def _finish(tr: SpanTracer, req: RequestTrace, error: Optional[str]) -> None:
         req.status, req.detail = "error", error
     elif req.status == "interrupted":
         pass  # marked mid-flight by cancel/interrupt
-    elif tr.slow_s > 0 and req.dur >= tr.slow_s:
-        req.status = "slow"
-        req.detail = f"e2e {req.dur:.3f}s >= {tr.slow_s:.3f}s threshold"
     else:
-        req.status = "ok"
+        slow = tr.slow_detail(req)
+        if slow is None:
+            req.status = "ok"
+        else:
+            req.status, req.detail = "slow", slow
     root = Span(req.root_id, None, req.name, req.t0, req.dur,
                 threading.get_ident(), dict(req.attrs, status=req.status))
     tr.record(req, root)
@@ -282,28 +327,47 @@ def _finish(tr: SpanTracer, req: RequestTrace, error: Optional[str]) -> None:
             duration_s=req.dur, events=tr.events_for(req))
 
 
-@contextlib.contextmanager
-def span(name: str, **attrs: Any) -> Iterator[Optional[Span]]:
-    """Child span under the active request; cheap no-op outside one."""
+def open_span(name: str, t0: Optional[float] = None, **attrs: Any):
+    """Open a child span under the active request WITHOUT a ``with`` block:
+    for a wait that ends inside another context manager's body (the
+    dispatcher's ``queue_wait`` and ``engine.wait`` end inside
+    ``_device()``). Returns the handle :func:`close_span` takes, None
+    outside a request. Hand-opened spans close on the thread that opened
+    them, innermost first, like nested ``with`` blocks. ``t0`` backdates
+    the span's recorded start (the annotation starts now)."""
     tr = TRACER
     ctx = _CURRENT.get()
     if ctx is None or not tr.enabled:
-        yield None
-        return
+        return None
     req, parent = ctx
-    sp = Span(next(_IDS), parent, name, time.perf_counter(), 0.0,
-              threading.get_ident(), dict(attrs))
+    sp = Span(next(_IDS), parent, name,
+              time.perf_counter() if t0 is None else t0, 0.0,
+              threading.get_ident(), attrs)
     token = _CURRENT.set((req, sp.span_id))
-    ann = _annotate(name, request_id=req.request_id,
-                    span_id=sp.span_id)
+    ann = _annotate(name, request_id=req.request_id, span_id=sp.span_id)
+    return sp, req, token, ann
+
+
+def close_span(handle) -> None:
+    """End a span :func:`open_span` opened and record it."""
+    if handle is None:
+        return
+    sp, req, token, ann = handle
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    _CURRENT.reset(token)
+    sp.dur = time.perf_counter() - sp.t0
+    TRACER.record(req, sp)
+
+
+@contextlib.contextmanager
+def span(name: str, **attrs: Any) -> Iterator[Optional[Span]]:
+    """Child span under the active request; cheap no-op outside one."""
+    handle = open_span(name, **attrs)
     try:
-        yield sp
+        yield None if handle is None else handle[0]
     finally:
-        if ann is not None:
-            ann.__exit__(None, None, None)
-        _CURRENT.reset(token)
-        sp.dur = time.perf_counter() - sp.t0
-        tr.record(req, sp)
+        close_span(handle)
 
 
 @contextlib.contextmanager
@@ -379,23 +443,20 @@ def http_exchange() -> Iterator[Optional[Exchange]]:
 @contextlib.contextmanager
 def http_respond() -> Iterator[Optional[Span]]:
     """``http.respond`` of the exchange's request: serialising and writing
-    the response. A no-op when this exchange minted no request."""
+    the response, each a child span (:func:`span` works inside). A no-op
+    when this exchange minted no request."""
     exchange = _EXCHANGE.get()
     req = None if exchange is None else exchange.req
     if req is None:
         yield None
         return
-    sp = Span(next(_IDS), None, "http.respond", time.perf_counter(), 0.0,
-              threading.get_ident(), {})
-    ann = _annotate("http.respond", request_id=req.request_id,
-                    span_id=sp.span_id)
+    # the request's context again, under no parent: beside the root
+    token = _CURRENT.set((req, None))
     try:
-        yield sp
+        with span("http.respond") as sp:
+            yield sp
     finally:
-        if ann is not None:
-            ann.__exit__(None, None, None)
-        sp.dur = time.perf_counter() - sp.t0
-        TRACER.record(req, sp)
+        _CURRENT.reset(token)
 
 
 # -- cross-thread / cross-request recording ----------------------------------
@@ -459,15 +520,6 @@ def add_child(name: str, seconds: float, **attrs: Any) -> Optional[Span]:
     req, parent = ctx
     return add_span(req, name, time.perf_counter() - seconds, seconds,
                     attrs, parent_id=parent)
-
-
-def mirror_span(req: Optional[RequestTrace], name: str, src: Optional[Span],
-                **attrs: Any) -> Optional[Span]:
-    """Copy ``src``'s interval into another request's trace — the
-    leader/follower link for coalesced dispatches."""
-    if req is None or src is None:
-        return None
-    return add_span(req, name, src.t0, src.dur, attrs=dict(attrs))
 
 
 def mark(req: Optional[RequestTrace], status: str, detail: str = "") -> None:

@@ -1361,91 +1361,94 @@ class Engine:
                 payload, x, image_keys, conds, pooleds, width, height,
                 start_step, steps, job, mask_lat, init_lat, controls,
                 end_step, inpaint_cond)
-        (ctx_u, ctx_c) = conds
-        au, ac = self._added_cond(*pooleds, width, height)
-        batch = x.shape[0]
-        if lora is None and self._traced_lora is not None:
-            from stable_diffusion_webui_distributed_tpu.models import (
-                lora as lora_mod,
-            )
-
-            ts = self._traced_lora
-            lora = (ts.sig, ts.content,
-                    lora_mod.broadcast_set(ts, batch)["unet"])
-        lora_sig, lora_content, lora_rows = lora or ("", "", None)
-        masked = mask_lat is not None
-        inpainting = self.family.inpaint and inpaint_cond is not None
-        inputs = denoise.Inputs(
-            ctx_u, ctx_c, jnp.float32(payload.cfg_scale), image_keys, au, ac,
-            mask_lat, init_lat if masked else None,
-            inpaint_cond=inpaint_cond if inpainting else None,
-            lora=lora_rows, ragged=ragged)
-        carry = kd.init_carry(x)
-        end = steps if end_step is None else min(end_step, steps)
-
-        # Step-cache policy (pipeline/stepcache.py): deep-feature reuse +
-        # CFG truncation. Inactive (cadence 1, cutoff 0 — the default)
-        # routes every chunk to the UNCHANGED plain executable, so default
-        # outputs stay byte-identical by construction. The cutoff sigma is
-        # located on the built ladder host-side (searchsorted, like the
-        # adaptive path's CN window gating) and rides into the executable
-        # as a traced step index.
-        spec = kd.resolve_sampler(payload.sampler_name)
-        sc = stepcache.resolve(payload)
-        # Serving precision (pipeline/precision.py): resolved once per
-        # range, static in the chunk executable key. A request that
-        # specifies nothing resolves to the policy default, whose module
-        # pair IS the constructor-built one — the default path routes to
-        # the unchanged executables byte-for-byte. The int8 activation
-        # scales are computed inside the traced fn per call (dynamic
-        # per-tensor, ops/quant.py), so they never recompile anything.
-        prec = precision_mod.resolve(payload, self.policy)
-        cfg_stop = stepcache.cutoff_step(
-            np.asarray(kd.build_sigmas(spec, self.schedule, steps)),
-            sc.cutoff_sigma)
-        use_cache = (sc.active and cache_supported(self.family.unet)
-                     and ragged is None)
-        cache = valid = None
-        if use_cache:
-            # [uncond; cond] deep-feature rows; a fresh range starts
-            # INVALID so the first step always refreshes — which is also
-            # what makes an interrupt-resume boundary safe mid-cadence
-            cache = jnp.zeros(
-                deep_cache_shape(self.family.unet, 2 * batch,
-                                 x.shape[1], x.shape[2]),
-                self.policy.compute_dtype)
-            valid = jnp.asarray(False)
-            cached_inputs = inputs._replace(cadence=jnp.int32(sc.cadence),
-                                            cfg_stop=jnp.int32(cfg_stop))
-
-        # Denoise prefix sharing (cache/prefix.py, SDTPU_CACHE): only for
-        # ranges where a captured prefix can be BYTE-identical — the plain
-        # txt2img base range with nothing that injects per-step state the
-        # capture can't carry (masks, inpaint conditioning, ControlNet
-        # windows) and nothing already consumed (start_step 0). The
-        # non-sync path never paces on fences, so a capture's host
-        # materialization has no safe point there.
-        prefix_plan = None
-        if (job == "txt2img" and sync and start_step == 0 and not masked
-                and not inpainting and not controls and end > 0
-                and ragged is None):
-            from stable_diffusion_webui_distributed_tpu.cache import (
-                keys as cache_keys,
-            )
-
-            if cache_keys.enabled():
-                from stable_diffusion_webui_distributed_tpu.cache import (
-                    prefix as cache_prefix,
+        # everything up to the first enqueue runs with the device idle:
+        # these two spans own it
+        with obs_spans.span("denoise.inputs"):
+            (ctx_u, ctx_c) = conds
+            au, ac = self._added_cond(*pooleds, width, height)
+            batch = x.shape[0]
+            if lora is None and self._traced_lora is not None:
+                from stable_diffusion_webui_distributed_tpu.models import (
+                    lora as lora_mod,
                 )
 
-                prefix_plan = cache_prefix.plan(
-                    self, payload, batch=batch, width=width, height=height,
-                    steps=steps, end=end,
-                    cadence=(sc.cadence if use_cache else 1),
-                    sc_active=use_cache, precision=prec.name,
-                    cfg_stop=cfg_stop, lora=lora_content)
+                ts = self._traced_lora
+                lora = (ts.sig, ts.content,
+                        lora_mod.broadcast_set(ts, batch)["unet"])
+            lora_sig, lora_content, lora_rows = lora or ("", "", None)
+            masked = mask_lat is not None
+            inpainting = self.family.inpaint and inpaint_cond is not None
+            inputs = denoise.Inputs(
+                ctx_u, ctx_c, jnp.float32(payload.cfg_scale), image_keys,
+                au, ac, mask_lat, init_lat if masked else None,
+                inpaint_cond=inpaint_cond if inpainting else None,
+                lora=lora_rows, ragged=ragged)
+            carry = kd.init_carry(x)
+            end = steps if end_step is None else min(end_step, steps)
+        with obs_spans.span("denoise.plan"):
+            # Step-cache policy (pipeline/stepcache.py): deep-feature reuse +
+            # CFG truncation. Inactive (cadence 1, cutoff 0 — the default)
+            # routes every chunk to the UNCHANGED plain executable, so default
+            # outputs stay byte-identical by construction. The cutoff sigma is
+            # located on the built ladder host-side (searchsorted, like the
+            # adaptive path's CN window gating) and rides into the executable
+            # as a traced step index.
+            spec = kd.resolve_sampler(payload.sampler_name)
+            sc = stepcache.resolve(payload)
+            # Serving precision (pipeline/precision.py): resolved once per
+            # range, static in the chunk executable key. A request that
+            # specifies nothing resolves to the policy default, whose module
+            # pair IS the constructor-built one — the default path routes to
+            # the unchanged executables byte-for-byte. The int8 activation
+            # scales are computed inside the traced fn per call (dynamic
+            # per-tensor, ops/quant.py), so they never recompile anything.
+            prec = precision_mod.resolve(payload, self.policy)
+            cfg_stop = stepcache.cutoff_step(
+                np.asarray(kd.build_sigmas(spec, self.schedule, steps)),
+                sc.cutoff_sigma)
+            use_cache = (sc.active and cache_supported(self.family.unet)
+                         and ragged is None)
+            cache = valid = None
+            if use_cache:
+                # [uncond; cond] deep-feature rows; a fresh range starts
+                # INVALID so the first step always refreshes — which is also
+                # what makes an interrupt-resume boundary safe mid-cadence
+                cache = jnp.zeros(
+                    deep_cache_shape(self.family.unet, 2 * batch,
+                                     x.shape[1], x.shape[2]),
+                    self.policy.compute_dtype)
+                valid = jnp.asarray(False)
+                cached_inputs = inputs._replace(cadence=jnp.int32(sc.cadence),
+                                                cfg_stop=jnp.int32(cfg_stop))
 
-        self.state.begin(job, end - start_step)
+            # Denoise prefix sharing (cache/prefix.py, SDTPU_CACHE): only for
+            # ranges where a captured prefix can be BYTE-identical — the plain
+            # txt2img base range with nothing that injects per-step state the
+            # capture can't carry (masks, inpaint conditioning, ControlNet
+            # windows) and nothing already consumed (start_step 0). The
+            # non-sync path never paces on fences, so a capture's host
+            # materialization has no safe point there.
+            prefix_plan = None
+            if (job == "txt2img" and sync and start_step == 0 and not masked
+                    and not inpainting and not controls and end > 0
+                    and ragged is None):
+                from stable_diffusion_webui_distributed_tpu.cache import (
+                    keys as cache_keys,
+                )
+
+                if cache_keys.enabled():
+                    from stable_diffusion_webui_distributed_tpu.cache import (
+                        prefix as cache_prefix,
+                    )
+
+                    prefix_plan = cache_prefix.plan(
+                        self, payload, batch=batch, width=width, height=height,
+                        steps=steps, end=end,
+                        cadence=(sc.cadence if use_cache else 1),
+                        sc_active=use_cache, precision=prec.name,
+                        cfg_stop=cfg_stop, lora=lora_content)
+
+            self.state.begin(job, end - start_step)
         done = 0
         pos = start_step
         if prefix_plan is not None and prefix_plan.resume is not None:
@@ -1618,11 +1621,11 @@ class Engine:
         # sampled latent channels — NOT unet.in_channels, which counts the
         # mask/masked-image conditioning of inpainting checkpoints too
         C = self.family.vae.latent_channels
-        spec = kd.resolve_sampler(payload.sampler_name)
-        sigmas = kd.build_sigmas(spec, self.schedule, payload.steps)
-
-        controls = self._prepare_controls(payload, width, height)
-        refiner = self._refiner_engine(payload)
+        with obs_spans.span("request.plan"):
+            spec = kd.resolve_sampler(payload.sampler_name)
+            sigmas = kd.build_sigmas(spec, self.schedule, payload.steps)
+            controls = self._prepare_controls(payload, width, height)
+            refiner = self._refiner_engine(payload)
         from stable_diffusion_webui_distributed_tpu.parallel import (
             stage_graph,
         )
@@ -1693,15 +1696,17 @@ class Engine:
                         seed_resize=self._seed_resize_latent(payload),
                         pin_index=payload.same_seed)
                 ragged = None
-                if ragged_wh is not None:
-                    noise = jnp.pad(
-                        noise, ((0, 0), (0, h - tr), (0, 0), (0, 0)))
-                    ragged = (jnp.full((gen_n,), tr, jnp.int32),
-                              jnp.full((gen_n,), ctx_true[0], jnp.int32),
-                              jnp.full((gen_n,), ctx_true[1], jnp.int32))
-                x = self._place_batch(
-                    noise.astype(jnp.float32) * sigmas[0])
-                keys = self._image_keys(payload, pos, gen_n)
+                with obs_spans.span("batch.assemble"):
+                    if ragged_wh is not None:
+                        noise = jnp.pad(
+                            noise, ((0, 0), (0, h - tr), (0, 0), (0, 0)))
+                        ragged = (
+                            jnp.full((gen_n,), tr, jnp.int32),
+                            jnp.full((gen_n,), ctx_true[0], jnp.int32),
+                            jnp.full((gen_n,), ctx_true[1], jnp.int32))
+                    x = self._place_batch(
+                        noise.astype(jnp.float32) * sigmas[0])
+                    keys = self._image_keys(payload, pos, gen_n)
                 if payload.all_prompts:
                     conds, pooleds, ref_cond = self._group_conds(
                         payload, pos, gen_n, refiner)
@@ -2285,10 +2290,20 @@ class Engine:
                             incomplete))
         return entries
 
+    @staticmethod
+    def _fetch_decoded(imgs_dev) -> np.ndarray:
+        """A decoded batch on the host, inside the caller's
+        ``vae_decode_fetch``: the wait for the decode executable (the device
+        busy), then the copy down (the device idle)."""
+        with obs_spans.span("decode.wait"):
+            jax.block_until_ready(imgs_dev)
+        with obs_spans.span("fetch.copy", bytes=int(imgs_dev.nbytes)):
+            return np.asarray(imgs_dev)
+
     def _flush_decoded(self, out, payload, pending) -> None:
         for imgs_dev, pos, n, width, height, incomplete in pending:
             with trace.STATS.timer("vae_decode_fetch"):
-                imgs = np.asarray(imgs_dev)
+                imgs = self._fetch_decoded(imgs_dev)
             self._append_images(out, payload, imgs, pos, n, width, height,
                                 incomplete=incomplete)
 

@@ -101,14 +101,16 @@ class PromptExpander:
         with obs_spans.span("expand.tokenize"):
             prefix = [tok.bos] + tok.encode(args.instruction)
             user = tok.encode(prompt) or [tok.eos]
-        chunks = -(-(args.max_new_tokens - 1) // DECODE_STEPS)
-        capacity = kv.capacity_for(len(prefix) + kv.chunk_bucket(len(user))
-                                   + chunks * DECODE_STEPS)
-        key = jax.random.fold_in(rng.key_for_image(seed, image_index),
-                                 _KEY_DOMAIN)
-        temperature = jnp.float32(args.temperature)
-        sizes = kv.state_bytes(self.config, capacity, self.cache.dtype)
-        copied = sum(sizes.values())
+        with obs_spans.span("expand.setup"):
+            chunks = -(-(args.max_new_tokens - 1) // DECODE_STEPS)
+            capacity = kv.capacity_for(
+                len(prefix) + kv.chunk_bucket(len(user))
+                + chunks * DECODE_STEPS)
+            key = jax.random.fold_in(rng.key_for_image(seed, image_index),
+                                     _KEY_DOMAIN)
+            temperature = jnp.float32(args.temperature)
+            sizes = kv.state_bytes(self.config, capacity, self.cache.dtype)
+            copied = sum(sizes.values())
         with obs_spans.span("expand.prefix_copy", bytes=copied) as sp:
             cache, held = self.cache.acquire(prefix, capacity)
             if sp is not None:
@@ -168,21 +170,23 @@ class PromptExpander:
                 fetch(pending.pop(0))
         for out in pending:
             fetch(out)
-        made = made[:args.max_new_tokens]
-        if not args.ignore_eos and tok.eos in made:
-            made = made[:made.index(tok.eos)]
-        length = len(prefix) + len(user) + len(made)
-        loads, none_held = zip(*jax.device_get(routed))
-        EXPANDER.record(
-            prefilled=len(user) + (0 if held else len(prefix)),
-            from_prefix=held, decoded=len(made), decode_steps=steps,
-            load=np.sum(loads, axis=0), none_held=int(np.sum(none_held)),
-            positions=self.cache.positions_in_use(length),
-            state_bytes=sizes, prefix_snapshots=self.cache.snapshots,
-            padded_rows_masked=masked,
-            residual_streams=self.config.residual_streams,
-            sinkhorn_iters=(self.config.sinkhorn_iters
-                            if self.config.residual_streams > 1 else 0))
+        # the cut and the counters' fetch: host work with the device idle
+        with obs_spans.span("expand.account", fetched=2 * len(routed)):
+            made = made[:args.max_new_tokens]
+            if not args.ignore_eos and tok.eos in made:
+                made = made[:made.index(tok.eos)]
+            length = len(prefix) + len(user) + len(made)
+            loads, none_held = zip(*jax.device_get(routed))
+            EXPANDER.record(
+                prefilled=len(user) + (0 if held else len(prefix)),
+                from_prefix=held, decoded=len(made), decode_steps=steps,
+                load=np.sum(loads, axis=0), none_held=int(np.sum(none_held)),
+                positions=self.cache.positions_in_use(length),
+                state_bytes=sizes, prefix_snapshots=self.cache.snapshots,
+                padded_rows_masked=masked,
+                residual_streams=self.config.residual_streams,
+                sinkhorn_iters=(self.config.sinkhorn_iters
+                                if self.config.residual_streams > 1 else 0))
         return made
 
     def _fit(self, text: str, chunks: Optional[int]) -> str:

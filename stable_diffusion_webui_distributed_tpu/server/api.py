@@ -179,8 +179,11 @@ class ApiServer:
 
         rid = str(getattr(payload, "request_id", "") or uuid.uuid4().hex)
         payload.request_id = rid
+        # width, height and steps class the request for the flight
+        # recorder's slow rule (obs/spans.py:SLOW_RATIO)
         return obs_spans.request(rid, name=route.rsplit("/", 1)[-1],
-                                 route=route)
+                                 route=route, width=payload.width,
+                                 height=payload.height, steps=payload.steps)
 
     def _submit_dispatch(self, payload: GenerationPayload,
                          job: str) -> GenerationResult:
@@ -1056,16 +1059,20 @@ class ApiServer:
             def _send(self, status: int, obj: Any,
                       headers: Optional[Dict[str, str]] = None):
                 with obs_spans.http_respond() as sp:
-                    data = json.dumps(obj).encode()
+                    with obs_spans.span("respond.serialize") as part:
+                        data = json.dumps(obj).encode()
+                        if part is not None:
+                            part.attrs["bytes"] = len(data)
                     if sp is not None:
                         sp.attrs.update(bytes=len(data), status=status)
-                    self.send_response(status)
-                    self.send_header("Content-Type", "application/json")
-                    self.send_header("Content-Length", str(len(data)))
-                    for k, v in (headers or {}).items():
-                        self.send_header(k, v)
-                    self.end_headers()
-                    self.wfile.write(data)
+                    with obs_spans.span("respond.write", bytes=len(data)):
+                        self.send_response(status)
+                        self.send_header("Content-Type", "application/json")
+                        self.send_header("Content-Length", str(len(data)))
+                        for k, v in (headers or {}).items():
+                            self.send_header(k, v)
+                        self.end_headers()
+                        self.wfile.write(data)
 
             def _send_html(self, status: int, text: str):
                 data = text.encode()

@@ -218,8 +218,7 @@ class ShapeBucketer:
             spans as obs_spans,
         )
 
-        with obs_spans.span("bucket", width=payload.width,
-                            height=payload.height) as sp:
+        with obs_spans.span("bucket") as sp:   # the asked size: the root's
             run = payload.model_copy()
             if ragged:
                 bucket = self.bucket_shape_ragged(payload.width,

@@ -113,9 +113,16 @@ class Ticket:
         self.cancelled = threading.Event()
         self.result = None
         self.error: Optional[BaseException] = None
-        #: submitting thread's obs trace; the coalesce leader records
-        #: queue-wait and mirrored device spans into it cross-thread
+        #: submitting thread's obs trace; the coalesce leader records a
+        #: follower's queue wait into it cross-thread
         self.obs_req = obs_spans.current()
+        #: the wait spans this ticket's own thread holds open by hand
+        #: (``queue_wait``, then ``engine.wait``), innermost last
+        self.waits: list = []
+        #: the dispatch that carried a grouped ticket: the leader's request
+        #: id and its ``dispatch.device`` span id, set by the leader as the
+        #: attrs of a follower's ``coalesced.wait``
+        self.leader_link: dict = {}
         #: per-stage completion callback (stage-graph mode): called as
         #: ``on_stage(request_id, stage_name, seconds)`` after each of the
         #: group's encode/denoise/decode/merge stages instead of one
@@ -195,7 +202,9 @@ class ServingDispatcher:
             CHAOS_HOOK("dispatcher.submit", payload=payload, rid=rid)
         # root the obs trace here for direct callers; HTTP ingress already
         # minted one for API traffic (maybe_request joins it)
-        with obs_spans.maybe_request(rid, name=f"serve.{job}"):
+        with obs_spans.maybe_request(
+                rid, name=f"serve.{job}", width=payload.width,
+                height=payload.height, steps=payload.steps):
             jr_on = obs_journal.enabled()
             if jr_on:
                 # post-fix_seed dump: the replay anchor (tools/replay.py)
@@ -675,12 +684,48 @@ class ServingDispatcher:
                 "coalesced_leader" if leader else "coalesced_follower",
                 ticket.request_id, images=n, leader_request_id=leader_rid)
         if not leader:
-            ticket.done.wait()
+            with obs_spans.span("coalesced.wait") as sp:
+                ticket.done.wait()
+                if sp is not None:
+                    sp.attrs.update(ticket.leader_link)
             return
-        if self.window > 0:
-            time.sleep(self.window)
-        with self._checkout_engine():
-            self._run_grouped_leader(g, key)
+        self._begin_wait(ticket)
+        try:
+            if self.window > 0:
+                with obs_spans.span("coalesce.window", window_s=self.window):
+                    time.sleep(self.window)
+            self._begin_engine_wait(ticket)
+            with self._checkout_engine():
+                self._run_grouped_leader(g, key)
+        finally:
+            self._end_wait(ticket)      # a wait that never reached the device
+
+    # -- the wait before the device, live ----------------------------------
+    #
+    # ``queue_wait`` (ticket creation -> start of the device section) and
+    # its child ``engine.wait`` end inside the bodies of _checkout_engine()
+    # and _device(), so they are opened and closed by hand, on the ticket's
+    # own thread: the leader's or the solo request's. A follower's
+    # ``queue_wait`` ends when its LEADER starts and stays an after-the-fact
+    # record; its live span is ``coalesced.wait``.
+
+    def _begin_wait(self, ticket: Ticket) -> None:
+        ticket.waits.append(obs_spans.open_span(
+            "queue_wait", t0=ticket.enqueued_perf))
+
+    def _begin_engine_wait(self, ticket: Ticket) -> None:
+        """``engine.wait``: the engine checkout and the device lock or
+        fleet gate. ``queued``: the other tickets in the dispatcher (waiting
+        or running) when the wait began."""
+        if ticket.waits[-1] is None:    # not traced
+            return
+        with self._lock:
+            queued = len(self._tickets) - 1
+        ticket.waits.append(obs_spans.open_span("engine.wait", queued=queued))
+
+    def _end_wait(self, ticket: Ticket) -> None:
+        while ticket.waits:
+            obs_spans.close_span(ticket.waits.pop())
 
     def _run_grouped_leader(self, g: _Group, key) -> None:
         """The leader's execution: device section + (stage-graph mode)
@@ -695,6 +740,7 @@ class ServingDispatcher:
                     self._groups.pop(key)
             start = time.monotonic()
             start_perf = time.perf_counter()
+            self._end_wait(g.tickets[0])
             leader_req = obs_spans.current()
             jr_on = obs_journal.enabled()
             # adapter cell label for spans/journal/ledger; only attached
@@ -713,8 +759,11 @@ class ServingDispatcher:
                 if self.fleet is not None:
                     obs_prom.fleet_observe_queue_wait(
                         self.fleet.policy.resolve(t.fleet_class).name, wait)
-                obs_spans.add_span(t.obs_req, "queue_wait", t.enqueued_perf,
-                                   start_perf - t.enqueued_perf)
+                if t is not g.tickets[0]:
+                    # a follower's wait ended when this leader started
+                    obs_spans.add_span(t.obs_req, "queue_wait",
+                                       t.enqueued_perf,
+                                       start_perf - t.enqueued_perf)
                 if jr_on:
                     obs_journal.emit("dispatched", t.request_id,
                                      group=len(g.tickets),
@@ -762,22 +811,17 @@ class ServingDispatcher:
                 self._finish_group(g, dsp, leader_req)
 
     def _finish_group(self, g: _Group, dsp, leader_req) -> None:
-        """Terminal bookkeeping for a dispatched group: mirror the
-        leader's device span into follower traces, record SLO samples,
-        and release every waiting ticket."""
-        # leader/follower link: mirror the leader's device span into
-        # every follower's trace so a follower's tree shows where its
-        # wall-clock went
+        """Terminal bookkeeping for a dispatched group: hand every
+        follower the link to the leader's device span (its
+        ``coalesced.wait`` carries it), record SLO samples, and release
+        every waiting ticket."""
+        link = {}
         if dsp is not None and leader_req is not None:
-            for t in g.tickets:
-                if t.obs_req is not None \
-                        and t.obs_req is not leader_req:
-                    obs_spans.mirror_span(
-                        t.obs_req, "coalesced.dispatch", dsp,
-                        leader_request_id=leader_req.request_id,
-                        leader_span_id=dsp.span_id)
+            link = {"leader_request_id": leader_req.request_id,
+                    "leader_span_id": dsp.span_id}
         for t in g.tickets:
             self._record_slo(t)
+            t.leader_link = link
             t.done.set()
 
     @staticmethod
@@ -842,8 +886,13 @@ class ServingDispatcher:
             pass
 
     def _run_solo(self, ticket: Ticket) -> None:
-        with self._checkout_engine():
-            self._run_solo_inner(ticket)
+        self._begin_wait(ticket)
+        self._begin_engine_wait(ticket)
+        try:
+            with self._checkout_engine():
+                self._run_solo_inner(ticket)
+        finally:
+            self._end_wait(ticket)      # cancelled before dispatch
 
     def _run_solo_inner(self, ticket: Ticket) -> None:
         engine = self._engine()
@@ -862,10 +911,7 @@ class ServingDispatcher:
                     obs_prom.fleet_observe_queue_wait(
                         self.fleet.policy.resolve(
                             ticket.fleet_class).name, wait)
-                obs_spans.add_span(ticket.obs_req, "queue_wait",
-                                   ticket.enqueued_perf,
-                                   time.perf_counter()
-                                   - ticket.enqueued_perf)
+                self._end_wait(ticket)
                 prec = self._precision_name(ticket.run)
                 METRICS.record_dispatch(1, precision=prec)
                 obs_prom.count_precision(prec, 1)
@@ -1062,15 +1108,16 @@ class ServingDispatcher:
         width, height = rp.width, rp.height
         h, w = engine._latent_hw(width, height)
         C = engine.family.vae.latent_channels
-        spec = kd.resolve_sampler(rp.sampler_name)
-        sigmas = kd.build_sigmas(spec, engine.schedule, rp.steps)
+        with obs_spans.span("request.plan"):
+            spec = kd.resolve_sampler(rp.sampler_name)
+            sigmas = kd.build_sigmas(spec, engine.schedule, rp.steps)
 
-        engine.state.begin_request()
-        engine._adaptive_incomplete = False
-        # tagless groups: restores pristine params; traced groups
-        # (non-zero cell in the key): restores pristine params too — the
-        # deltas ride as jit arguments, installed per member below
-        engine._apply_prompt_loras(rp)
+            engine.state.begin_request()
+            engine._adaptive_incomplete = False
+            # tagless groups: restores pristine params; traced groups
+            # (non-zero cell in the key): restores pristine params too —
+            # the deltas ride as jit arguments, installed per member below
+            engine._apply_prompt_loras(rp)
         # traced-LoRA cell from the group key (key[-3:-1]): every member
         # carries SOME adapter set in this (rank_bucket, slot_count) cell,
         # possibly a different one per member — each row gets its own
@@ -1146,53 +1193,56 @@ class ServingDispatcher:
                     padded_tok += pt
                 except Exception:  # noqa: BLE001 — telemetry stays passive
                     pass
-            key_parts.append(engine._image_keys(p, 0, n_p))
-            self._drain_cache_notes(t.request_id, prefix=False)
-            ctx_rows.append(jnp.broadcast_to(cc, (n_p,) + cc.shape[1:]))
-            pooled_rows.append(jnp.broadcast_to(pc, (n_p,) + pc.shape[1:]))
+            with obs_spans.span("batch.assemble", rows=n_p):
+                key_parts.append(engine._image_keys(p, 0, n_p))
+                self._drain_cache_notes(t.request_id, prefix=False)
+                ctx_rows.append(jnp.broadcast_to(cc, (n_p,) + cc.shape[1:]))
+                pooled_rows.append(
+                    jnp.broadcast_to(pc, (n_p,) + pc.shape[1:]))
             if ctx_u is None:
                 ctx_u, pooled_u = cu, pu  # equal negatives across the key
 
-        b_raw = sum(counts)
-        b_run = self.bucketer.bucket_batch(b_raw)
-        noise = jnp.concatenate(noise_parts, axis=0)
-        keys = jnp.concatenate(key_parts, axis=0)
-        ctx_c = jnp.concatenate(ctx_rows, axis=0)
-        pooled_c = jnp.concatenate(pooled_rows, axis=0)
-        if b_run > b_raw:
-            # pad-and-drop up to the batch bucket: the extra rows repeat
-            # the last image and are discarded after decode
-            pad = b_run - b_raw
+        with obs_spans.span("batch.assemble", rows=sum(counts)):
+            b_raw = sum(counts)
+            b_run = self.bucketer.bucket_batch(b_raw)
+            noise = jnp.concatenate(noise_parts, axis=0)
+            keys = jnp.concatenate(key_parts, axis=0)
+            ctx_c = jnp.concatenate(ctx_rows, axis=0)
+            pooled_c = jnp.concatenate(pooled_rows, axis=0)
+            if b_run > b_raw:
+                # pad-and-drop up to the batch bucket: the extra rows repeat
+                # the last image and are discarded after decode
+                pad = b_run - b_raw
 
-            def _pad(a):
-                return jnp.concatenate(
-                    [a, jnp.repeat(a[-1:], pad, axis=0)], axis=0)
+                def _pad(a):
+                    return jnp.concatenate(
+                        [a, jnp.repeat(a[-1:], pad, axis=0)], axis=0)
 
-            noise, keys = _pad(noise), _pad(keys)
-            ctx_c, pooled_c = _pad(ctx_c), _pad(pooled_c)
+                noise, keys = _pad(noise), _pad(keys)
+                ctx_c, pooled_c = _pad(ctx_c), _pad(pooled_c)
+                if ragged_mode:
+                    true_rows_l += [true_rows_l[-1]] * pad
+                    ctx_true_u_l += [ctx_true_u_l[-1]] * pad
+                    ctx_true_c_l += [ctx_true_c_l[-1]] * pad
+            ragged_arg = None
             if ragged_mode:
-                true_rows_l += [true_rows_l[-1]] * pad
-                ctx_true_u_l += [ctx_true_u_l[-1]] * pad
-                ctx_true_c_l += [ctx_true_c_l[-1]] * pad
-        ragged_arg = None
-        if ragged_mode:
-            ragged_arg = (jnp.asarray(true_rows_l, jnp.int32),
-                          jnp.asarray(ctx_true_u_l, jnp.int32),
-                          jnp.asarray(ctx_true_c_l, jnp.int32))
-        lora_arg = None
-        if traced_group:
-            # per-row factor stack (pad rows repeat the last member's set,
-            # matching the pad-and-drop image rows); content joins each
-            # DISTINCT member content so prefix capture can't alias across
-            # adapter combos
-            uniq: List[str] = []
-            for ts in row_sets:
-                if ts.content not in uniq:
-                    uniq.append(ts.content)
-            lora_arg = (row_sets[0].sig, "|".join(uniq),
-                        lora_mod.stack_row_sets(row_sets, b_run)["unet"])
+                ragged_arg = (jnp.asarray(true_rows_l, jnp.int32),
+                              jnp.asarray(ctx_true_u_l, jnp.int32),
+                              jnp.asarray(ctx_true_c_l, jnp.int32))
+            lora_arg = None
+            if traced_group:
+                # per-row factor stack (pad rows repeat the last member's set,
+                # matching the pad-and-drop image rows); content joins each
+                # DISTINCT member content so prefix capture can't alias across
+                # adapter combos
+                uniq: List[str] = []
+                for ts in row_sets:
+                    if ts.content not in uniq:
+                        uniq.append(ts.content)
+                lora_arg = (row_sets[0].sig, "|".join(uniq),
+                            lora_mod.stack_row_sets(row_sets, b_run)["unet"])
 
-        x = engine._place_batch(noise.astype(jnp.float32) * sigmas[0])
+            x = engine._place_batch(noise.astype(jnp.float32) * sigmas[0])
         return {
             "live": live, "counts": counts, "rp": rp,
             "width": width, "height": height, "h": h, "f": f,
@@ -1281,8 +1331,9 @@ class ServingDispatcher:
         b_raw, b_run = built["b_raw"], built["b_run"]
         ragged_mode = built["ragged_mode"]
         with trace.STATS.timer("vae_decode_fetch"):
-            imgs = np.concatenate(
-                [np.asarray(e[0])[:e[2]] for e in entries], axis=0)
+            parts = [engine._fetch_decoded(e[0])[:e[2]] for e in entries]
+            with obs_spans.span("fetch.join", slices=len(parts)):
+                imgs = np.concatenate(parts, axis=0)
         jr_on = obs_journal.enabled()
         if jr_on:
             obs_journal.emit("decoded", live[0].request_id,

@@ -223,22 +223,23 @@ class TestCoalescedRunTracing:
             names = {s.name for s in tr.spans}
             assert "serve.txt2img" in names  # root
             assert "bucket" in names         # bucketer span joins the ctx
-            assert "queue_wait" in names     # recorded by the group leader
+            assert "queue_wait" in names     # live (leader) or recorded
             # the device time is visible either as this request's own
-            # dispatch span or as the mirrored leader span
+            # dispatch span or as the follower's wait on its leader's
             assert ("dispatch.device" in names
-                    or "coalesced.dispatch" in names), (rid, names)
+                    or "coalesced.wait" in names), (rid, names)
 
     def test_coalesce_links_leader_and_followers(self, run):
-        mirrored = [s for tr in run["traces"].values() for s in tr.spans
-                    if s.name == "coalesced.dispatch"]
+        waits = [s for tr in run["traces"].values() for s in tr.spans
+                 if s.name == "coalesced.wait"]
         if not all("dispatch.device" in {s.name for s in tr.spans}
                    for tr in run["traces"].values()):
-            assert mirrored, "followers must carry the mirrored leader span"
-        for sp in mirrored:
-            leader = sp.attrs["leader_request_id"]
-            assert leader in self.RIDS
-            assert "leader_span_id" in sp.attrs
+            assert waits, "followers must carry their wait on the leader"
+        for sp in waits:
+            leader = run["traces"][sp.attrs["leader_request_id"]]
+            device = [s for s in leader.spans
+                      if s.span_id == sp.attrs["leader_span_id"]]
+            assert [s.name for s in device] == ["dispatch.device"]
 
     def test_root_duration_matches_measured_e2e(self, run):
         # acceptance: the span tree accounts for the measured latency
@@ -282,6 +283,247 @@ class TestCoalescedRunTracing:
         assert n == 4
         want = sum(t.dur for t in run["traces"].values())
         assert total == pytest.approx(want, rel=0.01)
+
+
+# -- the wait before the device, live (ISSUE 37) -----------------------------
+
+class FakeEngine:
+    """What the dispatcher's queueing touches of an engine; no model."""
+
+    expander = None
+    policy = None
+    preempt_hook = None
+    model_name = "fake"
+
+    def __init__(self):
+        import types
+
+        self.family = types.SimpleNamespace(inpaint=False)
+        self.state = GenerationState()
+        self.device_started = []    # perf_counter at each device section
+
+    def _parse_controlnet_units(self, p):
+        return []
+
+    def generate_range(self, run, start, count, job):
+        from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
+            GenerationResult,
+        )
+
+        self.device_started.append(time.perf_counter())
+        return GenerationResult(parameters=run.model_dump())
+
+
+class FakeExecDispatcher(ServingDispatcher):
+    """The real queueing (groups, window, locks, spans) around a group
+    execution that runs no model: a short device section and one decoded
+    batch fetched the engine's way."""
+
+    def _execute_group(self, g):
+        import jax.numpy as jnp
+
+        from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
+            GenerationResult,
+        )
+
+        self.engine.device_started.append(time.perf_counter())
+        with obs_spans.span("vae_decode_fetch"):
+            Engine._fetch_decoded(jnp.zeros((1, 8, 8, 3), jnp.uint8))
+        time.sleep(0.02)
+        for t in g.tickets:
+            t.result = GenerationResult(parameters=t.payload.model_dump())
+
+
+def tree_of(rid):
+    tr = {t.request_id: t for t in obs_spans.TRACER.finished()}[rid]
+    return tr, {s.name: s for s in tr.spans}
+
+
+def children_of(tr, span):
+    return sorted(s.name for s in tr.spans if s.parent_id == span.span_id)
+
+
+class TestLiveWaitSpans:
+    @pytest.fixture()
+    def quiet(self):
+        """No slow marks from this machine's speed; clean stores."""
+        slow_s, obs_spans.TRACER.slow_s = obs_spans.TRACER.slow_s, 0.0
+        obs_spans.TRACER.clear()
+        METRICS.clear()
+        yield
+        obs_spans.TRACER.slow_s = slow_s
+
+    @pytest.mark.parametrize("path", ["solo", "grouped"])
+    def test_one_request_waits_live(self, quiet, bucketer, path):
+        engine = FakeEngine()
+        disp = FakeExecDispatcher(engine, bucketer=bucketer, window=0.05)
+        extra = {"enable_hr": True} if path == "solo" else {}
+        disp.submit(payload(request_id=f"live-{path}", **extra))
+        tr, by = tree_of(f"live-{path}")
+        wait = by["queue_wait"]
+        assert wait.parent_id == tr.root_id
+        assert children_of(tr, wait) == (
+            ["engine.wait"] if path == "solo"
+            else ["coalesce.window", "engine.wait"])
+        assert by["engine.wait"].attrs == {"queued": 0}
+        if path == "grouped":
+            assert by["coalesce.window"].attrs == {"window_s": 0.05}
+            assert by["coalesce.window"].dur >= 0.05
+        # the interval the after-the-fact record had: ticket creation to
+        # the start of the device section, which the histogram's sample
+        # (taken beside it) and the device section's own clock both say
+        assert wait.t0 >= tr.t0
+        assert wait.dur == pytest.approx(METRICS.avg_queue_wait(), abs=1e-3)
+        end = wait.t0 + wait.dur
+        assert end <= engine.device_started[0]
+        assert by["engine.wait"].t0 + by["engine.wait"].dur \
+            == pytest.approx(end, abs=1e-3)
+        assert by["dispatch.device"].parent_id == tr.root_id
+        assert by["dispatch.device"].t0 >= end
+
+    def test_a_follower_waits_on_its_leader(self, quiet, bucketer):
+        engine = FakeEngine()
+        disp = FakeExecDispatcher(engine, bucketer=bucketer, window=0.3)
+        threads = [threading.Thread(
+            target=disp.submit,
+            args=(payload(request_id=f"pair-{i}", seed=40 + i),))
+            for i in range(2)]
+        threads[0].start()
+        time.sleep(0.05)        # inside the leader's window
+        threads[1].start()
+        for t in threads:
+            t.join(timeout=30)
+            assert not t.is_alive()
+        (lead, by_lead), (tr, by) = tree_of("pair-0"), tree_of("pair-1")
+        assert by_lead["dispatch.device"].attrs["requests"] == 2
+        wait = by["coalesced.wait"]
+        assert wait.parent_id == tr.root_id
+        assert wait.attrs == {
+            "leader_request_id": "pair-0",
+            "leader_span_id": by_lead["dispatch.device"].span_id}
+        assert "dispatch.device" not in by and "engine.wait" not in by
+        # its queue_wait is the leader's record: it ends where the
+        # leader's own ends, and has no children
+        assert children_of(tr, by["queue_wait"]) == []
+        assert by["queue_wait"].t0 + by["queue_wait"].dur == pytest.approx(
+            by_lead["queue_wait"].t0 + by_lead["queue_wait"].dur, abs=1e-3)
+        assert children_of(lead, by_lead["queue_wait"]) \
+            == ["coalesce.window", "engine.wait"]
+        # the wait covers the dispatch that carried it
+        device = by_lead["dispatch.device"]
+        assert wait.t0 <= device.t0
+        assert wait.t0 + wait.dur >= device.t0 + device.dur
+
+    def test_a_cancelled_wait_still_closes(self, quiet, bucketer):
+        """Cancelled before dispatch: the hand-opened spans close on the
+        way out and the thread's context is the root's again."""
+        engine = FakeEngine()
+        disp = FakeExecDispatcher(engine, bucketer=bucketer, window=0.0)
+        seen = {}
+
+        def begin_request():
+            disp.cancel("live-cancel")
+
+        engine.state.begin_request = begin_request
+        with obs_spans.request("live-cancel", name="unit") as req:
+            disp.submit(payload(request_id="live-cancel", enable_hr=True))
+            seen["ctx"] = obs_spans._CURRENT.get()
+        assert seen["ctx"] == (req, req.root_id)
+        names = [s.name for s in req.spans]
+        assert names.count("queue_wait") == 1
+        assert names.count("engine.wait") == 1
+        assert "dispatch.device" not in names
+
+    def test_the_capture_holds_the_waits(self, quiet, bucketer, tmp_path):
+        import jax
+
+        engine = FakeEngine()
+        disp = FakeExecDispatcher(engine, bucketer=bucketer, window=0.02)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            disp.submit(payload(request_id="live-prof"))
+        finally:
+            jax.profiler.stop_trace()
+        tr, by = tree_of("live-prof")
+        import glob
+
+        data = jax.profiler.ProfileData.from_file(glob.glob(
+            str(tmp_path) + "/**/*.xplane.pb", recursive=True)[0])
+        found = {}
+        for plane in data.planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for event in line.events:
+                    if event.name.startswith("sdtpu:"):
+                        found[event.name[6:]] = dict(event.stats)
+        for name in ("queue_wait", "coalesce.window", "engine.wait",
+                     "decode.wait", "fetch.copy"):
+            assert found[name]["request_id"] == "live-prof", name
+            assert found[name]["span_id"] == by[name].span_id, name
+
+
+class TestSlowAgainstTheMedian:
+    @staticmethod
+    def finished(name, dur, **attrs):
+        req = obs_spans.RequestTrace("r", name, attrs)
+        req.dur = dur
+        return req
+
+    def test_synthetic_durations(self):
+        tr = obs_spans.SpanTracer(enabled=True, slow_s=30.0)
+        sdxl = dict(width=1024, height=1024, steps=30)
+        # fewer than SLOW_MIN_SAMPLES ok requests of the class: never
+        for _ in range(obs_spans.SLOW_MIN_SAMPLES):
+            assert tr.slow_detail(self.finished("txt2img", 9.0, **sdxl)) \
+                is None
+        tr.clear()
+        for i in range(obs_spans.SLOW_MIN_SAMPLES):
+            assert tr.slow_detail(
+                self.finished("txt2img", 3.2 + 0.01 * i, **sdxl)) is None
+        assert tr.slow_detail(self.finished("txt2img", 4.7, **sdxl)) is None
+        detail = tr.slow_detail(self.finished("txt2img", 6.0, **sdxl))
+        assert "median" in detail and "1024x1024" in detail
+        # a slow one does not join the window: the next is judged alike
+        assert tr.slow_detail(self.finished("txt2img", 6.0, **sdxl))
+        # another class (steps, or root name) has its own window
+        assert tr.slow_detail(self.finished(
+            "txt2img", 6.0, width=1024, height=1024, steps=60)) is None
+        assert tr.slow_detail(self.finished("img2img", 6.0, **sdxl)) is None
+        # a root without the three attrs is only held to the absolute rule
+        for dur in [0.01] * 10 + [5.0]:
+            assert tr.slow_detail(self.finished("unit", dur)) is None
+        assert "threshold" in tr.slow_detail(self.finished("unit", 31.0))
+        # the window is the last SLOW_WINDOW: a class that got slower for
+        # good stops being flagged
+        for _ in range(obs_spans.SLOW_WINDOW):
+            tr.slow_detail(self.finished("txt2img", 4.7, **sdxl))
+        assert tr.slow_detail(self.finished("txt2img", 6.0, **sdxl)) is None
+        # SDTPU_OBS_SLOW_S=0 turns slow capture off, this rule too
+        off = obs_spans.SpanTracer(enabled=True, slow_s=0.0)
+        for dur in [1.0] * 10 + [50.0]:
+            assert off.slow_detail(self.finished("txt2img", dur, **sdxl)) \
+                is None
+
+    def test_a_slow_request_is_kept_with_its_tree(self, monkeypatch):
+        obs_spans.TRACER.clear()
+        flightrec.RECORDER.clear()
+        monkeypatch.setattr(obs_spans.TRACER, "slow_s", 30.0)
+        tiny = dict(width=32, height=32, steps=2)
+        for i in range(obs_spans.SLOW_MIN_SAMPLES + 1):
+            with obs_spans.request(f"med-{i}", name="txt2img", **tiny):
+                with obs_spans.span("dispatch.device"):
+                    time.sleep(0.06 if i == obs_spans.SLOW_MIN_SAMPLES
+                               else 0.005)
+        entry = flightrec.RECORDER.dump()["entries"][-1]
+        assert entry["request_id"] == f"med-{obs_spans.SLOW_MIN_SAMPLES}"
+        assert entry["reason"] == "slow" and "median" in entry["detail"]
+        assert {e["name"] for e in entry["spans"]} \
+            == {"txt2img", "dispatch.device"}
+        assert len(flightrec.RECORDER) == 1
 
 
 # -- histogram mechanics -----------------------------------------------------

@@ -74,10 +74,10 @@ class TestTraceReport:
               dur_us=40_000.0),
             e("denoise_chunk", "aaa", 3, parent_id=2, ts=6_000.0,
               dur_us=30_000.0),
-            # request B: follower with a mirrored leader span
+            # request B: a follower's wait on its leader's dispatch
             e("txt2img", "bbb", 4, ts=1_000.0, dur_us=48_000.0),
-            e("coalesced.dispatch", "bbb", 5, parent_id=4, ts=5_000.0,
-              dur_us=40_000.0, leader_request_id="aaa"),
+            e("coalesced.wait", "bbb", 5, parent_id=4, ts=1_200.0,
+              dur_us=44_000.0, leader_request_id="aaa", leader_span_id=2),
         ], "displayTimeUnit": "ms"}
 
     def test_tree_structure_and_grouping(self, trace):
@@ -92,7 +92,7 @@ class TestTraceReport:
         # nesting depth shows in indentation: root < child < grandchild
         indents = [len(l) - len(l.lstrip()) for l in tree_a]
         assert indents[0] < indents[1] < indents[2]
-        # the mirrored leader link survives into the rendered line
+        # the link to the leader survives into the rendered line
         assert any("leader_request_id=aaa" in l
                    for l in report["requests"]["bbb"])
 

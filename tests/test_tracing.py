@@ -27,18 +27,25 @@ from test_pipeline import init_params
 #: root). ``text_encode`` and the others that existed keep their names.
 TXT2IMG_SPANS = {
     "http.read_parse": None, "http.respond": None,
-    "queue_wait": "txt2img", "dispatch.device": "txt2img",
-    "prepare": "dispatch.device", "tokenize": "prepare",
-    "text_encode": "prepare", "noise": "prepare",
+    "queue_wait": "txt2img", "coalesce.window": "queue_wait",
+    "engine.wait": "queue_wait", "dispatch.device": "txt2img",
+    "prepare": "dispatch.device", "request.plan": "prepare",
+    "tokenize": "prepare", "text_encode": "prepare", "noise": "prepare",
+    "batch.assemble": "prepare",
     "denoise_range": "dispatch.device", "denoise_chunk": "denoise_range",
+    "denoise.inputs": "denoise_range", "denoise.plan": "denoise_range",
     "chunk.enqueue": "denoise_chunk", "chunk.fence_wait": None,
     "vae_decode_dispatch": "dispatch.device",
     "vae_decode_fetch": "dispatch.device", "png_encode": None,
+    "decode.wait": "vae_decode_fetch", "fetch.copy": "vae_decode_fetch",
+    "fetch.join": "vae_decode_fetch",
+    "respond.serialize": "http.respond", "respond.write": "http.respond",
     "xla.compile": None,
 }
 IMG2IMG_SPANS = {
     "http.read_parse": None, "http.respond": None,
     "generate_range": "dispatch.device", "prepare": "generate_range",
+    "denoise.inputs": "denoise_range", "denoise.plan": "denoise_range",
     "init_image": "prepare", "png_decode": "init_image",
     "upload": "init_image", "vae_encode": "prepare", "noise": "prepare",
     "chunk.enqueue": "denoise_chunk", "png_encode": "generate_range",
@@ -226,13 +233,14 @@ class TestProfilerClock:
         assert {e[1]["request_id"] for e in mine} == {"trace-prof"}
 
     def test_every_live_span_is_annotated(self, served, host_events):
-        """All but the after-the-fact intervals (add_span: queue_wait,
-        xla.compile) are on the profiler's host plane, with the store's
-        own span ids."""
+        """All but the after-the-fact interval (add_child: xla.compile)
+        are on the profiler's host plane, with the store's own span ids:
+        the wait before the device too, since ISSUE 37."""
         annotated = {e[1]["span_id"]: e[0] for e in host_events
                      if e[1].get("request_id") == "trace-prof"}
+        assert "queue_wait" in annotated.values()
         for e in served["profiled"]:
-            if e["name"] in ("queue_wait", "xla.compile"):
+            if e["name"] == "xla.compile":
                 assert e["args"]["span_id"] not in annotated
             else:
                 assert annotated.get(e["args"]["span_id"]) == e["name"]

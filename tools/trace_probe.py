@@ -1,7 +1,7 @@
 """One run of a benchmark cell, read through the program's own tracing.
 
     python3 tools/trace_probe.py --workload <cell> --seed <n> --seconds <s>
-        --trace <0|1> [--prepared] [--tag <name>]
+        --trace <0|1> [--prepared] [--keep-slow] [--tag <name>]
 
 Runs ``benchmarks/run.py`` unchanged in this process (one process holds the
 chip) and keeps what that run reads and throws away: ``/internal/trace.json``
@@ -18,11 +18,21 @@ chip) and keeps what that run reads and throws away: ``/internal/trace.json``
 - with ``--trace 1``: ``annotated_missing`` (spans of the traced request
   that are not on a host plane as ``sdtpu:<name>`` with its id), ``gaps``
   (the device's longest idle gaps in the slice, its head and its tail, each
-  with the innermost program span over it and the spans inside it) and
-  ``op_meta`` (what the trace's event metadata says of a few ``XLA Ops``
-  events: ``tf_op`` carries the flax module path, and ``flops`` and
-  ``bytes_accessed`` ride beside it; ``ProfileData`` does not show event
-  metadata, so this reads the raw proto where ``tensorflow.tsl`` has it).
+  with the innermost program span over it and the spans inside it),
+  ``idle_by_name_ms`` and ``idle_by_class_ms`` (ALL the slice's idle time
+  by the span that owns it, a traced request: the table
+  ``benchmarks/readers/idle_by_owner.py`` makes, printed here too) and
+  ``op_meta`` (the reducer's rows of the ops with most time: ``scope``
+  carries the flax module path, ``flops`` and ``bytes`` ride beside it).
+  The proto is read through ``benchmarks/harness/xplane_proto.py``;
+- with ``--keep-slow``: ``slow``, every request the flight recorder kept as
+  ``slow`` (obs/spans.py: 1.5 x the running median of its class, or
+  ``SDTPU_OBS_SLOW_S``) as a tree whose rows end with the span's excess
+  over the median tree (its ms less the median over the window's requests
+  of the name's summed ms, shared among the name's spans), printed too;
+  and ``timeline``, every exchange's ms and the ms until the next one
+  starts, the rows that stand out printed: a window that lost time lost it
+  inside a request or between two.
 
 ``--prepared`` runs a cell of ``benchmarks/prepared.json`` (built, not
 admitted: ``refiner_img2img``) from a scratch copy of the manifest under
@@ -34,6 +44,7 @@ the gap owners into ``benchmarks/harness/trace_reduce.py``.
 from __future__ import annotations
 
 import argparse
+import collections
 import io
 import json
 import os
@@ -128,23 +139,25 @@ def xla_delta(before: dict, after: dict) -> dict:
                  > rows.get(r["fun_name"], {}).get("executables", 0)]}
 
 
-def read_xplane(path: str, traced_events: list) -> dict:
-    import jax.profiler
+def read_xplane(path: str, traced_events: list, summary: dict,
+                n_traced: int) -> dict:
+    """``summary``: what ``trace_reduce.reduce`` made of the same file."""
+    from benchmarks.harness import files, trace_reduce, xplane_proto
 
-    from benchmarks.harness import trace_reduce
-
-    profile = jax.profiler.ProfileData.from_file(path)
-    spans = []          # (start_ns, end_ns, name, request id, span id)
-    for plane in profile.planes:
-        if not plane.name.startswith("/host:"):
-            continue
+    space = xplane_proto.read_xspace(path)
+    spans = []     # (start_ns, end_ns, name, request id, span id, thread)
+    for plane in trace_reduce._host_planes(space):
+        stat = xplane_proto.stat_names(plane)
+        names = {e.key: e.value.name for e in plane.event_metadata}
         for line in plane.lines:
-            for e in line.events:
-                if e.name.startswith("sdtpu:"):
-                    stats = dict(e.stats)
-                    spans.append((e.start_ns, e.start_ns + e.duration_ns,
-                                  e.name[6:], stats.get("request_id"),
-                                  stats.get("span_id")))
+            for start, end, ev in trace_reduce._events(line):
+                name = names[ev.metadata_id]
+                if name.startswith("sdtpu:"):
+                    stats = xplane_proto.stats_of(ev, stat)
+                    spans.append((start, end, name[6:],
+                                  stats.get("request_id"),
+                                  stats.get("span_id"),
+                                  (plane.id, line.id)))
     have = {(s[3], s[4]) for s in spans}
     missing = sorted({e["name"] for e in traced_events
                       if (e["args"]["request_id"], e["args"]["span_id"])
@@ -164,60 +177,82 @@ def read_xplane(path: str, traced_events: list) -> dict:
                 "inside": [[name, ns / 1e6] for ns, name in part[:4]]}
 
     out = {"annotated_missing": missing, "annotations": len(spans),
-           "gaps": [], "op_meta": op_meta(path)}
-    devices = trace_reduce._device_ops(profile)
-    if devices:
-        ops = devices[min(devices)]
-        _, merged = trace_reduce.union_ns((s, e) for s, e, _ in ops)
+           "gaps": [], "op_meta": [
+               {"op": row["name"], "device_ms": row["seconds"] * 1e3,
+                "meta": {k: row[k] for k in ("scope", "category", "module",
+                                             "calls", "flops", "bytes",
+                                             "shape")}}
+               for row in summary["op_table"][:6]]}
+    bench = files.Bench(REPO)
+    reader = bench.load("readers", "idle_by_owner")
+    merged = reader.first_device_busy(space)
+    bounds = trace_reduce._slice_bounds(space)
+    if merged:
         idle = sorted(((b[0] - a[1], a[1], b[0])
                        for a, b in zip(merged, merged[1:])), reverse=True)
         out["gaps"] = [owner(s, e) for _, s, e in idle[:5]]
-        if spans:       # the exchange's two ends bound the slice
-            out["head"] = owner(min(s[0] for s in spans), merged[0][0])
-            out["tail"] = owner(merged[-1][1], max(s[1] for s in spans))
+    if merged and bounds:       # the load generator's marks bound the slice
+        out["head"] = owner(bounds[0], merged[0][0])
+        out["tail"] = owner(merged[-1][1], bounds[1])
+    if spans and bounds and n_traced:
+        spec = bench.read("idle_classes", "request.json")
+        by_name = reader.idle_by_name(
+            [s[:3] + s[5:] for s in spans], merged, bounds,
+            yields=tuple(spec.get("yields", ())))
+        out["idle_by_name_ms"] = dict(sorted(
+            ((k, v / 1e6 / n_traced) for k, v in by_name.items()),
+            key=lambda kv: -kv[1]))
+        by_class = out["idle_by_class_ms"] = reader.by_class(
+            out["idle_by_name_ms"], spec["classes"])
+        print(f"idle ms a traced request, by class: "
+              f"{json.dumps({k: round(v, 2) for k, v in by_class.items()})}")
+        for name, ms in out["idle_by_name_ms"].items():
+            print(f"  {ms:9.3f}  {name}")
     return out
 
 
-def op_meta(path: str, want: int = 6) -> list:
-    """Event metadata of the first device's longest ``XLA Ops`` (one entry
-    per HLO op, all its stats as text), or a note why not."""
-    try:
-        from tensorflow.tsl.profiler.protobuf import xplane_pb2
-    except ImportError as err:
-        return [{"unread": str(err)}]
-    from benchmarks.harness import trace_reduce
+def slow_entries(medians: dict) -> list:
+    """The flight recorder's ``slow`` entries, each with its tree; a row
+    ends with the span's excess over the median tree (a name that occurs
+    k times in the request is held to a k-th of the median sum)."""
+    from stable_diffusion_webui_distributed_tpu.obs import flightrec
 
-    space = xplane_pb2.XSpace()
-    with open(path, "rb") as fh:
-        space.ParseFromString(fh.read())
-    for plane in space.planes:
-        if not trace_reduce.DEVICE_PLANE.match(plane.name):
+    out = []
+    for entry in flightrec.RECORDER.dump()["entries"]:
+        if entry["reason"] != "slow":
             continue
-        names = {k: v.name for k, v in plane.stat_metadata.items()}
-        time_ps: dict = {}
-        for line in plane.lines:
-            if line.name == trace_reduce.OPS_LINE:
-                for e in line.events:
-                    time_ps[e.metadata_id] = (time_ps.get(e.metadata_id, 0)
-                                              + e.duration_ps)
-        out = []
-        for mid in sorted(time_ps, key=time_ps.get, reverse=True):
-            meta = plane.event_metadata[mid]
-            if trace_reduce.CONTAINERS.match(
-                    trace_reduce.short_name(meta.name)):
-                continue
-            stats = {}
-            for st in meta.stats:
-                value = (st.str_value or names.get(st.ref_value)
-                         or st.int64_value or st.uint64_value
-                         or st.double_value)
-                stats[names[st.metadata_id]] = str(value)[:240]
-            out.append({"op": trace_reduce.short_name(meta.name),
-                        "device_ms": time_ps[mid] / 1e9, "meta": stats})
-            if len(out) == want:
-                return out
-        return out
-    return [{"unread": "no device plane"}]
+        times = collections.Counter(e["name"] for e in entry["spans"])
+        rows = [row + [row[2] - medians.get(row[1], 0.0) / times[row[1]]]
+                for row in tree(entry["spans"])]
+        out.append({"request_id": entry["request_id"],
+                    "detail": entry["detail"], "tree": rows})
+        print(f"slow: {entry['request_id']}: {entry['detail']}")
+        for depth, name, ms, self_ms, _attrs, excess in rows:
+            print(f"  {'  ' * depth}{name}  {ms:.2f} ms  "
+                  f"(self {self_ms:.2f}, over the median {excess:+.2f})")
+    return out
+
+
+def timeline(window: dict) -> list:
+    """[[request id, ms of its exchange (its first span's start to its last
+    span's end), ms until the next exchange starts]] in time order: of a
+    window that lost time it says whether a request stretched (which the
+    recorder's rule is there to keep) or the time lies BETWEEN requests,
+    where no span of the program is alive. Printed: the medians and every
+    row more than a fifth of the median exchange over either."""
+    ends = sorted((min(e["ts"] for e in ev),
+                   max(e["ts"] + e["dur"] for e in ev), rid)
+                  for rid, ev in window.items())
+    rows = [[rid, (end - start) / 1e3, (nxt[0] - end) / 1e3]
+            for (start, end, rid), nxt in zip(ends, ends[1:])]
+    if rows:
+        took, gap = (statistics.median(r[i] for r in rows) for i in (1, 2))
+        print(f"timeline: {len(rows)} exchanges, median {took:.1f} ms, "
+              f"then {gap:.1f} ms to the next")
+        for rid, ms, after in rows:
+            if ms > 1.2 * took or after > gap + 0.2 * took:
+                print(f"  {rid}: {ms:.1f} ms, then {after:.1f} ms")
+    return rows
 
 
 def main(argv=None) -> int:
@@ -227,6 +262,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=8.0)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--prepared", action="store_true")
+    ap.add_argument("--keep-slow", action="store_true")
     ap.add_argument("--tag", default="")
     args = ap.parse_args(argv)
     root = prepared_root() if args.prepared else REPO
@@ -248,7 +284,8 @@ def main(argv=None) -> int:
 
     def keeping_reduce(path, *a, **kw):
         shutil.copy(path, os.path.join(kept, "slice.xplane.pb"))
-        return reduce(path, *a, **kw)
+        fetched["reduce"] = reduce(path, *a, **kw)
+        return fetched["reduce"]
 
     loadgen.get_json = keeping_get_json
     trace_reduce.reduce = keeping_reduce
@@ -284,7 +321,7 @@ def main(argv=None) -> int:
         n_traced = int(traced[0].split()[2]) if traced else 0
         first = [e for i in range(n_traced)
                  for e in window.get(f"w-{i}", [])]
-        out.update(read_xplane(xplane, first))
+        out.update(read_xplane(xplane, first, fetched["reduce"], n_traced))
     shutil.rmtree(kept, ignore_errors=True)
     untraced = {rid: ev for rid, ev in window.items()
                 if int(rid[2:]) >= n_traced} or window
@@ -293,6 +330,9 @@ def main(argv=None) -> int:
     if untraced:
         last = max(untraced, key=lambda rid: int(rid[2:]))
         out["tree"] = tree(untraced[last])
+    if args.keep_slow:
+        out["slow"] = slow_entries(out["span_median_ms"])
+        out["timeline"] = timeline(window)
     tag = args.tag or f"{args.workload}-{args.seed}-t{args.trace}"
     path = os.path.join(REPO, "chiprun_out", "probe", tag + ".json")
     os.makedirs(os.path.dirname(path), exist_ok=True)
