@@ -620,7 +620,19 @@ def make_added_cond(
     addition_time_embed_dim: int,
 ) -> jax.Array:
     """SDXL micro-conditioning vector: pooled text ++ fourier(time_ids)."""
+    return join_added_cond(
+        pooled_text, time_id_embedding(time_ids, addition_time_embed_dim))
+
+
+def time_id_embedding(time_ids: jax.Array,
+                      addition_time_embed_dim: int) -> jax.Array:
+    """The Fourier half of :func:`make_added_cond`, (B, ids) -> (B, ids *
+    dim) float32: a function of the ids alone, so the engine keeps it."""
     B = time_ids.shape[0]
     emb = timestep_embedding(time_ids.reshape(-1), addition_time_embed_dim)
-    emb = emb.reshape(B, -1)
+    return emb.reshape(B, -1)
+
+
+def join_added_cond(pooled_text: jax.Array, emb: jax.Array) -> jax.Array:
+    """The request's half: its pooled text before the embedded ids."""
     return jnp.concatenate([pooled_text.astype(jnp.float32), emb], axis=-1)

@@ -37,7 +37,8 @@ from stable_diffusion_webui_distributed_tpu.models.unet import (
     cache_supported,
     control_residual_count,
     deep_cache_shape,
-    make_added_cond,
+    join_added_cond,
+    time_id_embedding,
 )
 from stable_diffusion_webui_distributed_tpu.models.vae import VAE
 from stable_diffusion_webui_distributed_tpu.parallel.sharding import (
@@ -60,13 +61,29 @@ from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
 )
 from stable_diffusion_webui_distributed_tpu.obs import spans as obs_spans
 from stable_diffusion_webui_distributed_tpu.runtime import dtypes, rng, trace
+from stable_diffusion_webui_distributed_tpu.runtime.kept import KeptTable
 from stable_diffusion_webui_distributed_tpu.runtime import interrupt as interrupt_mod
 from stable_diffusion_webui_distributed_tpu.samplers import kdiffusion as kd
 from stable_diffusion_webui_distributed_tpu.samplers import schedules as sched
 from stable_diffusion_webui_distributed_tpu.serving import aot as aot_mod
 from stable_diffusion_webui_distributed_tpu.serving.metrics import (
-    METRICS, install_xla_listener,
+    METRICS, PLAN, install_xla_listener,
 )
+
+#: SDXL's embedded time ids by (ids, rows, embed dim): ``_added_cond``.
+_TIME_IDS = KeptTable(64)
+
+
+def _embedded_time_ids(ids, rows: int, dim: int):
+    """``(embedding, hit)``: the (rows, len(ids) * dim) float32 Fourier
+    embedding of ``rows`` copies of ``ids``, built by the ops every request
+    ran before it was kept."""
+    def build():
+        tid = jnp.broadcast_to(jnp.asarray([ids], jnp.float32),
+                               (rows, len(ids)))
+        return time_id_embedding(tid, dim)
+
+    return _TIME_IDS.get((tuple(ids), rows, dim), build)
 
 
 class Engine:
@@ -454,7 +471,8 @@ class Engine:
         boundaries land within one attempt of the fixed-grid step they
         correspond to — not exactly on it."""
         spec = kd.resolve_sampler(payload.sampler_name)
-        sigmas = kd.build_sigmas(spec, self.schedule, steps)
+        # the ladder's host copy: every use below reads single sigmas
+        sigmas = self._ladder(spec, steps).host
         end = steps if end_step is None else min(end_step, steps)
         if start_step >= end:
             return x
@@ -1109,12 +1127,17 @@ class Engine:
         return int(wh[0]), int(wh[1])
 
     def _added_cond(self, pooled_u, pooled_c, width, height,
-                    aesthetic_score: float = 6.0):
+                    aesthetic_score: float = 6.0, span=None):
         """SDXL micro-conditioning. The id-vector length is derived from the
         projection width: 6 ids for the base model (orig/crop/target sizes),
-        5 for the refiner (sizes + aesthetic score)."""
+        5 for the refiner (sizes + aesthetic score). ``span`` (obs/spans.py,
+        may be None) gets the attr ``added_cond``: ``none`` for a family
+        without it, else ``hit`` | ``built`` by whether the embedded ids
+        were kept."""
         ucfg = self.family.unet
         if not ucfg.addition_embed_dim:
+            if span is not None:
+                span.attrs["added_cond"] = "none"
             return None, None
         n_ids = (ucfg.projection_input_dim - ucfg.addition_embed_dim) \
             // ucfg.addition_time_embed_dim
@@ -1127,14 +1150,18 @@ class Engine:
             ids_c = [height, width, 0, 0, height, width][:n_ids]
             ids_u = ids_c
         # time-id rows track the pooled batch (per-image prompts make
-        # pooled_c (B, D) rather than (1, D))
-        tid_u = jnp.broadcast_to(jnp.asarray([ids_u], jnp.float32),
-                                 (pooled_u.shape[0], n_ids))
-        tid_c = jnp.broadcast_to(jnp.asarray([ids_c], jnp.float32),
-                                 (pooled_c.shape[0], n_ids))
-        au = make_added_cond(pooled_u, tid_u, ucfg.addition_time_embed_dim)
-        ac = make_added_cond(pooled_c, tid_c, ucfg.addition_time_embed_dim)
-        return au, ac
+        # pooled_c (B, D) rather than (1, D)). Their embedding depends on
+        # (ids, rows, embed dim) alone and is kept; the request pays the
+        # concatenation with its own pooled text.
+        dim = ucfg.addition_time_embed_dim
+        emb_u, hit_u = _embedded_time_ids(ids_u, pooled_u.shape[0], dim)
+        emb_c, hit_c = _embedded_time_ids(ids_c, pooled_c.shape[0], dim)
+        hit = hit_u and hit_c
+        PLAN.record("added_cond", hit)
+        if span is not None:
+            span.attrs["added_cond"] = "hit" if hit else "built"
+        return (join_added_cond(pooled_u, emb_u),
+                join_added_cond(pooled_c, emb_c))
 
     # -- generation ---------------------------------------------------------
 
@@ -1363,9 +1390,10 @@ class Engine:
                 end_step, inpaint_cond)
         # everything up to the first enqueue runs with the device idle:
         # these two spans own it
-        with obs_spans.span("denoise.inputs"):
+        with obs_spans.span("denoise.inputs") as inputs_span:
             (ctx_u, ctx_c) = conds
-            au, ac = self._added_cond(*pooleds, width, height)
+            au, ac = self._added_cond(*pooleds, width, height,
+                                      span=inputs_span)
             batch = x.shape[0]
             if lora is None and self._traced_lora is not None:
                 from stable_diffusion_webui_distributed_tpu.models import (
@@ -1385,7 +1413,7 @@ class Engine:
                 lora=lora_rows, ragged=ragged)
             carry = kd.init_carry(x)
             end = steps if end_step is None else min(end_step, steps)
-        with obs_spans.span("denoise.plan"):
+        with obs_spans.span("denoise.plan") as plan_span:
             # Step-cache policy (pipeline/stepcache.py): deep-feature reuse +
             # CFG truncation. Inactive (cadence 1, cutoff 0 — the default)
             # routes every chunk to the UNCHANGED plain executable, so default
@@ -1403,9 +1431,8 @@ class Engine:
             # scales are computed inside the traced fn per call (dynamic
             # per-tensor, ops/quant.py), so they never recompile anything.
             prec = precision_mod.resolve(payload, self.policy)
-            cfg_stop = stepcache.cutoff_step(
-                np.asarray(kd.build_sigmas(spec, self.schedule, steps)),
-                sc.cutoff_sigma)
+            ladder = self._ladder(spec, steps, plan_span)
+            cfg_stop = stepcache.cutoff_step(ladder.host, sc.cutoff_sigma)
             use_cache = (sc.active and cache_supported(self.family.unet)
                          and ragged is None)
             cache = valid = None
@@ -1570,9 +1597,13 @@ class Engine:
         self.state.finish()
         return carry.x
 
-    def _start_sigma(self, spec, steps):
-        sigmas = kd.build_sigmas(spec, self.schedule, steps)
-        return sigmas
+    def _ladder(self, spec, steps, span=None) -> kd.Ladder:
+        """This engine's kept sigma ladder; ``span`` (may be None) gets the
+        attr ``ladder``: ``hit`` | ``built``."""
+        ladder, hit = kd.ladder(spec, self.schedule, steps)
+        if span is not None:
+            span.attrs["ladder"] = "hit" if hit else "built"
+        return ladder
 
     # -- inpainting-model (hybrid) conditioning -----------------------------
 
@@ -1621,9 +1652,9 @@ class Engine:
         # sampled latent channels — NOT unet.in_channels, which counts the
         # mask/masked-image conditioning of inpainting checkpoints too
         C = self.family.vae.latent_channels
-        with obs_spans.span("request.plan"):
+        with obs_spans.span("request.plan") as plan_span:
             spec = kd.resolve_sampler(payload.sampler_name)
-            sigmas = kd.build_sigmas(spec, self.schedule, payload.steps)
+            sigmas = self._ladder(spec, payload.steps, plan_span).sigmas
             controls = self._prepare_controls(payload, width, height)
             refiner = self._refiner_engine(payload)
         from stable_diffusion_webui_distributed_tpu.parallel import (

@@ -23,8 +23,11 @@ from typing import Callable, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from stable_diffusion_webui_distributed_tpu.runtime.kept import KeptTable
 from stable_diffusion_webui_distributed_tpu.samplers import schedules as sched
+from stable_diffusion_webui_distributed_tpu.serving.metrics import PLAN
 
 # denoise_fn(x, sigma_scalar, step_index) -> denoised x0 prediction, same
 # shape as x. ``step_index`` lets conditioners gate by progress fraction
@@ -402,9 +405,43 @@ def run_steps(
     return carry
 
 
+class Ladder(NamedTuple):
+    """A kept sigma ladder, ``steps + 1`` float32: the device array the
+    executables close over and the host copy of the same bits (read-only),
+    for whoever searches it or reads one sigma as a Python float."""
+
+    sigmas: jax.Array
+    host: np.ndarray
+    #: the key's ``NoiseSchedule``: held so that its ``id`` stays its own
+    schedule: sched.NoiseSchedule
+
+
+#: Ladders by (schedule name, the engine's ``NoiseSchedule`` object, steps).
+#: ``NoiseSchedule`` wraps an ``ndarray`` and does not hash, so the key has
+#: the object's ``id`` and the entry holds the object.
+_LADDERS = KeptTable(64)
+
+
+def ladder(spec: SamplerSpec, schedule: sched.NoiseSchedule,
+           steps: int) -> Tuple[Ladder, bool]:
+    """``(ladder, hit)``. The first call for a key builds the ladder (the
+    schedule's own ops, one fetch); a later one runs no device op. The
+    arrays are shared by every request: index them, never donate them."""
+    def build() -> Ladder:
+        sigmas = jnp.asarray(sched.SCHEDULES[spec.schedule](schedule, steps))
+        host = np.asarray(sigmas)
+        host.setflags(write=False)
+        return Ladder(sigmas, host, schedule)
+
+    entry, hit = _LADDERS.get((spec.schedule, id(schedule), int(steps)),
+                              build)
+    PLAN.record("ladder", hit)
+    return entry, hit
+
+
 def build_sigmas(spec: SamplerSpec, schedule: sched.NoiseSchedule,
                  steps: int) -> jax.Array:
-    return jnp.asarray(sched.SCHEDULES[spec.schedule](schedule, steps))
+    return ladder(spec, schedule, steps)[0].sigmas
 
 
 # --------------------------------------------------------------------------

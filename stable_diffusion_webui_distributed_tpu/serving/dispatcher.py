@@ -1108,9 +1108,9 @@ class ServingDispatcher:
         width, height = rp.width, rp.height
         h, w = engine._latent_hw(width, height)
         C = engine.family.vae.latent_channels
-        with obs_spans.span("request.plan"):
+        with obs_spans.span("request.plan") as plan_span:
             spec = kd.resolve_sampler(rp.sampler_name)
-            sigmas = kd.build_sigmas(spec, engine.schedule, rp.steps)
+            sigmas = engine._ladder(spec, rp.steps, plan_span).sigmas
 
             engine.state.begin_request()
             engine._adaptive_incomplete = False

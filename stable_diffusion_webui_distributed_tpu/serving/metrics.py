@@ -23,7 +23,9 @@ Which attention the UNet's sites took is counted where they are traced:
 ``models/unet.py:Attention`` (and by ``models/lm.py:Attention`` for the
 resident language model's sites). What that model's ``expand`` stage did
 with tokens, experts and its cache is :data:`EXPANDER`
-(``summary()["expander"]``).
+(``summary()["expander"]``). How often a request's plan met a kept sigma
+ladder or a kept time-id embedding (runtime/kept.py) is :data:`PLAN`
+(``summary()["plan"]``).
 """
 
 from __future__ import annotations
@@ -194,6 +196,7 @@ class DispatchMetrics:
         out["xla"] = XLA.summary()    # its own lock, never under this one
         out["attention"] = ATTENTION.summary()
         out["expander"] = EXPANDER.summary()
+        out["plan"] = PLAN.summary()
         return out
 
 
@@ -498,6 +501,39 @@ class ExpanderStats:
                 "mixer_products": dict(self.mixers),
             }
 
+
+class PlanStats:
+    """Lookups of what a request's plan keeps by key (runtime/kept.py):
+    the sigma ``ladder`` (samplers/kdiffusion.py, every caller of
+    ``build_sigmas``) and SDXL's time-id embedding, ``added_cond``
+    (pipeline/engine.py). A ``build`` ran the device ops and the fetch, a
+    ``hit`` ran nothing; after a warm-up request every request of one
+    sampler, step count and size should only hit."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self.clear()
+
+    def clear(self) -> None:
+        with self._lock:
+            #: (table, "hits" | "builds") -> lookups
+            self.lookups: Dict[tuple, int] = defaultdict(int)  # guarded-by: _lock
+
+    def record(self, table: str, hit: bool) -> None:
+        with self._lock:
+            self.lookups[(table, "hits" if hit else "builds")] += 1
+
+    def summary(self) -> Dict[str, Dict[str, int]]:
+        """``{"ladder": {"hits": n, "builds": m}, "added_cond": {...}}``."""
+        with self._lock:
+            lookups = dict(self.lookups)
+        return {table: {kind: lookups.get((table, kind), 0)
+                        for kind in ("hits", "builds")}
+                for table in ("ladder", "added_cond")}
+
+
+#: Process-wide counts of kept-plan lookups (``summary()["plan"]``).
+PLAN = PlanStats()
 
 #: Process-wide prompt-expander counters (``summary()["expander"]``).
 EXPANDER = ExpanderStats()

@@ -10,6 +10,8 @@
 - one request traces ``run_chunk`` once, and nothing prices the UNet.
 """
 
+import types
+
 import numpy as np
 import pytest
 
@@ -19,25 +21,31 @@ import jax.numpy as jnp
 import quality
 from stable_diffusion_webui_distributed_tpu.models import lora as lora_mod
 from stable_diffusion_webui_distributed_tpu.models.configs import (
-    TINY, TINY_INPAINT,
+    TINY, TINY_INPAINT, TINY_REFINER, TINY_XL,
 )
 from stable_diffusion_webui_distributed_tpu.models.controlnet import (
     ControlNet,
 )
 from stable_diffusion_webui_distributed_tpu.models.unet import (
-    UNet, deep_cache_shape,
+    UNet, deep_cache_shape, make_added_cond,
 )
 from stable_diffusion_webui_distributed_tpu.obs import perf as obs_perf
+from stable_diffusion_webui_distributed_tpu.obs import spans as obs_spans
 from stable_diffusion_webui_distributed_tpu.parallel.sharding import (
     batch_concat,
 )
 from stable_diffusion_webui_distributed_tpu.pipeline import denoise as D
+from stable_diffusion_webui_distributed_tpu.pipeline import (
+    engine as engine_mod,
+)
+from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
 from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
     GenerationPayload,
 )
 from stable_diffusion_webui_distributed_tpu.samplers import kdiffusion as kd
 from stable_diffusion_webui_distributed_tpu.samplers import schedules as sched
-from stable_diffusion_webui_distributed_tpu.serving.metrics import XLA
+from stable_diffusion_webui_distributed_tpu.runtime.kept import KeptTable
+from stable_diffusion_webui_distributed_tpu.serving.metrics import PLAN, XLA
 from test_lora_traced import make_lora_sd
 
 STEPS, START, LENGTH, B, LAT = 4, 1, 2, 2, 4
@@ -350,3 +358,123 @@ def test_one_request_traces_run_chunk_once_and_prices_nothing():
     assert XLA.functions["run_chunk"]["traces"] == 1
     assert "call" not in XLA.functions
     assert len(first.images) == 1
+
+
+# -- what a request's plan keeps by key (runtime/kept.py, PR 38) -------------
+
+def _uncached_added_cond(family, pooled_u, pooled_c, width, height,
+                         score=6.0):
+    """``Engine._added_cond`` as every request ran it before it kept the
+    embedded ids."""
+    ucfg = family.unet
+    dim = ucfg.addition_time_embed_dim
+    n_ids = (ucfg.projection_input_dim - ucfg.addition_embed_dim) // dim
+    if n_ids == 5:
+        ids_c, ids_u = ([height, width, 0, 0, score],
+                        [height, width, 0, 0, 2.5])
+    else:
+        ids_c = ids_u = [height, width, 0, 0, height, width]
+    tid_u = jnp.broadcast_to(jnp.asarray([ids_u], jnp.float32),
+                             (pooled_u.shape[0], n_ids))
+    tid_c = jnp.broadcast_to(jnp.asarray([ids_c], jnp.float32),
+                             (pooled_c.shape[0], n_ids))
+    return (make_added_cond(pooled_u, tid_u, dim),
+            make_added_cond(pooled_c, tid_c, dim))
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.dtype, a.shape, a.tobytes()
+
+
+@pytest.mark.parametrize("rows", [1, 2])
+@pytest.mark.parametrize("family", [TINY_XL, TINY_REFINER],
+                         ids=["base-6-ids", "refiner-5-ids"])
+def test_kept_time_ids_give_the_uncached_added_cond_bit_for_bit(
+        family, rows):
+    engine_mod._TIME_IDS.clear()
+    this = types.SimpleNamespace(family=family)
+    width = family.unet.addition_embed_dim
+    pooled_u = _rand(11, (rows, width)).astype(jnp.bfloat16)
+    pooled_c = _rand(12, (rows, width))
+    want = _uncached_added_cond(family, pooled_u, pooled_c, 64, 96)
+    before = PLAN.summary()["added_cond"]
+    spans = [types.SimpleNamespace(attrs={}) for _ in range(3)]
+    for span in spans[:2]:
+        got = Engine._added_cond(this, pooled_u, pooled_c, 64, 96,
+                                 span=span)
+        assert [_bits(g) for g in got] == [_bits(w) for w in want]
+        assert got[0].dtype == jnp.float32
+        assert got[0].shape == (rows, family.unet.projection_input_dim)
+    # the negative half differs from the positive in the refiner alone
+    assert (_bits(want[0][:, width:]) != _bits(want[1][:, width:])) \
+        == (family is TINY_REFINER)
+    kept = len(engine_mod._TIME_IDS)
+    assert kept == (2 if family is TINY_REFINER else 1)
+    # a second size keys apart, and so does another row count
+    other = Engine._added_cond(this, pooled_u, pooled_c, 96, 64,
+                               span=spans[2])
+    assert [_bits(g) for g in other] == [_bits(w) for w in
+                                         _uncached_added_cond(
+                                             family, pooled_u, pooled_c,
+                                             96, 64)]
+    assert _bits(other[1]) != _bits(want[1])
+    assert len(engine_mod._TIME_IDS) == 2 * kept
+    assert [s.attrs["added_cond"] for s in spans] == [
+        "built", "hit", "built"]
+    after = PLAN.summary()["added_cond"]
+    assert (after["builds"] - before["builds"],
+            after["hits"] - before["hits"]) == (2, 1)
+
+
+def test_a_family_without_added_cond_says_none():
+    span = types.SimpleNamespace(attrs={})
+    this = types.SimpleNamespace(family=TINY)
+    assert Engine._added_cond(this, None, None, 64, 64, span=span) \
+        == (None, None)
+    assert span.attrs == {"added_cond": "none"}
+    assert Engine._added_cond(this, None, None, 64, 64) == (None, None)
+
+
+def _plan_attrs(req):
+    return {name: [s.attrs.get(attr) for s in req.spans if s.name == name]
+            for name, attr in (("request.plan", "ladder"),
+                               ("denoise.plan", "ladder"),
+                               ("denoise.inputs", "added_cond"))}
+
+
+@pytest.mark.parametrize("family,added", [
+    (TINY, ("none", "none")), (TINY_XL, ("built", "hit"))],
+    ids=["tiny", "tiny-xl"])
+def test_first_request_builds_the_second_hits_and_the_images_are_one(
+        family, added, monkeypatch):
+    """A fixed seed gives the same bytes from the request that builds the
+    kept ladder (and time ids), from one that meets them, and from one made
+    to build everything anew as every request did before."""
+    engine_mod._TIME_IDS.clear()
+    engine = quality.make_engine(family, chunk_size=4)   # its own schedule
+    payload = GenerationPayload(prompt="a cow", steps=5, width=32,
+                                height=32, seed=38, sampler_name="Euler a")
+    images, attrs = [], []
+    for rid in ("built", "hit"):
+        with obs_spans.request(f"rid-kept-{family.name}-{rid}") as req:
+            images.append(engine.txt2img(payload).images)
+        attrs.append(_plan_attrs(req))
+        if rid == "built":
+            kept = kd.ladder(kd.resolve_sampler("Euler a"),
+                             engine.schedule, 5)[0]
+            kept_bits = _bits(kept.sigmas), _bits(kept.host)
+    assert attrs[0] == {"request.plan": ["built"], "denoise.plan": ["hit"],
+                        "denoise.inputs": [added[0]]}
+    assert attrs[1] == {"request.plan": ["hit"], "denoise.plan": ["hit"],
+                        "denoise.inputs": [added[1]]}
+    assert images[0] == images[1] and len(images[0]) == 1
+    # nothing donated or wrote into what is kept
+    again = kd.ladder(kd.resolve_sampler("Euler a"), engine.schedule, 5)[0]
+    assert again.sigmas is kept.sigmas and again.host is kept.host
+    assert (_bits(again.sigmas), _bits(again.host)) == kept_bits
+    assert kept_bits[0] == _bits(jnp.asarray(
+        sched.default_sigmas(engine.schedule, 5)))
+    monkeypatch.setattr(KeptTable, "get",
+                        lambda self, key, build: (build(), False))
+    assert engine.txt2img(payload).images == images[0]

@@ -103,7 +103,7 @@ class TestTxt2Img:
             calls.append(1)
             return enc(*args, **kw)
 
-        monkeypatch.setattr(engine, "_encode_fn", lambda: counting)
+        monkeypatch.setattr(engine, "_encode_fn", lambda *sig: counting)
         again = engine.txt2img(p)
         assert again.images == first.images
         assert calls == []  # both cond and uncond came from the cache

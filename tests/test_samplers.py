@@ -8,10 +8,12 @@ import jax
 import jax.numpy as jnp
 
 from stable_diffusion_webui_distributed_tpu.runtime import rng
+from stable_diffusion_webui_distributed_tpu.runtime.kept import KeptTable
 from stable_diffusion_webui_distributed_tpu.samplers import (
     kdiffusion as kd,
     schedules as sched,
 )
+from stable_diffusion_webui_distributed_tpu.serving.metrics import PLAN
 
 SCHEDULE = sched.sd_schedule()
 
@@ -244,3 +246,113 @@ class TestChunking:
         for lo, hi in [(0, 3), (3, 7), (7, 10)]:
             c = kd.run_steps(step, c, lo, hi)
         np.testing.assert_array_equal(np.asarray(whole.x), np.asarray(c.x))
+
+
+def fresh_ladder(spec, schedule, steps):
+    """The ladder as every request built it before it was kept."""
+    return jnp.asarray(sched.SCHEDULES[spec.schedule](schedule, steps))
+
+
+def bits(a):
+    a = np.asarray(a)
+    return a.dtype, a.shape, a.tobytes()
+
+
+class TestKeptLadder:
+    """samplers/kdiffusion.py ``ladder``: built once per (schedule name,
+    NoiseSchedule object, steps) by the code that built it every request,
+    then served with no device op."""
+
+    @pytest.mark.parametrize("steps", [1, 20, 30])
+    @pytest.mark.parametrize("name", sorted(kd.SAMPLERS))
+    def test_kept_is_a_fresh_build_bit_for_bit(self, name, steps):
+        spec = kd.resolve_sampler(name)
+        kept, _ = kd.ladder(spec, SCHEDULE, steps)
+        fresh = bits(fresh_ladder(spec, SCHEDULE, steps))
+        assert fresh[0] == np.float32 and fresh[1] == (steps + 1,)
+        assert bits(kept.sigmas) == fresh
+        assert bits(kept.host) == fresh
+        assert not kept.host.flags.writeable
+        assert kd.build_sigmas(spec, SCHEDULE, steps) is kept.sigmas
+
+    @pytest.mark.parametrize("name", ["Euler a", "DPM++ 2M Karras", "DDIM",
+                                      "DPM fast"])
+    def test_second_call_is_the_same_objects_and_builds_nothing(
+            self, name, monkeypatch):
+        spec = kd.resolve_sampler(name)
+        schedule = sched.sd_schedule()
+        before = PLAN.summary()["ladder"]
+        first, hit = kd.ladder(spec, schedule, 17)
+        assert not hit
+
+        def boom(*a, **k):
+            raise AssertionError("a kept ladder was built again")
+
+        for key in sched.SCHEDULES:
+            monkeypatch.setitem(sched.SCHEDULES, key, boom)
+        monkeypatch.setattr(sched.NoiseSchedule, "t_to_sigma", boom)
+        second, hit = kd.ladder(spec, schedule, 17)
+        assert hit
+        assert second.sigmas is first.sigmas and second.host is first.host
+        assert kd.build_sigmas(spec, schedule, 17) is first.sigmas
+        after = PLAN.summary()["ladder"]
+        assert after["builds"] - before["builds"] == 1
+        assert after["hits"] - before["hits"] == 2
+
+    def test_two_noise_schedules_do_not_share_an_entry(self):
+        spec = kd.resolve_sampler("Euler a")
+        a, b = sched.sd_schedule(), sched.sd_schedule(beta_end=0.02)
+        twin = sched.sd_schedule()          # a's numbers, another object
+        (la, hit_a), (lb, hit_b), (lt, hit_t) = (
+            kd.ladder(spec, s, 9) for s in (a, b, twin))
+        assert (hit_a, hit_b, hit_t) == (False, False, False)
+        assert la.schedule is a and lb.schedule is b and lt.schedule is twin
+        assert bits(la.host) != bits(lb.host)
+        assert bits(lt.host) == bits(la.host) and lt.host is not la.host
+        assert bits(lb.sigmas) == bits(fresh_ladder(spec, b, 9))
+        # and the step count and the schedule's name key apart too
+        assert kd.ladder(spec, a, 10)[1] is False
+        assert kd.ladder(kd.resolve_sampler("Euler a Karras"), a, 9)[1] \
+            is False
+        assert kd.ladder(spec, a, 9) == (la, True)
+
+    def test_two_threads_build_one_new_key_once(self, monkeypatch):
+        import threading
+        import time
+
+        built = []
+        real = sched.SCHEDULES["default"]
+
+        def slow(schedule, steps):
+            built.append(steps)
+            time.sleep(0.05)
+            return real(schedule, steps)
+
+        monkeypatch.setitem(sched.SCHEDULES, "default", slow)
+        spec, schedule = kd.resolve_sampler("Euler a"), sched.sd_schedule()
+        gate, got = threading.Barrier(2), []
+
+        def ask():
+            gate.wait()
+            got.append(kd.ladder(spec, schedule, 13))
+
+        threads = [threading.Thread(target=ask) for _ in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        assert built == [13]
+        assert sorted(hit for _, hit in got) == [False, True]
+        assert got[0][0].sigmas is got[1][0].sigmas
+
+    @pytest.mark.parametrize("limit", [2, 3])
+    def test_table_is_bounded_and_drops_the_least_recently_used(
+            self, limit):
+        table = KeptTable(limit)
+        for k in range(limit):
+            assert table.get(k, lambda k=k: [k]) == ([k], False)
+        assert table.get(0, list)[1] is True        # 0 is now the newest
+        assert table.get("new", list) == ([], False)
+        assert len(table) == limit
+        assert table.get(0, list)[1] is True
+        assert table.get(1, list) == ([], False)    # 1 went, and is rebuilt

@@ -13,6 +13,11 @@ chip) and keeps what that run reads and throws away: ``/internal/trace.json``
 - ``span_median_ms``: per span name the median over the window's requests
   of that span's summed milliseconds in a request, and ``tree``: the last
   window request as a tree with self times (duration minus children);
+- ``plan_attrs``: over the window's requests, how many ``request.plan`` /
+  ``denoise.plan`` spans read ``ladder`` hit or built and how many
+  ``denoise.inputs`` read ``added_cond`` hit, built or none, and
+  ``plan_counts``: ``serving.plan`` of the last ``/internal/status`` (PR
+  38: after the warm-up request everything should read hit);
 - ``xla_setup``: ``serving.xla`` as read after warm-up (totals and the ten
   functions with most seconds), ``xla_window``: what the window added;
 - with ``--trace 1``: ``annotated_missing`` (spans of the traced request
@@ -106,6 +111,21 @@ def span_medians(requests: dict) -> dict:
         for name, ms in per.items():
             sums.setdefault(name, []).append(ms)
     return {name: statistics.median(v) for name, v in sorted(sums.items())}
+
+
+def attr_counts(requests: dict, attrs: tuple) -> dict:
+    """{"<span>.<attr>": {value: spans}} over the requests, for the attrs
+    that say which way a span went (``ladder``, ``added_cond``: hit, built
+    or none; PR 38)."""
+    out: dict = {}
+    for events in requests.values():
+        for e in events:
+            for attr in attrs:
+                if attr in e["args"]:
+                    row = out.setdefault(f"{e['name']}.{attr}", {})
+                    value = str(e["args"][attr])
+                    row[value] = row.get(value, 0) + 1
+    return out
 
 
 def tree(events: list) -> list:
@@ -327,6 +347,11 @@ def main(argv=None) -> int:
                 if int(rid[2:]) >= n_traced} or window
     out["requests"] = len(untraced)
     out["span_median_ms"] = span_medians(untraced)
+    out["plan_attrs"] = attr_counts(window, ("ladder", "added_cond"))
+    out["plan_counts"] = (statuses[-1].get("serving") or {}).get("plan") \
+        if statuses else None
+    print(f"plan: {json.dumps(out['plan_attrs'])} "
+          f"{json.dumps(out['plan_counts'])}")
     if untraced:
         last = max(untraced, key=lambda rid: int(rid[2:]))
         out["tree"] = tree(untraced[last])
