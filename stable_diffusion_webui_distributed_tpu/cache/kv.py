@@ -1,7 +1,7 @@
 """Per-layer state of the resident language model (models/lm.py), and the
 instruction prefix's state kept across requests.
 
-One manager holds four kinds of layer. A FULL layer keeps every position,
+One manager holds five kinds of layer. A FULL layer keeps every position,
 so its key and value buffers are as long as the sequence may get; lengths
 are bucketed (:data:`CAPACITY_STEP`) so that one executable serves every
 request of a traffic mix. A SLIDING layer only ever attends the last
@@ -10,7 +10,8 @@ whatever the length. A LINEAR layer has no positions: its recurrent state
 and the convolution's last inputs are of one size at every length. A LATENT
 layer keeps every position as a FULL one does, in one buffer whose row is
 the position's latent and its one rotated key, not every head's keys and
-values.
+values. A CONV layer has no positions either and no recurrence: all it
+keeps is the last ``taps - 1`` inputs of its convolution.
 
 The expander's requests all begin with the operator's instruction text.
 What its layers hold after the prefix's LAST token (every kind) is computed
@@ -56,8 +57,9 @@ def capacity_for(positions: int) -> int:
 def state_bytes(config, capacity: int, dtype) -> Dict[str, int]:
     """Bytes one sequence's cache takes at ``capacity``, by layer kind,
     from the shapes: keys, values and latents in ``dtype``, a linear
-    layer's state and kept inputs in float32. Full and sliding are always
-    named; linear and latent where the model has such layers."""
+    layer's state and a linear or conv layer's kept inputs in float32.
+    Full and sliding are always named; linear, latent and conv where the
+    model has such layers."""
     shapes = {name: iter(rows)
               for name, rows in lm.cache_shapes(config, capacity).items()}
     out = {lm.FULL: 0, lm.SLIDING: 0}
@@ -114,16 +116,17 @@ class KVCacheManager:
 
     def positions_in_use(self, length: int) -> Dict[str, int]:
         """Cache positions a sequence of ``length`` occupies, by layer
-        kind, summed over the layers of the kind; a linear layer uses
-        none at any length, a latent layer one a position."""
+        kind, summed over the layers of the kind; a linear or a conv
+        layer uses none at any length, a latent layer one a position."""
         cfg = self.config
         out = {
             lm.FULL: len(cfg.layers_of(lm.FULL)) * length,
             lm.SLIDING: len(cfg.layers_of(lm.SLIDING))
             * min(length, cfg.sliding_window),
         }
-        if lm.LINEAR in cfg.layer_types:
-            out[lm.LINEAR] = 0
+        for kind in (lm.LINEAR, lm.CONV):
+            if kind in cfg.layer_types:
+                out[kind] = 0
         if lm.LATENT in cfg.layer_types:
             out[lm.LATENT] = len(cfg.layers_of(lm.LATENT)) * length
         return out

@@ -94,11 +94,13 @@ class LMConfig:
     """A decoder-only language model read from lists: the kind of every
     layer's token mixer (``"full"`` or ``"sliding"`` attention over cached
     keys and values, ``"linear"``: a gated delta rule over a recurrent
-    state of fixed size behind a short causal convolution, or ``"latent"``:
+    state of fixed size behind a short causal convolution, ``"latent"``:
     attention over one cached low-rank latent and one rotated key a
-    position, shared by every head), its query
-    heads (an entry of a linear layer is not read), which layers have a
-    dense MLP and which a router over experts. ``num_experts``
+    position, shared by every head, or ``"conv"``: a gated causal
+    convolution of ``conv_taps`` taps over the hidden channels, whose whole
+    state is its last ``conv_taps - 1`` inputs), its query
+    heads (an entry of a linear or conv layer is not read), which layers
+    have a dense MLP and which a router over experts. ``num_experts``
     is the router's width (all of the layer's experts); ``experts_held`` and
     ``vocab_held`` are the contiguous ranges ``(first, count)`` of experts
     and of vocabulary ids whose weights THIS chip holds (``None``: all).
@@ -106,16 +108,19 @@ class LMConfig:
     ``attn_gate`` says where an attention layer's output gate comes from:
     ``"head"`` is one sigmoid a query head from its own ``g_proj``,
     ``"element"`` one a channel, the second half of every head's
-    ``q_proj`` columns. ``qk_norm`` puts an RMS norm over each head's query
+    ``q_proj`` columns, ``"none"`` no gate at all. ``qk_norm`` puts an RMS norm over each head's query
     and key before the rotation. ``zero_centred_norm`` makes every norm
     ``x_hat * (1 + weight)`` instead of ``x_hat * scale``.
     ``shared_expert_gate`` multiplies the shared expert by
-    ``sigmoid(w_s^T n)``. The defaults of these four are the ungated,
-    un-normed forms.
+    ``sigmoid(w_s^T n)``. The defaults of the last three are the ungated,
+    un-normed forms. ``shared_expert_intermediate_size`` 0 is an expert
+    layer with no shared expert: no such weights and no such product.
 
     ``router_scoring`` is ``"softmax"`` or ``"sigmoid"`` over the router's
     outputs; ``router_bias`` adds a learned per-expert bias to the scores
-    when the experts are CHOSEN and not when they are weighed.
+    when the experts are CHOSEN and not when they are weighed;
+    ``norm_topk_eps`` is added to the chosen scores' sum before they are
+    divided by it.
     ``residual_streams`` over 1 replaces ``x + F(norm(x))`` by that many
     streams a token, read, written and mixed per token by a mixer around
     every sublayer (models/lm.py:StreamMixer), whose mixing matrix is made
@@ -152,6 +157,8 @@ class LMConfig:
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
     linear_conv_kernel: int = 4
+    # "conv" layers: the taps of the gated convolution (``conv_L_cache``)
+    conv_taps: int = 3
     # "latent" layers: the ranks of the query's and the cache's low-rank
     # paths, a head's un-rotated and rotated key widths and its value
     # width; they rotate by ``rope_full`` over all of ``qk_rope_head_dim``.
@@ -164,6 +171,7 @@ class LMConfig:
     rope_mscale_all_dim: float = 0.0
     router_scoring: str = "softmax"
     router_bias: bool = False
+    norm_topk_eps: float = 0.0
     residual_streams: int = 1
     sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
@@ -577,6 +585,61 @@ TINY_LATENT_EXPAND = dataclasses.replace(
 def tiny_xing4_expander() -> ModelFamily:
     """Factory form of :data:`TINY_LATENT_EXPAND` (benchmark rehearsals)."""
     return TINY_LATENT_EXPAND
+
+
+# LFM2-24B-A2B (huggingface.co/LiquidAI/LFM2-24B-A2B config.json) at its
+# published widths: 40 layers, two leading conv layers then the pattern
+# full, conv, conv, conv; a conv layer is a gated 3-tap causal convolution
+# over the 2048 hidden channels between two projections, a full layer 32
+# query heads of width 64 over 8 KV heads with q/k norms, every dim rotated
+# and no output gate; two dense layers of width 11776 then a sigmoid router
+# with a selection bias over 64 experts of width 1536, 4 a token, weights
+# over (their sum + 1e-6), and no shared expert.
+_LFM2_PATTERN = ("conv", "conv") + ("full", "conv", "conv", "conv") * 9 \
+    + ("full", "conv")
+LFM2_24B_A2B = LMConfig(
+    vocab_size=65536, hidden_size=2048, layer_types=_LFM2_PATTERN,
+    num_heads_per_layer=(32,) * 40, num_kv_heads=8, head_dim=64,
+    rope_full=RopeConfig(theta=1e6), dense_layers=(0, 1),
+    intermediate_size=11776, num_experts=64, num_experts_per_tok=4,
+    moe_intermediate_size=1536, shared_expert_intermediate_size=0,
+    routed_scaling_factor=1.0, norm_topk_prob=True, norm_topk_eps=1e-6,
+    rms_norm_eps=1e-5, attn_gate="none", qk_norm=True, conv_taps=3,
+    router_scoring="sigmoid", router_bias=True)
+
+
+def sd15_lfm2_expander() -> ModelFamily:
+    """SD1.5 with LFM2-24B-A2B as its resident prompt expander, cut in
+    depth alone: layers 0-9 (the first of five pipeline stages: both dense
+    conv layers and two whole periods of expert layers), every layer whole
+    (all 64 experts, all 65536 vocabulary ids)."""
+    return dataclasses.replace(
+        SD15, name="sd15-lfm2-expand",
+        expander=lm_share(LFM2_24B_A2B, layers=10, chips=1, rank=0))
+
+
+# Tiny expander of gated short-convolution layers: two dense conv layers,
+# then full, conv, conv, conv with 4 ungated q/k-normed heads over 2 KV
+# heads, 3 taps, 16 experts top-4 by biased sigmoid scores, all held, and
+# no shared expert.
+TINY_CONV_LM = LMConfig(
+    vocab_size=512, hidden_size=32,
+    layer_types=("conv", "conv", "full", "conv", "conv", "conv"),
+    num_heads_per_layer=(4,) * 6, num_kv_heads=2, head_dim=8,
+    rope_full=RopeConfig(theta=1e6), dense_layers=(0, 1),
+    intermediate_size=64, num_experts=16, num_experts_per_tok=4,
+    moe_intermediate_size=16, shared_expert_intermediate_size=0,
+    routed_scaling_factor=1.0, norm_topk_eps=1e-6, rms_norm_eps=1e-5,
+    attn_gate="none", qk_norm=True, conv_taps=3, router_scoring="sigmoid",
+    router_bias=True)
+TINY_CONV_EXPAND = dataclasses.replace(
+    TINY, name="tiny-conv-expand",
+    expander=lm_share(TINY_CONV_LM, 6, chips=1, rank=0))
+
+
+def tiny_lfm2_expander() -> ModelFamily:
+    """Factory form of :data:`TINY_CONV_EXPAND` (benchmark rehearsals)."""
+    return TINY_CONV_EXPAND
 
 
 FAMILIES = {f.name: f for f in (SD15, SD21, SD21_BASE, SDXL_BASE,

@@ -8,15 +8,15 @@ position; ``"sliding"``: over the last ``sliding_window``; ``"linear"``: a
 gated delta rule over a recurrent state, ops/delta_rule.py, behind a short
 causal convolution; ``"latent"``: attention whose cache holds one low-rank
 latent and one rotated key a position, shared by every head, in two forms
-over that one cache, :class:`LatentAttention`), how many query heads an
-attention layer has (they may differ by layer; the KV heads are shared by
+over that one cache, :class:`LatentAttention`; ``"conv"``: a gated causal
+convolution over the hidden channels, :class:`ShortConv`), how many query
+heads an attention layer has (they may differ by layer; the KV heads are shared by
 groups of them), which rotary parameterisation goes with which kind,
 whether queries and keys are normed per head, and whether the MLP is dense
-or a router over experts with one shared expert (ops/moe.py), gated or
-not. An attention output passes a sigmoid gate before ``o_proj``: one a
-head from ``g_proj``, or one a channel from the second half of
-``q_proj``'s columns
-(``LMConfig.attn_gate``). A norm is ``x_hat * scale`` or, zero-centred,
+or a router over experts (ops/moe.py) with one shared expert, gated or
+not, or with none. An attention output passes a sigmoid gate before
+``o_proj``: one a head from ``g_proj``, one a channel from the second half
+of ``q_proj``'s columns, or no gate (``LMConfig.attn_gate``). A norm is ``x_hat * scale`` or, zero-centred,
 ``x_hat * (1 + weight)``. The router's scores are a softmax or sigmoids,
 with or without a bias that chooses (ops/moe.py:route). With
 ``residual_streams`` over 1 a token is ``(streams, hidden)`` between
@@ -27,18 +27,21 @@ One call, :meth:`DecoderLM.__call__`, runs a chunk of ``T`` tokens that
 starts at position ``start`` against the cache and returns the cache with
 the chunk written: a prefill is a long chunk, a decode step a chunk of one.
 The cache (cache/kv.py) holds, per layer, the buffers of the layer's kind,
-four kinds in all: a full layer's key and value buffers hold every
+five kinds in all: a full layer's key and value buffers hold every
 position up to their capacity; a sliding layer's are rings of
 ``sliding_window`` slots, slot ``p % window`` holding position ``p``; a
 linear layer has no positions at all but the recurrent state ``(value
 heads, key width, value width)`` in float32 and the convolution's last
 ``taps - 1`` inputs, neither growing with the sequence; a latent layer has
-ONE buffer, ``capacity`` rows of the normed latent beside the rotated key.
+ONE buffer, ``capacity`` rows of the normed latent beside the rotated key;
+a conv layer has one too, the ``taps - 1`` last inputs of its convolution,
+and that is all of its state.
 A chunk may be padded: only its first ``length`` tokens are real. A padded
 row is never written to a ring and lands beyond ``end`` in a full or a
 latent buffer, where no real query sees it; in a linear layer it neither
 decays the state nor writes to it (its decay and write strength are
-masked), and the convolution keeps the last REAL rows.
+masked), and a convolution, a linear layer's or a conv layer's, keeps the
+last REAL rows.
 
 Batch 1: a prompt is one sequence.
 """
@@ -67,18 +70,22 @@ from stable_diffusion_webui_distributed_tpu.serving.metrics import (
     ATTENTION, EXPANDER,
 )
 
-FULL, SLIDING, LINEAR, LATENT = "full", "sliding", "linear", "latent"
+FULL, SLIDING, LINEAR, LATENT, CONV = (
+    "full", "sliding", "linear", "latent", "conv")
 #: the cache's buffers of one layer, by the layer's kind
 ATTENTION_BUFFERS = ("k", "v")
 LINEAR_BUFFERS = ("state", "conv")
 LATENT_BUFFERS = ("latent",)
+CONV_BUFFERS = ("kept",)
+#: how a conv layer's mixer was traced: one token, or a chunk of several
+CONV_STEP, CONV_CHUNK = "step", "chunk"
 #: ops/attention.py records a latent layer's site under its form
 LATENT_ABSORBED, LATENT_EXPANDED = "latent_absorbed", "latent_expanded"
 
 
 def buffers_of(kind: str) -> Tuple[str, ...]:
-    return {LINEAR: LINEAR_BUFFERS, LATENT: LATENT_BUFFERS}.get(
-        kind, ATTENTION_BUFFERS)
+    return {LINEAR: LINEAR_BUFFERS, LATENT: LATENT_BUFFERS,
+            CONV: CONV_BUFFERS}.get(kind, ATTENTION_BUFFERS)
 
 
 def latent_form(tokens: int) -> str:
@@ -232,7 +239,8 @@ class MoE(nn.Module):
         routing = moe.route(logits, cfg.num_experts_per_tok,
                             renormalise=cfg.norm_topk_prob,
                             scale=cfg.routed_scaling_factor,
-                            scoring=cfg.router_scoring, bias=bias)
+                            scoring=cfg.router_scoring, bias=bias,
+                            eps=cfg.norm_topk_eps)
         kernels = Experts(held, cfg.moe_intermediate_size,
                           name="experts")(n.shape[-1])
         compute = [w.astype(self.dtype) for w in kernels]
@@ -240,13 +248,16 @@ class MoE(nn.Module):
             n.astype(self.dtype), routing, *compute, first=first,
             num_experts=cfg.num_experts, meshed=self.meshed)
         EXPANDER.record_product(path)
+        load, none_held = moe.load_counts(routing, first, held, valid)
+        beside = (routing.experts, load, none_held)
+        if not cfg.shared_expert_intermediate_size:     # no shared expert
+            return routed, beside
         shared = SwiGLU(cfg.shared_expert_intermediate_size, self.dtype,
                         self.quant, name="shared_expert")(n)
         if cfg.shared_expert_gate:
             shared = shared * jax.nn.sigmoid(Linear(
                 1, self.dtype, self.quant, name="shared_expert_gate")(n))
-        load, none_held = moe.load_counts(routing, first, held, valid)
-        return routed + shared, (routing.experts, load, none_held)
+        return routed + shared, beside
 
 
 class Attention(nn.Module):
@@ -312,7 +323,7 @@ class Attention(nn.Module):
         ATTENTION.record(path, tokens, keys.shape[0], dim)
         if cfg.attn_gate == "element":
             out = out.astype(jnp.float32) * jax.nn.sigmoid(gate)
-        else:
+        elif cfg.attn_gate == "head":
             gate = jax.nn.sigmoid(lin(heads, "g_proj")(n))
             out = out.astype(jnp.float32) * gate[:, :, None]
         return (lin(n.shape[-1], "o_proj")(out.reshape(tokens, heads * dim)),
@@ -551,6 +562,24 @@ def mixer_operands(params) -> dict:
     return {name: sub for name, sub in found.items() if sub}
 
 
+def causal_conv(kernel: jax.Array, kept: jax.Array, x: jax.Array, length):
+    """(convolved ``(T, channels)``, the rows to keep): the causal
+    depth-wise convolution of a chunk ``x`` ``(T, channels)`` whose first
+    ``length`` rows are real, by ``kernel`` ``(taps, channels)``, both
+    float32 (a lower-precision control hands in both in its dtype).
+    Tap ``j`` of row ``t`` reads input ``t - (taps - 1) + j``; the inputs
+    before the chunk are ``kept``, the ``taps - 1`` last real inputs, and
+    what is returned to keep are the last real ones after this chunk, in
+    ``kept``'s dtype: a padded row is never kept, and a chunk shorter than
+    ``taps - 1`` keeps older rows on. The taps' count and what follows the
+    sum (an activation, a gate) are the caller's."""
+    taps, tokens = kernel.shape[0], x.shape[0]
+    inputs = jnp.concatenate([kept.astype(kernel.dtype), x])
+    out = sum(kernel[j] * inputs[j:j + tokens] for j in range(taps))
+    return out, jax.lax.dynamic_slice_in_dim(
+        inputs, length, taps - 1, 0).astype(kept.dtype)
+
+
 def _decay_init(key, shape, dtype=jnp.float32):
     """``A_log``: the log of a decay rate drawn uniformly from (0, 16)."""
     return jnp.log(jax.random.uniform(key, shape, dtype, 1e-3, 16.0))
@@ -590,13 +619,8 @@ class DeltaMixer(nn.Module):
         a_log = self.param("A_log", _decay_init, (v_heads,)).astype(f32)
         dt_bias = self.param("dt_bias", nn.initializers.ones,
                              (v_heads,)).astype(f32)
-        # tap j of row t reads input t - (taps - 1) + j; the inputs before
-        # the chunk are the ones kept from the last real rows
-        inputs = jnp.concatenate([conv.astype(f32), qkv])
-        qkv = jax.nn.silu(sum(kernel[j] * inputs[j:j + tokens]
-                              for j in range(taps)))
-        conv = jax.lax.dynamic_slice_in_dim(
-            inputs, length, taps - 1, 0).astype(conv.dtype)
+        qkv, conv = causal_conv(kernel, conv, qkv, length)
+        qkv = jax.nn.silu(qkv)
         q, k, v = jnp.split(
             qkv, [k_heads * k_dim, 2 * k_heads * k_dim], axis=-1)
 
@@ -621,6 +645,36 @@ class DeltaMixer(nn.Module):
             out.reshape(tokens, v_heads * v_dim)), state, conv)
 
 
+class ShortConv(nn.Module):
+    """The token mixer of a ``"conv"`` layer: ``[B | C | x] = n W_in``
+    (three times the hidden width), ``u = B * x``, a causal depth-wise
+    convolution of ``conv_taps`` taps over ``u`` with no activation after
+    it, and ``(C * c) W_out``. ``kept`` holds the last ``taps - 1`` real
+    rows of ``u`` in float32, the layer's whole state: nothing of it grows
+    with the sequence. The gates and the taps are element-wise in float32,
+    as the linear kind's convolution is: ``conv_dtype`` is the
+    lower-precision control."""
+
+    config: LMConfig
+    dtype: jnp.dtype = jnp.float32
+    quant: bool = False
+    conv_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, n, length, kept):
+        cfg = self.config
+        EXPANDER.record_conv(CONV_STEP if n.shape[0] == 1 else CONV_CHUNK)
+        b, c, x = jnp.split(
+            Linear(3 * cfg.hidden_size, self.dtype, self.quant,
+                   name="in_proj")(n).astype(self.conv_dtype), 3, axis=-1)
+        kernel = self.param("conv_kernel", nn.initializers.lecun_normal(),
+                            (cfg.conv_taps, cfg.hidden_size))
+        mixed, kept = causal_conv(kernel.astype(self.conv_dtype), kept,
+                                  b * x, length)
+        return Linear(n.shape[-1], self.dtype, self.quant,
+                      name="out_proj")(c * mixed), kept
+
+
 class DecoderLayer(nn.Module):
     config: LMConfig
     layer: int
@@ -630,6 +684,8 @@ class DecoderLayer(nn.Module):
     #: lower-precision controls of a model with several residual streams
     stream_dtype: jnp.dtype = jnp.float32
     sinkhorn_dtype: jnp.dtype = jnp.float32
+    #: and of one with conv layers: their gates' and taps' products
+    conv_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x, q_pos, start, end, buffers):
@@ -645,6 +701,11 @@ class DecoderLayer(nn.Module):
                 mixed, *after = DeltaMixer(
                     cfg, self.dtype, self.quant, name="delta")(
                         n, q_pos < end, end - start, *buffers)
+            elif kind == CONV:
+                mixed, *after = ShortConv(
+                    cfg, self.dtype, self.quant, self.conv_dtype,
+                    name="short_conv")(
+                        n, end - start, *buffers)
             elif kind == LATENT:
                 mixed, *after = LatentAttention(
                     cfg, self.layer, self.dtype, self.quant, name="attn")(
@@ -705,6 +766,9 @@ class DecoderLM(nn.Module):
     #: Sinkhorn's iterations run in: float32; lower is a control
     stream_dtype: jnp.dtype = jnp.float32
     sinkhorn_dtype: jnp.dtype = jnp.float32
+    #: what a conv layer's gates and taps multiply in: float32; lower is a
+    #: control
+    conv_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, tokens, start, length, cache: Dict[str, jax.Array],
@@ -732,7 +796,7 @@ class DecoderLM(nn.Module):
             names = buffers_of(kind)
             x, buffers, r = DecoderLayer(
                 cfg, layer, self.dtype, self.quant_linears, self.meshed,
-                self.stream_dtype, self.sinkhorn_dtype,
+                self.stream_dtype, self.sinkhorn_dtype, self.conv_dtype,
                 name=f"layers_{layer}")(
                     x, q_pos, start, end,
                     tuple(cache[name][len(written[name])] for name in names))
@@ -761,8 +825,10 @@ def cache_shapes(cfg: LMConfig, capacity: int) -> Dict[str, list]:
     order. An attention layer has ``k`` and ``v``: a full layer holds
     ``capacity`` positions, a sliding layer a ring of its window. A linear
     layer has ``state`` and ``conv``, whatever the capacity. A latent layer
-    has ``latent``: ``capacity`` rows of ``latent_width``. A model without
-    layers of a kind has none of the kind's names."""
+    has ``latent``: ``capacity`` rows of ``latent_width``. A conv layer has
+    ``kept``: its convolution's ``conv_taps - 1`` last inputs, whatever the
+    capacity. A model without layers of a kind has none of the kind's
+    names."""
     rows = [(capacity if kind == FULL else cfg.sliding_window,
              cfg.num_kv_heads, cfg.head_dim)
             for kind in cfg.layer_types if kind in (FULL, SLIDING)]
@@ -777,19 +843,24 @@ def cache_shapes(cfg: LMConfig, capacity: int) -> Dict[str, list]:
     latent = len(cfg.layers_of(LATENT))
     if latent:
         shapes["latent"] = [(capacity, cfg.latent_width)] * latent
+    conv = len(cfg.layers_of(CONV))
+    if conv:
+        shapes["kept"] = [(cfg.conv_taps - 1, cfg.hidden_size)] * conv
     return shapes
 
 
 def buffer_dtype(name: str, dtype):
     """What a cache buffer holds: keys, values and latents are in the
-    cache's ``dtype``, a linear layer's state and kept convolution inputs
-    in float32."""
-    return jnp.dtype(jnp.float32 if name in LINEAR_BUFFERS else dtype)
+    cache's ``dtype``, a linear layer's state and a linear or conv
+    layer's kept convolution inputs in float32."""
+    return jnp.dtype(jnp.float32 if name in LINEAR_BUFFERS + CONV_BUFFERS
+                     else dtype)
 
 
 def empty_cache(cfg: LMConfig, capacity: int, dtype) -> Dict[str, list]:
     """Every buffer zero in its :func:`buffer_dtype` (zero is a linear
-    layer's state at position 0)."""
+    layer's state at position 0, and what a convolution reads before
+    it)."""
     return {name: [jnp.zeros(shape, buffer_dtype(name, dtype))
                    for shape in rows]
             for name, rows in cache_shapes(cfg, capacity).items()}

@@ -51,10 +51,12 @@ class Routing(NamedTuple):
 
 
 def route(logits: jax.Array, k: int, *, renormalise: bool, scale: float,
-          scoring: str = "softmax", bias: jax.Array | None = None) -> Routing:
+          scoring: str = "softmax", bias: jax.Array | None = None,
+          eps: float = 0.0) -> Routing:
     """Scores are float32 over every expert: a softmax, or (``scoring``
     ``"sigmoid"``) each expert's own sigmoid. The ``k`` largest are taken,
-    their scores optionally renormalised to sum to one, then scaled. With a
+    their scores optionally renormalised to sum to one (divided by their
+    sum plus ``eps``), then scaled. With a
     ``bias`` ``(experts,)`` the ``k`` are those with the largest ``score +
     bias``; the bias chooses and does not weigh: the weights are the
     chosen experts' scores without it."""
@@ -67,7 +69,8 @@ def route(logits: jax.Array, k: int, *, renormalise: bool, scale: float,
         _, experts = jax.lax.top_k(scores + bias, k)
         top = jnp.take_along_axis(scores, experts, axis=-1)
     if renormalise:
-        top = top / jnp.sum(top, axis=-1, keepdims=True)
+        total = jnp.sum(top, axis=-1, keepdims=True)
+        top = top / (total + eps if eps else total)
     return Routing(experts.astype(jnp.int32), top * scale)
 
 

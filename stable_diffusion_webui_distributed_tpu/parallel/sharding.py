@@ -25,6 +25,10 @@ over a state that is not split. Latent attention's low-rank projections
 (one latent a position has one head: splitting by heads would copy the
 cache to every chip), the residual streams' mixers (``attn_hc``,
 ``mlp_hc``) and the router's selection bias are whole on every chip too.
+A short-convolution mixer (module ``short_conv``: the fused in-projection
+whose columns are its two gates and its input, the taps, ``out_proj``) is
+whole as well: a split of the fused columns over ``tp`` would not fall on
+the three parts' borders.
 One chip's share (``LMConfig.experts_held``,
 ``vocab_held``) is what one position of those axes holds; the exchange that
 adds the parts exists only on a mesh that has the axis.
@@ -58,7 +62,8 @@ def tp_spec_for(path: str, ndim: int):
 
     if leaf in _EXPERT_LEAVES and module == "experts":
         return P("ep", None, None)
-    if "delta" in parts[:-1] or module in _LM_REPLICATED \
+    if "delta" in parts[:-1] or "short_conv" in parts[:-1] \
+            or module in _LM_REPLICATED \
             or leaf == "e_score_correction_bias":
         return P()
     if leaf == "embedding" and module == "embed_tokens":

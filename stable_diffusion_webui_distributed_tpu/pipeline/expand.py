@@ -18,7 +18,7 @@ same expansion on whichever worker its sub-range lands
 (scheduler/world.py), and however decoding was cut into chunks.
 
 What the layers hold after the instruction's last token (keys and values,
-latents, recurrent state, the convolution's inputs) is kept across requests
+latents, recurrent state, a convolution's inputs) is kept across requests
 as a snapshot (cache/kv.py): from the second request on only the user's
 own tokens are prefilled, against a copy of it. Every kind of state rides in
 the one cache tree, so the decode scan carries it and the executables
@@ -116,9 +116,10 @@ class PromptExpander:
             if sp is not None:
                 sp.attrs["hit"] = bool(held)
         recurrent = lm.LINEAR in self.config.layer_types
+        conv = lm.CONV in self.config.layer_types
         latent = lm.LATENT in self.config.layer_types
         routed = []       # per executable call: (load, none held)
-        masked = 0        # padded rows kept out of the recurrence
+        masked = 0        # padded rows kept out of a recurrence or kept rows
         token = None
         for ids, start, keep in ((prefix, 0, True),
                                  (user, len(prefix), False)):
@@ -127,9 +128,10 @@ class PromptExpander:
             padded = np.zeros(kv.chunk_bucket(len(ids)), np.int32)
             padded[:len(ids)] = ids
             attrs = {"tokens": len(ids), "prefix_hit": bool(held)}
-            if recurrent:     # rows masked out of the recurrence, its form
-                attrs.update(padded=len(padded) - len(ids),
-                             form=delta_rule.form(len(padded)))
+            if recurrent or conv:   # rows kept out of the layers' state
+                attrs["padded"] = len(padded) - len(ids)
+            if recurrent:           # the form its recurrence takes
+                attrs["form"] = delta_rule.form(len(padded))
             if latent:        # the form its attention takes over the cache
                 attrs["latent"] = lm.latent_form(len(padded))
             with obs_spans.span("expand.prefill", **attrs):

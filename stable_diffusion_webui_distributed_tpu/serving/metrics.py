@@ -404,7 +404,8 @@ class ExpanderStats:
     whose chosen experts is held here, the cache positions the last
     sequence occupied and the bytes its cache took by layer kind (keys and
     values of full and sliding layers, a linear layer's recurrent state and
-    kept convolution inputs, a latent layer's latents), the instruction
+    kept convolution inputs, a latent layer's latents, a conv layer's kept
+    rows), the instruction
     prefixes held as snapshots, the padded prefill rows that were masked
     out of a recurrence, and the residual streams a token has between
     sublayers with the Sinkhorn iterations each of their mixers runs (1
@@ -413,7 +414,9 @@ class ExpanderStats:
     (ops/moe.py:choose) when the model was TRACED, as :class:`AttentionSites`
     counts its sites: nothing is counted when an executable runs.
     ``mixer_products`` counts the residual streams' mixers the same way, by
-    the form ops/stream_mixer.py:choose gave them."""
+    the form ops/stream_mixer.py:choose gave them, and ``conv_mixers`` the
+    short-convolution mixers (models/lm.py:ShortConv), by whether the
+    trace was of one token (``step``) or of a longer chunk."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -438,6 +441,7 @@ class ExpanderStats:
             self.products = {"kernel": 0, "loop": 0,
                              "grouped": 0}  # guarded-by: _lock
             self.mixers = {"kernel": 0, "loop": 0}  # guarded-by: _lock
+            self.convs = {"step": 0, "chunk": 0}  # guarded-by: _lock
 
     def record_product(self, path: str) -> None:
         """One expert layer in one trace took product ``path``."""
@@ -448,6 +452,11 @@ class ExpanderStats:
         """One stream mixer in one trace took form ``path``."""
         with self._lock:
             self.mixers[path] += 1
+
+    def record_conv(self, form: str) -> None:
+        """One short-convolution mixer in one trace, of ``form``."""
+        with self._lock:
+            self.convs[form] += 1
 
     def record(self, *, prefilled: int, from_prefix: int, decoded: int,
                decode_steps: int, load, none_held: int,
@@ -499,6 +508,7 @@ class ExpanderStats:
                 "sinkhorn_iters": self.sinkhorn_iters,
                 "expert_products": dict(self.products),
                 "mixer_products": dict(self.mixers),
+                "conv_mixers": dict(self.convs),
             }
 
 

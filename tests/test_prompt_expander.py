@@ -401,20 +401,30 @@ class TestTheChosenExpertsKernel:
 
 
 class TestTheShareOfALayer:
+    @pytest.mark.parametrize("shared", [True, False])
     def test_two_halves_and_the_shared_expert_once_equal_the_uncut_layer(
-            self):
+            self, shared):
         """The reference's uncut expert layer against the sum of the two
         chips' layers: each chip's routed part, the shared expert counted
-        once, the residual once."""
-        whole = dataclasses.replace(CFG, experts_held=None, vocab_held=None)
+        once (a layer with none has no such weights and adds nothing for
+        one), the residual once."""
+        whole = dataclasses.replace(
+            CFG, experts_held=None, vocab_held=None,
+            **({} if shared else {"shared_expert_intermediate_size": 0}))
         p = lm_params(whole, seed=4)["layers_1"]
+        assert ("shared_expert" in p["mlp"]) == shared
         x = jax.random.normal(jax.random.key(9), (20, whole.hidden_size))
-        want, _ = REF.layer_forward(whole, 1, x, p)
         h = x + REF._attention(whole, 1, REF._rms(
             x, p["input_norm"]["scale"], whole.rms_norm_eps), p["attn"])
         n = REF._rms(h, p["post_attention_norm"]["scale"],
                      whole.rms_norm_eps)
-        total = h + REF._swiglu(n, p["mlp"]["shared_expert"])
+        once = REF._swiglu(n, p["mlp"]["shared_expert"]) if shared else 0.0
+        if shared:
+            want, _ = REF.layer_forward(whole, 1, x, p)
+        else:   # the reference's routed part over every expert, alone
+            want = h + REF.routed_part(
+                n, *REF.route(whole, n, p["mlp"]), p["mlp"]["experts"], 0)
+        total = h + once
         for rank in (0, 1):
             share = configs.lm_share(whole, whole.num_layers, 2, rank)
             lo, count = share.experts
@@ -422,7 +432,7 @@ class TestTheShareOfALayer:
                 k: w[lo:lo + count] for k, w in p["mlp"]["experts"].items()})
             out, _ = lm.MoE(share, jnp.float32).apply(
                 {"params": mlp}, n, jnp.ones(20, bool))
-            total = total + out - REF._swiglu(n, p["mlp"]["shared_expert"])
+            total = total + out - once
         np.testing.assert_allclose(total, want, rtol=2e-4, atol=2e-5)
 
     def test_lm_share_cuts_layers_experts_and_vocabulary(self):
@@ -654,9 +664,11 @@ class TestEnginePath:
             "expert_tokens",
             "expert_load_max_over_mean", "cache_positions", "state_bytes",
             "prefix_snapshots", "padded_rows_masked", "expert_products",
-            "mixer_products", "residual_streams", "sinkhorn_iters"}
+            "mixer_products", "conv_mixers", "residual_streams",
+            "sinkhorn_iters"}
         assert set(block["expert_products"]) == {"kernel", "loop", "grouped"}
         assert set(block["mixer_products"]) == {"kernel", "loop"}
+        assert block["conv_mixers"] == {"step": 0, "chunk": 0}
         json.dumps(block)
 
 

@@ -5,7 +5,8 @@ pipelined kernel (PERF.md section 6, PR 34).
 
 For each published expert shape (Laguna-S-2.1's 3072 x 1024 and
 Qwen3-Next's 2048 x 512, 128 of ``num_experts`` held, 10 a token; Xing4.0's
-3584 x 1024, 16 of 64 held, 4 a token; bf16) one expert layer's routed sum
+3584 x 1024, 16 of 64 held, 4 a token; LFM2's 2048 x 1536, all 64 held, 4 a
+token; bf16) one expert layer's routed sum
 runs ``--steps`` times in a device-side scan, each step on its own seeded
 draw of the token's experts among the layer's and fed the step before's
 result, once through ``ops/moe.py``'s loop and once through
@@ -31,7 +32,8 @@ OUT = os.path.join(REPO, "chiprun_out", "expert_trips.json")
 #: (name, d, f, experts of the layer, held here, chosen a token)
 SHAPES = (("laguna", 3072, 1024, 256, 128, 10),
           ("qwen3next", 2048, 512, 512, 128, 10),
-          ("xing4", 3584, 1024, 64, 16, 4))
+          ("xing4", 3584, 1024, 64, 16, 4),
+          ("lfm2", 2048, 1536, 64, 64, 4))
 REPEATS = 5
 
 
