@@ -29,13 +29,11 @@ from stable_diffusion_webui_distributed_tpu.models.configs import UNetConfig
 from stable_diffusion_webui_distributed_tpu.models.lora import (
     apply_site as _lora_site,
 )
-from stable_diffusion_webui_distributed_tpu.parallel.sharding import (
-    channel_concat,
-)
 from stable_diffusion_webui_distributed_tpu.ops.quant import (
     conv as _conv,
     linear as _linear,
 )
+from stable_diffusion_webui_distributed_tpu.ops.upsample import UpsampleConv
 from stable_diffusion_webui_distributed_tpu.serving.metrics import ATTENTION
 
 
@@ -353,10 +351,8 @@ class Upsample(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        B, H, W, C = x.shape
-        x = jax.image.resize(x, (B, H * 2, W * 2, C), method="nearest")
-        return _conv(self.quant_convs, self.channels, padding=1,
-                     dtype=self.dtype, name="conv")(x)
+        return UpsampleConv(self.channels, dtype=self.dtype,
+                            quant=self.quant_convs, name="conv")(x)
 
 
 #: Depth at which the step cache splits the UNet: levels < CACHE_SPLIT are
@@ -579,11 +575,7 @@ class UNet(nn.Module):
             ch = c.block_out_channels[level]
             depth = c.down_blocks[level]
             for i in range(c.layers_per_block + 1):
-                # channel_concat, not jnp.concatenate: under tensor
-                # parallelism the channel dim is tp-sharded and a sharded
-                # -dim concatenate mis-partitions on multi-axis meshes
-                # (parallel/sharding.py:channel_concat)
-                x = channel_concat([x, skips.pop()])
+                x = jnp.concatenate([x, skips.pop()], axis=-1)
                 x = ResBlock(ch, dtype=self.dtype,
                              quant_convs=self.quant_convs,
                              name=f"up_{level}_res_{i}")(x, temb)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from stable_diffusion_webui_distributed_tpu.models.configs import VAEConfig
 from stable_diffusion_webui_distributed_tpu.models.unet import GroupNorm32
+from stable_diffusion_webui_distributed_tpu.ops.upsample import UpsampleConv
 
 
 class VAEResBlock(nn.Module):
@@ -105,10 +106,8 @@ class Decoder(nn.Module):
                 x = VAEResBlock(ch, dtype=self.dtype,
                                 name=f"up_{level}_res_{i}")(x)
             if idx < len(c.block_out_channels) - 1:
-                B, H, W, C = x.shape
-                x = jax.image.resize(x, (B, H * 2, W * 2, C), method="nearest")
-                x = nn.Conv(ch, (3, 3), padding=1, dtype=self.dtype,
-                            name=f"up_{level}_us")(x)
+                x = UpsampleConv(ch, dtype=self.dtype,
+                                 name=f"up_{level}_us")(x)
         x = nn.silu(GroupNorm32(name="norm_out")(x))
         x = nn.Conv(c.in_channels, (3, 3), padding=1, dtype=jnp.float32,
                     name="conv_out")(x)

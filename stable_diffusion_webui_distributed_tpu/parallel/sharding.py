@@ -118,55 +118,6 @@ def shard_params(params, mesh, use_tp: bool = True):
     return jax.tree_util.tree_unflatten(treedef, placed)
 
 
-def batch_concat(parts):
-    """Concatenate equal-shaped blocks along axis 0 (the CFG [uncond; cond]
-    doubling and its conditioning rows) without ``jnp.concatenate``.
-
-    jax 0.4.x's SPMD partitioner mis-compiles a concatenate whose concat
-    dimension is sharded when the mesh carries a second axis the operands
-    do not use: each replica along that axis contributes a partial
-    concatenate that gets summed, scaling values by the axis size.
-    Minimal repro — place x with P('dp') on a ('dp','tp') mesh and
-    ``jnp.concatenate([x, x], axis=0)`` returns rows of 2*x. stack+reshape
-    expresses the identical layout through a reshape, which partitions
-    correctly on the same meshes (eager and jitted), so every batch-axis
-    concat reachable with a dp-sharded operand routes through here."""
-    import jax.numpy as jnp
-
-    parts = list(parts)
-    if len(parts) == 1:
-        return parts[0]
-    first = parts[0]
-    stacked = jnp.stack(parts, axis=0)
-    return stacked.reshape((len(parts) * first.shape[0],)
-                           + tuple(first.shape[1:]))
-
-
-def channel_concat(parts):
-    """Concatenate along the last (feature/channel) dimension without
-    ``jnp.concatenate`` — the same partitioner mis-lowering as
-    ``batch_concat`` hits here when the channel dim is tp-sharded (the
-    UNet decoder's skip concat, the SDXL dual-text-encoder context).
-    Parts may have different channel widths, so instead of stack+reshape
-    each part is zero-padded to the full output width at its own offset
-    and the padded blocks are summed; pad and add both partition
-    correctly on multi-axis meshes."""
-    import jax.numpy as jnp
-
-    parts = list(parts)
-    if len(parts) == 1:
-        return parts[0]
-    total = sum(p.shape[-1] for p in parts)
-    out = None
-    off = 0
-    for p in parts:
-        widths = [(0, 0)] * (p.ndim - 1) + [(off, total - off - p.shape[-1])]
-        padded = jnp.pad(p, widths)
-        out = padded if out is None else out + padded
-        off += p.shape[-1]
-    return out
-
-
 def place_batch(x, mesh):
     """Put a batch-major array on the mesh, axis 0 split over ``dp``."""
     from jax.sharding import NamedSharding, PartitionSpec as P

@@ -21,10 +21,6 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from stable_diffusion_webui_distributed_tpu.parallel.sharding import (
-    batch_concat,
-    channel_concat,
-)
 from stable_diffusion_webui_distributed_tpu.samplers import kdiffusion as kd
 
 
@@ -161,13 +157,10 @@ def cfg_rows(xin, t, inp: Inputs, inpaint: bool = False,
     B = xin.shape[0]
 
     def halves(u, c):
-        # batch_concat, not jnp.concatenate: the carry latent is dp-sharded
-        # under a mesh and a batch-axis concatenate mis-partitions there
-        # (parallel/sharding.py:batch_concat)
         c = jnp.broadcast_to(c, (B,) + c.shape[1:])
         if cond_only:
             return c
-        return batch_concat([jnp.broadcast_to(u, (B,) + u.shape[1:]), c])
+        return jnp.concatenate([jnp.broadcast_to(u, (B,) + u.shape[1:]), c])
 
     latent = halves(xin, xin)
     tb = jnp.full(latent.shape[:1], t, jnp.float32)
@@ -177,7 +170,7 @@ def cfg_rows(xin, t, inp: Inputs, inpaint: bool = False,
     unet_in = latent
     if inpaint:
         cond = halves(inp.inpaint_cond, inp.inpaint_cond).astype(xin.dtype)
-        unet_in = channel_concat([latent, cond])
+        unet_in = jnp.concatenate([latent, cond], axis=-1)
     return latent, unet_in, tb, ctx, added
 
 
@@ -193,7 +186,7 @@ def control_residuals(controlnet, controls, latent, tb, ctx, added, step,
                          0.0).astype(jnp.float32)
         hint_b = jnp.broadcast_to(hint, (B,) + hint.shape[1:])
         rs = controlnet.apply({"params": cn_params}, latent, tb, ctx,
-                              batch_concat([hint_b, hint_b]), added)
+                              jnp.concatenate([hint_b, hint_b]), added)
         rs = tuple(r.astype(jnp.float32) * gate for r in rs)
         residuals = rs if residuals is None else tuple(
             a + b for a, b in zip(residuals, rs))
@@ -248,12 +241,12 @@ def make_denoise(v: Variant, deps: Deps, unet_params, inp: Inputs):
     v_pred = deps.schedule.prediction_type == "v_prediction"
     # each image's adapter set rides both of its CFG rows
     lora2 = (None if inp.lora is None else jax.tree_util.tree_map(
-        lambda a: batch_concat([a, a]), inp.lora))
+        lambda a: jnp.concatenate([a, a]), inp.lora))
     ragged = {}
     if v.ragged:
         true_rows, ctx_true_u, ctx_true_c = inp.ragged
-        ragged = {"true_rows": batch_concat([true_rows, true_rows]),
-                  "ctx_true": batch_concat([ctx_true_u, ctx_true_c])}
+        ragged = {"true_rows": jnp.concatenate([true_rows, true_rows]),
+                  "ctx_true": jnp.concatenate([ctx_true_u, ctx_true_c])}
 
     def rows(xin, t, step, cond_only=False, **cache):
         latent, unet_in, tb, ctx, added = cfg_rows(
@@ -316,7 +309,7 @@ def make_step(v: Variant, deps: Deps, sigmas, unet_params, inp: Inputs):
 
             def deep_trunc(_):
                 d = rows(xin, t, i, True, cache_mode="deep")
-                return batch_concat([d, d])
+                return jnp.concatenate([d, d])
 
             return jax.lax.cond(i >= inp.cfg_stop, deep_trunc, deep_full,
                                 None).astype(cache.dtype)

@@ -41,9 +41,6 @@ from stable_diffusion_webui_distributed_tpu.models.unet import (
     time_id_embedding,
 )
 from stable_diffusion_webui_distributed_tpu.models.vae import VAE
-from stable_diffusion_webui_distributed_tpu.parallel.sharding import (
-    channel_concat,
-)
 from stable_diffusion_webui_distributed_tpu.models.tokenizer import load_tokenizer
 from stable_diffusion_webui_distributed_tpu.pipeline import (
     precision as precision_mod,
@@ -402,12 +399,9 @@ class Engine:
                         inject_values=inj_g, inject_mask=inj_mask,
                         lora=te2_lora,
                     )
-                    # channel_concat: both encoder outputs can be
-                    # tp-sharded along features under a mesh, and a
-                    # sharded-dim concatenate mis-partitions
-                    # (parallel/sharding.py:channel_concat)
-                    ctx = channel_concat(
-                        [ctx.astype(jnp.float32), ctx2.astype(jnp.float32)])
+                    ctx = jnp.concatenate(
+                        [ctx.astype(jnp.float32), ctx2.astype(jnp.float32)],
+                        axis=-1)
                     pooled = pooled2
                 ctx = ctx.astype(jnp.float32)
                 # emphasis: scale tokens, restore the chunk mean

@@ -23,7 +23,9 @@ Which attention the UNet's sites took is counted where they are traced:
 ``models/unet.py:Attention`` (and by ``models/lm.py:Attention`` for the
 resident language model's sites). What that model's ``expand`` stage did
 with tokens, experts and its cache is :data:`EXPANDER`
-(``summary()["expander"]``). How often a request's plan met a kept sigma
+(``summary()["expander"]``). Which form the UNet's and the VAE decoder's
+upsample sites took is :data:`UPSAMPLE` (``summary()["upsample"]``), fed
+by ``ops/upsample.py``. How often a request's plan met a kept sigma
 ladder or a kept time-id embedding (runtime/kept.py) is :data:`PLAN`
 (``summary()["plan"]``).
 """
@@ -195,6 +197,7 @@ class DispatchMetrics:
             }
         out["xla"] = XLA.summary()    # its own lock, never under this one
         out["attention"] = ATTENTION.summary()
+        out["upsample"] = UPSAMPLE.summary()
         out["expander"] = EXPANDER.summary()
         out["plan"] = PLAN.summary()
         return out
@@ -396,6 +399,34 @@ class AttentionSites:
         return out
 
 
+class UpsampleSites:
+    """Upsample sites (nearest-2x then a 3x3 convolution: the UNet's
+    ``up_{level}_us``, the VAE decoder's) by the form they took, counted
+    when a model is applied under a trace, never when it is initialised
+    nor when an executable runs: ``folded`` (the four 2x2 phases as one
+    convolution of the low-resolution input, ops/upsample.py) or ``plain``
+    (the 3x3 on the upsampled image: the int8 convolutions). Per trace, as
+    :class:`AttentionSites` counts."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self.clear()
+
+    def clear(self) -> None:
+        with self._lock:
+            self.sites: Dict[str, int] = defaultdict(int)  # guarded-by: _lock
+
+    def record(self, form: str) -> None:
+        with self._lock:
+            self.sites[form] += 1
+
+    def summary(self) -> Dict[str, int]:
+        """``{"folded": n, "plain": m}``."""
+        with self._lock:
+            return {form: self.sites.get(form, 0)
+                    for form in ("folded", "plain")}
+
+
 class ExpanderStats:
     """What the resident prompt expander (models/lm.py, the engine's
     ``expand`` stage) did: tokens prefilled, tokens whose cache came from
@@ -550,6 +581,9 @@ EXPANDER = ExpanderStats()
 
 #: Process-wide count of attention sites by path (fed at trace time).
 ATTENTION = AttentionSites()
+
+#: Process-wide count of upsample sites by form (fed at trace time).
+UPSAMPLE = UpsampleSites()
 
 #: Process-wide metrics instance (mirrors ``trace.STATS``).
 METRICS = DispatchMetrics()

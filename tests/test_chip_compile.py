@@ -1,6 +1,6 @@
 """The Pallas kernels (both attention kernels, the chosen experts' sum of a
-decode step, a residual-stream mixer's two), compiled by the TPU's own
-compiler.
+decode step, a residual-stream mixer's two) and one folded upsample site,
+compiled by the TPU's own compiler.
 
 Interpret mode (tests/test_ops.py, tests/test_ragged.py) checks the math;
 it cannot see what Mosaic refuses: a slice off the tiling, too much VMEM.
@@ -13,10 +13,12 @@ says the chip's compiler accepts the kernel, not that its result is right
 """
 
 import os
+import re
 import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import pytest
@@ -31,6 +33,7 @@ from stable_diffusion_webui_distributed_tpu.ops.flash_attention import (
 from stable_diffusion_webui_distributed_tpu.ops.ragged_attention import (
     _ragged_bhtd,
 )
+from stable_diffusion_webui_distributed_tpu.ops.upsample import UpsampleConv
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -64,6 +67,10 @@ def _no_compile_cache():
     yield
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+#: a gather as an HLO instruction (the text also names source frames)
+_GATHER = re.compile(r" gather\(")
 
 
 def _compiled_text(fn, *args):
@@ -118,6 +125,36 @@ def test_flash_kernel_compiles_under_highest_matmul_precision(one_chip):
             lambda q, k, v: flash_attention(q, k, v, interpret=False),
             qkv, qkv, qkv)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("batch,side,channels,dtype", [
+    (2, 64, 640, jnp.bfloat16), (1, 128, 512, jnp.float32)])
+def test_folded_upsample_site_holds_no_gather(one_chip, batch, side, channels,
+                                              dtype):
+    """SDXL's ``up_1_us`` (64² -> 128², bf16) and the VAE decoder's first
+    upsample at 1024² (float32), weights as the chip stores them: the
+    v5e's optimised HLO of a folded site convolves and never gathers,
+    where ``jax.image.resize`` ahead of the 3x3 left a gather of the
+    upsampled activation's size."""
+    def on_chip(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    x = on_chip((batch, side, side, channels), dtype)
+    variables = {"params": {
+        "kernel": on_chip((3, 3, channels, channels), jnp.bfloat16),
+        "bias": on_chip((channels,), jnp.bfloat16)}}
+    site = UpsampleConv(channels, dtype=dtype)
+    text = _compiled_text(site.apply, variables, x)
+    assert not _GATHER.search(text)
+    assert "convolution(" in text
+
+    def resized(variables, x):
+        up = jax.image.resize(x, (batch, 2 * side, 2 * side, channels),
+                              method="nearest")
+        return nn.Conv(channels, (3, 3), padding=1, dtype=dtype).apply(
+            variables, up)
+
+    assert _GATHER.search(_compiled_text(resized, variables, x))
 
 
 @pytest.mark.parametrize("precision", ["default", "highest"])

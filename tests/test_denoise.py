@@ -31,9 +31,6 @@ from stable_diffusion_webui_distributed_tpu.models.unet import (
 )
 from stable_diffusion_webui_distributed_tpu.obs import perf as obs_perf
 from stable_diffusion_webui_distributed_tpu.obs import spans as obs_spans
-from stable_diffusion_webui_distributed_tpu.parallel.sharding import (
-    batch_concat,
-)
 from stable_diffusion_webui_distributed_tpu.pipeline import denoise as D
 from stable_diffusion_webui_distributed_tpu.pipeline import (
     engine as engine_mod,
@@ -107,7 +104,7 @@ def plain_loop(v, deps, params, inp, x, length=LENGTH):
     v_pred = SCHEDULE.prediction_type == "v_prediction"
     P = {"params": params}
     lora2 = (None if inp.lora is None else jax.tree_util.tree_map(
-        lambda a: batch_concat([a, a]), inp.lora))
+        lambda a: jnp.concatenate([a, a]), inp.lora))
     for i in range(START, START + length):
         sigma = sigmas[i]
         xin, t = D.scale_in(SCHEDULE, x, sigma)
@@ -120,14 +117,14 @@ def plain_loop(v, deps, params, inp, x, length=LENGTH):
             if not valid or i % int(inp.cadence) == 0:
                 cache = deps.unet.apply(P, unet_in, tb, ctx, added,
                                         cache_mode="deep", lora=lora)
-                cache = batch_concat([cache, cache]) if trunc else cache
+                cache = jnp.concatenate([cache, cache]) if trunc else cache
                 valid = True
             kw = {"cache": cache[B:] if trunc else cache,
                   "cache_mode": "reuse"}
         if v.ragged:
             true_rows, ctx_true_u, ctx_true_c = inp.ragged
-            kw = {"true_rows": batch_concat([true_rows, true_rows]),
-                  "ctx_true": batch_concat([ctx_true_u, ctx_true_c])}
+            kw = {"true_rows": jnp.concatenate([true_rows, true_rows]),
+                  "ctx_true": jnp.concatenate([ctx_true_u, ctx_true_c])}
         residuals = D.control_residuals(
             deps.controlnet, inp.controls, latent, tb, ctx, added,
             jnp.int32(i), v.steps) if inp.controls else None

@@ -518,15 +518,15 @@ class TestInt8UnderMesh:
 
 
 class TestMeshSafeConcat:
-    """Regression guards for the SPMD partitioner concat hazard: on the
-    pinned jax 0.4.x, ``jnp.concatenate`` along a sharded dimension on a
-    mesh with a second (operand-unused) axis sums the replicas along that
-    axis into the output — rows come out scaled by the axis size. The
-    engine and the UNet route every such concat through
-    ``parallel/sharding.py``'s batch_concat/channel_concat, whose
-    stack+reshape / pad+add lowerings partition correctly. These tests pin
-    the helpers' semantics AND their correctness on sharded operands
-    (which is exactly what the raw concatenate gets wrong)."""
+    """Regression guards for the SPMD partitioner concat hazard: jax 0.4.x
+    mis-partitioned a ``jnp.concatenate`` along a sharded dimension on a
+    mesh with a second (operand-unused) axis, summing the replicas along
+    that axis into the output (rows came out scaled by the axis size), and
+    ``parallel/sharding.py`` carried stack+reshape and pad+add stand-ins for
+    it. The installed jax partitions the plain concatenate correctly, so the
+    engine and the UNet call it (the pad+add cost 30 ms an SDXL request,
+    PERF.md section 6, PR 43); these pin that it stays correct on sharded
+    operands, at the shapes the engine and the UNet concatenate."""
 
     def _dp_sharded(self, x, mesh8):
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -535,52 +535,52 @@ class TestMeshSafeConcat:
         return jax.device_put(x, NamedSharding(mesh8, spec))
 
     def test_batch_concat_matches_concatenate_semantics(self):
-        from stable_diffusion_webui_distributed_tpu.parallel.sharding import (
-            batch_concat,
+        """The CFG halves as pipeline/denoise.py:cfg_rows joins them."""
+        from stable_diffusion_webui_distributed_tpu.pipeline.denoise import (
+            Inputs, cfg_rows,
         )
 
-        a = jnp.asarray(RNG.standard_normal((4, 3, 2), np.float32))
-        b = jnp.asarray(RNG.standard_normal((4, 3, 2), np.float32))
-        got = np.asarray(batch_concat([a, b]))
-        np.testing.assert_array_equal(got, np.concatenate([a, b], axis=0))
-        assert batch_concat([a]) is a
+        x = jnp.asarray(RNG.standard_normal((2, 3, 3, 4), np.float32))
+        u = jnp.asarray(RNG.standard_normal((1, 5, 6), np.float32))
+        c = jnp.asarray(RNG.standard_normal((2, 5, 6), np.float32))
+        inp = Inputs(ctx_u=u, ctx_c=c)
+        latent, unet_in, tb, ctx, added = cfg_rows(x, 3.0, inp)
+        np.testing.assert_array_equal(latent, np.concatenate([x, x]))
+        np.testing.assert_array_equal(
+            ctx, np.concatenate([np.broadcast_to(u, c.shape), c]))
+        assert unet_in is latent and added is None and tb.shape == (4,)
 
     def test_batch_concat_dp_sharded_operand(self, mesh8):
         """The CFG [x; x] doubling with a dp-sharded latent — the exact
         shape of the TestMeshEngine dp=4,tp=2 corruption."""
-        from stable_diffusion_webui_distributed_tpu.parallel.sharding import (
-            batch_concat,
-        )
-
         x = np.asarray(RNG.standard_normal((4, 8, 8, 4), np.float32))
         xs = self._dp_sharded(jnp.asarray(x), mesh8)
         want = np.concatenate([x, x], axis=0)
-        np.testing.assert_array_equal(np.asarray(batch_concat([xs, xs])),
+        np.testing.assert_array_equal(np.asarray(jnp.concatenate([xs, xs])),
                                       want)
-        jitted = jax.jit(lambda v: batch_concat([v, v]))
+        jitted = jax.jit(lambda v: jnp.concatenate([v, v]))
         np.testing.assert_array_equal(np.asarray(jitted(xs)), want)
 
     def test_channel_concat_matches_concatenate_semantics(self):
-        from stable_diffusion_webui_distributed_tpu.parallel.sharding import (
-            channel_concat,
+        """An inpainting model's [latent, mask, masked image] channels."""
+        from stable_diffusion_webui_distributed_tpu.pipeline.denoise import (
+            Inputs, cfg_rows,
         )
 
-        a = jnp.asarray(RNG.standard_normal((2, 4, 4, 3), np.float32))
-        b = jnp.asarray(RNG.standard_normal((2, 4, 4, 5), np.float32))
-        c = jnp.asarray(RNG.standard_normal((2, 4, 4, 2), np.float32))
-        got = np.asarray(channel_concat([a, b, c]))
+        x = jnp.asarray(RNG.standard_normal((2, 4, 4, 4), np.float32))
+        cond = jnp.asarray(RNG.standard_normal((2, 4, 4, 5), np.float32))
+        ctx = jnp.zeros((2, 3, 2), jnp.float32)
+        inp = Inputs(ctx_u=ctx, ctx_c=ctx, inpaint_cond=cond)
+        _, unet_in, _, _, _ = cfg_rows(x, 1.0, inp, inpaint=True)
         np.testing.assert_array_equal(
-            got, np.concatenate([a, b, c], axis=-1))
-        assert channel_concat([a]) is a
+            unet_in, np.concatenate(
+                [np.concatenate([x, x]), np.concatenate([cond, cond])],
+                axis=-1))
 
     def test_channel_concat_tp_sharded_operands(self, mesh8):
-        """The UNet decoder's skip concat with tp-sharded channels —
-        unequal widths, so the stack trick can't apply; pad+add must."""
+        """The UNet decoder's skip concat with tp-sharded channels of
+        unequal widths."""
         from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from stable_diffusion_webui_distributed_tpu.parallel.sharding import (
-            channel_concat,
-        )
 
         a = np.asarray(RNG.standard_normal((2, 4), np.float32))
         b = np.asarray(RNG.standard_normal((2, 6), np.float32))
@@ -589,8 +589,8 @@ class TestMeshSafeConcat:
             jax.device_put(jnp.asarray(b), sh)
         want = np.concatenate([a, b], axis=-1)
         np.testing.assert_array_equal(
-            np.asarray(channel_concat([as_, bs_])), want)
-        jitted = jax.jit(lambda u, v: channel_concat([u, v]))
+            np.asarray(jnp.concatenate([as_, bs_], axis=-1)), want)
+        jitted = jax.jit(lambda u, v: jnp.concatenate([u, v], axis=-1))
         np.testing.assert_array_equal(np.asarray(jitted(as_, bs_)), want)
 
 
