@@ -1,0 +1,30 @@
+"""A quotient of two sums of counters of one block of /internal/status,
+each the counter's growth over the window (``status_after`` less
+``status_before``: after warm-up, before the window; after it), times
+``scale``. ``path`` walks down to the block; ``over`` and ``under`` name
+the counters summed above and below the line. A program whose status lacks
+the block or a counter, or a window in which the counters below the line
+did not move, gives None."""
+
+
+def _block(context: dict, status: str, path: list[str]):
+    block = context.get(status)
+    for step in path:
+        if not isinstance(block, dict) or step not in block:
+            return None
+        block = block[step]
+    return block if isinstance(block, dict) else None
+
+
+def read(context: dict, path: list[str], over: list[str],
+         under: list[str], scale: float = 1.0):
+    before = _block(context, "status_before", path)
+    after = _block(context, "status_after", path)
+    if before is None or after is None:
+        return None
+    if any(not isinstance(block.get(key), (int, float))
+           for block in (before, after) for key in over + under):
+        return None
+    grown = [sum(after[key] - before[key] for key in keys)
+             for keys in (over, under)]
+    return scale * grown[0] / grown[1] if grown[1] else None

@@ -22,6 +22,13 @@ prompt. A snapshot serves exactly the prefix it was taken after: a key
 buffer could be read up to a shorter ``end``, a recurrent state cannot be
 cut back, so the key is the whole prefix. The executables donate the cache
 they are given, so what is kept is never handed out itself.
+
+The images of one request continue one prompt, each under its own key. Where
+every layer keeps keys and values (``lm.shares_a_step``) they are decoded as
+sequences of one step: the prompt is prefilled once and its cache
+:func:`fork`-ed, every buffer copied once a sequence along a new leading
+axis, full buffers and rings alike. The instruction's rows are copied, not
+shared: every sequence writes its own ring slots from its first step on.
 """
 
 from __future__ import annotations
@@ -54,17 +61,37 @@ def capacity_for(positions: int) -> int:
     return -(-positions // CAPACITY_STEP) * CAPACITY_STEP
 
 
-def state_bytes(config, capacity: int, dtype) -> Dict[str, int]:
-    """Bytes one sequence's cache takes at ``capacity``, by layer kind,
-    from the shapes: keys, values and latents in ``dtype``, a linear
-    layer's state and a linear or conv layer's kept inputs in float32.
+#: sequences one decode executable takes: a group of ``n`` is padded up to
+#: the first of these at or over ``n`` and cut into several over the last
+SEQUENCE_BUCKETS = (1, 2, 4, 8)
+
+
+def sequence_bucket(sequences: int) -> int:
+    return next(b for b in SEQUENCE_BUCKETS
+                if b >= min(sequences, SEQUENCE_BUCKETS[-1]))
+
+
+def fork(cache: Dict, sequences: int) -> Dict:
+    """``cache`` of one sequence as that of ``sequences`` which all stand
+    where it stands: every buffer ``(sequences, ...)``, each sequence's
+    rows its own copy (traced into one executable by its caller)."""
+    return jax.tree_util.tree_map(
+        lambda x: jnp.broadcast_to(x, (sequences,) + x.shape), cache)
+
+
+def state_bytes(config, capacity: int, dtype,
+                sequences: int = 1) -> Dict[str, int]:
+    """Bytes the caches of ``sequences`` sequences take at ``capacity``,
+    by layer kind, from the shapes: keys, values and latents in ``dtype``,
+    a linear layer's state and a linear or conv layer's kept inputs in
+    float32.
     Full and sliding are always named; linear, latent and conv where the
     model has such layers."""
     shapes = {name: iter(rows)
               for name, rows in lm.cache_shapes(config, capacity).items()}
     out = {lm.FULL: 0, lm.SLIDING: 0}
     for kind in config.layer_types:
-        out[kind] = out.get(kind, 0) + sum(
+        out[kind] = out.get(kind, 0) + sequences * sum(
             math.prod(next(shapes[name]))
             * lm.buffer_dtype(name, dtype).itemsize
             for name in lm.buffers_of(kind))
@@ -114,19 +141,22 @@ class KVCacheManager:
         with self._lock:
             return len(self._prefixes)
 
-    def positions_in_use(self, length: int) -> Dict[str, int]:
-        """Cache positions a sequence of ``length`` occupies, by layer
-        kind, summed over the layers of the kind; a linear or a conv
-        layer uses none at any length, a latent layer one a position."""
+    def positions_in_use(self, length: int,
+                         sequences: int = 1) -> Dict[str, int]:
+        """Cache positions ``sequences`` sequences of ``length`` occupy,
+        by layer kind, summed over the layers of the kind; a linear or a
+        conv layer uses none at any length, a latent layer one a
+        position."""
         cfg = self.config
         out = {
-            lm.FULL: len(cfg.layers_of(lm.FULL)) * length,
+            lm.FULL: len(cfg.layers_of(lm.FULL)) * length * sequences,
             lm.SLIDING: len(cfg.layers_of(lm.SLIDING))
-            * min(length, cfg.sliding_window),
+            * min(length, cfg.sliding_window) * sequences,
         }
         for kind in (lm.LINEAR, lm.CONV):
             if kind in cfg.layer_types:
                 out[kind] = 0
         if lm.LATENT in cfg.layer_types:
-            out[lm.LATENT] = len(cfg.layers_of(lm.LATENT)) * length
+            out[lm.LATENT] = (len(cfg.layers_of(lm.LATENT)) * length
+                              * sequences)
         return out

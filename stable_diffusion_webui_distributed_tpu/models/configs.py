@@ -642,6 +642,70 @@ def tiny_lfm2_expander() -> ModelFamily:
     return TINY_CONV_EXPAND
 
 
+# Mellum2-12B-A2.5B-Instruct
+# (huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct config.json) at its
+# published widths: 28 layers in the pattern sliding, sliding, sliding,
+# full (the full layer LAST in the period), 32 query heads of width 128
+# over 4 KV heads in both kinds, no gate and no q/k norm; a window of 1024
+# under plain RoPE, the full layers under YaRN (factor 16 over 8192), one
+# theta and every dim rotated in both; every layer a softmax router over 64
+# experts of width 896, 8 a token, renormalised, with no shared expert and
+# no dense layer.
+_MELLUM2_ROPE_FULL = RopeConfig(
+    theta=5e5, factor=16.0, original_max_position=8192, beta_fast=32.0,
+    beta_slow=1.0, attention_factor=1.2772588722239782)
+MELLUM2_12B_A2_5B = LMConfig(
+    vocab_size=98304, hidden_size=2304,
+    layer_types=("sliding", "sliding", "sliding", "full") * 7,
+    num_heads_per_layer=(32,) * 28, num_kv_heads=4, head_dim=128,
+    sliding_window=1024, rope_full=_MELLUM2_ROPE_FULL,
+    rope_sliding=RopeConfig(theta=5e5), dense_layers=(),
+    intermediate_size=7168, num_experts=64, num_experts_per_tok=8,
+    moe_intermediate_size=896, shared_expert_intermediate_size=0,
+    routed_scaling_factor=1.0, norm_topk_prob=True, rms_norm_eps=1e-6,
+    attn_gate="none")
+
+
+def sd15_mellum2_expander() -> ModelFamily:
+    """SD1.5 with Mellum2-12B-A2.5B-Instruct as its resident prompt
+    expander, cut in depth alone: layers 0-7 (the first of four pipeline
+    stages of 8, 8, 8 and 4: two whole periods, six window layers and two
+    full ones), every layer whole (all 64 experts, all 98304 vocabulary
+    ids). Three periods (10.9 GB) leave too little beside SD1.5 for the
+    VAE decoder's scratch at a batch of four 512x512 images (3.8 GB)."""
+    return dataclasses.replace(
+        SD15, name="sd15-mellum2-expand",
+        expander=lm_share(MELLUM2_12B_A2_5B, layers=8, chips=1, rank=0))
+
+
+# Tiny expander of one such period: sliding, sliding, sliding, full with 4
+# ungated heads over 2 KV heads, a window of 8 under plain RoPE and YaRN
+# over an original length of 16 in the full layer (its ramp lies inside a
+# test's positions), 8 experts top-2 by a renormalised softmax, all held,
+# no shared expert, no dense layer.
+TINY_WINDOW_LM = LMConfig(
+    vocab_size=512, hidden_size=32,
+    layer_types=("sliding", "sliding", "sliding", "full"),
+    num_heads_per_layer=(4,) * 4, num_kv_heads=2, head_dim=8,
+    sliding_window=8,
+    rope_full=RopeConfig(theta=1e4, factor=4.0, original_max_position=16,
+                         beta_fast=4.0, beta_slow=1.0,
+                         attention_factor=1.1386294361119891),
+    rope_sliding=RopeConfig(theta=1e4), dense_layers=(),
+    intermediate_size=64, num_experts=8, num_experts_per_tok=2,
+    moe_intermediate_size=16, shared_expert_intermediate_size=0,
+    routed_scaling_factor=1.0, norm_topk_prob=True, rms_norm_eps=1e-6,
+    attn_gate="none")
+TINY_WINDOW_EXPAND = dataclasses.replace(
+    TINY, name="tiny-window-expand",
+    expander=lm_share(TINY_WINDOW_LM, 4, chips=1, rank=0))
+
+
+def tiny_mellum2_expander() -> ModelFamily:
+    """Factory form of :data:`TINY_WINDOW_EXPAND` (benchmark rehearsals)."""
+    return TINY_WINDOW_EXPAND
+
+
 FAMILIES = {f.name: f for f in (SD15, SD21, SD21_BASE, SDXL_BASE,
                                 SDXL_REFINER, SD15_INPAINT, SD2_INPAINT,
                                 SDXL_INPAINT, TINY, TINY_XL, TINY_REFINER,

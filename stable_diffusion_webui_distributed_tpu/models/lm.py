@@ -43,7 +43,15 @@ decays the state nor writes to it (its decay and write strength are
 masked), and a convolution, a linear layer's or a conv layer's, keeps the
 last REAL rows.
 
-Batch 1: a prompt is one sequence.
+A prefill is one sequence: a prompt. A decode step may run several
+(``sequences``: the images of one request, forked from one prefill): row
+``b`` of the chunk is then sequence ``b``'s ONE token, every sequence at the
+same position, and the buffers carry a leading sequence axis. Norms,
+projections, the router, the experts and the head take the rows as they
+take a chunk's; attention alone tells the sequences apart. That holds for
+the ``full`` and ``sliding`` kinds (:func:`shares_a_step`); a model with a
+recurrent state, kept rows, latents or several residual streams decodes one
+sequence a step.
 """
 
 from __future__ import annotations
@@ -86,6 +94,13 @@ LATENT_ABSORBED, LATENT_EXPANDED = "latent_absorbed", "latent_expanded"
 def buffers_of(kind: str) -> Tuple[str, ...]:
     return {LINEAR: LINEAR_BUFFERS, LATENT: LATENT_BUFFERS,
             CONV: CONV_BUFFERS}.get(kind, ATTENTION_BUFFERS)
+
+
+def shares_a_step(cfg: LMConfig) -> bool:
+    """Whether several sequences can be decoded in one step: every layer
+    keeps keys and values (buffers or rings) and a token is one stream."""
+    return (set(cfg.layer_types) <= {FULL, SLIDING}
+            and cfg.residual_streams == 1)
 
 
 def latent_form(tokens: int) -> str:
@@ -267,7 +282,8 @@ class Attention(nn.Module):
     quant: bool = False
 
     @nn.compact
-    def __call__(self, n, q_pos, start, end, k_cache, v_cache):
+    def __call__(self, n, q_pos, start, end, k_cache, v_cache,
+                 sequences: bool = False):
         cfg = self.config
         kind = cfg.layer_types[self.layer]
         heads = cfg.num_heads_per_layer[self.layer]
@@ -296,7 +312,34 @@ class Attention(nn.Module):
         k = apply_rope(k, cos, sin).astype(store)
         v = lin(kv * dim, "v_proj")(n).reshape(tokens, kv, dim).astype(store)
         real = q_pos < end
-        if kind == FULL:
+        if sequences:
+            # row b is sequence b's one token at ``start``, the buffers
+            # ``(B, slots, kv, dim)``. Written first in both kinds: the
+            # ring slot the token takes held position ``start - window``,
+            # which its own query no longer sees
+            window = 0 if kind == FULL else k_cache.shape[1]
+            slots = jnp.arange(k_cache.shape[1])
+            into = start % window if window else start
+            k_cache = jax.lax.dynamic_update_slice_in_dim(
+                k_cache, k[:, None], into, 1)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(
+                v_cache, v[:, None], into, 1)
+            # a slot's newest position at or before ``start``; negative:
+            # never written
+            k_pos = (start - (start - slots) % window if window
+                     else jnp.where(slots < end, slots, -1))
+            paths = set()
+
+            def one(q, keys, values):
+                out, path = attend_positions(
+                    q[None], keys, values, q_pos[:1], k_pos,
+                    scale=dim ** -0.5, window=window)
+                paths.add(path)
+                return out[0]
+
+            out = jax.vmap(one)(q, k_cache, v_cache)
+            ATTENTION.record(paths.pop(), 1, k_cache.shape[1], dim)
+        elif kind == FULL:
             # written first: a padded row lands beyond ``end``, where no
             # query looks until a real token has overwritten it
             k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k, start, 0)
@@ -318,9 +361,10 @@ class Attention(nn.Module):
                              q_pos % window, window)
             k_cache = k_cache.at[into].set(k, mode="drop")
             v_cache = v_cache.at[into].set(v, mode="drop")
-        out, path = attend_positions(q, keys, values, q_pos, k_pos,
-                                     scale=dim ** -0.5, window=window)
-        ATTENTION.record(path, tokens, keys.shape[0], dim)
+        if not sequences:
+            out, path = attend_positions(q, keys, values, q_pos, k_pos,
+                                         scale=dim ** -0.5, window=window)
+            ATTENTION.record(path, tokens, keys.shape[0], dim)
         if cfg.attn_gate == "element":
             out = out.astype(jnp.float32) * jax.nn.sigmoid(gate)
         elif cfg.attn_gate == "head":
@@ -688,19 +732,29 @@ class DecoderLayer(nn.Module):
     conv_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, x, q_pos, start, end, buffers):
+    def __call__(self, x, q_pos, start, end, buffers,
+                 sequences: bool = False, real=None):
         """``buffers`` are the layer's own of the cache (:func:`buffers_of`
         its kind), returned as the chunk leaves them. ``x`` is ``(T,
-        hidden)``, or ``(T, streams, hidden)`` with several streams."""
+        hidden)``, or ``(T, streams, hidden)`` with several streams.
+        ``sequences``: the rows are one token each of as many sequences
+        (:func:`shares_a_step`), the buffers theirs side by side, and
+        ``real`` says which rows count."""
         cfg = self.config
         kind = cfg.layer_types[self.layer]
+
+        def counted():
+            """The rows that count: a chunk's before ``end``, or those the
+            caller says (made where they are used, as they always were:
+            the order of a trace's ops is part of its executable's key)."""
+            return q_pos < end if real is None else real
 
         def token_mixer(n):
             """(mixed, the layer's buffers as the chunk leaves them)."""
             if kind == LINEAR:
                 mixed, *after = DeltaMixer(
                     cfg, self.dtype, self.quant, name="delta")(
-                        n, q_pos < end, end - start, *buffers)
+                        n, counted(), end - start, *buffers)
             elif kind == CONV:
                 mixed, *after = ShortConv(
                     cfg, self.dtype, self.quant, self.conv_dtype,
@@ -713,7 +767,8 @@ class DecoderLayer(nn.Module):
             else:
                 mixed, *after = Attention(
                     cfg, self.layer, self.dtype, self.quant, name="attn")(
-                        n, q_pos, start, end, *buffers)
+                        n, q_pos, start, end, *buffers,
+                        sequences=sequences)
             return mixed, tuple(after)
 
         def mlp(n):
@@ -722,7 +777,7 @@ class DecoderLayer(nn.Module):
                 return SwiGLU(cfg.intermediate_size, self.dtype, self.quant,
                               name="mlp")(n), None
             return MoE(cfg, self.dtype, self.quant, self.meshed,
-                       name="mlp")(n, q_pos < end)
+                       name="mlp")(n, counted())
 
         streams = cfg.residual_streams
         beside = []     # what each sublayer returns beside its output
@@ -751,7 +806,10 @@ class DecoderLM(nn.Module):
     vocabulary ids; ``start`` is the first one's position and ``length``
     how many are real. ``logits`` are float32 over
     the held slice, for every row of the chunk or (``all_logits`` False)
-    for the last real one alone. ``routed`` has, stacked over the expert
+    for the last real one alone. With ``sequences`` the ``(B,)`` tokens are
+    one each of ``B`` sequences, all at position ``start``, of which the
+    first ``length`` are real (the others pad ``B``); every buffer of
+    ``cache`` has a leading ``B`` and the logits are every sequence's. ``routed`` has, stacked over the expert
     layers, the experts every token chose ``(layers, T, k)``, the tokens
     sent to each held expert ``(layers, held)`` and the tokens none of
     whose experts is held ``(layers,)``."""
@@ -772,10 +830,19 @@ class DecoderLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, start, length, cache: Dict[str, jax.Array],
-                 all_logits: bool = True):
+                 all_logits: bool = True, sequences: bool = False):
         cfg = self.config
-        q_pos = start + jnp.arange(tokens.shape[0], dtype=jnp.int32)
-        end = start + length
+        if sequences:
+            if not shares_a_step(cfg):
+                raise ValueError("a step of several sequences wants full "
+                                 "and sliding layers and one stream")
+            q_pos = jnp.full(tokens.shape, start, jnp.int32)
+            end, all_logits = start + 1, True
+            real = jnp.arange(tokens.shape[0]) < length
+        else:
+            q_pos = start + jnp.arange(tokens.shape[0], dtype=jnp.int32)
+            end = start + length
+            real = None     # a layer's own: the rows before ``end``
         # the table is sharded over the vocabulary: an id another chip
         # holds gets nothing here (their parts are summed in a deployment)
         first, count = cfg.vocab
@@ -799,7 +866,8 @@ class DecoderLM(nn.Module):
                 self.stream_dtype, self.sinkhorn_dtype, self.conv_dtype,
                 name=f"layers_{layer}")(
                     x, q_pos, start, end,
-                    tuple(cache[name][len(written[name])] for name in names))
+                    tuple(cache[name][len(written[name])] for name in names),
+                    sequences=sequences, real=real)
             for name, buffer in zip(names, buffers):
                 written[name].append(buffer)
             if r is not None:
@@ -879,18 +947,33 @@ def sample(logits: jax.Array, key: jax.Array, position, temperature,
                              jnp.argmax(logits)).astype(jnp.int32)
 
 
-def prefill_fn(module: DecoderLM):
+def sample_each(logits: jax.Array, keys: jax.Array, position, temperature,
+                first: int = 0):
+    """:func:`sample` for each of ``keys`` ``(B,)``: from its own row of
+    ``logits`` ``(B, V)``, or every key from the one row ``(V,)``. A draw is
+    what :func:`sample` gives that key alone, whatever its place among the
+    ``B``."""
+    return jax.vmap(sample, in_axes=(0 if logits.ndim == 2 else None, 0,
+                                     None, None, None))(
+        logits, keys, position, temperature, first)
+
+
+def prefill_fn(module: DecoderLM, sequences: bool = False):
     """``expand_prefill(params, cache, tokens, start, length, key,
     temperature) -> (cache, next token, routed load, none held)``: one
-    chunk, and the token that follows its last real one."""
+    chunk, and the token that follows its last real one. With
+    ``sequences``, ``key`` is ``(B,)`` keys and the next token ``(B,)``:
+    what each key draws from the chunk's one last row, the first tokens of
+    ``B`` sequences that share the chunk."""
 
     def expand_prefill(params, cache, tokens, start, length, key,
                        temperature):
         logits, cache, routed = module.apply(
             {"params": params}, tokens, start, length, cache,
             all_logits=False)
-        token = sample(logits[0], key, start + length, temperature,
-                       module.config.vocab[0])
+        draw = sample_each if sequences else sample
+        token = draw(logits[0], key, start + length, temperature,
+                     module.config.vocab[0])
         return cache, token, routed[1], routed[2]
 
     return expand_prefill
@@ -923,5 +1006,46 @@ def decode_chunk_fn(module: DecoderLM, steps: int):
         (cache, token, position, load, none_held), made = jax.lax.scan(
             step, (cache, token, position) + zero, None, length=steps)
         return cache, token, position, made, load, none_held
+
+    return expand_decode_chunk
+
+
+def decode_sequences_fn(module: DecoderLM, steps: int):
+    """:func:`decode_chunk_fn` over ``B`` sequences a step:
+    ``expand_decode_chunk(params, cache, tokens, position, keys,
+    temperature, live) -> (cache, tokens, position, the steps' tokens
+    (steps, B), routed load, none held, experts read)``. ``tokens`` and
+    ``keys`` are ``(B,)``, every buffer of ``cache`` ``(B, ...)``, and all
+    sequences sit at the one ``position``. Only the first ``live`` count:
+    the others pad ``B`` up to a size an executable exists for, repeat a
+    live one (so they choose no expert of their own) and are left out of
+    the load. ``experts read`` ``(expert
+    layers,)`` sums over the steps the DISTINCT held experts a step's rows
+    chose (ops/moe.py:experts_read): what a step streams, however many of
+    its rows chose one."""
+
+    def expand_decode_chunk(params, cache, tokens, position, keys,
+                            temperature, live):
+        variables = {"params": params}
+        cfg = module.config
+
+        def step(carry, _):
+            cache, tokens, position, load, none_held, read = carry
+            logits, cache, routed = module.apply(
+                variables, tokens, position, live, cache, sequences=True)
+            tokens = sample_each(logits, keys, position + 1, temperature,
+                                 cfg.vocab[0])
+            return (cache, tokens, position + 1, load + routed[1],
+                    none_held + routed[2],
+                    read + moe.experts_read(routed[1])), tokens
+
+        layers = len(cfg.expert_layers)
+        zero = (jnp.zeros((layers, cfg.experts[1]), jnp.int32),
+                jnp.zeros((layers,), jnp.int32),
+                jnp.zeros((layers,), jnp.int32))
+        (cache, tokens, position, load, none_held, read), made = \
+            jax.lax.scan(step, (cache, tokens, position) + zero, None,
+                         length=steps)
+        return cache, tokens, position, made, load, none_held, read
 
     return expand_decode_chunk

@@ -204,3 +204,12 @@ def load_counts(routing: Routing, first: int, count: int, valid=None):
     rows = jnp.ones(held.shape[0], bool) if valid is None else valid
     none_held = jnp.sum(rows & ~jnp.any(held, axis=-1))
     return per_expert.astype(jnp.int32), none_held.astype(jnp.int32)
+
+
+def experts_read(per_expert: jax.Array) -> jax.Array:
+    """Distinct held experts one call's rows chose, from its
+    :func:`load_counts` ``(..., count)``: the experts whose kernels the
+    call streams. The grouped product reads an expert once however many
+    rows chose it, so a step of several sequences reads this many, not its
+    picks; a step of one token reads as many as it has picks."""
+    return jnp.sum(per_expert > 0, axis=-1).astype(jnp.int32)

@@ -1202,14 +1202,25 @@ class Engine:
                         count: int) -> None:
         """The ``expand`` stage: images [start, start+count) get their
         prompts continued by the resident language model, each keyed by
-        its own seed, so a sub-range expands exactly its share. Mutates
-        ``payload`` (generate_range's copy)."""
+        its own seed, so a sub-range expands exactly its share. The images
+        of the range whose prompt text is equal (every image of a plain
+        batch; not a prompt matrix's) form one group, the groups run in
+        the order of their first image, and a group is expanded together
+        (pipeline/expand.py:expand_batch: one prefill, every image a
+        sequence of the decode steps where the expander's layer kinds
+        allow). Mutates ``payload`` (generate_range's copy)."""
         total = payload.total_images
         prompts = list(payload.all_prompts or [payload.prompt] * total)
+        groups: dict = {}   # prompt text -> {image's key index: images}
         for i in range(start, min(start + count, len(prompts))):
-            prompts[i] = self.expander.expand(
-                prompts[i], expansion, payload.seed,
-                0 if payload.same_seed else i)
+            groups.setdefault(prompts[i], {}).setdefault(
+                0 if payload.same_seed else i, []).append(i)
+        for text, by_index in groups.items():
+            expanded = self.expander.expand_batch(
+                text, expansion, payload.seed, list(by_index))
+            for images, new in zip(by_index.values(), expanded):
+                for i in images:
+                    prompts[i] = new
         if total == 1 and not payload.all_prompts:
             payload.prompt = prompts[0]
         else:

@@ -23,11 +23,23 @@ as a snapshot (cache/kv.py): from the second request on only the user's
 own tokens are prefilled, against a copy of it. Every kind of state rides in
 the one cache tree, so the decode scan carries it and the executables
 donate it whole.
+
+The images of one request that continue the same prompt differ in their
+key alone, so where the model's kinds allow (models/lm.py:shares_a_step)
+:meth:`PromptExpander.expand_batch` decodes them as sequences of ONE step:
+the prompt is prefilled once at one sequence, each image's first token is
+drawn from that one row of logits under its own key, the cache is forked
+(cache/kv.py:fork) and the scan runs over all of them, so a step streams
+the fixed weights once and each distinct expert once. Their count is
+padded up to one of cache/kv.py:SEQUENCE_BUCKETS with repeats of the last,
+whose tokens are dropped. One image takes the one-sequence executables
+under the keys they have always had.
 """
 
 from __future__ import annotations
 
-from typing import List, Optional
+import functools
+from typing import List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -70,33 +82,73 @@ class PromptExpander:
 
     # -- executables ---------------------------------------------------------
 
-    def _prefill_fn(self, chunk: int, capacity: int):
+    def _prefill_fn(self, chunk: int, capacity: int, sequences: int = 1):
+        """``sequences`` over 1: the chunk's one sequence, and the first
+        token of each of that many that go on from it."""
+        many = sequences > 1
         return self.engine._cached(
-            ("expand_prefill", chunk, capacity),
-            lambda: jax.jit(lm.prefill_fn(self.module), donate_argnums=(1,)))
+            ("expand_prefill", chunk, capacity)
+            + ((sequences,) if many else ()),
+            lambda: jax.jit(lm.prefill_fn(self.module, sequences=many),
+                            donate_argnums=(1,)))
 
-    def _decode_fn(self, capacity: int):
+    def _fork_fn(self, capacity: int, sequences: int):
         return self.engine._cached(
-            ("expand_decode_chunk", DECODE_STEPS, capacity),
-            lambda: jax.jit(lm.decode_chunk_fn(self.module, DECODE_STEPS),
+            ("expand_fork", capacity, sequences),
+            lambda: jax.jit(functools.partial(kv.fork,
+                                              sequences=sequences)))
+
+    def _decode_fn(self, capacity: int, sequences: int = 1):
+        """One image keeps the key and the function it has always had."""
+        many = sequences > 1
+        make = lm.decode_sequences_fn if many else lm.decode_chunk_fn
+        return self.engine._cached(
+            ("expand_decode_chunk", DECODE_STEPS, capacity)
+            + ((sequences,) if many else ()),
+            lambda: jax.jit(make(self.module, DECODE_STEPS),
                             donate_argnums=(1,)))
 
     # -- the stage -----------------------------------------------------------
+
+    @property
+    def shares_a_step(self) -> bool:
+        """Whether :meth:`expand_batch` decodes its images together."""
+        return lm.shares_a_step(self.config)
 
     def expand(self, prompt: str, args: PromptExpansion, seed: int,
                image_index: int) -> str:
         """``prompt`` + the model's continuation of ``instruction`` +
         ``prompt``, cut to the script's ``context_chunks``."""
-        with obs_spans.span("expand", new_tokens=args.max_new_tokens):
-            made = self._generate(prompt, args, seed, image_index)
-            with obs_spans.span("expand.detokenize", tokens=len(made)):
-                text = self.tokenizer.decode(made)
-                return self._fit(f"{prompt} {text}".strip(),
-                                 args.context_chunks)
+        return self.expand_batch(prompt, args, seed, [image_index])[0]
+
+    def expand_batch(self, prompt: str, args: PromptExpansion, seed: int,
+                     image_indices: Sequence[int]) -> List[str]:
+        """:meth:`expand` for each of ``image_indices``, which all continue
+        the one ``prompt``: decoded together, at most the largest of
+        cache/kv.py:SEQUENCE_BUCKETS a time, where the model's kinds allow
+        and else one after the other. Image ``i`` gets what its own seed
+        gives, whoever it is decoded beside."""
+        most = kv.SEQUENCE_BUCKETS[-1] if self.shares_a_step else 1
+        texts: List[str] = []
+        for at in range(0, len(image_indices), most):
+            group = list(image_indices[at:at + most])
+            with obs_spans.span("expand", new_tokens=args.max_new_tokens,
+                                sequences=len(group)):
+                made = self._generate(prompt, args, seed, group)
+                with obs_spans.span("expand.detokenize",
+                                    tokens=sum(map(len, made))):
+                    texts += [self._fit(
+                        f"{prompt} {self.tokenizer.decode(one)}".strip(),
+                        args.context_chunks) for one in made]
+        return texts
 
     def _generate(self, prompt: str, args: PromptExpansion, seed: int,
-                  image_index: int) -> List[int]:
+                  image_indices: Sequence[int]) -> List[List[int]]:
+        """The tokens made for each image. ``live`` images are ``batch``
+        sequences of the executables (1: the one-sequence ones)."""
         tok = self.tokenizer
+        live = len(image_indices)
+        batch = kv.sequence_bucket(live)
         params = self.engine.params["expander"]
         with obs_spans.span("expand.tokenize"):
             prefix = [tok.bos] + tok.encode(args.instruction)
@@ -106,11 +158,20 @@ class PromptExpander:
             capacity = kv.capacity_for(
                 len(prefix) + kv.chunk_bucket(len(user))
                 + chunks * DECODE_STEPS)
-            key = jax.random.fold_in(rng.key_for_image(seed, image_index),
-                                     _KEY_DOMAIN)
+            if batch == 1:
+                key = first_key = jax.random.fold_in(
+                    rng.key_for_image(seed, image_indices[0]), _KEY_DOMAIN)
+            else:       # the pad repeats the last image
+                padded_to = list(image_indices) \
+                    + [image_indices[-1]] * (batch - live)
+                key = jax.vmap(lambda i: jax.random.fold_in(
+                    rng.key_for_image(seed, i), _KEY_DOMAIN))(
+                        jnp.asarray(padded_to, jnp.uint32))
+                first_key = key[0]
             temperature = jnp.float32(args.temperature)
-            sizes = kv.state_bytes(self.config, capacity, self.cache.dtype)
-            copied = sum(sizes.values())
+            sizes = kv.state_bytes(self.config, capacity, self.cache.dtype,
+                                   batch)
+            copied = sum(sizes.values()) // batch   # one sequence's
         with obs_spans.span("expand.prefix_copy", bytes=copied) as sp:
             cache, held = self.cache.acquire(prefix, capacity)
             if sp is not None:
@@ -134,11 +195,14 @@ class PromptExpander:
                 attrs["form"] = delta_rule.form(len(padded))
             if latent:        # the form its attention takes over the cache
                 attrs["latent"] = lm.latent_form(len(padded))
+            # the instruction's chunk yields no token that is kept: it runs
+            # at one sequence whatever follows it
             with obs_spans.span("expand.prefill", **attrs):
                 cache, token, step_load, step_none = self._prefill_fn(
-                    len(padded), capacity)(
+                    len(padded), capacity, 1 if keep else batch)(
                         params, cache, padded, jnp.int32(start),
-                        jnp.int32(len(ids)), key, temperature)
+                        jnp.int32(len(ids)), first_key if keep else key,
+                        temperature)
                 # fenced: the span is the chunk's device time, not its
                 # enqueue
                 jax.block_until_ready(token)
@@ -148,42 +212,67 @@ class PromptExpander:
                     self.cache.keep_prefix(prefix, capacity, cache)
             routed.append((step_load, step_none))
             masked += attrs.get("padded", 0)
-        made: List[int] = [int(token)]
+        if batch > 1:
+            with obs_spans.span("expand.fork", sequences=batch,
+                                bytes=sum(sizes.values())):
+                cache = self._fork_fn(capacity, batch)(cache)
+                jax.block_until_ready(cache)    # fenced, as a prefill is
+        # (live, tokens so far): the first of each from the prompt's row
+        made = np.asarray(token).reshape(-1, 1)[:live].tolist()
         position = jnp.int32(len(prefix) + len(user))
-        decode = self._decode_fn(capacity)
+        decode = self._decode_fn(capacity, batch)
+        more = () if batch == 1 else (jnp.int32(live),)
         pending = []      # at most one chunk whose tokens are not fetched
         steps = 0
+        decoded_from = len(routed)    # the executable calls that decode
+        reads = []        # per decode call of several sequences
 
         def fetch(out) -> None:
             with obs_spans.span("expand.fence_wait"):
-                made.extend(np.asarray(jax.device_get(out)).tolist())
+                out = np.asarray(jax.device_get(out)).reshape(
+                    DECODE_STEPS, -1)
+                for one, column in zip(made, out.T):
+                    one.extend(column.tolist())
 
         for _ in range(chunks):
-            if self.engine.state.flag.interrupted \
-                    or (not args.ignore_eos and tok.eos in made):
+            if self.engine.state.flag.interrupted or (
+                    not args.ignore_eos
+                    and all(tok.eos in one for one in made)):
                 break
-            with obs_spans.span("expand.decode_chunk", tokens=DECODE_STEPS):
-                cache, token, position, out, step_load, step_none = decode(
-                    params, cache, token, position, key, temperature)
+            with obs_spans.span("expand.decode_chunk", tokens=DECODE_STEPS,
+                                sequences=live):
+                cache, token, position, out, step_load, step_none, *read = \
+                    decode(params, cache, token, position, key,
+                           temperature, *more)
             steps += DECODE_STEPS
             routed.append((step_load, step_none))
+            reads += read
             pending.append(out)
             if len(pending) > 1:
                 fetch(pending.pop(0))
         for out in pending:
             fetch(out)
         # the cut and the counters' fetch: host work with the device idle
-        with obs_spans.span("expand.account", fetched=2 * len(routed)):
-            made = made[:args.max_new_tokens]
-            if not args.ignore_eos and tok.eos in made:
-                made = made[:made.index(tok.eos)]
-            length = len(prefix) + len(user) + len(made)
+        with obs_spans.span("expand.account",
+                            fetched=2 * len(routed) + len(reads)):
+            made = [one[:args.max_new_tokens] for one in made]
+            if not args.ignore_eos:     # each sequence is cut at its own
+                made = [one[:one.index(tok.eos)] if tok.eos in one else one
+                        for one in made]
+            length = len(prefix) + len(user) + max(map(len, made))
             loads, none_held = zip(*jax.device_get(routed))
+            # a step of one token reads as many experts as it has picks
+            # held; a step of several the distinct ones, counted beside
+            # the load on the device
+            read = np.sum(jax.device_get(reads)) if reads \
+                else np.sum(loads[decoded_from:])
             EXPANDER.record(
                 prefilled=len(user) + (0 if held else len(prefix)),
-                from_prefix=held, decoded=len(made), decode_steps=steps,
+                from_prefix=held, sequences=live,
+                decoded=sum(map(len, made)), decode_steps=steps,
+                experts_read=int(read),
                 load=np.sum(loads, axis=0), none_held=int(np.sum(none_held)),
-                positions=self.cache.positions_in_use(length),
+                positions=self.cache.positions_in_use(length, live),
                 state_bytes=sizes, prefix_snapshots=self.cache.snapshots,
                 padded_rows_masked=masked,
                 residual_streams=self.config.residual_streams,

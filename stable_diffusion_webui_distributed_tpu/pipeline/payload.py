@@ -361,6 +361,14 @@ def canonical_dump(payload: "GenerationPayload") -> Dict[str, Any]:
 # image <-> base64 PNG (wire format parity with the reference)
 # --------------------------------------------------------------------------
 
+class Base64Text(str):
+    """Text of the base64 alphabet alone, as :func:`encode_b64png` made
+    it: a JSON writer may copy it between quotes without reading it
+    (``server/api.py:json_body``). Any other ``str`` is read as before."""
+
+    __slots__ = ()
+
+
 def encode_b64png(img: np.ndarray) -> Tuple[str, int]:
     """(H,W,3) uint8 -> (base64 PNG string, strips it was deflated as).
 
@@ -379,7 +387,7 @@ def encode_b64png(img: np.ndarray) -> Tuple[str, int]:
         Image.fromarray(img).save(buf, format="PNG")
         encoded = buf.getvalue(), 1
     data, strips = encoded
-    return base64.b64encode(data).decode("ascii"), strips
+    return Base64Text(base64.b64encode(data).decode("ascii")), strips
 
 
 def array_to_b64png(img: np.ndarray) -> str:

@@ -430,10 +430,17 @@ class UpsampleSites:
 class ExpanderStats:
     """What the resident prompt expander (models/lm.py, the engine's
     ``expand`` stage) did: tokens prefilled, tokens whose cache came from
-    the kept instruction prefix, tokens decoded, how many tokens the router
+    the kept instruction prefix, the sequences decoded (the images of a
+    request that shared their decode steps count one each), tokens decoded
+    over all sequences and the steps that made them apart (their quotient
+    is the tokens a step), the distinct held experts whose kernels the
+    decode steps streamed (summed over layers and steps: a step of several
+    sequences reads an expert once however many of them chose it), how many
+    tokens the router
     sent to each expert held here (load and its imbalance), tokens none of
     whose chosen experts is held here, the cache positions the last
-    sequence occupied and the bytes its cache took by layer kind (keys and
+    request's sequences occupied and the bytes their caches took by layer
+    kind (keys and
     values of full and sliding layers, a linear layer's recurrent state and
     kept convolution inputs, a latent layer's latents, a conv layer's kept
     rows), the instruction
@@ -458,8 +465,10 @@ class ExpanderStats:
             self.requests = 0          # guarded-by: _lock
             self.prefilled = 0         # guarded-by: _lock
             self.from_prefix = 0       # guarded-by: _lock
+            self.sequences = 0         # guarded-by: _lock
             self.decoded = 0           # guarded-by: _lock
             self.decode_steps = 0      # guarded-by: _lock
+            self.experts_read = 0      # guarded-by: _lock
             self.none_held = 0         # guarded-by: _lock
             #: per expert layer, tokens sent to each held expert
             self.load: List[List[int]] = []  # guarded-by: _lock
@@ -489,21 +498,25 @@ class ExpanderStats:
         with self._lock:
             self.convs[form] += 1
 
-    def record(self, *, prefilled: int, from_prefix: int, decoded: int,
-               decode_steps: int, load, none_held: int,
+    def record(self, *, prefilled: int, from_prefix: int, sequences: int,
+               decoded: int, decode_steps: int, experts_read: int, load,
+               none_held: int,
                positions: Dict[str, int], state_bytes: Dict[str, int],
                prefix_snapshots: int, padded_rows_masked: int,
                residual_streams: int, sinkhorn_iters: int) -> None:
         """``load`` is (expert layers, held experts) counts of one
         request; ``decode_steps`` the steps its decode executables ran
-        (whole chunks, so at least ``decoded - 1``)."""
+        (whole chunks, so at least ``decoded / sequences - 1``), each a
+        token of every one of its ``sequences``."""
         rows = [[int(n) for n in row] for row in load]
         with self._lock:
             self.requests += 1
             self.prefilled += int(prefilled)
             self.from_prefix += int(from_prefix)
+            self.sequences += int(sequences)
             self.decoded += int(decoded)
             self.decode_steps += int(decode_steps)
+            self.experts_read += int(experts_read)
             self.none_held += int(none_held)
             if len(self.load) != len(rows):
                 self.load = rows
@@ -525,8 +538,10 @@ class ExpanderStats:
                 "requests": self.requests,
                 "tokens_prefilled": self.prefilled,
                 "tokens_from_prefix_cache": self.from_prefix,
+                "sequences": self.sequences,
                 "tokens_decoded": self.decoded,
                 "decode_steps": self.decode_steps,
+                "experts_read": self.experts_read,
                 "tokens_no_held_expert": self.none_held,
                 "expert_tokens": [list(row) for row in self.load],
                 "expert_load_max_over_mean":

@@ -223,6 +223,13 @@ def test_stream_mixer_kernels_compile_for_v5e(one_chip, stored):
     # values and eight layers' kept rows, 4.3 MB
     ("decode", "sd15_lfm2_expander", 10.8, 8, 64, 4),
     ("prefill", "sd15_lfm2_expander", 10.8, 0, 64, 4),
+    # at a 2 560-slot cache (a 2 048-token instruction): eight expert
+    # kernels at one sequence; four sequences a step take the grouped
+    # product (no kernel) and donate four forked caches, 92 MB; the
+    # instruction's one chunk, twice the window, needs 0.9 GB of scores
+    ("decode", "sd15_mellum2_expander", 7.6, 8, 64, 23),
+    ("decode4", "sd15_mellum2_expander", 7.6, 0, 64, 92),
+    ("prefill2048", "sd15_mellum2_expander", 7.6, 0, 1000, 23),
 ])
 def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
         one_chip, monkeypatch, which, expander, argument_gb, kernels,
@@ -243,13 +250,16 @@ def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = getattr(configs, expander)().expander
     module = lm.DecoderLM(cfg, dtype=jnp.bfloat16)
+    capacity = 2560 if expander == "sd15_mellum2_expander" else 1024
+    lead = (4,) if which == "decode4" else ()       # forked sequences
 
     def on_chip(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    cache = {name: [on_chip(shape, lm.buffer_dtype(name, jnp.bfloat16))
+    cache = {name: [on_chip(lead + shape,
+                            lm.buffer_dtype(name, jnp.bfloat16))
                     for shape in rows]
-             for name, rows in lm.cache_shapes(cfg, 1024).items()}
+             for name, rows in lm.cache_shapes(cfg, capacity).items()}
     small = {name: [jax.ShapeDtypeStruct(shape, jnp.float32)
                     for shape in rows]
              for name, rows in lm.cache_shapes(cfg, 8).items()}
@@ -266,10 +276,16 @@ def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
         lowered = jax.jit(lm.decode_chunk_fn(module, 32),
                           donate_argnums=(1,)).lower(
             params, cache, scalar, scalar, key, heat)
+    elif which == "decode4":
+        lowered = jax.jit(lm.decode_sequences_fn(module, 32),
+                          donate_argnums=(1,)).lower(
+            params, cache, on_chip((4,), jnp.int32), scalar,
+            on_chip((4,), jax.random.key(0).dtype), heat, scalar)
     else:
+        tokens = 2048 if which == "prefill2048" else 64
         lowered = jax.jit(lm.prefill_fn(module), donate_argnums=(1,)).lower(
-            params, cache, on_chip((64,), jnp.int32), scalar, scalar, key,
-            heat)
+            params, cache, on_chip((tokens,), jnp.int32), scalar, scalar,
+            key, heat)
     compiled = lowered.compile()
     calls = compiled.as_text().count("tpu_custom_call")
     assert calls >= kernels and bool(calls) == bool(kernels)

@@ -1,0 +1,630 @@
+"""The resident prompt expander whose every layer keeps keys and values
+(three window layers under plain RoPE, then a full one under YaRN, the
+period's LAST; ungated grouped-query attention; a renormalised softmax
+router over experts that are all held, no shared one), at the shape that
+makes such a model work: an instruction longer than the window, so the
+rings wrap, and the images of one request decoded as sequences of ONE
+step, forked from one prefill.
+
+Everything runs the tiny preset (models/configs.py ``TINY_WINDOW_LM``: a
+window of 8, 4 heads of width 8 over 2 KV heads, YaRN over an original
+length of 16, 8 experts top-2). The plain reference is the benchmark's own
+(benchmarks/reference/mellum2_ref.py: float32, one sequence, no cache, no
+ring, the window a mask on the whole score matrix).
+"""
+
+import importlib.util
+import os
+import zlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from stable_diffusion_webui_distributed_tpu.cache import kv
+from stable_diffusion_webui_distributed_tpu.models import configs, lm
+from stable_diffusion_webui_distributed_tpu.ops import moe
+from stable_diffusion_webui_distributed_tpu.pipeline import expand
+from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
+from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
+    Base64Text, GenerationPayload, prompt_expansion_args,
+)
+from stable_diffusion_webui_distributed_tpu.runtime import dtypes
+from stable_diffusion_webui_distributed_tpu.runtime.interrupt import (
+    GenerationState,
+)
+from stable_diffusion_webui_distributed_tpu.serving.metrics import (
+    ATTENTION, EXPANDER, METRICS,
+)
+from tests.test_pipeline import init_params
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+REF = _load(os.path.join(ROOT, "benchmarks", "reference", "mellum2_ref.py"),
+            "mellum2_ref_for_tests")
+FAMILY = configs.TINY_WINDOW_EXPAND
+CFG = FAMILY.expander
+STEPS = expand.DECODE_STEPS
+
+
+def lm_params(cfg, seed=0):
+    """``DecoderLM.init``'s tree with the norms off 1, so that reading one
+    as another would show."""
+    params = lm.DecoderLM(cfg).init(
+        jax.random.key(seed), jnp.zeros((4,), jnp.int32), jnp.int32(0),
+        jnp.int32(4), lm.empty_cache(cfg, 8, jnp.float32))["params"]
+    key = jax.random.key(seed + 100)
+
+    def off(path, x):
+        if getattr(path[-1], "key", "") != "scale":
+            return x
+        return x + 0.2 * jax.random.normal(
+            jax.random.fold_in(key, zlib.crc32(str(path).encode()) % 2 ** 31),
+            x.shape)
+
+    return jax.tree_util.tree_map_with_path(off, params)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return lm_params(CFG)
+
+
+def rel_rms(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.sqrt(np.mean((got - want) ** 2))
+                 / np.sqrt(np.mean(want ** 2)))
+
+
+# -- (a) program against reference --------------------------------------------
+
+class TestAgainstTheReference:
+    @pytest.mark.parametrize("size", [37, 74])
+    def test_chunks_fork_and_decode_match_four_full_forwards(self, params,
+                                                             size):
+        """The prefix as one chunk four and eight times the window (the
+        rings wrap as often), a copy, the prompt's chunk, a fork into four
+        and one step over all four a position, against a full forward of
+        each whole sequence: logits to 1e-5, routing identical."""
+        prefix, user, decoded = REF.split(size)
+        assert prefix >= 4 * CFG.sliding_window and decoded >= 4
+        ids, continuations = REF.inputs(FAMILY, 3, size)
+        got, chose = jax.jit(REF.program(FAMILY, dtypes.F32,
+                                         with_routing=True))(
+            params, ids, continuations)
+        want, own = jax.jit(lambda p, i, c: REF.forward(
+            FAMILY, p, i, c, with_routing=True))(params, ids, continuations)
+        rows = prefix + user + REF.SEQUENCES * decoded
+        assert got.shape == want.shape == (rows, CFG.vocab[1])
+        assert got.dtype == want.dtype == jnp.float32
+        assert rel_rms(got, want) < 1e-5
+        assert np.array_equal(np.sort(chose, -1), np.sort(own, -1))
+        # the four continuations part at their first row
+        tails = np.asarray(got[prefix + user:]).reshape(
+            REF.SEQUENCES, decoded, -1)
+        assert rel_rms(tails[1], tails[0]) > 0.1
+
+    @pytest.mark.parametrize("control", [None, "aliased_rings"])
+    def test_the_two_executables_give_what_the_one_gives(self, params,
+                                                         control):
+        """The chip's readings run the chunks with the fork and the decode
+        steps as two executables, as the timed path does: the same logits
+        and routing as the one jitted whole."""
+        ids, continuations = REF.inputs(FAMILY, 3, 74)
+        kwargs = dict(REF.CONTROLS)[control] if control else {}
+        whole, chose = jax.jit(REF.program(
+            FAMILY, dtypes.F32, with_routing=True, **kwargs))(
+                params, ids, continuations)
+        got, chose_staged = REF.staged(FAMILY, dtypes.F32, params, ids,
+                                       continuations, **kwargs)
+        np.testing.assert_allclose(got, whole, rtol=1e-6, atol=1e-6)
+        assert np.array_equal(chose, chose_staged)
+
+    @pytest.mark.parametrize("control", [name for name, _ in REF.CONTROLS])
+    def test_each_control_is_further_from_the_reference(self, params,
+                                                        control):
+        """The int8 linears, the windows attending everything, the windows
+        under the full layers' table and the aliased rings each read far
+        from the reference where the program reads 1e-6: the second and
+        the fourth only because the context is over the window."""
+        ids, continuations = REF.inputs(FAMILY, 3, 74)
+        want = jax.jit(lambda p, i, c: REF.forward(FAMILY, p, i, c))(
+            params, ids, continuations)
+        lower = jax.jit(REF.program(
+            FAMILY, dtypes.F32, **dict(REF.CONTROLS)[control]))(
+                params, ids, continuations)
+        assert rel_rms(lower, want) > 1e-2
+
+    def test_a_context_inside_the_window_hides_two_of_the_controls(
+            self, params):
+        """What the old traffic would have measured: at 7 positions the
+        window of 8 never binds, and a ring taken for a full buffer reads
+        as the program does."""
+        ids, continuations = REF.inputs(FAMILY, 3, 7)
+        want = jax.jit(lambda p, i, c: REF.forward(FAMILY, p, i, c))(
+            params, ids, continuations)
+        lower = jax.jit(REF.program(FAMILY, dtypes.F32,
+                                    windows_attend_all=True))(
+            params, ids, continuations)
+        assert rel_rms(lower, want) < 1e-5
+
+    def test_yarn_from_its_five_numbers(self):
+        """The reference's own frequencies against the program's, at the
+        published rope: the fast pairs kept, the slow ones divided by 16,
+        a ramp between, and the window layers' plain table apart."""
+        rope = configs.MELLUM2_12B_A2_5B.rope_full
+        own = REF.inverse_frequencies(rope, 128)
+        np.testing.assert_allclose(own, lm.rope_frequencies(rope, 128),
+                                   rtol=1e-12)
+        plain = REF.inverse_frequencies(
+            configs.MELLUM2_12B_A2_5B.rope_sliding, 128)
+        ratio = plain / own
+        assert ratio[0] == 1.0 and ratio[-1] == pytest.approx(16.0)
+        between = (ratio > 1.0 + 1e-9) & (ratio < 16.0 - 1e-9)
+        assert 10 < int(between.sum()) < 40
+        assert rope.attention_factor == pytest.approx(
+            0.1 * np.log(16.0) + 1.0)
+        # the tiny preset's ramp lies inside a test's positions
+        tiny = REF.inverse_frequencies(CFG.rope_full, CFG.head_dim)
+        assert (tiny != REF.inverse_frequencies(
+            CFG.rope_sliding, CFG.head_dim)).sum() == 3
+
+
+# -- (b) a step over B sequences ----------------------------------------------
+
+def _prefilled(params, length=21, capacity=256):
+    ids = jax.random.randint(jax.random.key(5), (length,), 0, 512)
+    logits, cache, _ = lm.DecoderLM(CFG).apply(
+        {"params": params}, ids, jnp.int32(0), jnp.int32(length),
+        lm.empty_cache(CFG, capacity, jnp.float32), all_logits=False)
+    return logits[0], cache, length
+
+
+def _keys(indices, seed=77):
+    from stable_diffusion_webui_distributed_tpu.runtime import rng
+
+    return jnp.stack([rng.key_for_image(seed, i) for i in indices])
+
+
+class TestSequencesOfOneStep:
+    @pytest.mark.parametrize("live,batch", [(1, 1), (2, 2), (4, 4), (3, 4)])
+    def test_each_sequence_gets_what_it_gets_alone(self, params, live,
+                                                   batch):
+        """A chunk of steps over ``batch`` sequences (``live`` of them
+        real, the pad a repeat of the last) against the one-sequence chunk
+        run once a key: the same tokens, the same rows in the cache, and a
+        load that leaves the pad out."""
+        module = lm.DecoderLM(CFG)
+        row, cache, length = _prefilled(params)
+        keys = _keys(list(range(live)) + [live - 1] * (batch - live))
+        first = lm.sample_each(row, keys, length, jnp.float32(1.0))
+        alone = jax.jit(lm.decode_chunk_fn(module, STEPS))
+        together = jax.jit(lm.decode_sequences_fn(module, STEPS))
+        forked, tokens, position, made, load, none_held, read = together(
+            params, kv.fork(cache, batch), first, jnp.int32(length), keys,
+            jnp.float32(1.0), jnp.int32(live))
+        assert made.shape == (STEPS, batch) and int(position) == length + STEPS
+        total = 0
+        for b in range(live):
+            token = lm.sample(row, keys[b], length, jnp.float32(1.0))
+            assert int(token) == int(first[b])
+            own, last, _, steps, own_load, _ = alone(
+                params, cache, token, jnp.int32(length), keys[b],
+                jnp.float32(1.0))
+            assert np.array_equal(steps, made[:, b])
+            assert int(last) == int(tokens[b])
+            for name in ("k", "v"):
+                for mine, theirs in zip(own[name], forked[name]):
+                    np.testing.assert_allclose(mine, theirs[b], rtol=2e-5,
+                                               atol=2e-5)
+            total = total + own_load
+        assert np.array_equal(load, total)      # the pad is not counted
+        assert int(none_held.sum()) == 0
+        # sequences part from their first token on
+        assert len({tuple(np.asarray(made[:, b])) for b in range(live)}) \
+            == live
+        # distinct experts a step: never over the picks, never under one
+        # sequence's two a layer
+        layers, k = len(CFG.expert_layers), CFG.num_experts_per_tok
+        assert np.all(read >= STEPS * k) and np.all(
+            read <= STEPS * min(live * k, CFG.num_experts))
+        assert int(read.sum()) <= int(load.sum())
+        if live == 1:
+            assert int(read.sum()) == STEPS * k * layers
+
+    def test_a_step_gives_each_sequence_the_logits_it_gets_alone(
+            self, params):
+        """Teacher-forced on continuations that differ: every sequence's
+        logits at every step, and both ring and buffer rows."""
+        module = lm.DecoderLM(CFG)
+        _, cache, length = _prefilled(params)
+        forced = jax.random.randint(jax.random.key(8), (12, 4), 0, 512)
+        together = kv.fork(cache, 4)
+        alone = [cache] * 4
+        for t in range(12):
+            logits, together, routed = module.apply(
+                {"params": params}, forced[t], jnp.int32(length + t),
+                jnp.int32(4), together, sequences=True)
+            assert logits.shape == (4, CFG.vocab[1])
+            for b in range(4):
+                want, alone[b], own = module.apply(
+                    {"params": params}, forced[t, b][None],
+                    jnp.int32(length + t), jnp.int32(1), alone[b])
+                np.testing.assert_allclose(logits[b], want[0], rtol=1e-5,
+                                           atol=1e-5)
+                assert np.array_equal(np.sort(routed[0][:, b], -1),
+                                      np.sort(own[0][:, 0], -1))
+        # 21 + 12 positions through rings of 8: every slot rewritten
+        for b in range(4):
+            for mine, theirs in zip(alone[b]["k"], together["k"]):
+                np.testing.assert_allclose(mine, theirs[b], rtol=2e-5,
+                                           atol=2e-5)
+
+    def test_a_fork_copies_every_buffer_once_a_sequence(self, params):
+        _, cache, _ = _prefilled(params)
+        forked = jax.jit(lambda c: kv.fork(c, 4))(cache)
+        assert [x.shape for x in forked["k"]] == \
+            [(4, 8, 2, 8)] * 3 + [(4, 256, 2, 8)]
+        for name in ("k", "v"):
+            for one, four in zip(cache[name], forked[name]):
+                assert np.array_equal(np.asarray(four),
+                                      np.broadcast_to(one, four.shape))
+
+    @pytest.mark.parametrize("images,bucket", [
+        (1, 1), (2, 2), (3, 4), (4, 4), (5, 8), (8, 8), (11, 8)])
+    def test_sequence_buckets(self, images, bucket):
+        assert kv.sequence_bucket(images) == bucket
+
+    @pytest.mark.parametrize("preset,shares", [
+        ("TINY_WINDOW_EXPAND", True), ("TINY_EXPAND", True),
+        ("TINY_DELTA_EXPAND", False), ("TINY_LATENT_EXPAND", False),
+        ("TINY_CONV_EXPAND", False)])
+    def test_which_kinds_share_a_step(self, preset, shares):
+        cfg = getattr(configs, preset).expander
+        assert lm.shares_a_step(cfg) is shares
+        if not shares:
+            with pytest.raises(ValueError):
+                jax.eval_shape(
+                    lambda: lm.DecoderLM(cfg).init(
+                        jax.random.key(0), jnp.zeros((2,), jnp.int32),
+                        jnp.int32(0), jnp.int32(2),
+                        lm.empty_cache(cfg, 8, jnp.float32),
+                        sequences=True))
+
+    def test_bytes_and_positions_times_sequences(self):
+        manager = kv.KVCacheManager(CFG, jnp.bfloat16)
+        # past the window the two kinds finally differ
+        assert manager.positions_in_use(40) == {"full": 40,
+                                                "sliding": 3 * 8}
+        assert manager.positions_in_use(40, 4) == {"full": 160,
+                                                   "sliding": 96}
+        one = kv.state_bytes(CFG, 256, jnp.bfloat16)
+        assert one == {"full": 2 * 256 * 2 * 8 * 2,
+                       "sliding": 3 * 2 * 8 * 2 * 8 * 2}
+        assert kv.state_bytes(CFG, 256, jnp.bfloat16, 4) == {
+            kind: 4 * size for kind, size in one.items()}
+
+    def test_experts_read_counts_each_held_expert_once(self):
+        routing = moe.Routing(
+            jnp.asarray([[0, 1], [1, 2], [1, 9]], jnp.int32),
+            jnp.ones((3, 2), jnp.float32))
+        load, _ = moe.load_counts(routing, 0, 8, jnp.ones(3, bool))
+        assert int(load.sum()) == 5 and int(moe.experts_read(load)) == 3
+        load, _ = moe.load_counts(routing, 0, 8,
+                                  jnp.asarray([True, False, False]))
+        assert int(moe.experts_read(load)) == 2
+        assert moe.experts_read(jnp.zeros((4, 8), jnp.int32)).shape == (4,)
+
+
+# -- the engine's path ----------------------------------------------------------
+
+INSTRUCTION = " ".join(f"word{i}" for i in range(30))
+
+
+def script(**args):
+    return {"prompt expansion": {"args": [dict(
+        {"instruction": INSTRUCTION, "max_new_tokens": 40,
+         "temperature": 1.0, "ignore_eos": True, "context_chunks": 1},
+        **args)]}}
+
+
+@pytest.fixture(scope="module")
+def engine():
+    params = init_params(configs.TINY)
+    params["expander"] = lm_params(CFG, seed=1)
+    return Engine(FAMILY, params, chunk_size=4, state=GenerationState())
+
+
+def payload(**kw):
+    base = dict(prompt="a cow in a valley", steps=4, width=32, height=32,
+                seed=1234, alwayson_scripts=script())
+    base.update(kw)
+    return GenerationPayload(**base)
+
+
+CAPACITY = kv.capacity_for(31 + 64 + 2 * STEPS)
+
+
+class TestOneImageTakesTheOneSequencePath:
+    def test_executable_key_cache_and_lowered_text(self, engine):
+        """(c): what one image runs is what the one-sequence path gives
+        without ``expand_batch`` in the way: the key it has always had, a
+        cache with no sequence axis, the ``loop`` product on a CPU, and the
+        decode function's lowered text."""
+        EXPANDER.clear()
+        engine.txt2img(payload())
+        keys = {k for k in engine.executable_keys()
+                if k[0].startswith("expand")}
+        assert keys == {("expand_prefill", 64, CAPACITY),
+                        ("expand_decode_chunk", STEPS, CAPACITY)}
+        stats = EXPANDER.summary()
+        assert stats["expert_products"] == {"kernel": 0, "loop": 4,
+                                            "grouped": 4}
+        assert stats["sequences"] == 1 and stats["tokens_decoded"] == 40
+        assert stats["decode_steps"] == 2 * STEPS
+        # a step of one token reads as many experts as it has picks
+        assert stats["experts_read"] == 2 * STEPS * 4 * 2
+        assert stats["state_bytes"] == kv.state_bytes(CFG, CAPACITY,
+                                                      jnp.float32)
+        module = engine.expander.module
+        cache = lm.empty_cache(CFG, CAPACITY, jnp.float32)
+        assert [x.ndim for x in cache["k"]] == [3] * 4
+        args = (engine.params["expander"], cache, jnp.int32(0),
+                jnp.int32(36), jax.random.key(0), jnp.float32(1.0))
+        served = engine.expander._decode_fn(CAPACITY).lower(*args).as_text()
+        plain = jax.jit(lm.decode_chunk_fn(module, STEPS),
+                        donate_argnums=(1,)).lower(*args).as_text()
+        assert served == plain
+        batched = engine.expander._decode_fn(CAPACITY, 2)
+        assert batched is not engine.expander._decode_fn(CAPACITY)
+
+    def test_a_one_image_expand_batch_is_expand(self, engine):
+        args = prompt_expansion_args(payload())
+        one = engine.expander.expand("a cow in a valley", args, 1234, 0)
+        assert engine.expander.expand_batch(
+            "a cow in a valley", args, 1234, [0]) == [one]
+        assert one == engine.txt2img(payload()).prompts[0]
+
+
+class TestABatchOfImages:
+    def test_every_image_its_own_expansion_in_any_range(self, engine):
+        """(d): images 2-3 of a four-image request get what they get in
+        the whole request and what four one-image requests with seeds
+        ``s + i`` give."""
+        whole = engine.txt2img(payload(batch_size=4))
+        assert len(set(whole.prompts)) == 4
+        part = engine.generate_range(payload(batch_size=4), 2, 2)
+        assert part.prompts == whole.prompts[2:]
+        assert part.images == whole.images[2:]
+        # four PNGs go back as the encoder's own base64, which the server
+        # copies into the response unread (server/api.py:json_body)
+        assert all(type(png) is Base64Text for png in whole.images)
+        for i in range(4):
+            solo = engine.txt2img(payload(seed=1234 + i))
+            assert solo.prompts[0] == whole.prompts[i], i
+        # three images: a batch of four whose fourth repeats the third
+        three = engine.txt2img(payload(batch_size=3))
+        assert three.prompts == whole.prompts[:3]
+        keys = {k for k in engine.executable_keys()
+                if k[0] == "expand_decode_chunk"}
+        assert keys == {("expand_decode_chunk", STEPS, CAPACITY),
+                        ("expand_decode_chunk", STEPS, CAPACITY, 2),
+                        ("expand_decode_chunk", STEPS, CAPACITY, 4)}
+
+    def test_one_prefill_and_four_sequences_a_step(self, engine):
+        """Tentpole 5: the counters and the spans of a four-image
+        request."""
+        from stable_diffusion_webui_distributed_tpu.obs import spans
+
+        engine.txt2img(payload(batch_size=4))       # the snapshot is kept
+        EXPANDER.clear()
+        spans.TRACER.clear()
+        with spans.request("rid-m2"):
+            engine.txt2img(payload(batch_size=4))
+        stats = METRICS.summary()["expander"]
+        assert stats["requests"] == 1 and stats["sequences"] == 4
+        assert stats["tokens_prefilled"] == 5       # the prompt, once
+        assert stats["tokens_from_prefix_cache"] == 31
+        assert stats["tokens_decoded"] == 4 * 40
+        assert stats["decode_steps"] == 2 * STEPS
+        picks = 2 * STEPS * 4 * 2       # steps x layers x k, one sequence
+        assert picks <= stats["experts_read"] <= min(4 * picks,
+                                                     2 * STEPS * 4 * 8)
+        routed = sum(map(sum, stats["expert_tokens"]))
+        assert routed == 5 * 4 * 2 + 4 * picks
+        assert stats["experts_read"] < 4 * picks
+        assert stats["cache_positions"] == {
+            "full": 4 * 76, "sliding": 4 * 3 * 8}
+        assert stats["state_bytes"] == kv.state_bytes(
+            CFG, CAPACITY, jnp.float32, 4)
+        events = [e for e in spans.TRACER.export_chrome()["traceEvents"]
+                  if e.get("ph") == "X"]
+        by_name = {}
+        for e in events:
+            by_name.setdefault(e["name"], []).append(e["args"])
+        assert [a["sequences"] for a in by_name["expand"]] == [4]
+        assert [a["tokens"] for a in by_name["expand.prefill"]] == [5]
+        (fork,) = by_name["expand.fork"]
+        assert fork["sequences"] == 4
+        assert fork["bytes"] == sum(stats["state_bytes"].values())
+        assert [a["sequences"] for a in by_name["expand.decode_chunk"]] \
+            == [4, 4]
+        by_id = {e["args"]["span_id"]: e for e in events}
+        for e in events:
+            if e["name"].startswith("expand."):
+                assert by_id[e["args"]["parent_id"]]["name"] == "expand"
+
+    def test_the_decode_trace_takes_the_grouped_product(self):
+        """A fresh engine's four-image request traces one prefill chunk
+        (31 and 5 tokens both pad to 64: one executable at one sequence,
+        one that draws four first tokens) and the four-sequence scan: all
+        three through the grouped product, four expert layers each."""
+        params = init_params(configs.TINY)
+        params["expander"] = lm_params(CFG, seed=1)
+        fresh = Engine(FAMILY, params, chunk_size=4, state=GenerationState())
+        EXPANDER.clear()
+        ATTENTION.clear()
+        fresh.txt2img(payload(batch_size=4))
+        stats = EXPANDER.summary()
+        assert stats["expert_products"] == {"kernel": 0, "loop": 0,
+                                            "grouped": 12}
+        keys = {k for k in fresh.executable_keys()
+                if k[0].startswith("expand")}
+        assert keys == {("expand_prefill", 64, CAPACITY),
+                        ("expand_prefill", 64, CAPACITY, 4),
+                        ("expand_fork", CAPACITY, 4),
+                        ("expand_decode_chunk", STEPS, CAPACITY, 4)}
+        sites = ATTENTION.summary()["by_shape"]
+        assert sites["T1 S8 D8"] == {"xla": 3}      # the rings, as they lie
+        assert sites[f"T1 S{CAPACITY} D8"] == {"xla": 1}
+        ATTENTION.clear()
+
+    def test_same_seed_images_are_expanded_once(self, engine):
+        EXPANDER.clear()
+        out = engine.txt2img(payload(batch_size=3, same_seed=True))
+        assert len(set(out.prompts)) == 1
+        assert out.prompts[0] == engine.txt2img(payload()).prompts[0]
+        assert EXPANDER.summary()["sequences"] == 2     # 1 + the solo
+
+    def test_a_prompt_matrix_groups_by_text(self, engine):
+        """Images whose prompts differ are groups of their own, in the
+        order of their first image."""
+        request = payload(batch_size=4, all_prompts=[
+            "a cow in a valley", "a red fox", "a cow in a valley",
+            "a red fox"])
+        EXPANDER.clear()
+        out = engine.generate_range(request, 0, 4)
+        assert EXPANDER.summary()["requests"] == 2
+        assert EXPANDER.summary()["sequences"] == 4
+        assert out.prompts[0].startswith("a cow in a valley")
+        assert out.prompts[1].startswith("a red fox")
+        assert out.prompts[2] == engine.txt2img(
+            payload(seed=1236)).prompts[0]
+        assert out.prompts[3] == engine.txt2img(
+            payload(prompt="a red fox", seed=1237)).prompts[0]
+
+    def test_eos_cuts_one_sequence_and_an_interrupt_ends_all(
+            self, engine, monkeypatch):
+        """(e), on the tokens the stage makes."""
+        args = prompt_expansion_args(payload(alwayson_scripts=script(
+            max_new_tokens=3 * STEPS, context_chunks=None)))
+        stage = engine.expander
+        images = [0, 1, 2, 3]
+        full = stage._generate("a cow in a valley", args, 1234, images)
+        assert [len(one) for one in full] == [3 * STEPS] * 4
+        # a token the second image draws early and the others never
+        others = {t for b in (0, 2, 3) for t in full[b]}
+        at, eos = next((i, t) for i, t in enumerate(full[1])
+                       if t not in others)
+        assert 0 < at < STEPS
+        monkeypatch.setattr(stage.tokenizer, "eos", eos)
+        cut = args.model_copy(update={"ignore_eos": False})
+        EXPANDER.clear()
+        got = stage._generate("a cow in a valley", cut, 1234, images)
+        assert got[1] == full[1][:at]
+        assert [got[b] for b in (0, 2, 3)] == [full[b] for b in (0, 2, 3)]
+        assert EXPANDER.summary()["tokens_decoded"] == 9 * STEPS + at
+        # both sequences drew it in the first chunk: the loop ends with
+        # the chunk enqueued ahead of that one's fetch, two of three
+        both = stage._generate("a cow in a valley", cut, 1234, [1, 1])
+        assert both == [full[1][:at]] * 2
+        assert EXPANDER.summary()["decode_steps"] == 3 * STEPS + 2 * STEPS
+        # an interrupt between chunks ends every sequence
+        real = stage._decode_fn(CAPACITY, 4)
+
+        def then_interrupt(*a):
+            engine.state.flag.interrupt()
+            return real(*a)
+
+        monkeypatch.setattr(stage, "_decode_fn",
+                            lambda capacity, sequences=1: then_interrupt)
+        try:
+            ended = stage._generate("a cow in a valley", args, 1234, images)
+        finally:
+            engine.state.flag.clear()
+        assert ended == [one[:1 + STEPS] for one in full]
+
+
+# -- (f) the published share, from shapes -------------------------------------
+
+class TestThePublishedShare:
+    def test_parameters_and_bytes_from_shapes(self):
+        share = configs.sd15_mellum2_expander().expander
+        whole = configs.MELLUM2_12B_A2_5B
+        assert share.layer_types == ("sliding", "sliding", "sliding",
+                                     "full") * 2
+        assert whole.layers_of("full") == tuple(range(3, 28, 4))
+        assert share.experts == (0, 64) and share.vocab == (0, 98304)
+        assert whole.intermediate_size == 8 * whole.moe_intermediate_size
+        shapes = jax.eval_shape(lambda: lm.DecoderLM(share).init(
+            jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
+            jnp.int32(4), lm.empty_cache(share, 8, jnp.float32)))["params"]
+
+        def count(tree):
+            return sum(int(np.prod(x.shape))
+                       for x in jax.tree_util.tree_leaves(tree))
+
+        layer = shapes["layers_0"]
+        assert count(layer["attn"]) == 21_233_664
+        assert layer["mlp"]["router"].shape == (2304, 64)
+        assert count(layer["mlp"]["experts"]) == 64 * 6_193_152
+        assert set(layer["mlp"]) == {"router", "experts"}
+        assert set(layer["attn"]) == {"q_proj", "k_proj", "v_proj",
+                                      "o_proj"}
+        norms = 2 * 2304
+        assert count(layer) == 417_742_848 + norms
+        assert count(shapes["embed_tokens"]) == count(shapes["lm_head"]) \
+            == 226_492_416
+        total = count(shapes)
+        assert total == 8 * (417_742_848 + norms) + 2 * 226_492_416 + 2304
+        assert round(total / 1e6) == 3795
+        assert round((28 * 417_742_848 + 2 * 226_492_416) / 1e9, 2) == 12.15
+        # three periods would be 5 466 M: 10.93 GB beside SD1.5's 2.13
+        assert round((total + 4 * (417_742_848 + norms)) / 1e6) == 5466
+        # a decoded token alone: 8 x 42.8 MB, the head, 64 experts
+        fixed = 8 * (21_233_664 + 147_456) * 2 + 226_492_416 * 2
+        assert round(fixed / 1e6) == 795
+        assert round((fixed + 64 * 6_193_152 * 2) / 1e6) == 1588
+        # the caches of four sequences at the cell's capacity
+        capacity = kv.capacity_for(2048 + 64 + 8 * STEPS)
+        sizes = kv.state_bytes(share, capacity, jnp.bfloat16, 4)
+        assert sizes == {"full": 4 * 2 * 2 * capacity * 4 * 128 * 2,
+                         "sliding": 4 * 6 * 2 * 1024 * 4 * 128 * 2}
+
+    def test_one_step_of_four_sequences_traces_the_grouped_product(self):
+        """One decode step of the share the cell runs, traced without
+        weights or FLOPs: eight expert layers through the grouped
+        product, six ring sites and two buffer sites."""
+        share = configs.sd15_mellum2_expander().expander
+        module = lm.DecoderLM(share, dtype=jnp.bfloat16)
+        s = jax.ShapeDtypeStruct
+        cache = {name: [s((4,) + shape, jnp.bfloat16) for shape in rows]
+                 for name, rows in lm.cache_shapes(share, 2560).items()}
+        shapes = jax.eval_shape(lambda: module.init(
+            jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
+            jnp.int32(4), lm.empty_cache(share, 8, jnp.float32)))
+        ATTENTION.clear()
+        EXPANDER.clear()
+        logits, after, routed = jax.eval_shape(
+            lambda v, c: module.apply(v, jnp.zeros((4,), jnp.int32),
+                                      jnp.int32(2200), jnp.int32(4), c,
+                                      sequences=True), shapes, cache)
+        assert logits.shape == (4, 98304)
+        assert [x.shape for x in after["k"]] == [x.shape for x in cache["k"]]
+        assert routed[0].shape == (8, 4, 8) and routed[1].shape == (8, 64)
+        assert EXPANDER.summary()["expert_products"] == {
+            "kernel": 0, "loop": 0, "grouped": 8}
+        assert ATTENTION.summary()["by_shape"] == {
+            "T1 S1024 D128": {"xla": 6}, "T1 S2560 D128": {"xla": 2}}
+        assert moe.row_tile(4, 8, 64) == 8      # a trip an expert
+        ATTENTION.clear()
+        EXPANDER.clear()

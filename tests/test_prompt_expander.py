@@ -201,16 +201,16 @@ class TestMasks:
 
 
 class TestExperts:
-    def _layer(self, tokens, seed=0):
+    def _layer(self, tokens, seed=0, experts=16, k=4, scale=2.5):
         key = jax.random.key(seed)
-        d, f, experts = 32, 16, 16
+        d, f = 32, 16
         ks = jax.random.split(key, 5)
         x = jax.random.normal(ks[0], (tokens, d))
         logits = jax.random.normal(ks[1], (tokens, experts))
         wg = jax.random.normal(ks[2], (experts, d, f)) / d ** 0.5
         wu = jax.random.normal(ks[3], (experts, d, f)) / d ** 0.5
         wd = jax.random.normal(ks[4], (experts, f, d)) / f ** 0.5
-        return x, moe.route(logits, 4, renormalise=True, scale=2.5), \
+        return x, moe.route(logits, k, renormalise=True, scale=scale), \
             wg, wu, wd
 
     def _dense(self, x, routing, wg, wu, wd):
@@ -243,18 +243,29 @@ class TestExperts:
         np.testing.assert_allclose(got, self._dense(x, routing, wg, wu, wd),
                                    rtol=2e-4, atol=2e-5)
 
-    @pytest.mark.parametrize("tokens", [1, 24])
-    def test_the_share(self, tokens):
+    @pytest.mark.parametrize("tokens,experts,k,scale", [
+        (1, 16, 4, 2.5), (24, 16, 4, 2.5),
+        # 64 experts, 8 a token, unscaled, no shared expert to count once;
+        # four rows: a decode step of four sequences
+        (4, 64, 8, 1.0)])
+    def test_the_share(self, tokens, experts, k, scale):
         """The two halves' routed parts add up to the uncut layer's: a chip
-        computes its own experts and adds nothing for the absent ones."""
-        x, routing, wg, wu, wd = self._layer(tokens, seed=2)
+        computes its own experts and adds nothing for the absent ones. A
+        share that holds every expert gives the whole layer's result."""
+        x, routing, wg, wu, wd = self._layer(tokens, seed=2, experts=experts,
+                                             k=k, scale=scale)
         whole = self._dense(x, routing, wg, wu, wd)
-        parts = [moe.routed_experts(x, routing, wg[lo:lo + 8],
-                                    wu[lo:lo + 8], wd[lo:lo + 8], first=lo,
-                                    num_experts=16)[0] for lo in (0, 8)]
+        half = experts // 2
+        parts = [moe.routed_experts(x, routing, wg[lo:lo + half],
+                                    wu[lo:lo + half], wd[lo:lo + half],
+                                    first=lo, num_experts=experts)[0]
+                 for lo in (0, half)]
         assert not np.allclose(parts[0], whole, atol=1e-3)
         np.testing.assert_allclose(parts[0] + parts[1], whole, rtol=2e-4,
                                    atol=2e-5)
+        held = moe.routed_experts(x, routing, wg, wu, wd, first=0,
+                                  num_experts=experts)[0]
+        np.testing.assert_allclose(held, whole, rtol=2e-4, atol=2e-5)
 
     def test_load_counts(self):
         _, routing, *_ = self._layer(24, seed=2)
@@ -660,8 +671,8 @@ class TestEnginePath:
         block = METRICS.summary()["expander"]
         assert set(block) == {
             "requests", "tokens_prefilled", "tokens_from_prefix_cache",
-            "tokens_decoded", "decode_steps", "tokens_no_held_expert",
-            "expert_tokens",
+            "sequences", "tokens_decoded", "decode_steps", "experts_read",
+            "tokens_no_held_expert", "expert_tokens",
             "expert_load_max_over_mean", "cache_positions", "state_bytes",
             "prefix_snapshots", "padded_rows_masked", "expert_products",
             "mixer_products", "conv_mixers", "residual_streams",
@@ -693,7 +704,12 @@ class TestDispatcher:
 
 
 class TestSharding:
-    def test_expert_and_vocabulary_axes(self):
+    @pytest.mark.parametrize("preset", ["TINY_EXPAND",
+                                        "TINY_WINDOW_EXPAND"])
+    def test_expert_and_vocabulary_axes(self, preset):
+        """``ep`` and ``vp`` partition the tree of a share with a dense
+        layer, a shared expert and gates, and of one with none of them
+        (every layer a router over held experts, ungated attention)."""
         from jax.sharding import PartitionSpec as P
 
         from stable_diffusion_webui_distributed_tpu.parallel.sharding import (
@@ -706,7 +722,8 @@ class TestSharding:
         assert tp_spec_for("lm_head/kernel", 2) == P(None, "vp")
         devices = np.array(jax.devices()[:4]).reshape(2, 2)
         mesh = jax.sharding.Mesh(devices, ("ep", "vp"))
-        placed = shard_params(lm_params(CFG), mesh)
+        placed = shard_params(
+            lm_params(getattr(configs, preset).expander), mesh)
         gate = placed["layers_1"]["mlp"]["experts"]["w_gate"]
         assert gate.sharding.spec == P("ep", None, None)
         assert placed["lm_head"]["kernel"].sharding.spec == P(None, "vp")
@@ -715,3 +732,13 @@ class TestSharding:
         # no tp axis on this mesh: a Megatron leaf stays whole
         assert placed["layers_0"]["attn"]["q_proj"]["kernel"].sharding.spec \
             == P()
+        if preset == "TINY_WINDOW_EXPAND":
+            # every layer's experts over ep, the router whole, no leaf
+            # left without a rule that a Laguna share's has
+            for layer in range(4):
+                mlp = placed[f"layers_{layer}"]["mlp"]
+                assert set(mlp) == {"router", "experts"}
+                assert mlp["router"].sharding.spec == P()
+                for leaf in mlp["experts"].values():
+                    assert leaf.sharding.spec == P("ep", None, None)
+                    assert leaf.addressable_shards[0].data.shape[0] == 4

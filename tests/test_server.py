@@ -522,3 +522,88 @@ class TestPinValidatedSurface:
             assert row["pin_validated"] is False
         finally:
             world.workers.remove(n)
+
+
+class TestJsonBody:
+    """``api.json_body`` gives ``json.dumps(obj).encode()`` byte for byte,
+    whichever way it takes: the encoder's own base64 copied in as bytes
+    (PR 45: 16 ms of a four-image response's serialising), or everything
+    through ``json.dumps``."""
+
+    MARK = "\x00sdtpu:images\x00"
+
+    @staticmethod
+    def png(seed: int):
+        import numpy as np
+
+        from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
+            encode_b64png,
+        )
+
+        return encode_b64png(np.random.default_rng(seed).integers(
+            0, 256, (16, 16, 3), dtype=np.uint8))[0]
+
+    @pytest.mark.parametrize("case", [
+        "one image", "four images", "a grid first", "another worker's image",
+        "the mark in a prompt", "the mark before the images", "no images",
+        "no dict"])
+    def test_byte_for_byte(self, case):
+        from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
+            Base64Text,
+        )
+        from stable_diffusion_webui_distributed_tpu.server import api
+
+        images = [self.png(i) for i in range(4)]
+        assert all(type(i) is Base64Text for i in images)
+        rest = {"parameters": {"prompt": "a \"quoted\" café", "n": 1},
+                "info": json.dumps({"infotexts": ["x\ny"]})}
+        obj = {
+            "one image": {"images": images[:1], **rest},
+            "four images": {"images": images, **rest},
+            "a grid first": {"images": [self.png(9)] + images, **rest},
+            "another worker's image": {"images": [str(images[0])]
+                                       + images[1:], **rest},
+            "the mark in a prompt": {"images": images[:2],
+                                     "parameters": {"prompt": self.MARK}},
+            "the mark before the images": {"prompt": self.MARK,
+                                           "images": images[:2]},
+            "no images": {"images": [], **rest},
+            "no dict": [1, "two"],
+        }[case]
+        data, copied = api.json_body(obj)
+        assert data == json.dumps(obj).encode()
+        assert json.loads(data) == obj
+        own = case in ("one image", "four images", "a grid first")
+        assert copied == (len(obj["images"]) if own else 0)
+
+    @pytest.mark.parametrize("grid", [False, True])
+    def test_a_generation_response_takes_the_copy(self, grid, monkeypatch):
+        """The response built from a result whose images the engine
+        encoded (``out.images.append``, as ``_append_images`` does), with
+        and without webui's grid in front: what ``json.dumps`` would have
+        sent, and ``json.dumps`` never reads an image."""
+        from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
+            GenerationResult,
+        )
+        from stable_diffusion_webui_distributed_tpu.server import api
+
+        result = GenerationResult(parameters={"prompt": "p"})
+        for i in range(4):
+            result.images.append(self.png(i))
+            result.seeds.append(i)
+            result.infotexts.append(f"p\nSeed: {i}")
+        srv = ApiServer(make_world(), state=GenerationState(),
+                        host="127.0.0.1", port=0)
+        srv.options["return_grid"] = grid
+        body = srv._generation_response(result)
+        assert len(body["images"]) == 4 + grid
+        want = json.dumps(body).encode()
+        seen, dumps = [], json.dumps
+
+        def watching(obj, *a, **kw):
+            seen.append(obj)
+            return dumps(obj, *a, **kw)
+
+        monkeypatch.setattr(api.json, "dumps", watching)
+        assert api.json_body(body) == (want, 4 + grid)
+        assert [obj["images"] for obj in seen] == [api._IMAGES_MARK]

@@ -205,6 +205,16 @@ class TestSpanTree:
             assert e["args"]["strips"] >= 1
             assert e["args"]["bytes"] > 0
 
+    @pytest.mark.parametrize("which", ["txt2img", "img2img", "profiled"])
+    def test_the_response_copies_the_engines_images(self, served, which):
+        """Through the dispatcher and the server the image is still the
+        encoder's own base64, so ``respond.serialize`` copies it into the
+        body and ``json.dumps`` does not read it (api.json_body)."""
+        part, = [e for e in served[which]
+                 if e["name"] == "respond.serialize"]
+        assert part["args"]["images_copied"] == 1
+        assert part["args"]["bytes"] > 0
+
     def test_warm_request_compiles_nothing(self, served):
         assert not [e for e in served["profiled"]
                     if e["name"] == "xla.compile"]
