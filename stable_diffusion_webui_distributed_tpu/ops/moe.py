@@ -10,25 +10,31 @@ those, and adds nothing for the absent ones. No code stands in for the
 other chips.
 
 Three products, chosen by what the call shows (:func:`choose`: the number
-of tokens, the platform, the operands' dtype and the kernels' shapes, all
-static, and whether a mesh will partition the program):
+of rows, the platform, the operands' dtype and the kernels' shapes, all
+static, and whether a mesh will partition the program). By row count:
 
-- ``T > 1`` (prefill), ``"grouped"``: tokens are sorted by the expert they
-  chose, each expert's rows padded up to a row tile, and one loop walks the
-  tiles that hold rows, each a ``(tile, d) @ (d, f)`` product against the
-  one expert the tile belongs to. An expert nobody chose is not read.
-- ``T == 1`` (a decode step), ``"loop"``: a loop over the chosen experts
-  held here (5 of 10 on average when half are held), each reading that
-  expert's three kernels once. The experts that were held but not chosen
-  are not read: that is what bounds a decoded token's bytes. Taken on a
-  CPU, for float32 operands, for widths off the lane tiling (the tests'
-  tiny presets) and under a mesh (``pjit`` does not partition a
-  ``pallas_call``).
-- ``T == 1`` on a TPU with bf16 operands and ``d`` and ``f`` multiples of
-  the lane width, no mesh, ``"kernel"``: the same sum as one pipelined
-  Pallas kernel (ops/moe_kernel.py) that reads expert ``j + 1``'s kernels
-  while expert ``j`` multiplies, where the loop's trips run one after the
-  other with nothing in flight between them. The same experts are read.
+- more rows than one row tile of 8 (every prefill: a chunk is never under
+  64 rows), ``"grouped"``: rows are sorted by the expert they chose, each
+  expert's rows padded up to a row tile, and one loop walks the tiles that
+  hold rows, each a ``(tile, d) @ (d, f)`` product against the one expert
+  the tile belongs to. An expert nobody chose is not read.
+- 1 to 8 rows (a decode step: one sequence, or the 2, 4 or 8 sequences of
+  ``cache/kv.py:SEQUENCE_BUCKETS``) on a TPU with bf16 operands and ``d``
+  and ``f`` multiples of the lane width, no mesh, ``"kernel"``: one
+  pipelined Pallas kernel (ops/moe_kernel.py) over the step's DISTINCT
+  held experts that reads expert ``j + 1``'s kernels while expert ``j``
+  multiplies. One row's picks are distinct already (:func:`_chosen`);
+  several rows' are made so and every expert takes the whole block of
+  rows, a per-row weight deciding what it adds (:func:`_block`): under one
+  row tile the grouped product would pad every expert's rows to 8 anyway,
+  one unpipelined trip an expert. The same experts are read.
+- where the kernel is not taken (a CPU, float32 operands, widths off the
+  lane tiling as the tests' tiny presets have, a mesh: ``pjit`` does not
+  partition a ``pallas_call``): one row, ``"loop"``: a loop over the chosen
+  experts held here (5 of 10 on average when half are held), each reading
+  that expert's three kernels once; 2 to 8 rows, ``"grouped"``. The experts
+  that were held but not chosen are not read: that is what bounds a decoded
+  token's bytes.
 """
 
 from __future__ import annotations
@@ -74,11 +80,16 @@ def route(logits: jax.Array, k: int, *, renormalise: bool, scale: float,
     return Routing(experts.astype(jnp.int32), top * scale)
 
 
+#: the grouped product's smallest row tile: a call of no more rows than
+#: this pads every expert's rows to all of it, and takes the kernel instead
+MIN_ROW_TILE = 8
+
+
 def row_tile(tokens: int, k: int, num_experts: int) -> int:
     """Rows of one tile of the grouped product: the power of two at or over
     the rows an expert gets on average, within [8, 256]."""
     mean = max(1, -(-tokens * k // num_experts))
-    return int(min(256, max(8, 1 << (mean - 1).bit_length())))
+    return int(min(256, max(MIN_ROW_TILE, 1 << (mean - 1).bit_length())))
 
 
 def _swiglu(x, w_gate, w_up, w_down):
@@ -159,21 +170,40 @@ def _chosen(x, routing: Routing, w_gate, w_up, w_down, first: int,
                              jnp.zeros(x.shape, jnp.float32))
 
 
+def _block(x, routing: Routing, w_gate, w_up, w_down, first: int):
+    """A step of 2-8 rows through the kernel: the step's distinct held
+    experts first (as :func:`_chosen` orders a token's held picks), each
+    with a column of per-row weights, zero where the row did not choose
+    it."""
+    rows, k = routing.experts.shape
+    count = w_gate.shape[0]
+    slots = min(rows * k, count)
+    local, held = held_mask(routing.experts, first, count)
+    weights = jnp.zeros((count, rows), jnp.float32).at[
+        jnp.where(held, local, count), jnp.arange(rows)[:, None]].add(
+            routing.weights, mode="drop")                # absent: dropped
+    chosen = jnp.any(weights != 0.0, axis=1)
+    experts = jnp.argsort(~chosen, stable=True)[:slots]  # chosen ones first
+    return moe_kernel.chosen_experts(x, experts, weights[experts],
+                                     jnp.sum(chosen), w_gate, w_up, w_down)
+
+
 def choose(platform: str, tokens: int, dtype, d: int, f: int, *,
            meshed: bool = False) -> str:
     """``"grouped"``, ``"loop"`` or ``"kernel"`` for one expert layer's
-    call, from what the call shows. The kernel wants a decode step on a
+    call, from what the call shows. The kernel wants a decode step (no
+    more rows than one row tile: a prefill chunk is never that short) on a
     TPU, the serving policy's bf16 (the only dtype run on the chip), widths
     that tile (ops/moe_kernel.py:f_tile) and a program no mesh partitions:
     ``pjit`` would run a ``pallas_call`` whole on every chip, against
     kernels it has split by expert."""
-    if tokens != 1:
+    if tokens > MIN_ROW_TILE:
         return GROUPED
     dtype = jnp.dtype(dtype)
     if (platform == "tpu" and not meshed and dtype == jnp.bfloat16
             and moe_kernel.f_tile(d, f, dtype.itemsize) is not None):
         return KERNEL
-    return LOOP
+    return LOOP if tokens == 1 else GROUPED
 
 
 def routed_experts(x: jax.Array, routing: Routing, w_gate: jax.Array,
@@ -189,6 +219,8 @@ def routed_experts(x: jax.Array, routing: Routing, w_gate: jax.Array,
     if path == GROUPED:
         return _grouped(x, routing, w_gate, w_up, w_down, first,
                         num_experts), path
+    if x.shape[0] > 1:
+        return _block(x, routing, w_gate, w_up, w_down, first), path
     return _chosen(x, routing, w_gate, w_up, w_down, first,
                    kernel=path == KERNEL), path
 
@@ -209,7 +241,9 @@ def load_counts(routing: Routing, first: int, count: int, valid=None):
 def experts_read(per_expert: jax.Array) -> jax.Array:
     """Distinct held experts one call's rows chose, from its
     :func:`load_counts` ``(..., count)``: the experts whose kernels the
-    call streams. The grouped product reads an expert once however many
-    rows chose it, so a step of several sequences reads this many, not its
-    picks; a step of one token reads as many as it has picks."""
+    call streams. Every product reads an expert once however many rows
+    chose it (the kernel walks a step's distinct experts, the grouped
+    product an expert's tiles), so a step of several sequences reads this
+    many, not its picks; a step of one token reads as many as it has
+    picks."""
     return jnp.sum(per_expert > 0, axis=-1).astype(jnp.int32)

@@ -1,5 +1,6 @@
 """A decode step's sum over the chosen experts held here, as one pipelined
-Pallas kernel.
+Pallas kernel: one row (a step of one sequence) or a block of 2-8 rows (a
+step of several sequences, every distinct expert taking the whole block).
 
 ``ops/moe.py:_chosen`` walks the chosen experts in a ``fori_loop`` whose
 trip count is dynamic. On the TPU such a loop runs its trips strictly one
@@ -24,7 +25,19 @@ tile])``, cast to the operand dtype, times ``Wd[tile, :]``; the float32 sum
 of ``weight * that`` stays in VMEM (the output block, whose index never
 changes) and is written once.
 
-Only experts that are chosen and held are read, with one exception: a token
+A step of several rows (``rows`` = 2-8: the sequences of one request, one
+token each) runs the same grid over the step's DISTINCT held experts, at
+most ``slots = min(rows * k, held)`` of them. Sorting rows by expert would
+buy nothing under one row tile, so every expert multiplies the whole block
+``(rows, d)`` (padded to the bf16 sublane tile) and a per-row weight says
+what it adds: ``out[r] += W[slot, r] * E_slot(x)[r]``, with ``W[slot, r]``
+zero where row ``r`` did not choose the expert. The weights lie flat in
+SMEM, ``rows`` a slot; a row that did not choose an expert is SELECTED out,
+not multiplied by zero, so an overflowed product of a row that never asked
+for it cannot reach that row. One row is ``rows == 1``, ``slots == k``: a
+token's picks are distinct already, and its one weight stays a scalar.
+
+Only experts that are chosen and held are read, with one exception: a step
 none of whose experts is held still costs the read of ONE tile of local
 expert 0 (the pipeline fetches the first step's blocks before it can know),
 which no product uses.
@@ -53,8 +66,12 @@ _WEIGHT_VMEM = 24 * 2 ** 20
 #: compiles both published shapes with 1 MiB; the decode span does not move
 #: between 1, 2 and 4)
 _VMEM_SLACK = 4 * 2 ** 20
-#: experts' reads XLA is told one call costs (:func:`_call`)
+#: experts' reads XLA is told one call costs, whatever its rows
+#: (:func:`_call`)
 _COST_EXPERTS = 3
+#: rows of one block: the bf16 sublane tile, and the most a call takes
+#: (ops/moe.py:choose sends at most one row tile of 8 here)
+ROW_BLOCK = 16
 
 
 def f_tile(d: int, f: int, itemsize: int) -> int | None:
@@ -71,10 +88,21 @@ def f_tile(d: int, f: int, itemsize: int) -> int | None:
     return None
 
 
+def _column(weights_ref, first, rows: int, block: int):
+    """``(block, 1)`` float32: the ``rows`` SMEM scalars from ``first`` down
+    the sublanes, zero under them."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+    column = jnp.zeros((block, 1), jnp.float32)
+    for r in range(rows):
+        column = jnp.where(row == r, weights_ref[first + r], column)
+    return column
+
+
 def _kernel(ids_ref, held_ref, weights_ref, x_ref, gate_ref, up_ref,
             down_ref, out_ref):
     step, tile = pl.program_id(0), pl.program_id(1)
     slot = step - (pl.num_programs(0) - held_ref[0])   # idle steps first
+    rows = weights_ref.shape[0] // ids_ref.shape[0]    # static: 1, or 2-8
 
     @pl.when((step == 0) & (tile == 0))
     def _zero():
@@ -91,7 +119,14 @@ def _kernel(ids_ref, held_ref, weights_ref, x_ref, gate_ref, up_ref,
                        if x.dtype == jnp.bfloat16 else None))
         hidden = (jax.nn.silu(dot(x, gate_ref[...]))
                   * dot(x, up_ref[...])).astype(x.dtype)
-        out_ref[...] += weights_ref[slot] * dot(hidden, down_ref[...])
+        if rows == 1:
+            out_ref[...] += weights_ref[slot] * dot(hidden, down_ref[...])
+        else:
+            # a row that did not choose this expert gets nothing from it,
+            # not ``0 * y``: its product may have overflowed
+            weight = _column(weights_ref, slot * rows, rows, x.shape[0])
+            out_ref[...] += jnp.where(
+                weight != 0.0, weight * dot(hidden, down_ref[...]), 0.0)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -115,19 +150,38 @@ def _call(ids, held, weights, x, w_gate, w_up, w_down, *, tile: int,
 
     Three reads is inside the better stretch of both (the Qwen3-Next share
     holds 2.5 of a token's 10 experts on average and its call takes as long
-    as 3.1 reads would; the Laguna share holds 5 and takes 5.6)."""
+    as 3.1 reads would; the Laguna share holds 5 and takes 5.6).
+
+    A call of several rows claims the same three, though it reads many more
+    (26.5 of Mellum2's 64 for 4 rows of 8 by ``held * (1 - (1 - k /
+    experts) ** rows)``; 24.3 measured). The same two spans of a traced
+    four-image request, by the reads claimed (one v5e, my chip runs, PR 46;
+    the grouped loop: 1 401.3):
+
+        claimed      2       3       4     13.2   26.5 (the shapes')   53
+        Mellum2   1 144.7 1 155.0 1 162.5 1 187.2     1 190.2       1 186.2
+
+    The call's own time is the same in every column (842-844 ms); the
+    Linears' ``copy-done`` waits are 29 ms shorter at three than at the
+    shapes' figure (and 15 shorter again at two, where Qwen3-Next's
+    one-row call above does 38 ms worse). And at 13.2 and 26.5 XLA's own
+    compiler dies (a null dereference in its memory assignment's
+    ``BestFitRepacker``) on the executable benchmarks/verify_reference.py
+    builds for that configuration, whole at 592 positions; it compiles at
+    3 and at 53. So the rule stays a constant, not the shapes' figure."""
     from jax.experimental.pallas import tpu as pltpu
 
-    k = ids.shape[0]
-    _, d, f = w_gate.shape
+    slots = ids.shape[0]
+    block_rows, d = x.shape
+    f = w_gate.shape[2]
     tiles = f // tile
 
     def block(step, t, ids, held, weights):
         """(local expert, tile of f) grid step ``(step, t)`` reads. The
-        ``k - held`` idle steps come first and sit on the first block a
-        held slot will read, which the pipeline fetches as the call
+        ``slots - held`` idle steps come first and sit on the first block
+        a held slot will read, which the pipeline fetches as the call
         starts: they pass while that read is in flight."""
-        slot = step - (k - held[0])
+        slot = step - (slots - held[0])
         return ids[jnp.maximum(slot, 0)], jnp.where(slot >= 0, t, 0)
 
     def columns(step, t, *prefetched):
@@ -138,14 +192,14 @@ def _call(ids, held, weights, x, w_gate, w_up, w_down, *, tile: int,
         expert, t = block(step, t, *prefetched)
         return expert, t, 0
 
-    whole = pl.BlockSpec((1, d), lambda step, t, *_: (0, 0))
+    whole = pl.BlockSpec((block_rows, d), lambda step, t, *_: (0, 0))
     itemsize = w_gate.dtype.itemsize
     return pl.pallas_call(
         _kernel,
-        out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((block_rows, d), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(k, tiles),
+            grid=(slots, tiles),
             in_specs=[whole,
                       pl.BlockSpec((None, d, tile), columns),
                       pl.BlockSpec((None, d, tile), columns),
@@ -167,17 +221,27 @@ def chosen_experts(x: jax.Array, experts: jax.Array, weights: jax.Array,
                    w_down: jax.Array, *,
                    interpret: bool | None = None) -> jax.Array:
     """``sum_j weights[j] E_experts[j](x)`` over the first ``held`` of the
-    ``k`` slots: ``x`` ``(1, d)``, ``experts`` ``(k,)`` local ids with the
-    held ones first, ``weights`` ``(k,)`` float32, ``held`` an int32
-    scalar, the kernels stacked as ``moe.routed_experts`` takes them.
-    Float32 ``(1, d)``. ``interpret`` is for a compile without the chip."""
+    slots: ``x`` ``(rows, d)`` with ``rows`` at most :data:`ROW_BLOCK`,
+    ``experts`` ``(slots,)`` local ids with the held ones first, ``held``
+    an int32 scalar, the kernels stacked as ``moe.routed_experts`` takes
+    them. ``weights`` is float32 ``(slots,)`` for one row and ``(slots,
+    rows)`` for several, zero where a row did not choose the slot's expert
+    (such a row gets nothing from it). Float32 ``(rows, d)``.
+    ``interpret`` is for a compile without the chip."""
+    rows = x.shape[0]
     _, d, f = w_gate.shape
     tile = f_tile(d, f, w_gate.dtype.itemsize)
     if tile is None:
         raise ValueError(f"experts of {d} x {f} do not tile")
+    if rows > ROW_BLOCK:
+        raise ValueError(f"{rows} rows are over one block of {ROW_BLOCK}")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return _call(experts.astype(jnp.int32),
-                 jnp.reshape(held, (1,)).astype(jnp.int32),
-                 weights.astype(jnp.float32), x, w_gate, w_up, w_down,
-                 tile=tile, interpret=interpret)
+    weights = weights.astype(jnp.float32)
+    if rows > 1:
+        x = jnp.pad(x, ((0, ROW_BLOCK - rows), (0, 0)))
+        weights = weights.reshape(-1)
+    out = _call(experts.astype(jnp.int32),
+                jnp.reshape(held, (1,)).astype(jnp.int32), weights, x,
+                w_gate, w_up, w_down, tile=tile, interpret=interpret)
+    return out if rows == 1 else out[:rows]

@@ -159,12 +159,12 @@ def test_folded_upsample_site_holds_no_gather(one_chip, batch, side, channels,
 
 @pytest.mark.parametrize("precision", ["default", "highest"])
 @pytest.mark.parametrize("d,f", [(3072, 1024), (2048, 512), (3584, 1024),
-                                 (2048, 1536)])
+                                 (2048, 1536), (2304, 896)])
 def test_chosen_experts_kernel_compiles_for_v5e(one_chip, d, f, precision):
-    """The four published expert shapes (Laguna-S-2.1's, Qwen3-Next's,
-    Xing4.0's, whose blocks are the largest: 22.0 MB double-buffered, and
-    LFM2's, the widest: two tiles of 768 an expert), 128 held, 10 chosen,
-    bf16; also under benchmarks/verify_reference.py's
+    """The five published expert shapes (Laguna-S-2.1's, Qwen3-Next's,
+    Xing4.0's, LFM2's, the widest: two tiles of 768 an expert, and
+    Mellum2's, whose blocks are the largest: one tile of 896, 24.8 MB
+    double-buffered), 128 held, 10 chosen, bf16; also under benchmarks/verify_reference.py's
     ``default_matmul_precision("highest")``, which must not reach the
     kernel's dots."""
     def on_chip(shape, dtype):
@@ -177,6 +177,28 @@ def test_chosen_experts_kernel_compiles_for_v5e(one_chip, d, f, precision):
             on_chip((1, d), jnp.bfloat16), on_chip((10,), jnp.int32),
             on_chip((10,), jnp.float32), on_chip((), jnp.int32), wide, wide,
             on_chip((128, f, d), jnp.bfloat16))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+@pytest.mark.parametrize("rows", [2, 4, 8])
+def test_block_of_rows_kernel_compiles_for_v5e(one_chip, rows, precision):
+    """A decode step of 2, 4 or 8 sequences at Mellum2's shape (2304 x 896,
+    all 64 held, 8 a token): ``min(rows * 8, 64)`` grid steps of a whole
+    expert, the rows padded to one bf16 sublane tile, a weight a slot and
+    row in SMEM."""
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    d, f, held = 2304, 896, 64
+    slots = min(rows * 8, held)
+    wide = on_chip((held, d, f), jnp.bfloat16)
+    with jax.default_matmul_precision(precision):
+        text = _compiled_text(
+            lambda *a: moe_kernel.chosen_experts(*a, interpret=False),
+            on_chip((rows, d), jnp.bfloat16), on_chip((slots,), jnp.int32),
+            on_chip((slots, rows), jnp.float32), on_chip((), jnp.int32),
+            wide, wide, on_chip((held, f, d), jnp.bfloat16))
     assert "tpu_custom_call" in text
 
 
@@ -224,11 +246,11 @@ def test_stream_mixer_kernels_compile_for_v5e(one_chip, stored):
     ("decode", "sd15_lfm2_expander", 10.8, 8, 64, 4),
     ("prefill", "sd15_lfm2_expander", 10.8, 0, 64, 4),
     # at a 2 560-slot cache (a 2 048-token instruction): eight expert
-    # kernels at one sequence; four sequences a step take the grouped
-    # product (no kernel) and donate four forked caches, 92 MB; the
-    # instruction's one chunk, twice the window, needs 0.9 GB of scores
+    # kernels at one sequence and at four sequences a step (the whole
+    # block of rows an expert), which donate four forked caches, 92 MB;
+    # the instruction's one chunk, twice the window, needs 0.9 GB of scores
     ("decode", "sd15_mellum2_expander", 7.6, 8, 64, 23),
-    ("decode4", "sd15_mellum2_expander", 7.6, 0, 64, 92),
+    ("decode4", "sd15_mellum2_expander", 7.6, 8, 64, 92),
     ("prefill2048", "sd15_mellum2_expander", 7.6, 0, 1000, 23),
 ])
 def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(

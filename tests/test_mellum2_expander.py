@@ -628,3 +628,39 @@ class TestThePublishedShare:
         assert moe.row_tile(4, 8, 64) == 8      # a trip an expert
         ATTENTION.clear()
         EXPANDER.clear()
+
+    @pytest.mark.parametrize("sequences", [2, 4, 8])
+    def test_on_the_chip_that_step_takes_the_kernel(self, monkeypatch,
+                                                    sequences):
+        """The same trace with the chooser told it is on a TPU (nothing
+        compiles): all eight expert layers take the pipelined kernel with
+        the whole block of rows, as the cell's ``m2_expert_kernel_sites``
+        reads it, and a prefill chunk keeps the grouped product."""
+        share = configs.sd15_mellum2_expander().expander
+        module = lm.DecoderLM(share, dtype=jnp.bfloat16)
+        s = jax.ShapeDtypeStruct
+        cache = {name: [s((sequences,) + shape, jnp.bfloat16)
+                        for shape in rows]
+                 for name, rows in lm.cache_shapes(share, 2560).items()}
+        shapes = jax.eval_shape(lambda: module.init(
+            jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
+            jnp.int32(4), lm.empty_cache(share, 8, jnp.float32)))
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        EXPANDER.clear()
+        tokens = jnp.zeros((sequences,), jnp.int32)
+        jax.eval_shape(
+            lambda v, c: module.apply(v, tokens, jnp.int32(2200),
+                                      jnp.int32(sequences), c,
+                                      sequences=True), shapes, cache)
+        assert EXPANDER.summary()["expert_products"] == {
+            "kernel": 8, "loop": 0, "grouped": 0}
+        one = {name: [s(shape, jnp.bfloat16) for shape in rows]
+               for name, rows in lm.cache_shapes(share, 2560).items()}
+        jax.eval_shape(
+            lambda v, c: module.apply(v, jnp.zeros((64,), jnp.int32),
+                                      jnp.int32(2048), jnp.int32(64), c),
+            shapes, one)
+        assert EXPANDER.summary()["expert_products"] == {
+            "kernel": 8, "loop": 0, "grouped": 8}
+        ATTENTION.clear()
+        EXPANDER.clear()

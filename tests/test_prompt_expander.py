@@ -360,6 +360,16 @@ class TestTheChosenExpertsKernel:
         ("tpu", 1, jnp.bfloat16, 3072, 1024, True, moe.LOOP),
         ("tpu", 64, jnp.bfloat16, 3072, 1024, False, moe.GROUPED),
         ("cpu", 2, jnp.float32, 32, 16, True, moe.GROUPED),
+        # a decode step of 2, 4 or 8 sequences: no more than one row tile
+        ("tpu", 2, jnp.bfloat16, 2304, 896, False, moe.KERNEL),
+        ("tpu", 4, jnp.bfloat16, 2304, 896, False, moe.KERNEL),
+        ("tpu", 8, jnp.bfloat16, 2304, 896, False, moe.KERNEL),
+        ("tpu", 9, jnp.bfloat16, 2304, 896, False, moe.GROUPED),
+        ("tpu", 64, jnp.bfloat16, 2304, 896, False, moe.GROUPED),
+        ("tpu", 4, jnp.bfloat16, 2304, 896, True, moe.GROUPED),
+        ("cpu", 4, jnp.bfloat16, 2304, 896, False, moe.GROUPED),
+        ("tpu", 4, jnp.float32, 2304, 896, False, moe.GROUPED),
+        ("tpu", 4, jnp.bfloat16, 32, 16, False, moe.GROUPED),
     ])
     def test_the_choice_is_made_from_what_the_call_shows(
             self, platform, tokens, dtype, d, f, meshed, path):
@@ -369,7 +379,8 @@ class TestTheChosenExpertsKernel:
     @pytest.mark.parametrize("platform,meshed,tokens,path", [
         ("cpu", False, 1, "loop"), ("cpu", False, 6, "grouped"),
         ("tpu", False, 1, "kernel"), ("tpu", True, 1, "loop"),
-        ("tpu", False, 6, "grouped")])
+        ("tpu", False, 6, "kernel"), ("tpu", True, 6, "grouped"),
+        ("tpu", False, 9, "grouped")])
     def test_an_expert_layer_counts_the_product_it_was_traced_with(
             self, monkeypatch, platform, meshed, tokens, path):
         """``serving.expander`` ``expert_products``: one count a layer a
@@ -409,6 +420,164 @@ class TestTheChosenExpertsKernel:
         assert EXPANDER.summary()["expert_products"] == {
             "kernel": 0, "loop": 3, "grouped": 3}
         assert moe.row_tile(100000, 10, 256) == 256
+
+
+class TestTheBlockOfRowsKernel:
+    """ops/moe_kernel.py at 2-8 rows in interpret mode: a step's distinct
+    held experts, each taking the whole block of rows under a per-row
+    weight (``moe._block``), against the grouped product and a plain sum a
+    row. The share holds experts 4-11 of 16, so some picks are absent."""
+
+    D, F, EXPERTS, FIRST, HELD = 128, 256, 16, 4, 8
+
+    def _kernels(self, dtype=jnp.float32, seed=5):
+        ks = jax.random.split(jax.random.key(seed), 3)
+        d, f, e = self.D, self.F, self.EXPERTS
+        return ((jax.random.normal(ks[0], (e, d, f)) / d ** 0.5).astype(dtype),
+                (jax.random.normal(ks[1], (e, d, f)) / d ** 0.5).astype(dtype),
+                (jax.random.normal(ks[2], (e, f, d)) / f ** 0.5).astype(dtype))
+
+    def _routing(self, rows, case):
+        """(rows, k) picks, distinct within a row."""
+        held = list(range(self.FIRST, self.FIRST + self.HELD))
+        absent = [e for e in range(self.EXPERTS) if e not in held]
+        if case == "one_expert":
+            picks = [[9]] * rows
+        elif case == "all_distinct":       # 2 rows: 4 picks ... 8 rows: 16
+            picks = [[2 * r, 2 * r + 1] for r in range(rows)]
+        elif case == "one_row_none_held":
+            picks = [[held[(3 * r) % 8], held[(3 * r + 1) % 8]]
+                     for r in range(rows)]
+            picks[rows // 2] = absent[:2]
+        else:                               # "none_held"
+            picks = [[absent[r % 8], absent[(r + 3) % 8]]
+                     for r in range(rows)]
+        experts = jnp.array(picks, jnp.int32)
+        weights = 0.2 + jax.random.uniform(jax.random.key(rows),
+                                           experts.shape)
+        return moe.Routing(experts, weights)
+
+    def _share(self, kernels):
+        return [w[self.FIRST:self.FIRST + self.HELD] for w in kernels]
+
+    def _a_row_at_a_time(self, x, routing, share):
+        out = np.zeros(x.shape, np.float64)
+        for r in range(x.shape[0]):
+            for e, w in zip(np.asarray(routing.experts[r]) - self.FIRST,
+                            np.asarray(routing.weights[r])):
+                if 0 <= e < self.HELD:
+                    out[r] += w * np.asarray(moe._swiglu(
+                        x[r:r + 1], *(k[e] for k in share)))[0]
+        return out
+
+    @pytest.mark.parametrize("case", ["one_expert", "all_distinct",
+                                      "one_row_none_held", "none_held"])
+    @pytest.mark.parametrize("rows", [2, 4, 8])
+    def test_the_block_equals_the_grouped_product_and_a_sum_a_row(
+            self, rows, case):
+        share = self._share(self._kernels())
+        x = jax.random.normal(jax.random.key(rows + 10), (rows, self.D))
+        routing = self._routing(rows, case)
+        got = moe._block(x, routing, *share, self.FIRST)
+        assert got.shape == (rows, self.D) and got.dtype == jnp.float32
+        grouped = moe._grouped(x, routing, *share, self.FIRST, self.EXPERTS)
+        np.testing.assert_allclose(got, grouped, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(
+            got, self._a_row_at_a_time(x, routing, share), rtol=1e-4,
+            atol=1e-5)
+        _, held = moe.held_mask(routing.experts, self.FIRST, self.HELD)
+        for r in range(rows):           # no held pick: exactly nothing
+            assert bool(np.any(np.asarray(got[r]))) == bool(held[r].any())
+
+    @pytest.mark.parametrize("precision", [None, "highest"])
+    def test_bf16_operands_accumulate_in_float32(self, precision):
+        share = self._share(self._kernels(jnp.bfloat16))
+        x = jax.random.normal(jax.random.key(3), (4, self.D)).astype(
+            jnp.bfloat16)
+        routing = self._routing(4, "one_row_none_held")
+        want = moe._grouped(x, routing, *share, self.FIRST, self.EXPERTS)
+        with jax.default_matmul_precision(precision or "default"):
+            got = moe._block(x, routing, *share, self.FIRST)
+        np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+
+    def test_a_row_gets_nothing_from_an_expert_it_did_not_choose(self):
+        """Expert 9's product overflows for every row of the block; only
+        the rows that chose it may see that: selected out, not ``0 * inf``."""
+        wg, wu, wd = self._kernels()
+        wd = wd.at[9].set(jnp.inf)
+        share = self._share((wg, wu, wd))
+        x = jax.random.normal(jax.random.key(4), (4, self.D))
+        routing = moe.Routing(
+            jnp.array([[9, 5], [5, 6], [6, 9], [7, 1]], jnp.int32),
+            jnp.full((4, 2), 0.5, jnp.float32))
+        got = np.asarray(moe._block(x, routing, *share, self.FIRST))
+        assert not np.isfinite(got[0]).any() and not np.isfinite(got[2]).any()
+        assert np.isfinite(got[1]).all() and np.isfinite(got[3]).all()
+        clean = moe._block(x, routing, *self._share((wg, wu, wd.at[9].set(
+            0.0))), self.FIRST)
+        np.testing.assert_array_equal(got[[1, 3]], np.asarray(clean)[[1, 3]])
+
+    @pytest.mark.parametrize("rows,k,held,slots", [
+        (2, 8, 64, 16), (4, 8, 64, 32), (8, 8, 64, 64), (8, 10, 64, 64),
+        (4, 4, 8, 8)])
+    def test_the_grid_is_the_most_distinct_experts_a_step_can_choose(
+            self, rows, k, held, slots):
+        """``min(rows * k, held)`` grid steps, the block padded to the bf16
+        sublane tile, and the one-row call's cost estimate (three reads:
+        ``moe_kernel._call`` says why not the reads the shapes let
+        expect)."""
+        d, f = 128, 256
+        s = jax.ShapeDtypeStruct
+        wide = s((held, d, f), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(
+            lambda *a: moe._block(a[0], moe.Routing(a[1], a[2]), *a[3:], 0))(
+            s((rows, d), jnp.bfloat16), s((rows, k), jnp.int32),
+            s((rows, k), jnp.float32), wide, wide,
+            s((held, f, d), jnp.bfloat16))
+        (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "jit"
+                   and e.params["name"] == "_call"]
+        ids, n, weights, x = (v.aval.shape for v in call.invars[:4])
+        assert (ids, n, weights, x) == ((slots,), (1,), (slots * rows,),
+                                        (moe_kernel.ROW_BLOCK, d))
+        (kernel,) = [e for e in call.params["jaxpr"].eqns
+                     if e.primitive.name == "pallas_call"]
+        assert kernel.params["grid_mapping"].grid == (slots, 1)
+        cost = kernel.params["cost_estimate"]
+        assert (cost.flops, cost.transcendentals, cost.bytes_accessed) == (
+            18 * d * f, 3 * f, 18 * d * f)
+
+    def test_one_row_keeps_the_kernel_it_had(self):
+        """The body is one for every row count, and one row's trace must
+        stay what it was: a scalar weight a slot, ``k`` grid steps, three
+        reads claimed, and no select on a weight column."""
+        s = jax.ShapeDtypeStruct
+        d, f, k = 128, 256, 4
+        wide = s((16, d, f), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(lambda *a: moe_kernel.chosen_experts(*a))(
+            s((1, d), jnp.bfloat16), s((k,), jnp.int32),
+            s((k,), jnp.float32), s((), jnp.int32), wide, wide,
+            s((16, f, d), jnp.bfloat16))
+        (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "jit"]
+        assert [v.aval.shape for v in call.invars[:4]] == [
+            (k,), (1,), (k,), (1, d)]
+        assert [e.primitive.name for e in jaxpr.jaxpr.eqns] == [
+            "reshape", "jit"]               # no pad, no slice of the result
+        (kernel,) = [e for e in call.params["jaxpr"].eqns
+                     if e.primitive.name == "pallas_call"]
+        assert kernel.params["grid_mapping"].grid == (k, 1)
+        cost = kernel.params["cost_estimate"]
+        assert (cost.flops, cost.transcendentals, cost.bytes_accessed) == (
+            18 * d * f, 3 * f, 18 * d * f)
+
+        def names(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield eqn.primitive.name
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from names(sub)
+
+        body = list(names(kernel.params["jaxpr"]))
+        assert "select_n" not in body and "iota" not in body
+        assert body.count("dot_general") == 3 and body.count("swap") == 2
 
 
 class TestTheShareOfALayer:
@@ -596,6 +765,28 @@ class TestEnginePath:
         assert len(stats["expert_tokens"]) == 3
         assert 0 < routed <= 3 * 4 * (31 + 5 + 64 + 5 + 64)
         assert stats["expert_load_max_over_mean"] >= 1.0
+
+    def test_one_sequence_decodes_with_the_plain_functions_lowered_text(
+            self, engine):
+        """A solo preset with expert layers: what one image runs is
+        ``lm.decode_chunk_fn`` under its old key, lowered text and all;
+        nothing of the several-sequence path is in its way."""
+        EXPANDER.clear()
+        engine.txt2img(payload())
+        (key,) = [k for k in engine.executable_keys()
+                  if k[0] == "expand_decode_chunk"]
+        _, steps, capacity = key                # no sequence count
+        assert EXPANDER.summary()["sequences"] == 1
+        cache = lm.empty_cache(CFG, capacity, jnp.float32)
+        args = (engine.params["expander"], cache, jnp.int32(0),
+                jnp.int32(36), jax.random.key(0), jnp.float32(1.0))
+        served = engine.expander._decode_fn(capacity).lower(*args).as_text()
+        EXPANDER.clear()
+        plain = jax.jit(lm.decode_chunk_fn(engine.expander.module, steps),
+                        donate_argnums=(1,)).lower(*args).as_text()
+        assert served == plain
+        assert EXPANDER.summary()["expert_products"] == {
+            "kernel": 0, "loop": 3, "grouped": 0}
 
     def test_another_seed_gets_another_expansion(self, engine):
         a = engine.txt2img(payload())

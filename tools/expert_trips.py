@@ -1,19 +1,23 @@
-"""What one chosen expert costs a decode step: the loop against the
-pipelined kernel (PERF.md section 6, PR 34).
+"""What one chosen expert costs a decode step: XLA's loops against the
+pipelined kernel (PERF.md section 6, PR 34 and PR 46).
 
-    chiprun -- python3 tools/expert_trips.py [--budgets 3 6 12 24 40]
+    chiprun -- python3 tools/expert_trips.py [--rows 1 4 8]
+        [--budgets 3 6 12 24 40]
 
 For each published expert shape (Laguna-S-2.1's 3072 x 1024 and
 Qwen3-Next's 2048 x 512, 128 of ``num_experts`` held, 10 a token; Xing4.0's
 3584 x 1024, 16 of 64 held, 4 a token; LFM2's 2048 x 1536, all 64 held, 4 a
-token; bf16) one expert layer's routed sum
+token; Mellum2's 2304 x 896, all 64 held, 8 a token; bf16) and each
+``--rows`` (the sequences a step carries) one expert layer's routed sum
 runs ``--steps`` times in a device-side scan, each step on its own seeded
-draw of the token's experts among the layer's and fed the step before's
-result, once through ``ops/moe.py``'s loop and once through
-``ops/moe_kernel.py`` at each ``--budgets`` MiB of VMEM for the kernels'
-blocks (the ``f`` tile follows from the budget). A row says microseconds a
-step and a chosen-and-held expert, GB/s over the bytes those experts'
-kernels hold, and the kernel's largest difference from the loop over the
+draw of every row's experts among the layer's (the rows draw
+independently) and fed the step before's result, once through
+``ops/moe.py``'s XLA product (the loop over a token's chosen experts at one
+row, the grouped product at several) and once through ``ops/moe_kernel.py``
+at each ``--budgets`` MiB of VMEM for the kernels' blocks (the ``f`` tile
+follows from the budget). A row says microseconds a step and a DISTINCT
+chosen-and-held expert (a trip), GB/s over the bytes those experts' kernels
+hold, and the kernel's largest difference from XLA's product over the
 steps' results. Written to ``chiprun_out/expert_trips.json``; a CPU is
 refused: a time comes from the chip.
 """
@@ -33,7 +37,8 @@ OUT = os.path.join(REPO, "chiprun_out", "expert_trips.json")
 SHAPES = (("laguna", 3072, 1024, 256, 128, 10),
           ("qwen3next", 2048, 512, 512, 128, 10),
           ("xing4", 3584, 1024, 64, 16, 4),
-          ("lfm2", 2048, 1536, 64, 64, 4))
+          ("lfm2", 2048, 1536, 64, 64, 4),
+          ("mellum2", 2304, 896, 64, 64, 8))
 REPEATS = 5
 
 
@@ -41,6 +46,7 @@ def main() -> int:
     parser = argparse.ArgumentParser()
     parser.add_argument("--steps", type=int, default=256)
     parser.add_argument("--budgets", type=int, nargs="*", default=[24])
+    parser.add_argument("--rows", type=int, nargs="*", default=[1])
     parser.add_argument("--seed", type=int, default=0)
     args = parser.parse_args()
 
@@ -55,7 +61,7 @@ def main() -> int:
         print("expert_trips.py times the chip: no TPU here", file=sys.stderr)
         return 1
 
-    def layer(name, d, f, experts, held, k):
+    def layer(name, d, f, experts, held, k, tokens):
         keys = jax.random.split(jax.random.key(args.seed), 5)
 
         def kernel(key, shape, fan_in):
@@ -65,18 +71,26 @@ def main() -> int:
         w = (kernel(keys[0], (held, d, f), d),
              kernel(keys[1], (held, d, f), d),
              kernel(keys[2], (held, f, d), f))
-        x0 = jax.random.normal(keys[3], (1, d), jnp.bfloat16)
-        logits = jax.random.normal(keys[4], (args.steps, experts))
-        routing = moe.route(logits, k, renormalise=True, scale=1.0)
-        held_trips = int(np.sum(np.asarray(routing.experts) < held))
+        x0 = jax.random.normal(keys[3], (tokens, d), jnp.bfloat16)
+        logits = jax.random.normal(keys[4], (args.steps * tokens, experts))
+        routing = moe.Routing(*(
+            a.reshape(args.steps, tokens, k)
+            for a in moe.route(logits, k, renormalise=True, scale=1.0)))
+        chosen = np.asarray(routing.experts)
+        held_trips = sum(len(set(step[step < held])) for step in chosen)
+
+        def product(use_kernel, x, route, w):
+            if tokens == 1:
+                return moe._chosen(x, route, *w, 0, kernel=use_kernel)
+            if use_kernel:
+                return moe._block(x, route, *w, 0)
+            return moe._grouped(x, route, *w, 0, experts)
 
         def run(use_kernel):
             def steps(x, w, routing):
                 def step(x, route):
-                    out = moe._chosen(x, moe.Routing(route[0][None],
-                                                     route[1][None]),
-                                      *w, 0, kernel=use_kernel)
-                    return (0.9 * x + 0.1 * out).astype(x.dtype), out[0]
+                    out = product(use_kernel, x, moe.Routing(*route), w)
+                    return (0.9 * x + 0.1 * out).astype(x.dtype), out
 
                 return jax.lax.scan(step, x, tuple(routing))[1]
 
@@ -97,13 +111,13 @@ def main() -> int:
 
         def row(path, seconds, **more):
             return dict(
-                shape=name, path=path, steps=args.steps,
+                shape=name, rows=tokens, path=path, steps=args.steps,
                 held_trips=held_trips,
                 us_a_step=1e6 * seconds / args.steps,
                 us_a_trip=1e6 * seconds / held_trips,
                 gb_s=held_trips * expert_bytes / seconds / 1e9, **more)
 
-        rows.append(row("loop", seconds))
+        rows.append(row("loop" if tokens == 1 else "grouped", seconds))
         for budget in args.budgets:
             moe_kernel._WEIGHT_VMEM = budget * 2 ** 20
             tile = moe_kernel.f_tile(d, f, 2)
@@ -116,13 +130,17 @@ def main() -> int:
                 max_abs=float(jnp.max(jnp.abs(want)))))
         return rows
 
-    rows = [r for shape in SHAPES for r in layer(*shape)]
+    rows = []
+    for shape in SHAPES:
+        for tokens in args.rows:
+            made = layer(*shape, tokens)
+            for r in made:
+                print(json.dumps(r), flush=True)
+            rows += made
     os.makedirs(os.path.dirname(OUT), exist_ok=True)
     with open(OUT, "w") as out:
         json.dump({"device": jax.devices()[0].device_kind, "rows": rows},
                   out, indent=1)
-    for r in rows:
-        print(json.dumps(r))
     return 0
 
 
