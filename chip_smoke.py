@@ -400,22 +400,27 @@ def phase_mesh(report: Report, counter: CompileCounter, family, policy,
     def generate(name: str, engine, n: int, first_seed: int):
         payload = GenerationPayload(**dict(
             calibration.model_dump(), batch_size=n, seed=first_seed))
-        # the decoded device array is the last thing that can say where
-        # the output lived; Engine.txt2img returns PNG strings
+        # the decode executable's output is the last thing that can say
+        # where the output lived; Engine.txt2img returns PNG strings
         shardings = []
-        queue = engine._queue_decoded
+        decode_fn = engine._decode_u8_fn
 
-        def watch(latents, *args):
-            entries = queue(latents, *args)
-            shardings.extend(entry[0].sharding for entry in entries)
-            return entries
+        def watch(*shape):
+            decode = decode_fn(*shape)
 
-        engine._queue_decoded = watch
+            def decode_and_note(*args):
+                imgs = decode(*args)
+                shardings.append(imgs.sharding)
+                return imgs
+
+            return decode_and_note
+
+        engine._decode_u8_fn = watch
         xla0 = counter.counts["executables"]
         t0 = time.perf_counter()
         result = engine.txt2img(payload)   # returns fetched PNGs
         seconds = time.perf_counter() - t0
-        del engine._queue_decoded
+        del engine._decode_u8_fn
         report.fact(f"run {name}", f"batch {n}, seed {first_seed}, "
                     f"{seconds:.2f} s, "
                     f"{counter.counts['executables'] - xla0} XLA "

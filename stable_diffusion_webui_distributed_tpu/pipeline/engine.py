@@ -2107,26 +2107,15 @@ class Engine:
             if upscale is not None:
                 # image-space (ESRGAN-family) hires: decode -> model
                 # upscale to target -> re-encode (webui's non-latent path);
-                # rows are DISTINCT images, so bound VAE scratch by slicing
-                # each stage under the decode pixel budget
-                from stable_diffusion_webui_distributed_tpu.runtime \
-                    .config import env_int
-
-                budget = env_int("SDTPU_DECODE_PIXELS",
-                                 self._DECODE_PIXEL_BUDGET)
-                per_lo = max(1, budget // max(1, payload.width
-                                              * payload.height))
-                per_hi = max(1, budget // max(1, tw * th))
+                # rows are DISTINCT images: one image a VAE dispatch at
+                # each stage, as in _queue_decoded
+                decode = self._decode_fn(payload.width, payload.height, 1)
+                encode = self._encode_image_fn(tw, th, 1)
                 with trace.STATS.timer("hires_upscale"):
-                    ups = []
-                    for s in range(0, n, min(per_lo, per_hi)):
-                        e = min(n, s + min(per_lo, per_hi))
-                        imgs = self._decode_fn(
-                            payload.width, payload.height, e - s)(
-                                self.params["vae"], latents[s:e])
-                        ups.append(self._encode_image_fn(tw, th, e - s)(
-                            self.params["vae"], upscale(imgs, tw, th)))
-                    up = ups[0] if len(ups) == 1 else jnp.concatenate(ups)
+                    ups = [encode(self.params["vae"], upscale(
+                        decode(self.params["vae"], latents[s:s + 1]),
+                        tw, th)) for s in range(n)]
+                    up = ups[0] if n == 1 else jnp.concatenate(ups)
         if up is None:
             up = jax.image.resize(latents, (n, th // f, tw // f, C),
                                   _latent_resize_method(payload.hr_upscaler))
@@ -2256,38 +2245,30 @@ class Engine:
         self._flush_decoded(out, payload, pending)
         return out
 
-    def _append_decoded(self, out, payload, latents, pos, n, width, height):
-        """Dispatch decode + materialize immediately (single-group path)."""
-        self._flush_decoded(out, payload, self._queue_decoded(
-            latents, pos, n, width, height))
-
-    #: default decode micro-batch budget: images decoded per dispatch =
-    #: max(1, budget // (width*height)). The (f32-pinned) VAE decoder's
-    #: temps are ~16 bytes/pixel/image at its widest layer — batch-8
-    #: 1024x1024 in one dispatch needs 16 GB of HBM scratch (measured OOM,
-    #: PERF.md round 3); per-dispatch slicing caps scratch while the slices
-    #: still pipeline back-to-back on device.
-    _DECODE_PIXEL_BUDGET = 1024 * 1024
-
     def _queue_decoded(self, latents, pos, n, width, height):
         """Dispatch the VAE decode WITHOUT waiting: the returned device
         arrays materialize later, so the decode of group i pipelines with
         the denoise of group i+1 (SURVEY.md §7 hard part #6 overlap).
 
-        Returns a LIST of pending entries — the batch is decoded in
-        micro-batches under a pixel budget (see _DECODE_PIXEL_BUDGET) so
-        decoder scratch stays bounded at SDXL sizes.
+        Returns a LIST of pending entries, one an image. ONE IMAGE A
+        DISPATCH A CHIP, enqueued back to back: ``_flush_decoded`` (and the
+        dispatcher's merge stage) walk the entries in order, so image i's
+        copy-down and PNG encode run on the host while the device decodes
+        image i+1. The f32 decoder costs more an image in a batch than
+        alone at every size measured on a v5e (PERF.md section 5, "the
+        decode by batch"): four 512x512 images in one dispatch take 7.56 x
+        one image's time and 6.9 x its scratch (120.5 ms and 3 770 MB
+        against 15.9 and 549), four 256x256 27.3 ms against 17.0 as four
+        dispatches, and two dispatches of one cost exactly twice one. One
+        executable key a size serves every batch size. Latents split over
+        a mesh's ``dp`` axis (``_place_batch``) go as ONE partitioned
+        dispatch, every chip decoding its own rows: a row sliced out of
+        them would be decoded by every chip.
 
         ``n`` is how many images to KEEP; latents may carry extra
-        pad-and-drop rows. A final short slice is padded back up to the
-        micro-batch row count (repeating its last row) whenever a
-        full-size slice ran before it, so every dispatch in the loop
-        shares ONE compiled executable; a batch small enough to fit in a
-        single slice keys on its actual row count (that key IS the only
-        one, so there is nothing to reuse)."""
-        from stable_diffusion_webui_distributed_tpu.runtime.config import (
-            env_int,
-        )
+        pad-and-drop rows, which one chip never decodes."""
+        import warnings
+
         from stable_diffusion_webui_distributed_tpu.serving.metrics import (
             METRICS,
         )
@@ -2298,81 +2279,77 @@ class Engine:
         # same request once the depth-1 decode pipeline interleaves flushes
         incomplete = getattr(self, "_adaptive_incomplete", False)
         self._adaptive_incomplete = False
+        keep = min(n, latents.shape[0])
+        # rows a dispatch: all of them where they are split over chips
+        split = latents.sharding.shard_shape(latents.shape)[0] < len(latents)
+        per = len(latents) if split else 1
+        starts = range(0, keep, per)
         # FLOPs-per-image denominator: every kept row is one output image,
         # counted at the single point all decode paths (engine loops, the
         # serving dispatcher, the stage pipeline) funnel through
-        METRICS.record_unet_images(min(n, latents.shape[0]))
-        budget = env_int("SDTPU_DECODE_PIXELS", self._DECODE_PIXEL_BUDGET)
-        per = max(1, budget // max(1, width * height))
+        METRICS.record_decoded(rows=keep, dispatches=len(starts))
+        decode = self._decode_u8_fn(width, height, per)
         entries = []
-        for s in range(0, min(n, latents.shape[0]), per):
-            rows = latents[s:s + per]
-            keep = min(n - s, rows.shape[0])
-            if s > 0 and rows.shape[0] < per:
-                pad = jnp.repeat(rows[-1:], per - rows.shape[0], axis=0)
-                rows = jnp.concatenate([rows, pad], axis=0)
-            decode = self._decode_u8_fn(width, height, rows.shape[0])
-            import warnings as _warnings
-
-            with trace.STATS.timer("vae_decode_dispatch"), \
-                    _warnings.catch_warnings():
-                # the latent rows are f32 and the output is uint8 pixels, so
-                # the declared donation can never alias an output buffer —
-                # JAX flags that at first lowering; expected, not actionable
-                _warnings.filterwarnings(
-                    "ignore", message="Some donated buffers were not usable")
-                imgs = decode(self.params["vae"], rows)
-            entries.append((imgs, pos + s, keep, width, height,
-                            incomplete))
+        with warnings.catch_warnings():
+            # the latent rows are f32 and the output is uint8 pixels, so
+            # the declared donation can never alias an output buffer —
+            # JAX flags that at first lowering; expected, not actionable
+            warnings.filterwarnings(
+                "ignore", message="Some donated buffers were not usable")
+            for s in starts:
+                with trace.STATS.timer("vae_decode_dispatch"):
+                    imgs = decode(self.params["vae"], latents[s:s + per])
+                # a whole slice of an array is the array: no copy at per 1
+                entries += [(imgs[r:r + 1], pos + s + r, width, height,
+                             incomplete) for r in range(min(per, keep - s))]
         return entries
 
     @staticmethod
-    def _fetch_decoded(imgs_dev) -> np.ndarray:
-        """A decoded batch on the host, inside the caller's
+    def _fetch_decoded(img_dev) -> np.ndarray:
+        """A decode dispatch's image on the host, inside the caller's
         ``vae_decode_fetch``: the wait for the decode executable (the device
         busy), then the copy down (the device idle)."""
-        with obs_spans.span("decode.wait"):
-            jax.block_until_ready(imgs_dev)
-        with obs_spans.span("fetch.copy", bytes=int(imgs_dev.nbytes)):
-            return np.asarray(imgs_dev)
+        with obs_spans.span("decode.wait", rows=int(img_dev.shape[0])):
+            jax.block_until_ready(img_dev)
+        with obs_spans.span("fetch.copy", bytes=int(img_dev.nbytes)):
+            return np.asarray(img_dev)[0]
 
     def _flush_decoded(self, out, payload, pending) -> None:
-        for imgs_dev, pos, n, width, height, incomplete in pending:
+        for img_dev, i, width, height, incomplete in pending:
             with trace.STATS.timer("vae_decode_fetch"):
-                imgs = self._fetch_decoded(imgs_dev)
-            self._append_images(out, payload, imgs, pos, n, width, height,
-                                incomplete=incomplete)
+                img = self._fetch_decoded(img_dev)
+            self._append_image(out, payload, img, i, width, height,
+                               incomplete=incomplete)
 
-    def _append_images(self, out, payload, imgs, pos, n, width, height,
-                       incomplete=False):
+    def _append_image(self, out, payload, img, i, width, height,
+                      incomplete=False):
+        """Encode image ``i`` of the request into its gallery."""
         pinned = payload.subseed_strength > 0 or payload.same_seed
-        for j in range(n):
-            i = pos + j
-            seed_i = payload.seed + (0 if pinned else i)
-            sub_i = payload.subseed + (0 if payload.same_seed else i)
-            prompt_i = payload.prompt
-            if payload.all_prompts and i < len(payload.all_prompts):
-                prompt_i = payload.all_prompts[i]
-            with obs_spans.span("png_encode") as sp:
-                png, strips = encode_b64png(imgs[j])
-                if sp is not None:
-                    sp.attrs["bytes"] = len(png) * 3 // 4  # base64 -> PNG
-                    sp.attrs["strips"] = strips
-            out.images.append(png)
-            out.seeds.append(int(seed_i))
-            out.subseeds.append(int(sub_i))
-            out.prompts.append(prompt_i)
-            out.negative_prompts.append(payload.negative_prompt)
-            text = build_infotext(
-                payload, int(seed_i), int(sub_i), self.model_name,
-                width, height, prompt_override=prompt_i)
-            if incomplete:
-                # DPM adaptive hit its attempt backstop before reaching
-                # sigma_min — flag the partially-denoised result where
-                # webui users read generation provenance
-                text += ", DPM adaptive: incomplete"
-            out.infotexts.append(text)
-            out.worker_labels.append("")
+        seed_i = payload.seed + (0 if pinned else i)
+        sub_i = payload.subseed + (0 if payload.same_seed else i)
+        prompt_i = payload.prompt
+        if payload.all_prompts and i < len(payload.all_prompts):
+            prompt_i = payload.all_prompts[i]
+        with obs_spans.span("png_encode") as sp:
+            png, strips = encode_b64png(img)
+            if sp is not None:
+                sp.attrs["bytes"] = len(png) * 3 // 4  # base64 -> PNG
+                sp.attrs["strips"] = strips
+        out.images.append(png)
+        out.seeds.append(int(seed_i))
+        out.subseeds.append(int(sub_i))
+        out.prompts.append(prompt_i)
+        out.negative_prompts.append(payload.negative_prompt)
+        text = build_infotext(
+            payload, int(seed_i), int(sub_i), self.model_name,
+            width, height, prompt_override=prompt_i)
+        if incomplete:
+            # DPM adaptive hit its attempt backstop before reaching
+            # sigma_min — flag the partially-denoised result where
+            # webui users read generation provenance
+            text += ", DPM adaptive: incomplete"
+        out.infotexts.append(text)
+        out.worker_labels.append("")
 
 
 def _box1d(a: np.ndarray, r: int, axis: int) -> np.ndarray:

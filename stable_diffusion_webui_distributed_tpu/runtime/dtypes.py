@@ -27,10 +27,10 @@ class Policy:
     use_remat: bool = False
     # Decoder conv dtype override (SDTPU_DECODE_DTYPE=bf16): runs the VAE
     # decoder's convs in bf16 while GroupNorm statistics and the final
-    # conv_out stay f32 (models/vae.py). Halves decode HBM scratch — the
-    # round-3 b8 1024² OOM was 16 GB of f32 conv temps — and halves
-    # decode bytes fetched per dispatch under the pixel budget. Off by
-    # default: banding risk is unvalidated without real weights
+    # conv_out stay f32 (models/vae.py). Halves the decode's HBM scratch
+    # and the bytes it moves (one image a dispatch:
+    # pipeline/engine.py:_queue_decoded). Off by default: banding risk
+    # is unvalidated without real weights
     # (README "numerical-parity status"); measure via sweep cell
     # c2-decodebf16 before promoting.
     decode_in_bf16: bool = False

@@ -660,7 +660,7 @@ class StubBackend:
                     time.sleep(0.01)
             if self.interrupted:
                 break
-            # per-image seed/prompt arithmetic mirrors Engine._append_images
+            # per-image seed/prompt arithmetic mirrors Engine._append_image
             seed_i = payload.seed + (0 if pinned else i)
             sub_i = payload.subseed + (0 if payload.same_seed else i)
             prompt_i = payload.prompt

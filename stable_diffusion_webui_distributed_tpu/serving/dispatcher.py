@@ -45,8 +45,6 @@ import time
 import uuid
 from typing import Callable, Dict, List, Optional
 
-import numpy as np
-
 from stable_diffusion_webui_distributed_tpu.fleet import (
     admission as fleet_admission,
 )
@@ -1318,51 +1316,51 @@ class ServingDispatcher:
             latents, 0, built["b_raw"], built["width"], built["height"])
 
     def _group_merge(self, g: _Group, built: Dict, entries) -> None:
-        """Merge stage: block on the decoded images, then split the
-        coalesced batch back into per-ticket results (bucket crops,
-        gallery assembly, journal records) and finish the progress
-        record."""
+        """Merge stage: walk the decode dispatches in order (one image
+        each, ``engine._queue_decoded``): wait for image i and copy it
+        down, crop it if bucketed and encode it into the ticket that owns
+        it, all while the device decodes image i+1. A ticket's result
+        (gallery, journal record) is set once all of ITS images are in; a
+        cancelled ticket's images are still fetched, and dropped. Finishes
+        the progress record."""
         from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
             GenerationResult,
         )
 
         engine = self._engine()
         live, counts = built["live"], built["counts"]
-        b_raw, b_run = built["b_raw"], built["b_run"]
-        ragged_mode = built["ragged_mode"]
-        with trace.STATS.timer("vae_decode_fetch"):
-            parts = [engine._fetch_decoded(e[0])[:e[2]] for e in entries]
-            with obs_spans.span("fetch.join", slices=len(parts)):
-                imgs = np.concatenate(parts, axis=0)
+        # ragged rows are TOP-aligned (valid latent rows form a prefix);
+        # only the width snap center-crops
+        crop = self.bucketer.crop_ragged if built["ragged_mode"] \
+            else self.bucketer.crop
         jr_on = obs_journal.enabled()
-        if jr_on:
-            obs_journal.emit("decoded", live[0].request_id,
-                             images=b_raw, batch_run=b_run)
-
-        with obs_spans.span("merge.split", requests=len(live),
-                            images=b_raw):
-            off = 0
-            for t, n_p in zip(live, counts):
-                rows = imgs[off:off + n_p]
-                off += n_p
-                if t.cancelled.is_set():
-                    t.result = self._empty_result(t)
-                    continue
-                out = GenerationResult(parameters=t.payload.model_dump())
+        # the batch's kept rows in order: the ticket that owns the row (its
+        # place in the group, the ticket, its gallery), the row's index in
+        # the gallery, whether it is the ticket's last
+        owners = []
+        for k, (t, n_p) in enumerate(zip(live, counts)):
+            out = GenerationResult(parameters=t.payload.model_dump())
+            owners += [(k, t, out, j, j + 1 == n_p) for j in range(n_p)]
+        for (img_dev, *_), (k, t, out, j, last) in zip(entries, owners):
+            with trace.STATS.timer("vae_decode_fetch"):
+                img = engine._fetch_decoded(img_dev)
+            if jr_on and (k, j) == (0, 0):
+                obs_journal.emit("decoded", live[0].request_id,
+                                 images=built["b_raw"],
+                                 batch_run=built["b_run"])
+            with obs_spans.span("merge.split", request=k, image=j):
                 ow, oh = t.payload.width, t.payload.height
-                if t.bucketed and ragged_mode:
-                    # ragged rows are TOP-aligned (valid latent rows form
-                    # a prefix); only the width snap center-crops
-                    rows = np.stack(
-                        [self.bucketer.crop_ragged(im, ow, oh)
-                         for im in rows])
-                elif t.bucketed:
-                    rows = np.stack(
-                        [self.bucketer.crop(im, ow, oh) for im in rows])
-                engine._append_images(out, t.payload, rows, 0, n_p, ow, oh)
-                t.result = out
-                if jr_on:
-                    obs_journal.emit("merged", t.request_id, images=n_p)
+                if not t.cancelled.is_set():
+                    engine._append_image(
+                        out, t.payload,
+                        crop(img, ow, oh) if t.bucketed else img, j, ow, oh)
+                if last and t.cancelled.is_set():
+                    t.result = self._empty_result(t)
+                elif last:
+                    t.result = out
+                    if jr_on:
+                        obs_journal.emit("merged", t.request_id,
+                                         images=j + 1)
         engine.state.finish()
 
     # -- result fix-up -----------------------------------------------------

@@ -23,7 +23,9 @@ Which attention the UNet's sites took is counted where they are traced:
 ``models/unet.py:Attention`` (and by ``models/lm.py:Attention`` for the
 resident language model's sites). What that model's ``expand`` stage did
 with tokens, experts and its cache is :data:`EXPANDER`
-(``summary()["expander"]``). Which form the UNet's and the VAE decoder's
+(``summary()["expander"]``). How many images a VAE decode dispatch carried
+is ``summary()["decode"]`` (``rows`` over ``dispatches``, fed by
+``Engine._queue_decoded``). Which form the UNet's and the VAE decoder's
 upsample sites took is :data:`UPSAMPLE` (``summary()["upsample"]``), fed
 by ``ops/upsample.py``. How often a request's plan met a kept sigma
 ladder or a kept time-id embedding (runtime/kept.py) is :data:`PLAN`
@@ -74,8 +76,11 @@ class DispatchMetrics:
             #: sum of (bucket px / requested px) per bucketed request
             self.padding_ratio_total = 0.0  # guarded-by: _lock
             self.padding_ratio_count = 0  # guarded-by: _lock
-            #: images decoded to outputs
+            #: images decoded to outputs (pad-and-drop rows are not)
             self.unet_images = 0  # guarded-by: _lock
+            #: VAE decode executables enqueued for them: images over
+            #: dispatches is what a decode dispatch carried
+            self.decode_dispatches = 0  # guarded-by: _lock
             #: resolved precision name -> device dispatches / requests
             #: carried (pipeline/precision.py; "" = caller didn't say)
             self.precision_dispatches: Dict[str, int] = defaultdict(int)  # guarded-by: _lock
@@ -126,9 +131,12 @@ class DispatchMetrics:
             self.queue_wait_total += float(seconds)
             self.queue_wait_count += 1
 
-    def record_unet_images(self, n: int) -> None:
+    def record_decoded(self, rows: int, dispatches: int) -> None:
+        """``rows`` kept images went to the VAE as ``dispatches``
+        executables (``Engine._queue_decoded``)."""
         with self._lock:
-            self.unet_images += int(n)
+            self.unet_images += int(rows)
+            self.decode_dispatches += int(dispatches)
 
     # -- readers ----------------------------------------------------------
 
@@ -184,6 +192,8 @@ class DispatchMetrics:
                                       / self.padding_ratio_count
                                       if self.padding_ratio_count else None),
                 "unet_images": self.unet_images,
+                "decode": {"dispatches": self.decode_dispatches,
+                           "rows": self.unet_images},
                 # per-precision dispatch mix (flows into /internal/status
                 # under serving.precision; ISSUE 7 observability)
                 "precision": {

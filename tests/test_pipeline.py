@@ -25,6 +25,7 @@ from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
 from stable_diffusion_webui_distributed_tpu.runtime.interrupt import (
     GenerationState,
 )
+from stable_diffusion_webui_distributed_tpu.serving.metrics import METRICS
 
 
 def init_params(family):
@@ -111,17 +112,44 @@ class TestTxt2Img:
         engine.txt2img(p)
         assert calls  # stale epoch -> re-encoded
 
-    def test_decode_microbatch_slices_match(self, engine, monkeypatch):
-        """Forcing the decode pixel budget down to one image per dispatch
-        must yield the same images and ordering as a single-dispatch
-        decode (SDXL-scale scratch bounding, engine._queue_decoded)."""
-        p = GenerationPayload(prompt="mb", steps=3, width=32, height=32,
-                              batch_size=3, seed=77)
-        whole = engine.txt2img(p)
-        monkeypatch.setenv("SDTPU_DECODE_PIXELS", str(32 * 32))
+    @pytest.mark.parametrize("size,batch", [(32, 4), (64, 4), (32, 3)])
+    def test_decode_one_image_a_dispatch(self, engine, monkeypatch, size,
+                                         batch):
+        """A batch decodes one image a dispatch at every size (PERF.md
+        section 5, "the decode by batch"), through ONE executable key,
+        and yields the images, seeds and order of the one-dispatch decode
+        (engine._queue_decoded); serving.decode counts rows and
+        dispatches."""
+        from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
+            array_to_b64png,
+        )
+        p = GenerationPayload(prompt="mb", steps=3, width=size, height=size,
+                              batch_size=batch, seed=77)
+        seen = []
+        queue = engine._queue_decoded
+
+        def watch(latents, *args):
+            entries = queue(jnp.array(latents), *args)  # rows are donated
+            seen.append((latents, entries))
+            return entries
+
+        monkeypatch.setattr(engine, "_queue_decoded", watch)
+        before = METRICS.summary()["decode"]
+        had = set(engine._cache)
         sliced = engine.txt2img(p)
-        assert sliced.images == whole.images
-        assert sliced.seeds == whole.seeds
+        made = [k for k in set(engine._cache) - had if k[0] == "decode-u8"]
+        after = METRICS.summary()["decode"]
+        assert after["rows"] - before["rows"] == batch
+        assert after["dispatches"] - before["dispatches"] == batch
+        (latents, entries), = seen
+        assert [(e[0].shape, e[1]) for e in entries] == [
+            ((1, size, size, 3), i) for i in range(batch)]
+        assert all(k[1:4] == (size, size, 1) for k in made), made
+        assert ("decode-u8", size, size, 1, TINY.name) in engine._cache
+        whole = np.asarray(engine._decode_u8_fn(size, size, batch)(
+            engine.params["vae"], latents))
+        assert sliced.images == [array_to_b64png(img) for img in whole]
+        assert sliced.seeds == [77 + i for i in range(batch)]
 
     def test_remainder_group_pad_and_drop(self, engine):
         """7 images at batch_size 2: the final odd group reuses the
@@ -465,7 +493,13 @@ class TestMeshEngine:
         p = GenerationPayload(prompt="mesh cow", steps=4, width=32,
                               height=32, batch_size=4, seed=21)
         a = engine.txt2img(p)
+        before = METRICS.summary()["decode"]
         b = sharded.txt2img(p)
+        after = METRICS.summary()["decode"]
+        # a batch split over dp decodes as ONE partitioned dispatch, every
+        # chip its own rows (engine._queue_decoded)
+        assert after["rows"] - before["rows"] == 4
+        assert after["dispatches"] - before["dispatches"] == 1
         ia = np.stack([decode(x) for x in a.images]).astype(np.int32)
         ib = np.stack([decode(x) for x in b.images]).astype(np.int32)
         # identical placement-independent math; allow 1 LSB for reduction
@@ -512,8 +546,12 @@ class TestMeshEngine:
                          state=GenerationState(), mesh=mesh8)
         p = GenerationPayload(prompt="odd", steps=4, width=32, height=32,
                               batch_size=3, seed=22)
+        before = METRICS.summary()["decode"]
         r = sharded.txt2img(p)
+        after = METRICS.summary()["decode"]
         assert len(r.images) == 3
+        # not split over dp: one image a dispatch, as on one chip
+        assert after["dispatches"] - before["dispatches"] == 3
 
 
 class TestRefiner:

@@ -579,7 +579,7 @@ class TestJsonBody:
     @pytest.mark.parametrize("grid", [False, True])
     def test_a_generation_response_takes_the_copy(self, grid, monkeypatch):
         """The response built from a result whose images the engine
-        encoded (``out.images.append``, as ``_append_images`` does), with
+        encoded (``out.images.append``, as ``_append_image`` does), with
         and without webui's grid in front: what ``json.dumps`` would have
         sent, and ``json.dumps`` never reads an image."""
         from stable_diffusion_webui_distributed_tpu.pipeline.payload import (

@@ -141,6 +141,17 @@ class TestMetrics:
         assert m.coalesce_factor() == 0.0
 
 
+    def test_decode_block_counts_kept_rows_a_dispatch(self):
+        m = DispatchMetrics()
+        assert m.summary()["decode"] == {"dispatches": 0, "rows": 0}
+        m.record_decoded(rows=4, dispatches=4)
+        m.record_decoded(rows=1, dispatches=1)
+        assert m.summary()["decode"] == {"dispatches": 5, "rows": 5}
+        assert m.summary()["unet_images"] == 5
+        m.clear()
+        assert m.summary()["decode"] == {"dispatches": 0, "rows": 0}
+
+
 class TestEtaOverheads:
     def test_padding_scales_and_wait_adds(self):
         cal = EtaCalibration(avg_ipm=6.0)
@@ -307,6 +318,141 @@ class TestContinuousBatching:
         monkeypatch.setenv("SDTPU_WARMUP", "0")
         report = warmup_engine(None)  # engine untouched when disabled
         assert report["skipped"] is True
+
+
+class TestDecodeDispatch:
+    def test_engine_batch_goes_one_image_a_dispatch(self, engine):
+        """The engine's own path (``_flush_decoded``): a batch of four is
+        four decode dispatches of one image through ONE executable key,
+        with the images, seeds and order of each image generated alone
+        (tests/test_pipeline.py, a slow module, holds the same batch
+        against the one-dispatch decode)."""
+        p = payload(batch_size=4, seed=77)
+        before, had = METRICS.summary()["decode"], set(engine._cache)
+        got = engine.txt2img(p)
+        after = METRICS.summary()["decode"]
+        assert {k: after[k] - before[k] for k in after} == {
+            "dispatches": 4, "rows": 4}
+        assert all(k[3] == 1 for k in set(engine._cache) - had
+                   if k[0] == "decode-u8")
+        assert ("decode-u8", 32, 32, 1, TINY.name) in engine._cache
+        alone = [engine.generate_range(p, i, 1) for i in range(4)]
+        assert got.seeds == [77, 78, 79, 80]
+        assert got.images == [r.images[0] for r in alone]
+        assert got.infotexts == [r.infotexts[0] for r in alone]
+
+
+class TestMergeStage:
+    """The dispatcher's merge stage walks the decode dispatches one image
+    at a time: image i is copied down, cropped and encoded into the
+    ticket that owns it before image i+1 is awaited
+    (dispatcher._group_merge)."""
+
+    @staticmethod
+    def _pair(engine, bucketer, tag, cancel=False):
+        from stable_diffusion_webui_distributed_tpu.obs import (
+            journal as obs_journal, spans as obs_spans,
+        )
+
+        disp = ServingDispatcher(engine, bucketer=bucketer, window=0.6)
+        lead = payload(seed=21, batch_size=2, request_id=f"{tag}-lead")
+        follow = payload(width=24, height=32, seed=31, batch_size=2,
+                         prompt="a crop", request_id=f"{tag}-follow")
+        results = {}
+        if cancel:
+            # once the batch is on the device: its rows are all decoded
+            decode = disp._group_decode
+
+            def cancel_then_decode(*args):
+                assert disp.cancel(follow.request_id)
+                return decode(*args)
+
+            disp._group_decode = cancel_then_decode
+
+        def run(name, p):
+            results[name] = disp.submit(p)
+
+        threads = [threading.Thread(target=run, args=("lead", lead)),
+                   threading.Thread(target=run, args=("follow", follow))]
+        before = METRICS.summary()["decode"]
+        threads[0].start()
+        time.sleep(0.1)      # the first to arrive leads
+        threads[1].start()
+        for t in threads:
+            t.join()
+        after = METRICS.summary()["decode"]
+        traces = {tr.request_id: tr for tr in obs_spans.TRACER.finished()}
+        spans = sorted(traces[lead.request_id].spans, key=lambda sp: sp.t0)
+        return {
+            "results": results, "lead": lead, "follow": follow,
+            "spans": spans,
+            "decode": {k: after[k] - before[k] for k in after},
+            "journal": {
+                name: [e["event"] for e in obs_journal.JOURNAL.events_for(
+                    p.request_id)]
+                for name, p in (("lead", lead), ("follow", follow))},
+        }
+
+    @pytest.fixture(scope="class")
+    def pair(self, engine, bucketer):
+        from stable_diffusion_webui_distributed_tpu.obs import (
+            journal as obs_journal,
+        )
+
+        mp = pytest.MonkeyPatch()
+        mp.setenv("SDTPU_JOURNAL", "1")
+        obs_journal.JOURNAL.clear()
+        try:
+            yield self._pair(engine, bucketer, "merge")
+        finally:
+            obs_journal.JOURNAL.clear()
+            mp.undo()
+
+    def test_coalesced_as_one_dispatch(self, pair):
+        device = [sp for sp in pair["spans"] if sp.name == "dispatch.device"]
+        assert [sp.attrs["requests"] for sp in device] == [2]
+
+    def test_image_i_is_encoded_before_image_i_plus_1_is_awaited(self, pair):
+        tail = [sp for sp in pair["spans"]
+                if sp.name in ("decode.wait", "png_encode")]
+        assert [sp.name for sp in tail] == ["decode.wait", "png_encode"] * 4
+        assert all(sp.attrs["rows"] == 1 for sp in tail
+                   if sp.name == "decode.wait")
+        assert not [sp for sp in pair["spans"] if sp.name == "fetch.join"]
+
+    def test_decode_counters_grow_by_rows_and_dispatches(self, pair):
+        assert pair["decode"] == {"dispatches": 4, "rows": 4}
+
+    @pytest.mark.parametrize("who", ["lead", "follow"])
+    def test_results_match_the_request_served_alone(self, pair, engine,
+                                                    bucketer, who):
+        solo = ServingDispatcher(engine, bucketer=bucketer, window=0.0)
+        alone = solo.submit(pair[who].model_copy(
+            update={"request_id": None}))
+        got = pair["results"][who]
+        assert got.seeds == alone.seeds == [pair[who].seed + i
+                                            for i in range(2)]
+        assert got.infotexts == alone.infotexts
+        assert got.images == alone.images
+
+    def test_journal_order(self, pair):
+        lead, follow = pair["journal"]["lead"], pair["journal"]["follow"]
+        assert lead[-3:] == ["decoded", "merged", "completed"]
+        assert follow[-2:] == ["merged", "completed"]
+        assert "decoded" not in follow
+
+    def test_cancelled_ticket_is_fetched_and_dropped(self, engine, bucketer):
+        got = self._pair(engine, bucketer, "merge-cancel", cancel=True)
+        dropped = got["results"]["follow"]
+        assert dropped.images == []
+        assert dropped.parameters.get("cancelled") is True
+        assert got["decode"] == {"dispatches": 4, "rows": 4}
+        names = [sp.name for sp in got["spans"]
+                 if sp.name in ("decode.wait", "png_encode")]
+        assert names == ["decode.wait", "png_encode"] * 2 \
+            + ["decode.wait"] * 2
+        kept = got["results"]["lead"]
+        assert kept.seeds == [21, 22] and len(kept.images) == 2
 
 
 class TestPrecisionDispatch:

@@ -15,12 +15,10 @@ class TestSweepCells:
         import sweep
 
         for name, cell in sweep.CELLS.items():
-            cfg_n, pol_kwargs, chunk, *rest = cell
+            cfg_n, pol_kwargs, chunk = cell
             assert 1 <= cfg_n <= 5, name
             assert isinstance(pol_kwargs, dict), name
             assert chunk > 0, name
-            if rest:
-                assert isinstance(rest[0], dict), name
 
 
 @pytest.mark.slow

@@ -38,7 +38,6 @@ TXT2IMG_SPANS = {
     "vae_decode_dispatch": "dispatch.device",
     "vae_decode_fetch": "dispatch.device", "png_encode": None,
     "decode.wait": "vae_decode_fetch", "fetch.copy": "vae_decode_fetch",
-    "fetch.join": "vae_decode_fetch",
     "respond.serialize": "http.respond", "respond.write": "http.respond",
     "xla.compile": None,
 }

@@ -65,20 +65,13 @@ CELLS = {
     "c4-bf16":    (4, {}, 5),
     "c5-bf16":    (5, {}, 5),
     # hires 2048² second pass: 65536-token SD1.5 self-attention is the
-    # quadratic blowup flash attention exists for; decode4m doubles the
-    # VAE micro-batch pixel budget (decode runs bf16-conv/f32-GroupNorm,
-    # so scratch per pixel is half the round-3 OOM estimate)
+    # quadratic blowup flash attention exists for
     "c5-flash":   (5, {"attention": "flash"}, 10),
-    # 4M-pixel decode micro-batches are only safe with bf16 conv temps
-    # (f32 at 4.2 Mpx is ~8 GB scratch — the round-3 OOM class)
-    "c5-decode4m": (5, {"decode_bf16": True}, 10,
-                    {"SDTPU_DECODE_PIXELS": "4194304"}),
-    # bf16 decoder convs (f32 GroupNorm/conv_out): halves the decode
-    # scratch that OOM'd round 3's b8 1024² decode and halves decode HBM
-    # bytes; quality vs f32 must be eyeballed with real weights before
-    # this becomes a default
-    "c2-decodebf16": (2, {"decode_bf16": True}, 10,
-                      {"SDTPU_DECODE_PIXELS": "4194304"}),
+    # bf16 decoder convs (f32 GroupNorm/conv_out): halves the decode's
+    # scratch and HBM bytes (the decode runs one image a dispatch);
+    # quality vs f32 must be eyeballed with real weights before this
+    # becomes a default
+    "c2-decodebf16": (2, {"decode_bf16": True}, 10),
     # dynamic W8A8 transformer linears (ops/quant.py): the int8-MXU lever
     # from PERF.md's roofline; throughput row only — image fidelity needs
     # real weights to judge
@@ -115,11 +108,9 @@ def run_cell(name):
 
     enable_compilation_cache()
 
-    cfg_n, pol_kwargs, chunk, *rest = CELLS[name]
+    cfg_n, pol_kwargs, chunk = CELLS[name]
     dtypes.TPU = _policy(**pol_kwargs)  # bench._make_engine reads dtypes.TPU
     os.environ["SDTPU_CHUNK"] = str(chunk)
-    for key, val in (rest[0] if rest else {}).items():
-        os.environ[key] = val
 
     # SDTPU_BENCH_TINY=1 rehearses the whole sweep machinery (subprocess
     # choreography, row parsing, jsonl append) on CPU with tiny models —
