@@ -676,158 +676,6 @@ def run_serving(tiny):
     }
 
 
-def run_stages(tiny):
-    """--stages: stage-graph executor microbench (SDTPU_STAGE_GRAPH). Two
-    phases over one mixed txt2img + ControlNet workload, serial gate-off
-    then staged gate-on: plain requests coalesce through the dispatcher's
-    staged group path, ControlNet requests take the engine's staged solo
-    path with the residual tower one sigma-step ahead. The phases must
-    produce byte-identical images (the executor only reorders host work);
-    the headline value is the staged phase's stage_overlap_ratio — stage
-    host-seconds spent inside other groups' denoise windows — with the
-    chunk-compile delta and the census alarm gated at zero.
-    Counts and ratios, not wall-clock — the overlap ratio is tiny on CPU
-    (XLA CPU executes near-synchronously) but must stay > 0. Writes
-    BENCH_stages.json + a "stages" ledger row (CPU-safe)."""
-    import jax
-
-    from stable_diffusion_webui_distributed_tpu.models import configs as C
-    from stable_diffusion_webui_distributed_tpu.obs import perf as obs_perf
-    from stable_diffusion_webui_distributed_tpu.parallel import stage_graph
-    from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
-        GenerationPayload,
-    )
-    from stable_diffusion_webui_distributed_tpu.serving.bucketer import (
-        ShapeBucketer,
-    )
-    from stable_diffusion_webui_distributed_tpu.serving.dispatcher import (
-        ServingDispatcher,
-    )
-    from stable_diffusion_webui_distributed_tpu.serving.metrics import METRICS
-
-    dev = jax.devices()[0]
-    if tiny or dev.platform == "cpu":
-        ladder, steps, family = [(64, 64)], 4, C.TINY
-    else:
-        ladder, steps, family = [(512, 512)], 20, C.SD15
-    w, h = ladder[0]
-    hint = _synth_b64_image(w, h)
-
-    def payloads():
-        # 4 plain single-image requests coalescing into TWO dispatcher
-        # groups (bucket batch 2) — group A's denoise window stays open
-        # through its out-of-lock finalize while group B encodes — plus
-        # 2 ControlNet requests whose 4 images split into two engine-side
-        # groups each (the bucketer pins group_size to the bucket batch,
-        # so n_iter must exceed it for the GraphRunner to see siblings)
-        out = [GenerationPayload(prompt=f"bench stage cow {i % 2}",
-                                 steps=steps, width=w, height=h,
-                                 seed=500 + i, sampler_name="Euler a")
-               for i in range(4)]
-        out += [GenerationPayload(prompt=f"bench stage hint {i}",
-                                  steps=steps, width=w, height=h,
-                                  seed=520 + i, n_iter=4,
-                                  sampler_name="Euler a",
-                                  alwayson_scripts=_controlnet_scripts(hint))
-                for i in range(2)]
-        return out
-
-    def phase(staged):
-        engine = _make_engine(family, controlnet=True)
-        bucketer = ShapeBucketer(shapes=ladder, batches=[2])
-        dispatcher = ServingDispatcher(engine, bucketer=bucketer,
-                                       window=0.5)
-        METRICS.clear()
-        obs_perf.LEDGER.clear()
-        stage_graph.CLOCK.reset()
-        results = [None] * 6
-        errs = []
-        with _EnvPatch(SDTPU_PERF="1",
-                       SDTPU_STAGE_GRAPH="1" if staged else None):
-
-            def submit(i, p):
-                try:
-                    results[i] = dispatcher.submit(p)
-                except Exception as e:  # noqa: BLE001 — in the JSON line
-                    errs.append(repr(e))
-
-            threads = [threading.Thread(target=submit, args=(i, p))
-                       for i, p in enumerate(payloads())]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-            census = obs_perf.executables_census(engine)
-        s = METRICS.summary()
-        clock = stage_graph.CLOCK.summary()
-        groups = obs_perf.LEDGER.summary()["groups"]
-        ov = [g["stage_overlap_ratio"] for g in groups
-              if g.get("stage_overlap_ratio")]
-        return {
-            "chunk_compiles": s["compiles"].get("chunk", 0),
-            "cn_stage_compiles": (s["compiles"].get("cnres", 0)
-                                  + s["compiles"].get("cnstep", 0)),
-            "dispatches": s["dispatches"],
-            "stage_overlap_ratio": round(clock["stage_overlap_ratio"], 6),
-            "stage_s": round(clock["stage_s"], 4),
-            "overlap_s": round(clock["overlap_s"], 4),
-            "ledger_overlap_rows": len(ov),
-            "census_alarm": bool(census["alarm"]),
-            "images": [img for r in results if r is not None
-                       for img in r.images],
-            "errors": errs,
-        }
-
-    t0 = time.time()
-    serial = phase(staged=False)
-    staged = phase(staged=True)
-    wall = time.time() - t0
-    if serial["errors"] or staged["errors"]:
-        _dump_flightrec("stages")
-    byte_identical = serial["images"] == staged["images"]
-    # the compile gate: staging may REPLACE chunk-with-controls
-    # executables with cnres/cnstep pairs, but must never add chunk
-    # compiles on top of the serial phase's
-    compile_delta = staged["chunk_compiles"] - serial["chunk_compiles"]
-    for ph in (serial, staged):
-        ph["images"] = len(ph["images"])
-    out = {
-        "metric": ("tiny_" if tiny or dev.platform == "cpu" else "")
-        + "stage_overlap_ratio",
-        "value": staged["stage_overlap_ratio"],
-        "unit": "overlap_s/stage_s",
-        "vs_baseline": serial["stage_overlap_ratio"],
-        "stage_overlap_ratio": staged["stage_overlap_ratio"],
-        "stage_graph_chunk_compiles": compile_delta,
-        "chunk_compiles": staged["chunk_compiles"],
-        "cn_stage_compiles": staged["cn_stage_compiles"],
-        "byte_identical": int(byte_identical),
-        "census_alarm": int(staged["census_alarm"]),
-        "phases": {"serial": serial, "staged": staged},
-        "requests": 6,
-        "bucket": f"{w}x{h}",
-        "wall_s": round(wall, 2),
-        "device": dev.device_kind,
-        "errors": serial["errors"] + staged["errors"],
-    }
-    base = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(base, "BENCH_stages.json"), "w",
-              encoding="utf-8") as f:
-        json.dump(out, f, indent=2, sort_keys=True)
-        f.write("\n")
-    row = _ledger_row("stages", {
-        "stage_overlap_ratio": staged["stage_overlap_ratio"],
-        "stage_graph_chunk_compiles": compile_delta,
-        "chunk_compiles": staged["chunk_compiles"],
-        "byte_identical": int(byte_identical),
-        "census_alarm": int(staged["census_alarm"]),
-    }, dev.device_kind, tiny, time.time())
-    with open(os.path.join(base, "BENCH_LEDGER.jsonl"), "a",
-              encoding="utf-8") as f:
-        f.write(json.dumps(row, sort_keys=True) + "\n")
-    return out
-
-
 def run_aot(tiny):
     """--aot: AOT-artifact + warm-pool cold-start bench (SDTPU_AOT /
     SDTPU_POOL). Three phases against ONE shared artifact store under a
@@ -2858,13 +2706,6 @@ def main() -> None:
                          "host-merge counts per switch, embed-cache "
                          "survival, census silence; writes "
                          "BENCH_lora.json + a ledger row (CPU-safe)")
-    ap.add_argument("--stages", action="store_true",
-                    help="stage-graph executor microbench: mixed "
-                         "txt2img+ControlNet workload, serial vs "
-                         "SDTPU_STAGE_GRAPH — byte identity, "
-                         "stage_overlap_ratio, chunk-compile delta; "
-                         "writes BENCH_stages.json + a ledger row "
-                         "(CPU-safe)")
     ap.add_argument("--ragged", action="store_true",
                     help="ragged-dispatch microbench: mixed-height "
                          "workload under a fine ladder, a coarse classic "
@@ -2955,8 +2796,6 @@ def main() -> None:
             print(json.dumps(run_lora(tiny)))
         elif args.ragged:
             print(json.dumps(run_ragged(tiny)))
-        elif args.stages:
-            print(json.dumps(run_stages(tiny)))
         elif args.aot:
             print(json.dumps(run_aot(tiny)))
         elif args.int8:

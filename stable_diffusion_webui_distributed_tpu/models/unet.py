@@ -369,22 +369,6 @@ def cache_supported(cfg: UNetConfig) -> bool:
     return len(cfg.block_out_channels) > CACHE_SPLIT
 
 
-def control_residual_count(cfg: UNetConfig) -> int:
-    """Length of the ``control_residuals`` tuple the full forward expects.
-
-    One residual per down-path skip — conv_in, ``layers_per_block`` per
-    level, a Downsample for every level but the last — plus one for the
-    mid block. The stage-graph executor (parallel/stage_graph.py) computes
-    residuals on a separate mesh slice one sigma-step ahead of the UNet
-    and feeds them in as stage inputs; it validates the tuple against
-    this count on the host before dispatch, mirroring the traced
-    ``assert len(control_residuals) == len(skips) + 1`` inside __call__.
-    """
-    n_levels = len(cfg.block_out_channels)
-    skips = 1 + n_levels * cfg.layers_per_block + (n_levels - 1)
-    return skips + 1
-
-
 def deep_cache_shape(cfg: UNetConfig, batch: int, lat_h: int,
                      lat_w: int) -> Tuple[int, int, int, int]:
     """Shape of the cached deep feature: the up-path hidden state right

@@ -212,45 +212,6 @@ class PerfLedger:
         except Exception:  # noqa: BLE001 — telemetry must not fail dispatch
             pass
 
-    def record_stages(self, *, bucket: str, cadence: int, precision: str,
-                      lora: str = "", stage_s: float,
-                      overlap_s: float) -> None:
-        """Stage-graph accounting for one dispatch group
-        (SDTPU_STAGE_GRAPH, parallel/stage_graph.py): host seconds spent
-        in the non-denoise stages (encode / decode dispatch / merge
-        fetch) and the slice of them that overlapped OTHER groups'
-        denoise windows. Merged into the same (bucket, cadence,
-        precision, lora) accumulator as record_dispatch so the group row
-        gains a ``stage_overlap_ratio`` column; rows that never ran the
-        stage graph default both to 0 and read identically to before.
-        No-op (and never raises) when ``SDTPU_PERF`` is off."""
-        if not enabled():
-            return
-        try:
-            key = (str(bucket), int(cadence), str(precision), str(lora))
-            with self._lock:
-                g = self._groups.get(key)
-                if g is None:
-                    # stage records may land before/without a dispatch
-                    # record (finalize runs outside the device lock);
-                    # seed the same accumulator record_dispatch builds
-                    if len(self._groups) >= self.max_groups:
-                        self._groups.popitem(last=False)
-                        self._groups_evicted += 1
-                    g = {"dispatches": 0, "requests": 0, "device_s": 0.0,
-                         "true_pixels": 0, "padded_pixels": 0,
-                         "batch_raw": 0, "batch_run": 0, "masked_pixels": 0,
-                         "true_tokens": 0, "padded_tokens": 0}
-                    self._groups[key] = g
-                else:
-                    self._groups.move_to_end(key)
-                g["stage_s"] = g.get("stage_s", 0.0) \
-                    + max(0.0, float(stage_s))
-                g["stage_overlap_s"] = g.get("stage_overlap_s", 0.0) \
-                    + max(0.0, float(overlap_s))
-        except Exception:  # noqa: BLE001 — telemetry must not fail dispatch
-            pass
-
     def record_compile(self, kind: str, seconds: float,
                        source: str = "fresh_compile") -> None:
         """One compiled-stage build (``Engine._cached``); also feeds the
@@ -344,11 +305,6 @@ class PerfLedger:
         masked_px = int(g.get("masked_pixels", 0))
         true_tok = int(g.get("true_tokens", 0))
         padded_tok = int(g.get("padded_tokens", 0))
-        # stage-graph split (defaulted 0.0 so pre-stage-graph rows read
-        # identically): fraction of encode/decode/merge host seconds that
-        # ran inside another group's denoise window
-        stage_s = float(g.get("stage_s", 0.0))
-        stage_ov = float(g.get("stage_overlap_s", 0.0))
         return {
             "bucket": key[0], "cadence": key[1], "precision": key[2],
             "lora": key[3],
@@ -365,8 +321,6 @@ class PerfLedger:
             if true_px else None,
             "token_padding_ratio": (padded_tok / true_tok)
             if true_tok else None,
-            "stage_overlap_ratio": (stage_ov / stage_s) if stage_s
-            else 0.0,
             # device-memory watermark (defaulted None: CPU rows and
             # pre-telemetry rows read identically — never fabricated)
             "hbm_peak_bytes": g.get("hbm_peak_bytes"),

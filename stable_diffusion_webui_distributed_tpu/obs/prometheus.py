@@ -251,8 +251,6 @@ def clear_histograms() -> None:
         _COMPILE_LAT.clear()
     with _AOT_LOAD_LOCK:
         _AOT_LOAD_LAT.clear()
-    with _STAGE_GRAPH_LOCK:
-        _STAGE_GRAPH_LAT.clear()
     for c in FLEET_COUNTERS.values():
         c.clear()
     PRECISION_COUNTER.clear()
@@ -319,31 +317,6 @@ def observe_aot_load(kind: str, seconds: float) -> None:
 def observe_cold_start(seconds: float) -> None:
     """One fresh engine's time-to-first-image (bench arms, pool spawns)."""
     HISTOGRAMS["cold_start"].observe(seconds)
-
-
-# -- stage-graph executor (parallel/stage_graph.py) --------------------------
-
-_STAGE_GRAPH_LOCK = threading.Lock()
-#: per-stage-node host latency histograms, created on first observation.
-#: Family name is sdtpu_stage_graph_seconds, NOT the sdtpu_stage_seconds
-#: the issue sketch suggested: that family is already registered as a
-#: GAUGE (StageStats rolling stats above) and register_metric enforces
-#: one type per name — a histogram re-registration would raise.
-_STAGE_GRAPH_LAT: Dict[str, Histogram] = {}  # guarded-by: _STAGE_GRAPH_LOCK
-
-
-def observe_stage_graph(stage: str, seconds: float) -> None:
-    """One stage-graph node's host interval (encode / denoise dispatch /
-    decode dispatch / merge fetch), labeled by stage name."""
-    with _STAGE_GRAPH_LOCK:
-        h = _STAGE_GRAPH_LAT.get(stage)
-        if h is None:
-            h = Histogram(
-                "sdtpu_stage_graph_seconds",
-                "Stage-graph node host seconds by stage.",
-                labels=f'stage="{_label(stage)}"')
-            _STAGE_GRAPH_LAT[stage] = h
-    h.observe(seconds)
 
 
 # -- fleet tier (fleet/ package) --------------------------------------------
@@ -891,11 +864,6 @@ def render() -> str:
     with _AOT_LOAD_LOCK:
         aot_hists = [_AOT_LOAD_LAT[k] for k in sorted(_AOT_LOAD_LAT)]
     for i, h in enumerate(aot_hists):
-        lines.extend(h.render(header=(i == 0)))
-    with _STAGE_GRAPH_LOCK:
-        stage_hists = [_STAGE_GRAPH_LAT[k]
-                       for k in sorted(_STAGE_GRAPH_LAT)]
-    for i, h in enumerate(stage_hists):
         lines.extend(h.render(header=(i == 0)))
     _render_perf(lines)
 

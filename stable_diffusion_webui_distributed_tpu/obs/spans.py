@@ -491,20 +491,13 @@ def current_request_id() -> Optional[str]:
 
 def add_span(req: Optional[RequestTrace], name: str, t0: float, dur: float,
              attrs: Optional[Dict[str, Any]] = None,
-             parent_id: Optional[int] = None,
-             lane: Optional[int] = None) -> Optional[Span]:
+             parent_id: Optional[int] = None) -> Optional[Span]:
     """Record an already-measured interval into ``req`` from any thread
-    (the coalesce leader records queue waits for its followers).
-
-    ``lane`` overrides the span's tid: the stage-graph executor assigns
-    each stage kind a fixed negative lane (parallel/stage_graph.py LANES)
-    so /internal/trace.json renders overlapped stages from different
-    groups on per-stage swimlanes instead of one thread row."""
+    (the coalesce leader records queue waits for its followers)."""
     if req is None or not TRACER.enabled:
         return None
     sp = Span(next(_IDS), req.root_id if parent_id is None else parent_id,
-              name, t0, max(0.0, dur),
-              threading.get_ident() if lane is None else lane,
+              name, t0, max(0.0, dur), threading.get_ident(),
               dict(attrs or {}))
     TRACER.record(req, sp)
     return sp

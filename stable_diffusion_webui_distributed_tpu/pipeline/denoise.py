@@ -28,10 +28,9 @@ class Variant(NamedTuple):
     """What is static in a denoise executable; a chunk's tuple IS its cache
     key (the AOT store and the census of ``obs/perf.py`` depend on it).
     ``kind``: ``chunk`` (a scan over ``length`` steps from a traced index),
-    ``cnstep`` (one step whose ControlNet residuals are an input), ``cnres``
-    (the ControlNet tower alone, a step ahead), ``adaptive`` (one DPM-adaptive
-    attempt) or ``adaptive-pin``. Prompt, seed, cfg, adapter names and ranks,
-    ragged lengths, cadence and cutoff are data (sdtpu-lint RC001):
+    ``adaptive`` (one DPM-adaptive attempt) or ``adaptive-pin``. Prompt,
+    seed, cfg, adapter names and ranks, ragged lengths, cadence and cutoff
+    are data (sdtpu-lint RC001):
     ``lora_sig`` is "" or a ladder cell ``lora:r{rb}s{sc}``, ``precision`` a
     rung of pipeline/precision.py's ladder."""
 
@@ -57,13 +56,8 @@ class Variant(NamedTuple):
             return tuple(self)
         if self.kind == "adaptive-pin":
             return (self.kind, self.family)
-        size = (self.width, self.height, self.batch)
-        if self.kind == "adaptive":
-            return (self.kind,) + size + (
+        return (self.kind, self.width, self.height, self.batch,
                 self.n_controls, self.inpaint, self.family, self.precision)
-        units = (self.n_controls,) if self.kind == "cnres" else ()
-        return (self.kind, self.sampler, self.steps) + size + units + (
-            self.family, self.precision)
 
 
 def parse_key(key: Any) -> Optional[Variant]:
@@ -77,7 +71,7 @@ def parse_key(key: Any) -> Optional[Variant]:
 def check(v: Variant) -> None:
     """Refuse a combination no executable serves."""
     why = None
-    if v.kind not in "chunk cnstep cnres adaptive adaptive-pin".split():
+    if v.kind not in ("chunk", "adaptive", "adaptive-pin"):
         why = "unknown kind"
     elif v.ragged and v.step_cache:
         why = "ragged chunks disable the step cache"
@@ -88,20 +82,17 @@ def check(v: Variant) -> None:
         why = "ControlNet windows bypass the step cache"
     elif v.kind != "chunk" and (v.ragged or v.step_cache or v.lora_sig):
         why = f"a {v.kind} carries no ragged rows, step cache or traced LoRA"
-    elif v.kind in ("cnstep", "cnres") and (v.masked or v.inpaint):
-        why = "the staged ControlNet path covers plain txt2img only"
     if why:
         raise ValueError(f"{why}: {v}")
 
 
 class Deps(NamedTuple):
     """From the engine: the precision's module pair (weights stay jit
-    ARGUMENTS), the noise schedule, the mesh a ``cnres`` pins its rows to."""
+    ARGUMENTS), the noise schedule."""
 
     unet: Any
     controlnet: Any
     schedule: Any
-    mesh: Any = None
 
 
 class Inputs(NamedTuple):
@@ -112,8 +103,7 @@ class Inputs(NamedTuple):
     active unit. ``lora``: per-row ``[B, slots, ...]`` UNet deltas
     (models/lora.py). ``ragged``: ``(true_rows, ctx_true_u, ctx_true_c)``,
     (B,) int32 valid latent rows and context tokens. ``cadence``, ``cfg_stop``:
-    the step cache's refresh cadence and first cond-only step. ``residuals``:
-    the staged path's ControlNet residuals."""
+    the step cache's refresh cadence and first cond-only step."""
 
     ctx_u: Any
     ctx_c: Any
@@ -129,7 +119,6 @@ class Inputs(NamedTuple):
     ragged: Any = None
     cadence: Any = None
     cfg_stop: Any = None
-    residuals: Any = None
 
 
 class CachedState(NamedTuple):
@@ -222,13 +211,9 @@ def pin_unmasked(x, mask_lat, init_lat, image_keys, sigma, *domain):
     return mask_lat * x + (1 - mask_lat) * pinned
 
 
-def scan_chunk(step, state, start, length: Optional[int] = None):
-    """Steps ``start … start + length`` (without a length: one step and no
-    scan): (state, fence)."""
-    if length is None:
-        state, _ = step(state, start)
-    else:
-        state, _ = jax.lax.scan(step, state, start + jnp.arange(length))
+def scan_chunk(step, state, start, length: int):
+    """Steps ``start … start + length``: (state, fence)."""
+    state, _ = jax.lax.scan(step, state, start + jnp.arange(length))
     carry = state.carry if isinstance(state, CachedState) else state
     return state, carry.x.reshape(-1)[:1]
 
@@ -251,11 +236,11 @@ def make_denoise(v: Variant, deps: Deps, unet_params, inp: Inputs):
     def rows(xin, t, step, cond_only=False, **cache):
         latent, unet_in, tb, ctx, added = cfg_rows(
             xin, t, inp, v.inpaint, cond_only)
-        residuals = inp.residuals
+        residuals = None
         if inp.controls:
             residuals = control_residuals(
                 deps.controlnet, inp.controls, latent, tb, ctx, added, step,
-                v.steps, residuals)
+                v.steps)
         return deps.unet.apply(
             params, unet_in, tb, ctx, added, control_residuals=residuals,
             lora=inp.lora if cond_only else lora2, **ragged, **cache)
@@ -359,33 +344,8 @@ def build(v: Variant, deps: Deps) -> Callable:
 
     sigmas = kd.build_sigmas(kd.resolve_sampler(v.sampler), deps.schedule,
                              v.steps)
-    if v.kind == "cnres":
-        def pin_rows(a):
-            # pin the CFG-doubled rows to dp: left to propagation, Shardy
-            # (jax 0.9.0) may run this stage replicated and the fused chunk
-            # batch-sharded, and the two then round differently
-            if deps.mesh is None or a.shape[0] % deps.mesh.shape["dp"]:
-                return a
-            return jax.lax.with_sharding_constraint(
-                a, jax.sharding.NamedSharding(
-                    deps.mesh, jax.sharding.PartitionSpec("dp")))
-
-        def run_res(x, step, inputs):
-            xin, t = scale_in(deps.schedule, x, sigmas[step])
-            latent, _, tb, ctx, added = cfg_rows(xin, t, inputs)
-            return control_residuals(
-                deps.controlnet, inputs.controls, pin_rows(latent), tb, ctx,
-                added, step, v.steps)
-
-        return jax.jit(run_res)
-
     def run_chunk(unet_params, state, start, inputs):
         return scan_chunk(make_step(v, deps, sigmas, unet_params, inputs),
                           state, start, v.length)
 
-    def run_step(unet_params, state, start, inputs):
-        return scan_chunk(make_step(v, deps, sigmas, unet_params, inputs),
-                          state, start)
-
-    return jax.jit(run_step if v.kind == "cnstep" else run_chunk,
-                   donate_argnums=(1,))
+    return jax.jit(run_chunk, donate_argnums=(1,))

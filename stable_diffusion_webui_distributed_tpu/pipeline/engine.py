@@ -35,7 +35,6 @@ from stable_diffusion_webui_distributed_tpu.models.configs import ModelFamily
 from stable_diffusion_webui_distributed_tpu.models.unet import (
     UNet,
     cache_supported,
-    control_residual_count,
     deep_cache_shape,
     join_added_cond,
     time_id_embedding,
@@ -272,9 +271,6 @@ class Engine:
         # yield sees the same attribute and no-ops — so installation needs
         # no lock: only the gate-holding thread ever swaps it.
         self.preempt_hook = None
-        # stage-graph ControlNet slice (SDTPU_STAGE_CN_DEVICES): built on
-        # first use, cached per device count (_stage_cn_mesh)
-        self._stage_cn_mesh_cache = None
 
     # -- compiled stage factories ------------------------------------------
 
@@ -438,14 +434,9 @@ class Engine:
             family=self.family.name, precision=prec, **static)
         denoise.check(variant)
         unet, controlnet = self._modules_for(prec)
-        key, mesh = variant.key(), None
-        if kind == "cnres":
-            # the mesh this stage runs on: its own slice, else the engine's
-            cn_mesh = self._stage_cn_mesh()
-            mesh = cn_mesh or self.mesh
-            key += (0 if cn_mesh is None else cn_mesh.size,)
-        deps = denoise.Deps(unet, controlnet, self.schedule, mesh)
-        return self._cached(key, lambda: denoise.build(variant, deps))
+        deps = denoise.Deps(unet, controlnet, self.schedule)
+        return self._cached(variant.key(),
+                            lambda: denoise.build(variant, deps))
 
     def _denoise_adaptive(self, payload, x, image_keys, conds, pooleds,
                           width, height, start_step, steps, job,
@@ -1342,8 +1333,7 @@ class Engine:
     def _denoise_range(self, payload, x, image_keys, conds, pooleds,
                        width, height, start_step, steps, job,
                        mask_lat, init_lat, controls=(), end_step=None,
-                       inpaint_cond=None, sync=True, ragged=None,
-                       lora=None):
+                       inpaint_cond=None, ragged=None, lora=None):
         """Obs-span wrapper around the chunk loop: one ``denoise_range``
         span (host-side perf_counter, no extra device sync) grouping the
         per-chunk ``denoise_chunk`` spans StageStats feeds in, each the
@@ -1354,23 +1344,17 @@ class Engine:
             return self._denoise_range_timed(
                 payload, x, image_keys, conds, pooleds, width, height,
                 start_step, steps, job, mask_lat, init_lat, controls,
-                end_step, inpaint_cond, sync, ragged, lora)
+                end_step, inpaint_cond, ragged, lora)
 
     def _denoise_range_timed(self, payload, x, image_keys, conds, pooleds,
                              width, height, start_step, steps, job,
                              mask_lat, init_lat, controls=(), end_step=None,
-                             inpaint_cond=None, sync=True, ragged=None,
-                             lora=None):
+                             inpaint_cond=None, ragged=None, lora=None):
         """Host-side chunk loop with interrupt/progress between dispatches
         (compiled-loop version of the reference's 0.5 s poll,
         worker.py:440-448). ``steps`` sizes the sigma ladder; the loop runs
         [start_step, end_step or steps) — a partial range is how the
         base half of a base+refiner pass stops at the switch point.
-
-        ``sync=False`` (parallel/stage_pipeline.py) skips every
-        ``block_until_ready`` so the host can keep dispatching to OTHER
-        device groups while this one chews — progress then reports at
-        group granularity and interrupt latency grows to a full range.
 
         ``ragged``: ``(true_rows, ctx_true_u, ctx_true_c)`` traced (B,)
         int32 vectors (serving/dispatcher.py ragged mode). Routes every
@@ -1457,11 +1441,9 @@ class Engine:
             # ranges where a captured prefix can be BYTE-identical — the plain
             # txt2img base range with nothing that injects per-step state the
             # capture can't carry (masks, inpaint conditioning, ControlNet
-            # windows) and nothing already consumed (start_step 0). The
-            # non-sync path never paces on fences, so a capture's host
-            # materialization has no safe point there.
+            # windows) and nothing already consumed (start_step 0).
             prefix_plan = None
-            if (job == "txt2img" and sync and start_step == 0 and not masked
+            if (job == "txt2img" and start_step == 0 and not masked
                     and not inpainting and not controls and end > 0
                     and ragged is None):
                 from stable_diffusion_webui_distributed_tpu.cache import (
@@ -1512,11 +1494,7 @@ class Engine:
         while pos < end:
             if self.state.flag.interrupted:
                 break
-            # preemption is a dispatcher(sync)-only protocol: the
-            # non-sync path (parallel/stage_pipeline) shares the progress
-            # record across device groups and never paces on fences, so a
-            # yield there would hand over the device with work in flight
-            hook = self.preempt_hook if sync else None
+            hook = self.preempt_hook
             if hook is not None and hook.should_yield():
                 # chunk-boundary yield: drain the in-flight chunk so the
                 # device is quiet, then block in the gate until the fleet
@@ -1579,7 +1557,7 @@ class Engine:
                             # a plain (CN-active) chunk advanced the latent
                             # outside the cache's view — refresh on re-entry
                             valid = jnp.asarray(False)
-                if sync and pending is not None:
+                if pending is not None:
                     with obs_spans.span("chunk.fence_wait",
                                         steps=pending[1]):
                         pending[0].block_until_ready()
@@ -1594,7 +1572,7 @@ class Engine:
                 # gone. The implied device sync is the price of the
                 # gated-on path only.
                 cache_prefix.maybe_capture(prefix_plan, pos, tuple(carry))
-        if sync and pending is not None:
+        if pending is not None:
             with obs_spans.span("chunk.fence_wait", steps=pending[1]):
                 pending[0].block_until_ready()
             done += pending[1]
@@ -1662,19 +1640,6 @@ class Engine:
             sigmas = self._ladder(spec, payload.steps, plan_span).sigmas
             controls = self._prepare_controls(payload, width, height)
             refiner = self._refiner_engine(payload)
-        from stable_diffusion_webui_distributed_tpu.parallel import (
-            stage_graph,
-        )
-
-        if (stage_graph.enabled() and refiner is None
-                and not payload.enable_hr and not spec.adaptive):
-            # stage-graph executor (SDTPU_STAGE_GRAPH=1): byte-identical
-            # images — the graph only reorders host dispatch and the seed
-            # contract keys draws by global image index. Hires, refiner
-            # and adaptive keep the serial loop (multi-pass handoffs and
-            # host-driven step control don't decompose into fixed nodes).
-            return self._run_txt2img_staged(payload, start, count, job,
-                                            width, height, controls)
         # ragged solo dispatch (SDTPU_RAGGED): the bucketer stamped the
         # true requested shape; denoise at the bucket shape with the true
         # latent row count as traced data. Guarded by the same exclusions
@@ -1767,278 +1732,6 @@ class Engine:
             remaining -= n
         self._flush_decoded(out, payload, pending)
         return out
-
-    def _run_txt2img_staged(self, payload, start, count, job,
-                            width, height, controls) -> GenerationResult:
-        """Stage-graph txt2img executor (SDTPU_STAGE_GRAPH=1,
-        parallel/stage_graph.py): each dispatch group becomes an explicit
-        Encode -> Denoise -> Decode graph whose nodes dispatch async
-        (``sync=False``), with the flush (host materialization) deferred
-        through a depth-limited GraphRunner — group *i*'s VAE fetch and
-        group *i+1*'s CLIP encode overlap group *i+1*'s denoise on the
-        host timeline. ControlNet requests that qualify additionally run
-        the tower one sigma-step ahead (_denoise_range_staged_cn).
-
-        Byte-identity with the serial loop: noise/keys are keyed by
-        global image index, pad-and-drop uses the same bucket probe, and
-        decode order is FIFO (the runner's invariant) — only host pacing
-        changes. Preemption happens at GROUP boundaries here (the async
-        denoise loop never polls the hook): drain everything in flight,
-        yield, re-apply this request's adapters, restore the interrupt
-        latch — the same protocol the chunk loop runs mid-range."""
-        from stable_diffusion_webui_distributed_tpu.parallel import (
-            stage_graph,
-        )
-
-        h, w = self._latent_hw(width, height)
-        C = self.family.vae.latent_channels
-        spec = kd.resolve_sampler(payload.sampler_name)
-        sigmas = kd.build_sigmas(spec, self.schedule, payload.steps)
-        conds = pooleds = None
-        if not payload.all_prompts:
-            conds, pooleds = self.encode_prompts(payload)
-        out = GenerationResult(parameters=payload.model_dump())
-        group = max(1, payload.group_size or payload.batch_size)
-        runner = stage_graph.GraphRunner(depth=stage_graph.depth(),
-                                         clock=stage_graph.CLOCK)
-        # ControlNet-on-slice eligibility: the stage-ahead residual
-        # executable reproduces the in-chunk math only when the sampler
-        # makes exactly ONE denoise eval per step at (x_i, sigma_i), the
-        # step cache is off (cached chunks would diverge), no traced
-        # adapter deltas ride the chunk args, and the checkpoint isn't an
-        # inpainting hybrid. Everything else keeps CN inside the chunk
-        # executable — still dispatched async.
-        sc = stepcache.resolve(payload)
-        cn_staged = bool(controls) and spec.evals_per_step == 1 \
-            and not sc.active and self._traced_lora is None \
-            and not self.family.inpaint
-        pos = start
-        remaining = count
-        while remaining > 0 and not self.state.flag.interrupted:
-            hook = self.preempt_hook
-            if hook is not None and hook.should_yield():
-                # group-boundary yield: quiesce every in-flight graph
-                # (ordered flush keeps the gallery in index order), hand
-                # the device over, then restore this request's view
-                runner.drain()
-                interrupted_before_yield = self.state.flag.interrupted
-                hook.yield_device()
-                self._apply_prompt_loras(payload)
-                self.state.restore_interrupt(interrupted_before_yield)
-                continue
-            n = min(group, remaining)
-            gen_n = n
-            if n < group and self._has_batch_bucket(
-                    payload.sampler_name, payload.steps, width, height,
-                    group):
-                gen_n = group  # pad-and-drop, same probe as the serial loop
-            graph = stage_graph.StageGraph(
-                label=f"txt2img[{pos}:{pos + n}]", group=pos,
-                clock=stage_graph.CLOCK)
-
-            def encode_stage(p0=pos, g_n=gen_n):
-                if payload.all_prompts:
-                    c, pl, _ = self._group_conds(payload, p0, g_n, None)
-                    return c, pl
-                return conds, pooleds
-
-            def denoise_stage(cp, p0=pos, g_n=gen_n):
-                c, pl = cp
-                noise = rng.batch_noise(
-                    payload.seed, payload.subseed, payload.subseed_strength,
-                    p0, g_n, (h, w, C),
-                    seed_resize=self._seed_resize_latent(payload),
-                    pin_index=payload.same_seed)
-                x = self._place_batch(noise.astype(jnp.float32) * sigmas[0])
-                keys = self._image_keys(payload, p0, g_n)
-                if cn_staged:
-                    return self._denoise_range_staged_cn(
-                        payload, x, keys, c, pl, width, height,
-                        payload.steps, job, controls)
-                inp = (self._blank_inpaint_cond(g_n, width, height)
-                       if self.family.inpaint else None)
-                return self._denoise_range(
-                    payload, x, keys, c, pl, width, height, 0,
-                    payload.steps, job, None, None, controls,
-                    inpaint_cond=inp, sync=False)
-
-            def decode_stage(lat, p0=pos, keep=n):
-                return self._queue_decoded(lat, p0, keep, width, height)
-
-            graph.add("encode", encode_stage, kind="stage")
-            graph.add("denoise", denoise_stage, deps=("encode",),
-                      kind="denoise")
-            graph.add("decode", decode_stage, deps=("denoise",),
-                      kind="stage")
-            runner.submit(graph, flush=lambda res: self._flush_decoded(
-                out, payload, res["decode"]))
-            pos += n
-            remaining -= n
-        runner.drain()
-        return out
-
-    def _denoise_range_staged_cn(self, payload, x, image_keys, conds,
-                                 pooleds, width, height, steps, job,
-                                 controls):
-        """Denoise [0, steps) with the ControlNet tower evaluated one
-        sigma-step AHEAD of the UNet in its own executable — and, when
-        ``SDTPU_STAGE_CN_DEVICES`` carves a mesh slice, on its own
-        devices (models/unet.py takes the residual tuple as a stage
-        input via ``control_residuals``).
-
-        Bitwise equality with the in-executable path: residuals for step
-        *i* are computed from exactly the inputs the fused chunk uses —
-        ``carry.x`` at step *i*, ``sigmas[i]``, the same CFG doubling —
-        and unit gating replicates the serial loop's CHUNK-window drop
-        (a unit inactive for the whole chunk is absent, not zero-gated;
-        a zero-gated residual row could still flip -0.0 to +0.0 in the
-        skip adds). Eligibility is enforced by the caller
-        (_run_txt2img_staged): 1-eval-per-step samplers, no step cache,
-        no traced LoRA, no inpainting hybrid."""
-        (ctx_u, ctx_c) = conds
-        au, ac = self._added_cond(*pooleds, width, height)
-        batch = x.shape[0]
-        cfg = jnp.float32(payload.cfg_scale)
-        prec = precision_mod.resolve(payload, self.policy)
-        cn_mesh = self._stage_cn_mesh()
-        carry = kd.init_carry(x)
-        self.state.begin(job, steps)
-
-        # CN-side per-request constants hop to the slice once per range
-        cn_inputs = denoise.Inputs(ctx_u, ctx_c, added_u=au, added_c=ac)
-        cn_controls = controls
-        if cn_mesh is not None:
-            from stable_diffusion_webui_distributed_tpu.parallel import (
-                stage_graph,
-            )
-            from stable_diffusion_webui_distributed_tpu.runtime.mesh import (
-                replicated,
-            )
-
-            cn_controls = jax.device_put(controls, replicated(cn_mesh))
-            cn_inputs = jax.tree.map(
-                lambda a: stage_graph.to_mesh(a, cn_mesh, batch=False),
-                cn_inputs)
-
-        def active_idxs(chunk_pos):
-            # the serial loop drops units whose window misses the whole
-            # chunk — replicate per chunk window, not per step
-            length = min(self.chunk_size, steps - chunk_pos)
-            lo = (chunk_pos + 0.5) / steps
-            hi = (chunk_pos + length - 0.5) / steps
-            return tuple(k for k, c in enumerate(controls)
-                         if c[3] <= hi and c[4] >= lo)
-
-        def residuals_for(x_now, i):
-            idxs = active_idxs((i // self.chunk_size) * self.chunk_size)
-            if not idxs:
-                return None
-            resfn = self._denoise_fn(
-                "cnres", payload.sampler_name, steps, width, height, batch,
-                precision=prec.name, n_controls=len(idxs))
-            x_cn = x_now
-            if cn_mesh is not None:
-                from stable_diffusion_webui_distributed_tpu.parallel import (
-                    stage_graph,
-                )
-
-                x_cn = stage_graph.to_mesh(x_now, cn_mesh, batch=True)
-            rs = resfn(x_cn, jnp.int32(i), cn_inputs._replace(
-                controls=tuple(cn_controls[k] for k in idxs)))
-            # Host-side stage-input check: the UNet's traced assert on
-            # residual arity only fires inside the step executable, long
-            # after the CN-slice dispatch — validate here instead.
-            want = control_residual_count(self.family.unet)
-            if len(rs) != want:
-                raise RuntimeError(
-                    f"controlnet residual stage input has {len(rs)} "
-                    f"tensors, UNet expects {want}")
-            if cn_mesh is not None:
-                from stable_diffusion_webui_distributed_tpu.parallel import (
-                    stage_graph,
-                )
-
-                # Hop back REPLICATED: when the residuals are computed
-                # on the engine mesh the jitted stage emits them with a
-                # replicated layout (the CFG doubling concat defeats
-                # batch-dim propagation), and the step executable is
-                # keyed on input shardings — handing it a batch-sharded
-                # copy would compile a second, differently-partitioned
-                # executable whose rounding breaks byte identity.
-                rs = tuple(
-                    stage_graph.to_mesh(r, self.mesh, batch=False)
-                    if self.mesh is not None else jax.device_put(r)
-                    for r in rs)
-            return rs
-
-        stepfn = self._denoise_fn("cnstep", payload.sampler_name, steps,
-                                  width, height, batch, precision=prec.name)
-        inputs = denoise.Inputs(ctx_u, ctx_c, cfg, image_keys, au, ac)
-        fences = []  # completed-dispatch fences; depth-2 host pacing
-        done = 0
-        res = residuals_for(carry.x, 0)
-        i = 0
-        while i < steps:
-            if self.state.flag.interrupted:
-                break
-            with trace.STATS.timer("denoise_chunk"), \
-                    obs_spans.span("chunk.enqueue", pos=i, steps=1):
-                carry, fence = stepfn(
-                    self.params["unet"], carry, jnp.int32(i),
-                    inputs._replace(residuals=res))
-            fences.append(fence)
-            i += 1
-            if i < steps:
-                # one sigma-step ahead: step i's UNet is still running
-                # when step i's residual dispatch (for the NEXT step)
-                # enqueues on the slice — the towers overlap on silicon
-                res = residuals_for(carry.x, i)
-            while len(fences) > 2:
-                with obs_spans.span("chunk.fence_wait", steps=1):
-                    fences.pop(0).block_until_ready()
-                done += 1
-                self.state.step(done)
-        # NO final drain: like _denoise_range(sync=False), the tail
-        # steps stay in flight so the caller's decode dispatch — and the
-        # NEXT group's stages — overlap this group's denoise window on
-        # the host timeline. The depth-2 pacing above already bounds
-        # in-flight buffers; finish() only snapshots progress.
-        self.state.finish()
-        return carry.x
-
-    def _stage_cn_mesh(self):
-        """Mesh slice for the stage-ahead ControlNet tower
-        (``SDTPU_STAGE_CN_DEVICES=N``): the last N visible devices OUTSIDE
-        the engine's mesh when that many are free, else the trailing N of
-        all devices. None when the knob is 0 or the slice would swallow
-        every device (the tower then shares the UNet's devices — still
-        correct, just no disaggregation win)."""
-        from stable_diffusion_webui_distributed_tpu.parallel import (
-            stage_graph,
-        )
-
-        n = stage_graph.cn_slice_devices()
-        if n <= 0:
-            return None
-        cached = self._stage_cn_mesh_cache
-        if cached is not None and cached[0] == n:
-            return cached[1]
-        devs = list(jax.devices())
-        pool = devs
-        if self.mesh is not None:
-            used = {d.id for d in self.mesh.devices.flat}
-            free = [d for d in devs if d.id not in used]
-            if len(free) >= n:
-                pool = free
-        mesh = None
-        if len(pool) >= n and not (pool is devs and len(devs) <= n):
-            from stable_diffusion_webui_distributed_tpu.runtime.mesh import (
-                build_mesh,
-            )
-
-            mesh = build_mesh(f"dp={n}", devices=pool[-n:])
-        self._stage_cn_mesh_cache = (n, mesh)
-        return mesh
 
     def _refiner_engine(self, payload) -> Optional["Engine"]:
         if not payload.refiner_checkpoint or payload.refiner_switch_at >= 1.0:
@@ -2286,7 +1979,7 @@ class Engine:
         starts = range(0, keep, per)
         # FLOPs-per-image denominator: every kept row is one output image,
         # counted at the single point all decode paths (engine loops, the
-        # serving dispatcher, the stage pipeline) funnel through
+        # serving dispatcher) funnel through
         METRICS.record_decoded(rows=keep, dispatches=len(starts))
         decode = self._decode_u8_fn(width, height, per)
         entries = []

@@ -355,31 +355,6 @@ def _logger():
 #   Unset, each call site keeps its historical default (stitch 5.0,
 #   backend probes 3.0).
 #
-# Stage-graph executor knobs (parallel/stage_graph.py, pipeline/engine.py,
-# serving/dispatcher.py; README "Stage-graph execution"):
-#
-# - ``SDTPU_STAGE_GRAPH`` (flag, default off): the stage-graph executor.
-#   On, every dispatch group becomes an explicit Encode -> Denoise ->
-#   Decode (dispatcher groups: -> Merge) node graph whose stages dispatch
-#   async, with host materialization deferred through a depth-limited
-#   runner — group *i*'s VAE fetch and group *i+1*'s CLIP encode overlap
-#   group *i+1*'s denoise on the host timeline — and eligible ControlNet
-#   requests evaluate the tower one sigma-step ahead of the UNet in its
-#   own executable. Host pacing only: images/seeds/infotexts are
-#   byte-identical to the serial path (the seed contract keys every draw
-#   by global image index; hash-pinned in tests/test_stagegraph.py).
-#   Off (the default) nothing changes — the serial path is gate-off
-#   golden-pinned.
-# - ``SDTPU_STAGE_DEPTH`` (int, default 1): graphs the runner keeps in
-#   flight before flushing the oldest. 1 reproduces the classic
-#   decode-trails-one-group schedule; deeper widens host overlap at the
-#   cost of more live latent batches.
-# - ``SDTPU_STAGE_CN_DEVICES`` (int, default 0 = off): carve this many
-#   devices (preferring devices OUTSIDE the engine's mesh) into a
-#   ControlNet mesh slice; stage-ahead residuals evaluate there and hop
-#   back to the UNet mesh as stage inputs. 0 keeps residuals on the
-#   engine mesh; values that would swallow every device fall back to 0.
-#
 # AOT artifact / warm-pool knobs (serving/aot.py, fleet/pool.py;
 # README "AOT artifacts & warm pools"):
 #

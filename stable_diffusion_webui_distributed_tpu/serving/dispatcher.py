@@ -43,7 +43,7 @@ import dataclasses
 import threading
 import time
 import uuid
-from typing import Callable, Dict, List, Optional
+from typing import Dict, List, Optional
 
 from stable_diffusion_webui_distributed_tpu.fleet import (
     admission as fleet_admission,
@@ -121,11 +121,6 @@ class Ticket:
         #: id and its ``dispatch.device`` span id, set by the leader as the
         #: attrs of a follower's ``coalesced.wait``
         self.leader_link: dict = {}
-        #: per-stage completion callback (stage-graph mode): called as
-        #: ``on_stage(request_id, stage_name, seconds)`` after each of the
-        #: group's encode/denoise/decode/merge stages instead of one
-        #: blocking _execute_group return; best-effort, errors swallowed
-        self.on_stage: Optional[Callable[[str, str, float], None]] = None
 
 
 class _Group:
@@ -726,9 +721,8 @@ class ServingDispatcher:
             obs_spans.close_span(ticket.waits.pop())
 
     def _run_grouped_leader(self, g: _Group, key) -> None:
-        """The leader's execution: device section + (stage-graph mode)
-        the post-release finalize — both on this thread, both on the
-        engine :meth:`_checkout_engine` resolved."""
+        """The leader's execution, on this thread and on the engine
+        :meth:`_checkout_engine` resolved."""
         with self._device(g.tickets, g.images):
             # close AFTER taking the engine: followers kept joining while
             # a previous batch held the device (continuous batching)
@@ -767,7 +761,6 @@ class ServingDispatcher:
                                      group=len(g.tickets),
                                      precision=str(g.key[-1]), **lora_cell)
             dsp = None
-            finalize = None
             wd = obs_watchdog.arm(
                 g.tickets[0].request_id, "dispatch.device",
                 self._dispatch_eta(g.tickets[0].run, g.images))
@@ -778,34 +771,13 @@ class ServingDispatcher:
                                     requests=len(g.tickets),
                                     precision=g.key[-1],
                                     **lora_cell) as dsp:
-                    if self._stage_graph_on():
-                        # stage-graph mode: encode/denoise/decode dispatch
-                        # under the device lock; the returned finalize
-                        # (blocking fetch + merge) runs after release so
-                        # the next group's stages overlap it
-                        finalize = self._execute_group_staged(g)
-                    else:
-                        self._execute_group(g)
+                    self._execute_group(g)
             except BaseException as e:  # noqa: BLE001 — delivered per ticket
-                finalize = None
                 for t in g.tickets:
                     if t.error is None and t.result is None:
                         t.error = e
             finally:
                 obs_watchdog.disarm(wd)
-                if finalize is None:
-                    self._finish_group(g, dsp, leader_req)
-        if finalize is not None:
-            # outside the device lock: group i's merge overlaps group
-            # i+1's encode/denoise on the host timeline; tickets complete
-            # only after their images actually materialized
-            try:
-                finalize()
-            except BaseException as e:  # noqa: BLE001 — delivered per ticket
-                for t in g.tickets:
-                    if t.error is None and t.result is None:
-                        t.error = e
-            finally:
                 self._finish_group(g, dsp, leader_req)
 
     def _finish_group(self, g: _Group, dsp, leader_req) -> None:
@@ -821,16 +793,6 @@ class ServingDispatcher:
             self._record_slo(t)
             t.leader_link = link
             t.done.set()
-
-    @staticmethod
-    def _stage_graph_on() -> bool:
-        """Gate probe for the stage-graph dispatch path (import is cheap:
-        parallel/stage_graph.py pulls no jax at module scope)."""
-        from stable_diffusion_webui_distributed_tpu.parallel import (
-            stage_graph,
-        )
-
-        return stage_graph.enabled()
 
     def _record_slo(self, ticket: Ticket) -> None:
         """Feed the perf ledger's per-(tenant, class) SLO attainment and
@@ -994,9 +956,7 @@ class ServingDispatcher:
     # -- merged execution --------------------------------------------------
 
     def _execute_group(self, g: _Group) -> None:
-        """Serial group execution: the four stages back-to-back on the
-        calling thread, byte-identical to the pre-stage-graph code (the
-        stages are the same statements, split at data-dependency seams)."""
+        """A group's four stages, back to back on the calling thread."""
         with obs_spans.span("prepare", requests=len(g.tickets)):
             built = self._group_build_inputs(g)
         if built is None:
@@ -1004,81 +964,6 @@ class ServingDispatcher:
         latents = self._group_denoise(g, built)
         entries = self._group_decode(g, built, latents)
         self._group_merge(g, built, entries)
-
-    def _execute_group_staged(self, g: _Group):
-        """Stage-graph group execution (SDTPU_STAGE_GRAPH): the same four
-        stages as explicit :class:`StageGraph` nodes. Encode, async
-        denoise dispatch, and decode dispatch run NOW (under the device
-        lock the caller holds); the returned finalize closure — the
-        blocking image fetch + per-ticket merge — runs after the caller
-        releases the device, so the next group's encode/denoise overlap
-        it on the host timeline. Per-stage completion fans out to every
-        ticket's ``on_stage`` callback as stages land."""
-        from stable_diffusion_webui_distributed_tpu.parallel import (
-            stage_graph,
-        )
-
-        leader_rid = g.tickets[0].request_id
-        graph = stage_graph.StageGraph(
-            label=f"group[{leader_rid}]", group=leader_rid,
-            clock=stage_graph.CLOCK, on_stage=self._stage_notifier(g))
-        # None flows through when every ticket cancelled before dispatch
-        # (build returns None): downstream nodes become no-ops, matching
-        # the serial path's early return
-        graph.add("encode", lambda: self._group_build_inputs(g),
-                  kind="stage")
-        graph.add("denoise",
-                  lambda built: None if built is None
-                  else self._group_denoise(g, built, sync=False),
-                  deps=("encode",), kind="denoise")
-        graph.add("decode",
-                  lambda built, latents: None if built is None
-                  else self._group_decode(g, built, latents),
-                  deps=("encode", "denoise"), kind="stage")
-        graph.add("merge",
-                  lambda built, entries: None if built is None
-                  else self._group_merge(g, built, entries),
-                  deps=("encode", "decode"), kind="stage")
-        graph.run(until="decode")
-
-        def finalize() -> None:
-            try:
-                graph.run()  # merge: np fetch blocks until device done
-            finally:
-                # fetch returned (or failed): the group's device work is
-                # over — close its denoise window, then ledger the
-                # per-group stage/overlap seconds
-                graph.close_denoise()
-                if obs_perf.enabled():
-                    try:
-                        lora_rb, lora_sc = int(g.key[-3]), int(g.key[-2])
-                        obs_perf.LEDGER.record_stages(
-                            bucket=f"{int(g.key[3])}x{int(g.key[4])}",
-                            cadence=int(g.key[8]),
-                            precision=str(g.key[-1]),
-                            lora=(f"r{lora_rb}s{lora_sc}"
-                                  if (lora_rb or lora_sc) else ""),
-                            stage_s=graph.stage_seconds(),
-                            overlap_s=graph.stage_overlap())
-                    except Exception:  # noqa: BLE001 — ledger best-effort
-                        pass
-
-        return finalize
-
-    def _stage_notifier(self, g: _Group):
-        """Per-stage completion fan-out: each finished stage calls every
-        ticket's ``on_stage(request_id, stage, seconds)``; best-effort —
-        a callback error never fails the group."""
-        def notify(stage: str, seconds: float) -> None:
-            for t in g.tickets:
-                cb = t.on_stage
-                if cb is not None:
-                    try:
-                        cb(t.request_id, stage, seconds)
-                    except Exception:  # noqa: BLE001 — callback isolation
-                        pass
-
-        return notify
 
     def _group_build_inputs(self, g: _Group) -> Optional[Dict]:
         """Encode stage: cancellation filter, per-ticket prompt encodes +
@@ -1254,13 +1139,9 @@ class ServingDispatcher:
             "lora_rb": lora_rb, "lora_sc": lora_sc,
         }
 
-    def _group_denoise(self, g: _Group, built: Dict, *,
-                       sync: bool = True):
+    def _group_denoise(self, g: _Group, built: Dict):
         """Denoise stage: the single coalesced ``_denoise_range`` call
-        plus its perf-ledger record. ``sync=False`` (stage-graph mode)
-        returns as soon as the chunk executables are dispatched — the
-        ledger's device_s then measures dispatch host time, with the
-        stage-overlap columns carrying the pipelining story."""
+        plus its perf-ledger record."""
         engine = self._engine()
         live, counts, rp = built["live"], built["counts"], built["rp"]
         width, height = built["width"], built["height"]
@@ -1278,7 +1159,7 @@ class ServingDispatcher:
             rp, built["x"], built["keys"], (ctx_u, ctx_c),
             (pooled_u, pooled_c),
             width, height, 0, rp.steps, "txt2img", None, None, (),
-            ragged=built["ragged"], lora=built["lora"], sync=sync)
+            ragged=built["ragged"], lora=built["lora"])
         self._drain_cache_notes(live[0].request_id, embed=False)
         if perf_on:
             # masked pixels: resident tail rows the ragged kernel skips —

@@ -29,10 +29,8 @@ The harnesses cover the lock protocols the static tier reasons about:
 condition-variable handoff (FleetGate), two-lock leader/follower
 coalescing with cancellation (dispatcher), multi-channel
 producer/drain-daemon shutdown (notifier), daemon stop/restart
-(StoppableDaemon), the push-plane delta subscriber's cursor-resume
-fetch/apply cycle racing reconnect and stop (DeltaSubscriber), and the
-stage-graph runner's submit/drain FIFO with per-stage completion
-callbacks racing cancel and preempt (GraphRunner).
+(StoppableDaemon), and the push-plane delta subscriber's cursor-resume
+fetch/apply cycle racing reconnect and stop (DeltaSubscriber).
 """
 
 from __future__ import annotations
@@ -58,7 +56,6 @@ __all__ = [
     "fleet_gate_harness",
     "notifier_drain_harness",
     "run_harness",
-    "stage_graph_harness",
     "warm_pool_harness",
 ]
 
@@ -380,81 +377,6 @@ def daemon_restart_harness(ex: "sched.Explorer") -> Callable[[], List[str]]:
     return check
 
 
-# -- StageGraph/GraphRunner: submit vs preempt-drain vs cancel ---------------
-
-def stage_graph_harness(ex: "sched.Explorer") -> Callable[[], List[str]]:
-    """A producer submits three encode→denoise→decode StageGraphs through
-    one GraphRunner while a preemptor drains mid-stream (the engine's
-    chunk-boundary yield runs drain() from a racing thread) and a
-    canceller stops the producer between submissions (the interrupt
-    seam). Whatever the interleaving: each submitted group's per-stage
-    completion callbacks fire in dependency order, every submitted group
-    flushes exactly once in submission (FIFO = gallery) order, nothing
-    stays in flight, and every denoise window is closed."""
-    from ..parallel import stage_graph
-
-    # fresh objects: the module-level CLOCK's lock was born raw at import
-    clock = stage_graph.OverlapClock()
-    runner = stage_graph.GraphRunner(depth=1, clock=clock)
-    stages: List[tuple] = []   # (group, stage) completion log
-    flushes: List[int] = []    # group ids in flush order
-    submitted: List[int] = []
-    cancel = threading.Event()  # post-install: cooperative wait
-
-    def make_graph(gid: int):
-        g = stage_graph.StageGraph(
-            label=f"g{gid}", group=gid, clock=clock,
-            on_stage=lambda name, secs, gid=gid: stages.append((gid, name)),
-            obs=False)
-        g.add("encode", lambda gid=gid: f"enc{gid}", kind="stage")
-        g.add("denoise", lambda e, gid=gid: f"lat{gid}",
-              deps=("encode",), kind="denoise")
-        g.add("decode", lambda e, lat, gid=gid: f"img{gid}",
-              deps=("encode", "denoise"), kind="stage")
-        return g
-
-    def producer() -> None:
-        for gid in range(3):
-            if cancel.is_set():
-                break
-            submitted.append(gid)
-            runner.submit(make_graph(gid),
-                          lambda res, gid=gid: flushes.append(gid))
-        runner.drain()
-
-    def preemptor() -> None:
-        runner.drain()
-
-    def canceller() -> None:
-        cancel.set()
-
-    ex.spawn(producer, "producer")
-    ex.spawn(preemptor, "preempt-drain")
-    ex.spawn(canceller, "cancel")
-
-    def check() -> List[str]:
-        out: List[str] = []
-        for gid in submitted:
-            order = [s for g, s in stages if g == gid]
-            if order != ["encode", "denoise", "decode"]:
-                out.append(f"group {gid} stage callbacks out of order: "
-                           f"{order}")
-        if flushes != submitted:
-            out.append(f"flush order {flushes} != submit order {submitted}")
-        if runner.in_flight():
-            out.append(f"{runner.in_flight()} graphs left in flight")
-        if runner.flushed != len(submitted):
-            out.append(f"flushed {runner.flushed} != "
-                       f"submitted {len(submitted)}")
-        with clock._lock:
-            left_open = len(clock._open)
-        if left_open:
-            out.append(f"{left_open} denoise windows left open")
-        return out
-
-    return check
-
-
 # -- WarmPool: checkout vs chaos-kill vs heal vs retire ----------------------
 
 def warm_pool_harness(ex: "sched.Explorer") -> Callable[[], List[str]]:
@@ -532,7 +454,6 @@ HARNESSES: Dict[str, Callable[["sched.Explorer"],
     "notifier_drain": notifier_drain_harness,
     "daemon_restart": daemon_restart_harness,
     "delta_subscriber": delta_subscriber_harness,
-    "stage_graph": stage_graph_harness,
     "warm_pool": warm_pool_harness,
 }
 

@@ -200,25 +200,6 @@ def test_built_chunk_is_the_plain_loop_over_the_parts(name, nets):
         assert bool(state.valid)
 
 
-def test_staged_controlnet_step_is_the_fused_step(nets):
-    """cnres a step ahead, then cnstep with its residuals as an input: one
-    step of the loop with the unit fused."""
-    deps, params = nets[TINY.name]
-    base = D.Variant("cnstep", "Euler", STEPS, LAT * 8, LAT * 8, B,
-                     family=TINY.name, precision="bf16")
-    x = _rand(21, (B, LAT, LAT, 4), 3.0)
-    inp = _inputs()
-    residuals = D.build(base._replace(kind="cnres", n_controls=1), deps)(
-        x, jnp.int32(START), inp._replace(controls=_controls(nets)))
-    want = plain_loop(base._replace(kind="chunk", n_controls=1), deps,
-                      params, inp._replace(controls=_controls(nets)), x,
-                      length=1)
-    carry, _ = D.build(base, deps)(      # donates x
-        params, kd.init_carry(x), jnp.int32(START),
-        inp._replace(residuals=residuals))
-    np.testing.assert_allclose(carry.x, want, rtol=2e-4, atol=2e-5)
-
-
 def test_adaptive_attempt_is_the_solver_over_the_parts(nets):
     deps, params = nets[TINY.name]
     inp = _inputs()
@@ -279,8 +260,6 @@ def test_residual_stage_rows_are_the_fused_rows_bit_for_bit():
     {"step_cache": True, "n_controls": 1},
     {"kind": "adaptive", "lora_sig": "lora:r8s1"},
     {"kind": "adaptive", "step_cache": True},
-    {"kind": "cnstep", "masked": True},
-    {"kind": "cnres", "inpaint": True},
     {"kind": "chunks"},
 ], ids=lambda f: "+".join(f"{k}={v}" for k, v in f.items()))
 def test_variant_refuses_what_no_executable_serves(fields):
@@ -289,6 +268,11 @@ def test_variant_refuses_what_no_executable_serves(fields):
         D.check(v)
     with pytest.raises(ValueError):
         D.build(v, None)
+
+
+@pytest.mark.parametrize("kind", ["chunk", "adaptive", "adaptive-pin"])
+def test_check_accepts_the_three_kinds(kind):
+    D.check(D.Variant(kind, "Euler", 4, 32, 32, 1, 2))
 
 
 #: keys an engine at the parent of the PR that added the record held after
@@ -330,11 +314,8 @@ def test_key_is_the_recorded_tuple_and_the_census_reads_it_by_name():
 
 
 def test_other_kinds_keep_their_keys():
-    v = D.Variant("cnstep", "Euler", 8, 64, 64, 2, n_controls=1,
+    v = D.Variant("chunk", "Euler", 8, 64, 64, 2, n_controls=1,
                   family="tiny", precision="bf16")
-    assert v.key() == ("cnstep", "Euler", 8, 64, 64, 2, "tiny", "bf16")
-    assert v._replace(kind="cnres").key() == (
-        "cnres", "Euler", 8, 64, 64, 2, 1, "tiny", "bf16")
     assert v._replace(kind="adaptive", inpaint=True).key() == (
         "adaptive", 64, 64, 2, 1, True, "tiny", "bf16")
     assert v._replace(kind="adaptive-pin").key() == ("adaptive-pin", "tiny")

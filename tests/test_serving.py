@@ -203,6 +203,27 @@ class TestContinuousBatching:
                                    prompt=f"cow {i}"))
         return out
 
+    @staticmethod
+    def _submit_at_once(dispatcher, payloads):
+        """Every payload from a thread of its own; the results in order."""
+        results = [None] * len(payloads)
+        errors = []
+
+        def run(i, p):
+            try:
+                results[i] = dispatcher.submit(p)
+            except Exception as e:  # noqa: BLE001 — surfaced by assert
+                errors.append(e)
+
+        threads = [threading.Thread(target=run, args=(i, p))
+                   for i, p in enumerate(payloads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        assert not errors, errors
+        return results
+
     def test_acceptance_coalesce_and_byte_exactness(self, engine, bucketer):
         serial = ServingDispatcher(engine, bucketer=bucketer, window=0.0)
         coalesced = ServingDispatcher(engine, bucketer=bucketer, window=0.6)
@@ -213,22 +234,7 @@ class TestContinuousBatching:
         assert METRICS.summary()["dispatches"] == 8
 
         METRICS.clear()
-        results = [None] * 8
-        errors = []
-
-        def run(i, p):
-            try:
-                results[i] = coalesced.submit(p)
-            except Exception as e:  # noqa: BLE001 — surfaced by assert
-                errors.append(e)
-
-        threads = [threading.Thread(target=run, args=(i, p))
-                   for i, p in enumerate(self._payloads())]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        assert not errors, errors
+        results = self._submit_at_once(coalesced, self._payloads())
 
         s = METRICS.summary()
         # the whole point: 4 raw shapes -> 2 executables, and the serial
@@ -242,6 +248,21 @@ class TestContinuousBatching:
             assert got.seeds == want.seeds
             assert got.infotexts == want.infotexts
             assert got.images == want.images  # pixel bytes, not just shape
+
+    def test_groups_of_two_match_solo(self, engine):
+        """Four requests of two prompts on a ladder of batch 2: several
+        coalesced groups, each ticket with the bytes of its solo run."""
+        bucketer = ShapeBucketer(shapes=[(32, 32)], batches=[2])
+        payloads = [payload(prompt=f"stage cow {i % 2}", seed=200 + i)
+                    for i in range(4)]
+        serial = ServingDispatcher(engine, bucketer=bucketer, window=0.0)
+        baseline = [serial.submit(p) for p in payloads]
+        coalesced = ServingDispatcher(engine, bucketer=bucketer, window=0.6)
+        results = self._submit_at_once(coalesced, payloads)
+        for got, want in zip(results, baseline):
+            assert got.seeds == want.seeds
+            assert got.infotexts == want.infotexts
+            assert got.images == want.images
 
     def test_infotext_reports_requested_size(self, engine, bucketer):
         disp = ServingDispatcher(engine, bucketer=bucketer, window=0.0)
@@ -281,6 +302,41 @@ class TestContinuousBatching:
         assert results["keep"].seeds == alone.seeds
         assert results["keep"].images == alone.images
         assert results["keep"].infotexts == alone.infotexts
+
+    def test_group_cancelled_whole_makes_no_device_call(self, engine,
+                                                        bucketer,
+                                                        monkeypatch):
+        """Every ticket of a group cancelled inside the window: the leader
+        still takes the device and finishes the group (each ``done`` set,
+        each requester answered empty), and nothing is encoded, denoised
+        or decoded."""
+        disp = ServingDispatcher(engine, bucketer=bucketer, window=0.6)
+        calls = []
+        for name in ("encode_prompts", "_denoise_range", "_queue_decoded"):
+            monkeypatch.setattr(
+                engine, name,
+                lambda *a, _name=name, **kw: calls.append(_name))
+        results = {}
+
+        def run(rid):
+            results[rid] = disp.submit(
+                payload(width=32, height=32, seed=13, request_id=rid))
+
+        threads = [threading.Thread(target=run, args=(rid,))
+                   for rid in ("req-a", "req-b")]
+        before = METRICS.summary()["dispatches"]
+        for t in threads:
+            t.start()
+        time.sleep(0.15)  # inside the coalesce window
+        assert disp.cancel("req-a") and disp.cancel("req-b")
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+        assert calls == []
+        assert METRICS.summary()["dispatches"] == before
+        for r in results.values():
+            assert r.images == []
+            assert r.parameters.get("cancelled") is True
 
     def test_solo_bucketed_run_restored(self, engine):
         # batch above the ladder top -> not coalescable -> solo path,

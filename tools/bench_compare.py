@@ -97,14 +97,6 @@ THRESHOLDS = {
     # stale verdict is a paging/federation regression at any size
     "notify_delivery_rate": ("down", "abs", 0.0),
     "federation_staleness_fp": ("up", "abs", 0.0),
-    # stage-graph rows (bench.py run_stages): the mixed workload is
-    # fixed, so the overlap ratio collapsing means the executor stopped
-    # overlapping stage host-work with sibling denoise windows (e.g. a
-    # node went back to blocking); the chunk-compile DELTA vs the serial
-    # phase moving above zero means staging started minting extra chunk
-    # executables instead of replacing them with cnres/cnstep pairs
-    "stage_overlap_ratio": ("down", "rel", 0.05),
-    "stage_graph_chunk_compiles": ("up", "abs", 0.0),
     # aot rows (bench.py run_aot): cold_start_seconds is the warm arm's
     # time-to-first-image — it creeping UP means artifact hydration
     # stopped replacing compiles; aot_hit_rate dropping means cells fell
