@@ -29,6 +29,17 @@ sequences of one step: the prompt is prefilled once and its cache
 :func:`fork`-ed, every buffer copied once a sequence along a new leading
 axis, full buffers and rings alike. The instruction's rows are copied, not
 shared: every sequence writes its own ring slots from its first step on.
+
+A looped model (``LMConfig.total_ut_steps`` over 1) passes a token through
+its whole stack several times over one set of weights, and pass ``t`` of a
+layer attends what pass ``t`` wrote for the earlier positions. So a full
+layer's key and value buffers carry a PASS AXIS in front of their slots,
+``(passes, capacity, kv heads, head_dim)``: a position occupies one row of
+every pass, the model has ``passes x layers`` cache slots a position, and
+everything here that counts or copies takes the axis with it: the shapes
+come from ``lm.cache_shapes``, a snapshot and a fork copy whole buffers
+(a fork puts the sequences in front of the passes), and the positions in
+use are a position a pass a layer.
 """
 
 from __future__ import annotations
@@ -146,10 +157,11 @@ class KVCacheManager:
         """Cache positions ``sequences`` sequences of ``length`` occupy,
         by layer kind, summed over the layers of the kind; a linear or a
         conv layer uses none at any length, a latent layer one a
-        position."""
+        position, a full layer of a looped model one a pass."""
         cfg = self.config
         out = {
-            lm.FULL: len(cfg.layers_of(lm.FULL)) * length * sequences,
+            lm.FULL: len(cfg.layers_of(lm.FULL)) * length * sequences
+            * cfg.total_ut_steps,
             lm.SLIDING: len(cfg.layers_of(lm.SLIDING))
             * min(length, cfg.sliding_window) * sequences,
         }

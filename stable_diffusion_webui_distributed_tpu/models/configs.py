@@ -124,7 +124,21 @@ class LMConfig:
     ``residual_streams`` over 1 replaces ``x + F(norm(x))`` by that many
     streams a token, read, written and mixed per token by a mixer around
     every sublayer (models/lm.py:StreamMixer), whose mixing matrix is made
-    doubly stochastic by ``sinkhorn_iters`` Sinkhorn iterations."""
+    doubly stochastic by ``sinkhorn_iters`` Sinkhorn iterations.
+
+    ``total_ut_steps`` over 1 is a looped model: a token goes through the
+    whole stack that many times over the SAME weights, the final norm
+    closing every pass, and pass ``t`` of a layer writes and attends keys
+    and values of its own (models/lm.py: the cache's pass axis). All
+    passes always run. A learned gate ``lambda_t = sigmoid(w_g . h_t +
+    b_g)`` reads each pass's normed output: the head
+    reads the first pass whose cumulated exit probability ``S_t = sum_{i <=
+    t} lambda_i prod_{j < i} (1 - lambda_j)`` reaches
+    ``early_exit_threshold`` (``S`` of the last pass counts as 1, so at
+    threshold 1 it is the last). ``post_sublayer_norm`` puts an RMS norm
+    after each sublayer as well as before it: ``x + norm(F(norm(x)))``.
+    At one pass and without such norms a model is what it was before these
+    keys existed: no loop, no gate, no pass axis, no leaf more."""
 
     vocab_size: int = 100352
     hidden_size: int = 3072
@@ -176,6 +190,18 @@ class LMConfig:
     sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
+    total_ut_steps: int = 1
+    early_exit_threshold: float = 1.0
+    post_sublayer_norm: bool = False
+
+    def __post_init__(self) -> None:
+        if self.total_ut_steps > 1 and (
+                set(self.layer_types) != {"full"}
+                or len(set(self.num_heads_per_layer)) != 1
+                or self.residual_streams != 1 or self.expert_layers):
+            raise ValueError("a looped stack (total_ut_steps over 1) wants "
+                             "full attention layers all alike, dense MLPs "
+                             "and one stream")
 
     @property
     def num_layers(self) -> int:
@@ -704,6 +730,51 @@ TINY_WINDOW_EXPAND = dataclasses.replace(
 def tiny_mellum2_expander() -> ModelFamily:
     """Factory form of :data:`TINY_WINDOW_EXPAND` (benchmark rehearsals)."""
     return TINY_WINDOW_EXPAND
+
+
+# Ouro-2.6B (huggingface.co/ByteDance/Ouro-2.6B config.json; the LoopLM
+# family, arXiv 2510.25741) at its published widths, whole: 48 full-attention
+# layers of 16 heads of width 128 over 16 KV heads (no grouping), RoPE theta
+# 1e6 over every dim, a dense SwiGLU of width 5632 in every layer, an RMS norm
+# before AND after each sublayer, vocabulary 49152, head untied; a token
+# passes the whole stack FOUR times over the same weights, each pass with
+# keys and values of its own, and a learned gate picks the pass the head
+# reads (at the published threshold 1: the fourth).
+OURO_2_6B = LMConfig(
+    vocab_size=49152, hidden_size=2048, layer_types=("full",) * 48,
+    num_heads_per_layer=(16,) * 48, num_kv_heads=16, head_dim=128,
+    rope_full=RopeConfig(theta=1e6), dense_layers=tuple(range(48)),
+    intermediate_size=5632, num_experts=0, num_experts_per_tok=0,
+    moe_intermediate_size=0, shared_expert_intermediate_size=0,
+    rms_norm_eps=1e-6, attn_gate="none", total_ut_steps=4,
+    early_exit_threshold=1.0, post_sublayer_norm=True)
+
+
+def sd15_ouro_expander() -> ModelFamily:
+    """SD1.5 with Ouro-2.6B as its resident prompt expander, held whole:
+    all 48 layers, all 16 heads, all 49152 vocabulary ids, all four
+    passes."""
+    return dataclasses.replace(SD15, name="sd15-ouro-expand",
+                               expander=OURO_2_6B)
+
+
+# Tiny looped expander: 4 full layers of 4 ungrouped heads passed 3 times,
+# sandwich norms, the exit gate at the published threshold.
+TINY_LOOP_LM = LMConfig(
+    vocab_size=512, hidden_size=64, layer_types=("full",) * 4,
+    num_heads_per_layer=(4,) * 4, num_kv_heads=4, head_dim=16,
+    rope_full=RopeConfig(theta=1e6), dense_layers=(0, 1, 2, 3),
+    intermediate_size=128, num_experts=0, num_experts_per_tok=0,
+    moe_intermediate_size=0, shared_expert_intermediate_size=0,
+    rms_norm_eps=1e-6, attn_gate="none", total_ut_steps=3,
+    early_exit_threshold=1.0, post_sublayer_norm=True)
+TINY_LOOP_EXPAND = dataclasses.replace(
+    TINY, name="tiny-loop-expand", expander=TINY_LOOP_LM)
+
+
+def tiny_ouro_expander() -> ModelFamily:
+    """Factory form of :data:`TINY_LOOP_EXPAND` (benchmark rehearsals)."""
+    return TINY_LOOP_EXPAND
 
 
 FAMILIES = {f.name: f for f in (SD15, SD21, SD21_BASE, SDXL_BASE,

@@ -52,6 +52,22 @@ take a chunk's; attention alone tells the sequences apart. That holds for
 the ``full`` and ``sliding`` kinds (:func:`shares_a_step`); a model with a
 recurrent state, kept rows, latents or several residual streams decodes one
 sequence a step.
+
+A LOOPED model (``LMConfig.total_ut_steps`` over 1: full attention, dense
+MLPs, one stream) passes every token through its whole stack that many
+times over one set of weights. The stack is the body of ONE loop over the
+passes (``jax.lax.scan`` in :class:`DecoderLM`), its layers are alike and
+share one trace of a layer, the final norm closes every pass, and a
+learned gate says after which pass the head reads. Pass ``t`` of a layer
+attends the keys and values that pass ``t`` wrote for the earlier
+positions, so a layer's key and value buffers carry a PASS AXIS in front
+of their slots: ``(passes, capacity, kv heads, head_dim)``, with the
+sequence axis in front of that under ``sequences`` (:func:`cache_shapes`):
+the model has ``passes x layers`` cache slots a position. Every pass of
+every chunk always runs (a later token attends all of them); the gate's
+rule only picks the state that is read. With ``post_sublayer_norm`` a
+layer norms each sublayer's output too. A model of one pass has no loop,
+no gate, no such norm and no pass axis, as before these existed.
 """
 
 from __future__ import annotations
@@ -283,15 +299,42 @@ class Attention(nn.Module):
 
     @nn.compact
     def __call__(self, n, q_pos, start, end, k_cache, v_cache,
-                 sequences: bool = False):
+                 sequences: bool = False, pass_index=None):
+        """``pass_index``: which pass of a looped stack this is; the
+        buffers then carry the pass axis just before their slots' and the
+        chunk writes and attends that pass's rows alone."""
         cfg = self.config
         kind = cfg.layer_types[self.layer]
         heads = cfg.num_heads_per_layer[self.layer]
         kv, dim = cfg.num_kv_heads, cfg.head_dim
         tokens = n.shape[0]
+        passes = 1 if pass_index is None else cfg.total_ut_steps
 
         def lin(features, name):
             return Linear(features, self.dtype, self.quant, name=name)
+
+        def put(cache, rows, at, axis):
+            """``rows`` written from slot ``at`` of the slots' axis, in the
+            pass's own rows where the buffer has a pass axis."""
+            if pass_index is None:
+                return jax.lax.dynamic_update_slice_in_dim(
+                    cache, rows, at, axis)
+            index = [0] * cache.ndim
+            index[axis:axis + 2] = pass_index, at
+            return jax.lax.dynamic_update_slice(
+                cache, jnp.expand_dims(rows, axis), index)
+
+        def of_pass(cache, axis):
+            """The rows this chunk attends: the pass's own. (XLA copies
+            them out of the buffer before the products read them, 8.5 ms
+            of a 32 ms decode step at the published widths; a branch a
+            pass over a buffer a (layer, pass) slot reads in place and
+            costs 58 ms a step in the copies XLA makes for the branches:
+            PERF.md section 6, PR 49.)"""
+            if pass_index is None:
+                return cache
+            return jax.lax.dynamic_index_in_dim(cache, pass_index, axis,
+                                                keepdims=False)
 
         cos, sin = rope_tables(
             cfg.rope_full if kind == FULL else cfg.rope_sliding, dim, q_pos)
@@ -318,12 +361,10 @@ class Attention(nn.Module):
             # ring slot the token takes held position ``start - window``,
             # which its own query no longer sees
             window = 0 if kind == FULL else k_cache.shape[1]
-            slots = jnp.arange(k_cache.shape[1])
+            slots = jnp.arange(k_cache.shape[-3])
             into = start % window if window else start
-            k_cache = jax.lax.dynamic_update_slice_in_dim(
-                k_cache, k[:, None], into, 1)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(
-                v_cache, v[:, None], into, 1)
+            k_cache = put(k_cache, k[:, None], into, 1)
+            v_cache = put(v_cache, v[:, None], into, 1)
             # a slot's newest position at or before ``start``; negative:
             # never written
             k_pos = (start - (start - slots) % window if window
@@ -337,15 +378,15 @@ class Attention(nn.Module):
                 paths.add(path)
                 return out[0]
 
-            out = jax.vmap(one)(q, k_cache, v_cache)
-            ATTENTION.record(paths.pop(), 1, k_cache.shape[1], dim)
+            out = jax.vmap(one)(q, of_pass(k_cache, 1), of_pass(v_cache, 1))
+            ATTENTION.record(paths.pop(), 1, k_cache.shape[-3], dim, passes)
         elif kind == FULL:
             # written first: a padded row lands beyond ``end``, where no
             # query looks until a real token has overwritten it
-            k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k, start, 0)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v, start, 0)
-            slots = jnp.arange(k_cache.shape[0])
-            keys, values = k_cache, v_cache
+            k_cache = put(k_cache, k, start, 0)
+            v_cache = put(v_cache, v, start, 0)
+            keys, values = of_pass(k_cache, 0), of_pass(v_cache, 0)
+            slots = jnp.arange(keys.shape[0])
             k_pos = jnp.where(slots < end, slots, -1)
             window = 0
         else:
@@ -364,7 +405,7 @@ class Attention(nn.Module):
         if not sequences:
             out, path = attend_positions(q, keys, values, q_pos, k_pos,
                                          scale=dim ** -0.5, window=window)
-            ATTENTION.record(path, tokens, keys.shape[0], dim)
+            ATTENTION.record(path, tokens, keys.shape[0], dim, passes)
         if cfg.attn_gate == "element":
             out = out.astype(jnp.float32) * jax.nn.sigmoid(gate)
         elif cfg.attn_gate == "head":
@@ -733,13 +774,14 @@ class DecoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x, q_pos, start, end, buffers,
-                 sequences: bool = False, real=None):
+                 sequences: bool = False, real=None, pass_index=None):
         """``buffers`` are the layer's own of the cache (:func:`buffers_of`
         its kind), returned as the chunk leaves them. ``x`` is ``(T,
         hidden)``, or ``(T, streams, hidden)`` with several streams.
         ``sequences``: the rows are one token each of as many sequences
         (:func:`shares_a_step`), the buffers theirs side by side, and
-        ``real`` says which rows count."""
+        ``real`` says which rows count. ``pass_index``: the pass of a
+        looped stack, whose rows of the buffers the layer then takes."""
         cfg = self.config
         kind = cfg.layer_types[self.layer]
 
@@ -768,7 +810,7 @@ class DecoderLayer(nn.Module):
                 mixed, *after = Attention(
                     cfg, self.layer, self.dtype, self.quant, name="attn")(
                         n, q_pos, start, end, *buffers,
-                        sequences=sequences)
+                        sequences=sequences, pass_index=pass_index)
             return mixed, tuple(after)
 
         def mlp(n):
@@ -786,14 +828,23 @@ class DecoderLayer(nn.Module):
                 (mlp, "post_attention_norm", "mlp_hc")):
             norm = RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm,
                            name=norm_name)
+
+            def normed_after(out):
+                """The sublayer's output as the residual takes it."""
+                if not cfg.post_sublayer_norm:
+                    return out
+                return RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm,
+                               name=norm_name + "_2")(out)
+
             if streams == 1:
                 out, more = sublayer(norm(x))
-                x = x + out
+                x = x + normed_after(out)
                 beside.append(more)
                 continue
             mixed = StreamMixer(
                 cfg, self.sinkhorn_dtype, self.meshed, name=hc)(x)
             out, more = sublayer(norm(mixed.read))
+            out = normed_after(out)
             beside.append(more)
             with jax.named_scope(hc):
                 x = written(x, mixed, out)
@@ -855,35 +906,121 @@ class DecoderLM(nn.Module):
             x = jnp.broadcast_to(
                 x[:, None, :], (x.shape[0], cfg.residual_streams,
                                 x.shape[1])).astype(self.stream_dtype)
-        # a buffer list has one entry for each layer that has the buffer,
-        # in layer order
-        written = {name: [] for name in cache}
-        routed = []
-        for layer, kind in enumerate(cfg.layer_types):
-            names = buffers_of(kind)
-            x, buffers, r = DecoderLayer(
+
+        def layer_module(layer, **how):
+            return DecoderLayer(
                 cfg, layer, self.dtype, self.quant_linears, self.meshed,
                 self.stream_dtype, self.sinkhorn_dtype, self.conv_dtype,
-                name=f"layers_{layer}")(
-                    x, q_pos, start, end,
+                **how)
+
+        def named_layer(layer, x, buffers, pass_index):
+            """Layer ``layer`` as this module's own submodule."""
+            return layer_module(layer, name=f"layers_{layer}")(
+                x, q_pos, start, end, buffers, sequences=sequences,
+                real=real, pass_index=pass_index)
+
+        def stack(apply_layer, x, cache, pass_index=None):
+            """(x, the cache, what the expert layers routed) after every
+            layer once. A buffer list has one entry for each layer that has
+            the buffer, in layer order."""
+            written = {name: [] for name in cache}
+            routed = []
+            for layer, kind in enumerate(cfg.layer_types):
+                names = buffers_of(kind)
+                x, buffers, r = apply_layer(
+                    layer, x,
                     tuple(cache[name][len(written[name])] for name in names),
-                    sequences=sequences, real=real)
-            for name, buffer in zip(names, buffers):
-                written[name].append(buffer)
-            if r is not None:
-                routed.append(r)
-        cache = written
-        if cfg.residual_streams > 1:    # the streams' sum is the output
-            x = jnp.sum(x.astype(jnp.float32), axis=1)
-        if not all_logits:
-            x = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, 0)
-        n = RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm, name="norm")(x)
+                    pass_index)
+                for name, buffer in zip(names, buffers):
+                    written[name].append(buffer)
+                if r is not None:
+                    routed.append(r)
+            return x, written, routed
+
+        def final_norm(**how):
+            return RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm, **how)
+
+        if cfg.total_ut_steps == 1:
+            x, cache, routed = stack(named_layer, x, cache)
+            if cfg.residual_streams > 1:    # the streams' sum is the output
+                x = jnp.sum(x.astype(jnp.float32), axis=1)
+            if not all_logits:
+                x = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, 0)
+            n = final_norm(name="norm")(x)
+        elif self.is_initializing():
+            # one pass through the named submodules makes every parameter
+            x, cache, routed = stack(named_layer, x, cache, jnp.int32(0))
+            h = jnp.stack([final_norm(name="norm")(x)] * cfg.total_ut_steps)
+            n = self.read_pass(h, None if all_logits else length)
+        else:
+            params = self.variables["params"]
+            one = layer_module(0, parent=None)
+            # every layer of a looped stack is alike, so ONE trace of a
+            # layer serves all of them, each called on its own parameters:
+            # 48 layers traced in turn cost a cold set-up more than the
+            # whole of SD1.5's (PERF.md section 6, PR 49)
+            @jax.jit
+            def looped_layer(p, x, buffers, pass_index, *where):
+                return one.apply({"params": p}, x, *where, buffers,
+                                 sequences=sequences, real=real,
+                                 pass_index=pass_index)
+
+            def shared_layer(layer, x, buffers, pass_index):
+                return looped_layer(params[f"layers_{layer}"], x, buffers,
+                                    pass_index, q_pos, start, end)
+
+            def one_pass(carry, pass_index):
+                x, cache, _ = stack(shared_layer, *carry, pass_index)
+                x = final_norm(parent=None).apply(
+                    {"params": params["norm"]}, x)
+                return (x, cache), x
+
+            # ONE loop whose body is the stack, the final norm closing
+            # every pass: the next pass, the gate and the head read the
+            # normed state
+            (_, cache), h = jax.lax.scan(one_pass, (x, cache),
+                                         jnp.arange(cfg.total_ut_steps))
+            routed = []
+            n = self.read_pass(h, None if all_logits else length)
         logits = Linear(cfg.vocab[1], self.dtype, self.quant_linears,
                         name="lm_head")(n)
         if not routed:     # no expert layer: the three parts, empty
             none = jnp.zeros((0,), jnp.int32)
-            return logits, cache, (none[:, None, None], none[:, None], none)
+            return logits, cache, (
+                none[:, None, None],
+                jnp.zeros((0, cfg.experts[1]), jnp.int32), none)
         return logits, cache, tuple(jnp.stack(part) for part in zip(*routed))
+
+    @nn.nowrap
+    def read_pass(self, h, last):
+        """The state the head reads, of a looped model's ``h`` ``(passes,
+        T, hidden)``: each pass's normed output. Every pass always runs (a
+        later token attends each pass's keys and values of this one); what
+        the learned gate decides is which pass's state the head reads, per
+        row: ``lambda_t = sigmoid(w_g . h_t + b_g)``, ``S_t = sum_{i <= t}
+        lambda_i prod_{j < i} (1 - lambda_j)`` with the last pass's ``S``
+        taken as 1, and the first pass whose ``S_t`` reaches
+        ``early_exit_threshold``. ``last``: only the row before it is read
+        (None: every row). The gates ``(passes, rows)`` and the chosen
+        passes ``(rows,)`` are sown under ``"passes"``
+        (:func:`apply_counting`)."""
+        cfg = self.config
+        if last is not None:
+            h = jax.lax.dynamic_slice_in_dim(h, last - 1, 1, 1)
+        # the gate sees the state in float32, as a router does: a pass
+        # chosen by rounding the input would read another state
+        gate = nn.Dense(1, dtype=jnp.float32, param_dtype=jnp.float32,
+                        precision=jax.lax.Precision.HIGHEST,
+                        name="early_exit_gate")
+        lam = jax.nn.sigmoid(gate(h)[..., 0])
+        stay = jnp.cumprod(1.0 - lam, axis=0)
+        left = jnp.cumsum(
+            lam * jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]]),
+            axis=0).at[-1].set(1.0)
+        chosen = jnp.argmax(left >= cfg.early_exit_threshold, axis=0)
+        self.sow("passes", "gates", lam)
+        self.sow("passes", "exit", chosen)
+        return jnp.take_along_axis(h, chosen[None, :, None], axis=0)[0]
 
 
 # -- the cache's shapes, and the executables the engine builds ----------------
@@ -896,9 +1033,11 @@ def cache_shapes(cfg: LMConfig, capacity: int) -> Dict[str, list]:
     has ``latent``: ``capacity`` rows of ``latent_width``. A conv layer has
     ``kept``: its convolution's ``conv_taps - 1`` last inputs, whatever the
     capacity. A model without layers of a kind has none of the kind's
-    names."""
-    rows = [(capacity if kind == FULL else cfg.sliding_window,
-             cfg.num_kv_heads, cfg.head_dim)
+    names. A looped model's ``k`` and ``v`` carry the pass axis in front:
+    ``(passes, capacity, kv heads, head_dim)``."""
+    passes = (cfg.total_ut_steps,) if cfg.total_ut_steps > 1 else ()
+    rows = [passes + (capacity if kind == FULL else cfg.sliding_window,
+                      cfg.num_kv_heads, cfg.head_dim)
             for kind in cfg.layer_types if kind in (FULL, SLIDING)]
     shapes = {"k": rows, "v": list(rows)} if rows else {}
     linear = len(cfg.layers_of(LINEAR))
@@ -958,23 +1097,58 @@ def sample_each(logits: jax.Array, keys: jax.Array, position, temperature,
         logits, keys, position, temperature, first)
 
 
+def apply_counting(module: DecoderLM, variables, *args, live=None,
+                   **kwargs):
+    """``module.apply`` as ``(logits, cache, routed, exits)``. ``exits`` is
+    what an executable of a looped model returns beside the rest, ``()``
+    for a model of one pass (whose executables return what they always
+    did): one pair of the rows by the pass whose state the head read
+    ``(passes,)`` and the largest exit probability any gate gave, over the
+    first ``live`` rows whose logits were made (None: all of them)."""
+    cfg = module.config
+    if cfg.total_ut_steps == 1:
+        return module.apply(variables, *args, **kwargs) + ((),)
+    (logits, cache, routed), sown = module.apply(
+        variables, *args, mutable=["passes"], **kwargs)
+    (gates,), (chosen,) = sown["passes"]["gates"], sown["passes"]["exit"]
+    real = jnp.arange(chosen.shape[0]) < (
+        chosen.shape[0] if live is None else live)
+    counts = jnp.sum(jax.nn.one_hot(chosen, cfg.total_ut_steps,
+                                    dtype=jnp.int32) * real[:, None], axis=0)
+    return logits, cache, routed, (
+        (counts, jnp.max(jnp.where(real[None], gates, 0.0))),)
+
+
+def no_exits(cfg: LMConfig):
+    """What :func:`apply_counting`'s ``exits`` add up from."""
+    if cfg.total_ut_steps == 1:
+        return ()
+    return ((jnp.zeros((cfg.total_ut_steps,), jnp.int32), jnp.float32(0)),)
+
+
+def add_exits(so_far, exits):
+    return tuple((counts + more, jnp.maximum(most, gate))
+                 for (counts, most), (more, gate) in zip(so_far, exits))
+
+
 def prefill_fn(module: DecoderLM, sequences: bool = False):
     """``expand_prefill(params, cache, tokens, start, length, key,
     temperature) -> (cache, next token, routed load, none held)``: one
     chunk, and the token that follows its last real one. With
     ``sequences``, ``key`` is ``(B,)`` keys and the next token ``(B,)``:
     what each key draws from the chunk's one last row, the first tokens of
-    ``B`` sequences that share the chunk."""
+    ``B`` sequences that share the chunk. A looped model's returns
+    :func:`apply_counting`'s ``exits`` of that row after these."""
 
     def expand_prefill(params, cache, tokens, start, length, key,
                        temperature):
-        logits, cache, routed = module.apply(
-            {"params": params}, tokens, start, length, cache,
+        logits, cache, routed, exits = apply_counting(
+            module, {"params": params}, tokens, start, length, cache,
             all_logits=False)
         draw = sample_each if sequences else sample
         token = draw(logits[0], key, start + length, temperature,
                      module.config.vocab[0])
-        return cache, token, routed[1], routed[2]
+        return (cache, token, routed[1], routed[2]) + exits
 
     return expand_prefill
 
@@ -983,29 +1157,31 @@ def decode_chunk_fn(module: DecoderLM, steps: int):
     """``expand_decode_chunk(params, cache, token, position, key,
     temperature) -> (cache, token, position, the steps' tokens, routed
     load, none held)``: ``steps`` tokens, each fed back as the next input;
-    ``token`` sits at ``position`` and is not yet in the cache."""
+    ``token`` sits at ``position`` and is not yet in the cache. A looped
+    model's returns the steps' ``exits`` after these."""
 
     def expand_decode_chunk(params, cache, token, position, key,
                             temperature):
         variables = {"params": params, "mixers": mixer_operands(params)}
 
         def step(carry, _):
-            cache, token, position, load, none_held = carry
-            logits, cache, routed = module.apply(
-                variables, token[None], position, 1, cache,
+            cache, token, position, load, none_held, *exits = carry
+            logits, cache, routed, more = apply_counting(
+                module, variables, token[None], position, 1, cache,
                 all_logits=False)
             token = sample(logits[0], key, position + 1, temperature,
                            module.config.vocab[0])
             return (cache, token, position + 1, load + routed[1],
-                    none_held + routed[2]), token
+                    none_held + routed[2]) + add_exits(exits, more), token
 
         cfg = module.config
         layers = len(cfg.expert_layers)
         zero = (jnp.zeros((layers, cfg.experts[1]), jnp.int32),
-                jnp.zeros((layers,), jnp.int32))
-        (cache, token, position, load, none_held), made = jax.lax.scan(
-            step, (cache, token, position) + zero, None, length=steps)
-        return cache, token, position, made, load, none_held
+                jnp.zeros((layers,), jnp.int32)) + no_exits(cfg)
+        (cache, token, position, load, none_held, *exits), made = \
+            jax.lax.scan(step, (cache, token, position) + zero, None,
+                         length=steps)
+        return (cache, token, position, made, load, none_held) + tuple(exits)
 
     return expand_decode_chunk
 
@@ -1022,7 +1198,8 @@ def decode_sequences_fn(module: DecoderLM, steps: int):
     the load. ``experts read`` ``(expert
     layers,)`` sums over the steps the DISTINCT held experts a step's rows
     chose (ops/moe.py:experts_read): what a step streams, however many of
-    its rows chose one."""
+    its rows chose one. A looped model's returns the live rows' ``exits``
+    after these."""
 
     def expand_decode_chunk(params, cache, tokens, position, keys,
                             temperature, live):
@@ -1030,22 +1207,25 @@ def decode_sequences_fn(module: DecoderLM, steps: int):
         cfg = module.config
 
         def step(carry, _):
-            cache, tokens, position, load, none_held, read = carry
-            logits, cache, routed = module.apply(
-                variables, tokens, position, live, cache, sequences=True)
+            cache, tokens, position, load, none_held, read, *exits = carry
+            logits, cache, routed, more = apply_counting(
+                module, variables, tokens, position, live, cache,
+                sequences=True, live=live)
             tokens = sample_each(logits, keys, position + 1, temperature,
                                  cfg.vocab[0])
             return (cache, tokens, position + 1, load + routed[1],
                     none_held + routed[2],
-                    read + moe.experts_read(routed[1])), tokens
+                    read + moe.experts_read(routed[1])) \
+                + add_exits(exits, more), tokens
 
         layers = len(cfg.expert_layers)
         zero = (jnp.zeros((layers, cfg.experts[1]), jnp.int32),
                 jnp.zeros((layers,), jnp.int32),
-                jnp.zeros((layers,), jnp.int32))
-        (cache, tokens, position, load, none_held, read), made = \
+                jnp.zeros((layers,), jnp.int32)) + no_exits(cfg)
+        (cache, tokens, position, load, none_held, read, *exits), made = \
             jax.lax.scan(step, (cache, tokens, position) + zero, None,
                          length=steps)
-        return cache, tokens, position, made, load, none_held, read
+        return (cache, tokens, position, made, load, none_held,
+                read) + tuple(exits)
 
     return expand_decode_chunk

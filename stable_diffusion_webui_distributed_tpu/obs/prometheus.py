@@ -797,6 +797,19 @@ def render() -> str:
             s["avg_padding_ratio"])
     _scalar(lines, "sdtpu_serving_unet_images_total", "counter",
             "Images decoded to outputs.", s["unet_images"])
+    expander = s["expander"]
+    _scalar(lines, "sdtpu_expander_layer_passes_total", "counter",
+            "Passes of the whole stack the prompt expander's token steps "
+            "ran (a looped model: total_ut_steps a step).",
+            expander["layer_passes"])
+    _labeled_family(
+        lines, "sdtpu_expander_exit_pass_total", "counter",
+        "Tokens the prompt expander made, by the pass whose state the "
+        "head read (1 is the first).",
+        [(f'pass="{i + 1}"', n) for i, n in enumerate(expander["exit_pass"])])
+    _scalar(lines, "sdtpu_expander_exit_lambda_max", "gauge",
+            "Largest exit probability a pass's gate gave.",
+            expander["exit_lambda_max"])
 
     _labeled_family(
         lines, "sdtpu_stage_compiles_total", "counter",

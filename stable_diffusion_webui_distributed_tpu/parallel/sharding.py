@@ -28,7 +28,9 @@ cache to every chip), the residual streams' mixers (``attn_hc``,
 A short-convolution mixer (module ``short_conv``: the fused in-projection
 whose columns are its two gates and its input, the taps, ``out_proj``) is
 whole as well: a split of the fused columns over ``tp`` would not fall on
-the three parts' borders.
+the three parts' borders. A looped model's exit gate (``early_exit_gate``:
+one column and a bias) and the norms after its sublayers (``input_norm_2``,
+``post_attention_norm_2``) are whole on every chip, as every norm is.
 One chip's share (``LMConfig.experts_held``,
 ``vocab_held``) is what one position of those axes holds; the exchange that
 adds the parts exists only on a mesh that has the axis.
@@ -49,7 +51,8 @@ _ROW_ENDINGS = ("out_proj", "fc2", "ff_out", "time_fc2", "add_fc2",
 _EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 #: modules of the language model whose leaves every chip holds whole
 _LM_REPLICATED = ("shared_expert_gate", "q_a_proj", "q_b_proj",
-                  "kv_a_proj_with_mqa", "kv_b_proj", "attn_hc", "mlp_hc")
+                  "kv_a_proj_with_mqa", "kv_b_proj", "attn_hc", "mlp_hc",
+                  "early_exit_gate")
 
 
 def tp_spec_for(path: str, ndim: int):

@@ -34,6 +34,13 @@ the fixed weights once and each distinct expert once. Their count is
 padded up to one of cache/kv.py:SEQUENCE_BUCKETS with repeats of the last,
 whose tokens are dropped. One image takes the one-sequence executables
 under the keys they have always had.
+
+A looped model (``LMConfig.total_ut_steps`` over 1) runs its whole stack
+that many times a token inside each executable; its spans carry ``passes``
+and its executables return, beside the rest, which pass's state the head
+read for each token made and the largest exit probability
+(models/lm.py:apply_counting), which go to ``serving.expander`` as
+``exit_pass``, ``exit_lambda_max`` and ``layer_passes``.
 """
 
 from __future__ import annotations
@@ -179,6 +186,10 @@ class PromptExpander:
         recurrent = lm.LINEAR in self.config.layer_types
         conv = lm.CONV in self.config.layer_types
         latent = lm.LATENT in self.config.layer_types
+        passes = self.config.total_ut_steps
+        # span attributes of a looped model alone
+        looped = {"passes": passes} if passes > 1 else {}
+        exits = []        # per executable call of a looped model
         routed = []       # per executable call: (load, none held)
         masked = 0        # padded rows kept out of a recurrence or kept rows
         token = None
@@ -188,7 +199,7 @@ class PromptExpander:
                 continue
             padded = np.zeros(kv.chunk_bucket(len(ids)), np.int32)
             padded[:len(ids)] = ids
-            attrs = {"tokens": len(ids), "prefix_hit": bool(held)}
+            attrs = {"tokens": len(ids), "prefix_hit": bool(held), **looped}
             if recurrent or conv:   # rows kept out of the layers' state
                 attrs["padded"] = len(padded) - len(ids)
             if recurrent:           # the form its recurrence takes
@@ -198,11 +209,12 @@ class PromptExpander:
             # the instruction's chunk yields no token that is kept: it runs
             # at one sequence whatever follows it
             with obs_spans.span("expand.prefill", **attrs):
-                cache, token, step_load, step_none = self._prefill_fn(
-                    len(padded), capacity, 1 if keep else batch)(
-                        params, cache, padded, jnp.int32(start),
-                        jnp.int32(len(ids)), first_key if keep else key,
-                        temperature)
+                cache, token, step_load, step_none, *chose = \
+                    self._prefill_fn(
+                        len(padded), capacity, 1 if keep else batch)(
+                            params, cache, padded, jnp.int32(start),
+                            jnp.int32(len(ids)), first_key if keep else key,
+                            temperature)
                 # fenced: the span is the chunk's device time, not its
                 # enqueue
                 jax.block_until_ready(token)
@@ -211,10 +223,12 @@ class PromptExpander:
                                     bytes=copied):
                     self.cache.keep_prefix(prefix, capacity, cache)
             routed.append((step_load, step_none))
+            if not keep:    # the instruction's chunk yields no token
+                exits += chose
             masked += attrs.get("padded", 0)
         if batch > 1:
             with obs_spans.span("expand.fork", sequences=batch,
-                                bytes=sum(sizes.values())):
+                                bytes=sum(sizes.values()), **looped):
                 cache = self._fork_fn(capacity, batch)(cache)
                 jax.block_until_ready(cache)    # fenced, as a prefill is
         # (live, tokens so far): the first of each from the prompt's row
@@ -240,12 +254,14 @@ class PromptExpander:
                     and all(tok.eos in one for one in made)):
                 break
             with obs_spans.span("expand.decode_chunk", tokens=DECODE_STEPS,
-                                sequences=live):
+                                sequences=live, **looped):
                 cache, token, position, out, step_load, step_none, *read = \
                     decode(params, cache, token, position, key,
                            temperature, *more)
             steps += DECODE_STEPS
             routed.append((step_load, step_none))
+            if looped:      # the last of what a looped model returns
+                exits.append(read.pop())
             reads += read
             pending.append(out)
             if len(pending) > 1:
@@ -254,7 +270,8 @@ class PromptExpander:
             fetch(out)
         # the cut and the counters' fetch: host work with the device idle
         with obs_spans.span("expand.account",
-                            fetched=2 * len(routed) + len(reads)):
+                            fetched=2 * len(routed) + len(reads)
+                            + 2 * len(exits)):
             made = [one[:args.max_new_tokens] for one in made]
             if not args.ignore_eos:     # each sequence is cut at its own
                 made = [one[:one.index(tok.eos)] if tok.eos in one else one
@@ -277,8 +294,24 @@ class PromptExpander:
                 padded_rows_masked=masked,
                 residual_streams=self.config.residual_streams,
                 sinkhorn_iters=(self.config.sinkhorn_iters
-                                if self.config.residual_streams > 1 else 0))
+                                if self.config.residual_streams > 1 else 0),
+                **self._passes_run(exits, steps))
         return made
+
+    def _passes_run(self, exits, steps: int) -> dict:
+        """What a looped model adds to ``EXPANDER.record``: the passes of
+        the stack its ``steps`` decode steps ran (every pass of every
+        step; a prefill chunk's are not among them), the tokens by the
+        pass the head read, the largest exit probability. ``exits`` are
+        the executables' own counts, still on the device. A prefill of
+        several sequences reads ONE row for all their first tokens, and
+        counts it once."""
+        if not exits:
+            return {}
+        counts, gates = zip(*jax.device_get(exits))
+        return {"layer_passes": self.config.total_ut_steps * steps,
+                "exit_pass": np.sum(counts, axis=0),
+                "exit_lambda_max": float(np.max(gates))}
 
     def _fit(self, text: str, chunks: Optional[int]) -> str:
         """The longest tail of ``text``'s words whose CLIP tokens fit

@@ -380,7 +380,10 @@ class AttentionSites:
     request or a mesh asked for those. A site is one ``Attention`` call in
     one trace, so a model traced twice (the chunk executable, then the
     FLOPs pricing of pipeline/stepcache.py) counts twice; nothing is
-    counted when an executable runs."""
+    counted when an executable runs. A site of a looped language model
+    (models/lm.py: its layers are alike and share ONE trace of a layer an
+    executable, so it records one site) carries the passes it runs: ``P4``
+    after its shape."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -388,12 +391,14 @@ class AttentionSites:
 
     def clear(self) -> None:
         with self._lock:
-            #: (path, tokens, context tokens, head_dim) -> sites
+            #: (path, tokens, context tokens, head_dim, passes) -> sites
             self.sites: Dict[tuple, int] = defaultdict(int)  # guarded-by: _lock
 
-    def record(self, path: str, t: int, s: int, head_dim: int) -> None:
+    def record(self, path: str, t: int, s: int, head_dim: int,
+               passes: int = 1) -> None:
         with self._lock:
-            self.sites[(path, int(t), int(s), int(head_dim))] += 1
+            self.sites[(path, int(t), int(s), int(head_dim),
+                        int(passes))] += 1
 
     def summary(self) -> Dict[str, Any]:
         """``{"tiled": n, "xla": m, "by_shape": {"T4096 S4096 D64":
@@ -402,9 +407,10 @@ class AttentionSites:
             sites = dict(self.sites)
         out: Dict[str, Any] = {"tiled": 0, "xla": 0}
         by_shape: Dict[str, Dict[str, int]] = {}
-        for (path, t, s, d), n in sorted(sites.items()):
+        for (path, t, s, d, passes), n in sorted(sites.items()):
             out[path] = out.get(path, 0) + n
-            by_shape.setdefault(f"T{t} S{s} D{d}", {})[path] = n
+            shape = f"T{t} S{s} D{d}" + (f" P{passes}" if passes > 1 else "")
+            by_shape.setdefault(shape, {})[path] = n
         out["by_shape"] = by_shape
         return out
 
@@ -464,7 +470,13 @@ class ExpanderStats:
     ``mixer_products`` counts the residual streams' mixers the same way, by
     the form ops/stream_mixer.py:choose gave them, and ``conv_mixers`` the
     short-convolution mixers (models/lm.py:ShortConv), by whether the
-    trace was of one token (``step``) or of a longer chunk."""
+    trace was of one token (``step``) or of a longer chunk.
+    Of a looped model (``LMConfig.total_ut_steps`` over 1): ``layer_passes``,
+    the passes of the whole stack its decode steps ran (every pass of every
+    step, whichever the head read; over ``decode_steps``: passes a token); ``exit_pass``, the tokens made by the
+    pass whose state the head read for them (index 0 is the first pass);
+    ``exit_lambda_max``, the largest exit probability a gate gave. A model
+    of one pass leaves them 0, empty and 0."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -473,6 +485,9 @@ class ExpanderStats:
     def clear(self) -> None:
         with self._lock:
             self.requests = 0          # guarded-by: _lock
+            self.layer_passes = 0      # guarded-by: _lock
+            self.exit_pass: List[int] = []  # guarded-by: _lock
+            self.exit_lambda_max = 0.0  # guarded-by: _lock
             self.prefilled = 0         # guarded-by: _lock
             self.from_prefix = 0       # guarded-by: _lock
             self.sequences = 0         # guarded-by: _lock
@@ -513,7 +528,9 @@ class ExpanderStats:
                none_held: int,
                positions: Dict[str, int], state_bytes: Dict[str, int],
                prefix_snapshots: int, padded_rows_masked: int,
-               residual_streams: int, sinkhorn_iters: int) -> None:
+               residual_streams: int, sinkhorn_iters: int,
+               layer_passes: int = 0, exit_pass=(),
+               exit_lambda_max: float = 0.0) -> None:
         """``load`` is (expert layers, held experts) counts of one
         request; ``decode_steps`` the steps its decode executables ran
         (whole chunks, so at least ``decoded / sequences - 1``), each a
@@ -539,6 +556,13 @@ class ExpanderStats:
             self.padded_rows_masked += int(padded_rows_masked)
             self.residual_streams = int(residual_streams)
             self.sinkhorn_iters = int(sinkhorn_iters)
+            self.layer_passes += int(layer_passes)
+            if len(exit_pass):
+                old = self.exit_pass or [0] * len(exit_pass)
+                self.exit_pass = [a + int(b)
+                                  for a, b in zip(old, exit_pass)]
+            self.exit_lambda_max = max(self.exit_lambda_max,
+                                       float(exit_lambda_max))
 
     def summary(self) -> Dict[str, Any]:
         with self._lock:
@@ -565,6 +589,9 @@ class ExpanderStats:
                 "expert_products": dict(self.products),
                 "mixer_products": dict(self.mixers),
                 "conv_mixers": dict(self.convs),
+                "layer_passes": self.layer_passes,
+                "exit_pass": list(self.exit_pass),
+                "exit_lambda_max": self.exit_lambda_max,
             }
 
 

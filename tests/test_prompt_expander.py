@@ -867,7 +867,11 @@ class TestEnginePath:
             "expert_load_max_over_mean", "cache_positions", "state_bytes",
             "prefix_snapshots", "padded_rows_masked", "expert_products",
             "mixer_products", "conv_mixers", "residual_streams",
-            "sinkhorn_iters"}
+            "sinkhorn_iters", "layer_passes", "exit_pass",
+            "exit_lambda_max"}
+        # a model of one pass leaves the looped model's counters alone
+        assert (block["layer_passes"], block["exit_pass"],
+                block["exit_lambda_max"]) == (0, [], 0.0)
         assert set(block["expert_products"]) == {"kernel", "loop", "grouped"}
         assert set(block["mixer_products"]) == {"kernel", "loop"}
         assert block["conv_mixers"] == {"step": 0, "chunk": 0}
