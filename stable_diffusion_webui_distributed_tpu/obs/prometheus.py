@@ -254,6 +254,7 @@ def clear_histograms() -> None:
     for c in FLEET_COUNTERS.values():
         c.clear()
     PRECISION_COUNTER.clear()
+    COALESCE_WINDOW_COUNTER.clear()
     LORA_SWITCH_COUNTER.clear()
     AOT_COUNTER.clear()
     for c in WORKER_COUNTERS.values():
@@ -393,6 +394,16 @@ PRECISION_COUNTER = LabeledCounter(
     "sdtpu_dispatch_precision_total",
     "Requests dispatched to the device by resolved serving precision.",
     ("precision",))
+
+#: Coalesce windows by what ended them: ``full`` — the leader's group
+#: reached ``max_batch`` and could take no joiner, so it went at once;
+#: ``timer`` — the group stayed open for the whole window. One increment
+#: a leader (serving/dispatcher.py), with or without tracing.
+COALESCE_WINDOW_COUNTER = LabeledCounter(
+    "sdtpu_coalesce_window_total",
+    "Coalesce windows a group's leader waited, by what ended the wait "
+    "(full/timer).",
+    ("ended_by",))
 
 #: Adapter-set activations by serving mode: ``merged`` — host merge into
 #: the param tree (epoch bump, caches retired); ``traced`` — factor set
@@ -583,6 +594,11 @@ def count_precision(precision: str, n: float = 1.0) -> None:
     """One device dispatch carrying ``n`` requests at ``precision``."""
     if precision:
         PRECISION_COUNTER.inc(n, precision=precision)
+
+
+def count_coalesce_window(ended_by: str) -> None:
+    """One leader's coalesce window, ended by ``full`` or ``timer``."""
+    COALESCE_WINDOW_COUNTER.inc(ended_by=ended_by)
 
 
 def fleet_observe_queue_wait(cls: str, seconds: float) -> None:
@@ -836,6 +852,7 @@ def render() -> str:
          for stage in sorted(timings)])
 
     lines.extend(PRECISION_COUNTER.render())
+    lines.extend(COALESCE_WINDOW_COUNTER.render())
     lines.extend(LORA_SWITCH_COUNTER.render())
     lines.extend(AOT_COUNTER.render())
     for c in FLEET_COUNTERS.values():

@@ -7,11 +7,12 @@ dispatcher instead gives every request a ticket and groups compatible
 concurrent tickets — same sampler / steps / cfg / negative prompt /
 clip-skip and the same shape BUCKET (see :mod:`.bucketer`) — into one
 merged denoise loop, then splits images, seeds and infotext back per
-requester.  The first ticket of a group becomes the *leader*: it sleeps
-one coalesce window (``SDTPU_COALESCE_WINDOW`` /
-``ConfigModel.coalesce_window``, seconds) so followers can join, runs the
-merged batch under the engine-execution lock, and wakes the followers
-with their slice.
+requester.  The first ticket of a group becomes the *leader*: it waits
+for followers to join, at the longest one coalesce window
+(``SDTPU_COALESCE_WINDOW`` / ``ConfigModel.coalesce_window``, seconds: the
+longest the first request of a group waits; a group that is full goes at
+once), runs the merged batch under the engine-execution lock, and wakes
+the followers with their slice.
 
 Seed-exactness: every stochastic draw in the engine is keyed by
 ``(request seed + image index)`` and never by batch position
@@ -129,6 +130,10 @@ class _Group:
         self.tickets: List[Ticket] = []
         self.images = 0
         self.closed = False
+        #: set, under the dispatcher's ``_lock``, by the ticket that brings
+        #: ``images`` to ``max_batch``: no joiner fits any more (a cancelled
+        #: ticket keeps its rows), so the leader's window ends there
+        self.full = threading.Event()
 
 
 class ServingDispatcher:
@@ -669,6 +674,8 @@ class ServingDispatcher:
                 leader = False
             g.tickets.append(ticket)
             g.images += n
+            if g.images >= self.max_batch:
+                g.full.set()
             leader_rid = g.tickets[0].request_id
         if obs_journal.enabled():
             # journal the join decision for replay: a follower's outcome
@@ -685,8 +692,13 @@ class ServingDispatcher:
         self._begin_wait(ticket)
         try:
             if self.window > 0:
-                with obs_spans.span("coalesce.window", window_s=self.window):
-                    time.sleep(self.window)
+                with obs_spans.span("coalesce.window",
+                                    window_s=self.window) as sp:
+                    ended_by = "full" if g.full.wait(self.window) \
+                        else "timer"
+                    if sp is not None:
+                        sp.attrs["ended_by"] = ended_by
+                obs_prom.count_coalesce_window(ended_by)
             self._begin_engine_wait(ticket)
             with self._checkout_engine():
                 self._run_grouped_leader(g, key)

@@ -367,7 +367,9 @@ class TestLiveWaitSpans:
             else ["coalesce.window", "engine.wait"])
         assert by["engine.wait"].attrs == {"queued": 0}
         if path == "grouped":
-            assert by["coalesce.window"].attrs == {"window_s": 0.05}
+            # one image under a ladder whose top rung is 4: not full
+            assert by["coalesce.window"].attrs == {"window_s": 0.05,
+                                                   "ended_by": "timer"}
             assert by["coalesce.window"].dur >= 0.05
         # the interval the after-the-fact record had: ticket creation to
         # the start of the device section, which the histogram's sample
@@ -380,6 +382,26 @@ class TestLiveWaitSpans:
             == pytest.approx(end, abs=1e-3)
         assert by["dispatch.device"].parent_id == tr.root_id
         assert by["dispatch.device"].t0 >= end
+
+    def test_coalesce_window_counter_has_both_label_values(self, quiet):
+        """``sdtpu_coalesce_window_total{ended_by}``: one increment a
+        leader, ``full`` under a ladder a request fills alone, ``timer``
+        under one it does not; a solo request counts nowhere."""
+        prometheus.COALESCE_WINDOW_COUNTER.clear()
+        for batches, extra in (([1], {}), ([4], {}), ([4], {}),
+                               ([1], {"enable_hr": True})):
+            FakeExecDispatcher(
+                FakeEngine(), window=0.05,
+                bucketer=ShapeBucketer(shapes=[(32, 32)], batches=batches),
+            ).submit(payload(**extra))
+        assert prometheus.COALESCE_WINDOW_COUNTER.snapshot() == {
+            ("full",): 1.0, ("timer",): 2.0}
+        text = prometheus.render()
+        assert "# TYPE sdtpu_coalesce_window_total counter" in text
+        assert 'sdtpu_coalesce_window_total{ended_by="full"} 1' in text
+        assert 'sdtpu_coalesce_window_total{ended_by="timer"} 2' in text
+        prometheus.clear_histograms()
+        assert prometheus.COALESCE_WINDOW_COUNTER.total() == 0
 
     def test_a_follower_waits_on_its_leader(self, quiet, bucketer):
         engine = FakeEngine()

@@ -14,6 +14,9 @@ import numpy as np
 import pytest
 
 from stable_diffusion_webui_distributed_tpu.models.configs import TINY
+from stable_diffusion_webui_distributed_tpu.obs import (
+    prometheus as obs_prom, spans as obs_spans,
+)
 from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
 from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
     GenerationPayload, b64png_to_array,
@@ -374,6 +377,131 @@ class TestContinuousBatching:
         monkeypatch.setenv("SDTPU_WARMUP", "0")
         report = warmup_engine(None)  # engine untouched when disabled
         assert report["skipped"] is True
+
+
+class TestFullGroupEndsTheWindow:
+    """The leader's wait ends the moment its group holds ``max_batch``
+    images (no joiner fits any more) and lasts the whole window otherwise.
+    Window 0.6 s, so a wait the timer ended cannot pass for one the group
+    ended."""
+
+    WINDOW = 0.6
+    #: case -> batch ladder; the arrivals as (seconds after the one before,
+    #: images); what ends the first leader's window; the requests each
+    #: dispatch carries, in order; the arrival cancelled while the device
+    #: is held against its (full) group, if any; the ladder under which
+    #: the same arrivals share a group that is NOT full, if compared
+    CASES = {
+        "ladder1_one_request": dict(
+            ladder=[1], arrivals=[(0.0, 1)], ended_by="full",
+            dispatches=[1]),
+        "ladder2_follower_fills_it": dict(
+            ladder=[2], arrivals=[(0.0, 1), (0.1, 1)], ended_by="full",
+            dispatches=[2], not_full_ladder=[4]),
+        "ladder4_one_request": dict(
+            ladder=[4], arrivals=[(0.0, 1)], ended_by="timer",
+            dispatches=[1]),
+        "ladder2_two_images_full_on_arrival": dict(
+            ladder=[2], arrivals=[(0.0, 2)], ended_by="full",
+            dispatches=[1]),
+        "ladder2_cancelled_follower_keeps_it_full": dict(
+            ladder=[2], arrivals=[(0.0, 1), (0.1, 1), (0.0, 1)],
+            ended_by="full", dispatches=[2, 1], cancel=1),
+    }
+
+    @staticmethod
+    def _serve(engine, ladder, arrivals, tag, window, cancel=None):
+        """The arrivals through one dispatcher, each from a thread of its
+        own; with ``cancel``, the device is held until every arrival has
+        queued, and that one is cancelled once it has joined its group,
+        before the next arrives."""
+        disp = ServingDispatcher(
+            engine, bucketer=ShapeBucketer(shapes=[(32, 32)],
+                                           batches=ladder), window=window)
+        payloads = [payload(prompt=f"full cow {i}", seed=300 + 10 * i,
+                            batch_size=n, request_id=f"{tag}-{i}")
+                    for i, (_delay, n) in enumerate(arrivals)]
+        results = [None] * len(payloads)
+
+        def run(i):
+            results[i] = disp.submit(payloads[i])
+
+        threads = [threading.Thread(target=run, args=(i,))
+                   for i in range(len(payloads))]
+        if cancel is not None:
+            disp._exec_lock.acquire()
+        try:
+            for i, (delay, _n) in enumerate(arrivals):
+                time.sleep(delay)
+                threads[i].start()
+                if i == cancel:
+                    time.sleep(0.1)     # it has joined its group
+                    assert disp.cancel(payloads[i].request_id)
+        finally:
+            if cancel is not None:
+                disp._exec_lock.release()
+        for t in threads:
+            t.join(timeout=120)
+            assert not t.is_alive()
+        traces = {tr.request_id: tr for tr in obs_spans.TRACER.finished()}
+        spans = [{sp.name: sp for sp in traces[p.request_id].spans}
+                 for p in payloads]
+        return results, spans
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_window_ends_when_the_group_is_full(self, engine, case):
+        spec = self.CASES[case]
+        counted = {k: obs_prom.COALESCE_WINDOW_COUNTER.value(ended_by=k)
+                   for k in ("full", "timer")}
+        results, spans = self._serve(
+            engine, spec["ladder"], spec["arrivals"], case, self.WINDOW,
+            cancel=spec.get("cancel"))
+
+        # one dispatch a leader, each with the requests of its group
+        leaders = [by for by in spans if "dispatch.device" in by]
+        assert [by["dispatch.device"].attrs["requests"]
+                for by in leaders] == spec["dispatches"]
+        # every leader has a window span, however short, and is counted
+        window = spans[0]["coalesce.window"]
+        assert window.attrs == {"window_s": self.WINDOW,
+                                "ended_by": spec["ended_by"]}
+        ends = [by["coalesce.window"].attrs["ended_by"] for by in leaders]
+        for k in counted:
+            assert obs_prom.COALESCE_WINDOW_COUNTER.value(ended_by=k) \
+                == counted[k] + ends.count(k)
+
+        if spec["ended_by"] == "timer":
+            assert window.dur >= self.WINDOW
+        else:
+            # within 0.1 s of the arrival that filled the group: the
+            # leader's own, or its last follower's join
+            filled = max([window.t0] + [by["coalesced.wait"].t0
+                                        for by in spans
+                                        if "coalesced.wait" in by])
+            assert abs(window.t0 + window.dur - filled) < 0.1
+
+        if spec.get("cancel") is not None:
+            # the cancelled follower kept its rows: the group stayed full
+            # and the later arrival led a group of its own
+            dropped = results[spec["cancel"]]
+            assert dropped.images == []
+            assert dropped.parameters.get("cancelled") is True
+            assert "dispatch.device" in spans[-1]
+            assert spans[-1]["coalesce.window"].attrs["ended_by"] == "timer"
+            assert len(results[0].images) == len(results[-1].images) == 1
+
+        if spec.get("not_full_ladder"):
+            # the same requests in a group the timer ends: same answers
+            want, by = self._serve(
+                engine, spec["not_full_ladder"], spec["arrivals"],
+                f"{case}-open", self.WINDOW)
+            assert by[0]["coalesce.window"].attrs["ended_by"] == "timer"
+            assert by[0]["dispatch.device"].attrs["requests"] \
+                == len(spec["arrivals"])
+            for got, ref in zip(results, want):
+                assert got.seeds == ref.seeds
+                assert got.infotexts == ref.infotexts
+                assert got.images == ref.images
 
 
 class TestDecodeDispatch:

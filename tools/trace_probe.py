@@ -18,6 +18,11 @@ chip) and keeps what that run reads and throws away: ``/internal/trace.json``
   ``denoise.inputs`` read ``added_cond`` hit, built or none, and
   ``plan_counts``: ``serving.plan`` of the last ``/internal/status`` (PR
   38: after the warm-up request everything should read hit);
+- ``dispatch_attrs``: over the window's requests, how many
+  ``coalesce.window`` spans read ``ended_by`` full or timer and how many
+  ``dispatch.device`` spans carried 1, 2, ... ``requests``, and
+  ``coalesce_windows``: ``sdtpu_coalesce_window_total`` by ``ended_by``
+  for the whole process (PR 50: a group that is full goes at once);
 - ``xla_setup``: ``serving.xla`` as read after warm-up (totals and the ten
   functions with most seconds), ``xla_window``: what the window added;
 - with ``--trace 1``: ``annotated_missing`` (spans of the traced request
@@ -116,7 +121,7 @@ def span_medians(requests: dict) -> dict:
 def attr_counts(requests: dict, attrs: tuple) -> dict:
     """{"<span>.<attr>": {value: spans}} over the requests, for the attrs
     that say which way a span went (``ladder``, ``added_cond``: hit, built
-    or none; PR 38)."""
+    or none, PR 38; ``ended_by``, ``requests``, PR 50)."""
     out: dict = {}
     for events in requests.values():
         for e in events:
@@ -352,6 +357,14 @@ def main(argv=None) -> int:
         if statuses else None
     print(f"plan: {json.dumps(out['plan_attrs'])} "
           f"{json.dumps(out['plan_counts'])}")
+    from stable_diffusion_webui_distributed_tpu.obs import prometheus
+
+    out["dispatch_attrs"] = attr_counts(window, ("ended_by", "requests"))
+    out["coalesce_windows"] = {
+        key[0]: n for key, n in
+        prometheus.COALESCE_WINDOW_COUNTER.snapshot().items()}
+    print(f"dispatch: {json.dumps(out['dispatch_attrs'])} "
+          f"{json.dumps(out['coalesce_windows'])}")
     if untraced:
         last = max(untraced, key=lambda rid: int(rid[2:]))
         out["tree"] = tree(untraced[last])
