@@ -26,9 +26,16 @@ they are given, so what is kept is never handed out itself.
 The images of one request continue one prompt, each under its own key. Where
 every layer keeps keys and values (``lm.shares_a_step``) they are decoded as
 sequences of one step: the prompt is prefilled once and its cache
-:func:`fork`-ed, every buffer copied once a sequence along a new leading
-axis, full buffers and rings alike. The instruction's rows are copied, not
-shared: every sequence writes its own ring slots from its first step on.
+:func:`fork`-ed. A fork copies nothing. Every buffer, a full layer's and a
+ring alike, stays where the prefill left it, held once, read by every
+sequence and written by none (``k_shared``, ``v_shared``), and gets behind
+it a few rows a sequence (as many as it will decode) for what each makes
+itself (``k``, ``v``: ``(sequences, [passes,] slots, kv heads,
+head_dim)``). The prompt's rows are most of what a step attends, so a step
+reads them once where copies would be read once a sequence; a ring is
+never overwritten because a sequence's new rows go to its own, not to the
+ring. Both kinds go one way because a fork is handed buffers, not a
+config, and a ring cannot be told from a buffer by its length.
 
 A looped model (``LMConfig.total_ut_steps`` over 1) passes a token through
 its whole stack several times over one set of weights, and pass ``t`` of a
@@ -37,8 +44,8 @@ layer's key and value buffers carry a PASS AXIS in front of their slots,
 ``(passes, capacity, kv heads, head_dim)``: a position occupies one row of
 every pass, the model has ``passes x layers`` cache slots a position, and
 everything here that counts or copies takes the axis with it: the shapes
-come from ``lm.cache_shapes``, a snapshot and a fork copy whole buffers
-(a fork puts the sequences in front of the passes), and the positions in
+come from ``lm.cache_shapes``, a snapshot copies whole buffers, a fork's
+own rows have the sequences in front of the passes, and the positions in
 use are a position a pass a layer.
 """
 
@@ -82,29 +89,59 @@ def sequence_bucket(sequences: int) -> int:
                 if b >= min(sequences, SEQUENCE_BUCKETS[-1]))
 
 
-def fork(cache: Dict, sequences: int) -> Dict:
+def own_rows(cache: Dict, sequences: int, slots: int = 0) -> Dict:
+    """What a fork of ``cache`` makes anew: for each of its buffers
+    ``sequences`` times ``slots`` empty rows (0: as many as the buffer
+    has) under the buffer's name, and the position of the fork, not yet
+    known (negative: the first step sets it to where it stands), a row a
+    sequence like the rest. Only the buffers' shapes are read, so traced
+    into an executable of its own it touches no buffer."""
+    own = jax.tree_util.tree_map(
+        lambda x: jnp.zeros((sequences,) + x.shape[:-3]
+                            + (slots or x.shape[-3],) + x.shape[-2:],
+                            x.dtype), cache)
+    own[lm.FORKED_AT] = [jnp.full((sequences, 1), -1, jnp.int32)]
+    return own
+
+
+def forked(cache: Dict, own: Dict) -> Dict:
+    """``cache`` of one sequence and :func:`own_rows` of it as one cache:
+    the shared buffers ARE ``cache``'s."""
+    return {**own, **{shared: cache[name] for name, shared in zip(
+        lm.ATTENTION_BUFFERS, lm.SHARED_BUFFERS)}}
+
+
+def fork(cache: Dict, sequences: int, own_slots: int = 0) -> Dict:
     """``cache`` of one sequence as that of ``sequences`` which all stand
-    where it stands: every buffer ``(sequences, ...)``, each sequence's
-    rows its own copy (traced into one executable by its caller)."""
-    return jax.tree_util.tree_map(
-        lambda x: jnp.broadcast_to(x, (sequences,) + x.shape), cache)
+    where it stands and go on apart for at most ``own_slots`` positions
+    (0: as many as a buffer has slots). Nothing is copied: the module's
+    text says where everything lies."""
+    return forked(cache, own_rows(cache, sequences, own_slots))
 
 
-def state_bytes(config, capacity: int, dtype,
-                sequences: int = 1) -> Dict[str, int]:
+def state_bytes(config, capacity: int, dtype, sequences: int = 1,
+                own_slots: int = 0) -> Dict[str, int]:
     """Bytes the caches of ``sequences`` sequences take at ``capacity``,
     by layer kind, from the shapes: keys, values and latents in ``dtype``,
     a linear layer's state and a linear or conv layer's kept inputs in
-    float32.
+    float32. Several sequences are a :func:`fork` of one: every buffer
+    once and ``own_slots`` rows of it a sequence.
     Full and sliding are always named; linear, latent and conv where the
     model has such layers."""
     shapes = {name: iter(rows)
               for name, rows in lm.cache_shapes(config, capacity).items()}
+
+    def held(shape) -> int:
+        """A buffer's elements, and its sequences' own rows behind it."""
+        if sequences == 1:
+            return math.prod(shape)
+        return math.prod(shape) // shape[-3] * (
+            shape[-3] + sequences * own_slots)
+
     out = {lm.FULL: 0, lm.SLIDING: 0}
     for kind in config.layer_types:
-        out[kind] = out.get(kind, 0) + sequences * sum(
-            math.prod(next(shapes[name]))
-            * lm.buffer_dtype(name, dtype).itemsize
+        out[kind] = out.get(kind, 0) + sum(
+            held(next(shapes[name])) * lm.buffer_dtype(name, dtype).itemsize
             for name in lm.buffers_of(kind))
     return out
 
@@ -152,18 +189,21 @@ class KVCacheManager:
         with self._lock:
             return len(self._prefixes)
 
-    def positions_in_use(self, length: int,
-                         sequences: int = 1) -> Dict[str, int]:
+    def positions_in_use(self, length: int, sequences: int = 1,
+                         forked_at: int = 0) -> Dict[str, int]:
         """Cache positions ``sequences`` sequences of ``length`` occupy,
         by layer kind, summed over the layers of the kind; a linear or a
         conv layer uses none at any length, a latent layer one a
-        position, a full layer of a looped model one a pass."""
+        position, a full layer of a looped model one a pass. Sequences
+        forked at ``forked_at`` hold the positions before it once and the
+        rest once each (a sliding layer at most its window of either)."""
         cfg = self.config
+        own, window = length - forked_at, cfg.sliding_window
         out = {
-            lm.FULL: len(cfg.layers_of(lm.FULL)) * length * sequences
-            * cfg.total_ut_steps,
+            lm.FULL: len(cfg.layers_of(lm.FULL)) * cfg.total_ut_steps
+            * (forked_at + sequences * own),
             lm.SLIDING: len(cfg.layers_of(lm.SLIDING))
-            * min(length, cfg.sliding_window) * sequences,
+            * (min(forked_at, window) + sequences * min(own, window)),
         }
         for kind in (lm.LINEAR, lm.CONV):
             if kind in cfg.layer_types:

@@ -46,9 +46,16 @@ last REAL rows.
 A prefill is one sequence: a prompt. A decode step may run several
 (``sequences``: the images of one request, forked from one prefill): row
 ``b`` of the chunk is then sequence ``b``'s ONE token, every sequence at the
-same position, and the buffers carry a leading sequence axis. Norms,
-projections, the router, the experts and the head take the rows as they
-take a chunk's; attention alone tells the sequences apart. That holds for
+same position, and the cache is a FORKED one (cache/kv.py:fork): every
+attention layer's ``k_shared`` and ``v_shared`` are the prefill's own
+buffers, which all sequences read and none writes, and its ``k`` and ``v``
+each sequence's own rows behind them, ``(sequences, [passes,] slots, kv
+heads, head_dim)``, the token a sequence makes at position ``p`` in slot
+``(p - forked_at) % slots``, in a full layer and a sliding one alike (a full
+layer wants a slot for every position a sequence will decode, a sliding one
+no more than its window). Norms, projections, the router, the experts and
+the head take the rows as they take a chunk's; attention alone tells the
+sequences apart, and reads what they share once. That holds for
 the ``full`` and ``sliding`` kinds (:func:`shares_a_step`); a model with a
 recurrent state, kept rows, latents or several residual streams decodes one
 sequence a step.
@@ -61,8 +68,9 @@ share one trace of a layer, the final norm closes every pass, and a
 learned gate says after which pass the head reads. Pass ``t`` of a layer
 attends the keys and values that pass ``t`` wrote for the earlier
 positions, so a layer's key and value buffers carry a PASS AXIS in front
-of their slots: ``(passes, capacity, kv heads, head_dim)``, with the
-sequence axis in front of that under ``sequences`` (:func:`cache_shapes`):
+of their slots: ``(passes, capacity, kv heads, head_dim)``, and a
+sequence's own rows ``(sequences, passes, slots, ...)`` under ``sequences``
+(:func:`cache_shapes`):
 the model has ``passes x layers`` cache slots a position. Every pass of
 every chunk always runs (a later token attends all of them); the gate's
 rule only picks the state that is read. With ``post_sublayer_norm`` a
@@ -87,7 +95,7 @@ from stable_diffusion_webui_distributed_tpu.ops import (
     delta_rule, moe, stream_mixer,
 )
 from stable_diffusion_webui_distributed_tpu.ops.attention import (
-    attend_positions,
+    attend_positions, attend_two_ranges,
 )
 from stable_diffusion_webui_distributed_tpu.ops.quant import int8_dot
 from stable_diffusion_webui_distributed_tpu.serving.metrics import (
@@ -107,9 +115,17 @@ CONV_STEP, CONV_CHUNK = "step", "chunk"
 LATENT_ABSORBED, LATENT_EXPANDED = "latent_absorbed", "latent_expanded"
 
 
-def buffers_of(kind: str) -> Tuple[str, ...]:
+#: under a fork (cache/kv.py:fork) an attention layer has two more: what
+#: the prefill left, which every sequence reads and none writes
+SHARED_BUFFERS = ("k_shared", "v_shared")
+#: and the cache one entry that is no layer's: the position of the fork
+FORKED_AT = "forked_at"
+
+
+def buffers_of(kind: str, forked: bool = False) -> Tuple[str, ...]:
     return {LINEAR: LINEAR_BUFFERS, LATENT: LATENT_BUFFERS,
-            CONV: CONV_BUFFERS}.get(kind, ATTENTION_BUFFERS)
+            CONV: CONV_BUFFERS}.get(
+                kind, ATTENTION_BUFFERS + (SHARED_BUFFERS if forked else ()))
 
 
 def shares_a_step(cfg: LMConfig) -> bool:
@@ -299,10 +315,14 @@ class Attention(nn.Module):
 
     @nn.compact
     def __call__(self, n, q_pos, start, end, k_cache, v_cache,
-                 sequences: bool = False, pass_index=None):
+                 k_shared=None, v_shared=None, sequences: bool = False,
+                 pass_index=None, forked_at=None):
         """``pass_index``: which pass of a looped stack this is; the
         buffers then carry the pass axis just before their slots' and the
-        chunk writes and attends that pass's rows alone."""
+        chunk writes and attends that pass's rows alone. ``sequences``:
+        the buffers are a forked cache's, ``k_cache`` and ``v_cache`` each
+        sequence's own rows from position ``forked_at`` on; the shared
+        ones are returned behind them as they came."""
         cfg = self.config
         kind = cfg.layer_types[self.layer]
         heads = cfg.num_heads_per_layer[self.layer]
@@ -325,12 +345,15 @@ class Attention(nn.Module):
                 cache, jnp.expand_dims(rows, axis), index)
 
         def of_pass(cache, axis):
-            """The rows this chunk attends: the pass's own. (XLA copies
-            them out of the buffer before the products read them, 8.5 ms
-            of a 32 ms decode step at the published widths; a branch a
-            pass over a buffer a (layer, pass) slot reads in place and
+            """The rows this chunk attends: the pass's own. (On the chip
+            the slice is the asynchronous READ of those rows into on-chip
+            memory, ``slice-done`` in a trace: the stream the products
+            need, not a copy made before them. A step of four sequences
+            over their own 512-slot copies moved 23.15 GB in 31.6 ms, 89 %
+            of the HBM's peak with the rows read once: PERF.md section 6,
+            PR 51. A branch a pass over a buffer a (layer, pass) slot
             costs 58 ms a step in the copies XLA makes for the branches:
-            PERF.md section 6, PR 49.)"""
+            PR 49.)"""
             if pass_index is None:
                 return cache
             return jax.lax.dynamic_index_in_dim(cache, pass_index, axis,
@@ -356,30 +379,29 @@ class Attention(nn.Module):
         v = lin(kv * dim, "v_proj")(n).reshape(tokens, kv, dim).astype(store)
         real = q_pos < end
         if sequences:
-            # row b is sequence b's one token at ``start``, the buffers
-            # ``(B, slots, kv, dim)``. Written first in both kinds: the
-            # ring slot the token takes held position ``start - window``,
-            # which its own query no longer sees
-            window = 0 if kind == FULL else k_cache.shape[1]
-            slots = jnp.arange(k_cache.shape[-3])
-            into = start % window if window else start
-            k_cache = put(k_cache, k[:, None], into, 1)
-            v_cache = put(v_cache, v[:, None], into, 1)
-            # a slot's newest position at or before ``start``; negative:
-            # never written
-            k_pos = (start - (start - slots) % window if window
-                     else jnp.where(slots < end, slots, -1))
-            paths = set()
-
-            def one(q, keys, values):
-                out, path = attend_positions(
-                    q[None], keys, values, q_pos[:1], k_pos,
-                    scale=dim ** -0.5, window=window)
-                paths.add(path)
-                return out[0]
-
-            out = jax.vmap(one)(q, of_pass(k_cache, 1), of_pass(v_cache, 1))
-            ATTENTION.record(paths.pop(), 1, k_cache.shape[-3], dim, passes)
+            # row b is sequence b's one token at ``start``: it goes to its
+            # sequence's own rows, what the prefill left is only read, and
+            # both are the keys of one softmax
+            shared, own = k_shared.shape[-3], k_cache.shape[-3]
+            window = 0 if kind == FULL else shared
+            behind = start - forked_at
+            k_cache = put(k_cache, k[:, None], behind % own, 1)
+            v_cache = put(v_cache, v[:, None], behind % own, 1)
+            slots = jnp.arange(shared)
+            # the position a shared slot held at the fork, a ring's the
+            # newest that fell on it; negative: none (never written, or a
+            # padded row of the prompt's chunk)
+            shared_pos = (
+                forked_at - 1 - (forked_at - 1 - slots) % window if window
+                else jnp.where(slots < forked_at, slots, -1))
+            # own slot j holds the newest position that fell on it
+            own_pos = start - (behind - jnp.arange(own)) % own
+            out, path = attend_two_ranges(
+                q, of_pass(k_shared, 0), of_pass(v_shared, 0),
+                of_pass(k_cache, 1), of_pass(v_cache, 1), q_pos, shared_pos,
+                jnp.where(own_pos >= forked_at, own_pos, -1),
+                scale=dim ** -0.5, window=window)
+            ATTENTION.record(path, 1, shared + own, dim, passes)
         elif kind == FULL:
             # written first: a padded row lands beyond ``end``, where no
             # query looks until a real token has overwritten it
@@ -412,7 +434,7 @@ class Attention(nn.Module):
             gate = jax.nn.sigmoid(lin(heads, "g_proj")(n))
             out = out.astype(jnp.float32) * gate[:, :, None]
         return (lin(n.shape[-1], "o_proj")(out.reshape(tokens, heads * dim)),
-                k_cache, v_cache)
+                k_cache, v_cache) + ((k_shared, v_shared) if sequences else ())
 
 
 class LatentUp(nn.Module):
@@ -774,14 +796,16 @@ class DecoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x, q_pos, start, end, buffers,
-                 sequences: bool = False, real=None, pass_index=None):
+                 sequences: bool = False, real=None, pass_index=None,
+                 forked_at=None):
         """``buffers`` are the layer's own of the cache (:func:`buffers_of`
         its kind), returned as the chunk leaves them. ``x`` is ``(T,
         hidden)``, or ``(T, streams, hidden)`` with several streams.
         ``sequences``: the rows are one token each of as many sequences
-        (:func:`shares_a_step`), the buffers theirs side by side, and
-        ``real`` says which rows count. ``pass_index``: the pass of a
-        looped stack, whose rows of the buffers the layer then takes."""
+        (:func:`shares_a_step`), the buffers a forked cache's, forked at
+        position ``forked_at``, and ``real`` says which rows count.
+        ``pass_index``: the pass of a looped stack, whose rows of the
+        buffers the layer then takes."""
         cfg = self.config
         kind = cfg.layer_types[self.layer]
 
@@ -810,7 +834,8 @@ class DecoderLayer(nn.Module):
                 mixed, *after = Attention(
                     cfg, self.layer, self.dtype, self.quant, name="attn")(
                         n, q_pos, start, end, *buffers,
-                        sequences=sequences, pass_index=pass_index)
+                        sequences=sequences, pass_index=pass_index,
+                        forked_at=forked_at)
             return mixed, tuple(after)
 
         def mlp(n):
@@ -859,11 +884,11 @@ class DecoderLM(nn.Module):
     the held slice, for every row of the chunk or (``all_logits`` False)
     for the last real one alone. With ``sequences`` the ``(B,)`` tokens are
     one each of ``B`` sequences, all at position ``start``, of which the
-    first ``length`` are real (the others pad ``B``); every buffer of
-    ``cache`` has a leading ``B`` and the logits are every sequence's. ``routed`` has, stacked over the expert
-    layers, the experts every token chose ``(layers, T, k)``, the tokens
-    sent to each held expert ``(layers, held)`` and the tokens none of
-    whose experts is held ``(layers,)``."""
+    first ``length`` are real (the others pad ``B``); ``cache`` is a forked
+    one (cache/kv.py:fork) and the logits are every sequence's. ``routed``
+    has, stacked over the expert layers, the experts every token chose
+    ``(layers, T, k)``, the tokens sent to each held expert ``(layers,
+    held)`` and the tokens none of whose experts is held ``(layers,)``."""
 
     config: LMConfig
     dtype: jnp.dtype = jnp.float32
@@ -890,10 +915,15 @@ class DecoderLM(nn.Module):
             q_pos = jnp.full(tokens.shape, start, jnp.int32)
             end, all_logits = start + 1, True
             real = jnp.arange(tokens.shape[0]) < length
+            # a fork does not know where it stands: its first step does
+            cache = dict(cache)
+            (stamp,) = cache.pop(FORKED_AT)
+            forked_at = jnp.where(stamp[0, 0] < 0, start, stamp[0, 0])
         else:
             q_pos = start + jnp.arange(tokens.shape[0], dtype=jnp.int32)
             end = start + length
             real = None     # a layer's own: the rows before ``end``
+            forked_at = None
         # the table is sharded over the vocabulary: an id another chip
         # holds gets nothing here (their parts are summed in a deployment)
         first, count = cfg.vocab
@@ -917,7 +947,7 @@ class DecoderLM(nn.Module):
             """Layer ``layer`` as this module's own submodule."""
             return layer_module(layer, name=f"layers_{layer}")(
                 x, q_pos, start, end, buffers, sequences=sequences,
-                real=real, pass_index=pass_index)
+                real=real, pass_index=pass_index, forked_at=forked_at)
 
         def stack(apply_layer, x, cache, pass_index=None):
             """(x, the cache, what the expert layers routed) after every
@@ -926,7 +956,7 @@ class DecoderLM(nn.Module):
             written = {name: [] for name in cache}
             routed = []
             for layer, kind in enumerate(cfg.layer_types):
-                names = buffers_of(kind)
+                names = buffers_of(kind, sequences)
                 x, buffers, r = apply_layer(
                     layer, x,
                     tuple(cache[name][len(written[name])] for name in names),
@@ -963,7 +993,7 @@ class DecoderLM(nn.Module):
             def looped_layer(p, x, buffers, pass_index, *where):
                 return one.apply({"params": p}, x, *where, buffers,
                                  sequences=sequences, real=real,
-                                 pass_index=pass_index)
+                                 pass_index=pass_index, forked_at=forked_at)
 
             def shared_layer(layer, x, buffers, pass_index):
                 return looped_layer(params[f"layers_{layer}"], x, buffers,
@@ -982,6 +1012,8 @@ class DecoderLM(nn.Module):
                                          jnp.arange(cfg.total_ut_steps))
             routed = []
             n = self.read_pass(h, None if all_logits else length)
+        if sequences:
+            cache[FORKED_AT] = [jnp.full_like(stamp, forked_at)]
         logits = Linear(cfg.vocab[1], self.dtype, self.quant_linears,
                         name="lm_head")(n)
         if not routed:     # no expert layer: the three parts, empty
@@ -1191,8 +1223,8 @@ def decode_sequences_fn(module: DecoderLM, steps: int):
     ``expand_decode_chunk(params, cache, tokens, position, keys,
     temperature, live) -> (cache, tokens, position, the steps' tokens
     (steps, B), routed load, none held, experts read)``. ``tokens`` and
-    ``keys`` are ``(B,)``, every buffer of ``cache`` ``(B, ...)``, and all
-    sequences sit at the one ``position``. Only the first ``live`` count:
+    ``keys`` are ``(B,)``, ``cache`` a forked one (cache/kv.py:fork), and
+    all sequences sit at the one ``position``. Only the first ``live`` count:
     the others pad ``B`` up to a size an executable exists for, repeat a
     live one (so they choose no expert of their own) and are left out of
     the load. ``experts read`` ``(expert

@@ -4,9 +4,14 @@ One place decides, from what the call itself shows: the platform, whether
 it is self-attention, the shapes and the dtype, whether it is masked and
 whether query heads share KV heads. No environment variable and no option:
 a shape where the kernel has not measured faster stays on XLA
-(``jax.nn.dot_product_attention`` for a UNet's sites,
-:func:`attend_positions` for a decoder LM's causal, windowed, grouped-query
-sites over a cache, which the kernel cannot take at all).
+(``jax.nn.dot_product_attention`` for a UNet's sites; a decoder LM's
+causal, windowed, grouped-query sites over a cache, which the kernel cannot
+take at all, go to :func:`attend_positions`, and to :func:`attend_two_ranges`
+where the keys are two buffers: a decode step of several sequences forked
+from one prefill, models/lm.py ``Attention`` with ``sequences``, whose
+queries all attend the prefill's rows, read once for all of them, and each
+its own rows behind them. A prefill's chunk, a one-sequence decode step and
+a latent layer take :func:`attend_positions`).
 
 The readings that set :data:`TILED_MIN_TOKENS` (one v5e, bf16, CFG batch 2,
 each alone in a device-side loop of 20 calls, median of 5; my chip runs,
@@ -85,6 +90,25 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, *, scale: float,
     return jax.nn.dot_product_attention(q, k, v, scale=scale), path
 
 
+def _weights(scores: jax.Array, seen: jax.Array) -> jax.Array:
+    """The softmax of float32 ``scores`` over the keys ``seen`` (last
+    axis); a query that sees none gets zeros."""
+    scores = jnp.where(seen, scores, -jnp.inf)
+    top = jnp.max(scores, axis=-1, keepdims=True)
+    weights = jnp.exp(scores - jnp.where(jnp.isfinite(top), top, 0.0))
+    total = jnp.sum(weights, axis=-1, keepdims=True)
+    return weights / jnp.where(total > 0, total, 1.0)
+
+
+def _seen(q_pos: jax.Array, k_pos: jax.Array, window: int) -> jax.Array:
+    """``(T, S)``: whether query ``i`` sees key ``j``."""
+    delta = q_pos[:, None] - k_pos[None, :]
+    seen = (delta >= 0) & (k_pos[None, :] >= 0)
+    if window:
+        seen &= delta < window
+    return seen
+
+
 def attend_positions(q: jax.Array, k: jax.Array, v: jax.Array,
                      q_pos: jax.Array, k_pos: jax.Array, *, scale: float,
                      window: int = 0):
@@ -106,17 +130,49 @@ def attend_positions(q: jax.Array, k: jax.Array, v: jax.Array,
     groups = heads // kv
     path = choose(jax.default_backend(), t, s, q.dtype, self_attention=True,
                   masked=True, kv_groups=groups)
-    delta = q_pos[:, None] - k_pos[None, :]
-    seen = (delta >= 0) & (k_pos[None, :] >= 0)
-    if window:
-        seen &= delta < window
+    seen = _seen(q_pos, k_pos, window)
     scores = jnp.einsum("tkgd,skd->kgts", q.reshape(t, kv, groups, dim), k,
                         preferred_element_type=jnp.float32) * scale
-    scores = jnp.where(seen[None, None], scores, -jnp.inf)
-    top = jnp.max(scores, axis=-1, keepdims=True)
-    weights = jnp.exp(scores - jnp.where(jnp.isfinite(top), top, 0.0))
-    total = jnp.sum(weights, axis=-1, keepdims=True)
-    weights = weights / jnp.where(total > 0, total, 1.0)
+    weights = _weights(scores, seen[None, None])
     out = jnp.einsum("kgts,skd->tkgd", weights.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
     return out.reshape(t, heads, v.shape[-1]).astype(q.dtype), path
+
+
+def attend_two_ranges(q: jax.Array, k_shared: jax.Array, v_shared: jax.Array,
+                      k_own: jax.Array, v_own: jax.Array, q_pos: jax.Array,
+                      shared_pos: jax.Array, own_pos: jax.Array, *,
+                      scale: float, window: int = 0):
+    """:func:`attend_positions` for ``B`` sequences of one query each whose
+    keys are two ranges under ONE softmax: ``k_shared`` ``(S, KV, D)`` and
+    ``v_shared``, which every sequence attends, and ``k_own`` ``(B, T, KV,
+    D)`` and ``v_own``, sequence ``b``'s own rows. ``q`` is ``(B, H, D)``,
+    ``q_pos`` ``(B,)``, ``shared_pos`` ``(S,)`` and ``own_pos`` ``(T,)``
+    (slot ``j`` of every sequence's own rows holds the same position).
+    Sequence ``b`` gets what ``attend_positions`` gives its query over the
+    shared keys followed by its own.
+
+    The sequences are the shared product's query rows: a KV head's shared
+    keys are read once for all ``B`` of them, not once a sequence. The same
+    masking rules, float32 scores and softmax, output in ``q``'s dtype."""
+    b, heads, dim = q.shape
+    s, kv, _ = k_shared.shape
+    groups = heads // kv
+    path = choose(jax.default_backend(), 1, s + k_own.shape[1], q.dtype,
+                  self_attention=True, masked=True, kv_groups=groups)
+    q = q.reshape(b, kv, groups, dim)
+    scores = jnp.concatenate(
+        [jnp.einsum("bkgd,skd->kgbs", q, k_shared,
+                    preferred_element_type=jnp.float32),
+         jnp.einsum("bkgd,bskd->kgbs", q, k_own,
+                    preferred_element_type=jnp.float32)], axis=-1) * scale
+    seen = jnp.concatenate([_seen(q_pos, shared_pos, window),
+                            _seen(q_pos, own_pos, window)], axis=-1)
+    to_shared, to_own = jnp.split(
+        _weights(scores, seen[None, None]).astype(v_shared.dtype), [s],
+        axis=-1)
+    out = jnp.einsum("kgbs,skd->bkgd", to_shared, v_shared,
+                     preferred_element_type=jnp.float32) \
+        + jnp.einsum("kgbs,bskd->bkgd", to_own, v_own,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, heads, v_shared.shape[-1]).astype(q.dtype), path

@@ -29,8 +29,10 @@ key alone, so where the model's kinds allow (models/lm.py:shares_a_step)
 :meth:`PromptExpander.expand_batch` decodes them as sequences of ONE step:
 the prompt is prefilled once at one sequence, each image's first token is
 drawn from that one row of logits under its own key, the cache is forked
-(cache/kv.py:fork) and the scan runs over all of them, so a step streams
-the fixed weights once and each distinct expert once. Their count is
+(cache/kv.py:fork: the prompt's rows stay where the prefill left them, held
+once, and each sequence gets rows of its own for what it decodes) and the
+scan runs over all of them, so a step streams the fixed weights once, each
+distinct expert once and the prompt's keys and values once. Their count is
 padded up to one of cache/kv.py:SEQUENCE_BUCKETS with repeats of the last,
 whose tokens are dropped. One image takes the one-sequence executables
 under the keys they have always had.
@@ -71,6 +73,17 @@ DECODE_STEPS = 32
 _KEY_DOMAIN = 0x6C6D
 
 
+def rows_of(sequences: int, forked_at: int, steps: int) -> dict:
+    """``rows_attended`` and ``rows_read`` of ``EXPANDER.record``: the
+    positions ``steps`` decode steps' queries attended in a layer that
+    keeps every position, step ``i``'s at ``forked_at + i`` once a
+    sequence, and the positions read for them, where what lies before the
+    fork is read once a step for all sequences."""
+    attended = sequences * (steps * forked_at + steps * (steps + 1) // 2)
+    return {"rows_attended": attended,
+            "rows_read": attended - (sequences - 1) * steps * forked_at}
+
+
 class PromptExpander:
     """The stage, for one engine whose family has an ``expander`` and whose
     ``params`` hold its weights under ``"expander"``."""
@@ -99,11 +112,13 @@ class PromptExpander:
             lambda: jax.jit(lm.prefill_fn(self.module, sequences=many),
                             donate_argnums=(1,)))
 
-    def _fork_fn(self, capacity: int, sequences: int):
+    def _fork_fn(self, capacity: int, sequences: int, own_slots: int):
+        """What a fork makes anew (cache/kv.py:own_rows): the cache it is
+        called with gives the shapes and is not read."""
         return self.engine._cached(
-            ("expand_fork", capacity, sequences),
-            lambda: jax.jit(functools.partial(kv.fork,
-                                              sequences=sequences)))
+            ("expand_fork", capacity, sequences, own_slots),
+            lambda: jax.jit(functools.partial(
+                kv.own_rows, sequences=sequences, slots=own_slots)))
 
     def _decode_fn(self, capacity: int, sequences: int = 1):
         """One image keeps the key and the function it has always had."""
@@ -176,9 +191,12 @@ class PromptExpander:
                         jnp.asarray(padded_to, jnp.uint32))
                 first_key = key[0]
             temperature = jnp.float32(args.temperature)
+            # a sequence's own rows behind a fork: a slot a decode step
+            own_slots = chunks * DECODE_STEPS
             sizes = kv.state_bytes(self.config, capacity, self.cache.dtype,
-                                   batch)
-            copied = sum(sizes.values()) // batch   # one sequence's
+                                   batch, own_slots)
+            copied = sum(kv.state_bytes(     # one sequence's: a snapshot
+                self.config, capacity, self.cache.dtype).values())
         with obs_spans.span("expand.prefix_copy", bytes=copied) as sp:
             cache, held = self.cache.acquire(prefix, capacity)
             if sp is not None:
@@ -226,14 +244,18 @@ class PromptExpander:
             if not keep:    # the instruction's chunk yields no token
                 exits += chose
             masked += attrs.get("padded", 0)
+        forked_at = len(prefix) + len(user)
         if batch > 1:
+            # the bytes made: the prompt's rows stay where they are
             with obs_spans.span("expand.fork", sequences=batch,
-                                bytes=sum(sizes.values()), **looped):
-                cache = self._fork_fn(capacity, batch)(cache)
+                                bytes=sum(sizes.values()) - copied,
+                                **looped):
+                cache = kv.forked(
+                    cache, self._fork_fn(capacity, batch, own_slots)(cache))
                 jax.block_until_ready(cache)    # fenced, as a prefill is
         # (live, tokens so far): the first of each from the prompt's row
         made = np.asarray(token).reshape(-1, 1)[:live].tolist()
-        position = jnp.int32(len(prefix) + len(user))
+        position = jnp.int32(forked_at)
         decode = self._decode_fn(capacity, batch)
         more = () if batch == 1 else (jnp.int32(live),)
         pending = []      # at most one chunk whose tokens are not fetched
@@ -276,7 +298,7 @@ class PromptExpander:
             if not args.ignore_eos:     # each sequence is cut at its own
                 made = [one[:one.index(tok.eos)] if tok.eos in one else one
                         for one in made]
-            length = len(prefix) + len(user) + max(map(len, made))
+            length = forked_at + max(map(len, made))
             loads, none_held = zip(*jax.device_get(routed))
             # a step of one token reads as many experts as it has picks
             # held; a step of several the distinct ones, counted beside
@@ -289,7 +311,9 @@ class PromptExpander:
                 decoded=sum(map(len, made)), decode_steps=steps,
                 experts_read=int(read),
                 load=np.sum(loads, axis=0), none_held=int(np.sum(none_held)),
-                positions=self.cache.positions_in_use(length, live),
+                **rows_of(live, forked_at, steps),
+                positions=self.cache.positions_in_use(
+                    length, live, forked_at if batch > 1 else 0),
                 state_bytes=sizes, prefix_snapshots=self.cache.snapshots,
                 padded_rows_masked=masked,
                 residual_streams=self.config.residual_streams,

@@ -451,7 +451,13 @@ class ExpanderStats:
     over all sequences and the steps that made them apart (their quotient
     is the tokens a step), the distinct held experts whose kernels the
     decode steps streamed (summed over layers and steps: a step of several
-    sequences reads an expert once however many of them chose it), how many
+    sequences reads an expert once however many of them chose it), the
+    positions the decode steps' queries attended in a layer that keeps every
+    position (``rows_attended``: a step at position ``p`` counts ``p + 1`` a
+    sequence) and the positions read for them (``rows_read``: what lies
+    before a fork is read once a step for all its sequences, so their
+    quotient is the queries a row read serves; one sequence counts the same
+    in both), how many
     tokens the router
     sent to each expert held here (load and its imbalance), tokens none of
     whose chosen experts is held here, the cache positions the last
@@ -494,6 +500,8 @@ class ExpanderStats:
             self.decoded = 0           # guarded-by: _lock
             self.decode_steps = 0      # guarded-by: _lock
             self.experts_read = 0      # guarded-by: _lock
+            self.rows_attended = 0     # guarded-by: _lock
+            self.rows_read = 0         # guarded-by: _lock
             self.none_held = 0         # guarded-by: _lock
             #: per expert layer, tokens sent to each held expert
             self.load: List[List[int]] = []  # guarded-by: _lock
@@ -529,6 +537,7 @@ class ExpanderStats:
                positions: Dict[str, int], state_bytes: Dict[str, int],
                prefix_snapshots: int, padded_rows_masked: int,
                residual_streams: int, sinkhorn_iters: int,
+               rows_attended: int = 0, rows_read: int = 0,
                layer_passes: int = 0, exit_pass=(),
                exit_lambda_max: float = 0.0) -> None:
         """``load`` is (expert layers, held experts) counts of one
@@ -544,6 +553,8 @@ class ExpanderStats:
             self.decoded += int(decoded)
             self.decode_steps += int(decode_steps)
             self.experts_read += int(experts_read)
+            self.rows_attended += int(rows_attended)
+            self.rows_read += int(rows_read)
             self.none_held += int(none_held)
             if len(self.load) != len(rows):
                 self.load = rows
@@ -576,6 +587,8 @@ class ExpanderStats:
                 "tokens_decoded": self.decoded,
                 "decode_steps": self.decode_steps,
                 "experts_read": self.experts_read,
+                "rows_attended": self.rows_attended,
+                "rows_read": self.rows_read,
                 "tokens_no_held_expert": self.none_held,
                 "expert_tokens": [list(row) for row in self.load],
                 "expert_load_max_over_mean":

@@ -231,6 +231,55 @@ def test_stream_mixer_kernels_compile_for_v5e(one_chip, stored):
     assert text.count("tpu_custom_call") == 2
 
 
+def _forked_on(chip, cache, sequences, own_slots):
+    """``cache``'s shapes forked (cache/kv.py:fork), placed on ``chip``."""
+    from stable_diffusion_webui_distributed_tpu.cache import kv
+
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        jax.eval_shape(lambda c: kv.fork(c, sequences, own_slots), cache))
+
+
+@pytest.mark.parametrize("preset", ["TINY_LOOP_EXPAND",
+                                    "TINY_WINDOW_EXPAND"])
+def test_a_forked_decode_chunk_compiles_for_v5e(one_chip, preset):
+    """The decode chunk of four sequences forked from one prefill, at the
+    tiny looped preset (a pass axis in the shared buffers and in a
+    sequence's own rows) and at the tiny window preset (rings and a buffer
+    shared alike): the chip's compiler takes the two ranges under one
+    softmax, and the donated cache, shared buffers included, goes through
+    to the result without a copy of its own."""
+    from stable_diffusion_webui_distributed_tpu.models import configs, lm
+
+    cfg = getattr(configs, preset).expander
+    module = lm.DecoderLM(cfg, dtype=jnp.bfloat16)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    one = {name: [jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+                  for shape in rows]
+           for name, rows in lm.cache_shapes(cfg, 256).items()}
+    cache = _forked_on(one_chip, one, 4, 64)
+    scalar = on_chip((), jnp.int32)
+    shapes = jax.eval_shape(
+        lambda *a: module.init(jax.random.key(0), *a),
+        jax.ShapeDtypeStruct((4,), jnp.int32), scalar, scalar,
+        jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, jnp.float32),
+            one))["params"]
+    params = jax.tree_util.tree_map(
+        lambda x: on_chip(x.shape, jnp.bfloat16), shapes)
+    compiled = jax.jit(lm.decode_sequences_fn(module, 32),
+                       donate_argnums=(1,)).lower(
+        params, cache, on_chip((4,), jnp.int32), scalar,
+        on_chip((4,), jax.random.key(0).dtype), on_chip((), jnp.float32),
+        scalar).compile()
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(cache))
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
+
+
 @pytest.mark.parametrize(
     "which,expander,argument_gb,kernels,temp_mb,alias_mb", [
     ("decode", "sd15_laguna_expander", 11.1, 4, 64, 14),
@@ -247,10 +296,11 @@ def test_stream_mixer_kernels_compile_for_v5e(one_chip, stored):
     ("prefill", "sd15_lfm2_expander", 10.8, 0, 64, 4),
     # at a 2 560-slot cache (a 2 048-token instruction): eight expert
     # kernels at one sequence and at four sequences a step (the whole
-    # block of rows an expert), which donate four forked caches, 92 MB;
+    # block of rows an expert), which donate a forked cache: the one
+    # sequence's 23 MB and 256 slots a layer for each of four, 40 MB;
     # the instruction's one chunk, twice the window, needs 0.9 GB of scores
     ("decode", "sd15_mellum2_expander", 7.6, 8, 64, 23),
-    ("decode4", "sd15_mellum2_expander", 7.6, 8, 64, 92),
+    ("decode4", "sd15_mellum2_expander", 7.6, 8, 64, 39),
     ("prefill2048", "sd15_mellum2_expander", 7.6, 0, 1000, 23),
 ])
 def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
@@ -273,15 +323,15 @@ def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
     cfg = getattr(configs, expander)().expander
     module = lm.DecoderLM(cfg, dtype=jnp.bfloat16)
     capacity = 2560 if expander == "sd15_mellum2_expander" else 1024
-    lead = (4,) if which == "decode4" else ()       # forked sequences
 
     def on_chip(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    cache = {name: [on_chip(lead + shape,
-                            lm.buffer_dtype(name, jnp.bfloat16))
+    cache = {name: [on_chip(shape, lm.buffer_dtype(name, jnp.bfloat16))
                     for shape in rows]
              for name, rows in lm.cache_shapes(cfg, capacity).items()}
+    if which == "decode4":      # four sequences forked, 256 slots each
+        cache = _forked_on(one_chip, cache, 4, 256)
     small = {name: [jax.ShapeDtypeStruct(shape, jnp.float32)
                     for shape in rows]
              for name, rows in lm.cache_shapes(cfg, 8).items()}

@@ -13,6 +13,7 @@ length of 16, 8 experts top-2). The plain reference is the benchmark's own
 ring, the window a mask on the whole score matrix).
 """
 
+import functools
 import importlib.util
 import os
 import zlib
@@ -195,7 +196,118 @@ def _keys(indices, seed=77):
     return jnp.stack([rng.key_for_image(seed, i) for i in indices])
 
 
+def forked_shapes(cfg, capacity, sequences, own_slots,
+                  dtype=jnp.bfloat16):
+    """The shapes of a forked cache, as ``kv.fork`` lays it out."""
+    return jax.eval_shape(
+        lambda c: kv.fork(c, sequences, own_slots),
+        {name: [jax.ShapeDtypeStruct(shape, dtype) for shape in rows]
+         for name, rows in lm.cache_shapes(cfg, capacity).items()})
+
+
+def assert_own_rows(cfg, alone, forked, b, first, steps):
+    """Sequence ``b``'s own rows of a forked cache against the cache of
+    that sequence decoded alone for ``steps`` positions from ``first``:
+    position ``p`` lies in its own slot ``(p - first) % slots``, and alone
+    in slot ``p`` of a buffer or ``p % window`` of a ring (which keeps the
+    last ``window`` only)."""
+    for name in ("k", "v"):
+        for kind, mine, theirs in zip(cfg.layer_types, alone[name],
+                                      forked[name]):
+            window = mine.shape[-3] if kind == "sliding" else 0
+            positions = np.arange(first + steps - min(steps, window or steps),
+                                  first + steps)
+            np.testing.assert_allclose(
+                np.take(np.asarray(mine),
+                        positions % window if window else positions,
+                        axis=-3),
+                np.take(np.asarray(theirs[b]),
+                        (positions - first) % theirs.shape[-3], axis=-3),
+                rtol=2e-5, atol=2e-5)
+
+
+@functools.lru_cache(maxsize=None)
+def _executables(cfg):
+    """(the one-sequence decode chunk, the several-sequences one, a step
+    of each that returns its logits), jitted once a config."""
+    module = lm.DecoderLM(cfg)
+
+    def one_step(params, cache, token, position):
+        return module.apply({"params": params}, token[None], position,
+                            jnp.int32(1), cache)[:2]
+
+    def forked_step(params, cache, tokens, position, live):
+        return module.apply({"params": params}, tokens, position, live,
+                            cache, sequences=True)[:2]
+
+    return (jax.jit(lm.decode_chunk_fn(module, STEPS)),
+            jax.jit(lm.decode_sequences_fn(module, STEPS)),
+            jax.jit(one_step), jax.jit(forked_step))
+
+
+def forked_against_alone(cfg, params, user, live, batch, prefix=21):
+    """A prefix's chunk, then a prompt of ``user`` real tokens in its
+    padded chunk (the bucket's other rows land behind the prompt in every
+    buffer, where a forked step must not see them), then ``batch``
+    sequences forked from that one prefill against each of the ``live``
+    decoded alone from the same cache by the one-sequence executable: a
+    chunk of steps token for token, and the logits of a few teacher-forced
+    steps after it."""
+    module = lm.DecoderLM(cfg)
+    alone, together, one_step, forked_step = _executables(cfg)
+    bucket = kv.chunk_bucket(user)
+    capacity = kv.capacity_for(prefix + bucket + 2 * STEPS)
+    ids = jax.random.randint(jax.random.key(user), (prefix + bucket,), 0,
+                             512)
+    _, cache, _ = module.apply(
+        {"params": params}, ids[:prefix], jnp.int32(0), jnp.int32(prefix),
+        lm.empty_cache(cfg, capacity, jnp.float32), all_logits=False)
+    row, cache, _ = module.apply(
+        {"params": params}, ids[prefix:], jnp.int32(prefix),
+        jnp.int32(user), cache, all_logits=False)
+    length = prefix + user
+    keys = _keys(list(range(live)) + [live - 1] * (batch - live))
+    first = lm.sample_each(row[0], keys, length, jnp.float32(1.0))
+    forked, tokens, position, made, *_ = together(
+        params, kv.fork(cache, batch, 2 * STEPS), first, jnp.int32(length),
+        keys, jnp.float32(1.0), jnp.int32(live))
+    assert int(position) == length + STEPS
+    # the shared rows are the prefill's, untouched
+    for name, shared in zip(lm.ATTENTION_BUFFERS, lm.SHARED_BUFFERS):
+        for mine, theirs in zip(cache[name], forked[shared]):
+            assert np.array_equal(np.asarray(mine), np.asarray(theirs))
+    assert int(forked[lm.FORKED_AT][0][0, 0]) == length
+    own = []
+    for b in range(live):
+        after, last, _, steps, *_ = alone(
+            params, cache, first[b], jnp.int32(length), keys[b],
+            jnp.float32(1.0))
+        assert np.array_equal(steps, made[:, b]), b
+        assert int(last) == int(tokens[b])
+        assert_own_rows(cfg, after, forked, b, length, STEPS)
+        own.append(after)
+    assert len({tuple(np.asarray(made[:, b])) for b in range(live)}) == live
+    forced = jax.random.randint(jax.random.key(8), (4, batch), 0, 512)
+    for t, row in enumerate(forced):
+        at = jnp.int32(length + STEPS + t)
+        logits, forked = forked_step(params, forked, row, at,
+                                     jnp.int32(live))
+        for b in range(live):
+            want, own[b] = one_step(params, own[b], row[b], at)
+            np.testing.assert_allclose(logits[b], want[0], rtol=1e-5,
+                                       atol=1e-5)
+
+
 class TestSequencesOfOneStep:
+    @pytest.mark.parametrize("user", [1, 16, 63, 64])
+    @pytest.mark.parametrize("live,batch", [(2, 2), (4, 4), (3, 4)])
+    def test_a_forked_decode_is_each_sequence_alone(self, params, user,
+                                                    live, batch):
+        """At the chunk bucket's edges: a prompt of 1 leaves 63 padded
+        rows behind the fork, one of 64 none. The prefix's 21 positions
+        have wrapped the rings of 8."""
+        forked_against_alone(CFG, params, user, live, batch)
+
     @pytest.mark.parametrize("live,batch", [(1, 1), (2, 2), (4, 4), (3, 4)])
     def test_each_sequence_gets_what_it_gets_alone(self, params, live,
                                                    batch):
@@ -222,10 +334,7 @@ class TestSequencesOfOneStep:
                 jnp.float32(1.0))
             assert np.array_equal(steps, made[:, b])
             assert int(last) == int(tokens[b])
-            for name in ("k", "v"):
-                for mine, theirs in zip(own[name], forked[name]):
-                    np.testing.assert_allclose(mine, theirs[b], rtol=2e-5,
-                                               atol=2e-5)
+            assert_own_rows(CFG, own, forked, b, length, STEPS)
             total = total + own_load
         assert np.array_equal(load, total)      # the pad is not counted
         assert int(none_held.sum()) == 0
@@ -263,21 +372,40 @@ class TestSequencesOfOneStep:
                                            atol=1e-5)
                 assert np.array_equal(np.sort(routed[0][:, b], -1),
                                       np.sort(own[0][:, 0], -1))
-        # 21 + 12 positions through rings of 8: every slot rewritten
+        # 21 + 12 positions through rings of 8: alone every slot is
+        # rewritten, forked the ring is as the prefill left it
         for b in range(4):
-            for mine, theirs in zip(alone[b]["k"], together["k"]):
-                np.testing.assert_allclose(mine, theirs[b], rtol=2e-5,
-                                           atol=2e-5)
+            assert_own_rows(CFG, alone[b], together, b, length, 12)
+        for mine, theirs in zip(cache["k"], together["k_shared"]):
+            assert mine is theirs
 
-    def test_a_fork_copies_every_buffer_once_a_sequence(self, params):
+    def test_a_fork_copies_nothing(self, params):
+        """(c): the shared buffers ARE the prefill's, rings and buffers
+        alike; what is made is a few rows a sequence and the position,
+        not yet known."""
         _, cache, _ = _prefilled(params)
-        forked = jax.jit(lambda c: kv.fork(c, 4))(cache)
-        assert [x.shape for x in forked["k"]] == \
-            [(4, 8, 2, 8)] * 3 + [(4, 256, 2, 8)]
-        for name in ("k", "v"):
-            for one, four in zip(cache[name], forked[name]):
-                assert np.array_equal(np.asarray(four),
-                                      np.broadcast_to(one, four.shape))
+        forked = kv.fork(cache, 4, 2 * STEPS)
+        assert set(forked) == {"k", "v", "k_shared", "v_shared",
+                               "forked_at"}
+        for name, shared in zip(("k", "v"), ("k_shared", "v_shared")):
+            assert all(mine is theirs for mine, theirs
+                       in zip(cache[name], forked[shared]))
+            assert [x.shape for x in forked[shared]] == \
+                [(8, 2, 8)] * 3 + [(256, 2, 8)]
+            assert [x.shape for x in forked[name]] == [(4, 64, 2, 8)] * 4
+            assert not any(np.any(np.asarray(x)) for x in forked[name])
+        (at,) = forked["forked_at"]
+        assert at.shape == (4, 1) and np.all(np.asarray(at) == -1)
+        # what the engine's fork executable makes: the same, from shapes
+        made = jax.jit(lambda c: kv.own_rows(c, 4, 2 * STEPS))(cache)
+        again = kv.forked(cache, made)
+        assert jax.tree_util.tree_structure(again) \
+            == jax.tree_util.tree_structure(forked)
+        assert all(mine is theirs
+                   for mine, theirs in zip(cache["v"], again["v_shared"]))
+        # without a count of slots a sequence gets a buffer's own
+        assert [x.shape for x in kv.fork(cache, 2)["k"]] == \
+            [(2, 8, 2, 8)] * 3 + [(2, 256, 2, 8)]
 
     @pytest.mark.parametrize("images,bucket", [
         (1, 1), (2, 2), (3, 4), (4, 4), (5, 8), (8, 8), (11, 8)])
@@ -305,13 +433,19 @@ class TestSequencesOfOneStep:
         # past the window the two kinds finally differ
         assert manager.positions_in_use(40) == {"full": 40,
                                                 "sliding": 3 * 8}
-        assert manager.positions_in_use(40, 4) == {"full": 160,
-                                                   "sliding": 96}
+        # four sequences forked at 30: what lies before it once (a ring
+        # its 8), the 10 behind it once each
+        assert manager.positions_in_use(40, 4, 30) == {
+            "full": 30 + 4 * 10, "sliding": 3 * (8 + 4 * 8)}
+        assert manager.positions_in_use(34, 4, 30) == {
+            "full": 30 + 4 * 4, "sliding": 3 * (8 + 4 * 4)}
         one = kv.state_bytes(CFG, 256, jnp.bfloat16)
-        assert one == {"full": 2 * 256 * 2 * 8 * 2,
-                       "sliding": 3 * 2 * 8 * 2 * 8 * 2}
-        assert kv.state_bytes(CFG, 256, jnp.bfloat16, 4) == {
-            kind: 4 * size for kind, size in one.items()}
+        row = 2 * 8 * 2                     # a slot's keys, bfloat16
+        assert one == {"full": 2 * 256 * row, "sliding": 3 * 2 * 8 * row}
+        # a forked group: every buffer once and 64 slots a sequence
+        assert kv.state_bytes(CFG, 256, jnp.bfloat16, 4, 64) == {
+            "full": 2 * (256 + 4 * 64) * row,
+            "sliding": 3 * 2 * (8 + 4 * 64) * row}
 
     def test_experts_read_counts_each_held_expert_once(self):
         routing = moe.Routing(
@@ -442,10 +576,16 @@ class TestABatchOfImages:
         routed = sum(map(sum, stats["expert_tokens"]))
         assert routed == 5 * 4 * 2 + 4 * picks
         assert stats["experts_read"] < 4 * picks
+        # forked at 31 + 5: those positions once (a ring the last 8),
+        # the 40 behind them once a sequence (a window of them)
         assert stats["cache_positions"] == {
-            "full": 4 * 76, "sliding": 4 * 3 * 8}
+            "full": 36 + 4 * 40, "sliding": 3 * (8 + 4 * 8)}
+        steps = range(36, 36 + 2 * STEPS)
+        assert stats["rows_attended"] == sum(4 * (p + 1) for p in steps)
+        assert stats["rows_read"] == sum(36 + 4 * (p + 1 - 36)
+                                         for p in steps)
         assert stats["state_bytes"] == kv.state_bytes(
-            CFG, CAPACITY, jnp.float32, 4)
+            CFG, CAPACITY, jnp.float32, 4, 2 * STEPS)
         events = [e for e in spans.TRACER.export_chrome()["traceEvents"]
                   if e.get("ph") == "X"]
         by_name = {}
@@ -455,7 +595,11 @@ class TestABatchOfImages:
         assert [a["tokens"] for a in by_name["expand.prefill"]] == [5]
         (fork,) = by_name["expand.fork"]
         assert fork["sequences"] == 4
-        assert fork["bytes"] == sum(stats["state_bytes"].values())
+        # the bytes a fork makes: four layers' keys and values of 64
+        # slots a sequence, float32; rings and buffers stay where they are
+        assert fork["bytes"] == 4 * 2 * 4 * 2 * STEPS * 2 * 8 * 4 \
+            == sum(stats["state_bytes"].values()) - sum(kv.state_bytes(
+                CFG, CAPACITY, jnp.float32).values())
         assert [a["sequences"] for a in by_name["expand.decode_chunk"]] \
             == [4, 4]
         by_id = {e["args"]["span_id"]: e for e in events}
@@ -481,11 +625,13 @@ class TestABatchOfImages:
                 if k[0].startswith("expand")}
         assert keys == {("expand_prefill", 64, CAPACITY),
                         ("expand_prefill", 64, CAPACITY, 4),
-                        ("expand_fork", CAPACITY, 4),
+                        ("expand_fork", CAPACITY, 4, 2 * STEPS),
                         ("expand_decode_chunk", STEPS, CAPACITY, 4)}
         sites = ATTENTION.summary()["by_shape"]
-        assert sites["T1 S8 D8"] == {"xla": 3}      # the rings, as they lie
-        assert sites[f"T1 S{CAPACITY} D8"] == {"xla": 1}
+        # a forked step's keys: the ring or the buffer as the prefill
+        # left it, and a sequence's own 64 slots behind it
+        assert sites[f"T1 S{8 + 2 * STEPS} D8"] == {"xla": 3}
+        assert sites[f"T1 S{CAPACITY + 2 * STEPS} D8"] == {"xla": 1}
         ATTENTION.clear()
 
     def test_same_seed_images_are_expanded_once(self, engine):
@@ -594,11 +740,13 @@ class TestThePublishedShare:
         fixed = 8 * (21_233_664 + 147_456) * 2 + 226_492_416 * 2
         assert round(fixed / 1e6) == 795
         assert round((fixed + 64 * 6_193_152 * 2) / 1e6) == 1588
-        # the caches of four sequences at the cell's capacity
+        # the caches of four forked sequences at the cell's capacity:
+        # every buffer once and 256 slots a sequence
         capacity = kv.capacity_for(2048 + 64 + 8 * STEPS)
-        sizes = kv.state_bytes(share, capacity, jnp.bfloat16, 4)
-        assert sizes == {"full": 4 * 2 * 2 * capacity * 4 * 128 * 2,
-                         "sliding": 4 * 6 * 2 * 1024 * 4 * 128 * 2}
+        sizes = kv.state_bytes(share, capacity, jnp.bfloat16, 4, 8 * STEPS)
+        row = 2 * 4 * 128 * 2               # a layer's slot
+        assert sizes == {"full": 2 * (capacity + 4 * 256) * row,
+                         "sliding": 6 * (1024 + 4 * 256) * row}
 
     def test_one_step_of_four_sequences_traces_the_grouped_product(self):
         """One decode step of the share the cell runs, traced without
@@ -606,9 +754,7 @@ class TestThePublishedShare:
         product, six ring sites and two buffer sites."""
         share = configs.sd15_mellum2_expander().expander
         module = lm.DecoderLM(share, dtype=jnp.bfloat16)
-        s = jax.ShapeDtypeStruct
-        cache = {name: [s((4,) + shape, jnp.bfloat16) for shape in rows]
-                 for name, rows in lm.cache_shapes(share, 2560).items()}
+        cache = forked_shapes(share, 2560, 4, 256)
         shapes = jax.eval_shape(lambda: module.init(
             jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
             jnp.int32(4), lm.empty_cache(share, 8, jnp.float32)))
@@ -623,8 +769,12 @@ class TestThePublishedShare:
         assert routed[0].shape == (8, 4, 8) and routed[1].shape == (8, 64)
         assert EXPANDER.summary()["expert_products"] == {
             "kernel": 0, "loop": 0, "grouped": 8}
+        assert [x.shape for x in after["k_shared"]] \
+            == [(1024, 4, 128)] * 3 + [(2560, 4, 128)] \
+            + [(1024, 4, 128)] * 3 + [(2560, 4, 128)]
+        assert {x.shape for x in after["k"]} == {(4, 256, 4, 128)}
         assert ATTENTION.summary()["by_shape"] == {
-            "T1 S1024 D128": {"xla": 6}, "T1 S2560 D128": {"xla": 2}}
+            "T1 S1280 D128": {"xla": 6}, "T1 S2816 D128": {"xla": 2}}
         assert moe.row_tile(4, 8, 64) == 8      # a trip an expert
         ATTENTION.clear()
         EXPANDER.clear()
@@ -639,9 +789,7 @@ class TestThePublishedShare:
         share = configs.sd15_mellum2_expander().expander
         module = lm.DecoderLM(share, dtype=jnp.bfloat16)
         s = jax.ShapeDtypeStruct
-        cache = {name: [s((sequences,) + shape, jnp.bfloat16)
-                        for shape in rows]
-                 for name, rows in lm.cache_shapes(share, 2560).items()}
+        cache = forked_shapes(share, 2560, sequences, 256)
         shapes = jax.eval_shape(lambda: module.init(
             jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
             jnp.int32(4), lm.empty_cache(share, 8, jnp.float32)))

@@ -37,6 +37,9 @@ from stable_diffusion_webui_distributed_tpu.runtime.interrupt import (
 from stable_diffusion_webui_distributed_tpu.serving.metrics import (
     ATTENTION, EXPANDER, METRICS,
 )
+from tests.test_mellum2_expander import (
+    assert_own_rows, forked_against_alone, forked_shapes,
+)
 from tests.test_pipeline import init_params
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -192,6 +195,14 @@ def _keys(indices, seed=77):
 
 
 class TestSequencesOfOneStep:
+    @pytest.mark.parametrize("user", [1, 16, 63, 64])
+    @pytest.mark.parametrize("live,batch", [(2, 2), (4, 4), (3, 4)])
+    def test_a_forked_decode_is_each_sequence_alone(self, params, user,
+                                                    live, batch):
+        """The looped stack at the chunk bucket's edges: every pass reads
+        its own rows of the shared buffers and of a sequence's own."""
+        forked_against_alone(CFG, params, user, live, batch)
+
     @pytest.mark.parametrize("live,batch", [(1, 1), (2, 2), (4, 4), (3, 4)])
     def test_each_sequence_gets_what_it_gets_alone(self, params, live,
                                                    batch):
@@ -220,11 +231,11 @@ class TestSequencesOfOneStep:
             assert int(last) == int(tokens[b])
             assert own_counts.tolist() == [0] * (PASSES - 1) + [STEPS]
             largest = max(largest, float(own_most))
-            for name in ("k", "v"):
-                for mine, theirs in zip(own[name], forked[name]):
-                    assert mine.shape == (PASSES, 256, 4, 16)
-                    np.testing.assert_allclose(mine, theirs[b], rtol=2e-5,
-                                               atol=2e-5)
+            assert all(x.shape == (PASSES, 256, 4, 16)
+                       for x in own["k"] + own["v"])
+            assert_own_rows(CFG, own, forked, b, length, STEPS)
+        assert all(x.shape == (batch, PASSES, 256, 4, 16)
+                   for x in forked["k"] + forked["v"])
         assert float(most) == pytest.approx(largest, rel=1e-5)
         assert len({tuple(np.asarray(made[:, b])) for b in range(live)}) \
             == live
@@ -241,14 +252,24 @@ class TestSequencesOfOneStep:
                                      axis=(1, 2)))
             assert rel_rms(rows[1, :length], rows[0, :length]) > 0.05
 
-    def test_a_fork_copies_every_pass_once_a_sequence(self, params):
+    def test_a_fork_copies_no_pass(self, params):
+        """(c): the shared buffers ARE the prefill's, every pass of them;
+        a sequence's own rows have the passes behind the sequences."""
         _, cache, _ = _prefilled(params)
-        forked = jax.jit(lambda c: kv.fork(c, 4))(cache)
-        assert [x.shape for x in forked["k"]] == [(4, PASSES, 256, 4, 16)] * 4
-        for name in ("k", "v"):
-            for one, four in zip(cache[name], forked[name]):
-                assert np.array_equal(np.asarray(four),
-                                      np.broadcast_to(one, four.shape))
+        forked = kv.fork(cache, 4, 2 * STEPS)
+        for name, shared in zip(("k", "v"), ("k_shared", "v_shared")):
+            assert all(mine is theirs for mine, theirs
+                       in zip(cache[name], forked[shared]))
+            assert [x.shape for x in forked[shared]] == \
+                [(PASSES, 256, 4, 16)] * 4
+            assert [x.shape for x in forked[name]] == \
+                [(4, PASSES, 64, 4, 16)] * 4
+        (at,) = forked["forked_at"]
+        assert at.shape == (4, 1) and np.all(np.asarray(at) == -1)
+        made = jax.jit(lambda c: kv.own_rows(c, 4, 2 * STEPS))(cache)
+        assert [x.shape for x in made["k"]] == [(4, PASSES, 64, 4, 16)] * 4
+        assert not any(np.any(np.asarray(x)) for x in made["v"])
+        assert set(made) == {"k", "v", "forked_at"}
 
     def test_a_looped_model_shares_a_step(self):
         assert lm.shares_a_step(CFG)
@@ -298,13 +319,17 @@ class TestTheCacheCountsThePassAxis:
         # a position occupies a row of every pass of every layer
         assert manager.positions_in_use(40) == {"full": 4 * 40 * PASSES,
                                                 "sliding": 0}
-        assert manager.positions_in_use(40, 4) == {
-            "full": 4 * 4 * 40 * PASSES, "sliding": 0}
+        # four sequences forked at 30: what lies before it once, the 10
+        # behind it once each, in every pass of every layer
+        assert manager.positions_in_use(40, 4, 30) == {
+            "full": 4 * (30 + 4 * 10) * PASSES, "sliding": 0}
         one = kv.state_bytes(CFG, 256, jnp.bfloat16)
         assert one == {"full": 4 * PASSES * 2 * 256 * 4 * 16 * 2,
                        "sliding": 0}
-        assert kv.state_bytes(CFG, 256, jnp.bfloat16, 4) == {
-            kind: 4 * size for kind, size in one.items()}
+        # a forked group: every buffer once and 64 slots a sequence
+        assert kv.state_bytes(CFG, 256, jnp.bfloat16, 4, 64) == {
+            "full": 4 * PASSES * 2 * (256 + 4 * 64) * 4 * 16 * 2,
+            "sliding": 0}
         assert lm.cache_shapes(CFG, 256) == {
             "k": [(PASSES, 256, 4, 16)] * 4, "v": [(PASSES, 256, 4, 16)] * 4}
 
@@ -352,7 +377,7 @@ class TestTheEnginePath:
                 if k[0].startswith("expand")}
         assert keys == {("expand_prefill", 64, CAPACITY),
                         ("expand_prefill", 64, CAPACITY, 4),
-                        ("expand_fork", CAPACITY, 4),
+                        ("expand_fork", CAPACITY, 4, 2 * STEPS),
                         ("expand_decode_chunk", STEPS, CAPACITY),
                         ("expand_decode_chunk", STEPS, CAPACITY, 4)}
 
@@ -375,11 +400,18 @@ class TestTheEnginePath:
         assert stats["exit_pass"] == [0] * (PASSES - 1) + [1 + 4 * 2 * STEPS]
         assert 0.5 < stats["exit_lambda_max"] < 0.9999
         assert stats["experts_read"] == 0 and stats["expert_tokens"] == []
-        assert stats["cache_positions"] == {"full": 4 * 4 * 76 * PASSES,
-                                            "sliding": 0}
-        sizes = kv.state_bytes(CFG, CAPACITY, jnp.float32, 4)
+        # forked at 31 + 5: those positions once, the 40 behind them once
+        # a sequence
+        assert stats["cache_positions"] == {
+            "full": 4 * (36 + 4 * 40) * PASSES, "sliding": 0}
+        steps = range(36, 36 + 2 * STEPS)
+        assert stats["rows_attended"] == sum(4 * (p + 1) for p in steps)
+        assert stats["rows_read"] == sum(36 + 4 * (p + 1 - 36)
+                                         for p in steps)
+        sizes = kv.state_bytes(CFG, CAPACITY, jnp.float32, 4, 2 * STEPS)
         assert stats["state_bytes"] == sizes
-        assert sizes["full"] == 4 * 4 * PASSES * 2 * CAPACITY * 4 * 16 * 4
+        row = PASSES * 2 * 4 * 16 * 4       # a layer's slot, float32
+        assert sizes["full"] == 4 * (CAPACITY + 4 * 2 * STEPS) * row
         events = [e for e in spans.TRACER.export_chrome()["traceEvents"]
                   if e.get("ph") == "X"]
         by_name = {}
@@ -388,7 +420,8 @@ class TestTheEnginePath:
         assert [a["passes"] for a in by_name["expand.prefill"]] == [PASSES]
         (fork,) = by_name["expand.fork"]
         assert fork["passes"] == PASSES and fork["sequences"] == 4
-        assert fork["bytes"] == sum(sizes.values())
+        # the bytes a fork makes: the sequences' own rows alone
+        assert fork["bytes"] == 4 * 4 * 2 * STEPS * row
         assert [(a["passes"], a["sequences"])
                 for a in by_name["expand.decode_chunk"]] == [(PASSES, 4)] * 2
         text = prometheus.render()
@@ -410,7 +443,9 @@ class TestTheEnginePath:
         fresh.txt2img(payload(batch_size=4))
         sites = ATTENTION.summary()["by_shape"]
         assert sites[f"T64 S{CAPACITY} D16 P{PASSES}"] == {"xla": 2}
-        assert sites[f"T1 S{CAPACITY} D16 P{PASSES}"] == {"xla": 1}
+        # a forked step's keys: the shared buffer and a sequence's own
+        assert sites[f"T1 S{CAPACITY + 2 * STEPS} D16 P{PASSES}"] \
+            == {"xla": 1}
         ATTENTION.clear()
 
     def test_a_threshold_below_one_counts_the_earlier_passes(self):
@@ -476,8 +511,9 @@ class TestThePublishedModel:
         assert sizes["full"] // 512 == 48 * 4 * 2 * 16 * 128 * 2 \
             == 3 * 2 ** 19
         assert sizes["full"] == 3 * 2 ** 28
-        assert kv.state_bytes(cfg, 512, jnp.bfloat16, 4)["full"] \
-            == 3 * 2 ** 30
+        # four sequences forked: the 0.75 GiB once and 64 slots each
+        assert kv.state_bytes(cfg, 512, jnp.bfloat16, 4, 64)["full"] \
+            == 3 * 2 ** 28 + 4 * 64 * 3 * 2 ** 19 == 9 * 2 ** 27
         assert kv.capacity_for(256 + 64 + 2 * STEPS) == 512
 
     def test_one_step_of_four_sequences_is_48_layers_in_one_loop(self):
@@ -486,12 +522,12 @@ class TestThePublishedModel:
         not 192, and every site marked with its four passes."""
         cfg = configs.OURO_2_6B
         module = lm.DecoderLM(cfg, dtype=jnp.bfloat16)
-        s = jax.ShapeDtypeStruct
-        cache = {name: [s((4,) + shape, jnp.bfloat16) for shape in rows]
-                 for name, rows in lm.cache_shapes(cfg, 512).items()}
-        # a buffer a layer: every sequence's four passes of 512 rows
-        assert len(cache["k"]) == len(cache["v"]) == 48
-        assert cache["k"][0].shape == (4, 4, 512, 16, 128)
+        cache = forked_shapes(cfg, 512, 4, 64)
+        # a layer: four passes of 512 shared rows, and every sequence's
+        # four passes of 64 of its own
+        assert len(cache["k"]) == len(cache["v_shared"]) == 48
+        assert cache["k_shared"][0].shape == (4, 512, 16, 128)
+        assert cache["k"][0].shape == (4, 4, 64, 16, 128)
         shapes = jax.eval_shape(lambda: module.init(
             jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
             jnp.int32(4), lm.empty_cache(cfg, 8, jnp.float32)))
@@ -500,12 +536,14 @@ class TestThePublishedModel:
             v, jnp.zeros((4,), jnp.int32), jnp.int32(330), jnp.int32(4), c,
             sequences=True)).lower(shapes, cache).as_text()
         assert text.count("stablehlo.while") == 1
-        # the 48 layers are 48 calls of ONE traced layer: its 7 Linears
-        # and attention's two products; the gate and the head beside them
         assert text.count("stablehlo.case") == 0
-        assert text.count("stablehlo.dot_general") == 7 + 2 + 2
+        # the 48 layers are 48 calls of ONE traced layer: its 7 Linears
+        # and attention's four products (scores and sums over the shared
+        # rows and over a sequence's own); the gate and the head beside
+        # them
+        assert text.count("stablehlo.dot_general") == 7 + 4 + 2
         assert ATTENTION.summary()["by_shape"] == {
-            "T1 S512 D128 P4": {"xla": 1}}
+            "T1 S576 D128 P4": {"xla": 1}}
         assert text.count("call @looped_layer(") == 48
         ATTENTION.clear()
 

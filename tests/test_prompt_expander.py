@@ -27,6 +27,7 @@ from stable_diffusion_webui_distributed_tpu.models.tokenizer import (
 from stable_diffusion_webui_distributed_tpu.ops import (
     attention, moe, moe_kernel,
 )
+from stable_diffusion_webui_distributed_tpu.pipeline import expand
 from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
 from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
     GenerationPayload, prompt_expansion_args,
@@ -179,6 +180,60 @@ class TestMasks:
             q, k, v, jnp.array([0, 5]), jnp.array([-1, 3, 9]), scale=1.0)
         np.testing.assert_array_equal(out[0], 0.0)    # sees nothing
         np.testing.assert_allclose(out[1], 1.0)       # sees position 3 only
+
+    @pytest.mark.parametrize("window", [0, 12])
+    @pytest.mark.parametrize("filled", [1, 8])
+    @pytest.mark.parametrize("forked_at", [20, 32])
+    @pytest.mark.parametrize("groups", [1, 4])
+    def test_two_ranges_are_one_softmax_over_both(self, groups, forked_at,
+                                                  filled, window):
+        """Four sequences' queries over 32 shared slots (the fork inside
+        them or at their end: the slots behind it are empty) and 8 of
+        their own (one filled, the first step; all filled), against each
+        query alone over the shared keys followed by its own."""
+        b, kv_heads, dim, s, t = 4, 2, 8, 32, 8
+        key = jax.random.key(2)
+
+        def normal(i, shape):
+            return jax.random.normal(jax.random.fold_in(key, i), shape)
+
+        q = normal(0, (b, kv_heads * groups, dim))
+        k_shared, v_shared = normal(1, (s, kv_heads, dim)), \
+            normal(2, (s, kv_heads, dim))
+        k_own, v_own = normal(3, (b, t, kv_heads, dim)), \
+            normal(4, (b, t, kv_heads, dim))
+        q_pos = jnp.full((b,), forked_at + filled - 1)
+        shared_pos = jnp.where(jnp.arange(s) < forked_at, jnp.arange(s), -1)
+        own_pos = forked_at + jnp.arange(t)     # the unfilled lie ahead
+        out, path = attention.attend_two_ranges(
+            q, k_shared, v_shared, k_own, v_own, q_pos, shared_pos, own_pos,
+            scale=dim ** -0.5, window=window)
+        assert path == attention.XLA and out.shape == q.shape
+        for i in range(b):
+            want, _ = attention.attend_positions(
+                q[i][None], jnp.concatenate([k_shared, k_own[i]]),
+                jnp.concatenate([v_shared, v_own[i]]), q_pos[:1],
+                jnp.concatenate([shared_pos, own_pos]), scale=dim ** -0.5,
+                window=window)
+            np.testing.assert_allclose(out[i], want[0], rtol=1e-5,
+                                       atol=1e-6)
+        assert not np.allclose(out[0], out[1])
+
+    def test_two_ranges_and_a_query_that_sees_nothing(self):
+        """Empty slots in both ranges; the second sequence's query stands
+        before every key and gets zeros."""
+        q = jnp.ones((2, 2, 4))
+        k_shared = v_shared = jnp.ones((3, 1, 4))
+        k_own = v_own = 2 * jnp.ones((2, 2, 1, 4))
+        out, _ = attention.attend_two_ranges(
+            q, k_shared, v_shared, k_own, v_own, jnp.array([6, 1]),
+            jnp.array([-1, 3, 9]), jnp.array([-1, 8]), scale=1.0)
+        np.testing.assert_allclose(out[0], 1.0)     # position 3 only
+        np.testing.assert_array_equal(out[1], 0.0)
+        out, _ = attention.attend_two_ranges(
+            q, k_shared, v_shared, k_own, v_own, jnp.array([8, 8]),
+            jnp.array([-1, -1, 9]), jnp.array([-1, 8]), scale=1.0)
+        np.testing.assert_allclose(out, 2.0)        # its own row only
 
     @pytest.mark.parametrize("site", [
         ("tpu", 4096, 4096, jnp.bfloat16, True),
@@ -863,6 +918,7 @@ class TestEnginePath:
         assert set(block) == {
             "requests", "tokens_prefilled", "tokens_from_prefix_cache",
             "sequences", "tokens_decoded", "decode_steps", "experts_read",
+            "rows_attended", "rows_read",
             "tokens_no_held_expert", "expert_tokens",
             "expert_load_max_over_mean", "cache_positions", "state_bytes",
             "prefix_snapshots", "padded_rows_masked", "expert_products",
@@ -876,6 +932,40 @@ class TestEnginePath:
         assert set(block["mixer_products"]) == {"kernel", "loop"}
         assert block["conv_mixers"] == {"step": 0, "chunk": 0}
         json.dumps(block)
+
+    @pytest.mark.parametrize("sequences,forked_at,steps", [
+        (1, 300, 64), (4, 296, 64), (4, 2088, 256), (3, 40, 32)])
+    def test_rows_attended_and_rows_read(self, sequences, forked_at, steps):
+        """What the stage hands ``record`` (pipeline/expand.py), against
+        the sums written out: a step at position ``p`` attends ``p + 1``
+        rows a sequence and reads what lies before the fork once for all.
+        One sequence counts the same in both; four forked at 296 for 64
+        steps read a row for 3.1 queries."""
+        from stable_diffusion_webui_distributed_tpu.serving.metrics import (
+            ExpanderStats,
+        )
+
+        attended = sum(sequences * (p + 1)
+                       for p in range(forked_at, forked_at + steps))
+        read = sum(forked_at + sequences * (p + 1 - forked_at)
+                   for p in range(forked_at, forked_at + steps))
+        stats = ExpanderStats()
+        for _ in range(2):      # counters add up over requests
+            stats.record(
+                prefilled=0, from_prefix=0, sequences=sequences, decoded=0,
+                decode_steps=steps, experts_read=0, load=[], none_held=0,
+                positions={}, state_bytes={}, prefix_snapshots=0,
+                padded_rows_masked=0, residual_streams=1, sinkhorn_iters=0,
+                **expand.rows_of(sequences, forked_at, steps))
+        block = stats.summary()
+        assert block["rows_attended"] == 2 * attended
+        assert block["rows_read"] == 2 * read
+        if sequences == 1:
+            assert attended == read
+        if (sequences, forked_at, steps) == (4, 296, 64):
+            assert round(attended / read, 1) == 3.1
+        if forked_at == 2088:
+            assert round(attended / read, 1) == 3.4
 
 
 class TestDispatcher:
