@@ -24,14 +24,19 @@ cut back, so the key is the whole prefix. The executables donate the cache
 they are given, so what is kept is never handed out itself.
 
 The images of one request continue one prompt, each under its own key. Where
-every layer keeps keys and values (``lm.shares_a_step``) they are decoded as
+every layer keeps a row a position (keys and values, or latents:
+``lm.shares_a_step``) they are decoded as
 sequences of one step: the prompt is prefilled once and its cache
-:func:`fork`-ed. A fork copies nothing. Every buffer, a full layer's and a
-ring alike, stays where the prefill left it, held once, read by every
-sequence and written by none (``k_shared``, ``v_shared``), and gets behind
+:func:`fork`-ed. A fork copies nothing. Every buffer, a full layer's, a
+ring and a latent layer's alike, stays where the prefill left it, held once,
+read by every
+sequence and written by none (``k_shared``, ``v_shared``,
+``latent_shared``), and gets behind
 it a few rows a sequence (as many as it will decode) for what each makes
 itself (``k``, ``v``: ``(sequences, [passes,] slots, kv heads,
-head_dim)``). The prompt's rows are most of what a step attends, so a step
+head_dim)``; ``latent``: ``(sequences, slots, width)``: which axis counts
+the slots is ``lm.slots_axis`` of the buffer's name). The prompt's rows are
+most of what a step attends, so a step
 reads them once where copies would be read once a sequence; a ring is
 never overwritten because a sequence's new rows go to its own, not to the
 ring. Both kinds go one way because a fork is handed buffers, not a
@@ -96,10 +101,13 @@ def own_rows(cache: Dict, sequences: int, slots: int = 0) -> Dict:
     known (negative: the first step sets it to where it stands), a row a
     sequence like the rest. Only the buffers' shapes are read, so traced
     into an executable of its own it touches no buffer."""
-    own = jax.tree_util.tree_map(
-        lambda x: jnp.zeros((sequences,) + x.shape[:-3]
-                            + (slots or x.shape[-3],) + x.shape[-2:],
-                            x.dtype), cache)
+    def rows(name, x):
+        shape, axis = list(x.shape), lm.slots_axis(name)
+        shape[axis] = slots or shape[axis]
+        return jnp.zeros((sequences, *shape), x.dtype)
+
+    own = {name: [rows(name, x) for x in cache[name]]
+           for name in sorted(cache)}
     own[lm.FORKED_AT] = [jnp.full((sequences, 1), -1, jnp.int32)]
     return own
 
@@ -107,8 +115,7 @@ def own_rows(cache: Dict, sequences: int, slots: int = 0) -> Dict:
 def forked(cache: Dict, own: Dict) -> Dict:
     """``cache`` of one sequence and :func:`own_rows` of it as one cache:
     the shared buffers ARE ``cache``'s."""
-    return {**own, **{shared: cache[name] for name, shared in zip(
-        lm.ATTENTION_BUFFERS, lm.SHARED_BUFFERS)}}
+    return {**own, **{lm.SHARED_OF[name]: cache[name] for name in cache}}
 
 
 def fork(cache: Dict, sequences: int, own_slots: int = 0) -> Dict:
@@ -131,17 +138,18 @@ def state_bytes(config, capacity: int, dtype, sequences: int = 1,
     shapes = {name: iter(rows)
               for name, rows in lm.cache_shapes(config, capacity).items()}
 
-    def held(shape) -> int:
+    def held(name, shape) -> int:
         """A buffer's elements, and its sequences' own rows behind it."""
         if sequences == 1:
             return math.prod(shape)
-        return math.prod(shape) // shape[-3] * (
-            shape[-3] + sequences * own_slots)
+        slots = shape[lm.slots_axis(name)]
+        return math.prod(shape) // slots * (slots + sequences * own_slots)
 
     out = {lm.FULL: 0, lm.SLIDING: 0}
     for kind in config.layer_types:
         out[kind] = out.get(kind, 0) + sum(
-            held(next(shapes[name])) * lm.buffer_dtype(name, dtype).itemsize
+            held(name, next(shapes[name]))
+            * lm.buffer_dtype(name, dtype).itemsize
             for name in lm.buffers_of(kind))
     return out
 
@@ -196,7 +204,8 @@ class KVCacheManager:
         conv layer uses none at any length, a latent layer one a
         position, a full layer of a looped model one a pass. Sequences
         forked at ``forked_at`` hold the positions before it once and the
-        rest once each (a sliding layer at most its window of either)."""
+        rest once each (a sliding layer at most its window of either; a
+        latent layer as a full one)."""
         cfg = self.config
         own, window = length - forked_at, cfg.sliding_window
         out = {
@@ -209,6 +218,6 @@ class KVCacheManager:
             if kind in cfg.layer_types:
                 out[kind] = 0
         if lm.LATENT in cfg.layer_types:
-            out[lm.LATENT] = (len(cfg.layers_of(lm.LATENT)) * length
-                              * sequences)
+            out[lm.LATENT] = len(cfg.layers_of(lm.LATENT)) * (
+                forked_at + sequences * own)
         return out

@@ -78,7 +78,9 @@ class RopeConfig:
     """One rotary parameterisation. ``factor`` 0 is plain RoPE; over 0 the
     frequencies are YaRN's (interpolated below ``beta_slow`` rotations of
     the original context, kept above ``beta_fast``) and cos/sin are
-    multiplied by ``attention_factor``."""
+    multiplied by ``attention_factor``. ``interleaved`` says which dims
+    make a rotated pair: ``(i, i + half)`` (False, the ``rotate_half``
+    convention) or neighbours ``(2i, 2i + 1)``."""
 
     theta: float = 1e4
     partial_rotary_factor: float = 1.0   # share of head_dim that is rotated
@@ -87,6 +89,7 @@ class RopeConfig:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: float = 1.0
+    interleaved: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -174,7 +177,8 @@ class LMConfig:
     # "conv" layers: the taps of the gated convolution (``conv_L_cache``)
     conv_taps: int = 3
     # "latent" layers: the ranks of the query's and the cache's low-rank
-    # paths, a head's un-rotated and rotated key widths and its value
+    # paths (``q_lora_rank`` 0: no query latent, one ``q_proj``), a head's
+    # un-rotated and rotated key widths and its value
     # width; they rotate by ``rope_full`` over all of ``qk_rope_head_dim``.
     # YaRN's ``mscale_all_dim`` scales their softmax, not the tables.
     q_lora_rank: int = 0
@@ -775,6 +779,64 @@ TINY_LOOP_EXPAND = dataclasses.replace(
 def tiny_ouro_expander() -> ModelFamily:
     """Factory form of :data:`TINY_LOOP_EXPAND` (benchmark rehearsals)."""
     return TINY_LOOP_EXPAND
+
+
+# kanana-2-30b-a3b-instruct-2601
+# (huggingface.co/kakaocorp/kanana-2-30b-a3b-instruct-2601 config.json,
+# ``model_type: deepseek_v3``) at its published widths: 48 latent-attention
+# layers of 32 heads with NO query latent (``q_lora_rank`` null: one
+# ``q_proj``), a cached 512-wide latent and one 64-wide rotated key, keys of
+# 128 + 64 and values of 128 a head, plain RoPE theta 1e6 over neighbouring
+# pairs (``rope_interleave``), one stream; one dense layer of width 6144
+# then a sigmoid router with a selection bias over 128 experts of width 768,
+# 6 a token, renormalised over (their sum + 1e-20) at scale 2.448, plus two
+# shared experts that are ONE SwiGLU of width 1536.
+KANANA_2_30B_A3B = LMConfig(
+    vocab_size=128256, hidden_size=2048, layer_types=("latent",) * 48,
+    num_heads_per_layer=(32,) * 48,
+    rope_full=RopeConfig(theta=1e6, interleaved=True), dense_layers=(0,),
+    intermediate_size=6144, num_experts=128, num_experts_per_tok=6,
+    moe_intermediate_size=768, shared_expert_intermediate_size=1536,
+    routed_scaling_factor=2.448, norm_topk_prob=True, norm_topk_eps=1e-20,
+    rms_norm_eps=1e-6, q_lora_rank=0, kv_lora_rank=512,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    router_scoring="sigmoid", router_bias=True)
+
+
+def sd15_kanana2_expander() -> ModelFamily:
+    """SD1.5 with kanana-2-30b-a3b-instruct-2601 as its resident prompt
+    expander, cut in depth alone: layers 0-7 (the first of seven pipeline
+    stages of 8, 7, 7, 7, 7, 7 and 5: the dense layer and seven expert
+    layers), every layer whole (all 128 experts, all 128256 vocabulary
+    ids)."""
+    return dataclasses.replace(
+        SD15, name="sd15-kanana2-expand",
+        expander=lm_share(KANANA_2_30B_A3B, layers=8, chips=1, rank=0))
+
+
+# Tiny expander of that block: four latent layers of 4 heads with no query
+# latent (a cached latent of 16 + a rotated key of 8, under keys of 8 + 8
+# and values of 8 a head), neighbouring rotary pairs, one stream, one dense
+# layer then 16 experts top-4 by biased sigmoid scores over (their sum +
+# 1e-20), all held, and a shared expert of twice the routed width.
+TINY_KANANA_LM = LMConfig(
+    vocab_size=512, hidden_size=32, layer_types=("latent",) * 4,
+    num_heads_per_layer=(4,) * 4,
+    rope_full=RopeConfig(theta=1e6, interleaved=True), dense_layers=(0,),
+    intermediate_size=64, num_experts=16, num_experts_per_tok=4,
+    moe_intermediate_size=16, shared_expert_intermediate_size=32,
+    routed_scaling_factor=2.448, norm_topk_prob=True, norm_topk_eps=1e-20,
+    rms_norm_eps=1e-6, q_lora_rank=0, kv_lora_rank=16, qk_nope_head_dim=8,
+    qk_rope_head_dim=8, v_head_dim=8, router_scoring="sigmoid",
+    router_bias=True)
+TINY_KANANA_EXPAND = dataclasses.replace(
+    TINY, name="tiny-kanana2-expand",
+    expander=lm_share(TINY_KANANA_LM, 4, chips=1, rank=0))
+
+
+def tiny_kanana2_expander() -> ModelFamily:
+    """Factory form of :data:`TINY_KANANA_EXPAND` (benchmark rehearsals)."""
+    return TINY_KANANA_EXPAND
 
 
 FAMILIES = {f.name: f for f in (SD15, SD21, SD21_BASE, SDXL_BASE,

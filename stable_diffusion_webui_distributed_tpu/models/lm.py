@@ -7,7 +7,7 @@ token mixer a layer has (``"full"``: attention over every earlier
 position; ``"sliding"``: over the last ``sliding_window``; ``"linear"``: a
 gated delta rule over a recurrent state, ops/delta_rule.py, behind a short
 causal convolution; ``"latent"``: attention whose cache holds one low-rank
-latent and one rotated key a position, shared by every head, in two forms
+latent and one rotated key a position, shared by every head, in three forms
 over that one cache, :class:`LatentAttention`; ``"conv"``: a gated causal
 convolution over the hidden channels, :class:`ShortConv`), how many query
 heads an attention layer has (they may differ by layer; the KV heads are shared by
@@ -53,11 +53,15 @@ each sequence's own rows behind them, ``(sequences, [passes,] slots, kv
 heads, head_dim)``, the token a sequence makes at position ``p`` in slot
 ``(p - forked_at) % slots``, in a full layer and a sliding one alike (a full
 layer wants a slot for every position a sequence will decode, a sliding one
-no more than its window). Norms, projections, the router, the experts and
+no more than its window). A latent layer goes the same way with its one
+buffer: ``latent_shared`` is the prefill's ``(capacity, width)`` latents,
+``latent`` each sequence's own ``(sequences, slots, width)`` behind them.
+Norms, projections, the router, the experts and
 the head take the rows as they take a chunk's; attention alone tells the
 sequences apart, and reads what they share once. That holds for
-the ``full`` and ``sliding`` kinds (:func:`shares_a_step`); a model with a
-recurrent state, kept rows, latents or several residual streams decodes one
+the ``full``, ``sliding`` and ``latent`` kinds of one stream
+(:func:`shares_a_step`); a model with a
+recurrent state, kept rows or several residual streams decodes one
 sequence a step.
 
 A LOOPED model (``LMConfig.total_ut_steps`` over 1: full attention, dense
@@ -113,32 +117,50 @@ CONV_BUFFERS = ("kept",)
 CONV_STEP, CONV_CHUNK = "step", "chunk"
 #: ops/attention.py records a latent layer's site under its form
 LATENT_ABSORBED, LATENT_EXPANDED = "latent_absorbed", "latent_expanded"
+LATENT_FORKED = "latent_forked"
 
 
 #: under a fork (cache/kv.py:fork) an attention layer has two more: what
 #: the prefill left, which every sequence reads and none writes
 SHARED_BUFFERS = ("k_shared", "v_shared")
+#: and a latent layer one
+LATENT_SHARED = ("latent_shared",)
+#: a buffer that keeps positions, by name: its shared twin under a fork
+SHARED_OF = dict(zip(ATTENTION_BUFFERS + LATENT_BUFFERS,
+                     SHARED_BUFFERS + LATENT_SHARED))
 #: and the cache one entry that is no layer's: the position of the fork
 FORKED_AT = "forked_at"
 
 
 def buffers_of(kind: str, forked: bool = False) -> Tuple[str, ...]:
-    return {LINEAR: LINEAR_BUFFERS, LATENT: LATENT_BUFFERS,
-            CONV: CONV_BUFFERS}.get(
-                kind, ATTENTION_BUFFERS + (SHARED_BUFFERS if forked else ()))
+    if kind == LATENT:
+        return LATENT_BUFFERS + (LATENT_SHARED if forked else ())
+    return {LINEAR: LINEAR_BUFFERS, CONV: CONV_BUFFERS}.get(
+        kind, ATTENTION_BUFFERS + (SHARED_BUFFERS if forked else ()))
+
+
+def slots_axis(name: str) -> int:
+    """The axis of buffer ``name`` that counts positions: keys and values
+    are ``(..., slots, kv heads, head_dim)``, latents ``(..., slots,
+    width)``."""
+    return -2 if name in LATENT_BUFFERS + LATENT_SHARED else -3
 
 
 def shares_a_step(cfg: LMConfig) -> bool:
     """Whether several sequences can be decoded in one step: every layer
-    keeps keys and values (buffers or rings) and a token is one stream."""
-    return (set(cfg.layer_types) <= {FULL, SLIDING}
+    keeps a row a position (keys and values in buffers or rings, or
+    latents) and a token is one stream."""
+    return (set(cfg.layer_types) <= {FULL, SLIDING, LATENT}
             and cfg.residual_streams == 1)
 
 
-def latent_form(tokens: int) -> str:
+def latent_form(tokens: int, sequences: bool = False) -> str:
     """The form a latent layer's chunk of ``tokens`` takes: a decode step
-    attends the cache as it lies, a longer chunk rebuilds keys and values
-    from it (:class:`LatentAttention`)."""
+    attends the cache as it lies (``sequences``: the rows are one token
+    each of as many sequences, over a forked cache's two ranges), a longer
+    chunk rebuilds keys and values from it (:class:`LatentAttention`)."""
+    if sequences:
+        return LATENT_FORKED
     return LATENT_ABSORBED if tokens == 1 else LATENT_EXPANDED
 
 
@@ -175,12 +197,21 @@ def rope_tables(rope: RopeConfig, head_dim: int, positions: jax.Array):
             jnp.sin(angles) * rope.attention_factor)
 
 
-def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
+               interleaved: bool = False) -> jax.Array:
     """Rotates the first ``2 * cos.shape[-1]`` dims of ``(T, H, D)`` ``x``
-    (pairs are (i, i + half), the ``rotate_half`` convention); the rest
-    pass. Float32 result."""
+    (pairs are (i, i + half), the ``rotate_half`` convention, or with
+    ``interleaved`` the neighbours (2i, 2i + 1), each pair turned in
+    place); the rest pass. Float32 result."""
     half = cos.shape[-1]
     x = x.astype(jnp.float32)
+    if interleaved:
+        pairs = x[..., :2 * half]
+        a, b = pairs[..., 0::2], pairs[..., 1::2]
+        c, s = cos[:, None, :], sin[:, None, :]
+        turned = jnp.stack([a * c - b * s, b * c + a * s], axis=-1)
+        return jnp.concatenate(
+            [turned.reshape(pairs.shape), x[..., 2 * half:]], axis=-1)
     a, b, rest = x[..., :half], x[..., half:2 * half], x[..., 2 * half:]
     c, s = cos[:, None, :], sin[:, None, :]
     return jnp.concatenate([a * c - b * s, b * c + a * s, rest], axis=-1)
@@ -359,8 +390,8 @@ class Attention(nn.Module):
             return jax.lax.dynamic_index_in_dim(cache, pass_index, axis,
                                                 keepdims=False)
 
-        cos, sin = rope_tables(
-            cfg.rope_full if kind == FULL else cfg.rope_sliding, dim, q_pos)
+        rope = cfg.rope_full if kind == FULL else cfg.rope_sliding
+        cos, sin = rope_tables(rope, dim, q_pos)
         store = k_cache.dtype
         if cfg.attn_gate == "element":
             # every head's columns are its query, then its gate
@@ -374,8 +405,8 @@ class Attention(nn.Module):
                         name="q_norm")(q)
             k = RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm,
                         name="k_norm")(k)
-        q = apply_rope(q, cos, sin).astype(self.dtype)
-        k = apply_rope(k, cos, sin).astype(store)
+        q = apply_rope(q, cos, sin, rope.interleaved).astype(self.dtype)
+        k = apply_rope(k, cos, sin, rope.interleaved).astype(store)
         v = lin(kv * dim, "v_proj")(n).reshape(tokens, kv, dim).astype(store)
         real = q_pos < end
         if sequences:
@@ -457,20 +488,29 @@ class LatentUp(nn.Module):
 
 class LatentAttention(nn.Module):
     """The token mixer of a ``"latent"`` layer. Queries go through a normed
-    low-rank latent; a position's keys and values all derive from one
+    low-rank latent (``q_a_proj``, ``q_a_norm``, ``q_b_proj``) or, with
+    ``q_lora_rank`` 0, through one ``q_proj``; a position's keys and values
+    all derive from one
     normed latent ``c`` (``kv_lora_rank``) and one rotated key shared by
     every head (``qk_rope_head_dim``), and those two are all the cache
     holds: ``cache`` is ``(capacity, rank + rope)``.
 
-    Two forms over that cache, by the chunk's length
-    (:func:`latent_form`). *Expanded* (a prefill): every cached position's
+    Three forms over that cache, by the chunk's length and whose rows they
+    are (:func:`latent_form`). *Expanded* (a prefill): every cached position's
     per-head key ``[W_k c | k_rope]`` and value ``W_v c`` are rebuilt
     through ``kv_b_proj`` and attended as any keys and values. *Absorbed*
     (one token): ``q W_k^T`` is a query over the latent itself, so the step
     attends the cache as it lies, one KV head of width ``rank + rope``
     whose values are its first ``rank`` columns, and ``W_v`` is applied to
     each head's attended latent instead of to every position. The same
-    scores and the same sum, in another order."""
+    scores and the same sum, in another order. *Forked* (``sequences``: row
+    ``b`` is sequence ``b``'s one token): absorbed over two ranges under one
+    softmax, ``shared`` the prefill's latents as they lie ``(capacity,
+    width)``, which every sequence attends and none writes, and ``cache``
+    each sequence's own rows ``(sequences, slots, width)`` from position
+    ``forked_at`` on. One KV head serves every head, so the sequences' heads
+    are all query rows of ONE product over the shared latents, read once;
+    ``shared`` is returned behind the cache as it came."""
 
     config: LMConfig
     layer: int
@@ -478,7 +518,8 @@ class LatentAttention(nn.Module):
     quant: bool = False
 
     @nn.compact
-    def __call__(self, n, q_pos, start, end, cache):
+    def __call__(self, n, q_pos, start, end, cache, shared=None,
+                 sequences: bool = False, forked_at=None):
         cfg = self.config
         heads = cfg.num_heads_per_layer[self.layer]
         rank, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
@@ -497,31 +538,52 @@ class LatentAttention(nn.Module):
             return jnp.einsum(spec, a.astype(self.dtype), b,
                               preferred_element_type=f32)
 
+        def turned(x):
+            return apply_rope(x, cos, sin, cfg.rope_full.interleaved)
+
         cos, sin = rope_tables(cfg.rope_full, rope, q_pos)
-        q = lin(heads * (nope + rope), "q_b_proj")(
-            norm("q_a_norm")(lin(cfg.q_lora_rank, "q_a_proj")(n))).reshape(
+        if cfg.q_lora_rank:
+            q = lin(heads * (nope + rope), "q_b_proj")(
+                norm("q_a_norm")(lin(cfg.q_lora_rank, "q_a_proj")(n))
+            ).reshape(tokens, heads, nope + rope)
+        else:       # no query latent: no norm on the query path either
+            q = lin(heads * (nope + rope), "q_proj")(n).reshape(
                 tokens, heads, nope + rope)
-        q_nope, q_rope = q[..., :nope], apply_rope(q[..., nope:], cos, sin)
+        q_nope, q_rope = q[..., :nope], turned(q[..., nope:])
         c, k_rope = jnp.split(lin(rank + rope, "kv_a_proj_with_mqa")(n),
                               [rank], axis=-1)
         row = jnp.concatenate(
-            [norm("kv_a_norm")(c),
-             apply_rope(k_rope[:, None, :], cos, sin)[:, 0]], axis=-1)
-        # written first: a padded row lands beyond ``end``
-        cache = jax.lax.dynamic_update_slice_in_dim(
-            cache, row.astype(cache.dtype), start, 0)
-        slots = jnp.arange(cache.shape[0])
-        k_pos = jnp.where(slots < end, slots, -1)
+            [norm("kv_a_norm")(c), turned(k_rope[:, None, :])[:, 0]],
+            axis=-1)
+        form = latent_form(tokens, sequences)
+        if sequences:
+            # row b is sequence b's one token at ``start``: it goes to its
+            # sequence's own rows, what the prefill left is only read, and
+            # both are the keys of one softmax (as ``Attention`` does)
+            own = cache.shape[1]
+            behind = start - forked_at
+            cache = jax.lax.dynamic_update_slice_in_dim(
+                cache, row[:, None].astype(cache.dtype), behind % own, 1)
+            slots = jnp.arange(shared.shape[0])
+            k_pos = jnp.where(slots < forked_at, slots, -1)
+            own_pos = start - (behind - jnp.arange(own)) % own
+            own_pos = jnp.where(own_pos >= forked_at, own_pos, -1)
+        else:
+            # written first: a padded row lands beyond ``end``
+            cache = jax.lax.dynamic_update_slice_in_dim(
+                cache, row.astype(cache.dtype), start, 0)
+            slots = jnp.arange(cache.shape[0])
+            k_pos = jnp.where(slots < end, slots, -1)
         up = LatentUp(heads, nope + v_dim, name="kv_b_proj")(rank).astype(
             self.dtype)
         w_k, w_v = up[..., :nope], up[..., nope:]
-        form = latent_form(tokens)
-        if form == LATENT_ABSORBED:
+        if form != LATENT_EXPANDED:
             q = jnp.concatenate([dot("thd,rhd->thr", q_nope, w_k), q_rope],
                                 axis=-1).astype(self.dtype)
+        if form == LATENT_ABSORBED:
             keys = cache[:, None, :]
             values = keys[..., :rank]
-        else:
+        elif form == LATENT_EXPANDED:
             q = jnp.concatenate([q_nope, q_rope], axis=-1).astype(self.dtype)
             held = dot("sr,rhd->shd", cache[:, :rank], up)
             keys = jnp.concatenate(
@@ -530,13 +592,23 @@ class LatentAttention(nn.Module):
                                   held.shape[:2] + (rope,))],
                 axis=-1).astype(cache.dtype)
             values = held[..., nope:].astype(cache.dtype)
-        out, _ = attend_positions(q, keys, values, q_pos, k_pos,
-                                  scale=cfg.latent_softmax_scale)
-        ATTENTION.record(form, tokens, cache.shape[0], cache.shape[1])
-        if form == LATENT_ABSORBED:
+        if sequences:
+            # keys the rows as they lie, values their first ``rank`` columns
+            out, _ = attend_two_ranges(
+                q, shared[:, None, :], shared[:, None, :rank],
+                cache[:, :, None, :], cache[:, :, None, :rank], q_pos,
+                k_pos, own_pos, scale=cfg.latent_softmax_scale)
+            ATTENTION.record(form, tokens, shared.shape[0], cache.shape[-1],
+                             own=own)
+        else:
+            out, _ = attend_positions(q, keys, values, q_pos, k_pos,
+                                      scale=cfg.latent_softmax_scale)
+            ATTENTION.record(form, tokens, cache.shape[0], cache.shape[1])
+        if form != LATENT_EXPANDED:
             out = dot("thr,rhd->thd", out, w_v)
         return (lin(n.shape[-1], "o_proj")(
-            out.reshape(tokens, heads * v_dim)), cache)
+            out.reshape(tokens, heads * v_dim)), cache) \
+            + ((shared,) if sequences else ())
 
 
 class Mixed(NamedTuple):
@@ -829,7 +901,8 @@ class DecoderLayer(nn.Module):
             elif kind == LATENT:
                 mixed, *after = LatentAttention(
                     cfg, self.layer, self.dtype, self.quant, name="attn")(
-                        n, q_pos, start, end, *buffers)
+                        n, q_pos, start, end, *buffers,
+                        sequences=sequences, forked_at=forked_at)
             else:
                 mixed, *after = Attention(
                     cfg, self.layer, self.dtype, self.quant, name="attn")(
@@ -910,8 +983,8 @@ class DecoderLM(nn.Module):
         cfg = self.config
         if sequences:
             if not shares_a_step(cfg):
-                raise ValueError("a step of several sequences wants full "
-                                 "and sliding layers and one stream")
+                raise ValueError("a step of several sequences wants full, "
+                                 "sliding or latent layers and one stream")
             q_pos = jnp.full(tokens.shape, start, jnp.int32)
             end, all_logits = start + 1, True
             real = jnp.arange(tokens.shape[0]) < length
@@ -1062,7 +1135,8 @@ def cache_shapes(cfg: LMConfig, capacity: int) -> Dict[str, list]:
     order. An attention layer has ``k`` and ``v``: a full layer holds
     ``capacity`` positions, a sliding layer a ring of its window. A linear
     layer has ``state`` and ``conv``, whatever the capacity. A latent layer
-    has ``latent``: ``capacity`` rows of ``latent_width``. A conv layer has
+    has ``latent``: ``capacity`` rows of ``latent_width`` (no pass axis: a
+    looped stack is full attention). A conv layer has
     ``kept``: its convolution's ``conv_taps - 1`` last inputs, whatever the
     capacity. A model without layers of a kind has none of the kind's
     names. A looped model's ``k`` and ``v`` carry the pass axis in front:

@@ -814,6 +814,19 @@ def render() -> str:
     _scalar(lines, "sdtpu_serving_unet_images_total", "counter",
             "Images decoded to outputs.", s["unet_images"])
     expander = s["expander"]
+    _scalar(lines, "sdtpu_expander_rows_attended_total", "counter",
+            "Cache positions the prompt expander's decode steps' queries "
+            "attended in a layer that keeps every position (keys and "
+            "values, or latents), a sequence at a time.",
+            expander["rows_attended"])
+    shared = expander["rows_read_shared"]
+    _labeled_family(
+        lines, "sdtpu_expander_rows_read_total", "counter",
+        "Cache positions read for them: the shared range before a fork "
+        "once a step for all its sequences, a sequence's own rows once "
+        "each.",
+        [('range="own"', expander["rows_read"] - shared),
+         ('range="shared"', shared)])
     _scalar(lines, "sdtpu_expander_layer_passes_total", "counter",
             "Passes of the whole stack the prompt expander's token steps "
             "ran (a looped model: total_ut_steps a step).",

@@ -32,7 +32,8 @@ drawn from that one row of logits under its own key, the cache is forked
 (cache/kv.py:fork: the prompt's rows stay where the prefill left them, held
 once, and each sequence gets rows of its own for what it decodes) and the
 scan runs over all of them, so a step streams the fixed weights once, each
-distinct expert once and the prompt's keys and values once. Their count is
+distinct expert once and the prompt's keys and values (a latent layer's
+latents) once. Their count is
 padded up to one of cache/kv.py:SEQUENCE_BUCKETS with repeats of the last,
 whose tokens are dropped. One image takes the one-sequence executables
 under the keys they have always had.
@@ -74,14 +75,16 @@ _KEY_DOMAIN = 0x6C6D
 
 
 def rows_of(sequences: int, forked_at: int, steps: int) -> dict:
-    """``rows_attended`` and ``rows_read`` of ``EXPANDER.record``: the
-    positions ``steps`` decode steps' queries attended in a layer that
-    keeps every position, step ``i``'s at ``forked_at + i`` once a
-    sequence, and the positions read for them, where what lies before the
-    fork is read once a step for all sequences."""
-    attended = sequences * (steps * forked_at + steps * (steps + 1) // 2)
-    return {"rows_attended": attended,
-            "rows_read": attended - (sequences - 1) * steps * forked_at}
+    """``rows_attended``, ``rows_read`` and ``rows_read_shared`` of
+    ``EXPANDER.record``: the positions ``steps`` decode steps' queries
+    attended in a layer that keeps every position (keys and values, or
+    latents), step ``i``'s at ``forked_at + i`` once a sequence, and the
+    positions read for them: what lies before the fork (the shared range)
+    once a step for all sequences, a sequence's own rows once each."""
+    shared = steps * forked_at
+    own = sequences * (steps * (steps + 1) // 2)
+    return {"rows_attended": sequences * shared + own,
+            "rows_read": shared + own, "rows_read_shared": shared}
 
 
 class PromptExpander:
@@ -207,6 +210,10 @@ class PromptExpander:
         passes = self.config.total_ut_steps
         # span attributes of a looped model alone
         looped = {"passes": passes} if passes > 1 else {}
+        # and of one whose latent layers share a step: the form a decode
+        # step takes over the cache
+        stepped = {"latent": lm.latent_form(1, sequences=batch > 1)} \
+            if latent and self.shares_a_step else {}
         exits = []        # per executable call of a looped model
         routed = []       # per executable call: (load, none held)
         masked = 0        # padded rows kept out of a recurrence or kept rows
@@ -224,6 +231,8 @@ class PromptExpander:
                 attrs["form"] = delta_rule.form(len(padded))
             if latent:        # the form its attention takes over the cache
                 attrs["latent"] = lm.latent_form(len(padded))
+                if self.shares_a_step:  # whose first tokens the chunk draws
+                    attrs["sequences"] = 1 if keep else live
             # the instruction's chunk yields no token that is kept: it runs
             # at one sequence whatever follows it
             with obs_spans.span("expand.prefill", **attrs):
@@ -249,7 +258,7 @@ class PromptExpander:
             # the bytes made: the prompt's rows stay where they are
             with obs_spans.span("expand.fork", sequences=batch,
                                 bytes=sum(sizes.values()) - copied,
-                                **looped):
+                                **looped, **stepped):
                 cache = kv.forked(
                     cache, self._fork_fn(capacity, batch, own_slots)(cache))
                 jax.block_until_ready(cache)    # fenced, as a prefill is
@@ -276,7 +285,7 @@ class PromptExpander:
                     and all(tok.eos in one for one in made)):
                 break
             with obs_spans.span("expand.decode_chunk", tokens=DECODE_STEPS,
-                                sequences=live, **looped):
+                                sequences=live, **looped, **stepped):
                 cache, token, position, out, step_load, step_none, *read = \
                     decode(params, cache, token, position, key,
                            temperature, *more)

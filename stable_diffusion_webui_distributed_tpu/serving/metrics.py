@@ -383,7 +383,10 @@ class AttentionSites:
     counted when an executable runs. A site of a looped language model
     (models/lm.py: its layers are alike and share ONE trace of a layer an
     executable, so it records one site) carries the passes it runs: ``P4``
-    after its shape."""
+    after its shape. A site whose keys are two ranges named apart (a latent
+    layer's forked step: the prefill's rows, shared, and a sequence's own
+    behind them) carries both: ``T4 S2560+256 D576`` is four sequences'
+    rows over 2 560 shared slots and 256 own ones of width 576."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -391,14 +394,15 @@ class AttentionSites:
 
     def clear(self) -> None:
         with self._lock:
-            #: (path, tokens, context tokens, head_dim, passes) -> sites
+            #: (path, tokens, context tokens, head_dim, passes, own slots)
+            #: -> sites
             self.sites: Dict[tuple, int] = defaultdict(int)  # guarded-by: _lock
 
     def record(self, path: str, t: int, s: int, head_dim: int,
-               passes: int = 1) -> None:
+               passes: int = 1, own: int = 0) -> None:
         with self._lock:
-            self.sites[(path, int(t), int(s), int(head_dim),
-                        int(passes))] += 1
+            self.sites[(path, int(t), int(s), int(head_dim), int(passes),
+                        int(own))] += 1
 
     def summary(self) -> Dict[str, Any]:
         """``{"tiled": n, "xla": m, "by_shape": {"T4096 S4096 D64":
@@ -407,9 +411,10 @@ class AttentionSites:
             sites = dict(self.sites)
         out: Dict[str, Any] = {"tiled": 0, "xla": 0}
         by_shape: Dict[str, Dict[str, int]] = {}
-        for (path, t, s, d, passes), n in sorted(sites.items()):
+        for (path, t, s, d, passes, own), n in sorted(sites.items()):
             out[path] = out.get(path, 0) + n
-            shape = f"T{t} S{s} D{d}" + (f" P{passes}" if passes > 1 else "")
+            shape = (f"T{t} S{s}" + (f"+{own}" if own else "") + f" D{d}"
+                     + (f" P{passes}" if passes > 1 else ""))
             by_shape.setdefault(shape, {})[path] = n
         out["by_shape"] = by_shape
         return out
@@ -457,7 +462,8 @@ class ExpanderStats:
     sequence) and the positions read for them (``rows_read``: what lies
     before a fork is read once a step for all its sequences, so their
     quotient is the queries a row read serves; one sequence counts the same
-    in both), how many
+    in both; ``rows_read_shared`` is the part of them before the fork, the
+    shared range, and the rest the sequences' own rows), how many
     tokens the router
     sent to each expert held here (load and its imbalance), tokens none of
     whose chosen experts is held here, the cache positions the last
@@ -502,6 +508,7 @@ class ExpanderStats:
             self.experts_read = 0      # guarded-by: _lock
             self.rows_attended = 0     # guarded-by: _lock
             self.rows_read = 0         # guarded-by: _lock
+            self.rows_read_shared = 0  # guarded-by: _lock
             self.none_held = 0         # guarded-by: _lock
             #: per expert layer, tokens sent to each held expert
             self.load: List[List[int]] = []  # guarded-by: _lock
@@ -538,6 +545,7 @@ class ExpanderStats:
                prefix_snapshots: int, padded_rows_masked: int,
                residual_streams: int, sinkhorn_iters: int,
                rows_attended: int = 0, rows_read: int = 0,
+               rows_read_shared: int = 0,
                layer_passes: int = 0, exit_pass=(),
                exit_lambda_max: float = 0.0) -> None:
         """``load`` is (expert layers, held experts) counts of one
@@ -555,6 +563,7 @@ class ExpanderStats:
             self.experts_read += int(experts_read)
             self.rows_attended += int(rows_attended)
             self.rows_read += int(rows_read)
+            self.rows_read_shared += int(rows_read_shared)
             self.none_held += int(none_held)
             if len(self.load) != len(rows):
                 self.load = rows
@@ -589,6 +598,7 @@ class ExpanderStats:
                 "experts_read": self.experts_read,
                 "rows_attended": self.rows_attended,
                 "rows_read": self.rows_read,
+                "rows_read_shared": self.rows_read_shared,
                 "tokens_no_held_expert": self.none_held,
                 "expert_tokens": [list(row) for row in self.load],
                 "expert_load_max_over_mean":

@@ -159,12 +159,13 @@ def test_folded_upsample_site_holds_no_gather(one_chip, batch, side, channels,
 
 @pytest.mark.parametrize("precision", ["default", "highest"])
 @pytest.mark.parametrize("d,f", [(3072, 1024), (2048, 512), (3584, 1024),
-                                 (2048, 1536), (2304, 896)])
+                                 (2048, 1536), (2304, 896), (2048, 768)])
 def test_chosen_experts_kernel_compiles_for_v5e(one_chip, d, f, precision):
-    """The five published expert shapes (Laguna-S-2.1's, Qwen3-Next's,
-    Xing4.0's, LFM2's, the widest: two tiles of 768 an expert, and
+    """The six published expert shapes (Laguna-S-2.1's, Qwen3-Next's,
+    Xing4.0's, LFM2's, the widest: two tiles of 768 an expert,
     Mellum2's, whose blocks are the largest: one tile of 896, 24.8 MB
-    double-buffered), 128 held, 10 chosen, bf16; also under benchmarks/verify_reference.py's
+    double-buffered, and kanana-2's, one tile of 768), 128 held, 10 chosen,
+    bf16; also under benchmarks/verify_reference.py's
     ``default_matmul_precision("highest")``, which must not reach the
     kernel's dots."""
     def on_chip(shape, dtype):
@@ -302,6 +303,13 @@ def test_a_forked_decode_chunk_compiles_for_v5e(one_chip, preset):
     ("decode", "sd15_mellum2_expander", 7.6, 8, 64, 23),
     ("decode4", "sd15_mellum2_expander", 7.6, 8, 64, 39),
     ("prefill2048", "sd15_mellum2_expander", 7.6, 0, 1000, 23),
+    # seven expert kernels (the sixth published shape, 2048 x 768, 24
+    # slots a call of four rows) behind eight forked latent attentions:
+    # four sequences donate the one sequence's 23.6 MB of latents (shared,
+    # handed through) and 256 own slots a layer each, 33 MB in all; the
+    # prompt's 64-token chunk in the expanded form over 2 560 latents
+    ("decode4", "sd15_kanana2_expander", 10.15, 7, 160, 32),
+    ("prefill", "sd15_kanana2_expander", 10.15, 0, 400, 23),
 ])
 def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
         one_chip, monkeypatch, which, expander, argument_gb, kernels,
@@ -322,7 +330,8 @@ def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = getattr(configs, expander)().expander
     module = lm.DecoderLM(cfg, dtype=jnp.bfloat16)
-    capacity = 2560 if expander == "sd15_mellum2_expander" else 1024
+    capacity = 2560 if expander in ("sd15_mellum2_expander",
+                                    "sd15_kanana2_expander") else 1024
 
     def on_chip(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

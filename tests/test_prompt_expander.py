@@ -918,7 +918,7 @@ class TestEnginePath:
         assert set(block) == {
             "requests", "tokens_prefilled", "tokens_from_prefix_cache",
             "sequences", "tokens_decoded", "decode_steps", "experts_read",
-            "rows_attended", "rows_read",
+            "rows_attended", "rows_read", "rows_read_shared",
             "tokens_no_held_expert", "expert_tokens",
             "expert_load_max_over_mean", "cache_positions", "state_bytes",
             "prefix_snapshots", "padded_rows_masked", "expert_products",
