@@ -676,207 +676,6 @@ def run_serving(tiny):
     }
 
 
-def run_aot(tiny):
-    """--aot: AOT-artifact + warm-pool cold-start bench (SDTPU_AOT /
-    SDTPU_POOL). Three phases against ONE shared artifact store under a
-    fixed in-checkout SDTPU_AOT_DIR, emptied first:
-
-    - cold arm: fresh engine over an empty store — every stage pays a
-      fresh XLA compile and serializes its executable into the store;
-    - warm arm: ANOTHER fresh engine over the now-populated store. The
-      acceptance gate: zero fresh chunk compiles (every stage hydrates),
-      first image byte-identical to the cold arm's, and time-to-first-
-      image at least 2x faster. The warm arm's time-to-first-image is
-      the headline ``cold_start_seconds`` the ledger tracks;
-    - pool heal: a WarmPool of two residents serving through the
-      dispatcher; one resident is chaos-killed mid-traffic, the pool
-      heals back to target size (timed — spawns hydrate from the same
-      store), the dead resident takes no further checkouts, and every
-      request delivers exactly once (``double_merged_images`` == 0).
-
-    The speedup is real on CPU tiny (XLA compiles dominate the first
-    image even at 64x64) but the absolute seconds are NOT a TPU claim.
-    Each phase gets a fresh XLA persistent-cache dir so the warm arm
-    wins through the artifact store, not XLA's own disk cache. Writes
-    BENCH_aot.json + an "aot" ledger row."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache
-
-    from stable_diffusion_webui_distributed_tpu.fleet import (
-        pool as fleet_pool,
-    )
-    from stable_diffusion_webui_distributed_tpu.models import configs as C
-    from stable_diffusion_webui_distributed_tpu.obs import perf as obs_perf
-    from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
-        GenerationPayload,
-    )
-    from stable_diffusion_webui_distributed_tpu.serving import aot as aot_mod
-    from stable_diffusion_webui_distributed_tpu.serving.bucketer import (
-        ShapeBucketer,
-    )
-    from stable_diffusion_webui_distributed_tpu.serving.dispatcher import (
-        ServingDispatcher,
-    )
-    from stable_diffusion_webui_distributed_tpu.serving.metrics import METRICS
-
-    dev = jax.devices()[0]
-    if tiny or dev.platform == "cpu":
-        ladder, steps, family = [(64, 64)], 4, C.TINY
-    else:
-        ladder, steps, family = [(512, 512)], 20, C.SD15
-    w, h = ladder[0]
-    # fixed directories inside the checkout (git-ignored), emptied first:
-    # a temp name would be a new XLA cache key on every run
-    arms_root = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                             ".jax_cache_aot_arms")
-    shutil.rmtree(arms_root, ignore_errors=True)
-    aot_dir = os.path.join(arms_root, "store")
-    os.makedirs(aot_dir)
-
-    def payload(seed):
-        return GenerationPayload(prompt="bench aot cow", steps=steps,
-                                 width=w, height=h, seed=seed,
-                                 sampler_name="Euler a")
-
-    def fresh_xla_cache(tag):
-        # the one place that moves XLA's disk cache in code, on purpose:
-        # each arm must start with an EMPTY one. reset_cache() makes the
-        # already-initialized cache reopen at the new directory.
-        path = os.path.join(arms_root, f"xla-{tag}")
-        os.makedirs(path)
-        jax.config.update("jax_compilation_cache_dir", path)
-        compilation_cache.reset_cache()
-
-    def arm(name):
-        fresh_xla_cache(name)
-        METRICS.clear()
-        obs_perf.LEDGER.clear()
-        engine = _make_engine(family)
-        t0 = time.time()
-        res = engine.txt2img(payload(seed=7))
-        first_image_s = time.time() - t0
-        s = METRICS.summary()
-        return {
-            "first_image_s": round(first_image_s, 3),
-            "compiles": dict(s["compiles"]),
-            "aot_loads": dict(s["aot_loads"]),
-            "fresh_chunk_compiles": s["compiles"].get("chunk", 0),
-            "aot_hit_rate": obs_perf.LEDGER.summary()["aot_hit_rate"],
-            "image": res.images[0],
-        }
-
-    def pool_phase():
-        fresh_xla_cache("pool")
-        METRICS.clear()
-        obs_perf.LEDGER.clear()
-        pool = fleet_pool.WarmPool(lambda name: _make_engine(family),
-                                   size=2)
-        pool.heal()  # resident-1, resident-2 — hydrate lazily from store
-        with pool._lock:
-            primary = pool._residents["resident-1"]
-        results = {}
-        errs = []
-        with _EnvPatch(SDTPU_POOL="1"):
-            # batches=[1]: every request is its own group, so routing
-            # (not coalescing) decides which resident serves it
-            dispatcher = ServingDispatcher(
-                primary.engine,
-                bucketer=ShapeBucketer(shapes=ladder, batches=[1]),
-                window=0.0, pool=pool)
-
-            def submit(i):
-                try:
-                    results[i] = dispatcher.submit(payload(seed=900 + i))
-                except Exception as e:  # noqa: BLE001 — in the JSON line
-                    errs.append(repr(e))
-
-            def wave(ids):
-                threads = [threading.Thread(target=submit, args=(i,))
-                           for i in ids]
-                for t in threads:
-                    t.start()
-                for t in threads:
-                    t.join()
-
-            wave(range(2))
-            checkouts_at_kill = primary.checkouts_total
-            pool.kill("resident-1")
-            t0 = time.time()
-            healed = pool.heal()
-            heal_s = time.time() - t0
-            wave(range(2, 4))
-            summary = pool.summary()
-        delivered = sum(len(r.images) for r in results.values())
-        if errs:
-            _dump_flightrec("aot")
-        return {
-            "heal_s": round(heal_s, 3),
-            "healed": healed,
-            "requests": 4,
-            "delivered_images": delivered,
-            "double_merged_images": max(0, delivered - 4),
-            "dead_checkouts_after_kill": (primary.checkouts_total
-                                          - checkouts_at_kill),
-            "fresh_chunk_compiles": METRICS.summary()["compiles"]
-            .get("chunk", 0),
-            "pool": summary,
-            "errors": errs,
-        }
-
-    t0 = time.time()
-    with _EnvPatch(SDTPU_PERF="1", SDTPU_AOT="1", SDTPU_AOT_DIR=aot_dir):
-        cold = arm("cold")
-        warm = arm("warm")
-        pool_info = pool_phase()
-        store = aot_mod.get_store()
-        store_stats = store.stats_snapshot()
-        store_ok = bool(store.verify()["ok"])
-    wall = time.time() - t0
-    byte_identical = cold["image"] == warm["image"]
-    for ph in (cold, warm):
-        ph.pop("image")
-    speedup = cold["first_image_s"] / max(warm["first_image_s"], 1e-9)
-    out = {
-        "metric": ("tiny_" if tiny or dev.platform == "cpu" else "")
-        + "aot_cold_start_speedup",
-        "value": round(speedup, 2),
-        "unit": "x (cold first-image / warm first-image)",
-        "vs_baseline": cold["first_image_s"],
-        "cold_start_seconds": warm["first_image_s"],
-        "aot_hit_rate": warm["aot_hit_rate"],
-        "warm_fresh_chunk_compiles": warm["fresh_chunk_compiles"],
-        "byte_identical": int(byte_identical),
-        "pool_heal_seconds": pool_info["heal_s"],
-        "double_merged_images": pool_info["double_merged_images"],
-        "store_stats": store_stats,
-        "store_verified": int(store_ok),
-        "phases": {"cold": cold, "warm": warm, "pool": pool_info},
-        "aot_dir": aot_dir,
-        "bucket": f"{w}x{h}",
-        "wall_s": round(wall, 2),
-        "device": dev.device_kind,
-        "errors": pool_info["errors"],
-    }
-    base = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(base, "BENCH_aot.json"), "w",
-              encoding="utf-8") as f:
-        json.dump(out, f, indent=2, sort_keys=True)
-        f.write("\n")
-    row = _ledger_row("aot", {
-        "cold_start_seconds": warm["first_image_s"],
-        "aot_speedup": round(speedup, 2),
-        "aot_hit_rate": warm["aot_hit_rate"],
-        "warm_fresh_chunk_compiles": warm["fresh_chunk_compiles"],
-        "byte_identical": int(byte_identical),
-        "double_merged_images": pool_info["double_merged_images"],
-        "pool_heal_seconds": pool_info["heal_s"],
-    }, dev.device_kind, tiny, time.time())
-    with open(os.path.join(base, "BENCH_LEDGER.jsonl"), "a",
-              encoding="utf-8") as f:
-        f.write(json.dumps(row, sort_keys=True) + "\n")
-    return out
-
-
 def run_ragged(tiny):
     """--ragged: ragged-dispatch microbench (SDTPU_RAGGED). Three phases
     over one mixed-HEIGHT workload (8 requests, 4 heights, one width):
@@ -2610,10 +2409,6 @@ def run_ledger(tiny):
         serving = run_serving(tiny)
         fleet = run_fleet(tiny)
         watchdog = run_watchdog(tiny)
-    # run_aot appends its own "aot" row (it manages its own env patches
-    # and temp artifact dirs); running it last keeps its per-phase XLA
-    # cache repointing away from the rows above
-    aot = run_aot(tiny)
     recorded_at = time.time()
     rows = [
         _ledger_row("serving", {
@@ -2645,13 +2440,8 @@ def run_ledger(tiny):
         for row in rows:
             f.write(json.dumps(row, sort_keys=True) + "\n")
     print(f"bench: {len(rows)} ledger rows appended to {path} "
-          f"(+1 aot row from run_aot; diff with tools/bench_compare.py)",
-          file=sys.stderr)
-    return {"ledger_path": path, "rows": rows,
-            "aot": {k: aot.get(k) for k in (
-                "cold_start_seconds", "aot_hit_rate",
-                "warm_fresh_chunk_compiles", "byte_identical",
-                "double_merged_images")}}
+          f"(diff with tools/bench_compare.py)", file=sys.stderr)
+    return {"ledger_path": path, "rows": rows}
 
 
 def _dump_flightrec(tag):
@@ -2746,13 +2536,6 @@ def main() -> None:
                          "webhooks, zero delta-stream loss and a "
                          "causally clean fleet timeline; writes "
                          "BENCH_obsplane.json + a ledger row (CPU-safe)")
-    ap.add_argument("--aot", action="store_true",
-                    help="AOT-artifact cold-start bench: cold vs warm "
-                         "engine over one SDTPU_AOT artifact store "
-                         "(byte identity, zero warm compiles, >=2x "
-                         "time-to-first-image) plus a warm-pool "
-                         "kill/heal phase; writes BENCH_aot.json + a "
-                         "ledger row (CPU-safe)")
     ap.add_argument("--ledger", action="store_true",
                     help="run the serving, fleet and watchdog microbenches "
                          "with the perf ledger on and append structural "
@@ -2796,8 +2579,6 @@ def main() -> None:
             print(json.dumps(run_lora(tiny)))
         elif args.ragged:
             print(json.dumps(run_ragged(tiny)))
-        elif args.aot:
-            print(json.dumps(run_aot(tiny)))
         elif args.int8:
             print(json.dumps(run_int8(tiny)))
         else:

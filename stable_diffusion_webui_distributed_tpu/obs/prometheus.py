@@ -421,14 +421,15 @@ def count_lora_switch(mode: str, n: float = 1.0) -> None:
     LORA_SWITCH_COUNTER.inc(n, mode=mode)
 
 
-#: AOT artifact-store events by outcome: ``hit`` (executable
-#: deserialized), ``miss`` (no cell — fresh compile), ``saved`` (fresh
-#: compile persisted back), ``fallback`` (cell present but
-#: fingerprint-mismatched or corrupt — compiled instead, journaled as
-#: ``aot_fallback``). Fed by serving/aot.py through :func:`aot_count`.
+#: Kept-program events by outcome: ``hit`` (executable deserialized),
+#: ``miss`` (no cell — traced), ``saved`` (the traced program kept),
+#: ``fallback`` (cell present but corrupt or unloadable — traced instead,
+#: journaled as ``aot_fallback``), ``refused`` (an artifact that could
+#: not be made or did not load: not kept). Fed by serving/aot.py through
+#: :func:`aot_count`.
 AOT_COUNTER = LabeledCounter(
     "sdtpu_aot_total",
-    "AOT executable artifact events (SDTPU_AOT) by outcome.",
+    "Kept stage program events (serving/aot.py) by outcome.",
     ("outcome",))
 
 

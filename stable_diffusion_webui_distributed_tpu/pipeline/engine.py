@@ -22,6 +22,8 @@ Key properties:
 
 from __future__ import annotations
 
+import dataclasses
+import hashlib
 import threading
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -226,6 +228,8 @@ class Engine:
 
         self._cache: Dict[Tuple, Callable] = {}  # guarded-by: _cache_lock
         self._cache_lock = threading.Lock()
+        #: :meth:`_program_context`, once it has been asked for
+        self._context: Optional[str] = None
         # resident prompt expander (pipeline/expand.py): only a family that
         # has one, loaded with its weights, gets the ``expand`` stage; every
         # other engine ignores the always-on script
@@ -275,37 +279,60 @@ class Engine:
     # -- compiled stage factories ------------------------------------------
 
     def _cached(self, key: Tuple, build: Callable[[], Callable],
-                static_argnums: Tuple[int, ...] = ()) -> Callable:
-        if aot_mod.enabled():
-            # AOT path (SDTPU_AOT): the cell is an AotFunction that
-            # deserializes a persisted executable per call signature
-            # before it ever compiles; compile/aot-load accounting moves
-            # to first-call-per-signature (serving/aot.py), where it can
-            # tell a 200ms artifact hydration from a real XLA compile.
-            with self._cache_lock:
-                fn = self._cache.get(key)
-                if fn is None:
-                    fn = aot_mod.AotFunction(
-                        key, build, static_argnums=static_argnums)
-                    self._cache[key] = fn
-                else:
-                    METRICS.record_cache_hit(key[0])
-            return fn
-
+                static_argnums: Tuple[int, ...] = (),
+                weights: int = 1) -> Callable:
+        """The stage of ``key``, built once. Where a persistent compile
+        cache is placed the stage keeps its programs beside it and a later
+        process loads them instead of tracing (serving/aot.py; the first
+        ``weights`` arguments of the stage are parameter trees); elsewhere
+        it is the plain ``jax.jit`` that ``build`` makes."""
         with self._cache_lock:
             fn = self._cache.get(key)
-            if fn is None:
-                # each build is a fresh jitted executable for this exact
-                # shape key — i.e. one XLA compile at first dispatch; the
-                # serving layer asserts on this counter (compile count,
-                # bucket hit rate) instead of wall-clock. build() only
-                # makes the jit wrapper: the compile's seconds are the
-                # xla.compile span and serving.xla (serving/metrics.py)
-                METRICS.record_compile(key[0])
-                fn = self._cache[key] = build()
-            else:
+            if fn is not None:
                 METRICS.record_cache_hit(key[0])
+                return fn
+            # each build is one stage of this exact shape key: a trace and
+            # an XLA compile at first dispatch, or a load; the serving
+            # layer asserts on this counter (compile count, bucket hit
+            # rate) instead of wall-clock. build() only makes the jit
+            # wrapper: the compile's seconds are the xla.compile span and
+            # serving.xla, which way a program came serving.programs
+            # (serving/metrics.py)
+            METRICS.record_compile(key[0])
+            if aot_mod.store_dir() is None:
+                METRICS.record_traced(key[0])
+                fn = build()
+            else:
+                fn = aot_mod.AotFunction(
+                    key, build, static_argnums=static_argnums,
+                    weights=weights, context=self._program_context())
+            self._cache[key] = fn
         return fn
+
+    def _program_context(self) -> str:
+        """The engine's part of a kept program's id (serving/aot.py): what
+        its stages' functions close over beside their compile keys. The
+        family's and the modules' own hyperparameters (the flax
+        dataclasses' ``repr``: configuration, dtype, attention
+        implementation, quantisation, mesh), the policy's dtypes, the
+        noise schedule's tables and the mesh. Weights are arguments of
+        every stage and are not here."""
+        if self._context is None:
+            def told(value) -> str:     # a table by its bytes
+                if hasattr(value, "shape"):
+                    return hashlib.sha256(
+                        np.asarray(value).tobytes()).hexdigest()
+                return repr(value)
+
+            parts = [self.family, self.policy, self.mesh, self.unet,
+                     self.controlnet_module, self.vae, self.text_encoder,
+                     self.text_encoder_2,
+                     self.expander and self.expander.module]
+            parts += [(f.name, told(getattr(self.schedule, f.name)))
+                      for f in dataclasses.fields(self.schedule)]
+            self._context = hashlib.sha256("\n".join(
+                map(repr, parts)).encode("utf-8")).hexdigest()[:16]
+        return self._context
 
     def executable_keys(self) -> list:
         """Snapshot of the live compiled-stage cache keys — the input to
@@ -415,7 +442,7 @@ class Engine:
             return jax.jit(encode, static_argnums=(4,))
 
         key = ("encode",) if not lora_sig else ("encode", lora_sig)
-        return self._cached(key, build, static_argnums=(4,))
+        return self._cached(key, build, static_argnums=(4,), weights=2)
 
     def _denoise_fn(self, kind: str, sampler_name: str = "", steps: int = 1,
                     width: int = 0, height: int = 0, batch: int = 0,
@@ -435,8 +462,11 @@ class Engine:
         denoise.check(variant)
         unet, controlnet = self._modules_for(prec)
         deps = denoise.Deps(unet, controlnet, self.schedule)
+        # every kind's first argument is the UNet's parameters, but the
+        # pin's, which takes none
         return self._cached(variant.key(),
-                            lambda: denoise.build(variant, deps))
+                            lambda: denoise.build(variant, deps),
+                            weights=0 if kind == "adaptive-pin" else 1)
 
     def _denoise_adaptive(self, payload, x, image_keys, conds, pooleds,
                           width, height, start_step, steps, job,

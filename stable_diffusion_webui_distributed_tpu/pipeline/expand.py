@@ -121,7 +121,8 @@ class PromptExpander:
         return self.engine._cached(
             ("expand_fork", capacity, sequences, own_slots),
             lambda: jax.jit(functools.partial(
-                kv.own_rows, sequences=sequences, slots=own_slots)))
+                kv.own_rows, sequences=sequences, slots=own_slots)),
+            weights=0)
 
     def _decode_fn(self, capacity: int, sequences: int = 1):
         """One image keeps the key and the function it has always had."""

@@ -355,25 +355,16 @@ def _logger():
 #   Unset, each call site keeps its historical default (stitch 5.0,
 #   backend probes 3.0).
 #
-# AOT artifact / warm-pool knobs (serving/aot.py, fleet/pool.py;
-# README "AOT artifacts & warm pools"):
+# Warm-pool knobs (fleet/pool.py; README "Kept programs & warm pools").
+# Kept programs (serving/aot.py) have NO knob: wherever the persistent
+# compile cache is placed (``JAX_COMPILATION_CACHE_DIR``, else
+# runtime/mesh.py:enable_compilation_cache) every ``Engine._cached`` cell
+# loads its executable from ``sdtpu-programs/`` inside that directory
+# before it traces, and keeps what it traces; with no cache placed a
+# stage is the plain ``jax.jit``. Every ``SDTPU_*`` variable as set is
+# part of a kept program's id (many are read at trace time), so changing
+# one makes the next start trace again.
 #
-# - ``SDTPU_AOT`` (flag, default off): AOT executable artifacts. On,
-#   every ``Engine._cached`` cell becomes a load-before-build
-#   dispatcher: the first call per concrete signature deserializes the
-#   stage's compiled executable from the artifact store instead of
-#   tracing + compiling, and a fresh compile (store miss) serializes
-#   its result back. Cells are keyed by the existing compile key + call
-#   signature + a jax/jaxlib/platform/device/topology fingerprint; a
-#   fingerprint mismatch or damaged artifact falls back to a fresh
-#   compile (journaled ``aot_fallback``) — never a wrong executable,
-#   never a crash. Off (the default) ``Engine._cached`` takes its
-#   pre-existing path byte-identically (golden-pinned in
-#   tests/test_aot.py).
-# - ``SDTPU_AOT_DIR`` (path, default ``~/.cache/sdtpu-aot``): artifact
-#   store root — a JSON manifest plus content-addressed ``*.aotx``
-#   files (inspect/verify with ``tools/aot_report.py``). Re-read per
-#   store access so tests and bench phases can repoint it.
 # - ``SDTPU_POOL`` (flag, default off): the warm engine pool
 #   (fleet/pool.py). On, a dispatcher constructed with ``pool=`` checks
 #   each execution out to the least-loaded ready resident; autoscale
@@ -393,6 +384,14 @@ def _logger():
 def read_env(name: str, default: str = "") -> str:
     """The package's only sanctioned raw environment read (EV001)."""
     return os.environ.get(name, default)
+
+
+def env_as_set(prefix: str, names=()) -> list:
+    """``[(name, value), ...]``, sorted: every variable whose name starts
+    with ``prefix`` or is one of ``names``, as set now. What was read at
+    trace time is part of a kept program's id (serving/aot.py)."""
+    return sorted((k, v) for k, v in os.environ.items()
+                  if k.startswith(prefix) or k in names)
 
 
 def env_str(name: str, default: str = "") -> str:

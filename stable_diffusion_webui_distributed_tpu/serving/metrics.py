@@ -29,14 +29,23 @@ is ``summary()["decode"]`` (``rows`` over ``dispatches``, fed by
 upsample sites took is :data:`UPSAMPLE` (``summary()["upsample"]``), fed
 by ``ops/upsample.py``. How often a request's plan met a kept sigma
 ladder or a kept time-id embedding (runtime/kept.py) is :data:`PLAN`
-(``summary()["plan"]``).
+(``summary()["plan"]``). How each stage's program came to be since the
+process started is ``summary()["programs"]``: ``loaded`` from the kept
+programs (serving/aot.py) with the seconds that took (``load_s``), or
+``traced``.
+
+The counters fed at trace time count nothing when a stage's program is
+loaded instead of traced, so what a trace counted is kept beside the
+program (:func:`capture_sites`) and counted again when it is loaded
+(:func:`replay_sites`).
 """
 
 from __future__ import annotations
 
+import contextlib
 import threading
 from collections import defaultdict
-from typing import Any, Dict, List
+from typing import Any, Dict, Iterator, List
 
 
 class DispatchMetrics:
@@ -54,10 +63,14 @@ class DispatchMetrics:
             self.compiles: Dict[str, int] = defaultdict(int)  # guarded-by: _lock
             #: stage-kind -> cache hits (stage already built)
             self.cache_hits: Dict[str, int] = defaultdict(int)  # guarded-by: _lock
-            #: stage-kind -> executables hydrated from AOT artifacts
-            #: (serving/aot.py; a load is NOT a compile — the cold-start
-            #: bench asserts compiles stay 0 while these climb)
+            #: stage-kind -> executables loaded from the kept programs
+            #: (serving/aot.py)
             self.aot_loads: Dict[str, int] = defaultdict(int)  # guarded-by: _lock
+            #: stage-kind -> seconds reading and deserialising them
+            self.aot_load_s: Dict[str, float] = defaultdict(float)  # guarded-by: _lock
+            #: stage-kind -> programs traced and lowered (where programs
+            #: are kept: one an executable; elsewhere one a stage build)
+            self.traced: Dict[str, int] = defaultdict(int)  # guarded-by: _lock
             self.requests = 0  # guarded-by: _lock
             #: request shape already equal to its bucket
             self.bucket_hits = 0  # guarded-by: _lock
@@ -96,9 +109,14 @@ class DispatchMetrics:
         with self._lock:
             self.cache_hits[str(kind)] += 1
 
-    def record_aot_load(self, kind: str) -> None:
+    def record_aot_load(self, kind: str, seconds: float = 0.0) -> None:
         with self._lock:
             self.aot_loads[str(kind)] += 1
+            self.aot_load_s[str(kind)] += float(seconds)
+
+    def record_traced(self, kind: str) -> None:
+        with self._lock:
+            self.traced[str(kind)] += 1
 
     # -- dispatcher-side --------------------------------------------------
 
@@ -175,6 +193,16 @@ class DispatchMetrics:
                 "compiles": dict(self.compiles),
                 "cache_hits": dict(self.cache_hits),
                 "aot_loads": dict(self.aot_loads),
+                "programs": {
+                    "loaded": sum(self.aot_loads.values()),
+                    "traced": sum(self.traced.values()),
+                    "load_s": sum(self.aot_load_s.values()),
+                    "by_kind": {
+                        kind: {"loaded": self.aot_loads.get(kind, 0),
+                               "traced": self.traced.get(kind, 0),
+                               "load_s": self.aot_load_s.get(kind, 0.0)}
+                        for kind in sorted(set(self.aot_loads)
+                                           | set(self.traced))}},
                 "requests": self.requests,
                 "bucket_hits": self.bucket_hits,
                 "bucket_misses": self.bucket_misses,
@@ -258,7 +286,8 @@ class XlaCompileStats:
     def __init__(self) -> None:
         self._lock = threading.Lock()
         #: per thread: ``depth`` of open jaxpr traces, ``compiling`` the
-        #: function whose backend compile is open
+        #: function whose backend compile is open, ``hits`` of the
+        #: persistent cache since the thread started
         self._thread = threading.local()
         self.clear()
 
@@ -280,6 +309,8 @@ class XlaCompileStats:
         if key is None:
             return
         fun = getattr(self._thread, "compiling", None)
+        if key == "cache_hits":
+            self._thread.hits = getattr(self._thread, "hits", 0) + 1
         with self._lock:
             self.cache[key] += 1
             if fun is not None:
@@ -325,6 +356,12 @@ class XlaCompileStats:
     def executables(self, fun_name: str) -> int:
         with self._lock:
             return int(self.functions.get(fun_name, {}).get("executables", 0))
+
+    def cache_hits_on_thread(self) -> int:
+        """Executables the persistent cache has handed this thread: one
+        more after a compile than before it means that compile's was
+        (serving/aot.py asks)."""
+        return getattr(self._thread, "hits", 0)
 
     def summary(self) -> Dict[str, Any]:
         """Totals, the functions the persistent cache missed (``missed``),
@@ -373,6 +410,41 @@ def install_xla_listener() -> None:
         _xla_installed = True
 
 
+#: per thread: the open :func:`capture_sites` list, if any
+_SITES = threading.local()
+
+
+@contextlib.contextmanager
+def capture_sites() -> Iterator[List[list]]:
+    """While open, every count the trace-time counters take on this thread
+    (:data:`ATTENTION`, :data:`UPSAMPLE`, ``EXPANDER``'s products, mixers
+    and convs) is also appended to the list yielded, as JSON-able rows
+    that :func:`replay_sites` counts again."""
+    was = getattr(_SITES, "rows", None)
+    rows: List[list] = []
+    _SITES.rows = rows
+    try:
+        yield rows
+    finally:
+        _SITES.rows = was
+
+
+def _note_site(counter: str, *args: Any) -> None:
+    rows = getattr(_SITES, "rows", None)
+    if rows is not None:
+        rows.append([counter, *args])
+
+
+def replay_sites(rows) -> None:
+    """Count again what a trace counted (rows of :func:`capture_sites`)."""
+    counters = {"attention": ATTENTION.record, "upsample": UPSAMPLE.record,
+                "product": EXPANDER.record_product,
+                "mixer": EXPANDER.record_mixer,
+                "conv": EXPANDER.record_conv}
+    for counter, *args in rows:
+        counters[counter](*args)
+
+
 class AttentionSites:
     """Attention sites by the path they took, counted when a UNet (or
     ControlNet) is traced: ``tiled`` (ops/flash_attention.py), ``xla``
@@ -400,6 +472,8 @@ class AttentionSites:
 
     def record(self, path: str, t: int, s: int, head_dim: int,
                passes: int = 1, own: int = 0) -> None:
+        _note_site("attention", str(path), int(t), int(s), int(head_dim),
+                   int(passes), int(own))
         with self._lock:
             self.sites[(path, int(t), int(s), int(head_dim), int(passes),
                         int(own))] += 1
@@ -438,6 +512,7 @@ class UpsampleSites:
             self.sites: Dict[str, int] = defaultdict(int)  # guarded-by: _lock
 
     def record(self, form: str) -> None:
+        _note_site("upsample", str(form))
         with self._lock:
             self.sites[form] += 1
 
@@ -525,16 +600,19 @@ class ExpanderStats:
 
     def record_product(self, path: str) -> None:
         """One expert layer in one trace took product ``path``."""
+        _note_site("product", str(path))
         with self._lock:
             self.products[path] += 1
 
     def record_mixer(self, path: str) -> None:
         """One stream mixer in one trace took form ``path``."""
+        _note_site("mixer", str(path))
         with self._lock:
             self.mixers[path] += 1
 
     def record_conv(self, form: str) -> None:
         """One short-convolution mixer in one trace, of ``form``."""
+        _note_site("conv", str(form))
         with self._lock:
             self.convs[form] += 1
 

@@ -25,13 +25,12 @@ the executables every adapter bucketed into those cells will share
 (models/lora.py ladder; under SDTPU_LORA_TRACED adapter CONTENT is a
 jit argument, so one all-zero stand-in set per cell covers all of them).
 
-Under ``SDTPU_AOT`` (serving/aot.py) the same sweep becomes a
-HYDRATION pass: every cell already present in the artifact manifest is
-deserialized instead of compiled (seconds, not minutes), only the
-missing cells pay a fresh compile, and each fresh compile back-fills
-the manifest — so the report's ``aot`` block shows loads climbing and
-``stage_builds`` shrinking toward zero as the store converges on the
-serving ladder.
+The sweep places the compile cache, so its stages keep their programs
+beside it (serving/aot.py): on a restart every program the store holds is
+deserialized instead of traced and compiled (seconds, not minutes), only
+the missing ones pay a trace, and each of those back-fills the store. The
+report's ``programs`` block says how many came each way and where they
+are kept.
 """
 
 from __future__ import annotations
@@ -43,6 +42,7 @@ from typing import Dict, List, Optional
 from stable_diffusion_webui_distributed_tpu.runtime.config import (
     env_int, env_str,
 )
+from stable_diffusion_webui_distributed_tpu.serving import aot as aot_mod
 from stable_diffusion_webui_distributed_tpu.serving.bucketer import (
     ShapeBucketer,
 )
@@ -124,7 +124,7 @@ def warmup_engine(engine, bucketer: Optional[ShapeBucketer] = None,
     lora_cells = _warmup_lora_cells()
     summary0 = METRICS.summary()
     before = dict(summary0["compiles"])
-    aot_before = dict(summary0["aot_loads"])
+    programs_before = summary0["programs"]
     t0 = time.monotonic()
     warmed = []
     try:
@@ -152,12 +152,8 @@ def warmup_engine(engine, bucketer: Optional[ShapeBucketer] = None,
     after = summary1["compiles"]
     built = {k: after.get(k, 0) - before.get(k, 0)
              for k in after if after.get(k, 0) != before.get(k, 0)}
-    aot_after = summary1["aot_loads"]
-    hydrated = {k: aot_after.get(k, 0) - aot_before.get(k, 0)
-                for k in aot_after
-                if aot_after.get(k, 0) != aot_before.get(k, 0)}
-    n_loads = sum(hydrated.values())
-    n_fresh = sum(built.values())
+    programs = {k: summary1["programs"][k] - programs_before[k]
+                for k in ("loaded", "traced", "load_s")}
     report = {
         "skipped": False,
         "buckets": warmed,
@@ -167,18 +163,9 @@ def warmup_engine(engine, bucketer: Optional[ShapeBucketer] = None,
         "lora_cells": ["r%ds%d" % c for c in lora_cells if c is not None],
         "stage_builds": built,
         "xla_cache_dir": active_cache,
+        # how the sweep's programs came to be (loaded from the store
+        # beside the compile cache, or traced and kept there)
+        "programs": dict(programs, dir=aot_mod.store_dir()),
         "wall_s": round(time.monotonic() - t0, 2),
     }
-    from stable_diffusion_webui_distributed_tpu.serving import aot as aot_mod
-
-    if aot_mod.enabled():
-        # hydration accounting: which cells came off disk vs paid a
-        # fresh compile (fresh ones back-filled the manifest above)
-        report["aot"] = {
-            "enabled": True,
-            "dir": aot_mod.default_dir(),
-            "hydrated": hydrated,
-            "hit_rate": (n_loads / (n_loads + n_fresh)
-                         if (n_loads + n_fresh) else None),
-        }
     return report

@@ -106,6 +106,24 @@ def pytest_collection_modifyitems(config, items):
         item.add_marker(pytest.mark.slow if slow else pytest.mark.fast)
 
 
+@pytest.fixture(autouse=True)
+def _a_compile_cache_goes_with_the_test_that_placed_it():
+    """A stage keeps its programs wherever the persistent compile cache is
+    placed (serving/aot.py), so a test that places it
+    (``enable_compilation_cache``: the warm-up sweep, the CLI) must not
+    leave it placed for whichever test this worker runs next: that test
+    would load programs an earlier run of the suite kept."""
+    import jax
+
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    if jax.config.jax_compilation_cache_dir != was:
+        from jax.experimental.compilation_cache import compilation_cache
+
+        jax.config.update("jax_compilation_cache_dir", was)
+        compilation_cache.reset_cache()
+
+
 @pytest.fixture(scope="session")
 def devices():
     import jax

@@ -354,17 +354,23 @@ class TestContinuousBatching:
         assert all("Size: 24x32" in t for t in r.infotexts)
         assert r.seeds == [21, 22]
 
-    def test_warmup_prebuilds_ladder(self, engine):
+    def test_warmup_prebuilds_ladder(self, engine, tmp_path, monkeypatch):
+        from stable_diffusion_webui_distributed_tpu.runtime import mesh
         from stable_diffusion_webui_distributed_tpu.serving.warmup import (
             warmup_engine,
         )
 
+        # the sweep places the compile cache, and the programs it keeps go
+        # beside it: in the test's own directory, not the checkout's
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setattr(mesh, "DEFAULT_COMPILE_CACHE", str(tmp_path))
         b = ShapeBucketer(shapes=[(32, 32)], batches=[1])
         report = warmup_engine(engine, b, steps=4, sampler="Euler a")
         assert report["skipped"] is False
         assert report["buckets"] == [(32, 32, 1)]
         assert report["steps"] == 4 and report["sampler"] == "Euler a"
         assert isinstance(report["stage_builds"], dict)
+        assert report["programs"]["dir"].startswith(str(tmp_path))
         # a second sweep over the same ladder builds nothing new
         again = warmup_engine(engine, b, steps=4, sampler="Euler a")
         assert again["stage_builds"] == {}

@@ -1,19 +1,21 @@
 #!/usr/bin/env python
-"""Render + verify the AOT executable artifact store (serving/aot.py).
+"""Render + verify the store of kept stage programs (serving/aot.py).
 
-Reads the manifest under ``SDTPU_AOT_DIR`` (or ``--dir``) and reports
-every cell — stage kind, compile key, artifact size, the runtime
-fingerprint it was built under and whether that fingerprint matches THIS
-process — plus per-kind byte totals, the process-local hit/miss/saved/
-fallback tallies, and the last ``bench.py --aot`` run's store stats when
-a BENCH_aot.json sits next to the repo.
+Reads the manifest in the ``sdtpu-programs`` directory of the persistent
+compile cache (``JAX_COMPILATION_CACHE_DIR``, else the checkout's
+``.jax_cache``: where ``runtime/mesh.py:enable_compilation_cache`` places
+it; or ``--dir``) and reports every cell — stage kind, compile key,
+artifact size, the runtime fingerprint it was built under (jax, the
+backend's build, the package's sources) and whether that fingerprint
+matches THIS process, whether it was refused — plus per-kind byte totals
+and the process-local hit/miss/saved/fallback/refused tallies.
 
     python tools/aot_report.py                      # JSON to stdout
-    python tools/aot_report.py --dir /tmp/aot       # explicit store root
+    python tools/aot_report.py --dir /tmp/xla/sdtpu-programs
     python tools/aot_report.py -o aot.json          # ... or to a file
 
-The verify pass is the gate: every cell's artifact must exist on disk
-with the manifest's content hash, and every ``*.aotx`` file must be
+The verify pass is the gate: every kept cell's artifact must exist on
+disk with the manifest's content hash, and every ``*.aotx`` file must be
 claimed by some cell. Exit code 0 when the store is coherent, 1 on any
 divergence (missing artifact, content-hash mismatch, orphan artifact),
 2 when the store root does not exist.
@@ -34,23 +36,20 @@ from stable_diffusion_webui_distributed_tpu.serving import (  # noqa: E402
 )
 
 
-def _bench_stats(path=None):
-    """The last ``bench.py --aot`` run's store stats, when present."""
-    path = path or os.path.join(REPO, "BENCH_aot.json")
-    try:
-        with open(path, encoding="utf-8") as f:
-            doc = json.load(f)
-    except (OSError, ValueError):
-        return None
-    return {"path": path,
-            "store_stats": doc.get("store_stats"),
-            "cold_start_seconds": doc.get("cold_start_seconds"),
-            "aot_hit_rate": doc.get("aot_hit_rate"),
-            "speedup": doc.get("value")}
+def default_dir():
+    """Where this process would keep its programs: beside the compile
+    cache as placed (``aot.store_dir``), else where
+    ``enable_compilation_cache`` would place it."""
+    from stable_diffusion_webui_distributed_tpu.runtime.mesh import (
+        DEFAULT_COMPILE_CACHE,
+    )
+
+    return aot_mod.store_dir() \
+        or os.path.join(DEFAULT_COMPILE_CACHE, aot_mod.SUBDIR)
 
 
 def build_report(root=None):
-    store = aot_mod.AotStore(root) if root else aot_mod.get_store()
+    store = aot_mod.AotStore(root or default_dir())
     verify = store.verify()
     cells = verify["cells"]
     by_kind = {}
@@ -65,7 +64,6 @@ def build_report(root=None):
                                   == verify["fingerprint_id"])
     report = {
         "root": verify["root"],
-        "enabled": aot_mod.enabled(),
         "runtime_fingerprint": verify["fingerprint"],
         "runtime_fingerprint_id": verify["fingerprint_id"],
         "cells": cells,
@@ -77,21 +75,19 @@ def build_report(root=None):
         "stats": store.stats_snapshot(),
         "ok": verify["ok"],
     }
-    bench = _bench_stats()
-    if bench is not None:
-        report["last_bench"] = bench
     return report
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--dir", default=None,
-                    help="store root (default: SDTPU_AOT_DIR)")
+                    help="store root (default: sdtpu-programs inside the "
+                         "compile cache's directory)")
     ap.add_argument("-o", "--output", default=None,
                     help="write JSON here instead of stdout")
     args = ap.parse_args(argv)
 
-    root = args.dir or aot_mod.default_dir()
+    root = args.dir or default_dir()
     if not os.path.isdir(root):
         print(f"aot_report: store root {root} does not exist",
               file=sys.stderr)

@@ -97,15 +97,6 @@ THRESHOLDS = {
     # stale verdict is a paging/federation regression at any size
     "notify_delivery_rate": ("down", "abs", 0.0),
     "federation_staleness_fp": ("up", "abs", 0.0),
-    # aot rows (bench.py run_aot): cold_start_seconds is the warm arm's
-    # time-to-first-image — it creeping UP means artifact hydration
-    # stopped replacing compiles; aot_hit_rate dropping means cells fell
-    # out of the manifest (fingerprint churn, serialization break); any
-    # fresh chunk compile on the warm arm or double-merged image in the
-    # pool-heal phase is a contract break at any count
-    "cold_start_seconds": ("up", "rel", 0.20),
-    "aot_hit_rate": ("down", "abs", 0.05),
-    "warm_fresh_chunk_compiles": ("up", "abs", 0.0),
     # push control plane rows (bench.py run_obsplane): cursor-resume
     # delta streaming is lossless by contract — ANY lost entry is a
     # protocol break; a misrouted notification (page landing on the warn

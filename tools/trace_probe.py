@@ -24,7 +24,10 @@ chip) and keeps what that run reads and throws away: ``/internal/trace.json``
   ``coalesce_windows``: ``sdtpu_coalesce_window_total`` by ``ended_by``
   for the whole process (PR 50: a group that is full goes at once);
 - ``xla_setup``: ``serving.xla`` as read after warm-up (totals and the ten
-  functions with most seconds), ``xla_window``: what the window added;
+  functions with most seconds), ``xla_window``: what the window added,
+  and ``programs_setup``: ``serving.programs`` then (PR 53: the stages
+  loaded from the store beside the compile cache or traced, with the
+  seconds of loading, by stage kind);
 - with ``--trace 1``: ``annotated_missing`` (spans of the traced request
   that are not on a host plane as ``sdtpu:<name>`` with its id), ``gaps``
   (the device's longest idle gaps in the slice, its head and its tail, each
@@ -336,6 +339,8 @@ def main(argv=None) -> int:
         before, after = (s["serving"]["xla"] for s in statuses[:2])
         out["xla_setup"] = before
         out["xla_window"] = xla_delta(before, after)
+        out["programs_setup"] = statuses[0]["serving"].get("programs")
+        print(f"programs: {json.dumps(out['programs_setup'])}")
     requests = by_request((fetched.get("/internal/trace.json") or [{}])[-1])
     window = {rid: ev for rid, ev in requests.items()
               if rid.startswith("w-")}
