@@ -6,9 +6,10 @@ hands every non-``ok`` request trace here at close time (exported trace
 events, so entries stay plain JSON), and this module attaches the
 correlated log lines captured by ``runtime/logging.py``'s per-request
 index. The ring is bounded (``SDTPU_OBS_FLIGHTREC`` entries, default 16 —
-the same capacity instinct as the GUI log ring) and exposed at
-``/internal/flightrec``; ``bench.py`` dumps it to a JSON file when a run
-dies so the evidence survives the process.
+the same capacity instinct as the GUI log ring). Two read it:
+``/internal/flightrec`` serves it, and ``tools/trace_probe.py --keep-slow``
+prints each ``slow`` entry's tree and, where the host clock caught the
+request while it was still slow (obs/watchdog.py), its ``live`` sample.
 """
 
 from __future__ import annotations
@@ -38,8 +39,11 @@ class FlightRecorder:
     def record(self, request_id: str, reason: str, detail: str,
                events: List[Dict[str, Any]],
                duration_s: float = 0.0,
-               perf: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
+               perf: Optional[Dict[str, Any]] = None,
+               live: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
         """Append one failure entry; returns it (already JSON-plain).
+        ``live`` is the host clock's one sample of the request while it
+        ran (thread stacks, open spans, recent stalls), where it took one.
 
         ``perf`` carries the failing request's device-time attribution
         (padding / compile totals); left ``None`` the recorder pulls
@@ -70,6 +74,7 @@ class FlightRecorder:
             "perf": perf,
             "spans": list(events),
             "logs": lines_for_request(request_id),
+            "live": live,
             # what the detectors saw (satellite: postmortem enrichment)
             # — both None with the SDTPU_ALERTS / SDTPU_TSDB gates off
             "alerts": self._alert_snapshot(),

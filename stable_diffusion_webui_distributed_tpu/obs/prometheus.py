@@ -255,6 +255,8 @@ def clear_histograms() -> None:
         c.clear()
     PRECISION_COUNTER.clear()
     COALESCE_WINDOW_COUNTER.clear()
+    HOST_STALL_COUNTER.clear()
+    GC_PAUSE_COUNTER.clear()
     LORA_SWITCH_COUNTER.clear()
     AOT_COUNTER.clear()
     for c in WORKER_COUNTERS.values():
@@ -404,6 +406,16 @@ COALESCE_WINDOW_COUNTER = LabeledCounter(
     "Coalesce windows a group's leader waited, by what ended the wait "
     "(full/timer).",
     ("ended_by",))
+
+#: Seconds the interpreter was not to be had, by the host clock's lag, and
+#: seconds of garbage collection by generation (obs/watchdog.py's clock
+#: feeds them where it feeds ``serving.host``).
+HOST_STALL_COUNTER = LabeledCounter(
+    "sdtpu_host_stall_seconds_total",
+    "Seconds the host clock woke late by 20 ms or more.", ())
+GC_PAUSE_COUNTER = LabeledCounter(
+    "sdtpu_gc_pause_seconds_total",
+    "Seconds of garbage collection, by generation.", ("generation",))
 
 #: Adapter-set activations by serving mode: ``merged`` — host merge into
 #: the param tree (epoch bump, caches retired); ``traced`` — factor set
@@ -600,6 +612,14 @@ def count_precision(precision: str, n: float = 1.0) -> None:
 def count_coalesce_window(ended_by: str) -> None:
     """One leader's coalesce window, ended by ``full`` or ``timer``."""
     COALESCE_WINDOW_COUNTER.inc(ended_by=ended_by)
+
+
+def count_host_stall(seconds: float) -> None:
+    HOST_STALL_COUNTER.inc(seconds)
+
+
+def count_gc_pause(generation: int, seconds: float) -> None:
+    GC_PAUSE_COUNTER.inc(seconds, generation=generation)
 
 
 def fleet_observe_queue_wait(cls: str, seconds: float) -> None:
@@ -867,6 +887,8 @@ def render() -> str:
 
     lines.extend(PRECISION_COUNTER.render())
     lines.extend(COALESCE_WINDOW_COUNTER.render())
+    lines.extend(HOST_STALL_COUNTER.render())
+    lines.extend(GC_PAUSE_COUNTER.render())
     lines.extend(LORA_SWITCH_COUNTER.render())
     lines.extend(AOT_COUNTER.render())
     for c in FLEET_COUNTERS.values():

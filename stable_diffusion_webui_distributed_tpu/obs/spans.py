@@ -21,8 +21,9 @@ it), every span opened through :func:`request` or :func:`span` is also a
 profiler's clock; with no capture running that costs one flag check. A wait
 that ends inside another context manager's body is opened and closed by hand
 (:func:`open_span` / :func:`close_span`). Intervals recorded after the fact
-(:func:`add_span`) exist only here. The
-store is bounded (``SDTPU_OBS_MAX_REQUESTS`` finished
+(:func:`add_span`) exist only here, as do the stalls the host clock finds
+(obs/watchdog.py: ``host.stall``), which can also name the spans a request
+has OPEN. The store is bounded (``SDTPU_OBS_MAX_REQUESTS`` finished
 traces) and lock-disciplined: one lock, nothing external called while
 holding it. Export is Chrome trace-event JSON ("X" complete events with
 ph/ts/dur/pid/tid), loadable in Perfetto / ``chrome://tracing``.
@@ -34,6 +35,7 @@ import contextlib
 import contextvars
 import hashlib
 import itertools
+import math
 import os
 import statistics
 import threading
@@ -55,10 +57,13 @@ DEFAULT_SLOW_S = 30.0
 #: A request is also slow when its root exceeds SLOW_RATIO x the median of
 #: the last SLOW_WINDOW ``ok`` requests of its class (root name, ``width``,
 #: ``height``, ``steps`` of the root's attrs); never before SLOW_MIN_SAMPLES
-#: of them, never for a root without the three attrs.
+#: of them (three: with the warm-up's two, the first of which holds the
+#: program loads, a window's first request already makes the steady request
+#: the median), never for a root without the three attrs. The host clock
+#: holds an ACTIVE request to the same rule (:meth:`SpanTracer.watch`).
 SLOW_RATIO = 1.5
 SLOW_WINDOW = 32
-SLOW_MIN_SAMPLES = 8
+SLOW_MIN_SAMPLES = 3
 #: classes whose durations are kept (the oldest goes first)
 SLOW_MAX_CLASSES = 64
 
@@ -124,7 +129,7 @@ class RequestTrace:
     """All spans of one request plus its terminal status."""
 
     __slots__ = ("request_id", "name", "attrs", "t0", "dur", "status",
-                 "detail", "spans", "root_id")
+                 "detail", "spans", "root_id", "open", "live")
 
     def __init__(self, request_id: str, name: str,
                  attrs: Dict[str, Any]) -> None:
@@ -137,6 +142,12 @@ class RequestTrace:
         self.detail = ""
         self.spans: List[Span] = []  # appended under TRACER's lock
         self.root_id = next(_IDS)
+        #: the spans open now, by id: :func:`open_span` fills and
+        #: :func:`close_span` empties it (a dict operation each under the
+        #: GIL, no lock); the host clock reads a copy, and leaves its ONE
+        #: sample of a request alive past the slow rule's ratio in ``live``
+        self.open: Dict[int, Span] = {}
+        self.live: Optional[Dict[str, Any]] = None
 
 
 def _span_event(req: RequestTrace, sp: Span) -> Dict[str, Any]:
@@ -179,8 +190,11 @@ class SpanTracer:
         self._active: Dict[str, RequestTrace] = {}  # guarded-by: _lock
         self._done: Deque[RequestTrace] = deque(
             maxlen=max(1, int(max_requests or DEFAULT_MAX_REQUESTS)))  # guarded-by: _lock
-        #: class -> durations of its last ``ok`` requests
+        #: class -> durations of its last ``ok`` requests, and their median
+        #: from SLOW_MIN_SAMPLES on: taken where a duration joins, so the
+        #: host clock's tick compares one float a request
         self._ok_durs: Dict[tuple, Deque[float]] = {}  # guarded-by: _lock
+        self._medians: Dict[tuple, float] = {}  # guarded-by: _lock
 
     # -- store ------------------------------------------------------------
 
@@ -205,6 +219,13 @@ class SpanTracer:
             self._active.clear()
             self._done.clear()
             self._ok_durs.clear()
+            self._medians.clear()
+
+    @staticmethod
+    def _class(req: RequestTrace) -> Optional[tuple]:
+        """The slow rule's class of a request; None without the attrs."""
+        shape = tuple(req.attrs.get(k) for k in ("width", "height", "steps"))
+        return None if None in shape else (req.name,) + shape
 
     def slow_detail(self, req: RequestTrace) -> Optional[str]:
         """Why a finished request counts as slow, or None; the duration of
@@ -213,25 +234,38 @@ class SpanTracer:
             return None
         if req.dur >= self.slow_s:
             return f"e2e {req.dur:.3f}s >= {self.slow_s:.3f}s threshold"
-        shape = tuple(req.attrs.get(k) for k in ("width", "height", "steps"))
-        if None in shape:
+        key = self._class(req)
+        if key is None:
             return None
-        key = (req.name,) + shape
         with self._lock:
             recent = self._ok_durs.get(key)
             if recent is None:
                 if len(self._ok_durs) >= SLOW_MAX_CLASSES:
+                    self._medians.pop(next(iter(self._ok_durs)), None)
                     self._ok_durs.pop(next(iter(self._ok_durs)))
                 recent = self._ok_durs[key] = deque(maxlen=SLOW_WINDOW)
-            if len(recent) >= SLOW_MIN_SAMPLES:
-                median = statistics.median(recent)
-                if req.dur > SLOW_RATIO * median:
-                    return (f"e2e {req.dur:.3f}s > {SLOW_RATIO} x median "
-                            f"{median:.3f}s of the last {len(recent)} ok "
-                            f"{req.name} {shape[0]}x{shape[1]} "
-                            f"{shape[2]} steps")
+            median = self._medians.get(key, math.inf)
+            if req.dur > SLOW_RATIO * median:
+                return (f"e2e {req.dur:.3f}s > {SLOW_RATIO} x median "
+                        f"{median:.3f}s of the last {len(recent)} ok "
+                        f"{req.name} {key[1]}x{key[2]} {key[3]} steps")
             recent.append(req.dur)
+            if len(recent) >= SLOW_MIN_SAMPLES:
+                self._medians[key] = statistics.median(recent)
         return None
+
+    def watch(self, now: float) -> Tuple[List[RequestTrace],
+                                         List[RequestTrace]]:
+        """For the host clock (obs/watchdog.py): the active requests, and
+        those of them alive by ``now`` for longer than the rule lets a
+        finished one take, that hold no sample yet."""
+        with self._lock:
+            active = list(self._active.values())
+            late = [] if self.slow_s <= 0 else [
+                req for req in active if req.live is None
+                and now - req.t0 > SLOW_RATIO * self._medians.get(
+                    self._class(req), math.inf)]
+        return active, late
 
     # -- export -----------------------------------------------------------
 
@@ -324,7 +358,7 @@ def _finish(tr: SpanTracer, req: RequestTrace, error: Optional[str]) -> None:
     if req.status != "ok":
         flightrec.RECORDER.record(
             request_id=req.request_id, reason=req.status, detail=req.detail,
-            duration_s=req.dur, events=tr.events_for(req))
+            duration_s=req.dur, events=tr.events_for(req), live=req.live)
 
 
 def open_span(name: str, t0: Optional[float] = None, **attrs: Any):
@@ -344,6 +378,7 @@ def open_span(name: str, t0: Optional[float] = None, **attrs: Any):
               time.perf_counter() if t0 is None else t0, 0.0,
               threading.get_ident(), attrs)
     token = _CURRENT.set((req, sp.span_id))
+    req.open[sp.span_id] = sp
     ann = _annotate(name, request_id=req.request_id, span_id=sp.span_id)
     return sp, req, token, ann
 
@@ -357,6 +392,7 @@ def close_span(handle) -> None:
         ann.__exit__(None, None, None)
     _CURRENT.reset(token)
     sp.dur = time.perf_counter() - sp.t0
+    req.open.pop(sp.span_id, None)
     TRACER.record(req, sp)
 
 
@@ -386,36 +422,77 @@ def maybe_request(request_id: Optional[str] = None, name: str = "request",
 
 # -- the HTTP exchange around a request ---------------------------------------
 
+class Accept:
+    """``http.accept`` of a connection's first request: from the accept
+    (``stamp``, read on the accept thread) to where ``http.read_parse``
+    begins: the handler thread's start and the standard library's read of
+    the request line and headers. Made as the handler's first act on its
+    own thread: the annotation begins there, the record at the stamp. No
+    request id exists yet: the annotation carries the ``span_id`` alone."""
+
+    __slots__ = ("t0", "started", "span_id", "_ann")
+
+    def __init__(self, stamp: float) -> None:
+        self.t0 = stamp
+        self.started = time.perf_counter()
+        self.span_id = next(_IDS)
+        self._ann = _annotate("http.accept", span_id=self.span_id)
+
+    def close(self) -> None:
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+
+
 class Exchange:
-    """The two intervals of one HTTP exchange that lie outside the root
-    span: ``http.read_parse`` ends where the root starts (the trace, and
-    the request id, exist only from there), ``http.respond`` starts after
-    it has closed. Both are recorded into the request's trace with no
-    parent, beside the root; the finished trace stays in the store, so
-    the export holds them."""
+    """The intervals of one HTTP exchange that lie outside the root span:
+    ``http.between`` (where the exchange found the server empty: from the
+    end of the last exchange in flight, ``between`` seconds before this one
+    began) and ``http.accept`` (a connection's first request only) end
+    where ``http.read_parse`` starts, that ends where the root starts (the
+    trace, and the request id, exist only from there), ``http.respond``
+    starts after it has closed. All are recorded into the request's trace
+    with no parent, beside the root; the finished trace stays in the
+    store, so the export holds them."""
 
-    __slots__ = ("t0", "req", "attrs", "_ann")
+    __slots__ = ("t0", "req", "attrs", "accept", "between", "_ann")
 
-    def __init__(self) -> None:
+    def __init__(self, accept: Optional[Accept] = None,
+                 between: Optional[float] = None) -> None:
         self.t0 = time.perf_counter()
         self.req: Optional[RequestTrace] = None
-        #: of ``http.read_parse`` (the handler notes the body's ``bytes``)
+        #: of ``http.read_parse`` (the handler notes the body's ``bytes``,
+        #: and ``reused`` on a kept connection's later request)
         self.attrs: Dict[str, Any] = {}
+        self.accept, self.between = accept, between
+        if accept is not None:
+            accept.close()
         self._ann = _annotate("http.read_parse")
 
     def adopt(self, req: RequestTrace) -> None:
-        """Called by :func:`request` when the handler mints the root: the
-        read ends here."""
+        """``req`` is the request this exchange carries: its root has just
+        opened, so ``http.read_parse`` ends here."""
         if self.req is not None:
             return      # a second root on this thread is not the exchange's
         self.req = req
+        tid = threading.get_ident()
         sp = Span(next(_IDS), None, "http.read_parse", self.t0,
-                  req.t0 - self.t0, threading.get_ident(), self.attrs)
+                  req.t0 - self.t0, tid, self.attrs)
         if self._ann is not None:
             self._ann.set_metadata(request_id=req.request_id,
                                    span_id=sp.span_id)
         self.close_read()
         TRACER.record(req, sp)
+        acc, began = self.accept, self.t0
+        if acc is not None:
+            began = acc.t0
+            TRACER.record(req, Span(
+                acc.span_id, None, "http.accept", began, self.t0 - began,
+                tid, {"thread_start_ms": (acc.started - began) * 1e3}))
+        if self.between is not None:    # found after the fact: no annotation
+            TRACER.record(req, Span(
+                next(_IDS), None, "http.between", began - self.between,
+                self.between, tid, {}))
 
     def close_read(self) -> None:
         ann, self._ann = self._ann, None
@@ -424,14 +501,18 @@ class Exchange:
 
 
 @contextlib.contextmanager
-def http_exchange() -> Iterator[Optional[Exchange]]:
+def http_exchange(accept: Optional[Accept] = None,
+                  between: Optional[float] = None
+                  ) -> Iterator[Optional[Exchange]]:
     """Around one HTTP handler call (server/api.py ``_dispatch``), from
-    before the body is read until the response is written. Records nothing
-    unless the handler mints a request."""
+    before the body is read until the response is written; ``accept`` is
+    the connection's :class:`Accept` on its first request, ``between`` the
+    seconds the server had been empty. Records nothing unless the handler
+    mints a request."""
     if not TRACER.enabled:
         yield None
         return
-    exchange = Exchange()
+    exchange = Exchange(accept, between)
     token = _EXCHANGE.set(exchange)
     try:
         yield exchange
@@ -493,7 +574,8 @@ def add_span(req: Optional[RequestTrace], name: str, t0: float, dur: float,
              attrs: Optional[Dict[str, Any]] = None,
              parent_id: Optional[int] = None) -> Optional[Span]:
     """Record an already-measured interval into ``req`` from any thread
-    (the coalesce leader records queue waits for its followers)."""
+    (the coalesce leader records queue waits for its followers, the host
+    clock its stalls)."""
     if req is None or not TRACER.enabled:
         return None
     sp = Span(next(_IDS), req.root_id if parent_id is None else parent_id,

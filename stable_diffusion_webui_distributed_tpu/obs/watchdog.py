@@ -1,12 +1,23 @@
-"""Hang watchdog: stall detection for dispatches and remote jobs.
+"""The host clock, and the hang watchdog for dispatches and remote jobs.
 
-The scheduler already *predicts* how long a job should take (the paper's
-benchmark/ETA loop, scheduler/eta.py); nothing watches whether reality
-agrees. A wedged remote worker or a device dispatch stuck in a collective
-just sits there until the 3600s HTTP timeout. This module arms a small
-daemon timer around any operation with a known ETA: if the operation has
-not disarmed the timer after ``SDTPU_WATCHDOG_FACTOR`` x ETA seconds, the
-watchdog
+**On whenever spans are (``SDTPU_OBS``, the default): the clock.** Every
+span and counter of the program lies INSIDE a request's tree. What lies
+under all of them at once has no span: a stopped interpreter (a
+collection, a C call that keeps the GIL, the process descheduled).
+:class:`HostClock` is one ``StoppableDaemon`` a server (``ApiServer``
+starts and stops it) that asks to wake every TICK_S and reads how late it
+woke; a lag of STALL_S is a ``host.stall``, kept in a ring and added to
+the tree of every request active then. The same tick counts the
+collections its ``gc.callbacks`` entry stamped, and takes ONE sample
+(every thread's stack, the open spans, the last stalls) of an active
+request that is alive past the slow rule's ratio (obs/spans.py), which
+the flight recorder keeps as the entry's ``live``. Its ``stats`` are
+``serving.host`` of ``/internal/status`` (serving/metrics.py).
+
+**Gated off by default: ``arm``.** The scheduler already *predicts* how
+long a job should take (scheduler/eta.py); :func:`arm` starts a timer
+thread around one operation with a known ETA, and if the operation has not
+disarmed it after ``SDTPU_WATCHDOG_FACTOR`` x ETA seconds the watchdog
 
 - captures a full thread-stack dump into the flight recorder
   (:mod:`.flightrec`) so the hang site is diagnosable post-mortem,
@@ -16,21 +27,37 @@ watchdog
   abandon the stalled job thread so the slice falls into the existing
   ``_requeue_failed`` path.
 
-Gated off by default: ``SDTPU_WATCHDOG_FACTOR`` <= 0 (the default 0)
-means :func:`arm` returns ``None`` and nothing is spawned, keeping the
-default serving path byte-identical. The arm/disarm shape mirrors
+``SDTPU_WATCHDOG_FACTOR`` <= 0 (the default 0) means :func:`arm` returns
+``None`` and nothing is spawned. The arm/disarm shape mirrors
 ``WorkerNode._start_interrupt_watchdog``.
 """
 
 from __future__ import annotations
 
+import gc
 import sys
 import threading
+import time
 import traceback
-from typing import Callable, Optional
+from collections import deque
+from typing import Any, Callable, Deque, Dict, List, Optional
 
 from ..runtime.config import env_float
 from ..runtime.daemon import StoppableDaemon
+from ..serving.metrics import HostStats
+from . import prometheus, spans
+
+#: the clock asks to wake this often: what it can resolve, at 100 wake-ups
+#: a second of tens of microseconds each
+TICK_S = 0.010
+#: a wake-up this late is a stall: twice the tick, over a loaded host's
+#: scheduling jitter, under the shortest pause worth a line (PR 27: 30 ms)
+STALL_S = 0.020
+#: stalls kept for /internal/trace.json and a live sample: a 40 s window
+#: that stalls more often has its story in the counters
+STALL_RING = 64
+#: stalls a live sample carries: enough to show one under the request
+SAMPLE_STALLS = 8
 
 
 def factor() -> float:
@@ -102,3 +129,90 @@ def _record_stall(request_id: str, name: str, eta_s: float,
         f"{name} exceeded {factor():g}x ETA ({eta_s:.2f}s ETA, waited "
         f"{waited_s:.2f}s); thread stacks:\n{stacks}",
         events=[], duration_s=waited_s)
+
+
+#: whose ``request_id`` the clock's own events carry
+_HOST = spans.RequestTrace("host", "host", {})
+
+
+class HostClock:
+    """See the module docstring. ``tick()`` runs inline in a test, with
+    ``clock`` injected; ``start()`` / ``stop()`` also register and remove
+    the ``gc.callbacks`` entry, so a stopped clock leaves nothing behind."""
+
+    def __init__(self, clock: Callable[[], float] = time.perf_counter) -> None:
+        self._clock = clock
+        self._due: Optional[float] = None
+        self.stats = HostStats()
+        #: the last stalls as ``host.stall`` spans (attrs ``requests``,
+        #: ``spans``), oldest first; only ``tick`` appends
+        self.ring: Deque[spans.Span] = deque(maxlen=STALL_RING)
+        #: (seconds, generation) a collection: ALL the callback shares
+        #: with ``tick`` (deque appends are atomic)
+        self._collections: Deque[tuple] = deque()
+        self._gc_t0 = 0.0
+        self._daemon = StoppableDaemon("host-clock", self.tick, TICK_S,
+                                       immediate=False)
+
+    def start(self) -> "HostClock":
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+        self._due = None
+        self._daemon.start()
+        return self
+
+    def stop(self) -> None:
+        self._daemon.stop()
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        """Takes no lock and calls nothing of the tracer: a collection can
+        begin inside ``SpanTracer.record`` with its lock held."""
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+        else:
+            self._collections.append(
+                (time.perf_counter() - self._gc_t0, info["generation"]))
+
+    def tick(self) -> None:
+        now = self._clock()
+        lag = 0.0 if self._due is None else now - self._due
+        active, late = spans.TRACER.watch(now)
+        stalled = lag >= STALL_S
+        self.stats.ticked(lag if stalled else 0.0)
+        if stalled:
+            prometheus.count_host_stall(lag)
+            innermost = [max(req.open.copy().values(), default=req,
+                             key=lambda sp: sp.t0).name for req in active]
+            self.ring.append(spans.Span(
+                next(spans._IDS), None, "host.stall", self._due, lag,
+                threading.get_ident(),
+                {"requests": [req.request_id for req in active],
+                 "spans": innermost}))
+            for req in active:      # cut to where the request began
+                start = max(self._due, req.t0)
+                spans.add_span(req, "host.stall", start, now - start)
+        while self._collections:
+            seconds, generation = self._collections.popleft()
+            self.stats.collected(generation, seconds)
+            prometheus.count_gc_pause(generation, seconds)
+        for req in late:
+            req.live = self._sample(req, now)
+        self._due = self._clock() + TICK_S
+
+    def _sample(self, req: Any, now: float) -> Dict[str, Any]:
+        """Every thread's stack, the request's open spans (innermost
+        first, each with the thread that opened it) and the last stalls."""
+        spans_open = sorted(req.open.copy().values(), key=lambda sp: -sp.t0)
+        return {"age_ms": (now - req.t0) * 1e3,
+                "open": [{"name": sp.name, "age_ms": (now - sp.t0) * 1e3,
+                          "thread": sp.tid} for sp in spans_open],
+                "stacks": dump_stacks(),
+                "stalls": self.events()[-SAMPLE_STALLS:]}
+
+    def events(self) -> List[Dict[str, Any]]:
+        """The ring as Chrome complete events on the clock's own ``tid``
+        (``request_id`` "host": the process's own row of a by-request
+        reading), for ``/internal/trace.json`` and a live sample."""
+        return [spans._span_event(_HOST, sp) for sp in list(self.ring)]

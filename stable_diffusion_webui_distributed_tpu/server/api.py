@@ -64,6 +64,25 @@ class TextResponse(str):
     content_type = "text/plain; version=0.0.4; charset=utf-8"
 
 
+class _StampingServer(ThreadingHTTPServer):
+    """Stamps a connection on the accept thread, where ``http.accept``
+    begins. ``accepted`` (socket -> stamp) and ``host`` (the host clock's
+    stats) are ``_bind``'s, and None with spans off."""
+
+    accepted = host = None
+
+    def get_request(self):
+        request, address = super().get_request()
+        if self.accepted is not None:
+            self.accepted[request] = time.perf_counter()
+        return request, address
+
+    def shutdown_request(self, request):
+        if self.accepted is not None:   # one no handler took up
+            self.accepted.pop(request, None)
+        super().shutdown_request(request)
+
+
 class ApiServer:
     """One generation node's REST surface.
 
@@ -102,6 +121,7 @@ class ApiServer:
             "CLIP_stop_at_last_layers": 1,
         }
         self._httpd: Optional[ThreadingHTTPServer] = None
+        self.host_clock = None      # obs/watchdog.py's, while serving
         self._busy = threading.Lock()
         self._benchmarking = threading.Lock()
         self.restart_requested = False
@@ -502,6 +522,8 @@ class ApiServer:
             serving["batch_ladder"] = list(self.dispatcher.bucketer.batches)
             serving["eta_overhead"] = self.dispatcher.eta_overhead()
             serving["fleet"] = self.dispatcher.fleet_summary()
+            if self.host_clock is not None:
+                serving["host"] = self.host_clock.stats.summary()
         from stable_diffusion_webui_distributed_tpu.obs import (
             flightrec, spans as obs_spans,
         )
@@ -543,7 +565,10 @@ class ApiServer:
             spans as obs_spans,
         )
 
-        return obs_spans.TRACER.export_chrome()
+        doc = obs_spans.TRACER.export_chrome()
+        if self.host_clock is not None:     # its ring, as ``host.stall``
+            doc["traceEvents"].extend(self.host_clock.events())
+        return doc
 
     def handle_stitched_trace(self) -> Dict[str, Any]:
         """Cross-node merged Chrome trace (obs/stitch.py): the master's
@@ -1026,11 +1051,37 @@ class ApiServer:
                 self.end_headers()
                 return False
 
+            def setup(self):
+                # this thread's first act: http.accept's annotation begins;
+                # the exchange only once finish() is sure to end it
+                stamps = self.server.accepted
+                stamp = stamps.pop(self.request, None) if stamps else None
+                self._accept = stamp and obs_spans.Accept(stamp)
+                super().setup()
+                self._between = stamp and self.server.host.exchange_began(
+                    stamp)
+
+            def finish(self):
+                super().finish()
+                if self._accept is not None:    # no request ever came
+                    self._accept.close()
+                    self.server.host.exchange_ended(time.perf_counter())
+
             def _dispatch(self, method: str):
-                # http.read_parse / http.respond of a request minted in
-                # here: the two ends of the exchange outside its root span
-                with obs_spans.http_exchange() as exchange:
-                    self._route(method, exchange)
+                # what of a request's exchange lies outside its root span
+                host = self.server.host
+                if host is None:        # spans are off
+                    return self._route(method, None)
+                accept, self._accept = self._accept, None
+                between = self._between if accept is not None else \
+                    host.exchange_began(time.perf_counter())    # a kept one's
+                try:
+                    with obs_spans.http_exchange(accept, between) as exchange:
+                        if accept is None and exchange is not None:
+                            exchange.attrs["reused"] = True
+                        self._route(method, exchange)
+                finally:
+                    host.exchange_ended(time.perf_counter())
 
             def _route(self, method: str, exchange):
                 if not self._check_auth():
@@ -1126,11 +1177,20 @@ class ApiServer:
 
         return Handler
 
+    def _bind(self) -> ThreadingHTTPServer:
+        """Bound; with spans on it stamps accepts and the host clock runs."""
+        from stable_diffusion_webui_distributed_tpu.obs import spans, watchdog
+
+        httpd = _StampingServer((self.host, self.port), self.make_handler())
+        self.port = httpd.server_port  # resolves port 0
+        if spans.TRACER.enabled:
+            self.host_clock = watchdog.HostClock().start()
+            httpd.accepted, httpd.host = {}, self.host_clock.stats
+        return httpd
+
     def start(self) -> "ApiServer":
         """Serve in a daemon thread; returns self when the port is bound."""
-        self._httpd = ThreadingHTTPServer((self.host, self.port),
-                                          self.make_handler())
-        self.port = self._httpd.server_port  # resolves port 0
+        self._httpd = self._bind()
         t = threading.Thread(target=self._httpd.serve_forever,
                              name="sdapi-server", daemon=True)
         t.start()
@@ -1140,9 +1200,7 @@ class ApiServer:
     def serve_forever(self) -> None:
         """Blocking serve with SIGINT/SIGTERM cleanup (the reference chains
         handlers that save config before exiting, distributed.py:359-375)."""
-        self._httpd = ThreadingHTTPServer((self.host, self.port),
-                                          self.make_handler())
-        self.port = self._httpd.server_port
+        self._httpd = self._bind()
         previous = {}
 
         def on_signal(signum, frame):
@@ -1170,6 +1228,9 @@ class ApiServer:
             self._httpd.shutdown()
             self._httpd.server_close()
             self._httpd = None
+        if self.host_clock is not None:
+            self.host_clock.stop()
+            self.host_clock = None
 
 
 class ApiError(Exception):

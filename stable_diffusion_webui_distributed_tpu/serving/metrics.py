@@ -238,7 +238,7 @@ class DispatchMetrics:
         out["upsample"] = UPSAMPLE.summary()
         out["expander"] = EXPANDER.summary()
         out["plan"] = PLAN.summary()
-        return out
+        return out     # server/api.py adds "host" beside a host clock
 
 
 #: jax.monitoring duration events of one executable's making -> the key
@@ -724,6 +724,63 @@ class PlanStats:
         return {table: {kind: lookups.get((table, kind), 0)
                         for kind in ("hits", "builds")}
                 for table in ("ladder", "added_cond")}
+
+
+class HostStats:
+    """``serving.host``, the time no request's tree covers; one a host
+    clock (obs/watchdog.py), which feeds ``ticks``, ``stalls`` and the
+    collections. server/api.py feeds ``exchanges`` and, where one began
+    with none in flight, the ms since the last ended (``betweens``)."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self.counts: Dict[str, Any] = dict(  # guarded-by: _lock
+            ticks=0, stalls=0, stall_ms=0.0, stall_ms_max=0.0,
+            gc_pause_ms=0.0, gc_pause_ms_max=0.0,
+            gc_collections={"0": 0, "1": 0, "2": 0}, exchanges=0,
+            betweens=0, between_ms=0.0, between_ms_max=0.0)
+        self._in_flight = 0  # guarded-by: _lock
+        self._idle_since: Any = None  # guarded-by: _lock
+
+    @staticmethod
+    def _add(counts: Dict[str, Any], key: str, ms: float) -> None:
+        counts[key] += ms
+        counts[key + "_max"] = max(counts[key + "_max"], ms)
+
+    def ticked(self, stall_s: float = 0.0) -> None:
+        with self._lock:
+            self.counts["ticks"] += 1
+            if stall_s:
+                self.counts["stalls"] += 1
+                self._add(self.counts, "stall_ms", stall_s * 1e3)
+
+    def collected(self, generation: int, seconds: float) -> None:
+        with self._lock:
+            self.counts["gc_collections"][str(generation)] += 1
+            self._add(self.counts, "gc_pause_ms", seconds * 1e3)
+
+    def exchange_began(self, at: float) -> Any:
+        """The seconds the server had been empty, where it was."""
+        with self._lock:
+            self.counts["exchanges"] += 1
+            self._in_flight += 1
+            since, self._idle_since = self._idle_since, None
+            if since is None or at < since:     # accepted with one in flight
+                return None
+            self.counts["betweens"] += 1
+            self._add(self.counts, "between_ms", (at - since) * 1e3)
+            return at - since
+
+    def exchange_ended(self, at: float) -> None:
+        with self._lock:
+            self._in_flight -= 1
+            if self._in_flight <= 0:
+                self._in_flight, self._idle_since = 0, at
+
+    def summary(self) -> Dict[str, Any]:
+        with self._lock:
+            return dict(self.counts, gc_collections=dict(
+                self.counts["gc_collections"]))
 
 
 #: Process-wide counts of kept-plan lookups (``summary()["plan"]``).

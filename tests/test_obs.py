@@ -7,8 +7,11 @@ serve parseable Prometheus text with the four latency histograms. Spans are
 default-on, so the overhead test pins that recording stays negligible.
 """
 
+import gc
 import json
 import re
+import socket
+import sys
 import threading
 import time
 import urllib.request
@@ -16,7 +19,9 @@ import urllib.request
 import pytest
 
 from stable_diffusion_webui_distributed_tpu.models.configs import TINY
-from stable_diffusion_webui_distributed_tpu.obs import flightrec, prometheus
+from stable_diffusion_webui_distributed_tpu.obs import (
+    flightrec, prometheus, watchdog,
+)
 from stable_diffusion_webui_distributed_tpu.obs import spans as obs_spans
 from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
 from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
@@ -34,7 +39,9 @@ from stable_diffusion_webui_distributed_tpu.serving.bucketer import (
 from stable_diffusion_webui_distributed_tpu.serving.dispatcher import (
     ServingDispatcher,
 )
-from stable_diffusion_webui_distributed_tpu.serving.metrics import METRICS
+from stable_diffusion_webui_distributed_tpu.serving.metrics import (
+    METRICS, HostStats,
+)
 from test_pipeline import init_params
 
 
@@ -530,6 +537,20 @@ class TestSlowAgainstTheMedian:
             assert off.slow_detail(self.finished("txt2img", dur, **sdxl)) \
                 is None
 
+    def test_a_slow_first_request_does_not_blind_the_class(self):
+        """SLOW_MIN_SAMPLES is 3: the warm-up's first request holds the
+        program loads and took five times the rest; the median of the
+        three is still the steady request."""
+        tr = obs_spans.SpanTracer(enabled=True, slow_s=30.0)
+        tiny = dict(width=512, height=512, steps=20)
+        for dur in (5.0, 1.0, 1.01):
+            assert tr.slow_detail(self.finished("txt2img", dur, **tiny)) \
+                is None
+        assert tr.slow_detail(self.finished("txt2img", 1.02, **tiny)) is None
+        assert "median 1.0" in tr.slow_detail(
+            self.finished("txt2img", 1.6, **tiny))
+        assert tr.slow_detail(self.finished("txt2img", 1.0, **tiny)) is None
+
     def test_a_slow_request_is_kept_with_its_tree(self, monkeypatch):
         obs_spans.TRACER.clear()
         flightrec.RECORDER.clear()
@@ -546,6 +567,209 @@ class TestSlowAgainstTheMedian:
         assert {e["name"] for e in entry["spans"]} \
             == {"txt2img", "dispatch.device"}
         assert len(flightrec.RECORDER) == 1
+
+
+# -- the host clock: what no request's tree covers (ISSUE 54) ----------------
+
+def dawdle_in_a_named_function(clock, ticks):
+    for _ in range(ticks):
+        clock.tick()
+        time.sleep(0.01)
+
+
+class TestHostClock:
+    @pytest.fixture()
+    def clean(self, monkeypatch):
+        monkeypatch.setattr(obs_spans.TRACER, "slow_s", 30.0)
+        obs_spans.TRACER.clear()
+        flightrec.RECORDER.clear()
+        prometheus.clear_histograms()
+
+    @pytest.mark.parametrize("lag_ms, stalls", [(5, 0), (30, 1), (200, 1)])
+    def test_a_late_wake_up_is_a_stall(self, clean, lag_ms, stalls):
+        """``tick()`` inline on an injected clock: the lag is the stall, in
+        the block, the ring and the tree of the request active then."""
+        with obs_spans.request("stalled", name="unit"):
+            with obs_spans.span("inner"):
+                now = [time.perf_counter()]
+                clock = watchdog.HostClock(clock=lambda: now[0])
+                clock.tick()        # the first wake-up is due from here
+                now[0] += watchdog.TICK_S + lag_ms / 1e3
+                clock.tick()
+        host = clock.stats.summary()
+        assert host["ticks"] == 2 and host["stalls"] == stalls
+        assert host["stall_ms"] == pytest.approx(lag_ms * stalls)
+        assert host["stall_ms_max"] == pytest.approx(lag_ms * stalls)
+        assert [(round(sp.dur * 1e3), sp.attrs["requests"],
+                 sp.attrs["spans"]) for sp in clock.ring] \
+            == [(lag_ms, ["stalled"], ["inner"])] * stalls
+        tr, spans = tree_of("stalled")
+        assert ("host.stall" in spans) == bool(stalls)
+        if stalls:
+            assert spans["host.stall"].parent_id == tr.root_id
+            assert spans["host.stall"].dur == pytest.approx(lag_ms / 1e3)
+        events = clock.events()
+        assert [e["name"] for e in events] == ["host.stall"] * stalls
+        for e in events:
+            assert_chrome_event(e)
+            assert e["dur"] == pytest.approx(lag_ms * 1e3)
+        assert prometheus.HOST_STALL_COUNTER.total() \
+            == pytest.approx(lag_ms / 1e3 * stalls)
+        assert ("sdtpu_host_stall_seconds_total{} " in prometheus.render()) \
+            == bool(stalls)
+
+    def test_the_thread_counts_a_call_that_keeps_the_gil(self, clean):
+        """The real thread: with the switch interval over it, 0.2 s of
+        bytecode on this thread is 0.2 s no other thread ran."""
+        clock = watchdog.HostClock().start()
+        interval = sys.getswitchinterval()
+        try:
+            time.sleep(0.03)
+            sys.setswitchinterval(0.5)
+            until = time.perf_counter() + 0.2
+            while time.perf_counter() < until:
+                pass
+        finally:
+            sys.setswitchinterval(interval)
+            time.sleep(0.03)
+            clock.stop()
+        host = clock.stats.summary()
+        assert host["stalls"] >= 1 and host["stall_ms_max"] >= 150
+        assert host["ticks"] >= 3
+
+    def test_a_request_is_sampled_once_while_it_is_slow(self, clean,
+                                                        monkeypatch):
+        dumps = []
+        dump_stacks = watchdog.dump_stacks
+        monkeypatch.setattr(watchdog, "dump_stacks",
+                            lambda: dumps.append(1) or dump_stacks())
+        tiny = dict(width=32, height=32, steps=2)
+        clock = watchdog.HostClock()
+        for i in range(obs_spans.SLOW_MIN_SAMPLES + 1):
+            with obs_spans.request(f"quick-{i}", name="txt2img", **tiny):
+                dawdle_in_a_named_function(clock, 1)
+        assert not dumps and not len(flightrec.RECORDER)
+        assert all(t.live is None for t in obs_spans.TRACER.finished())
+        with obs_spans.request("dawdler", name="txt2img", **tiny):
+            with obs_spans.span("dispatch.device"):
+                with obs_spans.span("chunk.fence_wait"):
+                    dawdle_in_a_named_function(clock, 8)
+        assert len(dumps) == 1
+        entry, = flightrec.RECORDER.dump()["entries"]
+        assert entry["request_id"] == "dawdler" and entry["reason"] == "slow"
+        live = entry["live"]
+        assert "dawdle_in_a_named_function" in live["stacks"]
+        assert [sp["name"] for sp in live["open"]] \
+            == ["chunk.fence_wait", "dispatch.device"]
+        assert live["open"][0]["thread"] == threading.get_ident()
+        assert 0 < live["open"][0]["age_ms"] <= live["age_ms"]
+        assert live["stalls"] == clock.events()
+        json.dumps(entry)       # the recorder's entries stay plain JSON
+
+    def test_slow_capture_off_takes_no_sample(self, clean, monkeypatch):
+        monkeypatch.setattr(obs_spans.TRACER, "slow_s", 0.0)
+        clock = watchdog.HostClock()
+        tiny = dict(width=32, height=32, steps=2)
+        for i in range(5):
+            with obs_spans.request(f"off-{i}", name="txt2img", **tiny):
+                dawdle_in_a_named_function(clock, 6 if i == 4 else 1)
+        assert all(t.live is None for t in obs_spans.TRACER.finished())
+
+    def test_a_stall_is_cut_to_where_the_request_began(self, clean):
+        """A request that began inside the stall owns what it overlapped."""
+        now = [time.perf_counter()]
+        clock = watchdog.HostClock(clock=lambda: now[0])
+        clock.tick()
+        time.sleep(2 * watchdog.TICK_S)     # past where the clock was due
+        with obs_spans.request("latecomer", name="unit") as req:
+            now[0] = req.t0 + 0.1
+            clock.tick()
+        _, spans = tree_of("latecomer")
+        assert spans["host.stall"].t0 == req.t0
+        assert spans["host.stall"].dur == pytest.approx(0.1)
+        assert clock.ring[0].dur > 0.1
+
+    def test_a_tick_takes_no_median(self, clean, monkeypatch):
+        """The rule's median is taken where a duration joins its class: a
+        tick compares a float a request, however many are active."""
+        tiny = dict(width=32, height=32, steps=2)
+        clock = watchdog.HostClock()
+        for i in range(obs_spans.SLOW_MIN_SAMPLES):
+            with obs_spans.request(f"seed-{i}", name="txt2img", **tiny):
+                time.sleep(0.002)
+        monkeypatch.setattr(obs_spans.statistics, "median",
+                            lambda values: pytest.fail("a median a tick"))
+        with obs_spans.request("slow-one", name="txt2img", **tiny) as req:
+            time.sleep(0.02)
+            clock.tick()
+            assert req.live is not None and not req.live["open"]
+        with obs_spans.request("other-class", name="txt2img", width=64,
+                               height=64, steps=2) as other:
+            time.sleep(0.02)
+            clock.tick()
+            assert other.live is None
+
+    def test_a_collection_is_counted(self, clean):
+        before = list(gc.callbacks)
+        clock = watchdog.HostClock().start()
+        try:
+            assert len(gc.callbacks) == len(before) + 1
+            with obs_spans.request("collected", name="unit"):
+                gc.collect()
+                time.sleep(0.05)    # a few ticks: the deque is drained
+            # a collection that begins with the tracer's lock held (inside
+            # SpanTracer.record) must end: the callback takes no lock
+            done = threading.Event()
+
+            def collect_under_the_lock():
+                with obs_spans.TRACER._lock:
+                    gc.collect()
+                done.set()
+
+            t = threading.Thread(target=collect_under_the_lock, daemon=True)
+            t.start()
+            t.join(timeout=10)
+            assert done.is_set()
+            time.sleep(0.05)
+        finally:
+            clock.stop()
+        assert gc.callbacks == before
+        assert not any(t.name == "host-clock" for t in threading.enumerate())
+        host = clock.stats.summary()
+        assert host["gc_collections"]["2"] >= 2
+        assert 0 < host["gc_pause_ms_max"] <= host["gc_pause_ms"]
+        assert 'sdtpu_gc_pause_seconds_total{generation="2"}' \
+            in prometheus.render()
+
+    def test_each_clock_counts_for_itself(self, clean):
+        """Two servers in one process: a block holds its own clock's."""
+        now = [time.perf_counter()]
+        one, other = (watchdog.HostClock(clock=lambda: now[0])
+                      for _ in range(2))
+        one.tick()
+        now[0] += 0.1
+        one.tick()
+        other.tick()
+        assert one.stats.summary()["stalls"] == 1 and len(one.ring) == 1
+        assert other.stats.summary()["stalls"] == 0 and not other.ring
+        assert other.stats.summary()["ticks"] == 1
+
+    def test_between_two_exchanges(self):
+        host = HostStats()
+        assert host.exchange_began(10.0) is None    # nothing before it
+        assert host.exchange_began(10.5) is None    # the first is in flight
+        host.exchange_ended(11.0)
+        host.exchange_ended(12.0)           # the last in flight: the stamp
+        assert host.exchange_began(12.1) == pytest.approx(0.1)
+        host.exchange_ended(12.2)
+        assert host.exchange_began(12.225) == pytest.approx(0.025)
+        host.exchange_ended(12.4)
+        # accepted (the stamp) before the last one ended, taken up after
+        assert host.exchange_began(12.3) is None
+        out = host.summary()
+        assert out["exchanges"] == 5 and out["betweens"] == 2
+        assert out["between_ms"] == pytest.approx(125.0)
+        assert out["between_ms_max"] == pytest.approx(100.0)
 
 
 # -- histogram mechanics -----------------------------------------------------
@@ -677,6 +901,90 @@ class TestInternalEndpoints:
         obs = json.loads(status)["obs"]
         assert obs["enabled"] is True
         assert "retained" in obs and "flightrec_entries" in obs
+
+
+    @staticmethod
+    def _host(server):
+        status, _ = TestInternalEndpoints._get(server, "/internal/status")
+        return json.loads(status)["serving"]["host"]
+
+    def test_status_carries_the_host_block(self, server):
+        first = self._host(server)
+        assert set(first) == {
+            "ticks", "stalls", "stall_ms", "stall_ms_max", "gc_pause_ms",
+            "gc_pause_ms_max", "gc_collections", "exchanges", "betweens",
+            "between_ms", "between_ms_max"}
+        assert set(first["gc_collections"]) == {"0", "1", "2"}
+        time.sleep(0.1)
+        second = self._host(server)
+        assert second["exchanges"] == first["exchanges"] + 1
+        assert second["betweens"] == first["betweens"] + 1
+        assert 95 <= second["between_ms"] - first["between_ms"] < 2000
+        assert second["ticks"] > first["ticks"]
+        body, _ = self._get(server, "/internal/metrics")
+        assert "# TYPE sdtpu_host_stall_seconds_total counter" in body
+        assert "# TYPE sdtpu_gc_pause_seconds_total counter" in body
+
+    def test_a_refused_connection_leaves_nothing(self, server):
+        """Stamped on the accept thread, taken up by no handler: the stamp
+        goes with the socket, and no exchange began that could not end."""
+        httpd = server._httpd
+        before = self._host(server)
+        httpd.verify_request = lambda request, address: False
+        try:
+            with socket.create_connection(("127.0.0.1", server.port),
+                                          timeout=10) as conn:
+                assert conn.recv(1) == b""      # the server closed it
+        finally:
+            del httpd.verify_request
+        after = self._host(server)
+        assert not httpd.accepted
+        # the second status read alone, and it found the server empty
+        assert after["exchanges"] == before["exchanges"] + 1
+        assert after["betweens"] == before["betweens"] + 1
+
+    def test_one_clock_however_many_requests(self, server):
+        def clocks():
+            return [t for t in threading.enumerate()
+                    if t.name == "host-clock"]
+
+        seen = []
+        posts = [threading.Thread(target=self._post, args=(
+            server, "/sdapi/v1/txt2img",
+            {"prompt": "cow", "steps": 2, "width": 32, "height": 32,
+             "seed": 20 + i})) for i in range(4)]
+        for t in posts:
+            t.start()
+        while any(t.is_alive() for t in posts):
+            seen.append(len(clocks()))
+            time.sleep(0.005)
+        for t in posts:
+            t.join(timeout=60)
+        assert set(seen) == {1}
+        callbacks = list(gc.callbacks)
+        server.stop()
+        assert not clocks() and len(gc.callbacks) == len(callbacks) - 1
+
+    def test_spans_off_nothing_runs(self, engine, monkeypatch):
+        from stable_diffusion_webui_distributed_tpu.server.api import (
+            ApiServer,
+        )
+
+        monkeypatch.setattr(obs_spans.TRACER, "enabled", False)
+        callbacks = list(gc.callbacks)
+        threads = {t.name for t in threading.enumerate()}
+        srv = ApiServer(engine, state=engine.state,
+                        host="127.0.0.1", port=0).start()
+        try:
+            status, _ = self._get(srv, "/internal/status")
+            assert "host" not in json.loads(status)["serving"]
+            assert gc.callbacks == callbacks and srv.host_clock is None
+            assert srv._httpd.accepted is None
+            assert {t.name for t in threading.enumerate()} - threads \
+                <= {"sdapi-server"} | {t.name for t in threading.enumerate()
+                                       if "process_request" in t.name}
+        finally:
+            srv.stop()
 
 
 # -- flight recorder ---------------------------------------------------------

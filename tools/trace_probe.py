@@ -28,6 +28,18 @@ chip) and keeps what that run reads and throws away: ``/internal/trace.json``
   and ``programs_setup``: ``serving.programs`` then (PR 53: the stages
   loaded from the store beside the compile cache or traced, with the
   seconds of loading, by stage kind);
+- ``host`` (PR 54; printed as the ``host:`` line): what no request's tree
+  covers, over the window: ``serving.host`` of ``/internal/status`` after
+  the window less before it (``stalls``, ``stall_ms``: the host clock woke
+  late, obs/watchdog.py; ``gc_pause_ms`` and ``gc_collections``;
+  ``exchanges``, ``betweens``, ``between_ms``), ``gc_pause_ms_by_generation``
+  (``sdtpu_gc_pause_seconds_total`` over the same two reads), the largest
+  single readings where the window raised them (``*_ms_max``; None: the
+  set-up's stands), ``stalls_in_window`` (the clock's ring, the
+  ``host.stall`` events of ``/internal/trace.json``, from the window's
+  first request on), ``http_accept_ms`` and ``http_between_ms`` (the
+  medians of ``http.accept`` and of ``http.between``, the gap a request
+  that found the server empty has beside its root);
 - with ``--trace 1``: ``annotated_missing`` (spans of the traced request
   that are not on a host plane as ``sdtpu:<name>`` with its id), ``gaps``
   (the device's longest idle gaps in the slice, its head and its tail, each
@@ -42,10 +54,14 @@ chip) and keeps what that run reads and throws away: ``/internal/trace.json``
   ``slow`` (obs/spans.py: 1.5 x the running median of its class, or
   ``SDTPU_OBS_SLOW_S``) as a tree whose rows end with the span's excess
   over the median tree (its ms less the median over the window's requests
-  of the name's summed ms, shared among the name's spans), printed too;
+  of the name's summed ms, shared among the name's spans), printed too,
+  with its ``live`` sample where the host clock caught it while it was
+  still slow (its age and open spans, then every thread's stack, the one
+  that owns its innermost open span first, and the stalls before it);
   and ``timeline``, every exchange's ms and the ms until the next one
-  starts, the rows that stand out printed: a window that lost time lost it
-  inside a request or between two.
+  starts, then the clock's stalls that began in that stretch (``[ms into
+  it, ms long]``), the rows that stand out printed: a window that lost time
+  lost it inside a request or between two.
 
 ``--prepared`` runs a cell of ``benchmarks/prepared.json`` (built, not
 admitted: ``refiner_img2img``) from a scratch copy of the manifest under
@@ -61,6 +77,7 @@ import collections
 import io
 import json
 import os
+import re
 import shutil
 import statistics
 import sys
@@ -68,6 +85,9 @@ import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 OWN = ("request_id", "span_id", "parent_id")
+#: found after the fact (obs/spans.py add_span / add_child, an exchange's
+#: gap): never an annotation with the store's span id
+AFTER_THE_FACT = ("xla.compile", "host.stall", "http.between")
 
 
 class Tee(io.TextIOBase):
@@ -187,9 +207,13 @@ def read_xplane(path: str, traced_events: list, summary: dict,
                                   stats.get("span_id"),
                                   (plane.id, line.id)))
     have = {(s[3], s[4]) for s in spans}
-    missing = sorted({e["name"] for e in traced_events
-                      if (e["args"]["request_id"], e["args"]["span_id"])
-                      not in have})
+    # no request id exists while http.accept is open: by span id alone
+    have |= {(None, s[4]) for s in spans}
+    missing = sorted({
+        e["name"] for e in traced_events
+        if e["name"] not in AFTER_THE_FACT and (
+            None if e["name"] == "http.accept" else e["args"]["request_id"],
+            e["args"]["span_id"]) not in have})
 
     def owner(start, end):
         """{ms, owner: the shortest program span that holds the whole
@@ -239,6 +263,49 @@ def read_xplane(path: str, traced_events: list, summary: dict,
     return out
 
 
+def host_block(statuses: list, gc_seconds: list, stalls: list,
+               window: dict, medians: dict):
+    """The ``host`` entry (module docstring), printed as the ``host:``
+    line; None where the program's status has no ``serving.host``."""
+    before, after = ((s.get("serving") or {}).get("host")
+                     for s in (statuses + [{}, {}])[:2])
+    if not before or not after:
+        return None
+    out = {k: after[k] - before[k] for k in after
+           if not k.endswith("_max") and k != "gc_collections"}
+    out["gc_collections"] = {g: n - before["gc_collections"][g]
+                             for g, n in after["gc_collections"].items()}
+    out["gc_pause_ms_by_generation"] = {
+        key[0]: (s - gc_seconds[0].get(key, 0.0)) * 1e3
+        for key, s in sorted(gc_seconds[1].items())}
+    out.update({k: after[k] if after[k] > before[k] else None
+                for k in after if k.endswith("_max")})
+    first = min((e["ts"] for ev in window.values() for e in ev), default=0.0)
+    out["stalls_in_window"] = [
+        {"ms": e["dur"] / 1e3, "at_ms": (e["ts"] - first) / 1e3,
+         "requests": e["args"]["requests"], "spans": e["args"]["spans"]}
+        for e in stalls if e["ts"] >= first]
+    out["http_accept_ms"] = medians.get("http.accept")
+    out["http_between_ms"] = medians.get("http.between")
+    print("host: " + json.dumps({k: round(v, 3) if isinstance(v, float)
+                                 else v for k, v in out.items()}))
+    return out
+
+
+def print_live(live: dict) -> None:
+    """A kept request's sample, taken while it was still slow."""
+    print(f"  live at {live['age_ms']:.1f} ms, open: " + ", ".join(
+        f"{sp['name']} ({sp['age_ms']:.1f} ms)" for sp in live["open"]))
+    for stall in live["stalls"]:
+        print(f"    stall {stall['dur'] / 1e3:.1f} ms under "
+              f"{stall['args']['spans']}")
+    owner = live["open"][0]["thread"] if live["open"] else None
+    stacks = re.split(r"(?m)^(?=Thread )", live["stacks"])
+    # the thread that owns the innermost open span first (a stable sort)
+    for stack in sorted(stacks, key=lambda st: f"(ident={owner})" not in st):
+        print("    " + stack.rstrip().replace("\n", "\n    "))
+
+
 def slow_entries(medians: dict) -> list:
     """The flight recorder's ``slow`` entries, each with its tree; a row
     ends with the span's excess over the median tree (a name that occurs
@@ -253,17 +320,21 @@ def slow_entries(medians: dict) -> list:
         rows = [row + [row[2] - medians.get(row[1], 0.0) / times[row[1]]]
                 for row in tree(entry["spans"])]
         out.append({"request_id": entry["request_id"],
-                    "detail": entry["detail"], "tree": rows})
+                    "detail": entry["detail"], "tree": rows,
+                    "live": entry.get("live")})
         print(f"slow: {entry['request_id']}: {entry['detail']}")
         for depth, name, ms, self_ms, _attrs, excess in rows:
             print(f"  {'  ' * depth}{name}  {ms:.2f} ms  "
                   f"(self {self_ms:.2f}, over the median {excess:+.2f})")
+        if entry.get("live"):
+            print_live(entry["live"])
     return out
 
 
-def timeline(window: dict) -> list:
+def timeline(window: dict, stalls: list) -> list:
     """[[request id, ms of its exchange (its first span's start to its last
-    span's end), ms until the next exchange starts]] in time order: of a
+    span's end), ms until the next exchange starts, the host clock's stalls
+    that began before the next one did]] in time order: of a
     window that lost time it says whether a request stretched (which the
     recorder's rule is there to keep) or the time lies BETWEEN requests,
     where no span of the program is alive. Printed: the medians and every
@@ -271,15 +342,19 @@ def timeline(window: dict) -> list:
     ends = sorted((min(e["ts"] for e in ev),
                    max(e["ts"] + e["dur"] for e in ev), rid)
                   for rid, ev in window.items())
-    rows = [[rid, (end - start) / 1e3, (nxt[0] - end) / 1e3]
+    rows = [[rid, (end - start) / 1e3, (nxt[0] - end) / 1e3,
+             [[(e["ts"] - start) / 1e3, e["dur"] / 1e3] for e in stalls
+              if start <= e["ts"] < nxt[0]]]
             for (start, end, rid), nxt in zip(ends, ends[1:])]
     if rows:
         took, gap = (statistics.median(r[i] for r in rows) for i in (1, 2))
         print(f"timeline: {len(rows)} exchanges, median {took:.1f} ms, "
               f"then {gap:.1f} ms to the next")
-        for rid, ms, after in rows:
-            if ms > 1.2 * took or after > gap + 0.2 * took:
-                print(f"  {rid}: {ms:.1f} ms, then {after:.1f} ms")
+        for rid, ms, after, stalled in rows:
+            if ms > 1.2 * took or after > gap + 0.2 * took or stalled:
+                print(f"  {rid}: {ms:.1f} ms, then {after:.1f} ms" + "".join(
+                    f"; stall {long:.1f} ms at {at:.1f}"
+                    for at, long in stalled))
     return rows
 
 
@@ -298,6 +373,7 @@ def main(argv=None) -> int:
 
     import benchmarks.run as run
     from benchmarks.harness import loadgen, trace_reduce
+    from stable_diffusion_webui_distributed_tpu.obs import prometheus
 
     fetched: dict = {}
     get_json = loadgen.get_json
@@ -305,6 +381,10 @@ def main(argv=None) -> int:
     def keeping_get_json(base, route):
         out = get_json(base, route)
         fetched.setdefault(route, []).append(out)
+        if route == "/internal/status":     # what the block holds by now
+            gc_seconds = getattr(prometheus, "GC_PAUSE_COUNTER", None)
+            fetched.setdefault("gc_seconds", []).append(
+                gc_seconds.snapshot() if gc_seconds else {})
         return out
 
     kept = tempfile.mkdtemp(prefix="probe-xplane-")
@@ -362,8 +442,6 @@ def main(argv=None) -> int:
         if statuses else None
     print(f"plan: {json.dumps(out['plan_attrs'])} "
           f"{json.dumps(out['plan_counts'])}")
-    from stable_diffusion_webui_distributed_tpu.obs import prometheus
-
     out["dispatch_attrs"] = attr_counts(window, ("ended_by", "requests"))
     out["coalesce_windows"] = {
         key[0]: n for key, n in
@@ -373,9 +451,15 @@ def main(argv=None) -> int:
     if untraced:
         last = max(untraced, key=lambda rid: int(rid[2:]))
         out["tree"] = tree(untraced[last])
+    stalls = requests.get("host", [])
+    # an exchange begins at its accept: the gap before it is the row above's
+    exchanges = {rid: [e for e in ev if e["name"] != "http.between"]
+                 for rid, ev in window.items()}
+    out["host"] = host_block(statuses, fetched.get("gc_seconds", []), stalls,
+                             exchanges, out["span_median_ms"])
     if args.keep_slow:
         out["slow"] = slow_entries(out["span_median_ms"])
-        out["timeline"] = timeline(window)
+        out["timeline"] = timeline(exchanges, stalls)
     tag = args.tag or f"{args.workload}-{args.seed}-t{args.trace}"
     path = os.path.join(REPO, "chiprun_out", "probe", tag + ".json")
     os.makedirs(os.path.dirname(path), exist_ok=True)
