@@ -56,10 +56,11 @@ use are a position a pass a layer.
 
 from __future__ import annotations
 
+import functools
 import math
 import threading
 from collections import OrderedDict
-from typing import Dict, Sequence, Tuple
+from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -126,6 +127,18 @@ def fork(cache: Dict, sequences: int, own_slots: int = 0) -> Dict:
     return forked(cache, own_rows(cache, sequences, own_slots))
 
 
+def copy_tree(cache: Dict) -> Dict:
+    """Every buffer of ``cache`` anew. Jitted whole it is ONE dispatch a
+    snapshot where ``tree_map(jnp.copy, ...)`` called eagerly is one a
+    buffer, each a few hundred microseconds of the host's with the device
+    idle for a copy that takes the device microseconds."""
+    return jax.tree_util.tree_map(jnp.copy, cache)
+
+
+#: for a manager whose maker hands it no executable of its own
+_COPY = jax.jit(copy_tree)
+
+
 def state_bytes(config, capacity: int, dtype, sequences: int = 1,
                 own_slots: int = 0) -> Dict[str, int]:
     """Bytes the caches of ``sequences`` sequences take at ``capacity``,
@@ -134,7 +147,14 @@ def state_bytes(config, capacity: int, dtype, sequences: int = 1,
     float32. Several sequences are a :func:`fork` of one: every buffer
     once and ``own_slots`` rows of it a sequence.
     Full and sliding are always named; linear, latent and conv where the
-    model has such layers."""
+    model has such layers. A request asks for the sizes of its model at
+    its capacity, the same as the request before it: kept by argument."""
+    return dict(_state_bytes(config, capacity, dtype, sequences, own_slots))
+
+
+@functools.lru_cache(maxsize=64)
+def _state_bytes(config, capacity: int, dtype, sequences: int,
+                 own_slots: int) -> Dict[str, int]:
     shapes = {name: iter(rows)
               for name, rows in lm.cache_shapes(config, capacity).items()}
 
@@ -158,9 +178,15 @@ class KVCacheManager:
     """Hands out caches of one model at bucketed capacities, keeps the
     snapshots of instruction prefixes, and counts what is in use."""
 
-    def __init__(self, config, dtype) -> None:
+    def __init__(self, config, dtype,
+                 copier: Optional[Callable[[int], Callable]] = None) -> None:
+        """``copier(capacity)`` is :func:`copy_tree` as one executable for
+        the caches of that capacity (the expander's comes through the
+        engine's cache of stages, so a warm start loads it); without one
+        the manager jits its own."""
         self.config = config
         self.dtype = dtype
+        self._copier = copier or (lambda capacity: _COPY)
         self._lock = threading.Lock()
         #: (prefix ids, capacity) -> snapshot at the prefix's last token
         self._prefixes: "OrderedDict[Tuple, Dict]" = OrderedDict()  # guarded-by: _lock
@@ -180,12 +206,12 @@ class KVCacheManager:
                 self.prefix_misses += 1
         if held is None:
             return lm.empty_cache(self.config, capacity, self.dtype), 0
-        return jax.tree_util.tree_map(jnp.copy, held), len(key[0])
+        return self._copier(capacity)(held), len(key[0])
 
     def keep_prefix(self, prefix: Sequence[int], capacity: int,
                     cache: Dict) -> None:
         """Keeps a copy of ``cache`` as the state after ``prefix``."""
-        copy = jax.tree_util.tree_map(jnp.copy, cache)
+        copy = self._copier(capacity)(cache)
         with self._lock:
             self._prefixes[(tuple(prefix), capacity)] = copy
             while len(self._prefixes) > MAX_PREFIXES:

@@ -26,7 +26,7 @@ import dataclasses
 import hashlib
 import threading
 import time
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +82,43 @@ def _embedded_time_ids(ids, rows: int, dim: int):
         return time_id_embedding(tid, dim)
 
     return _TIME_IDS.get((tuple(ids), rows, dim), build)
+
+
+class RequestPlan(NamedTuple):
+    """What a txt2img range settles before its first group (span
+    ``request.plan``)."""
+
+    sigmas: jax.Array
+    controls: tuple
+    refiner: Optional["Engine"]
+
+
+class RangePlan(NamedTuple):
+    """The part of a denoise range's plan (span ``denoise.plan``) that
+    reads the payload's settings alone."""
+
+    sc: Any             # stepcache.resolve
+    prec: Any           # precision_mod.resolve
+    cfg_stop: int       # the step-cache cutoff sigma's step on the ladder
+
+
+class Drawn(NamedTuple):
+    """The first group of a txt2img range as far as it reads nothing of the
+    prompt's text: the seed, the size, the sampler, the step count. An
+    expanded request makes it under the expander's first decode chunk
+    (``Engine._draw_ahead``, span ``expand.ahead``), when the device is
+    busy and the engine's thread has nothing to do but wait;
+    ``_run_txt2img`` and ``_denoise_range_timed`` then take from it what
+    they would have made themselves, by the same functions, with the
+    device idle. A value handed from one to the next, never kept."""
+
+    group: tuple            # (first image, rows, width, height): its use
+    plan: RequestPlan
+    x: jax.Array
+    keys: jax.Array
+    cfg: jax.Array
+    carry: kd.Carry
+    range_plan: RangePlan
 
 
 class Engine:
@@ -1202,25 +1239,71 @@ class Engine:
         self._adaptive_incomplete = False
         expansion = prompt_expansion_args(payload) \
             if self.expander is not None else None
+        drawn = account = None
         if expansion is not None:
             count = payload.total_images if count is None else count
-            self._expand_prompts(payload, expansion, start_index, count)
-        if payload.all_prompts and payload.context_chunks is None:
-            # full-request entry (a sub-range over HTTP arrives with the
-            # master's value): pin the request-wide context length so
-            # group membership can't change an image's conditioning
-            payload.context_chunks = self.request_context_chunks(payload)
-        self._apply_prompt_loras(payload)
-        count = payload.total_images if count is None else count
-        with obs_spans.span("generate_range", job=job,
-                            start=int(start_index), count=int(count),
-                            size=f"{payload.width}x{payload.height}"):
-            if payload.init_images:
-                return self._run_img2img(payload, start_index, count, job)
-            return self._run_txt2img(payload, start_index, count, job)
+            drawn, account = self._expand_prompts(
+                payload, expansion, start_index, count)
+        try:
+            if payload.all_prompts and payload.context_chunks is None:
+                # full-request entry (a sub-range over HTTP arrives with
+                # the master's value): pin the request-wide context length
+                # so group membership can't change an image's conditioning
+                payload.context_chunks = self.request_context_chunks(payload)
+            self._apply_prompt_loras(payload)
+            count = payload.total_images if count is None else count
+            with obs_spans.span("generate_range", job=job,
+                                start=int(start_index), count=int(count),
+                                size=f"{payload.width}x{payload.height}"):
+                if payload.init_images:
+                    if account is not None:     # where it always ran
+                        account()
+                    return self._run_img2img(payload, start_index, count,
+                                             job)
+                return self._run_txt2img(payload, start_index, count, job,
+                                         drawn=drawn, account=account)
+        finally:
+            # an expansion's counters, where no chunk was enqueued to run
+            # them under (an interrupt, an error): /internal/status read
+            # after a request shows that request
+            if account is not None:
+                account()
 
-    def _expand_prompts(self, payload, expansion, start: int,
-                        count: int) -> None:
+    def _draws_ahead(self, payload) -> bool:
+        """Whether an expanded range's first group can be drawn before its
+        text is known: txt2img (img2img's noise waits for the init
+        latents) at the payload's own size with a fixed ladder of steps,
+        and nothing in the draw that is set up after it (ControlNet hints,
+        a refiner hand-over, the hires pass) or that reads the text (a
+        ragged range's true context length)."""
+        return not (
+            payload.init_images or payload.enable_hr
+            or (payload.refiner_checkpoint
+                and payload.refiner_switch_at < 1.0)
+            or self._ragged_plan(payload) is not None
+            or kd.resolve_sampler(payload.sampler_name).adaptive
+            or self._parse_controlnet_units(payload))
+
+    def _draw_ahead(self, payload, start: int, count: int) -> Drawn:
+        """:class:`Drawn` for the range's first group, through the
+        functions ``_run_txt2img`` and ``_denoise_range_timed`` run for a
+        range that brings none. Its few device programs (the noise, its
+        scale, the carry's zeros, the keys) queue behind the decode chunk
+        in flight."""
+        width, height = payload.width, payload.height
+        plan = self._request_plan(payload, width, height)
+        _, gen_n = self._group_rows(payload, start, count, width, height)
+        x, keys, _ = self._draw_group(payload, start, gen_n, width, height,
+                                      plan.sigmas)
+        with obs_spans.span("denoise.inputs"):
+            cfg, carry = self._range_start(payload, x)
+        with obs_spans.span("denoise.plan") as plan_span:
+            range_plan = self._range_plan(payload, payload.steps, plan_span)
+        PLAN.record_ahead("drawn")
+        return Drawn((start, gen_n, width, height), plan, x, keys, cfg,
+                     carry, range_plan)
+
+    def _expand_prompts(self, payload, expansion, start: int, count: int):
         """The ``expand`` stage: images [start, start+count) get their
         prompts continued by the resident language model, each keyed by
         its own seed, so a sub-range expands exactly its share. The images
@@ -1229,16 +1312,31 @@ class Engine:
         the order of their first image, and a group is expanded together
         (pipeline/expand.py:expand_batch: one prefill, every image a
         sequence of the decode steps where the expander's layer kinds
-        allow). Mutates ``payload`` (generate_range's copy)."""
+        allow). Mutates ``payload`` (generate_range's copy).
+
+        Returns ``(drawn, account)``. Under the first group's first decode
+        chunk the range's own first group is drawn (:class:`Drawn`; None
+        where ``_draws_ahead`` says no, where no chunk was enqueued, or
+        after an interrupt: ``_run_txt2img`` then draws as ever).
+        ``account`` fetches the counters the expansion left on the device
+        (``serving.expander``) and is safe to call again: the caller runs
+        it once the UNet is queued."""
         total = payload.total_images
         prompts = list(payload.all_prompts or [payload.prompt] * total)
         groups: dict = {}   # prompt text -> {image's key index: images}
         for i in range(start, min(start + count, len(prompts))):
             groups.setdefault(prompts[i], {}).setdefault(
                 0 if payload.same_seed else i, []).append(i)
+        drawn: list = []
+        accounts: list = []
+        meanwhile = (lambda: drawn.append(
+            self._draw_ahead(payload, start, count))) \
+            if self._draws_ahead(payload) else None
         for text, by_index in groups.items():
             expanded = self.expander.expand_batch(
-                text, expansion, payload.seed, list(by_index))
+                text, expansion, payload.seed, list(by_index),
+                meanwhile=meanwhile, later=accounts)
+            meanwhile = None    # the first group's alone
             for images, new in zip(by_index.values(), expanded):
                 for i in images:
                     prompts[i] = new
@@ -1248,6 +1346,12 @@ class Engine:
             payload.all_prompts = prompts
         if expansion.context_chunks:
             payload.context_chunks = int(expansion.context_chunks)
+
+        def account() -> None:
+            while accounts:
+                accounts.pop(0)()
+
+        return (drawn[0] if drawn else None), account
 
     def txt2img(self, payload: GenerationPayload) -> GenerationResult:
         # top-level request: reset the interrupt latch and expand native
@@ -1363,7 +1467,8 @@ class Engine:
     def _denoise_range(self, payload, x, image_keys, conds, pooleds,
                        width, height, start_step, steps, job,
                        mask_lat, init_lat, controls=(), end_step=None,
-                       inpaint_cond=None, ragged=None, lora=None):
+                       inpaint_cond=None, ragged=None, lora=None,
+                       drawn=None, account=None):
         """Obs-span wrapper around the chunk loop: one ``denoise_range``
         span (host-side perf_counter, no extra device sync) grouping the
         per-chunk ``denoise_chunk`` spans StageStats feeds in, each the
@@ -1374,12 +1479,43 @@ class Engine:
             return self._denoise_range_timed(
                 payload, x, image_keys, conds, pooleds, width, height,
                 start_step, steps, job, mask_lat, init_lat, controls,
-                end_step, inpaint_cond, ragged, lora)
+                end_step, inpaint_cond, ragged, lora, drawn, account)
+
+    def _range_start(self, payload, x):
+        """``(cfg scale, fresh carry)``: the part of ``denoise.inputs``
+        that reads no conditioning."""
+        return jnp.float32(payload.cfg_scale), kd.init_carry(x)
+
+    def _range_plan(self, payload, steps, span=None) -> RangePlan:
+        """The part of ``denoise.plan`` that reads no conditioning.
+
+        Step-cache policy (pipeline/stepcache.py): deep-feature reuse +
+        CFG truncation. Inactive (cadence 1, cutoff 0 — the default)
+        routes every chunk to the UNCHANGED plain executable, so default
+        outputs stay byte-identical by construction. The cutoff sigma is
+        located on the built ladder host-side (searchsorted, like the
+        adaptive path's CN window gating) and rides into the executable
+        as a traced step index.
+
+        Serving precision (pipeline/precision.py): resolved once per
+        range, static in the chunk executable key. A request that
+        specifies nothing resolves to the policy default, whose module
+        pair IS the constructor-built one — the default path routes to
+        the unchanged executables byte-for-byte. The int8 activation
+        scales are computed inside the traced fn per call (dynamic
+        per-tensor, ops/quant.py), so they never recompile anything."""
+        spec = kd.resolve_sampler(payload.sampler_name)
+        sc = stepcache.resolve(payload)
+        prec = precision_mod.resolve(payload, self.policy)
+        ladder = self._ladder(spec, steps, span)
+        cfg_stop = stepcache.cutoff_step(ladder.host, sc.cutoff_sigma)
+        return RangePlan(sc, prec, cfg_stop)
 
     def _denoise_range_timed(self, payload, x, image_keys, conds, pooleds,
                              width, height, start_step, steps, job,
                              mask_lat, init_lat, controls=(), end_step=None,
-                             inpaint_cond=None, ragged=None, lora=None):
+                             inpaint_cond=None, ragged=None, lora=None,
+                             drawn=None, account=None):
         """Host-side chunk loop with interrupt/progress between dispatches
         (compiled-loop version of the reference's 0.5 s poll,
         worker.py:440-448). ``steps`` sizes the sigma ladder; the loop runs
@@ -1398,8 +1534,15 @@ class Engine:
         tree as traced data. None (the default) adopts the engine's
         active traced set (_apply_prompt_loras), broadcast over this
         range's batch — the dispatcher passes an explicit stacked triple
-        for heterogeneous coalesced groups."""
+        for heterogeneous coalesced groups.
+
+        ``drawn``: what an expanded request made of this range while its
+        expander decoded (:class:`Drawn`: the carry of ``x``, the cfg
+        scalar, the plan); None makes them here. ``account``: the
+        expander's counters' fetch, run once the first chunk is queued."""
         if kd.resolve_sampler(payload.sampler_name).adaptive:
+            if account is not None:     # no chunk to run it under
+                account()
             # the adaptive attempt executable carries no delta args;
             # _apply_prompt_loras routes adaptive requests to the merged
             # path, so no traced set can be live here
@@ -1425,33 +1568,21 @@ class Engine:
             lora_sig, lora_content, lora_rows = lora or ("", "", None)
             masked = mask_lat is not None
             inpainting = self.family.inpaint and inpaint_cond is not None
+            if drawn is not None:
+                assert drawn.x is x and start_step == 0
+                cfg, carry = drawn.cfg, drawn.carry
+            else:
+                cfg, carry = self._range_start(payload, x)
             inputs = denoise.Inputs(
-                ctx_u, ctx_c, jnp.float32(payload.cfg_scale), image_keys,
+                ctx_u, ctx_c, cfg, image_keys,
                 au, ac, mask_lat, init_lat if masked else None,
                 inpaint_cond=inpaint_cond if inpainting else None,
                 lora=lora_rows, ragged=ragged)
-            carry = kd.init_carry(x)
             end = steps if end_step is None else min(end_step, steps)
         with obs_spans.span("denoise.plan") as plan_span:
-            # Step-cache policy (pipeline/stepcache.py): deep-feature reuse +
-            # CFG truncation. Inactive (cadence 1, cutoff 0 — the default)
-            # routes every chunk to the UNCHANGED plain executable, so default
-            # outputs stay byte-identical by construction. The cutoff sigma is
-            # located on the built ladder host-side (searchsorted, like the
-            # adaptive path's CN window gating) and rides into the executable
-            # as a traced step index.
-            spec = kd.resolve_sampler(payload.sampler_name)
-            sc = stepcache.resolve(payload)
-            # Serving precision (pipeline/precision.py): resolved once per
-            # range, static in the chunk executable key. A request that
-            # specifies nothing resolves to the policy default, whose module
-            # pair IS the constructor-built one — the default path routes to
-            # the unchanged executables byte-for-byte. The int8 activation
-            # scales are computed inside the traced fn per call (dynamic
-            # per-tensor, ops/quant.py), so they never recompile anything.
-            prec = precision_mod.resolve(payload, self.policy)
-            ladder = self._ladder(spec, steps, plan_span)
-            cfg_stop = stepcache.cutoff_step(ladder.host, sc.cutoff_sigma)
+            sc, prec, cfg_stop = drawn.range_plan \
+                if drawn is not None \
+                else self._range_plan(payload, steps, plan_span)
             use_cache = (sc.active and cache_supported(self.family.unet)
                          and ragged is None)
             cache = valid = None
@@ -1595,6 +1726,11 @@ class Engine:
                     self.state.step(done)
             pending = (fence, length)
             pos += length
+            if account is not None:
+                # the UNet is queued: what the expander counted comes down
+                # under it
+                account()
+                account = None
             if prefix_plan is not None and not prefix_plan.captured:
                 # capture at the designated chunk boundary: np.asarray
                 # materializes host copies of the carry NOW — the next
@@ -1657,19 +1793,75 @@ class Engine:
             jnp.float32))[None].repeat(batch, axis=0)
         return jnp.concatenate([mask_lat, lat], axis=-1)
 
-    def _run_txt2img(self, payload, start, count, job,
-                     width=None, height=None) -> GenerationResult:
-        width = width or payload.width
-        height = height or payload.height
-        h, w = self._latent_hw(width, height)
-        # sampled latent channels — NOT unet.in_channels, which counts the
-        # mask/masked-image conditioning of inpainting checkpoints too
-        C = self.family.vae.latent_channels
+    def _request_plan(self, payload, width, height) -> RequestPlan:
         with obs_spans.span("request.plan") as plan_span:
             spec = kd.resolve_sampler(payload.sampler_name)
             sigmas = self._ladder(spec, payload.steps, plan_span).sigmas
             controls = self._prepare_controls(payload, width, height)
             refiner = self._refiner_engine(payload)
+        return RequestPlan(sigmas, controls, refiner)
+
+    def _group_rows(self, payload, pos, remaining, width, height):
+        """``(images kept, rows generated)`` of the group that starts at
+        image ``pos`` with ``remaining`` still to make."""
+        group = max(1, payload.group_size or payload.batch_size)
+        n = min(group, remaining)
+        if n < group and self._has_batch_bucket(
+                payload.sampler_name, payload.steps, width, height, group):
+            # pad-and-drop: reuse the already-compiled full-group
+            # executable instead of compiling a remainder bucket (the
+            # TPU replacement for the reference's remainder round-robin,
+            # SURVEY.md §7 layer 5; extra images cost FLOPs once, a new
+            # compile costs minutes)
+            return n, group
+        return n, n
+
+    def _draw_group(self, payload, pos, gen_n, width, height, sigmas,
+                    ragged_wh=None, ctx_true=None):
+        """``(x, image keys, ragged)`` of the ``gen_n`` rows from image
+        ``pos`` on: the noise at the ladder's first sigma."""
+        h, w = self._latent_hw(width, height)
+        # sampled latent channels — NOT unet.in_channels, which counts the
+        # mask/masked-image conditioning of inpainting checkpoints too
+        C = self.family.vae.latent_channels
+        # ragged: true latent rows (ceil: a partial row still needs
+        # its pixels); noise drawn at the TRUE height and
+        # zero-padded so the masked tail starts exactly 0 and row
+        # content is independent of the bucket height the request
+        # landed in
+        tr = h if ragged_wh is None else min(
+            h, -(-ragged_wh[1] // self.family.vae_scale_factor))
+        with obs_spans.span("noise"):
+            noise = rng.batch_noise(
+                payload.seed, payload.subseed,
+                payload.subseed_strength, pos, gen_n, (tr, w, C),
+                seed_resize=self._seed_resize_latent(payload),
+                pin_index=payload.same_seed)
+        ragged = None
+        with obs_spans.span("batch.assemble"):
+            if ragged_wh is not None:
+                noise = jnp.pad(
+                    noise, ((0, 0), (0, h - tr), (0, 0), (0, 0)))
+                ragged = (
+                    jnp.full((gen_n,), tr, jnp.int32),
+                    jnp.full((gen_n,), ctx_true[0], jnp.int32),
+                    jnp.full((gen_n,), ctx_true[1], jnp.int32))
+            x = self._place_batch(
+                noise.astype(jnp.float32) * sigmas[0])
+            keys = self._image_keys(payload, pos, gen_n)
+        return x, keys, ragged
+
+    def _run_txt2img(self, payload, start, count, job,
+                     width=None, height=None, drawn=None,
+                     account=None) -> GenerationResult:
+        """``drawn``: the first group as an expanded request drew it ahead
+        (:class:`Drawn`); ``account``: the expansion's counters' fetch,
+        for the first denoise range to run once its first chunk is queued
+        (``generate_range`` runs it where none was)."""
+        width = width or payload.width
+        height = height or payload.height
+        sigmas, controls, refiner = drawn.plan if drawn is not None \
+            else self._request_plan(payload, width, height)
         # ragged solo dispatch (SDTPU_RAGGED): the bucketer stamped the
         # true requested shape; denoise at the bucket shape with the true
         # latent row count as traced data. Guarded by the same exclusions
@@ -1696,48 +1888,28 @@ class Engine:
 
         # Generate in groups of batch_size so the compiled batch dim is
         # stable across n_iter (reference batches the same way).
-        group = max(1, payload.group_size or payload.batch_size)
         pos = start
         remaining = count
         pending = []
         while remaining > 0 and not self.state.flag.interrupted:
-            n = min(group, remaining)
-            gen_n = n
-            if n < group and self._has_batch_bucket(
-                    payload.sampler_name, payload.steps, width, height,
-                    group):
-                # pad-and-drop: reuse the already-compiled full-group
-                # executable instead of compiling a remainder bucket (the
-                # TPU replacement for the reference's remainder round-robin,
-                # SURVEY.md §7 layer 5; extra images cost FLOPs once, a new
-                # compile costs minutes)
-                gen_n = group
+            n, gen_n = self._group_rows(payload, pos, remaining, width,
+                                        height)
             with obs_spans.span("prepare", group=pos, images=gen_n):
-                # ragged: true latent rows (ceil: a partial row still needs
-                # its pixels); noise drawn at the TRUE height and
-                # zero-padded so the masked tail starts exactly 0 and row
-                # content is independent of the bucket height the request
-                # landed in
-                tr = h if ragged_wh is None else min(
-                    h, -(-ragged_wh[1] // self.family.vae_scale_factor))
-                with obs_spans.span("noise"):
-                    noise = rng.batch_noise(
-                        payload.seed, payload.subseed,
-                        payload.subseed_strength, pos, gen_n, (tr, w, C),
-                        seed_resize=self._seed_resize_latent(payload),
-                        pin_index=payload.same_seed)
-                ragged = None
-                with obs_spans.span("batch.assemble"):
-                    if ragged_wh is not None:
-                        noise = jnp.pad(
-                            noise, ((0, 0), (0, h - tr), (0, 0), (0, 0)))
-                        ragged = (
-                            jnp.full((gen_n,), tr, jnp.int32),
-                            jnp.full((gen_n,), ctx_true[0], jnp.int32),
-                            jnp.full((gen_n,), ctx_true[1], jnp.int32))
-                    x = self._place_batch(
-                        noise.astype(jnp.float32) * sigmas[0])
-                    keys = self._image_keys(payload, pos, gen_n)
+                taken = None
+                if drawn is not None:
+                    # the first group's; one of another shape (the ladder
+                    # of batch buckets moved under the expansion) is of no
+                    # use
+                    if drawn.group == (pos, gen_n, width, height):
+                        taken = drawn
+                    PLAN.record_ahead("taken" if taken else "dropped")
+                    drawn = None
+                if taken is not None:
+                    x, keys, ragged = taken.x, taken.keys, None
+                else:
+                    x, keys, ragged = self._draw_group(
+                        payload, pos, gen_n, width, height, sigmas,
+                        ragged_wh, ctx_true)
                 if payload.all_prompts:
                     conds, pooleds, ref_cond = self._group_conds(
                         payload, pos, gen_n, refiner)
@@ -1746,7 +1918,9 @@ class Engine:
             latents = self._split_denoise(
                 payload, x, keys, conds, pooleds, width, height, job,
                 controls, refiner, ref_cond, payload.steps, 0,
-                inpaint_cond=inp, ragged=ragged)
+                inpaint_cond=inp, ragged=ragged, drawn=taken,
+                account=account)
+            account = None      # the first range's
             out_w, out_h = width, height
             if payload.enable_hr and not self.state.flag.interrupted:
                 latents, out_w, out_h = self._hires_pass(
@@ -1760,6 +1934,8 @@ class Engine:
                 pending = pending[-1:]
             pos += n
             remaining -= n
+        if drawn is not None:   # an interrupt before the first group
+            PLAN.record_ahead("dropped")
         self._flush_decoded(out, payload, pending)
         return out
 
@@ -1772,7 +1948,8 @@ class Engine:
 
     def _split_denoise(self, payload, x, keys, conds, pooleds, width, height,
                        job, controls, refiner, ref_cond, steps, start_step,
-                       inpaint_cond=None, ragged=None):
+                       inpaint_cond=None, ragged=None, drawn=None,
+                       account=None):
         """Denoise [start_step, steps) with an optional refiner handoff: the
         base model runs up to the switch point, then the refiner — its own
         text conditioning and aesthetic micro-conditioning — finishes on the
@@ -1786,8 +1963,11 @@ class Engine:
                                        width, height, start_step, steps, job,
                                        None, None, controls,
                                        inpaint_cond=inpaint_cond,
-                                       ragged=ragged)
-        assert ragged is None  # refiner handoff is ragged-ineligible
+                                       ragged=ragged, drawn=drawn,
+                                       account=account)
+        # refiner handoff is ragged-ineligible, and nothing is drawn ahead
+        # of one
+        assert ragged is None and drawn is None
         switch = int(steps * payload.refiner_switch_at)
         switch = max(start_step, min(steps - 1, switch))
         latents = x
@@ -1795,13 +1975,14 @@ class Engine:
             latents = self._denoise_range(
                 payload, latents, keys, conds, pooleds, width, height,
                 start_step, steps, job, None, None, controls,
-                end_step=switch, inpaint_cond=inpaint_cond)
+                end_step=switch, inpaint_cond=inpaint_cond, account=account)
+            account = None
         if self.state.flag.interrupted:
             return latents
         ref_conds, ref_pooleds = ref_cond
         return refiner._denoise_range(
             payload, latents, keys, ref_conds, ref_pooleds, width, height,
-            switch, steps, job + "+refiner", None, None)
+            switch, steps, job + "+refiner", None, None, account=account)
 
     def _hires_pass(self, payload, latents, image_keys, conds, pooleds, job,
                     refiner=None, ref_cond=None):

@@ -38,6 +38,18 @@ padded up to one of cache/kv.py:SEQUENCE_BUCKETS with repeats of the last,
 whose tokens are dropped. One image takes the one-sequence executables
 under the keys they have always had.
 
+The stage's host work follows one rule: a piece of it runs while the
+device is busy, or as one dispatch, never piece by piece while the device
+waits for it. The images' keys are one jitted call
+(runtime/rng.py:folded_keys) and a snapshot's copy another
+(cache/kv.py:copy_tree), both kept stages of the engine; the instruction's
+token ids are kept by its text; the caller's own work that needs none of
+the text (``meanwhile``: the engine draws its first group's noise, keys
+and carry, pipeline/engine.py:Drawn) runs under the first decode chunk
+(span ``expand.ahead``); and the fetch of the counters the executables
+leave on the device (span ``expand.account``) is handed to the caller
+(``later``), who runs it once the UNet's first chunk is queued.
+
 A looped model (``LMConfig.total_ut_steps`` over 1) runs its whole stack
 that many times a token inside each executable; its spans carry ``passes``
 and its executables return, beside the rest, which pass's state the head
@@ -49,7 +61,7 @@ read for each token made and the largest exit probability
 from __future__ import annotations
 
 import functools
-from typing import List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +78,7 @@ from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
     PromptExpansion,
 )
 from stable_diffusion_webui_distributed_tpu.runtime import rng
+from stable_diffusion_webui_distributed_tpu.runtime.kept import KeptTable
 from stable_diffusion_webui_distributed_tpu.serving.metrics import EXPANDER
 
 #: tokens one decode executable makes
@@ -101,7 +114,11 @@ class PromptExpander:
         self.tokenizer = tokenizer or load_lm_tokenizer(
             None, *self.config.vocab)
         self.cache = kv.KVCacheManager(self.config,
-                                       engine.policy.compute_dtype)
+                                       engine.policy.compute_dtype,
+                                       copier=self._copy_fn)
+        #: instruction text -> its token ids behind ``bos``: as many as the
+        #: cache keeps snapshots of, and like them the same every request
+        self._prefix_ids = KeptTable(kv.MAX_PREFIXES)
 
     # -- executables ---------------------------------------------------------
 
@@ -123,6 +140,18 @@ class PromptExpander:
             lambda: jax.jit(functools.partial(
                 kv.own_rows, sequences=sequences, slots=own_slots)),
             weights=0)
+
+    def _copy_fn(self, capacity: int):
+        """A snapshot's copy as one dispatch (cache/kv.py:copy_tree)."""
+        return self.engine._cached(("expand_copy", capacity),
+                                   lambda: jax.jit(kv.copy_tree), weights=0)
+
+    def _keys_fn(self, batch: int):
+        """The images' keys as one dispatch (runtime/rng.py:folded_keys):
+        one key at one sequence, ``(batch,)`` at several."""
+        return self.engine._cached(("expand_keys", batch),
+                                   lambda: jax.jit(rng.folded_keys),
+                                   weights=0)
 
     def _decode_fn(self, capacity: int, sequences: int = 1):
         """One image keeps the key and the function it has always had."""
@@ -148,19 +177,30 @@ class PromptExpander:
         return self.expand_batch(prompt, args, seed, [image_index])[0]
 
     def expand_batch(self, prompt: str, args: PromptExpansion, seed: int,
-                     image_indices: Sequence[int]) -> List[str]:
+                     image_indices: Sequence[int],
+                     meanwhile: Optional[Callable[[], None]] = None,
+                     later: Optional[List[Callable[[], None]]] = None
+                     ) -> List[str]:
         """:meth:`expand` for each of ``image_indices``, which all continue
         the one ``prompt``: decoded together, at most the largest of
         cache/kv.py:SEQUENCE_BUCKETS a time, where the model's kinds allow
         and else one after the other. Image ``i`` gets what its own seed
-        gives, whoever it is decoded beside."""
+        gives, whoever it is decoded beside.
+
+        ``meanwhile`` is host work of the caller's that needs none of the
+        text: it is called once, on this thread, under the first group's
+        first decode chunk (:meth:`_generate`), or never where the
+        expansion enqueues none. The counters' fetch of each group
+        (``serving.expander``) is run before this returns, or handed to
+        ``later`` for the caller to run when the device is busy again."""
         most = kv.SEQUENCE_BUCKETS[-1] if self.shares_a_step else 1
         texts: List[str] = []
         for at in range(0, len(image_indices), most):
             group = list(image_indices[at:at + most])
             with obs_spans.span("expand", new_tokens=args.max_new_tokens,
                                 sequences=len(group)):
-                made = self._generate(prompt, args, seed, group)
+                made = self._generate(prompt, args, seed, group,
+                                      meanwhile if at == 0 else None, later)
                 with obs_spans.span("expand.detokenize",
                                     tokens=sum(map(len, made))):
                     texts += [self._fit(
@@ -169,31 +209,39 @@ class PromptExpander:
         return texts
 
     def _generate(self, prompt: str, args: PromptExpansion, seed: int,
-                  image_indices: Sequence[int]) -> List[List[int]]:
+                  image_indices: Sequence[int],
+                  meanwhile: Optional[Callable[[], None]] = None,
+                  later: Optional[List[Callable[[], None]]] = None
+                  ) -> List[List[int]]:
         """The tokens made for each image. ``live`` images are ``batch``
-        sequences of the executables (1: the one-sequence ones)."""
+        sequences of the executables (1: the one-sequence ones).
+        ``meanwhile`` runs under the first decode chunk (span
+        ``expand.ahead``). The fetch of the counters the executables left
+        on the device (span ``expand.account``) goes to ``later`` where
+        there is one: nothing of the request reads what it fetches, so it
+        need not run here, with the device idle and the text encoder
+        waiting behind it."""
         tok = self.tokenizer
         live = len(image_indices)
         batch = kv.sequence_bucket(live)
         params = self.engine.params["expander"]
         with obs_spans.span("expand.tokenize"):
-            prefix = [tok.bos] + tok.encode(args.instruction)
+            prefix, _ = self._prefix_ids.get(
+                args.instruction,
+                lambda: (tok.bos, *tok.encode(args.instruction)))
             user = tok.encode(prompt) or [tok.eos]
         with obs_spans.span("expand.setup"):
             chunks = -(-(args.max_new_tokens - 1) // DECODE_STEPS)
             capacity = kv.capacity_for(
                 len(prefix) + kv.chunk_bucket(len(user))
                 + chunks * DECODE_STEPS)
-            if batch == 1:
-                key = first_key = jax.random.fold_in(
-                    rng.key_for_image(seed, image_indices[0]), _KEY_DOMAIN)
-            else:       # the pad repeats the last image
-                padded_to = list(image_indices) \
-                    + [image_indices[-1]] * (batch - live)
-                key = jax.vmap(lambda i: jax.random.fold_in(
-                    rng.key_for_image(seed, i), _KEY_DOMAIN))(
-                        jnp.asarray(padded_to, jnp.uint32))
-                first_key = key[0]
+            # one dispatch: a key for one image, a row of them for
+            # several, whose pad repeats the last
+            indices = image_indices[0] if batch == 1 else \
+                list(image_indices) + [image_indices[-1]] * (batch - live)
+            key = self._keys_fn(batch)(
+                np.uint32(seed), np.asarray(indices, np.uint32),
+                np.uint32(_KEY_DOMAIN))
             temperature = jnp.float32(args.temperature)
             # a sequence's own rows behind a fork: a slot a decode step
             own_slots = chunks * DECODE_STEPS
@@ -241,7 +289,8 @@ class PromptExpander:
                     self._prefill_fn(
                         len(padded), capacity, 1 if keep else batch)(
                             params, cache, padded, jnp.int32(start),
-                            jnp.int32(len(ids)), first_key if keep else key,
+                            jnp.int32(len(ids)),
+                            key[0] if keep and batch > 1 else key,
                             temperature)
                 # fenced: the span is the chunk's device time, not its
                 # enqueue
@@ -296,40 +345,56 @@ class PromptExpander:
                 exits.append(read.pop())
             reads += read
             pending.append(out)
+            if meanwhile is not None:
+                # the device has a chunk to run and this thread nothing to
+                # do but wait for it
+                with obs_spans.span("expand.ahead"):
+                    meanwhile()
+                meanwhile = None
             if len(pending) > 1:
                 fetch(pending.pop(0))
         for out in pending:
             fetch(out)
-        # the cut and the counters' fetch: host work with the device idle
-        with obs_spans.span("expand.account",
-                            fetched=2 * len(routed) + len(reads)
-                            + 2 * len(exits)):
-            made = [one[:args.max_new_tokens] for one in made]
-            if not args.ignore_eos:     # each sequence is cut at its own
-                made = [one[:one.index(tok.eos)] if tok.eos in one else one
-                        for one in made]
-            length = forked_at + max(map(len, made))
-            loads, none_held = zip(*jax.device_get(routed))
-            # a step of one token reads as many experts as it has picks
-            # held; a step of several the distinct ones, counted beside
-            # the load on the device
-            read = np.sum(jax.device_get(reads)) if reads \
-                else np.sum(loads[decoded_from:])
-            EXPANDER.record(
-                prefilled=len(user) + (0 if held else len(prefix)),
-                from_prefix=held, sequences=live,
-                decoded=sum(map(len, made)), decode_steps=steps,
-                experts_read=int(read),
-                load=np.sum(loads, axis=0), none_held=int(np.sum(none_held)),
-                **rows_of(live, forked_at, steps),
-                positions=self.cache.positions_in_use(
-                    length, live, forked_at if batch > 1 else 0),
-                state_bytes=sizes, prefix_snapshots=self.cache.snapshots,
-                padded_rows_masked=masked,
-                residual_streams=self.config.residual_streams,
-                sinkhorn_iters=(self.config.sinkhorn_iters
-                                if self.config.residual_streams > 1 else 0),
-                **self._passes_run(exits, steps))
+        made = [one[:args.max_new_tokens] for one in made]
+        if not args.ignore_eos:     # each sequence is cut at its own
+            made = [one[:one.index(tok.eos)] if tok.eos in one else one
+                    for one in made]
+        length = forked_at + max(map(len, made))
+        decoded = sum(map(len, made))
+
+        def account() -> None:
+            with obs_spans.span("expand.account",
+                                fetched=2 * len(routed) + len(reads)
+                                + 2 * len(exits)):
+                loads, none_held = zip(*jax.device_get(routed))
+                # a step of one token reads as many experts as it has picks
+                # held; a step of several the distinct ones, counted beside
+                # the load on the device
+                read = np.sum(jax.device_get(reads)) if reads \
+                    else np.sum(loads[decoded_from:])
+                EXPANDER.record(
+                    prefilled=len(user) + (0 if held else len(prefix)),
+                    from_prefix=held, sequences=live,
+                    decoded=decoded, decode_steps=steps,
+                    experts_read=int(read),
+                    load=np.sum(loads, axis=0),
+                    none_held=int(np.sum(none_held)),
+                    **rows_of(live, forked_at, steps),
+                    positions=self.cache.positions_in_use(
+                        length, live, forked_at if batch > 1 else 0),
+                    state_bytes=sizes,
+                    prefix_snapshots=self.cache.snapshots,
+                    padded_rows_masked=masked,
+                    residual_streams=self.config.residual_streams,
+                    sinkhorn_iters=(self.config.sinkhorn_iters
+                                    if self.config.residual_streams > 1
+                                    else 0),
+                    **self._passes_run(exits, steps))
+
+        if later is None:
+            account()
+        else:
+            later.append(account)
         return made
 
     def _passes_run(self, exits, steps: int) -> dict:

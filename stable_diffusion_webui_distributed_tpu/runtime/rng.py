@@ -151,6 +151,20 @@ def _batch_keys_jit(seed, start_index, batch_size, pin_index):
     return jax.vmap(lambda i: key_for_image(seed, i))(idx)
 
 
+def folded_keys(seed, image_indices, domain) -> jax.Array:
+    """``fold_in(key_for_image(seed, i), domain)`` for the image index or
+    the ``(batch,)`` indices given, bit for bit what the eager calls give:
+    the keys of a stage that must not share the noise's stream
+    (pipeline/expand.py). Meant to be jitted whole, like
+    :func:`batch_keys`: one dispatch a request, not a trace of a bare
+    ``vmap`` every time."""
+    def one(i):
+        return jax.random.fold_in(key_for_image(seed, i), domain)
+
+    return one(image_indices) if jnp.ndim(image_indices) == 0 \
+        else jax.vmap(one)(image_indices)
+
+
 def _paste_centered(noise: jax.Array, target_shape: Sequence[int],
                     dtype) -> jax.Array:
     """Center-paste (B, fh, fw, C) noise into zeros of (B, H, W, C) —

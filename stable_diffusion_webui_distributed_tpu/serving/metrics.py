@@ -702,7 +702,16 @@ class PlanStats:
     ``build_sigmas``) and SDXL's time-id embedding, ``added_cond``
     (pipeline/engine.py). A ``build`` ran the device ops and the fetch, a
     ``hit`` ran nothing; after a warm-up request every request of one
-    sampler, step count and size should only hit."""
+    sampler, step count and size should only hit.
+
+    ``ahead``: the first group of an expanded txt2img range made while the
+    expander decoded (pipeline/engine.py:Drawn): ``drawn`` by the closure
+    that ran under a decode chunk, ``taken`` by a range that used one,
+    ``dropped`` where one was drawn and not used (an interrupt, a group of
+    another shape). Every expanded request of a steady window draws one
+    and takes it."""
+
+    AHEAD = ("drawn", "taken", "dropped")
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -710,20 +719,28 @@ class PlanStats:
 
     def clear(self) -> None:
         with self._lock:
-            #: (table, "hits" | "builds") -> lookups
+            #: (table, "hits" | "builds") -> lookups; ("ahead", kind)
             self.lookups: Dict[tuple, int] = defaultdict(int)  # guarded-by: _lock
 
     def record(self, table: str, hit: bool) -> None:
         with self._lock:
             self.lookups[(table, "hits" if hit else "builds")] += 1
 
+    def record_ahead(self, kind: str) -> None:
+        with self._lock:
+            self.lookups[("ahead", kind)] += 1
+
     def summary(self) -> Dict[str, Dict[str, int]]:
-        """``{"ladder": {"hits": n, "builds": m}, "added_cond": {...}}``."""
+        """``{"ladder": {"hits": n, "builds": m}, "added_cond": {...},
+        "ahead": {"drawn": n, "taken": n, "dropped": 0}}``."""
         with self._lock:
             lookups = dict(self.lookups)
-        return {table: {kind: lookups.get((table, kind), 0)
-                        for kind in ("hits", "builds")}
-                for table in ("ladder", "added_cond")}
+        out = {table: {kind: lookups.get((table, kind), 0)
+                       for kind in ("hits", "builds")}
+               for table in ("ladder", "added_cond")}
+        out["ahead"] = {kind: lookups.get(("ahead", kind), 0)
+                        for kind in self.AHEAD}
+        return out
 
 
 class HostStats:

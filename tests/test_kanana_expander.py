@@ -484,7 +484,10 @@ class TestEnginePath:
         assert keys == {("expand_prefill", 64, CAPACITY),
                         ("expand_prefill", 64, CAPACITY, 4),
                         ("expand_fork", CAPACITY, 4, 2 * STEPS),
-                        ("expand_decode_chunk", STEPS, CAPACITY, 4)}
+                        ("expand_decode_chunk", STEPS, CAPACITY, 4),
+                        # one dispatch each: the images' keys, a
+                        # snapshot's copy
+                        ("expand_keys", 4), ("expand_copy", CAPACITY)}
         sites = ATTENTION.summary()
         assert sites["latent_forked"] == 4 and sites["latent_expanded"] == 8
         assert "latent_absorbed" not in sites
@@ -539,7 +542,10 @@ class TestEnginePath:
         by_id = {e["args"]["span_id"]: e for e in events}
         for e in events:
             if e["name"].startswith("expand."):
-                assert by_id[e["args"]["parent_id"]]["name"] == "expand"
+                # the counters come down once the UNet is queued
+                assert by_id[e["args"]["parent_id"]]["name"] == (
+                    "denoise_range" if e["name"] == "expand.account"
+                    else "expand")
         text = prometheus.render()
         assert "sdtpu_expander_rows_attended_total " \
             f"{stats['rows_attended']}" in text

@@ -499,7 +499,10 @@ class TestOneImageTakesTheOneSequencePath:
         keys = {k for k in engine.executable_keys()
                 if k[0].startswith("expand")}
         assert keys == {("expand_prefill", 64, CAPACITY),
-                        ("expand_decode_chunk", STEPS, CAPACITY)}
+                        ("expand_decode_chunk", STEPS, CAPACITY),
+                        # one dispatch each: the image's key, a
+                        # snapshot's copy
+                        ("expand_keys", 1), ("expand_copy", CAPACITY)}
         stats = EXPANDER.summary()
         assert stats["expert_products"] == {"kernel": 0, "loop": 4,
                                             "grouped": 4}
@@ -605,7 +608,10 @@ class TestABatchOfImages:
         by_id = {e["args"]["span_id"]: e for e in events}
         for e in events:
             if e["name"].startswith("expand."):
-                assert by_id[e["args"]["parent_id"]]["name"] == "expand"
+                # the counters come down once the UNet is queued
+                assert by_id[e["args"]["parent_id"]]["name"] == (
+                    "denoise_range" if e["name"] == "expand.account"
+                    else "expand")
 
     def test_the_decode_trace_takes_the_grouped_product(self):
         """A fresh engine's four-image request traces one prefill chunk
@@ -626,7 +632,8 @@ class TestABatchOfImages:
         assert keys == {("expand_prefill", 64, CAPACITY),
                         ("expand_prefill", 64, CAPACITY, 4),
                         ("expand_fork", CAPACITY, 4, 2 * STEPS),
-                        ("expand_decode_chunk", STEPS, CAPACITY, 4)}
+                        ("expand_decode_chunk", STEPS, CAPACITY, 4),
+                        ("expand_keys", 4), ("expand_copy", CAPACITY)}
         sites = ATTENTION.summary()["by_shape"]
         # a forked step's keys: the ring or the buffer as the prefill
         # left it, and a sequence's own 64 slots behind it

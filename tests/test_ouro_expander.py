@@ -379,7 +379,11 @@ class TestTheEnginePath:
                         ("expand_prefill", 64, CAPACITY, 4),
                         ("expand_fork", CAPACITY, 4, 2 * STEPS),
                         ("expand_decode_chunk", STEPS, CAPACITY),
-                        ("expand_decode_chunk", STEPS, CAPACITY, 4)}
+                        ("expand_decode_chunk", STEPS, CAPACITY, 4),
+                        # one dispatch each: the images' keys, a
+                        # snapshot's copy
+                        ("expand_keys", 1), ("expand_keys", 4),
+                        ("expand_copy", CAPACITY)}
 
     def test_counters_and_spans_of_the_passes(self, engine):
         from stable_diffusion_webui_distributed_tpu.obs import spans

@@ -37,7 +37,7 @@ from stable_diffusion_webui_distributed_tpu.runtime.interrupt import (
     GenerationState,
 )
 from stable_diffusion_webui_distributed_tpu.serving.metrics import (
-    EXPANDER, METRICS,
+    EXPANDER, METRICS, PLAN,
 )
 from tests.test_pipeline import init_params
 
@@ -749,8 +749,23 @@ class TestTokenizerAndCache:
             == [64, 64, 64, 128, 512]
         assert kv.capacity_for(960) == 1024 and kv.capacity_for(256) == 256
 
-    def test_a_kept_prefix_is_handed_out_as_a_copy(self):
-        manager = kv.KVCacheManager(CFG, jnp.float32)
+    @pytest.mark.parametrize("own_copier", [False, True])
+    def test_a_kept_prefix_is_handed_out_as_a_copy(self, own_copier):
+        """Each copy is ONE call of one executable (the manager's own, or
+        the one its maker hands it by capacity, as the expander does
+        through the engine's cache of stages), and what is held survives
+        the copy's donation."""
+        calls = []
+        copy = jax.jit(kv.copy_tree)
+
+        def copier(capacity):
+            def counted(tree):
+                calls.append(capacity)
+                return copy(tree)
+            return counted
+
+        manager = kv.KVCacheManager(CFG, jnp.float32,
+                                    copier=copier if own_copier else None)
         cache, held = manager.acquire([1, 2, 3], 256)
         assert held == 0 and [k.shape for k in cache["k"]] == [
             (256, 2, 16), (8, 2, 16), (8, 2, 16), (256, 2, 16)]
@@ -758,8 +773,22 @@ class TestTokenizerAndCache:
                             jax.tree_util.tree_map(lambda x: x + 1, cache))
         again, held = manager.acquire([1, 2, 3], 256)
         assert held == 3 and float(again["v"][1][0, 0, 0]) == 1.0
+        if own_copier:      # the snapshot's, then the hand-out's
+            assert calls == [256, 256]
+        # the copy goes the way of every cache, into a donating executable
+        # (where the backend does not take a donation, by hand)
+        spent = jax.jit(lambda tree: jax.tree_util.tree_map(
+            lambda x: x * 0, tree), donate_argnums=(0,))(again)
+        for leaf in jax.tree_util.tree_leaves(again):
+            if not leaf.is_deleted():
+                leaf.delete()
+        third, held = manager.acquire([1, 2, 3], 256)
+        assert held == 3
+        assert all(float(jnp.min(x)) == float(jnp.max(x)) == 1.0
+                   for x in jax.tree_util.tree_leaves(third))
+        assert float(jnp.max(spent["k"][0])) == 0.0
         assert manager.acquire([1, 2], 256)[1] == 0
-        assert (manager.prefix_hits, manager.prefix_misses) == (1, 2)
+        assert (manager.prefix_hits, manager.prefix_misses) == (2, 2)
         assert manager.positions_in_use(40) == {"full": 80, "sliding": 16}
 
 
@@ -883,6 +912,7 @@ class TestEnginePath:
     def test_spans(self, engine):
         from stable_diffusion_webui_distributed_tpu.obs import spans
 
+        engine.txt2img(payload())       # the instruction's snapshot is kept
         spans.TRACER.clear()
         with spans.request("rid-expand"):
             engine.txt2img(payload())
@@ -891,26 +921,83 @@ class TestEnginePath:
         names = [e["name"] for e in events]
         for name in ("expand", "expand.tokenize", "expand.prefill",
                      "expand.decode_chunk", "expand.fence_wait",
-                     "expand.detokenize", "prepare"):
+                     "expand.detokenize", "expand.ahead", "expand.account",
+                     "prepare"):
             assert name in names, name
         by_id = {e["args"]["span_id"]: e for e in events}
+
+        def parent(e):
+            return by_id[e["args"]["parent_id"]]["name"]
+
         for e in events:
+            # the counters come down once the UNet is queued
             if e["name"].startswith("expand."):
-                assert by_id[e["args"]["parent_id"]]["name"] == "expand"
+                assert parent(e) == ("denoise_range" if e["name"]
+                                     == "expand.account" else "expand")
         prefill = next(e for e in events if e["name"] == "expand.prefill")
         assert prefill["args"]["tokens"] == 5
         assert prefill["args"]["prefix_hit"] is True
+        # what reads nothing of the text is drawn under the first decode
+        # chunk: once, between its enqueue and the wait for its tokens
+        first = {}
+        for e in sorted(events, key=lambda e: e["ts"]):
+            first.setdefault(e["name"], e)
+        for name in ("request.plan", "noise", "batch.assemble",
+                     "denoise.inputs", "denoise.plan"):
+            assert parent(first[name]) == "expand.ahead", name
+        assert [names.count(n) for n in ("expand.ahead", "request.plan",
+                                         "noise", "batch.assemble")] \
+            == [1, 1, 1, 1]
+        ahead = first["expand.ahead"]
+        assert first["expand.decode_chunk"]["ts"] < ahead["ts"] \
+            < first["expand.fence_wait"]["ts"]
+        # the rest of the two spans runs where it ran, with the text
+        assert [parent(e) for e in events
+                if e["name"] == "denoise.inputs"] \
+            == ["expand.ahead", "denoise_range"]
+        account, = [e for e in events if e["name"] == "expand.account"]
+        assert first["chunk.enqueue"]["ts"] < account["ts"]
+        assert account["ts"] < first["chunk.fence_wait"]["ts"]
 
     def test_a_family_without_an_expander_is_untouched(self):
+        from stable_diffusion_webui_distributed_tpu.obs import spans
+
         plain = Engine(configs.TINY, init_params(configs.TINY),
                        chunk_size=4, state=GenerationState())
         assert plain.expander is None
-        with_script = plain.txt2img(payload())
+        before = PLAN.summary()["ahead"]
         without = plain.txt2img(payload(alwayson_scripts={}))
+        spans.TRACER.clear()
+        with spans.request("rid-plain"):
+            with_script = plain.txt2img(payload())
         assert with_script.images == without.images
         assert with_script.prompts == ["a cow in a valley"]
         kinds = {k[0] for k in plain.executable_keys()}
         assert not any(k.startswith("expand") for k in kinds)
+        # and its request is the tree it was before any request drew ahead
+        assert PLAN.summary()["ahead"] == before
+        events = sorted((e for e in spans.TRACER.export_chrome()[
+            "traceEvents"] if e.get("ph") == "X"), key=lambda e: e["ts"])
+        by_id = {e["args"]["span_id"]: e["name"] for e in events}
+        tree = [(e["name"], by_id.get(e["args"].get("parent_id")))
+                for e in events]
+        assert tree == [
+            ("request", None), ("generate_range", "request"),
+            ("request.plan", "generate_range"),
+            ("prepare", "generate_range"), ("tokenize", "prepare"),
+            ("text_encode", "prepare"), ("prepare", "generate_range"),
+            ("noise", "prepare"), ("batch.assemble", "prepare"),
+            ("denoise_range", "generate_range"),
+            ("denoise.inputs", "denoise_range"),
+            ("denoise.plan", "denoise_range"),
+            ("denoise_chunk", "denoise_range"),
+            ("chunk.enqueue", "denoise_chunk"),
+            ("chunk.fence_wait", "denoise_range"),
+            ("vae_decode_dispatch", "generate_range"),
+            ("vae_decode_fetch", "generate_range"),
+            ("decode.wait", "vae_decode_fetch"),
+            ("fetch.copy", "vae_decode_fetch"),
+            ("png_encode", "generate_range")]
 
     def test_status_block(self, engine):
         engine.txt2img(payload())
@@ -966,6 +1053,130 @@ class TestEnginePath:
             assert round(attended / read, 1) == 3.1
         if forked_at == 2088:
             assert round(attended / read, 1) == 3.4
+
+
+class TestDrawnAhead:
+    """What of a request reads nothing of the expanded text is made under
+    the expander's first decode chunk, the keys and a snapshot's copy are
+    one dispatch each, and the counters come down behind the UNet's first
+    chunk: the same functions on the same arguments in another order."""
+
+    @staticmethod
+    def ahead_since(before):
+        now = PLAN.summary()["ahead"]
+        return [now[k] - before[k] for k in ("drawn", "taken", "dropped")]
+
+    @pytest.mark.parametrize("images", [1, 4])
+    def test_the_images_are_what_the_old_order_gives(self, engine,
+                                                     monkeypatch, images):
+        assert engine.expander.shares_a_step
+        request = payload(batch_size=images, subseed=7)
+        engine.txt2img(payload())       # the instruction's snapshot is kept
+        before = PLAN.summary()["ahead"]
+        EXPANDER.clear()
+        ahead = engine.txt2img(request)
+        assert self.ahead_since(before) == [1, 1, 0]
+        def counters():     # but those a trace feeds
+            return {k: v for k, v in EXPANDER.summary().items()
+                    if not k.endswith(("_products", "_mixers"))}
+
+        counted = counters()
+        monkeypatch.setattr(engine, "_draws_ahead", lambda p: False)
+        EXPANDER.clear()
+        plain = engine.txt2img(request)
+        assert self.ahead_since(before) == [1, 1, 0]
+        assert len(ahead.images) == images
+        assert ahead.model_dump() == plain.model_dump()
+        assert counted == counters()
+        assert counted["requests"] == 1 and counted["sequences"] == images
+
+    def test_an_expansion_without_a_decode_chunk_draws_nothing(
+            self, engine, monkeypatch):
+        """``max_new_tokens`` 1: the one token comes from the prefill."""
+        request = payload(subseed=7,
+                          alwayson_scripts=script(max_new_tokens=1))
+        before = PLAN.summary()["ahead"]
+        EXPANDER.clear()
+        one = engine.txt2img(request)
+        assert self.ahead_since(before) == [0, 0, 0]
+        assert len(one.prompts[0].split()) == 6
+        assert EXPANDER.summary()["requests"] == 1
+        monkeypatch.setattr(engine, "_draws_ahead", lambda p: False)
+        assert engine.txt2img(request).model_dump() == one.model_dump()
+
+    def test_an_interrupt_during_the_expansion_drops_the_draw(
+            self, engine, monkeypatch):
+        whole = engine.txt2img(payload(subseed=7))
+        real = engine.expander._decode_fn
+
+        def then_interrupt(capacity, sequences=1):
+            def decode(*args):
+                engine.state.flag.interrupt()
+                return real(capacity, sequences)(*args)
+            return decode
+
+        before = PLAN.summary()["ahead"]
+        EXPANDER.clear()
+        with monkeypatch.context() as patch:
+            patch.setattr(engine.expander, "_decode_fn", then_interrupt)
+            cut = engine.txt2img(payload(subseed=7))
+        assert self.ahead_since(before) == [1, 0, 1]
+        assert cut.images == []
+        # the counters of the request that enqueued no chunk are there
+        stats = EXPANDER.summary()
+        assert stats["requests"] == 1
+        assert stats["decode_steps"] == expand.DECODE_STEPS
+        # and the next request is a whole one
+        assert engine.txt2img(payload(subseed=7)).model_dump() \
+            == whole.model_dump()
+        assert self.ahead_since(before) == [2, 1, 1]
+
+    @pytest.mark.parametrize("job", ["img2img", "hires", "adaptive"])
+    def test_what_cannot_be_drawn_ahead_keeps_its_order(self, engine, job):
+        import base64
+        import io
+
+        from PIL import Image
+
+        buf = io.BytesIO()
+        Image.new("RGB", (32, 32), (90, 120, 60)).save(buf, format="PNG")
+        request = {
+            "img2img": dict(init_images=[base64.b64encode(
+                buf.getvalue()).decode()], denoising_strength=0.5),
+            "hires": dict(enable_hr=True, hr_scale=2.0,
+                          hr_second_pass_steps=2, denoising_strength=0.5),
+            "adaptive": dict(sampler_name="DPM adaptive"),
+        }[job]
+        before = PLAN.summary()["ahead"]
+        EXPANDER.clear()
+        out = engine.generate_range(
+            payload(**request), 0, None,
+            "img2img" if job == "img2img" else "txt2img")
+        assert self.ahead_since(before) == [0, 0, 0]
+        assert len(out.images) == 1 and len(out.prompts[0].split()) == 45
+        assert EXPANDER.summary()["requests"] == 1
+
+    @pytest.mark.parametrize("images", [1, 2, 3, 4])
+    def test_the_keys_of_one_dispatch_are_the_eager_keys(self, engine,
+                                                         images):
+        """Bit for bit, at a seed whose sum with the index wraps; three
+        images are padded to four with the last."""
+        from stable_diffusion_webui_distributed_tpu.runtime import rng
+
+        seed = 2 ** 32 - 2
+        batch = kv.sequence_bucket(images)
+        indices = list(range(images)) + [images - 1] * (batch - images)
+        got = engine.expander._keys_fn(batch)(
+            np.uint32(seed),
+            np.asarray(indices[0] if batch == 1 else indices, np.uint32),
+            np.uint32(expand._KEY_DOMAIN))
+        want = [jax.random.fold_in(rng.key_for_image(seed, i),
+                                   expand._KEY_DOMAIN) for i in indices]
+        assert got.shape == (() if batch == 1 else (batch,))
+        np.testing.assert_array_equal(
+            jax.random.key_data(got).reshape(batch, -1),
+            np.stack([jax.random.key_data(k) for k in want]))
+        assert ("expand_keys", batch) in engine.executable_keys()
 
 
 class TestDispatcher:

@@ -17,7 +17,12 @@ chip) and keeps what that run reads and throws away: ``/internal/trace.json``
   ``denoise.plan`` spans read ``ladder`` hit or built and how many
   ``denoise.inputs`` read ``added_cond`` hit, built or none, and
   ``plan_counts``: ``serving.plan`` of the last ``/internal/status`` (PR
-  38: after the warm-up request everything should read hit);
+  38: after the warm-up request everything should read hit; PR 55:
+  ``ahead``, how many expanded ranges had their first group ``drawn``
+  under the expander's decode, ``taken`` and ``dropped``, process-wide
+  like the tables and printed with them on the ``plan:`` line: every
+  expanded request, the warm-up's among them, should add one to the
+  first two);
 - ``dispatch_attrs``: over the window's requests, how many
   ``coalesce.window`` spans read ``ended_by`` full or timer and how many
   ``dispatch.device`` spans carried 1, 2, ... ``requests``, and
