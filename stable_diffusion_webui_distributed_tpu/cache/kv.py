@@ -24,10 +24,11 @@ cut back, so the key is the whole prefix. The executables donate the cache
 they are given, so what is kept is never handed out itself.
 
 The images of one request continue one prompt, each under its own key. Where
-every layer keeps a row a position (keys and values, or latents:
-``lm.shares_a_step``) they are decoded as
+every layer keeps a row a position (keys and values, or latents) or a
+recurrent state (``lm.shares_a_step``) they are decoded as
 sequences of one step: the prompt is prefilled once and its cache
-:func:`fork`-ed. A fork copies nothing. Every buffer, a full layer's, a
+:func:`fork`-ed. A fork copies nothing that has positions. Every such
+buffer, a full layer's, a
 ring and a latent layer's alike, stays where the prefill left it, held once,
 read by every
 sequence and written by none (``k_shared``, ``v_shared``,
@@ -41,6 +42,13 @@ reads them once where copies would be read once a sequence; a ring is
 never overwritten because a sequence's new rows go to its own, not to the
 ring. Both kinds go one way because a fork is handed buffers, not a
 config, and a ring cannot be told from a buffer by its length.
+What has NO positions is copied: a linear layer's recurrent state and its
+convolution's kept inputs are the whole past folded into one size, each
+sequence folds its own tokens into them from the fork on, and so each gets
+its own copy under the buffer's name, ``(sequences, ...)`` (4.39 MB a
+layer a sequence at 64 value heads of 128 x 128 and 3 x 16 384 kept
+inputs). The copies are made in the fork's one executable; the prefill's
+own state is let go with the cache it came in.
 
 A looped model (``LMConfig.total_ut_steps`` over 1) passes a token through
 its whole stack several times over one set of weights, and pass ``t`` of a
@@ -96,14 +104,19 @@ def sequence_bucket(sequences: int) -> int:
 
 
 def own_rows(cache: Dict, sequences: int, slots: int = 0) -> Dict:
-    """What a fork of ``cache`` makes anew: for each of its buffers
-    ``sequences`` times ``slots`` empty rows (0: as many as the buffer
-    has) under the buffer's name, and the position of the fork, not yet
+    """What a fork of ``cache`` makes anew: for each of its buffers that
+    keep positions ``sequences`` times ``slots`` empty rows (0: as many as
+    the buffer has) under the buffer's name, for each that keeps none (a
+    linear layer's state and kept inputs) ``sequences`` copies of it, and
+    the position of the fork, not yet
     known (negative: the first step sets it to where it stands), a row a
-    sequence like the rest. Only the buffers' shapes are read, so traced
-    into an executable of its own it touches no buffer."""
+    sequence like the rest. Of a buffer that keeps positions only the
+    shape is read, so traced into an executable of its own this touches
+    none of them."""
     def rows(name, x):
         shape, axis = list(x.shape), lm.slots_axis(name)
+        if axis is None:        # no positions: every sequence its copy
+            return jnp.broadcast_to(x, (sequences, *shape))
         shape[axis] = slots or shape[axis]
         return jnp.zeros((sequences, *shape), x.dtype)
 
@@ -115,16 +128,31 @@ def own_rows(cache: Dict, sequences: int, slots: int = 0) -> Dict:
 
 def forked(cache: Dict, own: Dict) -> Dict:
     """``cache`` of one sequence and :func:`own_rows` of it as one cache:
-    the shared buffers ARE ``cache``'s."""
-    return {**own, **{lm.SHARED_OF[name]: cache[name] for name in cache}}
+    the shared buffers ARE ``cache``'s (a buffer without positions has no
+    shared twin: ``own`` holds the sequences' copies of it)."""
+    return {**own, **{lm.SHARED_OF[name]: cache[name] for name in cache
+                      if name in lm.SHARED_OF}}
 
 
 def fork(cache: Dict, sequences: int, own_slots: int = 0) -> Dict:
     """``cache`` of one sequence as that of ``sequences`` which all stand
     where it stands and go on apart for at most ``own_slots`` positions
-    (0: as many as a buffer has slots). Nothing is copied: the module's
-    text says where everything lies."""
+    (0: as many as a buffer has slots). Nothing that has positions is
+    copied: the module's text says where everything lies."""
     return forked(cache, own_rows(cache, sequences, own_slots))
+
+
+def copied_bytes(config, dtype, sequences: int) -> int:
+    """Bytes a :func:`fork` into ``sequences`` copies: the buffers that
+    keep no positions (:func:`state_bytes` of the linear kind), once a
+    sequence. 0 for a model whose every buffer keeps positions, and at
+    one sequence, which forks nothing."""
+    if sequences < 2:
+        return 0
+    shapes = lm.cache_shapes(config, 0)     # no capacity is read
+    return sequences * sum(
+        math.prod(shape) * lm.buffer_dtype(name, dtype).itemsize
+        for name in lm.LINEAR_BUFFERS for shape in shapes.get(name, ()))
 
 
 def copy_tree(cache: Dict) -> Dict:
@@ -145,7 +173,8 @@ def state_bytes(config, capacity: int, dtype, sequences: int = 1,
     by layer kind, from the shapes: keys, values and latents in ``dtype``,
     a linear layer's state and a linear or conv layer's kept inputs in
     float32. Several sequences are a :func:`fork` of one: every buffer
-    once and ``own_slots`` rows of it a sequence.
+    that keeps positions once and ``own_slots`` rows of it a sequence, one
+    that keeps none once a sequence.
     Full and sliding are always named; linear, latent and conv where the
     model has such layers. A request asks for the sizes of its model at
     its capacity, the same as the request before it: kept by argument."""
@@ -162,7 +191,10 @@ def _state_bytes(config, capacity: int, dtype, sequences: int,
         """A buffer's elements, and its sequences' own rows behind it."""
         if sequences == 1:
             return math.prod(shape)
-        slots = shape[lm.slots_axis(name)]
+        axis = lm.slots_axis(name)
+        if axis is None:
+            return sequences * math.prod(shape)
+        slots = shape[axis]
         return math.prod(shape) // slots * (slots + sequences * own_slots)
 
     out = {lm.FULL: 0, lm.SLIDING: 0}

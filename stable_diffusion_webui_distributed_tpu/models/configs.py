@@ -111,9 +111,21 @@ class LMConfig:
     ``attn_gate`` says where an attention layer's output gate comes from:
     ``"head"`` is one sigmoid a query head from its own ``g_proj``,
     ``"element"`` one a channel, the second half of every head's
-    ``q_proj`` columns, ``"none"`` no gate at all. ``qk_norm`` puts an RMS norm over each head's query
+    ``q_proj`` columns, ``"none"`` no gate at all. A latent layer reads
+    the same key: ``"element"`` is one sigmoid a value channel from a
+    ``g_proj`` of its own (a latent layer's queries come through a latent,
+    so no half of their columns can be the gate), applied to the heads'
+    outputs before ``o_proj`` in all three forms, ``"head"`` one a head.
+    ``qk_norm`` puts an RMS norm over each head's query
     and key before the rotation. ``zero_centred_norm`` makes every norm
-    ``x_hat * (1 + weight)`` instead of ``x_hat * scale``.
+    ``x_hat * (1 + weight)`` instead of ``x_hat * scale``;
+    ``norm_sigmoid_scale`` over 0 makes every norm ``x_hat * scale *
+    sigmoid(weight)`` (a gate that is 1 at ``weight`` 0 when the scale is
+    2). ``linear_sigmoid_gate_scale`` over 0 makes a linear layer's
+    read-out ``x_hat * (1 + weight) * scale * sigmoid(z)`` where 0 has
+    ``x_hat * scale * silu(z)``. ``swiglu_limit`` over 0 clamps every
+    SwiGLU, dense, shared and routed: ``silu(min(gate, limit)) * clip(up,
+    -limit, limit)``.
     ``shared_expert_gate`` multiplies the shared expert by
     ``sigmoid(w_s^T n)``. The defaults of the last three are the ungated,
     un-normed forms. ``shared_expert_intermediate_size`` 0 is an expert
@@ -197,6 +209,9 @@ class LMConfig:
     total_ut_steps: int = 1
     early_exit_threshold: float = 1.0
     post_sublayer_norm: bool = False
+    norm_sigmoid_scale: float = 0.0
+    linear_sigmoid_gate_scale: float = 0.0
+    swiglu_limit: float = 0.0
 
     def __post_init__(self) -> None:
         if self.total_ut_steps > 1 and (
@@ -452,16 +467,27 @@ LAGUNA_S_2_1 = LMConfig(
     rope_full=_LAGUNA_ROPE_FULL, rope_sliding=RopeConfig(theta=1e4))
 
 
-def lm_share(cfg: LMConfig, layers: int, chips: int, rank: int) -> LMConfig:
+def lm_share(cfg: LMConfig, layers, chips: int, rank: int,
+             vocab_chips: int = 0) -> LMConfig:
     """The share of ``cfg`` one chip of ``chips`` holds when they share each
     layer: the first ``layers`` layers (the others lie on further chips as
     pipeline stages), every attention head, the shared expert, and the
-    ``rank``-th contiguous part of the experts and of the vocabulary."""
+    ``rank``-th contiguous part of the experts and of the vocabulary.
+    ``layers`` may name the published layers held instead of counting them
+    (leading dense layers that repeat one kind and shape are held once):
+    a dense layer held keeps its dense MLP wherever it comes to lie.
+    ``vocab_chips``: the table and the head are sliced that many ways
+    where it is not as many as the experts (0: as many)."""
     experts = cfg.num_experts // chips
-    vocab = cfg.vocab_size // chips
+    vocab = cfg.vocab_size // (vocab_chips or chips)
+    if isinstance(layers, int):
+        layers = range(layers)
     return dataclasses.replace(
-        cfg, layer_types=cfg.layer_types[:layers],
-        num_heads_per_layer=cfg.num_heads_per_layer[:layers],
+        cfg, layer_types=tuple(cfg.layer_types[i] for i in layers),
+        num_heads_per_layer=tuple(cfg.num_heads_per_layer[i]
+                                  for i in layers),
+        dense_layers=tuple(at for at, i in enumerate(layers)
+                           if i in cfg.dense_layers),
         experts_held=(rank * experts, experts),
         vocab_held=(rank * vocab, vocab))
 
@@ -527,7 +553,7 @@ XING4_0_29B_A4B = LMConfig(
     kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
     v_head_dim=128, rope_mscale_all_dim=1.0, router_scoring="sigmoid",
     router_bias=True, residual_streams=4, sinkhorn_iters=20, hc_eps=1e-6,
-    hc_res_clamp=(-30.0, 30.0))
+    hc_res_clamp=(-30.0, 30.0), attn_gate="none")
 
 
 def sd15_xing4_expander() -> ModelFamily:
@@ -606,7 +632,8 @@ TINY_LATENT_LM = LMConfig(
     shared_expert_intermediate_size=16, routed_scaling_factor=2.0,
     q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8,
     qk_rope_head_dim=8, v_head_dim=8, rope_mscale_all_dim=1.0,
-    router_scoring="sigmoid", router_bias=True, residual_streams=4)
+    router_scoring="sigmoid", router_bias=True, residual_streams=4,
+    attn_gate="none")
 TINY_LATENT_EXPAND = dataclasses.replace(
     TINY, name="tiny-latent-expand",
     expander=lm_share(TINY_LATENT_LM, 4, chips=4, rank=0))
@@ -800,7 +827,7 @@ KANANA_2_30B_A3B = LMConfig(
     routed_scaling_factor=2.448, norm_topk_prob=True, norm_topk_eps=1e-20,
     rms_norm_eps=1e-6, q_lora_rank=0, kv_lora_rank=512,
     qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
-    router_scoring="sigmoid", router_bias=True)
+    router_scoring="sigmoid", router_bias=True, attn_gate="none")
 
 
 def sd15_kanana2_expander() -> ModelFamily:
@@ -828,7 +855,7 @@ TINY_KANANA_LM = LMConfig(
     routed_scaling_factor=2.448, norm_topk_prob=True, norm_topk_eps=1e-20,
     rms_norm_eps=1e-6, q_lora_rank=0, kv_lora_rank=16, qk_nope_head_dim=8,
     qk_rope_head_dim=8, v_head_dim=8, router_scoring="sigmoid",
-    router_bias=True)
+    router_bias=True, attn_gate="none")
 TINY_KANANA_EXPAND = dataclasses.replace(
     TINY, name="tiny-kanana2-expand",
     expander=lm_share(TINY_KANANA_LM, 4, chips=1, rank=0))
@@ -837,6 +864,90 @@ TINY_KANANA_EXPAND = dataclasses.replace(
 def tiny_kanana2_expander() -> ModelFamily:
     """Factory form of :data:`TINY_KANANA_EXPAND` (benchmark rehearsals)."""
     return TINY_KANANA_EXPAND
+
+
+# GigaChat3.5-432B-A28B
+# (huggingface.co/ai-sage/GigaChat3.5-432B-A28B config.json, ``model_type:
+# gigachat3_5``) at its published widths: 40 layers of hidden 7168 in the
+# pattern linear, linear, linear, latent (``full_attention_layers`` 3, 7,
+# ..., 39). A linear layer is a gated delta rule over 32 key and 64 value
+# heads of width 128 behind a 4-tap convolution, its read-out normed ``(1 +
+# w)`` and gated by ``2 sigmoid(z)``; a latent layer 64 heads through a
+# 1536-wide query latent over a cached 512-wide latent and one 64-wide
+# rotated key (neighbouring pairs, theta 1e5, YaRN by 8 over 32768 whose
+# mscale goes into the softmax), its heads' outputs under one sigmoid a
+# channel from a ``g_proj`` of its own. Every norm is ``x_hat * 2
+# sigmoid(w)`` and stands before AND after each sublayer; every SwiGLU is
+# clamped at 10. Three dense layers of width 18432, then a sigmoid router
+# with a selection bias over 256 experts of width 2048, 8 a token over
+# (their sum + 1e-20) at scale 2.5, plus one ungated shared expert.
+GIGACHAT_3_5 = LMConfig(
+    vocab_size=128256, hidden_size=7168,
+    layer_types=("linear", "linear", "linear", "latent") * 10,
+    num_heads_per_layer=(64,) * 40,
+    rope_full=RopeConfig(theta=1e5, factor=8.0, original_max_position=32768,
+                         beta_fast=32.0, beta_slow=1.0, attention_factor=1.0,
+                         interleaved=True),
+    dense_layers=(0, 1, 2), intermediate_size=18432, num_experts=256,
+    num_experts_per_tok=8, moe_intermediate_size=2048,
+    shared_expert_intermediate_size=2048, routed_scaling_factor=2.5,
+    norm_topk_prob=True, norm_topk_eps=1e-20, rms_norm_eps=1e-6,
+    attn_gate="element", linear_num_key_heads=32,
+    linear_num_value_heads=64, linear_key_head_dim=128,
+    linear_value_head_dim=128, linear_conv_kernel=4, q_lora_rank=1536,
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128, rope_mscale_all_dim=1.0, router_scoring="sigmoid",
+    router_bias=True, post_sublayer_norm=True, norm_sigmoid_scale=2.0,
+    linear_sigmoid_gate_scale=2.0, swiglu_limit=10.0)
+
+
+def sd15_gigachat35_expander() -> ModelFamily:
+    """SD1.5 with GigaChat3.5-432B-A28B as its resident prompt expander,
+    cut to one chip of sixteen that share each layer: published layers 0,
+    3, 4, 5 and 6 (the leading dense layers counted once, then one whole
+    period after them: latent, linear, linear, linear), experts 0-15 of
+    the 256 in each of the four expert layers, vocabulary ids 0-16031 (the
+    table and the head lie eight ways)."""
+    return dataclasses.replace(
+        SD15, name="sd15-gigachat35-expand",
+        expander=lm_share(GIGACHAT_3_5, layers=(0, 3, 4, 5, 6), chips=16,
+                          rank=0, vocab_chips=8))
+
+
+# Tiny expander with every kind of that model: a dense linear layer, then
+# latent, linear, linear, linear over experts; 2 key heads serving 4 value
+# heads of width 8 behind 4 taps; 4 latent heads through a 24-wide query
+# latent over a cached 16 + 8, neighbouring rotary pairs under YaRN whose
+# ramp lies inside a test's positions; gated norms before and after each
+# sublayer, the element-wise attention gate, the sigmoid read-out gate, a
+# clamp, 16 experts top-4 by biased sigmoid scores of which 4 are held, one
+# shared, a quarter of the vocabulary.
+TINY_GIGACHAT35_LM = LMConfig(
+    vocab_size=512, hidden_size=32,
+    layer_types=("linear", "linear", "linear", "latent") * 2,
+    num_heads_per_layer=(4,) * 8,
+    rope_full=RopeConfig(theta=1e4, factor=4.0, original_max_position=16,
+                         beta_fast=4.0, beta_slow=1.0, attention_factor=1.0,
+                         interleaved=True),
+    dense_layers=(0, 1, 2), intermediate_size=64, num_experts=16,
+    num_experts_per_tok=4, moe_intermediate_size=16,
+    shared_expert_intermediate_size=16, routed_scaling_factor=2.5,
+    norm_topk_prob=True, norm_topk_eps=1e-20, rms_norm_eps=1e-6,
+    attn_gate="element", linear_num_key_heads=2, linear_num_value_heads=4,
+    linear_key_head_dim=8, linear_value_head_dim=8, linear_conv_kernel=4,
+    q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8,
+    qk_rope_head_dim=8, v_head_dim=8, rope_mscale_all_dim=1.0,
+    router_scoring="sigmoid", router_bias=True, post_sublayer_norm=True,
+    norm_sigmoid_scale=2.0, linear_sigmoid_gate_scale=2.0, swiglu_limit=10.0)
+TINY_GIGACHAT35_EXPAND = dataclasses.replace(
+    TINY, name="tiny-gigachat35-expand",
+    expander=lm_share(TINY_GIGACHAT35_LM, (0, 3, 4, 5, 6), chips=4, rank=0))
+
+
+def tiny_gigachat35_expander() -> ModelFamily:
+    """Factory form of :data:`TINY_GIGACHAT35_EXPAND` (benchmark
+    rehearsals)."""
+    return TINY_GIGACHAT35_EXPAND
 
 
 FAMILIES = {f.name: f for f in (SD15, SD21, SD21_BASE, SDXL_BASE,

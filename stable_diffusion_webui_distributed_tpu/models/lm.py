@@ -16,8 +16,13 @@ whether queries and keys are normed per head, and whether the MLP is dense
 or a router over experts (ops/moe.py) with one shared expert, gated or
 not, or with none. An attention output passes a sigmoid gate before
 ``o_proj``: one a head from ``g_proj``, one a channel from the second half
-of ``q_proj``'s columns, or no gate (``LMConfig.attn_gate``). A norm is ``x_hat * scale`` or, zero-centred,
-``x_hat * (1 + weight)``. The router's scores are a softmax or sigmoids,
+of ``q_proj``'s columns, or no gate (``LMConfig.attn_gate``; a latent
+layer's gate is a ``g_proj`` of its own, one a head or one a value
+channel). A norm is ``x_hat * scale``, zero-centred ``x_hat * (1 +
+weight)``, or under a sigmoid ``x_hat * c sigmoid(weight)``
+(``norm_sigmoid_scale``); a SwiGLU may be clamped (``swiglu_limit``); a
+linear layer's read-out is gated by ``silu(z)`` or by ``c sigmoid(z)``
+(``linear_sigmoid_gate_scale``). The router's scores are a softmax or sigmoids,
 with or without a bias that chooses (ops/moe.py:route). With
 ``residual_streams`` over 1 a token is ``(streams, hidden)`` between
 sublayers and a :class:`StreamMixer` around each sublayer reads, writes
@@ -56,13 +61,19 @@ layer wants a slot for every position a sequence will decode, a sliding one
 no more than its window). A latent layer goes the same way with its one
 buffer: ``latent_shared`` is the prefill's ``(capacity, width)`` latents,
 ``latent`` each sequence's own ``(sequences, slots, width)`` behind them.
+A linear layer has nothing to share: a fork COPIES its
+``state`` and ``conv`` once a sequence, ``(sequences, value heads, key
+width, value width)`` and ``(sequences, taps - 1, channels)`` under the
+names they had, and row ``b`` of a step runs the recurrent step over
+sequence ``b``'s own state and a one-row convolution over its own kept
+rows; a row that does not count (a pad, a sequence that has ended) leaves
+both where they were, as a padded row of a chunk does.
 Norms, projections, the router, the experts and
-the head take the rows as they take a chunk's; attention alone tells the
-sequences apart, and reads what they share once. That holds for
-the ``full``, ``sliding`` and ``latent`` kinds of one stream
-(:func:`shares_a_step`); a model with a
-recurrent state, kept rows or several residual streams decodes one
-sequence a step.
+the head take the rows as they take a chunk's; the token mixers alone tell
+the sequences apart, and read what they share once. That holds for
+the ``full``, ``sliding``, ``latent`` and ``linear`` kinds of one stream
+(:func:`shares_a_step`); a model with conv layers' kept rows or several
+residual streams decodes one sequence a step.
 
 A LOOPED model (``LMConfig.total_ut_steps`` over 1: full attention, dense
 MLPs, one stream) passes every token through its whole stack that many
@@ -139,18 +150,23 @@ def buffers_of(kind: str, forked: bool = False) -> Tuple[str, ...]:
         kind, ATTENTION_BUFFERS + (SHARED_BUFFERS if forked else ()))
 
 
-def slots_axis(name: str) -> int:
+def slots_axis(name: str) -> int | None:
     """The axis of buffer ``name`` that counts positions: keys and values
     are ``(..., slots, kv heads, head_dim)``, latents ``(..., slots,
-    width)``."""
+    width)``; None for a buffer that has no positions (a linear layer's
+    state and kept inputs, a conv layer's kept rows: one size at every
+    length, which a fork copies whole)."""
+    if name in LINEAR_BUFFERS + CONV_BUFFERS:
+        return None
     return -2 if name in LATENT_BUFFERS + LATENT_SHARED else -3
 
 
 def shares_a_step(cfg: LMConfig) -> bool:
     """Whether several sequences can be decoded in one step: every layer
     keeps a row a position (keys and values in buffers or rings, or
-    latents) and a token is one stream."""
-    return (set(cfg.layer_types) <= {FULL, SLIDING, LATENT}
+    latents) or a recurrent state with a sequence axis (:class:`DeltaMixer`
+    under ``sequences``), and a token is one stream."""
+    return (set(cfg.layer_types) <= {FULL, SLIDING, LATENT, LINEAR}
             and cfg.residual_streams == 1)
 
 
@@ -240,14 +256,21 @@ class Linear(nn.Module):
 
 class RMSNorm(nn.Module):
     """``x_hat * scale`` over the last axis in float32;
-    ``zero_centred``: ``x_hat * (1 + weight)``."""
+    ``zero_centred``: ``x_hat * (1 + weight)``; ``sigmoid_scale`` ``c``
+    over 0: ``x_hat * c sigmoid(weight)``, the weight under a gate that is
+    ``c / 2`` where it is zero."""
 
     eps: float = 1e-6
     zero_centred: bool = False
+    sigmoid_scale: float = 0.0
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        if self.zero_centred:
+        if self.sigmoid_scale:
+            scale = self.sigmoid_scale * jax.nn.sigmoid(self.param(
+                "weight", nn.initializers.zeros,
+                (x.shape[-1],)).astype(jnp.float32))
+        elif self.zero_centred:
             scale = 1.0 + self.param(
                 "weight", nn.initializers.zeros,
                 (x.shape[-1],)).astype(jnp.float32)
@@ -259,19 +282,35 @@ class RMSNorm(nn.Module):
         return x * jax.lax.rsqrt(mean + self.eps) * scale
 
 
+def model_norm(cfg: LMConfig, **how) -> RMSNorm:
+    """A norm of the model's kind (``LMConfig.zero_centred_norm``,
+    ``norm_sigmoid_scale``)."""
+    return RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm,
+                   cfg.norm_sigmoid_scale, **how)
+
+
 class SwiGLU(nn.Module):
+    """``W_d(silu(W_g n) * W_u n)``; ``limit`` over 0:
+    ``silu(min(W_g n, limit)) * clip(W_u n, -limit, limit)``."""
+
     width: int
     dtype: jnp.dtype = jnp.float32
     quant: bool = False
+    limit: float = 0.0
 
     @nn.compact
     def __call__(self, n: jax.Array) -> jax.Array:
         def lin(features, name):
             return Linear(features, self.dtype, self.quant, name=name)
 
-        hidden = jax.nn.silu(lin(self.width, "gate_proj")(n)) \
-            * lin(self.width, "up_proj")(n)
-        return lin(n.shape[-1], "down_proj")(hidden)
+        gate = lin(self.width, "gate_proj")(n)
+        if self.limit:
+            gate = jnp.minimum(gate, self.limit)
+        gate = jax.nn.silu(gate)
+        up = lin(self.width, "up_proj")(n)
+        if self.limit:
+            up = jnp.clip(up, -self.limit, self.limit)
+        return lin(n.shape[-1], "down_proj")(gate * up)
 
 
 class Experts(nn.Module):
@@ -324,14 +363,16 @@ class MoE(nn.Module):
         compute = [w.astype(self.dtype) for w in kernels]
         routed, path = moe.routed_experts(
             n.astype(self.dtype), routing, *compute, first=first,
-            num_experts=cfg.num_experts, meshed=self.meshed)
+            num_experts=cfg.num_experts, meshed=self.meshed,
+            limit=cfg.swiglu_limit)
         EXPANDER.record_product(path)
         load, none_held = moe.load_counts(routing, first, held, valid)
         beside = (routing.experts, load, none_held)
         if not cfg.shared_expert_intermediate_size:     # no shared expert
             return routed, beside
         shared = SwiGLU(cfg.shared_expert_intermediate_size, self.dtype,
-                        self.quant, name="shared_expert")(n)
+                        self.quant, cfg.swiglu_limit,
+                        name="shared_expert")(n)
         if cfg.shared_expert_gate:
             shared = shared * jax.nn.sigmoid(Linear(
                 1, self.dtype, self.quant, name="shared_expert_gate")(n))
@@ -401,10 +442,8 @@ class Attention(nn.Module):
             q = lin(heads * dim, "q_proj")(n).reshape(tokens, heads, dim)
         k = lin(kv * dim, "k_proj")(n).reshape(tokens, kv, dim)
         if cfg.qk_norm:
-            q = RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm,
-                        name="q_norm")(q)
-            k = RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm,
-                        name="k_norm")(k)
+            q = model_norm(cfg, name="q_norm")(q)
+            k = model_norm(cfg, name="k_norm")(k)
         q = apply_rope(q, cos, sin, rope.interleaved).astype(self.dtype)
         k = apply_rope(k, cos, sin, rope.interleaved).astype(store)
         v = lin(kv * dim, "v_proj")(n).reshape(tokens, kv, dim).astype(store)
@@ -510,7 +549,12 @@ class LatentAttention(nn.Module):
     each sequence's own rows ``(sequences, slots, width)`` from position
     ``forked_at`` on. One KV head serves every head, so the sequences' heads
     are all query rows of ONE product over the shared latents, read once;
-    ``shared`` is returned behind the cache as it came."""
+    ``shared`` is returned behind the cache as it came.
+
+    With ``attn_gate`` the heads' outputs pass a sigmoid before ``o_proj``,
+    one a value channel (``"element"``) or one a head (``"head"``) from a
+    ``g_proj`` of the layer's input: in every form on the per-head values,
+    so in the absorbed and forked forms after ``W_v``."""
 
     config: LMConfig
     layer: int
@@ -531,8 +575,7 @@ class LatentAttention(nn.Module):
             return Linear(features, self.dtype, self.quant, name=name)
 
         def norm(name):
-            return RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm,
-                           name=name)
+            return model_norm(cfg, name=name)
 
         def dot(spec, a, b):
             return jnp.einsum(spec, a.astype(self.dtype), b,
@@ -606,6 +649,13 @@ class LatentAttention(nn.Module):
             ATTENTION.record(form, tokens, cache.shape[0], cache.shape[1])
         if form != LATENT_EXPANDED:
             out = dot("thr,rhd->thd", out, w_v)
+        if cfg.attn_gate == "element":
+            out = out.astype(f32) * jax.nn.sigmoid(
+                lin(heads * v_dim, "g_proj")(n)).reshape(
+                    tokens, heads, v_dim)
+        elif cfg.attn_gate == "head":
+            out = out.astype(f32) * jax.nn.sigmoid(
+                lin(heads, "g_proj")(n))[:, :, None]
         return (lin(n.shape[-1], "o_proj")(
             out.reshape(tokens, heads * v_dim)), cache) \
             + ((shared,) if sequences else ())
@@ -759,6 +809,19 @@ def causal_conv(kernel: jax.Array, kept: jax.Array, x: jax.Array, length):
         inputs, length, taps - 1, 0).astype(kept.dtype)
 
 
+def causal_conv_rows(kernel: jax.Array, kept: jax.Array, x: jax.Array,
+                     real: jax.Array):
+    """:func:`causal_conv` of ONE row for each of ``B`` sequences: ``x``
+    ``(B, channels)`` is sequence ``b``'s one input behind its own ``kept``
+    ``(B, taps - 1, channels)``. A sequence whose row does not count
+    (``real`` ``(B,)``) keeps the rows it had."""
+    inputs = jnp.concatenate(
+        [kept.astype(kernel.dtype), x[:, None, :]], axis=1)
+    out = sum(kernel[j] * inputs[:, j] for j in range(kernel.shape[0]))
+    return out, jnp.where(real[:, None, None], inputs[:, 1:],
+                          kept.astype(kernel.dtype)).astype(kept.dtype)
+
+
 def _decay_init(key, shape, dtype=jnp.float32):
     """``A_log``: the log of a decay rate drawn uniformly from (0, 16)."""
     return jnp.log(jax.random.uniform(key, shape, dtype, 1e-3, 16.0))
@@ -769,17 +832,23 @@ class DeltaMixer(nn.Module):
     through a causal depth-wise convolution and SiLU, queries and keys
     L2-normalised per head, then the gated delta rule over the layer's
     recurrent state; the read-out is RMS-normed per head, gated by
-    ``silu(z)`` and projected. ``state`` is ``(value heads, key width,
+    ``silu(z)`` (or, ``linear_sigmoid_gate_scale`` ``c`` over 0, normed
+    ``x_hat * (1 + weight)`` and gated by ``c sigmoid(z)``) and projected.
+    ``state`` is ``(value heads, key width,
     value width)`` float32; ``conv`` holds the convolution's last
-    ``taps - 1`` real inputs."""
+    ``taps - 1`` real inputs. ``sequences``: row ``b`` is sequence ``b``'s
+    one token, ``state`` and ``conv`` carry the sequences in front, each
+    row steps its own, and a row that is not ``real`` leaves both."""
 
     config: LMConfig
     dtype: jnp.dtype = jnp.float32
     quant: bool = False
 
     @nn.compact
-    def __call__(self, n, real, length, state, conv):
+    def __call__(self, n, real, length, state, conv,
+                 sequences: bool = False):
         cfg = self.config
+        EXPANDER.record_delta(delta_rule.form(n.shape[0], sequences))
         k_heads, v_heads = cfg.linear_num_key_heads, cfg.linear_num_value_heads
         k_dim, v_dim = cfg.linear_key_head_dim, cfg.linear_value_head_dim
         channels, taps = cfg.linear_conv_channels, cfg.linear_conv_kernel
@@ -798,7 +867,10 @@ class DeltaMixer(nn.Module):
         a_log = self.param("A_log", _decay_init, (v_heads,)).astype(f32)
         dt_bias = self.param("dt_bias", nn.initializers.ones,
                              (v_heads,)).astype(f32)
-        qkv, conv = causal_conv(kernel, conv, qkv, length)
+        if sequences:
+            qkv, conv = causal_conv_rows(kernel, conv, qkv, real)
+        else:
+            qkv, conv = causal_conv(kernel, conv, qkv, length)
         qkv = jax.nn.silu(qkv)
         q, k, v = jnp.split(
             qkv, [k_heads * k_dim, 2 * k_heads * k_dim], axis=-1)
@@ -814,12 +886,19 @@ class DeltaMixer(nn.Module):
                       -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias), 0.0)
         beta = jnp.where(real[:, None], jax.nn.sigmoid(b), 0.0)
         # computed in float32 whatever the buffer holds
-        out, after = delta_rule.gated_delta_rule(
+        step = delta_rule.recurrent_step_each if sequences \
+            else delta_rule.gated_delta_rule
+        out, after = step(
             state.astype(f32), unit(q) * k_dim ** -0.5, unit(k),
             v.reshape(tokens, v_heads, v_dim), g, beta)
         state = after.astype(state.dtype)
-        out = RMSNorm(cfg.rms_norm_eps, name="norm")(out) \
-            * jax.nn.silu(z.reshape(tokens, v_heads, v_dim))
+        # the read-out's norm first, then its gate (the order of a
+        # trace's ops is part of its executable's key)
+        gate_scale = cfg.linear_sigmoid_gate_scale
+        out = RMSNorm(cfg.rms_norm_eps, bool(gate_scale), name="norm")(out)
+        z = z.reshape(tokens, v_heads, v_dim)
+        out = out * (gate_scale * jax.nn.sigmoid(z) if gate_scale
+                     else jax.nn.silu(z))
         return (lin(n.shape[-1], "out_proj")(
             out.reshape(tokens, v_heads * v_dim)), state, conv)
 
@@ -892,7 +971,8 @@ class DecoderLayer(nn.Module):
             if kind == LINEAR:
                 mixed, *after = DeltaMixer(
                     cfg, self.dtype, self.quant, name="delta")(
-                        n, counted(), end - start, *buffers)
+                        n, counted(), end - start, *buffers,
+                        sequences=sequences)
             elif kind == CONV:
                 mixed, *after = ShortConv(
                     cfg, self.dtype, self.quant, self.conv_dtype,
@@ -915,7 +995,7 @@ class DecoderLayer(nn.Module):
             """(out, what an expert layer routed; None for a dense one)."""
             if self.layer in cfg.dense_layers:
                 return SwiGLU(cfg.intermediate_size, self.dtype, self.quant,
-                              name="mlp")(n), None
+                              cfg.swiglu_limit, name="mlp")(n), None
             return MoE(cfg, self.dtype, self.quant, self.meshed,
                        name="mlp")(n, counted())
 
@@ -924,15 +1004,13 @@ class DecoderLayer(nn.Module):
         for sublayer, norm_name, hc in (
                 (token_mixer, "input_norm", "attn_hc"),
                 (mlp, "post_attention_norm", "mlp_hc")):
-            norm = RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm,
-                           name=norm_name)
+            norm = model_norm(cfg, name=norm_name)
 
             def normed_after(out):
                 """The sublayer's output as the residual takes it."""
                 if not cfg.post_sublayer_norm:
                     return out
-                return RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm,
-                               name=norm_name + "_2")(out)
+                return model_norm(cfg, name=norm_name + "_2")(out)
 
             if streams == 1:
                 out, more = sublayer(norm(x))
@@ -984,7 +1062,8 @@ class DecoderLM(nn.Module):
         if sequences:
             if not shares_a_step(cfg):
                 raise ValueError("a step of several sequences wants full, "
-                                 "sliding or latent layers and one stream")
+                                 "sliding, latent or linear layers and one "
+                                 "stream")
             q_pos = jnp.full(tokens.shape, start, jnp.int32)
             end, all_logits = start + 1, True
             real = jnp.arange(tokens.shape[0]) < length
@@ -1041,7 +1120,7 @@ class DecoderLM(nn.Module):
             return x, written, routed
 
         def final_norm(**how):
-            return RMSNorm(cfg.rms_norm_eps, cfg.zero_centred_norm, **how)
+            return model_norm(cfg, **how)
 
         if cfg.total_ut_steps == 1:
             x, cache, routed = stack(named_layer, x, cache)

@@ -860,6 +860,21 @@ def render() -> str:
     _scalar(lines, "sdtpu_expander_exit_lambda_max", "gauge",
             "Largest exit probability a pass's gate gave.",
             expander["exit_lambda_max"])
+    _labeled_family(
+        lines, "sdtpu_expander_delta_mixers_total", "counter",
+        "Gated-delta-rule mixers traced, by the form their recurrence "
+        "took (recurrent_forked: one token each of several sequences, "
+        "every one over a state of its own).",
+        [(f'form="{_label(form)}"', n)
+         for form, n in sorted(expander["delta_mixers"].items())])
+    _scalar(lines, "sdtpu_expander_state_bytes_stepped_total", "counter",
+            "Bytes of linear layers' recurrent states and kept inputs the "
+            "prompt expander's decode steps read and wrote.",
+            expander["state_bytes_stepped"])
+    _scalar(lines, "sdtpu_expander_fork_bytes_copied_total", "counter",
+            "Bytes of recurrent state and kept inputs the prompt "
+            "expander's forks copied, once a sequence.",
+            expander["fork_bytes_copied"])
 
     _labeled_family(
         lines, "sdtpu_stage_compiles_total", "counter",

@@ -12,6 +12,11 @@ number of tokens, which is static), as ops/moe.py chooses its product:
 
 - ``T == 1`` (a decode step): the recurrence as written, element-wise
   products and sums over the state, so float32 stays float32 on a TPU.
+  Several sequences a step (:func:`recurrent_step_each`: row ``b`` is
+  sequence ``b``'s one token over its OWN state ``(B, H, K, V)``) are the
+  same step once a sequence, nothing shared between them: a state has no
+  positions, so unlike keys, values or latents no part of it can be read
+  once for all.
 - ``T > 1`` (prefill): the chunk-wise form. Tokens are cut into chunks of
   :data:`CHUNK`; inside a chunk the writes depend on each other through a
   unit lower-triangular system, solved row by row as the published
@@ -45,6 +50,12 @@ def recurrent_step(state, q, k, v, g, beta):
     u = beta[:, None] * (v - seen)
     state = state + k[:, :, None] * u[:, None, :]
     return jnp.sum(state * q[:, :, None], axis=1), state
+
+
+#: :func:`recurrent_step` for each of ``B`` sequences: every operand with a
+#: leading sequence axis, ``state`` ``(B, H, K, V)``. Returns ``(o (B, H,
+#: V), state)``.
+recurrent_step_each = jax.vmap(recurrent_step)
 
 
 def recurrent(state, q, k, v, g, beta):
@@ -132,6 +143,13 @@ def gated_delta_rule(state, q, k, v, g, beta):
     return chunked(state, q, k, v, g, beta)
 
 
-def form(tokens: int) -> str:
-    """Which form a chunk of ``tokens`` takes (spans, counters)."""
-    return "recurrent" if tokens == 1 else "chunked"
+RECURRENT, CHUNKED, FORKED = "recurrent", "chunked", "recurrent_forked"
+
+
+def form(tokens: int, sequences: bool = False) -> str:
+    """Which form a chunk of ``tokens`` takes (spans, counters);
+    ``sequences``: the rows are one token each of as many sequences, each
+    over a state of its own."""
+    if sequences:
+        return FORKED
+    return RECURRENT if tokens == 1 else CHUNKED

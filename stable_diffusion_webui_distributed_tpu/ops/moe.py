@@ -92,12 +92,15 @@ def row_tile(tokens: int, k: int, num_experts: int) -> int:
     return int(min(256, max(MIN_ROW_TILE, 1 << (mean - 1).bit_length())))
 
 
-def _swiglu(x, w_gate, w_up, w_down):
+def _swiglu(x, w_gate, w_up, w_down, limit: float = 0.0):
     """``W_d(silu(W_g x) * W_u x)`` for one expert; float32 accumulation,
-    operands in ``x``'s dtype, float32 result."""
+    operands in ``x``'s dtype, float32 result. ``limit`` over 0 clamps:
+    ``silu(min(W_g x, limit)) * clip(W_u x, -limit, limit)``."""
     f32 = jnp.float32
     gate = jnp.dot(x, w_gate, preferred_element_type=f32)
     up = jnp.dot(x, w_up, preferred_element_type=f32)
+    if limit:
+        gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
     hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
     return jnp.dot(hidden, w_down, preferred_element_type=f32)
 
@@ -109,7 +112,7 @@ def held_mask(experts: jax.Array, first: int, count: int):
 
 
 def _grouped(x, routing: Routing, w_gate, w_up, w_down, first: int,
-             num_experts: int):
+             num_experts: int, limit: float = 0.0):
     tokens, k = routing.experts.shape
     count = w_gate.shape[0]
     tile = row_tile(tokens, k, num_experts)
@@ -138,7 +141,7 @@ def _grouped(x, routing: Routing, w_gate, w_up, w_down, first: int,
     def one_tile(t, ys):
         expert = tile_expert[t]
         y = _swiglu(jax.lax.dynamic_slice_in_dim(xs, t * tile, tile),
-                    w_gate[expert], w_up[expert], w_down[expert])
+                    w_gate[expert], w_up[expert], w_down[expert], limit)
         return jax.lax.dynamic_update_slice_in_dim(ys, y, t * tile, 0)
 
     ys = jax.lax.fori_loop(0, tile_end[-1], one_tile,
@@ -151,7 +154,7 @@ def _grouped(x, routing: Routing, w_gate, w_up, w_down, first: int,
 
 
 def _chosen(x, routing: Routing, w_gate, w_up, w_down, first: int,
-            kernel: bool = False):
+            kernel: bool = False, limit: float = 0.0):
     count = w_gate.shape[0]
     local, held = held_mask(routing.experts[0], first, count)
     order = jnp.argsort(~held, stable=True)              # held ones first
@@ -159,18 +162,19 @@ def _chosen(x, routing: Routing, w_gate, w_up, w_down, first: int,
     weights = jnp.where(held, routing.weights[0], 0.0)[order]
     if kernel:
         return moe_kernel.chosen_experts(x, experts, weights, jnp.sum(held),
-                                         w_gate, w_up, w_down)
+                                         w_gate, w_up, w_down, limit=limit)
 
     def one_expert(j, acc):
         expert = experts[j]
         return acc + weights[j] * _swiglu(x, w_gate[expert], w_up[expert],
-                                          w_down[expert])
+                                          w_down[expert], limit)
 
     return jax.lax.fori_loop(0, jnp.sum(held), one_expert,
                              jnp.zeros(x.shape, jnp.float32))
 
 
-def _block(x, routing: Routing, w_gate, w_up, w_down, first: int):
+def _block(x, routing: Routing, w_gate, w_up, w_down, first: int,
+           limit: float = 0.0):
     """A step of 2-8 rows through the kernel: the step's distinct held
     experts first (as :func:`_chosen` orders a token's held picks), each
     with a column of per-row weights, zero where the row did not choose
@@ -185,7 +189,8 @@ def _block(x, routing: Routing, w_gate, w_up, w_down, first: int):
     chosen = jnp.any(weights != 0.0, axis=1)
     experts = jnp.argsort(~chosen, stable=True)[:slots]  # chosen ones first
     return moe_kernel.chosen_experts(x, experts, weights[experts],
-                                     jnp.sum(chosen), w_gate, w_up, w_down)
+                                     jnp.sum(chosen), w_gate, w_up, w_down,
+                                     limit=limit)
 
 
 def choose(platform: str, tokens: int, dtype, d: int, f: int, *,
@@ -208,21 +213,23 @@ def choose(platform: str, tokens: int, dtype, d: int, f: int, *,
 
 def routed_experts(x: jax.Array, routing: Routing, w_gate: jax.Array,
                    w_up: jax.Array, w_down: jax.Array, *, first: int,
-                   num_experts: int, meshed: bool = False):
+                   num_experts: int, meshed: bool = False,
+                   limit: float = 0.0):
     """(this chip's part of ``sum_e w_e E_e(x)``, the product taken):
     ``x`` is ``(T, d)``, the kernels are stacked ``(held, d, f)``, ``(held,
     d, f)``, ``(held, f, d)`` and are experts ``first .. first + held - 1``
-    of ``num_experts``; ``meshed`` says a mesh will partition the program.
+    of ``num_experts``; ``meshed`` says a mesh will partition the program;
+    ``limit`` over 0 clamps every expert's SwiGLU (:func:`_swiglu`).
     Float32 ``(T, d)``."""
     path = choose(jax.default_backend(), x.shape[0], x.dtype,
                   *w_gate.shape[1:], meshed=meshed)
     if path == GROUPED:
         return _grouped(x, routing, w_gate, w_up, w_down, first,
-                        num_experts), path
+                        num_experts, limit), path
     if x.shape[0] > 1:
-        return _block(x, routing, w_gate, w_up, w_down, first), path
+        return _block(x, routing, w_gate, w_up, w_down, first, limit), path
     return _chosen(x, routing, w_gate, w_up, w_down, first,
-                   kernel=path == KERNEL), path
+                   kernel=path == KERNEL, limit=limit), path
 
 
 def load_counts(routing: Routing, first: int, count: int, valid=None):

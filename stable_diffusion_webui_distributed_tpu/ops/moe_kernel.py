@@ -59,8 +59,9 @@ from jax.experimental import pallas as pl
 #: lane width: ``d`` and the ``f`` tile are multiples of it
 LANES = 128
 #: VMEM the three kernels' blocks may take, double-buffered. The ``f`` tile
-#: is the widest that fits: 512 at the three published shapes (3072 x 1024,
-#: 2048 x 512 and 3584 x 1024; 18.9, 12.6 and 22.0 MB).
+#: is the widest that fits: 512 at the first three published shapes (3072 x
+#: 1024, 2048 x 512 and 3584 x 1024; 18.9, 12.6 and 22.0 MB), all of 768 at
+#: 2048 x 768, and 256 at 7168 x 2048 (22.0 MB), the first under 512.
 _WEIGHT_VMEM = 24 * 2 ** 20
 #: beside the blocks: x, the output, a tile's float32 intermediates (Mosaic
 #: compiles both published shapes with 1 MiB; the decode span does not move
@@ -99,7 +100,7 @@ def _column(weights_ref, first, rows: int, block: int):
 
 
 def _kernel(ids_ref, held_ref, weights_ref, x_ref, gate_ref, up_ref,
-            down_ref, out_ref):
+            down_ref, out_ref, *, limit: float = 0.0):
     step, tile = pl.program_id(0), pl.program_id(1)
     slot = step - (pl.num_programs(0) - held_ref[0])   # idle steps first
     rows = weights_ref.shape[0] // ids_ref.shape[0]    # static: 1, or 2-8
@@ -117,8 +118,13 @@ def _kernel(ids_ref, held_ref, weights_ref, x_ref, gate_ref, up_ref,
             jnp.dot, preferred_element_type=jnp.float32,
             precision=(jax.lax.Precision.DEFAULT
                        if x.dtype == jnp.bfloat16 else None))
-        hidden = (jax.nn.silu(dot(x, gate_ref[...]))
-                  * dot(x, up_ref[...])).astype(x.dtype)
+        if limit:   # the clamp is element-wise, so a tile's is the whole's
+            hidden = (jax.nn.silu(jnp.minimum(dot(x, gate_ref[...]), limit))
+                      * jnp.clip(dot(x, up_ref[...]), -limit, limit)
+                      ).astype(x.dtype)
+        else:
+            hidden = (jax.nn.silu(dot(x, gate_ref[...]))
+                      * dot(x, up_ref[...])).astype(x.dtype)
         if rows == 1:
             out_ref[...] += weights_ref[slot] * dot(hidden, down_ref[...])
         else:
@@ -129,9 +135,9 @@ def _kernel(ids_ref, held_ref, weights_ref, x_ref, gate_ref, up_ref,
                 weight != 0.0, weight * dot(hidden, down_ref[...]), 0.0)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tile", "interpret", "limit"))
 def _call(ids, held, weights, x, w_gate, w_up, w_down, *, tile: int,
-          interpret: bool):
+          interpret: bool, limit: float = 0.0):
     """Jitted on its own so that the expert layers of one model trace and
     lower the kernel once per shape, not once per layer.
 
@@ -195,7 +201,8 @@ def _call(ids, held, weights, x, w_gate, w_up, w_down, *, tile: int,
     whole = pl.BlockSpec((block_rows, d), lambda step, t, *_: (0, 0))
     itemsize = w_gate.dtype.itemsize
     return pl.pallas_call(
-        _kernel,
+        # the unclamped kernel is the function itself, as it always was
+        functools.partial(_kernel, limit=limit) if limit else _kernel,
         out_shape=jax.ShapeDtypeStruct((block_rows, d), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -219,7 +226,8 @@ def _call(ids, held, weights, x, w_gate, w_up, w_down, *, tile: int,
 def chosen_experts(x: jax.Array, experts: jax.Array, weights: jax.Array,
                    held: jax.Array, w_gate: jax.Array, w_up: jax.Array,
                    w_down: jax.Array, *,
-                   interpret: bool | None = None) -> jax.Array:
+                   interpret: bool | None = None,
+                   limit: float = 0.0) -> jax.Array:
     """``sum_j weights[j] E_experts[j](x)`` over the first ``held`` of the
     slots: ``x`` ``(rows, d)`` with ``rows`` at most :data:`ROW_BLOCK`,
     ``experts`` ``(slots,)`` local ids with the held ones first, ``held``
@@ -227,7 +235,8 @@ def chosen_experts(x: jax.Array, experts: jax.Array, weights: jax.Array,
     them. ``weights`` is float32 ``(slots,)`` for one row and ``(slots,
     rows)`` for several, zero where a row did not choose the slot's expert
     (such a row gets nothing from it). Float32 ``(rows, d)``.
-    ``interpret`` is for a compile without the chip."""
+    ``interpret`` is for a compile without the chip; ``limit`` over 0
+    clamps each expert's SwiGLU as ``moe._swiglu`` does."""
     rows = x.shape[0]
     _, d, f = w_gate.shape
     tile = f_tile(d, f, w_gate.dtype.itemsize)
@@ -243,5 +252,6 @@ def chosen_experts(x: jax.Array, experts: jax.Array, weights: jax.Array,
         weights = weights.reshape(-1)
     out = _call(experts.astype(jnp.int32),
                 jnp.reshape(held, (1,)).astype(jnp.int32), weights, x,
-                w_gate, w_up, w_down, tile=tile, interpret=interpret)
+                w_gate, w_up, w_down, tile=tile, interpret=interpret,
+                limit=limit)
     return out if rows == 1 else out[:rows]

@@ -30,7 +30,9 @@ key alone, so where the model's kinds allow (models/lm.py:shares_a_step)
 the prompt is prefilled once at one sequence, each image's first token is
 drawn from that one row of logits under its own key, the cache is forked
 (cache/kv.py:fork: the prompt's rows stay where the prefill left them, held
-once, and each sequence gets rows of its own for what it decodes) and the
+once, each sequence gets rows of its own for what it decodes, and what has
+no positions, a linear layer's recurrent state and kept inputs, is copied
+once a sequence) and the
 scan runs over all of them, so a step streams the fixed weights once, each
 distinct expert once and the prompt's keys and values (a latent layer's
 latents) once. Their count is
@@ -133,8 +135,10 @@ class PromptExpander:
                             donate_argnums=(1,)))
 
     def _fork_fn(self, capacity: int, sequences: int, own_slots: int):
-        """What a fork makes anew (cache/kv.py:own_rows): the cache it is
-        called with gives the shapes and is not read."""
+        """What a fork makes anew (cache/kv.py:own_rows): of a buffer that
+        keeps positions the cache it is called with gives the shape and
+        is not read; one that keeps none (a linear layer's state and kept
+        inputs) is copied once a sequence, all in this one executable."""
         return self.engine._cached(
             ("expand_fork", capacity, sequences, own_slots),
             lambda: jax.jit(functools.partial(
@@ -247,8 +251,14 @@ class PromptExpander:
             own_slots = chunks * DECODE_STEPS
             sizes = kv.state_bytes(self.config, capacity, self.cache.dtype,
                                    batch, own_slots)
-            copied = sum(kv.state_bytes(     # one sequence's: a snapshot
-                self.config, capacity, self.cache.dtype).values())
+            alone = kv.state_bytes(     # one sequence's: a snapshot
+                self.config, capacity, self.cache.dtype)
+            copied = sum(alone.values())
+            # what a fork copies once a sequence, and what a step of the
+            # sequences reads and writes of it
+            fork_copied = kv.copied_bytes(self.config, self.cache.dtype,
+                                          batch)
+            stepped = 2 * batch * alone.get(lm.LINEAR, 0)
         with obs_spans.span("expand.prefix_copy", bytes=copied) as sp:
             cache, held = self.cache.acquire(prefix, capacity)
             if sp is not None:
@@ -261,8 +271,10 @@ class PromptExpander:
         looped = {"passes": passes} if passes > 1 else {}
         # and of one whose latent layers share a step: the form a decode
         # step takes over the cache
-        stepped = {"latent": lm.latent_form(1, sequences=batch > 1)} \
+        how = {"latent": lm.latent_form(1, sequences=batch > 1)} \
             if latent and self.shares_a_step else {}
+        if recurrent and self.shares_a_step:    # and its recurrence
+            how["delta"] = delta_rule.form(1, sequences=batch > 1)
         exits = []        # per executable call of a looped model
         routed = []       # per executable call: (load, none held)
         masked = 0        # padded rows kept out of a recurrence or kept rows
@@ -280,8 +292,9 @@ class PromptExpander:
                 attrs["form"] = delta_rule.form(len(padded))
             if latent:        # the form its attention takes over the cache
                 attrs["latent"] = lm.latent_form(len(padded))
-                if self.shares_a_step:  # whose first tokens the chunk draws
-                    attrs["sequences"] = 1 if keep else live
+            if (latent or recurrent) and self.shares_a_step:
+                # whose first tokens the chunk draws
+                attrs["sequences"] = 1 if keep else live
             # the instruction's chunk yields no token that is kept: it runs
             # at one sequence whatever follows it
             with obs_spans.span("expand.prefill", **attrs):
@@ -305,10 +318,14 @@ class PromptExpander:
             masked += attrs.get("padded", 0)
         forked_at = len(prefix) + len(user)
         if batch > 1:
-            # the bytes made: the prompt's rows stay where they are
+            # the bytes made: the prompt's rows stay where they are, and
+            # what has no positions is copied once a sequence (the
+            # prefill's own is let go)
             with obs_spans.span("expand.fork", sequences=batch,
-                                bytes=sum(sizes.values()) - copied,
-                                **looped, **stepped):
+                                bytes=sum(sizes.values()) - copied
+                                + alone.get(lm.LINEAR, 0),
+                                state_bytes_copied=fork_copied,
+                                **looped, **how):
                 cache = kv.forked(
                     cache, self._fork_fn(capacity, batch, own_slots)(cache))
                 jax.block_until_ready(cache)    # fenced, as a prefill is
@@ -335,7 +352,7 @@ class PromptExpander:
                     and all(tok.eos in one for one in made)):
                 break
             with obs_spans.span("expand.decode_chunk", tokens=DECODE_STEPS,
-                                sequences=live, **looped, **stepped):
+                                sequences=live, **looped, **how):
                 cache, token, position, out, step_load, step_none, *read = \
                     decode(params, cache, token, position, key,
                            temperature, *more)
@@ -389,6 +406,8 @@ class PromptExpander:
                     sinkhorn_iters=(self.config.sinkhorn_iters
                                     if self.config.residual_streams > 1
                                     else 0),
+                    state_bytes_stepped=steps * stepped,
+                    fork_bytes_copied=fork_copied,
                     **self._passes_run(exits, steps))
 
         if later is None:

@@ -440,7 +440,8 @@ def replay_sites(rows) -> None:
     counters = {"attention": ATTENTION.record, "upsample": UPSAMPLE.record,
                 "product": EXPANDER.record_product,
                 "mixer": EXPANDER.record_mixer,
-                "conv": EXPANDER.record_conv}
+                "conv": EXPANDER.record_conv,
+                "delta": EXPANDER.record_delta}
     for counter, *args in rows:
         counters[counter](*args)
 
@@ -557,7 +558,16 @@ class ExpanderStats:
     ``mixer_products`` counts the residual streams' mixers the same way, by
     the form ops/stream_mixer.py:choose gave them, and ``conv_mixers`` the
     short-convolution mixers (models/lm.py:ShortConv), by whether the
-    trace was of one token (``step``) or of a longer chunk.
+    trace was of one token (``step``) or of a longer chunk, and
+    ``delta_mixers`` the gated-delta-rule mixers (models/lm.py:DeltaMixer)
+    by the form their recurrence took (ops/delta_rule.py:form:
+    ``recurrent`` one token, ``chunked`` a longer chunk,
+    ``recurrent_forked`` one token each of several sequences, every one
+    over a state of its own). ``state_bytes_stepped`` is what the decode
+    steps read and wrote of linear layers' recurrent states and kept
+    inputs (a step reads and writes each of its sequences' once a layer),
+    ``fork_bytes_copied`` what forks copied of them (once a sequence; a
+    buffer that keeps positions is never copied).
     Of a looped model (``LMConfig.total_ut_steps`` over 1): ``layer_passes``,
     the passes of the whole stack its decode steps ran (every pass of every
     step, whichever the head read; over ``decode_steps``: passes a token); ``exit_pass``, the tokens made by the
@@ -597,6 +607,10 @@ class ExpanderStats:
                              "grouped": 0}  # guarded-by: _lock
             self.mixers = {"kernel": 0, "loop": 0}  # guarded-by: _lock
             self.convs = {"step": 0, "chunk": 0}  # guarded-by: _lock
+            self.deltas = {"recurrent": 0, "chunked": 0,
+                           "recurrent_forked": 0}  # guarded-by: _lock
+            self.state_bytes_stepped = 0  # guarded-by: _lock
+            self.fork_bytes_copied = 0  # guarded-by: _lock
 
     def record_product(self, path: str) -> None:
         """One expert layer in one trace took product ``path``."""
@@ -616,6 +630,12 @@ class ExpanderStats:
         with self._lock:
             self.convs[form] += 1
 
+    def record_delta(self, form: str) -> None:
+        """One gated-delta-rule mixer in one trace, of ``form``."""
+        _note_site("delta", str(form))
+        with self._lock:
+            self.deltas[form] += 1
+
     def record(self, *, prefilled: int, from_prefix: int, sequences: int,
                decoded: int, decode_steps: int, experts_read: int, load,
                none_held: int,
@@ -625,7 +645,8 @@ class ExpanderStats:
                rows_attended: int = 0, rows_read: int = 0,
                rows_read_shared: int = 0,
                layer_passes: int = 0, exit_pass=(),
-               exit_lambda_max: float = 0.0) -> None:
+               exit_lambda_max: float = 0.0, state_bytes_stepped: int = 0,
+               fork_bytes_copied: int = 0) -> None:
         """``load`` is (expert layers, held experts) counts of one
         request; ``decode_steps`` the steps its decode executables ran
         (whole chunks, so at least ``decoded / sequences - 1``), each a
@@ -655,6 +676,8 @@ class ExpanderStats:
             self.residual_streams = int(residual_streams)
             self.sinkhorn_iters = int(sinkhorn_iters)
             self.layer_passes += int(layer_passes)
+            self.state_bytes_stepped += int(state_bytes_stepped)
+            self.fork_bytes_copied += int(fork_bytes_copied)
             if len(exit_pass):
                 old = self.exit_pass or [0] * len(exit_pass)
                 self.exit_pass = [a + int(b)
@@ -690,6 +713,9 @@ class ExpanderStats:
                 "expert_products": dict(self.products),
                 "mixer_products": dict(self.mixers),
                 "conv_mixers": dict(self.convs),
+                "delta_mixers": dict(self.deltas),
+                "state_bytes_stepped": self.state_bytes_stepped,
+                "fork_bytes_copied": self.fork_bytes_copied,
                 "layer_passes": self.layer_passes,
                 "exit_pass": list(self.exit_pass),
                 "exit_lambda_max": self.exit_lambda_max,

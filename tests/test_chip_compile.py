@@ -181,6 +181,30 @@ def test_chosen_experts_kernel_compiles_for_v5e(one_chip, d, f, precision):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("rows", [1, 4])
+def test_clamped_experts_kernel_compiles_at_the_seventh_shape(one_chip,
+                                                              rows):
+    """GigaChat3.5's 7168 x 2048 (the seventh published shape: an ``f``
+    tile of 256, the first under 512; 16 held, 8 a token), its SwiGLU
+    clamped at 10 inside the tile body, at one row and at a block of four
+    (16 grid slots: every held expert may be chosen)."""
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    d, f, held = 7168, 2048, 16
+    assert moe_kernel.f_tile(d, f, 2) == 256
+    slots = min(rows * 8, held)
+    wide = on_chip((held, d, f), jnp.bfloat16)
+    weights = (slots,) if rows == 1 else (slots, rows)
+    text = _compiled_text(
+        lambda *a: moe_kernel.chosen_experts(*a, interpret=False,
+                                             limit=10.0),
+        on_chip((rows, d), jnp.bfloat16), on_chip((slots,), jnp.int32),
+        on_chip(weights, jnp.float32), on_chip((), jnp.int32), wide, wide,
+        on_chip((held, f, d), jnp.bfloat16))
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("precision", ["default", "highest"])
 @pytest.mark.parametrize("rows", [2, 4, 8])
 def test_block_of_rows_kernel_compiles_for_v5e(one_chip, rows, precision):
@@ -310,6 +334,17 @@ def test_a_forked_decode_chunk_compiles_for_v5e(one_chip, preset):
     # prompt's 64-token chunk in the expanded form over 2 560 latents
     ("decode4", "sd15_kanana2_expander", 10.15, 7, 160, 32),
     ("prefill", "sd15_kanana2_expander", 10.15, 0, 400, 23),
+    # four clamped expert kernels (the seventh published shape, 7168 x
+    # 2048, tile 256) behind one forked latent attention and four delta
+    # mixers that step a state a sequence: four sequences donate the one
+    # sequence's 2.9 MB of latents (shared, handed through), 256 own slots
+    # each and sixteen states with their kept rows, 70 MB; the prompt's
+    # 64-token chunk chunk-wise over four states and expanded over 2 560
+    # latents; the instruction's one chunk of 2 048
+    # (1.66 GB of temporaries: 64 heads' scores over 2 560 latents)
+    ("decode4", "sd15_gigachat35_expander", 9.46, 4, 64, 70),
+    ("prefill", "sd15_gigachat35_expander", 9.46, 0, 400, 20),
+    ("prefill2048", "sd15_gigachat35_expander", 9.46, 0, 2000, 20),
 ])
 def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
         one_chip, monkeypatch, which, expander, argument_gb, kernels,
@@ -331,7 +366,8 @@ def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
     cfg = getattr(configs, expander)().expander
     module = lm.DecoderLM(cfg, dtype=jnp.bfloat16)
     capacity = 2560 if expander in ("sd15_mellum2_expander",
-                                    "sd15_kanana2_expander") else 1024
+                                    "sd15_kanana2_expander",
+                                    "sd15_gigachat35_expander") else 1024
 
     def on_chip(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

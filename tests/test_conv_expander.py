@@ -426,7 +426,10 @@ class TestWhatAnLayerMayLack:
     def test_the_other_presets_keep_their_shared_expert(self, preset):
         cfg = getattr(configs, preset).expander
         assert cfg.shared_expert_intermediate_size == 16
-        assert cfg.norm_topk_eps == 0.0 and cfg.attn_gate != "none"
+        assert cfg.norm_topk_eps == 0.0
+        # a latent layer reads ``attn_gate`` since PR 56; until then the
+        # latent preset carried the default it never read
+        assert (cfg.attn_gate == "none") is (lm.LATENT in cfg.layer_types)
         shapes = jax.eval_shape(lambda: lm.DecoderLM(cfg).init(
             jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
             jnp.int32(4), lm.empty_cache(cfg, 8, jnp.float32)))["params"]

@@ -334,10 +334,11 @@ class TestSequencesOfOneStep:
     @pytest.mark.parametrize("preset,shares", [
         ("TINY_KANANA_EXPAND", True), ("TINY_WINDOW_EXPAND", True),
         ("TINY_LOOP_EXPAND", True), ("TINY_LATENT_EXPAND", False),
-        ("TINY_DELTA_EXPAND", False), ("TINY_CONV_EXPAND", False)])
+        ("TINY_DELTA_EXPAND", True), ("TINY_CONV_EXPAND", False)])
     def test_which_kinds_share_a_step(self, preset, shares):
-        """Latent layers of one stream do; four streams, a recurrence and
-        kept rows still decode one sequence a step."""
+        """Latent layers of one stream do, and since PR 56 a recurrent
+        state with a sequence axis; four streams and a conv layer's kept
+        rows still decode one sequence a step."""
         cfg = getattr(configs, preset).expander
         assert lm.shares_a_step(cfg) is shares
         if not shares:
@@ -691,6 +692,10 @@ PARENT = {
     },
     "TINY_DELTA_EXPAND": {
         "prefill": "da95d3f5fde87d89", "decode": "f874bd6fc3fd9312",
+        # new at PR 56, when a recurrent state got a sequence axis and the
+        # preset began to share a step: this PR's own, no parent's
+        "prefill4": "a473ed63136570d9", "fork": "0cdae66e2a7352fe",
+        "decode4": "17ee34382fce3ba0",
     },
     "TINY_LATENT_EXPAND": {
         "prefill": "a0a2d734b4c65d95", "decode": "e9c357475afd73d4",

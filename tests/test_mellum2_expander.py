@@ -414,7 +414,7 @@ class TestSequencesOfOneStep:
 
     @pytest.mark.parametrize("preset,shares", [
         ("TINY_WINDOW_EXPAND", True), ("TINY_EXPAND", True),
-        ("TINY_DELTA_EXPAND", False), ("TINY_LATENT_EXPAND", False),
+        ("TINY_DELTA_EXPAND", True), ("TINY_LATENT_EXPAND", False),
         ("TINY_CONV_EXPAND", False)])
     def test_which_kinds_share_a_step(self, preset, shares):
         cfg = getattr(configs, preset).expander

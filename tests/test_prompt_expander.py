@@ -1011,13 +1011,19 @@ class TestEnginePath:
             "prefix_snapshots", "padded_rows_masked", "expert_products",
             "mixer_products", "conv_mixers", "residual_streams",
             "sinkhorn_iters", "layer_passes", "exit_pass",
-            "exit_lambda_max"}
+            "exit_lambda_max", "delta_mixers", "state_bytes_stepped",
+            "fork_bytes_copied"}
         # a model of one pass leaves the looped model's counters alone
         assert (block["layer_passes"], block["exit_pass"],
                 block["exit_lambda_max"]) == (0, [], 0.0)
         assert set(block["expert_products"]) == {"kernel", "loop", "grouped"}
         assert set(block["mixer_products"]) == {"kernel", "loop"}
         assert block["conv_mixers"] == {"step": 0, "chunk": 0}
+        # nor has it a recurrent state to step or to copy at a fork
+        assert block["delta_mixers"] == {"recurrent": 0, "chunked": 0,
+                                         "recurrent_forked": 0}
+        assert (block["state_bytes_stepped"],
+                block["fork_bytes_copied"]) == (0, 0)
         json.dumps(block)
 
     @pytest.mark.parametrize("sequences,forked_at,steps", [

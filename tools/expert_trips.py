@@ -8,7 +8,9 @@ For each published expert shape (Laguna-S-2.1's 3072 x 1024 and
 Qwen3-Next's 2048 x 512, 128 of ``num_experts`` held, 10 a token; Xing4.0's
 3584 x 1024, 16 of 64 held, 4 a token; LFM2's 2048 x 1536, all 64 held, 4 a
 token; Mellum2's 2304 x 896, all 64 held, 8 a token; kanana-2's 2048 x 768,
-all 128 held, 6 a token; bf16) and each
+all 128 held, 6 a token; GigaChat3.5's 7168 x 2048, 16 of 256 held, 8 a
+token, the first whose ``f`` tile is under 512 at the default budget; bf16)
+and each
 ``--rows`` (the sequences a step carries) one expert layer's routed sum
 runs ``--steps`` times in a device-side scan, each step on its own seeded
 draw of every row's experts among the layer's (the rows draw
@@ -40,7 +42,8 @@ SHAPES = (("laguna", 3072, 1024, 256, 128, 10),
           ("xing4", 3584, 1024, 64, 16, 4),
           ("lfm2", 2048, 1536, 64, 64, 4),
           ("mellum2", 2304, 896, 64, 64, 8),
-          ("kanana2", 2048, 768, 128, 128, 6))
+          ("kanana2", 2048, 768, 128, 128, 6),
+          ("gigachat35", 7168, 2048, 256, 16, 8))
 REPEATS = 5
 
 
