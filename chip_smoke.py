@@ -53,11 +53,13 @@ KERNEL_TOL = 3e-2
 REORDER_PSNR_FLOOR_DB = 30.0
 
 #: (batch, heads, tokens, head_dim) of every UNet self-attention at CFG
-#: batch 2: SD1.5 at 512², then SDXL at 1024² — the (B*H, T, D) cases of
-#: tests/test_chip_compile.py
+#: batch 2: SD1.5 at 512², then SDXL at 1024²; then SD1.5's two tiled
+#: shapes at CFG batch 8 (four images a request) — the (B*H, T, D) cases
+#: of tests/test_chip_compile.py
 KERNEL_CASES = [
     (2, 8, 4096, 40), (2, 8, 1024, 80), (2, 8, 256, 160), (2, 8, 64, 160),
     (2, 10, 4096, 64), (2, 20, 1024, 64),
+    (8, 8, 4096, 40), (8, 8, 1024, 80),
 ]
 
 

@@ -13,22 +13,33 @@ queries all attend the prefill's rows, read once for all of them, and each
 its own rows behind them. A prefill's chunk, a one-sequence decode step and
 a latent layer take :func:`attend_positions`).
 
-The readings that set :data:`TILED_MIN_TOKENS` (one v5e, bf16, CFG batch 2,
-each alone in a device-side loop of 20 calls, median of 5; my chip runs,
-PR 25: ``chip_smoke.py`` prints the first six on every run, the rest are
-from the tile sweep; PERF.md section 6 has the table):
+The readings that set :data:`TILED_MIN_TOKENS` (one v5e, bf16, each alone
+in a device-side loop of 20 calls, median of 5; ``chip_smoke.py`` prints
+the first eight on every run: my chip run, PR 57, with every head size's
+heads side by side in the lanes; the last four are PR 25's, from the tile
+sweep; PERF.md section 6 has the tables):
 
     (B*H, T, D)        XLA ms    tiled ms
-    (20, 4096, 64)     3.713     1.106     SDXL 64x64
-    (40, 1024, 64)     0.532     0.191     SDXL 32x32
-    (16, 4096, 40)     2.974     0.928     SD1.5 64x64
-    (16, 1024, 80)     0.097     0.087     SD1.5 32x32
-    (16,  256, 160)    0.035     0.043     SD1.5 16x16
-    (16,   64, 160)    0.028     0.036     SD1.5 8x8
+    (20, 4096, 64)     3.727     1.117     SDXL 64x64
+    (40, 1024, 64)     0.530     0.187     SDXL 32x32
+    (16, 4096, 40)     2.975     0.818     SD1.5 64x64
+    (16, 1024, 80)     0.098     0.096     SD1.5 32x32
+    (16,  256, 160)    0.036     0.046     SD1.5 16x16
+    (16,   64, 160)    0.038     0.040     SD1.5 8x8
+    (64, 4096, 40)    11.756     3.214     SD1.5 64x64, four images
+    (64, 1024, 80)     0.823     0.287     SD1.5 32x32, four images
     (24, 4096, 64)     4.454     1.369     SDXL refiner
     (48, 1024, 64)     0.629     0.229     SDXL refiner
     (40,  256, 64)     0.044     0.056     SD2.1 16x16
     (40,   64, 64)     0.036     0.045     SD2.1 8x8
+
+``chip_smoke.py`` hands the kernel ``(B, T, H, D)`` arrays, whose
+flattening to ``(B, T, H*D)`` is a copy there and none in a UNet, where
+the qkv projection leaves that shape. On ``(B, T, H*D)`` operands, with
+whatever copies a layout needs counted (my chip run, PR 57): 0.794 ms at
+``(16, 4096, 40)``, 0.080 at ``(16, 1024, 80)``, 3.092 at ``(64, 4096,
+40)`` and 0.252 at ``(64, 1024, 80)``, where one head a block through
+``(B*H, T, D)`` copies read 0.973, 0.106, 4.027 and 0.339.
 
 At 1024 tokens and above the kernel wins at every head size measured; at
 256 and under XLA's score matrix is 5 MB or less and XLA wins.

@@ -13,12 +13,17 @@ under the serving policy) with float32 accumulation; the softmax statistics
 and the accumulator are float32, and the probabilities are cast to v's
 dtype for P·V exactly as XLA's path does.
 
-Heads are indexed through the ``BlockSpec`` where the head size allows:
-q, k, v stay ``(B, T, H*D)`` as the qkv projection leaves them, and one block
-is the 128 lanes of ``128 // D`` neighbouring heads, so nothing is transposed
-in HBM (one SDXL self-attention sublayer on a v5e: 1.27 ms against 1.47 ms
-through ``(B*H, T, D)`` copies; PERF.md section 6, PR 25). Other head sizes
-(SD1.5's 40, 80, 160) go through the ``(B*H, T, D)`` layout.
+Heads stay side by side in the lanes: q, k, v are ``(B, T, H*D)`` as the
+qkv projection leaves them and nothing is transposed in HBM
+(:func:`heads_per_block`). Where ``128 % D == 0`` one block is the 128 lanes
+of ``128 // D`` neighbouring heads (one SDXL self-attention sublayer on a
+v5e: 1.27 ms against 1.47 ms through ``(B*H, T, D)`` copies; PERF.md
+section 6, PR 25). Any other head size (SD1.5's 40, 80, 160) takes all ``H``
+heads in one block, which is legal because it is the array's whole last
+dimension: 320 lanes at T = 4096, 640 at T = 1024. A ``(B, T, 8, 40)`` copy
+for the other layout holds 40 of every 128 lanes in HBM, and a site needed
+four of them (PERF.md section 6, PR 57). Only a width whose K and V blocks
+would not fit the kernel's VMEM goes through ``(B*H, T, D)``.
 
 Falls back to ``jax.nn.dot_product_attention`` when the sequence does not
 tile (cross-attention's 77-token context).
@@ -32,6 +37,8 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from stable_diffusion_webui_distributed_tpu.serving.metrics import ATTENTION
 
 _NT = (((1,), (1,)), ((), ()))   # q @ k.T without a transpose in the kernel
 
@@ -173,6 +180,26 @@ def blocks(t: int, s: int) -> tuple[int, int] | None:
     return None if block_q is None else (block_q, block_k)
 
 
+def heads_per_block(h: int, d: int, block_q: int, block_k: int,
+                    itemsize: int) -> int:
+    """How many heads ride side by side in the lanes of one block, from the
+    shape alone; 0 when the kernel has to be handed ``(B*H, T, D)``.
+
+    ``128 // d`` neighbours where they fill 128 lanes exactly; else all
+    ``h``, when the double-buffered q, k, v and output blocks of the whole
+    ``h * d`` width (padded to whole 128-lane tiles), one head's scores,
+    exponentials and probabilities and every head's running state stay
+    under :data:`_VMEM_LIMIT` (SD1.5 at T = 4096: 26 of the 48 MiB)."""
+    if 128 % d == 0 and h % (128 // d) == 0:
+        return 128 // d
+    lanes = -(-h * d // 128) * 128
+    blocks_bytes = 2 * itemsize * lanes * 2 * (block_q + block_k)
+    scores_bytes = (4 + 4 + itemsize) * block_q * block_k
+    state_bytes = 3 * 4 * h * block_q * max(128, d)      # m, l, acc
+    return (h if blocks_bytes + scores_bytes + state_bytes <= _VMEM_LIMIT
+            else 0)
+
+
 def flash_attention(
     q: jax.Array,      # (B, T, H, D)
     k: jax.Array,      # (B, S, H, D)
@@ -205,13 +232,14 @@ def flash_attention(
                              block_k=chosen[1], scale=float(scale),
                              interpret=interpret)
 
-    per_block = 128 // d if 128 % d == 0 else 0
-    if per_block and h % per_block == 0:
+    heads = heads_per_block(h, d, *chosen, q.dtype.itemsize)
+    ATTENTION.record_layout("lanes" if heads else "heads_major")
+    if heads:
         def flat(x):
             return x.reshape(b, x.shape[1], h * d)
 
         return call(flat(q), flat(k), flat(v),
-                    heads=per_block).reshape(b, t, h, d)
+                    heads=heads).reshape(b, t, h, d)
 
     def to_bhtd(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)

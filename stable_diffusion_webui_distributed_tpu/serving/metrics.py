@@ -437,7 +437,9 @@ def _note_site(counter: str, *args: Any) -> None:
 
 def replay_sites(rows) -> None:
     """Count again what a trace counted (rows of :func:`capture_sites`)."""
-    counters = {"attention": ATTENTION.record, "upsample": UPSAMPLE.record,
+    counters = {"attention": ATTENTION.record,
+                "attention_layout": ATTENTION.record_layout,
+                "upsample": UPSAMPLE.record,
                 "product": EXPANDER.record_product,
                 "mixer": EXPANDER.record_mixer,
                 "conv": EXPANDER.record_conv,
@@ -459,7 +461,15 @@ class AttentionSites:
     after its shape. A site whose keys are two ranges named apart (a latent
     layer's forked step: the prefill's rows, shared, and a sequence's own
     behind them) carries both: ``T4 S2560+256 D576`` is four sequences'
-    rows over 2 560 shared slots and 256 own ones of width 576."""
+    rows over 2 560 shared slots and 256 own ones of width 576.
+
+    ``tiled_layout`` is the tiled kernel's calls by the layout it was
+    handed, counted where that is chosen (ops/flash_attention.py, at trace
+    time too): ``lanes`` (heads side by side in ``(B, T, H*D)``, nothing
+    transposed in HBM) or ``heads_major`` (``(B*H, T, D)`` through copies).
+    A UNet's ``tiled`` sites are the sum of the two."""
+
+    LAYOUTS = ("lanes", "heads_major")
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -470,6 +480,12 @@ class AttentionSites:
             #: (path, tokens, context tokens, head_dim, passes, own slots)
             #: -> sites
             self.sites: Dict[tuple, int] = defaultdict(int)  # guarded-by: _lock
+            self.layouts: Dict[str, int] = defaultdict(int)  # guarded-by: _lock
+
+    def record_layout(self, layout: str) -> None:
+        _note_site("attention_layout", str(layout))
+        with self._lock:
+            self.layouts[layout] += 1
 
     def record(self, path: str, t: int, s: int, head_dim: int,
                passes: int = 1, own: int = 0) -> None:
@@ -480,11 +496,15 @@ class AttentionSites:
                         int(own))] += 1
 
     def summary(self) -> Dict[str, Any]:
-        """``{"tiled": n, "xla": m, "by_shape": {"T4096 S4096 D64":
-        {"tiled": n}, ...}}``; other paths appear once they are taken."""
+        """``{"tiled": n, "xla": m, "tiled_layout": {"lanes": n,
+        "heads_major": 0}, "by_shape": {"T4096 S4096 D64": {"tiled": n},
+        ...}}``; other paths appear once they are taken."""
         with self._lock:
             sites = dict(self.sites)
+            layouts = dict(self.layouts)
         out: Dict[str, Any] = {"tiled": 0, "xla": 0}
+        out["tiled_layout"] = {layout: layouts.get(layout, 0)
+                               for layout in self.LAYOUTS}
         by_shape: Dict[str, Dict[str, int]] = {}
         for (path, t, s, d, passes, own), n in sorted(sites.items()):
             out[path] = out.get(path, 0) + n

@@ -42,7 +42,8 @@ from chip_smoke import KERNEL_CASES  # noqa: E402  (repo root on path)
 #: (batch*heads, tokens, head_dim) of every UNet self-attention at CFG
 #: batch 2 — the cases chip_smoke.py runs on the chip: (16, 4096, 40),
 #: (16, 1024, 80), (16, 256, 160), (16, 64, 160) for SD1.5 at 512²;
-#: (20, 4096, 64), (40, 1024, 64) for SDXL at 1024²
+#: (20, 4096, 64), (40, 1024, 64) for SDXL at 1024²; (64, 4096, 40) and
+#: (64, 1024, 80) for SD1.5's tiled sites at CFG batch 8
 SHAPES = [(b * h, t, d) for b, h, t, d in KERNEL_CASES]
 
 
@@ -80,8 +81,9 @@ def _compiled_text(fn, *args):
 @pytest.mark.parametrize("b,h,t,d", KERNEL_CASES)
 def test_flash_kernel_compiles_for_v5e(one_chip, b, h, t, d):
     """Through the public entry point, with the tiles it takes from the
-    shape: head_dim 64 rides the lanes two heads to a block, 40, 80 and
-    160 go through the (B*H, T, D) layout."""
+    shape: head_dim 64 rides the lanes two heads to a block; 40, 80 and
+    160 all eight heads in one block of the whole width, whose slices at
+    lane offsets 40, 80, 120 ... cross the 128-lane tiles."""
     qkv = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=one_chip)
     text = _compiled_text(
         lambda q, k, v: flash_attention(q, k, v, interpret=False),
@@ -89,11 +91,54 @@ def test_flash_kernel_compiles_for_v5e(one_chip, b, h, t, d):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("tokens", [16384, 65536])
-def test_flash_kernel_compiles_at_hires_lengths(one_chip, tokens):
+#: a copy or a transpose that writes a four-dimensional array: q, k, v or
+#: the output laid out by head, (B, T, H, D) or (B, H, T, D)
+_BY_HEAD_COPY = re.compile(r"= \w+\[\d+,\d+,\d+,\d+\]\S* (?:copy|transpose)\(")
+
+
+@pytest.mark.parametrize("b,h,t,d", [(2, 8, 4096, 40), (8, 8, 4096, 40),
+                                     (2, 8, 1024, 80), (8, 8, 1024, 80)])
+def test_sd15_site_holds_no_copy_by_head(one_chip, b, h, t, d):
+    """One of SD1.5's tiled self-attention sites in its context (the qkv
+    projection, the split, the kernel, the output projection and the
+    residual) at CFG batch 2 and 8: the split's three slices feed the
+    kernel and its result feeds ``out_proj``, with no ``[B,T,8,40]`` or
+    ``[B,8,T,80]`` array copied through HBM on either side. Handed
+    ``(B*H, T, D)`` the same site holds them."""
+    c = h * d
+
+    def on_chip(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def site(attention):
+        def fn(x, w_qkv, w_out):
+            q, k, v = (a.reshape(b, t, h, d)
+                       for a in jnp.split(x @ w_qkv, 3, axis=-1))
+            return attention(q, k, v).reshape(b, t, c) @ w_out + x
+        return fn
+
+    def heads_major(q, k, v):
+        out = flash_attention(*(a.transpose(0, 2, 1, 3).reshape(b * h, t, 1, d)
+                                for a in (q, k, v)), interpret=False)
+        return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+
+    args = on_chip(b, t, c), on_chip(c, 3 * c), on_chip(c, c)
+    text = _compiled_text(
+        site(lambda q, k, v: flash_attention(q, k, v, interpret=False)),
+        *args)
+    assert "tpu_custom_call" in text
+    assert not _BY_HEAD_COPY.search(text)
+    assert _BY_HEAD_COPY.search(_compiled_text(site(heads_major), *args))
+
+
+@pytest.mark.parametrize("tokens,heads,head_dim", [
+    (16384, 10, 64), (65536, 10, 64), (16384, 8, 40), (16384, 8, 80)])
+def test_flash_kernel_compiles_at_hires_lengths(one_chip, tokens, heads,
+                                                head_dim):
     """The hires second pass: K/V blocks of 4096 and the running softmax
-    state in VMEM scratch across the k steps."""
-    qkv = jax.ShapeDtypeStruct((1, tokens, 10, 64), jnp.bfloat16,
+    state in VMEM scratch across the k steps, two of SDXL's heads a block
+    or all eight of SD1.5's (scratch ``(8, block_q, ...)``)."""
+    qkv = jax.ShapeDtypeStruct((1, tokens, heads, head_dim), jnp.bfloat16,
                                sharding=one_chip)
     text = _compiled_text(
         lambda q, k, v: flash_attention(q, k, v, interpret=False),

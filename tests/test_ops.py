@@ -14,6 +14,7 @@ from stable_diffusion_webui_distributed_tpu.ops.flash_attention import (
     _tiled,
     blocks,
     flash_attention,
+    heads_per_block,
 )
 from stable_diffusion_webui_distributed_tpu.ops.ring_attention import (
     ring_attention,
@@ -140,16 +141,32 @@ class TestFlashAttentionStreaming:
                                    rtol=2e-5, atol=2e-5)
 
 
+def heads_major(q, k, v, block_q, block_k):
+    """The kernel handed ``(B*H, T, D)``, one head a block: the layout
+    every head size off 128's divisors took before PR 57."""
+    b, t, h, d = q.shape
+
+    def to_bhtd(x):
+        return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
+
+    out = _tiled(to_bhtd(q), to_bhtd(k), to_bhtd(v), heads=1, head_dim=d,
+                 block_q=block_q, block_k=block_k, scale=d ** -0.5,
+                 interpret=True)
+    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+
+
 class TestTiledKernel:
-    """The kernel as the default path calls it: tiles from the shape, both
-    layouts (heads in the lanes where 128 % head_dim == 0, else
-    ``(B*H, T, D)``), one plain softmax or the running state."""
+    """The kernel as the default path calls it: tiles from the shape, heads
+    side by side in the lanes (``128 // head_dim`` a block where that
+    divides, else all of them in one block of the whole width), one plain
+    softmax or the running state."""
 
     @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
                                            (jnp.bfloat16, 3e-2)])
-    @pytest.mark.parametrize("d", [40, 64, 80, 160])
-    def test_matches_xla_at_unet_head_dims(self, d, dtype, tol):
-        q, k, v = qkv(2, 128, 2, d)
+    @pytest.mark.parametrize("h,d", [(2, 40), (2, 64), (2, 80), (2, 160),
+                                     (8, 40), (8, 80)])
+    def test_matches_xla_at_unet_head_dims(self, h, d, dtype, tol):
+        q, k, v = qkv(2, 128, h, d)
         got = flash_attention(*(x.astype(dtype) for x in (q, k, v)),
                               interpret=True)
         assert got.dtype == dtype
@@ -157,13 +174,61 @@ class TestTiledKernel:
                                    np.asarray(reference(q, k, v)),
                                    rtol=tol, atol=tol)
 
-    @pytest.mark.parametrize("h,d", [(2, 64), (4, 32), (3, 64), (2, 40)])
-    def test_running_state_in_both_layouts(self, h, d):
-        """Four k steps: (2, 64) and (4, 32) ride the lanes, three heads of
-        64 and head_dim 40 go through (B*H, T, D)."""
+    @pytest.mark.parametrize("h,d", [(2, 64), (4, 32), (3, 64), (2, 40),
+                                     (8, 40), (8, 80)])
+    def test_running_state_at_every_block_width(self, h, d):
+        """Four k steps: (2, 64) and (4, 32) ride the lanes 128 a block;
+        three heads of 64 and SD1.5's eight of 40 or 80 all in one block."""
         q, k, v = qkv(1, 256, h, d)
         got = flash_attention(q, k, v, block_q=128, block_k=64,
                               interpret=True)
+        np.testing.assert_allclose(np.asarray(got),
+                                   np.asarray(reference(q, k, v)),
+                                   rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("t,d,block_q,block_k", [
+        (128, 40, 128, 128), (128, 80, 128, 128),
+        (256, 40, 128, 64), (256, 80, 64, 64)])    # k_steps 1, 1, 4, 4
+    def test_lanes_layout_is_the_heads_major_result(self, t, d, block_q,
+                                                    block_k, dtype):
+        """SD1.5's eight heads of 40 and 80 in one block give what one head
+        a block through ``(B*H, T, D)`` copies gives: the same dots, the
+        same float32 softmax, another place in the lanes."""
+        q, k, v = (x.astype(dtype) for x in qkv(2, t, 8, d))
+        assert heads_per_block(8, d, block_q, block_k,
+                               q.dtype.itemsize) == 8
+        got = flash_attention(q, k, v, block_q=block_q, block_k=block_k,
+                              interpret=True)
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32),
+            np.asarray(heads_major(q, k, v, block_q, block_k), np.float32))
+
+    @pytest.mark.parametrize("h,d,block_q,block_k,want", [
+        (10, 64, 256, 4096, 2),      # SDXL: two neighbours fill 128 lanes
+        (20, 64, 1024, 1024, 2),
+        (4, 32, 128, 128, 4),
+        (1, 128, 128, 128, 1),
+        (8, 40, 256, 4096, 8),       # SD1.5 64x64: 320 lanes, one block
+        (8, 80, 1024, 1024, 8),      # SD1.5 32x32: 640 lanes
+        (8, 160, 256, 256, 8),
+        (3, 64, 128, 64, 3),         # an odd count of 64s: the whole width
+        (20, 96, 256, 4096, 0),      # 1920 lanes of K and V do not fit
+    ])
+    def test_heads_a_block_come_from_the_shape(self, h, d, block_q, block_k,
+                                               want):
+        assert heads_per_block(h, d, block_q, block_k, 2) == want
+
+    def test_a_width_that_does_not_fit_goes_heads_major(self, monkeypatch):
+        """Nothing any model here traces; the branch still has to be right."""
+        import sys
+
+        # ops/__init__.py's function shadows the module of the same name
+        monkeypatch.setattr(sys.modules[flash_attention.__module__],
+                            "_VMEM_LIMIT", 1 << 16)
+        q, k, v = qkv(1, 128, 3, 40)
+        assert heads_per_block(3, 40, 128, 128, 4) == 0
+        got = flash_attention(q, k, v, interpret=True)
         np.testing.assert_allclose(np.asarray(got),
                                    np.asarray(reference(q, k, v)),
                                    rtol=2e-5, atol=2e-5)
@@ -286,7 +351,9 @@ class TestAttentionSites:
     """serving.attention of /internal/status: one UNet trace's sites by the
     path they took and by (T, S, head_dim)."""
 
-    def _trace(self, impl):
+    def _trace(self, impl, **changed):
+        import dataclasses
+
         from stable_diffusion_webui_distributed_tpu.models.configs import (
             TINY_XL,
         )
@@ -295,7 +362,7 @@ class TestAttentionSites:
             ATTENTION, METRICS,
         )
 
-        cfg = TINY_XL.unet
+        cfg = dataclasses.replace(TINY_XL.unet, **changed)
         unet = UNet(cfg, attention_impl=impl)
         x = jnp.zeros((2, 16, 16, cfg.in_channels))
         args = (x, jnp.zeros((2,)), jnp.zeros((2, 77, cfg.cross_attention_dim)),
@@ -324,6 +391,37 @@ class TestAttentionSites:
         assert got["tiled"] == got["xla"] == auto["xla"] // 2
         assert got["by_shape"]["T64 S64 D16"] == {"tiled": got["tiled"]}
         assert got["by_shape"]["T64 S77 D16"] == {"xla": got["xla"]}
+
+    @pytest.mark.parametrize("channels,heads,d", [(160, 4, 40), (128, 2, 64)])
+    def test_tiled_sites_by_the_layout_they_were_handed(self, channels,
+                                                        heads, d):
+        """SD1.5's head size and SDXL's both keep their heads in the lanes;
+        ``tiled`` counts what it counted."""
+        changed = dict(block_out_channels=(32, channels),
+                       num_attention_heads=heads)
+        auto = self._trace("auto", **changed)
+        assert auto["tiled_layout"] == {"lanes": 0, "heads_major": 0}
+        got = self._trace("flash", **changed)
+        assert got["tiled"] == got["xla"] == auto["xla"] // 2 > 0
+        assert got["by_shape"][f"T64 S64 D{d}"] == {"tiled": got["tiled"]}
+        assert got["tiled_layout"] == {"lanes": got["tiled"],
+                                       "heads_major": 0}
+
+    def test_a_loaded_program_counts_its_layouts_again(self):
+        """serving/aot.py keeps a trace's counts with the program."""
+        from stable_diffusion_webui_distributed_tpu.serving.metrics import (
+            ATTENTION, capture_sites, replay_sites,
+        )
+
+        ATTENTION.clear()
+        with capture_sites() as rows:
+            flash_attention(*qkv(1, 64, 2, 40), interpret=True)
+            flash_attention(*qkv(1, 64, 2, 64), interpret=True)
+        traced = ATTENTION.summary()
+        assert traced["tiled_layout"] == {"lanes": 2, "heads_major": 0}
+        ATTENTION.clear()
+        replay_sites(rows)
+        assert ATTENTION.summary() == traced
 
 
 @pytest.mark.slow

@@ -23,6 +23,11 @@ chip) and keeps what that run reads and throws away: ``/internal/trace.json``
   like the tables and printed with them on the ``plan:`` line: every
   expanded request, the warm-up's among them, should add one to the
   first two);
+- ``attention_sites`` (printed as the ``attention:`` line):
+  ``serving.attention`` of the last ``/internal/status`` without its
+  ``by_shape``: the sites by path, counted at trace time, and (PR 57)
+  ``tiled_layout``, the tiled kernel's calls by the layout they were
+  handed (``lanes`` or ``heads_major``; a parent before PR 57 has none);
 - ``dispatch_attrs``: over the window's requests, how many
   ``coalesce.window`` spans read ``ended_by`` full or timer and how many
   ``dispatch.device`` spans carried 1, 2, ... ``requests``, and
@@ -447,6 +452,11 @@ def main(argv=None) -> int:
         if statuses else None
     print(f"plan: {json.dumps(out['plan_attrs'])} "
           f"{json.dumps(out['plan_counts'])}")
+    attention = dict((statuses[-1].get("serving") or {}).get("attention")
+                     or {}) if statuses else {}
+    attention.pop("by_shape", None)
+    out["attention_sites"] = attention or None
+    print(f"attention: {json.dumps(out['attention_sites'])}")
     out["dispatch_attrs"] = attr_counts(window, ("ended_by", "requests"))
     out["coalesce_windows"] = {
         key[0]: n for key, n in
