@@ -3,9 +3,11 @@ names its configuration and traffic, metric files name their readers, and
 readers and generators are modules loaded from their own files. What
 belongs to one architecture is found from the configuration's file the same
 way: its ``components`` (where seeded weights come from), its ``counter``
-(operations its work needs) and its ``reference``. Adding a cell,
-configuration, architecture, metric, reader or generator adds files and
-edits none.
+(operations its work needs), its ``reference`` and, for a prompt expander,
+its ``op_classes`` (the stem of its class files: readers/op_class_ms.py).
+Adding a cell, configuration, architecture, metric, reader or generator
+adds files and edits none, but for the cell's name appended to the
+``workloads`` lists of the metrics it reports (README, "Adding things").
 """
 
 from __future__ import annotations

@@ -122,11 +122,12 @@ def _has_stat(plane, name: str):
     return lambda event: any(s.metadata_id in ids for s in event.stats)
 
 
-def _read_device(plane) -> tuple[list, dict, dict]:
+def _read_device(plane) -> tuple[list, dict, dict, dict]:
     """One device plane: ([(start_ns, end_ns, metadata id)] of XLA Ops,
-    {metadata id: table row without times}, {module name: seconds})."""
+    {metadata id: table row without times}, {module name: seconds},
+    {module name: launches})."""
     names = xplane_proto.stat_names(plane)
-    ops, modules, programs = [], {}, {}
+    ops, modules, programs, calls = [], {}, {}, {}
     metadata = {e.key: e.value for e in plane.event_metadata}
     for line in plane.lines:
         if line.name == OPS_LINE:
@@ -136,6 +137,7 @@ def _read_device(plane) -> tuple[list, dict, dict]:
                 full = metadata[ev.metadata_id].name
                 name = short_name(full)
                 modules[name] = modules.get(name, 0.0) + (end - start) / 1e9
+                calls[name] = calls.get(name, 0) + 1
                 programs[full[len(name):].strip("()")] = name
     rows = {}
     for mid in {m for _, _, m in ops}:
@@ -150,7 +152,7 @@ def _read_device(plane) -> tuple[list, dict, dict]:
             "bytes": float(stats.get("bytes_accessed") or 0),
             "shape": str(stats.get("shape_with_layout", "")),
         }
-    return ops, rows, modules
+    return ops, rows, modules, calls
 
 
 def _read_rehearsal(space) -> tuple[list, dict]:
@@ -234,6 +236,11 @@ def reduce(xplane_path: str, top: int = 10, gaps: int = 7,
     over all devices; ``collective_s``: mean over devices of the summed
     durations of collective ops;
     ``modules``: {executable: seconds summed over all devices};
+    ``module_calls``: {executable: launches the trace holds, summed over
+    all devices}: the events ``modules`` sums. A profiler that came back
+    short (a host stall inside the slice) lacks launches in BOTH, so a
+    reader that sets work against an executable's seconds counts the work
+    of the launches that are there;
     ``op_table``: one row per HLO op (containers left out), most time
     first: ``name``, ``scope`` (its ``tf_op``), ``category``, ``module``,
     ``calls`` and ``seconds`` summed over all devices, and for ONE call
@@ -252,15 +259,18 @@ def reduce(xplane_path: str, top: int = 10, gaps: int = 7,
     planes = _device_planes(space)
     out: dict = {"devices": {}, "device_ops": [], "idle_gaps": [],
                  "busy_s": 0.0, "span_s": 0.0, "collective_s": 0.0,
-                 "events": 0, "modules": {}, "op_table": [],
+                 "events": 0, "modules": {}, "module_calls": {},
+                 "op_table": [],
                  "head_s": 0.0, "tail_s": 0.0}
     per_device = {}
     for dev, plane in sorted(planes.items()):
-        ops, rows, mods = _read_device(plane)
+        ops, rows, mods, calls = _read_device(plane)
         if ops:
             per_device[dev] = (ops, rows)
         for name, seconds in mods.items():
             out["modules"][name] = out["modules"].get(name, 0.0) + seconds
+            out["module_calls"][name] = (out["module_calls"].get(name, 0)
+                                         + calls[name])
     if not planes:
         ops, rows = _read_rehearsal(space)
         if ops:

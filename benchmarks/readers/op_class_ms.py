@@ -6,7 +6,16 @@ flax module path and JAX primitive), ``category`` (``hlo_category``) and
 ``name``; the first rule that matches takes the op, and a rule without
 expressions takes everything left, so the classes partition the module's
 ops. Containers (``while``) are not in the table. Nothing of that module in
-the slice: nothing to read."""
+the slice: nothing to read.
+
+A metric that names no ``classes`` is one of the expander's decode step
+and finds its file from the cell's CONFIGURATION, as ``components``,
+``counter`` and ``reference`` are found (harness/files.py): the
+configuration's ``"op_classes": "<stem>"`` means
+``op_classes/<stem>_decode.json``. So one metric a class serves every
+configuration whose file has that class, and a new configuration brings a
+class file and no metric. A configuration that names no stem, a file
+without the class: nothing to read."""
 
 import re
 
@@ -45,7 +54,16 @@ def _by_class(context: dict, classes: str) -> dict | None:
     return out
 
 
-def read(context: dict, classes: str, cls: str):
+def decode_classes(context: dict) -> str | None:
+    """The class file of the configuration's decode executable."""
+    stem = (context.get("config") or {}).get("op_classes")
+    return stem + "_decode" if stem else None
+
+
+def read(context: dict, classes: str | None = None, cls: str = ""):
+    classes = classes or decode_classes(context)
+    if classes is None:
+        return None
     traced = [r for r in context["records"] if r.traced]
     seconds = by_class(context, classes)
     if not seconds or not traced or cls not in seconds:

@@ -4,7 +4,13 @@ each the counter's growth over the window (``status_after`` less
 ``scale``. ``path`` walks down to the block; ``over`` and ``under`` name
 the counters summed above and below the line. A program whose status lacks
 the block or a counter, or a window in which the counters below the line
-did not move, gives None."""
+did not move, gives None.
+
+``per`` names a tuple of the expander's ``LMConfig`` (``expert_layers``)
+and divides the quotient by its length: a count summed over the layers of
+one kind becomes a count a layer, in every configuration by its own number
+of such layers and with no literal in the metric's file. A family without
+an expander, or with none of those layers, gives None."""
 
 
 def _block(context: dict, status: str, path: list[str]):
@@ -17,7 +23,13 @@ def _block(context: dict, status: str, path: list[str]):
 
 
 def read(context: dict, path: list[str], over: list[str],
-         under: list[str], scale: float = 1.0):
+         under: list[str], scale: float = 1.0, per: str | None = None):
+    if per is not None:
+        cfg = getattr(context.get("family"), "expander", None)
+        layers = len(getattr(cfg, per, ()))
+        if not layers:
+            return None
+        scale = scale / layers
     before = _block(context, "status_before", path)
     after = _block(context, "status_after", path)
     if before is None or after is None:

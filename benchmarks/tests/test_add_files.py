@@ -1,7 +1,15 @@
 """A later PR adds a configuration, a cell, a traffic mix, a per-layer
 metric, a reader, a generator and a whole architecture (its weights'
 components, its counter of operations, its plain reference) as NEW files
-plus entries in BENCHMARK.json, and edits no file that is there."""
+plus entries in BENCHMARK.json, and edits no file that is there.
+
+In BENCHMARK.json it appends entries, and may make ONE edit of an entry
+that is there (PR 58): its new cell's name appended at the END of the
+``workloads`` list of a metric the cell reports, nothing removed and
+nothing reordered (:func:`manifest_faults`). That is how a cell joins a
+metric that exists where it would once have cloned it under a prefix; an
+expander's device classes and its roofline need no file either, they are
+found from its configuration (the last test here)."""
 
 import dataclasses
 import hashlib
@@ -197,6 +205,90 @@ def edit_manifest(root, change):
         json.dump(manifest, fh)
 
 
+def read_manifest(root):
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
+        return json.load(fh)
+
+
+def manifest_faults(before, after):
+    """What an edit of BENCHMARK.json did beyond what a PR that adds may
+    do: a list of sentences, empty when the edit only appended entries and
+    appended NEW cells' names to ``workloads`` lists that were there."""
+    faults = []
+    for key in ("command", "paths", "run_seconds"):
+        if before[key] != after[key]:
+            faults.append(f"{key} changed")
+    new_cells = {w["name"] for w in after["workloads"]} \
+        - {w["name"] for w in before["workloads"]}
+    for group in ("configs", "workloads", "end_to_end", "per_layer"):
+        was, now = before[group], after[group]
+        if [e["name"] for e in now[:len(was)]] != [e["name"] for e in was]:
+            faults.append(f"{group}: an entry removed, renamed, reordered "
+                          "or put before one that was there")
+            continue
+        for old, new in zip(was, now):
+            rest = {k: v for k, v in new.items() if k != "workloads"}
+            if rest != {k: v for k, v in old.items() if k != "workloads"}:
+                faults.append(f"{group}/{old['name']}: a key changed")
+            if ("workloads" in old) != ("workloads" in new):
+                faults.append(f"{group}/{old['name']}: workloads list "
+                              "added or taken away")
+                continue
+            cells, more = old.get("workloads", []), new.get("workloads", [])
+            if more[:len(cells)] != cells:
+                faults.append(f"{group}/{old['name']}: a cell removed from "
+                              "workloads, or the list reordered")
+            elif not set(more[len(cells):]) <= new_cells:
+                faults.append(f"{group}/{old['name']}: an accepted cell "
+                              "appended to workloads")
+    return faults
+
+
+def test_the_one_permitted_edit_of_an_entry_and_every_other_one():
+    import copy
+
+    before = read_manifest(rehearsal.REPO)
+
+    def edited(change):
+        after = copy.deepcopy(before)
+        after["workloads"].append({"name": "ninth", "config": "c",
+                                   "traffic": "t", "chips": 1, "why": "w"})
+        change(after)
+        return manifest_faults(before, after)
+
+    def metric(manifest, name):
+        return next(m for m in manifest["per_layer"] if m["name"] == name)
+
+    assert edited(lambda m: None) == []
+    assert edited(lambda m: metric(m, "expand_ms")["workloads"].append(
+        "ninth")) == []
+    assert edited(lambda m: m["per_layer"].append(
+        dict(metric(m, "expand_ms"), name="new_thing_ms"))) == []
+    # every other edit of what is there
+    for change, word in (
+            (lambda m: metric(m, "expand_ms")["workloads"].insert(
+                0, "ninth"), "reordered"),
+            (lambda m: metric(m, "expand_ms")["workloads"].pop(), "removed"),
+            (lambda m: metric(m, "expand_ms")["workloads"].reverse(),
+             "reordered"),
+            (lambda m: metric(m, "lm_norm_device_ms")["workloads"].append(
+                "sd15_expand_solo"), "accepted cell"),
+            (lambda m: metric(m, "host_overhead_ms").update(
+                workloads=["ninth"]), "added or taken away"),
+            (lambda m: metric(m, "expand_ms").pop("workloads"),
+             "added or taken away"),
+            (lambda m: metric(m, "expand_ms").update(unit="s"),
+             "a key changed"),
+            (lambda m: m["end_to_end"][0].update(bound=0.05),
+             "a key changed"),
+            (lambda m: m["per_layer"].pop(3), "removed"),
+            (lambda m: m["per_layer"].insert(0, dict(
+                metric(m, "expand_ms"), name="first")), "put before"),
+            (lambda m: m.update(run_seconds=10), "run_seconds changed")):
+        faults = edited(change)
+        assert faults and any(word in f for f in faults), (word, faults)
+
+
 def flops_metric(name, counter, cell):
     spec = {"name": name, "layer": "models and XLA kernels", "unit": "%",
             "better": "higher", "source": "device_trace",
@@ -262,7 +354,9 @@ def test_new_config_cell_metric_reader_generator_without_an_edit(tmp_path):
              if m["name"] == "unet_flops_util")["workloads"].append(
                  "tiny_v_counted")
 
+    was = read_manifest(root)
     edit_manifest(root, change)
+    assert manifest_faults(was, read_manifest(root)) == []
     rc, result, output = rehearsal.drive(root, "tiny_v_counted", trace=1)
     assert rc == 0 and result is not None, output[-3000:]
     assert result["correct"] is True
@@ -301,11 +395,13 @@ def standin_root(tmp_path):
     spec, entry = flops_metric("standin_flops_util", "flops_standin",
                                "standin_cell")
     write(root, "layer_metrics/standin_flops_util.json", spec)
+    was = read_manifest(root)
     edit_manifest(root, lambda m: (
         m["configs"].append({
             "name": "standin", "source": "test", "reduced": [],
             "file": "benchmarks/configs/standin.json", "why": "test"}),
         m["per_layer"].append(entry)))
+    assert manifest_faults(was, read_manifest(root)) == []
     sys.path.insert(0, root)
     try:
         yield root
@@ -367,3 +463,119 @@ def test_an_architecture_that_is_not_the_unet_by_new_files_only(
     assert result["program_vs_reference_relative_rms"] < 1e-5
     assert result["control_vs_reference_relative_rms"] > 1e-3
     assert result["control"].startswith("the module's own bfloat16")
+
+
+# -- a ninth prompt expander joins the metrics that exist -------------------
+
+TINY_EXPANDER = ("stable_diffusion_webui_distributed_tpu.models.configs:"
+                 "tiny_expander")
+#: what an expander cell with full and window attention, a dense layer
+#: and experts reports besides what every cell reports
+JOINED = ["expand_ms", "expand_prefill_ms", "expand_decode_ms",
+          "expand_ahead_ms", "expert_kernel_sites", "lm_linear_device_ms",
+          "expert_device_ms", "lm_attn_device_ms", "lm_other_device_ms",
+          "lm_decode_bytes_util"]
+
+
+def test_an_expander_cell_joins_by_its_name_appended_and_no_metric_file(
+        tmp_path):
+    root = rehearsal.make_root(str(tmp_path))
+    before = digest(root)
+    bench = files.Bench(root)
+    config = dict(bench.config("sd15_laguna_expand"), name="tiny_ninth",
+                  factory=TINY_EXPANDER, policy="F32")
+    assert config["op_classes"] == "laguna"     # its class file, by stem
+    write(root, "configs/tiny_ninth.json", config)
+    traffic = bench.traffic("sd15_512_expand384")
+    args = traffic["payload"]["alwayson_scripts"]["prompt expansion"][
+        "args"][0]
+    args.update(max_new_tokens=40, context_chunks=1,
+                instruction=" ".join(args["instruction"].split()[:30]))
+    write(root, "traffic/tiny_expand40.json", traffic)
+    write(root, "workloads/tiny_ninth_solo.json", dict(
+        bench.read("workloads", "sd15_expand_solo.json"),
+        config="tiny_ninth", traffic="tiny_expand40"))
+
+    def change(manifest):
+        manifest["configs"].append({
+            "name": "tiny_ninth", "source": "test", "reduced": [],
+            "file": "benchmarks/configs/tiny_ninth.json", "why": "test"})
+        manifest["workloads"].append({
+            "name": "tiny_ninth_solo", "config": "tiny_ninth",
+            "traffic": "tiny_expand40", "chips": 1, "why": "test"})
+        for name in JOINED:
+            next(m for m in manifest["per_layer"]
+                 if m["name"] == name)["workloads"].append("tiny_ninth_solo")
+
+    was = read_manifest(root)
+    edit_manifest(root, change)
+    now = read_manifest(root)
+    assert manifest_faults(was, now) == []
+    assert len(now["per_layer"]) == len(was["per_layer"])   # no clone
+    rc, result, output = rehearsal.drive(root, "tiny_ninth_solo", trace=1,
+                                         seconds=3.0)
+    assert rc == 0 and result is not None, output[-3000:]
+    assert result["correct"] is True and "raised" not in output
+    m = {k: v["value"] for k, v in result["metrics"].items()}
+    assert m["expand_ms"] > m["expand_decode_ms"] > 0
+    assert m["expand_prefill_ms"] > 0 and "expand_ahead_ms" in m
+    assert "expert_kernel_sites" in m
+    # the metrics of other kinds of expander list their own cells
+    assert not {"expand_fork_ms", "lm_tokens_per_step",
+                "lm_latent_device_ms", "conv_sites"} & set(m)
+    after = digest(root)
+    assert {p: h for p, h in after.items() if p in before} == before
+    assert not [p for p in after if p not in before
+                and os.sep + "layer_metrics" + os.sep in p]
+
+    # what only a chip's trace feeds, through the readers as run.py calls
+    # them: the class file and the bytes are found from the configuration
+    bench = files.Bench(root)
+    config = bench.config("tiny_ninth")
+    family = files.resolve_family(config)
+
+    @dataclasses.dataclass
+    class Rec:
+        payload: dict
+        traced: bool = True
+
+    payload = dict(traffic["payload"], prompt="a red fox")
+    scope = "jit(f)/DecoderLM/while/body/layers_1/"
+    rows = [{"module": "jit_expand_decode_chunk", "seconds": seconds,
+             "scope": scope + tail, "category": "x", "name": "fusion"}
+            for tail, seconds in (("attn/q_proj/dot_general", 0.004),
+                                  ("attn/exp", 0.002),
+                                  ("mlp/top_k", 0.003),
+                                  ("input_norm/rsqrt", 0.001))]
+    status = {"serving": {"expander": {
+        "decode_steps": 0, "tokens_decoded": 0, "experts_read": 0}}}
+    after_window = {"serving": {"expander": {
+        "decode_steps": 64, "tokens_decoded": 64, "experts_read": 128}}}
+    context = {
+        "records": [Rec(payload)], "family": family, "chips": 1,
+        "config": config, "bench": bench,
+        "trace": {"op_table": rows, "devices": {0: {}},
+                  "modules": {"jit_expand_decode_chunk": 0.01},
+                  # 40 tokens: two launches of 32 steps, both in the trace
+                  "module_calls": {"jit_expand_decode_chunk": 2}},
+        "peak": {"hbm_bytes_per_s": 1e9},
+        "status_before": status, "status_after": after_window}
+    read = {name: bench.load("readers", spec["reader"]).read(
+        context, **spec["args"])
+        for name in JOINED[5:]
+        for spec in [bench.layer_metric(name)]}
+    assert read["lm_linear_device_ms"] == pytest.approx(4.0)
+    assert read["lm_attn_device_ms"] == pytest.approx(2.0)
+    assert read["expert_device_ms"] == pytest.approx(3.0)
+    assert read["lm_other_device_ms"] == pytest.approx(1.0)
+    walker = bench.load("harness", "bytes_lm")
+    first = 1 + len(args["instruction"].split()) + 3
+    assert read["lm_decode_bytes_util"] == pytest.approx(
+        100 * walker.decode_bytes(family.expander, first, 64, 2.0, 1.0)
+        / (0.01 * 1e9))
+    # a class its file lacks, a configuration that names no stem: nothing
+    classer = bench.load("readers", "op_class_ms")
+    assert classer.read(context, cls="norm") is None
+    assert classer.read(dict(context, config={}), cls="linear") is None
+    assert classer.read(dict(context, config={}), cls="linear",
+                        classes="laguna_decode") == pytest.approx(4.0)

@@ -83,17 +83,201 @@ def test_per_layer_entries_match_their_files():
                                     "program_counter", "host_clock")
 
 
+def specs_of(cell):
+    """(entry, its file) of every per-layer metric the cell reports."""
+    return [(m, load(BENCH, "layer_metrics", m["name"] + ".json"))
+            for m in MANIFEST["per_layer"]
+            if "workloads" not in m or cell["name"] in m["workloads"]]
+
+
+def config_of(cell):
+    entry = next(c for c in MANIFEST["configs"]
+                 if c["name"] == cell["config"])
+    return load(rehearsal.REPO, entry["file"])
+
+
+def decode_classes(config):
+    """The class file a configuration names for its decode executable, as
+    readers/op_class_ms.py finds it."""
+    stem = config.get("op_classes")
+    return stem + "_decode" if stem else None
+
+
+def resolved(spec, config):
+    """A metric's (reader, args) in one cell, with the file names its reader
+    would find from the cell's configuration written out: two metrics that
+    resolve alike in a cell read the same thing there."""
+    args = dict(spec.get("args", {}))
+    if spec["reader"] == "op_class_ms" and "classes" not in args:
+        args["classes"] = decode_classes(config)
+    return spec["reader"], json.dumps(args, sort_keys=True)
+
+
+def clones(manifest, spec_of):
+    """Metrics that read what another metric reads: everywhere (the same
+    reader and args) or in one cell (alike once the configuration's file
+    names are written out). ``[(cell or None, metric, its twin)]``."""
+    out, seen = [], {}
+    for metric in manifest["per_layer"]:
+        spec = spec_of(metric["name"])
+        key = (spec["reader"], json.dumps(spec.get("args", {}),
+                                          sort_keys=True))
+        if key in seen:
+            out.append((None, metric["name"], seen[key]))
+        seen.setdefault(key, metric["name"])
+    for cell in manifest["workloads"]:
+        config, found = config_of(cell), {}
+        for metric in manifest["per_layer"]:
+            if "workloads" in metric and cell["name"] not in metric[
+                    "workloads"]:
+                continue
+            key = resolved(spec_of(metric["name"]), config)
+            if key in found:
+                out.append((cell["name"], metric["name"], found[key]))
+            found.setdefault(key, metric["name"])
+    return out
+
+
+def spec_on_disk(name):
+    return load(BENCH, "layer_metrics", name + ".json")
+
+
+def test_no_two_metrics_of_a_cell_read_the_same_thing():
+    """One metric a thing measured: a cell that joins appends its name to
+    the ``workloads`` list of the metric that exists (README, "Adding
+    things"); a copy of a metric under another name cannot come back."""
+    assert len(MANIFEST["per_layer"]) <= 128
+    assert clones(MANIFEST, spec_on_disk) == []
+
+
+def test_a_clone_under_a_prefix_or_a_named_class_file_is_found():
+    """What seven model_config PRs did (PR 52: fourteen times), and the
+    one way around the first check: naming the class file the
+    configuration already names."""
+    def with_metric(name, like, args=None, cells=None):
+        spec = dict(spec_on_disk(like), name=name)
+        if args is not None:
+            spec["args"] = args
+        entry = dict(next(m for m in MANIFEST["per_layer"]
+                          if m["name"] == like), name=name)
+        if cells is not None:
+            entry["workloads"] = cells
+        manifest = dict(MANIFEST, per_layer=MANIFEST["per_layer"] + [entry])
+        return manifest, lambda n: spec if n == name else spec_on_disk(n)
+
+    cell = "sd15_ouro_expand_b4"
+    assert clones(*with_metric("o9_expand_ms", "expand_ms", cells=[cell])) \
+        == [(None, "o9_expand_ms", "expand_ms"),
+            (cell, "o9_expand_ms", "expand_ms")]
+    named = {"classes": "ouro_decode", "cls": "norm"}
+    assert clones(*with_metric("o9_norm_device_ms", "lm_norm_device_ms",
+                               args=named)) \
+        == [(cell, "o9_norm_device_ms", "lm_norm_device_ms")]
+    # another configuration's file is another thing: no clone
+    other = {"classes": "laguna_decode", "cls": "attn"}
+    assert clones(*with_metric("o9_attn_device_ms", "lm_norm_device_ms",
+                               args=other)) == []
+
+
+def classes_of(config, spec):
+    name = spec["args"].get("classes") or decode_classes(config)
+    return name and load(BENCH, "op_classes", name + ".json")
+
+
 def test_op_class_metrics_name_a_class_of_their_file():
     for metric in MANIFEST["per_layer"]:
         spec = load(BENCH, "layer_metrics", metric["name"] + ".json")
-        if spec["reader"] == "op_class_ms":
-            classes = load(BENCH, "op_classes",
-                           spec["args"]["classes"] + ".json")
+        if spec["reader"] != "op_class_ms":
+            continue
+        assert metric.get("workloads"), metric["name"]
+        for cell in MANIFEST["workloads"]:
+            if cell["name"] not in metric["workloads"]:
+                continue
+            # the named file, or the one the cell's configuration names
+            classes = classes_of(config_of(cell), spec)
+            assert classes, (metric["name"], cell["name"])
             names = [rule["class"] for rule in classes["classes"]]
-            assert spec["args"]["cls"] in names
+            assert spec["args"]["cls"] in names, (metric["name"],
+                                                  cell["name"])
             # the last rule takes what is left: the classes partition
             assert not {"scope", "category", "name"} & set(
                 classes["classes"][-1])
+
+
+def test_every_class_of_a_cells_decode_file_is_a_metric_the_cell_reports():
+    """The classes of a configuration's decode file partition its
+    executable: a cell reports all of them (their sum is the executable's
+    device time) and is listed under no class its file lacks."""
+    by_class = {}
+    for metric in MANIFEST["per_layer"]:
+        spec = load(BENCH, "layer_metrics", metric["name"] + ".json")
+        if spec["reader"] == "op_class_ms" and "classes" not in spec["args"]:
+            by_class[spec["args"]["cls"]] = set(metric["workloads"])
+    stems = set()
+    for cell in MANIFEST["workloads"]:
+        config = config_of(cell)
+        if not config.get("op_classes"):
+            assert not any(cell["name"] in cells
+                           for cells in by_class.values())
+            continue
+        stems.add(config["op_classes"])
+        classes = load(BENCH, "op_classes",
+                       decode_classes(config) + ".json")
+        assert classes["module"] == "jit_expand_decode_chunk"
+        have = {rule["class"] for rule in classes["classes"]}
+        listed = {cls for cls, cells in by_class.items()
+                  if cell["name"] in cells}
+        assert listed == have, (cell["name"], listed ^ have)
+    # every configuration that names a stem has a cell that was looked at,
+    # each stem its own: the eight of PR 58 and whoever came later
+    named = [load(rehearsal.REPO, c["file"]).get("op_classes")
+             for c in MANIFEST["configs"]]
+    named = [stem for stem in named if stem]
+    assert sorted(stems) == sorted(named) and len(stems) >= 8
+
+
+def test_a_count_a_layer_divides_by_the_configurations_own_layers():
+    """``status_ratio``'s ``per`` names a tuple of the expander's LMConfig:
+    every listed cell's family has it, and it is not empty."""
+    from benchmarks.harness import files
+
+    bench = files.Bench(rehearsal.REPO)
+    for metric in MANIFEST["per_layer"]:
+        spec = load(BENCH, "layer_metrics", metric["name"] + ".json")
+        per = spec.get("args", {}).get("per")
+        if spec["reader"] != "status_ratio" or per is None:
+            continue
+        assert "scale" not in spec["args"]      # no literal a layer count
+        for name in metric["workloads"]:
+            config = bench.config(bench.cell(name)["config"])
+            model = files.resolve_family(config).expander
+            assert len(getattr(model, per)) > 0, (metric["name"], name)
+
+
+def test_the_decode_roofline_is_one_metric_one_reader_one_file():
+    found = [load(BENCH, "layer_metrics", m["name"] + ".json")
+             for m in MANIFEST["per_layer"]
+             if m["name"].endswith("decode_bytes_util")]
+    assert [s["name"] for s in found] == ["lm_decode_bytes_util"]
+    assert found[0]["reader"] == "bytes_util_steps"
+    assert found[0]["args"]["needs"] == "bytes_lm"
+    names = os.listdir(os.path.join(BENCH, "harness"))
+    assert [n for n in names if n.startswith("bytes_")] == ["bytes_lm.py"]
+    assert not os.path.exists(os.path.join(BENCH, "readers",
+                                           "bytes_util.py"))
+
+
+def test_every_metric_file_is_an_entry():
+    """No file of layer_metrics/ is left behind by a merge (but
+    collective_share: reader and test exist, no admitted cell has a
+    mesh; and what prepared.json's cells bring)."""
+    with open(os.path.join(BENCH, "prepared.json")) as fh:
+        prepared = {m["name"] for m in json.load(fh)["per_layer"]}
+    files_ = {n[:-5] for n in os.listdir(os.path.join(BENCH,
+                                                      "layer_metrics"))}
+    entries = {m["name"] for m in MANIFEST["per_layer"]}
+    assert entries <= files_
+    assert files_ - entries - prepared <= {"collective_share"}
 
 
 def test_sdxl_pair_sends_sdxl_solos_request_from_two_clients():

@@ -100,6 +100,7 @@ def test_recorded_v5e_trace():
     assert {k: top[k] for k in want["top_row"]} == want["top_row"]
     assert top["seconds"] == pytest.approx(want["top_leaf"][1], rel=1e-6)
     assert out["modules"] == {"jit_step": pytest.approx(4.13e-06)}
+    assert out["module_calls"] == {"jit_step": 2}      # "then 2 calls"
     assert out["head_s"] == out["tail_s"] == 0.0       # no marks in it
 
 
@@ -128,6 +129,8 @@ def test_table_scopes_modules_head_and_tail(with_metadata):
     out = with_metadata
     assert out["modules"] == {"jit_run_chunk": pytest.approx(12000e-9),
                               "jit_decode": pytest.approx(1000e-9)}
+    # the launches those seconds are summed over
+    assert out["module_calls"] == {"jit_run_chunk": 1, "jit_decode": 1}
     assert out["busy_s"] == pytest.approx(13000e-9)
     rows = {r["name"]: r for r in out["op_table"]}
     assert "while.2" not in rows and len(rows) == 7
