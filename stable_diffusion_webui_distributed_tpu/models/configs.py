@@ -153,7 +153,20 @@ class LMConfig:
     threshold 1 it is the last). ``post_sublayer_norm`` puts an RMS norm
     after each sublayer as well as before it: ``x + norm(F(norm(x)))``.
     At one pass and without such norms a model is what it was before these
-    keys existed: no loop, no gate, no pass axis, no leaf more."""
+    keys existed: no loop, no gate, no pass axis, no leaf more.
+
+    ``norm_placement`` says it a layer: ``"pre"`` (``x + F(norm(x))``),
+    ``"post"`` (``x + norm(F(x))``: the sublayers read the stream as it
+    is) or ``"both"``; empty, every layer is ``"both"`` under
+    ``post_sublayer_norm`` and ``"pre"`` without (:attr:`sublayer_norms`).
+    ``rope_full`` None: a full layer builds no rotary table and rotates
+    nothing (the order is then some other layer's to carry).
+    ``qk_norm_extent`` ``"projection"`` norms queries and keys over ALL
+    their heads' outputs at once, one RMS and one weight of ``heads *
+    head_dim``, before the heads are cut; ``"head"`` each head alone.
+    ``linear_write_scale`` ``c`` makes a linear layer's write strength
+    ``beta = c sigmoid(b)``: at 2 the state's transition ``I - beta k k^T``
+    has eigenvalues down to -1."""
 
     vocab_size: int = 100352
     hidden_size: int = 3072
@@ -162,7 +175,8 @@ class LMConfig:
     num_kv_heads: int = 8
     head_dim: int = 128
     sliding_window: int = 512
-    rope_full: RopeConfig = dataclasses.field(default_factory=RopeConfig)
+    rope_full: Optional[RopeConfig] = dataclasses.field(
+        default_factory=RopeConfig)
     rope_sliding: RopeConfig = dataclasses.field(default_factory=RopeConfig)
     dense_layers: Tuple[int, ...] = (0,)
     intermediate_size: int = 12288
@@ -212,8 +226,16 @@ class LMConfig:
     norm_sigmoid_scale: float = 0.0
     linear_sigmoid_gate_scale: float = 0.0
     swiglu_limit: float = 0.0
+    norm_placement: Tuple[str, ...] = ()
+    qk_norm_extent: str = "head"
+    linear_write_scale: float = 1.0
 
     def __post_init__(self) -> None:
+        if self.norm_placement and (
+                len(self.norm_placement) != self.num_layers
+                or not set(self.norm_placement) <= {"pre", "post", "both"}):
+            raise ValueError("norm_placement wants 'pre', 'post' or 'both' "
+                             "for every layer")
         if self.total_ut_steps > 1 and (
                 set(self.layer_types) != {"full"}
                 or len(set(self.num_heads_per_layer)) != 1
@@ -233,6 +255,14 @@ class LMConfig:
     @property
     def vocab(self) -> Tuple[int, int]:
         return self.vocab_held or (0, self.vocab_size)
+
+    @property
+    def sublayer_norms(self) -> Tuple[str, ...]:
+        """Where each layer norms its sublayers: ``norm_placement``, or
+        what ``post_sublayer_norm`` says of every layer."""
+        return self.norm_placement or (
+            ("both" if self.post_sublayer_norm else "pre"),
+        ) * self.num_layers
 
     def layers_of(self, kind: str) -> Tuple[int, ...]:
         return tuple(i for i, t in enumerate(self.layer_types) if t == kind)
@@ -484,6 +514,8 @@ def lm_share(cfg: LMConfig, layers, chips: int, rank: int,
         layers = range(layers)
     return dataclasses.replace(
         cfg, layer_types=tuple(cfg.layer_types[i] for i in layers),
+        norm_placement=tuple(cfg.norm_placement[i] for i in layers)
+        if cfg.norm_placement else (),
         num_heads_per_layer=tuple(cfg.num_heads_per_layer[i]
                                   for i in layers),
         dense_layers=tuple(at for at, i in enumerate(layers)
@@ -948,6 +980,64 @@ def tiny_gigachat35_expander() -> ModelFamily:
     """Factory form of :data:`TINY_GIGACHAT35_EXPAND` (benchmark
     rehearsals)."""
     return TINY_GIGACHAT35_EXPAND
+
+
+# Olmo-Hybrid-7B (huggingface.co/allenai/Olmo-Hybrid-7B config.json,
+# ``model_type: olmo_hybrid``) at its published widths: 32 layers of hidden
+# 3840 in the pattern linear, linear, linear, full. A linear layer norms its
+# sublayers' INPUT and is a gated delta rule over 30 key and 30 value heads
+# of widths 96 and 192 behind a 4-tap convolution, whose write strength is
+# ``2 sigmoid(b)`` (``linear_allow_neg_eigval``). A full layer norms its
+# sublayers' OUTPUT and attends with 30 heads of 128 over 30 KV heads (no
+# grouping), no rotary table (``rope_theta`` null), no gate, queries and
+# keys normed over the whole projection. A dense SwiGLU of 11008 in every
+# layer, vocabulary 100352, head untied.
+OLMO_HYBRID_7B = LMConfig(
+    vocab_size=100352, hidden_size=3840,
+    layer_types=("linear", "linear", "linear", "full") * 8,
+    num_heads_per_layer=(30,) * 32, num_kv_heads=30, head_dim=128,
+    rope_full=None, dense_layers=tuple(range(32)), intermediate_size=11008,
+    num_experts=0, num_experts_per_tok=0, moe_intermediate_size=0,
+    shared_expert_intermediate_size=0, rms_norm_eps=1e-6, attn_gate="none",
+    qk_norm=True, qk_norm_extent="projection", linear_num_key_heads=30,
+    linear_num_value_heads=30, linear_key_head_dim=96,
+    linear_value_head_dim=192, linear_conv_kernel=4, linear_write_scale=2.0,
+    norm_placement=("pre", "pre", "pre", "post") * 8)
+
+
+def sd15_olmo_hybrid_expander() -> ModelFamily:
+    """SD1.5 with Olmo-Hybrid-7B as its resident prompt expander, cut in
+    depth alone: layers 0-15 (the first of two pipeline stages of 16, four
+    whole periods), every layer whole, all 100352 vocabulary ids."""
+    return dataclasses.replace(
+        SD15, name="sd15-olmo-hybrid-expand",
+        expander=lm_share(OLMO_HYBRID_7B, layers=16, chips=1, rank=0))
+
+
+# Tiny expander of that stack: two periods of linear, linear, linear, full;
+# 3 key heads serving 3 value heads of widths 6 and 10 (unequal, neither a
+# power of two) behind 4 taps, written at up to 2; 3 ungrouped heads of 8
+# that rotate nothing and norm queries and keys over the whole projection;
+# linear layers normed before their sublayers, full layers after.
+TINY_OLMO_HYBRID_LM = LMConfig(
+    vocab_size=512, hidden_size=24,
+    layer_types=("linear", "linear", "linear", "full") * 2,
+    num_heads_per_layer=(3,) * 8, num_kv_heads=3, head_dim=8,
+    rope_full=None, dense_layers=tuple(range(8)), intermediate_size=48,
+    num_experts=0, num_experts_per_tok=0, moe_intermediate_size=0,
+    shared_expert_intermediate_size=0, rms_norm_eps=1e-6, attn_gate="none",
+    qk_norm=True, qk_norm_extent="projection", linear_num_key_heads=3,
+    linear_num_value_heads=3, linear_key_head_dim=6,
+    linear_value_head_dim=10, linear_conv_kernel=4, linear_write_scale=2.0,
+    norm_placement=("pre", "pre", "pre", "post") * 2)
+TINY_OLMO_HYBRID_EXPAND = dataclasses.replace(
+    TINY, name="tiny-olmo-hybrid-expand", expander=TINY_OLMO_HYBRID_LM)
+
+
+def tiny_olmo_hybrid_expander() -> ModelFamily:
+    """Factory form of :data:`TINY_OLMO_HYBRID_EXPAND` (benchmark
+    rehearsals)."""
+    return TINY_OLMO_HYBRID_EXPAND
 
 
 FAMILIES = {f.name: f for f in (SD15, SD21, SD21_BASE, SDXL_BASE,

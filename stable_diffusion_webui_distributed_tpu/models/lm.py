@@ -12,7 +12,11 @@ over that one cache, :class:`LatentAttention`; ``"conv"``: a gated causal
 convolution over the hidden channels, :class:`ShortConv`), how many query
 heads an attention layer has (they may differ by layer; the KV heads are shared by
 groups of them), which rotary parameterisation goes with which kind,
-whether queries and keys are normed per head, and whether the MLP is dense
+whether queries and keys are normed per head or over the whole projection
+(``qk_norm_extent``) and rotated at all (``rope_full`` None: no table),
+on which side of a sublayer a layer's norm stands (``norm_placement``, by
+layer), how strongly a linear layer may write (``linear_write_scale``),
+and whether the MLP is dense
 or a router over experts (ops/moe.py) with one shared expert, gated or
 not, or with none. An attention output passes a sigmoid gate before
 ``o_proj``: one a head from ``g_proj``, one a channel from the second half
@@ -26,7 +30,8 @@ linear layer's read-out is gated by ``silu(z)`` or by ``c sigmoid(z)``
 with or without a bias that chooses (ops/moe.py:route). With
 ``residual_streams`` over 1 a token is ``(streams, hidden)`` between
 sublayers and a :class:`StreamMixer` around each sublayer reads, writes
-and mixes the streams; with 1 a layer is ``x + F(norm(x))``.
+and mixes the streams; with 1 a layer is ``x + F(norm(x))``, ``x +
+norm(F(x))`` or ``x + norm(F(norm(x)))`` by its placement.
 
 One call, :meth:`DecoderLM.__call__`, runs a chunk of ``T`` tokens that
 starts at position ``start`` against the cache and returns the cache with
@@ -119,6 +124,9 @@ from stable_diffusion_webui_distributed_tpu.serving.metrics import (
 
 FULL, SLIDING, LINEAR, LATENT, CONV = (
     "full", "sliding", "linear", "latent", "conv")
+#: where a layer norms a sublayer (``LMConfig.sublayer_norms``): its input,
+#: its output, or (``"both"``) both
+PRE, POST = "pre", "post"
 #: the cache's buffers of one layer, by the layer's kind
 ATTENTION_BUFFERS = ("k", "v")
 LINEAR_BUFFERS = ("state", "conv")
@@ -168,6 +176,23 @@ def shares_a_step(cfg: LMConfig) -> bool:
     under ``sequences``), and a token is one stream."""
     return (set(cfg.layer_types) <= {FULL, SLIDING, LATENT, LINEAR}
             and cfg.residual_streams == 1)
+
+
+def site_attrs(cfg: LMConfig) -> dict:
+    """Span attributes of a model whose layers depart from input norms,
+    rotated attention and a write strength under 1, as one trace of its
+    stack counts them (``serving.expander`` ``sublayer_norms``,
+    ``attention_unrotated``, ``write_strength_bound``); ``{}`` for one
+    that departs in none."""
+    attrs = {}
+    if cfg.norm_placement:
+        attrs["norms_pre"] = 2 * sum(p != POST for p in cfg.norm_placement)
+        attrs["norms_post"] = 2 * sum(p != PRE for p in cfg.norm_placement)
+    if cfg.rope_full is None and FULL in cfg.layer_types:
+        attrs["unrotated"] = len(cfg.layers_of(FULL))
+    if cfg.linear_write_scale != 1.0 and LINEAR in cfg.layer_types:
+        attrs["write_strength_bound"] = cfg.linear_write_scale
+    return attrs
 
 
 def latent_form(tokens: int, sequences: bool = False) -> str:
@@ -432,20 +457,37 @@ class Attention(nn.Module):
                                                 keepdims=False)
 
         rope = cfg.rope_full if kind == FULL else cfg.rope_sliding
-        cos, sin = rope_tables(rope, dim, q_pos)
+        if rope is not None:    # (made here: the order of a trace's ops
+            cos, sin = rope_tables(rope, dim, q_pos)    # is part of its key)
         store = k_cache.dtype
+        whole = cfg.qk_norm and cfg.qk_norm_extent == "projection"
+        if whole and cfg.attn_gate == "element":
+            raise ValueError("a norm over the whole query projection wants "
+                             "no gate among q_proj's columns")
+
+        def heads_of(x, count, name):
+            """``x`` cut into its heads; under ``whole`` normed first,
+            over all of them at once (one RMS, a weight a column)."""
+            if whole:
+                x = model_norm(cfg, name=name)(x)
+            return x.reshape(tokens, count, -1)
+
         if cfg.attn_gate == "element":
             # every head's columns are its query, then its gate
             q, gate = jnp.split(lin(2 * heads * dim, "q_proj")(n).reshape(
                 tokens, heads, 2 * dim), 2, axis=-1)
         else:
-            q = lin(heads * dim, "q_proj")(n).reshape(tokens, heads, dim)
-        k = lin(kv * dim, "k_proj")(n).reshape(tokens, kv, dim)
-        if cfg.qk_norm:
+            q = heads_of(lin(heads * dim, "q_proj")(n), heads, "q_norm")
+        k = heads_of(lin(kv * dim, "k_proj")(n), kv, "k_norm")
+        if cfg.qk_norm and not whole:
             q = model_norm(cfg, name="q_norm")(q)
             k = model_norm(cfg, name="k_norm")(k)
-        q = apply_rope(q, cos, sin, rope.interleaved).astype(self.dtype)
-        k = apply_rope(k, cos, sin, rope.interleaved).astype(store)
+        if rope is None:        # no table is built, nothing is rotated
+            EXPANDER.record_unrotated(delta_rule.form(tokens, sequences))
+            q, k = q.astype(self.dtype), k.astype(store)
+        else:
+            q = apply_rope(q, cos, sin, rope.interleaved).astype(self.dtype)
+            k = apply_rope(k, cos, sin, rope.interleaved).astype(store)
         v = lin(kv * dim, "v_proj")(n).reshape(tokens, kv, dim).astype(store)
         real = q_pos < end
         if sequences:
@@ -831,7 +873,8 @@ class DeltaMixer(nn.Module):
     """The token mixer of a ``"linear"`` layer: queries, keys and values
     through a causal depth-wise convolution and SiLU, queries and keys
     L2-normalised per head, then the gated delta rule over the layer's
-    recurrent state; the read-out is RMS-normed per head, gated by
+    recurrent state at a write strength ``linear_write_scale sigmoid(b)``;
+    the read-out is RMS-normed per head, gated by
     ``silu(z)`` (or, ``linear_sigmoid_gate_scale`` ``c`` over 0, normed
     ``x_hat * (1 + weight)`` and gated by ``c sigmoid(z)``) and projected.
     ``state`` is ``(value heads, key width,
@@ -848,7 +891,8 @@ class DeltaMixer(nn.Module):
     def __call__(self, n, real, length, state, conv,
                  sequences: bool = False):
         cfg = self.config
-        EXPANDER.record_delta(delta_rule.form(n.shape[0], sequences))
+        EXPANDER.record_delta(delta_rule.form(n.shape[0], sequences),
+                              cfg.linear_write_scale)
         k_heads, v_heads = cfg.linear_num_key_heads, cfg.linear_num_value_heads
         k_dim, v_dim = cfg.linear_key_head_dim, cfg.linear_value_head_dim
         channels, taps = cfg.linear_conv_channels, cfg.linear_conv_kernel
@@ -884,7 +928,11 @@ class DeltaMixer(nn.Module):
         # a padded row neither decays the state nor writes to it
         g = jnp.where(real[:, None],
                       -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias), 0.0)
-        beta = jnp.where(real[:, None], jax.nn.sigmoid(b), 0.0)
+        # ``c sigmoid(b)``: at ``c`` 2 ``I - beta k k^T`` reflects too
+        scale = cfg.linear_write_scale
+        beta = jnp.where(
+            real[:, None], jax.nn.sigmoid(b) if scale == 1.0
+            else scale * jax.nn.sigmoid(b), 0.0)
         # computed in float32 whatever the buffer holds
         step = delta_rule.recurrent_step_each if sequences \
             else delta_rule.gated_delta_rule
@@ -1000,16 +1048,25 @@ class DecoderLayer(nn.Module):
                        name="mlp")(n, counted())
 
         streams = cfg.residual_streams
+        placement = cfg.sublayer_norms[self.layer]
+        form = delta_rule.form(x.shape[0], sequences)
         beside = []     # what each sublayer returns beside its output
         for sublayer, norm_name, hc in (
                 (token_mixer, "input_norm", "attn_hc"),
                 (mlp, "post_attention_norm", "mlp_hc")):
-            norm = model_norm(cfg, name=norm_name)
+
+            def norm(x):
+                """The stream as the sublayer reads it."""
+                if placement == POST:
+                    return x
+                EXPANDER.record_norm(PRE, form)
+                return model_norm(cfg, name=norm_name)(x)
 
             def normed_after(out):
                 """The sublayer's output as the residual takes it."""
-                if not cfg.post_sublayer_norm:
+                if placement == PRE:
                     return out
+                EXPANDER.record_norm(POST, form)
                 return model_norm(cfg, name=norm_name + "_2")(out)
 
             if streams == 1:

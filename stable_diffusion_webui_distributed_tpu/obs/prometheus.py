@@ -875,6 +875,24 @@ def render() -> str:
             "Bytes of recurrent state and kept inputs the prompt "
             "expander's forks copied, once a sequence.",
             expander["fork_bytes_copied"])
+    _labeled_family(
+        lines, "sdtpu_expander_sublayer_norms_total", "counter",
+        "Sublayer norms traced, by where they stand (pre: a sublayer's "
+        "input, post: its output) and the form of the executable.",
+        [(f'placement="{_label(placement)}",form="{_label(form)}"', n)
+         for placement, by_form in sorted(
+             expander["sublayer_norms"].items())
+         for form, n in sorted(by_form.items())])
+    _labeled_family(
+        lines, "sdtpu_expander_attention_unrotated_total", "counter",
+        "Attention sites traced that built no rotary table, by the form "
+        "of the executable.",
+        [(f'form="{_label(form)}"', n)
+         for form, n in sorted(expander["attention_unrotated"].items())])
+    _scalar(lines, "sdtpu_expander_write_strength_bound", "gauge",
+            "Largest write strength the last delta-rule mixer traced can "
+            "give (1 for sigmoid(b), 2 for 2 sigmoid(b); 0: none traced).",
+            expander["write_strength_bound"])
 
     _labeled_family(
         lines, "sdtpu_stage_compiles_total", "counter",

@@ -2,10 +2,14 @@
 (models/lm.py, layer kind ``"linear"``) over a state of fixed size.
 
 Per head, with the state ``S`` of shape ``(K, V)``, a key ``k`` and a query
-``q`` of width ``K``, a value ``v`` of width ``V``, a log-decay ``g <= 0``
-and a write strength ``beta`` in (0, 1)::
+``q`` of width ``K``, a value ``v`` of width ``V`` (the two widths need not
+be equal), a log-decay ``g <= 0`` and a write strength ``beta`` in (0, 2)::
 
     S <- exp(g) S;   u = beta (v - S^T k);   S <- S + k u^T;   o = S^T q
+
+(the transition ``I - beta k k^T`` has the eigenvalue ``1 - beta`` along a
+unit ``k``: a caller whose ``beta`` is ``sigmoid(b)`` stays in (0, 1), one
+whose is ``2 sigmoid(b)`` also reflects; no form below assumes either).
 
 Two forms of the same mathematics, chosen by what the call shows (the
 number of tokens, which is static), as ops/moe.py chooses its product:

@@ -275,6 +275,8 @@ class PromptExpander:
             if latent and self.shares_a_step else {}
         if recurrent and self.shares_a_step:    # and its recurrence
             how["delta"] = delta_rule.form(1, sequences=batch > 1)
+        # and of one whose layers depart from pre-normed, rotated ones
+        sites = lm.site_attrs(self.config)
         exits = []        # per executable call of a looped model
         routed = []       # per executable call: (load, none held)
         masked = 0        # padded rows kept out of a recurrence or kept rows
@@ -285,7 +287,8 @@ class PromptExpander:
                 continue
             padded = np.zeros(kv.chunk_bucket(len(ids)), np.int32)
             padded[:len(ids)] = ids
-            attrs = {"tokens": len(ids), "prefix_hit": bool(held), **looped}
+            attrs = {"tokens": len(ids), "prefix_hit": bool(held), **looped,
+                     **sites}
             if recurrent or conv:   # rows kept out of the layers' state
                 attrs["padded"] = len(padded) - len(ids)
             if recurrent:           # the form its recurrence takes
@@ -325,7 +328,7 @@ class PromptExpander:
                                 bytes=sum(sizes.values()) - copied
                                 + alone.get(lm.LINEAR, 0),
                                 state_bytes_copied=fork_copied,
-                                **looped, **how):
+                                **looped, **how, **sites):
                 cache = kv.forked(
                     cache, self._fork_fn(capacity, batch, own_slots)(cache))
                 jax.block_until_ready(cache)    # fenced, as a prefill is
@@ -352,7 +355,7 @@ class PromptExpander:
                     and all(tok.eos in one for one in made)):
                 break
             with obs_spans.span("expand.decode_chunk", tokens=DECODE_STEPS,
-                                sequences=live, **looped, **how):
+                                sequences=live, **looped, **how, **sites):
                 cache, token, position, out, step_load, step_none, *read = \
                     decode(params, cache, token, position, key,
                            temperature, *more)

@@ -443,7 +443,9 @@ def replay_sites(rows) -> None:
                 "product": EXPANDER.record_product,
                 "mixer": EXPANDER.record_mixer,
                 "conv": EXPANDER.record_conv,
-                "delta": EXPANDER.record_delta}
+                "delta": EXPANDER.record_delta,
+                "norm": EXPANDER.record_norm,
+                "unrotated": EXPANDER.record_unrotated}
     for counter, *args in rows:
         counters[counter](*args)
 
@@ -588,6 +590,14 @@ class ExpanderStats:
     inputs (a step reads and writes each of its sequences' once a layer),
     ``fork_bytes_copied`` what forks copied of them (once a sequence; a
     buffer that keeps positions is never copied).
+    ``sublayer_norms`` counts, the same way and by the same three forms of
+    the executable traced, a layer's norms by where they stand (``pre``: a
+    sublayer's input, ``post``: its output; a layer normed both ways
+    counts under both), ``attention_unrotated`` the attention sites traced
+    with no rotary table, and ``write_strength_bound`` is the largest write
+    strength the last delta-rule mixer traced can give (``LMConfig.
+    linear_write_scale``: 1.0 for ``sigmoid(b)``, 2.0 for ``2 sigmoid(b)``;
+    0.0 before any was traced).
     Of a looped model (``LMConfig.total_ut_steps`` over 1): ``layer_passes``,
     the passes of the whole stack its decode steps ran (every pass of every
     step, whichever the head read; over ``decode_steps``: passes a token); ``exit_pass``, the tokens made by the
@@ -631,6 +641,10 @@ class ExpanderStats:
                            "recurrent_forked": 0}  # guarded-by: _lock
             self.state_bytes_stepped = 0  # guarded-by: _lock
             self.fork_bytes_copied = 0  # guarded-by: _lock
+            self.norms = {placement: dict.fromkeys(self.deltas, 0)
+                          for placement in ("pre", "post")}  # guarded-by: _lock
+            self.unrotated = dict.fromkeys(self.deltas, 0)  # guarded-by: _lock
+            self.write_bound = 0.0     # guarded-by: _lock
 
     def record_product(self, path: str) -> None:
         """One expert layer in one trace took product ``path``."""
@@ -650,11 +664,26 @@ class ExpanderStats:
         with self._lock:
             self.convs[form] += 1
 
-    def record_delta(self, form: str) -> None:
-        """One gated-delta-rule mixer in one trace, of ``form``."""
-        _note_site("delta", str(form))
+    def record_delta(self, form: str, bound: float = 1.0) -> None:
+        """One gated-delta-rule mixer in one trace, of ``form``, whose
+        write strength lies under ``bound``."""
+        _note_site("delta", str(form), float(bound))
         with self._lock:
             self.deltas[form] += 1
+            self.write_bound = float(bound)
+
+    def record_norm(self, placement: str, form: str) -> None:
+        """One sublayer norm in one trace of an executable of ``form``,
+        before (``pre``) or after (``post``) its sublayer."""
+        _note_site("norm", str(placement), str(form))
+        with self._lock:
+            self.norms[placement][form] += 1
+
+    def record_unrotated(self, form: str) -> None:
+        """One attention site in one trace that built no rotary table."""
+        _note_site("unrotated", str(form))
+        with self._lock:
+            self.unrotated[form] += 1
 
     def record(self, *, prefilled: int, from_prefix: int, sequences: int,
                decoded: int, decode_steps: int, experts_read: int, load,
@@ -736,6 +765,10 @@ class ExpanderStats:
                 "delta_mixers": dict(self.deltas),
                 "state_bytes_stepped": self.state_bytes_stepped,
                 "fork_bytes_copied": self.fork_bytes_copied,
+                "sublayer_norms": {placement: dict(by_form) for
+                                   placement, by_form in self.norms.items()},
+                "attention_unrotated": dict(self.unrotated),
+                "write_strength_bound": self.write_bound,
                 "layer_passes": self.layer_passes,
                 "exit_pass": list(self.exit_pass),
                 "exit_lambda_max": self.exit_lambda_max,

@@ -390,6 +390,19 @@ def test_a_forked_decode_chunk_compiles_for_v5e(one_chip, preset):
     ("decode4", "sd15_gigachat35_expander", 9.46, 4, 64, 70),
     ("prefill", "sd15_gigachat35_expander", 9.46, 0, 400, 20),
     ("prefill2048", "sd15_gigachat35_expander", 9.46, 0, 2000, 20),
+    # no kernel at all: twelve delta mixers that step a (30, 96, 192) state
+    # a sequence at strength up to 2 beside four unrotated attentions of 30
+    # ungrouped heads; four sequences donate the one sequence's 157 MB of
+    # keys and values (shared, handed through), 256 own slots a layer each
+    # and forty-eight states with their kept rows, 333 MB; the prompt's
+    # chunk chunk-wise over twelve states; the instruction's one chunk of
+    # 2 048 (30 heads' scores over 2 560 positions)
+    # (the arguments are the 8.20 GB of weights and the cache: a state's
+    # 192-wide minor axis lies in tiles of 256, so forty-eight states take
+    # 142 MB where their shapes say 106)
+    ("decode4", "sd15_olmo_hybrid_expander", 8.50, 0, 128, 330),
+    ("prefill", "sd15_olmo_hybrid_expander", 8.30, 0, 400, 180),
+    ("prefill2048", "sd15_olmo_hybrid_expander", 8.30, 0, 2000, 180),
 ])
 def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
         one_chip, monkeypatch, which, expander, argument_gb, kernels,
@@ -412,7 +425,8 @@ def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
     module = lm.DecoderLM(cfg, dtype=jnp.bfloat16)
     capacity = 2560 if expander in ("sd15_mellum2_expander",
                                     "sd15_kanana2_expander",
-                                    "sd15_gigachat35_expander") else 1024
+                                    "sd15_gigachat35_expander",
+                                    "sd15_olmo_hybrid_expander") else 1024
 
     def on_chip(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

@@ -1012,7 +1012,8 @@ class TestEnginePath:
             "mixer_products", "conv_mixers", "residual_streams",
             "sinkhorn_iters", "layer_passes", "exit_pass",
             "exit_lambda_max", "delta_mixers", "state_bytes_stepped",
-            "fork_bytes_copied"}
+            "fork_bytes_copied", "sublayer_norms", "attention_unrotated",
+            "write_strength_bound"}
         # a model of one pass leaves the looped model's counters alone
         assert (block["layer_passes"], block["exit_pass"],
                 block["exit_lambda_max"]) == (0, [], 0.0)
@@ -1024,6 +1025,12 @@ class TestEnginePath:
                                          "recurrent_forked": 0}
         assert (block["state_bytes_stepped"],
                 block["fork_bytes_copied"]) == (0, 0)
+        # every layer norms its sublayers' input alone and rotates, and
+        # no delta-rule mixer was traced to bound a write strength
+        assert set(block["sublayer_norms"]) == {"pre", "post"}
+        assert not any(block["sublayer_norms"]["post"].values())
+        assert not any(block["attention_unrotated"].values())
+        assert block["write_strength_bound"] == 0.0
         json.dumps(block)
 
     @pytest.mark.parametrize("sequences,forked_at,steps", [
@@ -1084,7 +1091,8 @@ class TestDrawnAhead:
         assert self.ahead_since(before) == [1, 1, 0]
         def counters():     # but those a trace feeds
             return {k: v for k, v in EXPANDER.summary().items()
-                    if not k.endswith(("_products", "_mixers"))}
+                    if not k.endswith(("_products", "_mixers", "_norms",
+                                       "_unrotated", "_bound"))}
 
         counted = counters()
         monkeypatch.setattr(engine, "_draws_ahead", lambda p: False)
