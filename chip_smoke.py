@@ -5,12 +5,13 @@ One process, one chip, no arguments (``python chip_smoke.py``):
 1. the compile cache is placed (``JAX_COMPILATION_CACHE_DIR``, else
    ``<checkout>/.jax_cache``) and its directory and entry count printed;
 2. both Pallas attention kernels are compiled through their public entry
-   points at every UNet self-attention shape of SD1.5 512² and SDXL 1024²,
-   the compiled text is searched for the Mosaic call, and each runs once on
-   seeded q/k/v against its reference on the same device; the tiled kernel
-   and XLA's attention are then timed alone at each shape and both times
-   printed beside what the default path takes there, so the crossover of
-   ops/attention.py can be read again on any chip;
+   points at every UNet self-attention shape of SD1.5 512² and SDXL 1024²
+   (the tiled one also at the cross-attention shapes over the crossover,
+   77 and 231 keys), the compiled text is searched for the Mosaic call, and
+   each runs once on seeded q/k/v against its reference on the same device;
+   the tiled kernel and XLA's attention are then timed alone at each shape
+   and both times printed beside what the default path takes there, so the
+   rule of ops/attention.py can be read again on any chip;
 3. SD1.5 is built at its published width with seeded random weights in the
    serving policy's dtype, wrapped in ``ApiServer(engine, port=0)`` and asked
    over real HTTP for the reference's calibration image, the same image
@@ -60,6 +61,20 @@ KERNEL_CASES = [
     (2, 8, 4096, 40), (2, 8, 1024, 80), (2, 8, 256, 160), (2, 8, 64, 160),
     (2, 10, 4096, 64), (2, 20, 1024, 64),
     (8, 8, 4096, 40), (8, 8, 1024, 80),
+]
+
+#: (batch, heads, tokens, head_dim, keys) of the cross-attention sites at or
+#: over the crossover: SD1.5 over an expanded context of three 77-token
+#: chunks at CFG batch 2 and 8, then SDXL over 77 tokens at CFG batch 2 and
+#: 4 (sdxl_pair), then SD1.5's 64x64 site at CFG batch 4 (two images), the
+#: first whose scores XLA cannot keep on chip; the key count is off the
+#: tiling, so the kernel pads and masks it
+CROSS_CASES = [
+    (2, 8, 4096, 40, 231), (8, 8, 4096, 40, 231),
+    (2, 8, 1024, 80, 231), (8, 8, 1024, 80, 231),
+    (2, 10, 4096, 64, 77), (2, 20, 1024, 64, 77),
+    (4, 10, 4096, 64, 77), (4, 20, 1024, 64, 77),
+    (4, 8, 4096, 40, 231),
 ]
 
 
@@ -149,11 +164,12 @@ TIMING_CALLS = 20
 TIMING_LOOPS = 5
 
 
-def phase_kernels(report: Report, cases, seed: int) -> None:
+def phase_kernels(report: Report, cases, seed: int, cross=()) -> None:
     """flash_attention / ragged_attention through their public entry points:
     is the Mosaic kernel in the compiled text, and does one run on seeded
     bf16 q/k/v agree with the reference on the same device. Then the tiled
-    kernel and XLA's attention alone, in milliseconds a call."""
+    kernel and XLA's attention alone, in milliseconds a call. ``cross``
+    cases carry a key count of their own and take the tiled kernel only."""
     import statistics
 
     import jax
@@ -189,16 +205,11 @@ def phase_kernels(report: Report, cases, seed: int) -> None:
             seconds.append(time.perf_counter() - t0)
         return statistics.median(seconds[1:]) / TIMING_CALLS * 1e3
 
-    for b, h, t, d in cases:
-        shape = f"B{b} H{h} T{t} D{d}"
-        kq, kk, kv = jax.random.split(jax.random.key(seed), 3)
-        q, k, v = (jax.random.normal(key, (b, t, h, d), jnp.bfloat16)
-                   for key in (kq, kk, kv))
-        # one full row and one cut inside a tile, so the in-tile mask and
-        # the skipped tail tiles both run
-        true_len = jnp.asarray(
-            [t] + [max(1, (5 * t) // 8 + 3)] * (b - 1), jnp.int32)
-
+    def tiled_against_xla(b, h, t, d, s) -> tuple:
+        shape = f"B{b} H{h} T{t} D{d}" + (f" S{s}" if s != t else "")
+        q, k, v = (jax.random.normal(key, (b, n, h, d), jnp.bfloat16)
+                   for key, n in zip(jax.random.split(jax.random.key(seed), 3),
+                                     (t, s, s)))
         flash = jax.jit(flash_attention).lower(q, k, v).compile()
         report.check(f"flash kernel in compiled text [{shape}]",
                      "tpu_custom_call" in flash.as_text())
@@ -209,7 +220,17 @@ def phase_kernels(report: Report, cases, seed: int) -> None:
             f"tiled {alone_ms(flash_attention, q, k, v):.4f} ms, "
             f"XLA {alone_ms(jax.nn.dot_product_attention, q, k, v):.4f} ms "
             f"a call; the default path takes "
-            f"{choose(jax.default_backend(), t, t, q.dtype, self_attention=True)}")
+            f"{choose(jax.default_backend(), t, s, q.dtype, batch_heads=b * h)}")
+        return shape, q, k, v
+
+    for b, h, t, d, s in cross:
+        tiled_against_xla(b, h, t, d, s)
+    for b, h, t, d in cases:
+        shape, q, k, v = tiled_against_xla(b, h, t, d, t)
+        # one full row and one cut inside a tile, so the in-tile mask and
+        # the skipped tail tiles both run
+        true_len = jnp.asarray(
+            [t] + [max(1, (5 * t) // 8 + 3)] * (b - 1), jnp.int32)
 
         ragged = jax.jit(ragged_attention).lower(q, k, v, true_len).compile()
         report.check(f"ragged kernel in compiled text [{shape}]",
@@ -501,7 +522,7 @@ def run(args, device: dict) -> bool:
     cache_dir = phase_cache(report)
     family, size, steps = FAMILIES["sd15"], 512, 20
     if args.chips == 1:
-        phase_kernels(report, KERNEL_CASES, args.seed)
+        phase_kernels(report, KERNEL_CASES, args.seed, CROSS_CASES)
         phase_serve(report, counter, family, dtypes.TPU, size, size, steps,
                     args.seed)
     else:

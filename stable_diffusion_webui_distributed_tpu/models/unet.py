@@ -148,9 +148,11 @@ class Attention(nn.Module):
     sequence tiles), "ring" (sequence-parallel over the mesh's ``sp`` axis
     for token counts beyond one chip — requires ``mesh``), or "ragged"
     (per-row true-length masked kernel, ops/ragged_attention.py).
-    Cross-attention's 77-token context always takes the XLA path, as does
-    any shape the chosen impl can't tile. Every site is counted at trace
-    time by the path it took (serving/metrics.py ``ATTENTION``).
+    Cross-attention follows "auto", "xla" and "flash" like self-attention
+    (its context of ``n * 77`` tokens padded and masked in the kernel) and
+    takes the XLA path under "ring" and "ragged", as does any shape the
+    chosen impl can't tile. Every site is counted at trace time by the
+    path it took (serving/metrics.py ``ATTENTION``).
 
     ``true_len`` (traced (B,) int32, optional) forces the ragged path
     regardless of ``impl``: for self-attention the row's valid spatial
@@ -229,8 +231,7 @@ class Attention(nn.Module):
             )
 
             out, path = attend(q, k, v, scale=1.0 / head_dim**0.5,
-                               impl=self.impl,
-                               self_attention=context is None)
+                               impl=self.impl)
         ATTENTION.record(path, T, ctx_len, head_dim)
         out = out.reshape(B, T, C)
         y = _linear(self.quant_linears, C, dtype=self.dtype,
@@ -279,6 +280,7 @@ class TransformerBlock(nn.Module):
             true_len=true_len, lora=sub("attn1"),
         )
         x = x + Attention(self.num_heads, dtype=self.dtype,
+                          impl=self.attention_impl,
                           quant_linears=qz, name="attn2")(
             nn.LayerNorm(dtype=jnp.float32, name="ln2")(x), context,
             true_len=ctx_true, lora=sub("attn2"),

@@ -1,8 +1,8 @@
 """Which attention a site takes: the tiled Pallas kernel or XLA.
 
-One place decides, from what the call itself shows: the platform, whether
-it is self-attention, the shapes and the dtype, whether it is masked and
-whether query heads share KV heads. No environment variable and no option:
+One place decides, from what the call itself shows: the platform, the
+shapes (queries, keys, batch x heads) and the dtype, whether it is masked
+and whether query heads share KV heads. No environment variable and no option:
 a shape where the kernel has not measured faster stays on XLA
 (``jax.nn.dot_product_attention`` for a UNet's sites; a decoder LM's
 causal, windowed, grouped-query sites over a cache, which the kernel cannot
@@ -43,6 +43,35 @@ whatever copies a layout needs counted (my chip run, PR 57): 0.794 ms at
 
 At 1024 tokens and above the kernel wins at every head size measured; at
 256 and under XLA's score matrix is 5 MB or less and XLA wins.
+
+Cross-attention over a context of ``n * 77`` keys, which the kernel pads to
+128 or 256 rows and masks (``ops/flash_attention.py:_tiled_keys``; my chip
+runs, PR 60: alone in ``chip_smoke.py``'s loop as above, and the same site
+inside the cells' ``jit_run_chunk`` by ``benchmarks/op_table.py``, where
+nothing is flattened and XLA fuses around the call; PERF.md sections 5, 6):
+
+                              alone              in the cell
+    (B, T, S, H, D)           XLA ms   tiled ms   XLA ms   tiled ms
+    (2, 4096, 231, 8, 40)     0.083    0.076      0.040    0.034   SD1.5, 3 chunks
+    (2, 1024, 231, 8, 80)     0.046    0.051      0.012    0.011
+    (8, 4096, 231, 8, 40)     0.739    0.211      0.695    0.136   four images
+    (8, 1024, 231, 8, 80)     0.089    0.109      0.048    0.044
+    (4, 4096, 231, 8, 40)     0.396    0.123                       two images
+    (2, 4096, 77, 10, 64)     0.055    0.102      0.020    0.036   SDXL
+    (2, 1024, 77, 20, 64)     0.045    0.066      0.010    0.022
+    (4, 4096, 77, 10, 64)     0.076    0.162               0.072   sdxl_pair
+    (4, 1024, 77, 20, 64)     0.056    0.105               0.044
+
+XLA's path is three ops with the float32 scores and the bf16 probabilities
+between them, six bytes a score. Where those fit the core's 128 MiB of VMEM
+(91 MB at SD1.5's batch 2 and at its 32x32 sites of batch 8, 38 and 76 MB
+in SDXL) the compiled cell keeps them there (memory space ``S(1)`` on the
+arrays in the solo cell's optimised HLO): SDXL's sites are twice as fast on
+XLA, and SD1.5's 91 MB sites within 0.6 ms a request of the kernel (ahead
+by 8 to 15 % of a call), which no cell resolves. Where they do not fit (363
+MB at ``(8, 4096, 231, 8, 40)``, 182 at batch 4) both arrays go through HBM
+and the kernel is three to five times faster. :data:`SCORE_BYTES` and
+:data:`ON_CHIP_BYTES` are that line.
 """
 
 from __future__ import annotations
@@ -56,43 +85,52 @@ from stable_diffusion_webui_distributed_tpu.ops.flash_attention import (
 
 #: fewest tokens at which the tiled kernel measured faster than XLA (above)
 TILED_MIN_TOKENS = 1024
+#: what XLA's attention holds for one score between its three ops: the
+#: float32 score and the bf16 probability
+SCORE_BYTES = 6
+#: a v5e core's VMEM: scores over this go through HBM on XLA's path
+ON_CHIP_BYTES = 128 * 2 ** 20
 
 TILED = "tiled"
 XLA = "xla"
 
 
-def choose(platform: str, t: int, s: int, dtype, *,
-           self_attention: bool, masked: bool = False,
-           kv_groups: int = 1) -> str:
+def choose(platform: str, t: int, s: int, dtype, *, batch_heads: int = 1,
+           masked: bool = False, kv_groups: int = 1) -> str:
     """``"tiled"`` or ``"xla"`` for one site, from what the site shows.
 
-    Tiled wants a TPU, self-attention (cross-attention's 77-token context
-    is small and does not tile), the serving policy's bf16 (the only dtype
-    timed) and a sequence at or over the crossover that tiles evenly. The
-    kernel takes no mask and one KV head a query head, so a causal or
-    windowed site (``masked``) and a grouped-query one (``kv_groups`` query
-    heads a KV head) stay on XLA; neither has been timed on the kernel."""
+    Tiled wants a TPU, the serving policy's bf16 (the only dtype timed) and
+    ``t`` queries at or over the crossover that tile evenly. Over ``s`` keys
+    at or over the crossover too (self-attention) that is all. Over a short
+    context (cross-attention's ``n * 77`` keys) XLA is ahead as long as it
+    keeps the scores on chip, so such a site goes to the kernel only where
+    ``batch_heads * t * s`` scores at :data:`SCORE_BYTES` each are more than
+    :data:`ON_CHIP_BYTES`. The kernel takes no mask of a caller's and one KV
+    head a query head, so a causal or windowed site (``masked``) and a
+    grouped-query one (``kv_groups`` query heads a KV head) stay on XLA;
+    neither has been timed on the kernel."""
     if masked or kv_groups != 1:
         return XLA
-    if (platform == "tpu" and self_attention
-            and jnp.dtype(dtype) == jnp.bfloat16
+    if not (platform == "tpu" and jnp.dtype(dtype) == jnp.bfloat16
             and t >= TILED_MIN_TOKENS and blocks(t, s) is not None):
-        return TILED
-    return XLA
+        return XLA
+    if s < TILED_MIN_TOKENS and (batch_heads * t * s * SCORE_BYTES
+                                 <= ON_CHIP_BYTES):
+        return XLA
+    return TILED
 
 
 def attend(q: jax.Array, k: jax.Array, v: jax.Array, *, scale: float,
-           impl: str = "auto", self_attention: bool):
+           impl: str = "auto"):
     """(output, path taken) for ``(B, T, H, D)`` q and ``(B, S, H, D)`` k, v.
 
-    ``impl`` "auto" asks :func:`choose`; "flash" forces the kernel on
-    self-attention wherever the sequence tiles (tests, chip_smoke.py);
-    anything else is XLA."""
-    t, s = q.shape[1], k.shape[1]
+    ``impl`` "auto" asks :func:`choose`; "flash" forces the kernel wherever
+    the sequences tile (tests, chip_smoke.py); anything else is XLA."""
+    b, t, h, _ = q.shape
+    s = k.shape[1]
     if impl == "auto":
-        path = choose(jax.default_backend(), t, s, q.dtype,
-                      self_attention=self_attention)
-    elif impl == "flash" and self_attention and blocks(t, s) is not None:
+        path = choose(jax.default_backend(), t, s, q.dtype, batch_heads=b * h)
+    elif impl == "flash" and blocks(t, s) is not None:
         path = TILED
     else:
         path = XLA
@@ -139,8 +177,8 @@ def attend_positions(q: jax.Array, k: jax.Array, v: jax.Array,
     t, heads, dim = q.shape
     s, kv, _ = k.shape
     groups = heads // kv
-    path = choose(jax.default_backend(), t, s, q.dtype, self_attention=True,
-                  masked=True, kv_groups=groups)
+    path = choose(jax.default_backend(), t, s, q.dtype, masked=True,
+                  kv_groups=groups)
     seen = _seen(q_pos, k_pos, window)
     scores = jnp.einsum("tkgd,skd->kgts", q.reshape(t, kv, groups, dim), k,
                         preferred_element_type=jnp.float32) * scale
@@ -170,7 +208,7 @@ def attend_two_ranges(q: jax.Array, k_shared: jax.Array, v_shared: jax.Array,
     s, kv, _ = k_shared.shape
     groups = heads // kv
     path = choose(jax.default_backend(), 1, s + k_own.shape[1], q.dtype,
-                  self_attention=True, masked=True, kv_groups=groups)
+                  masked=True, kv_groups=groups)
     q = q.reshape(b, kv, groups, dim)
     scores = jnp.concatenate(
         [jnp.einsum("bkgd,skd->kgbs", q, k_shared,

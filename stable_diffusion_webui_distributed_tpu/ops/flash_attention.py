@@ -1,4 +1,4 @@
-"""Tiled online-softmax attention (Pallas) for the UNet's latent self-attention.
+"""Tiled online-softmax attention (Pallas) for the UNet's attention sites.
 
 ``jax.nn.dot_product_attention`` writes the whole (T x S) score matrix to
 HBM and reads it back: 1.34 GB a layer at SDXL 1024² (T = 4096, 20 batch x
@@ -25,8 +25,21 @@ for the other layout holds 40 of every 128 lanes in HBM, and a site needed
 four of them (PERF.md section 6, PR 57). Only a width whose K and V blocks
 would not fit the kernel's VMEM goes through ``(B*H, T, D)``.
 
-Falls back to ``jax.nn.dot_product_attention`` when the sequence does not
-tile (cross-attention's 77-token context).
+A key count off the sublane tiling is a cross-attention context (``n * 77``
+tokens: 77, 231) and takes a kernel of its own, :func:`_keys_kernel`, under
+its own jitted name :func:`_tiled_keys` (a trace's reader prices a
+``_tiled`` call as ``T x T`` work from its result's shape, and must not find
+this one). k and v are padded with zero rows to the next multiple of 128 and
+the kernel is told the true key count: the scores at or past it are set to
+``-inf`` before the maximum, so they weigh ``exp(-inf) = 0``. With so few
+keys the scores lie TRANSPOSED in that kernel, keys on the sublanes and
+queries on the lanes: a row's maximum over 256 lanes is seven rotations of
+the cross-lane unit for every two vregs of scores, and the kernel above took
+0.62 ms at SD1.5's ``(8, 4096, 231, 8, 40)`` where XLA, writing the float32
+scores to HBM, takes 0.74; over the sublanes a maximum is one elementwise
+pass, the sum rides the MXU as a row of ones under ``v^T``, and the call
+takes 0.21 ms (PERF.md section 6, PR 60). A query sequence off the tiling
+falls back to ``jax.nn.dot_product_attention``.
 """
 
 from __future__ import annotations
@@ -110,6 +123,100 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *scratch, heads: int,
                     o_ref.dtype)
 
 
+def _keys_kernel(q_ref, k_ref, vt_ref, o_ref, *, heads: int, head_dim: int,
+                 scale: float, keys: int):
+    """One (batch, head group, q-tile) step over a short key sequence whose
+    first ``keys`` rows are keys and the rest padding.
+
+    ``q_ref`` and ``o_ref`` are ``(1, block_q, heads*head_dim)``, ``k_ref``
+    ``(1, S, heads*head_dim)``, ``vt_ref`` ``(1, heads, rows, S)``: a head's
+    values transposed, and at row :func:`_ones_row` a row of ones over the
+    keys, so that ``v^T p`` carries the softmax's denominator. The scores are
+    ``(S, block_q)``: the maximum and the mask run over the sublanes."""
+    block_q, s_len = q_ref.shape[1], k_ref.shape[1]
+    precision = (jax.lax.Precision.DEFAULT
+                 if q_ref.dtype == jnp.bfloat16 else None)
+    padding = jax.lax.broadcasted_iota(jnp.int32, (s_len, block_q), 0) >= keys
+    ones = _ones_row(head_dim)
+    outs = []
+    for g in range(heads):
+        lanes = slice(g * head_dim, (g + 1) * head_dim)
+        q = q_ref[0, :, lanes] * scale                       # (block_q, D)
+        s = jax.lax.dot_general(k_ref[0, :, lanes], q, _NT,
+                                precision=precision,
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(padding, -jnp.inf, s)
+        p = jnp.exp(s - s.max(axis=0, keepdims=True))        # (S, block_q)
+        o = jnp.dot(vt_ref[0, g], p.astype(vt_ref.dtype), precision=precision,
+                    preferred_element_type=jnp.float32)      # (rows, block_q)
+        outs.append(o[:head_dim] / o[ones:ones + 1])
+    width = heads * head_dim
+    if width % 128:     # the transposition wants whole 128-lane tiles
+        outs.append(jnp.zeros((-width % 128, block_q), jnp.float32))
+    o_ref[0] = jnp.concatenate(outs, axis=0).T[:, :width].astype(o_ref.dtype)
+
+
+def _ones_row(head_dim: int) -> int:
+    """Where ``v^T`` carries its row of ones: the first sublane tile behind
+    the values."""
+    return -(-head_dim // 8) * 8
+
+
+def _keys_call(q, k, vt, *, keys: int, heads: int, head_dim: int,
+               block_q: int, scale: float, interpret: bool):
+    """:func:`_keys_kernel` on ``(N, T, W)`` q, ``(N, S, W)`` k and ``(N,
+    W / head_dim, rows, S)`` vt, the first ``keys`` of ``S`` keys."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, t, w = q.shape
+    s_len = k.shape[1]
+    width = heads * head_dim
+    all_heads = n * (w // head_dim)
+    q_spec = pl.BlockSpec((1, block_q, width), lambda b, h, i: (b, i, h))
+    return pl.pallas_call(
+        functools.partial(_keys_kernel, heads=heads, head_dim=head_dim,
+                          scale=scale, keys=keys),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid=(n, w // width, t // block_q),
+        in_specs=[
+            q_spec,
+            pl.BlockSpec((1, s_len, width), lambda b, h, i: (b, 0, h)),
+            pl.BlockSpec((1, heads, vt.shape[2], s_len),
+                         lambda b, h, i: (b, h, 0, 0)),
+        ],
+        out_specs=q_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * all_heads * t * keys * head_dim,
+            transcendentals=all_heads * t * keys,
+            bytes_accessed=q.dtype.itemsize * (2 * q.size + k.size + vt.size)),
+        interpret=interpret,
+    )(q, k, vt)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "head_dim", "block_q", "scale", "interpret"))
+def _tiled_keys(q, k, v, *, heads: int, head_dim: int, block_q: int,
+                scale: float, interpret: bool):
+    """:func:`_tiled` for a key count off the tiling: ``(N, T, W) x (N, S,
+    W) -> (N, T, W)`` through :func:`_keys_kernel`, k and v padded to
+    :func:`padded` rows in one block. Under a name of its own: the call's
+    work is ``T x S``, not what a ``_tiled`` result's shape says."""
+    n, keys, w = k.shape
+    rows = (0, padded(keys) - keys)
+    ones = _ones_row(head_dim)
+    vt = jnp.concatenate(
+        [v.reshape(n, keys, w // head_dim, head_dim).transpose(0, 2, 3, 1),
+         jnp.zeros((n, w // head_dim, ones - head_dim, keys), v.dtype),
+         jnp.ones((n, w // head_dim, 8, keys), v.dtype)], axis=2)
+    return _keys_call(q, jnp.pad(k, ((0, 0), rows, (0, 0))),
+                      jnp.pad(vt, ((0, 0), (0, 0), (0, 0), rows)),
+                      keys=keys, heads=heads, head_dim=head_dim,
+                      block_q=block_q, scale=scale, interpret=interpret)
+
+
 @functools.partial(jax.jit, static_argnames=(
     "heads", "head_dim", "block_q", "block_k", "scale", "interpret"))
 def _tiled(q, k, v, *, heads: int, head_dim: int, block_q: int,
@@ -167,16 +274,30 @@ def _block(n: int, cap: int) -> int | None:
                 None)
 
 
+def padded(s: int) -> int:
+    """The K/V rows the kernel is handed for ``s`` keys: ``s`` itself on
+    the sublane tiling, else the next multiple of 128 (77 -> 128, 231 ->
+    256), the rows past ``s`` masked (:func:`_tiled_keys`)."""
+    return s if s % 8 == 0 else -(-s // 128) * 128
+
+
 def blocks(t: int, s: int) -> tuple[int, int] | None:
     """(block_q, block_k) from the shape, or None when ``(t, s)`` does not
-    tile: a sequence under 8 tokens or off the sublane tiling (77), or a
-    long one with no divisor that is a multiple of 128."""
-    if t % 8 or s % 8:
+    tile: queries under 8 tokens or off the sublane tiling, a long sequence
+    with no divisor that is a multiple of 128, or keys off the tiling that
+    do not fit one block padded. Those take a q tile of up to 2048, which
+    read 9 % faster than 1024 in their kernel (PERF.md section 6, PR 60)."""
+    if t % 8:
         return None
-    block_k = _block(s, _MAX_BLOCK_K)
-    if block_k is None:
-        return None
-    block_q = _block(t, max(128, min(1024, _SCORE_TILE // block_k)))
+    if s % 8:
+        block_k, widest = padded(s), 2048
+        if block_k > _MAX_BLOCK_K:
+            return None
+    else:
+        block_k, widest = _block(s, _MAX_BLOCK_K), 1024
+        if block_k is None:
+            return None
+    block_q = _block(t, max(128, min(widest, _SCORE_TILE // block_k)))
     return None if block_q is None else (block_q, block_k)
 
 
@@ -212,8 +333,9 @@ def flash_attention(
     """Drop-in for ``jax.nn.dot_product_attention`` (no mask/bias path).
 
     Tile sizes come from the shape (:func:`blocks`); ``block_q`` and
-    ``block_k`` override them for tests. A sequence that does not tile
-    takes the XLA path."""
+    ``block_k`` override them for tests. A key count off the sublane tiling
+    is padded and masked in one K/V block (:func:`_tiled_keys`); a sequence
+    that does not tile takes the XLA path."""
     b, t, h, d = q.shape
     s = k.shape[1]
     if scale is None:
@@ -222,15 +344,20 @@ def flash_attention(
         chosen = blocks(t, s)
     else:
         block_q = min(block_q or t, t)
-        block_k = min(block_k or s, s)
-        chosen = None if t % block_q or s % block_k else (block_q, block_k)
+        block_k = padded(s) if s % 8 else min(block_k or s, s)
+        chosen = (None if t % block_q or padded(s) % block_k
+                  else (block_q, block_k))
     if chosen is None:
         return jax.nn.dot_product_attention(q, k, v, scale=scale)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    call = functools.partial(_tiled, head_dim=d, block_q=chosen[0],
-                             block_k=chosen[1], scale=float(scale),
-                             interpret=interpret)
+    if s % 8:
+        call = functools.partial(_tiled_keys, head_dim=d, block_q=chosen[0],
+                                 scale=float(scale), interpret=interpret)
+    else:
+        call = functools.partial(_tiled, head_dim=d, block_q=chosen[0],
+                                 block_k=chosen[1], scale=float(scale),
+                                 interpret=interpret)
 
     heads = heads_per_block(h, d, *chosen, q.dtype.itemsize)
     ATTENTION.record_layout("lanes" if heads else "heads_major")

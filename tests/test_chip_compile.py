@@ -7,7 +7,8 @@ it cannot see what Mosaic refuses: a slice off the tiling, too much VMEM.
 The TPU compiler is installed here and compiles for a chip that is
 described and not attached, so the kernels of the main path are lowered
 with ``interpret=False`` at the real self-attention shapes of SD1.5 512²
-and SDXL 1024² for one chip of a ``v5e:2x2`` host. Nothing runs: a pass
+and SDXL 1024² (and the cross-attention shapes over the crossover) for one
+chip of a ``v5e:2x2`` host. Nothing runs: a pass
 says the chip's compiler accepts the kernel, not that its result is right
 (chip_smoke.py compares results on the chip).
 """
@@ -37,7 +38,7 @@ from stable_diffusion_webui_distributed_tpu.ops.upsample import UpsampleConv
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from chip_smoke import KERNEL_CASES  # noqa: E402  (repo root on path)
+from chip_smoke import CROSS_CASES, KERNEL_CASES  # noqa: E402  (repo root on path)
 
 #: (batch*heads, tokens, head_dim) of every UNet self-attention at CFG
 #: batch 2 — the cases chip_smoke.py runs on the chip: (16, 4096, 40),
@@ -89,6 +90,58 @@ def test_flash_kernel_compiles_for_v5e(one_chip, b, h, t, d):
         lambda q, k, v: flash_attention(q, k, v, interpret=False),
         qkv, qkv, qkv)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("b,h,t,d,s", CROSS_CASES)
+def test_flash_kernel_compiles_over_a_context_off_the_tiling(one_chip, b, h,
+                                                             t, d, s):
+    """Cross-attention over 231 and 77 keys (SD1.5's expanded context at
+    CFG batch 2 and 8, SDXL's at 2 and 4): the keys padded and masked, under
+    the jitted entry of its own name."""
+    q = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    text = _compiled_text(
+        lambda q, k, v: flash_attention(q, k, v, interpret=False), q, kv, kv)
+    assert "tpu_custom_call" in text
+    assert "_tiled_keys" in text and not re.search(r"%_tiled(\.\d+)? =", text)
+
+
+#: a float32 array of batch x heads x 4096 queries: the score matrix
+_SCORES = re.compile(r"f32\[8,8,4096,\d+\]")
+#: q or the result copied through HBM laid out by head
+_Q_BY_HEAD = re.compile(
+    r"= \w+\[8,(?:4096,8|8,4096),40\]\S* (?:copy|transpose)\(")
+
+
+def test_sd15_cross_site_holds_no_score_matrix(one_chip):
+    """A 64x64 ``attn2`` site of SD1.5 in its context (the q and kv
+    projections, the attention, ``out_proj`` and the residual) at CFG batch
+    8 over 231 keys: through the kernel no ``f32[8,8,4096,231]`` array is
+    written, through XLA's attention it is (243 MB, and the bf16
+    probabilities behind it)."""
+    b, h, t, d, s, c, ctx = 8, 8, 4096, 40, 231, 320, 768
+
+    def on_chip(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def site(attention):
+        def fn(x, context, w_q, w_kv, w_out):
+            q = (x @ w_q).reshape(b, t, h, d)
+            k, v = (a.reshape(b, s, h, d)
+                    for a in jnp.split(context @ w_kv, 2, axis=-1))
+            return attention(q, k, v).reshape(b, t, c) @ w_out + x
+        return fn
+
+    args = (on_chip(b, t, c), on_chip(b, s, ctx), on_chip(c, c),
+            on_chip(ctx, 2 * c), on_chip(c, c))
+    text = _compiled_text(
+        site(lambda q, k, v: flash_attention(q, k, v, interpret=False)),
+        *args)
+    assert "tpu_custom_call" in text
+    assert not _SCORES.search(text)
+    assert not _Q_BY_HEAD.search(text)
+    assert _SCORES.search(_compiled_text(
+        site(jax.nn.dot_product_attention), *args))
 
 
 #: a copy or a transpose that writes a four-dimensional array: q, k, v or

@@ -11,10 +11,13 @@ import jax.numpy as jnp
 
 from stable_diffusion_webui_distributed_tpu.ops import attention as attention_ops
 from stable_diffusion_webui_distributed_tpu.ops.flash_attention import (
+    _keys_call,
     _tiled,
+    _tiled_keys,
     blocks,
     flash_attention,
     heads_per_block,
+    padded,
 )
 from stable_diffusion_webui_distributed_tpu.ops.ring_attention import (
     ring_attention,
@@ -46,10 +49,11 @@ class TestFlashAttention:
                                    np.asarray(reference(q, k, v)),
                                    rtol=2e-5, atol=2e-5)
 
-    def test_non_tiling_falls_back(self):
-        # 77-token cross-attention context: must still be correct via the
-        # XLA fallback path
-        q, k, v = qkv(1, 64, 4, 32, s=77)
+    @pytest.mark.parametrize("t,s", [(64, 77), (60, 77), (60, 64)])
+    def test_non_tiling_is_still_correct(self, t, s):
+        # a 77-token cross-attention context is padded and masked in the
+        # kernel; queries off the tiling take the XLA fallback path
+        q, k, v = qkv(1, t, 4, 32, s=s)
         out = flash_attention(q, k, v, interpret=True)
         np.testing.assert_allclose(np.asarray(out),
                                    np.asarray(reference(q, k, v)),
@@ -143,16 +147,32 @@ class TestFlashAttentionStreaming:
 
 def heads_major(q, k, v, block_q, block_k):
     """The kernel handed ``(B*H, T, D)``, one head a block: the layout
-    every head size off 128's divisors took before PR 57."""
+    every head size off 128's divisors took before PR 57. Keys off the
+    tiling go through the entry that pads and masks them."""
     b, t, h, d = q.shape
 
     def to_bhtd(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
 
-    out = _tiled(to_bhtd(q), to_bhtd(k), to_bhtd(v), heads=1, head_dim=d,
-                 block_q=block_q, block_k=block_k, scale=d ** -0.5,
-                 interpret=True)
+    static = dict(heads=1, head_dim=d, block_q=block_q, scale=d ** -0.5,
+                  interpret=True)
+    if padded(k.shape[1]) == k.shape[1]:
+        out = _tiled(to_bhtd(q), to_bhtd(k), to_bhtd(v), block_k=block_k,
+                     **static)
+    else:
+        out = _tiled_keys(to_bhtd(q), to_bhtd(k), to_bhtd(v), **static)
     return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+
+
+def traced_kernel(q, k, v):
+    """(name of the jitted entry, its ``pallas_call`` equation) of one
+    ``flash_attention`` site."""
+    jaxpr = jax.make_jaxpr(
+        lambda *a: flash_attention(*a, interpret=True))(q, k, v)
+    (inner,) = [e for e in jaxpr.eqns if e.primitive.name in ("pjit", "jit")]
+    (call,) = [e for e in inner.params["jaxpr"].eqns
+               if e.primitive.name == "pallas_call"]
+    return inner.params["name"], call
 
 
 class TestTiledKernel:
@@ -204,6 +224,82 @@ class TestTiledKernel:
             np.asarray(got, np.float32),
             np.asarray(heads_major(q, k, v, block_q, block_k), np.float32))
 
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                           (jnp.bfloat16, 3e-2)])
+    @pytest.mark.parametrize("h,d", [(8, 40), (2, 64), (8, 80)])
+    @pytest.mark.parametrize("s", [77, 231, 154])
+    def test_keys_off_the_tiling_are_padded_and_masked(self, s, h, d, dtype,
+                                                       tol):
+        """Cross-attention's context of one, two and three 77-token chunks:
+        k and v grow zero rows to 128 or 256 and the kernel masks them, the
+        heads side by side in the lanes."""
+        q, k, v = qkv(2, 128, h, d, s=s)
+        assert heads_per_block(h, d, *blocks(128, s), 4) == (8 if h == 8
+                                                             else 2)
+        got = flash_attention(*(x.astype(dtype) for x in (q, k, v)),
+                              interpret=True)
+        assert got.dtype == dtype and got.shape == q.shape
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(reference(q, k, v)),
+                                   rtol=tol, atol=tol)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("s,d", [(77, 40), (231, 64), (154, 80)])
+    def test_masked_keys_by_either_layout(self, s, d, dtype):
+        """``(B*H, T, D)`` operands, one head a block, give the lanes
+        layout's result over padded keys too."""
+        q, k, v = (x.astype(dtype) for x in qkv(2, 128, 8, d, s=s))
+        block_q, block_k = blocks(128, s)
+        got = flash_attention(q, k, v, interpret=True)
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32),
+            np.asarray(heads_major(q, k, v, block_q, block_k), np.float32))
+
+    @pytest.mark.parametrize("d", [40, 64])
+    def test_the_mask_and_not_the_zeros_does_the_work(self, d):
+        """K rows and V^T columns past the key count hold garbage (finite,
+        large; the row of ones too): the result is the reference's over the
+        keys alone; with every row counted as a key it is not."""
+        q, k, v = qkv(2, 64, 2, d, s=231)
+
+        def garbage(*shape):
+            return 30.0 * jnp.asarray(RNG.standard_normal(shape, np.float32))
+
+        vt = jnp.concatenate([v.transpose(0, 2, 3, 1),
+                              jnp.ones((2, 2, 8, 231))], axis=2)
+        operands = (q.reshape(2, 64, 2 * d),
+                    jnp.concatenate([k.reshape(2, 231, 2 * d),
+                                     garbage(2, 25, 2 * d)], axis=1),
+                    jnp.concatenate([vt, garbage(2, 2, d + 8, 25)], axis=3))
+        static = dict(heads=2, head_dim=d, block_q=64, scale=d ** -0.5,
+                      interpret=True)
+        want = np.asarray(reference(q, k, v)).reshape(2, 64, 2 * d)
+        np.testing.assert_allclose(
+            np.asarray(_keys_call(*operands, keys=231, **static)), want,
+            rtol=2e-5, atol=2e-5)
+        unmasked = np.asarray(_keys_call(*operands, keys=256, **static))
+        assert np.isfinite(unmasked).all()
+        assert np.abs(unmasked - want).max() > 0.5
+
+    def test_a_tiling_key_count_traces_no_mask(self):
+        """A self-attention site's kernel is what it was: no column index
+        and no comparison in the kernel's jaxpr; 231 keys trace both."""
+        def kernel_ops(s):
+            name, call = traced_kernel(*qkv(1, 128, 2, 64, s=s))
+            return name, {e.primitive.name
+                          for e in call.params["jaxpr"].eqns}
+
+        name, ops = kernel_ops(128)
+        assert name == "_tiled" and not ops & {"iota", "ge"}
+        name, ops = kernel_ops(231)
+        assert name == "_tiled_keys" and {"iota", "ge"} <= ops
+
+    @pytest.mark.parametrize("s,want", [(77, 128), (154, 256), (231, 256),
+                                        (616, 616), (64, 64), (4096, 4096),
+                                        (129, 256), (4097, 4224)])
+    def test_padded_key_rows(self, s, want):
+        assert padded(s) == want
+
     @pytest.mark.parametrize("h,d,block_q,block_k,want", [
         (10, 64, 256, 4096, 2),      # SDXL: two neighbours fill 128 lanes
         (20, 64, 1024, 1024, 2),
@@ -248,7 +344,13 @@ class TestTiledKernel:
         (64, 64, (64, 64)),
         (65536, 65536, (256, 4096)),   # hires: 16 k steps of 4096
         (9216, 9216, (256, 3072)),     # 96x96 latent: divisors of 128
-        (64, 77, None),                # cross-attention's context
+        (64, 77, (64, 128)),           # cross-attention's context, padded
+        (4096, 77, (2048, 128)),       # SDXL 64x64 over one chunk
+        (4096, 231, (2048, 256)),      # SD1.5 64x64 over three chunks
+        (1024, 231, (1024, 256)),
+        (4096, 4090, (256, 4096)),     # the longest context of one block
+        (4096, 4107, None),            # padded, more than one block
+        (60, 77, None),                # queries off the sublane tiling
         (4104, 4104, None),            # over a block and no divisor of 128
     ])
     def test_blocks_come_from_the_shape(self, t, s, want):
@@ -283,47 +385,85 @@ class TestTiledKernel:
     def test_cost_estimate_counts_both_matmuls(self):
         """XLA's cost analysis prices a custom call at nothing: the kernel
         says what it does (FlopsAccountant, obs/perf.py read it)."""
-        q, k, v = (x.astype(jnp.bfloat16) for x in qkv(2, 128, 2, 64))
-        jaxpr = jax.make_jaxpr(
-            lambda q, k, v: flash_attention(q, k, v, interpret=True))(q, k, v)
-        (inner,) = [e for e in jaxpr.eqns if e.primitive.name in ("pjit",
-                                                                  "jit")]
-        (call,) = [e for e in inner.params["jaxpr"].eqns
-                   if e.primitive.name == "pallas_call"]
+        _, call = traced_kernel(*(x.astype(jnp.bfloat16)
+                                  for x in qkv(2, 128, 2, 64)))
         cost = call.params["cost_estimate"]
         assert cost.flops == 4 * 2 * 2 * 128 * 128 * 64
         assert cost.transcendentals == 2 * 2 * 128 * 128
         assert cost.bytes_accessed == 2 * 4 * (2 * 128 * 2 * 64)
 
+    def test_cost_estimate_counts_the_keys_and_not_the_padding(self):
+        _, call = traced_kernel(*(x.astype(jnp.bfloat16)
+                                  for x in qkv(2, 128, 2, 64, s=77)))
+        cost = call.params["cost_estimate"]
+        assert cost.flops == 4 * 2 * 2 * 128 * 77 * 64
+        assert cost.transcendentals == 2 * 2 * 128 * 77
+        # q read and the result written; k and v^T (with its ones) padded
+        assert cost.bytes_accessed == 2 * 2 * 2 * 128 * (2 * 64 + 64 + 72)
+
 
 class TestChooser:
-    """ops/attention.py: the tiled kernel on a TPU, for bf16 self-attention
-    at or over the crossover; XLA everywhere else. No chip needed."""
+    """ops/attention.py: the tiled kernel on a TPU, for bf16 sites of 1024
+    queries and more, over 1024 keys and more or over a short context whose
+    scores XLA cannot keep on chip; XLA everywhere else. No chip needed."""
 
     @pytest.mark.parametrize("t", [1024, 4096, 16384])
     def test_tpu_self_attention_over_the_crossover_is_tiled(self, t):
+        assert attention_ops.choose("tpu", t, t, jnp.bfloat16) == "tiled"
         assert attention_ops.choose("tpu", t, t, jnp.bfloat16,
-                                    self_attention=True) == "tiled"
+                                    batch_heads=16) == "tiled"
 
-    @pytest.mark.parametrize("platform,t,s,dtype,self_attention", [
-        ("tpu", 4096, 77, jnp.bfloat16, False),    # cross-attention
-        ("tpu", 256, 256, jnp.bfloat16, True),     # under the crossover
-        ("tpu", 64, 64, jnp.bfloat16, True),
-        ("tpu", 4104, 4104, jnp.bfloat16, True),   # does not tile
-        ("tpu", 1023, 1023, jnp.bfloat16, True),   # odd
-        ("tpu", 4096, 4096, jnp.float32, True),    # a dtype never timed
-        ("cpu", 4096, 4096, jnp.bfloat16, True),
-        ("gpu", 4096, 4096, jnp.bfloat16, True),
+    @pytest.mark.parametrize("platform,t,s,dtype,batch_heads", [
+        ("tpu", 4096, 231, jnp.bfloat16, 16),      # SD1.5 expanded, one image
+        ("tpu", 1024, 231, jnp.bfloat16, 16),
+        ("tpu", 1024, 231, jnp.bfloat16, 64),      # four images: 91 MB
+        ("tpu", 4096, 77, jnp.bfloat16, 20),       # SDXL, one image
+        ("tpu", 1024, 77, jnp.bfloat16, 40),
+        ("tpu", 4096, 77, jnp.bfloat16, 40),       # sdxl_pair
+        ("tpu", 1024, 77, jnp.bfloat16, 80),
+        ("tpu", 4096, 77, jnp.bfloat16, 16),       # SD1.5, one chunk
+        ("tpu", 256, 231, jnp.bfloat16, 64),       # under the crossover
+        ("tpu", 256, 256, jnp.bfloat16, 16),
+        ("tpu", 64, 64, jnp.bfloat16, 16),
+        ("tpu", 4104, 4104, jnp.bfloat16, 16),     # does not tile
+        ("tpu", 1023, 1023, jnp.bfloat16, 16),     # odd
+        ("tpu", 4096, 4096, jnp.float32, 16),      # a dtype never timed
+        ("tpu", 4096, 231, jnp.float32, 64),
+        ("cpu", 4096, 4096, jnp.bfloat16, 16),
+        ("cpu", 4096, 231, jnp.bfloat16, 64),
+        ("gpu", 4096, 4096, jnp.bfloat16, 16),
     ])
     def test_everything_else_is_xla(self, platform, t, s, dtype,
-                                    self_attention):
+                                    batch_heads):
         assert attention_ops.choose(platform, t, s, dtype,
-                                    self_attention=self_attention) == "xla"
+                                    batch_heads=batch_heads) == "xla"
+
+    @pytest.mark.parametrize("t,s,batch_heads", [
+        (4096, 231, 64),       # SD1.5 expanded, four images: 363 MB of scores
+        (4096, 231, 32),       # two images: 182 MB
+        (4096, 154, 64),       # a context of two chunks
+        (4096, 616, 16),       # eight chunks, one image
+    ])
+    def test_a_short_context_whose_scores_leave_the_chip_is_tiled(
+            self, t, s, batch_heads):
+        assert attention_ops.choose("tpu", t, s, jnp.bfloat16,
+                                    batch_heads=batch_heads) == "tiled"
+        for extra in ({"masked": True}, {"kv_groups": 2}):
+            assert attention_ops.choose("tpu", t, s, jnp.bfloat16,
+                                        batch_heads=batch_heads,
+                                        **extra) == "xla"
+
+    def test_the_line_is_what_xla_holds_a_score_against_the_vmem(self):
+        at = attention_ops.ON_CHIP_BYTES // (4096 * 231 * 6)
+        assert 16 < at < 32       # between the solo cells and two images
+        assert attention_ops.choose("tpu", 4096, 231, jnp.bfloat16,
+                                    batch_heads=at) == "xla"
+        assert attention_ops.choose("tpu", 4096, 231, jnp.bfloat16,
+                                    batch_heads=at + 1) == "tiled"
 
     def test_auto_on_this_cpu_is_xla_bit_for_bit(self):
         q, k, v = (x.astype(jnp.bfloat16) for x in qkv(1, 1024, 2, 64))
-        out, path = attention_ops.attend(q, k, v, scale=0.125,
-                                         self_attention=True)
+        out, path = attention_ops.attend(q, k, v, scale=0.125)
         assert path == "xla"
         np.testing.assert_array_equal(
             np.asarray(out, np.float32),
@@ -332,15 +472,15 @@ class TestChooser:
 
     @pytest.mark.parametrize("impl,self_attention,s,want", [
         ("flash", True, 128, "tiled"),
-        ("flash", False, 77, "xla"),     # cross-attention is never tiled
+        ("flash", False, 77, "tiled"),   # cross-attention: padded, masked
+        ("flash", False, 231, "tiled"),
         ("flash", True, 76, "xla"),      # does not tile
         ("xla", True, 128, "xla"),
         ("ring", True, 128, "xla"),      # a ring site that fell through
     ])
     def test_explicit_impl_forces_a_side(self, impl, self_attention, s, want):
         q, k, v = qkv(1, s if self_attention else 64, 2, 64, s=s)
-        out, path = attention_ops.attend(q, k, v, scale=0.125, impl=impl,
-                                         self_attention=self_attention)
+        out, path = attention_ops.attend(q, k, v, scale=0.125, impl=impl)
         assert path == want
         np.testing.assert_allclose(np.asarray(out),
                                    np.asarray(reference(q, k, v)),
@@ -385,12 +525,15 @@ class TestAttentionSites:
                 == got["by_shape"]["T64 S77 D16"]
                 == {"xla": got["xla"] // 2})
 
-    def test_forced_kernel_takes_the_self_attention_sites_only(self):
+    def test_forced_kernel_takes_every_site(self):
+        """Self-attention and, its 77 keys padded and masked,
+        cross-attention."""
         auto = self._trace("auto")
         got = self._trace("flash")
-        assert got["tiled"] == got["xla"] == auto["xla"] // 2
-        assert got["by_shape"]["T64 S64 D16"] == {"tiled": got["tiled"]}
-        assert got["by_shape"]["T64 S77 D16"] == {"xla": got["xla"]}
+        assert got["tiled"] == auto["xla"] and got["xla"] == 0
+        assert (got["by_shape"]["T64 S64 D16"]
+                == got["by_shape"]["T64 S77 D16"]
+                == {"tiled": got["tiled"] // 2})
 
     @pytest.mark.parametrize("channels,heads,d", [(160, 4, 40), (128, 2, 64)])
     def test_tiled_sites_by_the_layout_they_were_handed(self, channels,
@@ -402,8 +545,10 @@ class TestAttentionSites:
         auto = self._trace("auto", **changed)
         assert auto["tiled_layout"] == {"lanes": 0, "heads_major": 0}
         got = self._trace("flash", **changed)
-        assert got["tiled"] == got["xla"] == auto["xla"] // 2 > 0
-        assert got["by_shape"][f"T64 S64 D{d}"] == {"tiled": got["tiled"]}
+        assert got["tiled"] == auto["xla"] > 0 and got["xla"] == 0
+        assert (got["by_shape"][f"T64 S64 D{d}"]
+                == got["by_shape"][f"T64 S77 D{d}"]
+                == {"tiled": got["tiled"] // 2})
         assert got["tiled_layout"] == {"lanes": got["tiled"],
                                        "heads_major": 0}
 

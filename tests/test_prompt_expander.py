@@ -236,22 +236,22 @@ class TestMasks:
         np.testing.assert_allclose(out, 2.0)        # its own row only
 
     @pytest.mark.parametrize("site", [
-        ("tpu", 4096, 4096, jnp.bfloat16, True),
-        ("tpu", 1024, 1024, jnp.bfloat16, True),
-        ("tpu", 256, 256, jnp.bfloat16, True),
-        ("tpu", 4096, 77, jnp.bfloat16, False),
-        ("tpu", 4096, 4096, jnp.float32, True),
-        ("cpu", 4096, 4096, jnp.bfloat16, True),
+        ("tpu", 4096, 4096, jnp.bfloat16, 20),
+        ("tpu", 1024, 1024, jnp.bfloat16, 40),
+        ("tpu", 256, 256, jnp.bfloat16, 16),
+        ("tpu", 4096, 77, jnp.bfloat16, 20),
+        ("tpu", 4096, 4096, jnp.float32, 20),
+        ("cpu", 4096, 4096, jnp.bfloat16, 20),
     ])
     def test_the_unet_sites_choose_as_before(self, site):
-        platform, t, s, dtype, self_attention = site
-        want = (attention.TILED if platform == "tpu" and self_attention
+        platform, t, s, dtype, batch_heads = site
+        want = (attention.TILED if platform == "tpu" and s == t
                 and dtype == jnp.bfloat16 and t >= 1024 else attention.XLA)
         assert attention.choose(platform, t, s, dtype,
-                                self_attention=self_attention) == want
+                                batch_heads=batch_heads) == want
         for extra in ({"masked": True}, {"kv_groups": 6}):
             assert attention.choose(platform, t, s, dtype,
-                                    self_attention=self_attention,
+                                    batch_heads=batch_heads,
                                     **extra) == attention.XLA
 
 
