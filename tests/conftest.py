@@ -37,6 +37,16 @@ _SLOW_MODULES = {
 }
 
 
+def pytest_generate_tests(metafunc):
+    """A test that a class inherits from tests/expander_contract.py takes
+    its cases from the class that binds it: ``PARAMETERS = {test name:
+    [(argnames, values), ...]}``, applied as ``parametrize`` marks from the
+    function outwards would be, so that each model keeps its own ids."""
+    table = getattr(metafunc.cls, "PARAMETERS", {})
+    for argnames, values in table.get(metafunc.definition.name, ()):
+        metafunc.parametrize(argnames, values)
+
+
 def pytest_sessionfinish(session, exitstatus):
     """SDTPU_LOCKSAN=1: diff the observed lock-order graph against the
     static LK005 graph; an edge the static model has no path for fails

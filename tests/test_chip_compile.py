@@ -403,35 +403,36 @@ def test_a_forked_decode_chunk_compiles_for_v5e(one_chip, preset):
     assert compiled.memory_analysis().alias_size_in_bytes >= held
 
 
-@pytest.mark.parametrize(
-    "which,expander,argument_gb,kernels,temp_mb,alias_mb", [
-    ("decode", "sd15_laguna_expander", 11.1, 4, 64, 14),
-    ("prefill", "sd15_laguna_expander", 11.1, 0, 64, 14),
-    ("decode", "sd15_qwen3next_expander", 10.8, 12, 64, 14),
+#: (which executable, the share, its cache's slots, then the bounds: the
+#: arguments' GB, the expert kernels, MB of temporaries, MB aliased)
+EXPANDER_EXECUTABLES = [
+    ("decode", "sd15_laguna_expander", 1024, 11.1, 4, 64, 14),
+    ("prefill", "sd15_laguna_expander", 1024, 11.1, 0, 64, 14),
+    ("decode", "sd15_qwen3next_expander", 1024, 10.8, 12, 64, 14),
     # eighteen expert kernels and forty mixers of two; forty transposed
     # copies of phi (0.9 MB each in bf16 tiles) made before the scan and
     # eighteen float32 routers hoisted out of it: 124 MB
-    ("decode", "sd15_xing4_expander", 8.75, 98, 128, 14),
-    ("prefill", "sd15_xing4_expander", 8.75, 0, 128, 14),
+    ("decode", "sd15_xing4_expander", 1024, 8.75, 98, 128, 14),
+    ("prefill", "sd15_xing4_expander", 1024, 8.75, 0, 128, 14),
     # eight expert kernels; the donated cache is two layers' keys and
     # values and eight layers' kept rows, 4.3 MB
-    ("decode", "sd15_lfm2_expander", 10.8, 8, 64, 4),
-    ("prefill", "sd15_lfm2_expander", 10.8, 0, 64, 4),
+    ("decode", "sd15_lfm2_expander", 1024, 10.8, 8, 64, 4),
+    ("prefill", "sd15_lfm2_expander", 1024, 10.8, 0, 64, 4),
     # at a 2 560-slot cache (a 2 048-token instruction): eight expert
     # kernels at one sequence and at four sequences a step (the whole
     # block of rows an expert), which donate a forked cache: the one
     # sequence's 23 MB and 256 slots a layer for each of four, 40 MB;
     # the instruction's one chunk, twice the window, needs 0.9 GB of scores
-    ("decode", "sd15_mellum2_expander", 7.6, 8, 64, 23),
-    ("decode4", "sd15_mellum2_expander", 7.6, 8, 64, 39),
-    ("prefill2048", "sd15_mellum2_expander", 7.6, 0, 1000, 23),
+    ("decode", "sd15_mellum2_expander", 2560, 7.6, 8, 64, 23),
+    ("decode4", "sd15_mellum2_expander", 2560, 7.6, 8, 64, 39),
+    ("prefill2048", "sd15_mellum2_expander", 2560, 7.6, 0, 1000, 23),
     # seven expert kernels (the sixth published shape, 2048 x 768, 24
     # slots a call of four rows) behind eight forked latent attentions:
     # four sequences donate the one sequence's 23.6 MB of latents (shared,
     # handed through) and 256 own slots a layer each, 33 MB in all; the
     # prompt's 64-token chunk in the expanded form over 2 560 latents
-    ("decode4", "sd15_kanana2_expander", 10.15, 7, 160, 32),
-    ("prefill", "sd15_kanana2_expander", 10.15, 0, 400, 23),
+    ("decode4", "sd15_kanana2_expander", 2560, 10.15, 7, 160, 32),
+    ("prefill", "sd15_kanana2_expander", 2560, 10.15, 0, 400, 23),
     # four clamped expert kernels (the seventh published shape, 7168 x
     # 2048, tile 256) behind one forked latent attention and four delta
     # mixers that step a state a sequence: four sequences donate the one
@@ -440,9 +441,9 @@ def test_a_forked_decode_chunk_compiles_for_v5e(one_chip, preset):
     # 64-token chunk chunk-wise over four states and expanded over 2 560
     # latents; the instruction's one chunk of 2 048
     # (1.66 GB of temporaries: 64 heads' scores over 2 560 latents)
-    ("decode4", "sd15_gigachat35_expander", 9.46, 4, 64, 70),
-    ("prefill", "sd15_gigachat35_expander", 9.46, 0, 400, 20),
-    ("prefill2048", "sd15_gigachat35_expander", 9.46, 0, 2000, 20),
+    ("decode4", "sd15_gigachat35_expander", 2560, 9.46, 4, 64, 70),
+    ("prefill", "sd15_gigachat35_expander", 2560, 9.46, 0, 400, 20),
+    ("prefill2048", "sd15_gigachat35_expander", 2560, 9.46, 0, 2000, 20),
     # no kernel at all: twelve delta mixers that step a (30, 96, 192) state
     # a sequence at strength up to 2 beside four unrotated attentions of 30
     # ungrouped heads; four sequences donate the one sequence's 157 MB of
@@ -453,13 +454,21 @@ def test_a_forked_decode_chunk_compiles_for_v5e(one_chip, preset):
     # (the arguments are the 8.20 GB of weights and the cache: a state's
     # 192-wide minor axis lies in tiles of 256, so forty-eight states take
     # 142 MB where their shapes say 106)
-    ("decode4", "sd15_olmo_hybrid_expander", 8.50, 0, 128, 330),
-    ("prefill", "sd15_olmo_hybrid_expander", 8.30, 0, 400, 180),
-    ("prefill2048", "sd15_olmo_hybrid_expander", 8.30, 0, 2000, 180),
-])
+    ("decode4", "sd15_olmo_hybrid_expander", 2560, 8.50, 0, 128, 330),
+    ("prefill", "sd15_olmo_hybrid_expander", 2560, 8.30, 0, 400, 180),
+    ("prefill2048", "sd15_olmo_hybrid_expander", 2560, 8.30, 0, 2000, 180),
+]
+
+
+@pytest.mark.parametrize(
+    "which,expander,capacity,argument_gb,kernels,temp_mb,alias_mb",
+    EXPANDER_EXECUTABLES,
+    # the ids these cases had before the capacity was a column
+    ids=["-".join(map(str, row[:2] + row[3:]))
+         for row in EXPANDER_EXECUTABLES])
 def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
-        one_chip, monkeypatch, which, expander, argument_gb, kernels,
-        temp_mb, alias_mb):
+        one_chip, monkeypatch, which, expander, capacity, argument_gb,
+        kernels, temp_mb, alias_mb):
     """A share's decode chunk (and two shares' 64-token prefill) at the
     published widths (5.57 B, 5.42 B and 4.39 B parameters as bfloat16
     shapes, a 1 024-slot cache; the last with twenty layers of latent
@@ -476,10 +485,6 @@ def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = getattr(configs, expander)().expander
     module = lm.DecoderLM(cfg, dtype=jnp.bfloat16)
-    capacity = 2560 if expander in ("sd15_mellum2_expander",
-                                    "sd15_kanana2_expander",
-                                    "sd15_gigachat35_expander",
-                                    "sd15_olmo_hybrid_expander") else 1024
 
     def on_chip(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

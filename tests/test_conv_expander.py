@@ -13,13 +13,9 @@ The plain reference is the benchmark's own
 (benchmarks/reference/lfm2_ref.py: float32, no cache, no chunks, the
 convolution as three shifted products of the whole sequence).
 """
-
 import dataclasses
-import importlib.util
 import json
-import os
 import urllib.request
-import zlib
 
 import jax
 import jax.numpy as jnp
@@ -29,133 +25,48 @@ import pytest
 from stable_diffusion_webui_distributed_tpu.cache import kv
 from stable_diffusion_webui_distributed_tpu.models import configs, lm
 from stable_diffusion_webui_distributed_tpu.ops import moe, moe_kernel
-from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
-from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
-    GenerationPayload,
-)
-from stable_diffusion_webui_distributed_tpu.runtime import dtypes
-from stable_diffusion_webui_distributed_tpu.runtime.interrupt import (
-    GenerationState,
-)
 from stable_diffusion_webui_distributed_tpu.serving.metrics import (
-    ATTENTION, EXPANDER, METRICS,
+    ATTENTION, EXPANDER,
 )
-from tests.test_pipeline import init_params
+from tests import expander_contract as contract
+from tests.expander_contract import close, empty, rel_rms, run
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-REF = _load(os.path.join(ROOT, "benchmarks", "reference", "lfm2_ref.py"),
-            "lfm2_ref_for_tests")
-FAMILY = configs.TINY_CONV_EXPAND
-CFG = FAMILY.expander
-
-
-def lm_params(cfg, seed=0):
-    """``DecoderLM.init``'s tree with the norms off 1 and the selection
-    bias (which starts at zero) drawn, so that reading one as another
-    would show."""
-    module = lm.DecoderLM(cfg)
-    params = module.init(
-        jax.random.key(seed), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-        jnp.int32(4), lm.empty_cache(cfg, 8, jnp.float32))["params"]
-    key = jax.random.key(seed + 100)
-    spread = {"scale": 0.2, "e_score_correction_bias": 0.1}
-
-    def off(path, x):
-        name = getattr(path[-1], "key", "")
-        if name not in spread:
-            return x
-        return x + spread[name] * jax.random.normal(
-            jax.random.fold_in(key, zlib.crc32(str(path).encode()) % 2 ** 31),
-            x.shape)
-
-    return jax.tree_util.tree_map_with_path(off, params)
-
-
-@pytest.fixture(scope="module")
-def params():
-    return lm_params(CFG)
-
-
-def rel_rms(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / np.sqrt(np.mean(want ** 2)))
-
-
-def run(params, ids, start, length, cache, cfg=CFG, **kw):
-    return lm.DecoderLM(cfg).apply(
-        {"params": params}, ids, jnp.int32(start), jnp.int32(length), cache,
-        **kw)
-
-
-def empty(capacity=64, cfg=CFG):
-    return lm.empty_cache(cfg, capacity, jnp.float32)
-
-
-def close(a, b, tol=2e-5):
-    for x, y in zip(jax.tree_util.tree_leaves(a),
-                    jax.tree_util.tree_leaves(b)):
-        np.testing.assert_allclose(x, y, rtol=tol, atol=tol)
+REF = contract.load_reference("lfm2")
+#: the norms off 1 and the selection bias (which starts at zero) drawn
+CASE = contract.Case(
+    configs.TINY_CONV_EXPAND, REF,
+    how=(("spread", (("scale", 0.2), ("e_score_correction_bias", 0.1))),),
+    word="rule")
+FAMILY, CFG = CASE.family, CASE.cfg
+params, engine = contract.fixtures(CASE)
 
 
 # -- program against reference ------------------------------------------------
 
-class TestAgainstTheReference:
-    @pytest.mark.parametrize("size", [40, 300])
-    def test_one_chunk_matches_the_full_forward(self, params, size):
-        (ids,) = REF.inputs(FAMILY, 3, size)
-        got, _, routed = jax.jit(lambda p, i: run(
-            p, i, 0, size, empty(kv.capacity_for(size))))(params, ids)
-        want, own = jax.jit(lambda p, i: REF.forward(
-            FAMILY, p, i, with_routing=True))(params, ids)
-        assert got.shape == want.shape == (size, CFG.vocab[1])
-        assert rel_rms(got, want) < 1e-5
-        assert np.array_equal(np.sort(routed[0], -1), np.sort(own, -1))
-
-    @pytest.mark.parametrize("size", [40, 300])
-    def test_prefill_then_decode_through_the_kept_rows(self, params, size):
-        """Prefix prefill, the user chunk against a copy of the snapshot,
-        then one token a step through the cache (under and over one
-        capacity step of 256), against the reference's one full forward."""
-        (ids,) = REF.inputs(FAMILY, 3, size)
-        got, chose = jax.jit(REF.program(FAMILY, dtypes.F32,
-                                         with_routing=True))(params, ids)
-        want, own = jax.jit(lambda p, i: REF.forward(
-            FAMILY, p, i, with_routing=True))(params, ids)
-        assert rel_rms(got, want) < 1e-5
-        assert np.array_equal(np.sort(chose, -1), np.sort(own, -1))
+class TestAgainstTheReference(contract.OneChunkAgainstTheReference,
+                              contract.OneSequenceAgainstTheReference):
+    """Under and over one capacity step of 256."""
+    CASE = CASE
+    DROPPED = "kept"
+    test_prefill_then_decode_through_the_kept_rows = \
+        contract.OneSequenceAgainstTheReference \
+        .prefill_then_decode_matches_the_full_forward
+    test_kept_rows_that_are_dropped_show = \
+        contract.OneSequenceAgainstTheReference.a_buffer_that_is_dropped_shows
+    PARAMETERS = {
+        "test_one_chunk_matches_the_full_forward": [("size", [40, 300])],
+        "test_prefill_then_decode_through_the_kept_rows": [
+            ("size", [40, 300])]}
 
     @pytest.mark.parametrize("control", [name for name, _ in REF.CONTROLS])
     def test_each_control_is_further_from_the_reference(self, params,
                                                         control):
         """The int8 linears, the kept rows zeroed between calls, the
         gates' and taps' products in bfloat16."""
-        kwargs = dict(REF.CONTROLS)[control]
-        (ids,) = REF.inputs(FAMILY, 3, 40)
-        want = REF.forward(FAMILY, params, ids)
-        program = jax.jit(REF.program(FAMILY, dtypes.F32))(params, ids)
-        lower = jax.jit(REF.program(FAMILY, dtypes.F32, **kwargs))(
-            params, ids)
+        (ids,), want, _ = CASE.referred(40)
+        program = CASE.program()(params, ids)
+        lower = CASE.program(**dict(REF.CONTROLS)[control])(params, ids)
         assert rel_rms(lower, want) > 1e-3 > 100 * rel_rms(program, want)
-
-    def test_kept_rows_that_are_dropped_show(self, params):
-        (ids,) = REF.inputs(FAMILY, 3, 40)
-        want = REF.forward(FAMILY, params, ids)
-        _, cache, _ = run(params, ids[:30], 0, 30, empty())
-        kept, _, _ = run(params, ids[30:31], 30, 1, cache)
-        cache["kept"] = [jnp.zeros_like(rows) for rows in cache["kept"]]
-        dropped, _, _ = run(params, ids[30:31], 30, 1, cache)
-        assert rel_rms(kept, want[30:31]) < 1e-5
-        assert rel_rms(dropped, want[30:31]) > 1e-2
 
 
 # -- the convolution ----------------------------------------------------------
@@ -202,9 +113,7 @@ class TestTheConvolution:
         whole = lm.causal_conv
         monkeypatch.setattr(lm, "causal_conv", lambda kernel, *a: (
             seen.append(kernel.shape), whole(kernel, *a))[1])
-        jax.eval_shape(lambda: lm.DecoderLM(cfg).init(
-            jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-            jnp.int32(4), lm.empty_cache(cfg, 8, jnp.float32)))
+        contract.param_shapes(cfg)
         assert seen == [(4, cfg.linear_conv_channels)] * 2
 
     def test_a_conv_mixer_is_the_written_one(self, params):
@@ -229,68 +138,57 @@ class TestTheConvolution:
 
 # -- padded chunks, snapshots, the decode scan --------------------------------
 
-class TestPaddingAndSnapshots:
+class TestPaddingAndSnapshots(contract.PaddingAndSnapshots):
+    CASE = CASE
+
     def test_a_padded_chunk_gives_what_the_exact_chunk_gives(self, params):
         """Logits and kept rows: the kept rows are the last two REAL rows
         of ``u``, never a pad row's."""
         (ids,) = REF.inputs(FAMILY, 5, 24)
-        exact, cache_a, _ = run(params, ids[:19], 0, 19, empty(32),
+        exact, cache_a, _ = run(CFG, params, ids[:19], 0, 19, empty(CFG, 32),
                                 all_logits=False)
-        padded, cache_b, _ = run(params, ids, 0, 19, empty(32),
+        padded, cache_b, _ = run(CFG, params, ids, 0, 19, empty(CFG, 32),
                                  all_logits=False)
         np.testing.assert_allclose(exact, padded, rtol=2e-5, atol=2e-5)
         close(cache_a["kept"], cache_b["kept"])
         assert float(jnp.max(jnp.abs(cache_a["kept"][0]))) > 1e-3
-        nxt = lambda c: run(params, ids[19:20], 19, 1, c,      # noqa: E731
+        nxt = lambda c: run(CFG, params, ids[19:20], 19, 1, c,  # noqa: E731
                             all_logits=False)[0]
         np.testing.assert_allclose(nxt(cache_a), nxt(cache_b), rtol=2e-5,
                                    atol=2e-5)
-        want = REF.forward(FAMILY, params, ids[:20])
+        _, want, _ = CASE.referred_on(ids[:20])
         assert rel_rms(nxt(cache_b), want[19:20]) < 1e-5
 
     def test_a_chunk_of_one_real_row_keeps_an_older_one(self, params):
         """One real row in a padded chunk: the kept rows are the last one
         from before the chunk and the chunk's one."""
         (ids,) = REF.inputs(FAMILY, 6, 11)
-        _, cache, _ = run(params, ids[:10], 0, 10, empty(32))
+        _, cache, _ = run(CFG, params, ids[:10], 0, 10, empty(CFG, 32))
         before = cache["kept"][0]
-        _, whole, _ = run(params, ids, 0, 11, empty(32))
-        _, after, _ = run(params, jnp.pad(ids[10:], (0, 7)), 10, 1, cache)
+        _, whole, _ = run(CFG, params, ids, 0, 11, empty(CFG, 32))
+        _, after, _ = run(CFG, params, jnp.pad(ids[10:], (0, 7)), 10, 1,
+                          cache)
         np.testing.assert_allclose(after["kept"][0][0], before[-1],
                                    rtol=1e-6)
         close(after["kept"], whole["kept"])
-
-    def test_snapshot_plus_prompt_equals_one_whole_prefill(self, params):
-        (ids,) = REF.inputs(FAMILY, 7, 48)
-        whole, cache_w, _ = run(params, ids, 0, 48, empty())
-        first, snapshot, _ = run(params, ids[:31], 0, 31, empty())
-        copy = jax.tree_util.tree_map(jnp.copy, snapshot)
-        rest, cache_s, _ = run(params, ids[31:], 31, 17, copy)
-        np.testing.assert_allclose(jnp.concatenate([first, rest]), whole,
-                                   rtol=5e-5, atol=5e-5)
-        close(cache_s["kept"], cache_w["kept"])
-        close([k[:48] for k in cache_s["k"]], [k[:48] for k in cache_w["k"]])
-        # the snapshot itself is as the prefix's last token left it
-        again, _, _ = run(params, ids[31:], 31, 17, snapshot)
-        np.testing.assert_array_equal(again, rest)
 
     def test_a_resumed_snapshot_decodes_as_the_uninterrupted_run(self,
                                                                  params):
         """Through the manager: the prefix's cache kept, handed out as a
         copy, the prompt prefilled against it and eight tokens decoded,
         against the same from one prefill of prefix and prompt together."""
-        module = lm.DecoderLM(CFG)
         (ids,) = REF.inputs(FAMILY, 8, 40)
         prefix = np.asarray(ids[:29]).tolist()
         manager = kv.KVCacheManager(CFG, jnp.float32)
         cache, held = manager.acquire(prefix, 64)
         assert held == 0
-        _, cache, _ = run(params, ids[:29], 0, 29, cache)
+        _, cache, _ = run(CFG, params, ids[:29], 0, 29, cache)
         manager.keep_prefix(prefix, 64, cache)
-        decode = jax.jit(lm.decode_chunk_fn(module, 8))
+        decode = contract.executables(CFG, 8).alone
 
         def finish(cache, start):
-            _, cache, _ = run(params, ids[start:], start, 40 - start, cache)
+            _, cache, _ = run(CFG, params, ids[start:], start, 40 - start,
+                              cache)
             out = decode(params, cache, ids[5], jnp.int32(40),
                          jax.random.key(3), jnp.float32(1.0))
             return out[0], np.asarray(out[3]).tolist()
@@ -298,35 +196,18 @@ class TestPaddingAndSnapshots:
         resumed, held = manager.acquire(prefix, 64)
         assert held == 29
         cache_r, made_r = finish(resumed, 29)
-        cache_u, made_u = finish(empty(), 0)
+        cache_u, made_u = finish(empty(CFG), 0)
         assert made_r == made_u and len(set(made_u)) > 2
         close(cache_r["kept"], cache_u["kept"])
-
-    def test_decoding_cut_into_chunks_equals_one_scan(self, params):
-        module = lm.DecoderLM(CFG)
-        key = jax.random.key(11)
-        first = jnp.int32(CFG.vocab[0] + 3)
-
-        def decode(steps, calls):
-            fn = jax.jit(lm.decode_chunk_fn(module, steps),
-                         donate_argnums=(1,))
-            cache = empty(128)
-            token, position, made = first, jnp.int32(0), []
-            for _ in range(calls):
-                cache, token, position, out, _, _ = fn(
-                    params, cache, token, position, key, jnp.float32(1.0))
-                made += np.asarray(out).tolist()
-            return made, cache
-
-        one, cache_one = decode(64, 1)
-        cut, cache_cut = decode(32, 2)
-        assert one == cut and len(set(one)) > 8
-        close(cache_one["kept"], cache_cut["kept"])
 
 
 # -- the cache manager --------------------------------------------------------
 
-class TestTheCacheManager:
+class TestTheCacheManager(contract.TheCacheManager):
+    CASE = CASE
+    test_a_snapshot_is_handed_out_as_a_copy = \
+        contract.TheCacheManager.a_snapshot_is_handed_out_as_a_copy
+
     def test_a_conv_layer_has_one_buffer_of_two_rows(self):
         assert lm.buffers_of(lm.CONV) == ("kept",)
         assert lm.cache_shapes(CFG, 256) == {
@@ -367,21 +248,6 @@ class TestTheCacheManager:
         assert kv.KVCacheManager(share, jnp.bfloat16).positions_in_use(
             960) == {"full": 1920, "sliding": 0, "conv": 0}
 
-    def test_a_snapshot_is_handed_out_as_a_copy(self):
-        manager = kv.KVCacheManager(CFG, jnp.float32)
-        cache, held = manager.acquire([1, 2, 3], 256)
-        assert held == 0 and manager.snapshots == 0
-        manager.keep_prefix([1, 2, 3], 256,
-                            jax.tree_util.tree_map(lambda x: x + 1, cache))
-        again, held = manager.acquire([1, 2, 3], 256)
-        assert held == 3 and manager.snapshots == 1
-        assert float(again["kept"][4][1, 5]) == 1.0
-        again["kept"][4] = again["kept"][4] + 1
-        third, _ = manager.acquire([1, 2, 3], 256)
-        assert float(third["kept"][4][1, 5]) == 1.0
-        # a shorter prefix is another prefix: kept rows cannot be cut back
-        assert manager.acquire([1, 2], 256)[1] == 0
-
 
 # -- ungated attention, no shared expert, the router's epsilon ----------------
 
@@ -404,7 +270,7 @@ class TestWhatAnLayerMayLack:
                                            ("element", None)])
     def test_the_gated_forms_are_what_they_were(self, gate, leaf):
         cfg = dataclasses.replace(CFG, attn_gate=gate)
-        attn = lm_params(cfg)["layers_2"]["attn"]
+        attn = contract.param_shapes(cfg)["layers_2"]["attn"]
         assert ("g_proj" in attn) == (leaf == "g_proj")
         width = 4 * 8 * (2 if gate == "element" else 1)
         assert attn["q_proj"]["kernel"].shape == (32, width)
@@ -430,9 +296,7 @@ class TestWhatAnLayerMayLack:
         # a latent layer reads ``attn_gate`` since PR 56; until then the
         # latent preset carried the default it never read
         assert (cfg.attn_gate == "none") is (lm.LATENT in cfg.layer_types)
-        shapes = jax.eval_shape(lambda: lm.DecoderLM(cfg).init(
-            jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-            jnp.int32(4), lm.empty_cache(cfg, 8, jnp.float32)))["params"]
+        shapes = contract.param_shapes(cfg)
         layer = shapes[f"layers_{cfg.expert_layers[0]}"]
         assert "shared_expert" in layer["mlp"]
         assert "short_conv" not in layer
@@ -482,14 +346,7 @@ class TestTheShare:
         """Shapes only: a conv mixer 16.78 M, an attention mixer 10.49 M,
         an expert 9.437 M, a dense MLP 72.35 M, table and head 134.2 M
         each; the whole model by the same count 23.98 B."""
-        count = lambda tree: sum(    # noqa: E731
-            x.size for x in jax.tree_util.tree_leaves(tree))
-
-        def shapes_of(cfg):
-            return jax.eval_shape(lambda: lm.DecoderLM(cfg).init(
-                jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-                jnp.int32(4), lm.empty_cache(cfg, 8, jnp.float32)))["params"]
-
+        count, shapes_of = contract.count, contract.param_shapes
         layers = shapes_of(configs.sd15_lfm2_expander().expander)
         assert round(count(layers["layers_0"]["short_conv"]) / 1e6, 2) \
             == 16.78
@@ -508,7 +365,7 @@ class TestTheShare:
 
 # -- the tree and the sharding rules ------------------------------------------
 
-class TestTheTreeAndItsRules:
+class TestTheTreeAndItsRules(contract.ShardingRules):
     def test_the_presets_parameter_tree(self, params):
         assert set(params["layers_0"]) == {
             "input_norm", "post_attention_norm", "short_conv", "mlp"}
@@ -522,89 +379,50 @@ class TestTheTreeAndItsRules:
         assert set(params["layers_2"]) == {
             "input_norm", "post_attention_norm", "attn", "mlp"}
 
-    def test_sharding_leaves_the_mixer_whole(self, params):
+    WHOLE = (("layers_0/short_conv/in_proj/kernel", 2),
+             ("layers_0/short_conv/out_proj/kernel", 2),
+             ("layers_0/short_conv/conv_kernel", 2),
+             ("layers_3/mlp/e_score_correction_bias", 1))
+    EXPERT_LAYER = 3
+    PLACED_WHOLE = ("layers_1/short_conv/in_proj/kernel",)
+    test_sharding_leaves_the_mixer_whole = contract.ShardingRules.sharding_rules
+
+    def check_placed(self, placed, mesh):
         from jax.sharding import PartitionSpec as P
 
         from stable_diffusion_webui_distributed_tpu.parallel.sharding import (
-            shard_params, tp_spec_for,
+            tp_spec_for,
         )
 
-        for path, ndim in (("layers_0/short_conv/in_proj/kernel", 2),
-                           ("layers_0/short_conv/out_proj/kernel", 2),
-                           ("layers_0/short_conv/conv_kernel", 2),
-                           ("layers_3/mlp/e_score_correction_bias", 1)):
-            assert tp_spec_for(path, ndim) == P(), path
         # the UNet's convolutions keep their rule
         assert tp_spec_for("down_0/res_0/conv/kernel", 4) \
             == P(None, None, None, "tp")
-        assert tp_spec_for("layers_3/mlp/experts/w_up", 3) \
-            == P("ep", None, None)
-        devices = np.array(jax.devices()[:4]).reshape(2, 2)
-        mesh = jax.sharding.Mesh(devices, ("ep", "vp"))
-        placed = shard_params(params, mesh)
-        assert placed["layers_3"]["mlp"]["experts"]["w_gate"].sharding.spec \
-            == P("ep", None, None)
-        assert placed["layers_1"]["short_conv"]["in_proj"]["kernel"] \
-            .sharding.spec == P()
-        assert placed["lm_head"]["kernel"].sharding.spec == P(None, "vp")
         # and the meshed program still runs: the partitioned experts'
         # logits are the unpartitioned program's
         ids = jax.random.randint(jax.random.key(1), (8,), *CFG.vocab)
         module = lm.DecoderLM(CFG, meshed=True)
         with mesh:
             got, _, _ = jax.jit(lambda p, i: module.apply(
-                {"params": p}, i, jnp.int32(0), jnp.int32(8), empty(32)))(
-                    placed, ids)
-        want, _, _ = run(params, ids, 0, 8, empty(32))
+                {"params": p}, i, jnp.int32(0), jnp.int32(8),
+                empty(CFG, 32)))(placed, ids)
+        want, _, _ = run(CFG, CASE.params(), ids, 0, 8, empty(CFG, 32))
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
 # -- the engine path ----------------------------------------------------------
 
-INSTRUCTION = " ".join(f"rule{i}" for i in range(30))
+class TestEnginePath(contract.SoloEnginePath):
+    CASE = CASE
+    STATUS_KEYS = frozenset({
+        "state_bytes", "cache_positions", "conv_mixers", "prefix_snapshots",
+        "padded_rows_masked", "tokens_no_held_expert", "expert_tokens"})
+    test_spans = contract.SoloEnginePath.spans_of_a_request
 
-
-def script(**args):
-    return {"prompt expansion": {"args": [dict(
-        {"instruction": INSTRUCTION, "max_new_tokens": 40,
-         "temperature": 1.0, "ignore_eos": True, "context_chunks": 1},
-        **args)]}}
-
-
-@pytest.fixture(scope="module")
-def engine():
-    params = init_params(configs.TINY)
-    params["expander"] = lm_params(CFG, seed=1)
-    return Engine(FAMILY, params, chunk_size=4, state=GenerationState())
-
-
-def payload(**kw):
-    base = dict(prompt="a cow in a valley", steps=4, width=32, height=32,
-                seed=1234, alwayson_scripts=script())
-    base.update(kw)
-    return GenerationPayload(**base)
-
-
-class TestEnginePath:
-    def test_a_request_from_the_kept_snapshot_equals_the_first(self, engine):
-        EXPANDER.clear()
-        a = engine.txt2img(payload())       # prefills the instruction
-        b = engine.txt2img(payload())       # starts from its snapshot
-        plain = engine.txt2img(payload(alwayson_scripts={}))
-        assert a.images == b.images and a.prompts == b.prompts
-        assert a.images != plain.images
-        words = a.prompts[0].split()
-        assert len(words) == 45 and len(set(words[5:])) > 8
-        stats = EXPANDER.summary()
-        assert stats["requests"] == 2
-        assert stats["tokens_prefilled"] == 31 + 5 + 5
-        assert stats["tokens_from_prefix_cache"] == 31
+    def check_stats(self, stats):
         assert stats["cache_positions"] == {"full": 76, "sliding": 0,
                                             "conv": 0}
-        assert stats["prefix_snapshots"] == 1
         # 31 -> 64 once, 5 -> 64 twice
         assert stats["padded_rows_masked"] == 33 + 2 * 59
-        assert stats["state_bytes"] == kv.state_bytes(CFG, 256, jnp.float32)
         assert stats["state_bytes"]["conv"] == 5 * 2 * 32 * 4
         assert len(stats["expert_tokens"]) == 4
         assert stats["tokens_no_held_expert"] == 0
@@ -615,38 +433,15 @@ class TestEnginePath:
         assert stats["expert_products"]["grouped"] == 4
         assert sum(stats["expert_products"].values()) == 8
 
-    def test_another_seed_gets_another_expansion(self, engine):
-        assert engine.txt2img(payload()).prompts \
-            != engine.txt2img(payload(seed=99)).prompts
+    def check_prefill_span(self, args):
+        assert args["padded"] == 59
+        assert "form" not in args and "latent" not in args  # no recurrence
 
-    def test_spans(self, engine):
-        from stable_diffusion_webui_distributed_tpu.obs import spans
-
-        spans.TRACER.clear()
-        with spans.request("rid-conv"):
-            engine.txt2img(payload())
-        events = [e for e in spans.TRACER.export_chrome()["traceEvents"]
-                  if e.get("ph") == "X"]
-        names = [e["name"] for e in events]
-        for name in ("expand", "expand.prefix_copy", "expand.prefill",
-                     "expand.decode_chunk", "expand.fence_wait", "prepare"):
-            assert name in names, name
-        by_id = {e["args"]["span_id"]: e for e in events}
-        for e in events:
-            if e["name"].startswith("expand."):
-                # the counters come down once the UNet is queued
-                assert by_id[e["args"]["parent_id"]]["name"] == (
-                    "denoise_range" if e["name"] == "expand.account"
-                    else "expand")
-        prefill = next(e for e in events if e["name"] == "expand.prefill")
-        assert prefill["args"]["tokens"] == 5
-        assert prefill["args"]["padded"] == 59
-        assert "form" not in prefill["args"]      # no recurrence here
-        assert "latent" not in prefill["args"]
-        copy = next(e for e in events if e["name"] == "expand.prefix_copy")
-        assert copy["args"]["hit"] is True
-        assert copy["args"]["bytes"] == sum(
-            kv.state_bytes(CFG, 256, jnp.float32).values())
+    def check_status(self, block):
+        assert block["cache_positions"]["conv"] == 0
+        assert set(block["conv_mixers"]) == {"step", "chunk"}
+        assert block["residual_streams"] == 1
+        assert block["sinkhorn_iters"] == 0
 
     def test_the_published_share_traces_eight_conv_mixers_and_two_sites(
             self):
@@ -654,21 +449,13 @@ class TestEnginePath:
         weights or FLOPs: eight conv mixers, and the two attention layers'
         sites at head width 64 over the cache's 1024 slots."""
         share = configs.sd15_lfm2_expander().expander
-        module = lm.DecoderLM(share, dtype=jnp.bfloat16)
-        s = jax.ShapeDtypeStruct
-        cache = {name: [s(shape, lm.buffer_dtype(name, jnp.bfloat16))
-                        for shape in rows]
-                 for name, rows in lm.cache_shapes(share, 1024).items()}
-        shapes = jax.eval_shape(lambda: module.init(
-            jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-            jnp.int32(4), lm.empty_cache(share, 8, jnp.float32)))
+        cache = contract.cache_structs(share, 1024)
+        shapes = contract.param_shapes(share)
         ATTENTION.clear()
         EXPANDER.clear()
-        logits, after, _ = jax.eval_shape(
-            lambda v, c: module.apply(v, jnp.zeros((1,), jnp.int32),
-                                      jnp.int32(600), jnp.int32(1), c,
-                                      all_logits=False),
-            shapes, cache)
+        logits, after, _ = contract.sites_of(
+            share, shapes, jnp.zeros((1,), jnp.int32), 600, 1,
+            cache, jnp.bfloat16, all_logits=False)
         assert logits.shape == (1, 65536)
         assert [x.shape for x in after["kept"]] == [(2, 2048)] * 8
         assert EXPANDER.summary()["conv_mixers"] == {"step": 8, "chunk": 0}
@@ -678,29 +465,10 @@ class TestEnginePath:
         ATTENTION.clear()
         EXPANDER.clear()
 
-    def test_status_block(self, engine):
-        engine.txt2img(payload())
-        block = METRICS.summary()["expander"]
-        assert {"state_bytes", "cache_positions", "conv_mixers",
-                "prefix_snapshots", "padded_rows_masked",
-                "tokens_no_held_expert", "expert_tokens"} <= set(block)
-        assert set(block["state_bytes"]) == {"full", "sliding", "conv"}
-        assert block["cache_positions"]["conv"] == 0
-        assert set(block["conv_mixers"]) == {"step", "chunk"}
-        assert block["residual_streams"] == 1
-        assert block["sinkhorn_iters"] == 0
-
     def test_an_expander_without_conv_layers_counts_none(self):
-        old = configs.TINY_EXPAND
-        params = init_params(configs.TINY)
-        module = lm.DecoderLM(old.expander)
-        params["expander"] = module.init(
-            jax.random.key(1), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-            jnp.int32(4), lm.empty_cache(old.expander, 8, jnp.float32))[
-                "params"]
-        engine = Engine(old, params, chunk_size=4, state=GenerationState())
+        engine = contract.engine_for(configs.TINY_EXPAND)
         EXPANDER.clear()
-        engine.txt2img(payload())
+        engine.txt2img(CASE.payload())
         stats = EXPANDER.summary()
         assert stats["conv_mixers"] == {"step": 0, "chunk": 0}
         assert stats["padded_rows_masked"] == 0
@@ -727,15 +495,12 @@ class TestTheServedPath:
         mp = pytest.MonkeyPatch()
         mp.setenv("SDTPU_BUCKET_LADDER", "32x32")
         mp.setenv("SDTPU_BATCH_LADDER", "1")
-        params = init_params(configs.TINY)
-        params["expander"] = lm_params(CFG, seed=1)
-        engine = Engine(FAMILY, params, chunk_size=4,
-                        state=GenerationState())
+        engine = CASE.engine()
         server = ApiServer(engine, state=engine.state, host="127.0.0.1",
                            port=0).start()
         body = {"prompt": "a cow in a valley", "steps": 4, "width": 32,
                 "height": 32, "seed": 77, "sampler_name": "Euler a",
-                "alwayson_scripts": script()}
+                "alwayson_scripts": CASE.script()}
         try:
             EXPANDER.clear()
             ATTENTION.clear()

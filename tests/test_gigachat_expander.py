@@ -15,11 +15,7 @@ the delta rule token by token, the expanded attention only).
 """
 
 import dataclasses
-import functools
 import hashlib
-import importlib.util
-import os
-import zlib
 
 import jax
 import jax.numpy as jnp
@@ -30,166 +26,78 @@ from stable_diffusion_webui_distributed_tpu.cache import kv
 from stable_diffusion_webui_distributed_tpu.models import configs, lm
 from stable_diffusion_webui_distributed_tpu.obs import prometheus
 from stable_diffusion_webui_distributed_tpu.ops import delta_rule, moe, moe_kernel
-from stable_diffusion_webui_distributed_tpu.pipeline import expand
-from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
-from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
-    GenerationPayload,
-)
-from stable_diffusion_webui_distributed_tpu.runtime import dtypes
-from stable_diffusion_webui_distributed_tpu.runtime.interrupt import (
-    GenerationState,
-)
 from stable_diffusion_webui_distributed_tpu.serving.metrics import (
     ATTENTION, EXPANDER, METRICS,
 )
-from tests.test_pipeline import init_params
+from tests import expander_contract as contract
+from tests.expander_contract import CAPACITY, STEPS, count, rel_rms, run
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-REF = _load(os.path.join(ROOT, "benchmarks", "reference",
-                         "gigachat35_ref.py"), "gigachat35_ref_for_tests")
-FAMILY = configs.TINY_GIGACHAT35_EXPAND
-CFG = FAMILY.expander
-STEPS = expand.DECODE_STEPS
-#: decay rates from a token to hundreds, as the benchmark seeds them
-A_LOG = (-3.0, -1.0, 0.5, 2.0)
-
-
-@functools.lru_cache(maxsize=None)
-def lm_params(cfg, seed=0, clamp_binds=False):
-    """``DecoderLM.init``'s tree with every norm's weight off 0 (deviation
-    0.5, as the benchmark seeds them), the decay rates spread, ``dt_bias``
-    off 1 and the selection bias off 0 (deviation 0.1), so that reading one
-    norm as another, sharing a state or leaving the bias out would show.
-    ``clamp_binds``: every SwiGLU's gate and up kernels times eight, so
-    that the clamp at 10 binds (at variance 1/fan_in it is inert)."""
-    params = jax.jit(lambda key: lm.DecoderLM(cfg).init(
-        key, jnp.zeros((4,), jnp.int32), jnp.int32(0), jnp.int32(4),
-        lm.empty_cache(cfg, 8, jnp.float32)))(jax.random.key(seed))["params"]
-    key = jax.random.key(seed + 100)
-
-    def off(path, x):
-        name = getattr(path[-1], "key", "")
-        noise = jax.random.normal(
-            jax.random.fold_in(key, zlib.crc32(str(path).encode()) % 2 ** 31),
-            x.shape)
-        if name in ("weight", "scale"):
-            return x + 0.5 * noise
-        if name == "e_score_correction_bias":
-            return 0.1 * noise
-        if name == "dt_bias":
-            return x + 0.3 * noise
-        if name == "A_log":
-            return jnp.asarray(A_LOG, jnp.float32)
-        gates = {"gate_proj", "up_proj"} & {
-            getattr(k, "key", "") for k in path}
-        if clamp_binds and (gates or name in ("w_gate", "w_up")):
-            return 8.0 * x
-        return x
-
-    return jax.tree_util.tree_map_with_path(off, params)
+REF = contract.load_reference("gigachat35")
+#: every norm's weight off 0 (deviation 0.5, as the benchmark seeds them),
+#: the decay rates spread from a token to hundreds, ``dt_bias`` off 1 and
+#: the selection bias off 0 (deviation 0.1)
+CASE = contract.Case(
+    configs.TINY_GIGACHAT35_EXPAND, REF,
+    how=(("spread", (("weight", 0.5), ("scale", 0.5),
+                     ("e_score_correction_bias", 0.1), ("dt_bias", 0.3))),
+         ("a_log", (-3.0, -1.0, 0.5, 2.0))),
+    tolerance=1e-4, staged_tolerance=1e-5, rows_tolerance=3e-4,
+    step_tolerance=3e-4,
+    controls=("control", "state_bf16", "state_shared", "kept_shared",
+              "silu_gate", "plain_norm", "no_post_norm", "no_attn_gate",
+              "no_mscale", "no_selection_bias"))
+FAMILY, CFG = CASE.family, CASE.cfg
+#: one sequence's state and kept rows in one linear layer, float32
+STATE = (4 * 8 * 8 + 3 * 64) * 4
+params, engine = contract.fixtures(CASE)
 
 
-@pytest.fixture(scope="module")
-def params():
-    return lm_params(CFG)
+def clamp_binds(params):
+    """Every SwiGLU's gate and up kernels times eight, so that the clamp
+    at 10 binds (at variance 1/fan_in it is inert)."""
+    def scaled(path, x):
+        names = {getattr(k, "key", "") for k in path}
+        return 8.0 * x if names & {"gate_proj", "up_proj", "w_gate",
+                                   "w_up"} else x
 
-
-def rel_rms(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / np.sqrt(np.mean(want ** 2)))
-
-
-@functools.lru_cache(maxsize=None)
-def _reference(size, clamp_binds=False):
-    """(ids, continuations, the reference's logits, its routing) at
-    ``size`` positions, the tiny preset's seeded weights."""
-    ids, continuations = REF.inputs(FAMILY, 3, size)
-    want, own = jax.jit(lambda p, i, c: REF.forward(
-        FAMILY, p, i, c, with_routing=True))(
-            lm_params(CFG, clamp_binds=clamp_binds), ids, continuations)
-    return ids, continuations, want, own
+    return jax.jit(lambda p: jax.tree_util.tree_map_with_path(scaled, p))(
+        params)
 
 
 # -- (a) program against reference --------------------------------------------
 
-class TestAgainstTheReference:
-    @pytest.mark.parametrize("size", [37, 148])
-    def test_prefill_fork_and_decode_match_four_full_forwards(self, params,
-                                                              size):
-        """The prefix as one chunk (expanded latent form, chunk-wise delta
-        rule), a copy, the prompt's chunk, a fork into four and one step
-        over all four a position (the forked latent form, a recurrent step
-        a sequence), against a full forward of each whole sequence:
-        logits to 1e-4, routing identical."""
-        prefix, user, decoded = REF.split(size)
-        ids, continuations, want, own = _reference(size)
-        got, chose = jax.jit(REF.program(FAMILY, dtypes.F32,
-                                         with_routing=True))(
-            params, ids, continuations)
-        rows = prefix + user + REF.SEQUENCES * decoded
-        assert got.shape == want.shape == (rows, CFG.vocab[1])
-        assert got.dtype == want.dtype == jnp.float32
-        assert rel_rms(got, want) < 1e-4
-        assert chose.shape == (len(CFG.expert_layers), rows,
-                               CFG.num_experts_per_tok)
-        assert np.array_equal(np.sort(chose, -1), np.sort(own, -1))
-        # the four continuations part at their first row
-        tails = np.asarray(got[prefix + user:]).reshape(
-            REF.SEQUENCES, decoded, -1)
-        assert rel_rms(tails[1], tails[0]) > 0.1
+class TestAgainstTheReference(contract.ForkedAgainstTheReference,
+                              contract.StagedAsTheTimedPathRunsIt):
+    """Expanded latent form and chunk-wise delta rule, a copy, a fork into
+    four and the forked latent form with a recurrent step a sequence:
+    logits to 1e-4, routing identical. Int8 linears, the state in
+    bfloat16, one state or one set of kept rows shared by the sequences,
+    ``silu(z)`` for ``2 sigmoid(z)``, ``1 + w`` for ``2 sigmoid(w)``, the
+    post-sublayer norms, the attention gate, ``m^2`` or the selection bias
+    left out: each misses ten times over the tolerance the program meets
+    ten times over; the state in bfloat16 reads the nearest, 4e-3."""
+    CASE = CASE
+    test_prefill_fork_and_decode_match_four_full_forwards = \
+        contract.ForkedAgainstTheReference.program_matches_four_full_forwards
+    PARAMETERS = {
+        "test_prefill_fork_and_decode_match_four_full_forwards": [
+            ("size", [37, 148])],
+        "test_each_control_is_further_from_the_reference": [
+            ("control", [name for name, _ in REF.CONTROLS])]}
 
-    def test_the_two_executables_give_what_the_one_gives(self, params):
-        ids, continuations = REF.inputs(FAMILY, 3, 37)
-        whole, chose = jax.jit(REF.program(FAMILY, dtypes.F32,
-                                           with_routing=True))(
-            params, ids, continuations)
-        got, chose_staged = REF.staged(FAMILY, dtypes.F32, params, ids,
-                                       continuations)
-        np.testing.assert_allclose(got, whole, rtol=1e-5, atol=1e-5)
-        assert np.array_equal(chose, chose_staged)
-
-    @pytest.mark.parametrize("control", [name for name, _ in REF.CONTROLS])
-    def test_each_control_is_further_from_the_reference(self, params,
-                                                        control):
-        """Int8 linears, the state in bfloat16, one state or one set of
-        kept rows shared by the sequences, ``silu(z)`` for ``2
-        sigmoid(z)``, ``1 + w`` for ``2 sigmoid(w)``, the post-sublayer
-        norms, the attention gate, ``m^2`` or the selection bias left out:
-        each misses ten times over the tolerance (1e-4) the program meets
-        ten times over; the state in bfloat16 reads the nearest, 4e-3."""
-        ids, continuations, want, _ = _reference(148)
-        lower = jax.jit(REF.program(
-            FAMILY, dtypes.F32, **dict(REF.CONTROLS)[control]))(
-                params, ids, continuations)
-        assert rel_rms(lower, want) > 1e-3, control
-        assert [name for name, _ in REF.CONTROLS] == [
-            "control", "state_bf16", "state_shared", "kept_shared",
-            "silu_gate", "plain_norm", "no_post_norm", "no_attn_gate",
-            "no_mscale", "no_selection_bias"]
-
-    def test_the_clamp_where_it_binds(self):
+    def test_the_clamp_where_it_binds(self, params):
         """At variance 1/fan_in a SwiGLU's products have deviation about 1
         and the clamp at 10 is inert (the chip's readings cannot see it
         left out): here every gate and up kernel is scaled by eight, the
         program with the clamp meets the reference and the program
         without it does not."""
-        scaled = lm_params(CFG, clamp_binds=True)
-        ids, continuations, want, _ = _reference(37, clamp_binds=True)
-        got = jax.jit(REF.program(FAMILY, dtypes.F32))(
+        scaled = clamp_binds(params)
+        ids, continuations = REF.inputs(FAMILY, 3, 37)
+        want = jax.jit(lambda p, i, c: REF.forward(FAMILY, p, i, c))(
             scaled, ids, continuations)
+        got = CASE.program()(scaled, ids, continuations)
         assert rel_rms(got, want) < 1e-4
-        without = jax.jit(REF.program(FAMILY, dtypes.F32, no_clamp=True))(
-            scaled, ids, continuations)
+        without = CASE.program(no_clamp=True)(scaled, ids, continuations)
         assert rel_rms(without, want) > 1e-2
 
     def test_the_selection_bias_changes_most_choices(self, params):
@@ -266,230 +174,32 @@ class TestAgainstTheReference:
 
 # -- (b) a step over B sequences ----------------------------------------------
 
-def _keys(indices, seed=77):
-    from stable_diffusion_webui_distributed_tpu.runtime import rng
+class TestSequencesOfOneStep(contract.SequencesOfOneStep,
+                             contract.StatesOfOneStep,
+                             contract.WhichKindsShareAStep):
+    """To 3e-4: thirty-two steps of a float32 recurrence fused two ways. A
+    recurrent state with a sequence axis shares a step; a conv layer's
+    kept rows and several streams still decode one sequence a step."""
+    CASE = CASE
+    SHARE = ("sd15_gigachat35_expander", "sd15_qwen3next_expander")
+    ONE_A_STEP = ("sd15_lfm2_expander", "sd15_xing4_expander")
+    test_a_snapshot_restores_latents_and_states = \
+        contract.StatesOfOneStep.a_snapshot_restores_every_buffer
+    PARAMETERS = {
+        "test_a_forked_decode_is_each_sequence_alone": [
+            ("user,live,batch", [(1, 4, 4), (64, 3, 4)])],
+        "test_which_kinds_share_a_step": [("preset,shares", [
+            ("TINY_GIGACHAT35_EXPAND", True), ("TINY_DELTA_EXPAND", True),
+            ("TINY_KANANA_EXPAND", True), ("TINY_LATENT_EXPAND", False),
+            ("TINY_CONV_EXPAND", False)])]}
 
-    return jnp.stack([rng.key_for_image(seed, i) for i in indices])
+    test_a_fork_shares_every_latent_and_copies_every_state = contract.SequencesOfOneStep \
+        .a_fork_shares_what_has_positions_and_copies_the_rest
 
-
-@functools.lru_cache(maxsize=None)
-def _executables(cfg):
-    """(the one-sequence decode chunk, the several-sequences one, a step
-    of each that returns its logits), jitted once a config."""
-    module = lm.DecoderLM(cfg)
-
-    def one_step(params, cache, token, position):
-        return module.apply({"params": params}, token[None], position,
-                            jnp.int32(1), cache)[:2]
-
-    def forked_step(params, cache, tokens, position, live):
-        return module.apply({"params": params}, tokens, position, live,
-                            cache, sequences=True)[:2]
-
-    return (jax.jit(lm.decode_chunk_fn(module, STEPS)),
-            jax.jit(lm.decode_sequences_fn(module, STEPS)),
-            jax.jit(one_step), jax.jit(forked_step))
-
-
-def _prefilled(cfg, params, user, prefix=21, capacity=None):
-    """(the prompt's last row of logits, the cache, its length) after a
-    prefix's chunk and a prompt of ``user`` real tokens in its padded
-    chunk: the bucket's other rows must leave the states and the kept rows
-    where the prompt's last real token put them."""
-    module = lm.DecoderLM(cfg)
-    bucket = kv.chunk_bucket(user)
-    capacity = capacity or kv.capacity_for(prefix + bucket + 2 * STEPS)
-    first, count = cfg.vocab
-    ids = jax.random.randint(jax.random.key(user), (prefix + bucket,),
-                             first, first + count)
-    _, cache, _ = module.apply(
-        {"params": params}, ids[:prefix], jnp.int32(0), jnp.int32(prefix),
-        lm.empty_cache(cfg, capacity, jnp.float32), all_logits=False)
-    row, cache, _ = module.apply(
-        {"params": params}, ids[prefix:], jnp.int32(prefix),
-        jnp.int32(user), cache, all_logits=False)
-    return row[0], cache, prefix + user
-
-
-def assert_own_state(alone, forked, b, first, steps):
-    """Sequence ``b``'s state, kept rows and own latent rows against the
-    cache of that sequence decoded alone."""
-    for name in lm.LINEAR_BUFFERS:
-        for mine, theirs in zip(alone[name], forked[name]):
-            np.testing.assert_allclose(mine, theirs[b], rtol=3e-4,
-                                       atol=3e-4)
-    positions = np.arange(first, first + steps)
-    for mine, theirs in zip(alone.get("latent", ()),
-                            forked.get("latent", ())):
-        np.testing.assert_allclose(
-            np.asarray(mine)[positions],
-            np.asarray(theirs[b])[(positions - first) % theirs.shape[1]],
-            rtol=3e-4, atol=3e-4)
-
-
-class TestSequencesOfOneStep:
-    @pytest.mark.parametrize("user,live,batch", [(1, 4, 4), (64, 3, 4)])
-    def test_a_forked_decode_is_each_sequence_alone(self, params, user,
-                                                    live, batch):
-        """``batch`` sequences forked from one prefill against each of the
-        ``live`` decoded alone from the same cache by the one-sequence
-        executable: a chunk of steps token for token, the states, kept
-        rows and latent rows written, the load without the pad, and the
-        logits of a few teacher-forced steps after it (to 3e-4: thirty-two
-        steps of a float32 recurrence fused two ways). A group of three
-        padded to four leaves the pad's copies as the fork made them."""
-        alone, together, one_step, forked_step = _executables(CFG)
-        row, cache, length = _prefilled(CFG, params, user)
-        keys = _keys(list(range(live)) + [live - 1] * (batch - live))
-        first = lm.sample_each(row, keys, length, jnp.float32(1.0),
-                               CFG.vocab[0])
-        start = kv.fork(cache, batch, 2 * STEPS)
-        forked, tokens, position, made, load, none_held, read = together(
-            params, start, first, jnp.int32(length), keys,
-            jnp.float32(1.0), jnp.int32(live))
-        assert int(position) == length + STEPS
-        # the shared latents are the prefill's, untouched
-        for mine, theirs in zip(cache["latent"], forked["latent_shared"]):
-            assert np.array_equal(np.asarray(mine), np.asarray(theirs))
-        assert int(forked[lm.FORKED_AT][0][0, 0]) == length
-        own, total, none = [], 0, 0
-        for b in range(live):
-            after, last, _, steps, own_load, own_none = alone(
-                params, cache, first[b], jnp.int32(length), keys[b],
-                jnp.float32(1.0))
-            assert np.array_equal(steps, made[:, b]), b
-            assert int(last) == int(tokens[b])
-            assert_own_state(after, forked, b, length, STEPS)
-            own.append(after)
-            total, none = total + own_load, none + own_none
-        assert np.array_equal(load, total)      # the pad is not counted
-        assert np.array_equal(none_held, none)
-        assert int(none_held.sum()) > 0         # a share: some find none
-        for b in range(live, batch):            # a pad's copies stay
-            for name in lm.LINEAR_BUFFERS:
-                for mine, theirs in zip(cache[name], forked[name]):
-                    assert np.array_equal(np.asarray(mine),
-                                          np.asarray(theirs[b]))
-        assert len({tuple(np.asarray(made[:, b])) for b in range(live)}) \
-            == live
-        forced = jax.random.randint(jax.random.key(8), (3, batch),
-                                    *np.cumsum(CFG.vocab))
-        for t, row in enumerate(forced):
-            at = jnp.int32(length + STEPS + t)
-            logits, forked = forked_step(params, forked, row, at,
-                                         jnp.int32(live))
-            for b in range(live):
-                want, own[b] = one_step(params, own[b], row[b], at)
-                np.testing.assert_allclose(logits[b], want[0], rtol=3e-4,
-                                           atol=3e-4)
-
-    def test_a_sequence_that_has_ended_leaves_the_others_alone(self, params):
-        """A sequence goes on being stepped after its end-of-sequence (its
-        tokens are cut afterwards): whatever it is fed, the other
-        sequences' logits, states, kept rows and latent rows are bit for
-        bit what they are beside any other neighbour."""
-        *_, forked_step = _executables(CFG)
-        row, cache, length = _prefilled(CFG, params, 7)
-        tokens = jnp.array([130, 131, 132, 133], jnp.int32) % CFG.vocab[1]
-        results = []
-        for fed in (5, 99):
-            forked = kv.fork(cache, 4, STEPS)
-            for t in range(3):
-                logits, forked = forked_step(
-                    params, forked, tokens.at[2].set(fed + t),
-                    jnp.int32(length + t), jnp.int32(4))
-            results.append((logits, forked))
-        (a, ca), (b, cb) = results
-        others = np.array([0, 1, 3])
-        assert np.array_equal(np.asarray(a)[others], np.asarray(b)[others])
-        assert not np.array_equal(np.asarray(a)[2], np.asarray(b)[2])
-        for name in ("state", "conv", "latent"):
-            for mine, theirs in zip(ca[name], cb[name]):
-                assert np.array_equal(np.asarray(mine)[others],
-                                      np.asarray(theirs)[others]), name
-                assert not np.array_equal(np.asarray(mine)[2],
-                                          np.asarray(theirs)[2]), name
-
-    def test_a_fork_shares_every_latent_and_copies_every_state(self, params):
-        _, cache, _ = _prefilled(CFG, params, 5)
-        forked = kv.fork(cache, 4, 2 * STEPS)
-        assert set(forked) == {"latent", "latent_shared", "state", "conv",
-                               "forked_at"}
-        assert all(mine is theirs for mine, theirs
-                   in zip(cache["latent"], forked["latent_shared"]))
-        assert [x.shape for x in forked["latent_shared"]] == [(256, 24)]
-        assert [x.shape for x in forked["latent"]] == [(4, 64, 24)]
-        assert not any(np.any(np.asarray(x)) for x in forked["latent"])
-        assert [x.shape for x in forked["state"]] == [(4, 4, 8, 8)] * 4
-        assert [x.shape for x in forked["conv"]] == [(4, 3, 64)] * 4
-        for name in lm.LINEAR_BUFFERS:
-            for mine, theirs in zip(cache[name], forked[name]):
-                assert np.any(np.asarray(mine))
-                for b in range(4):
-                    assert np.array_equal(np.asarray(mine),
-                                          np.asarray(theirs[b]))
-        # what the engine's fork executable makes: the same, in one call
-        made = jax.jit(lambda c: kv.own_rows(c, 4, 2 * STEPS))(cache)
-        again = kv.forked(cache, made)
-        assert jax.tree_util.tree_structure(again) \
-            == jax.tree_util.tree_structure(forked)
-        for name in lm.LINEAR_BUFFERS:
-            for mine, theirs in zip(forked[name], again[name]):
-                assert np.array_equal(np.asarray(mine), np.asarray(theirs))
+    def check_fork(self, forked):
         assert lm.buffers_of(lm.LINEAR, forked=True) == ("state", "conv")
         assert lm.slots_axis("state") is None and lm.slots_axis("conv") is None
         assert lm.slots_axis("latent") == -2 and lm.slots_axis("k") == -3
-
-    def test_a_snapshot_restores_latents_and_states(self, params):
-        """What the manager keeps after the instruction's last token is a
-        copy of every kind of buffer; a request that starts from it gets
-        copies again, whatever the one before did to its own."""
-        manager = kv.KVCacheManager(CFG, jnp.float32)
-        prefix = tuple(range(1, 22))
-        cache, held = manager.acquire(prefix, 256)
-        assert held == 0 and not np.any(np.asarray(cache["state"][0]))
-        module = lm.DecoderLM(CFG)
-        apply = jax.jit(lambda t, start, c: module.apply(
-            {"params": params}, t, start, jnp.int32(t.shape[0]), c,
-            all_logits=False))
-        _, cache, _ = apply(jnp.asarray(prefix, jnp.int32), jnp.int32(0),
-                            cache)
-        manager.keep_prefix(prefix, 256, cache)
-        kept = jax.tree_util.tree_map(np.asarray, cache)
-        first, held = manager.acquire(prefix, 256)
-        assert held == 21 and manager.snapshots == 1
-        # the request runs on and spoils its copy
-        _, spoiled, _ = apply(jnp.arange(5, dtype=jnp.int32), jnp.int32(21),
-                              first)
-        assert not np.array_equal(np.asarray(spoiled["state"][0]),
-                                  kept["state"][0])
-        second, held = manager.acquire(prefix, 256)
-        assert held == 21
-        for name in ("latent", "state", "conv"):
-            for mine, theirs in zip(kept[name], second[name]):
-                assert np.array_equal(mine, np.asarray(theirs)), name
-
-    @pytest.mark.parametrize("preset,shares", [
-        ("TINY_GIGACHAT35_EXPAND", True), ("TINY_DELTA_EXPAND", True),
-        ("TINY_KANANA_EXPAND", True), ("TINY_LATENT_EXPAND", False),
-        ("TINY_CONV_EXPAND", False)])
-    def test_which_kinds_share_a_step(self, preset, shares):
-        """A recurrent state with a sequence axis does; a conv layer's
-        kept rows and several streams still decode one sequence a step."""
-        cfg = getattr(configs, preset).expander
-        assert lm.shares_a_step(cfg) is shares
-        if not shares:
-            with pytest.raises(ValueError):
-                jax.eval_shape(
-                    lambda: lm.DecoderLM(cfg).init(
-                        jax.random.key(0), jnp.zeros((2,), jnp.int32),
-                        jnp.int32(0), jnp.int32(2),
-                        lm.empty_cache(cfg, 8, jnp.float32),
-                        sequences=True))
-        assert lm.shares_a_step(configs.sd15_gigachat35_expander().expander)
-        assert lm.shares_a_step(configs.sd15_qwen3next_expander().expander)
-        assert not lm.shares_a_step(configs.sd15_lfm2_expander().expander)
-        assert not lm.shares_a_step(configs.sd15_xing4_expander().expander)
 
     def test_bytes_and_positions_of_a_forked_cache_of_both_kinds(self):
         manager = kv.KVCacheManager(CFG, jnp.bfloat16)
@@ -498,23 +208,22 @@ class TestSequencesOfOneStep:
         assert manager.positions_in_use(40, 4, 30) == {
             "full": 0, "sliding": 0, "linear": 0, "latent": 30 + 4 * 10}
         row = 24 * 2                            # a slot's latent, bfloat16
-        state = (4 * 8 * 8 + 3 * 64) * 4        # float32, with kept rows
         assert kv.state_bytes(CFG, 256, jnp.bfloat16) == {
-            "full": 0, "sliding": 0, "linear": 4 * state,
+            "full": 0, "sliding": 0, "linear": 4 * STATE,
             "latent": 256 * row}
         # a forked group: the latents once and 64 slots a sequence, the
         # states once a sequence
         assert kv.state_bytes(CFG, 256, jnp.bfloat16, 4, 64) == {
-            "full": 0, "sliding": 0, "linear": 4 * 4 * state,
+            "full": 0, "sliding": 0, "linear": 4 * 4 * STATE,
             "latent": (256 + 4 * 64) * row}
-        assert kv.copied_bytes(CFG, jnp.bfloat16, 4) == 4 * 4 * state
+        assert kv.copied_bytes(CFG, jnp.bfloat16, 4) == 4 * 4 * STATE
         assert kv.copied_bytes(CFG, jnp.bfloat16, 1) == 0
         assert kv.copied_bytes(configs.TINY_KANANA_EXPAND.expander,
                                jnp.bfloat16, 4) == 0
         # the delta-rule sibling's bytes are what they were
         other = configs.TINY_DELTA_EXPAND.expander
         assert kv.state_bytes(other, 256, jnp.float32)["linear"] \
-            == 2 * state
+            == 2 * STATE
 
     def test_the_forms_by_rows_and_whose_they_are(self):
         assert delta_rule.form(64) == "chunked"
@@ -549,8 +258,34 @@ class TestSequencesOfOneStep:
 
 # -- (c) the delta-rule configuration through the shared step ------------------
 
-QWEN = _load(os.path.join(ROOT, "benchmarks", "reference",
-                          "qwen3next_ref.py"), "qwen3next_ref_for_g35_tests")
+SHARED, OWN = 21, 12
+
+
+def _forked_program(cfg):
+    """A prefill of ``SHARED`` tokens, a fork into as many sequences as
+    there are continuations and a step for all a position, teacher-forced:
+    (sequences, ``OWN``, vocabulary) logits."""
+    module = lm.DecoderLM(cfg)
+
+    def program(params, ids, continuations):
+        batch = continuations.shape[0]
+        _, cache, _ = module.apply(
+            {"params": params}, ids, jnp.int32(0), jnp.int32(SHARED),
+            lm.empty_cache(cfg, 64, jnp.float32))
+        cache = kv.fork(cache, batch, OWN)
+
+        def step(carry, tokens):
+            cache, position = carry
+            logits, cache, _ = module.apply(
+                {"params": params}, tokens, position, jnp.int32(batch),
+                cache, sequences=True)
+            return (cache, position + 1), logits
+
+        _, logits = jax.lax.scan(step, (cache, jnp.int32(SHARED)),
+                                 continuations.T)
+        return jnp.moveaxis(logits, 1, 0)
+
+    return jax.jit(program)
 
 
 @pytest.mark.parametrize("batch", [2, 4])
@@ -560,43 +295,18 @@ def test_the_delta_rule_preset_shares_a_step_against_its_reference(batch):
     forked into ``batch`` and decoded a step for all, teacher-forced on
     continuations that differ, against its OWN plain reference's full
     forward of each whole sequence."""
-    from tests.test_delta_expander import lm_params as delta_params
+    from tests.test_delta_expander import CASE as DELTA
 
-    family = configs.TINY_DELTA_EXPAND
-    cfg = family.expander
-    params = delta_params(cfg)
-    module = lm.DecoderLM(cfg)
-    first, count = cfg.vocab
-    shared, own = 21, 12
-    ids = jax.random.randint(jax.random.key(4), (shared,), first,
+    first, count = DELTA.cfg.vocab
+    ids = jax.random.randint(jax.random.key(4), (SHARED,), first,
                              first + count)
-    continuations = jax.random.randint(jax.random.key(5), (batch, own),
+    continuations = jax.random.randint(jax.random.key(5), (batch, OWN),
                                        first, first + count)
-
-    @jax.jit
-    def program(params):
-        cache = lm.empty_cache(cfg, 64, jnp.float32)
-        _, cache, _ = module.apply({"params": params}, ids, jnp.int32(0),
-                                   jnp.int32(shared), cache)
-        cache = kv.fork(cache, batch, own)
-
-        def step(carry, tokens):
-            cache, position = carry
-            logits, cache, _ = module.apply(
-                {"params": params}, tokens, position, jnp.int32(batch),
-                cache, sequences=True)
-            return (cache, position + 1), logits
-
-        _, logits = jax.lax.scan(step, (cache, jnp.int32(shared)),
-                                 continuations.T)
-        return jnp.moveaxis(logits, 1, 0)       # (batch, own, vocabulary)
-
-    got = program(params)
+    got = _forked_program(DELTA.cfg)(DELTA.params(), ids, continuations)
     for b in range(batch):
-        whole = jnp.concatenate([ids, continuations[b]])
-        want = jax.jit(lambda p, i: QWEN.forward(family, p, i))(params,
-                                                                whole)
-        assert rel_rms(got[b], want[shared:]) < 1e-4, b
+        _, want, _ = DELTA.referred_on(
+            jnp.concatenate([ids, continuations[b]]))
+        assert rel_rms(got[b], want[SHARED:]) < 1e-4, b
     assert rel_rms(got[1], got[0]) > 0.1
 
 
@@ -611,7 +321,7 @@ def _dense_layer(cfg, n, p):
             REF._swiglu(cfg, n, p["shared_expert"]))
 
 
-class TestTheTreeAndItsRules:
+class TestTheTreeAndItsRules(contract.ShardingRules):
     def test_the_leaves_of_each_kind(self, params):
         assert CFG.layer_types == ("linear", "latent", "linear", "linear",
                                    "linear") and CFG.dense_layers == (0,)
@@ -645,10 +355,7 @@ class TestTheTreeAndItsRules:
         for name in ("TINY_LATENT_EXPAND", "TINY_KANANA_EXPAND"):
             other = getattr(configs, name).expander
             assert other.attn_gate == "none"
-            shapes = jax.eval_shape(lambda: lm.DecoderLM(other).init(
-                jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-                jnp.int32(4),
-                lm.empty_cache(other, 8, jnp.float32)))["params"]
+            shapes = contract.param_shapes(other)
             assert "g_proj" not in shapes["layers_0"]["attn"]
 
     def test_the_share_holds_the_published_layers_it_names(self):
@@ -671,7 +378,7 @@ class TestTheTreeAndItsRules:
         layer; and the slices' logits side by side are the uncut head's."""
         whole = configs.lm_share(configs.TINY_GIGACHAT35_LM,
                                  (0, 3, 4, 5, 6), chips=1, rank=0)
-        uncut = lm_params(whole, seed=3)
+        uncut = CASE.params(3, whole)
         n = jax.random.normal(jax.random.key(9), (6, 32))
         p = uncut["layers_1"]["mlp"]
         routed, shared = _dense_layer(whole, n, p)
@@ -699,179 +406,87 @@ class TestTheTreeAndItsRules:
         np.testing.assert_allclose(jnp.concatenate(slices, -1), x @ head,
                                    rtol=1e-6, atol=1e-6)
         # a token whose id another slice holds gets nothing from the table
-        module = lm.DecoderLM(CFG)
-        cache = lm.empty_cache(CFG, 8, jnp.float32)
-        apply = jax.jit(lambda p, t: module.apply(
-            {"params": p}, t, jnp.int32(0), jnp.int32(2), cache)[0])
-        inside = apply(lm_params(CFG), jnp.array([5, 6], jnp.int32))
-        outside = apply(lm_params(CFG), jnp.array([5, 300], jnp.int32))
+        inside, outside = (
+            run(CFG, CASE.params(), jnp.array(ids, jnp.int32), 0, 2,
+                contract.empty(CFG, 8))[0] for ids in ([5, 6], [5, 300]))
         assert np.allclose(inside[0], outside[0], atol=1e-6)
         assert not np.allclose(inside[1], outside[1], atol=1e-3)
 
-    def test_sharding_rules(self, params):
-        """The new leaves: a latent layer's ``g_proj`` splits by its
-        columns as ``q_proj`` does, what makes and reads the latent or
-        the recurrent state stays whole on every chip, a norm's ``weight``
-        is replicated."""
-        from jax.sharding import PartitionSpec as P
-
-        from stable_diffusion_webui_distributed_tpu.parallel.sharding import (
-            shard_params, tp_spec_for,
-        )
-
-        for path, ndim in (("layers_1/attn/kv_a_proj_with_mqa/kernel", 2),
-                           ("layers_1/attn/kv_b_proj/kernel", 2),
-                           ("layers_1/attn/kv_a_norm/weight", 1),
-                           ("layers_1/input_norm_2/weight", 1),
-                           ("layers_0/delta/norm/weight", 1),
-                           ("layers_0/delta/A_log", 1),
-                           ("layers_0/delta/conv_kernel", 2),
-                           ("layers_1/mlp/e_score_correction_bias", 1)):
-            assert tp_spec_for(path, ndim) == P(), path
-        assert tp_spec_for("layers_1/mlp/experts/w_up", 3) \
-            == P("ep", None, None)
-        devices = np.array(jax.devices()[:4]).reshape(2, 2)
-        mesh = jax.sharding.Mesh(devices, ("ep", "vp"))
-        placed = shard_params(params, mesh)
-        assert placed["layers_1"]["mlp"]["experts"]["w_gate"].sharding.spec \
-            == P("ep", None, None)
-        for leaf in (placed["layers_1"]["attn"]["g_proj"]["kernel"],
-                     placed["layers_0"]["delta"]["qkvz_proj"]["kernel"],
-                     placed["layers_1"]["input_norm_2"]["weight"]):
-            assert leaf.sharding.spec == P()    # no tp axis on this mesh
-        assert placed["lm_head"]["kernel"].sharding.spec == P(None, "vp")
+    #: the new leaves: what makes and reads the latent or the recurrent
+    #: state stays whole on every chip, a norm's ``weight`` is replicated
+    #: (a latent layer's ``g_proj`` splits by its columns as ``q_proj``)
+    WHOLE = (("layers_1/attn/kv_a_proj_with_mqa/kernel", 2),
+             ("layers_1/attn/kv_b_proj/kernel", 2),
+             ("layers_1/attn/kv_a_norm/weight", 1),
+             ("layers_1/input_norm_2/weight", 1),
+             ("layers_0/delta/norm/weight", 1),
+             ("layers_0/delta/A_log", 1),
+             ("layers_0/delta/conv_kernel", 2),
+             ("layers_1/mlp/e_score_correction_bias", 1))
+    EXPERT_LAYER = 1
+    PLACED_WHOLE = ("layers_1/attn/g_proj/kernel",
+                    "layers_0/delta/qkvz_proj/kernel",
+                    "layers_1/input_norm_2/weight")
+    test_sharding_rules = contract.ShardingRules.sharding_rules
 
 
 # -- (e) the engine's path ----------------------------------------------------
 
-INSTRUCTION = " ".join(f"word{i}" for i in range(30))
+class TestEnginePath(contract.ForkedEnginePath):
+    test_a_batch_prefills_once_forks_and_decodes_four_a_step = contract.ForkedEnginePath \
+        .a_batch_prefills_once_forks_and_decodes_four_a_step
+    test_every_image_its_own_expansion_and_one_image_the_old_path = contract.ForkedEnginePath \
+        .every_image_its_own_expansion_and_one_image_the_old_path
+    CASE = CASE
 
-
-def script(**args):
-    return {"prompt expansion": {"args": [dict(
-        {"instruction": INSTRUCTION, "max_new_tokens": 40,
-         "temperature": 1.0, "ignore_eos": True, "context_chunks": 1},
-        **args)]}}
-
-
-@pytest.fixture(scope="module")
-def engine():
-    params = init_params(configs.TINY)
-    params["expander"] = lm_params(CFG, seed=1)
-    return Engine(configs.tiny_gigachat35_expander(), params, chunk_size=4,
-                  state=GenerationState())
-
-
-def payload(**kw):
-    base = dict(prompt="a cow in a valley", steps=4, width=32, height=32,
-                seed=1234, alwayson_scripts=script())
-    base.update(kw)
-    return GenerationPayload(**base)
-
-
-CAPACITY = kv.capacity_for(31 + 64 + 2 * STEPS)
-
-
-class TestEnginePath:
-    def test_a_batch_prefills_once_forks_and_decodes_four_a_step(self,
-                                                                 engine):
-        """The spans, counters and Prometheus families of a four-image
-        request from the kept snapshot; the second request repeats the
-        first byte for byte."""
-        from stable_diffusion_webui_distributed_tpu.obs import spans
-
-        assert engine.expander.shares_a_step
-        ATTENTION.clear()
-        EXPANDER.clear()
-        whole = engine.txt2img(payload(batch_size=4))   # keeps the snapshot
-        assert len(set(whole.prompts)) == 4
-        keys = {k for k in engine.executable_keys()
-                if k[0].startswith("expand")}
-        assert keys == {("expand_prefill", 64, CAPACITY),
-                        ("expand_prefill", 64, CAPACITY, 4),
-                        ("expand_fork", CAPACITY, 4, 2 * STEPS),
-                        ("expand_decode_chunk", STEPS, CAPACITY, 4),
-                        ("expand_keys", 4), ("expand_copy", CAPACITY)}
-        sites = ATTENTION.summary()
+    def check_traced(self, sites, traced):
         assert sites["latent_forked"] == 1 and sites["latent_expanded"] == 2
         assert "latent_absorbed" not in sites
         assert sites["by_shape"][f"T4 S{CAPACITY}+{2 * STEPS} D24"] \
             == {"latent_forked": 1}
-        traced = EXPANDER.summary()["delta_mixers"]
-        assert traced == {"recurrent": 0, "chunked": 8,
-                          "recurrent_forked": 4}
-        EXPANDER.clear()
-        spans.TRACER.clear()
-        with spans.request("rid-g35"):
-            again = engine.txt2img(payload(batch_size=4))
-        assert again.prompts == whole.prompts
-        assert again.images == whole.images
-        stats = METRICS.summary()["expander"]
-        assert stats["requests"] == 1 and stats["sequences"] == 4
-        assert stats["tokens_prefilled"] == 5       # the prompt, once
-        assert stats["tokens_from_prefix_cache"] == 31
-        assert stats["tokens_decoded"] == 4 * 40
-        assert stats["decode_steps"] == 2 * STEPS
+        assert traced["delta_mixers"] == {"recurrent": 0, "chunked": 8,
+                                          "recurrent_forked": 4}
+
+    def check_counted(self, stats, sizes, one):
         # a quarter of the experts is held: some tokens find none
         assert 0 < stats["tokens_no_held_expert"] < 4 * (5 + 4 * 2 * STEPS)
         assert 0 < stats["experts_read"] <= 2 * STEPS * 4 * 4
         assert stats["expert_products"]["kernel"] == 0      # a CPU
         assert stats["cache_positions"] == {
             "full": 0, "sliding": 0, "linear": 0, "latent": 36 + 4 * 40}
-        sizes = kv.state_bytes(CFG, CAPACITY, jnp.float32, 4, 2 * STEPS)
-        assert stats["state_bytes"] == sizes
-        one = kv.state_bytes(CFG, CAPACITY, jnp.float32)
-        state = 4 * (4 * 8 * 8 + 3 * 64) * 4
-        assert one["linear"] == state and sizes["linear"] == 4 * state
-        assert stats["fork_bytes_copied"] == 4 * state
+        assert one["linear"] == 4 * STATE and sizes["linear"] == 16 * STATE
+        assert stats["fork_bytes_copied"] == 16 * STATE
         # a step reads and writes each sequence's states once
-        assert stats["state_bytes_stepped"] == 2 * STEPS * 2 * 4 * state
-        events = [e for e in spans.TRACER.export_chrome()["traceEvents"]
-                  if e.get("ph") == "X"]
-        by_name = {}
-        for e in events:
-            by_name.setdefault(e["name"], []).append(e["args"])
-        assert [a["sequences"] for a in by_name["expand"]] == [4]
-        (prefill,) = by_name["expand.prefill"]
-        assert prefill["tokens"] == 5 and prefill["sequences"] == 4
-        assert prefill["latent"] == "latent_expanded"
-        assert prefill["form"] == "chunked" and prefill["padded"] == 59
-        (fork,) = by_name["expand.fork"]
-        assert fork["sequences"] == 4 and fork["latent"] == "latent_forked"
-        assert fork["delta"] == "recurrent_forked"
-        assert fork["state_bytes_copied"] == 4 * state
-        # the bytes a fork makes: one latent layer's own rows of 64 slots
-        # a sequence and four copies of every state, float32
-        assert fork["bytes"] == 4 * 2 * STEPS * 24 * 4 + 4 * state
-        assert [(a["sequences"], a["latent"], a["delta"])
-                for a in by_name["expand.decode_chunk"]] \
-            == [(4, "latent_forked", "recurrent_forked")] * 2
-        hits = [a for a in by_name["expand.prefix_copy"] if a.get("hit")]
-        assert hits and hits[0]["bytes"] == sum(one.values())
+        assert stats["state_bytes_stepped"] == 2 * STEPS * 2 * 16 * STATE
         text = prometheus.render()
         assert 'sdtpu_expander_delta_mixers_total{form="recurrent_forked"}' \
             in text
         assert "sdtpu_expander_state_bytes_stepped_total " \
             f"{stats['state_bytes_stepped']}" in text
-        assert f"sdtpu_expander_fork_bytes_copied_total {4 * state}" in text
+        assert f"sdtpu_expander_fork_bytes_copied_total {16 * STATE}" in text
 
-    def test_every_image_its_own_expansion_and_one_image_the_old_path(
-            self, engine):
-        whole = engine.txt2img(payload(batch_size=4))
-        ATTENTION.clear()
-        EXPANDER.clear()
-        for i in (0, 3):
-            solo = engine.txt2img(payload(seed=1234 + i))
-            assert solo.prompts[0] == whole.prompts[i], i
-        part = engine.generate_range(payload(batch_size=4), 2, 2)
-        assert part.prompts == whole.prompts[2:]
-        assert ("expand_decode_chunk", STEPS, CAPACITY) \
-            in set(engine.executable_keys())
-        assert ATTENTION.summary()["latent_absorbed"] == 1
-        stats = EXPANDER.summary()
+    def check_spans(self, by_name, sizes, one):
+        (prefill,) = by_name["expand.prefill"]
+        assert prefill["sequences"] == 4    # whose first tokens it draws
+        assert prefill["latent"] == "latent_expanded"
+        assert prefill["form"] == "chunked" and prefill["padded"] == 59
+        (fork,) = by_name["expand.fork"]
+        assert fork["latent"] == "latent_forked"
+        assert fork["delta"] == "recurrent_forked"
+        assert fork["state_bytes_copied"] == 16 * STATE
+        # one latent layer's own rows of 64 slots a sequence and four
+        # copies of every state, float32
+        assert fork["bytes"] == 4 * 2 * STEPS * 24 * 4 + 16 * STATE
+        assert [(a["latent"], a["delta"])
+                for a in by_name["expand.decode_chunk"]] \
+            == [("latent_forked", "recurrent_forked")] * 2
+        hits = [a for a in by_name["expand.prefix_copy"] if a.get("hit")]
+        assert hits and hits[0]["bytes"] == sum(one.values())
+
+    def check_one_image(self, sites, stats):
+        assert sites["latent_absorbed"] == 1
         assert stats["delta_mixers"]["recurrent"] == 4
-        assert stats["fork_bytes_copied"] == 2 * 4 * (4 * 8 * 8 + 3 * 64) * 4
-        ATTENTION.clear()
+        assert stats["fork_bytes_copied"] == 2 * 4 * STATE
 
     def test_the_status_keys(self, engine):
         summary = METRICS.summary()["expander"]
@@ -881,17 +496,6 @@ class TestEnginePath:
 
 
 # -- (f) the published share, from shapes -------------------------------------
-
-def _published_shapes(share):
-    return jax.eval_shape(lambda: lm.DecoderLM(share).init(
-        jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-        jnp.int32(4), lm.empty_cache(share, 8, jnp.float32)))["params"]
-
-
-def _count(tree):
-    return sum(int(np.prod(x.shape))
-               for x in jax.tree_util.tree_leaves(tree))
-
 
 class TestThePublishedShare:
     def test_parameters_and_bytes_from_shapes(self):
@@ -912,25 +516,25 @@ class TestThePublishedShare:
                 whole.swiglu_limit, whole.norm_sigmoid_scale,
                 whole.linear_sigmoid_gate_scale) == (2.5, 1e-20, 10, 2, 2)
         assert moe_kernel.f_tile(7168, 2048, 2) == 256
-        shapes = _published_shapes(share)
-        delta = _count(shapes["layers_2"]["delta"])
+        shapes = contract.param_shapes(share)
+        delta = count(shapes["layers_2"]["delta"])
         assert round(delta / 1e6, 1) == 235.9
         attn = shapes["layers_1"]["attn"]
-        gate = _count(attn["g_proj"])
+        gate = count(attn["g_proj"])
         assert gate == 7168 * 64 * 128 and round(gate / 1e6, 1) == 58.7
-        assert round((_count(attn) - gate) / 1e6, 1) == 101.1
+        assert round((count(attn) - gate) / 1e6, 1) == 101.1
         mlp = shapes["layers_1"]["mlp"]
-        assert _count(mlp["experts"]) == 16 * 44_040_192
+        assert count(mlp["experts"]) == 16 * 44_040_192
         assert round(16 * 44.040192, 1) == 704.6
-        assert _count(mlp["shared_expert"]) == 44_040_192
+        assert count(mlp["shared_expert"]) == 44_040_192
         assert mlp["router"].shape == (7168, 256)
-        assert round(_count(shapes["layers_0"]["mlp"]) / 1e6, 1) == 396.4
-        assert _count(shapes["embed_tokens"]) == _count(shapes["lm_head"]) \
+        assert round(count(shapes["layers_0"]["mlp"]) / 1e6, 1) == 396.4
+        assert count(shapes["embed_tokens"]) == count(shapes["lm_head"]) \
             == 7168 * 16032
-        assert round(_count(shapes["layers_0"]) / 1e6, 1) == 632.3
-        assert round(_count(shapes["layers_1"]) / 1e6, 1) == 910.4
-        assert round(_count(shapes["layers_2"]) / 1e6, 1) == 986.4
-        total = _count(shapes)
+        assert round(count(shapes["layers_0"]) / 1e6, 1) == 632.3
+        assert round(count(shapes["layers_1"]) / 1e6, 1) == 910.4
+        assert round(count(shapes["layers_2"]) / 1e6, 1) == 986.4
+        total = count(shapes)
         assert round(total / 1e6) == 4732
         assert round(total * 2 / 1e9, 2) == 9.46
         # beside SD1.5's 1 066 M: 11.60 GB = 10.80 GiB
@@ -940,9 +544,9 @@ class TestThePublishedShare:
         assert round((total - 4 * 8 * 44_040_192) / 1e6) == 3322
         # the whole model from the same shapes: 432 B, 28 B a token's
         linear_layer = delta + 4 * 7168
-        latent_layer = _count(attn) + 4 * 7168
+        latent_layer = count(attn) + 4 * 7168
         experts = 256 * 44_040_192 + 44_040_192 + 7168 * 256 + 256
-        dense = _count(shapes["layers_0"]["mlp"])
+        dense = count(shapes["layers_0"]["mlp"])
         published = (30 * linear_layer + 10 * latent_layer + 3 * dense
                      + 37 * experts + 2 * 7168 * 128256 + 7168)
         # (the two next-token modules, left out, are the rest of 432 B)
@@ -967,25 +571,20 @@ class TestThePublishedShare:
         delta mixers a recurrent step a sequence, one forked latent site
         over 2 560 shared and 256 own rows of 576."""
         share = configs.sd15_gigachat35_expander().expander
-        module = lm.DecoderLM(share, dtype=jnp.bfloat16)
-        s = jax.ShapeDtypeStruct
-        one = {name: [s(shape, lm.buffer_dtype(name, jnp.bfloat16))
-                      for shape in rows]
-               for name, rows in lm.cache_shapes(share, 2560).items()}
-        cache = jax.eval_shape(lambda c: kv.fork(c, 4, 256), one)
+        one = contract.cache_structs(share, 2560)
+        cache = contract.forked_structs(share, 2560, 4, 256)
         assert [x.shape for x in cache["latent_shared"]] == [(2560, 576)]
         assert [x.shape for x in cache["latent"]] == [(4, 256, 576)]
         assert [(x.shape, x.dtype) for x in cache["state"]] \
             == [((4, 64, 128, 128), jnp.float32)] * 4
         assert [x.shape for x in cache["conv"]] == [(4, 3, 16384)] * 4
-        shapes = {"params": _published_shapes(share)}
+        shapes = contract.param_shapes(share)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         ATTENTION.clear()
         EXPANDER.clear()
-        logits, after, routed = jax.eval_shape(
-            lambda v, c: module.apply(v, jnp.zeros((4,), jnp.int32),
-                                      jnp.int32(2200), jnp.int32(4), c,
-                                      sequences=True), shapes, cache)
+        logits, after, routed = contract.sites_of(
+            share, shapes, jnp.zeros((4,), jnp.int32), 2200, 4,
+            cache, jnp.bfloat16, sequences=True)
         assert logits.shape == (4, 16032)
         assert jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), after) \
             == jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), cache)
@@ -999,10 +598,9 @@ class TestThePublishedShare:
             "T4 S2560+256 D576": {"latent_forked": 1}}
         # a prefill chunk keeps the grouped product, the chunk-wise rule
         # and the expanded form
-        jax.eval_shape(
-            lambda v, c: module.apply(v, jnp.zeros((64,), jnp.int32),
-                                      jnp.int32(2048), jnp.int32(64), c),
-            shapes, one)
+        contract.sites_of(
+            share, shapes, jnp.zeros((64,), jnp.int32), 2048, 64,
+            one, jnp.bfloat16)
         stats = EXPANDER.summary()
         assert stats["expert_products"]["grouped"] == 4
         assert stats["delta_mixers"]["chunked"] == 4

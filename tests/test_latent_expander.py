@@ -14,9 +14,6 @@ float32, no cache, no chunks, expanded attention only).
 """
 
 import dataclasses
-import importlib.util
-import os
-import zlib
 
 import jax
 import jax.numpy as jnp
@@ -31,77 +28,22 @@ from stable_diffusion_webui_distributed_tpu.ops import (
 from stable_diffusion_webui_distributed_tpu.ops.attention import (
     attend_positions,
 )
-from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
-from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
-    GenerationPayload,
-)
-from stable_diffusion_webui_distributed_tpu.runtime import dtypes
-from stable_diffusion_webui_distributed_tpu.runtime.interrupt import (
-    GenerationState,
-)
 from stable_diffusion_webui_distributed_tpu.serving.metrics import (
     ATTENTION, EXPANDER, METRICS,
 )
-from tests.test_pipeline import init_params
+from tests import expander_contract as contract
+from tests.expander_contract import ROOT, empty, rel_rms, run
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-REF = _load(os.path.join(ROOT, "benchmarks", "reference", "xing4_ref.py"),
-            "xing4_ref_for_tests")
-FAMILY = configs.TINY_LATENT_EXPAND
-CFG = FAMILY.expander
-
-
-def lm_params(cfg, seed=0):
-    """``DecoderLM.init``'s tree with the norms off 1 and the leaves that
-    start at zero (the selection bias, the mixers' ``b_pre`` and
-    ``b_post``) drawn, so that reading one as another would show."""
-    module = lm.DecoderLM(cfg)
-    params = module.init(
-        jax.random.key(seed), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-        jnp.int32(4), lm.empty_cache(cfg, 8, jnp.float32))["params"]
-    key = jax.random.key(seed + 100)
-    spread = {"scale": 0.2, "e_score_correction_bias": 0.1, "b_pre": 0.3,
-              "b_post": 0.3, "alpha": 0.2}
-
-    def off(path, x):
-        name = getattr(path[-1], "key", "")
-        if name not in spread:
-            return x
-        return x + spread[name] * jax.random.normal(
-            jax.random.fold_in(key, zlib.crc32(str(path).encode()) % 2 ** 31),
-            x.shape)
-
-    return jax.tree_util.tree_map_with_path(off, params)
-
-
-@pytest.fixture(scope="module")
-def params():
-    return lm_params(CFG)
-
-
-def rel_rms(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / np.sqrt(np.mean(want ** 2)))
-
-
-def run(params, ids, start, length, cache, cfg=CFG, **kw):
-    return lm.DecoderLM(cfg).apply(
-        {"params": params}, ids, jnp.int32(start), jnp.int32(length), cache,
-        **kw)
-
-
-def empty(capacity=64, cfg=CFG):
-    return lm.empty_cache(cfg, capacity, jnp.float32)
+REF = contract.load_reference("xing4")
+#: the norms off 1 and the leaves that start at zero (the selection bias,
+#: the mixers' ``b_pre`` and ``b_post``) drawn
+CASE = contract.Case(
+    configs.TINY_LATENT_EXPAND, REF,
+    how=(("spread", (("scale", 0.2), ("e_score_correction_bias", 0.1),
+                     ("b_pre", 0.3), ("b_post", 0.3), ("alpha", 0.2))),),
+    word="rule")
+FAMILY, CFG = CASE.family, CASE.cfg
+params, engine = contract.fixtures(CASE)
 
 
 def lm_params_of_a_mixer(mixer, streams, stored, at_the_clamp=False):
@@ -119,57 +61,33 @@ def stream_maps(cfg, p, streams):
 
 # -- program against reference ------------------------------------------------
 
-class TestAgainstTheReference:
-    @pytest.mark.parametrize("size", [40, 200])
-    def test_one_chunk_matches_the_full_forward(self, params, size):
-        (ids,) = REF.inputs(FAMILY, 3, size)
-        got, _, routed = jax.jit(lambda p, i: run(
-            p, i, 0, size, empty(kv.capacity_for(size))))(params, ids)
-        want, own = jax.jit(lambda p, i: REF.forward(
-            FAMILY, p, i, with_routing=True))(params, ids)
-        assert got.shape == want.shape == (size, CFG.vocab[1])
-        assert rel_rms(got, want) < 1e-5
-        assert np.array_equal(np.sort(routed[0], -1), np.sort(own, -1))
-
-    @pytest.mark.parametrize("size", [40, 200])
-    def test_prefill_then_decode_through_the_latent_cache(self, params,
-                                                          size):
-        """Prefix prefill (expanded), the user chunk against a copy of the
-        snapshot, then one token a step (absorbed) through the cache,
-        against the reference's one full forward."""
-        (ids,) = REF.inputs(FAMILY, 3, size)
-        got, chose = jax.jit(REF.program(FAMILY, dtypes.F32,
-                                         with_routing=True))(params, ids)
-        want, own = jax.jit(lambda p, i: REF.forward(
-            FAMILY, p, i, with_routing=True))(params, ids)
-        assert rel_rms(got, want) < 1e-5
-        assert np.array_equal(np.sort(chose, -1), np.sort(own, -1))
+class TestAgainstTheReference(contract.OneChunkAgainstTheReference,
+                              contract.OneSequenceAgainstTheReference):
+    """Prefix prefill (expanded), the user chunk against a copy of the
+    snapshot, then one token a step (absorbed) through the cache."""
+    CASE = CASE
+    DROPPED = "latent"
+    test_prefill_then_decode_through_the_latent_cache = \
+        contract.OneSequenceAgainstTheReference \
+        .prefill_then_decode_matches_the_full_forward
+    test_a_cache_that_is_dropped_shows = \
+        contract.OneSequenceAgainstTheReference.a_buffer_that_is_dropped_shows
+    PARAMETERS = {
+        "test_one_chunk_matches_the_full_forward": [("size", [40, 200])],
+        "test_prefill_then_decode_through_the_latent_cache": [
+            ("size", [40, 200])]}
 
     def test_the_int8_control_is_further_from_the_reference(self, params):
-        (ids,) = REF.inputs(FAMILY, 3, 40)
-        want = REF.forward(FAMILY, params, ids)
-        program = jax.jit(REF.program(FAMILY, dtypes.F32))(params, ids)
-        control = jax.jit(REF.program(FAMILY, dtypes.F32, control=True))(
-            params, ids)
+        (ids,), want, _ = CASE.referred(40)
+        program = CASE.program()(params, ids)
+        control = CASE.program(control=True)(params, ids)
         assert rel_rms(control, want) > 1e-3 > 100 * rel_rms(program, want)
 
     @pytest.mark.parametrize("lower", ["stream_dtype", "sinkhorn_dtype"])
     def test_streams_or_sinkhorn_in_bfloat16_show(self, params, lower):
-        (ids,) = REF.inputs(FAMILY, 3, 40)
-        want = REF.forward(FAMILY, params, ids)
-        got = jax.jit(REF.program(FAMILY, dtypes.F32,
-                                  **{lower: jnp.bfloat16}))(params, ids)
+        (ids,), want, _ = CASE.referred(40)
+        got = CASE.program(**{lower: jnp.bfloat16})(params, ids)
         assert rel_rms(got, want) > 1e-3
-
-    def test_a_cache_that_is_dropped_shows(self, params):
-        (ids,) = REF.inputs(FAMILY, 3, 40)
-        want = REF.forward(FAMILY, params, ids)
-        _, cache, _ = run(params, ids[:30], 0, 30, empty())
-        kept, _, _ = run(params, ids[30:31], 30, 1, cache)
-        cache["latent"] = [jnp.zeros_like(c) for c in cache["latent"]]
-        dropped, _, _ = run(params, ids[30:31], 30, 1, cache)
-        assert rel_rms(kept, want[30:31]) < 1e-5
-        assert rel_rms(dropped, want[30:31]) > 1e-2
 
 
 # -- latent attention's two forms ---------------------------------------------
@@ -184,16 +102,18 @@ class TestTheTwoForms:
         """One real token at position 30: as a chunk of one (absorbed) and
         as the first row of a padded chunk of two (expanded)."""
         (ids,) = REF.inputs(FAMILY, 4, 32)
-        _, cache, _ = run(params, ids[:30], 0, 30, empty())
+        _, cache, _ = run(CFG, params, ids[:30], 0, 30, empty(CFG))
         ATTENTION.clear()
-        absorbed, cache_a, _ = run(params, ids[30:31], 30, 1, cache,
-                                   all_logits=False)
+        contract.sites_of(CFG, params, ids[30:31], 30, 1, cache)
         assert ATTENTION.summary()["latent_absorbed"] == CFG.num_layers
         ATTENTION.clear()
-        expanded, cache_e, _ = run(params, ids[30:32], 30, 1, cache,
-                                   all_logits=False)
+        contract.sites_of(CFG, params, ids[30:32], 30, 1, cache)
         assert ATTENTION.summary()["latent_expanded"] == CFG.num_layers
         assert "latent_absorbed" not in ATTENTION.summary()
+        absorbed, cache_a, _ = run(CFG, params, ids[30:31], 30, 1, cache,
+                                   all_logits=False)
+        expanded, cache_e, _ = run(CFG, params, ids[30:32], 30, 1, cache,
+                                   all_logits=False)
         np.testing.assert_allclose(absorbed, expanded, rtol=1e-5, atol=1e-5)
         for a, e in zip(cache_a["latent"], cache_e["latent"]):
             np.testing.assert_allclose(a[:31], e[:31], rtol=1e-5, atol=1e-5)
@@ -203,7 +123,7 @@ class TestTheTwoForms:
         """Against the reference's own arithmetic for layer 0, whose input
         is the embedding read through the first mixer."""
         (ids,) = REF.inputs(FAMILY, 4, 12)
-        _, cache, _ = run(params, ids, 0, 12, empty())
+        _, cache, _ = run(CFG, params, ids, 0, 12, empty(CFG))
         rows = cache["latent"][0]
         assert rows.shape == (64, CFG.kv_lora_rank + CFG.qk_rope_head_dim)
         assert not np.any(np.asarray(rows[12:]))
@@ -257,13 +177,15 @@ class TestTheTwoForms:
 
 # -- padding, chunks, snapshots -----------------------------------------------
 
-class TestPaddingAndSnapshots:
+class TestPaddingAndSnapshots(contract.PaddingAndSnapshots):
+    CASE = CASE
+
     def test_a_chunked_prefill_gives_what_one_chunk_gives(self, params):
         (ids,) = REF.inputs(FAMILY, 5, 48)
-        whole, cache_w, _ = run(params, ids, 0, 48, empty())
-        first, cache, _ = run(params, ids[:20], 0, 20, empty())
-        second, cache, _ = run(params, ids[20:36], 20, 16, cache)
-        third, cache, _ = run(params, ids[36:], 36, 12, cache)
+        whole, cache_w, _ = run(CFG, params, ids, 0, 48, empty(CFG))
+        first, cache, _ = run(CFG, params, ids[:20], 0, 20, empty(CFG))
+        second, cache, _ = run(CFG, params, ids[20:36], 20, 16, cache)
+        third, cache, _ = run(CFG, params, ids[36:], 36, 12, cache)
         np.testing.assert_allclose(
             jnp.concatenate([first, second, third]), whole, rtol=2e-5,
             atol=2e-5)
@@ -275,55 +197,28 @@ class TestPaddingAndSnapshots:
         whatever the pad rows wrote beyond ``end`` is overwritten before
         any query can see it."""
         (ids,) = REF.inputs(FAMILY, 5, 24)
-        exact, cache_a, _ = run(params, ids[:19], 0, 19, empty(),
+        exact, cache_a, _ = run(CFG, params, ids[:19], 0, 19, empty(CFG),
                                 all_logits=False)
         garbage = ids.at[19:].set(CFG.vocab[0] + 1)
-        padded, cache_b, _ = run(params, garbage, 0, 19, empty(),
+        padded, cache_b, _ = run(CFG, params, garbage, 0, 19, empty(CFG),
                                  all_logits=False)
         np.testing.assert_allclose(exact, padded, rtol=2e-5, atol=2e-5)
         assert np.any(np.asarray(cache_b["latent"][0][19:24]))
         for step in range(19, 22):
-            a, cache_a, _ = run(params, ids[step:step + 1], step, 1, cache_a)
-            b, cache_b, _ = run(params, ids[step:step + 1], step, 1, cache_b)
-            np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
-
-    def test_snapshot_plus_prompt_equals_one_whole_prefill(self, params):
-        (ids,) = REF.inputs(FAMILY, 7, 48)
-        whole, _, _ = run(params, ids, 0, 48, empty())
-        first, snapshot, _ = run(params, ids[:31], 0, 31, empty())
-        copy = jax.tree_util.tree_map(jnp.copy, snapshot)
-        rest, _, _ = run(params, ids[31:], 31, 17, copy)
-        np.testing.assert_allclose(jnp.concatenate([first, rest]), whole,
-                                   rtol=5e-5, atol=5e-5)
-        again, _, _ = run(params, ids[31:], 31, 17, snapshot)
-        np.testing.assert_array_equal(again, rest)
-
-    def test_decoding_cut_into_chunks_equals_one_scan(self, params):
-        module = lm.DecoderLM(CFG)
-        key = jax.random.key(11)
-        first = jnp.int32(CFG.vocab[0] + 3)
-
-        def decode(steps, calls):
-            fn = jax.jit(lm.decode_chunk_fn(module, steps),
-                         donate_argnums=(1,))
-            cache = empty(128)
-            token, position, made = first, jnp.int32(0), []
-            for _ in range(calls):
-                cache, token, position, out, _, _ = fn(
-                    params, cache, token, position, key, jnp.float32(1.0))
-                made += np.asarray(out).tolist()
-            return made, cache
-
-        one, cache_one = decode(64, 1)
-        cut, cache_cut = decode(32, 2)
-        assert one == cut and len(set(one)) > 8
-        for a, b in zip(cache_one["latent"], cache_cut["latent"]):
+            a, cache_a, _ = run(CFG, params, ids[step:step + 1], step, 1,
+                                cache_a)
+            b, cache_b, _ = run(CFG, params, ids[step:step + 1], step, 1,
+                                cache_b)
             np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
 
 
 # -- the cache manager --------------------------------------------------------
 
-class TestTheCacheManager:
+class TestTheCacheManager(contract.TheCacheManager):
+    CASE = CASE
+    test_a_snapshot_is_handed_out_as_a_copy = \
+        contract.TheCacheManager.a_snapshot_is_handed_out_as_a_copy
+
     def test_a_latent_layer_has_one_buffer(self):
         assert lm.buffers_of(lm.LATENT) == ("latent",)
         assert lm.cache_shapes(CFG, 256) == {"latent": [(256, 24)] * 4}
@@ -353,19 +248,6 @@ class TestTheCacheManager:
         assert round(sizes["latent"] / 1e6, 1) == 23.6
         # against every head's keys (192) and values (128): 14 times less
         assert 32 * (192 + 128) / 576 > 14
-
-    def test_a_snapshot_is_handed_out_as_a_copy(self):
-        manager = kv.KVCacheManager(CFG, jnp.float32)
-        cache, held = manager.acquire([1, 2, 3], 256)
-        assert held == 0 and manager.snapshots == 0
-        manager.keep_prefix([1, 2, 3], 256,
-                            jax.tree_util.tree_map(lambda x: x + 1, cache))
-        again, held = manager.acquire([1, 2, 3], 256)
-        assert held == 3 and manager.snapshots == 1
-        assert float(again["latent"][3][7, 5]) == 1.0
-        again["latent"][3] = again["latent"][3] + 1
-        assert float(manager.acquire([1, 2, 3], 256)[0]["latent"][3][7, 5]) \
-            == 1.0
 
 
 # -- the residual streams -----------------------------------------------------
@@ -413,7 +295,7 @@ class TestTheStreams:
         q_pos = jnp.arange(20, dtype=jnp.int32)
         got, _, routed = lm.DecoderLayer(CFG, 3).apply(
             {"params": p}, streams, q_pos, jnp.int32(0), jnp.int32(20),
-            (empty(32)["latent"][3],))
+            (empty(CFG, 32)["latent"][3],))
         assert got.shape == (20, 4, 32) and got.dtype == jnp.float32
         np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
         assert np.array_equal(np.sort(routed[0], -1), np.sort(chosen, -1))
@@ -425,22 +307,18 @@ class TestTheStreams:
         old = configs.TINY_EXPAND.expander
         assert (old.residual_streams, old.router_scoring,
                 old.router_bias) == (1, "softmax", False)
-        init = lambda cfg: lm.DecoderLM(cfg).init(     # noqa: E731
-            jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-            jnp.int32(4), lm.empty_cache(cfg, 8, jnp.float32))["params"]
-        p = init(old)
+        p = contract.lm_params(old)
         assert set(p["layers_1"]) == {"attn", "input_norm", "mlp",
                                       "post_attention_norm"}
         assert set(p["layers_1"]["mlp"]) == {"router", "experts",
                                              "shared_expert"}
         biased = dataclasses.replace(old, router_bias=True)
-        q = init(biased)
+        q = contract.lm_params(biased)
         assert float(jnp.abs(
             q["layers_1"]["mlp"]["e_score_correction_bias"]).max()) == 0.0
         ids = jax.random.randint(jax.random.key(1), (24,), *old.vocab)
-        a, cache_a, routed_a = run(p, ids, 0, 24, empty(32, old), cfg=old)
-        b, cache_b, routed_b = run(q, ids, 0, 24, empty(32, biased),
-                                   cfg=biased)
+        a, cache_a, routed_a = run(old, p, ids, 0, 24, empty(old, 32))
+        b, cache_b, routed_b = run(biased, q, ids, 0, 24, empty(biased, 32))
         np.testing.assert_array_equal(a, b)
         np.testing.assert_array_equal(routed_a[0], routed_b[0])
         for x, y in zip(jax.tree_util.tree_leaves(cache_a),
@@ -450,15 +328,12 @@ class TestTheStreams:
     def test_one_stream_traces_the_plain_residual(self):
         """No stream axis, no mixer's op, no sigmoid in the router."""
         old = configs.TINY_EXPAND.expander
-        text = str(jax.make_jaxpr(lambda p, i: run(
-            p, i, 0, 4, empty(8, old), cfg=old))(
-                jax.eval_shape(lambda: lm.DecoderLM(old).init(
-                    jax.random.key(0), jnp.zeros((4,), jnp.int32),
-                    jnp.int32(0), jnp.int32(4), empty(8, old)))["params"],
-                jnp.zeros((4,), jnp.int32)))
+        ids = jnp.zeros((4,), jnp.int32)
+        text = str(jax.make_jaxpr(lambda p: run(
+            old, p, ids, 0, 4, empty(old, 8)))(contract.param_shapes(old)))
         assert "f32[4,4,32]" not in text and "logistic" in text  # SiLU only
-        latent = str(jax.make_jaxpr(lambda p, i: run(
-            p, i, 0, 4, empty(8)))(lm_params(CFG), jnp.zeros((4,), jnp.int32)))
+        latent = str(jax.make_jaxpr(lambda p: run(
+            CFG, p, ids, 0, 4, empty(CFG, 8)))(contract.param_shapes(CFG)))
         assert "f32[4,4,32]" in latent
 
 
@@ -548,18 +423,20 @@ class TestTheMixerKernels:
         drawing at temperature 1.0, whose mixers read what
         ``mixer_operands`` made outside the scan."""
         cfg = dataclasses.replace(CFG, hidden_size=128)
-        p = lm_params(cfg)
+        p = CASE.params(0, cfg)
         module = lm.DecoderLM(cfg)
         ids = jax.random.randint(jax.random.key(1), (12,), *cfg.vocab)
-        _, cache, _ = run(p, ids, 0, 12, empty(32, cfg), cfg=cfg)
-        chunk = lm.decode_chunk_fn(module, 6)
+        _, cache, _ = run(cfg, p, ids, 0, 12, empty(cfg, 32))
         args = (p, cache, ids[3], jnp.int32(12), jax.random.key(7),
                 jnp.float32(1.0))
 
         def both():
-            logits, after, _ = run(p, ids[3:4], 12, 1, cache, cfg=cfg)
+            """Traced anew: the chooser is read when a mixer is traced."""
+            logits, after, _ = jax.jit(lambda p, c: module.apply(
+                {"params": p}, ids[3:4], jnp.int32(12), jnp.int32(1), c))(
+                    p, cache)
             EXPANDER.clear()
-            made = chunk(*args)
+            made = jax.jit(lm.decode_chunk_fn(module, 6))(*args)
             return logits, after, made, EXPANDER.summary()["mixer_products"]
 
         want = both()
@@ -671,7 +548,7 @@ class TestTheShareOfALayer:
         chips' routed parts, with attention, both mixers and the shared
         expert counted once."""
         whole = dataclasses.replace(CFG, experts_held=None, vocab_held=None)
-        p = lm_params(whole, seed=4)["layers_2"]
+        p = CASE.params(4, whole)["layers_2"]
         streams = jax.random.normal(jax.random.key(9), (20, 4, 32))
         want, _, _ = REF.layer_forward(whole, 2, streams, p)
         attention = lambda u: REF._latent_attention(     # noqa: E731
@@ -704,8 +581,9 @@ class TestTheShareOfALayer:
         columns lie side by side: on ids of its own slice a chip's logits
         are the uncut model's columns of that slice."""
         whole = dataclasses.replace(CFG, vocab_held=None)
-        p = lm_params(whole, seed=5)
+        p = CASE.params(5, whole)
         family = dataclasses.replace(FAMILY, expander=whole)
+        forward = jax.jit(lambda p, i: REF.forward(family, p, i))
         table, head = p["embed_tokens"]["embedding"], p["lm_head"]["kernel"]
         ids = jax.random.randint(jax.random.key(6), (16,), 0, 512)
         parts = 0.0
@@ -722,8 +600,8 @@ class TestTheShareOfALayer:
             sliced = dict(p, embed_tokens={"embedding": table[lo:lo + count]},
                           lm_head={"kernel": head[:, lo:lo + count]})
             held = dataclasses.replace(share, experts_held=whole.experts_held)
-            got, _, _ = run(sliced, own, 0, 8, empty(32, held), cfg=held)
-            want = REF.forward(family, p, own)
+            got, _, _ = run(held, sliced, own, 0, 8, empty(held, 32))
+            want = forward(p, own)
             assert got.shape == (8, 128) and want.shape == (8, 512)
             np.testing.assert_allclose(got, want[:, lo:lo + count],
                                        rtol=2e-4, atol=2e-5)
@@ -748,13 +626,7 @@ class TestTheShareOfALayer:
         """Shapes only: latent attention 28.41 M a layer, two mixers 0.72 M
         with their norms, a dense MLP 99.09 M, sixteen experts 176.16 M."""
         share = configs.sd15_xing4_expander().expander
-        shapes = jax.eval_shape(
-            lambda: lm.DecoderLM(share).init(
-                jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-                jnp.int32(4), lm.empty_cache(share, 8, jnp.float32)))
-        count = lambda tree: sum(    # noqa: E731
-            x.size for x in jax.tree_util.tree_leaves(tree))
-        layers = shapes["params"]
+        layers, count = contract.param_shapes(share), contract.count
         assert round(count(layers["layers_0"]["attn"]) / 1e6, 2) == 28.41
         assert round(count(layers["layers_0"]["mlp"]) / 1e6, 2) == 99.09
         assert count(layers["layers_5"]["mlp"]["experts"]) \
@@ -768,7 +640,7 @@ class TestTheShareOfALayer:
 
 # -- the parameter tree, the sharding rules -----------------------------------
 
-class TestTheTreeAndItsRules:
+class TestTheTreeAndItsRules(contract.ShardingRules):
     def test_the_presets_parameter_tree(self, params):
         attn = params["layers_0"]["attn"]
         assert set(attn) == {"q_a_proj", "q_a_norm", "q_b_proj",
@@ -789,131 +661,61 @@ class TestTheTreeAndItsRules:
         assert set(params["layers_2"]["mlp"]) == {
             "router", "e_score_correction_bias", "experts", "shared_expert"}
 
-    def test_sharding_leaves_the_new_leaves_whole(self, params):
+    WHOLE = (("layers_0/attn/q_a_proj/kernel", 2),
+             ("layers_0/attn/q_b_proj/kernel", 2),
+             ("layers_0/attn/kv_a_proj_with_mqa/kernel", 2),
+             ("layers_0/attn/kv_b_proj/kernel", 2),
+             ("layers_0/attn_hc/phi", 2),
+             ("layers_0/mlp_hc/b_res", 2),
+             ("layers_0/mlp_hc/norm/scale", 1),
+             ("layers_2/mlp/e_score_correction_bias", 1))
+    EXPERT_LAYER = 2
+    PLACED_WHOLE = ("layers_1/attn/kv_b_proj/kernel",
+                    "layers_1/attn_hc/phi")
+    test_sharding_leaves_the_new_leaves_whole = contract.ShardingRules.sharding_rules
+
+    def check_placed(self, placed, mesh):
         from jax.sharding import PartitionSpec as P
 
-        from stable_diffusion_webui_distributed_tpu.parallel.sharding import (
-            shard_params, tp_spec_for,
-        )
-
-        for path, ndim in (("layers_0/attn/q_a_proj/kernel", 2),
-                           ("layers_0/attn/q_b_proj/kernel", 2),
-                           ("layers_0/attn/kv_a_proj_with_mqa/kernel", 2),
-                           ("layers_0/attn/kv_b_proj/kernel", 2),
-                           ("layers_0/attn_hc/phi", 2),
-                           ("layers_0/mlp_hc/b_res", 2),
-                           ("layers_0/mlp_hc/norm/scale", 1),
-                           ("layers_2/mlp/e_score_correction_bias", 1)):
-            assert tp_spec_for(path, ndim) == P(), path
-        assert tp_spec_for("layers_2/mlp/experts/w_up", 3) \
-            == P("ep", None, None)
-        devices = np.array(jax.devices()[:4]).reshape(2, 2)
-        mesh = jax.sharding.Mesh(devices, ("ep", "vp"))
-        placed = shard_params(params, mesh)
-        assert placed["layers_2"]["mlp"]["experts"]["w_gate"].sharding.spec \
-            == P("ep", None, None)
-        assert placed["layers_1"]["attn"]["kv_b_proj"]["kernel"] \
-            .sharding.spec == P()
-        assert placed["layers_1"]["attn_hc"]["phi"].sharding.spec == P()
         assert placed["embed_tokens"]["embedding"].sharding.spec \
             == P("vp", None)
 
 
 # -- the engine path ----------------------------------------------------------
 
-INSTRUCTION = " ".join(f"rule{i}" for i in range(30))
+class TestEnginePath(contract.SoloEnginePath):
+    CASE = CASE
+    STATUS_KEYS = frozenset({
+        "state_bytes", "cache_positions", "residual_streams",
+        "sinkhorn_iters", "expert_products", "mixer_products"})
+    test_spans = contract.SoloEnginePath.spans_of_a_request
 
-
-def script(**args):
-    return {"prompt expansion": {"args": [dict(
-        {"instruction": INSTRUCTION, "max_new_tokens": 40,
-         "temperature": 1.0, "ignore_eos": True, "context_chunks": 1},
-        **args)]}}
-
-
-@pytest.fixture(scope="module")
-def engine():
-    family = configs.tiny_xing4_expander()
-    params = init_params(configs.TINY)
-    params["expander"] = lm_params(family.expander, seed=1)
-    return Engine(family, params, chunk_size=4, state=GenerationState())
-
-
-def payload(**kw):
-    base = dict(prompt="a cow in a valley", steps=4, width=32, height=32,
-                seed=1234, alwayson_scripts=script())
-    base.update(kw)
-    return GenerationPayload(**base)
-
-
-class TestEnginePath:
-    def test_a_request_from_the_kept_snapshot_equals_the_first(self, engine):
-        EXPANDER.clear()
-        a = engine.txt2img(payload())       # prefills the instruction
-        b = engine.txt2img(payload())       # starts from its snapshot
-        plain = engine.txt2img(payload(alwayson_scripts={}))
-        assert a.images == b.images and a.prompts == b.prompts
-        assert a.images != plain.images
-        words = a.prompts[0].split()
-        assert len(words) == 45 and len(set(words[5:])) > 8
-        stats = EXPANDER.summary()
-        assert stats["requests"] == 2
-        assert stats["tokens_prefilled"] == 31 + 5 + 5
-        assert stats["tokens_from_prefix_cache"] == 31
+    def check_stats(self, stats):
         assert stats["cache_positions"] == {"full": 0, "sliding": 0,
                                             "latent": 4 * 76}
-        assert stats["prefix_snapshots"] == 1
-        assert stats["state_bytes"] == kv.state_bytes(CFG, 256, jnp.float32)
         assert stats["state_bytes"]["latent"] == 4 * 256 * 24 * 4
         assert (stats["residual_streams"], stats["sinkhorn_iters"]) \
             == (4, 20)
         assert len(stats["expert_tokens"]) == 2        # two expert layers
         assert stats["padded_rows_masked"] == 0        # no recurrence
 
-    def test_another_seed_gets_another_expansion(self, engine):
-        assert engine.txt2img(payload()).prompts \
-            != engine.txt2img(payload(seed=99)).prompts
+    def check_prefill_span(self, args):
+        assert args["latent"] == "latent_expanded"
+        assert "form" not in args       # no recurrence to name
 
-    def test_spans(self, engine):
-        from stable_diffusion_webui_distributed_tpu.obs import spans
-
-        engine.txt2img(payload())           # the snapshot is held from here
-        spans.TRACER.clear()
-        with spans.request("rid-latent"):
-            engine.txt2img(payload())
-        events = [e for e in spans.TRACER.export_chrome()["traceEvents"]
-                  if e.get("ph") == "X"]
-        names = [e["name"] for e in events]
-        for name in ("expand", "expand.prefix_copy", "expand.prefill",
-                     "expand.decode_chunk", "expand.fence_wait", "prepare"):
-            assert name in names, name
-        by_id = {e["args"]["span_id"]: e for e in events}
-        for e in events:
-            if e["name"].startswith("expand."):
-                # the counters come down once the UNet is queued
-                assert by_id[e["args"]["parent_id"]]["name"] == (
-                    "denoise_range" if e["name"] == "expand.account"
-                    else "expand")
-        prefill = next(e for e in events if e["name"] == "expand.prefill")
-        assert prefill["args"]["tokens"] == 5
-        assert prefill["args"]["prefix_hit"] is True
-        assert prefill["args"]["latent"] == "latent_expanded"
-        assert "form" not in prefill["args"]     # no recurrence to name
-        copy = next(e for e in events if e["name"] == "expand.prefix_copy")
-        assert copy["args"]["hit"] is True
-        assert copy["args"]["bytes"] == 4 * 256 * 24 * 4
+    def check_status(self, block):
+        assert set(block["mixer_products"]) == {"kernel", "loop"}
+        # on a CPU, in float32, at these widths every expert layer loops
+        assert block["expert_products"]["kernel"] == 0
 
     def test_sites_are_counted_by_form_when_the_model_is_traced(self):
         """A new engine's first request: the decode scan's body is traced
         once and both prefills share one executable (one bucket, 64)."""
-        family = configs.tiny_xing4_expander()
-        params = init_params(configs.TINY)
-        params["expander"] = lm_params(family.expander, seed=1)
-        fresh = Engine(family, params, chunk_size=4, state=GenerationState())
+        fresh = CASE.engine()
         ATTENTION.clear()
         EXPANDER.clear()
-        fresh.txt2img(payload())
-        fresh.txt2img(payload())
+        fresh.txt2img(CASE.payload())
+        fresh.txt2img(CASE.payload())
         # /internal/status: eight mixers a trace, two traces, none of them
         # the kernel on a CPU
         assert METRICS.summary()["expander"]["mixer_products"] == {
@@ -924,27 +726,10 @@ class TestEnginePath:
         assert sites["by_shape"]["T1 S256 D24"] == {"latent_absorbed": 4}
         assert sites["by_shape"]["T64 S256 D24"] == {"latent_expanded": 4}
 
-    def test_status_block(self, engine):
-        engine.txt2img(payload())
-        block = METRICS.summary()["expander"]
-        assert {"state_bytes", "cache_positions", "residual_streams",
-                "sinkhorn_iters", "expert_products",
-                "mixer_products"} <= set(block)
-        assert set(block["mixer_products"]) == {"kernel", "loop"}
-        assert set(block["state_bytes"]) == {"full", "sliding", "latent"}
-        # on a CPU, in float32, at these widths every expert layer loops
-        assert block["expert_products"]["kernel"] == 0
-
     def test_an_expander_with_one_stream_reports_no_sinkhorn(self):
-        old = configs.TINY_EXPAND
-        params = init_params(configs.TINY)
-        params["expander"] = lm.DecoderLM(old.expander).init(
-            jax.random.key(1), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-            jnp.int32(4), lm.empty_cache(old.expander, 8, jnp.float32))[
-                "params"]
-        engine = Engine(old, params, chunk_size=4, state=GenerationState())
+        engine = contract.engine_for(configs.TINY_EXPAND)
         EXPANDER.clear()
-        engine.txt2img(payload())
+        engine.txt2img(CASE.payload())
         stats = EXPANDER.summary()
         assert (stats["residual_streams"], stats["sinkhorn_iters"]) == (1, 0)
         assert set(stats["state_bytes"]) == {"full", "sliding"}

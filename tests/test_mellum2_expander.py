@@ -13,11 +13,6 @@ length of 16, 8 experts top-2). The plain reference is the benchmark's own
 ring, the window a mask on the whole score matrix).
 """
 
-import functools
-import importlib.util
-import os
-import zlib
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,93 +21,55 @@ import pytest
 from stable_diffusion_webui_distributed_tpu.cache import kv
 from stable_diffusion_webui_distributed_tpu.models import configs, lm
 from stable_diffusion_webui_distributed_tpu.ops import moe
-from stable_diffusion_webui_distributed_tpu.pipeline import expand
-from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
 from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
-    Base64Text, GenerationPayload, prompt_expansion_args,
+    Base64Text, prompt_expansion_args,
 )
 from stable_diffusion_webui_distributed_tpu.runtime import dtypes
-from stable_diffusion_webui_distributed_tpu.runtime.interrupt import (
-    GenerationState,
-)
 from stable_diffusion_webui_distributed_tpu.serving.metrics import (
-    ATTENTION, EXPANDER, METRICS,
+    ATTENTION, EXPANDER,
 )
-from tests.test_pipeline import init_params
+from tests import expander_contract as contract
+from tests.expander_contract import (
+    CAPACITY, STEPS, assert_own_rows, rel_rms, run,
+)
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-REF = _load(os.path.join(ROOT, "benchmarks", "reference", "mellum2_ref.py"),
-            "mellum2_ref_for_tests")
-FAMILY = configs.TINY_WINDOW_EXPAND
-CFG = FAMILY.expander
-STEPS = expand.DECODE_STEPS
+REF = contract.load_reference("mellum2")
+#: the norms off 1, so that reading one as another would show
+CASE = contract.Case(
+    configs.TINY_WINDOW_EXPAND, REF, how=(("spread", (("scale", 0.2),)),),
+    control_floor=1e-2, control_size=74)
+FAMILY, CFG = CASE.family, CASE.cfg
+params, engine = contract.fixtures(CASE)
 
 
-def lm_params(cfg, seed=0):
-    """``DecoderLM.init``'s tree with the norms off 1, so that reading one
-    as another would show."""
-    params = lm.DecoderLM(cfg).init(
-        jax.random.key(seed), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-        jnp.int32(4), lm.empty_cache(cfg, 8, jnp.float32))["params"]
-    key = jax.random.key(seed + 100)
-
-    def off(path, x):
-        if getattr(path[-1], "key", "") != "scale":
-            return x
-        return x + 0.2 * jax.random.normal(
-            jax.random.fold_in(key, zlib.crc32(str(path).encode()) % 2 ** 31),
-            x.shape)
-
-    return jax.tree_util.tree_map_with_path(off, params)
-
-
-@pytest.fixture(scope="module")
-def params():
-    return lm_params(CFG)
-
-
-def rel_rms(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / np.sqrt(np.mean(want ** 2)))
+def prefilled(params):
+    """One chunk of 21 positions, none padded: the rings of 8 have
+    wrapped."""
+    return contract.prefilled(CFG, params, 21, prefix=0, capacity=256,
+                              bucket=21)
 
 
 # -- (a) program against reference --------------------------------------------
 
-class TestAgainstTheReference:
-    @pytest.mark.parametrize("size", [37, 74])
+class TestAgainstTheReference(contract.ForkedAgainstTheReference):
+    """The int8 linears, the windows attending everything, the windows
+    under the full layers' table and the aliased rings each read far from
+    the reference where the program reads 1e-6: the second and the fourth
+    only because the context is over the window."""
+    CASE = CASE
+    PARAMETERS = {
+        "test_chunks_fork_and_decode_match_four_full_forwards": [
+            ("size", [37, 74])],
+        "test_each_control_is_further_from_the_reference": [
+            ("control", [name for name, _ in REF.CONTROLS])]}
+
     def test_chunks_fork_and_decode_match_four_full_forwards(self, params,
                                                              size):
         """The prefix as one chunk four and eight times the window (the
-        rings wrap as often), a copy, the prompt's chunk, a fork into four
-        and one step over all four a position, against a full forward of
-        each whole sequence: logits to 1e-5, routing identical."""
-        prefix, user, decoded = REF.split(size)
+        rings wrap as often): logits to 1e-5, routing identical."""
+        prefix, _, decoded = REF.split(size)
         assert prefix >= 4 * CFG.sliding_window and decoded >= 4
-        ids, continuations = REF.inputs(FAMILY, 3, size)
-        got, chose = jax.jit(REF.program(FAMILY, dtypes.F32,
-                                         with_routing=True))(
-            params, ids, continuations)
-        want, own = jax.jit(lambda p, i, c: REF.forward(
-            FAMILY, p, i, c, with_routing=True))(params, ids, continuations)
-        rows = prefix + user + REF.SEQUENCES * decoded
-        assert got.shape == want.shape == (rows, CFG.vocab[1])
-        assert got.dtype == want.dtype == jnp.float32
-        assert rel_rms(got, want) < 1e-5
-        assert np.array_equal(np.sort(chose, -1), np.sort(own, -1))
-        # the four continuations part at their first row
-        tails = np.asarray(got[prefix + user:]).reshape(
-            REF.SEQUENCES, decoded, -1)
-        assert rel_rms(tails[1], tails[0]) > 0.1
+        self.program_matches_four_full_forwards(params, size)
 
     @pytest.mark.parametrize("control", [None, "aliased_rings"])
     def test_the_two_executables_give_what_the_one_gives(self, params,
@@ -122,40 +79,20 @@ class TestAgainstTheReference:
         and routing as the one jitted whole."""
         ids, continuations = REF.inputs(FAMILY, 3, 74)
         kwargs = dict(REF.CONTROLS)[control] if control else {}
-        whole, chose = jax.jit(REF.program(
-            FAMILY, dtypes.F32, with_routing=True, **kwargs))(
-                params, ids, continuations)
+        whole, chose = CASE.program(with_routing=True, **kwargs)(
+            params, ids, continuations)
         got, chose_staged = REF.staged(FAMILY, dtypes.F32, params, ids,
                                        continuations, **kwargs)
         np.testing.assert_allclose(got, whole, rtol=1e-6, atol=1e-6)
         assert np.array_equal(chose, chose_staged)
-
-    @pytest.mark.parametrize("control", [name for name, _ in REF.CONTROLS])
-    def test_each_control_is_further_from_the_reference(self, params,
-                                                        control):
-        """The int8 linears, the windows attending everything, the windows
-        under the full layers' table and the aliased rings each read far
-        from the reference where the program reads 1e-6: the second and
-        the fourth only because the context is over the window."""
-        ids, continuations = REF.inputs(FAMILY, 3, 74)
-        want = jax.jit(lambda p, i, c: REF.forward(FAMILY, p, i, c))(
-            params, ids, continuations)
-        lower = jax.jit(REF.program(
-            FAMILY, dtypes.F32, **dict(REF.CONTROLS)[control]))(
-                params, ids, continuations)
-        assert rel_rms(lower, want) > 1e-2
 
     def test_a_context_inside_the_window_hides_two_of_the_controls(
             self, params):
         """What the old traffic would have measured: at 7 positions the
         window of 8 never binds, and a ring taken for a full buffer reads
         as the program does."""
-        ids, continuations = REF.inputs(FAMILY, 3, 7)
-        want = jax.jit(lambda p, i, c: REF.forward(FAMILY, p, i, c))(
-            params, ids, continuations)
-        lower = jax.jit(REF.program(FAMILY, dtypes.F32,
-                                    windows_attend_all=True))(
-            params, ids, continuations)
+        inputs, want, _ = CASE.referred(7)
+        lower = CASE.program(windows_attend_all=True)(params, *inputs)
         assert rel_rms(lower, want) < 1e-5
 
     def test_yarn_from_its_five_numbers(self):
@@ -182,192 +119,48 @@ class TestAgainstTheReference:
 
 # -- (b) a step over B sequences ----------------------------------------------
 
-def _prefilled(params, length=21, capacity=256):
-    ids = jax.random.randint(jax.random.key(5), (length,), 0, 512)
-    logits, cache, _ = lm.DecoderLM(CFG).apply(
-        {"params": params}, ids, jnp.int32(0), jnp.int32(length),
-        lm.empty_cache(CFG, capacity, jnp.float32), all_logits=False)
-    return logits[0], cache, length
-
-
-def _keys(indices, seed=77):
-    from stable_diffusion_webui_distributed_tpu.runtime import rng
-
-    return jnp.stack([rng.key_for_image(seed, i) for i in indices])
-
-
-def forked_shapes(cfg, capacity, sequences, own_slots,
-                  dtype=jnp.bfloat16):
-    """The shapes of a forked cache, as ``kv.fork`` lays it out."""
-    return jax.eval_shape(
-        lambda c: kv.fork(c, sequences, own_slots),
-        {name: [jax.ShapeDtypeStruct(shape, dtype) for shape in rows]
-         for name, rows in lm.cache_shapes(cfg, capacity).items()})
-
-
-def assert_own_rows(cfg, alone, forked, b, first, steps):
-    """Sequence ``b``'s own rows of a forked cache against the cache of
-    that sequence decoded alone for ``steps`` positions from ``first``:
-    position ``p`` lies in its own slot ``(p - first) % slots``, and alone
-    in slot ``p`` of a buffer or ``p % window`` of a ring (which keeps the
-    last ``window`` only)."""
-    for name in ("k", "v"):
-        for kind, mine, theirs in zip(cfg.layer_types, alone[name],
-                                      forked[name]):
-            window = mine.shape[-3] if kind == "sliding" else 0
-            positions = np.arange(first + steps - min(steps, window or steps),
-                                  first + steps)
-            np.testing.assert_allclose(
-                np.take(np.asarray(mine),
-                        positions % window if window else positions,
-                        axis=-3),
-                np.take(np.asarray(theirs[b]),
-                        (positions - first) % theirs.shape[-3], axis=-3),
-                rtol=2e-5, atol=2e-5)
-
-
-@functools.lru_cache(maxsize=None)
-def _executables(cfg):
-    """(the one-sequence decode chunk, the several-sequences one, a step
-    of each that returns its logits), jitted once a config."""
-    module = lm.DecoderLM(cfg)
-
-    def one_step(params, cache, token, position):
-        return module.apply({"params": params}, token[None], position,
-                            jnp.int32(1), cache)[:2]
-
-    def forked_step(params, cache, tokens, position, live):
-        return module.apply({"params": params}, tokens, position, live,
-                            cache, sequences=True)[:2]
-
-    return (jax.jit(lm.decode_chunk_fn(module, STEPS)),
-            jax.jit(lm.decode_sequences_fn(module, STEPS)),
-            jax.jit(one_step), jax.jit(forked_step))
-
-
-def forked_against_alone(cfg, params, user, live, batch, prefix=21):
-    """A prefix's chunk, then a prompt of ``user`` real tokens in its
-    padded chunk (the bucket's other rows land behind the prompt in every
-    buffer, where a forked step must not see them), then ``batch``
-    sequences forked from that one prefill against each of the ``live``
-    decoded alone from the same cache by the one-sequence executable: a
-    chunk of steps token for token, and the logits of a few teacher-forced
-    steps after it."""
-    module = lm.DecoderLM(cfg)
-    alone, together, one_step, forked_step = _executables(cfg)
-    bucket = kv.chunk_bucket(user)
-    capacity = kv.capacity_for(prefix + bucket + 2 * STEPS)
-    ids = jax.random.randint(jax.random.key(user), (prefix + bucket,), 0,
-                             512)
-    _, cache, _ = module.apply(
-        {"params": params}, ids[:prefix], jnp.int32(0), jnp.int32(prefix),
-        lm.empty_cache(cfg, capacity, jnp.float32), all_logits=False)
-    row, cache, _ = module.apply(
-        {"params": params}, ids[prefix:], jnp.int32(prefix),
-        jnp.int32(user), cache, all_logits=False)
-    length = prefix + user
-    keys = _keys(list(range(live)) + [live - 1] * (batch - live))
-    first = lm.sample_each(row[0], keys, length, jnp.float32(1.0))
-    forked, tokens, position, made, *_ = together(
-        params, kv.fork(cache, batch, 2 * STEPS), first, jnp.int32(length),
-        keys, jnp.float32(1.0), jnp.int32(live))
-    assert int(position) == length + STEPS
-    # the shared rows are the prefill's, untouched
-    for name, shared in zip(lm.ATTENTION_BUFFERS, lm.SHARED_BUFFERS):
-        for mine, theirs in zip(cache[name], forked[shared]):
-            assert np.array_equal(np.asarray(mine), np.asarray(theirs))
-    assert int(forked[lm.FORKED_AT][0][0, 0]) == length
-    own = []
-    for b in range(live):
-        after, last, _, steps, *_ = alone(
-            params, cache, first[b], jnp.int32(length), keys[b],
-            jnp.float32(1.0))
-        assert np.array_equal(steps, made[:, b]), b
-        assert int(last) == int(tokens[b])
-        assert_own_rows(cfg, after, forked, b, length, STEPS)
-        own.append(after)
-    assert len({tuple(np.asarray(made[:, b])) for b in range(live)}) == live
-    forced = jax.random.randint(jax.random.key(8), (4, batch), 0, 512)
-    for t, row in enumerate(forced):
-        at = jnp.int32(length + STEPS + t)
-        logits, forked = forked_step(params, forked, row, at,
-                                     jnp.int32(live))
-        for b in range(live):
-            want, own[b] = one_step(params, own[b], row[b], at)
-            np.testing.assert_allclose(logits[b], want[0], rtol=1e-5,
-                                       atol=1e-5)
-
-
-class TestSequencesOfOneStep:
-    @pytest.mark.parametrize("user", [1, 16, 63, 64])
-    @pytest.mark.parametrize("live,batch", [(2, 2), (4, 4), (3, 4)])
-    def test_a_forked_decode_is_each_sequence_alone(self, params, user,
-                                                    live, batch):
-        """At the chunk bucket's edges: a prompt of 1 leaves 63 padded
-        rows behind the fork, one of 64 none. The prefix's 21 positions
-        have wrapped the rings of 8."""
-        forked_against_alone(CFG, params, user, live, batch)
+class TestSequencesOfOneStep(contract.SequencesOfOneStep,
+                             contract.WhichKindsShareAStep):
+    """At the chunk bucket's edges: a prompt of 1 leaves 63 padded rows
+    behind the fork, one of 64 none. The prefix's 21 positions have
+    wrapped the rings of 8."""
+    CASE = CASE
+    PARAMETERS = {
+        "test_a_forked_decode_is_each_sequence_alone": [
+            ("live,batch", [(2, 2), (4, 4), (3, 4)]),
+            ("user", [1, 16, 63, 64])],
+        "test_which_kinds_share_a_step": [("preset,shares", [
+            ("TINY_WINDOW_EXPAND", True), ("TINY_EXPAND", True),
+            ("TINY_DELTA_EXPAND", True), ("TINY_LATENT_EXPAND", False),
+            ("TINY_CONV_EXPAND", False)])]}
 
     @pytest.mark.parametrize("live,batch", [(1, 1), (2, 2), (4, 4), (3, 4)])
     def test_each_sequence_gets_what_it_gets_alone(self, params, live,
                                                    batch):
-        """A chunk of steps over ``batch`` sequences (``live`` of them
-        real, the pad a repeat of the last) against the one-sequence chunk
-        run once a key: the same tokens, the same rows in the cache, and a
-        load that leaves the pad out."""
-        module = lm.DecoderLM(CFG)
-        row, cache, length = _prefilled(params)
-        keys = _keys(list(range(live)) + [live - 1] * (batch - live))
-        first = lm.sample_each(row, keys, length, jnp.float32(1.0))
-        alone = jax.jit(lm.decode_chunk_fn(module, STEPS))
-        together = jax.jit(lm.decode_sequences_fn(module, STEPS))
-        forked, tokens, position, made, load, none_held, read = together(
-            params, kv.fork(cache, batch), first, jnp.int32(length), keys,
-            jnp.float32(1.0), jnp.int32(live))
-        assert made.shape == (STEPS, batch) and int(position) == length + STEPS
-        total = 0
-        for b in range(live):
-            token = lm.sample(row, keys[b], length, jnp.float32(1.0))
-            assert int(token) == int(first[b])
-            own, last, _, steps, own_load, _ = alone(
-                params, cache, token, jnp.int32(length), keys[b],
-                jnp.float32(1.0))
-            assert np.array_equal(steps, made[:, b])
-            assert int(last) == int(tokens[b])
-            assert_own_rows(CFG, own, forked, b, length, STEPS)
-            total = total + own_load
-        assert np.array_equal(load, total)      # the pad is not counted
-        assert int(none_held.sum()) == 0
-        # sequences part from their first token on
-        assert len({tuple(np.asarray(made[:, b])) for b in range(live)}) \
-            == live
-        # distinct experts a step: never over the picks, never under one
-        # sequence's two a layer
-        layers, k = len(CFG.expert_layers), CFG.num_experts_per_tok
-        assert np.all(read >= STEPS * k) and np.all(
-            read <= STEPS * min(live * k, CFG.num_experts))
-        assert int(read.sum()) <= int(load.sum())
-        if live == 1:
-            assert int(read.sum()) == STEPS * k * layers
+        """From one chunk of 21 positions, none padded, and with a
+        buffer's own count of slots a sequence: the same tokens, the same
+        rows in the cache, a load that leaves the pad out, and never more
+        distinct experts a step than picks."""
+        contract.forked_against_alone(CASE, params, live, batch, own_slots=0,
+                                      user=21, prefix=0, capacity=256,
+                                      bucket=21)
 
     def test_a_step_gives_each_sequence_the_logits_it_gets_alone(
             self, params):
         """Teacher-forced on continuations that differ: every sequence's
         logits at every step, and both ring and buffer rows."""
-        module = lm.DecoderLM(CFG)
-        _, cache, length = _prefilled(params)
+        _, cache, length = prefilled(params)
         forced = jax.random.randint(jax.random.key(8), (12, 4), 0, 512)
         together = kv.fork(cache, 4)
         alone = [cache] * 4
         for t in range(12):
-            logits, together, routed = module.apply(
-                {"params": params}, forced[t], jnp.int32(length + t),
-                jnp.int32(4), together, sequences=True)
+            logits, together, routed = run(
+                CFG, params, forced[t], length + t, 4, together,
+                sequences=True)
             assert logits.shape == (4, CFG.vocab[1])
             for b in range(4):
-                want, alone[b], own = module.apply(
-                    {"params": params}, forced[t, b][None],
-                    jnp.int32(length + t), jnp.int32(1), alone[b])
+                want, alone[b], own = run(
+                    CFG, params, forced[t, b][None], length + t, 1, alone[b])
                 np.testing.assert_allclose(logits[b], want[0], rtol=1e-5,
                                            atol=1e-5)
                 assert np.array_equal(np.sort(routed[0][:, b], -1),
@@ -375,58 +168,17 @@ class TestSequencesOfOneStep:
         # 21 + 12 positions through rings of 8: alone every slot is
         # rewritten, forked the ring is as the prefill left it
         for b in range(4):
-            assert_own_rows(CFG, alone[b], together, b, length, 12)
+            assert_own_rows(alone[b], together, b, length, 12)
         for mine, theirs in zip(cache["k"], together["k_shared"]):
-            assert mine is theirs
+            assert np.array_equal(np.asarray(mine), np.asarray(theirs))
 
-    def test_a_fork_copies_nothing(self, params):
-        """(c): the shared buffers ARE the prefill's, rings and buffers
-        alike; what is made is a few rows a sequence and the position,
-        not yet known."""
-        _, cache, _ = _prefilled(params)
-        forked = kv.fork(cache, 4, 2 * STEPS)
-        assert set(forked) == {"k", "v", "k_shared", "v_shared",
-                               "forked_at"}
-        for name, shared in zip(("k", "v"), ("k_shared", "v_shared")):
-            assert all(mine is theirs for mine, theirs
-                       in zip(cache[name], forked[shared]))
-            assert [x.shape for x in forked[shared]] == \
-                [(8, 2, 8)] * 3 + [(256, 2, 8)]
-            assert [x.shape for x in forked[name]] == [(4, 64, 2, 8)] * 4
-            assert not any(np.any(np.asarray(x)) for x in forked[name])
-        (at,) = forked["forked_at"]
-        assert at.shape == (4, 1) and np.all(np.asarray(at) == -1)
-        # what the engine's fork executable makes: the same, from shapes
-        made = jax.jit(lambda c: kv.own_rows(c, 4, 2 * STEPS))(cache)
-        again = kv.forked(cache, made)
-        assert jax.tree_util.tree_structure(again) \
-            == jax.tree_util.tree_structure(forked)
-        assert all(mine is theirs
-                   for mine, theirs in zip(cache["v"], again["v_shared"]))
-        # without a count of slots a sequence gets a buffer's own
-        assert [x.shape for x in kv.fork(cache, 2)["k"]] == \
-            [(2, 8, 2, 8)] * 3 + [(2, 256, 2, 8)]
+    test_a_fork_copies_nothing = contract.SequencesOfOneStep \
+        .a_fork_shares_what_has_positions_and_copies_the_rest
 
     @pytest.mark.parametrize("images,bucket", [
         (1, 1), (2, 2), (3, 4), (4, 4), (5, 8), (8, 8), (11, 8)])
     def test_sequence_buckets(self, images, bucket):
         assert kv.sequence_bucket(images) == bucket
-
-    @pytest.mark.parametrize("preset,shares", [
-        ("TINY_WINDOW_EXPAND", True), ("TINY_EXPAND", True),
-        ("TINY_DELTA_EXPAND", True), ("TINY_LATENT_EXPAND", False),
-        ("TINY_CONV_EXPAND", False)])
-    def test_which_kinds_share_a_step(self, preset, shares):
-        cfg = getattr(configs, preset).expander
-        assert lm.shares_a_step(cfg) is shares
-        if not shares:
-            with pytest.raises(ValueError):
-                jax.eval_shape(
-                    lambda: lm.DecoderLM(cfg).init(
-                        jax.random.key(0), jnp.zeros((2,), jnp.int32),
-                        jnp.int32(0), jnp.int32(2),
-                        lm.empty_cache(cfg, 8, jnp.float32),
-                        sequences=True))
 
     def test_bytes_and_positions_times_sequences(self):
         manager = kv.KVCacheManager(CFG, jnp.bfloat16)
@@ -461,32 +213,6 @@ class TestSequencesOfOneStep:
 
 # -- the engine's path ----------------------------------------------------------
 
-INSTRUCTION = " ".join(f"word{i}" for i in range(30))
-
-
-def script(**args):
-    return {"prompt expansion": {"args": [dict(
-        {"instruction": INSTRUCTION, "max_new_tokens": 40,
-         "temperature": 1.0, "ignore_eos": True, "context_chunks": 1},
-        **args)]}}
-
-
-@pytest.fixture(scope="module")
-def engine():
-    params = init_params(configs.TINY)
-    params["expander"] = lm_params(CFG, seed=1)
-    return Engine(FAMILY, params, chunk_size=4, state=GenerationState())
-
-
-def payload(**kw):
-    base = dict(prompt="a cow in a valley", steps=4, width=32, height=32,
-                seed=1234, alwayson_scripts=script())
-    base.update(kw)
-    return GenerationPayload(**base)
-
-
-CAPACITY = kv.capacity_for(31 + 64 + 2 * STEPS)
-
 
 class TestOneImageTakesTheOneSequencePath:
     def test_executable_key_cache_and_lowered_text(self, engine):
@@ -495,7 +221,7 @@ class TestOneImageTakesTheOneSequencePath:
         cache with no sequence axis, the ``loop`` product on a CPU, and the
         decode function's lowered text."""
         EXPANDER.clear()
-        engine.txt2img(payload())
+        engine.txt2img(CASE.payload())
         keys = {k for k in engine.executable_keys()
                 if k[0].startswith("expand")}
         assert keys == {("expand_prefill", 64, CAPACITY),
@@ -525,31 +251,33 @@ class TestOneImageTakesTheOneSequencePath:
         assert batched is not engine.expander._decode_fn(CAPACITY)
 
     def test_a_one_image_expand_batch_is_expand(self, engine):
-        args = prompt_expansion_args(payload())
+        args = prompt_expansion_args(CASE.payload())
         one = engine.expander.expand("a cow in a valley", args, 1234, 0)
         assert engine.expander.expand_batch(
             "a cow in a valley", args, 1234, [0]) == [one]
-        assert one == engine.txt2img(payload()).prompts[0]
+        assert one == engine.txt2img(CASE.payload()).prompts[0]
 
 
-class TestABatchOfImages:
+class TestABatchOfImages(contract.ForkedEnginePath):
+    CASE, KEYS = CASE, None
+
     def test_every_image_its_own_expansion_in_any_range(self, engine):
         """(d): images 2-3 of a four-image request get what they get in
         the whole request and what four one-image requests with seeds
         ``s + i`` give."""
-        whole = engine.txt2img(payload(batch_size=4))
+        whole = engine.txt2img(CASE.payload(batch_size=4))
         assert len(set(whole.prompts)) == 4
-        part = engine.generate_range(payload(batch_size=4), 2, 2)
+        part = engine.generate_range(CASE.payload(batch_size=4), 2, 2)
         assert part.prompts == whole.prompts[2:]
         assert part.images == whole.images[2:]
         # four PNGs go back as the encoder's own base64, which the server
         # copies into the response unread (server/api.py:json_body)
         assert all(type(png) is Base64Text for png in whole.images)
         for i in range(4):
-            solo = engine.txt2img(payload(seed=1234 + i))
+            solo = engine.txt2img(CASE.payload(seed=1234 + i))
             assert solo.prompts[0] == whole.prompts[i], i
         # three images: a batch of four whose fourth repeats the third
-        three = engine.txt2img(payload(batch_size=3))
+        three = engine.txt2img(CASE.payload(batch_size=3))
         assert three.prompts == whole.prompts[:3]
         keys = {k for k in engine.executable_keys()
                 if k[0] == "expand_decode_chunk"}
@@ -557,22 +285,10 @@ class TestABatchOfImages:
                         ("expand_decode_chunk", STEPS, CAPACITY, 2),
                         ("expand_decode_chunk", STEPS, CAPACITY, 4)}
 
-    def test_one_prefill_and_four_sequences_a_step(self, engine):
-        """Tentpole 5: the counters and the spans of a four-image
-        request."""
-        from stable_diffusion_webui_distributed_tpu.obs import spans
+    test_one_prefill_and_four_sequences_a_step = contract.ForkedEnginePath \
+        .a_batch_prefills_once_forks_and_decodes_four_a_step
 
-        engine.txt2img(payload(batch_size=4))       # the snapshot is kept
-        EXPANDER.clear()
-        spans.TRACER.clear()
-        with spans.request("rid-m2"):
-            engine.txt2img(payload(batch_size=4))
-        stats = METRICS.summary()["expander"]
-        assert stats["requests"] == 1 and stats["sequences"] == 4
-        assert stats["tokens_prefilled"] == 5       # the prompt, once
-        assert stats["tokens_from_prefix_cache"] == 31
-        assert stats["tokens_decoded"] == 4 * 40
-        assert stats["decode_steps"] == 2 * STEPS
+    def check_counted(self, stats, sizes, one):
         picks = 2 * STEPS * 4 * 2       # steps x layers x k, one sequence
         assert picks <= stats["experts_read"] <= min(4 * picks,
                                                      2 * STEPS * 4 * 8)
@@ -587,43 +303,22 @@ class TestABatchOfImages:
         assert stats["rows_attended"] == sum(4 * (p + 1) for p in steps)
         assert stats["rows_read"] == sum(36 + 4 * (p + 1 - 36)
                                          for p in steps)
-        assert stats["state_bytes"] == kv.state_bytes(
-            CFG, CAPACITY, jnp.float32, 4, 2 * STEPS)
-        events = [e for e in spans.TRACER.export_chrome()["traceEvents"]
-                  if e.get("ph") == "X"]
-        by_name = {}
-        for e in events:
-            by_name.setdefault(e["name"], []).append(e["args"])
-        assert [a["sequences"] for a in by_name["expand"]] == [4]
-        assert [a["tokens"] for a in by_name["expand.prefill"]] == [5]
+
+    def check_spans(self, by_name, sizes, one):
+        # four layers' keys and values of 64 slots a sequence, float32;
+        # rings and buffers stay where they are
         (fork,) = by_name["expand.fork"]
-        assert fork["sequences"] == 4
-        # the bytes a fork makes: four layers' keys and values of 64
-        # slots a sequence, float32; rings and buffers stay where they are
-        assert fork["bytes"] == 4 * 2 * 4 * 2 * STEPS * 2 * 8 * 4 \
-            == sum(stats["state_bytes"].values()) - sum(kv.state_bytes(
-                CFG, CAPACITY, jnp.float32).values())
-        assert [a["sequences"] for a in by_name["expand.decode_chunk"]] \
-            == [4, 4]
-        by_id = {e["args"]["span_id"]: e for e in events}
-        for e in events:
-            if e["name"].startswith("expand."):
-                # the counters come down once the UNet is queued
-                assert by_id[e["args"]["parent_id"]]["name"] == (
-                    "denoise_range" if e["name"] == "expand.account"
-                    else "expand")
+        assert fork["bytes"] == 4 * 2 * 4 * 2 * STEPS * 2 * 8 * 4
 
     def test_the_decode_trace_takes_the_grouped_product(self):
         """A fresh engine's four-image request traces one prefill chunk
         (31 and 5 tokens both pad to 64: one executable at one sequence,
         one that draws four first tokens) and the four-sequence scan: all
         three through the grouped product, four expert layers each."""
-        params = init_params(configs.TINY)
-        params["expander"] = lm_params(CFG, seed=1)
-        fresh = Engine(FAMILY, params, chunk_size=4, state=GenerationState())
+        fresh = CASE.engine()
         EXPANDER.clear()
         ATTENTION.clear()
-        fresh.txt2img(payload(batch_size=4))
+        fresh.txt2img(CASE.payload(batch_size=4))
         stats = EXPANDER.summary()
         assert stats["expert_products"] == {"kernel": 0, "loop": 0,
                                             "grouped": 12}
@@ -643,15 +338,15 @@ class TestABatchOfImages:
 
     def test_same_seed_images_are_expanded_once(self, engine):
         EXPANDER.clear()
-        out = engine.txt2img(payload(batch_size=3, same_seed=True))
+        out = engine.txt2img(CASE.payload(batch_size=3, same_seed=True))
         assert len(set(out.prompts)) == 1
-        assert out.prompts[0] == engine.txt2img(payload()).prompts[0]
+        assert out.prompts[0] == engine.txt2img(CASE.payload()).prompts[0]
         assert EXPANDER.summary()["sequences"] == 2     # 1 + the solo
 
     def test_a_prompt_matrix_groups_by_text(self, engine):
         """Images whose prompts differ are groups of their own, in the
         order of their first image."""
-        request = payload(batch_size=4, all_prompts=[
+        request = CASE.payload(batch_size=4, all_prompts=[
             "a cow in a valley", "a red fox", "a cow in a valley",
             "a red fox"])
         EXPANDER.clear()
@@ -661,14 +356,14 @@ class TestABatchOfImages:
         assert out.prompts[0].startswith("a cow in a valley")
         assert out.prompts[1].startswith("a red fox")
         assert out.prompts[2] == engine.txt2img(
-            payload(seed=1236)).prompts[0]
+            CASE.payload(seed=1236)).prompts[0]
         assert out.prompts[3] == engine.txt2img(
-            payload(prompt="a red fox", seed=1237)).prompts[0]
+            CASE.payload(prompt="a red fox", seed=1237)).prompts[0]
 
     def test_eos_cuts_one_sequence_and_an_interrupt_ends_all(
             self, engine, monkeypatch):
         """(e), on the tokens the stage makes."""
-        args = prompt_expansion_args(payload(alwayson_scripts=script(
+        args = prompt_expansion_args(CASE.payload(alwayson_scripts=CASE.script(
             max_new_tokens=3 * STEPS, context_chunks=None)))
         stage = engine.expander
         images = [0, 1, 2, 3]
@@ -718,14 +413,7 @@ class TestThePublishedShare:
         assert whole.layers_of("full") == tuple(range(3, 28, 4))
         assert share.experts == (0, 64) and share.vocab == (0, 98304)
         assert whole.intermediate_size == 8 * whole.moe_intermediate_size
-        shapes = jax.eval_shape(lambda: lm.DecoderLM(share).init(
-            jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-            jnp.int32(4), lm.empty_cache(share, 8, jnp.float32)))["params"]
-
-        def count(tree):
-            return sum(int(np.prod(x.shape))
-                       for x in jax.tree_util.tree_leaves(tree))
-
+        shapes, count = contract.param_shapes(share), contract.count
         layer = shapes["layers_0"]
         assert count(layer["attn"]) == 21_233_664
         assert layer["mlp"]["router"].shape == (2304, 64)
@@ -760,17 +448,13 @@ class TestThePublishedShare:
         weights or FLOPs: eight expert layers through the grouped
         product, six ring sites and two buffer sites."""
         share = configs.sd15_mellum2_expander().expander
-        module = lm.DecoderLM(share, dtype=jnp.bfloat16)
-        cache = forked_shapes(share, 2560, 4, 256)
-        shapes = jax.eval_shape(lambda: module.init(
-            jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-            jnp.int32(4), lm.empty_cache(share, 8, jnp.float32)))
+        cache = contract.forked_structs(share, 2560, 4, 256)
+        shapes = contract.param_shapes(share)
         ATTENTION.clear()
         EXPANDER.clear()
-        logits, after, routed = jax.eval_shape(
-            lambda v, c: module.apply(v, jnp.zeros((4,), jnp.int32),
-                                      jnp.int32(2200), jnp.int32(4), c,
-                                      sequences=True), shapes, cache)
+        logits, after, routed = contract.sites_of(
+            share, shapes, jnp.zeros((4,), jnp.int32), 2200, 4,
+            cache, jnp.bfloat16, sequences=True)
         assert logits.shape == (4, 98304)
         assert [x.shape for x in after["k"]] == [x.shape for x in cache["k"]]
         assert routed[0].shape == (8, 4, 8) and routed[1].shape == (8, 64)
@@ -794,27 +478,19 @@ class TestThePublishedShare:
         the whole block of rows, as the cell's ``m2_expert_kernel_sites``
         reads it, and a prefill chunk keeps the grouped product."""
         share = configs.sd15_mellum2_expander().expander
-        module = lm.DecoderLM(share, dtype=jnp.bfloat16)
-        s = jax.ShapeDtypeStruct
-        cache = forked_shapes(share, 2560, sequences, 256)
-        shapes = jax.eval_shape(lambda: module.init(
-            jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-            jnp.int32(4), lm.empty_cache(share, 8, jnp.float32)))
+        cache = contract.forked_structs(share, 2560, sequences, 256)
+        shapes = contract.param_shapes(share)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         EXPANDER.clear()
         tokens = jnp.zeros((sequences,), jnp.int32)
-        jax.eval_shape(
-            lambda v, c: module.apply(v, tokens, jnp.int32(2200),
-                                      jnp.int32(sequences), c,
-                                      sequences=True), shapes, cache)
+        contract.sites_of(share, shapes, tokens, 2200, sequences, cache,
+                          jnp.bfloat16, sequences=True)
         assert EXPANDER.summary()["expert_products"] == {
             "kernel": 8, "loop": 0, "grouped": 0}
-        one = {name: [s(shape, jnp.bfloat16) for shape in rows]
-               for name, rows in lm.cache_shapes(share, 2560).items()}
-        jax.eval_shape(
-            lambda v, c: module.apply(v, jnp.zeros((64,), jnp.int32),
-                                      jnp.int32(2048), jnp.int32(64), c),
-            shapes, one)
+        one = contract.cache_structs(share, 2560)
+        contract.sites_of(
+            share, shapes, jnp.zeros((64,), jnp.int32), 2048, 64,
+            one, jnp.bfloat16)
         assert EXPANDER.summary()["expert_products"] == {
             "kernel": 8, "loop": 0, "grouped": 8}
         ATTENTION.clear()

@@ -15,11 +15,6 @@ the three forms of the delta rule at strengths up to 2 and unequal widths;
 its spans, counters and Prometheus families; (f) the published share from
 shapes."""
 
-import functools
-import importlib.util
-import os
-import zlib
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,135 +24,58 @@ from stable_diffusion_webui_distributed_tpu.cache import kv
 from stable_diffusion_webui_distributed_tpu.models import configs, lm
 from stable_diffusion_webui_distributed_tpu.obs import prometheus
 from stable_diffusion_webui_distributed_tpu.ops import delta_rule
-from stable_diffusion_webui_distributed_tpu.pipeline import expand
-from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
-from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
-    GenerationPayload,
-)
 from stable_diffusion_webui_distributed_tpu.runtime import dtypes
-from stable_diffusion_webui_distributed_tpu.runtime.interrupt import (
-    GenerationState,
-)
 from stable_diffusion_webui_distributed_tpu.serving.metrics import (
     ATTENTION, EXPANDER, METRICS,
 )
-from tests.test_pipeline import init_params
+from tests import expander_contract as contract
+from tests.expander_contract import CAPACITY, STEPS, count, rel_rms
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-REF = _load(os.path.join(ROOT, "benchmarks", "reference",
-                         "olmo_hybrid_ref.py"), "olmo_hybrid_ref_for_tests")
-FAMILY = configs.TINY_OLMO_HYBRID_EXPAND
-CFG = FAMILY.expander
-STEPS = expand.DECODE_STEPS
-#: decay rates from a token to hundreds, as the benchmark seeds them
-A_LOG = (-3.0, -0.5, 2.0)
+REF = contract.load_reference("olmo_hybrid")
+#: every norm's scale off 1 (deviation 0.5), the decay rates spread from a
+#: token to hundreds, as the benchmark seeds them, and ``dt_bias`` off 1
+CASE = contract.Case(
+    configs.TINY_OLMO_HYBRID_EXPAND, REF,
+    how=(("spread", (("scale", 0.5), ("dt_bias", 0.3))),
+         ("a_log", (-3.0, -0.5, 2.0))),
+    extra="with_beta", tolerance=1e-4, rows_tolerance=3e-4,
+    step_tolerance=3e-4,
+    controls=("control", "state_bf16", "sigmoid_beta", "state_shared",
+              "qk_norm_per_head", "rotary", "full_pre_normed",
+              "linear_post_normed", "both_normed"))
+FAMILY, CFG = CASE.family, CASE.cfg
 #: one sequence's state and kept rows in one linear layer, float32
 STATE = (3 * 6 * 10 + 3 * (2 * 3 * 6 + 3 * 10)) * 4
 LINEAR_LAYERS, FULL_LAYERS = 6, 2
-
-
-@functools.lru_cache(maxsize=None)
-def lm_params(cfg, seed=0):
-    """``DecoderLM.init``'s tree with every norm's scale off 1 (deviation
-    0.5), the decay rates spread and ``dt_bias`` off 1, so that a norm on
-    the wrong side, a shared state or a norm of another extent would
-    show."""
-    params = jax.jit(lambda key: lm.DecoderLM(cfg).init(
-        key, jnp.zeros((4,), jnp.int32), jnp.int32(0), jnp.int32(4),
-        lm.empty_cache(cfg, 8, jnp.float32)))(jax.random.key(seed))["params"]
-    key = jax.random.key(seed + 100)
-
-    def off(path, x):
-        name = getattr(path[-1], "key", "")
-        noise = jax.random.normal(
-            jax.random.fold_in(key, zlib.crc32(str(path).encode()) % 2 ** 31),
-            x.shape)
-        if name == "scale":
-            return x + 0.5 * noise
-        if name == "dt_bias":
-            return x + 0.3 * noise
-        if name == "A_log":
-            return jnp.asarray(A_LOG, jnp.float32)
-        return x
-
-    return jax.tree_util.tree_map_with_path(off, params)
-
-
-@pytest.fixture(scope="module")
-def params():
-    return lm_params(CFG)
-
-
-def rel_rms(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / np.sqrt(np.mean(want ** 2)))
-
-
-@functools.lru_cache(maxsize=None)
-def _reference(size):
-    """(ids, continuations, the reference's logits, the largest write
-    strength it saw) at ``size`` positions, the tiny preset's weights."""
-    ids, continuations = REF.inputs(FAMILY, 3, size)
-    want, beta = jax.jit(lambda p, i, c: REF.forward(
-        FAMILY, p, i, c, with_beta=True))(lm_params(CFG), ids, continuations)
-    return ids, continuations, want, float(beta)
+params, engine = contract.fixtures(CASE)
 
 
 # -- (a) program against reference --------------------------------------------
 
-class TestAgainstTheReference:
-    @pytest.mark.parametrize("size", [37, 148])
-    def test_prefill_fork_and_decode_match_four_full_forwards(self, params,
-                                                              size):
-        """The prefix as one chunk (chunk-wise delta rule from a zero
-        state, full attention over what it wrote), a copy, the prompt's
-        chunk, a fork into four and one step over all four a position (a
-        recurrent step a sequence, two ranges of keys under one softmax),
-        against a full forward of each whole sequence: logits to 1e-4."""
-        prefix, user, decoded = REF.split(size)
-        ids, continuations, want, beta = _reference(size)
-        got = jax.jit(REF.program(FAMILY, dtypes.F32))(
-            params, ids, continuations)
-        rows = prefix + user + REF.SEQUENCES * decoded
-        assert got.shape == want.shape == (rows, CFG.vocab[1])
-        assert got.dtype == want.dtype == jnp.float32
-        assert rel_rms(got, want) < 1e-4
+class TestAgainstTheReference(contract.ForkedAgainstTheReference):
+    """Chunk-wise delta rule from a zero state and full attention over
+    what it wrote, a copy, a fork into four and a recurrent step a
+    sequence with two ranges of keys under one softmax: logits to 1e-4.
+    Int8 linears, the state in bfloat16, ``sigmoid(b)`` for ``2
+    sigmoid(b)``, one state shared by the sequences, the query and key
+    norms per head, a rotary table applied, the full layers pre-normed,
+    the linear layers post-normed, both kinds normed both ways: each
+    misses ten times over the tolerance the program meets."""
+    CASE = CASE
+    PROGRAM = {}
+    test_prefill_fork_and_decode_match_four_full_forwards = \
+        contract.ForkedAgainstTheReference.program_matches_four_full_forwards
+    PARAMETERS = {
+        "test_prefill_fork_and_decode_match_four_full_forwards": [
+            ("size", [37, 148])],
+        "test_each_control_is_further_from_the_reference": [
+            ("control", [name for name, _ in REF.CONTROLS])]}
+
+    def check_extra(self, got, want, rows):
         # the reference wrote with strengths over 1: half the tokens turn
         # an eigenvalue of the transition negative
-        assert 1.5 < beta < 2.0
-        # the four continuations part at their first row
-        tails = np.asarray(got[prefix + user:]).reshape(
-            REF.SEQUENCES, decoded, -1)
-        assert rel_rms(tails[1], tails[0]) > 0.1
-
-    @pytest.mark.parametrize("control", [name for name, _ in REF.CONTROLS])
-    def test_each_control_is_further_from_the_reference(self, params,
-                                                        control):
-        """Int8 linears, the state in bfloat16, ``sigmoid(b)`` for ``2
-        sigmoid(b)``, one state shared by the sequences, the query and key
-        norms per head, a rotary table applied, the full layers
-        pre-normed, the linear layers post-normed, both kinds normed both
-        ways: each misses ten times over the tolerance (1e-4) the program
-        meets."""
-        ids, continuations, want, _ = _reference(148)
-        lower = jax.jit(REF.program(
-            FAMILY, dtypes.F32, **dict(REF.CONTROLS)[control]))(
-                params, ids, continuations)
-        assert rel_rms(lower, want) > 1e-3, control
-        assert [name for name, _ in REF.CONTROLS] == [
-            "control", "state_bf16", "sigmoid_beta", "state_shared",
-            "qk_norm_per_head", "rotary", "full_pre_normed",
-            "linear_post_normed", "both_normed"]
+        (beta,) = want
+        assert 1.5 < float(beta) < 2.0
 
     def test_the_reference_held_to_the_programs_operand_precision(self):
         """The second limit's reading: with bfloat16 matmul operands the
@@ -168,7 +86,7 @@ class TestAgainstTheReference:
         policy = dtypes.Policy(param_dtype=jnp.dtype(jnp.bfloat16))
         assert policy.compute_dtype == jnp.bfloat16
         stored = jax.tree_util.tree_map(
-            lambda x: x.astype(jnp.bfloat16), lm_params(CFG))
+            lambda x: x.astype(jnp.bfloat16), CASE.params())
         ids, continuations = REF.inputs(FAMILY, 3, 148)
         want = jax.jit(lambda p, i, c: REF.forward(FAMILY, p, i, c))(
             stored, ids, continuations)
@@ -206,210 +124,16 @@ class TestAgainstTheReference:
 
 # -- (b) a step over B sequences ----------------------------------------------
 
-def _keys(indices, seed=77):
-    from stable_diffusion_webui_distributed_tpu.runtime import rng
+class TestSequencesOfOneStep(contract.SequencesOfOneStep,
+                             contract.StatesOfOneStep):
+    CASE = CASE
+    test_a_snapshot_restores_keys_values_and_states = \
+        contract.StatesOfOneStep.a_snapshot_restores_every_buffer
+    PARAMETERS = {"test_a_forked_decode_is_each_sequence_alone": [
+        ("user,live,batch", [(1, 4, 4), (64, 3, 4)])]}
 
-    return jnp.stack([rng.key_for_image(seed, i) for i in indices])
-
-
-@functools.lru_cache(maxsize=None)
-def _executables(cfg):
-    """(the one-sequence decode chunk, the several-sequences one, a step
-    of each that returns its logits), jitted once a config."""
-    module = lm.DecoderLM(cfg)
-
-    def one_step(params, cache, token, position):
-        return module.apply({"params": params}, token[None], position,
-                            jnp.int32(1), cache)[:2]
-
-    def forked_step(params, cache, tokens, position, live):
-        return module.apply({"params": params}, tokens, position, live,
-                            cache, sequences=True)[:2]
-
-    return (jax.jit(lm.decode_chunk_fn(module, STEPS)),
-            jax.jit(lm.decode_sequences_fn(module, STEPS)),
-            jax.jit(one_step), jax.jit(forked_step))
-
-
-def _prefilled(cfg, params, user, prefix=21, capacity=None):
-    """(the prompt's last row of logits, the cache, its length) after a
-    prefix's chunk and a prompt of ``user`` real tokens in its padded
-    chunk: the bucket's other rows must leave the states and the kept rows
-    where the prompt's last real token put them."""
-    module = lm.DecoderLM(cfg)
-    bucket = kv.chunk_bucket(user)
-    capacity = capacity or kv.capacity_for(prefix + bucket + 2 * STEPS)
-    first, count = cfg.vocab
-    ids = jax.random.randint(jax.random.key(user), (prefix + bucket,),
-                             first, first + count)
-    _, cache, _ = module.apply(
-        {"params": params}, ids[:prefix], jnp.int32(0), jnp.int32(prefix),
-        lm.empty_cache(cfg, capacity, jnp.float32), all_logits=False)
-    row, cache, _ = module.apply(
-        {"params": params}, ids[prefix:], jnp.int32(prefix),
-        jnp.int32(user), cache, all_logits=False)
-    return row[0], cache, prefix + user
-
-
-def assert_own_rows(alone, forked, b, first, steps):
-    """Sequence ``b``'s states, kept rows and own keys and values against
-    the cache of that sequence decoded alone."""
-    for name in lm.LINEAR_BUFFERS:
-        for mine, theirs in zip(alone[name], forked[name]):
-            np.testing.assert_allclose(mine, theirs[b], rtol=3e-4,
-                                       atol=3e-4)
-    positions = np.arange(first, first + steps)
-    for name in lm.ATTENTION_BUFFERS:
-        for mine, theirs in zip(alone[name], forked[name]):
-            np.testing.assert_allclose(
-                np.asarray(mine)[positions],
-                np.asarray(theirs[b])[(positions - first) % theirs.shape[1]],
-                rtol=3e-4, atol=3e-4)
-
-
-class TestSequencesOfOneStep:
-    @pytest.mark.parametrize("user,live,batch", [(1, 4, 4), (64, 3, 4)])
-    def test_a_forked_decode_is_each_sequence_alone(self, params, user,
-                                                    live, batch):
-        """``batch`` sequences forked from one prefill against each of the
-        ``live`` decoded alone from the same cache by the one-sequence
-        executable: a chunk of steps token for token, the states, kept
-        rows, keys and values written, and the logits of a few
-        teacher-forced steps after it. A group of three padded to four
-        leaves the pad's copies as the fork made them."""
-        alone, together, one_step, forked_step = _executables(CFG)
-        row, cache, length = _prefilled(CFG, params, user)
-        keys = _keys(list(range(live)) + [live - 1] * (batch - live))
-        first = lm.sample_each(row, keys, length, jnp.float32(1.0),
-                               CFG.vocab[0])
-        start = kv.fork(cache, batch, 2 * STEPS)
-        forked, tokens, position, made, *_ = together(
-            params, start, first, jnp.int32(length), keys,
-            jnp.float32(1.0), jnp.int32(live))
-        assert int(position) == length + STEPS
-        # the shared keys and values are the prefill's, untouched
-        for name, shared in lm.SHARED_OF.items():
-            for mine, theirs in zip(cache.get(name, ()),
-                                    forked.get(shared, ())):
-                assert np.array_equal(np.asarray(mine), np.asarray(theirs))
-        assert int(forked[lm.FORKED_AT][0][0, 0]) == length
-        own = []
-        for b in range(live):
-            after, last, _, steps, *_ = alone(
-                params, cache, first[b], jnp.int32(length), keys[b],
-                jnp.float32(1.0))
-            assert np.array_equal(steps, made[:, b]), b
-            assert int(last) == int(tokens[b])
-            assert_own_rows(after, forked, b, length, STEPS)
-            own.append(after)
-        for b in range(live, batch):            # a pad's copies stay
-            for name in lm.LINEAR_BUFFERS:
-                for mine, theirs in zip(cache[name], forked[name]):
-                    assert np.array_equal(np.asarray(mine),
-                                          np.asarray(theirs[b]))
-        assert len({tuple(np.asarray(made[:, b])) for b in range(live)}) \
-            == live
-        forced = jax.random.randint(jax.random.key(8), (3, batch),
-                                    *np.cumsum(CFG.vocab))
-        for t, row in enumerate(forced):
-            at = jnp.int32(length + STEPS + t)
-            logits, forked = forked_step(params, forked, row, at,
-                                         jnp.int32(live))
-            for b in range(live):
-                want, own[b] = one_step(params, own[b], row[b], at)
-                np.testing.assert_allclose(logits[b], want[0], rtol=3e-4,
-                                           atol=3e-4)
-
-    def test_a_sequence_that_has_ended_leaves_the_others_alone(self, params):
-        """A sequence goes on being stepped after its end-of-sequence (its
-        tokens are cut afterwards): whatever it is fed, the other
-        sequences' logits, states, kept rows, keys and values are bit for
-        bit what they are beside any other neighbour."""
-        *_, forked_step = _executables(CFG)
-        row, cache, length = _prefilled(CFG, params, 7)
-        tokens = jnp.array([130, 131, 132, 133], jnp.int32) % CFG.vocab[1]
-        results = []
-        for fed in (5, 99):
-            forked = kv.fork(cache, 4, STEPS)
-            for t in range(3):
-                logits, forked = forked_step(
-                    params, forked, tokens.at[2].set(fed + t),
-                    jnp.int32(length + t), jnp.int32(4))
-            results.append((logits, forked))
-        (a, ca), (b, cb) = results
-        others = np.array([0, 1, 3])
-        assert np.array_equal(np.asarray(a)[others], np.asarray(b)[others])
-        assert not np.array_equal(np.asarray(a)[2], np.asarray(b)[2])
-        for name in ("state", "conv", "k", "v"):
-            for mine, theirs in zip(ca[name], cb[name]):
-                assert np.array_equal(np.asarray(mine)[others],
-                                      np.asarray(theirs)[others]), name
-                assert not np.array_equal(np.asarray(mine)[2],
-                                          np.asarray(theirs)[2]), name
-
-    def test_a_fork_shares_every_key_and_value_and_copies_every_state(
-            self, params):
-        _, cache, _ = _prefilled(CFG, params, 5)
-        forked = kv.fork(cache, 4, 2 * STEPS)
-        assert set(forked) == {"k", "v", "k_shared", "v_shared", "state",
-                               "conv", "forked_at"}
-        for name, shared in (("k", "k_shared"), ("v", "v_shared")):
-            assert all(mine is theirs for mine, theirs
-                       in zip(cache[name], forked[shared]))
-            assert [x.shape for x in forked[shared]] \
-                == [(256, 3, 8)] * FULL_LAYERS
-            assert [x.shape for x in forked[name]] \
-                == [(4, 64, 3, 8)] * FULL_LAYERS
-            assert not any(np.any(np.asarray(x)) for x in forked[name])
-        # a state whose widths differ, three heads: no power of two
-        assert [x.shape for x in forked["state"]] \
-            == [(4, 3, 6, 10)] * LINEAR_LAYERS
-        assert [x.shape for x in forked["conv"]] \
-            == [(4, 3, 66)] * LINEAR_LAYERS
-        for name in lm.LINEAR_BUFFERS:
-            for mine, theirs in zip(cache[name], forked[name]):
-                assert np.any(np.asarray(mine))
-                for b in range(4):
-                    assert np.array_equal(np.asarray(mine),
-                                          np.asarray(theirs[b]))
-        # what the engine's fork executable makes: the same, in one call
-        made = jax.jit(lambda c: kv.own_rows(c, 4, 2 * STEPS))(cache)
-        again = kv.forked(cache, made)
-        assert jax.tree_util.tree_structure(again) \
-            == jax.tree_util.tree_structure(forked)
-        for name in lm.LINEAR_BUFFERS:
-            for mine, theirs in zip(forked[name], again[name]):
-                assert np.array_equal(np.asarray(mine), np.asarray(theirs))
-
-    def test_a_snapshot_restores_keys_values_and_states(self, params):
-        """What the manager keeps after the instruction's last token is a
-        copy of every kind of buffer; a request that starts from it gets
-        copies again, whatever the one before did to its own."""
-        manager = kv.KVCacheManager(CFG, jnp.float32)
-        prefix = tuple(range(1, 22))
-        cache, held = manager.acquire(prefix, 256)
-        assert held == 0 and not np.any(np.asarray(cache["state"][0]))
-        module = lm.DecoderLM(CFG)
-        apply = jax.jit(lambda t, start, c: module.apply(
-            {"params": params}, t, start, jnp.int32(t.shape[0]), c,
-            all_logits=False))
-        _, cache, _ = apply(jnp.asarray(prefix, jnp.int32), jnp.int32(0),
-                            cache)
-        manager.keep_prefix(prefix, 256, cache)
-        kept = jax.tree_util.tree_map(np.asarray, cache)
-        first, held = manager.acquire(prefix, 256)
-        assert held == 21 and manager.snapshots == 1
-        # the request runs on and spoils its copy
-        _, spoiled, _ = apply(jnp.arange(5, dtype=jnp.int32), jnp.int32(21),
-                              first)
-        assert not np.array_equal(np.asarray(spoiled["state"][0]),
-                                  kept["state"][0])
-        second, held = manager.acquire(prefix, 256)
-        assert held == 21
-        for name in ("k", "v", "state", "conv"):
-            assert len(kept[name]) in (FULL_LAYERS, LINEAR_LAYERS)
-            for mine, theirs in zip(kept[name], second[name]):
-                assert np.array_equal(mine, np.asarray(theirs)), name
+    test_a_fork_shares_every_key_and_value_and_copies_every_state = contract.SequencesOfOneStep \
+        .a_fork_shares_what_has_positions_and_copies_the_rest
 
     def test_bytes_and_positions_of_a_forked_cache_of_both_kinds(self):
         assert lm.shares_a_step(CFG)
@@ -447,6 +171,10 @@ def _delta_operands(tokens, heads, k_dim, v_dim, seed=0, most=2.0):
     return state, q, k, v, g, beta
 
 
+RECURRENT = jax.jit(delta_rule.recurrent)
+CHUNKED = jax.jit(delta_rule.chunked)
+
+
 class TestTheDeltaRuleAtStrengthsUpToTwo:
     @pytest.mark.parametrize("tokens,heads,k_dim,v_dim", [
         (200, 5, 96, 192), (64, 3, 6, 10), (130, 30, 12, 24)])
@@ -459,15 +187,15 @@ class TestTheDeltaRuleAtStrengthsUpToTwo:
         the token-by-token recurrence."""
         operands = _delta_operands(tokens, heads, k_dim, v_dim)
         assert float(jnp.max(operands[-1])) > 1.9
-        want, state = jax.jit(delta_rule.recurrent)(*operands)
-        got, after = jax.jit(delta_rule.chunked)(*operands)
+        want, state = RECURRENT(*operands)
+        got, after = CHUNKED(*operands)
         assert got.shape == (tokens, heads, v_dim)
         assert after.shape == (heads, k_dim, v_dim)
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
         np.testing.assert_allclose(after, state, rtol=2e-4, atol=2e-5)
         # and not the rule at half the strength
         state, q, k, v, g, beta = operands
-        half, _ = jax.jit(delta_rule.recurrent)(state, q, k, v, g, beta / 2)
+        half, _ = RECURRENT(state, q, k, v, g, beta / 2)
         assert rel_rms(half, want) > 0.05
 
     def test_a_step_of_each_is_the_step_of_one(self):
@@ -504,7 +232,7 @@ class TestTheDeltaRuleAtStrengthsUpToTwo:
 
 # -- (d) the tree, the counts and the rules ------------------------------------
 
-class TestTheTreeAndItsRules:
+class TestTheTreeAndItsRules(contract.ShardingRules):
     def test_the_leaves_of_each_kind(self, params):
         assert CFG.layer_types == ("linear", "linear", "linear", "full") * 2
         assert CFG.sublayer_norms == ("pre", "pre", "pre", "post") * 2
@@ -559,32 +287,22 @@ class TestTheTreeAndItsRules:
         assert configs.lm_share(configs.TINY_LM, 2, chips=2,
                                 rank=0).norm_placement == ()
 
-    def test_sharding_rules(self, params):
-        """The new leaves: a query norm's weight over the whole projection
-        and the norms after the sublayers are replicated; a state whose
-        widths differ stays whole on every chip."""
-        from jax.sharding import PartitionSpec as P
+    #: the new leaves: a query norm's weight over the whole projection and
+    #: the norms after the sublayers are replicated; a state whose widths
+    #: differ stays whole on every chip
+    WHOLE = (("layers_3/attn/q_norm/scale", 1),
+             ("layers_3/attn/k_norm/scale", 1),
+             ("layers_3/input_norm_2/scale", 1),
+             ("layers_3/post_attention_norm_2/scale", 1),
+             ("layers_0/delta/norm/scale", 1),
+             ("layers_0/delta/A_log", 1),
+             ("layers_0/delta/conv_kernel", 2))
+    PLACED_WHOLE = ("layers_3/attn/q_norm/scale",
+                    "layers_3/input_norm_2/scale",
+                    "layers_0/delta/qkvz_proj/kernel")
+    test_sharding_rules = contract.ShardingRules.sharding_rules
 
-        from stable_diffusion_webui_distributed_tpu.parallel.sharding import (
-            shard_params, tp_spec_for,
-        )
-
-        for path, ndim in (("layers_3/attn/q_norm/scale", 1),
-                           ("layers_3/attn/k_norm/scale", 1),
-                           ("layers_3/input_norm_2/scale", 1),
-                           ("layers_3/post_attention_norm_2/scale", 1),
-                           ("layers_0/delta/norm/scale", 1),
-                           ("layers_0/delta/A_log", 1),
-                           ("layers_0/delta/conv_kernel", 2)):
-            assert tp_spec_for(path, ndim) == P(), path
-        devices = np.array(jax.devices()[:4]).reshape(2, 2)
-        mesh = jax.sharding.Mesh(devices, ("ep", "vp"))
-        placed = shard_params(params, mesh)
-        for leaf in (placed["layers_3"]["attn"]["q_norm"]["scale"],
-                     placed["layers_3"]["input_norm_2"]["scale"],
-                     placed["layers_0"]["delta"]["qkvz_proj"]["kernel"]):
-            assert leaf.sharding.spec == P()    # no tp axis on this mesh
-        assert placed["lm_head"]["kernel"].sharding.spec == P(None, "vp")
+    def check_placed(self, placed, mesh):
         # the published state and query norm, as shapes
         share = configs.sd15_olmo_hybrid_expander().expander
         shapes = lm.cache_shapes(share, 2560)
@@ -595,58 +313,19 @@ class TestTheTreeAndItsRules:
 
 # -- (e) the engine's path ----------------------------------------------------
 
-INSTRUCTION = " ".join(f"word{i}" for i in range(30))
+class TestEnginePath(contract.ForkedEnginePath):
+    test_a_batch_prefills_once_forks_and_decodes_four_a_step = contract.ForkedEnginePath \
+        .a_batch_prefills_once_forks_and_decodes_four_a_step
+    test_every_image_its_own_expansion_and_one_image_the_old_path = contract.ForkedEnginePath \
+        .every_image_its_own_expansion_and_one_image_the_old_path
+    CASE = CASE
+    #: how the model departs from input norms, rotation and strength 1
+    DEPARTURES = {"norms_pre": 12, "norms_post": 4, "unrotated": 2,
+                  "write_strength_bound": 2.0}
 
-
-def script(**args):
-    return {"prompt expansion": {"args": [dict(
-        {"instruction": INSTRUCTION, "max_new_tokens": 40,
-         "temperature": 1.0, "ignore_eos": True, "context_chunks": 1},
-        **args)]}}
-
-
-@pytest.fixture(scope="module")
-def engine():
-    params = init_params(configs.TINY)
-    params["expander"] = lm_params(CFG, seed=1)
-    return Engine(configs.tiny_olmo_hybrid_expander(), params, chunk_size=4,
-                  state=GenerationState())
-
-
-def payload(**kw):
-    base = dict(prompt="a cow in a valley", steps=4, width=32, height=32,
-                seed=1234, alwayson_scripts=script())
-    base.update(kw)
-    return GenerationPayload(**base)
-
-
-CAPACITY = kv.capacity_for(31 + 64 + 2 * STEPS)
-
-
-class TestEnginePath:
-    def test_a_batch_prefills_once_forks_and_decodes_four_a_step(self,
-                                                                 engine):
-        """The spans, counters and Prometheus families of a four-image
-        request from the kept snapshot; the second request repeats the
-        first byte for byte."""
-        from stable_diffusion_webui_distributed_tpu.obs import spans
-
-        assert engine.expander.shares_a_step
-        ATTENTION.clear()
-        EXPANDER.clear()
-        whole = engine.txt2img(payload(batch_size=4))   # keeps the snapshot
-        assert len(set(whole.prompts)) == 4
-        keys = {k for k in engine.executable_keys()
-                if k[0].startswith("expand")}
-        assert keys == {("expand_prefill", 64, CAPACITY),
-                        ("expand_prefill", 64, CAPACITY, 4),
-                        ("expand_fork", CAPACITY, 4, 2 * STEPS),
-                        ("expand_decode_chunk", STEPS, CAPACITY, 4),
-                        ("expand_keys", 4), ("expand_copy", CAPACITY)}
-        sites = ATTENTION.summary()["by_shape"]
-        assert sum(sites[f"T1 S{CAPACITY + 2 * STEPS} D8"].values()) \
-            == FULL_LAYERS
-        traced = EXPANDER.summary()
+    def check_traced(self, sites, traced):
+        assert sum(sites["by_shape"][
+            f"T1 S{CAPACITY + 2 * STEPS} D8"].values()) == FULL_LAYERS
         # two prefill executables and one forked decode chunk were traced
         assert traced["delta_mixers"] == {
             "recurrent": 0, "chunked": 2 * LINEAR_LAYERS,
@@ -660,24 +339,11 @@ class TestEnginePath:
             "recurrent": 0, "chunked": 2 * FULL_LAYERS,
             "recurrent_forked": FULL_LAYERS}
         assert traced["write_strength_bound"] == 2.0
-        EXPANDER.clear()
-        spans.TRACER.clear()
-        with spans.request("rid-olmo-hybrid"):
-            again = engine.txt2img(payload(batch_size=4))
-        assert again.prompts == whole.prompts
-        assert again.images == whole.images
-        stats = METRICS.summary()["expander"]
-        assert stats["requests"] == 1 and stats["sequences"] == 4
-        assert stats["tokens_prefilled"] == 5       # the prompt, once
-        assert stats["tokens_from_prefix_cache"] == 31
-        assert stats["tokens_decoded"] == 4 * 40
-        assert stats["decode_steps"] == 2 * STEPS
+
+    def check_counted(self, stats, sizes, one):
         assert stats["tokens_no_held_expert"] == 0 == stats["experts_read"]
         assert stats["cache_positions"] == {
             "full": FULL_LAYERS * (36 + 4 * 40), "sliding": 0, "linear": 0}
-        sizes = kv.state_bytes(CFG, CAPACITY, jnp.float32, 4, 2 * STEPS)
-        assert stats["state_bytes"] == sizes
-        one = kv.state_bytes(CFG, CAPACITY, jnp.float32)
         state = LINEAR_LAYERS * STATE
         assert one["linear"] == state and sizes["linear"] == 4 * state
         assert stats["fork_bytes_copied"] == 4 * state
@@ -685,44 +351,38 @@ class TestEnginePath:
         assert stats["state_bytes_stepped"] == 2 * STEPS * 2 * 4 * state
         # nothing was traced again: the counters of the sites stay 0
         assert stats["write_strength_bound"] == 0.0
-        events = [e for e in spans.TRACER.export_chrome()["traceEvents"]
-                  if e.get("ph") == "X"]
-        by_name = {}
-        for e in events:
-            by_name.setdefault(e["name"], []).append(e["args"])
-        assert [a["sequences"] for a in by_name["expand"]] == [4]
-        departures = {"norms_pre": 12, "norms_post": 4, "unrotated": 2,
-                      "write_strength_bound": 2.0}
+
+    def check_spans(self, by_name, sizes, one):
+        state = LINEAR_LAYERS * STATE
         (prefill,) = by_name["expand.prefill"]
-        assert prefill["tokens"] == 5 and prefill["sequences"] == 4
+        assert prefill["sequences"] == 4    # whose first tokens it draws
         assert prefill["form"] == "chunked" and prefill["padded"] == 59
         (fork,) = by_name["expand.fork"]
-        assert fork["sequences"] == 4
         assert fork["delta"] == "recurrent_forked"
         assert fork["state_bytes_copied"] == 4 * state
-        # the bytes a fork makes: two full layers' own rows of 64 slots a
-        # sequence and four copies of every state, float32
+        # two full layers' own rows of 64 slots a sequence and four copies
+        # of every state, float32
         assert fork["bytes"] == FULL_LAYERS * 4 * 2 * STEPS * 2 * 24 * 4 \
             + 4 * state
         chunks = by_name["expand.decode_chunk"]
-        assert [(a["sequences"], a["delta"]) for a in chunks] \
-            == [(4, "recurrent_forked")] * 2
+        assert [a["delta"] for a in chunks] == ["recurrent_forked"] * 2
         for attrs in [prefill, fork] + chunks:
-            assert {k: attrs[k] for k in departures} == departures
+            assert {k: attrs[k] for k in self.DEPARTURES} == self.DEPARTURES
         hits = [a for a in by_name["expand.prefix_copy"] if a.get("hit")]
         assert hits and hits[0]["bytes"] == sum(one.values())
+
+    def check_one_image(self, sites, stats):
+        assert stats["delta_mixers"]["recurrent"] == LINEAR_LAYERS
+        assert stats["attention_unrotated"]["recurrent"] == FULL_LAYERS
+        assert stats["fork_bytes_copied"] == 2 * LINEAR_LAYERS * STATE
 
     def test_the_prometheus_families_and_the_status_keys(self, engine):
         ATTENTION.clear()
         EXPANDER.clear()
-        module = lm.DecoderLM(CFG)
-        cache = jax.eval_shape(
-            lambda: kv.fork(lm.empty_cache(CFG, 64, jnp.float32), 4, 32))
-        jax.eval_shape(
-            lambda p, c: module.apply({"params": p}, jnp.zeros((4,), int),
-                                      jnp.int32(40), jnp.int32(4), c,
-                                      sequences=True),
-            lm_params(CFG), cache)
+        contract.sites_of(
+            CFG, CASE.params(), jnp.zeros((4,), jnp.int32), 40, 4,
+            contract.forked_structs(CFG, 64, 4, 32, jnp.float32),
+            sequences=True)
         summary = METRICS.summary()["expander"]
         assert {"sublayer_norms", "attention_unrotated",
                 "write_strength_bound", "delta_mixers",
@@ -739,9 +399,7 @@ class TestEnginePath:
         # a sibling's mixer writes under 1
         EXPANDER.clear()
         other = configs.TINY_DELTA_EXPAND.expander
-        jax.eval_shape(lambda: lm.DecoderLM(other).init(
-            jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-            jnp.int32(4), lm.empty_cache(other, 8, jnp.float32)))
+        contract.param_shapes(other)
         summary = EXPANDER.summary()
         assert summary["write_strength_bound"] == 1.0
         assert summary["attention_unrotated"]["chunked"] == 0
@@ -769,36 +427,8 @@ class TestEnginePath:
         assert summary["write_strength_bound"] == 2.0
         EXPANDER.clear()
 
-    def test_every_image_its_own_expansion_and_one_image_the_old_path(
-            self, engine):
-        whole = engine.txt2img(payload(batch_size=4))
-        EXPANDER.clear()
-        for i in (0, 3):
-            solo = engine.txt2img(payload(seed=1234 + i))
-            assert solo.prompts[0] == whole.prompts[i], i
-        part = engine.generate_range(payload(batch_size=4), 2, 2)
-        assert part.prompts == whole.prompts[2:]
-        assert ("expand_decode_chunk", STEPS, CAPACITY) \
-            in set(engine.executable_keys())
-        stats = EXPANDER.summary()
-        assert stats["delta_mixers"]["recurrent"] == LINEAR_LAYERS
-        assert stats["attention_unrotated"]["recurrent"] == FULL_LAYERS
-        assert stats["fork_bytes_copied"] == 2 * LINEAR_LAYERS * STATE
-        ATTENTION.clear()
-
 
 # -- (f) the published model and its share, from shapes -----------------------
-
-def _published_shapes(share):
-    return jax.eval_shape(lambda: lm.DecoderLM(share).init(
-        jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-        jnp.int32(4), lm.empty_cache(share, 8, jnp.float32)))["params"]
-
-
-def _count(tree):
-    return sum(int(np.prod(x.shape))
-               for x in jax.tree_util.tree_leaves(tree))
-
 
 class TestThePublishedShare:
     def test_parameters_and_bytes_from_shapes(self):
@@ -818,29 +448,29 @@ class TestThePublishedShare:
                 share.linear_key_head_dim, share.linear_value_head_dim,
                 share.linear_conv_kernel, share.linear_write_scale) \
             == (30, 30, 96, 192, 4, 2.0)
-        shapes = _published_shapes(share)
+        shapes = contract.param_shapes(share)
         delta = shapes["layers_0"]["delta"]
         assert delta["qkvz_proj"]["kernel"].shape == (3840, 11520 + 5760)
         assert delta["ba_proj"]["kernel"].shape == (3840, 60)
         assert delta["out_proj"]["kernel"].shape == (5760, 3840)
         assert delta["conv_kernel"].shape == (4, 11520)
-        assert round(_count(delta["qkvz_proj"]) / 1e6, 2) == 66.36
-        assert round(_count(delta["ba_proj"]) / 1e6, 2) == 0.23
-        assert round(_count(delta["out_proj"]) / 1e6, 2) == 22.12
-        assert round(_count(delta["conv_kernel"]) / 1e6, 2) == 0.05
-        assert round(_count(delta) / 1e6, 2) == 88.75
+        assert round(count(delta["qkvz_proj"]) / 1e6, 2) == 66.36
+        assert round(count(delta["ba_proj"]) / 1e6, 2) == 0.23
+        assert round(count(delta["out_proj"]) / 1e6, 2) == 22.12
+        assert round(count(delta["conv_kernel"]) / 1e6, 2) == 0.05
+        assert round(count(delta) / 1e6, 2) == 88.75
         attn = shapes["layers_3"]["attn"]
         assert attn["q_norm"]["scale"].shape == (3840,)
-        assert round(_count(attn) / 1e6, 2) == 58.99    # 58.98 + the norms
-        assert round(_count(shapes["layers_0"]["mlp"]) / 1e6, 2) == 126.81
-        linear, full = _count(shapes["layers_0"]), _count(shapes["layers_3"])
+        assert round(count(attn) / 1e6, 2) == 58.99    # 58.98 + the norms
+        assert round(count(shapes["layers_0"]["mlp"]) / 1e6, 2) == 126.81
+        linear, full = count(shapes["layers_0"]), count(shapes["layers_3"])
         assert round(linear / 1e6, 2) == 215.57
         assert round(full / 1e6, 2) == 185.81
         assert round((3 * linear + full) / 1e6, 1) == 832.5
-        assert _count(shapes["embed_tokens"]) == _count(shapes["lm_head"]) \
+        assert count(shapes["embed_tokens"]) == count(shapes["lm_head"]) \
             == 3840 * 100352
         assert round(3840 * 100352 / 1e6, 2) == 385.35
-        total = _count(shapes)
+        total = count(shapes)
         # ISSUE 59's 4 100.6 M and the norms' weights, A_log and dt_bias
         assert round(total / 1e6, 1) == 4100.8
         assert round(total * 2 / 1e9, 2) == 8.20
@@ -882,25 +512,19 @@ class TestThePublishedShare:
         sites over 2 560 shared and 256 own rows of 128, 24 norms before
         a sublayer and 8 after."""
         share = configs.sd15_olmo_hybrid_expander().expander
-        module = lm.DecoderLM(share, dtype=jnp.bfloat16)
-        s = jax.ShapeDtypeStruct
-        one = {name: [s(shape, lm.buffer_dtype(name, jnp.bfloat16))
-                      for shape in rows]
-               for name, rows in lm.cache_shapes(share, 2560).items()}
-        cache = jax.eval_shape(lambda c: kv.fork(c, 4, 256), one)
+        cache = contract.forked_structs(share, 2560, 4, 256)
         assert [x.shape for x in cache["k_shared"]] == [(2560, 30, 128)] * 4
         assert [x.shape for x in cache["k"]] == [(4, 256, 30, 128)] * 4
         assert [(x.shape, x.dtype) for x in cache["state"]] \
             == [((4, 30, 96, 192), jnp.float32)] * 12
         assert [x.shape for x in cache["conv"]] == [(4, 3, 11520)] * 12
-        shapes = {"params": _published_shapes(share)}
+        shapes = contract.param_shapes(share)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         ATTENTION.clear()
         EXPANDER.clear()
-        logits, after, routed = jax.eval_shape(
-            lambda v, c: module.apply(v, jnp.zeros((4,), jnp.int32),
-                                      jnp.int32(2200), jnp.int32(4), c,
-                                      sequences=True), shapes, cache)
+        logits, after, routed = contract.sites_of(
+            share, shapes, jnp.zeros((4,), jnp.int32), 2200, 4,
+            cache, jnp.bfloat16, sequences=True)
         assert logits.shape == (4, 100352)
         assert jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), after) \
             == jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), cache)

@@ -12,9 +12,8 @@ the last class holds every preset the other test files run to that.
 """
 
 import dataclasses
-import importlib.util
-import os
-import zlib
+import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -25,76 +24,42 @@ from stable_diffusion_webui_distributed_tpu.cache import kv
 from stable_diffusion_webui_distributed_tpu.models import configs, lm
 from stable_diffusion_webui_distributed_tpu.obs import prometheus
 from stable_diffusion_webui_distributed_tpu.parallel import sharding
-from stable_diffusion_webui_distributed_tpu.pipeline import expand
-from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
-from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
-    GenerationPayload,
-)
-from stable_diffusion_webui_distributed_tpu.runtime import dtypes
-from stable_diffusion_webui_distributed_tpu.runtime.interrupt import (
-    GenerationState,
-)
 from stable_diffusion_webui_distributed_tpu.serving.metrics import (
-    ATTENTION, EXPANDER, METRICS,
+    ATTENTION, EXPANDER,
 )
-from tests.test_mellum2_expander import (
-    assert_own_rows, forked_against_alone, forked_shapes,
-)
-from tests.test_pipeline import init_params
+from tests import expander_contract as contract
+from tests.expander_contract import CAPACITY, STEPS, count, rel_rms
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-REF = _load(os.path.join(ROOT, "benchmarks", "reference", "ouro_ref.py"),
-            "ouro_ref_for_tests")
-FAMILY = configs.TINY_LOOP_EXPAND
-CFG = FAMILY.expander
+REF = contract.load_reference("ouro")
+#: the norms off 1 and the gate's bias off 0, so that reading one norm as
+#: another, or no bias, would show
+CASE = contract.Case(
+    configs.TINY_LOOP_EXPAND, REF,
+    how=(("spread", (("scale", 0.2),)), ("shift", (("bias", 0.25),))),
+    extra="with_gates")
+FAMILY, CFG = CASE.family, CASE.cfg
 PASSES = CFG.total_ut_steps
-STEPS = expand.DECODE_STEPS
+params, engine = contract.fixtures(CASE)
 
 
-def lm_params(cfg, seed=0):
-    """``DecoderLM.init``'s tree with the norms off 1 and the gate's bias
-    off 0, so that reading one norm as another, or no bias, would show."""
-    params = lm.DecoderLM(cfg).init(
-        jax.random.key(seed), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-        jnp.int32(4), lm.empty_cache(cfg, 8, jnp.float32))["params"]
-    key = jax.random.key(seed + 100)
-
-    def off(path, x):
-        leaf = getattr(path[-1], "key", "")
-        if leaf == "bias":
-            return x + 0.25
-        if leaf != "scale":
-            return x
-        return x + 0.2 * jax.random.normal(
-            jax.random.fold_in(key, zlib.crc32(str(path).encode()) % 2 ** 31),
-            x.shape)
-
-    return jax.tree_util.tree_map_with_path(off, params)
-
-
-@pytest.fixture(scope="module")
-def params():
-    return lm_params(CFG)
-
-
-def rel_rms(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / np.sqrt(np.mean(want ** 2)))
-
-
+@functools.lru_cache(maxsize=None)
 def with_threshold(threshold):
-    return dataclasses.replace(FAMILY, expander=dataclasses.replace(
-        CFG, early_exit_threshold=threshold))
+    """The model leaving at the first pass whose gates add up to
+    ``threshold``."""
+    return dataclasses.replace(CASE, family=dataclasses.replace(
+        FAMILY, expander=dataclasses.replace(
+            CFG, early_exit_threshold=threshold)))
+
+
+@functools.lru_cache(maxsize=None)
+def reference_with(**how):
+    return jax.jit(lambda p, i, c: REF.forward(FAMILY, p, i, c, **how))
+
+
+def prefilled(params):
+    """One chunk of 21 positions, none padded."""
+    return contract.prefilled(CFG, params, 21, prefix=0, capacity=256,
+                              bucket=21)
 
 
 # -- (a) program against reference --------------------------------------------
@@ -108,11 +73,8 @@ class TestAgainstTheReference:
         layer) cache, against a full forward of each whole sequence:
         logits and every pass's gate to 1e-5, the chosen pass the same."""
         prefix, user, decoded = REF.split(size)
-        ids, continuations = REF.inputs(FAMILY, 3, size)
-        got, gates, chose = jax.jit(REF.program(
-            FAMILY, dtypes.F32, with_gates=True))(params, ids, continuations)
-        want, lam, own = jax.jit(lambda p, i, c: REF.forward(
-            FAMILY, p, i, c, with_gates=True))(params, ids, continuations)
+        inputs, want, lam, own = CASE.referred(size)
+        got, gates, chose = CASE.program(with_gates=True)(params, *inputs)
         rows = prefix + user + REF.SEQUENCES * decoded
         assert got.shape == want.shape == (rows, CFG.vocab[1])
         assert gates.shape == lam.shape == (PASSES, rows)
@@ -134,16 +96,10 @@ class TestAgainstTheReference:
         norms after the sublayers, the final norm after the last pass
         alone and the int8 linears each read far from the reference where
         the program reads 1e-6."""
-        ids, continuations = REF.inputs(FAMILY, 3, 48)
-        want = jax.jit(lambda p, i, c: REF.forward(FAMILY, p, i, c))(
-            params, ids, continuations)
+        inputs, want, *_ = CASE.referred(48)
         side, kwargs = dict(REF.CONTROLS)[control]
-        if side == "program":
-            lower = jax.jit(REF.program(FAMILY, dtypes.F32, **kwargs))(
-                params, ids, continuations)
-        else:
-            lower = jax.jit(lambda p, i, c: REF.forward(
-                FAMILY, p, i, c, **kwargs))(params, ids, continuations)
+        lower = (CASE.program if side == "program" else reference_with)(
+            **kwargs)(params, *inputs)
         assert rel_rms(lower, want) > 1e-2
 
     @pytest.mark.parametrize("threshold", [0.5, 0.9, 0.99])
@@ -152,20 +108,16 @@ class TestAgainstTheReference:
         """Program and reference leave at the same pass, row by row, and
         read the same logits; every pass still ran (the later rows'
         logits need each pass's keys of the earlier ones)."""
-        family = with_threshold(threshold)
-        ids, continuations = REF.inputs(family, 3, 48)
-        got, gates, chose = jax.jit(REF.program(
-            family, dtypes.F32, with_gates=True))(params, ids, continuations)
-        want, lam, own = jax.jit(lambda p, i, c: REF.forward(
-            family, p, i, c, with_gates=True))(params, ids, continuations)
+        early = with_threshold(threshold)
+        inputs, want, lam, own = early.referred(48)
+        got, gates, chose = early.program(with_gates=True)(params, *inputs)
         assert np.array_equal(chose, own)
         assert len(set(np.asarray(own).tolist())) > 1
         assert np.any(np.asarray(own) < PASSES - 1)
         assert rel_rms(got, want) < 1e-5
         np.testing.assert_allclose(gates, lam, atol=1e-5)
         # the gates do not depend on the threshold; what is read does
-        at_one = jax.jit(REF.program(FAMILY, dtypes.F32))(
-            params, ids, continuations)
+        at_one = CASE.program()(params, *inputs)
         assert rel_rms(got, at_one) > 1e-2
 
     def test_the_exit_rule_by_hand(self):
@@ -180,70 +132,34 @@ class TestAgainstTheReference:
 
 # -- (b) a step over B sequences ----------------------------------------------
 
-def _prefilled(params, length=21, capacity=256):
-    ids = jax.random.randint(jax.random.key(5), (length,), 0, 512)
-    logits, cache, _ = lm.DecoderLM(CFG).apply(
-        {"params": params}, ids, jnp.int32(0), jnp.int32(length),
-        lm.empty_cache(CFG, capacity, jnp.float32), all_logits=False)
-    return logits[0], cache, length
-
-
-def _keys(indices, seed=77):
-    from stable_diffusion_webui_distributed_tpu.runtime import rng
-
-    return jnp.stack([rng.key_for_image(seed, i) for i in indices])
-
-
-class TestSequencesOfOneStep:
-    @pytest.mark.parametrize("user", [1, 16, 63, 64])
-    @pytest.mark.parametrize("live,batch", [(2, 2), (4, 4), (3, 4)])
-    def test_a_forked_decode_is_each_sequence_alone(self, params, user,
-                                                    live, batch):
-        """The looped stack at the chunk bucket's edges: every pass reads
-        its own rows of the shared buffers and of a sequence's own."""
-        forked_against_alone(CFG, params, user, live, batch)
+class TestSequencesOfOneStep(contract.SequencesOfOneStep):
+    """The looped stack at the chunk bucket's edges: every pass reads its
+    own rows of the shared buffers and of a sequence's own."""
+    CASE = CASE
+    PARAMETERS = {"test_a_forked_decode_is_each_sequence_alone": [
+        ("live,batch", [(2, 2), (4, 4), (3, 4)]),
+        ("user", [1, 16, 63, 64])]}
 
     @pytest.mark.parametrize("live,batch", [(1, 1), (2, 2), (4, 4), (3, 4)])
     def test_each_sequence_gets_what_it_gets_alone(self, params, live,
                                                    batch):
-        """A chunk of steps over ``batch`` forked sequences against the
-        one-sequence chunk run once a key: the same tokens, the same rows
-        of every pass in the cache, and the pad left out of the exits."""
-        module = lm.DecoderLM(CFG)
-        row, cache, length = _prefilled(params)
-        keys = _keys(list(range(live)) + [live - 1] * (batch - live))
-        first = lm.sample_each(row, keys, length, jnp.float32(1.0))
-        alone = jax.jit(lm.decode_chunk_fn(module, STEPS))
-        together = jax.jit(lm.decode_sequences_fn(module, STEPS))
-        (forked, tokens, position, made, load, none_held, read,
-         (counts, most)) = together(
-            params, kv.fork(cache, batch), first, jnp.int32(length), keys,
-            jnp.float32(1.0), jnp.int32(live))
-        assert made.shape == (STEPS, batch)
-        assert load.shape == (0, 0) and read.shape == (0,)
+        """From one chunk of 21 positions, none padded, and with a
+        buffer's own count of slots a sequence: the same tokens, the same
+        rows of every pass in the cache, and the pad left out of the
+        exits."""
+        ((counts, most),), own = contract.forked_against_alone(
+            CASE, params, live, batch, own_slots=0, user=21, prefix=0,
+            capacity=256, bucket=21)
         assert counts.tolist() == [0] * (PASSES - 1) + [STEPS * live]
-        largest = 0.0
-        for b in range(live):
-            own, last, _, steps, _, _, (own_counts, own_most) = alone(
-                params, cache, first[b], jnp.int32(length), keys[b],
-                jnp.float32(1.0))
-            assert np.array_equal(steps, made[:, b])
-            assert int(last) == int(tokens[b])
-            assert own_counts.tolist() == [0] * (PASSES - 1) + [STEPS]
-            largest = max(largest, float(own_most))
-            assert all(x.shape == (PASSES, 256, 4, 16)
-                       for x in own["k"] + own["v"])
-            assert_own_rows(CFG, own, forked, b, length, STEPS)
-        assert all(x.shape == (batch, PASSES, 256, 4, 16)
-                   for x in forked["k"] + forked["v"])
-        assert float(most) == pytest.approx(largest, rel=1e-5)
-        assert len({tuple(np.asarray(made[:, b])) for b in range(live)}) \
-            == live
+        assert [c.tolist() for (c, _), in own] \
+            == [[0] * (PASSES - 1) + [STEPS]] * live
+        assert float(most) == pytest.approx(
+            max(float(m) for (_, m), in own), rel=1e-5)
 
     def test_every_pass_writes_rows_of_its_own(self, params):
         """After a prefill every pass's rows of the written positions are
         filled and differ from pass to pass; the rest stay zero."""
-        _, cache, length = _prefilled(params)
+        _, cache, length = prefilled(params)
         for rows in cache["k"] + cache["v"]:
             assert rows.shape == (PASSES, 256, 4, 16)
             assert np.all(np.asarray(rows[:, length:]) == 0)
@@ -252,24 +168,8 @@ class TestSequencesOfOneStep:
                                      axis=(1, 2)))
             assert rel_rms(rows[1, :length], rows[0, :length]) > 0.05
 
-    def test_a_fork_copies_no_pass(self, params):
-        """(c): the shared buffers ARE the prefill's, every pass of them;
-        a sequence's own rows have the passes behind the sequences."""
-        _, cache, _ = _prefilled(params)
-        forked = kv.fork(cache, 4, 2 * STEPS)
-        for name, shared in zip(("k", "v"), ("k_shared", "v_shared")):
-            assert all(mine is theirs for mine, theirs
-                       in zip(cache[name], forked[shared]))
-            assert [x.shape for x in forked[shared]] == \
-                [(PASSES, 256, 4, 16)] * 4
-            assert [x.shape for x in forked[name]] == \
-                [(4, PASSES, 64, 4, 16)] * 4
-        (at,) = forked["forked_at"]
-        assert at.shape == (4, 1) and np.all(np.asarray(at) == -1)
-        made = jax.jit(lambda c: kv.own_rows(c, 4, 2 * STEPS))(cache)
-        assert [x.shape for x in made["k"]] == [(4, PASSES, 64, 4, 16)] * 4
-        assert not any(np.any(np.asarray(x)) for x in made["v"])
-        assert set(made) == {"k", "v", "forked_at"}
+    test_a_fork_copies_no_pass = contract.SequencesOfOneStep \
+        .a_fork_shares_what_has_positions_and_copies_the_rest
 
     def test_a_looped_model_shares_a_step(self):
         assert lm.shares_a_step(CFG)
@@ -285,34 +185,10 @@ class TestSequencesOfOneStep:
 # -- (c) the cache's manager over buffers with a pass axis ----------------------
 
 class TestTheCacheCountsThePassAxis:
-    def test_a_kept_prefix_restores_every_pass(self, params):
-        manager = kv.KVCacheManager(CFG, jnp.float32)
-        prefix = list(range(1, 22))
-        empty, held = manager.acquire(prefix, 256)
-        assert held == 0
-        assert all(not np.any(np.asarray(x)) for x in empty["k"])
-        _, cache, length = _prefilled(params)
-        manager.keep_prefix(prefix, 256, cache)
-        # the executables donate what they are given: the kept copy is
-        # neither the cache it was made from nor the one handed out
-        again, held = manager.acquire(prefix, 256)
-        assert held == length == 21 and manager.snapshots == 1
-        for name in ("k", "v"):
-            for kept, made in zip(again[name], cache[name]):
-                assert kept is not made
-                assert kept.shape == (PASSES, 256, 4, 16)
-                assert np.array_equal(np.asarray(kept), np.asarray(made))
-        # a chunk continued from the copy is the chunk continued from the
-        # original, in every pass
-        module = lm.DecoderLM(CFG)
-        more = jnp.arange(7, dtype=jnp.int32) + 30
-        a, after_a, _ = module.apply({"params": params}, more,
-                                     jnp.int32(21), jnp.int32(7), again)
-        b, after_b, _ = module.apply({"params": params}, more,
-                                     jnp.int32(21), jnp.int32(7), cache)
-        assert np.array_equal(np.asarray(a), np.asarray(b))
-        for x, y in zip(after_a["k"], after_b["k"]):
-            assert np.array_equal(np.asarray(x), np.asarray(y))
+    CASE = CASE
+
+    test_a_kept_prefix_restores_every_pass = \
+        contract.StatesOfOneStep.a_snapshot_restores_every_buffer
 
     def test_bytes_and_positions(self):
         manager = kv.KVCacheManager(CFG, jnp.bfloat16)
@@ -336,41 +212,17 @@ class TestTheCacheCountsThePassAxis:
 
 # -- the engine's path ----------------------------------------------------------
 
-INSTRUCTION = " ".join(f"word{i}" for i in range(30))
 
+class TestTheEnginePath(contract.ForkedEnginePath):
+    CASE, KEYS = CASE, None
 
-def script(**args):
-    return {"prompt expansion": {"args": [dict(
-        {"instruction": INSTRUCTION, "max_new_tokens": 40,
-         "temperature": 1.0, "ignore_eos": True, "context_chunks": 1},
-        **args)]}}
-
-
-@pytest.fixture(scope="module")
-def engine():
-    params = init_params(configs.TINY)
-    params["expander"] = lm_params(CFG, seed=1)
-    return Engine(FAMILY, params, chunk_size=4, state=GenerationState())
-
-
-def payload(**kw):
-    base = dict(prompt="a cow in a valley", steps=4, width=32, height=32,
-                seed=1234, alwayson_scripts=script())
-    base.update(kw)
-    return GenerationPayload(**base)
-
-
-CAPACITY = kv.capacity_for(31 + 64 + 2 * STEPS)
-
-
-class TestTheEnginePath:
     def test_every_image_its_own_expansion(self, engine):
-        whole = engine.txt2img(payload(batch_size=4))
+        whole = engine.txt2img(CASE.payload(batch_size=4))
         assert len(set(whole.prompts)) == 4
         for i in (0, 3):
-            solo = engine.txt2img(payload(seed=1234 + i))
+            solo = engine.txt2img(CASE.payload(seed=1234 + i))
             assert solo.prompts[0] == whole.prompts[i], i
-        again = engine.txt2img(payload(batch_size=4))
+        again = engine.txt2img(CASE.payload(batch_size=4))
         assert again.prompts == whole.prompts
         assert again.images == whole.images
         keys = {k for k in engine.executable_keys()
@@ -385,49 +237,24 @@ class TestTheEnginePath:
                         ("expand_keys", 1), ("expand_keys", 4),
                         ("expand_copy", CAPACITY)}
 
-    def test_counters_and_spans_of_the_passes(self, engine):
-        from stable_diffusion_webui_distributed_tpu.obs import spans
+    test_counters_and_spans_of_the_passes = contract.ForkedEnginePath \
+        .a_batch_prefills_once_forks_and_decodes_four_a_step
 
-        engine.txt2img(payload(batch_size=4))       # the snapshot is kept
-        EXPANDER.clear()
-        spans.TRACER.clear()
-        with spans.request("rid-ou"):
-            engine.txt2img(payload(batch_size=4))
-        stats = METRICS.summary()["expander"]
-        assert stats["requests"] == 1 and stats["sequences"] == 4
-        assert stats["tokens_from_prefix_cache"] == 31
-        assert stats["tokens_decoded"] == 4 * 40
-        assert stats["decode_steps"] == 2 * STEPS
+    def check_counted(self, stats, sizes, one):
         # every decode step ran every pass
         assert stats["layer_passes"] == PASSES * 2 * STEPS
         # the prompt's one row and every step's four, all at the last pass
         assert stats["exit_pass"] == [0] * (PASSES - 1) + [1 + 4 * 2 * STEPS]
         assert 0.5 < stats["exit_lambda_max"] < 0.9999
         assert stats["experts_read"] == 0 and stats["expert_tokens"] == []
-        # forked at 31 + 5: those positions once, the 40 behind them once
-        # a sequence
         assert stats["cache_positions"] == {
             "full": 4 * (36 + 4 * 40) * PASSES, "sliding": 0}
         steps = range(36, 36 + 2 * STEPS)
         assert stats["rows_attended"] == sum(4 * (p + 1) for p in steps)
         assert stats["rows_read"] == sum(36 + 4 * (p + 1 - 36)
                                          for p in steps)
-        sizes = kv.state_bytes(CFG, CAPACITY, jnp.float32, 4, 2 * STEPS)
-        assert stats["state_bytes"] == sizes
         row = PASSES * 2 * 4 * 16 * 4       # a layer's slot, float32
         assert sizes["full"] == 4 * (CAPACITY + 4 * 2 * STEPS) * row
-        events = [e for e in spans.TRACER.export_chrome()["traceEvents"]
-                  if e.get("ph") == "X"]
-        by_name = {}
-        for e in events:
-            by_name.setdefault(e["name"], []).append(e["args"])
-        assert [a["passes"] for a in by_name["expand.prefill"]] == [PASSES]
-        (fork,) = by_name["expand.fork"]
-        assert fork["passes"] == PASSES and fork["sequences"] == 4
-        # the bytes a fork makes: the sequences' own rows alone
-        assert fork["bytes"] == 4 * 4 * 2 * STEPS * row
-        assert [(a["passes"], a["sequences"])
-                for a in by_name["expand.decode_chunk"]] == [(PASSES, 4)] * 2
         text = prometheus.render()
         assert f"sdtpu_expander_layer_passes_total {PASSES * 2 * STEPS}" \
             in text
@@ -435,16 +262,23 @@ class TestTheEnginePath:
             f"{1 + 4 * 2 * STEPS}" in text
         assert "sdtpu_expander_exit_lambda_max 0." in text
 
+    def check_spans(self, by_name, sizes, one):
+        (prefill,) = by_name["expand.prefill"]
+        (fork,) = by_name["expand.fork"]
+        chunks = by_name["expand.decode_chunk"]
+        assert [a["passes"] for a in [prefill, fork] + chunks] \
+            == [PASSES] * 4
+        # the bytes a fork makes: the sequences' own rows alone
+        assert fork["bytes"] == 4 * 4 * 2 * STEPS * PASSES * 2 * 4 * 16 * 4
+
     def test_a_site_carries_its_passes(self):
         """A looped stack's layers are alike and share ONE trace of a
         layer an executable: a fresh engine's four-image request records
         one site for each of its two prefill executables and one for the
         decode scan, marked with the passes."""
-        params = init_params(configs.TINY)
-        params["expander"] = lm_params(CFG, seed=1)
-        fresh = Engine(FAMILY, params, chunk_size=4, state=GenerationState())
+        fresh = CASE.engine()
         ATTENTION.clear()
-        fresh.txt2img(payload(batch_size=4))
+        fresh.txt2img(CASE.payload(batch_size=4))
         sites = ATTENTION.summary()["by_shape"]
         assert sites[f"T64 S{CAPACITY} D16 P{PASSES}"] == {"xla": 2}
         # a forked step's keys: the shared buffer and a sequence's own
@@ -453,12 +287,9 @@ class TestTheEnginePath:
         ATTENTION.clear()
 
     def test_a_threshold_below_one_counts_the_earlier_passes(self):
-        family = with_threshold(0.9)
-        params = init_params(configs.TINY)
-        params["expander"] = lm_params(CFG, seed=1)
-        early = Engine(family, params, chunk_size=4, state=GenerationState())
+        early = CASE.engine(with_threshold(0.9).family)
         EXPANDER.clear()
-        early.txt2img(payload(batch_size=4))
+        early.txt2img(CASE.payload(batch_size=4))
         stats = EXPANDER.summary()
         assert sum(stats["exit_pass"]) == 1 + 4 * 2 * STEPS
         assert sum(stats["exit_pass"][:-1]) > 0
@@ -479,32 +310,26 @@ class TestNewLeavesAreWholeOnEveryChip:
 
 # -- the published model, from shapes -----------------------------------------
 
-def _count(tree):
-    return sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(tree))
-
-
 class TestThePublishedModel:
     def test_parameters_and_bytes_from_shapes(self):
         cfg = configs.sd15_ouro_expander().expander
         assert cfg is configs.OURO_2_6B
         assert cfg.layer_types == ("full",) * 48 and cfg.total_ut_steps == 4
         assert cfg.vocab == (0, 49152) and cfg.expert_layers == ()
-        shapes = jax.eval_shape(lambda: lm.DecoderLM(cfg).init(
-            jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-            jnp.int32(4), lm.empty_cache(cfg, 8, jnp.float32)))["params"]
+        shapes = contract.param_shapes(cfg)
         layer = shapes["layers_0"]
         assert set(layer) == {"attn", "mlp", "input_norm", "input_norm_2",
                               "post_attention_norm",
                               "post_attention_norm_2"}
         assert set(layer["attn"]) == {"q_proj", "k_proj", "v_proj", "o_proj"}
-        assert _count(layer["attn"]) == 4 * 2048 * 2048 == 16_777_216
-        assert _count(layer["mlp"]) == 3 * 2048 * 5632 == 34_603_008
+        assert count(layer["attn"]) == 4 * 2048 * 2048 == 16_777_216
+        assert count(layer["mlp"]) == 3 * 2048 * 5632 == 34_603_008
         norms = 4 * 2048
-        assert round((_count(layer) - norms) / 1e6, 1) == 51.4
-        assert _count(shapes["embed_tokens"]) == _count(shapes["lm_head"]) \
+        assert round((count(layer) - norms) / 1e6, 1) == 51.4
+        assert count(shapes["embed_tokens"]) == count(shapes["lm_head"]) \
             == 100_663_296
-        assert _count(shapes["early_exit_gate"]) == 2049
-        total = _count(shapes)
+        assert count(shapes["early_exit_gate"]) == 2049
+        total = count(shapes)
         assert total == 48 * (51_380_224 + norms) + 2 * 100_663_296 \
             + 2048 + 2049
         assert round(total / 1e6) == 2668
@@ -526,15 +351,13 @@ class TestThePublishedModel:
         not 192, and every site marked with its four passes."""
         cfg = configs.OURO_2_6B
         module = lm.DecoderLM(cfg, dtype=jnp.bfloat16)
-        cache = forked_shapes(cfg, 512, 4, 64)
+        cache = contract.forked_structs(cfg, 512, 4, 64)
         # a layer: four passes of 512 shared rows, and every sequence's
         # four passes of 64 of its own
         assert len(cache["k"]) == len(cache["v_shared"]) == 48
         assert cache["k_shared"][0].shape == (4, 512, 16, 128)
         assert cache["k"][0].shape == (4, 4, 64, 16, 128)
-        shapes = jax.eval_shape(lambda: module.init(
-            jax.random.key(0), jnp.zeros((4,), jnp.int32), jnp.int32(0),
-            jnp.int32(4), lm.empty_cache(cfg, 8, jnp.float32)))
+        shapes = {"params": contract.param_shapes(cfg)}
         ATTENTION.clear()
         text = jax.jit(lambda v, c: module.apply(
             v, jnp.zeros((4,), jnp.int32), jnp.int32(330), jnp.int32(4), c,
@@ -599,6 +422,35 @@ def layers_one_after_the_other(cfg, params, tokens, start, length, cache):
         {"params": params["lm_head"]}, n), written
 
 
+class OnePass(NamedTuple):
+    """What a preset of one pass runs, jitted once a config."""
+    served: object
+    by_hand: object
+    prefill: object
+    one_step: object
+    decode: object
+    many: object
+
+
+@functools.lru_cache(maxsize=None)
+def one_pass(cfg):
+    module = lm.DecoderLM(cfg)
+
+    def one_step(p, *a):
+        logits, after, _ = module.apply(
+            {"params": p, "mixers": lm.mixer_operands(p)}, *a,
+            all_logits=False)
+        return after, lm.sample(logits[0], jax.random.key(0), a[1] + 1,
+                                jnp.float32(1.0))
+
+    return OnePass(
+        jax.jit(lambda p, *a: module.apply({"params": p}, *a)[:2]),
+        jax.jit(lambda p, *a: layers_one_after_the_other(cfg, p, *a)),
+        jax.jit(lm.prefill_fn(module)), jax.jit(one_step),
+        jax.jit(lm.decode_chunk_fn(module, STEPS)),
+        jax.jit(lm.decode_sequences_fn(module, STEPS)))
+
+
 class TestAModelOfOnePassIsWhatItWas:
     @pytest.mark.parametrize("preset", ONE_PASS_PRESETS)
     def test_no_loop_no_pass_axis_and_the_same_bits(self, preset):
@@ -610,11 +462,10 @@ class TestAModelOfOnePassIsWhatItWas:
         if "k" in today:
             today["v"] = today["k"]
         assert shapes == today
-        module = lm.DecoderLM(cfg)
+        fns = one_pass(cfg)
         cache = lm.empty_cache(cfg, 64, jnp.float32)
         tokens = jax.random.randint(jax.random.key(2), (12,), 0, 256)
-        params = module.init(jax.random.key(1), tokens, jnp.int32(0),
-                             jnp.int32(12), cache)["params"]
+        params = contract.lm_params(cfg, 1)
         # no leaf more: no gate, no norm after a sublayer
         names = {str(getattr(k, "key", k)) for path, _ in
                  jax.tree_util.tree_flatten_with_path(params)[0]
@@ -625,52 +476,38 @@ class TestAModelOfOnePassIsWhatItWas:
         # other, bit for bit, and the lowered text holds no loop that
         # theirs does not hold
         args = (tokens, jnp.int32(0), jnp.int32(12), cache)
-        served = jax.jit(lambda p, *a: module.apply({"params": p}, *a)[:2])
-        by_hand = jax.jit(lambda p, *a: layers_one_after_the_other(
-            cfg, p, *a))
-        logits, after = served(params, *args)
-        want, want_after = by_hand(params, *args)
+        logits, after = fns.served(params, *args)
+        want, want_after = fns.by_hand(params, *args)
         assert np.array_equal(np.asarray(logits), np.asarray(want))
         for name in after:
             for mine, theirs in zip(after[name], want_after[name]):
                 assert mine.shape == theirs.shape
                 assert np.array_equal(np.asarray(mine), np.asarray(theirs))
-        loops = by_hand.lower(params, *args).as_text().count(
+        loops = fns.by_hand.lower(params, *args).as_text().count(
             "stablehlo.while")
-        text = served.lower(params, *args).as_text()
+        text = fns.served.lower(params, *args).as_text()
         assert text.count("stablehlo.while") == loops
         # nothing is sown, so nothing can be read of passes
-        _, sown = module.apply({"params": params}, *args,
-                               mutable=["passes"])
+        _, sown = jax.eval_shape(lambda p: lm.DecoderLM(cfg).apply(
+            {"params": p}, *args, mutable=["passes"]), params)
         assert not sown
         # the executables return what they always did, and the decode
         # scan is the one loop around a step's own
-        prefill = jax.jit(lm.prefill_fn(module))
-        out = prefill(params, cache, tokens, jnp.int32(0), jnp.int32(12),
-                      jax.random.key(0), jnp.float32(1.0))
+        out = fns.prefill(params, cache, tokens, jnp.int32(0), jnp.int32(12),
+                          jax.random.key(0), jnp.float32(1.0))
         assert len(out) == 4
-        step_args = (tokens[:1], jnp.int32(12), jnp.int32(1), out[0])
-
-        def one_step(p, *a):
-            logits, after, _ = module.apply(
-                {"params": p, "mixers": lm.mixer_operands(p)}, *a,
-                all_logits=False)
-            return after, lm.sample(logits[0], jax.random.key(0), a[1] + 1,
-                                    jnp.float32(1.0))
-
-        step_loops = jax.jit(one_step).lower(
-            params, *step_args).as_text().count("stablehlo.while")
-        decode = jax.jit(lm.decode_chunk_fn(module, STEPS))
+        step_loops = fns.one_step.lower(
+            params, tokens[:1], jnp.int32(12), jnp.int32(1),
+            out[0]).as_text().count("stablehlo.while")
         decode_args = (params, out[0], out[1], jnp.int32(12),
                        jax.random.key(0), jnp.float32(1.0))
-        decode_text = decode.lower(*decode_args).as_text()
+        decode_text = fns.decode.lower(*decode_args).as_text()
         assert decode_text.count("stablehlo.while") == 1 + step_loops
-        assert len(decode(*decode_args)) == 6
+        assert len(fns.decode(*decode_args)) == 6
         if lm.shares_a_step(cfg):
-            many = jax.jit(lm.decode_sequences_fn(module, STEPS))
             keys = jax.random.split(jax.random.key(3), 2)
-            outs = many(params, kv.fork(out[0], 2),
-                        jnp.stack([out[1]] * 2), jnp.int32(12), keys,
-                        jnp.float32(1.0), jnp.int32(2))
+            outs = fns.many(params, kv.fork(out[0], 2),
+                            jnp.stack([out[1]] * 2), jnp.int32(12), keys,
+                            jnp.float32(1.0), jnp.int32(2))
             assert len(outs) == 7
             assert [x.ndim for x in outs[0]["k"]] == [4] * len(outs[0]["k"])

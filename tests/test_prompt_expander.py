@@ -10,9 +10,7 @@ YaRN and full plain rotary. The plain reference is the benchmark's own
 """
 
 import dataclasses
-import importlib.util
 import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -30,97 +28,60 @@ from stable_diffusion_webui_distributed_tpu.ops import (
 from stable_diffusion_webui_distributed_tpu.pipeline import expand
 from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
 from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
-    GenerationPayload, prompt_expansion_args,
+    prompt_expansion_args,
 )
-from stable_diffusion_webui_distributed_tpu.runtime import dtypes
 from stable_diffusion_webui_distributed_tpu.runtime.interrupt import (
     GenerationState,
 )
 from stable_diffusion_webui_distributed_tpu.serving.metrics import (
     EXPANDER, METRICS, PLAN,
 )
-from tests.test_pipeline import init_params
+from tests import expander_contract as contract
+from tests.expander_contract import empty, rel_rms, run, tiny_params
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+REF = contract.load_reference("laguna")
+#: ``DecoderLM.init``'s tree as it is
+CASE = contract.Case(configs.TINY_EXPAND, REF, word="rule", tolerance=1e-4)
+FAMILY, CFG = CASE.family, CASE.cfg
+params, engine = contract.fixtures(CASE)
 
 
-REF = _load(os.path.join(ROOT, "benchmarks", "reference", "laguna_ref.py"),
-            "laguna_ref_for_tests")
-FAMILY = configs.TINY_EXPAND
-CFG = FAMILY.expander
-
-
-def lm_params(cfg, seed=0):
-    module = lm.DecoderLM(cfg)
-    cache = lm.empty_cache(cfg, 8, jnp.float32)
-    return module.init(jax.random.key(seed), jnp.zeros((4,), jnp.int32),
-                       jnp.int32(0), jnp.int32(4), cache)["params"]
-
-
-@pytest.fixture(scope="module")
-def params():
-    return lm_params(CFG)
-
-
-def rel_rms(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.sqrt(np.mean((got - want) ** 2))
-                 / np.sqrt(np.mean(want ** 2)))
-
-
-class TestAgainstTheReference:
-    @pytest.mark.parametrize("size", [40, 60])
-    def test_prefill_then_cached_decode_matches_the_full_forward(
-            self, params, size):
-        """Prefix prefill, user-chunk prefill against it, then one token a
-        step through both cache kinds; the ring (8 slots) wraps several
-        times. Logits at every position against one plain forward."""
-        (ids,) = REF.inputs(FAMILY, 3, size)
-        got, chose = jax.jit(REF.program(FAMILY, dtypes.F32,
-                                         with_routing=True))(params, ids)
-        want, own = jax.jit(lambda p, i: REF.forward(
-            FAMILY, p, i, with_routing=True))(params, ids)
-        assert got.shape == want.shape == (size, CFG.vocab[1])
-        assert rel_rms(got, want) < 1e-4
-        assert np.array_equal(np.sort(chose, -1), np.sort(own, -1))
+class TestAgainstTheReference(contract.OneSequenceAgainstTheReference):
+    """Prefix prefill, user-chunk prefill against it, then one token a
+    step through both cache kinds; the ring (8 slots) wraps several times.
+    Logits at every position against one plain forward."""
+    CASE = CASE
+    test_prefill_then_cached_decode_matches_the_full_forward = \
+        contract.OneSequenceAgainstTheReference \
+        .prefill_then_decode_matches_the_full_forward
+    PARAMETERS = {
+        "test_prefill_then_cached_decode_matches_the_full_forward": [
+            ("size", [40, 60])]}
 
     def test_the_int8_control_is_further_from_the_reference(self, params):
-        (ids,) = REF.inputs(FAMILY, 3, 40)
-        want = REF.forward(FAMILY, params, ids)
-        control = jax.jit(REF.program(FAMILY, dtypes.F32, control=True))(
-            params, ids)
+        (ids,), want, _ = CASE.referred(40)
+        control = CASE.program(control=True)(params, ids)
         assert rel_rms(control, want) > 1e-3
 
     def test_a_padded_chunk_gives_what_the_exact_chunk_gives(self, params):
-        module = lm.DecoderLM(CFG)
         (ids,) = REF.inputs(FAMILY, 5, 24)
-        run = lambda t, n: module.apply(    # noqa: E731
-            {"params": params}, t, jnp.int32(0), jnp.int32(n),
-            lm.empty_cache(CFG, 32, jnp.float32), all_logits=False)
-        exact, cache_a, _ = run(ids[:19], 19)
-        padded, cache_b, _ = run(ids, 19)
+        exact, cache_a, _ = run(CFG, params, ids[:19], 0, 19, empty(CFG, 32),
+                                all_logits=False)
+        padded, cache_b, _ = run(CFG, params, ids, 0, 19, empty(CFG, 32),
+                                 all_logits=False)
         np.testing.assert_allclose(exact, padded, rtol=1e-5, atol=1e-5)
         # decoding on from both caches agrees: the pad rows left no trace
-        nxt = lambda c: module.apply(       # noqa: E731
-            {"params": params}, ids[19:20], jnp.int32(19), jnp.int32(1), c,
-            all_logits=False)[0]
+        nxt = lambda c: run(CFG, params, ids[19:20], 19, 1, c,  # noqa: E731
+                            all_logits=False)[0]
         np.testing.assert_allclose(nxt(cache_a), nxt(cache_b), rtol=1e-5,
                                    atol=1e-5)
 
     def test_reference_held_to_other_routing_differs(self, params):
-        (ids,) = REF.inputs(FAMILY, 3, 16)
-        want, own = REF.forward(FAMILY, params, ids, with_routing=True)
-        same = REF.forward(FAMILY, params, ids, forced=own)
-        np.testing.assert_allclose(same, want, rtol=1e-5, atol=1e-5)
-        other = REF.forward(FAMILY, params, ids,
-                            forced=(own + 1) % CFG.num_experts)
+        (ids,), want, own = CASE.referred(16)
+        forced = jax.jit(lambda p, i, f: REF.forward(FAMILY, p, i, forced=f))
+        np.testing.assert_allclose(forced(params, ids, own), want,
+                                   rtol=1e-5, atol=1e-5)
+        other = forced(params, ids, (own + 1) % CFG.num_experts)
         assert rel_rms(other, want) > 1e-3
 
 
@@ -255,6 +216,10 @@ class TestMasks:
                                     **extra) == attention.XLA
 
 
+ROUTED_EXPERTS = jax.jit(lambda *a: moe.routed_experts(
+    *a, first=0, num_experts=16)[0])
+
+
 class TestExperts:
     def _layer(self, tokens, seed=0, experts=16, k=4, scale=2.5):
         key = jax.random.key(seed)
@@ -293,7 +258,8 @@ class TestExperts:
             paths.append(path)
             return out
 
-        got = jax.jit(layer)(x, routing, wg, wu, wd)
+        jax.eval_shape(layer, x, routing, wg, wu, wd)   # chosen when traced
+        got = ROUTED_EXPERTS(x, routing, wg, wu, wd)
         assert paths == [moe.LOOP if tokens == 1 else moe.GROUPED]
         np.testing.assert_allclose(got, self._dense(x, routing, wg, wu, wd),
                                    rtol=2e-4, atol=2e-5)
@@ -461,7 +427,7 @@ class TestTheChosenExpertsKernel:
         """Three expert layers traced once inside the scan's body, on the
         loop: a CPU, float32, widths off the lanes."""
         module = lm.DecoderLM(CFG)
-        params = jax.eval_shape(lambda: lm_params(CFG))
+        params = contract.param_shapes(CFG)
         cache = lm.empty_cache(CFG, 16, jnp.float32)
         EXPANDER.clear()
         jax.eval_shape(lm.decode_chunk_fn(module, 4), params, cache,
@@ -646,7 +612,7 @@ class TestTheShareOfALayer:
         whole = dataclasses.replace(
             CFG, experts_held=None, vocab_held=None,
             **({} if shared else {"shared_expert_intermediate_size": 0}))
-        p = lm_params(whole, seed=4)["layers_1"]
+        p = contract.lm_params(whole, 4)["layers_1"]
         assert ("shared_expert" in p["mlp"]) == shared
         x = jax.random.normal(jax.random.key(9), (20, whole.hidden_size))
         h = x + REF._attention(whole, 1, REF._rms(
@@ -696,21 +662,14 @@ class TestSampling:
             == int(jnp.argmax(logits))
 
     def test_chunked_decode_equals_one_step_at_a_time(self, params):
-        module = lm.DecoderLM(CFG)
-        key = jax.random.key(11)
-        first = jnp.int32(CFG.vocab[0] + 3)
+        made = [contract.decoded(CFG, params, steps, calls, 64)[0]
+                for steps, calls in ((12, 1), (4, 3), (1, 12))]
+        assert made[0] == made[1] == made[2]
 
-        def run(steps, calls):
-            fn = jax.jit(lm.decode_chunk_fn(module, steps))
-            cache = lm.empty_cache(CFG, 64, jnp.float32)
-            token, position, made = first, jnp.int32(0), []
-            for _ in range(calls):
-                cache, token, position, out, _, _ = fn(
-                    params, cache, token, position, key, jnp.float32(1.0))
-                made += np.asarray(out).tolist()
-            return made
 
-        assert run(12, 1) == run(4, 3) == run(1, 12)
+COPY_TREE = jax.jit(kv.copy_tree)
+ZEROED_IN_PLACE = jax.jit(lambda tree: jax.tree_util.tree_map(
+    lambda x: x * 0, tree), donate_argnums=(0,))
 
 
 class TestTokenizerAndCache:
@@ -756,7 +715,7 @@ class TestTokenizerAndCache:
         through the engine's cache of stages), and what is held survives
         the copy's donation."""
         calls = []
-        copy = jax.jit(kv.copy_tree)
+        copy = COPY_TREE
 
         def copier(capacity):
             def counted(tree):
@@ -777,8 +736,7 @@ class TestTokenizerAndCache:
             assert calls == [256, 256]
         # the copy goes the way of every cache, into a donating executable
         # (where the backend does not take a donation, by hand)
-        spent = jax.jit(lambda tree: jax.tree_util.tree_map(
-            lambda x: x * 0, tree), donate_argnums=(0,))(again)
+        spent = ZEROED_IN_PLACE(again)
         for leaf in jax.tree_util.tree_leaves(again):
             if not leaf.is_deleted():
                 leaf.delete()
@@ -792,45 +750,21 @@ class TestTokenizerAndCache:
         assert manager.positions_in_use(40) == {"full": 80, "sliding": 16}
 
 
-INSTRUCTION = " ".join(f"rule{i}" for i in range(30))
-
-
-def script(**args):
-    return {"prompt expansion": {"args": [dict(
-        {"instruction": INSTRUCTION, "max_new_tokens": 40,
-         "temperature": 1.0, "ignore_eos": True, "context_chunks": 1},
-        **args)]}}
-
-
-@pytest.fixture(scope="module")
-def engine():
-    params = init_params(configs.TINY)
-    params["expander"] = lm_params(CFG, seed=1)
-    return Engine(FAMILY, params, chunk_size=4, state=GenerationState())
-
-
-def payload(**kw):
-    base = dict(prompt="a cow in a valley", steps=4, width=32, height=32,
-                seed=1234, alwayson_scripts=script())
-    base.update(kw)
-    return GenerationPayload(**base)
-
-
 class TestEnginePath:
     def test_the_script_is_parsed_from_alwayson_scripts(self):
-        args = prompt_expansion_args(payload())
+        args = prompt_expansion_args(CASE.payload())
         assert args.max_new_tokens == 40 and args.context_chunks == 1
-        assert prompt_expansion_args(payload(alwayson_scripts={})) is None
-        off = payload(alwayson_scripts=script(max_new_tokens=0))
+        assert prompt_expansion_args(CASE.payload(alwayson_scripts={})) is None
+        off = CASE.payload(alwayson_scripts=CASE.script(max_new_tokens=0))
         assert prompt_expansion_args(off) is None
-        assert prompt_expansion_args(payload(alwayson_scripts={
+        assert prompt_expansion_args(CASE.payload(alwayson_scripts={
             "Prompt Expansion": {"args": []}})).max_new_tokens == 64
 
     def test_expanded_txt2img_repeats_and_differs_from_plain(self, engine):
         EXPANDER.clear()
-        a = engine.txt2img(payload())
-        b = engine.txt2img(payload())
-        plain = engine.txt2img(payload(alwayson_scripts={}))
+        a = engine.txt2img(CASE.payload())
+        b = engine.txt2img(CASE.payload())
+        plain = engine.txt2img(CASE.payload(alwayson_scripts={}))
         assert a.images == b.images and a.prompts == b.prompts
         assert a.images != plain.images
         assert plain.prompts == ["a cow in a valley"]
@@ -856,7 +790,7 @@ class TestEnginePath:
         ``lm.decode_chunk_fn`` under its old key, lowered text and all;
         nothing of the several-sequence path is in its way."""
         EXPANDER.clear()
-        engine.txt2img(payload())
+        engine.txt2img(CASE.payload())
         (key,) = [k for k in engine.executable_keys()
                   if k[0] == "expand_decode_chunk"]
         _, steps, capacity = key                # no sequence count
@@ -873,49 +807,49 @@ class TestEnginePath:
             "kernel": 0, "loop": 3, "grouped": 0}
 
     def test_another_seed_gets_another_expansion(self, engine):
-        a = engine.txt2img(payload())
-        b = engine.txt2img(payload(seed=99))
+        a = engine.txt2img(CASE.payload())
+        b = engine.txt2img(CASE.payload(seed=99))
         assert a.prompts != b.prompts
 
     def test_a_batch_expands_each_image_by_its_own_seed(self, engine):
-        both = engine.txt2img(payload(batch_size=2))
-        second = engine.generate_range(payload(batch_size=2), 1, 1)
+        both = engine.txt2img(CASE.payload(batch_size=2))
+        second = engine.generate_range(CASE.payload(batch_size=2), 1, 1)
         assert both.prompts[0] != both.prompts[1]
         assert second.prompts == both.prompts[1:]
         assert second.images == both.images[1:]
-        solo = engine.txt2img(payload(seed=1235))
+        solo = engine.txt2img(CASE.payload(seed=1235))
         assert solo.prompts[0] == both.prompts[1]
 
     def test_context_chunks_keeps_the_tail(self, engine):
-        long = engine.txt2img(payload(alwayson_scripts=script(
+        long = engine.txt2img(CASE.payload(alwayson_scripts=CASE.script(
             max_new_tokens=100)))
         words = long.prompts[0].split()
         assert len(words) == 75 and "cow" not in words
 
     def test_eos_ends_the_expansion(self, engine, monkeypatch):
-        full = engine.txt2img(payload()).prompts[0].split()[5:]
+        full = engine.txt2img(CASE.payload()).prompts[0].split()[5:]
         eos = int(full[9][1:])
         monkeypatch.setattr(engine.expander.tokenizer, "eos", eos)
-        cut = engine.txt2img(payload(alwayson_scripts=script(
+        cut = engine.txt2img(CASE.payload(alwayson_scripts=CASE.script(
             ignore_eos=False))).prompts[0].split()[5:]
         assert cut == full[:full.index(f"w{eos}")]
 
     def test_the_stage_builds_its_executables_through_the_engines_cache(
             self, engine):
-        engine.txt2img(payload())
+        engine.txt2img(CASE.payload())
         kinds = {k[0] for k in engine.executable_keys()}
         assert {"expand_prefill", "expand_decode_chunk"} <= kinds
         before = dict(METRICS.summary()["compiles"])
-        engine.txt2img(payload(prompt="another prompt of five"))
+        engine.txt2img(CASE.payload(prompt="another prompt of five"))
         assert METRICS.summary()["compiles"] == before
 
     def test_spans(self, engine):
         from stable_diffusion_webui_distributed_tpu.obs import spans
 
-        engine.txt2img(payload())       # the instruction's snapshot is kept
+        engine.txt2img(CASE.payload())       # the instruction's snapshot is kept
         spans.TRACER.clear()
         with spans.request("rid-expand"):
-            engine.txt2img(payload())
+            engine.txt2img(CASE.payload())
         events = [e for e in spans.TRACER.export_chrome()["traceEvents"]
                   if e.get("ph") == "X"]
         names = [e["name"] for e in events]
@@ -962,14 +896,14 @@ class TestEnginePath:
     def test_a_family_without_an_expander_is_untouched(self):
         from stable_diffusion_webui_distributed_tpu.obs import spans
 
-        plain = Engine(configs.TINY, init_params(configs.TINY),
-                       chunk_size=4, state=GenerationState())
+        plain = Engine(configs.TINY, dict(tiny_params()), chunk_size=4,
+                       state=GenerationState())
         assert plain.expander is None
         before = PLAN.summary()["ahead"]
-        without = plain.txt2img(payload(alwayson_scripts={}))
+        without = plain.txt2img(CASE.payload(alwayson_scripts={}))
         spans.TRACER.clear()
         with spans.request("rid-plain"):
-            with_script = plain.txt2img(payload())
+            with_script = plain.txt2img(CASE.payload())
         assert with_script.images == without.images
         assert with_script.prompts == ["a cow in a valley"]
         kinds = {k[0] for k in plain.executable_keys()}
@@ -1000,7 +934,7 @@ class TestEnginePath:
             ("png_encode", "generate_range")]
 
     def test_status_block(self, engine):
-        engine.txt2img(payload())
+        engine.txt2img(CASE.payload())
         block = METRICS.summary()["expander"]
         assert set(block) == {
             "requests", "tokens_prefilled", "tokens_from_prefix_cache",
@@ -1083,8 +1017,8 @@ class TestDrawnAhead:
     def test_the_images_are_what_the_old_order_gives(self, engine,
                                                      monkeypatch, images):
         assert engine.expander.shares_a_step
-        request = payload(batch_size=images, subseed=7)
-        engine.txt2img(payload())       # the instruction's snapshot is kept
+        request = CASE.payload(batch_size=images, subseed=7)
+        engine.txt2img(CASE.payload())       # the instruction's snapshot is kept
         before = PLAN.summary()["ahead"]
         EXPANDER.clear()
         ahead = engine.txt2img(request)
@@ -1107,8 +1041,8 @@ class TestDrawnAhead:
     def test_an_expansion_without_a_decode_chunk_draws_nothing(
             self, engine, monkeypatch):
         """``max_new_tokens`` 1: the one token comes from the prefill."""
-        request = payload(subseed=7,
-                          alwayson_scripts=script(max_new_tokens=1))
+        request = CASE.payload(subseed=7,
+                          alwayson_scripts=CASE.script(max_new_tokens=1))
         before = PLAN.summary()["ahead"]
         EXPANDER.clear()
         one = engine.txt2img(request)
@@ -1120,7 +1054,7 @@ class TestDrawnAhead:
 
     def test_an_interrupt_during_the_expansion_drops_the_draw(
             self, engine, monkeypatch):
-        whole = engine.txt2img(payload(subseed=7))
+        whole = engine.txt2img(CASE.payload(subseed=7))
         real = engine.expander._decode_fn
 
         def then_interrupt(capacity, sequences=1):
@@ -1133,7 +1067,7 @@ class TestDrawnAhead:
         EXPANDER.clear()
         with monkeypatch.context() as patch:
             patch.setattr(engine.expander, "_decode_fn", then_interrupt)
-            cut = engine.txt2img(payload(subseed=7))
+            cut = engine.txt2img(CASE.payload(subseed=7))
         assert self.ahead_since(before) == [1, 0, 1]
         assert cut.images == []
         # the counters of the request that enqueued no chunk are there
@@ -1141,7 +1075,7 @@ class TestDrawnAhead:
         assert stats["requests"] == 1
         assert stats["decode_steps"] == expand.DECODE_STEPS
         # and the next request is a whole one
-        assert engine.txt2img(payload(subseed=7)).model_dump() \
+        assert engine.txt2img(CASE.payload(subseed=7)).model_dump() \
             == whole.model_dump()
         assert self.ahead_since(before) == [2, 1, 1]
 
@@ -1164,7 +1098,7 @@ class TestDrawnAhead:
         before = PLAN.summary()["ahead"]
         EXPANDER.clear()
         out = engine.generate_range(
-            payload(**request), 0, None,
+            CASE.payload(**request), 0, None,
             "img2img" if job == "img2img" else "txt2img")
         assert self.ahead_since(before) == [0, 0, 0]
         assert len(out.images) == 1 and len(out.prompts[0].split()) == 45
@@ -1206,11 +1140,11 @@ class TestDispatcher:
         stub.engine = engine
         stub._traced_rowspec = lambda p: (0, 0)
         assert ServingDispatcher._coalescable(
-            stub, payload(alwayson_scripts={}))
-        assert not ServingDispatcher._coalescable(stub, payload())
-        stub.engine = Engine(configs.TINY, init_params(configs.TINY),
+            stub, CASE.payload(alwayson_scripts={}))
+        assert not ServingDispatcher._coalescable(stub, CASE.payload())
+        stub.engine = Engine(configs.TINY, dict(tiny_params()),
                              state=GenerationState())
-        assert ServingDispatcher._coalescable(stub, payload())
+        assert ServingDispatcher._coalescable(stub, CASE.payload())
 
 
 class TestSharding:
@@ -1233,7 +1167,7 @@ class TestSharding:
         devices = np.array(jax.devices()[:4]).reshape(2, 2)
         mesh = jax.sharding.Mesh(devices, ("ep", "vp"))
         placed = shard_params(
-            lm_params(getattr(configs, preset).expander), mesh)
+            contract.lm_params(getattr(configs, preset).expander), mesh)
         gate = placed["layers_1"]["mlp"]["experts"]["w_gate"]
         assert gate.sharding.spec == P("ep", None, None)
         assert placed["lm_head"]["kernel"].sharding.spec == P(None, "vp")

@@ -216,6 +216,10 @@ class TestSpanTree:
         before = get(server, "/internal/status")["serving"]["host"]
         time.sleep(0.1)
         post(server, "/sdapi/v1/txt2img", dict(body, request_id="gap-1"))
+        # the server ends an exchange after it has answered: under six
+        # loaded workers the next accept can come first, and then has no
+        # gap to count (once in three whole runs, PR 61)
+        time.sleep(0.1)
         after = get(server, "/internal/status")["serving"]["host"]
         tops = {e["name"]: e for e in events_of(server, "gap-1")
                 if "parent_id" not in e["args"]}
