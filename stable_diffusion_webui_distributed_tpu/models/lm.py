@@ -881,11 +881,14 @@ class DeltaMixer(nn.Module):
     value width)`` float32; ``conv`` holds the convolution's last
     ``taps - 1`` real inputs. ``sequences``: row ``b`` is sequence ``b``'s
     one token, ``state`` and ``conv`` carry the sequences in front, each
-    row steps its own, and a row that is not ``real`` leaves both."""
+    row steps its own, and a row that is not ``real`` leaves both, by the
+    Pallas kernel or element-wise as ``delta_rule.step_form`` says of the
+    state (``meshed``: a mesh partitions the program, so element-wise)."""
 
     config: LMConfig
     dtype: jnp.dtype = jnp.float32
     quant: bool = False
+    meshed: bool = False
 
     @nn.compact
     def __call__(self, n, real, length, state, conv,
@@ -934,8 +937,12 @@ class DeltaMixer(nn.Module):
             real[:, None], jax.nn.sigmoid(b) if scale == 1.0
             else scale * jax.nn.sigmoid(b), 0.0)
         # computed in float32 whatever the buffer holds
-        step = delta_rule.recurrent_step_each if sequences \
-            else delta_rule.gated_delta_rule
+        step = delta_rule.gated_delta_rule
+        if sequences:
+            path = delta_rule.step_form(jax.default_backend(), state.dtype,
+                                        state.shape, meshed=self.meshed)
+            EXPANDER.record_delta_step(path)
+            step = delta_rule.FORKED_STEPS[path]
         out, after = step(
             state.astype(f32), unit(q) * k_dim ** -0.5, unit(k),
             v.reshape(tokens, v_heads, v_dim), g, beta)
@@ -1018,7 +1025,8 @@ class DecoderLayer(nn.Module):
             """(mixed, the layer's buffers as the chunk leaves them)."""
             if kind == LINEAR:
                 mixed, *after = DeltaMixer(
-                    cfg, self.dtype, self.quant, name="delta")(
+                    cfg, self.dtype, self.quant, self.meshed,
+                    name="delta")(
                         n, counted(), end - start, *buffers,
                         sequences=sequences)
             elif kind == CONV:

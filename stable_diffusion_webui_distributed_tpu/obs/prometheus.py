@@ -867,6 +867,13 @@ def render() -> str:
         "every one over a state of its own).",
         [(f'form="{_label(form)}"', n)
          for form, n in sorted(expander["delta_mixers"].items())])
+    _labeled_family(
+        lines, "sdtpu_expander_delta_steps_total", "counter",
+        "Forked gated-delta-rule mixers traced, by the step that moves "
+        "their states (kernel: one Pallas kernel that holds a head's state "
+        "in VMEM; elementwise: XLA's fusions).",
+        [(f'step="{_label(step)}"', n)
+         for step, n in sorted(expander["delta_steps"].items())])
     _scalar(lines, "sdtpu_expander_state_bytes_stepped_total", "counter",
             "Bytes of linear layers' recurrent states and kept inputs the "
             "prompt expander's decode steps read and wrote.",

@@ -11,16 +11,29 @@ be equal), a log-decay ``g <= 0`` and a write strength ``beta`` in (0, 2)::
 unit ``k``: a caller whose ``beta`` is ``sigmoid(b)`` stays in (0, 1), one
 whose is ``2 sigmoid(b)`` also reflects; no form below assumes either).
 
-Two forms of the same mathematics, chosen by what the call shows (the
-number of tokens, which is static), as ops/moe.py chooses its product:
+Three forms of the same mathematics, chosen by what the call shows (the
+number of tokens and of sequences, the platform, the state's dtype and
+shape, all static, and whether a mesh will partition the program), as
+ops/moe.py chooses its product:
 
-- ``T == 1`` (a decode step): the recurrence as written, element-wise
-  products and sums over the state, so float32 stays float32 on a TPU.
-  Several sequences a step (:func:`recurrent_step_each`: row ``b`` is
-  sequence ``b``'s one token over its OWN state ``(B, H, K, V)``) are the
-  same step once a sequence, nothing shared between them: a state has no
-  positions, so unlike keys, values or latents no part of it can be read
-  once for all.
+- ``T == 1`` (a decode step of one sequence): the recurrence as written
+  (:func:`recurrent_step`), element-wise products and sums over the state,
+  so float32 stays float32 on a TPU.
+- several sequences a step (row ``b`` is sequence ``b``'s one token over
+  its OWN state ``(B, H, K, V)``): the same step once a sequence, nothing
+  shared between them: a state has no positions, so unlike keys, values or
+  latents no part of it can be read once for all. :func:`step_form` picks
+  how: on a TPU, with no mesh, over a float32 state whose value width is a
+  multiple of the 128 lanes (and whose key width and heads tile the
+  sublanes), ``"kernel"``: one Pallas kernel (ops/delta_kernel.py) that
+  reads a block of heads' states into VMEM, runs the step's five lines
+  there in float32 on the VPU and stores the block, one read and one write
+  of the state where XLA's two fusions read it twice. Everywhere else (a
+  CPU, a mesh: ``pjit`` does not partition a ``pallas_call``, a state kept
+  in another dtype, a value width off the lanes such as Olmo-Hybrid's 192,
+  which XLA keeps on chip between steps in eight layers of twelve: PERF.md
+  section 7), ``"elementwise"``: :func:`recurrent_step_each`, the one-
+  sequence step under ``vmap``.
 - ``T > 1`` (prefill): the chunk-wise form. Tokens are cut into chunks of
   :data:`CHUNK`; inside a chunk the writes depend on each other through a
   unit lower-triangular system, solved row by row as the published
@@ -39,6 +52,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from stable_diffusion_webui_distributed_tpu.ops import delta_kernel
 
 #: tokens of one chunk of the chunk-wise form
 CHUNK = 64
@@ -148,6 +163,7 @@ def gated_delta_rule(state, q, k, v, g, beta):
 
 
 RECURRENT, CHUNKED, FORKED = "recurrent", "chunked", "recurrent_forked"
+KERNEL, ELEMENTWISE = "kernel", "elementwise"
 
 
 def form(tokens: int, sequences: bool = False) -> str:
@@ -157,3 +173,24 @@ def form(tokens: int, sequences: bool = False) -> str:
     if sequences:
         return FORKED
     return RECURRENT if tokens == 1 else CHUNKED
+
+
+def step_form(platform: str, dtype, shape, *, meshed: bool = False) -> str:
+    """``"kernel"`` or ``"elementwise"`` for one forked step over a state
+    of ``dtype`` and ``shape`` ``(B, H, K, V)``, from what the call shows.
+    The kernel wants a TPU, a program no mesh partitions, several sequences
+    a step, a float32 state and a shape that tiles
+    (ops/delta_kernel.py:head_block: ``V`` a multiple of the 128 lanes,
+    ``K`` and the heads of the 8 sublanes)."""
+    sequences, heads, k_dim, v_dim = shape
+    if (platform == "tpu" and not meshed and sequences > 1
+            and jnp.dtype(dtype) == jnp.float32
+            and delta_kernel.head_block(heads, k_dim, v_dim) is not None):
+        return KERNEL
+    return ELEMENTWISE
+
+
+#: the forked step by the form :func:`step_form` names: the same operands,
+#: ``(o (B, H, V), state)``
+FORKED_STEPS = {KERNEL: delta_kernel.recurrent_step_each,
+                ELEMENTWISE: recurrent_step_each}

@@ -444,6 +444,7 @@ def replay_sites(rows) -> None:
                 "mixer": EXPANDER.record_mixer,
                 "conv": EXPANDER.record_conv,
                 "delta": EXPANDER.record_delta,
+                "delta_step": EXPANDER.record_delta_step,
                 "norm": EXPANDER.record_norm,
                 "unrotated": EXPANDER.record_unrotated}
     for counter, *args in rows:
@@ -585,9 +586,13 @@ class ExpanderStats:
     by the form their recurrence took (ops/delta_rule.py:form:
     ``recurrent`` one token, ``chunked`` a longer chunk,
     ``recurrent_forked`` one token each of several sequences, every one
-    over a state of its own). ``state_bytes_stepped`` is what the decode
-    steps read and wrote of linear layers' recurrent states and kept
-    inputs (a step reads and writes each of its sequences' once a layer),
+    over a state of its own), and ``delta_steps`` the ``recurrent_forked``
+    ones again by the step ops/delta_rule.py:step_form gave them:
+    ``kernel`` (ops/delta_kernel.py: a head's state held in VMEM across its
+    decay, write and read) or ``elementwise`` (XLA's fusions).
+    ``state_bytes_stepped`` is what the decode steps read and wrote of
+    linear layers' recurrent states and kept inputs (a step reads and
+    writes each of its sequences' once a layer),
     ``fork_bytes_copied`` what forks copied of them (once a sequence; a
     buffer that keeps positions is never copied).
     ``sublayer_norms`` counts, the same way and by the same three forms of
@@ -639,6 +644,8 @@ class ExpanderStats:
             self.convs = {"step": 0, "chunk": 0}  # guarded-by: _lock
             self.deltas = {"recurrent": 0, "chunked": 0,
                            "recurrent_forked": 0}  # guarded-by: _lock
+            self.delta_steps = {"kernel": 0,
+                                "elementwise": 0}  # guarded-by: _lock
             self.state_bytes_stepped = 0  # guarded-by: _lock
             self.fork_bytes_copied = 0  # guarded-by: _lock
             self.norms = {placement: dict.fromkeys(self.deltas, 0)
@@ -671,6 +678,13 @@ class ExpanderStats:
         with self._lock:
             self.deltas[form] += 1
             self.write_bound = float(bound)
+
+    def record_delta_step(self, path: str) -> None:
+        """One forked gated-delta-rule mixer in one trace stepped its
+        states by ``path``."""
+        _note_site("delta_step", str(path))
+        with self._lock:
+            self.delta_steps[path] += 1
 
     def record_norm(self, placement: str, form: str) -> None:
         """One sublayer norm in one trace of an executable of ``form``,
@@ -763,6 +777,7 @@ class ExpanderStats:
                 "mixer_products": dict(self.mixers),
                 "conv_mixers": dict(self.convs),
                 "delta_mixers": dict(self.deltas),
+                "delta_steps": dict(self.delta_steps),
                 "state_bytes_stepped": self.state_bytes_stepped,
                 "fork_bytes_copied": self.fork_bytes_copied,
                 "sublayer_norms": {placement: dict(by_form) for

@@ -1,6 +1,7 @@
 """The Pallas kernels (both attention kernels, the chosen experts' sum of a
-decode step, a residual-stream mixer's two) and one folded upsample site,
-compiled by the TPU's own compiler.
+decode step, a residual-stream mixer's two, the forked step of the gated
+delta rule) and one folded upsample site, compiled by the TPU's own
+compiler.
 
 Interpret mode (tests/test_ops.py, tests/test_ragged.py) checks the math;
 it cannot see what Mosaic refuses: a slice off the tiling, too much VMEM.
@@ -27,7 +28,9 @@ from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
-from stable_diffusion_webui_distributed_tpu.ops import moe_kernel, stream_mixer
+from stable_diffusion_webui_distributed_tpu.ops import (
+    delta_kernel, moe_kernel, stream_mixer,
+)
 from stable_diffusion_webui_distributed_tpu.ops.flash_attention import (
     flash_attention,
 )
@@ -354,6 +357,36 @@ def test_stream_mixer_kernels_compile_for_v5e(one_chip, stored):
     assert text.count("tpu_custom_call") == 2
 
 
+#: a recurrent state of the GigaChat3.5 share copied as an HLO instruction
+_STATE_COPY = re.compile(r"= f32\[4,64,128,128\]\S* copy\(")
+
+
+@pytest.mark.parametrize("shape", [(2, 64, 128, 128), (4, 64, 128, 128),
+                                   (8, 64, 128, 128), (4, 16, 64, 256)])
+def test_forked_delta_step_kernel_compiles_for_v5e(one_chip, shape):
+    """The published 64 heads of 128 x 128 float32, 2, 4 or 8 sequences a
+    step, and a state two lane tiles wide: the keys' and queries' ``(heads,
+    K)`` blocks transposed in the kernel, a head's column broadcast down
+    the lanes, the state aliased in to out (a donated state is stepped in
+    place: no copy of it, nothing temporary of its size)."""
+    def on_chip(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    b, h, k_dim, v_dim = shape
+    compiled = jax.jit(
+        lambda *a: delta_kernel.recurrent_step_each(*a, interpret=False),
+        donate_argnums=(0,)).lower(
+            on_chip(*shape), on_chip(b, h, k_dim), on_chip(b, h, k_dim),
+            on_chip(b, h, v_dim), on_chip(b, h), on_chip(b, h)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert " copy(" not in text.replace("copy_bitcast", "")
+    memory = compiled.memory_analysis()
+    state = b * h * k_dim * v_dim * 4
+    assert memory.alias_size_in_bytes == state
+    assert memory.temp_size_in_bytes < state // 8
+
+
 def _forked_on(chip, cache, sequences, own_slots):
     """``cache``'s shapes forked (cache/kv.py:fork), placed on ``chip``."""
     from stable_diffusion_webui_distributed_tpu.cache import kv
@@ -435,17 +468,21 @@ EXPANDER_EXECUTABLES = [
     ("prefill", "sd15_kanana2_expander", 2560, 10.15, 0, 400, 23),
     # four clamped expert kernels (the seventh published shape, 7168 x
     # 2048, tile 256) behind one forked latent attention and four delta
-    # mixers that step a state a sequence: four sequences donate the one
+    # mixers that step a state a sequence, each through the kernel that
+    # holds a head's state in VMEM (ops/delta_kernel.py: eight kernels in
+    # all, and no copy of a state in the scan): four sequences donate the one
     # sequence's 2.9 MB of latents (shared, handed through), 256 own slots
     # each and sixteen states with their kept rows, 70 MB; the prompt's
     # 64-token chunk chunk-wise over four states and expanded over 2 560
     # latents; the instruction's one chunk of 2 048
     # (1.66 GB of temporaries: 64 heads' scores over 2 560 latents)
-    ("decode4", "sd15_gigachat35_expander", 2560, 9.46, 4, 64, 70),
+    ("decode4", "sd15_gigachat35_expander", 2560, 9.46, 8, 64, 70),
     ("prefill", "sd15_gigachat35_expander", 2560, 9.46, 0, 400, 20),
     ("prefill2048", "sd15_gigachat35_expander", 2560, 9.46, 0, 2000, 20),
     # no kernel at all: twelve delta mixers that step a (30, 96, 192) state
-    # a sequence at strength up to 2 beside four unrotated attentions of 30
+    # a sequence at strength up to 2 (element-wise: a 192-wide state is off
+    # the lanes, ops/delta_rule.py:step_form) beside four unrotated
+    # attentions of 30
     # ungrouped heads; four sequences donate the one sequence's 157 MB of
     # keys and values (shared, handed through), 256 own slots a layer each
     # and forty-eight states with their kept rows, 333 MB; the prompt's
@@ -521,8 +558,10 @@ def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
             params, cache, on_chip((tokens,), jnp.int32), scalar, scalar,
             key, heat)
     compiled = lowered.compile()
-    calls = compiled.as_text().count("tpu_custom_call")
+    text = compiled.as_text()
+    calls = text.count("tpu_custom_call")
     assert calls >= kernels and bool(calls) == bool(kernels)
+    assert not _STATE_COPY.search(text)
     memory = compiled.memory_analysis()
     assert argument_gb * 1e9 < memory.argument_size_in_bytes \
         < (argument_gb + 0.1) * 1e9

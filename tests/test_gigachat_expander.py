@@ -25,7 +25,9 @@ import pytest
 from stable_diffusion_webui_distributed_tpu.cache import kv
 from stable_diffusion_webui_distributed_tpu.models import configs, lm
 from stable_diffusion_webui_distributed_tpu.obs import prometheus
-from stable_diffusion_webui_distributed_tpu.ops import delta_rule, moe, moe_kernel
+from stable_diffusion_webui_distributed_tpu.ops import (
+    delta_kernel, delta_rule, moe, moe_kernel,
+)
 from stable_diffusion_webui_distributed_tpu.serving.metrics import (
     ATTENTION, EXPANDER, METRICS,
 )
@@ -256,6 +258,101 @@ class TestSequencesOfOneStep(contract.SequencesOfOneStep,
             assert np.array_equal(after[b], a if real[b] else kept[b])
 
 
+# -- (b') the forked step as one kernel ----------------------------------------
+
+@pytest.mark.parametrize("strength", [1.0, 2.0])
+@pytest.mark.parametrize("shape", [(4, 8, 128, 128), (2, 16, 128, 128),
+                                   (4, 8, 128, 256)])
+def test_the_kernel_steps_as_the_elementwise_form(shape, strength):
+    """ops/delta_kernel.py in interpret mode against
+    ``recurrent_step_each`` at unit keys, at write strengths under 1 and
+    under 2: the sums over ``K`` run in another order and nothing else
+    differs; a masked sequence's state comes back bit for bit."""
+    b, h, k_dim, v_dim = shape
+    ks = jax.random.split(jax.random.key(b * h + v_dim), 6)
+
+    def unit(x):
+        return x * jax.lax.rsqrt(
+            jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+    state = jax.random.normal(ks[0], shape)
+    q = unit(jax.random.normal(ks[1], (b, h, k_dim))) * k_dim ** -0.5
+    k = unit(jax.random.normal(ks[2], (b, h, k_dim)))
+    v = jax.random.normal(ks[3], (b, h, v_dim))
+    masked = jnp.arange(b)[:, None] == 1
+    g = jnp.where(masked, 0.0,
+                  -jax.nn.softplus(jax.random.normal(ks[4], (b, h))))
+    beta = jnp.where(masked, 0.0, strength * jax.nn.sigmoid(
+        jax.random.normal(ks[5], (b, h))))
+    want_out, want = delta_rule.recurrent_step_each(state, q, k, v, g, beta)
+    out, after = delta_kernel.recurrent_step_each(state, q, k, v, g, beta,
+                                                  interpret=True)
+    assert after.dtype == jnp.float32 and out.shape == (b, h, v_dim)
+    np.testing.assert_allclose(after, want, rtol=0, atol=5e-6)
+    np.testing.assert_allclose(out, want_out, rtol=0, atol=5e-6)
+    assert np.array_equal(after[1], state[1])
+    assert not np.array_equal(after[0], state[0])
+
+
+@pytest.mark.parametrize("platform,meshed,dtype,shape,form", [
+    ("tpu", False, jnp.float32, (4, 64, 128, 128), "kernel"),   # published
+    ("tpu", False, jnp.float32, (2, 64, 128, 128), "kernel"),
+    ("tpu", False, jnp.float32, (8, 16, 64, 256), "kernel"),
+    ("cpu", False, jnp.float32, (4, 64, 128, 128), "elementwise"),
+    ("tpu", True, jnp.float32, (4, 64, 128, 128), "elementwise"),
+    # one sequence: nothing to batch, ``recurrent_step``'s own business
+    ("tpu", False, jnp.float32, (1, 64, 128, 128), "elementwise"),
+    # Olmo-Hybrid's: V off the lanes (and 30 heads off the sublanes)
+    ("tpu", False, jnp.float32, (4, 30, 96, 192), "elementwise"),
+    ("tpu", False, jnp.float32, (4, 32, 96, 192), "elementwise"),
+    ("tpu", False, jnp.float32, (4, 30, 96, 256), "elementwise"),
+    ("tpu", False, jnp.float32, (4, 32, 100, 128), "elementwise"),
+    # the lower-precision control's state
+    ("tpu", False, jnp.bfloat16, (4, 64, 128, 128), "elementwise"),
+    # the tiny presets' on the chip
+    ("tpu", False, jnp.float32, (4, 4, 8, 8), "elementwise"),
+])
+def test_the_rule_that_picks_the_forked_step(platform, meshed, dtype, shape,
+                                             form):
+    assert delta_rule.step_form(platform, dtype, shape,
+                                meshed=meshed) == form
+    assert set(delta_rule.FORKED_STEPS) == {"kernel", "elementwise"}
+
+
+def test_a_grid_steps_heads_fit_its_share_of_vmem():
+    # the published 64 heads of 128 x 128: as many as fit twice in and out
+    block = delta_kernel.head_block(64, 128, 128)
+    assert 64 % block == 0 and block % 8 == 0
+    assert 4 * block * 128 * 128 * 4 <= delta_kernel._STATE_VMEM
+    assert delta_kernel.head_block(8, 128, 128) == 8
+    assert delta_kernel.head_block(8, 4096, 1024) is None   # none fits
+    with pytest.raises(ValueError, match="does not tile"):
+        delta_kernel.recurrent_step_each(
+            jnp.zeros((2, 30, 96, 192)), *(jnp.zeros((2, 30, w))
+                                           for w in (96, 96, 192)),
+            jnp.zeros((2, 30)), jnp.zeros((2, 30)), interpret=True)
+
+
+def test_a_warm_start_counts_the_steps_again():
+    """What a trace counted is replayed when its program is loaded
+    (serving/aot.py): the benchmark's ``delta_kernel_sites`` reads the
+    same after a warm start."""
+    from stable_diffusion_webui_distributed_tpu.serving import metrics
+
+    EXPANDER.clear()
+    with metrics.capture_sites() as rows:
+        for _ in range(4):
+            EXPANDER.record_delta_step("kernel")
+        EXPANDER.record_delta_step("elementwise")
+    assert rows == [["delta_step", "kernel"]] * 4 \
+        + [["delta_step", "elementwise"]]
+    EXPANDER.clear()
+    metrics.replay_sites(rows)
+    assert EXPANDER.summary()["delta_steps"] == {"kernel": 4,
+                                                 "elementwise": 1}
+    EXPANDER.clear()
+
+
 # -- (c) the delta-rule configuration through the shared step ------------------
 
 SHARED, OWN = 21, 12
@@ -446,6 +543,8 @@ class TestEnginePath(contract.ForkedEnginePath):
             == {"latent_forked": 1}
         assert traced["delta_mixers"] == {"recurrent": 0, "chunked": 8,
                                           "recurrent_forked": 4}
+        # a CPU: every forked mixer steps its states element-wise
+        assert traced["delta_steps"] == {"kernel": 0, "elementwise": 4}
 
     def check_counted(self, stats, sizes, one):
         # a quarter of the experts is held: some tokens find none
@@ -461,6 +560,11 @@ class TestEnginePath(contract.ForkedEnginePath):
         text = prometheus.render()
         assert 'sdtpu_expander_delta_mixers_total{form="recurrent_forked"}' \
             in text
+        # counted when traced (``check_traced``), rendered as they stand
+        assert set(stats["delta_steps"]) == {"kernel", "elementwise"}
+        for step, n in stats["delta_steps"].items():
+            assert f'sdtpu_expander_delta_steps_total{{step="{step}"}} {n}' \
+                in text
         assert "sdtpu_expander_state_bytes_stepped_total " \
             f"{stats['state_bytes_stepped']}" in text
         assert f"sdtpu_expander_fork_bytes_copied_total {16 * STATE}" in text
@@ -490,7 +594,8 @@ class TestEnginePath(contract.ForkedEnginePath):
 
     def test_the_status_keys(self, engine):
         summary = METRICS.summary()["expander"]
-        assert {"delta_mixers", "state_bytes_stepped", "fork_bytes_copied",
+        assert {"delta_mixers", "delta_steps", "state_bytes_stepped",
+                "fork_bytes_copied",
                 "conv_mixers", "experts_read", "expert_products",
                 "tokens_no_held_expert"} <= set(summary)
 
@@ -594,6 +699,9 @@ class TestThePublishedShare:
                                             "grouped": 0}
         assert stats["delta_mixers"] == {"recurrent": 0, "chunked": 0,
                                          "recurrent_forked": 4}
+        # every forked mixer's (4, 64, 128, 128) float32 states through
+        # the kernel that holds a head's state in VMEM
+        assert stats["delta_steps"] == {"kernel": 4, "elementwise": 0}
         assert ATTENTION.summary()["by_shape"] == {
             "T4 S2560+256 D576": {"latent_forked": 1}}
         # a prefill chunk keeps the grouped product, the chunk-wise rule
@@ -604,6 +712,7 @@ class TestThePublishedShare:
         stats = EXPANDER.summary()
         assert stats["expert_products"]["grouped"] == 4
         assert stats["delta_mixers"]["chunked"] == 4
+        assert stats["delta_steps"] == {"kernel": 4, "elementwise": 0}
         assert ATTENTION.summary()["latent_expanded"] == 1
         ATTENTION.clear()
         EXPANDER.clear()

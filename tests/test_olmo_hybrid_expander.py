@@ -330,6 +330,8 @@ class TestEnginePath(contract.ForkedEnginePath):
         assert traced["delta_mixers"] == {
             "recurrent": 0, "chunked": 2 * LINEAR_LAYERS,
             "recurrent_forked": LINEAR_LAYERS}
+        assert traced["delta_steps"] == {"kernel": 0,
+                                         "elementwise": LINEAR_LAYERS}
         assert traced["sublayer_norms"] == {
             "pre": {"recurrent": 0, "chunked": 2 * 12,
                     "recurrent_forked": 12},
@@ -532,6 +534,7 @@ class TestThePublishedShare:
         stats = EXPANDER.summary()
         assert stats["delta_mixers"] == {"recurrent": 0, "chunked": 0,
                                          "recurrent_forked": 12}
+        assert stats["delta_steps"] == {"kernel": 0, "elementwise": 12}
         assert stats["sublayer_norms"]["pre"]["recurrent_forked"] == 24
         assert stats["sublayer_norms"]["post"]["recurrent_forked"] == 8
         assert stats["attention_unrotated"]["recurrent_forked"] == 4

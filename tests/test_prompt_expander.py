@@ -945,9 +945,9 @@ class TestEnginePath:
             "prefix_snapshots", "padded_rows_masked", "expert_products",
             "mixer_products", "conv_mixers", "residual_streams",
             "sinkhorn_iters", "layer_passes", "exit_pass",
-            "exit_lambda_max", "delta_mixers", "state_bytes_stepped",
-            "fork_bytes_copied", "sublayer_norms", "attention_unrotated",
-            "write_strength_bound"}
+            "exit_lambda_max", "delta_mixers", "delta_steps",
+            "state_bytes_stepped", "fork_bytes_copied", "sublayer_norms",
+            "attention_unrotated", "write_strength_bound"}
         # a model of one pass leaves the looped model's counters alone
         assert (block["layer_passes"], block["exit_pass"],
                 block["exit_lambda_max"]) == (0, [], 0.0)
@@ -957,6 +957,7 @@ class TestEnginePath:
         # nor has it a recurrent state to step or to copy at a fork
         assert block["delta_mixers"] == {"recurrent": 0, "chunked": 0,
                                          "recurrent_forked": 0}
+        assert block["delta_steps"] == {"kernel": 0, "elementwise": 0}
         assert (block["state_bytes_stepped"],
                 block["fork_bytes_copied"]) == (0, 0)
         # every layer norms its sublayers' input alone and rotates, and
