@@ -11,7 +11,12 @@ and the convolution's last inputs are of one size at every length. A LATENT
 layer keeps every position as a FULL one does, in one buffer whose row is
 the position's latent and its one rotated key, not every head's keys and
 values. A CONV layer has no positions either and no recurrence: all it
-keeps is the last ``taps - 1`` inputs of its convolution.
+keeps is the last ``taps - 1`` inputs of its convolution. A layer may hold
+several token mixers side by side (``configs.kind_parts``): it then has the
+buffers of each of its parts, a state-space (``ssm``) part's recurrence and
+kept inputs (no positions, as a LINEAR layer's, under names of their own)
+beside an attention part's keys and values, and everything here that counts
+does so by BASE kind.
 
 The expander's requests all begin with the operator's instruction text.
 What its layers hold after the prefix's LAST token (every kind) is computed
@@ -42,8 +47,9 @@ reads them once where copies would be read once a sequence; a ring is
 never overwritten because a sequence's new rows go to its own, not to the
 ring. Both kinds go one way because a fork is handed buffers, not a
 config, and a ring cannot be told from a buffer by its length.
-What has NO positions is copied: a linear layer's recurrent state and its
-convolution's kept inputs are the whole past folded into one size, each
+What has NO positions is copied: a linear layer's (a state-space part's)
+recurrent state and its convolution's kept inputs are the whole past folded
+into one size, each
 sequence folds its own tokens into them from the fork on, and so each gets
 its own copy under the buffer's name, ``(sequences, ...)`` (4.39 MB a
 layer a sequence at 64 value heads of 128 x 128 and 3 x 16 384 kept
@@ -144,15 +150,16 @@ def fork(cache: Dict, sequences: int, own_slots: int = 0) -> Dict:
 
 def copied_bytes(config, dtype, sequences: int) -> int:
     """Bytes a :func:`fork` into ``sequences`` copies: the buffers that
-    keep no positions (:func:`state_bytes` of the linear kind), once a
-    sequence. 0 for a model whose every buffer keeps positions, and at
-    one sequence, which forks nothing."""
+    keep no positions (:func:`state_bytes` of the linear and the
+    state-space kind), once a sequence. 0 for a model whose every buffer
+    keeps positions, and at one sequence, which forks nothing."""
     if sequences < 2:
         return 0
     shapes = lm.cache_shapes(config, 0)     # no capacity is read
     return sequences * sum(
         math.prod(shape) * lm.buffer_dtype(name, dtype).itemsize
-        for name in lm.LINEAR_BUFFERS for shape in shapes.get(name, ()))
+        for name in lm.LINEAR_BUFFERS + lm.SSM_BUFFERS
+        for shape in shapes.get(name, ()))
 
 
 def copy_tree(cache: Dict) -> Dict:
@@ -170,11 +177,13 @@ _COPY = jax.jit(copy_tree)
 def state_bytes(config, capacity: int, dtype, sequences: int = 1,
                 own_slots: int = 0) -> Dict[str, int]:
     """Bytes the caches of ``sequences`` sequences take at ``capacity``,
-    by layer kind, from the shapes: keys, values and latents in ``dtype``,
-    a linear layer's state and a linear or conv layer's kept inputs in
-    float32. Several sequences are a :func:`fork` of one: every buffer
-    that keeps positions once and ``own_slots`` rows of it a sequence, one
-    that keeps none once a sequence.
+    by BASE kind (a layer of several mixers adds to each of its parts':
+    ``full`` its rows, ``ssm`` its states), from the shapes: keys, values
+    and latents in ``dtype``, a linear layer's or a state-space part's
+    state and every kept convolution input in float32. Several sequences
+    are a :func:`fork` of one: every buffer that keeps positions once and
+    ``own_slots`` rows of it a sequence, one that keeps none once a
+    sequence.
     Full and sliding are always named; linear, latent and conv where the
     model has such layers. A request asks for the sizes of its model at
     its capacity, the same as the request before it: kept by argument."""
@@ -199,10 +208,11 @@ def _state_bytes(config, capacity: int, dtype, sequences: int,
 
     out = {lm.FULL: 0, lm.SLIDING: 0}
     for kind in config.layer_types:
-        out[kind] = out.get(kind, 0) + sum(
-            held(name, next(shapes[name]))
-            * lm.buffer_dtype(name, dtype).itemsize
-            for name in lm.buffers_of(kind))
+        for part in lm.kind_parts(kind):
+            out[part] = out.get(part, 0) + sum(
+                held(name, next(shapes[name]))
+                * lm.buffer_dtype(name, dtype).itemsize
+                for name in lm.buffers_of(part))
     return out
 
 
@@ -258,9 +268,10 @@ class KVCacheManager:
     def positions_in_use(self, length: int, sequences: int = 1,
                          forked_at: int = 0) -> Dict[str, int]:
         """Cache positions ``sequences`` sequences of ``length`` occupy,
-        by layer kind, summed over the layers of the kind; a linear or a
-        conv layer uses none at any length, a latent layer one a
-        position, a full layer of a looped model one a pass. Sequences
+        by base kind, summed over the layers that have the kind; a linear,
+        a conv or a state-space mixer uses none at any length, a latent
+        layer one a position, a full layer of a looped model one a pass.
+        Sequences
         forked at ``forked_at`` hold the positions before it once and the
         rest once each (a sliding layer at most its window of either; a
         latent layer as a full one)."""
@@ -272,10 +283,10 @@ class KVCacheManager:
             lm.SLIDING: len(cfg.layers_of(lm.SLIDING))
             * (min(forked_at, window) + sequences * min(own, window)),
         }
-        for kind in (lm.LINEAR, lm.CONV):
-            if kind in cfg.layer_types:
+        for kind in (lm.LINEAR, lm.CONV, lm.SSM):
+            if kind in cfg.base_kinds:
                 out[kind] = 0
-        if lm.LATENT in cfg.layer_types:
+        if lm.LATENT in cfg.base_kinds:
             out[lm.LATENT] = len(cfg.layers_of(lm.LATENT)) * (
                 forked_at + sequences * own)
         return out

@@ -92,6 +92,14 @@ class RopeConfig:
     interleaved: bool = False
 
 
+def kind_parts(kind: str) -> Tuple[str, ...]:
+    """The base kinds of a layer spelled ``kind``: one kind of token mixer
+    (models/lm.py), or several joined by ``+`` (mixers side by side under
+    one norm, each kind once, one of them at most keeping keys and
+    values)."""
+    return tuple(kind.split("+"))
+
+
 @dataclasses.dataclass(frozen=True)
 class LMConfig:
     """A decoder-only language model read from lists: the kind of every
@@ -166,7 +174,31 @@ class LMConfig:
     head_dim``, before the heads are cut; ``"head"`` each head alone.
     ``linear_write_scale`` ``c`` makes a linear layer's write strength
     ``beta = c sigmoid(b)``: at 2 the state's transition ``I - beta k k^T``
-    has eigenvalues down to -1."""
+    has eigenvalues down to -1.
+
+    A layer may hold SEVERAL token mixers side by side that read ONE norm
+    and add into the residual once: its entry of ``layer_types`` then
+    spells its base kinds joined by ``+`` (``"full+ssm"``), and
+    :func:`kind_parts` is the one place that splits the spelling. A base
+    kind alone is a layer as before. ``"ssm"`` is a selective state-space
+    mixer (models/lm.py:SSMMixer, ops/ssm.py): ``ssm_num_heads`` heads of
+    ``ssm_head_dim`` over states ``ssm_state_size`` wide, ``B`` and ``C``
+    shared by the heads of one of ``ssm_num_groups`` groups, behind a
+    causal convolution of ``ssm_conv_kernel`` taps (with a bias under
+    ``ssm_conv_bias``) over ``[x | B | C]``, its read-out gated and THEN
+    normed over each group's channels (``ssm_norm_before_gate``: normed,
+    then gated), its prefill cut into chunks of ``ssm_chunk``.
+
+    The forward multipliers are scalars the forward pass applies where it
+    says, none folded into a weight; every default is 1.0 and applies
+    nothing (no op is traced). ``embedding_multiplier`` scales the table's
+    output, ``logit_multiplier`` the head's logits, ``key_multiplier`` the
+    keys before their rotation (the cache holds them scaled),
+    ``mixer_multipliers`` a token mixer's input and its output by base
+    kind, as ``(kind, in, out)`` triples, ``ssm_multipliers`` the five
+    column ranges ``[z | x | B | C | dt]`` of a state-space mixer's
+    ``in_proj`` output, ``mlp_multipliers`` a dense SwiGLU's gate
+    pre-activation and its output."""
 
     vocab_size: int = 100352
     hidden_size: int = 3072
@@ -229,6 +261,23 @@ class LMConfig:
     norm_placement: Tuple[str, ...] = ()
     qk_norm_extent: str = "head"
     linear_write_scale: float = 1.0
+    # "ssm" parts: heads, their width, the state's width, the groups that
+    # share B and C, the convolution's taps and whether it has a bias, and
+    # the chunk of the prefill's chunk-wise form
+    ssm_num_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_num_groups: int = 1
+    ssm_conv_kernel: int = 4
+    ssm_conv_bias: bool = False
+    ssm_norm_before_gate: bool = False
+    ssm_chunk: int = 128
+    embedding_multiplier: float = 1.0
+    logit_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    mixer_multipliers: Tuple[Tuple[str, float, float], ...] = ()
+    ssm_multipliers: Tuple[float, float, float, float, float] = (1.0,) * 5
+    mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
 
     def __post_init__(self) -> None:
         if self.norm_placement and (
@@ -265,7 +314,43 @@ class LMConfig:
         ) * self.num_layers
 
     def layers_of(self, kind: str) -> Tuple[int, ...]:
-        return tuple(i for i, t in enumerate(self.layer_types) if t == kind)
+        """The layers that have a token mixer of base kind ``kind``, alone
+        or beside others."""
+        return tuple(i for i, t in enumerate(self.layer_types)
+                     if kind in kind_parts(t))
+
+    @property
+    def base_kinds(self) -> frozenset:
+        """The base kinds any layer has a part of."""
+        return frozenset(part for kind in self.layer_types
+                         for part in kind_parts(kind))
+
+    def mixer_multiplier(self, kind: str) -> Tuple[float, float]:
+        """``(in, out)`` of a token mixer of base kind ``kind``."""
+        for name, before, after in self.mixer_multipliers:
+            if name == kind:
+                return before, after
+        return 1.0, 1.0
+
+    @property
+    def multipliers_applied(self) -> int:
+        """How many of the forward multipliers are off 1."""
+        scalars = (self.embedding_multiplier, self.logit_multiplier,
+                   self.key_multiplier, *self.ssm_multipliers,
+                   *self.mlp_multipliers,
+                   *(m for _, *pair in self.mixer_multipliers for m in pair))
+        return sum(m != 1.0 for m in scalars)
+
+    @property
+    def ssm_inner(self) -> int:
+        """A state-space mixer's heads side by side."""
+        return self.ssm_num_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_channels(self) -> int:
+        """Channels a state-space mixer's convolution runs over: ``[x | B
+        | C]``, ``B`` and ``C`` one a group."""
+        return self.ssm_inner + 2 * self.ssm_num_groups * self.ssm_state_size
 
     @property
     def expert_layers(self) -> Tuple[int, ...]:
@@ -1038,6 +1123,71 @@ def tiny_olmo_hybrid_expander() -> ModelFamily:
     """Factory form of :data:`TINY_OLMO_HYBRID_EXPAND` (benchmark
     rehearsals)."""
     return TINY_OLMO_HYBRID_EXPAND
+
+
+# Falcon-H1-34B-Instruct (huggingface.co/tiiuae/Falcon-H1-34B-Instruct
+# config.json, ``model_type: falcon_h1``) at its published widths: 72 layers
+# of hidden 5120, every one alike: attention of 20 heads over 4 KV heads of
+# 128 (rotated over the whole head at theta 1e11, no gate, no query or key
+# norm) AND a selective state-space mixer of 32 heads of 128 over states 256
+# wide (B and C shared by groups of 16 heads, a 4-tap convolution with bias
+# over the 5120 channels [x | B | C]) side by side under ONE input norm,
+# then a dense SwiGLU of 21504; vocabulary 261120, head untied; fourteen
+# forward multipliers (one of them, attention's input, is 1).
+FALCON_H1_34B = LMConfig(
+    vocab_size=261120, hidden_size=5120, layer_types=("full+ssm",) * 72,
+    num_heads_per_layer=(20,) * 72, num_kv_heads=4, head_dim=128,
+    rope_full=RopeConfig(theta=1e11), dense_layers=tuple(range(72)),
+    intermediate_size=21504, num_experts=0, num_experts_per_tok=0,
+    moe_intermediate_size=0, shared_expert_intermediate_size=0,
+    rms_norm_eps=1e-5, attn_gate="none", ssm_num_heads=32,
+    ssm_head_dim=128, ssm_state_size=256, ssm_num_groups=2,
+    ssm_conv_kernel=4, ssm_conv_bias=True, ssm_chunk=128,
+    embedding_multiplier=5.656854249492381, logit_multiplier=0.0078125,
+    key_multiplier=0.011048543456039804,
+    mixer_multipliers=(("full", 1.0, 0.0375),
+                       ("ssm", 0.25, 0.08838834764831845)),
+    ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                     0.3535533905932738),
+    mlp_multipliers=(0.1767766952966369, 0.011160714285714284))
+
+
+def sd15_falcon_h1_expander() -> ModelFamily:
+    """SD1.5 with Falcon-H1-34B-Instruct as its resident prompt expander,
+    cut in depth and in the vocabulary: layers 0-8 (the first of eight
+    pipeline stages of nine), every layer whole, vocabulary ids 0-65279
+    (the table and the head lie four ways)."""
+    return dataclasses.replace(
+        SD15, name="sd15-falcon-h1-expand",
+        expander=lm_share(FALCON_H1_34B, layers=9, chips=1, rank=0,
+                          vocab_chips=4))
+
+
+# Tiny expander of that stack: three layers of attention (6 heads of 8 over
+# 2 KV heads, a group of 3) beside a state-space mixer (6 heads of 5 over
+# states 7 wide in 3 groups, a 4-tap convolution with bias over 72
+# channels) under one norm; a chunk of 16, so a prefill runs several; every
+# one of the fourteen multipliers off 1.
+TINY_FALCON_H1_LM = LMConfig(
+    vocab_size=512, hidden_size=24, layer_types=("full+ssm",) * 3,
+    num_heads_per_layer=(6,) * 3, num_kv_heads=2, head_dim=8,
+    rope_full=RopeConfig(theta=1e11), dense_layers=(0, 1, 2),
+    intermediate_size=48, num_experts=0, num_experts_per_tok=0,
+    moe_intermediate_size=0, shared_expert_intermediate_size=0,
+    rms_norm_eps=1e-5, attn_gate="none", ssm_num_heads=6, ssm_head_dim=5,
+    ssm_state_size=7, ssm_num_groups=3, ssm_conv_kernel=4,
+    ssm_conv_bias=True, ssm_chunk=16, embedding_multiplier=2.5,
+    logit_multiplier=0.5, key_multiplier=0.6,
+    mixer_multipliers=(("full", 0.8, 0.7), ("ssm", 0.5, 1.3)),
+    ssm_multipliers=(0.7, 1.4, 0.8, 1.2, 0.6), mlp_multipliers=(0.9, 0.75))
+TINY_FALCON_H1_EXPAND = dataclasses.replace(
+    TINY, name="tiny-falcon-h1-expand", expander=TINY_FALCON_H1_LM)
+
+
+def tiny_falcon_h1_expander() -> ModelFamily:
+    """Factory form of :data:`TINY_FALCON_H1_EXPAND` (benchmark
+    rehearsals)."""
+    return TINY_FALCON_H1_EXPAND
 
 
 FAMILIES = {f.name: f for f in (SD15, SD21, SD21_BASE, SDXL_BASE,

@@ -9,7 +9,13 @@ gated delta rule over a recurrent state, ops/delta_rule.py, behind a short
 causal convolution; ``"latent"``: attention whose cache holds one low-rank
 latent and one rotated key a position, shared by every head, in three forms
 over that one cache, :class:`LatentAttention`; ``"conv"``: a gated causal
-convolution over the hidden channels, :class:`ShortConv`), how many query
+convolution over the hidden channels, :class:`ShortConv`; ``"ssm"``: a
+selective state-space recurrence, ops/ssm.py, behind a short causal
+convolution with a bias, :class:`SSMMixer`), or SEVERAL of them side by
+side (``"full+ssm"``: the base kinds joined by ``+``, each reading the
+layer's ONE normed input over buffers of its own, their sum entering the
+residual once; ``configs.kind_parts`` is the one place that splits the
+spelling), how many query
 heads an attention layer has (they may differ by layer; the KV heads are shared by
 groups of them), which rotary parameterisation goes with which kind,
 whether queries and keys are normed per head or over the whole projection
@@ -28,6 +34,12 @@ weight)``, or under a sigmoid ``x_hat * c sigmoid(weight)``
 linear layer's read-out is gated by ``silu(z)`` or by ``c sigmoid(z)``
 (``linear_sigmoid_gate_scale``). The router's scores are a softmax or sigmoids,
 with or without a bias that chooses (ops/moe.py:route). With
+Forward multipliers (``LMConfig.embedding_multiplier``,
+``logit_multiplier``, ``key_multiplier``, ``mixer_multipliers``,
+``ssm_multipliers``, ``mlp_multipliers``) scale the table's output, the
+logits, the keys, each mixer's input and output, a state-space mixer's
+projected ranges and a dense SwiGLU's gate and output where the forward
+pass says; one that is 1.0 traces no op. With
 ``residual_streams`` over 1 a token is ``(streams, hidden)`` between
 sublayers and a :class:`StreamMixer` around each sublayer reads, writes
 and mixes the streams; with 1 a layer is ``x + F(norm(x))``, ``x +
@@ -36,8 +48,12 @@ norm(F(x))`` or ``x + norm(F(norm(x)))`` by its placement.
 One call, :meth:`DecoderLM.__call__`, runs a chunk of ``T`` tokens that
 starts at position ``start`` against the cache and returns the cache with
 the chunk written: a prefill is a long chunk, a decode step a chunk of one.
-The cache (cache/kv.py) holds, per layer, the buffers of the layer's kind,
-five kinds in all: a full layer's key and value buffers hold every
+The cache (cache/kv.py) holds, per layer, the buffers of the layer's kind
+(of each of its parts, where it has several: ``k`` and ``v`` beside
+``ssm_state`` and ``ssm_conv``), six base kinds in all (a state-space part
+is as a linear layer's: the recurrence's ``(heads, head width, state
+width)`` float32 and the convolution's last ``taps - 1`` inputs, under
+names of its own): a full layer's key and value buffers hold every
 position up to their capacity; a sliding layer's are rings of
 ``sliding_window`` slots, slot ``p % window`` holding position ``p``; a
 linear layer has no positions at all but the recurrent state ``(value
@@ -72,11 +88,14 @@ width, value width)`` and ``(sequences, taps - 1, channels)`` under the
 names they had, and row ``b`` of a step runs the recurrent step over
 sequence ``b``'s own state and a one-row convolution over its own kept
 rows; a row that does not count (a pad, a sequence that has ended) leaves
-both where they were, as a padded row of a chunk does.
+both where they were, as a padded row of a chunk does. A state-space part
+goes the same way, so a layer of attention and such a part has buffers
+some shared (its keys and values before the fork) and some copied.
 Norms, projections, the router, the experts and
 the head take the rows as they take a chunk's; the token mixers alone tell
 the sequences apart, and read what they share once. That holds for
-the ``full``, ``sliding``, ``latent`` and ``linear`` kinds of one stream
+the ``full``, ``sliding``, ``latent``, ``linear`` and ``ssm`` kinds of one
+stream
 (:func:`shares_a_step`); a model with conv layers' kept rows or several
 residual streams decodes one sequence a step.
 
@@ -109,10 +128,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from stable_diffusion_webui_distributed_tpu.models.configs import (
-    LMConfig, RopeConfig,
+    LMConfig, RopeConfig, kind_parts,
 )
 from stable_diffusion_webui_distributed_tpu.ops import (
-    delta_rule, moe, stream_mixer,
+    delta_rule, moe, ssm, stream_mixer,
 )
 from stable_diffusion_webui_distributed_tpu.ops.attention import (
     attend_positions, attend_two_ranges,
@@ -122,8 +141,8 @@ from stable_diffusion_webui_distributed_tpu.serving.metrics import (
     ATTENTION, EXPANDER,
 )
 
-FULL, SLIDING, LINEAR, LATENT, CONV = (
-    "full", "sliding", "linear", "latent", "conv")
+FULL, SLIDING, LINEAR, LATENT, CONV, SSM = (
+    "full", "sliding", "linear", "latent", "conv", "ssm")
 #: where a layer norms a sublayer (``LMConfig.sublayer_norms``): its input,
 #: its output, or (``"both"``) both
 PRE, POST = "pre", "post"
@@ -132,6 +151,11 @@ ATTENTION_BUFFERS = ("k", "v")
 LINEAR_BUFFERS = ("state", "conv")
 LATENT_BUFFERS = ("latent",)
 CONV_BUFFERS = ("kept",)
+#: a state-space part's recurrence and its convolution's kept inputs, under
+#: names of their own: a model may hold both recurrences
+SSM_BUFFERS = ("ssm_state", "ssm_conv")
+#: the buffers that keep no positions: one size at every length
+STATE_BUFFERS = LINEAR_BUFFERS + CONV_BUFFERS + SSM_BUFFERS
 #: how a conv layer's mixer was traced: one token, or a chunk of several
 CONV_STEP, CONV_CHUNK = "step", "chunk"
 #: ops/attention.py records a latent layer's site under its form
@@ -152,9 +176,15 @@ FORKED_AT = "forked_at"
 
 
 def buffers_of(kind: str, forked: bool = False) -> Tuple[str, ...]:
+    """The cache's buffers of one layer of ``kind``: a base kind's own, or
+    those of every part of a layer of several, part after part."""
+    parts = kind_parts(kind)
+    if len(parts) > 1:
+        return sum((buffers_of(part, forked) for part in parts), ())
     if kind == LATENT:
         return LATENT_BUFFERS + (LATENT_SHARED if forked else ())
-    return {LINEAR: LINEAR_BUFFERS, CONV: CONV_BUFFERS}.get(
+    return {LINEAR: LINEAR_BUFFERS, CONV: CONV_BUFFERS,
+            SSM: SSM_BUFFERS}.get(
         kind, ATTENTION_BUFFERS + (SHARED_BUFFERS if forked else ()))
 
 
@@ -162,9 +192,10 @@ def slots_axis(name: str) -> int | None:
     """The axis of buffer ``name`` that counts positions: keys and values
     are ``(..., slots, kv heads, head_dim)``, latents ``(..., slots,
     width)``; None for a buffer that has no positions (a linear layer's
-    state and kept inputs, a conv layer's kept rows: one size at every
-    length, which a fork copies whole)."""
-    if name in LINEAR_BUFFERS + CONV_BUFFERS:
+    state and kept inputs, a conv layer's kept rows, a state-space part's
+    state and kept inputs: one size at every length, which a fork copies
+    whole)."""
+    if name in STATE_BUFFERS:
         return None
     return -2 if name in LATENT_BUFFERS + LATENT_SHARED else -3
 
@@ -173,25 +204,34 @@ def shares_a_step(cfg: LMConfig) -> bool:
     """Whether several sequences can be decoded in one step: every layer
     keeps a row a position (keys and values in buffers or rings, or
     latents) or a recurrent state with a sequence axis (:class:`DeltaMixer`
-    under ``sequences``), and a token is one stream."""
-    return (set(cfg.layer_types) <= {FULL, SLIDING, LATENT, LINEAR}
+    and :class:`SSMMixer` under ``sequences``), in every part of it, and a
+    token is one stream."""
+    return (cfg.base_kinds <= {FULL, SLIDING, LATENT, LINEAR, SSM}
             and cfg.residual_streams == 1)
 
 
 def site_attrs(cfg: LMConfig) -> dict:
     """Span attributes of a model whose layers depart from input norms,
-    rotated attention and a write strength under 1, as one trace of its
-    stack counts them (``serving.expander`` ``sublayer_norms``,
-    ``attention_unrotated``, ``write_strength_bound``); ``{}`` for one
-    that departs in none."""
+    rotated attention, a write strength under 1, one mixer a layer and no
+    forward multiplier, as one trace of its stack counts them
+    (``serving.expander`` ``sublayer_norms``, ``attention_unrotated``,
+    ``write_strength_bound``, ``ssm_mixers``, ``joined_layers``,
+    ``multipliers_applied``); ``{}`` for one that departs in none."""
     attrs = {}
     if cfg.norm_placement:
         attrs["norms_pre"] = 2 * sum(p != POST for p in cfg.norm_placement)
         attrs["norms_post"] = 2 * sum(p != PRE for p in cfg.norm_placement)
-    if cfg.rope_full is None and FULL in cfg.layer_types:
+    if cfg.rope_full is None and FULL in cfg.base_kinds:
         attrs["unrotated"] = len(cfg.layers_of(FULL))
-    if cfg.linear_write_scale != 1.0 and LINEAR in cfg.layer_types:
+    if cfg.linear_write_scale != 1.0 and LINEAR in cfg.base_kinds:
         attrs["write_strength_bound"] = cfg.linear_write_scale
+    if SSM in cfg.base_kinds:
+        attrs["ssm_layers"] = len(cfg.layers_of(SSM))
+    joined = sum(len(kind_parts(kind)) > 1 for kind in cfg.layer_types)
+    if joined:
+        attrs["joined_layers"] = joined
+    if cfg.multipliers_applied:
+        attrs["multipliers"] = cfg.multipliers_applied
     return attrs
 
 
@@ -316,26 +356,33 @@ def model_norm(cfg: LMConfig, **how) -> RMSNorm:
 
 class SwiGLU(nn.Module):
     """``W_d(silu(W_g n) * W_u n)``; ``limit`` over 0:
-    ``silu(min(W_g n, limit)) * clip(W_u n, -limit, limit)``."""
+    ``silu(min(W_g n, limit)) * clip(W_u n, -limit, limit)``;
+    ``multipliers`` ``(m_g, m_d)`` off 1: ``m_d W_d(silu(m_g W_g n) * W_u
+    n)``."""
 
     width: int
     dtype: jnp.dtype = jnp.float32
     quant: bool = False
     limit: float = 0.0
+    multipliers: Tuple[float, float] = (1.0, 1.0)
 
     @nn.compact
     def __call__(self, n: jax.Array) -> jax.Array:
         def lin(features, name):
             return Linear(features, self.dtype, self.quant, name=name)
 
+        before, after = self.multipliers
         gate = lin(self.width, "gate_proj")(n)
+        if before != 1.0:
+            gate = gate * before
         if self.limit:
             gate = jnp.minimum(gate, self.limit)
         gate = jax.nn.silu(gate)
         up = lin(self.width, "up_proj")(n)
         if self.limit:
             up = jnp.clip(up, -self.limit, self.limit)
-        return lin(n.shape[-1], "down_proj")(gate * up)
+        out = lin(n.shape[-1], "down_proj")(gate * up)
+        return out if after == 1.0 else out * after
 
 
 class Experts(nn.Module):
@@ -409,6 +456,9 @@ class Attention(nn.Module):
     layer: int
     dtype: jnp.dtype = jnp.float32
     quant: bool = False
+    #: ``"full"`` or ``"sliding"`` where the layer holds other mixers too
+    #: (``""``: the layer's kind is the attention's)
+    kind: str = ""
 
     @nn.compact
     def __call__(self, n, q_pos, start, end, k_cache, v_cache,
@@ -421,7 +471,7 @@ class Attention(nn.Module):
         sequence's own rows from position ``forked_at`` on; the shared
         ones are returned behind them as they came."""
         cfg = self.config
-        kind = cfg.layer_types[self.layer]
+        kind = self.kind or cfg.layer_types[self.layer]
         heads = cfg.num_heads_per_layer[self.layer]
         kv, dim = cfg.num_kv_heads, cfg.head_dim
         tokens = n.shape[0]
@@ -478,7 +528,10 @@ class Attention(nn.Module):
                 tokens, heads, 2 * dim), 2, axis=-1)
         else:
             q = heads_of(lin(heads * dim, "q_proj")(n), heads, "q_norm")
-        k = heads_of(lin(kv * dim, "k_proj")(n), kv, "k_norm")
+        k = lin(kv * dim, "k_proj")(n)
+        if cfg.key_multiplier != 1.0:   # the cache holds the scaled keys
+            k = k * cfg.key_multiplier
+        k = heads_of(k, kv, "k_norm")
         if cfg.qk_norm and not whole:
             q = model_norm(cfg, name="q_norm")(q)
             k = model_norm(cfg, name="k_norm")(k)
@@ -833,7 +886,8 @@ def mixer_operands(params) -> dict:
     return {name: sub for name, sub in found.items() if sub}
 
 
-def causal_conv(kernel: jax.Array, kept: jax.Array, x: jax.Array, length):
+def causal_conv(kernel: jax.Array, kept: jax.Array, x: jax.Array, length,
+                bias: jax.Array | None = None):
     """(convolved ``(T, channels)``, the rows to keep): the causal
     depth-wise convolution of a chunk ``x`` ``(T, channels)`` whose first
     ``length`` rows are real, by ``kernel`` ``(taps, channels)``, both
@@ -842,17 +896,20 @@ def causal_conv(kernel: jax.Array, kept: jax.Array, x: jax.Array, length):
     before the chunk are ``kept``, the ``taps - 1`` last real inputs, and
     what is returned to keep are the last real ones after this chunk, in
     ``kept``'s dtype: a padded row is never kept, and a chunk shorter than
-    ``taps - 1`` keeps older rows on. The taps' count and what follows the
-    sum (an activation, a gate) are the caller's."""
+    ``taps - 1`` keeps older rows on. ``bias`` ``(channels,)`` is added to
+    every row's sum. The taps' count and what follows the sum (an
+    activation, a gate) are the caller's."""
     taps, tokens = kernel.shape[0], x.shape[0]
     inputs = jnp.concatenate([kept.astype(kernel.dtype), x])
     out = sum(kernel[j] * inputs[j:j + tokens] for j in range(taps))
+    if bias is not None:
+        out = out + bias
     return out, jax.lax.dynamic_slice_in_dim(
         inputs, length, taps - 1, 0).astype(kept.dtype)
 
 
 def causal_conv_rows(kernel: jax.Array, kept: jax.Array, x: jax.Array,
-                     real: jax.Array):
+                     real: jax.Array, bias: jax.Array | None = None):
     """:func:`causal_conv` of ONE row for each of ``B`` sequences: ``x``
     ``(B, channels)`` is sequence ``b``'s one input behind its own ``kept``
     ``(B, taps - 1, channels)``. A sequence whose row does not count
@@ -860,6 +917,8 @@ def causal_conv_rows(kernel: jax.Array, kept: jax.Array, x: jax.Array,
     inputs = jnp.concatenate(
         [kept.astype(kernel.dtype), x[:, None, :]], axis=1)
     out = sum(kernel[j] * inputs[:, j] for j in range(kernel.shape[0]))
+    if bias is not None:
+        out = out + bias
     return out, jnp.where(real[:, None, None], inputs[:, 1:],
                           kept.astype(kernel.dtype)).astype(kept.dtype)
 
@@ -988,6 +1047,103 @@ class ShortConv(nn.Module):
                       name="out_proj")(c * mixed), kept
 
 
+class GroupedRMSNorm(nn.Module):
+    """``x_hat * scale`` with the RMS taken over each of ``groups`` equal
+    runs of the last axis, one weight a channel, float32."""
+
+    groups: int
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        scale = self.param("scale", nn.initializers.ones,
+                           (x.shape[-1],)).astype(jnp.float32)
+        runs = x.astype(jnp.float32).reshape(
+            x.shape[:-1] + (self.groups, -1))
+        mean = jnp.mean(jnp.square(runs), axis=-1, keepdims=True)
+        return (runs * jax.lax.rsqrt(mean + self.eps)).reshape(x.shape) \
+            * scale
+
+
+class SSMMixer(nn.Module):
+    """The token mixer of an ``"ssm"`` part: a selective state-space
+    recurrence (ops/ssm.py). ``in_proj`` gives ``[z | x | B | C | dt]``
+    (the gate and the input ``ssm_num_heads * ssm_head_dim`` wide each,
+    ``B`` and ``C`` ``ssm_state_size`` a group, ``dt`` one a head), each
+    range scaled by its own of ``ssm_multipliers``; ``[x | B | C]`` pass a
+    causal depth-wise convolution of ``ssm_conv_kernel`` taps (with a bias
+    under ``ssm_conv_bias``) and SiLU; per head ``dt = softplus(dt +
+    dt_bias)``, the decay ``exp(-exp(A_log) dt)``, ``S <- decay S + dt x
+    B^T``, ``y = S C + D x`` with ``B`` and ``C`` those of the head's
+    group; the read-out is ``y * silu(z)``, THEN an RMS norm over each of
+    the groups' channels (``ssm_norm_before_gate``: the norm, then the
+    gate), through ``out_proj``. ``state`` is ``(heads,
+    head width, state width)`` float32; ``conv`` holds the convolution's
+    last ``taps - 1`` real inputs. ``sequences``: row ``b`` is sequence
+    ``b``'s one token, ``state`` and ``conv`` carry the sequences in
+    front, each row steps its own, and a row that is not ``real`` leaves
+    both (its ``dt`` is 0: no decay, no write)."""
+
+    config: LMConfig
+    dtype: jnp.dtype = jnp.float32
+    quant: bool = False
+
+    @nn.compact
+    def __call__(self, n, real, length, state, conv,
+                 sequences: bool = False):
+        cfg = self.config
+        EXPANDER.record_ssm(ssm.form(n.shape[0], sequences))
+        heads, dim = cfg.ssm_num_heads, cfg.ssm_head_dim
+        groups, width = cfg.ssm_num_groups, cfg.ssm_state_size
+        inner, channels = cfg.ssm_inner, cfg.ssm_conv_channels
+        tokens = n.shape[0]
+        f32 = jnp.float32
+
+        def lin(features, name):
+            return Linear(features, self.dtype, self.quant, name=name)
+
+        # columns: [z | x | B | C | dt]
+        ranges = (inner, inner, groups * width, groups * width, heads)
+        p = lin(inner + channels + heads, "in_proj")(n)
+        if any(m != 1.0 for m in cfg.ssm_multipliers):
+            p = p * jnp.asarray(np.repeat(cfg.ssm_multipliers, ranges), f32)
+        z, xbc, dt = jnp.split(p, [inner, inner + channels], axis=-1)
+        kernel = self.param("conv_kernel", nn.initializers.lecun_normal(),
+                            (cfg.ssm_conv_kernel, channels)).astype(f32)
+        bias = self.param("conv_bias", nn.initializers.zeros,
+                          (channels,)).astype(f32) \
+            if cfg.ssm_conv_bias else None
+        a_log = self.param("A_log", _decay_init, (heads,)).astype(f32)
+        skip = self.param("D", nn.initializers.ones, (heads,)).astype(f32)
+        dt_bias = self.param("dt_bias", nn.initializers.ones,
+                             (heads,)).astype(f32)
+        if sequences:
+            xbc, conv = causal_conv_rows(kernel, conv, xbc, real, bias)
+        else:
+            xbc, conv = causal_conv(kernel, conv, xbc, length, bias)
+        xbc = jax.nn.silu(xbc)
+        x, b, c = jnp.split(xbc, [inner, inner + groups * width], axis=-1)
+        x = x.reshape(tokens, heads, dim)
+        b, c = (m.reshape(tokens, groups, width) for m in (b, c))
+        # a row that does not count neither decays the state nor writes
+        dt = jnp.where(real[:, None], jax.nn.softplus(dt + dt_bias), 0.0)
+        log_decay = -jnp.exp(a_log) * dt
+        # computed in float32 whatever the buffer holds
+        if sequences:
+            y, after = ssm.step_each(state.astype(f32), x, b, c, dt,
+                                     log_decay)
+        else:
+            y, after = ssm.mix(state.astype(f32), x, b, c, dt, log_decay,
+                               cfg.ssm_chunk)
+        state = after.astype(state.dtype)
+        y = y + skip[:, None] * x
+        # the gate first, then the norm over each group's channels
+        y, gate = y.reshape(tokens, inner), jax.nn.silu(z)
+        norm = GroupedRMSNorm(groups, cfg.rms_norm_eps, name="norm")
+        out = norm(y) * gate if cfg.ssm_norm_before_gate else norm(y * gate)
+        return lin(n.shape[-1], "out_proj")(out), state, conv
+
+
 class DecoderLayer(nn.Module):
     config: LMConfig
     layer: int
@@ -1021,12 +1177,22 @@ class DecoderLayer(nn.Module):
             the order of a trace's ops is part of its executable's key)."""
             return q_pos < end if real is None else real
 
-        def token_mixer(n):
-            """(mixed, the layer's buffers as the chunk leaves them)."""
+        def one_mixer(kind, n, buffers):
+            """(mixed, its buffers as the chunk leaves them) of the
+            layer's token mixer of base kind ``kind``, its input and its
+            output under the kind's multipliers."""
+            before, behind = cfg.mixer_multiplier(kind)
+            if before != 1.0:
+                n = n * before
             if kind == LINEAR:
                 mixed, *after = DeltaMixer(
                     cfg, self.dtype, self.quant, self.meshed,
                     name="delta")(
+                        n, counted(), end - start, *buffers,
+                        sequences=sequences)
+            elif kind == SSM:
+                mixed, *after = SSMMixer(
+                    cfg, self.dtype, self.quant, name="ssm")(
                         n, counted(), end - start, *buffers,
                         sequences=sequences)
             elif kind == CONV:
@@ -1041,17 +1207,37 @@ class DecoderLayer(nn.Module):
                         sequences=sequences, forked_at=forked_at)
             else:
                 mixed, *after = Attention(
-                    cfg, self.layer, self.dtype, self.quant, name="attn")(
+                    cfg, self.layer, self.dtype, self.quant, kind,
+                    name="attn")(
                         n, q_pos, start, end, *buffers,
                         sequences=sequences, pass_index=pass_index,
                         forked_at=forked_at)
+            if behind != 1.0:
+                mixed = mixed * behind
             return mixed, tuple(after)
+
+        def token_mixer(n):
+            """(mixed, the layer's buffers as the chunk leaves them): the
+            layer's one mixer, or the sum of its several, which all read
+            the one normed input, each over its own buffers."""
+            parts = kind_parts(kind)
+            if len(parts) == 1:
+                return one_mixer(kind, n, buffers)
+            EXPANDER.record_joined(form)
+            mixed, after, at = None, (), 0
+            for part in parts:
+                own = len(buffers_of(part, sequences))
+                out, kept = one_mixer(part, n, buffers[at:at + own])
+                mixed = out if mixed is None else mixed + out
+                after, at = after + kept, at + own
+            return mixed, after
 
         def mlp(n):
             """(out, what an expert layer routed; None for a dense one)."""
             if self.layer in cfg.dense_layers:
                 return SwiGLU(cfg.intermediate_size, self.dtype, self.quant,
-                              cfg.swiglu_limit, name="mlp")(n), None
+                              cfg.swiglu_limit, cfg.mlp_multipliers,
+                              name="mlp")(n), None
             return MoE(cfg, self.dtype, self.quant, self.meshed,
                        name="mlp")(n, counted())
 
@@ -1124,11 +1310,12 @@ class DecoderLM(nn.Module):
     def __call__(self, tokens, start, length, cache: Dict[str, jax.Array],
                  all_logits: bool = True, sequences: bool = False):
         cfg = self.config
+        EXPANDER.record_multipliers(cfg.multipliers_applied)
         if sequences:
             if not shares_a_step(cfg):
                 raise ValueError("a step of several sequences wants full, "
-                                 "sliding, latent or linear layers and one "
-                                 "stream")
+                                 "sliding, latent, linear or ssm mixers "
+                                 "and one stream")
             q_pos = jnp.full(tokens.shape, start, jnp.int32)
             end, all_logits = start + 1, True
             real = jnp.arange(tokens.shape[0]) < length
@@ -1149,6 +1336,8 @@ class DecoderLM(nn.Module):
         x = nn.Embed(count, cfg.hidden_size, name="embed_tokens")(
             jnp.clip(local, 0, count - 1)).astype(jnp.float32)
         x = x * here[:, None]
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
         if cfg.residual_streams > 1:    # every stream starts as the token
             x = jnp.broadcast_to(
                 x[:, None, :], (x.shape[0], cfg.residual_streams,
@@ -1233,6 +1422,8 @@ class DecoderLM(nn.Module):
             cache[FORKED_AT] = [jnp.full_like(stamp, forked_at)]
         logits = Linear(cfg.vocab[1], self.dtype, self.quant_linears,
                         name="lm_head")(n)
+        if cfg.logit_multiplier != 1.0:
+            logits = logits * cfg.logit_multiplier
         if not routed:     # no expert layer: the three parts, empty
             none = jnp.zeros((0,), jnp.int32)
             return logits, cache, (
@@ -1282,13 +1473,16 @@ def cache_shapes(cfg: LMConfig, capacity: int) -> Dict[str, list]:
     has ``latent``: ``capacity`` rows of ``latent_width`` (no pass axis: a
     looped stack is full attention). A conv layer has
     ``kept``: its convolution's ``conv_taps - 1`` last inputs, whatever the
-    capacity. A model without layers of a kind has none of the kind's
-    names. A looped model's ``k`` and ``v`` carry the pass axis in front:
-    ``(passes, capacity, kv heads, head_dim)``."""
+    capacity. A state-space part has ``ssm_state`` and ``ssm_conv``,
+    whatever the capacity, beside what the layer's other parts have. A
+    model without layers of a kind has none of the kind's names. A looped
+    model's ``k`` and ``v`` carry the pass axis in front: ``(passes,
+    capacity, kv heads, head_dim)``."""
     passes = (cfg.total_ut_steps,) if cfg.total_ut_steps > 1 else ()
-    rows = [passes + (capacity if kind == FULL else cfg.sliding_window,
+    rows = [passes + (capacity if part == FULL else cfg.sliding_window,
                       cfg.num_kv_heads, cfg.head_dim)
-            for kind in cfg.layer_types if kind in (FULL, SLIDING)]
+            for kind in cfg.layer_types for part in kind_parts(kind)
+            if part in (FULL, SLIDING)]
     shapes = {"k": rows, "v": list(rows)} if rows else {}
     linear = len(cfg.layers_of(LINEAR))
     if linear:
@@ -1303,15 +1497,20 @@ def cache_shapes(cfg: LMConfig, capacity: int) -> Dict[str, list]:
     conv = len(cfg.layers_of(CONV))
     if conv:
         shapes["kept"] = [(cfg.conv_taps - 1, cfg.hidden_size)] * conv
+    space = len(cfg.layers_of(SSM))
+    if space:
+        shapes["ssm_state"] = [(cfg.ssm_num_heads, cfg.ssm_head_dim,
+                                cfg.ssm_state_size)] * space
+        shapes["ssm_conv"] = [(cfg.ssm_conv_kernel - 1,
+                               cfg.ssm_conv_channels)] * space
     return shapes
 
 
 def buffer_dtype(name: str, dtype):
     """What a cache buffer holds: keys, values and latents are in the
-    cache's ``dtype``, a linear layer's state and a linear or conv
-    layer's kept convolution inputs in float32."""
-    return jnp.dtype(jnp.float32 if name in LINEAR_BUFFERS + CONV_BUFFERS
-                     else dtype)
+    cache's ``dtype``, a linear layer's or a state-space part's state and
+    every kept convolution input in float32."""
+    return jnp.dtype(jnp.float32 if name in STATE_BUFFERS else dtype)
 
 
 def empty_cache(cfg: LMConfig, capacity: int, dtype) -> Dict[str, list]:

@@ -874,8 +874,26 @@ def render() -> str:
         "in VMEM; elementwise: XLA's fusions).",
         [(f'step="{_label(step)}"', n)
          for step, n in sorted(expander["delta_steps"].items())])
+    _labeled_family(
+        lines, "sdtpu_expander_ssm_mixers_total", "counter",
+        "Selective state-space mixers traced, by the form their "
+        "recurrence took (recurrent_forked: one token each of several "
+        "sequences, every one over a state of its own).",
+        [(f'form="{_label(form)}"', n)
+         for form, n in sorted(expander["ssm_mixers"].items())])
+    _labeled_family(
+        lines, "sdtpu_expander_joined_layers_total", "counter",
+        "Layers traced with several token mixers side by side under one "
+        "norm, by the form of the executable.",
+        [(f'form="{_label(form)}"', n)
+         for form, n in sorted(expander["joined_layers"].items())])
+    _scalar(lines, "sdtpu_expander_multipliers_applied", "gauge",
+            "Forward multipliers off 1 in the last language model traced "
+            "(0: the forward pass scales nothing).",
+            expander["multipliers_applied"])
     _scalar(lines, "sdtpu_expander_state_bytes_stepped_total", "counter",
-            "Bytes of linear layers' recurrent states and kept inputs the "
+            "Bytes of recurrent states and kept inputs (linear layers', "
+            "state-space mixers') the "
             "prompt expander's decode steps read and wrote.",
             expander["state_bytes_stepped"])
     _scalar(lines, "sdtpu_expander_fork_bytes_copied_total", "counter",

@@ -28,9 +28,13 @@ cache to every chip), the residual streams' mixers (``attn_hc``,
 A short-convolution mixer (module ``short_conv``: the fused in-projection
 whose columns are its two gates and its input, the taps, ``out_proj``) is
 whole as well: a split of the fused columns over ``tp`` would not fall on
-the three parts' borders. A looped model's exit gate (``early_exit_gate``:
-one column and a bias) and the norms after its sublayers (``input_norm_2``,
-``post_attention_norm_2``) are whole on every chip, as every norm is.
+the three parts' borders; so is a state-space mixer (module ``ssm``: the
+fused ``in_proj`` whose five column ranges are its gate, input, ``B``,
+``C`` and ``dt``, the taps with their bias, ``A_log``, ``D``, ``dt_bias``,
+the read-out's grouped norm, ``out_proj``). A looped model's exit gate
+(``early_exit_gate``: one column and a bias) and the norms after its
+sublayers (``input_norm_2``, ``post_attention_norm_2``) are whole on every
+chip, as every norm is.
 One chip's share (``LMConfig.experts_held``,
 ``vocab_held``) is what one position of those axes holds; the exchange that
 adds the parts exists only on a mesh that has the axis.
@@ -66,7 +70,7 @@ def tp_spec_for(path: str, ndim: int):
     if leaf in _EXPERT_LEAVES and module == "experts":
         return P("ep", None, None)
     if "delta" in parts[:-1] or "short_conv" in parts[:-1] \
-            or module in _LM_REPLICATED \
+            or "ssm" in parts[:-1] or module in _LM_REPLICATED \
             or leaf == "e_score_correction_bias":
         return P()
     if leaf == "embedding" and module == "embed_tokens":

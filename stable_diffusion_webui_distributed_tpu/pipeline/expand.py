@@ -31,8 +31,8 @@ the prompt is prefilled once at one sequence, each image's first token is
 drawn from that one row of logits under its own key, the cache is forked
 (cache/kv.py:fork: the prompt's rows stay where the prefill left them, held
 once, each sequence gets rows of its own for what it decodes, and what has
-no positions, a linear layer's recurrent state and kept inputs, is copied
-once a sequence) and the
+no positions, a linear layer's or a state-space part's recurrent state and
+kept inputs, is copied once a sequence) and the
 scan runs over all of them, so a step streams the fixed weights once, each
 distinct expert once and the prompt's keys and values (a latent layer's
 latents) once. Their count is
@@ -75,7 +75,7 @@ from stable_diffusion_webui_distributed_tpu.models.tokenizer import (
     load_lm_tokenizer,
 )
 from stable_diffusion_webui_distributed_tpu.obs import spans as obs_spans
-from stable_diffusion_webui_distributed_tpu.ops import delta_rule
+from stable_diffusion_webui_distributed_tpu.ops import delta_rule, ssm
 from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
     PromptExpansion,
 )
@@ -258,14 +258,15 @@ class PromptExpander:
             # sequences reads and writes of it
             fork_copied = kv.copied_bytes(self.config, self.cache.dtype,
                                           batch)
-            stepped = 2 * batch * alone.get(lm.LINEAR, 0)
+            states = alone.get(lm.LINEAR, 0) + alone.get(lm.SSM, 0)
+            stepped = 2 * batch * states
         with obs_spans.span("expand.prefix_copy", bytes=copied) as sp:
             cache, held = self.cache.acquire(prefix, capacity)
             if sp is not None:
                 sp.attrs["hit"] = bool(held)
-        recurrent = lm.LINEAR in self.config.layer_types
-        conv = lm.CONV in self.config.layer_types
-        latent = lm.LATENT in self.config.layer_types
+        kinds = self.config.base_kinds
+        recurrent, space = lm.LINEAR in kinds, lm.SSM in kinds
+        conv, latent = lm.CONV in kinds, lm.LATENT in kinds
         passes = self.config.total_ut_steps
         # span attributes of a looped model alone
         looped = {"passes": passes} if passes > 1 else {}
@@ -275,8 +276,13 @@ class PromptExpander:
             if latent and self.shares_a_step else {}
         if recurrent and self.shares_a_step:    # and its recurrence
             how["delta"] = delta_rule.form(1, sequences=batch > 1)
+        if space and self.shares_a_step:    # a state-space part's
+            how["ssm"] = ssm.form(1, sequences=batch > 1)
         # and of one whose layers depart from pre-normed, rotated ones
         sites = lm.site_attrs(self.config)
+        # the states a decode chunk's steps read and wrote
+        moved = {"ssm_state_bytes":
+                 DECODE_STEPS * 2 * batch * alone[lm.SSM]} if space else {}
         exits = []        # per executable call of a looped model
         routed = []       # per executable call: (load, none held)
         masked = 0        # padded rows kept out of a recurrence or kept rows
@@ -289,13 +295,15 @@ class PromptExpander:
             padded[:len(ids)] = ids
             attrs = {"tokens": len(ids), "prefix_hit": bool(held), **looped,
                      **sites}
-            if recurrent or conv:   # rows kept out of the layers' state
+            if recurrent or conv or space:  # rows kept out of the state
                 attrs["padded"] = len(padded) - len(ids)
-            if recurrent:           # the form its recurrence takes
+            if recurrent or space:  # the form its recurrence takes
                 attrs["form"] = delta_rule.form(len(padded))
+            if space:       # one sequence's states, read and written
+                attrs["ssm_state_bytes"] = 2 * alone[lm.SSM]
             if latent:        # the form its attention takes over the cache
                 attrs["latent"] = lm.latent_form(len(padded))
-            if (latent or recurrent) and self.shares_a_step:
+            if (latent or recurrent or space) and self.shares_a_step:
                 # whose first tokens the chunk draws
                 attrs["sequences"] = 1 if keep else live
             # the instruction's chunk yields no token that is kept: it runs
@@ -325,8 +333,7 @@ class PromptExpander:
             # what has no positions is copied once a sequence (the
             # prefill's own is let go)
             with obs_spans.span("expand.fork", sequences=batch,
-                                bytes=sum(sizes.values()) - copied
-                                + alone.get(lm.LINEAR, 0),
+                                bytes=sum(sizes.values()) - copied + states,
                                 state_bytes_copied=fork_copied,
                                 **looped, **how, **sites):
                 cache = kv.forked(
@@ -355,7 +362,8 @@ class PromptExpander:
                     and all(tok.eos in one for one in made)):
                 break
             with obs_spans.span("expand.decode_chunk", tokens=DECODE_STEPS,
-                                sequences=live, **looped, **how, **sites):
+                                sequences=live, **looped, **how, **sites,
+                                **moved):
                 cache, token, position, out, step_load, step_none, *read = \
                     decode(params, cache, token, position, key,
                            temperature, *more)

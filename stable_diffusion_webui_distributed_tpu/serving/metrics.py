@@ -446,7 +446,10 @@ def replay_sites(rows) -> None:
                 "delta": EXPANDER.record_delta,
                 "delta_step": EXPANDER.record_delta_step,
                 "norm": EXPANDER.record_norm,
-                "unrotated": EXPANDER.record_unrotated}
+                "unrotated": EXPANDER.record_unrotated,
+                "ssm": EXPANDER.record_ssm,
+                "joined": EXPANDER.record_joined,
+                "multipliers": EXPANDER.record_multipliers}
     for counter, *args in rows:
         counters[counter](*args)
 
@@ -603,6 +606,15 @@ class ExpanderStats:
     strength the last delta-rule mixer traced can give (``LMConfig.
     linear_write_scale``: 1.0 for ``sigmoid(b)``, 2.0 for ``2 sigmoid(b)``;
     0.0 before any was traced).
+    ``ssm_mixers`` counts the selective state-space mixers
+    (models/lm.py:SSMMixer) by the same three forms (ops/ssm.py:form),
+    ``joined_layers`` the layers traced with several token mixers side by
+    side under ONE norm, by the form of the executable, and
+    ``multipliers_applied`` is how many of the forward multipliers of the
+    last model traced are off 1 (``LMConfig.multipliers_applied``; 0: the
+    forward pass scales nothing). ``state_bytes_stepped`` and
+    ``fork_bytes_copied`` count a state-space part's state and kept inputs
+    as they count a linear layer's.
     Of a looped model (``LMConfig.total_ut_steps`` over 1): ``layer_passes``,
     the passes of the whole stack its decode steps ran (every pass of every
     step, whichever the head read; over ``decode_steps``: passes a token); ``exit_pass``, the tokens made by the
@@ -652,6 +664,9 @@ class ExpanderStats:
                           for placement in ("pre", "post")}  # guarded-by: _lock
             self.unrotated = dict.fromkeys(self.deltas, 0)  # guarded-by: _lock
             self.write_bound = 0.0     # guarded-by: _lock
+            self.ssms = dict.fromkeys(self.deltas, 0)  # guarded-by: _lock
+            self.joined = dict.fromkeys(self.deltas, 0)  # guarded-by: _lock
+            self.multipliers = 0       # guarded-by: _lock
 
     def record_product(self, path: str) -> None:
         """One expert layer in one trace took product ``path``."""
@@ -698,6 +713,25 @@ class ExpanderStats:
         _note_site("unrotated", str(form))
         with self._lock:
             self.unrotated[form] += 1
+
+    def record_ssm(self, form: str) -> None:
+        """One state-space mixer in one trace, of ``form``."""
+        _note_site("ssm", str(form))
+        with self._lock:
+            self.ssms[form] += 1
+
+    def record_joined(self, form: str) -> None:
+        """One layer of several token mixers under one norm in one trace
+        of an executable of ``form``."""
+        _note_site("joined", str(form))
+        with self._lock:
+            self.joined[form] += 1
+
+    def record_multipliers(self, applied: int) -> None:
+        """The model traced applies ``applied`` forward multipliers."""
+        _note_site("multipliers", int(applied))
+        with self._lock:
+            self.multipliers = int(applied)
 
     def record(self, *, prefilled: int, from_prefix: int, sequences: int,
                decoded: int, decode_steps: int, experts_read: int, load,
@@ -784,6 +818,9 @@ class ExpanderStats:
                                    placement, by_form in self.norms.items()},
                 "attention_unrotated": dict(self.unrotated),
                 "write_strength_bound": self.write_bound,
+                "ssm_mixers": dict(self.ssms),
+                "joined_layers": dict(self.joined),
+                "multipliers_applied": self.multipliers,
                 "layer_passes": self.layer_passes,
                 "exit_pass": list(self.exit_pass),
                 "exit_lambda_max": self.exit_lambda_max,

@@ -494,6 +494,18 @@ EXPANDER_EXECUTABLES = [
     ("decode4", "sd15_olmo_hybrid_expander", 2560, 8.50, 0, 128, 330),
     ("prefill", "sd15_olmo_hybrid_expander", 2560, 8.30, 0, 400, 180),
     ("prefill2048", "sd15_olmo_hybrid_expander", 2560, 8.30, 0, 2000, 180),
+    # no kernel either: nine layers of attention (20 heads over 4 KV heads,
+    # rotated at theta 1e11) AND a state-space mixer that steps a (32, 128,
+    # 256) float32 state a sequence, element-wise, under thirteen
+    # multipliers; four sequences donate the one sequence's 47 MB of keys
+    # and values (shared, handed through), 256 own slots a layer each and
+    # thirty-six states with their kept rows, 219 MB; the prompt's chunk
+    # chunk-wise (one chunk of 128 padded from 64) over nine states; the
+    # instruction's one chunk of 2 048 (sixteen chunks' segment sums and 20
+    # heads' scores over 2 560 positions: 0.82 GB of temporaries)
+    ("decode4", "sd15_falcon_h1_expander", 2560, 9.25, 0, 128, 215),
+    ("prefill", "sd15_falcon_h1_expander", 2560, 9.10, 0, 64, 84),
+    ("prefill2048", "sd15_falcon_h1_expander", 2560, 9.10, 0, 1000, 84),
 ]
 
 

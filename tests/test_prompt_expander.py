@@ -947,7 +947,8 @@ class TestEnginePath:
             "sinkhorn_iters", "layer_passes", "exit_pass",
             "exit_lambda_max", "delta_mixers", "delta_steps",
             "state_bytes_stepped", "fork_bytes_copied", "sublayer_norms",
-            "attention_unrotated", "write_strength_bound"}
+            "attention_unrotated", "write_strength_bound", "ssm_mixers",
+            "joined_layers", "multipliers_applied"}
         # a model of one pass leaves the looped model's counters alone
         assert (block["layer_passes"], block["exit_pass"],
                 block["exit_lambda_max"]) == (0, [], 0.0)
@@ -966,6 +967,11 @@ class TestEnginePath:
         assert not any(block["sublayer_norms"]["post"].values())
         assert not any(block["attention_unrotated"].values())
         assert block["write_strength_bound"] == 0.0
+        # nor a state-space mixer, a layer of two mixers or a multiplier
+        assert not any(block["ssm_mixers"].values())
+        assert not any(block["joined_layers"].values())
+        assert set(block["ssm_mixers"]) == set(block["delta_mixers"])
+        assert block["multipliers_applied"] == 0
         json.dumps(block)
 
     @pytest.mark.parametrize("sequences,forked_at,steps", [
