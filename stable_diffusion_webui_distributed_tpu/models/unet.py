@@ -34,7 +34,9 @@ from stable_diffusion_webui_distributed_tpu.ops.quant import (
     linear as _linear,
 )
 from stable_diffusion_webui_distributed_tpu.ops.upsample import UpsampleConv
-from stable_diffusion_webui_distributed_tpu.serving.metrics import ATTENTION
+from stable_diffusion_webui_distributed_tpu.serving.metrics import (
+    ATTENTION, NORM,
+)
 
 
 def timestep_embedding(t: jax.Array, dim: int, max_period: float = 10000.0) -> jax.Array:
@@ -47,10 +49,42 @@ def timestep_embedding(t: jax.Array, dim: int, max_period: float = 10000.0) -> j
 
 #: GroupNorm32 pins its input (below) from this many spatial positions a row
 #: on: SDXL's 128x128 level. Measured on a v5e (PERF.md section 6, PR 29):
-#: there the pin takes 306 ms off a request; at 64x64 and 32x32 the float32
-#: copies cost little and a pinned input makes XLA fuse the normalise into a
-#: plain convolution that runs at a third of the spatial-major one's speed.
+#: there the pin takes 306 ms off a request. Under it a pinned INPUT costs
+#: more than the float32 copies it saves (those lie in on-chip memory there):
+#: a convolution whose input comes from behind a barrier, and not from a
+#: neighbour in the spatial-major form, runs batch-major at a third of the
+#: speed (PERF.md section 6, PR 65: ``conv2`` of ten SD1.5 ResBlocks, the
+#: module 334 -> 352 ms).
 PIN_MIN_POSITIONS = 128 * 128
+
+#: ...and under it, up to this many rows (one image beside its unconditional
+#: twin), the norm's SUMS read a pinned copy and a ResBlock's 1x1 ``skip``
+#: is a matrix product over the positions. There the TPU compiler tiles the
+#: two rows alone on the sublanes (``T(2,128)``): every pass over the
+#: activation fills a quarter of a register and the 1x1 convolution runs
+#: batch-major. A pinned copy for the sums gives the activation whole tiles
+#: and leaves the normalise, between two convolutions, in their form
+#: (PERF.md section 6, PR 65, one v5e: SD1.5's module 334.3 -> 316.2 ms,
+#: SDXL's 2 912 -> 2 900; at four rows SDXL's 5 816 -> 5 839, so not there).
+TWO_ROW_TILE = 2
+
+
+def two_row_tiles(rows: int, dtype) -> bool:
+    """Whether a narrow activation of this many rows gets such tiles."""
+    return dtype != jnp.float32 and rows <= TWO_ROW_TILE
+
+
+def norm_form(rows: int, positions: int, dtype) -> str:
+    """How a GroupNorm site of this static shape is traced: ``pinned`` (the
+    whole norm behind an optimisation barrier), ``stats_pinned`` (only the
+    sums) or ``plain``. A float32 activation (the VAE) has no cast to hold
+    out of its neighbours: a barrier there forces float32 tensors XLA
+    otherwise keeps in bf16."""
+    if dtype == jnp.float32:
+        return "plain"
+    if positions >= PIN_MIN_POSITIONS:
+        return "pinned"
+    return "stats_pinned" if two_row_tiles(rows, dtype) else "plain"
 
 
 class _ChannelAffine(nn.Module):
@@ -73,15 +107,18 @@ class GroupNorm32(nn.Module):
     vectors, then one elementwise pass ``x * a + b`` in float32 that writes
     the storage dtype; the activation is never viewed by groups.
 
-    A narrower-than-float32 activation of ``PIN_MIN_POSITIONS`` or more
-    positions a row is pinned by an optimisation barrier first. Without it
-    the TPU compiler hoists the cast into the producing convolution's
-    epilogue, in that convolution's spatial-major shape with the batch
-    folded into a block index, and then moves float32 copies of the
-    activation, of its square and of the broadcast ``a`` and ``b`` through
-    HBM (PERF.md section 6, PR 29: 2.9 GB of float32 copies an SDXL step).
-    A float32 activation (the VAE decoder) has no cast to pin, and a barrier
-    there forces float32 tensors XLA otherwise keeps in bf16.
+    A narrower-than-float32 activation is pinned by an optimisation barrier
+    where :func:`norm_form` says so, from its static shape. With
+    ``PIN_MIN_POSITIONS`` or more positions a row the whole norm reads the
+    pinned activation: without it the TPU compiler hoists the cast into the
+    producing convolution's epilogue, in that convolution's spatial-major
+    shape with the batch folded into a block index, and then moves float32
+    copies of the activation, of its square and of the broadcast ``a`` and
+    ``b`` through HBM (PERF.md section 6, PR 29: 2.9 GB of float32 copies an
+    SDXL step). Under that size, at ``TWO_ROW_TILE`` rows or fewer, only the
+    sums read a pinned copy: the normalise stays in its neighbours' layout
+    and the activation leaves the two-row tiles (PR 65). Every site is
+    counted at trace time by its form (serving/metrics.py ``NORM``).
 
     Parameters sit at ``gn/scale`` and ``gn/bias``, where
     ``flax.linen.GroupNorm`` under that name kept them.
@@ -96,23 +133,48 @@ class GroupNorm32(nn.Module):
         per_group = channels // groups
         positions = x.size // (batch * channels)
         scale, bias = _ChannelAffine(name="gn")(channels)
-        if x.dtype != jnp.float32 and positions >= PIN_MIN_POSITIONS:
+        form = norm_form(batch, positions, x.dtype)
+        if not self.is_initializing():
+            NORM.record(form)
+        if form == "pinned":
             x = jax.lax.optimization_barrier(x)
         spatial = tuple(range(1, x.ndim - 1))
         x32 = x.astype(jnp.float32)
+        summed = x32
+        if form == "stats_pinned":
+            summed = jax.lax.optimization_barrier(x).astype(jnp.float32)
 
         def group_mean(per_channel_sum: jax.Array) -> jax.Array:
             grouped = per_channel_sum.reshape(batch, groups, per_group).sum(-1)
             return jnp.repeat(grouped / (positions * per_group), per_group,
                               axis=-1)
 
-        mean = group_mean(x32.sum(spatial))
-        var = jnp.maximum(0.0, group_mean(jnp.square(x32).sum(spatial))
+        mean = group_mean(summed.sum(spatial))
+        var = jnp.maximum(0.0, group_mean(jnp.square(summed).sum(spatial))
                           - jnp.square(mean))
         a = scale * jax.lax.rsqrt(var + 1e-6)  # flax GroupNorm's epsilon
         b = bias - mean * a
         a, b = (jnp.expand_dims(v, spatial) for v in (a, b))
         return (x32 * a + b).astype(x.dtype)
+
+
+class PointwiseConv(nn.Module):
+    """A 1x1 convolution to ``features`` channels as one matrix product
+    over the flattened positions, under ``nn.Conv``'s parameter names."""
+
+    features: int
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (1, 1, x.shape[-1], self.features))
+        bias = self.param("bias", nn.initializers.zeros, (self.features,))
+        batch, channels = x.shape[0], x.shape[-1]
+        out = jnp.dot(x.astype(self.dtype).reshape(batch, -1, channels),
+                      kernel[0, 0].astype(self.dtype))
+        return (out + bias.astype(self.dtype)).reshape(
+            *x.shape[:-1], self.features)
 
 
 class ResBlock(nn.Module):
@@ -134,8 +196,14 @@ class ResBlock(nn.Module):
         h = _conv(qc, self.out_channels, padding=1, dtype=self.dtype,
                   name="conv2")(h)
         if x.shape[-1] != self.out_channels:
-            x = _conv(qc, self.out_channels, (1, 1), padding=0,
-                      dtype=self.dtype, name="skip")(x)
+            # two rows on the sublanes: XLA runs the 1x1 convolution
+            # batch-major, the product over the positions as a Dense
+            if two_row_tiles(x.shape[0], self.dtype) and not qc:
+                x = PointwiseConv(self.out_channels, dtype=self.dtype,
+                                  name="skip")(x)
+            else:
+                x = _conv(qc, self.out_channels, (1, 1), padding=0,
+                          dtype=self.dtype, name="skip")(x)
         return (x.astype(jnp.float32) + h.astype(jnp.float32)).astype(self.dtype)
 
 

@@ -27,12 +27,13 @@ with tokens, experts and its cache is :data:`EXPANDER`
 is ``summary()["decode"]`` (``rows`` over ``dispatches``, fed by
 ``Engine._queue_decoded``). Which form the UNet's and the VAE decoder's
 upsample sites took is :data:`UPSAMPLE` (``summary()["upsample"]``), fed
-by ``ops/upsample.py``. How often a request's plan met a kept sigma
-ladder or a kept time-id embedding (runtime/kept.py) is :data:`PLAN`
-(``summary()["plan"]``). How each stage's program came to be since the
-process started is ``summary()["programs"]``: ``loaded`` from the kept
-programs (serving/aot.py) with the seconds that took (``load_s``), or
-``traced``.
+by ``ops/upsample.py``; which form a GroupNorm site took is :data:`NORM`
+(``summary()["norm"]``), fed by ``models/unet.py``. How often a request's
+plan met a kept sigma ladder or a kept time-id embedding (runtime/kept.py)
+is :data:`PLAN` (``summary()["plan"]``). How each stage's program came to
+be since the process started is ``summary()["programs"]``: ``loaded`` from
+the kept programs (serving/aot.py) with the seconds that took
+(``load_s``), or ``traced``.
 
 The counters fed at trace time count nothing when a stage's program is
 loaded instead of traced, so what a trace counted is kept beside the
@@ -236,6 +237,7 @@ class DispatchMetrics:
         out["xla"] = XLA.summary()    # its own lock, never under this one
         out["attention"] = ATTENTION.summary()
         out["upsample"] = UPSAMPLE.summary()
+        out["norm"] = NORM.summary()
         out["expander"] = EXPANDER.summary()
         out["plan"] = PLAN.summary()
         return out     # server/api.py adds "host" beside a host clock
@@ -417,9 +419,9 @@ _SITES = threading.local()
 @contextlib.contextmanager
 def capture_sites() -> Iterator[List[list]]:
     """While open, every count the trace-time counters take on this thread
-    (:data:`ATTENTION`, :data:`UPSAMPLE`, ``EXPANDER``'s products, mixers
-    and convs) is also appended to the list yielded, as JSON-able rows
-    that :func:`replay_sites` counts again."""
+    (:data:`ATTENTION`, :data:`UPSAMPLE`, :data:`NORM`, ``EXPANDER``'s
+    products, mixers and convs) is also appended to the list yielded, as
+    JSON-able rows that :func:`replay_sites` counts again."""
     was = getattr(_SITES, "rows", None)
     rows: List[list] = []
     _SITES.rows = rows
@@ -440,6 +442,7 @@ def replay_sites(rows) -> None:
     counters = {"attention": ATTENTION.record,
                 "attention_layout": ATTENTION.record_layout,
                 "upsample": UPSAMPLE.record,
+                "norm_site": NORM.record,
                 "product": EXPANDER.record_product,
                 "mixer": EXPANDER.record_mixer,
                 "conv": EXPANDER.record_conv,
@@ -521,14 +524,15 @@ class AttentionSites:
         return out
 
 
-class UpsampleSites:
-    """Upsample sites (nearest-2x then a 3x3 convolution: the UNet's
-    ``up_{level}_us``, the VAE decoder's) by the form they took, counted
-    when a model is applied under a trace, never when it is initialised
-    nor when an executable runs: ``folded`` (the four 2x2 phases as one
-    convolution of the low-resolution input, ops/upsample.py) or ``plain``
-    (the 3x3 on the upsampled image: the int8 convolutions). Per trace, as
-    :class:`AttentionSites` counts."""
+class FormSites:
+    """Sites of one kind by the form they took, counted when a model is
+    applied under a trace, never when it is initialised nor when an
+    executable runs. Per trace, as :class:`AttentionSites` counts.
+    ``FORMS`` names the forms ``summary()`` reports, ``NOTE`` the rows
+    :func:`capture_sites` keeps for :func:`replay_sites`."""
+
+    FORMS: tuple = ()
+    NOTE = ""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -539,15 +543,36 @@ class UpsampleSites:
             self.sites: Dict[str, int] = defaultdict(int)  # guarded-by: _lock
 
     def record(self, form: str) -> None:
-        _note_site("upsample", str(form))
+        _note_site(self.NOTE, str(form))
         with self._lock:
             self.sites[form] += 1
 
     def summary(self) -> Dict[str, int]:
-        """``{"folded": n, "plain": m}``."""
+        """``{form: sites}`` over ``FORMS``."""
         with self._lock:
-            return {form: self.sites.get(form, 0)
-                    for form in ("folded", "plain")}
+            return {form: self.sites.get(form, 0) for form in self.FORMS}
+
+
+class UpsampleSites(FormSites):
+    """Upsample sites (nearest-2x then a 3x3 convolution: the UNet's
+    ``up_{level}_us``, the VAE decoder's): ``folded`` (the four 2x2 phases
+    as one convolution of the low-resolution input, ops/upsample.py) or
+    ``plain`` (the 3x3 on the upsampled image: the int8 convolutions)."""
+
+    FORMS = ("folded", "plain")
+    NOTE = "upsample"
+
+
+class NormSites(FormSites):
+    """GroupNorm sites (models/unet.py ``GroupNorm32``: the UNet's, the
+    VAE's): ``pinned`` (the whole norm reads the activation behind an
+    optimisation barrier: a narrow activation of 128 x 128 positions or
+    more), ``stats_pinned`` (only the statistics read a pinned copy, the
+    normalise reads the activation as it lies: a narrow activation of two
+    rows or fewer below that size) or ``plain``."""
+
+    FORMS = ("pinned", "stats_pinned", "plain")
+    NOTE = "norm_site"
 
 
 class ExpanderStats:
@@ -942,6 +967,9 @@ ATTENTION = AttentionSites()
 
 #: Process-wide count of upsample sites by form (fed at trace time).
 UPSAMPLE = UpsampleSites()
+
+#: Process-wide count of GroupNorm sites by form (fed at trace time).
+NORM = NormSites()
 
 #: Process-wide metrics instance (mirrors ``trace.STATS``).
 METRICS = DispatchMetrics()

@@ -37,7 +37,7 @@ from stable_diffusion_webui_distributed_tpu.runtime.interrupt import (
 )
 from stable_diffusion_webui_distributed_tpu.serving import aot as aot_mod
 from stable_diffusion_webui_distributed_tpu.serving.metrics import (
-    ATTENTION, METRICS, UPSAMPLE, XLA,
+    ATTENTION, METRICS, NORM, UPSAMPLE, XLA,
 )
 from test_pipeline import init_params
 
@@ -86,6 +86,7 @@ def restart():
     XLA.clear()
     ATTENTION.clear()
     UPSAMPLE.clear()
+    NORM.clear()
 
 
 def run(p=None, params=None):
@@ -97,7 +98,7 @@ def run(p=None, params=None):
     with XLA._lock:
         traced = {fun for fun, row in XLA.functions.items()
                   if row["traces"]} & STAGE_FUNCTIONS
-    sites = (summary["attention"], summary["upsample"])
+    sites = (summary["attention"], summary["upsample"], summary["norm"])
     return result.images, summary["programs"], traced, sites
 
 

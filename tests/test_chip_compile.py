@@ -14,6 +14,7 @@ says the chip's compiler accepts the kernel, not that its result is right
 (chip_smoke.py compares results on the chip).
 """
 
+import math
 import os
 import re
 import sys
@@ -36,6 +37,9 @@ from stable_diffusion_webui_distributed_tpu.ops.flash_attention import (
 )
 from stable_diffusion_webui_distributed_tpu.ops.ragged_attention import (
     _ragged_bhtd,
+)
+from stable_diffusion_webui_distributed_tpu.models.unet import (
+    GroupNorm32, ResBlock,
 )
 from stable_diffusion_webui_distributed_tpu.ops.upsample import UpsampleConv
 
@@ -256,6 +260,69 @@ def test_folded_upsample_site_holds_no_gather(one_chip, batch, side, channels,
             variables, up)
 
     assert _GATHER.search(_compiled_text(resized, variables, x))
+
+
+#: a convolution instruction of the optimised HLO: result, window, labels, scope
+_CONVOLUTION = re.compile(
+    r" = (\w+)\[[\d,]*\]\S* convolution\(.*window=\{size=(\w+).*"
+    r'dim_labels=(\w+)_.*op_name="([^"]*)"')
+_FLOAT32_COPY = re.compile(
+    r" = f32\[([\d,]+)\]\S* copy\(.*op_name=\"([^\"]*)\"")
+
+
+class _ResBlocks(nn.Module):
+    """SD1.5's ``up_0``: a ResBlock over the concatenated skip (so a 1x1
+    ``skip``) and two more, then the next site's norm."""
+
+    @nn.compact
+    def __call__(self, x, temb):
+        for i in range(3):
+            x = ResBlock(320, dtype=jnp.bfloat16, name=f"res_{i}")(x, temb)
+        return GroupNorm32(name="norm")(x)
+
+
+@pytest.mark.parametrize("rows", [2, 8])
+def test_a_two_row_resblock_chain_keeps_its_convolutions_spatial_major(
+        one_chip, rows):
+    """SD1.5's 64 x 64 ResBlocks at CFG batch 2 with the sums of every
+    norm read from a pinned copy: every 3x3 convolution of the v5e's
+    optimised HLO still takes rows x column blocks as its batch
+    (``0b1f``; a pin on the norm's INPUT turned ``conv2`` batch-major in
+    the whole UNet, a third of the speed: PERF.md section 6, PR 65), no
+    float32 copy of the activation's size feeds a norm's sums (the
+    convolution's float32 result in the reduction's layout, and its
+    square: two a norm before; the broadcast ``a`` and ``b`` stay), the
+    1x1 ``skip`` is a product and not a convolution; at
+    eight rows it is the convolution it was. A chain this short does not
+    show the two-row tiles: ``tools/chunk_hlo.py`` reads the whole
+    executable's (``two_row_tile_mb``)."""
+    def on_chip(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    x, temb = on_chip((rows, 64, 64, 640)), on_chip((rows, 1280))
+    module = _ResBlocks()
+    shapes = jax.eval_shape(
+        lambda: module.init(jax.random.key(0), jnp.zeros(x.shape, x.dtype),
+                            jnp.zeros(temb.shape, temb.dtype)))
+    variables = jax.tree.map(lambda leaf: on_chip(leaf.shape), shapes)
+    text = _compiled_text(module.apply, variables, x, temb)
+    convolutions = _CONVOLUTION.findall(text)
+    three = [labels for _, window, labels, scope in convolutions
+             if window == "3x3" and "conv_general_dilated" in scope]
+    assert len(three) == 6
+    skips = [scope for *_, scope in convolutions if "/skip/" in scope]
+    assert len(skips) == 1
+    if rows == 8:
+        assert "conv_general_dilated" in skips[0]
+        return
+    assert set(three) == {"0b1f"}, three
+    assert "dot_general" in skips[0]
+    activation = rows * 64 * 64 * 320
+    copied = [scope for dims, scope in _FLOAT32_COPY.findall(text)
+              if math.prod(map(int, dims.split(","))) >= activation
+              and re.search(r"/norm[12]?/(convert_element_type|square)$",
+                            scope)]
+    assert not copied, copied
 
 
 @pytest.mark.parametrize("precision", ["default", "highest"])

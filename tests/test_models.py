@@ -455,21 +455,119 @@ class TestGroupNorm32:
         (out,) = closed.out_avals
         assert out.dtype == jnp.bfloat16 and out.shape == shape
 
-    @pytest.mark.parametrize("side,dtype,pinned", [
-        (128, jnp.bfloat16, True), (64, jnp.bfloat16, False),
-        (128, jnp.float32, False)])
-    def test_pins_only_a_narrow_activation_of_the_large_level(
-            self, side, dtype, pinned):
-        """The optimisation barrier goes with shape and dtype alone (no
-        knob): bf16 at 128 x 128 positions and over, where the TPU compiler
-        otherwise copies float32 activations through HBM; never a float32
-        activation (the VAE decoder), never the small levels."""
-        x = jnp.zeros((1, side, side, 8), dtype)
+    @pytest.mark.parametrize("rows,side,dtype,form", [
+        (rows, side, dtype,
+         "plain" if dtype == jnp.float32 else
+         "pinned" if side == 128 else
+         "stats_pinned" if rows == 2 else "plain")
+        for rows in (2, 4, 8) for side in (64, 32, 128)
+        for dtype in (jnp.bfloat16, jnp.float32)])
+    def test_the_form_goes_with_shape_and_dtype_alone(self, rows, side,
+                                                      dtype, form):
+        """No knob: bf16 at 128 x 128 positions and over is pinned whole
+        (the TPU compiler otherwise copies float32 activations through
+        HBM); under that, two rows pin a copy for the sums alone (two rows
+        lie alone on the sublanes, PERF.md section 6, PR 65) and four or
+        eight rows nothing (eight rows trace the program they traced
+        before); never a float32 activation (the VAE decoder). The site is
+        counted by its form when applied, not when initialised."""
+        from stable_diffusion_webui_distributed_tpu.models.unet import (
+            norm_form,
+        )
+        from stable_diffusion_webui_distributed_tpu.serving.metrics import (
+            NORM,
+        )
+
+        assert norm_form(rows, side * side, dtype) == form
+        x = jnp.zeros((rows, side, side, 8), dtype)
         module = GroupNorm32()
+        NORM.clear()
         params = jax.eval_shape(module.init, jax.random.key(0), x)
+        assert not any(NORM.summary().values())
         closed = jax.make_jaxpr(module.apply)(params, x)
-        names = [eqn.primitive.name for eqn in closed.jaxpr.eqns]
-        assert ("optimization_barrier" in names) == pinned
+        assert NORM.summary() == {
+            name: int(name == form) for name in NORM.FORMS}
+        barriers = [eqn for eqn in closed.jaxpr.eqns
+                    if eqn.primitive.name == "optimization_barrier"]
+        assert len(barriers) == (form != "plain")
+        if form == "stats_pinned":
+            # the normalise reads the activation as it lies: the pinned
+            # copy feeds the two sums and nothing else of its size
+            (pinned,) = barriers[0].outvars
+            readers = [eqn for eqn in closed.jaxpr.eqns
+                       if pinned in eqn.invars]
+            assert [eqn.primitive.name for eqn in readers] == [
+                "convert_element_type"]
+            final = closed.jaxpr.eqns[-1]
+            assert final.primitive.name == "convert_element_type"
+
+    @pytest.mark.parametrize("mean_over_std", [0.0, 10.0])
+    @pytest.mark.parametrize("channels", [320, 960])
+    def test_the_pinned_sums_are_the_plain_sums(self, channels,
+                                                mean_over_std):
+        """Two rows (sums from a pinned copy) against the same rows among
+        eight (plain): bit for bit, and so at the same float64 distances."""
+        rng = np.random.default_rng(channels)
+        x = jnp.asarray(1.3 * (rng.standard_normal((8, 16, 16, channels))
+                               + mean_over_std), jnp.bfloat16)
+        gn = {"scale": jnp.asarray(1.0 + 0.3 * rng.standard_normal(channels),
+                                   jnp.float32),
+              "bias": jnp.asarray(rng.standard_normal(channels), jnp.float32)}
+        apply = jax.jit(GroupNorm32().apply)
+        plain = apply({"params": {"gn": gn}}, x)
+        pinned = apply({"params": {"gn": gn}}, x[:2])
+        assert (np.asarray(pinned, np.float32)
+                == np.asarray(plain[:2], np.float32)).all()
+
+
+class TestPointwiseSkip:
+    """A ResBlock's 1x1 ``skip`` at two rows in a narrow dtype is a matrix
+    product under ``nn.Conv``'s parameter names (models/unet.py
+    ``PointwiseConv``): the convolution everywhere else."""
+
+    @pytest.mark.parametrize("rows,dtype,product", [
+        (2, jnp.bfloat16, True), (1, jnp.bfloat16, True),
+        (4, jnp.bfloat16, False), (8, jnp.bfloat16, False),
+        (2, jnp.float32, False)])
+    def test_the_skip_goes_with_rows_and_dtype_alone(self, rows, dtype,
+                                                     product):
+        from stable_diffusion_webui_distributed_tpu.models.unet import (
+            ResBlock,
+        )
+
+        x = jnp.zeros((rows, 8, 8, 64), dtype)
+        temb = jnp.zeros((rows, 32), dtype)
+        block = ResBlock(32, dtype=dtype)
+        params = jax.eval_shape(block.init, jax.random.key(0), x, temb)
+        skip = params["params"]["skip"]
+        assert skip["kernel"].shape == (1, 1, 64, 32)
+        assert skip["bias"].shape == (32,)
+        closed = jax.make_jaxpr(block.apply)(params, x, temb)
+        convs = [eqn for eqn in closed.jaxpr.eqns
+                 if eqn.primitive.name == "conv_general_dilated"]
+        assert len(convs) == (2 if product else 3)
+
+    @pytest.mark.parametrize("quant", [False, True])
+    def test_the_product_is_the_convolution(self, quant):
+        """Same parameters, two rows against the same rows among four: the
+        product's block is the convolution's to bf16's last bits, and an
+        int8 ResBlock keeps its convolution."""
+        from stable_diffusion_webui_distributed_tpu.models.unet import (
+            ResBlock,
+        )
+
+        rng = np.random.default_rng(5)
+        x = jnp.asarray(rng.standard_normal((4, 8, 8, 64)), jnp.bfloat16)
+        temb = jnp.asarray(rng.standard_normal((4, 32)), jnp.bfloat16)
+        block = ResBlock(32, dtype=jnp.bfloat16, quant_convs=quant)
+        params = block.init(jax.random.key(0), x, temb)
+        four = np.asarray(block.apply(params, x, temb), np.float32)
+        two = np.asarray(block.apply(params, x[:2], temb[:2]), np.float32)
+        if quant:
+            assert (two == four[:2]).all()
+        else:
+            assert np.abs(two - four[:2]).max() <= 2.0 ** -6 * np.abs(
+                four).max()
 
 
 class TestTokenizer:

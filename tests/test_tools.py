@@ -829,13 +829,28 @@ class TestChunkHlo:
   ROOT %p = bf16[2,8]{1,0} parameter(0)
 }
 
+%fused_computation.2 (p: bf16[128,16,17,960], k: bf16[3,3,960,320]) -> bf16[128,16,17,320] {
+  %p = bf16[128,16,17,960]{3,1,2,0:T(8,128)(2,1)} parameter(0)
+  %k = bf16[3,3,960,320]{3,2,1,0:T(8,128)(2,1)} parameter(1)
+  ROOT %conv.1 = bf16[128,16,17,320]{3,1,2,0:T(8,128)(2,1)} convolution(%p, %k), window={size=3x3 pad=1_1x1_1}, dim_labels=0b1f_01io->0b1f, metadata={op_name="jit(run_chunk)/while/body/closed_call/UNet/up_0_res_0/conv1/conv_general_dilated"}
+}
+
+%fused_computation.3 (p: bf16[2,32,32,1280], k: bf16[3,3,1280,1280]) -> (f32[2,1280], bf16[2,32,32,1280]) {
+  %p = bf16[2,32,32,1280]{3,0,2,1:T(2,128)(2,1)} parameter(0)
+  %k = bf16[3,3,1280,1280]{3,2,1,0:T(8,128)(2,1)} parameter(1)
+  %conv.2 = bf16[2,32,32,1280]{3,0,2,1:T(2,128)(2,1)} convolution(%p, %k), window={size=3x3 pad=1_1x1_1}, dim_labels=b01f_01io->b01f, metadata={op_name="jit(run_chunk)/while/body/closed_call/UNet/up_2_res_0/conv2/conv_general_dilated"}
+  %sum.3 = f32[2,1280]{1,0} reduce(%conv.2), dimensions={1,2}
+  ROOT %both = (f32[2,1280]{1,0}, bf16[2,32,32,1280]{3,0,2,1:T(2,128)(2,1)}) tuple(%sum.3, %conv.2)
+}
+
 %body (arg: (f32[2])) -> (f32[2]) {
   %x = bf16[128,16,17,960]{3,1,2,0:T(8,128)(2,1)} parameter(0)
   %copy.1 = f32[128,16,17,960]{0,3,2,1:T(8,128)} copy(%x), metadata={op_name="jit(run_chunk)/while/body/closed_call/UNet/up_0_res_0/norm1/convert_element_type"}
   %broadcast.2 = f32[128,2,136,960]{0,3,2,1:T(8,128)} broadcast(%x), dimensions={1,3}, metadata={op_name="jit(run_chunk)/while/body/closed_call/UNet/up_0_res_0/norm1/mul"}
   %bitcast.3 = f32[128,16,17,960]{0,3,2,1:T(8,128)} bitcast(%broadcast.2)
-  %fusion.4 = bf16[128,16,17,320]{3,1,2,0:T(8,128)(2,1)} fusion(%x), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(run_chunk)/while/body/closed_call/UNet/up_0_res_0/conv1/conv_general_dilated"}
-  %fusion.5 = bf16[2,32,32,1280]{3,0,2,1:T(2,128)(2,1)} fusion(%x), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(run_chunk)/while/body/closed_call/UNet/up_2_res_0/conv2/conv_general_dilated"}
+  %fusion.4 = bf16[128,16,17,320]{3,1,2,0:T(8,128)(2,1)} fusion(%x), kind=kOutput, calls=%fused_computation.2, metadata={op_name="jit(run_chunk)/while/body/closed_call/UNet/up_0_res_0/conv1/conv_general_dilated"}
+  %fusion.5 = (f32[2,1280]{1,0}, bf16[2,32,32,1280]{3,0,2,1:T(2,128)(2,1)}) fusion(%x), kind=kOutput, calls=%fused_computation.3, metadata={op_name="jit(run_chunk)/while/body/closed_call/UNet/up_2_attn_0/norm/reduce_sum"}
+  %pad.7 = bf16[2,36,32,1280]{3,0,2,1:T(2,128)(2,1)} pad(%x, %x), padding=0_0x2_2x0_0x0_0
   %small.6 = f32[2,960]{1,0} fusion(%x), kind=kLoop, calls=%fused_computation.1
   ROOT %t = (f32[2]) tuple(%small.6)
 }
@@ -854,11 +869,17 @@ ENTRY %main (a: f32[2]) -> f32[2] {
             ["copy", "f32", 1, round(each, 1)],
             ["broadcast", "f32", 1, round(each, 1)]]
         assert out["outputs"][2] == [
-            "fusion:Output", "bf16", 2,
-            round((128 * 16 * 17 * 320 + 2 * 32 * 32 * 1280) * 2 / 1e6, 1)]
+            "fusion:Output", "bf16", 1,
+            round(128 * 16 * 17 * 320 * 2 / 1e6, 1)]
         assert not any(op == "bitcast" for op, *_ in out["outputs"])
+        # read off the instruction, so a convolution whose fusion also
+        # writes the next norm's sums (a tuple, under that norm's scope)
+        # is found, and the spatial-major one is not
         assert out["plain_convolutions"] == [
             ["UNet/up_2_res_0/conv2/conv_general_dilated",
              "bf16[2,32,32,1280]"]]
+        pad = round(2 * 36 * 32 * 1280 * 2 / 1e6, 1)
+        assert out["float32_mb"] == round(2 * each, 1)
+        assert out["pad_mb"] == out["two_row_tile_mb"] == pad
         scoped = chunk_hlo.summarise(self.HLO, scope=r"/norm1/")["outputs"]
         assert [row[0] for row in scoped] == ["copy", "broadcast"]

@@ -17,12 +17,25 @@ JSON object:
 - ``outputs``: the ops that write ``--min-mb`` or more, summed by opcode
   (fusions by kind) and element type: ``[op, dtype, count, MB]``, largest
   first. float32 ``copy`` and ``broadcast`` rows of activation size are
-  layout copies through HBM (PERF.md section 6, PR 29);
-- ``plain_convolutions``: convolution fusions whose result lies batch-major
-  (``[B, H, W, C]{3,0,2,1}``) and not in the spatial-major shape ``[rows,
-  batch x blocks, columns, C]``: with a fused producer these ran at a third
-  of the other form's speed (same entry);
+  layout copies (PERF.md section 6, PR 29; through HBM at SDXL's 128x128
+  level, out of on-chip memory and nearly free where the shape carries
+  ``S(1)``: PR 65);
+- ``float32_mb``, ``pad_mb``: of those ops, what the float32 ones and the
+  ``pad`` ones write (custom calls left out of the first);
+- ``two_row_tile_mb``: what they write in ``T(2,128)`` tiles, the batch's two
+  rows alone on the sublanes: every pass over such a tensor fills a quarter
+  of a register (PR 65);
+- ``plain_convolutions``: the convolutions of flax scopes that XLA runs
+  batch-major (``dim_labels=b01f...``: at two rows a third of the speed of
+  the spatial-major form ``0b1f``, whose batch is rows x column blocks; same
+  entry), read off the convolution instruction inside its fusion, so a
+  fusion that also writes the next norm's sums counts (until PR 65 the
+  result's shape was matched, which missed those). At eight rows every
+  convolution is batch-major, at full speed: the list tells at fewer;
 - with ``--scope``: the same sums over the ops whose flax scope matches.
+
+An op whose result is a tuple (a fusion with several outputs) is in none of
+the sums.
 
 About three minutes for SDXL. No times: a time comes from the chip
 (``benchmarks/op_table.py``); this says which ops the chip will run.
@@ -45,9 +58,11 @@ NOT_RUN = {"bitcast", "get-tuple-element", "tuple", "parameter", "constant",
 COMPUTATION = re.compile(
     r"^(?:ENTRY )?%?[\w.\-]+ \(.*?\) -> .*? \{\n(.*?)^\}", re.S | re.M)
 OP = re.compile(r"\s*(?:ROOT )?(\S+) = (\S+) ([\w-]+)\(")
-#: a convolution result with the batch outermost and channels minor: not the
-#: spatial-major form, whose shape leads with the rows
-BATCH_MAJOR = re.compile(r"\(?\w+\[[\d,]+\]\{3,0,2,1")
+#: a convolution instruction that takes the batch as its batch (``b01f``) and
+#: not rows (``0b1f``: the spatial-major form), with its result and scope
+BATCH_MAJOR = re.compile(
+    r" = (\S+) convolution\(.*dim_labels=b01f_\w+->b01f.*"
+    r'op_name="([^"]*conv_general_dilated)"')
 
 
 def _rows(text: str):
@@ -78,20 +93,25 @@ def summarise(text: str, min_mb: float = 4.0, scope: str | None = None):
     """The sums the module docstring names, out of optimised HLO text."""
     total: dict = collections.Counter()
     count: dict = collections.Counter()
-    plain = []
+    two_row = 0
     wanted = re.compile(scope) if scope else None
     for op, dtype, written, shape, where in _rows(text):
-        if (op == "fusion:Output" and "conv_general_dilated" in where
-                and BATCH_MAJOR.match(shape)):
-            plain.append([where.split("closed_call/")[-1],
-                          shape.split("{")[0]])
         if written < min_mb * 1e6 or (wanted and not wanted.search(where)):
             continue
         total[op, dtype] += written
         count[op, dtype] += 1
+        two_row += written if "T(2,128)" in shape else 0
+    plain = sorted({(where.split("closed_call/")[-1], shape.split("{")[0])
+                    for shape, where in BATCH_MAJOR.findall(text)})
     return {"outputs": [[op, dtype, count[op, dtype], round(mb / 1e6, 1)]
                         for (op, dtype), mb in total.most_common()],
-            "plain_convolutions": plain}
+            "float32_mb": round(sum(
+                mb for (op, dtype), mb in total.items()
+                if dtype == "f32" and op != "custom-call") / 1e6, 1),
+            "pad_mb": round(sum(mb for (op, _), mb in total.items()
+                                if op == "pad") / 1e6, 1),
+            "two_row_tile_mb": round(two_row / 1e6, 1),
+            "plain_convolutions": [list(row) for row in plain]}
 
 
 def compile_chunk(workload: str) -> tuple[str, float]:
