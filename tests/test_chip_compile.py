@@ -325,6 +325,36 @@ def test_a_two_row_resblock_chain_keeps_its_convolutions_spatial_major(
     assert not copied, copied
 
 
+#: a fusion of the optimised HLO under a ``skip``'s product: result dtype, dims
+_SKIP_PRODUCT = re.compile(
+    r" = (\w+)\[([\d,]+)\]\S* fusion\(.*"
+    r"op_name=\"[^\"]*/skip/dot_general\"")
+
+
+@pytest.mark.parametrize("side,channels", [(64, 640), (128, 960)])
+def test_a_two_row_skip_product_is_written_in_the_storage_dtype(
+        one_chip, side, channels):
+    """SD1.5's ``up_0`` and SDXL's ``up_0`` at CFG batch 2: the 1x1
+    ``skip`` as a product reshaped to the activation's shape BEFORE its
+    bias leaves its fusion in bf16 and in ``(B, H, W, C)``. With the bias
+    added over the flattened positions the v5e's compiler wrote the
+    product as ``f32[2, H*W, 320]`` and copied it into the residual add's
+    layout (SDXL's module 2 900.4 -> 2 883.4 ms, SD1.5's 316.2 -> 314.4:
+    PERF.md section 6, PR 66)."""
+    def on_chip(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    x, temb = on_chip((2, side, side, channels)), on_chip((2, 1280))
+    module = _ResBlocks()
+    shapes = jax.eval_shape(
+        lambda: module.init(jax.random.key(0), jnp.zeros(x.shape, x.dtype),
+                            jnp.zeros(temb.shape, temb.dtype)))
+    variables = jax.tree.map(lambda leaf: on_chip(leaf.shape), shapes)
+    text = _compiled_text(module.apply, variables, x, temb)
+    products = _SKIP_PRODUCT.findall(text)
+    assert products == [("bf16", f"2,{side},{side},320")], products
+
+
 @pytest.mark.parametrize("precision", ["default", "highest"])
 @pytest.mark.parametrize("d,f", [(3072, 1024), (2048, 512), (3584, 1024),
                                  (2048, 1536), (2304, 896), (2048, 768)])

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from stable_diffusion_webui_distributed_tpu.models.configs import (
-    CLIPTextConfig, TINY, TINY_XL,
+    CLIPTextConfig, TINY, TINY_XL, UNetConfig,
 )
 from stable_diffusion_webui_distributed_tpu.models import convert
 from stable_diffusion_webui_distributed_tpu.models.clip import CLIPTextModel
@@ -568,6 +568,66 @@ class TestPointwiseSkip:
         else:
             assert np.abs(two - four[:2]).max() <= 2.0 ** -6 * np.abs(
                 four).max()
+
+    @pytest.mark.parametrize("shape", [(2, 8, 8, 64), (1, 4, 6, 96)])
+    def test_the_bias_is_added_in_the_activations_shape(self, shape):
+        """The product is reshaped to ``(B, H, W, features)`` BEFORE the
+        bias: added over the flattened positions, the bias made the v5e's
+        compiler emit the product in float32 and copy it (PERF.md section
+        6, PR 66)."""
+        from stable_diffusion_webui_distributed_tpu.models.unet import (
+            PointwiseConv,
+        )
+
+        x = jnp.zeros(shape, jnp.bfloat16)
+        conv = PointwiseConv(32, dtype=jnp.bfloat16)
+        params = jax.eval_shape(conv.init, jax.random.key(0), x)
+        eqns = jax.make_jaxpr(conv.apply)(params, x).jaxpr.eqns
+        names = [eqn.primitive.name for eqn in eqns]
+        product = names.index("dot_general")
+        assert names[product + 1] == "reshape"
+        assert eqns[product + 1].outvars[0].aval.shape == (*shape[:-1], 32)
+        (add,) = [eqn for eqn in eqns if eqn.primitive.name == "add"]
+        assert add.outvars[0].aval.shape == (*shape[:-1], 32)
+        assert names.index("add") > product + 1
+
+    #: sha256 of the lowered text of a small bf16 ``UNet`` by (rows, side).
+    #: Eight rows of 64 x 64 (the batch-4 cells' SD1.5 program) and four of
+    #: 128 x 128 (``sdxl_pair``'s) take no product and are the text of
+    #: d6ed944 (PR 65); two rows (the solo cells' SD1.5 and SDXL programs)
+    #: and one take it and are PR 66's text. A PR that means to change one
+    #: of these programs replaces its hash and says so.
+    LOWERED = {
+        (8, 64): "6b016c159572ba8b04aad2cbf233acf2"
+                 "24b6af3b238ceff4eb97e5b80d788bd0",
+        (4, 128): "42751f6ee290274ccf870b1e2041ab7a"
+                  "d0c7d520954f1278c7c81a4eabb79542",
+        (2, 64): "3d583350e25a558c9c9cfb0e7353a7d2"
+                 "c3dcb46acbb2f86c1ac37308ae314b28",
+        (2, 128): "b7a8b5f75c5c098e464d8963cf3fd4a1"
+                  "c24784b8232ed4bf5702cf0801a46e26",
+        (1, 64): "24d8ae08aa8d4787b35d1e2f5ba52045"
+                 "1d8f1b6a83ce5c885de6a6aaed2a13e3"}
+
+    @pytest.mark.parametrize("rows,side", sorted(LOWERED))
+    def test_the_lowered_text_is_the_recorded_one(self, rows, side):
+        """So that the next drift of a cell's denoise program is seen:
+        PR 66 changed the two-row programs (SD1.5's four solo cells too)
+        and no others."""
+        import hashlib
+
+        config = UNetConfig(
+            block_out_channels=(32, 64), down_blocks=(None, 1),
+            layers_per_block=2, cross_attention_dim=32,
+            num_attention_heads=4, mid_block_depth=1)
+        model = UNet(config, dtype=jnp.bfloat16)
+        args = (jnp.zeros((rows, side, side, 4), jnp.float32),
+                jnp.zeros((rows,), jnp.float32),
+                jnp.zeros((rows, 77, 32), jnp.float32))
+        params = jax.eval_shape(model.init, jax.random.key(0), *args)
+        text = jax.jit(model.apply).lower(params, *args).as_text()
+        assert (hashlib.sha256(text.encode()).hexdigest()
+                == self.LOWERED[rows, side])
 
 
 class TestTokenizer:

@@ -883,3 +883,28 @@ ENTRY %main (a: f32[2]) -> f32[2] {
         assert out["pad_mb"] == out["two_row_tile_mb"] == pad
         scoped = chunk_hlo.summarise(self.HLO, scope=r"/norm1/")["outputs"]
         assert [row[0] for row in scoped] == ["copy", "broadcast"]
+
+    @pytest.mark.parametrize("space,op,hbm,layout", [
+        ("", "copy", 1, 1), ("S(1)", "copy", 0, 0),
+        ("", "custom-call", 0, 0), ("", "slice-done", 0, 0),
+        ("", "convolution", 1, 0)])
+    def test_tells_hbm_from_on_chip_memory(self, space, op, hbm, layout):
+        """``hbm_mb`` sums what lies outside ``S(1)`` (memory space decides
+        what a copy costs: PR 65), kernels' results and the weights'
+        asynchronous slices left out; ``hbm_layout_mb`` of it the ops that
+        compute nothing. One more op of 20 MB beside the class's body."""
+        import chunk_hlo
+
+        one = 128 * 2 * 128 * 320 * 2 / 1e6
+        line = (f"  %more.8 = bf16[128,2,128,320]{{3,0,2,1:T(8,128)(2,1){space}}}"
+                f" {op}(%x)\n")
+        base = chunk_hlo.summarise(self.HLO)
+        out = chunk_hlo.summarise(
+            self.HLO.replace("  %small.6 =", line + "  %small.6 ="))
+        each = 128 * 16 * 17 * 960 * 4 / 1e6
+        conv = 128 * 16 * 17 * 320 * 2 / 1e6
+        pad = 2 * 36 * 32 * 1280 * 2 / 1e6
+        assert base["hbm_mb"] == round(2 * each + conv + pad, 1)
+        assert base["hbm_layout_mb"] == round(2 * each + pad, 1)
+        assert out["hbm_mb"] == round(2 * each + conv + pad + hbm * one, 1)
+        assert out["hbm_layout_mb"] == round(2 * each + pad + layout * one, 1)

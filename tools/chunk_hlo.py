@@ -2,7 +2,7 @@
 chip.
 
     python3 tools/chunk_hlo.py --workload sdxl_solo [--scope REGEX]
-        [--keep chunk.hlo] [--min-mb 4]
+        [--keep chunk.hlo] [--min-mb 4] [--batch-size 2]
 
 Builds the cell's engine here on the CPU with zeros for weights, sends the
 cell's own request, and where the engine would run its first ``run_chunk``
@@ -22,6 +22,13 @@ JSON object:
   ``S(1)``: PR 65);
 - ``float32_mb``, ``pad_mb``: of those ops, what the float32 ones and the
   ``pad`` ones write (custom calls left out of the first);
+- ``hbm_mb``, ``hbm_layout_mb``: what they write outside on-chip memory
+  (the result's layout carries no ``S(1)``; custom calls and the weights'
+  asynchronous slices left out), all of them and the ops that only move
+  (``copy``, ``reshape``, ``pad``, ``broadcast``, loop fusions). Memory
+  space decides what a copy costs: 874 MB a step in ``S(1)`` cost SD1.5
+  6 ms a request (PR 65), 650 MB a step out of HBM gained SDXL 17 and
+  820 MB another way 12 (PR 66), so neither sum prices a form: time it;
 - ``two_row_tile_mb``: what they write in ``T(2,128)`` tiles, the batch's two
   rows alone on the sublanes: every pass over such a tensor fills a quarter
   of a register (PR 65);
@@ -57,6 +64,11 @@ NOT_RUN = {"bitcast", "get-tuple-element", "tuple", "parameter", "constant",
            "copy-start", "copy-done"}
 COMPUTATION = re.compile(
     r"^(?:ENTRY )?%?[\w.\-]+ \(.*?\) -> .*? \{\n(.*?)^\}", re.S | re.M)
+#: ops that compute nothing: they move or re-lay what another op wrote
+LAYOUT_ONLY = {"copy", "reshape", "pad", "broadcast", "fusion:Loop"}
+#: not the scan body's own traffic through HBM: kernels' results, and the
+#: weights streamed in beside the step
+NOT_ACTIVATIONS = {"custom-call", "slice-start", "slice-done"}
 OP = re.compile(r"\s*(?:ROOT )?(\S+) = (\S+) ([\w-]+)\(")
 #: a convolution instruction that takes the batch as its batch (``b01f``) and
 #: not rows (``0b1f``: the spatial-major form), with its result and scope
@@ -93,7 +105,7 @@ def summarise(text: str, min_mb: float = 4.0, scope: str | None = None):
     """The sums the module docstring names, out of optimised HLO text."""
     total: dict = collections.Counter()
     count: dict = collections.Counter()
-    two_row = 0
+    two_row = hbm = hbm_layout = 0
     wanted = re.compile(scope) if scope else None
     for op, dtype, written, shape, where in _rows(text):
         if written < min_mb * 1e6 or (wanted and not wanted.search(where)):
@@ -101,6 +113,9 @@ def summarise(text: str, min_mb: float = 4.0, scope: str | None = None):
         total[op, dtype] += written
         count[op, dtype] += 1
         two_row += written if "T(2,128)" in shape else 0
+        if "S(1)" not in shape and op not in NOT_ACTIVATIONS:
+            hbm += written
+            hbm_layout += written if op in LAYOUT_ONLY else 0
     plain = sorted({(where.split("closed_call/")[-1], shape.split("{")[0])
                     for shape, where in BATCH_MAJOR.findall(text)})
     return {"outputs": [[op, dtype, count[op, dtype], round(mb / 1e6, 1)]
@@ -110,13 +125,18 @@ def summarise(text: str, min_mb: float = 4.0, scope: str | None = None):
                 if dtype == "f32" and op != "custom-call") / 1e6, 1),
             "pad_mb": round(sum(mb for (op, _), mb in total.items()
                                 if op == "pad") / 1e6, 1),
+            "hbm_mb": round(hbm / 1e6, 1),
+            "hbm_layout_mb": round(hbm_layout / 1e6, 1),
             "two_row_tile_mb": round(two_row / 1e6, 1),
             "plain_convolutions": [list(row) for row in plain]}
 
 
-def compile_chunk(workload: str) -> tuple[str, float]:
+def compile_chunk(workload: str,
+                  batch_size: int | None = None) -> tuple[str, float]:
     """(optimised HLO text, temporaries in MB) of the cell's first chunk
-    executable, compiled for one described v5e chip."""
+    executable, compiled for one described v5e chip; ``batch_size`` in
+    place of the request's own (``sdxl_pair``'s two clients send one image
+    each and the dispatcher makes them one batch of two: four rows)."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     os.environ["JAX_PLATFORMS"] = "cpu"
     sys.path.insert(0, ROOT)
@@ -172,8 +192,10 @@ def compile_chunk(workload: str) -> tuple[str, float]:
 
     denoise.build = compiling_build
     try:
-        engine.txt2img(GenerationPayload(
-            **dict(bench.traffic(cell["traffic"])["payload"], seed=1)))
+        payload = dict(bench.traffic(cell["traffic"])["payload"], seed=1)
+        if batch_size:
+            payload["batch_size"] = batch_size
+        engine.txt2img(GenerationPayload(**payload))
     except Compiled:
         pass
     finally:
@@ -189,8 +211,11 @@ def main(argv=None) -> int:
     ap.add_argument("--scope", help="regular expression on the flax scope")
     ap.add_argument("--keep", help="write the optimised HLO text here")
     ap.add_argument("--min-mb", type=float, default=4.0)
+    ap.add_argument("--batch-size", type=int,
+                    help="images a request, in place of the traffic's own "
+                    "(2 for the program sdxl_pair's coalesced pair runs)")
     args = ap.parse_args(argv)
-    text, temp_mb = compile_chunk(args.workload)
+    text, temp_mb = compile_chunk(args.workload, args.batch_size)
     if args.keep:
         with open(args.keep, "w") as fh:
             fh.write(text)
