@@ -198,7 +198,23 @@ class LMConfig:
     kind, as ``(kind, in, out)`` triples, ``ssm_multipliers`` the five
     column ranges ``[z | x | B | C | dt]`` of a state-space mixer's
     ``in_proj`` output, ``mlp_multipliers`` a dense SwiGLU's gate
-    pre-activation and its output."""
+    pre-activation and its output. ``latent_q_scale`` multiplies a latent
+    layer's queries (both parts, after ``q_b_proj``), ``latent_kv_scale``
+    its normed latent before ``kv_b_proj`` (the cache holds it scaled, so
+    the un-rotated keys and the values carry it in every form and the
+    rotated key does not).
+
+    ``zero_experts`` says how many of the router's LAST ids are
+    zero-compute experts, ``E_e(n) = n``: they have no kernels and are held
+    wherever the token lives; the real experts are the first ``num_experts
+    - zero_experts`` (:attr:`real_experts`), and ``experts_held`` is a range
+    of those. ``moe_shortcut`` carries an expert layer's routed sum (real
+    and identity experts) OUT of its layer: the layer adds only its shared
+    expert at its own residual, and the sum lands after the NEXT layer's
+    MLP sublayer, which must be a dense one: two layers of the list then
+    spell one shortcut-connected double layer, ``x1 = x + A_0(N(x)); n =
+    N(x1); s = M(n); x2 = x1 + F_0(n); x3 = x2 + A_1(N(x2)); y = x3 +
+    F_1(N(x3)) + s``. Each default is the plain form and traces no op."""
 
     vocab_size: int = 100352
     hidden_size: int = 3072
@@ -278,6 +294,10 @@ class LMConfig:
     mixer_multipliers: Tuple[Tuple[str, float, float], ...] = ()
     ssm_multipliers: Tuple[float, float, float, float, float] = (1.0,) * 5
     mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
+    zero_experts: int = 0
+    moe_shortcut: bool = False
+    latent_q_scale: float = 1.0
+    latent_kv_scale: float = 1.0
 
     def __post_init__(self) -> None:
         if self.norm_placement and (
@@ -292,14 +312,31 @@ class LMConfig:
             raise ValueError("a looped stack (total_ut_steps over 1) wants "
                              "full attention layers all alike, dense MLPs "
                              "and one stream")
+        if self.moe_shortcut and (
+                self.residual_streams != 1 or self.total_ut_steps != 1
+                or not self.shared_expert_intermediate_size
+                or any(i + 1 not in self.dense_layers
+                       for i in self.expert_layers)):
+            raise ValueError("moe_shortcut wants a dense layer after every "
+                             "expert layer, a shared expert (the dense path "
+                             "beside the router), one stream and one pass")
+        if not 0 <= self.zero_experts <= self.num_experts:
+            raise ValueError("zero_experts are some of the router's "
+                             "num_experts outputs")
 
     @property
     def num_layers(self) -> int:
         return len(self.layer_types)
 
     @property
+    def real_experts(self) -> int:
+        """The router's outputs that are experts with kernels: the first
+        ``num_experts - zero_experts`` ids."""
+        return self.num_experts - self.zero_experts
+
+    @property
     def experts(self) -> Tuple[int, int]:
-        return self.experts_held or (0, self.num_experts)
+        return self.experts_held or (0, self.real_experts)
 
     @property
     def vocab(self) -> Tuple[int, int]:
@@ -587,13 +624,14 @@ def lm_share(cfg: LMConfig, layers, chips: int, rank: int,
     """The share of ``cfg`` one chip of ``chips`` holds when they share each
     layer: the first ``layers`` layers (the others lie on further chips as
     pipeline stages), every attention head, the shared expert, and the
-    ``rank``-th contiguous part of the experts and of the vocabulary.
+    ``rank``-th contiguous part of the experts (those that have kernels)
+    and of the vocabulary.
     ``layers`` may name the published layers held instead of counting them
     (leading dense layers that repeat one kind and shape are held once):
     a dense layer held keeps its dense MLP wherever it comes to lie.
     ``vocab_chips``: the table and the head are sliced that many ways
     where it is not as many as the experts (0: as many)."""
-    experts = cfg.num_experts // chips
+    experts = cfg.real_experts // chips
     vocab = cfg.vocab_size // (vocab_chips or chips)
     if isinstance(layers, int):
         layers = range(layers)
@@ -1188,6 +1226,73 @@ def tiny_falcon_h1_expander() -> ModelFamily:
     """Factory form of :data:`TINY_FALCON_H1_EXPAND` (benchmark
     rehearsals)."""
     return TINY_FALCON_H1_EXPAND
+
+
+# LongCat-Flash-Chat (huggingface.co/meituan-longcat/LongCat-Flash-Chat
+# config.json) at its published widths: 28 shortcut-connected double layers
+# of hidden 6144, each TWO entries of these lists (``moe_shortcut``): an
+# expert layer whose shared expert is the first dense SwiGLU of 12288 (it
+# reads the router's input and is added at that residual) and a dense layer
+# of 12288, both latent attention of 64 heads through a 1536-wide query
+# latent over a cached 512-wide latent and one 64-wide rotated key (keys of
+# 128 + 64, values of 128 a head, plain RoPE theta 1e7), queries scaled by
+# (6144 / 1536)^0.5 and the normed latent by (6144 / 512)^0.5. ONE router a
+# double layer: a softmax over 768 outputs, 512 experts of width 2048 and
+# 256 zero-compute identity experts, 12 a token chosen under a selection
+# bias, weighed 6 p without it and not renormalised; its sum lands after
+# the second dense SwiGLU.
+LONGCAT_FLASH_CHAT = LMConfig(
+    vocab_size=131072, hidden_size=6144, layer_types=("latent",) * 56,
+    num_heads_per_layer=(64,) * 56, rope_full=RopeConfig(theta=1e7),
+    dense_layers=tuple(range(1, 56, 2)), intermediate_size=12288,
+    num_experts=768, zero_experts=256, num_experts_per_tok=12,
+    moe_intermediate_size=2048, shared_expert_intermediate_size=12288,
+    routed_scaling_factor=6.0, norm_topk_prob=False, rms_norm_eps=1e-5,
+    q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, router_scoring="softmax",
+    router_bias=True, attn_gate="none", moe_shortcut=True,
+    latent_q_scale=(6144 / 1536) ** 0.5, latent_kv_scale=(6144 / 512) ** 0.5)
+
+
+def sd15_longcat_flash_expander() -> ModelFamily:
+    """SD1.5 with LongCat-Flash-Chat as its resident prompt expander, cut
+    to one chip of 32 that share each layer: published double layers 0-3
+    (eight entries; one of seven pipeline stages of four), experts 0-15 of
+    the 512 that have kernels in each of the four routers (every identity
+    expert is held wherever the token lives), vocabulary ids 0-16383 (the
+    table and the head lie eight ways)."""
+    return dataclasses.replace(
+        SD15, name="sd15-longcat-flash-expand",
+        expander=lm_share(LONGCAT_FLASH_CHAT, layers=8, chips=32, rank=0,
+                          vocab_chips=8))
+
+
+# Tiny expander of that topology: two double layers (four entries) of 4
+# latent heads through a 24-wide query latent over a cached 16 + 8, both
+# latent scales off 1, a router over 16 experts and 8 identity experts, 4 a
+# token by biased softmax scores at scale 6 without renormalising, 4 of the
+# 16 held, a shared expert and a dense MLP of one width, a quarter of the
+# vocabulary.
+TINY_LONGCAT_FLASH_LM = LMConfig(
+    vocab_size=512, hidden_size=32, layer_types=("latent",) * 4,
+    num_heads_per_layer=(4,) * 4, rope_full=RopeConfig(theta=1e7),
+    dense_layers=(1, 3), intermediate_size=64, num_experts=24,
+    zero_experts=8, num_experts_per_tok=4, moe_intermediate_size=16,
+    shared_expert_intermediate_size=64, routed_scaling_factor=6.0,
+    norm_topk_prob=False, rms_norm_eps=1e-5, q_lora_rank=24,
+    kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+    router_scoring="softmax", router_bias=True, attn_gate="none",
+    moe_shortcut=True, latent_q_scale=(32 / 24) ** 0.5,
+    latent_kv_scale=(32 / 16) ** 0.5)
+TINY_LONGCAT_FLASH_EXPAND = dataclasses.replace(
+    TINY, name="tiny-longcat-flash-expand",
+    expander=lm_share(TINY_LONGCAT_FLASH_LM, 4, chips=4, rank=0))
+
+
+def tiny_longcat_flash_expander() -> ModelFamily:
+    """Factory form of :data:`TINY_LONGCAT_FLASH_EXPAND` (benchmark
+    rehearsals)."""
+    return TINY_LONGCAT_FLASH_EXPAND
 
 
 FAMILIES = {f.name: f for f in (SD15, SD21, SD21_BASE, SDXL_BASE,

@@ -39,7 +39,16 @@ Forward multipliers (``LMConfig.embedding_multiplier``,
 ``ssm_multipliers``, ``mlp_multipliers``) scale the table's output, the
 logits, the keys, each mixer's input and output, a state-space mixer's
 projected ranges and a dense SwiGLU's gate and output where the forward
-pass says; one that is 1.0 traces no op. With
+pass says; one that is 1.0 traces no op. A latent layer's queries and its
+normed latent may each carry a scalar (``latent_q_scale``,
+``latent_kv_scale``: the cache holds the latent scaled). A router's LAST
+ids may be zero-compute experts that return their input
+(``zero_experts``: no kernels, ``(the picks' weights on them) * n`` added
+where the token lives), and an expert layer's routed sum may be carried out
+of its layer and land after the NEXT layer's MLP sublayer
+(``moe_shortcut``: two entries of the lists then spell one
+shortcut-connected double layer, two token mixers, two dense MLPs and one
+router whose sum arrives a token mixer and an MLP late). With
 ``residual_streams`` over 1 a token is ``(streams, hidden)`` between
 sublayers and a :class:`StreamMixer` around each sublayer reads, writes
 and mixes the streams; with 1 a layer is ``x + F(norm(x))``, ``x +
@@ -212,11 +221,13 @@ def shares_a_step(cfg: LMConfig) -> bool:
 
 def site_attrs(cfg: LMConfig) -> dict:
     """Span attributes of a model whose layers depart from input norms,
-    rotated attention, a write strength under 1, one mixer a layer and no
-    forward multiplier, as one trace of its stack counts them
+    rotated attention, a write strength under 1, one mixer a layer, no
+    forward multiplier, routed sums added in place and experts that all
+    have kernels, as one trace of its stack counts them
     (``serving.expander`` ``sublayer_norms``, ``attention_unrotated``,
     ``write_strength_bound``, ``ssm_mixers``, ``joined_layers``,
-    ``multipliers_applied``); ``{}`` for one that departs in none."""
+    ``multipliers_applied``, ``moe_shortcuts``); ``{}`` for one that
+    departs in none."""
     attrs = {}
     if cfg.norm_placement:
         attrs["norms_pre"] = 2 * sum(p != POST for p in cfg.norm_placement)
@@ -232,6 +243,10 @@ def site_attrs(cfg: LMConfig) -> dict:
         attrs["joined_layers"] = joined
     if cfg.multipliers_applied:
         attrs["multipliers"] = cfg.multipliers_applied
+    if cfg.moe_shortcut:
+        attrs["moe_shortcuts"] = len(cfg.expert_layers)
+    if cfg.zero_experts:
+        attrs["zero_experts"] = cfg.zero_experts
     return attrs
 
 
@@ -405,10 +420,23 @@ class Experts(nn.Module):
 
 
 class MoE(nn.Module):
+    """``(out, beside)`` of an expert layer's MLP sublayer over the normed
+    rows ``n``: ``out`` is what the layer adds at its own residual, the
+    routed sum and the shared expert; under ``LMConfig.moe_shortcut``
+    ``(out, carried, beside)``: ``out`` is the shared expert alone and
+    ``carried`` the routed sum, which the NEXT layer adds after its MLP
+    sublayer. The routed sum is this chip's held experts' part
+    (ops/moe.py) and, with ``zero_experts``, the identity experts' ``(sum
+    of the picks' weights on them) * n``. ``beside`` is ``(experts chosen,
+    load, tokens with no held expert)`` and, with ``zero_experts``, the
+    picks that fell on identity experts. ``router_dtype`` under float32
+    makes the router's product in that dtype: a control."""
+
     config: LMConfig
     dtype: jnp.dtype = jnp.float32
     quant: bool = False
     meshed: bool = False
+    router_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, n: jax.Array, valid: jax.Array):
@@ -418,8 +446,13 @@ class MoE(nn.Module):
                             (n.shape[-1], cfg.num_experts))
         # the router sees the normed input in float32: a near-tie decided
         # by rounding the input would send a token to other experts
-        logits = jnp.dot(n, router.astype(jnp.float32),
-                         precision=jax.lax.Precision.HIGHEST)
+        if self.router_dtype == jnp.float32:
+            logits = jnp.dot(n, router.astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+        else:
+            logits = jnp.dot(n.astype(self.router_dtype),
+                             router.astype(self.router_dtype),
+                             preferred_element_type=jnp.float32)
         bias = None
         if cfg.router_bias:
             bias = self.param("e_score_correction_bias",
@@ -440,6 +473,9 @@ class MoE(nn.Module):
         EXPANDER.record_product(path)
         load, none_held = moe.load_counts(routing, first, held, valid)
         beside = (routing.experts, load, none_held)
+        if cfg.zero_experts:    # the identity experts: no kernel, no dispatch
+            routed = routed + moe.identity_part(n, routing, cfg.real_experts)
+            beside += (moe.identity_picks(routing, cfg.real_experts, valid),)
         if not cfg.shared_expert_intermediate_size:     # no shared expert
             return routed, beside
         shared = SwiGLU(cfg.shared_expert_intermediate_size, self.dtype,
@@ -448,6 +484,8 @@ class MoE(nn.Module):
         if cfg.shared_expert_gate:
             shared = shared * jax.nn.sigmoid(Linear(
                 1, self.dtype, self.quant, name="shared_expert_gate")(n))
+        if cfg.moe_shortcut:
+            return shared, routed, beside
         return routed + shared, beside
 
 
@@ -687,13 +725,21 @@ class LatentAttention(nn.Module):
         else:       # no query latent: no norm on the query path either
             q = lin(heads * (nope + rope), "q_proj")(n).reshape(
                 tokens, heads, nope + rope)
+        if cfg.latent_q_scale != 1.0:   # both parts, before the rotation
+            q = q * cfg.latent_q_scale
         q_nope, q_rope = q[..., :nope], turned(q[..., nope:])
         c, k_rope = jnp.split(lin(rank + rope, "kv_a_proj_with_mqa")(n),
                               [rank], axis=-1)
-        row = jnp.concatenate(
-            [norm("kv_a_norm")(c), turned(k_rope[:, None, :])[:, 0]],
-            axis=-1)
+        c = norm("kv_a_norm")(c)
+        if cfg.latent_kv_scale != 1.0:
+            # the cache holds the latent scaled: what ``kv_b_proj`` takes
+            # in the expanded form and both folds of the other two
+            c = c * cfg.latent_kv_scale
+        row = jnp.concatenate([c, turned(k_rope[:, None, :])[:, 0]],
+                              axis=-1)
         form = latent_form(tokens, sequences)
+        if cfg.latent_q_scale != 1.0 or cfg.latent_kv_scale != 1.0:
+            EXPANDER.record_latent_scaled(form)
         if sequences:
             # row b is sequence b's one token at ``start``: it goes to its
             # sequence's own rows, what the prefill left is only read, and
@@ -1155,19 +1201,26 @@ class DecoderLayer(nn.Module):
     sinkhorn_dtype: jnp.dtype = jnp.float32
     #: and of one with conv layers: their gates' and taps' products
     conv_dtype: jnp.dtype = jnp.float32
+    #: and of one with expert layers: the router's product
+    router_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x, q_pos, start, end, buffers,
                  sequences: bool = False, real=None, pass_index=None,
-                 forked_at=None):
-        """``buffers`` are the layer's own of the cache (:func:`buffers_of`
+                 forked_at=None, carried=None):
+        """``(x, buffers, routed)``. ``buffers`` are the layer's own of
+        the cache (:func:`buffers_of`
         its kind), returned as the chunk leaves them. ``x`` is ``(T,
         hidden)``, or ``(T, streams, hidden)`` with several streams.
         ``sequences``: the rows are one token each of as many sequences
         (:func:`shares_a_step`), the buffers a forked cache's, forked at
         position ``forked_at``, and ``real`` says which rows count.
         ``pass_index``: the pass of a looped stack, whose rows of the
-        buffers the layer then takes."""
+        buffers the layer then takes. ``carried``: the routed sum the
+        expert layer before this one left for it
+        (``LMConfig.moe_shortcut``), added to the residual after this
+        layer's MLP sublayer; under ``moe_shortcut`` what this layer
+        leaves for the next is returned last (None: nothing crosses)."""
         cfg = self.config
         kind = cfg.layer_types[self.layer]
 
@@ -1232,14 +1285,21 @@ class DecoderLayer(nn.Module):
                 after, at = after + kept, at + own
             return mixed, after
 
+        leaves = []     # the routed sum a shortcut carries out of the layer
+
         def mlp(n):
             """(out, what an expert layer routed; None for a dense one)."""
             if self.layer in cfg.dense_layers:
                 return SwiGLU(cfg.intermediate_size, self.dtype, self.quant,
                               cfg.swiglu_limit, cfg.mlp_multipliers,
                               name="mlp")(n), None
-            return MoE(cfg, self.dtype, self.quant, self.meshed,
-                       name="mlp")(n, counted())
+            out, *crosses, routed = MoE(
+                cfg, self.dtype, self.quant, self.meshed, self.router_dtype,
+                name="mlp")(n, counted())
+            if crosses:
+                EXPANDER.record_shortcut(form)
+                leaves.extend(crosses)
+            return out, routed
 
         streams = cfg.residual_streams
         placement = cfg.sublayer_norms[self.layer]
@@ -1266,6 +1326,10 @@ class DecoderLayer(nn.Module):
             if streams == 1:
                 out, more = sublayer(norm(x))
                 x = x + normed_after(out)
+                if carried is not None and sublayer is mlp:
+                    # the sum the layer before routed lands here, one
+                    # token mixer and one MLP after its router
+                    x = x + carried
                 beside.append(more)
                 continue
             mixed = StreamMixer(
@@ -1276,7 +1340,9 @@ class DecoderLayer(nn.Module):
             with jax.named_scope(hc):
                 x = written(x, mixed, out)
         buffers, routed = beside
-        return x, buffers, routed
+        if not cfg.moe_shortcut:
+            return x, buffers, routed
+        return x, buffers, routed, (leaves[0] if leaves else None)
 
 
 class DecoderLM(nn.Module):
@@ -1290,7 +1356,9 @@ class DecoderLM(nn.Module):
     one (cache/kv.py:fork) and the logits are every sequence's. ``routed``
     has, stacked over the expert layers, the experts every token chose
     ``(layers, T, k)``, the tokens sent to each held expert ``(layers,
-    held)`` and the tokens none of whose experts is held ``(layers,)``."""
+    held)``, the tokens none of whose experts is held ``(layers,)`` and,
+    where the router has zero-compute experts, the picks of rows that
+    count that fell on them ``(layers,)``."""
 
     config: LMConfig
     dtype: jnp.dtype = jnp.float32
@@ -1305,6 +1373,9 @@ class DecoderLM(nn.Module):
     #: what a conv layer's gates and taps multiply in: float32; lower is a
     #: control
     conv_dtype: jnp.dtype = jnp.float32
+    #: what an expert layer's router multiplies in: float32 at the highest
+    #: precision; lower is a control
+    router_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, tokens, start, length, cache: Dict[str, jax.Array],
@@ -1347,26 +1418,31 @@ class DecoderLM(nn.Module):
             return DecoderLayer(
                 cfg, layer, self.dtype, self.quant_linears, self.meshed,
                 self.stream_dtype, self.sinkhorn_dtype, self.conv_dtype,
-                **how)
+                self.router_dtype, **how)
 
-        def named_layer(layer, x, buffers, pass_index):
+        def named_layer(layer, x, buffers, pass_index, carried=None):
             """Layer ``layer`` as this module's own submodule."""
             return layer_module(layer, name=f"layers_{layer}")(
                 x, q_pos, start, end, buffers, sequences=sequences,
-                real=real, pass_index=pass_index, forked_at=forked_at)
+                real=real, pass_index=pass_index, forked_at=forked_at,
+                carried=carried)
 
         def stack(apply_layer, x, cache, pass_index=None):
             """(x, the cache, what the expert layers routed) after every
             layer once. A buffer list has one entry for each layer that has
-            the buffer, in layer order."""
+            the buffer, in layer order. An expert layer's routed sum that
+            a shortcut carries (``LMConfig.moe_shortcut``) goes from its
+            layer to the next: the sum is a row's, so under ``sequences``
+            it forks with the rows."""
             written = {name: [] for name in cache}
             routed = []
+            carried = ()    # under a shortcut: what the layer before left
             for layer, kind in enumerate(cfg.layer_types):
                 names = buffers_of(kind, sequences)
-                x, buffers, r = apply_layer(
+                x, buffers, r, *carried = apply_layer(
                     layer, x,
                     tuple(cache[name][len(written[name])] for name in names),
-                    pass_index)
+                    pass_index, *carried)
                 for name, buffer in zip(names, buffers):
                     written[name].append(buffer)
                 if r is not None:
@@ -1580,6 +1656,15 @@ def add_exits(so_far, exits):
                  for (counts, most), (more, gate) in zip(so_far, exits))
 
 
+def no_zero_picks(cfg: LMConfig):
+    """What a decode executable's count of picks on zero-compute experts
+    adds up from, by expert layer: ``()`` for a router that has none
+    (whose executables return what they always did)."""
+    if not cfg.zero_experts:
+        return ()
+    return (jnp.zeros((len(cfg.expert_layers),), jnp.int32),)
+
+
 def prefill_fn(module: DecoderLM, sequences: bool = False):
     """``expand_prefill(params, cache, tokens, start, length, key,
     temperature) -> (cache, next token, routed load, none held)``: one
@@ -1607,30 +1692,36 @@ def decode_chunk_fn(module: DecoderLM, steps: int):
     temperature) -> (cache, token, position, the steps' tokens, routed
     load, none held)``: ``steps`` tokens, each fed back as the next input;
     ``token`` sits at ``position`` and is not yet in the cache. A looped
-    model's returns the steps' ``exits`` after these."""
+    model's returns the steps' ``exits`` after these, and one whose router
+    has zero-compute experts the steps' picks on them ``(expert layers,)``
+    last."""
 
     def expand_decode_chunk(params, cache, token, position, key,
                             temperature):
         variables = {"params": params, "mixers": mixer_operands(params)}
+        cfg = module.config
+        looped = len(no_exits(cfg))
 
         def step(carry, _):
-            cache, token, position, load, none_held, *exits = carry
+            cache, token, position, load, none_held, *rest = carry
+            exits, zero = rest[:looped], rest[looped:]
             logits, cache, routed, more = apply_counting(
                 module, variables, token[None], position, 1, cache,
                 all_logits=False)
             token = sample(logits[0], key, position + 1, temperature,
                            module.config.vocab[0])
             return (cache, token, position + 1, load + routed[1],
-                    none_held + routed[2]) + add_exits(exits, more), token
+                    none_held + routed[2]) + add_exits(exits, more) \
+                + tuple(z + r for z, r in zip(zero, routed[3:])), token
 
-        cfg = module.config
         layers = len(cfg.expert_layers)
         zero = (jnp.zeros((layers, cfg.experts[1]), jnp.int32),
-                jnp.zeros((layers,), jnp.int32)) + no_exits(cfg)
-        (cache, token, position, load, none_held, *exits), made = \
+                jnp.zeros((layers,), jnp.int32)) + no_exits(cfg) \
+            + no_zero_picks(cfg)
+        (cache, token, position, load, none_held, *rest), made = \
             jax.lax.scan(step, (cache, token, position) + zero, None,
                          length=steps)
-        return (cache, token, position, made, load, none_held) + tuple(exits)
+        return (cache, token, position, made, load, none_held) + tuple(rest)
 
     return expand_decode_chunk
 
@@ -1648,15 +1739,18 @@ def decode_sequences_fn(module: DecoderLM, steps: int):
     layers,)`` sums over the steps the DISTINCT held experts a step's rows
     chose (ops/moe.py:experts_read): what a step streams, however many of
     its rows chose one. A looped model's returns the live rows' ``exits``
-    after these."""
+    after these, and one whose router has zero-compute experts the live
+    rows' picks on them ``(expert layers,)`` last."""
 
     def expand_decode_chunk(params, cache, tokens, position, keys,
                             temperature, live):
         variables = {"params": params}
         cfg = module.config
+        looped = len(no_exits(cfg))
 
         def step(carry, _):
-            cache, tokens, position, load, none_held, read, *exits = carry
+            cache, tokens, position, load, none_held, read, *rest = carry
+            exits, zero = rest[:looped], rest[looped:]
             logits, cache, routed, more = apply_counting(
                 module, variables, tokens, position, live, cache,
                 sequences=True, live=live)
@@ -1665,16 +1759,18 @@ def decode_sequences_fn(module: DecoderLM, steps: int):
             return (cache, tokens, position + 1, load + routed[1],
                     none_held + routed[2],
                     read + moe.experts_read(routed[1])) \
-                + add_exits(exits, more), tokens
+                + add_exits(exits, more) \
+                + tuple(z + r for z, r in zip(zero, routed[3:])), tokens
 
         layers = len(cfg.expert_layers)
         zero = (jnp.zeros((layers, cfg.experts[1]), jnp.int32),
                 jnp.zeros((layers,), jnp.int32),
-                jnp.zeros((layers,), jnp.int32)) + no_exits(cfg)
-        (cache, tokens, position, load, none_held, read, *exits), made = \
+                jnp.zeros((layers,), jnp.int32)) + no_exits(cfg) \
+            + no_zero_picks(cfg)
+        (cache, tokens, position, load, none_held, read, *rest), made = \
             jax.lax.scan(step, (cache, tokens, position) + zero, None,
                          length=steps)
         return (cache, tokens, position, made, load, none_held,
-                read) + tuple(exits)
+                read) + tuple(rest)
 
     return expand_decode_chunk

@@ -891,6 +891,22 @@ def render() -> str:
             "Forward multipliers off 1 in the last language model traced "
             "(0: the forward pass scales nothing).",
             expander["multipliers_applied"])
+    _labeled_family(
+        lines, "sdtpu_expander_moe_shortcuts_total", "counter",
+        "Expert layers traced whose routed sum crosses into the next "
+        "layer, by the form of the executable.",
+        [(f'form="{_label(form)}"', n)
+         for form, n in sorted(expander["moe_shortcuts"].items())])
+    _labeled_family(
+        lines, "sdtpu_expander_latent_scaled_total", "counter",
+        "Latent-attention sites traced with a query or a latent scale off "
+        "1, by the form of the site.",
+        [(f'form="{_label(form)}"', n)
+         for form, n in sorted(expander["latent_scaled"].items())])
+    _scalar(lines, "sdtpu_expander_zero_expert_picks_total", "counter",
+            "Picks of the prompt expander's decode steps that fell on "
+            "zero-compute (identity) experts.",
+            expander["zero_expert_picks"])
     _scalar(lines, "sdtpu_expander_state_bytes_stepped_total", "counter",
             "Bytes of recurrent states and kept inputs (linear layers', "
             "state-space mixers') the "

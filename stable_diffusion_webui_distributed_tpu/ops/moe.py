@@ -9,6 +9,14 @@ part: it is told which contiguous range of experts its stacked kernels are
 those, and adds nothing for the absent ones. No code stands in for the
 other chips.
 
+A router's LAST ids may be zero-compute experts (``LMConfig.zero_experts``,
+``E_e(n) = n``): they have no kernels, so every product below passes them
+by as it passes an absent expert, and what they add is ``(the sum of a
+token's weights on them) * n`` (:func:`identity_part`), computed where the
+token lives. A pick on one is neither held nor absent: the load, the
+experts read and the tokens with no held expert stay counts of the experts
+that have kernels, and :func:`identity_picks` counts these beside them.
+
 Three products, chosen by what the call shows (:func:`choose`: the number
 of rows, the platform, the operands' dtype and the kernels' shapes, all
 static, and whether a mesh will partition the program). By row count:
@@ -230,6 +238,24 @@ def routed_experts(x: jax.Array, routing: Routing, w_gate: jax.Array,
         return _block(x, routing, w_gate, w_up, w_down, first, limit), path
     return _chosen(x, routing, w_gate, w_up, w_down, first,
                    kernel=path == KERNEL, limit=limit), path
+
+
+def identity_part(x: jax.Array, routing: Routing, first_zero: int):
+    """``sum over a token's picks e >= first_zero of w_e E_e(x)`` with
+    ``E_e(x) = x``: the zero-compute experts' part of the routed sum, ``(T,
+    d)`` float32 from ``x`` in float32."""
+    weight = jnp.sum(jnp.where(routing.experts >= first_zero,
+                               routing.weights, 0.0), axis=-1)
+    return weight[:, None] * x.astype(jnp.float32)
+
+
+def identity_picks(routing: Routing, first_zero: int, valid=None):
+    """Picks that fell on a zero-compute expert, over the rows that count
+    (``valid`` masks padded rows); int32."""
+    picks = routing.experts >= first_zero
+    if valid is not None:
+        picks = picks & valid[:, None]
+    return jnp.sum(picks).astype(jnp.int32)
 
 
 def load_counts(routing: Routing, first: int, count: int, valid=None):

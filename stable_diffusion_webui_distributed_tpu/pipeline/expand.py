@@ -348,6 +348,7 @@ class PromptExpander:
         steps = 0
         decoded_from = len(routed)    # the executable calls that decode
         reads = []        # per decode call of several sequences
+        zeros = []        # per decode call: picks on zero-compute experts
 
         def fetch(out) -> None:
             with obs_spans.span("expand.fence_wait"):
@@ -369,6 +370,8 @@ class PromptExpander:
                            temperature, *more)
             steps += DECODE_STEPS
             routed.append((step_load, step_none))
+            if self.config.zero_experts:    # the last of what it returns
+                zeros.append(read.pop())
             if looped:      # the last of what a looped model returns
                 exits.append(read.pop())
             reads += read
@@ -393,7 +396,7 @@ class PromptExpander:
         def account() -> None:
             with obs_spans.span("expand.account",
                                 fetched=2 * len(routed) + len(reads)
-                                + 2 * len(exits)):
+                                + 2 * len(exits) + len(zeros)):
                 loads, none_held = zip(*jax.device_get(routed))
                 # a step of one token reads as many experts as it has picks
                 # held; a step of several the distinct ones, counted beside
@@ -419,6 +422,8 @@ class PromptExpander:
                                     else 0),
                     state_bytes_stepped=steps * stepped,
                     fork_bytes_copied=fork_copied,
+                    zero_expert_picks=int(np.sum(jax.device_get(zeros)))
+                    if zeros else 0,
                     **self._passes_run(exits, steps))
 
         if later is None:

@@ -452,7 +452,9 @@ def replay_sites(rows) -> None:
                 "unrotated": EXPANDER.record_unrotated,
                 "ssm": EXPANDER.record_ssm,
                 "joined": EXPANDER.record_joined,
-                "multipliers": EXPANDER.record_multipliers}
+                "multipliers": EXPANDER.record_multipliers,
+                "shortcut": EXPANDER.record_shortcut,
+                "latent_scaled": EXPANDER.record_latent_scaled}
     for counter, *args in rows:
         counters[counter](*args)
 
@@ -640,6 +642,15 @@ class ExpanderStats:
     forward pass scales nothing). ``state_bytes_stepped`` and
     ``fork_bytes_copied`` count a state-space part's state and kept inputs
     as they count a linear layer's.
+    ``moe_shortcuts`` counts, by the same three forms of the executable
+    traced, the expert layers whose routed sum crossed into the next layer
+    (``LMConfig.moe_shortcut``: 0 where every sum is added in place),
+    ``latent_scaled`` the latent-attention sites traced with a query or a
+    latent scale off 1, by the form of the site (models/lm.py:latent_form),
+    and ``zero_expert_picks`` the picks of the decode steps' rows that
+    count which fell on zero-compute experts (``LMConfig.zero_experts``;
+    counted on the device beside the load; over ``tokens_decoded`` and the
+    expert layers: identity picks a token a router).
     Of a looped model (``LMConfig.total_ut_steps`` over 1): ``layer_passes``,
     the passes of the whole stack its decode steps ran (every pass of every
     step, whichever the head read; over ``decode_steps``: passes a token); ``exit_pass``, the tokens made by the
@@ -692,6 +703,10 @@ class ExpanderStats:
             self.ssms = dict.fromkeys(self.deltas, 0)  # guarded-by: _lock
             self.joined = dict.fromkeys(self.deltas, 0)  # guarded-by: _lock
             self.multipliers = 0       # guarded-by: _lock
+            self.shortcuts = dict.fromkeys(self.deltas, 0)  # guarded-by: _lock
+            self.latent_scaled = {"latent_absorbed": 0, "latent_expanded": 0,
+                                  "latent_forked": 0}  # guarded-by: _lock
+            self.zero_picks = 0        # guarded-by: _lock
 
     def record_product(self, path: str) -> None:
         """One expert layer in one trace took product ``path``."""
@@ -758,6 +773,20 @@ class ExpanderStats:
         with self._lock:
             self.multipliers = int(applied)
 
+    def record_shortcut(self, form: str) -> None:
+        """One expert layer in one trace of an executable of ``form``
+        whose routed sum crosses into the next layer."""
+        _note_site("shortcut", str(form))
+        with self._lock:
+            self.shortcuts[form] += 1
+
+    def record_latent_scaled(self, form: str) -> None:
+        """One latent-attention site of ``form`` in one trace whose
+        queries or latent are scaled."""
+        _note_site("latent_scaled", str(form))
+        with self._lock:
+            self.latent_scaled[form] += 1
+
     def record(self, *, prefilled: int, from_prefix: int, sequences: int,
                decoded: int, decode_steps: int, experts_read: int, load,
                none_held: int,
@@ -768,7 +797,8 @@ class ExpanderStats:
                rows_read_shared: int = 0,
                layer_passes: int = 0, exit_pass=(),
                exit_lambda_max: float = 0.0, state_bytes_stepped: int = 0,
-               fork_bytes_copied: int = 0) -> None:
+               fork_bytes_copied: int = 0, zero_expert_picks: int = 0
+               ) -> None:
         """``load`` is (expert layers, held experts) counts of one
         request; ``decode_steps`` the steps its decode executables ran
         (whole chunks, so at least ``decoded / sequences - 1``), each a
@@ -800,6 +830,7 @@ class ExpanderStats:
             self.layer_passes += int(layer_passes)
             self.state_bytes_stepped += int(state_bytes_stepped)
             self.fork_bytes_copied += int(fork_bytes_copied)
+            self.zero_picks += int(zero_expert_picks)
             if len(exit_pass):
                 old = self.exit_pass or [0] * len(exit_pass)
                 self.exit_pass = [a + int(b)
@@ -846,6 +877,9 @@ class ExpanderStats:
                 "ssm_mixers": dict(self.ssms),
                 "joined_layers": dict(self.joined),
                 "multipliers_applied": self.multipliers,
+                "moe_shortcuts": dict(self.shortcuts),
+                "latent_scaled": dict(self.latent_scaled),
+                "zero_expert_picks": self.zero_picks,
                 "layer_passes": self.layer_passes,
                 "exit_pass": list(self.exit_pass),
                 "exit_lambda_max": self.exit_lambda_max,

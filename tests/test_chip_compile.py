@@ -603,6 +603,23 @@ EXPANDER_EXECUTABLES = [
     ("decode4", "sd15_falcon_h1_expander", 2560, 9.25, 0, 128, 215),
     ("prefill", "sd15_falcon_h1_expander", 2560, 9.10, 0, 64, 84),
     ("prefill2048", "sd15_falcon_h1_expander", 2560, 9.10, 0, 1000, 84),
+    # four expert kernels (the eighth published shape, 6144 x 2048, tile
+    # 256 by its own rule; a call of four rows of twelve picks walks at
+    # most the 16 held experts) behind eight forked latent attentions of 64
+    # heads, each router's sum carried past one attention and one dense MLP
+    # of 12 288 before it lands: four sequences donate the one sequence's
+    # 23.6 MB of latents (shared, handed through) and 256 own slots a
+    # sublayer each, 33 MB; 0.2 GB of temporaries (the routers in float32
+    # hoisted out of the scan, the 64 heads' folded queries). The prompt's
+    # 64-token chunk in the expanded form over 2 560 latents; the
+    # instruction's one chunk of 2 048: 1.63 GB of temporaries, 64 heads'
+    # float32 scores over 2 560 latents (1.34 GB) beside the grouped
+    # product's 2 048 x 12 picks sorted into tiles of 32 rows. This is
+    # where the share's fit beside SD1.5's 2.13 GB shows first: 10.37 GB of
+    # arguments + 1.63 is 12.0 of the chip's 16.9
+    ("decode4", "sd15_longcat_flash_expander", 2560, 10.35, 4, 260, 32),
+    ("prefill", "sd15_longcat_flash_expander", 2560, 10.35, 0, 400, 23),
+    ("prefill2048", "sd15_longcat_flash_expander", 2560, 10.35, 0, 2000, 23),
 ]
 
 

@@ -948,7 +948,8 @@ class TestEnginePath:
             "exit_lambda_max", "delta_mixers", "delta_steps",
             "state_bytes_stepped", "fork_bytes_copied", "sublayer_norms",
             "attention_unrotated", "write_strength_bound", "ssm_mixers",
-            "joined_layers", "multipliers_applied"}
+            "joined_layers", "multipliers_applied", "moe_shortcuts",
+            "latent_scaled", "zero_expert_picks"}
         # a model of one pass leaves the looped model's counters alone
         assert (block["layer_passes"], block["exit_pass"],
                 block["exit_lambda_max"]) == (0, [], 0.0)
@@ -972,6 +973,12 @@ class TestEnginePath:
         assert not any(block["joined_layers"].values())
         assert set(block["ssm_mixers"]) == set(block["delta_mixers"])
         assert block["multipliers_applied"] == 0
+        # nor a routed sum that crosses a layer, a scaled latent or an
+        # identity expert
+        assert not any(block["moe_shortcuts"].values())
+        assert set(block["moe_shortcuts"]) == set(block["delta_mixers"])
+        assert not any(block["latent_scaled"].values())
+        assert block["zero_expert_picks"] == 0
         json.dumps(block)
 
     @pytest.mark.parametrize("sequences,forked_at,steps", [
