@@ -456,26 +456,48 @@ class MoE(nn.Module):
         bias = None
         if cfg.router_bias:
             bias = self.param("e_score_correction_bias",
-                              nn.initializers.zeros,
-                              (cfg.num_experts,)).astype(jnp.float32)
-        routing = moe.route(logits, cfg.num_experts_per_tok,
-                            renormalise=cfg.norm_topk_prob,
-                            scale=cfg.routed_scaling_factor,
-                            scoring=cfg.router_scoring, bias=bias,
-                            eps=cfg.norm_topk_eps)
+                              nn.initializers.zeros, (cfg.num_experts,))
+        router = dict(renormalise=cfg.norm_topk_prob,
+                      scale=cfg.routed_scaling_factor,
+                      scoring=cfg.router_scoring, eps=cfg.norm_topk_eps)
+        # one predicate picks both kernels: a decode step that takes the
+        # pipelined expert kernel takes its routing as one launch too
+        path = moe.choose(jax.default_backend(), n.shape[0], self.dtype,
+                          n.shape[-1], cfg.moe_intermediate_size,
+                          meshed=self.meshed)
+        if path != moe.KERNEL:  # XLA's chain, its ops in the order they had
+            routing = moe.route(
+                logits, cfg.num_experts_per_tok,
+                bias=None if bias is None else bias.astype(jnp.float32),
+                **router)
         kernels = Experts(held, cfg.moe_intermediate_size,
                           name="experts")(n.shape[-1])
         compute = [w.astype(self.dtype) for w in kernels]
-        routed, path = moe.routed_experts(
-            n.astype(self.dtype), routing, *compute, first=first,
-            num_experts=cfg.num_experts, meshed=self.meshed,
-            limit=cfg.swiglu_limit)
+        if path == moe.KERNEL:
+            routed, step = moe.kernel_step(
+                n.astype(self.dtype), logits, bias, valid, *compute,
+                k=cfg.num_experts_per_tok, first=first,
+                zero_experts=cfg.zero_experts, limit=cfg.swiglu_limit,
+                **router)
+            beside = (step.picks, step.load, step.none_held)
+            if cfg.zero_experts:    # the identity experts: no kernel
+                routed = routed + step.identity_weight * n.astype(
+                    jnp.float32)
+                beside += (step.identity_picks,)
+        else:
+            routed, path = moe.routed_experts(
+                n.astype(self.dtype), routing, *compute, first=first,
+                num_experts=cfg.num_experts, meshed=self.meshed,
+                limit=cfg.swiglu_limit)
+            load, none_held = moe.load_counts(routing, first, held, valid)
+            beside = (routing.experts, load, none_held)
+            if cfg.zero_experts:
+                routed = routed + moe.identity_part(n, routing,
+                                                    cfg.real_experts)
+                beside += (moe.identity_picks(routing, cfg.real_experts,
+                                              valid),)
         EXPANDER.record_product(path)
-        load, none_held = moe.load_counts(routing, first, held, valid)
-        beside = (routing.experts, load, none_held)
-        if cfg.zero_experts:    # the identity experts: no kernel, no dispatch
-            routed = routed + moe.identity_part(n, routing, cfg.real_experts)
-            beside += (moe.identity_picks(routing, cfg.real_experts, valid),)
+        EXPANDER.record_route("kernel" if path == moe.KERNEL else "xla")
         if not cfg.shared_expert_intermediate_size:     # no shared expert
             return routed, beside
         shared = SwiGLU(cfg.shared_expert_intermediate_size, self.dtype,

@@ -898,6 +898,13 @@ def render() -> str:
         [(f'form="{_label(form)}"', n)
          for form, n in sorted(expander["moe_shortcuts"].items())])
     _labeled_family(
+        lines, "sdtpu_expander_route_products_total", "counter",
+        "Expert layers traced, by what stood between the router's logits "
+        "and the experts' product (kernel: one Pallas launch; xla: the "
+        "chain of top_k, sorts and gathers).",
+        [(f'form="{_label(form)}"', n)
+         for form, n in sorted(expander["route_products"].items())])
+    _labeled_family(
         lines, "sdtpu_expander_latent_scaled_total", "counter",
         "Latent-attention sites traced with a query or a latent scale off "
         "1, by the form of the site.",

@@ -35,7 +35,11 @@ static, and whether a mesh will partition the program). By row count:
   several rows' are made so and every expert takes the whole block of
   rows, a per-row weight deciding what it adds (:func:`_block`): under one
   row tile the grouped product would pad every expert's rows to 8 anyway,
-  one unpipelined trip an expert. The same experts are read.
+  one unpipelined trip an expert. The same experts are read. The same
+  answer takes the ROUTING as one kernel too (:func:`kernel_step`,
+  ops/route_kernel.py): scores, top-k, the held-first order and the counts
+  in one launch in front of the expert kernel, where :func:`route` and the
+  chain behind it are about twenty.
 - where the kernel is not taken (a CPU, float32 operands, widths off the
   lane tiling as the tests' tiny presets have, a mesh: ``pjit`` does not
   partition a ``pallas_call``): one row, ``"loop"``: a loop over the chosen
@@ -52,7 +56,9 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from stable_diffusion_webui_distributed_tpu.ops import moe_kernel
+from stable_diffusion_webui_distributed_tpu.ops import (
+    moe_kernel, route_kernel,
+)
 
 GROUPED = "grouped"
 LOOP = "loop"
@@ -238,6 +244,29 @@ def routed_experts(x: jax.Array, routing: Routing, w_gate: jax.Array,
         return _block(x, routing, w_gate, w_up, w_down, first, limit), path
     return _chosen(x, routing, w_gate, w_up, w_down, first,
                    kernel=path == KERNEL, limit=limit), path
+
+
+def kernel_step(x: jax.Array, logits: jax.Array, bias: jax.Array | None,
+                valid: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+                w_down: jax.Array, *, k: int, renormalise: bool,
+                scale: float, scoring: str, eps: float, first: int,
+                zero_experts: int = 0, limit: float = 0.0):
+    """A decode step :func:`choose` gave ``"kernel"``, in two launches
+    behind the router's product: ``(this chip's part of sum_e w_e E_e(x),
+    the ops/route_kernel.py:Step it was made from)``. The routing kernel
+    turns the router's float32 ``logits`` ``(rows, experts)`` into the
+    expert kernel's operands, as :func:`route` with :func:`_chosen` (one
+    row) or :func:`_block` (2-8 rows) would, and the counts of
+    :func:`load_counts`, :func:`identity_part` and :func:`identity_picks`
+    beside them; the expert kernel takes those as they come. ``bias`` in
+    the dtype it is stored in."""
+    step = route_kernel.routing(
+        logits, bias, valid, k=k, renormalise=renormalise, scale=scale,
+        scoring=scoring, eps=eps, first=first, count=w_gate.shape[0],
+        zero_experts=zero_experts)
+    return moe_kernel.chosen_experts(x, step.experts, step.weights,
+                                     step.held, w_gate, w_up, w_down,
+                                     limit=limit), step
 
 
 def identity_part(x: jax.Array, routing: Routing, first_zero: int):

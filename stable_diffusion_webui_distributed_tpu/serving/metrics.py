@@ -444,6 +444,7 @@ def replay_sites(rows) -> None:
                 "upsample": UPSAMPLE.record,
                 "norm_site": NORM.record,
                 "product": EXPANDER.record_product,
+                "route": EXPANDER.record_route,
                 "mixer": EXPANDER.record_mixer,
                 "conv": EXPANDER.record_conv,
                 "delta": EXPANDER.record_delta,
@@ -608,6 +609,10 @@ class ExpanderStats:
     ``expert_products`` counts expert layers by the product they took
     (ops/moe.py:choose) when the model was TRACED, as :class:`AttentionSites`
     counts its sites: nothing is counted when an executable runs.
+    ``route_products`` counts them again by what stood between the router's
+    logits and the product: ``kernel`` (ops/route_kernel.py, one launch in
+    front of the expert kernel) or ``xla`` (ops/moe.py:route and the chain
+    behind it).
     ``mixer_products`` counts the residual streams' mixers the same way, by
     the form ops/stream_mixer.py:choose gave them, and ``conv_mixers`` the
     short-convolution mixers (models/lm.py:ShortConv), by whether the
@@ -688,6 +693,7 @@ class ExpanderStats:
             self.sinkhorn_iters = 0    # guarded-by: _lock
             self.products = {"kernel": 0, "loop": 0,
                              "grouped": 0}  # guarded-by: _lock
+            self.routes = {"kernel": 0, "xla": 0}  # guarded-by: _lock
             self.mixers = {"kernel": 0, "loop": 0}  # guarded-by: _lock
             self.convs = {"step": 0, "chunk": 0}  # guarded-by: _lock
             self.deltas = {"recurrent": 0, "chunked": 0,
@@ -713,6 +719,12 @@ class ExpanderStats:
         _note_site("product", str(path))
         with self._lock:
             self.products[path] += 1
+
+    def record_route(self, path: str) -> None:
+        """One expert layer's routing in one trace took form ``path``."""
+        _note_site("route", str(path))
+        with self._lock:
+            self.routes[path] += 1
 
     def record_mixer(self, path: str) -> None:
         """One stream mixer in one trace took form ``path``."""
@@ -864,6 +876,7 @@ class ExpanderStats:
                 "residual_streams": self.residual_streams,
                 "sinkhorn_iters": self.sinkhorn_iters,
                 "expert_products": dict(self.products),
+                "route_products": dict(self.routes),
                 "mixer_products": dict(self.mixers),
                 "conv_mixers": dict(self.convs),
                 "delta_mixers": dict(self.deltas),

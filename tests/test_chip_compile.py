@@ -623,6 +623,46 @@ EXPANDER_EXECUTABLES = [
 ]
 
 
+#: one launch of the routing kernel (the jitted function's own name)
+_ROUTE_CALL = re.compile(r"%_route_call[\w.]* = [^\n]*tpu_custom_call")
+#: a sort or a scatter (fused or not) traced under an expert layer's MLP
+_CHAIN_OP = re.compile(
+    r" (?:sort|scatter)\([^\n]*op_name=\"[^\"]*/layers_\d+/mlp/")
+
+
+@pytest.mark.parametrize("rows,factory", [
+    (1, "sd15_qwen3next_expander"), (4, "sd15_longcat_flash_expander"),
+    (8, "sd15_mellum2_expander"), (2, "sd15_gigachat35_expander")])
+def test_routing_kernel_compiles_for_v5e(one_chip, rows, factory):
+    """One row of Qwen3-Next's 512 / 10, four of LongCat's 768 / 12 with
+    its bias (stored bf16, widened in the kernel) and 256 identity
+    experts, eight of Mellum2's 64 / 8 (64 grid slots, under one
+    register's lanes) and two of GigaChat3.5's 256 of which 16 held."""
+    from stable_diffusion_webui_distributed_tpu.models import configs
+    from stable_diffusion_webui_distributed_tpu.ops import route_kernel
+
+    cfg = getattr(configs, factory)().expander
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(logits, valid, *bias):
+        return route_kernel.routing(
+            logits, bias[0] if bias else None, valid,
+            k=cfg.num_experts_per_tok, renormalise=cfg.norm_topk_prob,
+            scale=cfg.routed_scaling_factor, scoring=cfg.router_scoring,
+            eps=cfg.norm_topk_eps, first=cfg.experts[0],
+            count=cfg.experts[1], zero_experts=cfg.zero_experts,
+            interpret=False)
+
+    bias = [on_chip((cfg.num_experts,), jnp.bfloat16)] * cfg.router_bias
+    text = _compiled_text(step, on_chip((rows, cfg.num_experts),
+                                        jnp.float32),
+                          on_chip((rows,), jnp.bool_), *bias)
+    assert len(_ROUTE_CALL.findall(text)) == 1
+    assert not re.search(r" (?:sort|scatter|gather)\(", text)
+
+
 @pytest.mark.parametrize(
     "which,expander,capacity,argument_gb,kernels,temp_mb,alias_mb",
     EXPANDER_EXECUTABLES,
@@ -687,6 +727,14 @@ def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
     text = compiled.as_text()
     calls = text.count("tpu_custom_call")
     assert calls >= kernels and bool(calls) == bool(kernels)
+    # a decode step's routing is one launch an expert layer in front of
+    # the expert kernel (ops/route_kernel.py), and XLA's chain is gone:
+    # no sort (top_k, argsort) and no scatter under an expert layer
+    routed = which != "prefill" and which != "prefill2048" and len(
+        cfg.expert_layers)
+    assert len(_ROUTE_CALL.findall(text)) == routed
+    if routed:
+        assert not _CHAIN_OP.search(text)
     assert not _STATE_COPY.search(text)
     memory = compiled.memory_analysis()
     assert argument_gb * 1e9 < memory.argument_size_in_bytes \

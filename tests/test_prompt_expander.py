@@ -943,7 +943,8 @@ class TestEnginePath:
             "tokens_no_held_expert", "expert_tokens",
             "expert_load_max_over_mean", "cache_positions", "state_bytes",
             "prefix_snapshots", "padded_rows_masked", "expert_products",
-            "mixer_products", "conv_mixers", "residual_streams",
+            "route_products", "mixer_products", "conv_mixers",
+            "residual_streams",
             "sinkhorn_iters", "layer_passes", "exit_pass",
             "exit_lambda_max", "delta_mixers", "delta_steps",
             "state_bytes_stepped", "fork_bytes_copied", "sublayer_norms",
@@ -954,6 +955,9 @@ class TestEnginePath:
         assert (block["layer_passes"], block["exit_pass"],
                 block["exit_lambda_max"]) == (0, [], 0.0)
         assert set(block["expert_products"]) == {"kernel", "loop", "grouped"}
+        # every expert layer traced on this CPU kept XLA's routing chain
+        assert block["route_products"] == {
+            "kernel": 0, "xla": sum(block["expert_products"].values())}
         assert set(block["mixer_products"]) == {"kernel", "loop"}
         assert block["conv_mixers"] == {"step": 0, "chunk": 0}
         # nor has it a recurrent state to step or to copy at a fork

@@ -908,3 +908,43 @@ ENTRY %main (a: f32[2]) -> f32[2] {
         assert base["hbm_layout_mb"] == round(2 * each + pad, 1)
         assert out["hbm_mb"] == round(2 * each + conv + pad + hbm * one, 1)
         assert out["hbm_layout_mb"] == round(2 * each + pad + layout * one, 1)
+
+
+class TestDecodeHlo:
+    """tools/decode_hlo.py's count of a decode step's launches (the compile
+    for a described chip is tests/test_chip_compile.py's)."""
+
+    RULES = [{"class": "linear", "scope": "/(gate_proj|lm_head)/[^/]*$"},
+             {"class": "expert",
+              "scope": "/layers_[0-9]+/mlp/(?!shared_expert)"},
+             {"class": "other"}]
+
+    @staticmethod
+    def line(name, op, scope):
+        return (f'  %{name} = f32[1,8]{{1,0}} {op}(%x), metadata='
+                f'{{op_name="jit(expand_decode_chunk)/{scope}"}}')
+
+    def test_counts_the_expert_class_a_layer(self):
+        import decode_hlo
+        from benchmarks.readers.op_class_ms import classify
+
+        body = "while/body/closed_call/DecoderLM/"
+        text = "\n".join([
+            self.line("fusion.1", "fusion", body + "layers_2/mlp/dot_general"),
+            self.line("_route_call.3", "custom-call",
+                      body + "layers_2/mlp/jit(_route_call)/pallas_call"),
+            self.line("_call.4", "custom-call",
+                      body + "layers_2/mlp/jit(_call)/pallas_call"),
+            self.line("fusion.5", "fusion", body + "layers_10/mlp/dot_general"),
+            self.line("fusion.6", "fusion",
+                      body + "layers_10/mlp/shared_expert/gate_proj/dot"),
+            self.line("fusion.7", "fusion", body + "layers_10/mlp/mul"),
+            self.line("fusion.8", "fusion", body + "norm/mul"),
+            # not a launch of the step: outside the body, or no launch
+            self.line("fusion.9", "fusion", "DecoderLM/layers_2/mlp/mul"),
+            self.line("bitcast.10", "bitcast", body + "layers_2/mlp/reshape"),
+        ])
+        launches, by_layer = decode_hlo.count_launches(
+            text, self.RULES, classify)
+        assert launches == {"expert": 5, "linear": 1, "other": 1}
+        assert by_layer == [3, 2]       # layer 2, then layer 10

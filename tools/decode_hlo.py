@@ -14,7 +14,13 @@ section 6, PR 35) the count moves before any chip time is spent. A loop
 nested in the step is counted once whatever its trips. Since PR 36 a mixer
 of a decode step is 2 launches, its two kernels (``hc`` 80 of a step's 927
 at 20 layers); in XLA's form it was 22 outside Sinkhorn's loop and 4 an
-iteration inside, about a hundred. The scope of an op the compiler hoisted
+iteration inside, about a hundred. ``expert_a_layer`` spreads the
+``expert`` class over the layers its scopes name (``/layers_N/``), in layer
+order: since PR 68 an expert layer of a decode step is 3 to 5 (the router's
+product, the routing kernel, the expert kernel, and what else rides in the
+class: a shared expert's gate's multiply, the identity experts' scaling),
+where XLA's chain of ``top_k``, sorts and gathers made it 19 or 20. The
+scope of an op the compiler hoisted
 out of the loop still says ``while/body``: where that matters read the
 ``--keep`` text by computation. It says nothing about a time.
 ``--layers`` cuts the depth for a faster answer; ``--keep`` writes the HLO
@@ -32,6 +38,29 @@ import re
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def count_launches(text: str, rules: list, classify) -> tuple[dict, list]:
+    """(launches of a step by class, the ``expert`` class's by layer in
+    layer order) of the optimised HLO ``text``: the fusions and custom
+    calls whose scope lies in the scan's body."""
+    launches = collections.Counter()
+    by_layer = collections.Counter()
+    for line in text.splitlines():
+        kind = re.search(r" (fusion|custom-call)\(", line)
+        scope = re.search(r'op_name="([^"]*)"', line)
+        if not kind or not scope or "/while/body/" not in scope.group(1):
+            continue
+        name = re.match(r"\s*(?:ROOT )?%?([\w.-]+) = ", line)
+        row = {"scope": scope.group(1), "category": "",
+               "name": name.group(1) if name else ""}
+        took = classify(row, rules)
+        launches[took] += 1
+        layer = re.search(r"/layers_(\d+)/", row["scope"])
+        if took == "expert" and layer:
+            by_layer[int(layer.group(1))] += 1
+    return dict(sorted(launches.items())), [
+        n for _, n in sorted(by_layer.items())]
 
 
 def main() -> int:
@@ -93,19 +122,10 @@ def main() -> int:
     bench = files.Bench(REPO)
     rules = bench.read("op_classes", args.classes + ".json")["classes"]
     classify = bench.load("readers", "op_class_ms").classify
-    launches = collections.Counter()
-    for line in text.splitlines():
-        kind = re.search(r" (fusion|custom-call)\(", line)
-        scope = re.search(r'op_name="([^"]*)"', line)
-        if not kind or not scope or "/while/body/" not in scope.group(1):
-            continue
-        name = re.match(r"\s*(?:ROOT )?%?([\w.-]+) = ", line)
-        row = {"scope": scope.group(1), "category": "",
-               "name": name.group(1) if name else ""}
-        launches[classify(row, rules)] += 1
+    launches, by_layer = count_launches(text, rules, classify)
     print(json.dumps({
         "expander": args.expander, "layers": cfg.num_layers,
-        "launches_a_step": dict(sorted(launches.items())),
+        "launches_a_step": launches, "expert_a_layer": by_layer,
         "all": sum(launches.values())}))
     return 0
 
