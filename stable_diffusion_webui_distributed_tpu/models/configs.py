@@ -214,7 +214,21 @@ class LMConfig:
     MLP sublayer, which must be a dense one: two layers of the list then
     spell one shortcut-connected double layer, ``x1 = x + A_0(N(x)); n =
     N(x1); s = M(n); x2 = x1 + F_0(n); x3 = x2 + A_1(N(x2)); y = x3 +
-    F_1(N(x3)) + s``. Each default is the plain form and traces no op."""
+    F_1(N(x3)) + s``. Each default is the plain form and traces no op.
+
+    ``tied_head``: the head IS the table. The logits are the normed state
+    times the held slice of ``embed_tokens`` transposed (contracted over
+    the hidden axis as the slice lies: one leaf, no ``lm_head``, no
+    transpose made), so ``vocab_held`` slices both at once.
+    ``residual_multiplier`` scales what EACH sublayer adds to the residual,
+    a token mixer's and a dense or an expert MLP's alike, after the
+    sublayer's post-norm where it has one: ``x + m * norm(F(norm(x)))``
+    (refused with several streams, whose mixers weigh what is written, and
+    with ``moe_shortcut``, whose carried sum is weighed elsewhere).
+    ``attention_scale`` over 0 is what a ``full`` or ``sliding`` layer
+    multiplies its scores by in all three of its forms (a chunk, a
+    one-sequence step, a forked step) where 0 has ``head_dim ** -0.5``.
+    Each default is the plain form and traces no op."""
 
     vocab_size: int = 100352
     hidden_size: int = 3072
@@ -298,6 +312,9 @@ class LMConfig:
     moe_shortcut: bool = False
     latent_q_scale: float = 1.0
     latent_kv_scale: float = 1.0
+    tied_head: bool = False
+    residual_multiplier: float = 1.0
+    attention_scale: float = 0.0
 
     def __post_init__(self) -> None:
         if self.norm_placement and (
@@ -323,6 +340,12 @@ class LMConfig:
         if not 0 <= self.zero_experts <= self.num_experts:
             raise ValueError("zero_experts are some of the router's "
                              "num_experts outputs")
+        if self.residual_multiplier != 1.0 and (
+                self.residual_streams != 1 or self.moe_shortcut):
+            raise ValueError("residual_multiplier wants one stream (a "
+                             "stream mixer weighs what a sublayer writes) "
+                             "and no moe_shortcut (a carried sum is weighed "
+                             "where it was routed)")
 
     @property
     def num_layers(self) -> int:
@@ -371,12 +394,13 @@ class LMConfig:
 
     @property
     def multipliers_applied(self) -> int:
-        """How many of the forward multipliers are off 1."""
+        """How many of the forward multipliers are off 1 (the scores' scale
+        off ``head_dim ** -0.5`` counts as one)."""
         scalars = (self.embedding_multiplier, self.logit_multiplier,
                    self.key_multiplier, *self.ssm_multipliers,
-                   *self.mlp_multipliers,
+                   *self.mlp_multipliers, self.residual_multiplier,
                    *(m for _, *pair in self.mixer_multipliers for m in pair))
-        return sum(m != 1.0 for m in scalars)
+        return sum(m != 1.0 for m in scalars) + bool(self.attention_scale)
 
     @property
     def ssm_inner(self) -> int:
@@ -1293,6 +1317,76 @@ def tiny_longcat_flash_expander() -> ModelFamily:
     """Factory form of :data:`TINY_LONGCAT_FLASH_EXPAND` (benchmark
     rehearsals)."""
     return TINY_LONGCAT_FLASH_EXPAND
+
+
+# granite-4.0-h-small (huggingface.co/ibm-granite/granite-4.0-h-small
+# config.json, ``model_type: granitemoehybrid``) at its published widths: 40
+# layers of hidden 4096, nine selective state-space mixers ALONE to one
+# attention (attention at 5, 15, 25, 35): a state-space layer is 128 heads
+# of 64 over states 128 wide, B and C one group, a 4-tap convolution with
+# bias over the 8448 channels [x | B | C], the read-out gated and then
+# normed over all 8192 channels; an attention layer 32 heads over 8 KV
+# heads of 128, NOT rotated (``nope``), its scores times 1/128. Every layer
+# then a softmax router over 72 experts of width 768, ten a token
+# renormalised, beside an ungated shared expert of 1536. The table's rows
+# times 12, what each sublayer adds to the residual times 0.22, the logits
+# (the table again: the head is tied) over 16; vocabulary 100352.
+_GRANITE_PERIOD = ("ssm",) * 5 + ("full",) + ("ssm",) * 4
+GRANITE_4_H_SMALL = LMConfig(
+    vocab_size=100352, hidden_size=4096, layer_types=_GRANITE_PERIOD * 4,
+    num_heads_per_layer=(32,) * 40, num_kv_heads=8, head_dim=128,
+    rope_full=None, attention_scale=0.0078125, attn_gate="none",
+    qk_norm=False, dense_layers=(), intermediate_size=768, num_experts=72,
+    num_experts_per_tok=10, moe_intermediate_size=768,
+    shared_expert_intermediate_size=1536, shared_expert_gate=False,
+    router_scoring="softmax", router_bias=False, norm_topk_prob=True,
+    routed_scaling_factor=1.0, rms_norm_eps=1e-5, ssm_num_heads=128,
+    ssm_head_dim=64, ssm_state_size=128, ssm_num_groups=1,
+    ssm_conv_kernel=4, ssm_conv_bias=True, ssm_norm_before_gate=False,
+    ssm_chunk=256, embedding_multiplier=12.0, logit_multiplier=0.0625,
+    residual_multiplier=0.22, tied_head=True)
+
+
+def sd15_granite_h_expander() -> ModelFamily:
+    """SD1.5 with granite-4.0-h-small as its resident prompt expander, cut
+    to one chip of a pair that share each layer: layers 0-9 (one period of
+    the pattern, five state-space layers, the attention, four more; one of
+    four pipeline stages), experts 0-35 of every layer's 72, vocabulary
+    ids 0-50175 (the first half of the table, which is also the head)."""
+    return dataclasses.replace(
+        SD15, name="sd15-granite-h-expand",
+        expander=lm_share(GRANITE_4_H_SMALL, layers=10, chips=2, rank=0,
+                          vocab_chips=2))
+
+
+# Tiny expander of that stack: two state-space layers (6 heads of 5 over
+# states 7 wide, one group, a 4-tap convolution with bias over 44 channels;
+# a chunk of 16, so a prefill runs several), an unrotated attention of 4
+# heads over 2 KV heads whose scores are NOT scaled by head_dim^-0.5, one
+# more state-space layer; every layer a softmax router over 12 experts, 3 a
+# token renormalised, beside a shared expert; the three multipliers and the
+# tied head all on; 4 of the 12 experts and half the vocabulary held.
+TINY_GRANITE_H_LM = LMConfig(
+    vocab_size=512, hidden_size=32,
+    layer_types=("ssm", "ssm", "full", "ssm"),
+    num_heads_per_layer=(4,) * 4, num_kv_heads=2, head_dim=8,
+    rope_full=None, attention_scale=0.2, attn_gate="none", dense_layers=(),
+    intermediate_size=16, num_experts=12, num_experts_per_tok=3,
+    moe_intermediate_size=16, shared_expert_intermediate_size=24,
+    router_scoring="softmax", norm_topk_prob=True,
+    routed_scaling_factor=1.0, rms_norm_eps=1e-5, ssm_num_heads=6,
+    ssm_head_dim=5, ssm_state_size=7, ssm_num_groups=1, ssm_conv_kernel=4,
+    ssm_conv_bias=True, ssm_chunk=16, embedding_multiplier=3.0,
+    logit_multiplier=0.25, residual_multiplier=0.6, tied_head=True)
+TINY_GRANITE_H_EXPAND = dataclasses.replace(
+    TINY, name="tiny-granite-h-expand",
+    expander=lm_share(TINY_GRANITE_H_LM, 4, chips=3, rank=0, vocab_chips=2))
+
+
+def tiny_granite_h_expander() -> ModelFamily:
+    """Factory form of :data:`TINY_GRANITE_H_EXPAND` (benchmark
+    rehearsals)."""
+    return TINY_GRANITE_H_EXPAND
 
 
 FAMILIES = {f.name: f for f in (SD15, SD21, SD21_BASE, SDXL_BASE,

@@ -39,8 +39,14 @@ Forward multipliers (``LMConfig.embedding_multiplier``,
 ``ssm_multipliers``, ``mlp_multipliers``) scale the table's output, the
 logits, the keys, each mixer's input and output, a state-space mixer's
 projected ranges and a dense SwiGLU's gate and output where the forward
-pass says; one that is 1.0 traces no op. A latent layer's queries and its
-normed latent may each carry a scalar (``latent_q_scale``,
+pass says; one that is 1.0 traces no op. ``residual_multiplier`` scales
+what EACH sublayer adds to the residual, an expert layer's routed sum and
+shared expert included, and ``attention_scale`` replaces ``head_dim **
+-0.5`` as the scores' multiplier of a full or a sliding layer, in all three
+of its forms. With ``tied_head`` the head is the token table itself: no
+``lm_head`` leaf, the logits the normed state contracted with the held
+slice as it lies (:meth:`DecoderLM.tied_logits`). A latent layer's queries
+and its normed latent may each carry a scalar (``latent_q_scale``,
 ``latent_kv_scale``: the cache holds the latent scaled). A router's LAST
 ids may be zero-compute experts that return their input
 (``zero_experts``: no kernels, ``(the picks' weights on them) * n`` added
@@ -226,8 +232,9 @@ def site_attrs(cfg: LMConfig) -> dict:
     have kernels, as one trace of its stack counts them
     (``serving.expander`` ``sublayer_norms``, ``attention_unrotated``,
     ``write_strength_bound``, ``ssm_mixers``, ``joined_layers``,
-    ``multipliers_applied``, ``moe_shortcuts``); ``{}`` for one that
-    departs in none."""
+    ``multipliers_applied``, ``moe_shortcuts``, ``tied_head``), a head of
+    its own, sublayers added unscaled and scores scaled by ``head_dim **
+    -0.5``; ``{}`` for one that departs in none."""
     attrs = {}
     if cfg.norm_placement:
         attrs["norms_pre"] = 2 * sum(p != POST for p in cfg.norm_placement)
@@ -247,6 +254,12 @@ def site_attrs(cfg: LMConfig) -> dict:
         attrs["moe_shortcuts"] = len(cfg.expert_layers)
     if cfg.zero_experts:
         attrs["zero_experts"] = cfg.zero_experts
+    if cfg.tied_head:
+        attrs["tied_head"] = True
+    if cfg.residual_multiplier != 1.0:
+        attrs["residual_multiplier"] = cfg.residual_multiplier
+    if cfg.attention_scale:
+        attrs["attention_scale"] = cfg.attention_scale
     return attrs
 
 
@@ -536,6 +549,8 @@ class Attention(nn.Module):
         kv, dim = cfg.num_kv_heads, cfg.head_dim
         tokens = n.shape[0]
         passes = 1 if pass_index is None else cfg.total_ut_steps
+        # what the scores are multiplied by, in all three forms below
+        scale = cfg.attention_scale or dim ** -0.5
 
         def lin(features, name):
             return Linear(features, self.dtype, self.quant, name=name)
@@ -625,7 +640,7 @@ class Attention(nn.Module):
                 q, of_pass(k_shared, 0), of_pass(v_shared, 0),
                 of_pass(k_cache, 1), of_pass(v_cache, 1), q_pos, shared_pos,
                 jnp.where(own_pos >= forked_at, own_pos, -1),
-                scale=dim ** -0.5, window=window)
+                scale=scale, window=window)
             ATTENTION.record(path, 1, shared + own, dim, passes)
         elif kind == FULL:
             # written first: a padded row lands beyond ``end``, where no
@@ -651,7 +666,7 @@ class Attention(nn.Module):
             v_cache = v_cache.at[into].set(v, mode="drop")
         if not sequences:
             out, path = attend_positions(q, keys, values, q_pos, k_pos,
-                                         scale=dim ** -0.5, window=window)
+                                         scale=scale, window=window)
             ATTENTION.record(path, tokens, keys.shape[0], dim, passes)
         if cfg.attn_gate == "element":
             out = out.astype(jnp.float32) * jax.nn.sigmoid(gate)
@@ -1347,7 +1362,12 @@ class DecoderLayer(nn.Module):
 
             if streams == 1:
                 out, more = sublayer(norm(x))
-                x = x + normed_after(out)
+                out = normed_after(out)
+                if cfg.residual_multiplier != 1.0:
+                    # a token mixer's, a dense MLP's, an expert layer's
+                    # routed sum and shared expert: all under the one
+                    out = out * cfg.residual_multiplier
+                x = x + out
                 if carried is not None and sublayer is mlp:
                     # the sum the layer before routed lands here, one
                     # token mixer and one MLP after its router
@@ -1426,8 +1446,8 @@ class DecoderLM(nn.Module):
         first, count = cfg.vocab
         local = tokens - first
         here = (local >= 0) & (local < count)
-        x = nn.Embed(count, cfg.hidden_size, name="embed_tokens")(
-            jnp.clip(local, 0, count - 1)).astype(jnp.float32)
+        table = nn.Embed(count, cfg.hidden_size, name="embed_tokens")
+        x = table(jnp.clip(local, 0, count - 1)).astype(jnp.float32)
         x = x * here[:, None]
         if cfg.embedding_multiplier != 1.0:
             x = x * cfg.embedding_multiplier
@@ -1518,8 +1538,13 @@ class DecoderLM(nn.Module):
             n = self.read_pass(h, None if all_logits else length)
         if sequences:
             cache[FORKED_AT] = [jnp.full_like(stamp, forked_at)]
-        logits = Linear(cfg.vocab[1], self.dtype, self.quant_linears,
-                        name="lm_head")(n)
+        if cfg.tied_head:
+            logits = self.tied_logits(table.embedding, n)
+            EXPANDER.record_tied_head(
+                delta_rule.form(tokens.shape[0], sequences))
+        else:
+            logits = Linear(cfg.vocab[1], self.dtype, self.quant_linears,
+                            name="lm_head")(n)
         if cfg.logit_multiplier != 1.0:
             logits = logits * cfg.logit_multiplier
         if not routed:     # no expert layer: the three parts, empty
@@ -1528,6 +1553,26 @@ class DecoderLM(nn.Module):
                 none[:, None, None],
                 jnp.zeros((0, cfg.experts[1]), jnp.int32), none)
         return logits, cache, tuple(jnp.stack(part) for part in zip(*routed))
+
+    @nn.nowrap
+    def tied_logits(self, table, n):
+        """``n E^T`` over the held slice ``table`` ``(count, hidden)`` of
+        ``embed_tokens``: the head a tied model has (``LMConfig.
+        tied_head``). The slice is contracted over its SECOND axis as it
+        lies, so no transpose of it is made and a step streams it once, as
+        it would a head's kernel; operands in ``dtype``, float32
+        accumulation and result, as :class:`Linear`. Under
+        ``quant_linears`` the control's int8 product takes the slice
+        transposed (ops/quant.py wants ``(in, out)``: the control makes
+        the copy the program does not). Scoped ``lm_head``: the name a
+        trace knows a head's product by."""
+        with jax.named_scope("lm_head"):
+            if self.quant_linears:
+                return int8_dot(n, table.T)
+            return jax.lax.dot_general(
+                n.astype(self.dtype), table.astype(self.dtype),
+                (((n.ndim - 1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     @nn.nowrap
     def read_pass(self, h, last):

@@ -914,6 +914,16 @@ def render() -> str:
             "Picks of the prompt expander's decode steps that fell on "
             "zero-compute (identity) experts.",
             expander["zero_expert_picks"])
+    _labeled_family(
+        lines, "sdtpu_expander_tied_head_total", "counter",
+        "Executables traced whose logits are read off the token table "
+        "itself (a tied head), by the form of the executable.",
+        [(f'form="{_label(form)}"', n)
+         for form, n in sorted(expander["tied_head"].items())])
+    _scalar(lines, "sdtpu_expander_expert_picks_held_total", "counter",
+            "Picks of the prompt expander's decode steps that fell on an "
+            "expert held here.",
+            expander["expert_picks_held"])
     _scalar(lines, "sdtpu_expander_state_bytes_stepped_total", "counter",
             "Bytes of recurrent states and kept inputs (linear layers', "
             "state-space mixers') the "

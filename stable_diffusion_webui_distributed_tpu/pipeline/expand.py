@@ -401,8 +401,9 @@ class PromptExpander:
                 # a step of one token reads as many experts as it has picks
                 # held; a step of several the distinct ones, counted beside
                 # the load on the device
+                held_picks = int(np.sum(loads[decoded_from:]))
                 read = np.sum(jax.device_get(reads)) if reads \
-                    else np.sum(loads[decoded_from:])
+                    else held_picks
                 EXPANDER.record(
                     prefilled=len(user) + (0 if held else len(prefix)),
                     from_prefix=held, sequences=live,
@@ -424,6 +425,7 @@ class PromptExpander:
                     fork_bytes_copied=fork_copied,
                     zero_expert_picks=int(np.sum(jax.device_get(zeros)))
                     if zeros else 0,
+                    expert_picks_held=held_picks,
                     **self._passes_run(exits, steps))
 
         if later is None:

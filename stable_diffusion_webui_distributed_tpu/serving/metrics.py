@@ -455,7 +455,8 @@ def replay_sites(rows) -> None:
                 "joined": EXPANDER.record_joined,
                 "multipliers": EXPANDER.record_multipliers,
                 "shortcut": EXPANDER.record_shortcut,
-                "latent_scaled": EXPANDER.record_latent_scaled}
+                "latent_scaled": EXPANDER.record_latent_scaled,
+                "tied_head": EXPANDER.record_tied_head}
     for counter, *args in rows:
         counters[counter](*args)
 
@@ -656,6 +657,13 @@ class ExpanderStats:
     count which fell on zero-compute experts (``LMConfig.zero_experts``;
     counted on the device beside the load; over ``tokens_decoded`` and the
     expert layers: identity picks a token a router).
+    ``tied_head`` counts, by the same three forms of the executable traced,
+    the sites where the logits were read off the token table itself
+    (``LMConfig.tied_head``: no ``lm_head`` exists; 0 where a model has a
+    head of its own), and ``expert_picks_held`` the picks of the decode
+    steps' rows that count which fell on an expert held here (the sum of
+    the load those steps counted on the device; over ``experts_read``: the
+    rows an expert's kernels serve once streamed, 1 at one sequence).
     Of a looped model (``LMConfig.total_ut_steps`` over 1): ``layer_passes``,
     the passes of the whole stack its decode steps ran (every pass of every
     step, whichever the head read; over ``decode_steps``: passes a token); ``exit_pass``, the tokens made by the
@@ -713,6 +721,8 @@ class ExpanderStats:
             self.latent_scaled = {"latent_absorbed": 0, "latent_expanded": 0,
                                   "latent_forked": 0}  # guarded-by: _lock
             self.zero_picks = 0        # guarded-by: _lock
+            self.tied_heads = dict.fromkeys(self.deltas, 0)  # guarded-by: _lock
+            self.picks_held = 0        # guarded-by: _lock
 
     def record_product(self, path: str) -> None:
         """One expert layer in one trace took product ``path``."""
@@ -799,6 +809,13 @@ class ExpanderStats:
         with self._lock:
             self.latent_scaled[form] += 1
 
+    def record_tied_head(self, form: str) -> None:
+        """One trace of an executable of ``form`` read its logits off the
+        token table."""
+        _note_site("tied_head", str(form))
+        with self._lock:
+            self.tied_heads[form] += 1
+
     def record(self, *, prefilled: int, from_prefix: int, sequences: int,
                decoded: int, decode_steps: int, experts_read: int, load,
                none_held: int,
@@ -809,8 +826,8 @@ class ExpanderStats:
                rows_read_shared: int = 0,
                layer_passes: int = 0, exit_pass=(),
                exit_lambda_max: float = 0.0, state_bytes_stepped: int = 0,
-               fork_bytes_copied: int = 0, zero_expert_picks: int = 0
-               ) -> None:
+               fork_bytes_copied: int = 0, zero_expert_picks: int = 0,
+               expert_picks_held: int = 0) -> None:
         """``load`` is (expert layers, held experts) counts of one
         request; ``decode_steps`` the steps its decode executables ran
         (whole chunks, so at least ``decoded / sequences - 1``), each a
@@ -843,6 +860,7 @@ class ExpanderStats:
             self.state_bytes_stepped += int(state_bytes_stepped)
             self.fork_bytes_copied += int(fork_bytes_copied)
             self.zero_picks += int(zero_expert_picks)
+            self.picks_held += int(expert_picks_held)
             if len(exit_pass):
                 old = self.exit_pass or [0] * len(exit_pass)
                 self.exit_pass = [a + int(b)
@@ -893,6 +911,8 @@ class ExpanderStats:
                 "moe_shortcuts": dict(self.shortcuts),
                 "latent_scaled": dict(self.latent_scaled),
                 "zero_expert_picks": self.zero_picks,
+                "tied_head": dict(self.tied_heads),
+                "expert_picks_held": self.picks_held,
                 "layer_passes": self.layer_passes,
                 "exit_pass": list(self.exit_pass),
                 "exit_lambda_max": self.exit_lambda_max,

@@ -620,6 +620,26 @@ EXPANDER_EXECUTABLES = [
     ("decode4", "sd15_longcat_flash_expander", 2560, 10.35, 4, 260, 32),
     ("prefill", "sd15_longcat_flash_expander", 2560, 10.35, 0, 400, 23),
     ("prefill2048", "sd15_longcat_flash_expander", 2560, 10.35, 0, 2000, 23),
+    # ten expert kernels (the ninth published shape, 4096 x 768, two tiles
+    # of 384 by the kernel's own rule; a call of four rows of ten picks
+    # walks at most the 36 held experts: 36 grid slots) behind ten routing
+    # kernels, nine state-space mixers that step a (128, 64, 128) float32
+    # state a sequence, element-wise, ONE forked unrotated attention, and
+    # the logits off the table (no lm_head among the 9.51 GB of
+    # arguments): four sequences donate the one sequence's 21 MB of keys
+    # and values of that ONE layer (shared, handed through), 256 own slots
+    # each and thirty-six states with their kept rows, 169 MB; 38 MB of
+    # temporaries. The prompt's 64-token chunk chunk-wise (one chunk of
+    # 256 padded from 64) over nine states: 171 MB of temporaries; the
+    # instruction's one chunk of 2 048: 1.38 GB, the same at ssm_chunk 128
+    # and 256 (so the published 256 stays): 32 heads' float32 scores over
+    # 2 560 positions (0.67 GB) and the grouped product's 2 048 x 10 picks
+    # sorted into tiles beside their float32 results are the peak, the
+    # chunk-wise form's (8, 128, 256, 256) float32 decay table and scores
+    # (268 MB each) are not live with them
+    ("decode4", "sd15_granite_h_expander", 2560, 9.6, 10, 64, 160),
+    ("prefill", "sd15_granite_h_expander", 2560, 9.5, 0, 260, 45),
+    ("prefill2048", "sd15_granite_h_expander", 2560, 9.5, 0, 2000, 45),
 ]
 
 
@@ -632,12 +652,15 @@ _CHAIN_OP = re.compile(
 
 @pytest.mark.parametrize("rows,factory", [
     (1, "sd15_qwen3next_expander"), (4, "sd15_longcat_flash_expander"),
-    (8, "sd15_mellum2_expander"), (2, "sd15_gigachat35_expander")])
+    (8, "sd15_mellum2_expander"), (2, "sd15_gigachat35_expander"),
+    (4, "sd15_granite_h_expander")])
 def test_routing_kernel_compiles_for_v5e(one_chip, rows, factory):
     """One row of Qwen3-Next's 512 / 10, four of LongCat's 768 / 12 with
     its bias (stored bf16, widened in the kernel) and 256 identity
     experts, eight of Mellum2's 64 / 8 (64 grid slots, under one
-    register's lanes) and two of GigaChat3.5's 256 of which 16 held."""
+    register's lanes), two of GigaChat3.5's 256 of which 16 held and four
+    of granite-4.0-h-small's 72 / 10 of which 36 held (a router under one
+    lane width, ten rounds, more grid slots than picks held)."""
     from stable_diffusion_webui_distributed_tpu.models import configs
     from stable_diffusion_webui_distributed_tpu.ops import route_kernel
 

@@ -950,7 +950,8 @@ class TestEnginePath:
             "state_bytes_stepped", "fork_bytes_copied", "sublayer_norms",
             "attention_unrotated", "write_strength_bound", "ssm_mixers",
             "joined_layers", "multipliers_applied", "moe_shortcuts",
-            "latent_scaled", "zero_expert_picks"}
+            "latent_scaled", "zero_expert_picks", "tied_head",
+            "expert_picks_held"}
         # a model of one pass leaves the looped model's counters alone
         assert (block["layer_passes"], block["exit_pass"],
                 block["exit_lambda_max"]) == (0, [], 0.0)
@@ -983,6 +984,12 @@ class TestEnginePath:
         assert set(block["moe_shortcuts"]) == set(block["delta_mixers"])
         assert not any(block["latent_scaled"].values())
         assert block["zero_expert_picks"] == 0
+        # it has a head of its own, and a pick that fell on a held expert
+        # is counted: an expert read serves one pick at least (exactly one
+        # at one sequence a step)
+        assert not any(block["tied_head"].values())
+        assert set(block["tied_head"]) == set(block["delta_mixers"])
+        assert block["expert_picks_held"] >= block["experts_read"] > 0
         json.dumps(block)
 
     @pytest.mark.parametrize("sequences,forked_at,steps", [
