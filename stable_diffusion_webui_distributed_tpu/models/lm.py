@@ -1805,7 +1805,11 @@ def decode_sequences_fn(module: DecoderLM, steps: int):
     the load. ``experts read`` ``(expert
     layers,)`` sums over the steps the DISTINCT held experts a step's rows
     chose (ops/moe.py:experts_read): what a step streams, however many of
-    its rows chose one. A looped model's returns the live rows' ``exits``
+    its rows chose one. A model with expert layers returns ``calls
+    unread`` ``(expert layers,)`` next: the steps in which the rows chose
+    no held expert, so that the layer's routed sum read nothing (a step of
+    one sequence counts them already, as ``none held``). A looped model's
+    returns the live rows' ``exits``
     after these, and one whose router has zero-compute experts the live
     rows' picks on them ``(expert layers,)`` last."""
 
@@ -1814,10 +1818,13 @@ def decode_sequences_fn(module: DecoderLM, steps: int):
         variables = {"params": params}
         cfg = module.config
         looped = len(no_exits(cfg))
+        layers = len(cfg.expert_layers)
+        calls = int(bool(layers))       # whether ``calls unread`` is carried
 
         def step(carry, _):
             cache, tokens, position, load, none_held, read, *rest = carry
-            exits, zero = rest[:looped], rest[looped:]
+            unread, exits, zero = (rest[:calls], rest[calls:calls + looped],
+                                   rest[calls + looped:])
             logits, cache, routed, more = apply_counting(
                 module, variables, tokens, position, live, cache,
                 sequences=True, live=live)
@@ -1826,13 +1833,15 @@ def decode_sequences_fn(module: DecoderLM, steps: int):
             return (cache, tokens, position + 1, load + routed[1],
                     none_held + routed[2],
                     read + moe.experts_read(routed[1])) \
+                + tuple(u + (moe.experts_read(routed[1]) == 0)
+                        for u in unread) \
                 + add_exits(exits, more) \
                 + tuple(z + r for z, r in zip(zero, routed[3:])), tokens
 
-        layers = len(cfg.expert_layers)
         zero = (jnp.zeros((layers, cfg.experts[1]), jnp.int32),
                 jnp.zeros((layers,), jnp.int32),
-                jnp.zeros((layers,), jnp.int32)) + no_exits(cfg) \
+                jnp.zeros((layers,), jnp.int32)) \
+            + (jnp.zeros((layers,), jnp.int32),) * calls + no_exits(cfg) \
             + no_zero_picks(cfg)
         (cache, tokens, position, load, none_held, read, *rest), made = \
             jax.lax.scan(step, (cache, tokens, position) + zero, None,

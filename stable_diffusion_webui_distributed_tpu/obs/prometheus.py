@@ -924,6 +924,14 @@ def render() -> str:
             "Picks of the prompt expander's decode steps that fell on an "
             "expert held here.",
             expander["expert_picks_held"])
+    _scalar(lines, "sdtpu_expander_expert_calls_total", "counter",
+            "Routed sums of the prompt expander's decode steps, one an "
+            "expert layer a step (a call of the expert kernel on the chip).",
+            expander["expert_calls"])
+    _scalar(lines, "sdtpu_expander_expert_calls_unread_total", "counter",
+            "Those calls whose rows chose no expert held here: they read "
+            "nothing of the experts' kernels.",
+            expander["expert_calls_unread"])
     _scalar(lines, "sdtpu_expander_state_bytes_stepped_total", "counter",
             "Bytes of recurrent states and kept inputs (linear layers', "
             "state-space mixers') the "

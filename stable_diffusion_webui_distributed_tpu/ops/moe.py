@@ -29,9 +29,11 @@ static, and whether a mesh will partition the program). By row count:
 - 1 to 8 rows (a decode step: one sequence, or the 2, 4 or 8 sequences of
   ``cache/kv.py:SEQUENCE_BUCKETS``) on a TPU with bf16 operands and ``d``
   and ``f`` multiples of the lane width, no mesh, ``"kernel"``: one
-  pipelined Pallas kernel (ops/moe_kernel.py) over the step's DISTINCT
-  held experts that reads expert ``j + 1``'s kernels while expert ``j``
-  multiplies. One row's picks are distinct already (:func:`_chosen`);
+  Pallas kernel (ops/moe_kernel.py) over the step's DISTINCT held experts
+  that walks their blocks through a ring of reads of its own, the next
+  block in flight while one multiplies, and reads nothing at all where
+  none of the step's experts is held. One row's picks are distinct
+  already (:func:`_chosen`);
   several rows' are made so and every expert takes the whole block of
   rows, a per-row weight deciding what it adds (:func:`_block`): under one
   row tile the grouped product would pad every expert's rows to 8 anyway,

@@ -1,6 +1,7 @@
-"""A decode step's sum over the chosen experts held here, as one pipelined
-Pallas kernel: one row (a step of one sequence) or a block of 2-8 rows (a
-step of several sequences, every distinct expert taking the whole block).
+"""A decode step's sum over the chosen experts held here, as one Pallas
+kernel that schedules its own reads: one row (a step of one sequence) or a
+block of 2-8 rows (a step of several sequences, every distinct expert
+taking the whole block).
 
 ``ops/moe.py:_chosen`` walks the chosen experts in a ``fori_loop`` whose
 trip count is dynamic. On the TPU such a loop runs its trips strictly one
@@ -10,63 +11,77 @@ one before has finished. The stream itself runs at the chip's bandwidth
 (826 GB/s on the margin between two expert sizes) but 6-9 us of every trip
 pass with nothing in flight (PERF.md section 6, PR 34).
 
-Here the grid runs over the ``k`` chosen slots times the tiles of the
-experts' width ``f``. The local ids of the chosen experts (held ones first,
+Here the three stacked kernels stay in HBM and ONE grid step walks the
+call's reads itself. The local ids of the chosen experts (held ones first,
 as ``_chosen`` sorts them), their routing weights and the held count are
-scalar-prefetched, and the three kernels' ``BlockSpec`` index maps read the
-id, so the pipeline fetches the blocks of step ``i + 1`` while step ``i``
-multiplies. The grid is static and the held count is not: the ``k - held``
-steps with nothing to do come FIRST and repeat the index of the first block
-a held slot reads. No read is issued for an unchanged index and their body
-is skipped, so they pass while that first read, which the pipeline starts
-with the call, is in flight. SwiGLU is separable over ``f`` and the down
-product sums over the tiles, so a tile is ``silu(x Wg[:, tile]) * (x Wu[:,
-tile])``, cast to the operand dtype, times ``Wd[tile, :]``; the float32 sum
-of ``weight * that`` stays in VMEM (the output block, whose index never
-changes) and is written once.
+scalar-prefetched; an expert's width ``f`` is cut into blocks (:func:`ring`)
+and a ``fori_loop`` whose trip count is ``held x blocks`` waits for a
+block's three copies, multiplies, and before the wait has started the next
+block's copies (``make_async_copy`` under DMA semaphores) into the slot of
+the ring the block before multiplied from: no moment of a call has no read
+outstanding. SwiGLU is separable over ``f`` and the down product sums over
+the blocks, so a block is ``silu(x Wg[:, block]) * (x Wu[:, block])``, cast
+to the operand dtype, times ``Wd[block, :]``; the float32 sum of ``weight *
+that`` stays in VMEM (the output block) and is written once.
+
+Only experts that are chosen and held are read, and a step none of whose
+experts is held reads NOTHING: the trip count is the condition, no copy is
+started and the zeroed output is the answer. (Until PR 70 a ``BlockSpec``
+pipeline over a static grid of ``k`` slots fetched its first step's blocks
+before it could know: one block of local expert 0, 11 MB at Xing4.0's
+shape, in the 30.6 % of that share's calls that hold nothing.) A block is
+as wide as the ring's two slots allow, since the same bytes in more copies
+stream slower (:func:`ring`).
 
 A step of several rows (``rows`` = 2-8: the sequences of one request, one
-token each) runs the same grid over the step's DISTINCT held experts, at
-most ``slots = min(rows * k, held)`` of them. Sorting rows by expert would
-buy nothing under one row tile, so every expert multiplies the whole block
-``(rows, d)`` (padded to the bf16 sublane tile) and a per-row weight says
-what it adds: ``out[r] += W[slot, r] * E_slot(x)[r]``, with ``W[slot, r]``
-zero where row ``r`` did not choose the expert. The weights lie flat in
-SMEM, ``rows`` a slot; a row that did not choose an expert is SELECTED out,
-not multiplied by zero, so an overflowed product of a row that never asked
-for it cannot reach that row. One row is ``rows == 1``, ``slots == k``: a
+token each) walks the step's DISTINCT held experts, at most ``slots =
+min(rows * k, held)`` of them. Sorting rows by expert would buy nothing
+under one row tile, so every expert multiplies the whole block ``(rows,
+d)`` (padded to the bf16 sublane tile) and a per-row weight says what it
+adds: ``out[r] += W[slot, r] * E_slot(x)[r]``, with ``W[slot, r]`` zero
+where row ``r`` did not choose the expert. The weights lie flat in SMEM,
+``rows`` a slot; a row that did not choose an expert is SELECTED out, not
+multiplied by zero, so an overflowed product of a row that never asked for
+it cannot reach that row. One row is ``rows == 1``, ``slots == k``: a
 token's picks are distinct already, and its one weight stays a scalar.
-
-Only experts that are chosen and held are read, with one exception: a step
-none of whose experts is held still costs the read of ONE tile of local
-expert 0 (the pipeline fetches the first step's blocks before it can know),
-which no product uses.
 
 Operands in their own dtype into the MXU, float32 accumulation, as
 ``moe._swiglu``; the dots pin their precision, since Mosaic refuses bf16
 operands at a caller's ``default_matmul_precision("highest")``. Not bit
-equal to the loop: the float32 sum over ``f`` runs tile by tile.
+equal to the loop: the float32 sum over ``f`` runs block by block.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-#: lane width: ``d`` and the ``f`` tile are multiples of it
+#: lane width: ``d`` and every block of ``f`` are multiples of it
 LANES = 128
-#: VMEM the three kernels' blocks may take, double-buffered. The ``f`` tile
-#: is the widest that fits: 512 at the first three published shapes (3072 x
-#: 1024, 2048 x 512 and 3584 x 1024; 18.9, 12.6 and 22.0 MB), all of 768 at
-#: 2048 x 768, and 256 at 7168 x 2048 (22.0 MB), the first under 512.
+#: VMEM the ring of blocks may take: :data:`_RING_BUFFERS` slots of the
+#: widest block's three slabs (what the ``BlockSpec`` pipeline's two
+#: buffers a weight took before)
 _WEIGHT_VMEM = 24 * 2 ** 20
-#: beside the blocks: x, the output, a tile's float32 intermediates (Mosaic
-#: compiles both published shapes with 1 MiB; the decode span does not move
+#: beside the ring: x, the output, a block's float32 intermediates (Mosaic
+#: compiles every published shape with 1 MiB; the decode span does not move
 #: between 1, 2 and 4)
 _VMEM_SLACK = 4 * 2 ** 20
+#: slots of the ring. A block's read is started before the wait for the
+#: block in front of it, so two reads are outstanding at every boundary; a
+#: third and a fourth slot changed no shape's call by more than its noise
+#: (PERF.md section 6, PR 70)
+_RING_BUFFERS = 2
+#: experts whose rows are longer than this (GigaChat3.5's 7168, LongCat's
+#: 6144) are read a lane width a block: 3-4 % less a call than the 256
+#: columns that fit. The shorter ones take the widest block that fits: the
+#: same bytes in more, narrower copies stream up to 9 % slower (3072 and
+#: 3584 rows; nothing to tell apart at 2048-2304 and 4096; my chip runs,
+#: PR 70)
+_LONG_ROWS = 4096
 #: experts' reads XLA is told one call costs, whatever its rows
 #: (:func:`_call`)
 _COST_EXPERTS = 3
@@ -75,18 +90,40 @@ _COST_EXPERTS = 3
 ROW_BLOCK = 16
 
 
-def f_tile(d: int, f: int, itemsize: int) -> int | None:
-    """The widest tile of ``f`` (all of it, else a divisor that is a
-    multiple of the lane width) whose three blocks fit :data:`_WEIGHT_VMEM`
-    twice over; None when ``d`` or ``f`` is off the lanes or none fits."""
+class Ring(NamedTuple):
+    """How a call reads its experts' width ``f``: every expert in ``wides``
+    blocks of ``wide`` columns, through ``buffers`` slots of VMEM."""
+    buffers: int
+    wide: int
+    wides: int
+
+    def vmem_bytes(self, d: int, itemsize: int) -> int:
+        return self.buffers * 3 * d * self.wide * itemsize
+
+
+def ring(d: int, f: int, itemsize: int) -> Ring | None:
+    """The blocks of ``f`` a call walks, from what it sees; None when ``d``
+    or ``f`` is off the lanes or no block fits. A block is the widest
+    divisor of ``f`` on the lanes (all of it where that fits) whose three
+    slabs fit :data:`_WEIGHT_VMEM` once a slot of the ring, one lane width
+    where the rows are over :data:`_LONG_ROWS`."""
     if d % LANES or f % LANES:
         return None
-    for tiles in range(1, f // LANES + 1):
-        tile = f // tiles
-        if f % tiles == 0 and tile % LANES == 0 \
-                and 2 * 3 * d * tile * itemsize <= _WEIGHT_VMEM:
-            return tile
-    return None
+    lanes = f // LANES
+    slab = 3 * d * LANES * itemsize         # one lane width of an expert
+    if _RING_BUFFERS * slab > _WEIGHT_VMEM:
+        return None
+    widest = 1 if d > _LONG_ROWS else _WEIGHT_VMEM // (_RING_BUFFERS * slab)
+    wide = next(n for n in range(min(lanes, widest), 0, -1)
+                if lanes % n == 0)
+    return Ring(_RING_BUFFERS, wide * LANES, lanes // wide)
+
+
+def f_tile(d: int, f: int, itemsize: int) -> int | None:
+    """The widest block of ``f`` a call reads (:func:`ring`); None when
+    these widths do not tile."""
+    blocks = ring(d, f, itemsize)
+    return None if blocks is None else blocks.wide
 
 
 def _column(weights_ref, first, rows: int, block: int):
@@ -99,44 +136,78 @@ def _column(weights_ref, first, rows: int, block: int):
     return column
 
 
-def _kernel(ids_ref, held_ref, weights_ref, x_ref, gate_ref, up_ref,
-            down_ref, out_ref, *, limit: float = 0.0):
-    step, tile = pl.program_id(0), pl.program_id(1)
-    slot = step - (pl.num_programs(0) - held_ref[0])   # idle steps first
+def _kernel(ids_ref, held_ref, weights_ref, x_ref, gate_hbm, up_hbm,
+            down_hbm, out_ref, gate_ref, up_ref, down_ref, landed, *,
+            blocks: Ring, limit: float = 0.0):
+    from jax.experimental.pallas import tpu as pltpu
+
     rows = weights_ref.shape[0] // ids_ref.shape[0]    # static: 1, or 2-8
+    reads = held_ref[0] * blocks.wides      # nothing held: nothing read
+    out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when((step == 0) & (tile == 0))
-    def _zero():
-        out_ref[...] = jnp.zeros_like(out_ref)
+    def copies(read):
+        """The three copies of the call's ``read``: block ``read %
+        wides`` of the expert in slot ``read // wides``, into the ring."""
+        slot = jax.lax.div(read, blocks.wides)
+        expert, buffer = ids_ref[slot], jax.lax.rem(read, blocks.buffers)
+        columns = pl.ds(pl.multiple_of(
+            (read - slot * blocks.wides) * blocks.wide, LANES), blocks.wide)
+        return (pltpu.make_async_copy(gate_hbm.at[expert, :, columns],
+                                      gate_ref.at[buffer],
+                                      landed.at[0, buffer]),
+                pltpu.make_async_copy(up_hbm.at[expert, :, columns],
+                                      up_ref.at[buffer],
+                                      landed.at[1, buffer]),
+                pltpu.make_async_copy(down_hbm.at[expert, columns, :],
+                                      down_ref.at[buffer],
+                                      landed.at[2, buffer]))
 
-    @pl.when(slot >= 0)
-    def _one_tile():
+    def start(read):
+        for copy in copies(read):
+            copy.start()
+
+    for read in range(blocks.buffers - 1):
+        pl.when(read < reads)(functools.partial(start, jnp.int32(read)))
+
+    def one_block(out_ref, read, _):
+        # started before the wait: the buffer it lands in is the one the
+        # block before multiplied from, and no moment has no read in flight
+        ahead = read + blocks.buffers - 1
+        pl.when(ahead < reads)(lambda: start(ahead))
+        for copy in copies(read):
+            copy.wait()
+        slot = jax.lax.div(read, blocks.wides)
+        buffer = jax.lax.rem(read, blocks.buffers)
         x = x_ref[...]
+        gate, up, down = gate_ref[buffer], up_ref[buffer], down_ref[buffer]
         # see flash_attention.py: bf16 products are exact in float32, and
         # Mosaic refuses bf16 operands at a higher precision
         dot = functools.partial(
             jnp.dot, preferred_element_type=jnp.float32,
             precision=(jax.lax.Precision.DEFAULT
                        if x.dtype == jnp.bfloat16 else None))
-        if limit:   # the clamp is element-wise, so a tile's is the whole's
-            hidden = (jax.nn.silu(jnp.minimum(dot(x, gate_ref[...]), limit))
-                      * jnp.clip(dot(x, up_ref[...]), -limit, limit)
+        if limit:   # the clamp is element-wise: a block's is the whole's
+            hidden = (jax.nn.silu(jnp.minimum(dot(x, gate), limit))
+                      * jnp.clip(dot(x, up), -limit, limit)
                       ).astype(x.dtype)
         else:
-            hidden = (jax.nn.silu(dot(x, gate_ref[...]))
-                      * dot(x, up_ref[...])).astype(x.dtype)
+            hidden = (jax.nn.silu(dot(x, gate))
+                      * dot(x, up)).astype(x.dtype)
         if rows == 1:
-            out_ref[...] += weights_ref[slot] * dot(hidden, down_ref[...])
+            out_ref[...] += weights_ref[slot] * dot(hidden, down)
         else:
             # a row that did not choose this expert gets nothing from it,
             # not ``0 * y``: its product may have overflowed
             weight = _column(weights_ref, slot * rows, rows, x.shape[0])
             out_ref[...] += jnp.where(
-                weight != 0.0, weight * dot(hidden, down_ref[...]), 0.0)
+                weight != 0.0, weight * dot(hidden, down), 0.0)
+
+    # the body is handed the block it accumulates into
+    jax.lax.fori_loop(0, reads, functools.partial(one_block, out_ref), None)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret", "limit"))
-def _call(ids, held, weights, x, w_gate, w_up, w_down, *, tile: int,
+@functools.partial(jax.jit, static_argnames=("blocks", "interpret", "limit"))
+def _call(ids, held, weights, x, w_gate, w_up, w_down, *, blocks: Ring,
           interpret: bool, limit: float = 0.0):
     """Jitted on its own so that the expert layers of one model trace and
     lower the kernel once per shape, not once per layer.
@@ -177,48 +248,32 @@ def _call(ids, held, weights, x, w_gate, w_up, w_down, *, tile: int,
     3 and at 53. So the rule stays a constant, not the shapes' figure."""
     from jax.experimental.pallas import tpu as pltpu
 
-    slots = ids.shape[0]
     block_rows, d = x.shape
     f = w_gate.shape[2]
-    tiles = f // tile
-
-    def block(step, t, ids, held, weights):
-        """(local expert, tile of f) grid step ``(step, t)`` reads. The
-        ``slots - held`` idle steps come first and sit on the first block
-        a held slot will read, which the pipeline fetches as the call
-        starts: they pass while that read is in flight."""
-        slot = step - (slots - held[0])
-        return ids[jnp.maximum(slot, 0)], jnp.where(slot >= 0, t, 0)
-
-    def columns(step, t, *prefetched):
-        expert, t = block(step, t, *prefetched)
-        return expert, 0, t
-
-    def rows(step, t, *prefetched):
-        expert, t = block(step, t, *prefetched)
-        return expert, t, 0
-
-    whole = pl.BlockSpec((block_rows, d), lambda step, t, *_: (0, 0))
-    itemsize = w_gate.dtype.itemsize
+    dtype = w_gate.dtype
+    whole = pl.BlockSpec((block_rows, d), lambda step, *_: (0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
-        # the unclamped kernel is the function itself, as it always was
-        functools.partial(_kernel, limit=limit) if limit else _kernel,
+        functools.partial(_kernel, blocks=blocks, limit=limit),
         out_shape=jax.ShapeDtypeStruct((block_rows, d), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(slots, tiles),
-            in_specs=[whole,
-                      pl.BlockSpec((None, d, tile), columns),
-                      pl.BlockSpec((None, d, tile), columns),
-                      pl.BlockSpec((None, tile, d), rows)],
-            out_specs=whole),
+            grid=(1,),
+            in_specs=[whole, in_hbm, in_hbm, in_hbm],
+            out_specs=whole,
+            scratch_shapes=[
+                pltpu.VMEM((blocks.buffers, d, blocks.wide), dtype),
+                pltpu.VMEM((blocks.buffers, d, blocks.wide), dtype),
+                pltpu.VMEM((blocks.buffers, blocks.wide, d), dtype),
+                pltpu.SemaphoreType.DMA((3, blocks.buffers))]),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=2 * 3 * d * tile * itemsize + _VMEM_SLACK),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=blocks.vmem_bytes(d, dtype.itemsize)
+            + _VMEM_SLACK),
         cost_estimate=pl.CostEstimate(
             flops=6 * _COST_EXPERTS * d * f,
             transcendentals=_COST_EXPERTS * f,
-            bytes_accessed=3 * _COST_EXPERTS * d * f * itemsize),
+            bytes_accessed=3 * _COST_EXPERTS * d * f * dtype.itemsize),
         interpret=interpret,
     )(ids, held, weights, x, w_gate, w_up, w_down)
 
@@ -239,8 +294,8 @@ def chosen_experts(x: jax.Array, experts: jax.Array, weights: jax.Array,
     clamps each expert's SwiGLU as ``moe._swiglu`` does."""
     rows = x.shape[0]
     _, d, f = w_gate.shape
-    tile = f_tile(d, f, w_gate.dtype.itemsize)
-    if tile is None:
+    blocks = ring(d, f, w_gate.dtype.itemsize)
+    if blocks is None:
         raise ValueError(f"experts of {d} x {f} do not tile")
     if rows > ROW_BLOCK:
         raise ValueError(f"{rows} rows are over one block of {ROW_BLOCK}")
@@ -252,6 +307,6 @@ def chosen_experts(x: jax.Array, experts: jax.Array, weights: jax.Array,
         weights = weights.reshape(-1)
     out = _call(experts.astype(jnp.int32),
                 jnp.reshape(held, (1,)).astype(jnp.int32), weights, x,
-                w_gate, w_up, w_down, tile=tile, interpret=interpret,
+                w_gate, w_up, w_down, blocks=blocks, interpret=interpret,
                 limit=limit)
     return out if rows == 1 else out[:rows]

@@ -348,6 +348,7 @@ class PromptExpander:
         steps = 0
         decoded_from = len(routed)    # the executable calls that decode
         reads = []        # per decode call of several sequences
+        unread = []       # such a call's routed sums that read nothing
         zeros = []        # per decode call: picks on zero-compute experts
 
         def fetch(out) -> None:
@@ -374,7 +375,8 @@ class PromptExpander:
                 zeros.append(read.pop())
             if looped:      # the last of what a looped model returns
                 exits.append(read.pop())
-            reads += read
+            reads += read[:1]
+            unread += read[1:]
             pending.append(out)
             if meanwhile is not None:
                 # the device has a chunk to run and this thread nothing to
@@ -396,14 +398,19 @@ class PromptExpander:
         def account() -> None:
             with obs_spans.span("expand.account",
                                 fetched=2 * len(routed) + len(reads)
-                                + 2 * len(exits) + len(zeros)):
+                                + len(unread) + 2 * len(exits)
+                                + len(zeros)):
                 loads, none_held = zip(*jax.device_get(routed))
                 # a step of one token reads as many experts as it has picks
                 # held; a step of several the distinct ones, counted beside
                 # the load on the device
                 held_picks = int(np.sum(loads[decoded_from:]))
-                read = np.sum(jax.device_get(reads)) if reads \
-                    else held_picks
+                if reads:
+                    read, none_read = map(np.sum,
+                                          jax.device_get((reads, unread)))
+                else:   # a step of one token: its tokens with no held pick
+                    read = held_picks
+                    none_read = np.sum(none_held[decoded_from:])
                 EXPANDER.record(
                     prefilled=len(user) + (0 if held else len(prefix)),
                     from_prefix=held, sequences=live,
@@ -426,6 +433,8 @@ class PromptExpander:
                     zero_expert_picks=int(np.sum(jax.device_get(zeros)))
                     if zeros else 0,
                     expert_picks_held=held_picks,
+                    expert_calls=steps * len(self.config.expert_layers),
+                    expert_calls_unread=int(none_read),
                     **self._passes_run(exits, steps))
 
         if later is None:

@@ -664,6 +664,12 @@ class ExpanderStats:
     steps' rows that count which fell on an expert held here (the sum of
     the load those steps counted on the device; over ``experts_read``: the
     rows an expert's kernels serve once streamed, 1 at one sequence).
+    ``expert_calls`` counts the routed sums the decode steps made (one an
+    expert layer a step: on the chip one call of ops/moe_kernel.py each)
+    and ``expert_calls_unread`` those whose rows chose no expert held
+    here, so that the call read nothing of the experts' kernels (counted
+    on the device: ``tokens_no_held_expert`` of a step of one sequence,
+    the steps that streamed no expert of one of several).
     Of a looped model (``LMConfig.total_ut_steps`` over 1): ``layer_passes``,
     the passes of the whole stack its decode steps ran (every pass of every
     step, whichever the head read; over ``decode_steps``: passes a token); ``exit_pass``, the tokens made by the
@@ -691,6 +697,8 @@ class ExpanderStats:
             self.rows_read = 0         # guarded-by: _lock
             self.rows_read_shared = 0  # guarded-by: _lock
             self.none_held = 0         # guarded-by: _lock
+            self.expert_calls = 0      # guarded-by: _lock
+            self.expert_calls_unread = 0  # guarded-by: _lock
             #: per expert layer, tokens sent to each held expert
             self.load: List[List[int]] = []  # guarded-by: _lock
             self.positions: Dict[str, int] = {}  # guarded-by: _lock
@@ -827,7 +835,8 @@ class ExpanderStats:
                layer_passes: int = 0, exit_pass=(),
                exit_lambda_max: float = 0.0, state_bytes_stepped: int = 0,
                fork_bytes_copied: int = 0, zero_expert_picks: int = 0,
-               expert_picks_held: int = 0) -> None:
+               expert_picks_held: int = 0, expert_calls: int = 0,
+               expert_calls_unread: int = 0) -> None:
         """``load`` is (expert layers, held experts) counts of one
         request; ``decode_steps`` the steps its decode executables ran
         (whole chunks, so at least ``decoded / sequences - 1``), each a
@@ -861,6 +870,8 @@ class ExpanderStats:
             self.fork_bytes_copied += int(fork_bytes_copied)
             self.zero_picks += int(zero_expert_picks)
             self.picks_held += int(expert_picks_held)
+            self.expert_calls += int(expert_calls)
+            self.expert_calls_unread += int(expert_calls_unread)
             if len(exit_pass):
                 old = self.exit_pass or [0] * len(exit_pass)
                 self.exit_pass = [a + int(b)
@@ -913,6 +924,8 @@ class ExpanderStats:
                 "zero_expert_picks": self.zero_picks,
                 "tied_head": dict(self.tied_heads),
                 "expert_picks_held": self.picks_held,
+                "expert_calls": self.expert_calls,
+                "expert_calls_unread": self.expert_calls_unread,
                 "layer_passes": self.layer_passes,
                 "exit_pass": list(self.exit_pass),
                 "exit_lambda_max": self.exit_lambda_max,

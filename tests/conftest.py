@@ -134,6 +134,34 @@ def _a_compile_cache_goes_with_the_test_that_placed_it():
         compilation_cache.reset_cache()
 
 
+#: memory maps of the process past which a test file's end lets jax's
+#: compiled programs go: the kernel gives a process 65 530
+#: (``vm.max_map_count``) and XLA:CPU's next compile dies at the limit
+MAPS_TO_RELEASE_AT = 30_000
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _compiled_programs_go_before_the_maps_run_out():
+    """Every program jax compiles here stays for the life of the worker,
+    about a dozen memory maps each; a worker that draws the files that
+    compile a program a case (interpret-mode kernels above all) stood at
+    63 896 of the 65 530 a process may hold, and past them XLA's next
+    compile is a segmentation fault in whatever test runs then (PR 70:
+    two whole runs). At a file's end, a worker over
+    :data:`MAPS_TO_RELEASE_AT` clears jax's caches: the next file
+    compiles what it needs again, as it would in a worker of its own."""
+    yield
+    try:
+        with open("/proc/self/maps") as fh:
+            maps = sum(1 for _ in fh)
+    except OSError:
+        return
+    if maps > MAPS_TO_RELEASE_AT:
+        import jax
+
+        jax.clear_caches()
+
+
 @pytest.fixture(scope="session")
 def devices():
     import jax

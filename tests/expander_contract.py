@@ -288,7 +288,7 @@ def forked_against_alone(case, params, live, batch, own_slots=2 * STEPS,
         for mine, theirs in zip(cache.get(name, ()), forked.get(shared, ())):
             assert np.array_equal(np.asarray(mine), np.asarray(theirs))
     assert int(forked[lm.FORKED_AT][0][0, 0]) == length
-    own, total, none, rests = [], 0, 0, []
+    own, total, none, rests, nones = [], 0, 0, [], []
     for b in range(live):
         assert int(first[b]) == int(lm.sample(
             row, own_keys[b], length, jnp.float32(1.0), cfg.vocab[0]))
@@ -301,8 +301,16 @@ def forked_against_alone(case, params, live, batch, own_slots=2 * STEPS,
         assert_own_rows(after, forked, b, length, STEPS, case.rows_tolerance)
         own.append(after)
         total, none = total + own_load, none + own_none
+        nones.append(np.asarray(own_none))
     assert len({tuple(np.asarray(made[:, b])) for b in range(live)}) == live
     if cfg.expert_layers:
+        # the steps in which NO row chose a held expert: each sequence
+        # found none in those at least, and one sequence's are its own
+        unread, *rest = rest
+        assert unread.shape == none_held.shape and unread.dtype == jnp.int32
+        assert np.all(unread <= np.min(nones, axis=0))
+        if live == 1:
+            assert np.array_equal(unread, none_held)
         assert np.array_equal(load, total)      # the pad is not counted
         assert np.array_equal(none_held, none)
         if cfg.experts[1] == cfg.num_experts:

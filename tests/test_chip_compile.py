@@ -360,9 +360,9 @@ def test_a_two_row_skip_product_is_written_in_the_storage_dtype(
                                  (2048, 1536), (2304, 896), (2048, 768)])
 def test_chosen_experts_kernel_compiles_for_v5e(one_chip, d, f, precision):
     """The six published expert shapes (Laguna-S-2.1's, Qwen3-Next's,
-    Xing4.0's, LFM2's, the widest: two tiles of 768 an expert,
-    Mellum2's, whose blocks are the largest: one tile of 896, 24.8 MB
-    double-buffered, and kanana-2's, one tile of 768), 128 held, 10 chosen,
+    Xing4.0's, LFM2's, the widest: two blocks of 768 an expert,
+    Mellum2's, whose blocks are the largest: one of 896, 24.8 MB in the
+    ring's two slots, and kanana-2's, one of 768), 128 held, 10 chosen,
     bf16; also under benchmarks/verify_reference.py's
     ``default_matmul_precision("highest")``, which must not reach the
     kernel's dots."""
@@ -382,15 +382,15 @@ def test_chosen_experts_kernel_compiles_for_v5e(one_chip, d, f, precision):
 @pytest.mark.parametrize("rows", [1, 4])
 def test_clamped_experts_kernel_compiles_at_the_seventh_shape(one_chip,
                                                               rows):
-    """GigaChat3.5's 7168 x 2048 (the seventh published shape: an ``f``
-    tile of 256, the first under 512; 16 held, 8 a token), its SwiGLU
-    clamped at 10 inside the tile body, at one row and at a block of four
-    (16 grid slots: every held expert may be chosen)."""
+    """GigaChat3.5's 7168 x 2048 (the seventh published shape: rows over
+    4 096, so sixteen blocks of one lane width an expert; 16 held, 8 a
+    token), its SwiGLU clamped at 10 inside the block's body, at one row
+    and at a block of four (16 slots: every held expert may be chosen)."""
     def on_chip(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     d, f, held = 7168, 2048, 16
-    assert moe_kernel.f_tile(d, f, 2) == 256
+    assert moe_kernel.f_tile(d, f, 2) == 128
     slots = min(rows * 8, held)
     wide = on_chip((held, d, f), jnp.bfloat16)
     weights = (slots,) if rows == 1 else (slots, rows)
@@ -407,7 +407,7 @@ def test_clamped_experts_kernel_compiles_at_the_seventh_shape(one_chip,
 @pytest.mark.parametrize("rows", [2, 4, 8])
 def test_block_of_rows_kernel_compiles_for_v5e(one_chip, rows, precision):
     """A decode step of 2, 4 or 8 sequences at Mellum2's shape (2304 x 896,
-    all 64 held, 8 a token): ``min(rows * 8, 64)`` grid steps of a whole
+    all 64 held, 8 a token): ``min(rows * 8, 64)`` slots of a whole
     expert, the rows padded to one bf16 sublane tile, a weight a slot and
     row in SMEM."""
     def on_chip(shape, dtype):
@@ -423,6 +423,42 @@ def test_block_of_rows_kernel_compiles_for_v5e(one_chip, rows, precision):
             on_chip((slots, rows), jnp.float32), on_chip((), jnp.int32),
             wide, wide, on_chip((held, f, d), jnp.bfloat16))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows", [1, 4])
+@pytest.mark.parametrize("d,f,held,k,limit", [
+    (3072, 1024, 128, 10, 0.0),     # Laguna-S-2.1
+    (2048, 512, 128, 10, 0.0),      # Qwen3-Next
+    (3584, 1024, 16, 4, 0.0),       # Xing4.0
+    (2048, 1536, 64, 4, 0.0),       # LFM2
+    (2304, 896, 64, 8, 0.0),        # Mellum2
+    (2048, 768, 128, 6, 0.0),       # kanana-2
+    (7168, 2048, 16, 8, 10.0),      # GigaChat3.5, clamped
+    (6144, 2048, 16, 12, 0.0),      # LongCat-Flash
+    (4096, 768, 36, 10, 0.0),       # granite-4.0-h-small
+])
+def test_the_ring_of_reads_compiles_at_the_nine_published_shapes(
+        one_chip, d, f, held, k, limit, rows):
+    """The nine cells' own calls (their experts held, their picks a token,
+    one row and the block of four): the experts' kernels stay in HBM, the
+    body copies their blocks into its ring of VMEM under DMA semaphores
+    and walks them in a loop whose trip count is the held count's, and
+    Mosaic takes the ring's bytes plus the slack as the call's VMEM."""
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    blocks = moe_kernel.ring(d, f, 2)
+    assert blocks.vmem_bytes(d, 2) + moe_kernel._VMEM_SLACK <= 28 * 2 ** 20
+    slots = k if rows == 1 else min(rows * k, held)
+    wide = on_chip((held, d, f), jnp.bfloat16)
+    text = _compiled_text(
+        lambda *a: moe_kernel.chosen_experts(*a, interpret=False,
+                                             limit=limit),
+        on_chip((rows, d), jnp.bfloat16), on_chip((slots,), jnp.int32),
+        on_chip((slots,) if rows == 1 else (slots, rows), jnp.float32),
+        on_chip((), jnp.int32), wide, wide,
+        on_chip((held, f, d), jnp.bfloat16))
+    assert text.count("tpu_custom_call") == 1
 
 
 @pytest.mark.parametrize("stored", [jnp.bfloat16, jnp.float32])

@@ -338,8 +338,10 @@ class TestTheShare:
         assert share.num_experts_per_tok == 4 and share.conv_taps == 3
         assert share.head_dim * share.num_heads_per_layer[2] \
             == share.hidden_size
-        # the fourth published shape the pipelined kernel tiles
+        # the fourth published shape the expert kernel tiles: two blocks
+        # an expert
         assert moe_kernel.f_tile(2048, 1536, 2) == 768
+        assert moe_kernel.ring(2048, 1536, 2) == moe_kernel.Ring(2, 768, 2)
         assert moe.choose("tpu", 1, jnp.bfloat16, 2048, 1536) == moe.KERNEL
 
     def test_the_share_has_5401_million_parameters(self):

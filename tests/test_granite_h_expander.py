@@ -425,6 +425,7 @@ class TestTheSharesAddUp:
         four rows of ten picks over 36 held experts fills every grid
         slot."""
         assert moe_kernel.f_tile(4096, 768, 2) == 384
+        assert moe_kernel.ring(4096, 768, 2) == moe_kernel.Ring(2, 384, 2)
         assert 2 * 3 * 4096 * 384 * 2 <= 24 * 2 ** 20 < 2 * 3 * 4096 * 768 * 2
         assert route_kernel.slots_of(4, 10, 36) == 36
         assert route_kernel.slots_of(1, 10, 36) == 10
@@ -752,12 +753,13 @@ class TestThePublishedShare:
 #: by sha256 prefix, at the parent commit (PR 68's tree): the ten before it
 #: are held by tests/test_kanana_expander.py's and
 #: tests/test_longcat_flash_expander.py's own tables, which this PR leaves
-#: as they were
+#: as they were (PR 70 replaced the forked decode chunk's: it returns the
+#: steps that streamed no expert, one more carry of the scan)
 PARENT = {
     "TINY_LONGCAT_FLASH_EXPAND": {
         "prefill": "49fedc4c34157e08", "decode": "23209b30bf115ee9",
         "prefill4": "a497011a1374bba2", "fork": "e6dc91369fb72543",
-        "decode4": "fdb1a9650c1fd19d"},
+        "decode4": "bc4d65b54e660ae8"},
 }
 
 
@@ -796,4 +798,4 @@ def test_the_new_presets_executables_have_no_head_product_of_their_own():
         jax.eval_shape(lambda: jax.random.split(jax.random.key(0), 4)),
         jax.ShapeDtypeStruct((), jnp.float32),
         jax.ShapeDtypeStruct((), jnp.int32))
-    assert len(out) == 7
+    assert len(out) == 8    # the unread calls behind the experts read

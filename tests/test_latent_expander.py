@@ -618,8 +618,10 @@ class TestTheShareOfALayer:
         assert share.latent_width == 576 and share.residual_streams == 4
         last = configs.lm_share(configs.XING4_0_29B_A4B, 20, 4, 3)
         assert last.experts == (48, 16) and last.vocab == (98304, 32768)
-        # the third published shape the pipelined kernel tiles
+        # the third published shape the expert kernel tiles: two blocks
+        # an expert through the ring's two slots
         assert moe_kernel.f_tile(3584, 1024, 2) == 512
+        assert moe_kernel.ring(3584, 1024, 2) == moe_kernel.Ring(2, 512, 2)
         assert moe.choose("tpu", 1, jnp.bfloat16, 3584, 1024) == moe.KERNEL
 
     def test_the_share_has_4389_million_parameters(self):

@@ -301,6 +301,64 @@ class TestExperts:
         assert moe.row_tile(64, 10, 256) == 8
 
 
+#: (f, the widest block the ring's VMEM admits, the blocks of an expert:
+#: ``wide`` x ``wides``) at d 128 in float32, where one lane width of an
+#: expert's three slabs is 192 KiB
+RINGS = {
+    "one_block": (256, 256, (256, 1)),
+    "two_blocks": (256, 128, (128, 2)),
+    "eight_blocks": (1024, 128, (128, 8)),
+    "three_blocks": (768, 256, (256, 3)),
+}
+
+
+def _ring(monkeypatch, name, d=128, itemsize=4):
+    """The ring's VMEM set so that ``RINGS[name]`` is what a call of these
+    widths walks; the width ``f``."""
+    f, widest, blocks = RINGS[name]
+    buffers = moe_kernel._RING_BUFFERS
+    monkeypatch.setattr(moe_kernel, "_WEIGHT_VMEM",
+                        buffers * 3 * d * widest * itemsize)
+    assert moe_kernel.ring(d, f, itemsize) == moe_kernel.Ring(buffers,
+                                                              *blocks)
+    return f
+
+
+@pytest.fixture(scope="class")
+def programs_released():
+    """The interpreter compiles a program a case, and jax keeps each for
+    the life of the process: a dozen memory maps a program, 30 000 by the
+    end of this file's kernel classes, of the 65 530 the kernel gives a
+    process. A worker that had run this file beside others came to the
+    limit and XLA's next compile died there (a segmentation fault in
+    another file's test). The kernel classes let theirs go."""
+    yield
+    jax.clear_caches()
+
+
+@pytest.fixture
+def copies_started(monkeypatch):
+    """The ``dma_start``s a kernel call EXECUTES in interpret mode, one
+    entry each: the rule that discharges a start to a plain copy also says
+    so to the host, from inside whatever ``cond`` or ``while`` holds it."""
+    from jax._src.pallas.mosaic import primitives as mosaic
+    from jax._src.state import discharge
+
+    started = []
+    rule = discharge._partial_discharge_rules[mosaic.dma_start_p]
+
+    def counting(*args, **kwargs):
+        jax.debug.callback(lambda: started.append(1))
+        return rule(*args, **kwargs)
+
+    monkeypatch.setitem(discharge._partial_discharge_rules,
+                        mosaic.dma_start_p, counting)
+    moe_kernel._call.clear_cache()      # nothing traced without the count
+    yield started
+    moe_kernel._call.clear_cache()
+
+
+@pytest.mark.usefixtures("programs_released")
 class TestTheChosenExpertsKernel:
     """ops/moe_kernel.py in interpret mode against ``moe._chosen``'s loop,
     at small widths on the lane tiling. The token chose experts 9, 2, 12
@@ -308,9 +366,9 @@ class TestTheChosenExpertsKernel:
 
     D, F, EXPERTS = 128, 256, 16
 
-    def _layer(self, dtype, seed=3):
+    def _layer(self, dtype, seed=3, f=None):
         ks = jax.random.split(jax.random.key(seed), 4)
-        d, f, e = self.D, self.F, self.EXPERTS
+        d, f, e = self.D, f or self.F, self.EXPERTS
         x = jax.random.normal(ks[0], (1, d)).astype(dtype)
         wg = (jax.random.normal(ks[1], (e, d, f)) / d ** 0.5).astype(dtype)
         wu = (jax.random.normal(ks[2], (e, d, f)) / d ** 0.5).astype(dtype)
@@ -319,27 +377,66 @@ class TestTheChosenExpertsKernel:
                               jnp.array([[0.9, 0.7, 0.5, 0.4]], jnp.float32))
         return x, routing, wg, wu, wd
 
-    @pytest.mark.parametrize("tile", [256, 128])
+    @pytest.mark.parametrize("blocks", list(RINGS))
     @pytest.mark.parametrize("first,count,held", [
         (13, 3, 0), (8, 2, 1), (4, 8, 2), (2, 11, 4), (0, 16, 4)])
     def test_the_kernel_equals_the_loop(self, monkeypatch, first, count,
-                                        held, tile):
+                                        held, blocks):
         """None, one, some and all of the chosen experts held, shares that
-        start past expert 0, a whole expert a block and two tiles of f."""
-        x, routing, wg, wu, wd = self._layer(jnp.float32)
+        start past expert 0; a whole expert a block, two, three and eight
+        blocks an expert (an odd count: the ring's slots alternate across
+        the experts' boundaries)."""
+        f = _ring(monkeypatch, blocks)
+        x, routing, wg, wu, wd = self._layer(jnp.float32, f=f)
         share = [w[first:first + count] for w in (wg, wu, wd)]
         assert int(moe.held_mask(routing.experts[0], first,
                                  count)[1].sum()) == held
-        # six blocks of a whole expert are 768 KiB: under that, f is cut
-        monkeypatch.setattr(moe_kernel, "_WEIGHT_VMEM",
-                            (768 if tile == 256 else 400) * 1024)
-        assert moe_kernel.f_tile(self.D, self.F, 4) == tile
         want = moe._chosen(x, routing, *share, first)
         got = moe._chosen(x, routing, *share, first, kernel=True)
         assert got.shape == (1, self.D) and got.dtype == jnp.float32
         if held == 0:
             assert not np.any(np.asarray(got))
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("blocks", ["one_block", "three_blocks"])
+    @pytest.mark.parametrize("rows", [1, 4])
+    @pytest.mark.parametrize("held", [0, 1, 2, 3, 4])
+    def test_a_call_reads_its_held_experts_blocks_and_nothing_else(
+            self, monkeypatch, copies_started, held, rows, blocks):
+        """``held x blocks`` reads of three copies each, whatever the slots: a
+        call that holds nothing starts NO copy and returns exact zeros (the
+        ``BlockSpec`` pipeline this replaced fetched its first step's
+        blocks before it could know: ``max(held, 1) x blocks`` less
+        repeats). The ids behind the held ones are never looked at: they
+        point past the share."""
+        f = _ring(monkeypatch, blocks)
+        x, _, wg, wu, wd = self._layer(jnp.float32, f=f)
+        x = jnp.tile(x, (rows, 1))
+        ids = jnp.where(jnp.arange(4) < held, jnp.array([3, 0, 2, 1]), 99)
+        weights = jnp.full((4,) if rows == 1 else (4, rows), 0.5)
+        got = moe_kernel.chosen_experts(x, ids, weights, jnp.int32(held),
+                                        wg[:4], wu[:4], wd[:4])
+        jax.block_until_ready(got)
+        jax.effects_barrier()
+        blocks = moe_kernel.ring(self.D, f, 4)
+        assert len(copies_started) == 3 * held * blocks.wides
+        want = sum(0.5 * moe._swiglu(x, wg[e], wu[e], wd[e])
+                   for e in [3, 0, 2, 1][:held]) if held else 0.0 * x
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        if not held:
+            assert not np.any(np.asarray(got))
+
+    @pytest.mark.parametrize("limit", [0.0, 0.25])
+    def test_the_clamp_is_a_blocks_as_it_is_the_wholes(self, monkeypatch,
+                                                       limit):
+        f = _ring(monkeypatch, "three_blocks")
+        x, routing, wg, wu, wd = self._layer(jnp.float32, f=f)
+        want = moe._chosen(x, routing, wg, wu, wd, 0, limit=limit)
+        got = moe._chosen(x, routing, wg, wu, wd, 0, kernel=True,
+                          limit=limit)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        unclamped = moe._chosen(x, routing, wg, wu, wd, 0)
+        assert bool(jnp.allclose(want, unclamped)) == (not limit)
 
     @pytest.mark.parametrize("precision", [None, "highest"])
     def test_bf16_operands_accumulate_in_float32(self, precision):
@@ -351,16 +448,46 @@ class TestTheChosenExpertsKernel:
             got = moe._chosen(x, routing, wg, wu, wd, 0, kernel=True)
         np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
 
-    @pytest.mark.parametrize("d,f,itemsize,tile", [
-        (3072, 1024, 2, 512),     # Laguna-S-2.1's experts: two tiles
-        (2048, 512, 2, 512),      # Qwen3-Next's: a whole expert a block
-        (128, 256, 4, 256),
+    @pytest.mark.parametrize("d,f,itemsize,blocks", [
+        (3072, 1024, 2, (512, 2)),  # Laguna-S-2.1's experts: two halves
+        (2048, 512, 2, (512, 1)),   # Qwen3-Next's: a whole expert a block
+        (128, 256, 4, (256, 1)),
         (32, 16, 4, None),        # the tiny presets: off the lanes
         (3072, 1000, 2, None),
-        (65536, 128, 2, None),    # on the lanes, and one tile is too much
+        (65536, 128, 2, None),    # on the lanes, and one block is too much
     ])
-    def test_the_tile_comes_from_the_shape(self, d, f, itemsize, tile):
-        assert moe_kernel.f_tile(d, f, itemsize) == tile
+    def test_the_blocks_come_from_the_shape(self, d, f, itemsize, blocks):
+        got = moe_kernel.ring(d, f, itemsize)
+        assert got == (blocks and moe_kernel.Ring(2, *blocks))
+        assert moe_kernel.f_tile(d, f, itemsize) == (blocks and got.wide)
+
+    @pytest.mark.parametrize("d,f,blocks", [
+        (3072, 1024, (512, 2)),     # Laguna-S-2.1
+        (2048, 512, (512, 1)),      # Qwen3-Next
+        (3584, 1024, (512, 2)),     # Xing4.0
+        (2048, 1536, (768, 2)),     # LFM2
+        (2304, 896, (896, 1)),      # Mellum2
+        (2048, 768, (768, 1)),      # kanana-2
+        (7168, 2048, (128, 16)),    # GigaChat3.5: long rows
+        (6144, 2048, (128, 16)),    # LongCat-Flash: long rows
+        (4096, 768, (384, 2)),      # granite-4.0-h-small
+    ])
+    def test_the_ring_fits_the_vmem_the_call_had(self, d, f, blocks):
+        """The nine published shapes in bf16: an expert's blocks cover
+        ``f`` on the lanes, the widest that fit (one lane width over 4 096
+        rows), and the ring's two slots stay under the 24 MiB the two
+        buffers a weight had, 28 with the slack: what XLA keeps for the
+        Linears' prefetched slices is untouched."""
+        got = moe_kernel.ring(d, f, 2)
+        assert got == moe_kernel.Ring(2, *blocks)
+        assert got.wide * got.wides == f and not got.wide % 128
+        assert got.vmem_bytes(d, 2) <= moe_kernel._WEIGHT_VMEM == 24 * 2 ** 20
+        assert (moe_kernel._WEIGHT_VMEM + moe_kernel._VMEM_SLACK
+                == 28 * 2 ** 20)
+        assert moe_kernel._COST_EXPERTS == 3
+        wider = next((w for w in range(got.wide + 128, f + 1, 128)
+                      if f % w == 0), None)
+        assert d > 4096 or wider is None or 2 * 3 * d * wider * 2 > 24 * 2 ** 20
 
     def test_a_width_that_does_not_tile_is_refused(self):
         x, routing, wg, wu, wd = self._layer(jnp.float32)
@@ -443,6 +570,7 @@ class TestTheChosenExpertsKernel:
         assert moe.row_tile(100000, 10, 256) == 256
 
 
+@pytest.mark.usefixtures("programs_released")
 class TestTheBlockOfRowsKernel:
     """ops/moe_kernel.py at 2-8 rows in interpret mode: a step's distinct
     held experts, each taking the whole block of rows under a per-row
@@ -491,11 +619,16 @@ class TestTheBlockOfRowsKernel:
                         x[r:r + 1], *(k[e] for k in share)))[0]
         return out
 
+    @pytest.mark.parametrize("blocks", ["one_block", "three_blocks"])
     @pytest.mark.parametrize("case", ["one_expert", "all_distinct",
                                       "one_row_none_held", "none_held"])
     @pytest.mark.parametrize("rows", [2, 4, 8])
     def test_the_block_equals_the_grouped_product_and_a_sum_a_row(
-            self, rows, case):
+            self, monkeypatch, rows, case, blocks):
+        """One expert a call and many (two rows' four picks to eight rows'
+        eight held of sixteen), a whole expert a block and three blocks
+        with a narrower last one."""
+        self.F = _ring(monkeypatch, blocks)
         share = self._share(self._kernels())
         x = jax.random.normal(jax.random.key(rows + 10), (rows, self.D))
         routing = self._routing(rows, case)
@@ -521,9 +654,13 @@ class TestTheBlockOfRowsKernel:
             got = moe._block(x, routing, *share, self.FIRST)
         np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
 
-    def test_a_row_gets_nothing_from_an_expert_it_did_not_choose(self):
-        """Expert 9's product overflows for every row of the block; only
-        the rows that chose it may see that: selected out, not ``0 * inf``."""
+    @pytest.mark.parametrize("blocks", ["one_block", "three_blocks"])
+    def test_a_row_gets_nothing_from_an_expert_it_did_not_choose(
+            self, monkeypatch, blocks):
+        """Expert 9's product overflows for every row of the block, in
+        every block of its width; only the rows that chose it may see
+        that: selected out, not ``0 * inf``."""
+        self.F = _ring(monkeypatch, blocks)
         wg, wu, wd = self._kernels()
         wd = wd.at[9].set(jnp.inf)
         share = self._share((wg, wu, wd))
@@ -541,12 +678,12 @@ class TestTheBlockOfRowsKernel:
     @pytest.mark.parametrize("rows,k,held,slots", [
         (2, 8, 64, 16), (4, 8, 64, 32), (8, 8, 64, 64), (8, 10, 64, 64),
         (4, 4, 8, 8)])
-    def test_the_grid_is_the_most_distinct_experts_a_step_can_choose(
+    def test_the_slots_are_the_most_distinct_experts_a_step_can_choose(
             self, rows, k, held, slots):
-        """``min(rows * k, held)`` grid steps, the block padded to the bf16
-        sublane tile, and the one-row call's cost estimate (three reads:
-        ``moe_kernel._call`` says why not the reads the shapes let
-        expect)."""
+        """``min(rows * k, held)`` slots the ring may walk in its one grid
+        step, the block padded to the bf16 sublane tile, and the one-row
+        call's cost estimate (three reads: ``moe_kernel._call`` says why
+        not the reads the shapes let expect)."""
         d, f = 128, 256
         s = jax.ShapeDtypeStruct
         wide = s((held, d, f), jnp.bfloat16)
@@ -562,15 +699,16 @@ class TestTheBlockOfRowsKernel:
                                         (moe_kernel.ROW_BLOCK, d))
         (kernel,) = [e for e in call.params["jaxpr"].eqns
                      if e.primitive.name == "pallas_call"]
-        assert kernel.params["grid_mapping"].grid == (slots, 1)
+        assert kernel.params["grid_mapping"].grid == (1,)
         cost = kernel.params["cost_estimate"]
         assert (cost.flops, cost.transcendentals, cost.bytes_accessed) == (
             18 * d * f, 3 * f, 18 * d * f)
 
     def test_one_row_keeps_the_kernel_it_had(self):
         """The body is one for every row count, and one row's trace must
-        stay what it was: a scalar weight a slot, ``k`` grid steps, three
-        reads claimed, and no select on a weight column."""
+        stay what it was: a scalar weight a slot, three reads claimed, no
+        pad, and no select on a weight column; the experts' kernels stay
+        in HBM and a ring of two slots of VMEM takes their blocks."""
         s = jax.ShapeDtypeStruct
         d, f, k = 128, 256, 4
         wide = s((16, d, f), jnp.bfloat16)
@@ -585,7 +723,12 @@ class TestTheBlockOfRowsKernel:
             "reshape", "jit"]               # no pad, no slice of the result
         (kernel,) = [e for e in call.params["jaxpr"].eqns
                      if e.primitive.name == "pallas_call"]
-        assert kernel.params["grid_mapping"].grid == (k, 1)
+        mapping = kernel.params["grid_mapping"]
+        assert mapping.grid == (1,)
+        assert [str(b.block_aval.memory_space) for b in
+                mapping.block_mappings[1:4]] == ["any"] * 3
+        assert [tuple(a.shape) for a in mapping.scratch_avals] == [
+            (2, d, f), (2, d, f), (2, f, d), (3, 2)]
         cost = kernel.params["cost_estimate"]
         assert (cost.flops, cost.transcendentals, cost.bytes_accessed) == (
             18 * d * f, 3 * f, 18 * d * f)
@@ -599,6 +742,115 @@ class TestTheBlockOfRowsKernel:
         body = list(names(kernel.params["jaxpr"]))
         assert "select_n" not in body and "iota" not in body
         assert body.count("dot_general") == 3 and body.count("swap") == 2
+        # the first read before the loop, the one that keeps the ring
+        # full and the wait inside it: three copies each
+        assert body.count("dma_start") == 6 and body.count("dma_wait") == 3
+        assert body.count("while") == 1
+
+
+@pytest.mark.usefixtures("programs_released")
+class TestCallsThatReadNothing:
+    """``serving.expander`` ``expert_calls`` / ``expert_calls_unread``:
+    the expert kernel's calls of the decode steps, and those whose rows
+    chose no held expert (ops/moe_kernel.py reads nothing for them)."""
+
+    #: the tiny expander at widths the kernel tiles, holding experts 12-15
+    #: of 16: four picks a row often miss all four
+    CFG = dataclasses.replace(
+        configs.TINY_EXPAND.expander, hidden_size=128,
+        moe_intermediate_size=128, experts_held=(12, 4))
+    STEPS = 6
+
+    def _decode(self, steps, cache, tokens, position):
+        fn = lm.decode_sequences_fn(lm.DecoderLM(self.CFG), steps)
+        return fn(contract.lm_params(self.CFG), cache, tokens, position,
+                  contract.keys([0, 1]), jnp.float32(1.0), jnp.int32(2))
+
+    def test_a_step_of_several_sequences_counts_them_on_the_device(
+            self, monkeypatch):
+        """The executable returns, behind the experts read, the steps in
+        which a layer's rows streamed none: what a step at a time counts
+        from each step's own experts read, and the same through the chain
+        and through the kernels (held to them here: both in the
+        interpreter), whose call then started no copy."""
+        layers = len(self.CFG.expert_layers)
+        cache = kv.fork(lm.empty_cache(self.CFG, 32, jnp.float32), 2, 16)
+        first = jnp.array([3, 4], jnp.int32)
+        plain = self._decode(self.STEPS, cache, first, jnp.int32(0))
+        monkeypatch.setattr(moe, "choose", lambda *a, **kw: moe.KERNEL)
+        got = self._decode(self.STEPS, cache, first, jnp.int32(0))
+        assert len(got) == len(plain) == 8
+        for ours, theirs in zip(got[3:], plain[3:]):    # tokens, counts
+            np.testing.assert_array_equal(ours, theirs)
+        unread = np.asarray(got[7])
+        assert unread.shape == (layers,) and unread.dtype == np.int32
+        want, tokens, position = np.zeros(layers, int), first, jnp.int32(0)
+        for _ in range(self.STEPS):
+            cache, tokens, position, _, _, _, read, one = self._decode(
+                1, cache, tokens, position)
+            np.testing.assert_array_equal(np.asarray(one),
+                                          np.asarray(read) == 0)
+            want += np.asarray(one)
+        np.testing.assert_array_equal(unread, want)
+        assert 0 < unread.sum() < layers * self.STEPS
+
+    def test_the_counters_and_the_layer_metric(self):
+        """benchmarks/layer_metrics/expert_calls_unread_share.json through
+        the harness's own loader and reader, its entry in BENCHMARK.json,
+        and the Prometheus twins."""
+        from benchmarks.harness import files
+        from stable_diffusion_webui_distributed_tpu.obs import prometheus
+
+        bench = files.Bench(contract.ROOT)
+        spec = bench.layer_metric("expert_calls_unread_share")
+        reader = bench.load("readers", spec["reader"])
+        assert (spec["reader"], spec["args"]["scale"]) == ("status_ratio",
+                                                           100)
+
+        def one_request(**calls):
+            EXPANDER.record(
+                prefilled=0, from_prefix=0, sequences=1, decoded=384,
+                decode_steps=384, experts_read=9000, load=[[1, 2]],
+                none_held=2115, positions={}, state_bytes={},
+                prefix_snapshots=0, padded_rows_masked=0,
+                residual_streams=1, sinkhorn_iters=0, **calls)
+            return {"serving": METRICS.summary()}
+
+        EXPANDER.clear()
+        before = {"serving": METRICS.summary()}
+        assert (before["serving"]["expander"]["expert_calls"],
+                before["serving"]["expander"]["expert_calls_unread"]) == (
+                    0, 0)
+        after = one_request(expert_calls=6912, expert_calls_unread=2115)
+        status = {"status_before": before, "status_after": after}
+        assert reader.read(status, **spec["args"]) == pytest.approx(
+            100 * 2115 / 6912)
+        text = prometheus.render()
+        assert "sdtpu_expander_expert_calls_total 6912" in text
+        assert "sdtpu_expander_expert_calls_unread_total 2115" in text
+        # every expert held: the calls read, and the share is 0, not nothing
+        held = one_request(expert_calls=3072)
+        status = {"status_before": after, "status_after": held}
+        assert reader.read(status, **spec["args"]) == 0.0
+        # no decode step in the window, and the parent's block, which has
+        # no such counters: nothing to read
+        status = {"status_before": held, "status_after": one_request()}
+        assert reader.read(status, **spec["args"]) is None
+        for block in status.values():
+            del block["serving"]["expander"]["expert_calls"]
+        assert reader.read(status, **spec["args"]) is None
+        EXPANDER.clear()
+        entry = next(m for m in bench.manifest["per_layer"]
+                     if m["name"] == "expert_calls_unread_share")
+        assert {key: entry[key] for key in entry if key != "workloads"} == {
+            key: spec[key] for key in (
+                "name", "unit", "better", "source", "layer", "moves")}
+        assert (entry["layer"], entry["moves"], entry["unit"]) == (
+            "kernels", "request_p50_s", "%")
+        kernel_cells = next(m for m in bench.manifest["per_layer"]
+                            if m["name"] == "expert_kernel_sites")
+        assert entry["workloads"] == kernel_cells["workloads"]
+        assert len(entry["workloads"]) == 9
 
 
 class TestTheShareOfALayer:
@@ -951,7 +1203,13 @@ class TestEnginePath:
             "attention_unrotated", "write_strength_bound", "ssm_mixers",
             "joined_layers", "multipliers_applied", "moe_shortcuts",
             "latent_scaled", "zero_expert_picks", "tied_head",
-            "expert_picks_held"}
+            "expert_picks_held", "expert_calls", "expert_calls_unread"}
+        # a routed sum a layer a step, and those with no held pick are the
+        # one-sequence steps' tokens that had none
+        assert block["expert_calls"] == block["decode_steps"] * len(
+            CFG.expert_layers)
+        assert 0 <= block["expert_calls_unread"] <= min(
+            block["tokens_no_held_expert"], block["expert_calls"])
         # a model of one pass leaves the looped model's counters alone
         assert (block["layer_passes"], block["exit_pass"],
                 block["exit_lambda_max"]) == (0, [], 0.0)
