@@ -9,7 +9,9 @@ Four fixed-ladder latency histograms give real p50/p95/p99 where
 - ``sdtpu_device_dispatch_seconds`` — denoise-chunk device time
   (fed from ``StageStats.timer("denoise_chunk")`` via
   :func:`observe_stage`);
-- ``sdtpu_decode_seconds`` — VAE decode dispatch + fetch.
+- ``sdtpu_decode_seconds`` — VAE decode fetch (the wait and the copy down;
+  the executable's own seconds are ``sdtpu_device_busy_seconds_total``
+  ``{kind="decode_u8"}``).
 
 :func:`render` additionally exposes every ``DispatchMetrics`` and
 ``StageStats`` scalar plus the live ETA mean-percent-error gauge
@@ -200,8 +202,8 @@ HISTOGRAMS: Dict[str, Histogram] = {
         "Denoise-chunk device dispatch latency (host-observed)."),
     "decode": Histogram(
         "sdtpu_decode_seconds",
-        "VAE decode latency (dispatch + fetch halves observed "
-        "separately)."),
+        "VAE decode fetch latency: the wait for the decode executable "
+        "and the copy down, an image."),
     "lora_apply": Histogram(
         "sdtpu_lora_apply_seconds",
         "LoRA adapter activation latency: traced factor-set builds "
@@ -217,7 +219,6 @@ HISTOGRAMS: Dict[str, Histogram] = {
 #: ``sdtpu_stage_seconds`` gauges).
 STAGE_TO_HIST: Dict[str, str] = {
     "denoise_chunk": "device_dispatch",
-    "vae_decode_dispatch": "decode",
     "vae_decode_fetch": "decode",
 }
 
@@ -257,6 +258,8 @@ def clear_histograms() -> None:
     COALESCE_WINDOW_COUNTER.clear()
     HOST_STALL_COUNTER.clear()
     GC_PAUSE_COUNTER.clear()
+    DEVICE_BUSY_COUNTER.clear()
+    DEVICE_DRY_COUNTER.clear()
     LORA_SWITCH_COUNTER.clear()
     AOT_COUNTER.clear()
     for c in WORKER_COUNTERS.values():
@@ -416,6 +419,19 @@ HOST_STALL_COUNTER = LabeledCounter(
 GC_PAUSE_COUNTER = LabeledCounter(
     "sdtpu_gc_pause_seconds_total",
     "Seconds of garbage collection, by generation.", ("generation",))
+
+#: The operator's "is my chip busy", with no profiler: seconds the device
+#: ran the requests' executables, by kind, and the enqueues that found it
+#: with nothing left to run (obs/spans.py feeds both at a request's end,
+#: where it feeds ``serving.device``).
+DEVICE_BUSY_COUNTER = LabeledCounter(
+    "sdtpu_device_busy_seconds_total",
+    "Seconds the device ran executables of the request path, by kind "
+    "(device.run spans).", ("kind",))
+DEVICE_DRY_COUNTER = LabeledCounter(
+    "sdtpu_device_dry_enqueues_total",
+    "Enqueues that found every earlier executable done: the device "
+    "waited for the host.", ())
 
 #: Adapter-set activations by serving mode: ``merged`` — host merge into
 #: the param tree (epoch bump, caches retired); ``traced`` — factor set
@@ -620,6 +636,14 @@ def count_host_stall(seconds: float) -> None:
 
 def count_gc_pause(generation: int, seconds: float) -> None:
     GC_PAUSE_COUNTER.inc(seconds, generation=generation)
+
+
+def count_device(busy_s: Dict[str, float], dry_enqueues: int) -> None:
+    """One finished request's ``device.run`` seconds by kind."""
+    for kind, seconds in busy_s.items():
+        DEVICE_BUSY_COUNTER.inc(seconds, kind=kind)
+    if dry_enqueues:
+        DEVICE_DRY_COUNTER.inc(dry_enqueues)
 
 
 def fleet_observe_queue_wait(cls: str, seconds: float) -> None:
@@ -988,6 +1012,8 @@ def render() -> str:
     lines.extend(COALESCE_WINDOW_COUNTER.render())
     lines.extend(HOST_STALL_COUNTER.render())
     lines.extend(GC_PAUSE_COUNTER.render())
+    lines.extend(DEVICE_BUSY_COUNTER.render())
+    lines.extend(DEVICE_DRY_COUNTER.render())
     lines.extend(LORA_SWITCH_COUNTER.render())
     lines.extend(AOT_COUNTER.render())
     for c in FLEET_COUNTERS.values():

@@ -23,10 +23,15 @@ that ends inside another context manager's body is opened and closed by hand
 (:func:`open_span` / :func:`close_span`). Intervals recorded after the fact
 (:func:`add_span`) exist only here, as do the stalls the host clock finds
 (obs/watchdog.py: ``host.stall``), which can also name the spans a request
-has OPEN. The store is bounded (``SDTPU_OBS_MAX_REQUESTS`` finished
-traces) and lock-disciplined: one lock, nothing external called while
-holding it. Export is Chrome trace-event JSON ("X" complete events with
-ph/ts/dur/pid/tid), loadable in Perfetto / ``chrome://tracing``.
+has OPEN. What the DEVICE did with each executable a request enqueued is
+in the same store, on the same clock, with no profiler (:func:`device_work`:
+a ``device.run`` span an executable, from where it could start to where
+its output was ready). The store is bounded (``SDTPU_OBS_MAX_REQUESTS``
+finished traces) and lock-disciplined: one lock for the trees and one for
+the device's queue, never both, nothing external called while holding
+either. Export is Chrome trace-event JSON ("X" complete events with
+ph/ts/dur/pid/tid; a ``device.run`` ran on no host thread and is a pair of
+async events, "b" and "e"), loadable in Perfetto / ``chrome://tracing``.
 """
 
 from __future__ import annotations
@@ -71,6 +76,14 @@ SLOW_MAX_CLASSES = 64
 #: tracing, not wall clock — Perfetto only needs a shared monotonic base).
 _EPOCH = time.perf_counter()
 _PID = os.getpid()
+#: the ``tid`` of a span that ran on the device, on no host thread
+DEVICE_TID = 0
+#: a parent's attrs that its ``device.run`` repeats: the work it stands for
+_WORK_ATTRS = ("steps", "tokens")
+#: the spans whose time less the ``device.run`` inside is the device's idle
+#: time in a request (``serving.device`` ``idle_s``): the dispatcher's
+#: device section, or for a caller of the engine itself its range
+_DEVICE_SECTIONS = ("dispatch.device", "generate_range")
 
 #: Process-wide span-id allocator. ``next()`` on itertools.count is atomic
 #: under the GIL, so ids are unique without touching the tracer lock.
@@ -129,7 +142,8 @@ class RequestTrace:
     """All spans of one request plus its terminal status."""
 
     __slots__ = ("request_id", "name", "attrs", "t0", "dur", "status",
-                 "detail", "spans", "root_id", "open", "live")
+                 "detail", "spans", "root_id", "open", "live", "works",
+                 "watched", "fences")
 
     def __init__(self, request_id: str, name: str,
                  attrs: Dict[str, Any]) -> None:
@@ -148,6 +162,13 @@ class RequestTrace:
         #: sample of a request alive past the slow rule's ratio in ``live``
         self.open: Dict[int, Span] = {}
         self.live: Optional[Dict[str, Any]] = None
+        #: the executables it enqueued, in order (:func:`device_work`;
+        #: appended on the thread that enqueues), whether the device
+        #: watcher follows them (the slow rule's doing), and its fences:
+        #: [all, those the host reached after the device]
+        self.works: List[DeviceWork] = []
+        self.watched = False
+        self.fences = [0, 0]
 
 
 def _span_event(req: RequestTrace, sp: Span) -> Dict[str, Any]:
@@ -168,6 +189,24 @@ def _span_event(req: RequestTrace, sp: Span) -> Dict[str, Any]:
         "dur": sp.dur * 1e6,
         "args": args,
     }
+
+
+def _span_events(req: RequestTrace, sp: Span) -> List[Dict[str, Any]]:
+    """A span as Chrome trace events: one complete event, or for a span
+    that ran on the device (``tid`` DEVICE_TID) a nestable async pair. The
+    host's spans nest on their threads and a reader may take every "X"
+    event of a request for host time (``request_unspanned_ms`` does); a
+    ``device.run`` overlaps them all. The "b" event carries ``dur`` too,
+    so a reader need not pair them, and the "e" event a ``dur`` of 0 for
+    one that adds ``ts`` and ``dur`` of whatever it is given."""
+    event = _span_event(req, sp)
+    if sp.tid != DEVICE_TID:
+        return [event]
+    event.update(ph="b", cat="sdtpu.device", id=sp.span_id)
+    return [event, {"ph": "e", "cat": "sdtpu.device", "name": sp.name,
+                    "pid": _PID, "tid": sp.tid, "id": sp.span_id,
+                    "ts": event["ts"] + event["dur"], "dur": 0.0,
+                    "args": {"request_id": req.request_id}}]
 
 
 class SpanTracer:
@@ -195,6 +234,18 @@ class SpanTracer:
         #: host clock's tick compares one float a request
         self._ok_durs: Dict[tuple, Deque[float]] = {}  # guarded-by: _lock
         self._medians: Dict[tuple, float] = {}  # guarded-by: _lock
+        #: the device's queue as the program knows it: the executables
+        #: enqueued whose output has no ready stamp yet, oldest first, and
+        #: the newest one enqueued (whose stamp the next one starts from)
+        self._device_lock = threading.Lock()
+        self._queue: Deque[DeviceWork] = deque()  # guarded-by: _device_lock
+        self._newest: Optional[DeviceWork] = None  # guarded-by: _device_lock
+        #: obs/watchdog.py's DeviceWatcher while a host clock runs, and
+        #: whether it is to stamp EVERY dispatch (``/internal/trace.json
+        #: ?device=1``); a profiler capture and the slow rule ask by
+        #: themselves
+        self.watcher: Any = None
+        self.armed = False
 
     # -- store ------------------------------------------------------------
 
@@ -220,6 +271,45 @@ class SpanTracer:
             self._done.clear()
             self._ok_durs.clear()
             self._medians.clear()
+        with self._device_lock:
+            self._queue.clear()
+            self._newest = None
+
+    # -- the device's side ------------------------------------------------
+
+    def settle(self, now: float, work: Optional["DeviceWork"] = None,
+               exact: bool = False) -> bool:
+        """``work``'s output was ready at ``now`` (where it has no stamp
+        yet; ``exact``: a wait just returned), and so, by ``now`` at the
+        latest, was every output at the queue's head that says it is
+        ready: the device runs what it is given in order. Each gets its
+        ``device.run``. Returns whether everything enqueued is now known
+        to be done (the device has run dry)."""
+        done: List[DeviceWork] = []
+        with self._device_lock:
+            if work is not None and work.ready is None:
+                work.ready, work.exact = now, exact
+            queue = self._queue
+            while queue:
+                head = queue[0]
+                if head.ready is None:
+                    if not _is_ready(head.output):
+                        break
+                    head.ready = now
+                done.append(queue.popleft())
+            if work is not None and work in queue:
+                # stamped behind one that cannot be told (another device)
+                queue.remove(work)
+                done.append(work)
+            dry = not queue
+        for one in done:
+            one.ran(self)
+        return dry
+
+    def enqueued(self, work: "DeviceWork") -> None:
+        with self._device_lock:
+            work.prev, self._newest = self._newest, work
+            self._queue.append(work)
 
     @staticmethod
     def _class(req: RequestTrace) -> Optional[tuple]:
@@ -276,7 +366,7 @@ class SpanTracer:
             reqs = list(self._done) + list(self._active.values())
             for req in reqs:
                 for sp in req.spans:
-                    events.append(_span_event(req, sp))
+                    events.extend(_span_events(req, sp))
         # clock_us lets a remote puller (obs/stitch.py) estimate this
         # process's trace-clock offset from one RTT-bracketed fetch.
         return {"traceEvents": events, "displayTimeUnit": "ms",
@@ -284,7 +374,8 @@ class SpanTracer:
 
     def events_for(self, req: RequestTrace) -> List[Dict[str, Any]]:
         with self._lock:
-            return [_span_event(req, sp) for sp in req.spans]
+            return [event for sp in req.spans
+                    for event in _span_events(req, sp)]
 
     def summary(self) -> Dict[str, Any]:
         with self._lock:
@@ -353,6 +444,8 @@ def _finish(tr: SpanTracer, req: RequestTrace, error: Optional[str]) -> None:
     root = Span(req.root_id, None, req.name, req.t0, req.dur,
                 threading.get_ident(), dict(req.attrs, status=req.status))
     tr.record(req, root)
+    if req.works:
+        _account_device(tr, req)
     tr.close(req)
     prometheus.observe_hist("e2e", req.dur)
     if req.status != "ok":
@@ -595,6 +688,237 @@ def add_child(name: str, seconds: float, **attrs: Any) -> Optional[Span]:
     req, parent = ctx
     return add_span(req, name, time.perf_counter() - seconds, seconds,
                     attrs, parent_id=parent)
+
+
+# -- the device's side of a request -------------------------------------------
+
+def _is_ready(output: Any) -> bool:
+    """Whether the device has made ``output``, without waiting. One that
+    its request let go at its end (None: nobody will wait for it) or the
+    next call deleted (which :meth:`DeviceWork.queued` is told never to be
+    handed) is done with as far as the queue is concerned."""
+    if output is None:
+        return True
+    try:
+        return output.is_ready()
+    except RuntimeError:
+        return True
+
+
+def _capturing() -> bool:
+    return _ANNOTATION is not None and _ANNOTATION.is_enabled()
+
+
+def _open_span() -> Optional[Span]:
+    """The innermost span open on this thread."""
+    ctx = _CURRENT.get()
+    return None if ctx is None else ctx[0].open.get(ctx[1])
+
+
+class DeviceWork:
+    """One executable a request enqueues, from before its call
+    (:func:`device_work`) to the stamp of when its output was ready.
+
+    The stamp is ``exact`` where a thread was blocked on the output when it
+    came (the request's own fence, :func:`fence`, or the device watcher,
+    obs/watchdog.py) and else a bound: the first moment after it at which
+    some thread asked (``is_ready()`` at the next enqueue or fence). The
+    ``device.run`` span it leaves runs from where the device could start
+    (the call's return, or the stamp of the executable enqueued before it,
+    whichever is later) to the stamp."""
+
+    __slots__ = ("kind", "req", "span", "dry", "queued_at", "output",
+                 "ready", "exact", "prev", "run")
+
+    def __init__(self, kind: str, req: RequestTrace, span: Optional[Span],
+                 dry: bool) -> None:
+        self.kind, self.req, self.span, self.dry = kind, req, span, dry
+        self.queued_at = 0.0
+        self.output: Any = None
+        self.ready: Optional[float] = None
+        self.exact = False
+        self.prev: Optional[DeviceWork] = None
+        self.run: Optional[Span] = None
+
+    def queued(self, output: Any, watch: bool = True) -> None:
+        """The call has returned. ``output`` is one array it made that NO
+        later call is given to donate (the chunk's fence, not its carry):
+        it is asked ``is_ready()`` from any thread until it is, and with
+        ``watch`` the device watcher may block on it. ``watch=False`` for
+        an output that is donated AFTER this thread has fenced it inline
+        (a fork's rows): nobody touches it once it is stamped."""
+        deleted = getattr(output, "is_deleted", None)
+        if deleted is not None and deleted():
+            raise ValueError(
+                f"device_work({self.kind!r}): the output is already "
+                "deleted (donated into a later call); register an output "
+                "that no call donates")
+        self.queued_at = time.perf_counter()
+        self.output = output
+        self.req.works.append(self)
+        tr = TRACER
+        tr.enqueued(self)
+        watcher = tr.watcher
+        if watch and watcher is not None and (
+                tr.armed or self.req.watched or _capturing()):
+            watcher.put(self)
+
+    def ran(self, tr: SpanTracer) -> None:
+        """Its ``device.run``, under the span that enqueued it."""
+        prev, self.prev = self.prev, None
+        start = self.queued_at
+        if prev is not None and prev.ready is not None:
+            start = max(start, prev.ready)
+        start = min(start, self.ready)
+        parent = self.span
+        attrs = {"kind": self.kind, "dry": self.dry,
+                 "exact" if self.exact else "bound": True}
+        if parent is not None:
+            attrs.update((k, parent.attrs[k]) for k in _WORK_ATTRS
+                         if k in parent.attrs)
+        self.run = Span(
+            next(_IDS), self.req.root_id if parent is None
+            else parent.span_id, "device.run", start, self.ready - start,
+            DEVICE_TID, attrs)
+        tr.record(self.req, self.run)
+
+    def sample(self) -> Dict[str, Any]:
+        """A row of a ``live`` sample's ``device``."""
+        t0 = self.req.t0
+        return {"kind": self.kind,
+                "enqueued_ms": (self.queued_at - t0) * 1e3,
+                "ready": self.ready is not None,
+                "ready_ms": None if self.ready is None
+                else (self.ready - t0) * 1e3,
+                "exact": self.exact}
+
+
+class _Fence:
+    """Around a wait on a dispatch's output (:func:`fence`)."""
+
+    __slots__ = ("work",)
+
+    def __init__(self, work: DeviceWork) -> None:
+        self.work = work
+
+    def __enter__(self) -> None:
+        work = self.work
+        TRACER.settle(time.perf_counter())
+        late = work.ready is not None
+        fences = work.req.fences
+        fences[0] += 1
+        fences[1] += late
+        sp = _open_span()
+        if sp is not None:
+            sp.attrs["late"] = late
+
+    def __exit__(self, *exc: Any) -> None:
+        if self.work.ready is None:
+            TRACER.settle(time.perf_counter(), self.work, exact=True)
+
+
+class _NoWork:
+    """What an enqueue site and a fence hold outside a request."""
+
+    def queued(self, output: Any, watch: bool = True) -> None:
+        pass
+
+    def __enter__(self) -> None:
+        pass
+
+    def __exit__(self, *exc: Any) -> None:
+        pass
+
+
+_NO_WORK = _NoWork()
+
+
+def device_work(kind: str):
+    """BEFORE an executable of the request path is enqueued, inside the
+    span that wraps the enqueue: notes on that span, and on the
+    ``device.run`` to come, whether the device had run ``dry`` (everything
+    enqueued before is done: it waits for the host), and returns the
+    handle whose :meth:`DeviceWork.queued` takes the call's output.
+    Outside a request, nothing."""
+    tr = TRACER
+    ctx = _CURRENT.get()
+    if ctx is None or not tr.enabled:
+        return _NO_WORK
+    req, parent = ctx
+    dry = tr.settle(time.perf_counter())
+    sp = req.open.get(parent)
+    if sp is not None:      # a span that enqueues several: any of them
+        sp.attrs["dry"] = dry or sp.attrs.get("dry", False)
+    return DeviceWork(kind, req, sp, dry)
+
+
+def fence(output: Any):
+    """Context manager around a wait for ``output``, the array a
+    :meth:`DeviceWork.queued` of this request took: it asks ``is_ready()``
+    first (``late`` on the open span: the device was done before the host
+    came, and the stamp it has is all that is known) and else stamps the
+    moment the wait returns."""
+    req = current()
+    if req is not None:
+        for work in reversed(req.works):
+            if work.output is output:
+                return _Fence(work)
+    return _NO_WORK
+
+
+def device_sample(req: RequestTrace) -> List[Dict[str, Any]]:
+    """Every dispatch ``req`` has registered: kind, when, ready or not."""
+    return [work.sample() for work in list(req.works)]
+
+
+def _uncovered(sections: List[Span], runs: List[Span]) -> float:
+    """Seconds of ``sections`` that no interval of ``runs`` covers."""
+    idle = 0.0
+    runs = sorted(runs, key=lambda sp: sp.t0)
+    for section in sections:
+        reach, end = section.t0, section.t0 + section.dur
+        for run in runs:
+            if run.t0 > reach:
+                idle += min(run.t0, end) - reach
+            reach = max(reach, min(run.t0 + run.dur, end))
+            if reach >= end:
+                break
+        idle += max(0.0, end - reach)
+    return idle
+
+
+def _account_device(tr: SpanTracer, req: RequestTrace) -> None:
+    """At a request's end: its dispatches to ``serving.device`` and the
+    two Prometheus families, and its arrays let go."""
+    # lazy: serving/ imports this module
+    from stable_diffusion_webui_distributed_tpu.serving.metrics import DEVICE
+
+    tr.settle(time.perf_counter())
+    works = list(req.works)
+    runs = [work.run for work in works if work.run is not None]
+    busy: Dict[str, float] = {}
+    for run in runs:
+        kind = run.attrs["kind"]
+        busy[kind] = busy.get(kind, 0.0) + run.dur
+    dispatches: Dict[str, int] = {}
+    for work in works:
+        dispatches[work.kind] = dispatches.get(work.kind, 0) + 1
+        work.output = None
+    dry = sum(work.dry for work in works)
+    with tr._lock:
+        spans_now = list(req.spans)
+    sections: List[Span] = []
+    for name in _DEVICE_SECTIONS:
+        sections = sections or [sp for sp in spans_now if sp.name == name]
+    DEVICE.record(dispatches=dispatches, busy_s=busy, dry_enqueues=dry,
+                  fences=req.fences[0], late_fences=req.fences[1],
+                  idle_s=_uncovered(sections, runs))
+    prometheus.count_device(busy, dry)
+    if req.live is not None:
+        at_sample = req.live.get("device", ())
+        req.live["device"] = rows = device_sample(req)
+        for row, was in zip(rows, at_sample):
+            row["ready_at_sample"] = was["ready"]
 
 
 def mark(req: Optional[RequestTrace], status: str, detail: str = "") -> None:

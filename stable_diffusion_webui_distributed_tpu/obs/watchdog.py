@@ -14,6 +14,17 @@ request that is alive past the slow rule's ratio (obs/spans.py), which
 the flight recorder keeps as the entry's ``live``. Its ``stats`` are
 ``serving.host`` of ``/internal/status`` (serving/metrics.py).
 
+**Beside it, only while asked for: the device watcher.** A request's own
+thread notes when an executable's output became ready only where it
+happens to wait for it (obs/spans.py: ``device.run``). :class:`DeviceWatcher`
+is one more ``StoppableDaemon`` the clock owns, whose thread exists from
+the first dispatch it is handed: it blocks on each output in the order
+they were enqueued and stamps the moment it returns. It is handed the
+dispatches of a request the slow rule has flagged (whose sample carries
+``device``: every dispatch it registered, ready or not), and every
+dispatch while a profiler capture runs or ``/internal/trace.json
+?device=1`` has armed it.
+
 **Gated off by default: ``arm``.** The scheduler already *predicts* how
 long a job should take (scheduler/eta.py); :func:`arm` starts a timer
 thread around one operation with a known ETA, and if the operation has not
@@ -58,6 +69,8 @@ STALL_S = 0.020
 STALL_RING = 64
 #: stalls a live sample carries: enough to show one under the request
 SAMPLE_STALLS = 8
+#: the device watcher looks this often for work nobody woke it for
+WATCH_IDLE_S = 1.0
 
 
 def factor() -> float:
@@ -131,6 +144,51 @@ def _record_stall(request_id: str, name: str, eta_s: float,
         events=[], duration_s=waited_s)
 
 
+class DeviceWatcher:
+    """See the module docstring. ``drain()`` runs inline in a test."""
+
+    def __init__(self, clock: Callable[[], float] = time.perf_counter) -> None:
+        self._clock = clock
+        #: dispatches to wait for, oldest first (deque operations are
+        #: atomic: an enqueueing thread appends, the watcher pops)
+        self._pending: Deque[spans.DeviceWork] = deque()
+        self.stamped = 0    # only ``drain`` adds
+        self._daemon = StoppableDaemon("device-watcher", self.drain,
+                                       WATCH_IDLE_S)
+
+    def put(self, work: "spans.DeviceWork") -> None:
+        self._pending.append(work)
+        self._daemon.start()
+        self._daemon.wake()
+
+    def follow(self, req: "spans.RequestTrace") -> None:
+        """Every dispatch of ``req``, those it has yet to make too."""
+        req.watched = True
+        for work in list(req.works):
+            if work.ready is None and work.output is not None:
+                self.put(work)
+
+    def drain(self) -> None:
+        while self._pending:
+            work = self._pending.popleft()
+            output = work.output
+            if work.ready is not None or output is None:
+                continue
+            try:
+                output.block_until_ready()
+            except RuntimeError:    # deleted under it: the fence has it
+                continue
+            spans.TRACER.settle(self._clock(), work, exact=True)
+            self.stamped += 1
+
+    def alive(self) -> bool:
+        return self._daemon.alive()
+
+    def stop(self) -> None:
+        self._pending.clear()
+        self._daemon.stop()
+
+
 #: whose ``request_id`` the clock's own events carry
 _HOST = spans.RequestTrace("host", "host", {})
 
@@ -151,6 +209,7 @@ class HostClock:
         #: with ``tick`` (deque appends are atomic)
         self._collections: Deque[tuple] = deque()
         self._gc_t0 = 0.0
+        self.watcher = DeviceWatcher(clock)
         self._daemon = StoppableDaemon("host-clock", self.tick, TICK_S,
                                        immediate=False)
 
@@ -158,11 +217,15 @@ class HostClock:
         if self._on_gc not in gc.callbacks:
             gc.callbacks.append(self._on_gc)
         self._due = None
+        spans.TRACER.watcher = self.watcher
         self._daemon.start()
         return self
 
     def stop(self) -> None:
         self._daemon.stop()
+        if spans.TRACER.watcher is self.watcher:
+            spans.TRACER.watcher = None
+        self.watcher.stop()
         if self._on_gc in gc.callbacks:
             gc.callbacks.remove(self._on_gc)
 
@@ -189,7 +252,7 @@ class HostClock:
                 next(spans._IDS), None, "host.stall", self._due, lag,
                 threading.get_ident(),
                 {"requests": [req.request_id for req in active],
-                 "spans": innermost}))
+                 "spans": innermost, "alive": len(active)}))
             for req in active:      # cut to where the request began
                 start = max(self._due, req.t0)
                 spans.add_span(req, "host.stall", start, now - start)
@@ -199,13 +262,18 @@ class HostClock:
             prometheus.count_gc_pause(generation, seconds)
         for req in late:
             req.live = self._sample(req, now)
+            self.watcher.follow(req)
         self._due = self._clock() + TICK_S
 
     def _sample(self, req: Any, now: float) -> Dict[str, Any]:
         """Every thread's stack, the request's open spans (innermost
-        first, each with the thread that opened it) and the last stalls."""
+        first, each with the thread that opened it), the last stalls and
+        the executables it enqueued, each ready by now or not (the
+        request's end fills in when the rest were)."""
         spans_open = sorted(req.open.copy().values(), key=lambda sp: -sp.t0)
+        spans.TRACER.settle(time.perf_counter())  # the spans' clock
         return {"age_ms": (now - req.t0) * 1e3,
+                "device": spans.device_sample(req),
                 "open": [{"name": sp.name, "age_ms": (now - sp.t0) * 1e3,
                           "thread": sp.tid} for sp in spans_open],
                 "stacks": dump_stacks(),

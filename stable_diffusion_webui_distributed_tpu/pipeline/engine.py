@@ -1047,10 +1047,13 @@ class Engine:
             pi, wi = pad_chunks(ids_c, w_c, n_enc, eos, bos)
             args = (te, te2, jnp.asarray(pi), jnp.asarray(wi), skip,
                     *inj_arrays(inj_c, n_enc))
-            if te_sig:
-                return enc(*args, te_lora=ts.tree.get("text_encoder"),
-                           te2_lora=ts.tree.get("text_encoder_2"))
-            return enc(*args)
+            more = {} if not te_sig else {
+                "te_lora": ts.tree.get("text_encoder"),
+                "te2_lora": ts.tree.get("text_encoder_2")}
+            work = obs_spans.device_work("encode")
+            out = enc(*args, **more)
+            work.queued(out[0])     # kept conditioning: never donated
+            return out
 
         def cached_enc(raw, ids_c, w_c, inj_c, negative=False, n_enc=None):
             # cross-request cache (webui's cached_c/uc): same text at the
@@ -1664,7 +1667,8 @@ class Engine:
                 # byte-identical and reuses the same executables.
                 if pending is not None:
                     with obs_spans.span("chunk.fence_wait",
-                                        steps=pending[1]):
+                                        steps=pending[1]), \
+                            obs_spans.fence(pending[0]):
                         pending[0].block_until_ready()
                     done += pending[1]
                     self.state.step(done)
@@ -1708,8 +1712,10 @@ class Engine:
             # i-1's fence: its two children say which
             with trace.STATS.timer("denoise_chunk"):
                 with obs_spans.span("chunk.enqueue", pos=pos, steps=length):
+                    work = obs_spans.device_work("run_chunk")
                     state, fence = fn(self.params["unet"], state,
                                       jnp.int32(pos), chunk_inputs)
+                    work.queued(fence)      # the carry is donated: never it
                     if cached_chunk:
                         carry, cache, valid = state
                     else:
@@ -1720,7 +1726,8 @@ class Engine:
                             valid = jnp.asarray(False)
                 if pending is not None:
                     with obs_spans.span("chunk.fence_wait",
-                                        steps=pending[1]):
+                                        steps=pending[1]), \
+                            obs_spans.fence(pending[0]):
                         pending[0].block_until_ready()
                     done += pending[1]
                     self.state.step(done)
@@ -1739,7 +1746,8 @@ class Engine:
                 # gated-on path only.
                 cache_prefix.maybe_capture(prefix_plan, pos, tuple(carry))
         if pending is not None:
-            with obs_spans.span("chunk.fence_wait", steps=pending[1]):
+            with obs_spans.span("chunk.fence_wait", steps=pending[1]), \
+                    obs_spans.fence(pending[0]):
                 pending[0].block_until_ready()
             done += pending[1]
             self.state.step(done)
@@ -2077,8 +2085,10 @@ class Engine:
             # ONCE at batch 1 (flat VAE scratch at SDXL sizes) and repeat
             # per group
             with obs_spans.span("vae_encode"):
+                work = obs_spans.device_work("vae_encode")
                 init_lat1 = self._encode_image_fn(width, height, 1)(
                     self.params["vae"], init_dev)
+                work.queued(init_lat1)      # repeated a group, not donated
 
         mask_lat = None
         mask_pixels = None
@@ -2202,7 +2212,9 @@ class Engine:
                 "ignore", message="Some donated buffers were not usable")
             for s in starts:
                 with trace.STATS.timer("vae_decode_dispatch"):
+                    work = obs_spans.device_work("decode_u8")
                     imgs = decode(self.params["vae"], latents[s:s + per])
+                    work.queued(imgs)
                 # a whole slice of an array is the array: no copy at per 1
                 entries += [(imgs[r:r + 1], pos + s + r, width, height,
                              incomplete) for r in range(min(per, keep - s))]
@@ -2213,7 +2225,8 @@ class Engine:
         """A decode dispatch's image on the host, inside the caller's
         ``vae_decode_fetch``: the wait for the decode executable (the device
         busy), then the copy down (the device idle)."""
-        with obs_spans.span("decode.wait", rows=int(img_dev.shape[0])):
+        with obs_spans.span("decode.wait", rows=int(img_dev.shape[0])), \
+                obs_spans.fence(img_dev):
             jax.block_until_ready(img_dev)
         with obs_spans.span("fetch.copy", bytes=int(img_dev.nbytes)):
             return np.asarray(img_dev)[0]

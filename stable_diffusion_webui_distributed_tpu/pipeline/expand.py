@@ -309,6 +309,7 @@ class PromptExpander:
             # the instruction's chunk yields no token that is kept: it runs
             # at one sequence whatever follows it
             with obs_spans.span("expand.prefill", **attrs):
+                work = obs_spans.device_work("expand_prefill")
                 cache, token, step_load, step_none, *chose = \
                     self._prefill_fn(
                         len(padded), capacity, 1 if keep else batch)(
@@ -316,9 +317,11 @@ class PromptExpander:
                             jnp.int32(len(ids)),
                             key[0] if keep and batch > 1 else key,
                             temperature)
+                work.queued(token)      # the cache is donated: never it
                 # fenced: the span is the chunk's device time, not its
                 # enqueue
-                jax.block_until_ready(token)
+                with obs_spans.fence(token):
+                    jax.block_until_ready(token)
             if keep:
                 with obs_spans.span("expand.prefix_copy", hit=False,
                                     bytes=copied):
@@ -336,9 +339,15 @@ class PromptExpander:
                                 bytes=sum(sizes.values()) - copied + states,
                                 state_bytes_copied=fork_copied,
                                 **looped, **how, **sites):
-                cache = kv.forked(
-                    cache, self._fork_fn(capacity, batch, own_slots)(cache))
-                jax.block_until_ready(cache)    # fenced, as a prefill is
+                work = obs_spans.device_work("expand_fork")
+                own = self._fork_fn(capacity, batch, own_slots)(cache)
+                cache = kv.forked(cache, own)
+                # every row it made goes into the first decode chunk, but
+                # only after the fence below: nobody else may wait on one
+                one = jax.tree_util.tree_leaves(own)[0]
+                work.queued(one, watch=False)
+                with obs_spans.fence(one):
+                    jax.block_until_ready(cache)    # as a prefill is
         # (live, tokens so far): the first of each from the prompt's row
         made = np.asarray(token).reshape(-1, 1)[:live].tolist()
         position = jnp.int32(forked_at)
@@ -352,7 +361,7 @@ class PromptExpander:
         zeros = []        # per decode call: picks on zero-compute experts
 
         def fetch(out) -> None:
-            with obs_spans.span("expand.fence_wait"):
+            with obs_spans.span("expand.fence_wait"), obs_spans.fence(out):
                 out = np.asarray(jax.device_get(out)).reshape(
                     DECODE_STEPS, -1)
                 for one, column in zip(made, out.T):
@@ -366,9 +375,11 @@ class PromptExpander:
             with obs_spans.span("expand.decode_chunk", tokens=DECODE_STEPS,
                                 sequences=live, **looped, **how, **sites,
                                 **moved):
+                work = obs_spans.device_work("expand_decode_chunk")
                 cache, token, position, out, step_load, step_none, *read = \
                     decode(params, cache, token, position, key,
                            temperature, *more)
+                work.queued(out)
             steps += DECODE_STEPS
             routed.append((step_load, step_none))
             if self.config.zero_experts:    # the last of what it returns

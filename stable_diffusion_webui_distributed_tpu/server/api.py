@@ -490,6 +490,9 @@ class ApiServer:
     def handle_internal_status(self) -> Dict[str, Any]:
         """Everything the status panel shows (reference Status tab data:
         worker lines at world.py:603-614, log ring at ui.py:72-88)."""
+        from stable_diffusion_webui_distributed_tpu.obs import (
+            flightrec, spans as obs_spans,
+        )
         from stable_diffusion_webui_distributed_tpu.runtime import trace
         from stable_diffusion_webui_distributed_tpu.runtime.logging import (
             get_ring_buffer,
@@ -524,10 +527,10 @@ class ApiServer:
             serving["fleet"] = self.dispatcher.fleet_summary()
             if self.host_clock is not None:
                 serving["host"] = self.host_clock.stats.summary()
-        from stable_diffusion_webui_distributed_tpu.obs import (
-            flightrec, spans as obs_spans,
-        )
-
+                watcher = self.host_clock.watcher
+                serving["device"]["watcher"] = {
+                    "armed": obs_spans.TRACER.armed,
+                    "alive": watcher.alive(), "stamped": watcher.stamped}
         obs = obs_spans.TRACER.summary()
         obs["flightrec_entries"] = len(flightrec.RECORDER)
         # warm pool (SDTPU_POOL, fleet/pool.py): resident table when one
@@ -558,13 +561,19 @@ class ApiServer:
             "logs": get_ring_buffer().dump(),
         }
 
-    def handle_trace_json(self) -> Dict[str, Any]:
+    def handle_trace_json(self, query: Optional[Dict[str, str]] = None
+                          ) -> Dict[str, Any]:
         """Chrome trace-event JSON of every retained request trace — save
-        the body and load it in Perfetto / chrome://tracing (PERF.md)."""
+        the body and load it in Perfetto / chrome://tracing (PERF.md).
+        ``?device=1`` arms the device watcher (obs/watchdog.py: every
+        dispatch from now on gets the exact moment its output was ready),
+        ``?device=0`` disarms it."""
         from stable_diffusion_webui_distributed_tpu.obs import (
             spans as obs_spans,
         )
 
+        if query and "device" in query:
+            obs_spans.TRACER.armed = query["device"] not in ("", "0")
         doc = obs_spans.TRACER.export_chrome()
         if self.host_clock is not None:     # its ring, as ``host.stall``
             doc["traceEvents"].extend(self.host_clock.events())

@@ -240,6 +240,7 @@ class DispatchMetrics:
         out["norm"] = NORM.summary()
         out["expander"] = EXPANDER.summary()
         out["plan"] = PLAN.summary()
+        out["device"] = DEVICE.summary()
         return out     # server/api.py adds "host" beside a host clock
 
 
@@ -1036,8 +1037,57 @@ class HostStats:
                 self.counts["gc_collections"]))
 
 
+class DeviceStats:
+    """``serving.device``: what the device did with the executables the
+    requests enqueued, as the program's own spans have it (obs/spans.py:
+    ``device.run``), profiler off. Fed once a request, at its end, from
+    its tree: the enqueue path takes no lock of this. ``busy_s`` by kind
+    sums the ``device.run`` spans; ``idle_s`` is the requests' device
+    sections (``dispatch.device``) less the ``device.run`` inside them;
+    ``dry_enqueues`` the enqueues that found the device with nothing left
+    to run (it waited for the host); ``late_fences`` of ``fences`` the
+    waits that found the device already done."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self.clear()
+
+    def clear(self) -> None:
+        with self._lock:
+            self.counts: Dict[str, Any] = dict(  # guarded-by: _lock
+                requests=0, dispatches={}, busy_s={}, dry_enqueues=0,
+                fences=0, late_fences=0, idle_s=0.0)
+
+    def record(self, dispatches: Dict[str, int], busy_s: Dict[str, float],
+               dry_enqueues: int, fences: int, late_fences: int,
+               idle_s: float) -> None:
+        with self._lock:
+            counts = self.counts
+            counts["requests"] += 1
+            for key, by_kind in (("dispatches", dispatches),
+                                 ("busy_s", busy_s)):
+                for kind, n in by_kind.items():
+                    counts[key][kind] = counts[key].get(kind, 0) + n
+            counts["dry_enqueues"] += dry_enqueues
+            counts["fences"] += fences
+            counts["late_fences"] += late_fences
+            counts["idle_s"] += idle_s
+
+    def summary(self) -> Dict[str, Any]:
+        with self._lock:
+            out = dict(self.counts, dispatches=dict(self.counts["dispatches"]),
+                       busy_s=dict(self.counts["busy_s"]))
+        # the totals a quotient of two counters can name
+        out["dispatches_total"] = sum(out["dispatches"].values())
+        out["busy_s_total"] = sum(out["busy_s"].values())
+        return out
+
+
 #: Process-wide counts of kept-plan lookups (``summary()["plan"]``).
 PLAN = PlanStats()
+
+#: Process-wide device counters of the requests' trees (``["device"]``).
+DEVICE = DeviceStats()
 
 #: Process-wide prompt-expander counters (``summary()["expander"]``).
 EXPANDER = ExpanderStats()

@@ -39,6 +39,9 @@ from stable_diffusion_webui_distributed_tpu.serving.bucketer import (
 from stable_diffusion_webui_distributed_tpu.serving.dispatcher import (
     ServingDispatcher,
 )
+from stable_diffusion_webui_distributed_tpu.serving import (
+    metrics as metrics_mod,
+)
 from stable_diffusion_webui_distributed_tpu.serving.metrics import (
     METRICS, HostStats,
 )
@@ -53,8 +56,14 @@ def payload(**kw):
 
 
 def assert_chrome_event(e):
-    """One Chrome trace-event "X" record with the sdtpu arg contract."""
-    assert e["ph"] == "X"
+    """One Chrome trace-event "X" record with the sdtpu arg contract; a
+    span that ran on the device is an async pair, whose "b" half carries
+    the same keys and whose "e" half closes it by ``id``."""
+    if e["ph"] == "e":
+        assert e["cat"] == "sdtpu.device" and e["id"] and e["ts"] >= 0
+        assert "request_id" in e["args"]
+        return
+    assert e["ph"] == ("b" if e["cat"] == "sdtpu.device" else "X")
     for key in ("name", "cat", "pid", "tid", "ts", "dur", "args"):
         assert key in e, f"missing {key}: {e}"
     assert isinstance(e["pid"], int) and isinstance(e["tid"], int)
@@ -190,6 +199,7 @@ class TestCoalescedRunTracing:
         obs_spans.TRACER.clear()
         flightrec.RECORDER.clear()
         METRICS.clear()
+        metrics_mod.DEVICE.clear()
         prometheus.clear_histograms()
         disp = ServingDispatcher(engine, bucketer=bucketer, window=0.6)
         shapes = [(32, 32), (24, 32), (48, 48), (40, 40)]
@@ -247,6 +257,28 @@ class TestCoalescedRunTracing:
             device = [s for s in leader.spans
                       if s.span_id == sp.attrs["leader_span_id"]]
             assert [s.name for s in device] == ["dispatch.device"]
+
+    def test_the_device_s_side_is_in_the_leader_s_tree(self, run):
+        """A coalesced dispatch's ``device.run`` spans belong to the tree
+        that holds its ``dispatch.device`` (ISSUE 71); a follower, whose
+        wait carries the leader's ids, has none."""
+        for tr in run["traces"].values():
+            names = {s.name for s in tr.spans}
+            runs = [s for s in tr.spans if s.name == "device.run"]
+            if "dispatch.device" not in names:
+                assert not runs and not tr.works
+                continue
+            section, = [s for s in tr.spans if s.name == "dispatch.device"]
+            assert {"run_chunk", "decode_u8"} \
+                <= {s.attrs["kind"] for s in runs}
+            for s in runs:
+                assert s.t0 >= section.t0
+                assert s.t0 + s.dur <= section.t0 + section.dur + 1e-6
+        leaders = [tr for tr in run["traces"].values() if tr.works]
+        block = METRICS.summary()["device"]
+        assert block["requests"] == len(leaders)
+        assert block["dispatches_total"] \
+            == sum(len(tr.works) for tr in leaders)
 
     def test_root_duration_matches_measured_e2e(self, run):
         # acceptance: the span tree accounts for the measured latency
@@ -770,6 +802,373 @@ class TestHostClock:
         assert out["exchanges"] == 5 and out["betweens"] == 2
         assert out["between_ms"] == pytest.approx(125.0)
         assert out["between_ms_max"] == pytest.approx(100.0)
+
+
+# -- the device's side: device.run, the watcher -----------------------------
+
+class Output:
+    """Stands for a device array: ready when told."""
+
+    def __init__(self, ready=False):
+        self.event = threading.Event()
+        self.waits = 0
+        if ready:
+            self.event.set()
+
+    def is_ready(self):
+        return self.event.is_set()
+
+    def block_until_ready(self):
+        self.waits += 1
+        assert self.event.wait(30)
+        return self
+
+
+def device_runs(req):
+    return [s for s in req.spans if s.name == "device.run"]
+
+
+class TestDeviceWork:
+    @pytest.fixture()
+    def clean(self, monkeypatch):
+        monkeypatch.setattr(obs_spans.TRACER, "slow_s", 30.0)
+        obs_spans.TRACER.clear()
+        flightrec.RECORDER.clear()
+        prometheus.clear_histograms()
+        metrics_mod.DEVICE.clear()
+
+    @pytest.mark.parametrize("ready_first", [False, True])
+    def test_a_fence_stamps_or_finds_it_done(self, clean, ready_first):
+        """``late`` from what ``is_ready()`` says before the wait: a wait
+        that blocked ends at the ready time (exact), one that found the
+        device done knows a bound."""
+        out = Output(ready=ready_first)
+        with obs_spans.request("fenced", name="unit") as req:
+            with obs_spans.span("chunk.enqueue", steps=3) as enq:
+                work = obs_spans.device_work("run_chunk")
+                work.queued(out)
+            assert enq.attrs["dry"] is True      # nothing was queued
+            with obs_spans.span("chunk.fence_wait") as wait, \
+                    obs_spans.fence(out):
+                out.event.set()
+            assert wait.attrs["late"] is ready_first
+            run, = device_runs(req)
+        assert run.parent_id == enq.span_id
+        assert run.attrs["kind"] == "run_chunk" and run.attrs["steps"] == 3
+        assert ("bound" in run.attrs) is ready_first
+        assert ("exact" in run.attrs) is not ready_first
+        assert run.tid == obs_spans.DEVICE_TID
+        assert run.t0 >= enq.t0 and run.dur >= 0
+        assert req.fences == [1, int(ready_first)]
+
+    def test_dry_says_whether_the_device_had_anything_left(self, clean):
+        first, second, third = Output(), Output(), Output()
+        with obs_spans.request("queue", name="unit") as req:
+            seen = []
+            for out in (first, second):
+                with obs_spans.span("chunk.enqueue") as enq:
+                    obs_spans.device_work("run_chunk").queued(out)
+                seen.append(enq.attrs["dry"])
+            first.event.set()
+            second.event.set()      # both done before the host is back
+            with obs_spans.span("chunk.enqueue") as enq:
+                obs_spans.device_work("run_chunk").queued(third)
+            seen.append(enq.attrs["dry"])
+            third.event.set()
+        assert seen == [True, False, True]
+        runs = device_runs(req)
+        assert len(runs) == 3 and all("bound" in r.attrs for r in runs)
+        # in the order they were enqueued, one after the other
+        for a, b in zip(runs, runs[1:]):
+            assert a.t0 + a.dur <= b.t0 + 1e-9
+        block = metrics_mod.DEVICE.summary()
+        assert block["dry_enqueues"] == 2 and block["requests"] == 1
+        assert block["dispatches"] == {"run_chunk": 3}
+        assert block["dispatches_total"] == 3 and block["fences"] == 0
+        assert block["busy_s"]["run_chunk"] == pytest.approx(
+            sum(r.dur for r in runs))
+
+    def test_a_span_that_enqueues_several_counts_each(self, clean):
+        """``text_encode`` encodes a request's prompts one after the
+        other: ``dry`` is each dispatch's own (its ``device.run`` says
+        it), the span's attr says whether any was."""
+        outs = [Output(), Output(), Output()]
+        with obs_spans.request("prompts", name="unit") as req:
+            with obs_spans.span("text_encode") as enc:
+                obs_spans.device_work("encode").queued(outs[0])
+                obs_spans.device_work("encode").queued(outs[1])
+                outs[0].event.set()
+                outs[1].event.set()
+                obs_spans.device_work("encode").queued(outs[2])
+                outs[2].event.set()
+        assert enc.attrs["dry"] is True
+        assert [r.attrs["dry"] for r in device_runs(req)] \
+            == [True, False, True]
+        assert {r.parent_id for r in device_runs(req)} == {enc.span_id}
+        assert metrics_mod.DEVICE.summary()["dry_enqueues"] == 2
+
+    def test_a_donated_output_is_refused(self, clean):
+        import jax
+        import jax.numpy as jnp
+
+        bump = jax.jit(lambda x: x + 1, donate_argnums=(0,))
+        carry = jnp.zeros((8, 128))
+        bump(carry).block_until_ready()
+        assert carry.is_deleted()
+        with obs_spans.request("donor", name="unit") as req:
+            with obs_spans.span("chunk.enqueue"):
+                work = obs_spans.device_work("run_chunk")
+                with pytest.raises(ValueError, match="no call donates"):
+                    work.queued(carry)
+        assert not req.works and not device_runs(req)
+
+    def test_outside_a_request_nothing_is_kept(self, clean):
+        out = Output(ready=True)
+        obs_spans.device_work("run_chunk").queued(out)
+        with obs_spans.fence(out):
+            pass
+        assert obs_spans.TRACER.settle(time.perf_counter()) \
+            and out.waits == 0
+
+    def test_what_a_request_never_fenced_does_not_hold_the_queue(self,
+                                                                clean):
+        """A request that ends with an output still unready (an interrupt)
+        lets the array go; the next touch closes its ``device.run``."""
+        left = Output()
+        with obs_spans.request("cut-short", name="unit") as req:
+            with obs_spans.span("chunk.enqueue"):
+                obs_spans.device_work("run_chunk").queued(left)
+        assert not device_runs(req) and req.works[0].output is None
+        with obs_spans.request("next", name="unit"):
+            with obs_spans.span("chunk.enqueue") as enq:
+                obs_spans.device_work("run_chunk").queued(Output(ready=True))
+        assert enq.attrs["dry"] is True
+        run, = device_runs(req)
+        assert "bound" in run.attrs and left.waits == 0
+
+    def test_idle_is_the_section_less_its_runs(self, clean):
+        """``serving.device`` ``idle_s``: ``dispatch.device`` less the
+        union of the ``device.run`` inside, and the two counters."""
+        out = Output()
+        with obs_spans.request("idle", name="unit") as req:
+            with obs_spans.span("dispatch.device") as section:
+                time.sleep(0.02)            # the device has nothing
+                with obs_spans.span("chunk.enqueue"):
+                    obs_spans.device_work("run_chunk").queued(out)
+                with obs_spans.span("chunk.fence_wait"), \
+                        obs_spans.fence(out):
+                    time.sleep(0.02)
+                    out.event.set()
+                time.sleep(0.01)            # nor has it here
+        run, = device_runs(req)
+        block = metrics_mod.DEVICE.summary()
+        assert block["idle_s"] == pytest.approx(section.dur - run.dur)
+        assert block["idle_s"] >= 0.03 and run.dur >= 0.02
+        assert block["busy_s_total"] == pytest.approx(run.dur)
+        text = prometheus.render()
+        assert 'sdtpu_device_busy_seconds_total{kind="run_chunk"}' in text
+        assert "sdtpu_device_dry_enqueues_total{} 1" in text
+        assert all(work.output is None for work in req.works)
+
+    def test_the_export_keeps_it_off_the_host_threads(self, clean):
+        """A ``device.run`` is an async pair: no "X" event, so a reader
+        that takes a request's "X" events for host time still does."""
+        out = Output()
+        with obs_spans.request("pair", name="unit"):
+            with obs_spans.span("chunk.enqueue"):
+                obs_spans.device_work("run_chunk").queued(out)
+            with obs_spans.fence(out):
+                out.event.set()
+        events = obs_spans.TRACER.export_chrome()["traceEvents"]
+        for e in events:
+            assert_chrome_event(e)
+        begin, end = (e for e in events if e["name"] == "device.run")
+        assert (begin["ph"], end["ph"]) == ("b", "e")
+        assert begin["id"] == end["id"] == begin["args"]["span_id"]
+        assert end["ts"] == pytest.approx(begin["ts"] + begin["dur"])
+        assert begin["args"]["kind"] == "run_chunk"
+        enqueue, = (e for e in events if e["name"] == "chunk.enqueue")
+        assert begin["args"]["parent_id"] == enqueue["args"]["span_id"]
+
+
+class TestDeviceWatcher:
+    @pytest.fixture()
+    def clean(self, monkeypatch):
+        monkeypatch.setattr(obs_spans.TRACER, "slow_s", 30.0)
+        monkeypatch.setattr(obs_spans.TRACER, "armed", False)
+        obs_spans.TRACER.clear()
+        flightrec.RECORDER.clear()
+        metrics_mod.DEVICE.clear()
+
+    @staticmethod
+    def watchers():
+        return [t for t in threading.enumerate()
+                if t.name == "device-watcher"]
+
+    def test_off_no_thread_is_started(self, clean):
+        """The census: a running clock, dispatches and fences, and no
+        watcher thread."""
+        clock = watchdog.HostClock().start()
+        try:
+            assert obs_spans.TRACER.watcher is clock.watcher
+            out = Output()
+            with obs_spans.request("unwatched", name="unit") as req:
+                with obs_spans.span("chunk.enqueue"):
+                    obs_spans.device_work("run_chunk").queued(out)
+                with obs_spans.fence(out):
+                    out.event.set()
+            assert not self.watchers() and not clock.watcher.alive()
+            assert out.waits == 0 and clock.watcher.stamped == 0
+            assert "exact" in device_runs(req)[0].attrs
+        finally:
+            clock.stop()
+        assert obs_spans.TRACER.watcher is None
+
+    def test_armed_it_stamps_what_no_fence_waits_for(self, clean):
+        """Armed (``/internal/trace.json?device=1``): an output nobody
+        fences gets the moment it was ready, not the next enqueue's."""
+        clock = watchdog.HostClock().start()
+        obs_spans.TRACER.armed = True
+        try:
+            out = Output()
+            with obs_spans.request("armed", name="unit") as req:
+                with obs_spans.span("text_encode"):
+                    obs_spans.device_work("encode").queued(out)
+                assert self.watchers()
+                time.sleep(0.02)
+                out.event.set()
+                deadline = time.monotonic() + 10
+                while not device_runs(req) and time.monotonic() < deadline:
+                    time.sleep(0.005)
+                run, = device_runs(req)
+            assert "exact" in run.attrs and run.dur >= 0.02
+            assert clock.watcher.stamped == 1 and out.waits == 1
+        finally:
+            clock.stop()
+        assert not self.watchers()      # it stops with the clock
+
+    def test_an_inline_fenced_output_is_not_handed_over(self, clean):
+        clock = watchdog.HostClock().start()
+        obs_spans.TRACER.armed = True
+        try:
+            out = Output()
+            with obs_spans.request("forked", name="unit"):
+                with obs_spans.span("expand.fork"):
+                    obs_spans.device_work("expand_fork").queued(
+                        out, watch=False)
+                    with obs_spans.fence(out):
+                        out.event.set()
+            assert out.waits == 0 and not self.watchers()
+        finally:
+            clock.stop()
+
+    def test_the_slow_rule_starts_it_and_the_sample_carries_device(
+            self, clean):
+        """A request alive past the rule: ONE sample, with every dispatch
+        it registered, ready or not; the watcher follows what is left and
+        the entry says when each came."""
+        tiny = dict(width=32, height=32, steps=2)
+        clock = watchdog.HostClock().start()
+        try:
+            for i in range(obs_spans.SLOW_MIN_SAMPLES + 1):
+                with obs_spans.request(f"quick-{i}", name="txt2img", **tiny):
+                    time.sleep(0.01)
+            assert not self.watchers()
+            done, held = Output(ready=True), Output()
+            with obs_spans.request("held-back", name="txt2img",
+                                   **tiny) as req:
+                with obs_spans.span("dispatch.device"):
+                    for out in (done, held):
+                        with obs_spans.span("chunk.enqueue"):
+                            obs_spans.device_work("run_chunk").queued(out)
+                    deadline = time.monotonic() + 10
+                    while req.live is None and time.monotonic() < deadline:
+                        time.sleep(0.005)
+                    assert req.live is not None and req.watched
+                    assert [(row["kind"], row["ready"])
+                            for row in req.live["device"]] \
+                        == [("run_chunk", True), ("run_chunk", False)]
+                    assert self.watchers()
+                    time.sleep(0.03)
+                    held.event.set()        # the device lets go at last
+                    with obs_spans.span("chunk.fence_wait"), \
+                            obs_spans.fence(held):
+                        held.block_until_ready()
+        finally:
+            clock.stop()
+        entry, = flightrec.RECORDER.dump()["entries"]
+        assert entry["request_id"] == "held-back"
+        first, second = entry["live"]["device"]
+        assert first["ready_at_sample"] and not second["ready_at_sample"]
+        assert second["ready"] and second["ready_ms"] > second["enqueued_ms"]
+        assert second["ready_ms"] - first["ready_ms"] >= 30
+        json.dumps(entry)
+
+    def test_many_threads_and_the_watcher_lose_no_dispatch(self, clean):
+        """More request threads than cores, the watcher armed, the switch
+        interval shortened: every dispatch gets exactly one ``device.run``
+        and the counters add up."""
+        threads, each = 12, 40
+        clock = watchdog.HostClock().start()
+        obs_spans.TRACER.armed = True
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        traces = []
+
+        def one(i):
+            with obs_spans.request(f"stress-{i}", name="unit") as req:
+                traces.append(req)
+                for j in range(each):
+                    out = Output(ready=j % 3 == 0)
+                    with obs_spans.span("chunk.enqueue"):
+                        obs_spans.device_work("run_chunk").queued(out)
+                    if j % 2:
+                        with obs_spans.span("chunk.fence_wait"), \
+                                obs_spans.fence(out):
+                            out.event.set()
+                    else:
+                        out.event.set()
+        try:
+            workers = [threading.Thread(target=one, args=(i,), daemon=True)
+                       for i in range(threads)]
+            for t in workers:
+                t.start()
+            for t in workers:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in workers)
+        finally:
+            sys.setswitchinterval(interval)
+            clock.stop()
+        obs_spans.TRACER.settle(time.perf_counter())
+        for req in traces:
+            runs = device_runs(req)
+            assert len(runs) == each == len(req.works)
+            assert len({r.span_id for r in runs}) == each
+            assert sorted(r.parent_id for r in runs) \
+                == sorted(w.span.span_id for w in req.works)
+            assert all(r.dur >= 0 for r in runs)
+        block = metrics_mod.DEVICE.summary()
+        assert block["requests"] == threads
+        assert block["dispatches_total"] == threads * each
+        assert block["fences"] == threads * (each // 2)
+
+    def test_a_stall_with_no_request_alive_says_so(self, clean):
+        """``alive``: 0 on a stall no request's tree can hold (none was
+        alive when the clock woke: a gap between two, or one that ended
+        while the clock was stopped)."""
+        now = [time.perf_counter()]
+        clock = watchdog.HostClock(clock=lambda: now[0])
+        clock.tick()
+        with obs_spans.request("gone-by-then", name="unit"):
+            pass
+        now[0] += watchdog.TICK_S + 0.05
+        clock.tick()
+        with obs_spans.request("there", name="unit"):
+            now[0] += watchdog.TICK_S + 0.04
+            clock.tick()
+        assert [(sp.attrs["alive"], sp.attrs["requests"])
+                for sp in clock.ring] == [(0, []), (1, ["there"])]
+        assert [e["args"]["alive"] for e in clock.events()] == [0, 1]
 
 
 # -- histogram mechanics -----------------------------------------------------

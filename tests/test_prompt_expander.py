@@ -1145,6 +1145,46 @@ class TestEnginePath:
         assert first["chunk.enqueue"]["ts"] < account["ts"]
         assert account["ts"] < first["chunk.fence_wait"]["ts"]
 
+    @pytest.mark.parametrize("batch, want", [
+        (1, {"expand_prefill": "expand.prefill",
+             "expand_decode_chunk": "expand.decode_chunk"}),
+        (2, {"expand_prefill": "expand.prefill",
+             "expand_fork": "expand.fork",
+             "expand_decode_chunk": "expand.decode_chunk"})])
+    def test_the_device_s_side_of_every_enqueue(self, engine, batch, want):
+        """A ``device.run`` an executable under the span that enqueued it
+        (ISSUE 71): the prefill's and the fork's fenced inside their own
+        span, a decode chunk's by the fetch of its tokens."""
+        from stable_diffusion_webui_distributed_tpu.obs import spans
+
+        if batch > 1 and not engine.expander.shares_a_step:
+            pytest.skip("one image after the other: no fork")
+        engine.txt2img(CASE.payload(batch_size=batch))
+        spans.TRACER.clear()
+        with spans.request("rid-device") as req:
+            engine.txt2img(CASE.payload(batch_size=batch))
+        by_id = {sp.span_id: sp for sp in req.spans}
+        runs = [sp for sp in req.spans if sp.name == "device.run"]
+        found = {}
+        for run in runs:
+            found.setdefault(run.attrs["kind"], set()).add(
+                by_id[run.parent_id].name)
+        assert {k: found.get(k) for k in want} \
+            == {k: {v} for k, v in want.items()}
+        # one a span, and the chunk's tokens ride along
+        chunks = [sp for sp in req.spans
+                  if sp.name == "expand.decode_chunk"]
+        mine = [r for r in runs if r.attrs["kind"] == "expand_decode_chunk"]
+        assert sorted(r.parent_id for r in mine) \
+            == sorted(sp.span_id for sp in chunks)
+        assert {r.attrs["tokens"] for r in mine} == {32}
+        fences = [sp for sp in req.spans if sp.name == "expand.fence_wait"]
+        assert len(fences) == len(chunks)
+        assert all("late" in sp.attrs for sp in fences)
+        ordered = sorted(runs, key=lambda sp: sp.t0)
+        for a, b in zip(ordered, ordered[1:]):
+            assert a.t0 + a.dur <= b.t0 + 1e-6
+
     def test_a_family_without_an_expander_is_untouched(self):
         from stable_diffusion_webui_distributed_tpu.obs import spans
 

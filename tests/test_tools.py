@@ -132,6 +132,113 @@ class TestTraceReport:
         assert trace_report.main([str(empty)]) == 1
         assert trace_report.main([str(tmp_path / "missing.json")]) == 2
 
+    def test_a_device_pair_shows_once_under_its_enqueue(self, trace):
+        """``device.run`` (ISSUE 71) comes as an async pair: the "b" half
+        is the span, the "e" half is dropped."""
+        import trace_report
+
+        run = self._event("device.run", "aaa", 6, parent_id=3, ts=7_000.0,
+                          dur_us=28_000.0, kind="run_chunk", exact=True)
+        run.update(ph="b", cat="sdtpu.device", id=6, tid=0)
+        end = {"ph": "e", "cat": "sdtpu.device", "name": "device.run",
+               "pid": 1, "tid": 0, "id": 6, "ts": 35_000.0, "dur": 0.0,
+               "args": {"request_id": "aaa"}}
+        trace["traceEvents"] += [run, end]
+        report = trace_report.build_report(trace)
+        assert report["event_count"] == 6
+        tree_a = report["requests"]["aaa"]
+        assert len(tree_a) == 4 and "device.run" in tree_a[3]
+        assert "kind=run_chunk" in tree_a[3]
+
+
+class TestTraceProbe:
+    """tools/trace_probe.py's reading of the device's side (ISSUE 71)."""
+
+    @staticmethod
+    def _events():
+        def host(name, sid, parent, ts, dur, **attrs):
+            return {"ph": "X", "name": name, "ts": ts, "dur": dur,
+                    "args": {"request_id": "w-1", "span_id": sid,
+                             "parent_id": parent, **attrs}}
+
+        def run(sid, parent, ts, dur, kind, **attrs):
+            return {"ph": "b", "name": "device.run", "ts": ts, "dur": dur,
+                    "id": sid, "args": {"request_id": "w-1", "span_id": sid,
+                                        "parent_id": parent, "kind": kind,
+                                        **attrs}}
+
+        events = [
+            host("dispatch.device", 1, 0, 0.0, 10_000.0, requests=1),
+            host("chunk.enqueue", 2, 1, 100.0, 400.0, dry=True),
+            host("chunk.enqueue", 3, 1, 600.0, 300.0, dry=False),
+            host("chunk.fence_wait", 4, 1, 900.0, 3_100.0, late=False),
+            host("chunk.fence_wait", 5, 1, 4_000.0, 3_000.0, late=False),
+            host("vae_decode_dispatch", 6, 1, 7_100.0, 200.0, dry=True),
+            host("decode.wait", 7, 1, 7_400.0, 10.0, late=True),
+            run(8, 2, 500.0, 3_500.0, "run_chunk", exact=True, dry=True),
+            run(9, 3, 4_000.0, 3_000.0, "run_chunk", exact=True, dry=False),
+            run(10, 6, 7_300.0, 100.0, "decode_u8", bound=True, dry=True),
+            {"ph": "e", "name": "device.run", "ts": 4_000.0, "dur": 0.0,
+             "id": 8, "args": {"request_id": "w-1"}},
+        ]
+        return {"traceEvents": events}
+
+    def test_by_request_keeps_the_device_off_the_host_rows(self):
+        import trace_probe
+
+        doc = self._events()
+        assert len(trace_probe.by_request(doc)["w-1"]) == 7
+        assert [e["args"]["span_id"]
+                for e in trace_probe.by_request(doc, "b")["w-1"]] \
+            == [8, 9, 10]
+
+    @pytest.mark.parametrize("intervals, inside", [
+        ([], 0.0), ([(1.0, 2.0), (3.0, 4.0)], 2.0),
+        ([(1.0, 6.0), (2.0, 3.0), (6.0, 7.0)], 6.0),
+        ([(-5.0, 1.0), (9.0, 20.0)], 2.0)])
+    def test_union_inside(self, intervals, inside):
+        import trace_probe
+
+        assert trace_probe.union_inside((0.0, 10.0), intervals) \
+            == pytest.approx(inside)
+
+    def test_device_of_a_request(self):
+        import trace_probe
+
+        doc = self._events()
+        row = trace_probe.device_of(trace_probe.by_request(doc)["w-1"],
+                                    trace_probe.by_request(doc, "b")["w-1"])
+        assert row["busy_ms"] == pytest.approx(6.6)
+        assert row["idle_ms"] == pytest.approx(3.4)
+        assert row["by_kind_ms"] == pytest.approx(
+            {"run_chunk": 6.5, "decode_u8": 0.1})
+        assert (row["dispatches"], row["dry"], row["fences"], row["late"],
+                row["exact"]) == (3, 2, 3, 1, 2)
+        # a follower's tree (no section) and a parent's (no run): nothing
+        assert trace_probe.device_of([], []) is None
+        assert trace_probe.device_of(
+            trace_probe.by_request(doc)["w-1"], []) is None
+
+    def test_device_block_is_the_window_s_median(self, capsys):
+        import trace_probe
+
+        doc = self._events()
+        host, runs = (trace_probe.by_request(doc, ph) for ph in "Xb")
+        before = {"serving": {"device": {"requests": 1, "idle_s": 0.5,
+                                         "dispatches": {"run_chunk": 2}}}}
+        after = {"serving": {"device": {
+            "requests": 3, "idle_s": 0.75, "dispatches": {"run_chunk": 6},
+            "watcher": {"armed": False, "alive": False, "stamped": 0}}}}
+        out = trace_probe.device_block(host, runs, [before, after],
+                                       ["MainThread", "host-clock"])
+        assert out["requests"] == 1 and out["busy_ms"] == pytest.approx(6.6)
+        assert out["exact_share"] == pytest.approx(2 / 3)
+        assert out["serving_device"] == {"requests": 2, "idle_s": 0.25}
+        assert out["watcher"]["alive"] is False
+        assert out["threads"] == ["MainThread", "host-clock"]
+        assert capsys.readouterr().out.startswith("device: {")
+        assert trace_probe.device_block({}, {}, [], []) is None
+
 
 class TestFleetReport:
     """tools/fleet_report.py: the BENCH_fleet.json digest — per-class

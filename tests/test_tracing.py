@@ -8,6 +8,7 @@ CPU-only, tiny model: counts and shapes of the tree, never a time.
 import glob
 import http.client
 import json
+import threading
 import time
 import urllib.request
 
@@ -72,9 +73,12 @@ def get(server, route):
         return json.loads(r.read())
 
 
-def events_of(server, rid):
+def events_of(server, rid, ph="X"):
+    """The request's spans on the host's threads; ``ph`` "b": the ones
+    that ran on the device (``device.run``, an async pair each)."""
     doc = get(server, "/internal/trace.json")
-    return [e for e in doc["traceEvents"] if e["args"]["request_id"] == rid]
+    return [e for e in doc["traceEvents"]
+            if e["args"]["request_id"] == rid and e["ph"] == ph]
 
 
 @pytest.fixture(scope="module")
@@ -123,6 +127,12 @@ def served(tmp_path_factory):
                "txt2img": events_of(server, "trace-t2i"),
                "img2img": events_of(server, "trace-i2i"),
                "profiled": events_of(server, "trace-prof"),
+               "device": {which: events_of(server, rid, "b")
+                          for which, rid in (("txt2img", "trace-t2i"),
+                                             ("img2img", "trace-i2i"),
+                                             ("profiled", "trace-prof"))},
+               "status": get(server, "/internal/status"),
+               "threads": sorted(t.name for t in threading.enumerate()),
                "xplane": xplane}
     finally:
         server.stop()
@@ -296,6 +306,81 @@ class TestSpanTree:
     def test_warm_request_compiles_nothing(self, served):
         assert not [e for e in served["profiled"]
                     if e["name"] == "xla.compile"]
+
+
+#: kind of a ``device.run`` -> (the request that has it, the span that
+#: enqueued it, how many of them that request's tree holds: 4 steps in
+#: chunks of 2, img2img at strength 0.5 runs one chunk; an image)
+DEVICE_RUNS = {
+    "encode": ("txt2img", "text_encode", 2),
+    "run_chunk": ("txt2img", "chunk.enqueue", 2),
+    "decode_u8": ("txt2img", "vae_decode_dispatch", 1),
+    "vae_encode": ("img2img", "vae_encode", 1),
+}
+
+
+class TestDeviceRun:
+    @pytest.mark.parametrize("kind", sorted(DEVICE_RUNS))
+    def test_one_a_dispatch_under_the_span_that_enqueued_it(self, served,
+                                                            kind):
+        which, parent, count = DEVICE_RUNS[kind]
+        runs = [e for e in served["device"][which]
+                if e["args"]["kind"] == kind]
+        assert [e["name"] for e in runs] == ["device.run"] * count
+        ids = by_id(served[which])
+        parents = [ids[e["args"]["parent_id"]] for e in runs]
+        assert {p["name"] for p in parents} == {parent}
+        if kind != "encode":    # one span encodes both prompts
+            assert len({p["args"]["span_id"] for p in parents}) == count
+        assert all("dry" in p["args"] for p in parents)
+        for e in runs:
+            assert ("exact" in e["args"]) != ("bound" in e["args"])
+        if kind == "run_chunk":
+            assert [e["args"]["steps"] for e in runs] == [2, 2]
+
+    @pytest.mark.parametrize("which", ["txt2img", "img2img", "profiled"])
+    def test_they_follow_one_another_inside_the_device_section(self, served,
+                                                               which):
+        runs = sorted(served["device"][which], key=lambda e: e["ts"])
+        enqueues = [e for e in served[which] if "dry" in e["args"]]
+        assert len(runs) >= len(enqueues) >= 3
+        for a, b in zip(runs, runs[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"] + 1.0      # us
+        section, = (e for e in served[which]
+                    if e["name"] == "dispatch.device")
+        assert runs[0]["ts"] >= section["ts"]
+        assert runs[-1]["ts"] + runs[-1]["dur"] \
+            <= section["ts"] + section["dur"] + 1.0
+        # every fence says whether the device was done before the host
+        fences = [e for e in served[which]
+                  if e["name"] in ("chunk.fence_wait", "decode.wait")]
+        assert fences and all("late" in e["args"] for e in fences)
+
+    def test_a_capture_arms_the_watcher_and_nothing_else_does(self, served):
+        """While the profiler ran the watcher was handed the dispatches
+        (its thread exists from then on); it made no annotation: a
+        ``device.run`` is found after the fact."""
+        block = served["status"]["serving"]["device"]
+        assert block["watcher"]["armed"] is False
+        assert block["watcher"]["alive"] is True
+        assert "device-watcher" in served["threads"]
+        data = jax.profiler.ProfileData.from_file(served["xplane"])
+        names = {event.name for plane in data.planes
+                 for line in plane.lines for event in line.events}
+        assert "sdtpu:chunk.enqueue" in names
+        assert "sdtpu:device.run" not in names
+
+    def test_status_counts_the_three_requests(self, served):
+        block = served["status"]["serving"]["device"]
+        assert block["requests"] == 3
+        assert block["dispatches"]["run_chunk"] == 5
+        assert block["dispatches"]["decode_u8"] == 3
+        assert block["dispatches"]["vae_encode"] == 1
+        assert block["dispatches_total"] == sum(block["dispatches"].values())
+        assert set(block["busy_s"]) == set(block["dispatches"])
+        assert block["fences"] >= 8 and block["late_fences"] >= 0
+        assert 3 <= block["dry_enqueues"] <= block["dispatches_total"]
+        assert block["idle_s"] > 0 and block["busy_s_total"] > 0
 
 
 class TestProfilerClock:

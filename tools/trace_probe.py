@@ -1,7 +1,7 @@
 """One run of a benchmark cell, read through the program's own tracing.
 
     python3 tools/trace_probe.py --workload <cell> --seed <n> --seconds <s>
-        --trace <0|1> [--prepared] [--keep-slow] [--tag <name>]
+        --trace <0|1> [--prepared] [--keep-slow] [--watch] [--tag <name>]
 
 Runs ``benchmarks/run.py`` unchanged in this process (one process holds the
 chip) and keeps what that run reads and throws away: ``/internal/trace.json``
@@ -49,7 +49,26 @@ chip) and keeps what that run reads and throws away: ``/internal/trace.json``
   ``host.stall`` events of ``/internal/trace.json``, from the window's
   first request on), ``http_accept_ms`` and ``http_between_ms`` (the
   medians of ``http.accept`` and of ``http.between``, the gap a request
-  that found the server empty has beside its root);
+  that found the server empty has beside its root) and (PR 71)
+  ``stall_ms_no_request``: the ms of the window's stalls whose ``alive``
+  reads 0 (no request was alive when the clock woke: between two, or the
+  one that was had ended by then), which ``host_stall_ms`` cannot see;
+- ``device`` (PR 71; printed as the ``device:`` line): the device's side
+  of the window's untraced requests by the program's own ``device.run``
+  spans (obs/spans.py, profiler off): per request the median ms the device
+  ran (``busy_ms``: the union inside ``dispatch.device``; ``by_kind_ms``
+  the sums by executable) and had nothing of the request to run
+  (``idle_ms``), the ``dry`` enqueues and ``late`` fences a request,
+  ``exact_share`` of the stamps, ``serving.device`` over the window, and
+  ``threads``, the census taken while the server was up (a
+  ``device-watcher`` is there only after a capture, ``--watch`` or the
+  slow rule). With ``--trace 1`` also ``traced``: for each traced request
+  the program's busy and idle ms beside the xplane's busy time inside
+  the same ``dispatch.device`` (``sdtpu:dispatch.device`` on the
+  profiler's clock) and the difference as a share of the request.
+  ``--watch`` arms the device watcher for the whole run (what
+  ``/internal/trace.json?device=1`` does), so every stamp is exact: the
+  cost of that is what a pair of runs with and without it shows;
 - with ``--trace 1``: ``annotated_missing`` (spans of the traced request
   that are not on a host plane as ``sdtpu:<name>`` with its id), ``gaps``
   (the device's longest idle gaps in the slice, its head and its tail, each
@@ -132,10 +151,80 @@ def prepared_root() -> str:
     return root
 
 
-def by_request(trace_json: dict) -> dict:
+def by_request(trace_json: dict, ph: str = "X") -> dict:
+    """The requests' spans on the host's threads; ``ph`` "b": the ones
+    that ran on the device (``device.run``: an async pair, whose "b" event
+    carries ``dur``)."""
     out: dict = {}
     for event in trace_json.get("traceEvents", []):
-        out.setdefault(event["args"]["request_id"], []).append(event)
+        if event.get("ph", "X") == ph:
+            out.setdefault(event["args"]["request_id"], []).append(event)
+    return out
+
+
+def union_inside(section: tuple, intervals: list) -> float:
+    """us of ``section`` (start, end) that the intervals' union covers."""
+    reach, total = section[0], 0.0
+    for start, end in sorted(intervals):
+        start, end = max(start, reach), min(end, section[1])
+        if end > start:
+            total += end - start
+            reach = end
+    return total
+
+
+def device_of(events: list, runs: list):
+    """One request's device side: busy and idle ms inside its
+    ``dispatch.device``, busy by kind, dry enqueues, late fences, exact
+    stamps; None for a tree without the section or without a run."""
+    sections = [(e["ts"], e["ts"] + e["dur"]) for e in events
+                if e["name"] == "dispatch.device"]
+    if not sections or not runs:
+        return None
+    spans = [(r["ts"], r["ts"] + r["dur"]) for r in runs]
+    busy = sum(union_inside(sec, spans) for sec in sections)
+    by_kind: dict = {}
+    for r in runs:
+        kind = r["args"]["kind"]
+        by_kind[kind] = by_kind.get(kind, 0.0) + r["dur"] / 1e3
+    return {"busy_ms": busy / 1e3,
+            "idle_ms": (sum(b - a for a, b in sections) - busy) / 1e3,
+            "by_kind_ms": by_kind, "dispatches": len(runs),
+            "dry": sum(1 for r in runs if r["args"].get("dry")),
+            "fences": sum(1 for e in events if "late" in e["args"]),
+            "late": sum(1 for e in events if e["args"].get("late")),
+            "exact": sum(1 for r in runs if r["args"].get("exact"))}
+
+
+def device_block(host: dict, runs: dict, statuses: list, threads: list):
+    """The ``device`` entry (module docstring), printed as ``device:``;
+    None where no request of the window has a ``device.run``."""
+    rows = [row for row in (device_of(ev, runs.get(rid, []))
+                            for rid, ev in host.items()) if row]
+    if not rows:
+        return None
+    kinds = sorted({k for row in rows for k in row["by_kind_ms"]})
+    out = {key: statistics.median(row[key] for row in rows)
+           for key in ("busy_ms", "idle_ms", "dispatches", "dry", "fences",
+                       "late")}
+    out["requests"] = len(rows)
+    out["by_kind_ms"] = {k: statistics.median(
+        row["by_kind_ms"].get(k, 0.0) for row in rows) for k in kinds}
+    out["exact_share"] = sum(r["exact"] for r in rows) / max(
+        1, sum(r["dispatches"] for r in rows))
+    out["idle_ms_all"] = sorted(round(row["idle_ms"], 3) for row in rows)
+    before, after = ((s.get("serving") or {}).get("device")
+                     for s in (statuses + [{}, {}])[:2])
+    if before and after:
+        out["serving_device"] = {
+            k: after[k] - before[k] for k in after
+            if isinstance(after[k], (int, float))
+            and not isinstance(after[k], bool)}
+        out["watcher"] = after.get("watcher")
+    out["threads"] = threads
+    print("device: " + json.dumps({
+        k: round(v, 3) if isinstance(v, float) else v
+        for k, v in out.items() if k != "idle_ms_all"}))
     return out
 
 
@@ -198,8 +287,9 @@ def xla_delta(before: dict, after: dict) -> dict:
 
 
 def read_xplane(path: str, traced_events: list, summary: dict,
-                n_traced: int) -> dict:
-    """``summary``: what ``trace_reduce.reduce`` made of the same file."""
+                n_traced: int, traced_runs: list = ()) -> dict:
+    """``summary``: what ``trace_reduce.reduce`` made of the same file;
+    ``traced_runs``: the traced requests' ``device.run`` events."""
     from benchmarks.harness import files, trace_reduce, xplane_proto
 
     space = xplane_proto.read_xspace(path)
@@ -256,6 +346,49 @@ def read_xplane(path: str, traced_events: list, summary: dict,
     if merged and bounds:       # the load generator's marks bound the slice
         out["head"] = owner(bounds[0], merged[0][0])
         out["tail"] = owner(merged[-1][1], bounds[1])
+    # the program's device.run beside the device's own busy intervals,
+    # inside each traced request's dispatch.device on either clock
+    out["traced"] = []
+    for sec in (e for e in traced_events if e["name"] == "dispatch.device"):
+        rid = sec["args"]["request_id"]
+        mine = [s for s in spans if s[2] == "dispatch.device" and s[3] == rid
+                and s[4] == sec["args"]["span_id"]]
+        runs = [r for r in traced_runs if r["args"]["request_id"] == rid]
+        if not mine or not runs or not merged:
+            continue
+        xbusy = union_inside((mine[0][0], mine[0][1]), merged) / 1e6
+        section_ms = sec["dur"] / 1e3
+        pbusy = union_inside(
+            (sec["ts"], sec["ts"] + sec["dur"]),
+            [(r["ts"], r["ts"] + r["dur"]) for r in runs]) / 1e3
+        by_kind: dict = {}
+        for r in runs:
+            by_kind[r["args"]["kind"]] = by_kind.get(
+                r["args"]["kind"], 0.0) + r["dur"] / 1e3
+        request_ms = sum(e["dur"] for e in traced_events
+                         if e["args"]["request_id"] == rid
+                         and "parent_id" not in e["args"]
+                         and e["name"] not in ("http.between",
+                                               "http.accept")) / 1e3
+        row = {"request_id": rid, "request_ms": request_ms,
+               "section_ms": section_ms,
+               "section_ms_xplane": (mine[0][1] - mine[0][0]) / 1e6,
+               "program_busy_ms": pbusy, "xplane_busy_ms": xbusy,
+               "program_idle_ms": section_ms - pbusy,
+               "xplane_idle_ms": (mine[0][1] - mine[0][0]) / 1e6 - xbusy,
+               "busy_diff_share_of_request":
+                   (pbusy - xbusy) / request_ms if request_ms else None,
+               "program_by_kind_ms": by_kind,
+               "xplane_modules_ms": {k: v * 1e3 / max(1, n_traced)
+                                     for k, v in sorted(
+                                         summary.get("modules", {}).items(),
+                                         key=lambda kv: -kv[1])[:8]},
+               "exact": sum(1 for r in runs if r["args"].get("exact")),
+               "dispatches": len(runs)}
+        out["traced"].append(row)
+        print("traced device: " + json.dumps(
+            {k: round(v, 3) if isinstance(v, float) else v
+             for k, v in row.items()}))
     if spans and bounds and n_traced:
         spec = bench.read("idle_classes", "request.json")
         by_name = reader.idle_by_name(
@@ -295,6 +428,10 @@ def host_block(statuses: list, gc_seconds: list, stalls: list,
         {"ms": e["dur"] / 1e3, "at_ms": (e["ts"] - first) / 1e3,
          "requests": e["args"]["requests"], "spans": e["args"]["spans"]}
         for e in stalls if e["ts"] >= first]
+    out["stall_ms_no_request"] = sum(
+        row["ms"] for row, e in zip(out["stalls_in_window"],
+                                    (e for e in stalls if e["ts"] >= first))
+        if e["args"].get("alive") == 0)
     out["http_accept_ms"] = medians.get("http.accept")
     out["http_between_ms"] = medians.get("http.between")
     print("host: " + json.dumps({k: round(v, 3) if isinstance(v, float)
@@ -309,6 +446,14 @@ def print_live(live: dict) -> None:
     for stall in live["stalls"]:
         print(f"    stall {stall['dur'] / 1e3:.1f} ms under "
               f"{stall['args']['spans']}")
+    for row in live.get("device", ()):      # PR 71: what it had enqueued
+        came = "not ready by the request's end" if row["ready_ms"] is None \
+            else (f"ready at {row['ready_ms']:.1f} ms"
+                  + ("" if row["exact"] else " or before"))
+        print(f"    device {row['kind']} enqueued at "
+              f"{row['enqueued_ms']:.1f} ms, "
+              + ("ready" if row.get("ready_at_sample", row["ready"])
+                 else "NOT ready") + f" at the sample; {came}")
     owner = live["open"][0]["thread"] if live["open"] else None
     stacks = re.split(r"(?m)^(?=Thread )", live["stacks"])
     # the thread that owns the innermost open span first (a stable sort)
@@ -326,9 +471,10 @@ def slow_entries(medians: dict) -> list:
     for entry in flightrec.RECORDER.dump()["entries"]:
         if entry["reason"] != "slow":
             continue
-        times = collections.Counter(e["name"] for e in entry["spans"])
+        host = [e for e in entry["spans"] if e.get("ph", "X") == "X"]
+        times = collections.Counter(e["name"] for e in host)
         rows = [row + [row[2] - medians.get(row[1], 0.0) / times[row[1]]]
-                for row in tree(entry["spans"])]
+                for row in tree(host)]
         out.append({"request_id": entry["request_id"],
                     "detail": entry["detail"], "tree": rows,
                     "live": entry.get("live")})
@@ -376,6 +522,7 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--prepared", action="store_true")
     ap.add_argument("--keep-slow", action="store_true")
+    ap.add_argument("--watch", action="store_true")
     ap.add_argument("--tag", default="")
     args = ap.parse_args(argv)
     root = prepared_root() if args.prepared else REPO
@@ -383,14 +530,21 @@ def main(argv=None) -> int:
 
     import benchmarks.run as run
     from benchmarks.harness import loadgen, trace_reduce
-    from stable_diffusion_webui_distributed_tpu.obs import prometheus
+    from stable_diffusion_webui_distributed_tpu.obs import prometheus, spans
 
+    if args.watch and hasattr(spans.TRACER, "armed"):
+        spans.TRACER.armed = True
     fetched: dict = {}
     get_json = loadgen.get_json
 
     def keeping_get_json(base, route):
         out = get_json(base, route)
         fetched.setdefault(route, []).append(out)
+        if route == "/internal/trace.json":     # the server is still up
+            import threading
+
+            fetched["threads"] = sorted(
+                t.name for t in threading.enumerate())
         if route == "/internal/status":     # what the block holds by now
             gc_seconds = getattr(prometheus, "GC_PAUSE_COUNTER", None)
             fetched.setdefault("gc_seconds", []).append(
@@ -431,7 +585,9 @@ def main(argv=None) -> int:
         out["xla_window"] = xla_delta(before, after)
         out["programs_setup"] = statuses[0]["serving"].get("programs")
         print(f"programs: {json.dumps(out['programs_setup'])}")
-    requests = by_request((fetched.get("/internal/trace.json") or [{}])[-1])
+    trace_json = (fetched.get("/internal/trace.json") or [{}])[-1]
+    requests = by_request(trace_json)
+    runs = by_request(trace_json, "b")
     window = {rid: ev for rid, ev in requests.items()
               if rid.startswith("w-")}
     n_traced = 0
@@ -441,7 +597,9 @@ def main(argv=None) -> int:
         n_traced = int(traced[0].split()[2]) if traced else 0
         first = [e for i in range(n_traced)
                  for e in window.get(f"w-{i}", [])]
-        out.update(read_xplane(xplane, first, fetched["reduce"], n_traced))
+        out.update(read_xplane(
+            xplane, first, fetched["reduce"], n_traced,
+            [r for i in range(n_traced) for r in runs.get(f"w-{i}", [])]))
     shutil.rmtree(kept, ignore_errors=True)
     untraced = {rid: ev for rid, ev in window.items()
                 if int(rid[2:]) >= n_traced} or window
@@ -472,6 +630,8 @@ def main(argv=None) -> int:
                  for rid, ev in window.items()}
     out["host"] = host_block(statuses, fetched.get("gc_seconds", []), stalls,
                              exchanges, out["span_median_ms"])
+    out["device"] = device_block(untraced, runs, statuses,
+                                 fetched.get("threads", []))
     if args.keep_slow:
         out["slow"] = slow_entries(out["span_median_ms"])
         out["timeline"] = timeline(exchanges, stalls)

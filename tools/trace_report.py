@@ -28,20 +28,17 @@ from typing import Any, Dict, List, Optional, Tuple
 def load_events(data: Any) -> List[Dict[str, Any]]:
     """Extract trace events from any of the three artifact shapes:
     ``{"traceEvents": [...]}``, a flight-recorder dump ``{"entries": [{...,
-    "spans": [...]}]}``, or a bare event list."""
-    if isinstance(data, list):
-        return [e for e in data if isinstance(e, dict)]
-    if not isinstance(data, dict):
+    "spans": [...]}]}``, or a bare event list. A span that ran on the
+    device (``device.run``) is an async pair: its "b" half carries ``dur``
+    and stands for it, its "e" half is dropped."""
+    if isinstance(data, dict) and "traceEvents" in data:
+        data = data["traceEvents"]
+    elif isinstance(data, dict):
+        data = [e for entry in data.get("entries", [])
+                for e in entry.get("spans", [])]
+    if not isinstance(data, list):
         return []
-    if "traceEvents" in data:
-        return [e for e in data["traceEvents"] if isinstance(e, dict)]
-    if "entries" in data:
-        events: List[Dict[str, Any]] = []
-        for entry in data["entries"]:
-            events.extend(e for e in entry.get("spans", [])
-                          if isinstance(e, dict))
-        return events
-    return []
+    return [e for e in data if isinstance(e, dict) and e.get("ph") != "e"]
 
 
 def group_requests(events: List[Dict[str, Any]]
