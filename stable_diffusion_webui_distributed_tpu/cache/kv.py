@@ -56,6 +56,21 @@ layer a sequence at 64 value heads of 128 x 128 and 3 x 16 384 kept
 inputs). The copies are made in the fork's one executable; the prefill's
 own state is let go with the cache it came in.
 
+The sequences of a step may also continue DIFFERENT prompts behind the one
+instruction: the requests of a dispatch group (serving/dispatcher.py,
+pipeline/expand.py:expand_group). What they share is then the instruction's
+rows alone, and what a fork leaves in the shared range, a prompt's rows,
+is each sequence's own: :func:`joined_rows` makes, from the sequences'
+one-sequence caches after their prompts' prefills, own rows that begin with
+the prompt's (right-aligned in one region as wide as the widest prompt's
+chunk, so that every sequence's decode slots begin at the same slot and a
+step still writes one slot for all), each sequence's copy of what keeps no
+positions, and a vector that says where each one's real rows begin
+(``lm.OWN_FROM``); :func:`forked` puts them behind a cache that stands at
+the instruction's end, exactly as it puts a fork's. The scan and the step
+are the fork's own: a sequence's position is the step's common one less
+its offset.
+
 A looped model (``LMConfig.total_ut_steps`` over 1) passes a token through
 its whole stack several times over one set of weights, and pass ``t`` of a
 layer attends what pass ``t`` wrote for the earlier positions. So a full
@@ -129,6 +144,55 @@ def own_rows(cache: Dict, sequences: int, slots: int = 0) -> Dict:
     own = {name: [rows(name, x) for x in cache[name]]
            for name in sorted(cache)}
     own[lm.FORKED_AT] = [jnp.full((sequences, 1), -1, jnp.int32)]
+    return own
+
+
+def joined_rows(caches: Sequence[Dict], lengths: jax.Array, forked_at,
+                region: int, slots: int) -> Dict:
+    """What a JOIN makes anew, as :func:`own_rows` does for a fork: the own
+    rows of ``len(caches)`` sequences that continue prompts of their OWN
+    behind one shared range. ``caches[b]`` is sequence ``b``'s one-sequence
+    cache after its prompt's prefill: the shared range's rows (the kept
+    instruction's, positions before ``forked_at``) and behind them its
+    prompt's ``lengths[b]``. Of each buffer that keeps positions a
+    sequence gets ``region + slots`` rows: its prompt's rows RIGHT-aligned
+    in the first ``region`` (``region`` at least the longest prompt: the
+    widest chunk bucket), then ``slots`` empty ones for what it decodes.
+    Right-aligned, every sequence's first decoded row falls on slot
+    ``region`` and a step writes ONE slot for all of them; what differs is
+    each sequence's first real slot, ``region - lengths[b]``, which the
+    cache carries as ``lm.OWN_FROM`` ``(sequences, 1)``, and a sequence's
+    position is the step's common one less that. Slot ``j`` of sequence
+    ``b`` is its position ``forked_at + j - own_from[b]``, read from the
+    slot of its buffer, or of its ring, that holds it; a slot in front of
+    ``own_from[b]``, and a ring's row that has left the window, holds some
+    other row, which no query sees (models/lm.py:own_positions, the
+    window). What keeps no positions is each sequence's own copy, from its
+    own cache. The fork's position is known: ``forked_at`` for all. The
+    shared range is not made here: :func:`forked` takes it from a cache
+    that stands at ``forked_at`` (a copy of the kept snapshot)."""
+    own_from = region - lengths.astype(jnp.int32)
+
+    def rows(name, buffers):
+        axis = lm.slots_axis(name)
+        if axis is None:        # no positions: every sequence its own
+            return jnp.stack(buffers)
+        first = buffers[0]
+        held = first.shape[axis]
+        taken = [jnp.take(x, (forked_at - own_from[b]
+                              + jnp.arange(region)) % held, axis=axis)
+                 for b, x in enumerate(buffers)]
+        shape = list(first.shape)
+        shape[axis] = slots
+        empty = jnp.zeros((len(buffers), *shape), first.dtype)
+        return jnp.concatenate([jnp.stack(taken), empty],
+                               axis=axis % first.ndim + 1)
+
+    own = {name: [rows(name, layer) for layer in zip(
+               *(cache[name] for cache in caches))]
+           for name in sorted(caches[0])}
+    own[lm.FORKED_AT] = [jnp.full((len(caches), 1), forked_at, jnp.int32)]
+    own[lm.OWN_FROM] = [own_from[:, None]]
     return own
 
 

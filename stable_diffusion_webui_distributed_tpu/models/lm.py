@@ -112,7 +112,15 @@ the sequences apart, and read what they share once. That holds for
 the ``full``, ``sliding``, ``latent``, ``linear`` and ``ssm`` kinds of one
 stream
 (:func:`shares_a_step`); a model with conv layers' kept rows or several
-residual streams decodes one sequence a step.
+residual streams decodes one sequence a step. The sequences of a step may
+also continue prompts of their OWN behind one shared range (the kept
+instruction; a JOINED cache, cache/kv.py:joined_rows, which carries
+:data:`OWN_FROM`): ``start`` is then the step's common position, which
+says where every sequence writes, and sequence ``b`` stands ``own_from[b]``
+short of it in everything that reads a position: its rotation, its
+window's reach into the shared range, the positions of its own rows
+(:func:`own_positions`) and the key of its draw. Which cache a step was
+handed is read off the cache; the fork's trace has none of the join's ops.
 
 A LOOPED model (``LMConfig.total_ut_steps`` over 1: full attention, dense
 MLPs, one stream) passes every token through its whole stack that many
@@ -188,6 +196,13 @@ SHARED_OF = dict(zip(ATTENTION_BUFFERS + LATENT_BUFFERS,
                      SHARED_BUFFERS + LATENT_SHARED))
 #: and the cache one entry that is no layer's: the position of the fork
 FORKED_AT = "forked_at"
+#: a JOINED cache (cache/kv.py:joined_rows) has one more: each sequence's
+#: first real own slot, ``(sequences, 1)``. Its sequences continue prompts
+#: of their own behind the one shared range (the kept instruction), each
+#: prompt's rows right-aligned in front of its sequence's decode slots, so
+#: that every sequence writes the same slot at a step; sequence ``b`` then
+#: stands ``own_from[b]`` positions short of the step's common position
+OWN_FROM = "own_from"
 
 
 def buffers_of(kind: str, forked: bool = False) -> Tuple[str, ...]:
@@ -271,6 +286,19 @@ def latent_form(tokens: int, sequences: bool = False) -> str:
     if sequences:
         return LATENT_FORKED
     return LATENT_ABSORBED if tokens == 1 else LATENT_EXPANDED
+
+
+def own_positions(own_pos: jax.Array, forked_at, own_from: jax.Array):
+    """``(B, slots)``: the position each sequence of a joined cache holds
+    in each of its own slots, from ``own_pos`` ``(slots,)``, the COMMON
+    position that fell on the slot (the step's position is common to the
+    sequences, and so is the slot a step writes). Sequence ``b`` stands
+    ``own_from[b]`` short of it, and its slots in front of ``own_from[b]``
+    (the pad in front of its right-aligned prompt) hold nothing:
+    negative."""
+    common = own_pos[None, :]
+    return jnp.where(common - forked_at >= own_from[:, None],
+                     common - own_from[:, None], -1)
 
 
 # -- rotary embeddings -------------------------------------------------------
@@ -536,13 +564,17 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, n, q_pos, start, end, k_cache, v_cache,
                  k_shared=None, v_shared=None, sequences: bool = False,
-                 pass_index=None, forked_at=None):
+                 pass_index=None, forked_at=None, own_from=None):
         """``pass_index``: which pass of a looped stack this is; the
         buffers then carry the pass axis just before their slots' and the
         chunk writes and attends that pass's rows alone. ``sequences``:
         the buffers are a forked cache's, ``k_cache`` and ``v_cache`` each
         sequence's own rows from position ``forked_at`` on; the shared
-        ones are returned behind them as they came."""
+        ones are returned behind them as they came. ``own_from`` ``(B,)``:
+        the cache is a joined one (:data:`OWN_FROM`), ``start`` the step's
+        common position and ``q_pos`` each sequence's own, ``start -
+        own_from``; an own slot then holds another position a sequence and
+        none in front of the sequence's first."""
         cfg = self.config
         kind = self.kind or cfg.layer_types[self.layer]
         heads = cfg.num_heads_per_layer[self.layer]
@@ -639,7 +671,9 @@ class Attention(nn.Module):
             out, path = attend_two_ranges(
                 q, of_pass(k_shared, 0), of_pass(v_shared, 0),
                 of_pass(k_cache, 1), of_pass(v_cache, 1), q_pos, shared_pos,
-                jnp.where(own_pos >= forked_at, own_pos, -1),
+                jnp.where(own_pos >= forked_at, own_pos, -1)
+                if own_from is None else
+                own_positions(own_pos, forked_at, own_from),
                 scale=scale, window=window)
             ATTENTION.record(path, 1, shared + own, dim, passes)
         elif kind == FULL:
@@ -733,7 +767,7 @@ class LatentAttention(nn.Module):
 
     @nn.compact
     def __call__(self, n, q_pos, start, end, cache, shared=None,
-                 sequences: bool = False, forked_at=None):
+                 sequences: bool = False, forked_at=None, own_from=None):
         cfg = self.config
         heads = cfg.num_heads_per_layer[self.layer]
         rank, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
@@ -788,7 +822,10 @@ class LatentAttention(nn.Module):
             slots = jnp.arange(shared.shape[0])
             k_pos = jnp.where(slots < forked_at, slots, -1)
             own_pos = start - (behind - jnp.arange(own)) % own
-            own_pos = jnp.where(own_pos >= forked_at, own_pos, -1)
+            if own_from is None:
+                own_pos = jnp.where(own_pos >= forked_at, own_pos, -1)
+            else:       # a joined cache: a position a sequence a slot
+                own_pos = own_positions(own_pos, forked_at, own_from)
         else:
             # written first: a padded row lands beyond ``end``
             cache = jax.lax.dynamic_update_slice_in_dim(
@@ -1244,14 +1281,15 @@ class DecoderLayer(nn.Module):
     @nn.compact
     def __call__(self, x, q_pos, start, end, buffers,
                  sequences: bool = False, real=None, pass_index=None,
-                 forked_at=None, carried=None):
+                 forked_at=None, carried=None, own_from=None):
         """``(x, buffers, routed)``. ``buffers`` are the layer's own of
         the cache (:func:`buffers_of`
         its kind), returned as the chunk leaves them. ``x`` is ``(T,
         hidden)``, or ``(T, streams, hidden)`` with several streams.
         ``sequences``: the rows are one token each of as many sequences
         (:func:`shares_a_step`), the buffers a forked cache's, forked at
-        position ``forked_at``, and ``real`` says which rows count.
+        position ``forked_at``, and ``real`` says which rows count;
+        ``own_from``: a joined one's (:data:`OWN_FROM`).
         ``pass_index``: the pass of a looped stack, whose rows of the
         buffers the layer then takes. ``carried``: the routed sum the
         expert layer before this one left for it
@@ -1294,14 +1332,15 @@ class DecoderLayer(nn.Module):
                 mixed, *after = LatentAttention(
                     cfg, self.layer, self.dtype, self.quant, name="attn")(
                         n, q_pos, start, end, *buffers,
-                        sequences=sequences, forked_at=forked_at)
+                        sequences=sequences, forked_at=forked_at,
+                        own_from=own_from)
             else:
                 mixed, *after = Attention(
                     cfg, self.layer, self.dtype, self.quant, kind,
                     name="attn")(
                         n, q_pos, start, end, *buffers,
                         sequences=sequences, pass_index=pass_index,
-                        forked_at=forked_at)
+                        forked_at=forked_at, own_from=own_from)
             if behind != 1.0:
                 mixed = mixed * behind
             return mixed, tuple(after)
@@ -1429,18 +1468,22 @@ class DecoderLM(nn.Module):
                 raise ValueError("a step of several sequences wants full, "
                                  "sliding, latent, linear or ssm mixers "
                                  "and one stream")
-            q_pos = jnp.full(tokens.shape, start, jnp.int32)
+            cache = dict(cache)
+            (lead,) = cache.pop(OWN_FROM, (None,))
+            # a joined cache: every sequence its own position
+            own_from = None if lead is None else lead[:, 0]
+            q_pos = jnp.full(tokens.shape, start, jnp.int32) \
+                if lead is None else start - own_from
             end, all_logits = start + 1, True
             real = jnp.arange(tokens.shape[0]) < length
             # a fork does not know where it stands: its first step does
-            cache = dict(cache)
             (stamp,) = cache.pop(FORKED_AT)
             forked_at = jnp.where(stamp[0, 0] < 0, start, stamp[0, 0])
         else:
             q_pos = start + jnp.arange(tokens.shape[0], dtype=jnp.int32)
             end = start + length
             real = None     # a layer's own: the rows before ``end``
-            forked_at = None
+            forked_at = own_from = None
         # the table is sharded over the vocabulary: an id another chip
         # holds gets nothing here (their parts are summed in a deployment)
         first, count = cfg.vocab
@@ -1467,7 +1510,7 @@ class DecoderLM(nn.Module):
             return layer_module(layer, name=f"layers_{layer}")(
                 x, q_pos, start, end, buffers, sequences=sequences,
                 real=real, pass_index=pass_index, forked_at=forked_at,
-                carried=carried)
+                carried=carried, own_from=own_from)
 
         def stack(apply_layer, x, cache, pass_index=None):
             """(x, the cache, what the expert layers routed) after every
@@ -1517,7 +1560,8 @@ class DecoderLM(nn.Module):
             def looped_layer(p, x, buffers, pass_index, *where):
                 return one.apply({"params": p}, x, *where, buffers,
                                  sequences=sequences, real=real,
-                                 pass_index=pass_index, forked_at=forked_at)
+                                 pass_index=pass_index, forked_at=forked_at,
+                                 own_from=own_from)
 
             def shared_layer(layer, x, buffers, pass_index):
                 return looped_layer(params[f"layers_{layer}"], x, buffers,
@@ -1538,6 +1582,8 @@ class DecoderLM(nn.Module):
             n = self.read_pass(h, None if all_logits else length)
         if sequences:
             cache[FORKED_AT] = [jnp.full_like(stamp, forked_at)]
+            if lead is not None:
+                cache[OWN_FROM] = [lead]
         if cfg.tied_head:
             logits = self.tied_logits(table.embedding, n)
             EXPANDER.record_tied_head(
@@ -1683,9 +1729,11 @@ def sample_each(logits: jax.Array, keys: jax.Array, position, temperature,
     """:func:`sample` for each of ``keys`` ``(B,)``: from its own row of
     ``logits`` ``(B, V)``, or every key from the one row ``(V,)``. A draw is
     what :func:`sample` gives that key alone, whatever its place among the
-    ``B``."""
+    ``B``. ``position`` is one for all, or ``(B,)``: each sequence's own
+    (the sequences of a joined cache stand at different positions)."""
     return jax.vmap(sample, in_axes=(0 if logits.ndim == 2 else None, 0,
-                                     None, None, None))(
+                                     0 if jnp.ndim(position) else None,
+                                     None, None))(
         logits, keys, position, temperature, first)
 
 
@@ -1799,7 +1847,12 @@ def decode_sequences_fn(module: DecoderLM, steps: int):
     temperature, live) -> (cache, tokens, position, the steps' tokens
     (steps, B), routed load, none held, experts read)``. ``tokens`` and
     ``keys`` are ``(B,)``, ``cache`` a forked one (cache/kv.py:fork), and
-    all sequences sit at the one ``position``. Only the first ``live`` count:
+    all sequences sit at the one ``position``; or a joined one
+    (cache/kv.py:joined_rows; read off the cache: it has :data:`OWN_FROM`),
+    whose sequences continue prompts of their own: ``position`` is then
+    the step's common one and sequence ``b`` stands ``own_from[b]`` short
+    of it, in its rotation, its window and the key of its draw, so that
+    it draws what it draws alone. Only the first ``live`` count:
     the others pad ``B`` up to a size an executable exists for, repeat a
     live one (so they choose no expert of their own) and are left out of
     the load. ``experts read`` ``(expert
@@ -1820,6 +1873,8 @@ def decode_sequences_fn(module: DecoderLM, steps: int):
         looped = len(no_exits(cfg))
         layers = len(cfg.expert_layers)
         calls = int(bool(layers))       # whether ``calls unread`` is carried
+        # a joined cache: how far each sequence stands short of the step
+        short = cache[OWN_FROM][0][:, 0] if OWN_FROM in cache else None
 
         def step(carry, _):
             cache, tokens, position, load, none_held, read, *rest = carry
@@ -1828,8 +1883,10 @@ def decode_sequences_fn(module: DecoderLM, steps: int):
             logits, cache, routed, more = apply_counting(
                 module, variables, tokens, position, live, cache,
                 sequences=True, live=live)
-            tokens = sample_each(logits, keys, position + 1, temperature,
-                                 cfg.vocab[0])
+            tokens = sample_each(
+                logits, keys,
+                position + 1 if short is None else position + 1 - short,
+                temperature, cfg.vocab[0])
             return (cache, tokens, position + 1, load + routed[1],
                     none_held + routed[2],
                     read + moe.experts_read(routed[1])) \

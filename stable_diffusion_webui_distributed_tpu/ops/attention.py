@@ -150,9 +150,15 @@ def _weights(scores: jax.Array, seen: jax.Array) -> jax.Array:
 
 
 def _seen(q_pos: jax.Array, k_pos: jax.Array, window: int) -> jax.Array:
-    """``(T, S)``: whether query ``i`` sees key ``j``."""
-    delta = q_pos[:, None] - k_pos[None, :]
-    seen = (delta >= 0) & (k_pos[None, :] >= 0)
+    """``(T, S)``: whether query ``i`` sees key ``j``. ``k_pos`` ``(T, S)``:
+    the keys' positions as query ``i`` counts them (each query a sequence
+    with rows of its own: :func:`attend_two_ranges`)."""
+    if k_pos.ndim == 2:
+        delta = q_pos[:, None] - k_pos
+        seen = (delta >= 0) & (k_pos >= 0)
+    else:
+        delta = q_pos[:, None] - k_pos[None, :]
+        seen = (delta >= 0) & (k_pos[None, :] >= 0)
     if window:
         seen &= delta < window
     return seen
@@ -197,7 +203,9 @@ def attend_two_ranges(q: jax.Array, k_shared: jax.Array, v_shared: jax.Array,
     ``v_shared``, which every sequence attends, and ``k_own`` ``(B, T, KV,
     D)`` and ``v_own``, sequence ``b``'s own rows. ``q`` is ``(B, H, D)``,
     ``q_pos`` ``(B,)``, ``shared_pos`` ``(S,)`` and ``own_pos`` ``(T,)``
-    (slot ``j`` of every sequence's own rows holds the same position).
+    (slot ``j`` of every sequence's own rows holds the same position) or
+    ``(B, T)`` (a position a sequence a slot: sequences that stand at
+    different positions, models/lm.py:own_positions).
     Sequence ``b`` gets what ``attend_positions`` gives its query over the
     shared keys followed by its own.
 

@@ -35,12 +35,23 @@ per-image prompts, adaptive samplers — the DPM adaptive controller
 consumes ONE error norm over the whole batch, so merging would change
 pixels) run solo under the same execution lock, still shape-bucketed when
 possible.
+
+Requests whose prompts the resident language model rewrites first (the
+``prompt expansion`` script, pipeline/expand.py) merge like any other
+where the expander can decode several sequences a step and the batch
+ladder has a rung for a second request: the script's arguments are part of
+the group key, so a group has one instruction and one token budget, and
+the group's ``expand`` stage decodes every live ticket's images as the
+sequences of ONE scan (``PromptExpander.expand_group``) before the
+per-ticket encodes. Each image is keyed by its own request's seed and its
+own index, so it gets the text it gets alone.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import threading
 import time
 import uuid
@@ -73,6 +84,8 @@ from stable_diffusion_webui_distributed_tpu.serving.bucketer import (
 from stable_diffusion_webui_distributed_tpu.serving.metrics import METRICS
 
 DEFAULT_COALESCE_WINDOW = 0.05
+#: where a group key holds the expansion script's arguments (``_group_key``)
+EXPANSION_AT = 11
 
 #: Sanctioned chaos-injection hook (sim/chaos.py). When armed, it is
 #: consulted once per submitted request (after seed fixing, before any
@@ -576,13 +589,29 @@ class ServingDispatcher:
             return False
         if self.engine.family.inpaint:
             return False
-        if getattr(self.engine, "expander", None) is not None \
-                and prompt_expansion_args(p) is not None:
-            # an expanded request runs its own token loop before the
-            # denoise and ends with its own prompt: it never shares a
-            # dispatch, with a plain request or with another expanded one
-            return False
+        if self._expansion(p) is not None:
+            # an expanded request runs a token loop before the denoise and
+            # ends with its own prompt. It shares a dispatch, and the
+            # group's ONE decode scan, with other expanded requests of
+            # equal script arguments (``_group_key``) where (a) the
+            # resident expander can decode several sequences a step and
+            # (b) the batch ladder has a rung that holds a second request
+            # of its size; elsewhere it runs solo, its images the
+            # sequences of its own scan (engine._expand_prompts). It
+            # never joins a plain request: the key keeps them apart.
+            return (self.engine.expander.shares_a_step
+                    and 2 * p.total_images <= self.max_batch)
         return p.total_images <= self.max_batch
+
+    def _expansion(self, p):
+        """The request's ``prompt expansion`` arguments where this worker
+        has a resident expander to run them, else None: a plain request.
+        Tolerates ``self`` being None / engineless, as
+        :meth:`_traced_rowspec` does."""
+        engine = getattr(self, "engine", None)
+        if getattr(engine, "expander", None) is None:
+            return None
+        return prompt_expansion_args(p)
 
     def _ragged_eligible(self, p) -> bool:
         """May this payload run ragged (SDTPU_RAGGED)? The coalescable
@@ -593,7 +622,8 @@ class ServingDispatcher:
             stepcache,
         )
 
-        if stepcache.resolve(p).active:
+        if stepcache.resolve(p).active or self._expansion(p) is not None:
+            # (an expanded request's true context length waits for its text)
             return False
         return self._coalescable(p)
 
@@ -630,13 +660,22 @@ class ServingDispatcher:
         # untouched, while any two adapter combos in one cell share a
         # group — the adapter NAMES never enter the key (they are traced
         # inputs, not executable identity).
+        # The expansion script's arguments sit at key[EXPANSION_AT], in
+        # front of the adapter cell: None for a plain request, so a plain
+        # request never joins an expanded one, and a group of expanded
+        # requests has ONE instruction (one kept prefix), one token budget,
+        # one temperature and one context length: its scan is one program
+        # over one shared range (pipeline/expand.py:expand_group).
         sc = stepcache.resolve(run)
         rs = ServingDispatcher._traced_rowspec(self, run) or (0, 0)
+        expansion = ServingDispatcher._expansion(self, run)
         return ("txt2img", run.sampler_name, int(run.steps),
                 int(run.width), int(run.height), float(run.cfg_scale),
                 run.negative_prompt or "", int(run.clip_skip or 0),
                 sc.cadence, sc.cutoff_sigma,
                 bool((run.override_settings or {}).get("ragged_true_wh")),
+                None if expansion is None
+                else tuple(expansion.model_dump().values()),
                 int(rs[0]), int(rs[1]),
                 ServingDispatcher._precision_name(self, run))
 
@@ -969,16 +1008,67 @@ class ServingDispatcher:
 
     def _execute_group(self, g: _Group) -> None:
         """A group's four stages, back to back on the calling thread."""
-        with obs_spans.span("prepare", requests=len(g.tickets)):
-            built = self._group_build_inputs(g)
-        if built is None:
-            return
-        latents = self._group_denoise(g, built)
-        entries = self._group_decode(g, built, latents)
-        self._group_merge(g, built, entries)
+        later: list = []    # an expansion's counters' fetches
+        try:
+            with obs_spans.span("prepare", requests=len(g.tickets)):
+                built = self._group_build_inputs(g, later)
+            if built is None:
+                return
+            latents = self._group_denoise(g, built)
+            entries = self._group_decode(g, built, latents)
+            self._group_merge(g, built, entries)
+        finally:
+            # where no chunk was enqueued to run them under (an error, an
+            # interrupt): /internal/status read after a group shows it
+            self._run_each(later)
 
-    def _group_build_inputs(self, g: _Group) -> Optional[Dict]:
-        """Encode stage: cancellation filter, per-ticket prompt encodes +
+    @staticmethod
+    def _run_each(later: list) -> None:
+        """Every fetch an expansion left for later, once."""
+        while later:
+            later.pop(0)()
+
+    def _group_expand(self, live: List[Ticket], runs: list, meanwhile,
+                      later: list) -> list:
+        """The group's ``expand`` stage: every live ticket's images are
+        the sequences of one decode scan behind the group's one
+        instruction (``PromptExpander.expand_group``: at most the largest
+        of cache/kv.py:SEQUENCE_BUCKETS a scan, padded up to the group's
+        rung as its UNet rows are), each keyed by ITS request's seed and
+        image index. ``runs`` (the tickets' execution payloads, copies)
+        get their texts as ``engine._expand_prompts`` gives a solo
+        request its: the prompt, or a prompt an image, and the script's
+        context length. Returns the tickets' user-visible payloads with
+        the same texts, for the galleries' infotexts. ``meanwhile`` runs
+        under the first decode chunk; the counters' fetch goes to
+        ``later``."""
+        engine = self._engine()
+        expansion = prompt_expansion_args(runs[0])
+        members = [(p.prompt, p.seed,
+                    [0 if p.same_seed else i
+                     for i in range(p.total_images)]) for p in runs]
+        texts = engine.expander.expand_group(
+            members, expansion,
+            rows=self.bucketer.bucket_batch(sum(p.total_images
+                                                for p in runs)),
+            meanwhile=meanwhile, later=later)
+        shown = []
+        for t, p, mine in zip(live, runs, texts):
+            seen = t.payload.model_copy()
+            for payload in (p, seen):
+                if len(mine) == 1:
+                    payload.prompt = mine[0]
+                else:
+                    payload.all_prompts = list(mine)
+                if expansion.context_chunks:
+                    payload.context_chunks = int(expansion.context_chunks)
+            shown.append(seen)
+        return shown
+
+    def _group_build_inputs(self, g: _Group,
+                            later: list) -> Optional[Dict]:
+        """Encode stage: cancellation filter, the group's ``expand`` stage
+        where its requests are expanded ones, per-ticket prompt encodes +
         noise draws, batch concat, pad-and-drop, LoRA row stacking, and
         the initial latent placement. Returns the denoise/decode/merge
         inputs, or None when no ticket is still live."""
@@ -1025,26 +1115,53 @@ class ServingDispatcher:
                 lora as lora_mod,
             )
 
-        # context length pinned to the group max so every merged request
-        # pads its conditioning identically (same contract the fleet pins
-        # via payload.context_chunks)
-        chunks = max(engine.request_context_chunks(p)
-                     for p in (t.run for t in live))
+        f = engine.family.vae_scale_factor
         # ragged group (SDTPU_RAGGED, a _group_key axis — uniform across
         # the group): every ticket carries its true shape in the marker,
         # noise is drawn at the TRUE latent rows and zero-padded to the
         # shared bucket, and the per-row true lengths ride into the
         # denoise as traced vectors — heterogeneous shapes, one executable
         ragged_mode = engine._ragged_plan(rp) is not None
-        f = engine.family.vae_scale_factor
+        runs = [t.run.model_copy() for t in live]
+        shown = [t.payload for t in live]   # what a gallery's infotext says
+        ahead: Dict[int, tuple] = {}    # a ticket's place -> its draw
+        pinned = 0      # chunks an expansion script pins the context to
+
+        def draw(p):
+            """(noise, true latent rows) of one ticket: what reads nothing
+            of its prompt's text."""
+            tr = h      # ragged: the TRUE latent rows, zero-padded to h
+            if ragged_mode:
+                tw, th = engine._ragged_plan(p) or (width, height)
+                tr = min(h, -(-th // f))
+            with obs_spans.span("noise"):
+                return rng.batch_noise(
+                    p.seed, p.subseed, p.subseed_strength, 0,
+                    p.total_images, (tr, w, C),
+                    seed_resize=engine._seed_resize_latent(p),
+                    pin_index=p.same_seed), tr
+
+        if g.key[EXPANSION_AT] is not None:
+            # the tickets' noise is drawn under the scan's first decode
+            # chunk, when this thread has nothing to do but wait
+            shown = self._group_expand(
+                live, runs,
+                lambda: ahead.update(
+                    (k, draw(p)) for k, p in enumerate(runs)), later)
+            pinned = int(runs[0].context_chunks or 0)
+        # context length pinned to the group max so every merged request
+        # pads its conditioning identically (same contract the fleet pins
+        # via payload.context_chunks); an expansion's own pin floors it,
+        # as it does a solo request's
+        chunks = max([engine.request_context_chunks(p) for p in runs]
+                     + [pinned])
         perf_on = obs_perf.enabled()
         counts, noise_parts, key_parts = [], [], []
         ctx_rows, pooled_rows = [], []
         true_rows_l, ctx_true_u_l, ctx_true_c_l = [], [], []
         true_tok = padded_tok = 0
         ctx_u = pooled_u = None
-        for t in live:
-            p = t.run.model_copy()
+        for k, (t, p) in enumerate(zip(live, runs)):
             p.context_chunks = chunks
             n_p = p.total_images
             counts.append(n_p)
@@ -1061,26 +1178,20 @@ class ServingDispatcher:
                         f"resolvable at dispatch")
                 engine._traced_lora = ts
                 row_sets += [ts] * n_p
-            tr = h      # ragged: the TRUE latent rows, zero-padded to h
-            if ragged_mode:
-                tw, th = engine._ragged_plan(p) or (width, height)
-                tr = min(h, -(-th // f))
-            with obs_spans.span("noise"):
-                part = rng.batch_noise(
-                    p.seed, p.subseed, p.subseed_strength, 0, n_p,
-                    (tr, w, C), seed_resize=engine._seed_resize_latent(p),
-                    pin_index=p.same_seed)
+            part, tr = ahead.pop(k, None) or draw(p)
+            # an expanded request of several images: a text an image
+            own = {"prompts": p.all_prompts} if p.all_prompts else {}
             if ragged_mode:
                 noise_parts.append(jnp.pad(
                     part, ((0, 0), (0, h - tr), (0, 0), (0, 0))))
                 (cu, cc), (pu, pc), (ct_u, ct_c) = engine.encode_prompts(
-                    p, ragged=True)
+                    p, ragged=True)     # (never an expanded request's)
                 true_rows_l += [tr] * n_p
                 ctx_true_u_l += [ct_u] * n_p
                 ctx_true_c_l += [ct_c] * n_p
             else:
                 noise_parts.append(part)
-                (cu, cc), (pu, pc) = engine.encode_prompts(p)
+                (cu, cc), (pu, pc) = engine.encode_prompts(p, **own)
             if perf_on:
                 try:
                     tt, pt = engine.request_token_stats(p, chunks=chunks)
@@ -1139,7 +1250,10 @@ class ServingDispatcher:
 
             x = engine._place_batch(noise.astype(jnp.float32) * sigmas[0])
         return {
-            "live": live, "counts": counts, "rp": rp,
+            "live": live, "counts": counts, "rp": rp, "shown": shown,
+            # an expansion's counters, fetched once the UNet is queued
+            "account": functools.partial(self._run_each, later)
+            if later else None,
             "width": width, "height": height, "h": h, "f": f,
             "x": x, "keys": keys,
             "ctx": (ctx_u, ctx_c), "pooled": (pooled_u, pooled_c),
@@ -1171,7 +1285,8 @@ class ServingDispatcher:
             rp, built["x"], built["keys"], (ctx_u, ctx_c),
             (pooled_u, pooled_c),
             width, height, 0, rp.steps, "txt2img", None, None, (),
-            ragged=built["ragged"], lora=built["lora"])
+            ragged=built["ragged"], lora=built["lora"],
+            account=built["account"])
         self._drain_cache_notes(live[0].request_id, embed=False)
         if perf_on:
             # masked pixels: resident tail rows the ragged kernel skips —
@@ -1231,10 +1346,13 @@ class ServingDispatcher:
         # place in the group, the ticket, its gallery), the row's index in
         # the gallery, whether it is the ticket's last
         owners = []
-        for k, (t, n_p) in enumerate(zip(live, counts)):
-            out = GenerationResult(parameters=t.payload.model_dump())
-            owners += [(k, t, out, j, j + 1 == n_p) for j in range(n_p)]
-        for (img_dev, *_), (k, t, out, j, last) in zip(entries, owners):
+        for k, (t, n_p, seen) in enumerate(zip(live, counts,
+                                               built["shown"])):
+            out = GenerationResult(parameters=seen.model_dump())
+            owners += [(k, t, seen, out, j, j + 1 == n_p)
+                       for j in range(n_p)]
+        for (img_dev, *_), (k, t, seen, out, j, last) in zip(entries,
+                                                           owners):
             with trace.STATS.timer("vae_decode_fetch"):
                 img = engine._fetch_decoded(img_dev)
             if jr_on and (k, j) == (0, 0):
@@ -1244,8 +1362,9 @@ class ServingDispatcher:
             with obs_spans.span("merge.split", request=k, image=j):
                 ow, oh = t.payload.width, t.payload.height
                 if not t.cancelled.is_set():
+                    # (an expanded ticket's: with the text it drew from)
                     engine._append_image(
-                        out, t.payload,
+                        out, seen,
                         crop(img, ow, oh) if t.bucketed else img, j, ow, oh)
                 if last and t.cancelled.is_set():
                     t.result = self._empty_result(t)

@@ -676,7 +676,16 @@ class ExpanderStats:
     step, whichever the head read; over ``decode_steps``: passes a token); ``exit_pass``, the tokens made by the
     pass whose state the head read for them (index 0 is the first pass);
     ``exit_lambda_max``, the largest exit probability a gate gave. A model
-    of one pass leaves them 0, empty and 0."""
+    of one pass leaves them 0, empty and 0.
+    ``requests`` counts SCANS (one ``record`` each: a request the
+    dispatcher ran solo is one or more of its own). ``scans_joined`` are
+    those among them whose sequences continued prompts of their own behind
+    one kept instruction (pipeline/expand.py:expand_group: the requests of
+    a dispatch group), ``requests_joined`` the requests they carried and
+    ``prompts_joined`` their distinct prompts: 2.0 and 2.0 a joined scan
+    where two clients' requests pair every time, 0 where every request
+    runs alone. A joined scan's steps are in ``decode_steps`` ONCE and its
+    tokens in ``tokens_decoded`` once a sequence, as a forked one's."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -685,6 +694,9 @@ class ExpanderStats:
     def clear(self) -> None:
         with self._lock:
             self.requests = 0          # guarded-by: _lock
+            self.scans_joined = 0      # guarded-by: _lock
+            self.requests_joined = 0   # guarded-by: _lock
+            self.prompts_joined = 0    # guarded-by: _lock
             self.layer_passes = 0      # guarded-by: _lock
             self.exit_pass: List[int] = []  # guarded-by: _lock
             self.exit_lambda_max = 0.0  # guarded-by: _lock
@@ -837,7 +849,8 @@ class ExpanderStats:
                exit_lambda_max: float = 0.0, state_bytes_stepped: int = 0,
                fork_bytes_copied: int = 0, zero_expert_picks: int = 0,
                expert_picks_held: int = 0, expert_calls: int = 0,
-               expert_calls_unread: int = 0) -> None:
+               expert_calls_unread: int = 0, requests_joined: int = 0,
+               prompts_joined: int = 0) -> None:
         """``load`` is (expert layers, held experts) counts of one
         request; ``decode_steps`` the steps its decode executables ran
         (whole chunks, so at least ``decoded / sequences - 1``), each a
@@ -845,6 +858,9 @@ class ExpanderStats:
         rows = [[int(n) for n in row] for row in load]
         with self._lock:
             self.requests += 1
+            self.scans_joined += bool(requests_joined)
+            self.requests_joined += int(requests_joined)
+            self.prompts_joined += int(prompts_joined)
             self.prefilled += int(prefilled)
             self.from_prefix += int(from_prefix)
             self.sequences += int(sequences)
@@ -886,6 +902,9 @@ class ExpanderStats:
             mean = sum(flat) / len(flat) if flat else 0.0
             return {
                 "requests": self.requests,
+                "scans_joined": self.scans_joined,
+                "requests_joined": self.requests_joined,
+                "prompts_joined": self.prompts_joined,
                 "tokens_prefilled": self.prefilled,
                 "tokens_from_prefix_cache": self.from_prefix,
                 "sequences": self.sequences,

@@ -346,6 +346,88 @@ def forked_against_alone(case, params, live, batch, own_slots=2 * STEPS,
 
 
 @functools.lru_cache(maxsize=None)
+def _joiner(region, slots):
+    return jax.jit(functools.partial(kv.joined_rows, region=region,
+                                     slots=slots))
+
+
+def joined_against_alone(case, params, users, batch, prefix=21,
+                         capacity=256):
+    """``len(users)`` sequences that continue prompts of their OWN
+    (``users``: their lengths, unequal) behind one shared prefix, JOINED
+    into one cache (cache/kv.py:joined_rows) and decoded as ``batch``
+    sequences a step, against each decoded alone from its own
+    one-sequence cache: a chunk of steps token for token (every draw keyed
+    by the sequence's own position), the shared range untouched, the
+    experts' load without the pad, and the logits of a few teacher-forced
+    steps after it. The prefix's 21 positions have wrapped a ring of 8, and
+    a window's reach into it differs a sequence."""
+    cfg = case.cfg
+    fns = executables(cfg)
+    first, held = cfg.vocab
+    live = len(users)
+    heat = jnp.float32(1.0)
+    ids = jax.random.randint(jax.random.key(1), (prefix,), first,
+                             first + held)
+    _, shared, _ = run(cfg, params, ids, 0, prefix, empty(cfg, capacity),
+                       all_logits=False)
+    own_keys = keys(list(range(live)) + [live - 1] * (batch - live))
+    caches, firsts = [], []
+    for b, user in enumerate(users):
+        ids = jax.random.randint(jax.random.key(100 + b),
+                                 (kv.chunk_bucket(user),), first,
+                                 first + held)
+        row, cache, _ = run(cfg, params, ids, prefix, user, shared,
+                            all_logits=False)
+        caches.append(cache)
+        firsts.append(lm.sample(row[0], own_keys[b], prefix + user, heat,
+                                first))
+    pad = batch - live
+    region = max(kv.chunk_bucket(user) for user in users)
+    own = _joiner(region, 2 * STEPS)(
+        tuple(caches + caches[-1:] * pad),
+        jnp.asarray(list(users) + list(users[-1:]) * pad, jnp.int32),
+        jnp.int32(prefix))
+    assert np.array_equal(own[lm.OWN_FROM][0][:live, 0],
+                          region - np.asarray(users))
+    joined, tokens, position, made, load, none_held, *_ = fns.together(
+        params, kv.forked(shared, own), jnp.stack(firsts + firsts[-1:] * pad),
+        jnp.int32(prefix + region), own_keys, heat, jnp.int32(live))
+    assert made.shape == (STEPS, batch)
+    assert int(position) == prefix + region + STEPS
+    for name, twin in lm.SHARED_OF.items():     # the shared range: as it was
+        for mine, theirs in zip(shared.get(name, ()), joined.get(twin, ())):
+            assert np.array_equal(np.asarray(mine), np.asarray(theirs))
+    assert np.all(np.asarray(joined[lm.FORKED_AT][0]) == prefix)
+    alone, total = [], 0
+    for b, user in enumerate(users):
+        after, last, at, steps, own_load, *_ = fns.alone(
+            params, caches[b], firsts[b], jnp.int32(prefix + user),
+            own_keys[b], heat)
+        assert np.array_equal(steps, made[:, b]), (b, user)
+        assert int(last) == int(tokens[b])
+        alone.append(after)
+        total = total + own_load
+    if cfg.expert_layers:
+        assert np.array_equal(load, total)      # the pad is not counted
+    forced = jax.random.randint(jax.random.key(8), (4, batch),
+                                *np.cumsum(cfg.vocab))
+    # (a joined step sums a prompt's keys among its sequence's own rows,
+    # a step alone among the buffer's: another order of float32 sums)
+    tol = 3 * case.step_tolerance
+    for t, row in enumerate(forced):
+        logits, joined = fns.forked_step(
+            params, joined, row, jnp.int32(prefix + region + STEPS + t),
+            jnp.int32(live))
+        for b, user in enumerate(users):
+            want, alone[b] = fns.one_step(
+                params, alone[b], row[b],
+                jnp.int32(prefix + user + STEPS + t))
+            np.testing.assert_allclose(logits[b], want[0], rtol=tol,
+                                       atol=tol)
+
+
+@functools.lru_cache(maxsize=None)
 def tiny_params():
     """The tiny SD family's weights without a program: ``init_params``'
     tree as shapes, filled on the host as its initialisers fill it (a
@@ -552,6 +634,15 @@ class SequencesOfOneStep:
     def test_a_forked_decode_is_each_sequence_alone(self, params, user,
                                                     live, batch):
         forked_against_alone(self.CASE, params, live, batch, user=user)
+
+    @pytest.mark.parametrize("users,batch", [
+        ((5, 16, 63), 4),       # one chunk bucket, a pad
+        ((7, 100), 2)])         # across two
+    def test_a_joined_decode_is_each_sequence_alone(self, params, users,
+                                                    batch):
+        """Sequences that continue prompts of their own behind one kept
+        prefix (the requests of a dispatch group), joined into one scan."""
+        joined_against_alone(self.CASE, params, users, batch)
 
     def check_fork(self, forked):
         """What only this model's forked cache must show."""

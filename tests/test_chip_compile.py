@@ -529,6 +529,21 @@ def _forked_on(chip, cache, sequences, own_slots):
         jax.eval_shape(lambda c: kv.fork(c, sequences, own_slots), cache))
 
 
+def _joined_on(chip, cache, sequences, region, own_slots):
+    """``cache``'s shapes as those of ``sequences`` one-sequence caches
+    joined behind one of them (cache/kv.py:joined_rows), placed on
+    ``chip``."""
+    from stable_diffusion_webui_distributed_tpu.cache import kv
+
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        jax.eval_shape(
+            lambda c, lengths, at: kv.forked(c, kv.joined_rows(
+                (c,) * sequences, lengths, at, region, own_slots)),
+            cache, jax.ShapeDtypeStruct((sequences,), jnp.int32), scalar))
+
+
 @pytest.mark.parametrize("preset", ["TINY_LOOP_EXPAND",
                                     "TINY_WINDOW_EXPAND"])
 def test_a_forked_decode_chunk_compiles_for_v5e(one_chip, preset):
@@ -573,6 +588,10 @@ def test_a_forked_decode_chunk_compiles_for_v5e(one_chip, preset):
 #: arguments' GB, the expert kernels, MB of temporaries, MB aliased)
 EXPANDER_EXECUTABLES = [
     ("decode", "sd15_laguna_expander", 1024, 11.1, 4, 64, 14),
+    # two requests' sequences behind the kept instruction (a JOINED cache:
+    # each prompt's 64 slots and 384 decode slots a sequence, 33 MB donated
+    # with the instruction's 14.7): the same four expert kernels
+    ("decode2_joined", "sd15_laguna_expander", 1024, 11.1, 4, 64, 30),
     ("prefill", "sd15_laguna_expander", 1024, 11.1, 0, 64, 14),
     ("decode", "sd15_qwen3next_expander", 1024, 10.8, 12, 64, 14),
     # eighteen expert kernels and forty mixers of two; forty transposed
@@ -756,6 +775,8 @@ def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
              for name, rows in lm.cache_shapes(cfg, capacity).items()}
     if which == "decode4":      # four sequences forked, 256 slots each
         cache = _forked_on(one_chip, cache, 4, 256)
+    elif which == "decode2_joined":
+        cache = _joined_on(one_chip, cache, 2, 64, 384)
     small = {name: [jax.ShapeDtypeStruct(shape, jnp.float32)
                     for shape in rows]
              for name, rows in lm.cache_shapes(cfg, 8).items()}
@@ -772,11 +793,12 @@ def test_the_prompt_expanders_executables_compile_and_fit_one_v5e(
         lowered = jax.jit(lm.decode_chunk_fn(module, 32),
                           donate_argnums=(1,)).lower(
             params, cache, scalar, scalar, key, heat)
-    elif which == "decode4":
+    elif which in ("decode4", "decode2_joined"):
+        rows = 4 if which == "decode4" else 2
         lowered = jax.jit(lm.decode_sequences_fn(module, 32),
                           donate_argnums=(1,)).lower(
-            params, cache, on_chip((4,), jnp.int32), scalar,
-            on_chip((4,), jax.random.key(0).dtype), heat, scalar)
+            params, cache, on_chip((rows,), jnp.int32), scalar,
+            on_chip((rows,), jax.random.key(0).dtype), heat, scalar)
     else:
         tokens = 2048 if which == "prefill2048" else 64
         lowered = jax.jit(lm.prefill_fn(module), donate_argnums=(1,)).lower(

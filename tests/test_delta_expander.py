@@ -182,6 +182,17 @@ class TestPaddingAndSnapshots(contract.PaddingAndSnapshots):
         close(after["state"], whole["state"])
 
 
+    @pytest.mark.parametrize("users,batch", [((5, 16, 63), 4),
+                                             ((7, 100), 2)])
+    def test_a_joined_decode_is_each_sequence_alone(self, params, users,
+                                                    batch):
+        """Sequences that continue prompts of their own behind one kept
+        prefix: each one's recurrent state and kept inputs are those its
+        own prompt's chunk left, its window and full layers' rows its
+        own."""
+        contract.joined_against_alone(CASE, params, users, batch)
+
+
 # -- the cache manager --------------------------------------------------------
 
 class TestTheCacheManager(contract.TheCacheManager):

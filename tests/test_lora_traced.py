@@ -348,7 +348,9 @@ class TestGroupKeyCells:
     def test_gate_off_tagged_keys_adapterless_cell(self):
         p = payload("a cow <lora:a:0.8>")
         key = ServingDispatcher._group_key(None, p)
-        assert len(key) == 14
+        # (fifteen since PR 72: the expansion script's arguments, None for
+        # a plain request, in front of the adapter cell)
+        assert len(key) == 15
         assert key[-3:-1] == (0, 0)
         assert isinstance(key[-1], str)
         # tagless payloads share the cell — adapterless grouping intact

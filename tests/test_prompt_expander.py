@@ -180,6 +180,60 @@ class TestMasks:
                                        atol=1e-6)
         assert not np.allclose(out[0], out[1])
 
+    @pytest.mark.parametrize("window", [0, 12, 24])
+    def test_two_ranges_whose_own_rows_hold_a_position_a_sequence(
+            self, window):
+        """A joined cache's site: the sequences stand at positions of
+        their own (prompts of 2, 9 and 16 rows right-aligned in 16 slots
+        behind 20 shared rows, then 3 decoded), so an own slot holds
+        another position a sequence (``own_pos`` ``(B, T)``, none in front
+        of a sequence's first) and a window reaches another depth into the
+        shared range for each: against each query alone over the shared
+        keys followed by its own real rows."""
+        b, kv_heads, dim, s, region, made = 3, 2, 8, 32, 16, 3
+        forked_at, users = 20, np.asarray([2, 9, 16])
+        key = jax.random.key(5)
+
+        def normal(i, shape):
+            return jax.random.normal(jax.random.fold_in(key, i), shape)
+
+        q = normal(0, (b, 4 * kv_heads, dim))
+        k_shared, v_shared = normal(1, (s, kv_heads, dim)), \
+            normal(2, (s, kv_heads, dim))
+        k_own, v_own = normal(3, (b, region + 8, kv_heads, dim)), \
+            normal(4, (b, region + 8, kv_heads, dim))
+        own_from = jnp.asarray(region - users)
+        start = forked_at + region + made - 1       # the step's, common
+        q_pos = start - own_from
+        shared_pos = jnp.where(jnp.arange(s) < forked_at, jnp.arange(s), -1)
+        slots = jnp.arange(region + 8)
+        common = jnp.where(slots < region + made, forked_at + slots, -1)
+        own_pos = lm.own_positions(common, forked_at, own_from)
+        assert own_pos.shape == (b, region + 8)
+        for i, user in enumerate(users):    # its prompt, then what it made
+            assert np.array_equal(
+                own_pos[i][own_pos[i] >= 0],
+                forked_at + np.arange(user + made))
+        out, _ = attention.attend_two_ranges(
+            q, k_shared, v_shared, k_own, v_own, q_pos, shared_pos, own_pos,
+            scale=dim ** -0.5, window=window)
+        reach = []
+        for i in range(b):
+            want, _ = attention.attend_positions(
+                q[i][None], jnp.concatenate([k_shared, k_own[i]]),
+                jnp.concatenate([v_shared, v_own[i]]), q_pos[i][None],
+                jnp.concatenate([shared_pos, own_pos[i]]),
+                scale=dim ** -0.5, window=window)
+            np.testing.assert_allclose(out[i], want[0], rtol=1e-5,
+                                       atol=1e-6)
+            reach.append(int(np.sum(np.asarray(attention._seen(
+                q_pos[i][None], shared_pos, window)))))
+        # the shortest prompt's window reaches furthest into what is shared
+        assert reach == ([forked_at] * 3 if not window
+                         else sorted(reach, reverse=True))
+        assert window != 12 or reach == [7, 0, 0]
+        assert window != 24 or reach == [19, 12, 5]
+
     def test_two_ranges_and_a_query_that_sees_nothing(self):
         """Empty slots in both ranges; the second sequence's query stands
         before every key and gets zeros."""
@@ -850,7 +904,10 @@ class TestCallsThatReadNothing:
         kernel_cells = next(m for m in bench.manifest["per_layer"]
                             if m["name"] == "expert_kernel_sites")
         assert entry["workloads"] == kernel_cells["workloads"]
-        assert len(entry["workloads"]) == 9
+        # the nine cells whose expander has expert layers on the kernel,
+        # and since PR 72 the two-client cell of the first of them
+        assert len(entry["workloads"]) == 10
+        assert entry["workloads"][-1] == "sd15_expand_pair"
 
 
 class TestTheShareOfALayer:
@@ -1229,7 +1286,8 @@ class TestEnginePath:
         engine.txt2img(CASE.payload())
         block = METRICS.summary()["expander"]
         assert set(block) == {
-            "requests", "tokens_prefilled", "tokens_from_prefix_cache",
+            "requests", "scans_joined", "requests_joined", "prompts_joined",
+            "tokens_prefilled", "tokens_from_prefix_cache",
             "sequences", "tokens_decoded", "decode_steps", "experts_read",
             "rows_attended", "rows_read", "rows_read_shared",
             "tokens_no_held_expert", "expert_tokens",
@@ -1452,12 +1510,17 @@ class TestDrawnAhead:
 
 class TestDispatcher:
     def test_an_expanded_request_never_shares_a_dispatch(self, engine):
+        """... where the ladder has no rung for a second request of its
+        size, or the expander decodes one sequence a step; with both it
+        shares a dispatch as a plain request does (the rule since PR 72:
+        tests/test_request_groups.py drives the groups)."""
         from stable_diffusion_webui_distributed_tpu.serving.dispatcher import (
             ServingDispatcher,
         )
 
         class Stub:
-            max_batch = 4
+            max_batch = 1
+            _expansion = ServingDispatcher._expansion
 
         stub = Stub()
         stub.engine = engine
@@ -1465,9 +1528,109 @@ class TestDispatcher:
         assert ServingDispatcher._coalescable(
             stub, CASE.payload(alwayson_scripts={}))
         assert not ServingDispatcher._coalescable(stub, CASE.payload())
+        stub.max_batch = 4      # the batch-4 cells: four images a request
+        assert not ServingDispatcher._coalescable(
+            stub, CASE.payload(batch_size=4))
+        assert ServingDispatcher._coalescable(stub, CASE.payload())
+        assert ServingDispatcher._coalescable(
+            stub, CASE.payload(batch_size=2))
+        stub.engine = contract.engine_for(configs.TINY_CONV_EXPAND)
+        assert not ServingDispatcher._coalescable(stub, CASE.payload())
         stub.engine = Engine(configs.TINY, dict(tiny_params()),
                              state=GenerationState())
+        stub.max_batch = 1
         assert ServingDispatcher._coalescable(stub, CASE.payload())
+
+
+class TestRequestsOfOneScan:
+    """``expand_group``: the images of several requests as the sequences
+    of one joined scan (the dispatcher's group stage)."""
+
+    MEMBERS = [("a cow in a valley", 1234, [0]),
+               ("an old lighthouse on a cliff above a stormy sea at dusk "
+                "in the first snow of the winter", 77, [0, 1])]
+
+    @pytest.mark.parametrize("users,batch", [((5, 16, 63), 4),
+                                             ((7, 100), 2)])
+    def test_a_joined_decode_is_each_sequence_alone(self, params, users,
+                                                    batch):
+        """The preset of the configuration the two-client cell runs."""
+        contract.joined_against_alone(CASE, params, users, batch)
+
+    def test_every_image_gets_the_text_it_gets_alone(self, engine):
+        args = prompt_expansion_args(CASE.payload())
+        alone = [[engine.expander.expand(prompt, args, seed, i)
+                  for i in indices] for prompt, seed, indices in self.MEMBERS]
+        EXPANDER.clear()
+        assert engine.expander.expand_group(self.MEMBERS, args,
+                                            rows=4) == alone
+        stats = EXPANDER.summary()
+        assert stats["requests"] == stats["scans_joined"] == 1
+        assert stats["requests_joined"] == stats["prompts_joined"] == 2
+        assert stats["sequences"] == 3 and stats["tokens_decoded"] == 120
+        assert stats["decode_steps"] == 2 * contract.STEPS  # counted once
+        # the 31 kept rows once a step, the prompts' 5 + 2 x 19 and what
+        # each decodes once a sequence
+        steps = 2 * contract.STEPS
+        assert stats["rows_read_shared"] == steps * 31
+        assert stats["rows_read"] == steps * 31 + steps * 43 \
+            + 3 * steps * (steps + 1) // 2
+        assert stats["rows_attended"] == 3 * steps * 31 + steps * 43 \
+            + 3 * steps * (steps + 1) // 2
+
+    def test_a_lone_request_pads_to_the_rung_and_one_row_takes_the_old_path(
+            self, engine):
+        args = prompt_expansion_args(CASE.payload())
+        prompt, seed, _ = self.MEMBERS[0]
+        alone = engine.expander.expand(prompt, args, seed, 0)
+        before = set(engine.executable_keys())
+        EXPANDER.clear()
+        assert engine.expander.expand_group(
+            [(prompt, seed, [0])], args, rows=1) == [[alone]]
+        assert EXPANDER.summary()["scans_joined"] == 0
+        assert set(engine.executable_keys()) == before
+        assert engine.expander.expand_group(
+            [(prompt, seed, [0])], args, rows=2) == [[alone]]
+        assert EXPANDER.summary()["requests_joined"] == 1
+        # (the keys of two, ``("expand_keys", 2)``, where no test before
+        # this one ran a batch of two)
+        made = set(engine.executable_keys()) - before - {("expand_keys", 2)}
+        assert made == {
+            ("expand_join", contract.CAPACITY, 2, 64, 2 * contract.STEPS),
+            ("expand_decode_chunk", contract.STEPS, contract.CAPACITY, 2,
+             "joined", 64)}
+        # the pair that follows compiles nothing
+        METRICS.clear()
+        engine.expander.expand_group(self.MEMBERS[:1] + [
+            (self.MEMBERS[1][0], 77, [0])], args, rows=2)
+        assert not METRICS.summary()["compiles"]
+
+    def test_spans_of_a_joined_scan(self, engine):
+        from stable_diffusion_webui_distributed_tpu.obs import spans
+
+        args = prompt_expansion_args(CASE.payload())
+        spans.TRACER.clear()
+        with spans.request("joined-spans"):
+            engine.expander.expand_group(self.MEMBERS, args, rows=4)
+        by_name = {}
+        for e in contract.span_events():
+            by_name.setdefault(e["name"], []).append(e["args"])
+        assert [(a["sequences"], a["requests"])
+                for a in by_name["expand"]] == [(3, 2)]
+        assert {(a["sequences"], a["requests"])
+                for a in by_name["expand.decode_chunk"]} == {(3, 2)}
+        (fork,) = by_name["expand.fork"]
+        assert fork["joined"] and fork["sequences"] == 4 \
+            and fork["prompts"] == 2
+        # the shared range's copy and one a sequence; a prompt a sequence
+        assert len(by_name["expand.prefix_copy"]) == 4
+        assert [a["tokens"] for a in by_name["expand.prefill"]] \
+            == [5, 19, 19]
+        kinds = [e["args"]["kind"] for e in
+                 spans.TRACER.export_chrome()["traceEvents"]
+                 if e.get("name") == "device.run" and e.get("ph") == "b"]
+        assert kinds.count("expand_join") == 1
+        assert kinds.count("expand_decode_chunk") == 2
 
 
 class TestSharding:

@@ -8,9 +8,20 @@ newest stays in flight under the next group's denoise. These tests hold that
 loop to the request's groups run one by one at their seeds: the golden pin,
 ControlNet units, an interrupt and a preemption between two groups, the
 order of decodes and fetches, and the padded rows of a remainder group.
+
+``TestExpandedRequestsOfOneGroup`` holds the OTHER kind of group, the
+dispatcher's, to the same rule where its requests are expanded ones
+(serving/dispatcher.py, pipeline/expand.py:expand_group): two clients'
+requests behind one instruction share one decode scan and one UNet
+dispatch, and each gets what it gets alone.
 """
 
+import threading
+import time
+
 import pytest
+
+from stable_diffusion_webui_distributed_tpu.models import configs
 
 from stable_diffusion_webui_distributed_tpu.models.configs import TINY
 from stable_diffusion_webui_distributed_tpu.pipeline.engine import Engine
@@ -20,7 +31,16 @@ from stable_diffusion_webui_distributed_tpu.pipeline.payload import (
 from stable_diffusion_webui_distributed_tpu.runtime.interrupt import (
     GenerationState,
 )
-from stable_diffusion_webui_distributed_tpu.serving.metrics import METRICS
+from stable_diffusion_webui_distributed_tpu.serving.bucketer import (
+    ShapeBucketer,
+)
+from stable_diffusion_webui_distributed_tpu.serving.dispatcher import (
+    EXPANSION_AT, ServingDispatcher,
+)
+from stable_diffusion_webui_distributed_tpu.serving.metrics import (
+    EXPANDER, METRICS,
+)
+from tests import expander_contract as contract
 from test_fleet import OneShotHook
 from test_goldens import _check, _controlnet_params, _hint_b64
 from test_pipeline import init_params
@@ -209,3 +229,163 @@ class TestGroupsOfARequest:
         assert after["dispatches"] - before["dispatches"] == 3
         assert three.images == full.images[:3]
         assert three.seeds == [64, 65, 66]
+
+
+# -- the dispatcher's groups of expanded requests ------------------------------
+
+#: the tiny preset of the configuration the two-client cell runs: window
+#: and full attention, a dense layer and expert layers
+EXPANDED = contract.Case(configs.TINY_EXPAND, None, word="rule")
+LONG = ("an old lighthouse on a cliff above a stormy sea at dusk in the "
+        "first snow of the winter")
+
+
+@pytest.fixture(scope="module")
+def expanding():
+    return EXPANDED.engine()
+
+
+def _dispatcher(engine, ladder, window=0.6):
+    return ServingDispatcher(
+        engine, bucketer=ShapeBucketer(shapes=[(32, 32)], batches=ladder),
+        window=window)
+
+
+def _at_once(dispatcher, payloads, cancel=None):
+    """Every payload from a thread of its own; the results in order."""
+    results, errors = [None] * len(payloads), []
+
+    def run(i, p):
+        try:
+            results[i] = dispatcher.submit(p)
+        except Exception as e:  # noqa: BLE001 - surfaced by the assert
+            errors.append(e)
+
+    threads = [threading.Thread(target=run, args=(i, p))
+               for i, p in enumerate(payloads)]
+    for t in threads:
+        t.start()
+    if cancel:
+        time.sleep(0.15)        # inside the coalesce window
+        assert dispatcher.cancel(cancel)
+    for t in threads:
+        t.join()
+    assert not errors, errors
+    return results
+
+
+class TestExpandedRequestsOfOneGroup:
+    @staticmethod
+    def _pair(**second):
+        return [EXPANDED.payload(seed=11, request_id="first"),
+                EXPANDED.payload(**dict(dict(prompt=LONG, seed=12,
+                                             request_id="second"),
+                                        **second))]
+
+    @pytest.fixture(scope="class")
+    def alone(self, expanding):
+        """Each of the pair through ``_run_solo``: a ladder of one rung
+        has none for a second request."""
+        solo = _dispatcher(expanding, [1], window=0.0)
+        assert not solo._coalescable(self._pair()[0])
+        return [solo.submit(p) for p in self._pair()]
+
+    def test_two_requests_share_one_scan_and_get_what_they_get_alone(
+            self, expanding, alone):
+        pair = _dispatcher(expanding, [2])
+        assert pair._coalescable(self._pair()[0])
+        # a lone request pads: UNet rows to the rung, the scan to its
+        # bucket with one live sequence, and compiles what a pair runs
+        lone = pair.submit(self._pair()[0])
+        assert lone.prompts == alone[0].prompts
+        assert lone.images == alone[0].images
+        EXPANDER.clear()
+        METRICS.clear()
+        got = _at_once(pair, self._pair())
+        stats, served = EXPANDER.summary(), METRICS.summary()
+        assert not served["compiles"]       # nothing on the pair
+        assert served["coalesce_factor"] == 2.0
+        assert stats["requests"] == stats["scans_joined"] == 1
+        assert stats["requests_joined"] == stats["prompts_joined"] == 2
+        assert stats["tokens_decoded"] == 2 * 40
+        assert stats["decode_steps"] == 2 * contract.STEPS
+        for mine, solo in zip(got, alone):
+            assert mine.prompts == solo.prompts != [LONG]
+            assert mine.seeds == solo.seeds
+            assert mine.infotexts == solo.infotexts
+            assert mine.images == solo.images
+            assert mine.parameters["prompt"] == solo.parameters["prompt"]
+        assert got[0].prompts != got[1].prompts
+
+    def test_a_request_of_two_images_is_two_sequences_of_the_scan(
+            self, expanding):
+        solo = _dispatcher(expanding, [2], window=0.0)
+        p = EXPANDED.payload(prompt=LONG, seed=31, batch_size=2)
+        assert not solo._coalescable(p)     # no rung for a second of two
+        want = solo.submit(p)
+        group = _dispatcher(expanding, [4], window=0.0)
+        assert group._coalescable(p)
+        EXPANDER.clear()
+        got = group.submit(p)
+        assert EXPANDER.summary()["sequences"] == 2
+        assert got.prompts == want.prompts and len(set(got.prompts)) == 2
+        assert got.infotexts == want.infotexts
+
+    @pytest.mark.parametrize("other", [
+        {"instruction": "another rule"}, {"max_new_tokens": 33},
+        {"temperature": 0.5}, {"ignore_eos": False}, {"context_chunks": 2},
+        None])
+    def test_other_script_arguments_or_none_do_not_join(self, expanding,
+                                                        other):
+        pair = _dispatcher(expanding, [2])
+        base = EXPANDED.payload()
+        scripts = {} if other is None else EXPANDED.script(**other)
+        key = pair._group_key(base)
+        assert key == pair._group_key(EXPANDED.payload(prompt=LONG, seed=5))
+        assert key != pair._group_key(
+            EXPANDED.payload(alwayson_scripts=scripts))
+        # the places consumers read stay where they were
+        assert key[-1] == "bf16" or isinstance(key[-1], str)
+        assert key[-3:-1] == (0, 0) and key[8] == 1
+        assert key[EXPANSION_AT][0].startswith("rule0 rule1")
+        plain = pair._group_key(EXPANDED.payload(alwayson_scripts={}))
+        assert plain[EXPANSION_AT] is None
+        # a worker without an expander reads the script as a plain request
+        assert ServingDispatcher._group_key(None, base)[EXPANSION_AT] is None
+
+    def test_a_different_budget_runs_in_a_group_of_its_own(self, expanding,
+                                                           alone):
+        pair = _dispatcher(expanding, [2], window=0.2)
+        METRICS.clear()
+        got = _at_once(pair, [
+            self._pair()[0],
+            EXPANDED.payload(prompt=LONG, seed=12,
+                             alwayson_scripts=EXPANDED.script(
+                                 max_new_tokens=33))])
+        assert METRICS.summary()["coalesce_factor"] == 1.0
+        assert got[0].prompts == alone[0].prompts
+        assert got[1].prompts != alone[1].prompts
+
+    @pytest.mark.parametrize("ladder,images,joins", [
+        ([1], 1, False), ([4], 4, False), ([2], 1, True), ([4], 2, True),
+        ([1, 2, 4], 3, False)])
+    def test_the_ladder_decides(self, expanding, ladder, images, joins):
+        """Ladder "1" at one image and ladder "4" at four (every expander
+        cell the benchmark had) leave the request solo."""
+        d = _dispatcher(expanding, ladder)
+        assert d._coalescable(
+            EXPANDED.payload(batch_size=images)) is joins
+        assert d._coalescable(EXPANDED.payload(
+            batch_size=images, alwayson_scripts={}))
+
+    def test_a_cancelled_members_sequence_is_dropped(self, expanding, alone):
+        # (a rung of four: two requests do not fill the group, so the
+        # window is still open when the cancel lands)
+        pair = _dispatcher(expanding, [4])
+        EXPANDER.clear()
+        got = _at_once(pair, self._pair(), cancel="second")
+        assert got[1].images == [] and got[1].parameters["cancelled"]
+        assert got[0].prompts == alone[0].prompts
+        assert got[0].images == alone[0].images
+        stats = EXPANDER.summary()      # the scan carried the one left
+        assert stats["sequences"] == 1 and stats["requests_joined"] == 1
